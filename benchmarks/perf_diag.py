@@ -13,15 +13,12 @@ from __future__ import annotations
 import json
 import os
 import re
+import statistics
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
-
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(os.path.dirname(os.path.dirname(
-                          os.path.abspath(__file__))), ".jax_cache"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2")
 
 import numpy as np  # noqa: E402
 
@@ -48,11 +45,22 @@ def hlo_stats(text):
     return interesting
 
 
-from benchmarks.pallas_smoke import _force, _time_median  # noqa: E402
+def _force(x):
+    """Force execution: device_get of a value completes only after the
+    program producing it does."""
+    import jax
+    return float(np.asarray(jax.device_get(x)).ravel()[0])
 
 
 def time_program(fn, reps=10):
-    return _time_median(lambda: _force(fn()), reps=reps)
+    """Median seconds of ``reps`` forced runs after one warm run."""
+    _force(fn())
+    laps = []
+    for _ in range(reps):
+        tic = time.perf_counter()
+        _force(fn())
+        laps.append(time.perf_counter() - tic)
+    return statistics.median(laps)
 
 
 def setup_ours():

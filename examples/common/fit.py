@@ -26,14 +26,22 @@ def add_fit_args(parser):
     parser.add_argument("--kv-store", type=str, default="local")
     parser.add_argument("--model-prefix", type=str, default=None)
     parser.add_argument("--load-epoch", type=int, default=None)
+    parser.add_argument("--gpus", type=str, default="",
+                        help="accelerator chips to train on, e.g. '0' or "
+                        "'0,1,2,3' (TPU chips on a TPU host); empty "
+                        "trains on the CPU")
     parser.add_argument("--num-devices", type=int, default=1,
-                        help="data-parallel device count (virtual CPU "
-                        "devices or TPU chips)")
-    parser.add_argument("--dtype", type=str, default="float32")
+                        help="without --gpus: data-parallel count of "
+                        "(virtual) CPU devices")
+    parser.add_argument("--dtype", type=str, default="float32",
+                        help="compute dtype over float32 master params "
+                        "(float32 | bfloat16)")
     return parser
 
 
 def _contexts(args):
+    if args.gpus:
+        return [mx.gpu(int(i)) for i in args.gpus.split(",")]
     if args.num_devices <= 1:
         return [mx.current_context()]
     return [mx.Context(mx.current_context().device_type, i)
@@ -41,7 +49,10 @@ def _contexts(args):
 
 
 def fit(args, network, data_iters, **fit_kwargs):
-    """Bind + train ``network`` on (train, val) iterators per ``args``."""
+    """Bind + train ``network`` on (train, val) iterators per ``args``.
+
+    ``batch_end_callback`` in ``fit_kwargs`` runs after the Speedometer
+    (reference fit.py contract); the rest goes to ``Module.fit``."""
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(message)s")
     train, val = data_iters
@@ -88,7 +99,15 @@ def fit(args, network, data_iters, **fit_kwargs):
         else:
             val = mx.io.PrefetchingIter(val, device=contexts[0])
 
-    mod = mx.mod.Module(network, context=contexts)
+    batch_end_callbacks = [mx.callback.Speedometer(args.batch_size,
+                                                   args.disp_batches)]
+    extra = fit_kwargs.pop("batch_end_callback", None)
+    if extra is not None:
+        batch_end_callbacks += extra if isinstance(extra, list) else [extra]
+
+    mod = mx.mod.Module(
+        network, context=contexts,
+        compute_dtype=None if args.dtype == "float32" else args.dtype)
     mod.fit(train,
             eval_data=val,
             eval_metric=["acc"],
@@ -98,8 +117,7 @@ def fit(args, network, data_iters, **fit_kwargs):
             aux_params=aux_params,
             begin_epoch=begin_epoch,
             num_epoch=args.num_epochs,
-            batch_end_callback=mx.callback.Speedometer(
-                args.batch_size, args.disp_batches),
+            batch_end_callback=batch_end_callbacks,
             epoch_end_callback=checkpoint,
             kvstore=args.kv_store,
             initializer=mx.initializer.Xavier(rnd_type="gaussian",

@@ -9,6 +9,12 @@ happens off the measured path in NDArrayIter. Run:
 
     python examples/train_imagenet.py --network resnet --num-layers 50 \
         --batch-size 64 --num-epochs 1
+
+On a TPU host name the chip(s) and the compute dtype (``--gpus`` is the
+reference's flag; ``mx.gpu(i)`` is the accelerator alias):
+
+    python examples/train_imagenet.py --gpus 0 --dtype bfloat16 \
+        --batch-size 256 --num-examples 2560
 """
 import argparse
 import os
@@ -22,7 +28,9 @@ from mxnet_tpu.models import (resnet, alexnet, vgg, inception_bn,
 from common import data, fit
 
 
-def main():
+def build(argv=None):
+    """``(args, network, (train, val))`` for a command line (default
+    ``sys.argv[1:]``) — everything ``main`` hands to ``fit.fit``."""
     parser = argparse.ArgumentParser(description="train imagenet")
     parser.add_argument("--network", type=str, default="resnet",
                         choices=("resnet", "alexnet", "vgg", "inception-bn",
@@ -39,7 +47,7 @@ def main():
     fit.add_fit_args(parser)
     parser.set_defaults(batch_size=64, num_epochs=1, lr=0.1,
                         disp_batches=10)
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
 
     if args.network == "resnet":
         net = resnet.get_symbol(num_classes=args.num_classes,
@@ -78,7 +86,11 @@ def main():
                                          image_shape=image_shape,
                                          num_train=args.num_examples,
                                          num_val=args.num_val)
-    fit.fit(args, net, iters)
+    return args, net, iters
+
+
+def main(argv=None):
+    return fit.fit(*build(argv))
 
 
 if __name__ == "__main__":

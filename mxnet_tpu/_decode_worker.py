@@ -22,20 +22,23 @@ Augmentation implements the param-driven fast path of CreateAugmenter
 mean/std normalize), matching image.py's per-augmenter semantics.
 Closure-based custom aug lists fall back to the in-process thread pool.
 """
-import json
 import os
-import struct
 import sys
 
 # Executed BY PATH, so sys.path[0] is this package directory — scrub it
 # before any further import, or stdlib modules shadowed by framework
 # files resolve wrongly and kill the worker (observed: shared_memory ->
 # secrets -> `import random` landing on mxnet_tpu/random.py, which then
-# pulls JAX into the decode worker and dies mid-import).
+# pulls JAX into the decode worker and dies mid-import; on Python 3.12
+# json -> re -> functools -> `import operator` landing on
+# mxnet_tpu/operator.py). Only os and sys, which the interpreter has
+# loaded before this file runs, may be imported above this line.
 _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path[:] = [p for p in sys.path
                if os.path.abspath(p or os.getcwd()) != _HERE]
 
+import json
+import struct
 from multiprocessing import shared_memory
 
 import numpy as np

@@ -16,53 +16,34 @@ XLA owns placement — so a Context is a value object used for:
 """
 from __future__ import annotations
 
-import logging
 import os
 import threading
 
 import jax
 
+from .base import MXNetError
+
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus"]
 
-_compilation_cache_wired = False
+#: the fixed default location of the persistent XLA compile cache: the
+#: path is part of the cache key, so it never comes from tempfile, a pid
+#: or the clock
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 
 def _init_compilation_cache():
-    """Wire the persistent XLA compilation cache at context init.
+    """Point jax's persistent compilation cache at one fixed place.
 
-    ``MXNET_COMPILATION_CACHE_DIR`` names an on-disk cache of compiled
-    XLA executables (jax's ``jax_compilation_cache_dir``): a warm
-    restart of the same training program skips its XLA compiles
-    entirely — the third leg of the dispatch/compile amortization layer
-    next to the process-wide program cache (program_cache.py) and the
-    K-step scan dispatch. ``MXNET_COMPILATION_CACHE_MIN_COMPILE_SECS``
-    optionally lowers jax's minimum-compile-time persistence threshold
-    (set 0 to persist even sub-second programs). Runs once; a user who
-    already configured jax's cache (e.g. bench.py's repo-local default
-    via ``JAX_COMPILATION_CACHE_DIR``) is left untouched.
+    Runs once, at ``import mxnet_tpu``. ``JAX_COMPILATION_CACHE_DIR`` in
+    the environment wins and nothing is set in code; otherwise the cache
+    lives in ``<checkout>/.jax_cache`` so a warm restart of the same
+    program skips its XLA compiles.
     """
-    global _compilation_cache_wired
-    if _compilation_cache_wired:
+    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
         return
-    _compilation_cache_wired = True
-    path = os.environ.get("MXNET_COMPILATION_CACHE_DIR")
-    if not path:
-        return
-    try:
-        if jax.config.jax_compilation_cache_dir:
-            return          # already configured (env/bench/user code)
-    except AttributeError:
-        pass
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        min_secs = os.environ.get("MXNET_COMPILATION_CACHE_MIN_COMPILE_SECS")
-        if min_secs is not None:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              float(min_secs))
-    except Exception as exc:   # cache is an optimization, never fatal
-        logging.warning("persistent compilation cache unavailable "
-                        "(%s): %s", path, exc)
+    jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
 
 
 class Context:
@@ -108,17 +89,20 @@ class Context:
         Multi-process: a Context names a device of THIS process —
         ``jax.devices()`` would enumerate the whole job's devices and
         hand other processes' (non-addressable) ones to low ids."""
-        _init_compilation_cache()
         if self.device_type in ("cpu", "cpu_pinned"):
             devs = _local_cpu_devices()
-        else:
-            # "gpu" is a compat alias for the accelerator backend: on a TPU
-            # machine it resolves to TPU chips so reference scripts using
-            # mx.gpu(i) run unchanged.
-            devs = _accelerator_devices()
-            if not devs:
-                devs = _local_cpu_devices()
-        return devs[min(self.device_id, len(devs) - 1)]
+            return devs[min(self.device_id, len(devs) - 1)]
+        # "gpu" is a compat alias for the accelerator backend: on a TPU
+        # machine it resolves to TPU chips so reference scripts using
+        # mx.gpu(i) run unchanged. An accelerator context never degrades
+        # to the CPU or to another chip: num_gpus() is the way to ask.
+        devs = _accelerator_devices()
+        if self.device_id >= len(devs):
+            raise MXNetError(
+                f"{self}: this host has {len(devs)} accelerator "
+                f"device(s); jax.local_devices() found "
+                f"{jax.local_devices()}")
+        return devs[self.device_id]
 
     def __enter__(self):
         if not hasattr(Context._local, "stack"):
@@ -172,3 +156,6 @@ def tpu(device_id=0):
 def num_gpus():
     """Number of accelerator devices visible (compat helper)."""
     return len(_accelerator_devices())
+
+
+_init_compilation_cache()

@@ -91,7 +91,7 @@ class _LazyOutputs:
 
 def _build_graph_runner(symbol, shape_overrides=None, tap=None, mp_plan=None,
                         compute_dtype=None, remat_segments=0,
-                        spmd_plan=None):
+                        spmd_plan=None, n_devices=1):
     """Close the symbol graph into run(arg_vals, aux_vals, is_train, rng).
 
     Returns (runner, arg_names, aux_names, loss_mask). The runner is pure:
@@ -223,7 +223,7 @@ def _build_graph_runner(symbol, shape_overrides=None, tap=None, mp_plan=None,
                            for x, t in zip(regular, in_tags)]
                 outs, aux_out = _kernel_tier.dispatch(
                     opdef, attrs, regular, aux, is_train, krng,
-                    spmd_plan=spmd_plan)
+                    spmd_plan=spmd_plan, n_devices=n_devices)
                 out_tags = [False] * len(outs)
         for j, t in enumerate(out_tags):
             entry_tags[(i, j)] = t
@@ -364,7 +364,7 @@ class Executor:
     def __init__(self, symbol, ctx, args, args_grad=None, grad_req="write",
                  aux_states=None, group2ctx=None, shared_exec=None,
                  compute_dtype=None, mirror=None, validate=None,
-                 mesh_token=None, spmd_plan=None):
+                 mesh_token=None, spmd_plan=None, n_devices=1):
         self._symbol = symbol
         self._ctx = ctx
         # the binding's SpmdPlan (spmd exec groups): threaded into the
@@ -443,7 +443,10 @@ class Executor:
                                     mp_plan=self._mp_plan,
                                     compute_dtype=compute_dtype,
                                     remat_segments=self._remat_segments,
-                                    spmd_plan=spmd_plan)
+                                    spmd_plan=spmd_plan,
+                                    n_devices=n_devices
+                                    if self._mp_plan is None
+                                    else self._mp_plan.mesh.size)
         self.aux_arrays = self._normalize_args(aux_states, self.aux_names,
                                                "aux_states", allow_none=True)
         self.grad_req = self._normalize_req(grad_req)

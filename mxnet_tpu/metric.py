@@ -124,8 +124,7 @@ class EvalMetric:
 
         The reference's metrics are host numpy, so every update is a
         device->host pull — through an accelerator runtime that makes
-        the metric the training loop's per-batch sync point (measured:
-        2 x ~100 ms round trips per batch on a remote chip). Device-side
+        the metric the training loop's per-batch sync point. Device-side
         metrics queue the async scalar instead; only reading the metric
         (``get``) synchronizes, once, fetching all queued scalars in a
         single transfer batch.

@@ -57,7 +57,6 @@ class NDArray:
                 # host data goes straight to the target device — going
                 # through jnp.asarray first would land it on the DEFAULT
                 # device and turn this into a cross-device round-trip
-                # (catastrophic when the default device is a remote chip)
                 data = jax.device_put(np.asarray(data), ctx.jax_device())
             else:
                 data = jnp.asarray(data)

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 from functools import partial
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -124,9 +125,10 @@ def _softmax_ce_bwd_kernel(scale, use_ignore, ignore_label):
     return kernel
 
 
-def _row_blocks(n, c):
-    """Row-block size bounded by a ~2 MiB VMEM working set."""
-    cap = max(8, (2 << 20) // max(1, 4 * c))
+def _row_blocks(n, c, block_bytes=2 << 20):
+    """Row-block size bounding one f32 (rows, c) block to
+    ``block_bytes``."""
+    cap = max(8, block_bytes // max(1, 4 * c))
     return _divisor_block(n, min(256, cap))
 
 
@@ -625,8 +627,8 @@ def _ln_bwd_dparams_kernel(x_ref, ct_ref, mean_ref, rstd_ref,
     db_ref[...] += jnp.sum(ct, axis=0)[None, :]
 
 
-def _ln_specs(n, c):
-    bn = _row_blocks(n, c)
+def _ln_specs(n, c, block_bytes=2 << 20):
+    bn = _row_blocks(n, c, block_bytes)
     row = pl.BlockSpec((bn, c), lambda i: (i, 0))
     stat = pl.BlockSpec((bn, 1), lambda i: (i, 0))
     par = pl.BlockSpec((1, c), lambda i: (0, 0))
@@ -739,26 +741,52 @@ _INV_SQRT2 = 0.7071067811865476
 _INV_SQRT2PI = 0.3989422804014327
 
 
-def _bias_gelu_core(x32):
-    return 0.5 * x32 * (1.0 + jax.lax.erf(x32 * _INV_SQRT2))
+# Mosaic lowers no ``erf`` primitive, so the kernels evaluate the f32
+# rational approximation x * P(x^2) / Q(x^2) (the Eigen/XLA float erf
+# coefficients, highest degree first) on the argument clamped to
+# +-erfinv(1 - 2^-23), beyond which erf is +-1 in f32. It agrees with
+# ``jax.lax.erf`` to a few f32 ulp (tests/test_kernel_tier.py).
+_ERF_P = (0.00022905065861350646, 0.0034082910107109506,
+          0.050955695062380861, 0.18520832239976145, 1.128379143519084)
+_ERF_Q = (-1.1791602954361697e-7, 0.000023547966471313185,
+          0.0010179625278914885, 0.014070470171167667,
+          0.11098505178285362, 0.49746925110067538, 1.0)
+_ERF_CLAMP = 3.7439211627767994
+
+
+def _horner(x, coeffs):
+    acc = jnp.full_like(x, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * x + c
+    return acc
+
+
+def _erf32(x32):
+    x = jnp.clip(x32, -_ERF_CLAMP, _ERF_CLAMP)
+    x2 = x * x
+    return x * _horner(x2, _ERF_P) / _horner(x2, _ERF_Q)
 
 
 def _bias_gelu_kernel(x_ref, b_ref, o_ref):
     x = x_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    o_ref[...] = _bias_gelu_core(x).astype(o_ref.dtype)
+    o_ref[...] = (0.5 * x * (1.0 + _erf32(x * _INV_SQRT2))).astype(
+        o_ref.dtype)
 
 
 def _bias_gelu_dx_kernel(x_ref, b_ref, ct_ref, dx_ref):
     z = x_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
     phi = jnp.exp(-0.5 * z * z) * _INV_SQRT2PI
-    dgelu = 0.5 * (1.0 + jax.lax.erf(z * _INV_SQRT2)) + z * phi
+    dgelu = 0.5 * (1.0 + _erf32(z * _INV_SQRT2)) + z * phi
     dx_ref[...] = (ct_ref[...].astype(jnp.float32) * dgelu).astype(
         dx_ref.dtype)
 
 
 def _pl_bias_gelu(x2, bias, kernel):
     n, c = x2.shape
-    bn, row, _stat, par = _ln_specs(n, c)
+    # 512 KiB blocks: besides the double-buffered operands the erf
+    # polynomial keeps several block-sized f32 temporaries live, and at
+    # 2 MiB blocks they overrun the chip's 16 MiB scoped VMEM
+    bn, row, _stat, par = _ln_specs(n, c, block_bytes=512 << 10)
     in_specs = [row, par] + ([row] if kernel is _bias_gelu_dx_kernel
                              else [])
 
@@ -804,7 +832,8 @@ def _bias_gelu_xla(attrs, data, bias):
     bshape = (1,) * (data.ndim - 1) + (-1,)
     x32 = data.astype(jnp.float32) + \
         bias.astype(jnp.float32).reshape(bshape)
-    return _bias_gelu_core(x32).astype(data.dtype)
+    return (0.5 * x32 * (1.0 + jax.lax.erf(x32 * _INV_SQRT2))).astype(
+        data.dtype)
 
 
 def _bias_gelu_variant(attrs, inputs, aux, is_train, rng):
@@ -816,7 +845,8 @@ def _bias_gelu_eligible(attrs, in_shapes, in_dtypes):
     data_s, bias_s = in_shapes[0], in_shapes[1]
     if len(data_s) < 2 or tuple(bias_s) != (data_s[-1],):
         return False
-    return data_s[-1] <= 65536 and str(in_dtypes[0]) in (
+    # an 8-row block must stay within _pl_bias_gelu's 512 KiB block bound
+    return data_s[-1] <= 16384 and str(in_dtypes[0]) in (
         "float32", "bfloat16", "float16")
 
 
@@ -828,11 +858,11 @@ def _bias_gelu_infer(attrs, in_shapes, out_known=None):
     return [data_s, c], [data_s], []
 
 
-#: row blocks with whole channels resident (C <= 65536): x, bias
+#: row blocks with whole channels resident (C <= 16384): x, bias
 #: broadcast rows, and the GeLU output
 _BIAS_GELU_KSPEC = {
-    "tiles": [((8, 65536), "float32"), ((8, 65536), "float32"),
-              ((8, 65536), "float32")],
+    "tiles": [((8, 16384), "float32"), ((8, 16384), "float32"),
+              ((8, 16384), "float32")],
     "dtypes": ("float32", "bfloat16", "float16"),
 }
 
@@ -852,31 +882,62 @@ _register_bias_gelu()
 
 # ==========================================================================
 # fused embedding lookup (Embedding pallas variant): one-pass gather
-# (+ optional scale) driven by scalar-prefetched ids — the row index IS
-# the weight BlockSpec's index_map — with a scatter-add backward
+# (+ optional scale) driven by scalar-prefetched ids, with a scatter-add
+# backward. Mosaic moves whole (sublane, lane) tiles, never one table
+# row, so the table is blocked in tile-aligned groups of ``sub`` rows and
+# handed to the kernel ``sub`` times: operand r's index map fetches the
+# group that holds the row output row r wants, and the kernel selects
+# that row out of the group.
 # ==========================================================================
-def _emb_gather_kernel(scale):
-    def kernel(ids_ref, w_ref, o_ref):
-        x = w_ref[...]
+def _emb_sublanes(dtype):
+    """Rows in one tile of ``dtype``: 8 for 4-byte, 16 for 2-byte."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def _emb_gather_kernel(sub, scale):
+    def kernel(ids_ref, *refs):
+        w_refs, o_ref = refs[:sub], refs[sub]
+        base = pl.program_id(0) * sub
+        rows = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 0)
+        acc = jnp.zeros(o_ref.shape, jnp.float32)
+        for r in range(sub):
+            group = w_refs[r][...].astype(jnp.float32)        # (sub, D)
+            pick = rows == ids_ref[base + r] % sub
+            row = jnp.sum(jnp.where(pick, group, 0.0), axis=0,
+                          keepdims=True)
+            acc = jnp.where(rows == r, row, acc)
         if scale != 1.0:
-            x = (x.astype(jnp.float32) * scale).astype(o_ref.dtype)
-        o_ref[...] = x
+            acc = acc * scale
+        o_ref[...] = acc.astype(o_ref.dtype)
     return kernel
 
 
 def _pl_embedding(ids, weight, scale):
     from jax.experimental.pallas import tpu as pltpu
     n = ids.shape[0]
-    _v, d = weight.shape
+    v, d = weight.shape
+    sub = _emb_sublanes(weight.dtype)
+    n_pad = -(-n // sub) * sub
+    # negative ids count from the end, as in the composition's jnp.take;
+    # a row index still outside the table would send the block fetch
+    # outside the array: clamp (the XLA gather fills such rows instead)
+    ids = jnp.clip(jnp.where(ids < 0, ids + v, ids), 0, v - 1)
+    ids = jnp.pad(ids, (0, n_pad - n))
+    # the composition multiplies by the scale rounded to the table dtype
+    scale = float(np.asarray(scale, dtype=weight.dtype))
+
+    def group_of(r):
+        return lambda i, ids_ref: (ids_ref[i * sub + r] // sub, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(n,),
-        in_specs=[pl.BlockSpec((1, d), lambda i, ids_ref:
-                               (ids_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i, ids_ref: (i, 0)))
-    return pallas_call(
-        _emb_gather_kernel(scale),
-        out_shape=jax.ShapeDtypeStruct((n, d), weight.dtype),
-        grid_spec=grid_spec)(ids, weight)
+        num_scalar_prefetch=1, grid=(n_pad // sub,),
+        in_specs=[pl.BlockSpec((sub, d), group_of(r)) for r in range(sub)],
+        out_specs=pl.BlockSpec((sub, d), lambda i, ids_ref: (i, 0)))
+    out = pallas_call(
+        _emb_gather_kernel(sub, scale),
+        out_shape=jax.ShapeDtypeStruct((n_pad, d), weight.dtype),
+        grid_spec=grid_spec)(ids, *([weight] * sub))
+    return out[:n]
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -922,17 +983,18 @@ def _embedding_eligible(attrs, in_shapes, in_dtypes):
         return False
     if str(in_dtypes[1]) not in ("float32", "bfloat16", "float16"):
         return False
-    if w_s[1] > 16384:
-        # one looked-up row must fit the declared VMEM tile (PK901's
-        # eligibility-side bound; wider tables keep the XLA gather)
+    if w_s[1] > 2048:
+        # the row groups must fit the declared VMEM tiles (PK901's
+        # eligibility-side bound); wider tables keep the XLA gather
         return False
     # Mosaic wants lane-aligned rows; interpret mode (off-TPU) takes any
     return w_s[1] % 128 == 0 or _interpret()
 
 
-#: one prefetched row in, one out, at the D <= 16384 eligibility bound
+#: at the D <= 2048 eligibility bound and the 2-byte worst case: 16
+#: row groups of (16, D) in, one (16, D) block out, the f32 accumulator
 _EMB_KSPEC = {
-    "tiles": [((8, 16384), "float32"), ((8, 16384), "float32")],
+    "tiles": [((16, 2048), "bfloat16")] * 17 + [((16, 2048), "float32")],
     "dtypes": ("float32", "bfloat16", "float16"),
 }
 

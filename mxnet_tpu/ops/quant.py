@@ -139,10 +139,13 @@ def _qfc_flatten(attrs, data):
 
 def _qfc_xla(attrs, data, weight, scale, bias=None):
     """The exact composition: f32 dequant, f32 matmul, cast back —
-    the reference both tiers are gated against."""
+    the reference both tiers are gated against. HIGHEST, like the
+    kernel: the TPU's default precision multiplies f32 operands in one
+    bf16 pass, which puts the reference itself outside NUMERIC_TOL."""
     data = _qfc_flatten(attrs, data)
     wf = dequantize(weight, scale, axis=0)
-    out = jnp.dot(data.astype(jnp.float32), wf.T)
+    out = jnp.dot(data.astype(jnp.float32), wf.T,
+                  precision=jax.lax.Precision.HIGHEST)
     if bias is not None:
         out = out + bias.astype(jnp.float32)
     return out.astype(data.dtype)

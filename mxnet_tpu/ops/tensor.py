@@ -75,7 +75,7 @@ _UNARY = {
     "arcsin": jnp.arcsin, "arcsinh": jnp.arcsinh, "arctan": jnp.arctan,
     "arctanh": jnp.arctanh, "ceil": jnp.ceil, "cos": jnp.cos,
     "cosh": jnp.cosh, "degrees": jnp.degrees, "exp": jnp.exp,
-    "expm1": jnp.expm1, "fix": jnp.fix, "floor": jnp.floor,
+    "expm1": jnp.expm1, "fix": jnp.trunc, "floor": jnp.floor,
     "gamma": lambda x: jnp.exp(_GAMMALN(x)), "gammaln": _GAMMALN,
     "log": jnp.log, "log10": jnp.log10, "log1p": jnp.log1p,
     "log2": jnp.log2, "negative": jnp.negative, "radians": jnp.radians,

@@ -14,32 +14,16 @@ from jax import lax
 
 
 def shard_map(f=None, **kwargs):
-    """Version-portable ``jax.shard_map``.
-
-    Newer jax exposes ``jax.shard_map`` (with ``check_vma``); 0.4.x only
-    has ``jax.experimental.shard_map.shard_map`` (with ``check_rep``).
-    Accepts both decorator-factory (``@shard_map(mesh=...)``) and direct
-    (``shard_map(fn, mesh=...)``) call styles and translates the
-    vma/rep-checking knob to whatever the installed jax understands.
-    """
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
+    """``jax.shard_map`` in both call styles: decorator factory
+    (``@shard_map(mesh=...)``) and direct (``shard_map(fn, mesh=...)``)."""
     if f is None:
-        return lambda fn: impl(fn, **kwargs)
-    return impl(f, **kwargs)
+        return lambda fn: jax.shard_map(fn, **kwargs)
+    return jax.shard_map(f, **kwargs)
 
 
 def axis_size(axis_name):
-    """Size of a mesh axis from inside shard_map (version-portable:
-    ``lax.axis_size`` only exists in newer jax; ``psum(1, axis)`` is the
-    classic spelling and folds to a compile-time constant)."""
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
+    """Size of a mesh axis from inside shard_map."""
+    return lax.axis_size(axis_name)
 
 
 def all_reduce(x, axis_name="data", op="sum"):

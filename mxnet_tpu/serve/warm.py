@@ -104,8 +104,8 @@ def restore_server(directory, clock=None, start=False, context=None,
     ``kind="serve"`` commit in ``directory``.
 
     Re-registering each model re-runs warmup (compile + pin every
-    rung — with ``MXNET_COMPILATION_CACHE_DIR`` set even those compiles
-    hit the persistent XLA cache), after which steady-state serving
+    rung — even those compiles hit the persistent XLA cache), after
+    which steady-state serving
     compiles nothing: ``compile_count()`` stays at the post-warmup
     mark. ``server_kw`` overrides the persisted server settings;
     ``context`` places the restored models (default: current device).
