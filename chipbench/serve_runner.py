@@ -1,0 +1,433 @@
+"""Runner for configurations of kind "serve": a decoder behind
+``mxnet_tpu.serve.serve_decoder`` under a traffic mix.
+
+Set-up (all of it counted in ``setup_s``): weights made on the device
+from the seed in one jitted call, ``serve_decoder`` binds, autotunes
+(first run of a checkout only) and warms every rung's S=1 and window
+program, the cursor pokes are warmed for every row count, the heap is
+frozen, the clients start, and the lead-in lets whole blocks complete.
+Then the window opens. ``correct`` is decided after it.
+"""
+from __future__ import annotations
+
+import functools
+import gc
+import math
+import threading
+import time
+
+import numpy as np
+
+from . import common, costs, stats, traffic as traffic_mod
+
+#: |served - reference| <= TOL + TOL * |reference| on every compared
+#: logit. The served path computes in bfloat16 (8 significant bits,
+#: relative step 2**-8 = 0.004) through 24 layers from float32 masters;
+#: the reference is float32 at the highest matmul precision. Measured
+#: on the v5e (PERF.md, Findings): max error 0.060-0.066 on logits of
+#: magnitude up to 8.7, i.e. 0.43-0.46 of this bound at its worst
+#: element. An 8-bit float compute path (3 significant bits, relative
+#: step 0.06) is 16 times coarser: its errors near a zero logit alone
+#: are several times the 0.12 allowed there.
+LOGIT_TOL = 0.12
+
+
+class _Record:
+    """One request as its client saw it (time.perf_counter)."""
+
+    __slots__ = ("req", "t_submit", "stamps", "t_done", "error", "n_tokens")
+
+    def __init__(self, req):
+        self.req = req
+        self.t_submit = None
+        self.stamps = []            # one per output token, at emission
+        self.t_done = None
+        self.error = None
+        self.n_tokens = None
+
+    def on_token(self, _handle, _token, _index):
+        self.stamps.append(time.perf_counter())
+
+
+class _Load:
+    """The clients. Closed loop: ``clients`` callers, each sends the
+    next request of the common sequence the moment its last one
+    completes - from the handle's done callback, which the scheduler
+    runs before its next iteration, so no thread's wake-up decides which
+    iteration admits the request and a run's schedule of iterations
+    follows from the mix alone. Open loop: one thread submits on the
+    schedule."""
+
+    def __init__(self, sched, mix, vocab, seed, horizon_s):
+        self.sched = sched
+        self.mix = mix
+        self.records = []
+        self._gen = traffic_mod.requests(mix, vocab, seed)
+        self._lock = threading.Lock()
+        self._stop = threading.Event()
+        self.completed = 0
+        self.done_cond = threading.Condition()
+        self.threads = []
+        if mix["kind"] == "open_loop":
+            self._due = traffic_mod.arrivals(mix, seed, horizon_s)
+            self.threads.append(threading.Thread(
+                target=self._open, name="chipbench-arrivals", daemon=True))
+        elif mix["kind"] != "closed_loop":
+            raise SystemExit(f"chipbench: traffic kind {mix['kind']!r} "
+                             "is not a serving mix")
+
+    def start(self):
+        self.t0 = time.perf_counter()
+        if self.mix["kind"] == "closed_loop":
+            for _ in range(int(self.mix["clients"])):
+                self._send_next()
+        for t in self.threads:
+            t.start()
+
+    def stop(self):
+        self._stop.set()
+
+    def join(self):
+        for t in self.threads:
+            t.join(timeout=120)
+
+    def _next(self):
+        with self._lock:
+            rec = _Record(next(self._gen))
+            self.records.append(rec)
+        return rec
+
+    def _send_next(self, due=None):
+        """Submit the next request; its done callback records the
+        outcome and, in a closed loop, sends the caller's next one."""
+        rec = self._next()
+        rec.t_submit = time.perf_counter()
+        if due is not None:     # open loop: time it from when it was DUE
+            rec.t_submit = min(rec.t_submit, due)
+        try:
+            handle = self.sched.submit(
+                rec.req.prompt, max_new_tokens=rec.req.max_new, eos_id=None,
+                prefix_id=rec.req.prefix_id)
+        except Exception as e:                # e.g. QueueFullError
+            self._done(rec, error=e)
+            return
+        handle.add_token_callback(rec.on_token)
+        handle.add_done_callback(lambda h, rec=rec: self._on_done(rec, h))
+
+    def _on_done(self, rec, handle):
+        err = handle.exception()
+        self._done(rec, error=err, n_tokens=None if err is not None
+                   else len(handle.tokens))
+        if self.mix["kind"] == "closed_loop" and not self._stop.is_set():
+            self._send_next()
+
+    def _done(self, rec, error=None, n_tokens=None):
+        if error is not None:                 # a failed request is data
+            rec.error = f"{type(error).__name__}: {error}"
+        rec.n_tokens = n_tokens
+        rec.t_done = time.perf_counter()
+        with self.done_cond:
+            self.completed += 1
+            self.done_cond.notify_all()
+
+    def _open(self):
+        for due in self._due:
+            delay = self.t0 + due - time.perf_counter()
+            if (delay > 0 and self._stop.wait(delay)) or self._stop.is_set():
+                break
+            self._send_next(due=self.t0 + due)
+
+    def wait_completed(self, n, timeout):
+        with self.done_cond:
+            return self.done_cond.wait_for(lambda: self.completed >= n,
+                                           timeout=timeout)
+
+
+def make_params(symbol, data_shapes, seed):
+    """Every parameter of ``symbol`` from the seed: N(0, 0.02) matrices
+    and embeddings, zero biases, unit LayerNorm gains (GPT-2's
+    initialisation), float32 as Module binds them. Made on the default
+    device in ONE jitted call, then handed over as host arrays - what a
+    loaded checkpoint is - and freed on the device: ``Module`` keeps its
+    own copy there, and two do not fit beside the KV pools."""
+    import jax
+    import jax.numpy as jnp
+    names = symbol.list_arguments()
+    shapes, _, _ = symbol.infer_shape(**data_shapes)
+    todo = [(n, tuple(s)) for n, s in zip(names, shapes)
+            if n not in data_shapes]
+
+    def gen(key):
+        out = {}
+        for i, (name, shape) in enumerate(todo):
+            if name.endswith("_gamma"):
+                out[name] = jnp.ones(shape, jnp.float32)
+            elif name.endswith(("_beta", "_bias")):
+                out[name] = jnp.zeros(shape, jnp.float32)
+            else:
+                out[name] = 0.02 * jax.random.normal(
+                    jax.random.fold_in(key, i), shape, jnp.float32)
+        return out
+
+    arrays = jax.jit(gen)(jax.random.PRNGKey(int(seed) % (1 << 31)))
+    host = {}
+    for name in list(arrays):
+        arr = arrays.pop(name)
+        host[name] = np.asarray(arr)
+        arr.delete()
+    return host
+
+
+def served_params(engine):
+    """The parameter values the engine serves, by name (device arrays;
+    no copy)."""
+    exe = engine._bm._leader._exec_group.executor
+    return {n: c.asjax() for n, c in exe.arg_dict.items()
+            if n not in engine.data_names}
+
+
+def check_reference(engine, arrays, config, seed):
+    """Prefill through the top rung's window program, then decode
+    through its S=1 program, on two seeded sequences; the logits of the
+    last 16 positions of each path against the plain float32 reference's
+    full forward. Returns ``(ok, report)``."""
+    import jax
+    from .reference import gpt2
+    rung = engine.ladder.max
+    drv = engine.driver(rung)
+    S = max(drv.window_lens) if drv.window_lens else 1
+    n_cmp = min(16, S)
+    t_pre = S * max(1, min(4, (engine.capacity - n_cmp) // S))
+    rng = np.random.default_rng([int(seed) % (1 << 32), 11])
+    n_seq = min(2, rung)
+    seqs = rng.integers(0, config["vocab_size"],
+                        (n_seq, t_pre + n_cmp)).astype(np.int32)
+    drv.active[:] = False
+    drv.rewind_many(list(range(rung)), [0] * rung)
+    for slot in range(n_seq):
+        drv.join(slot)
+    got = np.zeros((n_seq, 2 * n_cmp, config["vocab_size"]), np.float32)
+    for w in range(t_pre // S):
+        tokens = np.zeros((rung, S), np.int32)
+        tokens[:n_seq] = seqs[:, w * S:(w + 1) * S]
+        out = drv.step(tokens)
+        if w == t_pre // S - 1:
+            got[:, :n_cmp] = out.asnumpy()[:n_seq, S - n_cmp:] \
+                .astype(np.float32)
+    for j in range(n_cmp):
+        tokens = np.zeros((rung, 1), np.int32)
+        tokens[:n_seq, 0] = seqs[:, t_pre + j]
+        got[:, n_cmp + j] = drv.step(tokens).asnumpy()[:n_seq, 0] \
+            .astype(np.float32)
+    for slot in range(n_seq):
+        drv.leave(slot)
+    drv.rewind_many(list(range(rung)), [0] * rung)
+
+    fwd = jax.jit(functools.partial(gpt2.forward, config=config))
+    want = np.asarray(fwd(arrays, seqs))[:, t_pre - n_cmp:t_pre + n_cmp]
+    err = np.abs(got - want)
+    bound = LOGIT_TOL + LOGIT_TOL * np.abs(want)
+    ok = bool(np.all(err <= bound))       # a NaN fails
+    return ok, {"sequences": n_seq, "tokens": int(t_pre + n_cmp),
+                "positions_compared": 2 * n_cmp,
+                "max_abs_err": float(np.max(err)),
+                "max_err_over_bound": float(np.max(err / bound)),
+                "max_abs_logit": float(np.max(np.abs(want))),
+                "tolerance": LOGIT_TOL}
+
+
+def _counter(name, model):
+    from mxnet_tpu import telemetry
+    m = telemetry.get_metric(name, model=model)
+    return m.value if m is not None else 0
+
+
+_COUNTERS = ("serve.decode.tokens", "serve.decode.iterations",
+             "serve.decode.prefill.chunks", "serve.decode.requests",
+             "serve.decode.responses", "serve.decode.errors",
+             "serve.decode.migrations")
+
+
+def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
+    import jax
+    watch = common.CompileWatch()
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import transformer as tfm
+    from mxnet_tpu.telemetry import flightrec
+
+    cfg, mix = cell.config, cell.traffic
+    phases = {"import_s": time.perf_counter() - t_start}
+    model = dict(vocab_size=cfg["vocab_size"], d_model=cfg["n_embd"],
+                 n_layer=cfg["n_layer"], n_head=cfg["n_head"],
+                 pos_embed=cfg["position_embedding"])
+    if cfg["n_inner"] != 4 * cfg["n_embd"]:
+        raise SystemExit("chipbench: models/transformer.py fixes the "
+                         "feed-forward at 4 * n_embd")
+    capacity = cfg["capacity"]
+    context = mx.cpu(0) if rehearse else mx.tpu(0)
+
+    def gen(step_len):
+        return tfm.get_decode_symbol(
+            capacity=capacity, per_slot=True, step_len=step_len,
+            max_seq_len=cfg["n_positions"], **model)
+
+    t = time.perf_counter()
+    top = max(cfg["ladder"])
+    args = make_params(gen(1), {"data": (top, 1), "pos_ids": (top, 1)},
+                       seed)
+    phases["weights_s"] = time.perf_counter() - t
+
+    t = time.perf_counter()
+    if trace:
+        flightrec.configure(capacity=400000)
+    sched = mx.serve.serve_decoder(
+        gen(1), args, name=cfg["name"], capacity=capacity,
+        ladder=cfg["ladder"], context=context,
+        compute_dtype=cfg["compute_dtype"], symbol_gen=gen,
+        prefill_chunk=cfg["prefill_chunk"], start=False)
+    engine = sched.engine
+    del args
+    # the scheduler's cursor pokes take 1..rung rows: one tiny program
+    # for each count, warmed here so that none compiles in the window
+    for rung in engine.ladder.sizes:
+        drv = engine.driver(rung)
+        for k in range(1, rung + 1):
+            drv.rewind_many(list(range(k)), [0] * k)
+    phases["bind_warm_s"] = time.perf_counter() - t
+    tier = common.kernel_tier_table()
+    common.say("kernel_tier", decisions=tier, compile_cache=dict(watch.cache),
+               warmup_compiles=engine.warmup_compiles)
+
+    gc.collect()
+    gc.freeze()
+    t = time.perf_counter()
+    load = _Load(sched, mix, cfg["vocab_size"], seed,
+                 horizon_s=seconds + 600)
+    load.start()        # a closed loop's first requests queue up, and
+    sched.start()       # the first iteration admits them together
+    n_block = len(mix["block"])
+    if mix["kind"] == "closed_loop":
+        if not load.wait_completed(mix.get("lead_in_blocks", 1) * n_block,
+                                   timeout=600):
+            raise SystemExit("chipbench: the lead-in did not complete")
+    else:
+        time.sleep(float(mix.get("lead_in_s", 5.0)))
+    phases["lead_in_s"] = time.perf_counter() - t
+
+    # ------------------------------------------------------------ window
+    name = cfg["name"]
+    before = {c: _counter(c, name) for c in _COUNTERS}
+    t_open = time.perf_counter()
+    setup_s = t_open - t_start
+    tracer = common.Tracer(cell.name) if trace else None
+    pos_samples = []
+    if tracer is not None:
+        time.sleep(min(1.0, seconds / 4))
+        tracer.start()
+        t_end = time.perf_counter() + min(float(mix.get("trace_seconds", 4)),
+                                          seconds / 2)
+        drv = engine.driver(engine.ladder.max)
+        while time.perf_counter() < t_end:
+            live = drv.pos[drv.active]
+            if live.size:
+                pos_samples.append(float(live.mean()))
+            time.sleep(0.05)
+        tracer.stop()
+    remaining = t_open + seconds - time.perf_counter()
+    if remaining > 0:
+        time.sleep(remaining)
+    t_close = time.perf_counter()
+    after = {c: _counter(c, name) for c in _COUNTERS}
+    ring = [r for r in flightrec.get_records()
+            if t_open * 1e6 <= r.get("ts_us", 0) < t_close * 1e6]
+    compiles_in_window = watch.backend_compiles(t_open, t_close)
+
+    # lead-out: the load stays on until every request submitted inside
+    # the window has its first token, so time to first token is taken
+    # under the same load for all of them
+    def pending():
+        return [r for r in list(load.records)
+                if r.t_submit is not None and t_open <= r.t_submit < t_close
+                and not r.stamps and r.t_done is None]
+    t_limit = time.perf_counter() + 120
+    while pending() and time.perf_counter() < t_limit:
+        time.sleep(0.05)
+    load.stop()
+    records = list(load.records)
+    finished = [r for r in records if r.t_done is not None]
+    stats_now = sched.stats()
+    sched.stop(drain=False)
+    load.join()
+
+    # ----------------------------------------------------------- metrics
+    stamps = [r.stamps for r in records]
+    in_window = [r for r in records if r.t_submit is not None
+                 and t_open <= r.t_submit < t_close]
+    wrong_len = [r for r in finished if r.error is None
+                 and r.n_tokens != r.req.max_new]
+    failed = [r for r in in_window
+              if (r.error is not None and r.t_done is not None
+                  and r.t_done < t_close) or r in wrong_len]
+    ttft = stats.ttfts([(r.t_submit, r.stamps[0] if r.stamps else None,
+                         r in failed) for r in records], t_open, t_close)
+    gaps = stats.token_gaps(stamps, t_open, t_close)
+    values = {
+        "serve_tokens_per_s": stats.tokens_per_s(stamps, t_open, t_close),
+        "serve_ttft_p90_ms": 1e3 * stats.percentile(ttft, 90)
+        if ttft else None,
+        "serve_tpot_p95_ms": 1e3 * stats.percentile(gaps, 95)
+        if gaps else None,
+        "setup_s": setup_s,
+    }
+    n_tokens = sum(len(stats.in_window(s, t_open, t_close)) for s in stamps)
+    common.say("window", seconds=t_close - t_open, requests=len(in_window),
+               ttft_samples=len(ttft), gap_samples=len(gaps),
+               tokens=n_tokens, blocks=len(in_window) / n_block,
+               finished=len(finished), failed=len(failed),
+               wrong_length=len(wrong_len),
+               compiles_in_window=compiles_in_window[:8],
+               ttft_ms={q: 1e3 * stats.percentile(ttft, q)
+                        for q in (50, 75, 90, 95)} if ttft else None,
+               tpot_ms={q: 1e3 * stats.percentile(gaps, q)
+                        for q in (50, 75, 90, 95, 97, 99)} if gaps else None,
+               counters={c: after[c] - before[c] for c in _COUNTERS},
+               rung=stats_now["rung"], end_to_end=values, **device,
+               rehearsal=rehearse)
+    common.say("setup", setup_s=setup_s, **phases,
+               compile_events_s=watch.seconds(),
+               memory_stats=common.memory_stats(),
+               slow_compiles=watch.slowest(),
+               compile_cache=dict(watch.cache),
+               autotuned=common.autotuned_sites(tier))
+
+    peak = common.memory_peak_bytes(cell.chips)   # before the reference's
+    ok_ref, report = check_reference(engine, served_params(engine), cfg,
+                                     seed)
+    common.say("reference", ok=ok_ref, **report)
+    correct = (ok_ref and not failed and not wrong_len
+               and not compiles_in_window and n_tokens > 0)
+
+    if trace:
+        live_rows = float(np.mean(pos_samples)) if pos_samples else 0.0
+        top = engine.ladder.max
+        obs = {
+            "counters": {c: after[c] - before[c] for c in _COUNTERS},
+            "ring": ring, "series": {"gap_s": gaps,
+                                     "ttft_s": [v for v in ttft
+                                                if math.isfinite(v)]},
+            "events": tracer.events, "device_kind": device["kind"],
+            "chips": cell.chips,
+            "cost": {
+                "decode_step": costs.gpt_step(cfg, top, 1, live_rows),
+                "window_step": costs.gpt_step(cfg, top,
+                                              cfg["prefill_chunk"],
+                                              live_rows)},
+        }
+        common.say("traced", live_rows=live_rows,
+                   ring_records=len(ring),
+                   events=len(tracer.events or []))
+        metrics = common.per_layer_metrics(cell, obs)
+    else:
+        metrics = common.end_to_end_metrics(cell, values)
+    common.result_line(correct, len(in_window), len(failed), metrics,
+                       device, peak, tracer=tracer)
