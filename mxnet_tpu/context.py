@@ -22,6 +22,7 @@ import threading
 import jax
 
 from .base import MXNetError
+from .telemetry import core as _telemetry_core
 
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus"]
 
@@ -159,3 +160,4 @@ def num_gpus():
 
 
 _init_compilation_cache()
+_telemetry_core.watch_compiles()

@@ -611,6 +611,16 @@ class Executor:
         return self._prog_cache_base + \
             (("ktier", _kernel_tier.mode()),) + (kind,) + extras
 
+    def program_name(self, kind):
+        """The function name a program of this binding is jitted under
+        - its kind and the shape of the first argument, by convention
+        the data input (``fwd_infer_8x64``) - so that the profiler's
+        ``XLA Modules`` line and ``jax.monitoring``'s ``fun_name`` say
+        which rung and window ran, not ``jit_prog`` for all of them."""
+        first = self.arg_arrays[0] if self.arg_arrays else None
+        dims = () if first is None else first.shape
+        return kind + ("_" + "x".join(str(d) for d in dims) if dims else "")
+
     def _get_program(self, kind):
         from . import remat as _remat
         naive = naive_engine_active()
@@ -648,6 +658,7 @@ class Executor:
             def prog(arg_vals, aux_vals, rng):
                 return runner(arg_vals, aux_vals, is_train, rng)
 
+            prog.__name__ = self.program_name(kind)
             fn = _telemetry.wrap_dispatch(prog, kind, compiled=False) \
                 if naive else _telemetry.wrap_dispatch(jax.jit(prog), kind)
         elif kind == "fwd_bwd":
@@ -668,6 +679,7 @@ class Executor:
                 grads, = vjp_fn(head_grads)
                 return outs, new_aux, grads
 
+            prog.__name__ = self.program_name(kind)
             fn = _telemetry.wrap_dispatch(prog, kind, compiled=False) \
                 if naive else _telemetry.wrap_dispatch(jax.jit(prog), kind)
         else:

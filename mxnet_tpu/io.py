@@ -33,17 +33,17 @@ def _instrumented_next(next_fn):
     """Wrap a ``next`` implementation with telemetry: an ``io.next`` span
     (labeled with the concrete iterator class), a batches-served counter
     and a fetch-latency histogram — batches/sec falls out of the two.
-    Disabled telemetry costs one extra call + branch per batch."""
+    With the span buffer off the span is the profiler's annotation
+    alone."""
     import functools
 
     @functools.wraps(next_fn)
     def next_with_telemetry(self):
-        if not _telemetry.enabled():
-            return next_fn(self)
         cls = type(self).__name__
         with _telemetry.span("io.next", _hist="io.next.seconds", iter=cls):
             batch = next_fn(self)
-        _telemetry.counter("io.batches", iter=cls).inc()
+        if _telemetry.enabled():
+            _telemetry.counter("io.batches", iter=cls).inc()
         return batch
     return next_with_telemetry
 
@@ -379,24 +379,40 @@ class PrefetchingIter(DataIter):
         # config (stage()/ensure_device() both restart the producer via
         # reset() after writing); a stale read can only affect batches
         # the restart discards with the old queue
+        # in the profiler's trace one queue entry is ``io.prefetch.batch``
+        # enclosing ``.fetch`` (the inner iterators), ``.to_device`` or
+        # ``.stack`` (staging onto the device) and ``.put`` (blocked on
+        # the full queue: zero means this thread sets the pace)
+        span = _telemetry.span
         while not self._stop.is_set():
             try:
                 k = self._stack_k  # mxlint: guarded-by(gil)
                 if k <= 1:
-                    batches = self._next_batches()
-                    if self._device is not None:  # mxlint: guarded-by(gil)
-                        batches = [self._to_device(b) for b in batches]
-                    self._queue.put(batches)
+                    with span("io.prefetch.batch"):
+                        with span("io.prefetch.fetch"):
+                            batches = self._next_batches()
+                        if self._device is not None:  # mxlint: guarded-by(gil)
+                            with span("io.prefetch.to_device"):
+                                batches = [self._to_device(b)
+                                           for b in batches]
+                        with span("io.prefetch.put"):
+                            self._queue.put(batches)
                     continue
                 window, exhausted = [], False
-                for _ in range(k):
-                    try:
-                        window.append(self._merge(self._next_batches()))
-                    except StopIteration:
-                        exhausted = True
-                        break
-                if window:
-                    self._queue.put(self._stack(window))
+                with span("io.prefetch.batch", steps=k):
+                    with span("io.prefetch.fetch"):
+                        for _ in range(k):
+                            try:
+                                window.append(
+                                    self._merge(self._next_batches()))
+                            except StopIteration:
+                                exhausted = True
+                                break
+                    if window:
+                        with span("io.prefetch.stack"):
+                            stacked = self._stack(window)
+                        with span("io.prefetch.put"):
+                            self._queue.put(stacked)
                 if exhausted:
                     self._queue.put(None)
                     return

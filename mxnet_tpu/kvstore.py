@@ -183,7 +183,7 @@ class KVStore:
                 "kvstore.push", _hist="kvstore.push.seconds",
                 keys=len(keys), bytes=nbytes)
         else:
-            push_span = _telemetry.null_span
+            push_span = _telemetry.span("kvstore.push", keys=len(keys))
             _telemetry.flightrec.note("kvstore.push", keys=len(keys))
         try:
             with push_span:
@@ -232,7 +232,7 @@ class KVStore:
                 "kvstore.pull", _hist="kvstore.pull.seconds",
                 keys=len(keys), bytes=nbytes)
         else:
-            pull_span = _telemetry.null_span
+            pull_span = _telemetry.span("kvstore.pull", keys=len(keys))
             _telemetry.flightrec.note("kvstore.pull", keys=len(keys))
         try:
             with pull_span:
@@ -546,7 +546,7 @@ class KVStoreDistSync(KVStore):
                 "kvstore.allreduce", _hist="kvstore.allreduce.seconds",
                 bytes=nbytes)
         else:
-            ar_span = _telemetry.null_span
+            ar_span = _telemetry.span("kvstore.allreduce")
         with ar_span:
             n = flat.shape[0]
             padded = self._size_class(n)
@@ -614,7 +614,8 @@ class KVStoreDistSync(KVStore):
                 "kvstore.push", _hist="kvstore.push.seconds",
                 keys=len(keys), bytes=nbytes, dist=True)
         else:
-            push_span = _telemetry.null_span
+            push_span = _telemetry.span("kvstore.push", keys=len(keys),
+                                        dist=True)
             _telemetry.flightrec.note("kvstore.push", keys=len(keys),
                                       dist=True)
         try:

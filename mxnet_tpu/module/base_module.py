@@ -127,18 +127,15 @@ class BaseModule:
         it = iter(train_data)
         sa = _telemetry.stepattr
         while True:
-            if sa.armed():
-                t0 = sa.clock()
-                try:
+            armed = sa.armed()
+            t0 = sa.clock() if armed else 0.0
+            try:
+                with _telemetry.span("module.fit.data_wait"):
                     batch = next(it)
-                except StopIteration:
-                    return
+            except StopIteration:
+                return
+            if armed:
                 sa.note_data_wait(sa.clock() - t0)
-            else:
-                try:
-                    batch = next(it)
-                except StopIteration:
-                    return
             yield batch
 
     def _fit_epoch(self, epoch, train_data, eval_metric, batch_end_callback,
@@ -184,15 +181,17 @@ class BaseModule:
                     dur_us=(time.perf_counter_ns() - t0) // 1000,
                     batch_size=getattr(train_data, "batch_size", 0))
             self._health_tick(epoch, nbatch)
-            self.update_metric(eval_metric, batch.label)
+            with _telemetry.span("module.fit.update_metric"):
+                self.update_metric(eval_metric, batch.label)
             sa.step_end()
             if monitor is not None:
                 monitor.toc_print()
             if batch_end_callback is not None:
-                _fire(batch_end_callback,
-                      BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                    eval_metric=eval_metric,
-                                    locals=locals()))
+                with _telemetry.span("module.fit.callback"):
+                    _fire(batch_end_callback,
+                          BatchEndParam(epoch=epoch, nbatch=nbatch,
+                                        eval_metric=eval_metric,
+                                        locals=locals()))
             self._ckpt_tick(epoch, nbatch)
         # epoch end: release the one-boundary health-stat lag
         self._health_tick(epoch, nbatch + 1, steps=0, flush=True)
@@ -234,13 +233,15 @@ class BaseModule:
                              (time.perf_counter_ns() - t0) // 1000,
                              batch_size)
             self._health_tick(epoch, nbatch)
-            self.update_metric(eval_metric, batch.label)
+            with _telemetry.span("module.fit.update_metric"):
+                self.update_metric(eval_metric, batch.label)
             sa.step_end()
             if batch_end_callback is not None:
-                _fire(batch_end_callback,
-                      BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                    eval_metric=eval_metric,
-                                    locals=locals()))
+                with _telemetry.span("module.fit.callback"):
+                    _fire(batch_end_callback,
+                          BatchEndParam(epoch=epoch, nbatch=nbatch,
+                                        eval_metric=eval_metric,
+                                        locals=locals()))
             self._ckpt_tick(epoch, nbatch)
             nbatch += 1
 
@@ -276,12 +277,14 @@ class BaseModule:
                 labels = self._advance_scan_batch()
                 self._note_batch(epoch, nbatch, dur_us // steps,
                                  batch_size)
-                self.update_metric(eval_metric, labels)
+                with _telemetry.span("module.fit.update_metric"):
+                    self.update_metric(eval_metric, labels)
                 if batch_end_callback is not None:
-                    _fire(batch_end_callback,
-                          BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                        eval_metric=eval_metric,
-                                        locals=locals()))
+                    with _telemetry.span("module.fit.callback"):
+                        _fire(batch_end_callback,
+                              BatchEndParam(epoch=epoch, nbatch=nbatch,
+                                            eval_metric=eval_metric,
+                                            locals=locals()))
                 nbatch += 1
             # one attribution record per window: phases divide over the
             # K logical batches it retired

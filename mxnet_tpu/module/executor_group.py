@@ -635,6 +635,7 @@ class DataParallelExecutorGroup:
         else:
             if _telemetry.enabled():
                 _telemetry.counter("executor.jit_cache.miss").inc()
+            prog_fn.__name__ = exe.program_name("fused_step")
             self._fused_prog = _telemetry.wrap_dispatch(
                 jax.jit(prog_fn, donate_argnums=donate), "fused_step")
             if self._fused_cache_key is not None:
@@ -1040,6 +1041,7 @@ class DataParallelExecutorGroup:
         # the single step needs
         donate = (0, 1, 2, 3) if getattr(
             self, "_remat_policy", "none") != "none" else (0, 1, 2)
+        scan_fn.__name__ = self.executor.program_name(f"scan{K}_step")
         fn = _telemetry.wrap_dispatch(
             jax.jit(scan_fn, donate_argnums=donate), "scan_step")
         if gkey is not None:

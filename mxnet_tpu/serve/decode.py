@@ -102,6 +102,11 @@ def _env_int(name, default):
         return default
 
 
+def _us(seconds):
+    """Whole microseconds of a clock difference, never negative."""
+    return int(max(0.0, seconds) * 1e6)
+
+
 def default_prefill_chunk():
     """``MXNET_SERVE_PREFILL_CHUNK`` (docs/env_var.md), default 64:
     prompt tokens per prefill dispatch. 1 disables chunking (token-at-
@@ -353,6 +358,7 @@ class DecodeEngine:
                               else default_slot_ladder())
         self.exec_est = {}              # rung -> EMA'd step seconds
         self._warm_mark = None
+        self._warm_backend_mark = None
         self.warmup_compiles = None
         self._symbol = symbol
         self._context = context if context is not None \
@@ -487,6 +493,7 @@ class DecodeEngine:
             drv.rewind_many(list(range(rung)), [0] * rung)
         self._pin_programs()
         self._warm_mark = _progcache.compile_count()
+        self._warm_backend_mark = _telemetry.core.backend_compiles()
         self.warmup_compiles = self._warm_mark - mark
         return dict(self.exec_est)
 
@@ -502,9 +509,23 @@ class DecodeEngine:
         return max(known) if known else 0.0
 
     def compiles_since_warmup(self):
+        """Traces that entered the process-wide program cache since
+        warm-up (None before it): a new program of ``Module``/
+        ``Executor``. The eager one-operation programs of host
+        bookkeeping never pass through that cache;
+        ``backend_compiles_since_warmup`` sees those too."""
         if self._warm_mark is None:
             return None
         return _progcache.compile_count() - self._warm_mark
+
+    def backend_compiles_since_warmup(self):
+        """XLA backend compiles (or persistent-cache reads) of this
+        PROCESS since warm-up, whoever asked for them (None before
+        it): ``jax.monitoring``'s count, the ``xla.compile`` ring
+        records name each one."""
+        if self._warm_backend_mark is None:
+            return None
+        return _telemetry.core.backend_compiles() - self._warm_backend_mark
 
     def program_keys(self):
         keys = []
@@ -637,6 +658,7 @@ class DecodeScheduler:
         self._running = False
         self.iterations = 0
         self.migrations = 0
+        self._iter_handles = None       # (registry generation, handles)
         # draft first: the target's post-warmup compile mark is the
         # zero-compile gate stats() reports, so it must be taken LAST
         if self.draft is not None:
@@ -650,6 +672,7 @@ class DecodeScheduler:
             # the target's warmup compiles landed after the draft's
             # mark; refresh it so BOTH gates read 0 in steady state
             self.draft._warm_mark = _progcache.compile_count()
+            self.draft._warm_backend_mark = self.engine._warm_backend_mark
         self.logger.info(
             "decode %r warmed — slot ladder %s, windows %s, "
             "%d compiles, step est %s",
@@ -668,6 +691,23 @@ class DecodeScheduler:
     def _counter(self, key):
         return _telemetry.counter(f"serve.decode.{key}",
                                   model=self.engine.name)
+
+    def _iter_metrics(self):
+        """The registry handles every iteration writes, looked up once
+        (a lookup is a lock and a key tuple each) and again after the
+        registry resets."""
+        gen = _telemetry.metrics.generation()
+        if self._iter_handles is None or self._iter_handles[0] != gen:
+            handles = {k: self._counter(k) for k in
+                       ("iterations", "tokens", "prefill.chunks")}
+            handles.update({k: self._gauge(k) for k in
+                            ("active", "occupancy", "queue.depth")})
+            handles["step.seconds"] = _telemetry.histogram(
+                "serve.decode.step.seconds", model=self.engine.name)
+            handles["compiles_since_warmup"] = _telemetry.gauge(
+                "serve.program_cache.compiles_since_warmup")
+            self._iter_handles = (gen, handles)
+        return self._iter_handles[1]
 
     # ------------------------------------------------------------ admission
     def submit(self, prompt, max_new_tokens=None, eos_id=None,
@@ -876,7 +916,28 @@ class DecodeScheduler:
             return "spec", self.spec_k
         return "window", 1
 
-    def _dispatch_spec(self, drv, ddrv, base_tokens, meta, K):
+    def _step_fetch(self, drv, tokens, phases, t=None):
+        """One dispatch and its logits on the host, each under its own
+        annotation: ``serve.decode.iter.dispatch`` is ``drv.step`` alone
+        (staging and launch), ``serve.decode.iter.fetch`` the
+        ``.asnumpy()`` alone (wait for the device, copy, re-lay). Adds
+        both durations to ``phases`` on the scheduler's clock; ``t`` is
+        the reading that closed the previous phase (one read a
+        boundary), None reads it. Returns ``(logits, end)``."""
+        now = self._clock.now
+        if t is None:
+            t = now()
+        with _telemetry.span("serve.decode.iter.dispatch"):
+            out = drv.step(tokens)
+        t_launched = now()
+        with _telemetry.span("serve.decode.iter.fetch"):
+            logits = out.asnumpy()
+        end = now()
+        phases["dispatch"] += t_launched - t
+        phases["fetch"] += end - t_launched
+        return logits, end
+
+    def _dispatch_spec(self, drv, ddrv, base_tokens, meta, K, phases):
         """One speculative iteration's device work (runs OUTSIDE the
         scheduler lock, like every dispatch): K draft S=1 dispatches
         propose ``d_1..d_K`` per slot, then ONE target S=K window
@@ -889,7 +950,7 @@ class DecodeScheduler:
         draft_rows = {row: [] for row, _seq in meta}
         feed = base_tokens.copy()
         for j in range(K):
-            dlog = ddrv.step(feed).asnumpy()       # (rung, 1, V)
+            dlog, _ = self._step_fetch(ddrv, feed, phases)  # (rung, 1, V)
             feed = np.zeros((rung, 1), np.int32)
             for row, seq in meta:
                 d = sample_token(dlog[row, 0], seq.sampling, seq.rng)
@@ -900,7 +961,7 @@ class DecodeScheduler:
         window[:, 0] = base_tokens[:, 0]
         if K > 1:
             window[:, 1:] = proposals[:, :K - 1]
-        vlog = drv.step(window).asnumpy()          # (rung, K, V)
+        vlog, _ = self._step_fetch(drv, window, phases)     # (rung, K, V)
         out = {}
         for row, seq in meta:
             out[row] = speculative_verify(
@@ -910,8 +971,20 @@ class DecodeScheduler:
 
     def _iterate(self):
         """One scheduling iteration; returns tokens emitted (0 = no
-        work was ready)."""
-        with self._lock:
+        work was ready). ``serve.decode.iter`` in the profiler's trace,
+        enclosing its phases ``.plan``, ``.dispatch``, ``.fetch``,
+        ``.commit`` and ``.rewind``; the ring record
+        ``serve.decode.step`` carries the same ``iter`` number and the
+        phases' durations on the scheduler's clock."""
+        # read without the lock: only this, the iterating thread, ever
+        # writes the count (pump() and the dispatch thread never overlap)
+        it = self.iterations  # mxlint: guarded-by(gil)
+        with _telemetry.span("serve.decode.iter", iter=it) as iter_span:
+            return self._run_iteration(iter_span)
+
+    def _run_iteration(self, iter_span):
+        span = _telemetry.span
+        with span("serve.decode.iter.plan"), self._lock:
             now = self._clock.now()
             # retirement BEFORE dispatch: deadline-expired sequences
             # complete with their partial output; a slot whose next
@@ -967,108 +1040,133 @@ class DecodeScheduler:
             active = list(self._active())
             shared_sid = _trace.next_span_id() \
                 if any(s.trace is not None for s in active) else None
+            iter_span.set(mode=mode, window=S, rung=self._rung,
+                          active=len(active))
             t0 = now
+            planned = self._clock.now()
 
         # dispatch outside the lock: submits stay non-blocking while
         # the program runs (only pump()/the dispatch thread iterates,
         # so the engine itself needs no second guard)
+        phases = {"dispatch": 0.0, "fetch": 0.0}
         if mode == "spec":
             verdicts = self._dispatch_spec(
-                drv, ddrv, tokens, [(r, s) for r, s in meta], S)
+                drv, ddrv, tokens, [(r, s) for r, s in meta], S, phases)
+            end = self._clock.now()
         else:
-            logits = drv.step(tokens).asnumpy()    # (rung, S, V)
+            logits, end = self._step_fetch(drv, tokens, phases,
+                                           t=planned)  # (rung, S, V)
             if ddrv is not None:
                 # the draft shadows every non-speculative dispatch so
                 # its cache tracks the same stream positions
-                ddrv.step(tokens).asnumpy()
+                _, end = self._step_fetch(ddrv, tokens, phases, t=end)
 
         with self._lock:
-            end = self._clock.now()
             step_s = max(0.0, end - t0)
             self.engine.note_exec(self._rung if S == 1
                                   else (self._rung, S), step_s)
-            emitted = 0
             chunks = 0
             rew_rows, rew_pos = [], []
-            if mode == "spec":
-                emitted = self._commit_spec(
-                    meta, verdicts, S, t0, end, shared_sid,
-                    rew_rows, rew_pos)
-            else:
-                for row, seq, n in meta:
-                    if seq.slot is None:
-                        continue
-                    was_prefilling = seq.remaining() > 1
-                    samples = seq.fed + n == seq.stream_len()
-                    if seq.trace is not None:
-                        _trace.record(
-                            seq.trace, "serve.decode.step", t0, end,
-                            span_id=shared_sid, parent=seq.root_sid,
-                            rung=self._rung, n_active=len(active),
-                            shared=True, pos=seq.fed, window=n)
-                        if was_prefilling:
-                            _trace.record(
-                                seq.trace, "serve.decode.prefill",
-                                t0, end, parent=seq.root_sid,
-                                pos=seq.fed, tokens=n, chunk=S)
-                    if was_prefilling:
-                        chunks += 1
-                    tok = sample_token(logits[row, n - 1],
-                                       seq.sampling, seq.rng) \
-                        if samples else None
-                    seq.fed += n
-                    if n < S:
-                        # the dispatch advanced the cursor by S; pull
-                        # it back to the stream position actually fed
+            with span("serve.decode.iter.commit"):
+                if mode == "spec":
+                    emitted = self._commit_spec(
+                        meta, verdicts, S, t0, end, shared_sid,
+                        rew_rows, rew_pos)
+                else:
+                    emitted, chunks = self._commit_window(
+                        meta, logits, S, t0, end, shared_sid,
+                        len(active), rew_rows, rew_pos)
+            committed = self._clock.now()
+            with span("serve.decode.iter.rewind"):
+                # retired rows keep advancing one window per dispatch;
+                # pull any nearing capacity back to 0 so no dispatch
+                # ever sees a clamped window write for a row nobody owns
+                maxw = max([1] + list(drv.window_lens))
+                seen = set(rew_rows)
+                for row in range(self._rung):
+                    if self._slots[row] is None and row not in seen and \
+                            drv.pos[row] + maxw > self.engine.capacity:
                         rew_rows.append(row)
-                        rew_pos.append(seq.fed)
-                    self._capture_prefix(seq, end)
-                    if not samples:
-                        continue              # still prefilling
-                    if seq.eos_id is not None and tok == seq.eos_id:
-                        self._finish(seq, reason="eos", now=end)
-                        continue            # EOS retires, not emitted
-                    seq.generated.append(tok)
-                    seq.handle._emit(tok, now=end)
-                    emitted += 1
-                    if len(seq.generated) >= seq.max_new:
-                        self._finish(seq, reason="length", now=end)
-            # retired rows keep advancing one window per dispatch; pull
-            # any nearing capacity back to 0 so no dispatch ever sees a
-            # clamped window write for a row nobody owns
-            maxw = max([1] + list(drv.window_lens))
-            seen = set(rew_rows)
-            for row in range(self._rung):
-                if self._slots[row] is None and row not in seen and \
-                        drv.pos[row] + maxw > self.engine.capacity:
-                    rew_rows.append(row)
-                    rew_pos.append(0)
-            if rew_rows:
-                drv.rewind_many(rew_rows, rew_pos)
-                if ddrv is not None:
-                    ddrv.rewind_many(rew_rows, rew_pos)
+                        rew_pos.append(0)
+                if rew_rows:
+                    drv.rewind_many(rew_rows, rew_pos)
+                    if ddrv is not None:
+                        ddrv.rewind_many(rew_rows, rew_pos)
+            rewound = self._clock.now()
+            it = self.iterations
             self.iterations += 1
             n_active = len(self._active())
-            self._counter("iterations").inc()
+            m = self._iter_metrics()
+            m["iterations"].inc()
             if emitted:
-                self._counter("tokens").inc(emitted)
+                m["tokens"].inc(emitted)
             if chunks:
-                self._counter("prefill.chunks").inc(chunks)
-            _telemetry.histogram("serve.decode.step.seconds",
-                                 model=self.engine.name).observe(step_s)
-            self._gauge("active").set(n_active)
-            self._gauge("occupancy").set(n_active / self._rung)
-            self._gauge("queue.depth").set(len(self._queue))
+                m["prefill.chunks"].inc(chunks)
+            m["step.seconds"].observe(step_s)
+            m["active"].set(n_active)
+            m["occupancy"].set(n_active / self._rung)
+            m["queue.depth"].set(len(self._queue))
             compiles = self.engine.compiles_since_warmup()
-            _telemetry.gauge(
-                "serve.program_cache.compiles_since_warmup").set(
-                compiles or 0)
+            m["compiles_since_warmup"].set(compiles or 0)
+
             _telemetry.flightrec.note(
-                "serve.decode.step", model=self.engine.name,
+                "serve.decode.step", model=self.engine.name, iter=it,
                 rung=self._rung, active=n_active, emitted=emitted,
-                step_us=int(step_s * 1e6), mode=mode, window=S,
+                step_us=_us(step_s), plan_us=_us(planned - t0),
+                dispatch_us=_us(phases["dispatch"]),
+                fetch_us=_us(phases["fetch"]),
+                commit_us=_us(committed - end),
+                rewind_us=_us(rewound - committed), mode=mode, window=S,
                 compiles_since_warmup=compiles)
         return max(1, emitted)
+
+    def _commit_window(self, meta, logits, S, t0, end, shared_sid,
+                       n_active, rew_rows, rew_pos):
+        """Apply one window (or S=1) iteration's logits (caller holds
+        the lock): sample where a slot's stream is exhausted, stream the
+        tokens, retire on EOS / max-new, and queue a cursor rewind for
+        every slot that fed fewer than S tokens. Returns ``(emitted,
+        prefill chunks)``."""
+        emitted = chunks = 0
+        for row, seq, n in meta:
+            if seq.slot is None:
+                continue
+            was_prefilling = seq.remaining() > 1
+            samples = seq.fed + n == seq.stream_len()
+            if seq.trace is not None:
+                _trace.record(
+                    seq.trace, "serve.decode.step", t0, end,
+                    span_id=shared_sid, parent=seq.root_sid,
+                    rung=self._rung, n_active=n_active,
+                    shared=True, pos=seq.fed, window=n)
+                if was_prefilling:
+                    _trace.record(
+                        seq.trace, "serve.decode.prefill",
+                        t0, end, parent=seq.root_sid,
+                        pos=seq.fed, tokens=n, chunk=S)
+            if was_prefilling:
+                chunks += 1
+            tok = sample_token(logits[row, n - 1],
+                               seq.sampling, seq.rng) \
+                if samples else None
+            seq.fed += n
+            if n < S:
+                # the dispatch advanced the cursor by S; pull
+                # it back to the stream position actually fed
+                rew_rows.append(row)
+                rew_pos.append(seq.fed)
+            self._capture_prefix(seq, end)
+            if not samples:
+                continue              # still prefilling
+            if seq.eos_id is not None and tok == seq.eos_id:
+                self._finish(seq, reason="eos", now=end)
+                continue            # EOS retires, not emitted
+            seq.generated.append(tok)
+            seq.handle._emit(tok, now=end)
+            emitted += 1
+            if len(seq.generated) >= seq.max_new:
+                self._finish(seq, reason="length", now=end)
+        return emitted, chunks
 
     def _commit_spec(self, meta, verdicts, K, t0, end, shared_sid,
                      rew_rows, rew_pos):
@@ -1166,7 +1264,8 @@ class DecodeScheduler:
                 if not self._has_work():
                     # bounded wait so queued-request deadlines are
                     # noticed; a submit notifies sooner
-                    self._cond.wait(timeout=0.05)
+                    with _telemetry.span("serve.decode.idle"):
+                        self._cond.wait(timeout=0.05)
                     continue
             self._iterate()
 
@@ -1259,6 +1358,8 @@ class DecodeScheduler:
             "exec_est_ms": dict(sorted(exec_est.items())),
             "capacity": self.engine.capacity,
             "compiles_since_warmup": self.engine.compiles_since_warmup(),
+            "backend_compiles_since_warmup":
+            self.engine.backend_compiles_since_warmup(),
             "programs_resident": self.engine.programs_resident(),
         }
         if self.spec_k:

@@ -1,37 +1,54 @@
-"""Span tracer core: thread-local span stack over monotonic clocks.
+"""Span tracer core: one span API, three sinks.
 
 The reference collects per-op exec records engine-side into
 ``profiler.cc``'s ProfileStat ring and serializes them to chrome://tracing
 JSON on MXDumpProfile. Here the analogous record is a *span*: a named,
-nested interval measured with ``time.perf_counter_ns`` (monotonic,
-ns-resolution) carrying the thread/process ids chrome://tracing wants.
+nested interval. ``span(name, **args)`` is the only call-site API, and
+what it writes depends on who is listening:
+
+* **the JAX profiler** (always, once ``jax`` is in the process): the
+  span is a ``jax.profiler.TraceAnnotation`` - a no-op in C++ while no
+  profiler session runs (0.4 us a site), and while one runs an event on
+  the calling thread's line of ``/host:CPU``, under its plain name with
+  the keyword arguments as stats, on the one clock the device trace
+  shares. This is how a host phase is put next to the device's idle gaps.
+* **the span buffer** (``telemetry.enable()``, off by default because a
+  long run's spans are unbounded): a ``Span`` measured with
+  ``time.perf_counter_ns`` carrying the thread/process ids
+  chrome://tracing wants, kept until an exporter (chrome_trace,
+  prometheus, jsonl) drains a copy; ``clear()`` resets between runs.
+  An enabled ``Span`` opens the profiler annotation too.
+* **the flight ring** (always on, bounded): finished ``Span``s are
+  mirrored into it, and the hot loops write their own records there with
+  the durations the spans enclose (``serve.decode.step``,
+  ``module.fit.batch``), so an operator joins ring and trace by a shared
+  field such as ``iter``.
 
 Design constraints:
 
-* **Off by default, near-zero when off.** ``span()`` returns a shared
-  no-op context manager without allocating when telemetry is disabled, so
-  instrumented hot paths (Module.fit's batch loop, KVStore.push) cost one
-  function call and one branch — the tier-1 suites and production fit
-  loops are unaffected (benchmarks/telemetry_overhead.py gates this).
+* **Near-zero when nobody listens.** With the buffer disabled and no
+  profiler session a site costs one function call, one branch and one
+  small C++ object (benchmarks/telemetry_overhead.py gates the fit loop).
 * **Thread-safe.** The span *stack* (for parent attribution) is
   thread-local; the finished-span buffer is shared under one lock, so
   PrefetchingIter's producer thread and the main loop interleave safely.
-* **Pure stdlib.** No jax/numpy imports — any layer of the framework can
-  import telemetry without ordering constraints.
-
-Spans are buffered in-process until an exporter (chrome_trace, prometheus,
-jsonl) drains a copy; ``clear()`` resets between runs.
+* **Pure stdlib at import.** ``jax`` is never imported here: a process
+  that has not imported it (the launcher, the mp-decode workers) gets
+  the shared ``null_span``, so any layer of the framework can import
+  telemetry without ordering constraints.
 """
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 
 from . import flightrec as _flightrec
 
 __all__ = ["span", "event", "record_event", "enable", "disable", "enabled",
-           "clear", "get_spans", "get_events", "null_span", "wrap_dispatch"]
+           "clear", "get_spans", "get_events", "null_span", "wrap_dispatch",
+           "watch_compiles", "backend_compiles"]
 
 _lock = threading.Lock()
 _local = threading.local()
@@ -65,13 +82,38 @@ class _NullSpan:
 
 null_span = _NullSpan()
 
+_annotation_cls = None
+
+
+def _annotation():
+    """The profiler-annotation class of this process, or None while
+    ``jax`` has not been imported in it (never imported from here)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return None
+
+        class Annotation(profiler.TraceAnnotation):
+            """A profiler annotation with ``null_span``'s surface."""
+
+            __slots__ = ()
+            dur = 0
+
+            def set(self, **kwargs):
+                self.set_metadata(**kwargs)
+                return self
+
+        _annotation_cls = Annotation
+    return _annotation_cls
+
 
 class Span:
     """One named interval. ``ts``/``dur`` are microseconds on the
     perf_counter timeline (chrome://tracing's native unit)."""
 
     __slots__ = ("name", "args", "ts", "dur", "pid", "tid", "parent",
-                 "depth", "_hist")
+                 "depth", "_hist", "_annotation")
 
     def __init__(self, name, args, hist=None):
         self.name = name
@@ -83,12 +125,19 @@ class Span:
         self.parent = None
         self.depth = 0
         self._hist = hist
+        self._annotation = None
 
     def set(self, **kwargs):
         self.args.update(kwargs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**kwargs)
         return self
 
     def __enter__(self):
+        cls = _annotation()
+        if cls is not None:
+            self._annotation = cls(self.name, **self.args)
+            self._annotation.__enter__()
         st = _stack()
         if st:
             self.parent = st[-1].name
@@ -99,6 +148,8 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb):
         self.dur = time.perf_counter_ns() // 1000 - self.ts
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -112,15 +163,20 @@ class Span:
 
 
 def span(name, _hist=None, **args):
-    """Context manager measuring a named interval.
+    """Context manager around a named interval.
 
-    No-op (shared singleton, no allocation) while telemetry is disabled.
-    ``_hist`` names a histogram that additionally receives the duration
-    in seconds, so one call site feeds both the trace and the registry.
+    While the span buffer is disabled this is the profiler's annotation
+    alone (a no-op in C++ unless a profiler session runs), or the shared
+    ``null_span`` in a process without ``jax``. ``_hist`` names a
+    histogram that additionally receives an enabled span's duration in
+    seconds, so one call site feeds both the trace and the registry.
     """
-    if not _enabled:
+    if _enabled:
+        return Span(name, args, hist=_hist)
+    cls = _annotation()
+    if cls is None:
         return null_span
-    return Span(name, args, hist=_hist)
+    return cls(name, **args)
 
 
 def event(kind, **payload):
@@ -187,11 +243,13 @@ def wrap_dispatch(fn, kind, compiled=True):
 
     def dispatch(*args):
         first, state["first"] = state["first"], False
+        name = "executor.compile" if first else "executor.run"
         if not _enabled:
-            if _flightrec._enabled:
+            with span(name, kind=kind):
+                if not _flightrec._enabled:
+                    return fn(*args)
                 # always-on flight-recorder timing of the XLA dispatch —
                 # the crash-report timeline's backbone when tracing is off
-                name = "executor.compile" if first else "executor.run"
                 t0 = time.perf_counter_ns()
                 try:
                     return fn(*args)
@@ -199,8 +257,6 @@ def wrap_dispatch(fn, kind, compiled=True):
                     _flightrec.note(
                         name, program=kind,
                         dur_us=(time.perf_counter_ns() - t0) // 1000)
-            return fn(*args)
-        name = "executor.compile" if first else "executor.run"
         from .metrics import counter
         counter("executor.dispatch").inc()
         counter(name + ".calls", kind=kind).inc()
@@ -211,3 +267,44 @@ def wrap_dispatch(fn, kind, compiled=True):
     if hasattr(fn, "lower"):     # keep jitted introspection reachable
         dispatch.lower = fn.lower
     return dispatch
+
+
+# ---------------------------------------------------------- XLA compiles
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_backend_compiles = 0
+_watching_compiles = False
+
+
+def _on_compile(event, duration, **kw):
+    global _backend_compiles
+    if event != _COMPILE_EVENT:
+        return
+    with _lock:
+        _backend_compiles += 1
+    from .metrics import counter
+    counter("xla.compile.count").inc()
+    counter("xla.compile.seconds").inc(duration)
+    _flightrec.note("xla.compile", fun_name=kw.get("fun_name"),
+                    dur_us=int(duration * 1e6))
+
+
+def watch_compiles():
+    """Register, once a process, the ``jax.monitoring`` listener behind
+    ``xla.compile.count`` / ``xla.compile.seconds`` and the ``xla.compile``
+    ring record (``fun_name``, ``dur_us``): one for every program XLA's
+    backend compiled or read from the persistent cache, the eager
+    one-operation programs included - what ``program_cache.
+    compile_count()`` (traces entering the program cache) cannot see.
+    Called where the program first imports jax (context.py)."""
+    global _watching_compiles
+    if _watching_compiles:
+        return
+    _watching_compiles = True
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+
+
+def backend_compiles():
+    """Backend compiles of this process so far (monotone: unlike the
+    ``xla.compile.count`` counter, ``telemetry.reset()`` leaves it)."""
+    return _backend_compiles

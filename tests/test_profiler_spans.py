@@ -1,0 +1,328 @@
+"""One span API onto the profiler's clock (ISSUE 25).
+
+``telemetry.span`` is a ``jax.profiler.TraceAnnotation`` whenever jax is
+in the process, so the host phases of ``DecodeScheduler._iterate``,
+``Module.fit`` and the ``PrefetchingIter`` producer land in the device
+trace under fixed names - the contract the benchmark's readers
+(chipbench/spans.py) match letter for letter. Pinned here on the CPU:
+the names, their nesting and their counts with the span buffer
+DISABLED, the phase fields of the ``serve.decode.step`` ring record,
+the jax-free fallback, the backend-compile counter (ROADMAP D11) and
+the program names.
+"""
+import glob
+import logging
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu import telemetry as tm
+from mxnet_tpu.models import transformer as tfm
+from mxnet_tpu.serve import FakeClock
+from mxnet_tpu.serve.clock import MonotonicClock
+from mxnet_tpu.telemetry import flightrec
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+V, D, L, H, T, S = 64, 32, 2, 4, 32, 4     # tiny LM, window S
+ITERS = STEPS = 3
+
+
+@pytest.fixture(autouse=True)
+def _tracer_off():
+    tm.disable()
+    yield
+    tm.disable()
+
+
+def _profiled(tmp, body):
+    """Host events ``(thread, name, start_ns, end_ns, stats)`` of the
+    JAX profiler's trace around ``body()`` (Python tracer off)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(
+        str(tmp), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        # a thread is a line; two threads may share a line NAME
+        for thread, line in enumerate(plane.lines):
+            for ev in line.events:
+                out.append((thread, ev.name.split("#")[0], ev.start_ns,
+                            ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return out
+
+
+def _decode_symbol(step_len):
+    return tfm.get_decode_symbol(
+        vocab_size=V, d_model=D, n_layer=L, n_head=H, capacity=T,
+        per_slot=True, step_len=step_len, max_seq_len=T)
+
+
+def _scheduler(name, clock):
+    sym = tfm.get_symbol(vocab_size=V, d_model=D, n_layer=L, n_head=H,
+                         seq_len=8, include_loss=False, max_seq_len=T)
+    mod = mx.mod.Module(sym, label_names=[])
+    mod.bind([("data", (1, 8))], None, for_training=False)
+    mod.init_params(mx.initializer.Xavier(rnd_type="gaussian",
+                                          magnitude=2))
+    args, _ = mod.get_params()
+    return mx.serve.serve_decoder(
+        _decode_symbol(1), args, name=name, capacity=T, ladder=[2],
+        symbol_gen=_decode_symbol, prefill_chunk=S, start=False,
+        clock=clock)
+
+
+@pytest.fixture(scope="module")
+def decode_trace(tmp_path_factory):
+    """Three ``pump()`` iterations of a tiny scheduler (two window
+    iterations that prefill 9 tokens, then an S=1 one), profiled."""
+    tm.disable()
+    sched = _scheduler("spans-trace", MonotonicClock())
+    sched.submit(np.arange(1, 10), max_new_tokens=4)
+    events = _profiled(tmp_path_factory.mktemp("decode"),
+                       lambda: sched.pump(max_iterations=ITERS))
+    sched.pump()
+    return events
+
+
+@pytest.fixture(scope="module")
+def fit_trace(tmp_path_factory):
+    """Three steps of a tiny ``Module.fit`` over a ``PrefetchingIter``
+    that stages onto the device, profiled from the iterator's start."""
+    tm.disable()
+    rng = np.random.RandomState(0)
+    X = rng.rand(4 * STEPS, 10).astype("f")
+    Y = (rng.rand(4 * STEPS) * 3).astype("f")
+    net = mx.sym.SoftmaxOutput(
+        mx.sym.FullyConnected(mx.sym.var("data"), num_hidden=3),
+        name="softmax")
+    mod = mx.mod.Module(net, context=mx.cpu(),
+                        logger=logging.getLogger("spans_fit"))
+    seen = []
+
+    def body():
+        it = mx.io.PrefetchingIter(mx.io.NDArrayIter(X, Y, batch_size=4),
+                                   device=mx.cpu())
+        mod.fit(it, num_epoch=1, optimizer_params={"learning_rate": 0.1},
+                batch_end_callback=lambda p: seen.append(p.nbatch))
+
+    events = _profiled(tmp_path_factory.mktemp("fit"), body)
+    assert seen == list(range(STEPS))
+    # the epoch's end resets the iterator, whose new producer prefetches
+    # again until the trace stops: keep the epoch itself
+    epoch_end = max(e[3] for e in _named(events, "module.fit.data_wait"))
+    return [e for e in events if e[2] < epoch_end]
+
+
+def _named(events, name):
+    return [e for e in events if e[1] == name]
+
+
+def _assert_inside(events, child, parent):
+    """Every ``child`` event lies inside a ``parent`` event of its own
+    thread."""
+    parents = _named(events, parent)
+    for line, _n, start, end, _s in _named(events, child):
+        assert any(p[0] == line and p[2] <= start and end <= p[3]
+                   for p in parents), (child, "outside every", parent)
+
+
+DECODE_PHASES = ("plan", "dispatch", "fetch", "commit", "rewind")
+
+
+@pytest.mark.parametrize("phase", DECODE_PHASES)
+def test_decode_phase_once_per_iteration_inside_iter(decode_trace, phase):
+    name = "serve.decode.iter." + phase
+    assert len(_named(decode_trace, "serve.decode.iter")) == ITERS
+    assert len(_named(decode_trace, name)) == ITERS
+    _assert_inside(decode_trace, name, "serve.decode.iter")
+
+
+def test_decode_iter_annotation_carries_the_plan(decode_trace):
+    """``iter`` joins an annotation to its ring record; mode, window,
+    rung and active are known once the plan phase has run. The
+    dispatch phase encloses the executor's own span."""
+    stats = [e[4] for e in sorted(_named(decode_trace, "serve.decode.iter"),
+                                  key=lambda e: e[2])]
+    assert [int(s["iter"]) for s in stats] == list(range(ITERS))
+    assert [int(s["window"]) for s in stats] == [S, S, 1]
+    assert {s["mode"] for s in stats} == {"window"}
+    assert {int(s["rung"]) for s in stats} == {2}
+    assert {int(s["active"]) for s in stats} == {1}
+    runs = _named(decode_trace, "executor.run")
+    assert len(runs) == ITERS and {e[4]["kind"] for e in runs} == \
+        {"fwd_infer"}
+    _assert_inside(decode_trace, "executor.run",
+                   "serve.decode.iter.dispatch")
+
+
+# name -> events a 3-batch epoch leaves: the producer's and the loop's
+# last probe runs into StopIteration inside batch/fetch/data_wait
+FIT_NAMES = {"io.prefetch.batch": STEPS + 1, "io.prefetch.fetch": STEPS + 1,
+             "io.prefetch.to_device": STEPS, "io.prefetch.put": STEPS,
+             "module.fit.data_wait": STEPS + 1,
+             "module.fit.update_metric": STEPS,
+             "module.fit.callback": STEPS}
+
+
+@pytest.mark.parametrize("name", sorted(FIT_NAMES))
+def test_fit_and_producer_names_once_per_batch(fit_trace, name):
+    assert len(_named(fit_trace, name)) == FIT_NAMES[name]
+    if name.startswith("io.prefetch.") and name != "io.prefetch.batch":
+        _assert_inside(fit_trace, name, "io.prefetch.batch")
+
+
+def test_producer_and_loop_are_two_threads(fit_trace):
+    producer = {e[0] for e in _named(fit_trace, "io.prefetch.batch")}
+    loop = {e[0] for e in _named(fit_trace, "module.fit.data_wait")}
+    assert len(producer) == 1 and len(loop) == 1 and producer != loop
+    # the existing sites ride the same bridge without an edit
+    for name in ("module.fit.batch", "io.load_batch", "io.next"):
+        assert _named(fit_trace, name), name
+
+
+@pytest.mark.parametrize("clock", ["fake", "real"])
+def test_ring_record_phase_fields(clock):
+    """The always-on ring record splits ``step_us`` (first clock read ->
+    logits on the host) into plan + dispatch + fetch and adds commit and
+    rewind, all on the scheduler's clock seam: zero under FakeClock."""
+    flightrec.configure(capacity=4096)
+    flightrec.clear()
+    sched = _scheduler("spans-ring-" + clock,
+                       FakeClock() if clock == "fake" else MonotonicClock())
+    sched.submit(np.arange(1, 10), max_new_tokens=4)
+    sched.pump()
+    recs = [r for r in flightrec.get_records()
+            if r["kind"] == "serve.decode.step"]
+    assert [r["iter"] for r in recs] == list(range(len(recs)))
+    assert len(recs) >= ITERS
+    fields = ("plan_us", "dispatch_us", "fetch_us", "commit_us",
+              "rewind_us")
+    for r in recs:
+        assert all(r[f] >= 0 for f in fields), r
+        if clock == "fake":
+            assert all(r[f] == 0 for f in fields) and r["step_us"] == 0
+        else:
+            parts = r["plan_us"] + r["dispatch_us"] + r["fetch_us"]
+            # three truncations to whole microseconds
+            assert abs(parts - r["step_us"]) <= max(3, r["step_us"] // 100)
+    stats = sched.stats()
+    assert stats["compiles_since_warmup"] == 0
+    assert stats["backend_compiles_since_warmup"] >= 0
+
+
+def test_span_without_jax_is_the_null_span():
+    """A process that never imported jax (the launcher, a decode
+    worker) gets the shared no-op, and telemetry does not import it."""
+    code = (
+        "import sys, types\n"
+        "pkg = types.ModuleType('mxnet_tpu')\n"
+        f"pkg.__path__ = [{os.path.join(ROOT, 'mxnet_tpu')!r}]\n"
+        "sys.modules['mxnet_tpu'] = pkg\n"
+        "from mxnet_tpu.telemetry import core\n"
+        "s = core.span('launcher.phase', k=1)\n"
+        "assert s is core.null_span, s\n"
+        "with s as e:\n"
+        "    assert e.set(x=1) is e\n"
+        "run = core.wrap_dispatch(lambda x: x + 1, 'fwd_infer')\n"
+        "assert run(1) == 2\n"
+        "assert 'jax' not in sys.modules\n"
+        "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"
+
+
+def test_enabled_span_opens_the_annotation_too(tmp_path):
+    """``mx.profiler`` users (span buffer on) get both sinks."""
+    tm.reset()
+    tm.enable()
+
+    def body():
+        with tm.span("both.sinks", step=3) as s:
+            s.set(extra=1)
+
+    events = _profiled(tmp_path, body)
+    tm.disable()
+    (ev,) = _named(events, "both.sinks")
+    assert int(ev[4]["step"]) == 3 and int(ev[4]["extra"]) == 1
+    (span,) = [s for s in tm.get_spans() if s.name == "both.sinks"]
+    assert span.args == {"step": 3, "extra": 1}
+    tm.reset()
+
+
+def test_compile_counter_sees_backend_compiles():
+    """ROADMAP D11: ``xla.compile.count`` counts XLA backend compiles -
+    exactly one for a new jitted function, none on its second call -
+    and the ring names the function."""
+    tm.reset()
+
+    def spans_probe_fn(x):
+        return x * 3 + 1
+
+    fn = jax.jit(spans_probe_fn)
+    x = jnp.arange(4.0)
+    x.block_until_ready()
+
+    def count():
+        m = tm.get_metric("xla.compile.count")
+        return m.value if m is not None else 0
+
+    before, total = count(), tm.core.backend_compiles()
+    fn(x).block_until_ready()
+    assert count() - before == 1
+    assert tm.core.backend_compiles() - total == 1
+    fn(x).block_until_ready()
+    assert count() - before == 1
+    recs = [r for r in flightrec.get_records() if r["kind"] == "xla.compile"]
+    assert recs[-1]["fun_name"] == "jit(spans_probe_fn)"
+    assert recs[-1]["dur_us"] > 0
+    assert tm.get_metric("xla.compile.seconds").value > 0
+    tm.reset()
+    assert tm.core.backend_compiles() - total == 1      # monotone
+
+
+@pytest.mark.parametrize("kind", ["fwd_infer", "fused_step"])
+def test_programs_are_named_by_kind_and_data_shape(kind):
+    """The XLA module is ``jit_<kind>_<shape of the first input>``, so
+    a trace's XLA Modules line tells the programs apart."""
+    net = mx.sym.SoftmaxOutput(
+        mx.sym.FullyConnected(mx.sym.var("data"), num_hidden=3),
+        name="softmax")
+    mod = mx.mod.Module(net, context=mx.cpu(),
+                        logger=logging.getLogger("spans_names"))
+    if kind == "fwd_infer":
+        mod.bind([("data", (5, 10))], None, for_training=False)
+        mod.init_params()
+        exe = mod._exec_group.executor
+        assert exe.program_name(kind) == "fwd_infer_5x10"
+        text = exe._get_program(kind).lower(
+            exe._arg_vals(), exe._aux_vals(),
+            jax.random.PRNGKey(0)).as_text()
+        assert "module @jit_fwd_infer_5x10" in text
+        return
+    flightrec.configure(capacity=4096)
+    flightrec.clear()
+    X = np.random.rand(12, 10).astype("f")
+    Y = (np.random.rand(12) * 3).astype("f")
+    mod.fit(mx.io.NDArrayIter(X, Y, batch_size=6), num_epoch=1,
+            optimizer_params={"learning_rate": 0.1})
+    assert mod._fused_armed
+    compiled = [r["fun_name"] for r in flightrec.get_records()
+                if r["kind"] == "xla.compile"]
+    assert "jit(fused_step_6x10)" in compiled

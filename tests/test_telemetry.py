@@ -36,13 +36,16 @@ def _clean_telemetry():
 
 
 # --------------------------------------------------------------- span core
-def test_span_disabled_is_noop_singleton():
+def test_span_disabled_is_profiler_annotation_only():
+    """With the span buffer off a span is the JAX profiler's annotation
+    (jax is imported here) with null_span's surface; nothing buffers."""
+    import jax
     assert not tm.enabled()
     s1 = tm.span("anything", k=1)
-    s2 = tm.span("else")
-    assert s1 is s2 is tm.null_span
-    with s1:
-        pass
+    assert isinstance(s1, jax.profiler.TraceAnnotation)
+    with s1 as entered:
+        assert entered.set(more=2) is entered
+    assert s1.dur == 0
     assert tm.get_spans() == []
 
 
@@ -336,7 +339,10 @@ def test_fit_disabled_telemetry_records_nothing():
     assert tm.get_spans() == []
     assert tm.get_events() == []
     snap = tm.snapshot()
-    assert snap["counters"] == {}
+    # the compile counters are always on (they are how a compile inside
+    # a serving window is seen at all); nothing else counts
+    assert [k for k in snap["counters"]
+            if not k.startswith("xla.compile.")] == []
 
 
 # --------------------------------------------------------- flight recorder
