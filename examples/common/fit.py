@@ -86,18 +86,14 @@ def fit(args, network, data_iters, **fit_kwargs):
 
     contexts = _contexts(args)
     # overlap input with compute: decode/augment runs ahead of the step
-    # in a background thread with batches staged to the training device
-    # (reference: PrefetcherIter always tops the C++ iterator stack,
-    # iter_prefetcher.h:129). Iterators that already prefetch pass through.
-    if isinstance(train, mx.io.PrefetchingIter):
-        train.ensure_device(contexts[0])
-    else:
-        train = mx.io.PrefetchingIter(train, device=contexts[0])
-    if val is not None:
-        if isinstance(val, mx.io.PrefetchingIter):
-            val.ensure_device(contexts[0])
-        else:
-            val = mx.io.PrefetchingIter(val, device=contexts[0])
+    # in a background thread (reference: PrefetcherIter always tops the
+    # C++ iterator stack, iter_prefetcher.h:129), and Module.fit tells
+    # that thread where the bound module wants its batches, so it stages
+    # them there. Iterators that already prefetch pass through.
+    if not isinstance(train, mx.io.PrefetchingIter):
+        train = mx.io.PrefetchingIter(train)
+    if val is not None and not isinstance(val, mx.io.PrefetchingIter):
+        val = mx.io.PrefetchingIter(val)
 
     batch_end_callbacks = [mx.callback.Speedometer(args.batch_size,
                                                    args.disp_batches)]

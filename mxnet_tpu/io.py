@@ -10,6 +10,7 @@ reference's PrefetcherIter provides (iter_prefetcher.h:129).
 """
 from __future__ import annotations
 
+import functools
 import struct
 import gzip
 import os
@@ -201,6 +202,73 @@ class ResizeIter(DataIter):
         return self.current_batch.pad
 
 
+def _host_view(arr):
+    """``arr`` as NumPy without a copy where it lives in host memory
+    (NumPy itself, or one CPU-backend ``jax.Array``); an array on an
+    accelerator or spread over several devices stays a ``jax.Array``."""
+    if isinstance(arr, NDArray):
+        arr = arr.asjax()
+    if hasattr(arr, "devices"):
+        devs = arr.devices()
+        if len(devs) > 1 or next(iter(devs)).platform != "cpu":
+            return arr
+    return np.asarray(arr)
+
+
+def _destination(placement, shape, stacked=False):
+    """The ``jax.Device`` or ``Sharding`` a batch array of ``shape``
+    goes to (``stacked``: a K-window, batch on axis 1). ``placement`` is
+    what the consumer handed over: a ``Context``, a device, the bound
+    executor group's data sharding, or its SPMD plan's
+    ``data_sharding_for`` (the sharding depends on the shape there)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    if hasattr(placement, "jax_device"):
+        return placement.jax_device()
+    if callable(placement):
+        return placement(shape, stacked=stacked)
+    if stacked and isinstance(placement, NamedSharding):
+        return NamedSharding(placement.mesh, P(None, *placement.spec))
+    return placement
+
+
+@functools.lru_cache(maxsize=None)
+def _stack_program(dst):
+    """The jitted program that stacks a K-window's staged parts on a
+    new leading axis in device memory, its output under ``dst`` (a
+    host-side stack would fault in K batches of fresh pages a window)."""
+    import jax
+    import jax.numpy as jnp
+
+    def io_stack(*parts):
+        return jnp.stack(parts)
+
+    sharded = isinstance(dst, jax.sharding.Sharding)
+    return jax.jit(io_stack, out_shardings=dst if sharded else None)
+
+
+def _stage(parts, placement, stacked=False):
+    """Stage one batch array - or, ``stacked``, the K arrays of a scan
+    window, stacked on a new leading axis - into device memory under
+    ``placement``; returns when it is resident. The result is bit for
+    bit what ``parts`` held, in their shape and dtype. A host array
+    crosses the link once, as it lies, each device's rows straight to
+    that device."""
+    import jax
+    hosts = [_host_view(p) for p in parts]
+    shape = tuple(hosts[0].shape)
+    dst = _destination(placement, shape)
+    staged = [jax.device_put(h, dst) for h in hosts]
+    if stacked:
+        out = _stack_program(_destination(
+            placement, (len(parts),) + shape, stacked=True))(*staged)
+    else:
+        out = staged[0]
+    out.block_until_ready()
+    _telemetry.counter("io.prefetch.staged_bytes").inc(out.nbytes)
+    _telemetry.counter("io.prefetch.staged_batches").inc()
+    return NDArray(out)
+
+
 class PrefetchingIter(DataIter):
     """Background-thread prefetch over one or more iters.
 
@@ -219,6 +287,17 @@ class PrefetchingIter(DataIter):
     *consecutive* failures is a broken pipeline, not bad records, and
     raises regardless. The ``io.decode`` injection point sits after
     each fetch so tier-1 drives both paths deterministically.
+
+    Staging (docs/telemetry.md, "How a batch is staged"): with a
+    ``device`` - a ``Context``, or whatever placement ``Module.fit``
+    hands over through ``stack_windows`` - the producer thread lands
+    each batch in device memory before it queues it, under spans
+    ``io.prefetch.to_device`` / ``.stack`` (attribute ``placement``)
+    that close when the batch is resident. A host array goes to its
+    placement in one put, each device's rows straight to that device,
+    and unchanged: same shape, dtype and bits. Counters
+    ``io.prefetch.staged_bytes`` and ``io.prefetch.staged_batches``
+    say how much went that way.
     """
 
     def __init__(self, iters, rename_data=None, rename_label=None,
@@ -276,33 +355,32 @@ class PrefetchingIter(DataIter):
                      for x in i.provide_label]
                     for r, i in zip(self.rename_label, self.iters)], [])
 
-    def ensure_device(self, device):
-        """Enable prefetch-to-device staging if it wasn't configured.
-
-        Lets training wrappers (examples/common/fit.py) upgrade an
-        already-prefetching iterator — e.g. ImageRecordIter's default
-        ``PrefetchingIter(it)`` — to stage batches onto the training
-        device without double-wrapping. No-op when a device is set."""
-        if self._device is None:
-            self._device = device
-        return self
-
     def stack_windows(self, k, device=None):
-        """Producer-side K-batch stacking for scan-fused training.
+        """Producer-side K-batch stacking for scan-fused training, and
+        the consumer's word on where batches go.
 
         With ``k > 1`` the background thread groups every ``k``
         consecutive batches into one :class:`StackedDataBatch` (leading
-        axis = step) and — when a device is set — lands the stacked
-        buffers in device memory off-thread, so ``Module.fit``'s K-step
-        scan dispatch consumes HBM-resident windows without a per-batch
-        host round trip. A short tail yields a partial window
-        (``steps < k``). ``k=1`` restores per-batch mode. Returns self.
+        axis = step), staged in device memory off-thread, so
+        ``Module.fit``'s K-step scan dispatch consumes HBM-resident
+        windows without a per-batch host round trip. A short tail
+        yields a partial window (``steps < k``). ``k=1`` is per-batch
+        mode. ``device`` is the placement to stage under: a ``Context``,
+        a ``jax.Device``, a ``Sharding`` over the consumer's mesh (rows
+        on its first axis), or a callable ``(shape, stacked=False) ->
+        Sharding``. ``Module.fit`` passes its bound executor group's
+        own, so that every chip gets its rows straight from the host
+        and the step finds the batch where it wants it - and that wins
+        over what the iterator was built with. A change of either
+        restarts the producer from the start of the epoch, so every
+        batch the consumer sees lies under the one placement (a
+        consumer's programs compile per input sharding). Returns self.
         """
-        if device is not None:
-            self._device = device
         k = max(1, int(k))
-        if k != self._stack_k:
-            self._stack_k = k
+        if device is None:
+            device = self._device
+        if k != self._stack_k or device != self._device:
+            self._stack_k, self._device = k, device
             self.reset()       # restart the producer in the new mode
         return self
 
@@ -313,24 +391,20 @@ class PrefetchingIter(DataIter):
             label=sum([(b.label or []) for b in batches], []),
             pad=batches[0].pad, index=batches[0].index)
 
-    def _stack(self, window):
-        """Stack K merged batches into one StackedDataBatch, staged onto
-        the configured device (the off-thread H2D copy)."""
-        import jax
-        import jax.numpy as jnp
-        dev = None
-        if self._device is not None:
-            dev = self._device.jax_device() if hasattr(
-                self._device, "jax_device") else self._device
+    @staticmethod
+    def _stack(window, placement):
+        """Stack K merged batches into one StackedDataBatch, staged
+        under ``placement`` (host-side where there is none)."""
+        if placement is None:
+            import jax.numpy as jnp
 
-        def put(slot_arrays):
-            arr = jnp.stack([a.asjax() if isinstance(a, NDArray)
-                             else jnp.asarray(np.asarray(a))
-                             for a in slot_arrays])
-            if dev is not None:
-                arr = jax.device_put(arr, dev)
-            return NDArray(arr)
-
+            def put(slot_arrays):
+                return NDArray(jnp.stack(
+                    [a.asjax() if isinstance(a, NDArray)
+                     else jnp.asarray(np.asarray(a)) for a in slot_arrays]))
+        else:
+            put = functools.partial(_stage, placement=placement,
+                                    stacked=True)
         data = [put([b.data[i] for b in window])
                 for i in range(len(window[0].data))]
         label = [put([b.label[i] for b in window])
@@ -376,9 +450,9 @@ class PrefetchingIter(DataIter):
 
     def _producer(self):
         # _stack_k/_device are GIL-atomic snapshots of caller-side
-        # config (stage()/ensure_device() both restart the producer via
-        # reset() after writing); a stale read can only affect batches
-        # the restart discards with the old queue
+        # config (stack_windows() restarts the producer via reset()
+        # after writing); a stale read can only affect batches the
+        # restart discards with the old queue
         # in the profiler's trace one queue entry is ``io.prefetch.batch``
         # enclosing ``.fetch`` (the inner iterators), ``.to_device`` or
         # ``.stack`` (staging onto the device) and ``.put`` (blocked on
@@ -387,13 +461,15 @@ class PrefetchingIter(DataIter):
         while not self._stop.is_set():
             try:
                 k = self._stack_k  # mxlint: guarded-by(gil)
+                dev = self._device  # mxlint: guarded-by(gil)
                 if k <= 1:
                     with span("io.prefetch.batch"):
                         with span("io.prefetch.fetch"):
                             batches = self._next_batches()
-                        if self._device is not None:  # mxlint: guarded-by(gil)
-                            with span("io.prefetch.to_device"):
-                                batches = [self._to_device(b)
+                        if dev is not None:
+                            with span("io.prefetch.to_device",
+                                      placement=str(dev)):
+                                batches = [self._to_device(b, dev)
                                            for b in batches]
                         with span("io.prefetch.put"):
                             self._queue.put(batches)
@@ -409,8 +485,9 @@ class PrefetchingIter(DataIter):
                                 exhausted = True
                                 break
                     if window:
-                        with span("io.prefetch.stack"):
-                            stacked = self._stack(window)
+                        with span("io.prefetch.stack",
+                                  placement=str(dev)):
+                            stacked = self._stack(window, dev)
                         with span("io.prefetch.put"):
                             self._queue.put(stacked)
                 if exhausted:
@@ -423,15 +500,11 @@ class PrefetchingIter(DataIter):
                 self._queue.put(("__error__", exc))  # die into a hang
                 return
 
-    def _to_device(self, batch):
-        import jax
-        dev = self._device.jax_device() if hasattr(
-            self._device, "jax_device") else self._device
-
-        def put(arr):
-            return NDArray(jax.device_put(arr.asjax(), dev))
-        return DataBatch([put(d) for d in batch.data],
-                         [put(l) for l in (batch.label or [])],
+    @staticmethod
+    def _to_device(batch, placement):
+        return DataBatch([_stage([d], placement) for d in batch.data],
+                         [_stage([l], placement)
+                          for l in (batch.label or [])],
                          pad=batch.pad, index=batch.index)
 
     def _start(self):
@@ -498,6 +571,25 @@ def _init_data(data, allow_empty, default_name):
     return list(ret.items())
 
 
+_HOST_ALIGN = 64    # bytes: what the CPU backend wraps without a copy
+
+
+def _host_aligned(arr):
+    """``arr`` itself where its memory starts on a ``_HOST_ALIGN``
+    boundary, else one aligned copy. A batch sliced out of aligned
+    storage is wrapped by ``nd.array`` as it lies; out of any other it
+    is copied into fresh pages, every batch (154 MB of page faults
+    took 0.24 s a ResNet batch: PERF.md, PR 26)."""
+    arr = np.ascontiguousarray(arr)
+    if arr.ctypes.data % _HOST_ALIGN == 0:
+        return arr
+    raw = np.empty(arr.nbytes + _HOST_ALIGN, np.uint8)
+    start = -raw.ctypes.data % _HOST_ALIGN
+    out = raw[start:start + arr.nbytes].view(arr.dtype).reshape(arr.shape)
+    out[...] = arr
+    return out
+
+
 class NDArrayIter(DataIter):
     """In-memory iterator. reference: io.py:457."""
 
@@ -518,6 +610,8 @@ class NDArrayIter(DataIter):
             new_n = self.data[0][1].shape[0] - \
                 self.data[0][1].shape[0] % batch_size
             self.idx = self.idx[:new_n]
+        self.data = [(k, _host_aligned(v)) for k, v in self.data]
+        self.label = [(k, _host_aligned(v)) for k, v in self.label]
         self.data_list = [x[1] for x in self.data] + \
             [x[1] for x in self.label]
         self.num_source = len(self.data_list)

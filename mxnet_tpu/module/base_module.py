@@ -669,17 +669,17 @@ class BaseModule:
         eval_metric = metric_mod.create(eval_metric)
         validation_metric = validation_metric or eval_metric
 
-        # scan-capable fit over a prefetching iterator: have the
-        # producer thread stack K batches per window (and land them in
-        # device memory off-thread on a single-device binding)
+        # a prefetching iterator stages into device memory off-thread:
+        # tell it where this binding wants its batches (so that every
+        # chip gets its rows from the host and _load_batch moves
+        # nothing), and, scan-capable, to stack K batches per window
         K = self._scan_window_size()
+        placement = self._input_placement()
         if hasattr(train_data, "stack_windows"):
-            if K > 1:
-                ctxs = getattr(self, "_context", None)
-                dev = ctxs[0] if ctxs and len(ctxs) == 1 else None
-                train_data.stack_windows(K, device=dev)
-            elif getattr(train_data, "_stack_k", 1) > 1:
-                train_data.stack_windows(1)     # scan unavailable: unstack
+            train_data.stack_windows(K, device=placement)
+        if eval_data is not train_data and \
+                hasattr(eval_data, "stack_windows"):
+            eval_data.stack_windows(1, device=placement)
 
         # triage binding: checkpoint-level health/sentinel escalations
         # land their emergency commit through THIS fit's manager
@@ -707,6 +707,20 @@ class BaseModule:
             _telemetry.health.release_triage()
             if mgr_owned:
                 mgr.close()
+
+    def _input_placement(self):
+        """Where the bound executor group places a batch - what its
+        ``_place(arr, "data")`` asks for: the context of a one-device
+        binding, the mesh's data sharding, or under an SPMD plan its
+        shape-aware ``data_sharding_for``. None without a group."""
+        group = getattr(self, "_exec_group", None)
+        if group is None:
+            return None
+        if group._spmd_plan is not None:
+            return group._spmd_plan.data_sharding_for
+        if group._mesh is not None:
+            return group._data_sharding
+        return group.contexts[0]
 
     def _fit_epochs(self, train_data, eval_data, eval_metric,
                     validation_metric, epoch_end_callback,

@@ -12,6 +12,7 @@ import tempfile
 import time
 
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 
@@ -72,6 +73,66 @@ def test_prefetching_iter_to_device():
     assert n == 4
     it.reset()
     assert sum(1 for _ in it) == 4
+
+
+@pytest.mark.parametrize("K", [1, 2], ids=["per_batch", "scan_window"])
+def test_fit_hands_its_placement_to_the_iterator(K):
+    """The iterator is built for one device (as examples/common/fit.py
+    built it with contexts[0]), then Module.fit binds over two and hands
+    over the executor group's own placement. The producer restarts once,
+    before the first batch; every batch then arrives once, in order,
+    under the group's sharding; and the producer's spans keep the names
+    chipbench/spans.py matches."""
+    n, batch = 8, 16
+    ctxs = [mx.cpu(0), mx.cpu(1)]
+    X = np.random.RandomState(0).rand(n * batch, 6).astype("f")
+    y = np.arange(n * batch, dtype="f")         # a row's label names it
+    it = mx.io.PrefetchingIter(mx.io.NDArrayIter(X, y % 3, batch_size=batch),
+                               device=ctxs[0])
+    log, real_reset, real_next = [], it.reset, it.next
+
+    def reset():
+        log.append("reset")
+        real_reset()
+
+    def next_():
+        b = real_next()
+        log.append((tuple(b.data[0].shape), b.data[0].asjax().sharding,
+                    b.data[0].asnumpy().reshape(-1, 6)))
+        return b
+
+    it.reset, it.next = reset, next_
+    net = mx.sym.SoftmaxOutput(mx.sym.FullyConnected(
+        mx.sym.var("data"), num_hidden=3, name="fc"), name="softmax")
+    mod = mx.mod.Module(net, context=ctxs)
+    mx.telemetry.clear()
+    mx.telemetry.enable()
+    try:
+        mod.fit(it, num_epoch=1, steps_per_dispatch=K, kvstore=None,
+                optimizer_params={"learning_rate": 0.01})
+        spans = [s for s in mx.telemetry.get_spans()
+                 if s.name.startswith("io.prefetch.")]
+    finally:
+        mx.telemetry.disable()
+        mx.telemetry.clear()
+    group = mod._exec_group
+    assert it._device == mod._input_placement() == group._data_sharding
+    # one restart for the hand-over, one at the end of the epoch
+    assert [e for e in log if e == "reset"] == ["reset"] * 2
+    assert log[0] == "reset" and log[-1] == "reset"
+    seen = [e for e in log if e != "reset"]
+    want = group._data_sharding if K == 1 else group._stacked_sharding
+    assert [shape for shape, _, _ in seen] == \
+        [((batch, 6) if K == 1 else (K, batch, 6))] * (n // K)
+    assert all(sharding == want for _, sharding, _ in seen)
+    assert np.array_equal(np.concatenate([rows for _, _, rows in seen]), X)
+    staging = "io.prefetch.to_device" if K == 1 else "io.prefetch.stack"
+    names = {s.name for s in spans}
+    assert {"io.prefetch.batch", "io.prefetch.fetch", "io.prefetch.put",
+            staging} <= names
+    # the placement of the batches the loop trained on is on the span
+    assert str(group._data_sharding) in \
+        {s.args.get("placement") for s in spans if s.name == staging}
 
 
 def test_pipeline_throughput_floor(tmp_path):
