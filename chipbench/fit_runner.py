@@ -1,6 +1,9 @@
 """Runner for configurations of kind "fit": ``Module.fit`` through
 ``examples/common/fit.py:fit``, as ``examples/train_imagenet.py`` calls
-it, for a window of steady steps.
+it, for a window of steady steps. What is trained - the symbol, its
+data, its plain reference with its tolerance, its costs - is the
+configuration's architecture (``archs/<arch>.py``; README.md, "The
+architecture interface"). Here are the entry point and its window.
 
 The one epoch never ends inside the window: a pool of host batches is
 replayed through ``io.ResizeIter`` and (inside ``fit.fit``)
@@ -20,31 +23,7 @@ import time
 
 import numpy as np
 
-from . import common, costs
-from .manifest import ROOT
-
-#: |program loss - reference loss| <= LOSS_TOL * max(1, |reference|).
-#: The program computes the forward in bfloat16 (relative step 2**-8)
-#: through 50 layers with float32 BatchNorm statistics; the reference is
-#: float32 at the highest precision on the same parameters and batch.
-#: Measured on the v5e (PERF.md, Findings): a difference of a few
-#: thousandths of the loss. An 8-bit float forward, or a dropped layer,
-#: moves the loss by far more than 2 %.
-LOSS_TOL = 0.02
-
-
-def synthetic_pool(n, image_shape, num_classes, seed):
-    """Prototype-plus-noise images, as examples/common/data.py
-    ``synthetic_classification`` makes them (class k = a fixed random
-    pattern k), drawn in float32 so that set-up stays short."""
-    rng = np.random.default_rng([int(seed) % (1 << 32), 5])
-    labels = rng.integers(0, num_classes, n)
-    used, inverse = np.unique(labels, return_inverse=True)
-    protos = rng.random((len(used), *image_shape), np.float32) - 0.5
-    imgs = rng.standard_normal((n, *image_shape), np.float32)
-    imgs *= 0.35
-    imgs += protos[inverse]
-    return imgs, labels.astype(np.float32)
+from . import common, manifest
 
 
 def _loss(prob, label):
@@ -110,7 +89,7 @@ class _Window:
             self.stop()
 
 
-def _replay_iter(mx, imgs, labels, batch):
+def _replay_iter(mx, data, labels, batch):
     """The pool as an endless epoch: NDArrayIter (host arrays, one
     host-to-device copy a batch) under ResizeIter, ended by ``stop()``."""
 
@@ -120,20 +99,20 @@ def _replay_iter(mx, imgs, labels, batch):
         def iter_next(self):
             return not self.stopped and super().iter_next()
 
-    inner = mx.io.NDArrayIter(imgs, labels, batch, shuffle=False)
+    inner = mx.io.NDArrayIter(data, labels, batch, shuffle=False)
     return Replay(inner, size=1 << 40)
 
 
-def check_reference(mx, mod, cfg, x, y, chips):
+def check_reference(mx, mod, cfg, x, y, chips, arch):
     """One more step of the same fused program on a batch the run has
-    not trained on (fresh prototypes, so the outputs are not saturated),
+    not trained on (a fresh pool, so the outputs are not saturated),
     from a copy of the parameters taken before it: the program's loss
-    (from the step's softmax output) against the plain float32
-    reference's loss on the same parameters and batch. Returns
-    ``(ok, report)``."""
+    (from the step's softmax output) against the architecture's plain
+    float32 reference's loss on the same parameters and batch, within
+    its ``LOSS_TOL``: |program - reference| <= TOL * max(1,
+    |reference|). Returns ``(ok, report)``."""
     import jax
     import jax.numpy as jnp
-    from .reference import resnet50
     args, _aux = mod.get_params()
     # the fused step donates its parameter buffers: real copies
     params = {k: jnp.array(v.asjax(), copy=True) for k, v in args.items()}
@@ -142,7 +121,7 @@ def check_reference(mx, mod, cfg, x, y, chips):
     mod.update()
     got = _loss(mod.get_outputs()[0].asnumpy(), y)
     after, _ = mod.get_params()
-    watched = "fc1_weight"
+    watched = arch.UPDATED_PARAM
     changed = not np.array_equal(np.asarray(params[watched]),
                                  after[watched].asnumpy())
     devs = jax.devices()[:chips]
@@ -156,20 +135,20 @@ def check_reference(mx, mod, cfg, x, y, chips):
     # framework's per-device executors): the plain reference computes
     # both, the nearer one is compared and named
     want = {"global_batch": float(jax.jit(
-        lambda p, a, b: resnet50.loss(p, a, b, cfg))(params, xs, ys))}
+        lambda p, a, b: arch.reference_loss(p, a, b, cfg))(params, xs, ys))}
     if chips > 1:
         def per_chip(p, a, b):
             a = a.reshape(chips, -1, *a.shape[1:])
             b = b.reshape(chips, -1)
             return jnp.mean(jax.vmap(
-                lambda ai, bi: resnet50.loss(p, ai, bi, cfg))(a, b))
+                lambda ai, bi: arch.reference_loss(p, ai, bi, cfg))(a, b))
         want["per_chip"] = float(jax.jit(per_chip)(params, xs, ys))
     stats, ref = min(want.items(), key=lambda kv: abs(kv[1] - got))
     ok = bool(np.isfinite(got) and abs(got - ref)
-              <= LOSS_TOL * max(1.0, abs(ref)))
+              <= arch.LOSS_TOL * max(1.0, abs(ref)))
     return ok and changed, {"program_loss": got, "reference_loss": ref,
                             "abs_diff": abs(got - ref),
-                            "tolerance": LOSS_TOL,
+                            "tolerance": arch.LOSS_TOL,
                             "batchnorm_statistics": stats,
                             "reference_losses": want,
                             "parameter_changed": changed}
@@ -178,12 +157,12 @@ def check_reference(mx, mod, cfg, x, y, chips):
 def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
     watch = common.CompileWatch()
     import mxnet_tpu as mx
-    for path in (os.path.join(ROOT, "examples"), ROOT):
+    for path in (os.path.join(manifest.ROOT, "examples"), manifest.ROOT):
         if path not in sys.path:
             sys.path.insert(0, path)
     from common import fit as fit_mod       # examples/common/fit.py
-    from mxnet_tpu.models import resnet
     from mxnet_tpu.telemetry import stepattr
+    arch = manifest.load_arch(cell)
 
     cfg, mix = cell.config, cell.traffic
     phases = {"import_s": time.perf_counter() - t_start}
@@ -195,12 +174,12 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
     mx.random.seed(seed32)
     np.random.seed(seed32)
 
-    # exactly what train_imagenet.py hands to fit.fit: its arguments
-    # (fit.add_fit_args plus the configuration's argv) and its network
+    # exactly what the example's script hands to fit.fit: its arguments
+    # (its own flags, fit.add_fit_args, the configuration's argv) and
+    # its network
     parser = argparse.ArgumentParser()
-    parser.add_argument("--network", type=str)
-    for flag in ("--num-layers", "--num-classes"):
-        parser.add_argument(flag, type=int)
+    for flag, typ in arch.PARSER_FLAGS:
+        parser.add_argument(flag, type=typ)
     fit_mod.add_fit_args(parser)
     argv = list(cfg["argv"]) + ["--batch-size", str(batch)]
     if rehearse:
@@ -208,15 +187,11 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
     else:
         argv += ["--gpus", mix["gpus"]]
     args = parser.parse_args(argv)
-    network = resnet.get_symbol(
-        num_classes=cfg["num_classes"], num_layers=cfg["num_layers"],
-        image_shape=",".join(str(v) for v in cfg["image_shape"]))
+    network = arch.symbol(cfg)
 
     t = time.perf_counter()
-    imgs, labels = synthetic_pool(int(mix["pool_batches"]) * batch,
-                                  tuple(cfg["image_shape"]),
-                                  cfg["num_classes"], seed)
-    train = _replay_iter(mx, imgs, labels, batch)
+    data, labels = arch.pool(int(mix["pool_batches"]) * batch, cfg, seed)
+    train = _replay_iter(mx, data, labels, batch)
     phases["data_s"] = time.perf_counter() - t
 
     tracer = common.Tracer(cell.name) if trace else None
@@ -256,9 +231,7 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
 
     peak = common.memory_peak_bytes(cell.chips)   # before the reference's
     ok_ref, report = check_reference(
-        mx, mod, cfg, *synthetic_pool(batch, tuple(cfg["image_shape"]),
-                                      cfg["num_classes"], seed + 1),
-        cell.chips)
+        mx, mod, cfg, *arch.pool(batch, cfg, seed + 1), cell.chips, arch)
     common.say("reference", ok=ok_ref, **report)
     finite = bool(np.isfinite(win.first_loss) and np.isfinite(win.last_loss))
     correct = ok_ref and finite and not compiles_in_window and win.steps > 0
@@ -268,7 +241,7 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
                 if win.t_open * 1e6 <= r["ts_us"] < win.t_close * 1e6]
         obs = {"stepattr": recs, "events": tracer.events,
                "device_kind": device["kind"], "chips": cell.chips,
-               "cost": {"train_step": costs.resnet_train_step(cfg, batch)}}
+               "cost": arch.costs(cfg, batch)}
         common.say("traced", stepattr_records=len(recs),
                    events=len(tracer.events or []))
         metrics = common.per_layer_metrics(cell, obs)

@@ -8,9 +8,13 @@ per-layer metric is a file of its own under one of the manifest's
     <path>/traffic/<traffic>.json
     <path>/layers/<metric>.json    a declaration for a built-in reducer
     <path>/layers/<metric>.py      or a reader: ``read(obs) -> float|None``
+    <path>/archs/<arch>.py         the architecture a configuration's
+                                   ``"arch"`` names (README.md, "The
+                                   architecture interface")
 
-A later PR adds a cell, a configuration, a traffic mix or a metric as
-new files plus new manifest entries; nothing here names any of them.
+A later PR adds a cell, a configuration, a traffic mix, a metric or an
+architecture as new files plus new manifest entries; nothing here names
+any of them.
 """
 from __future__ import annotations
 
@@ -37,12 +41,23 @@ class Metric:
     reader: object | None = None    # layers/<name>.py: read(obs)
 
 
+#: What ``archs/<arch>.py`` exposes for each entry point (a
+#: configuration's ``kind``); chipbench/README.md says what each is.
+ARCH_INTERFACE = {
+    "serve": ("decode_symbol", "data_shapes", "make_params",
+              "reference_logits", "LOGIT_TOL", "costs"),
+    "fit": ("symbol", "pool", "PARSER_FLAGS", "UPDATED_PARAM",
+            "reference_loss", "LOSS_TOL", "costs"),
+}
+
+
 @dataclass
 class Cell:
     name: str
     chips: int
     config: dict
     traffic: dict
+    arch_file: str          # archs/<config["arch"]>.py, found not loaded
     end_to_end: list = field(default_factory=list)
     per_layer: list = field(default_factory=list)
 
@@ -85,13 +100,32 @@ def _layer_metric(root, paths, m):
         raise ManifestError(
             f"per-layer metric {m['name']!r}: no layers/{m['name']}.json "
             f"or .py under {paths}")
+    out.reader = _load_file("layer", code).read
+    return out
+
+
+def _load_file(kind, path):
+    stem = os.path.splitext(os.path.basename(path))[0]
     spec = importlib.util.spec_from_file_location(
-        "chipbench_layer_" + m["name"].replace(".", "_").replace("-", "_"),
-        code)
+        f"chipbench_{kind}_" + stem.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    out.reader = mod.read
-    return out
+    return mod
+
+
+def load_arch(cell):
+    """The cell's architecture module. It imports the program, so a
+    runner loads it where it imports the program - after the
+    configuration's ``env`` is in place, inside ``setup_s`` - and
+    ``resolve`` only finds it."""
+    mod = _load_file("arch", cell.arch_file)
+    kind = cell.config["kind"]
+    missing = [n for n in ARCH_INTERFACE[kind] if not hasattr(mod, n)]
+    if missing:
+        raise ManifestError(
+            f"{cell.arch_file} lacks {missing} of the {kind!r} "
+            f"interface {list(ARCH_INTERFACE[kind])}")
+    return mod
 
 
 def resolve(manifest, workload, root=ROOT):
@@ -108,12 +142,22 @@ def resolve(manifest, workload, root=ROOT):
     if w["config"] not in configs:
         raise ManifestError(f"workload {workload!r} names configuration "
                             f"{w['config']!r}, which the manifest lacks")
-    config = _read_json(os.path.join(root, configs[w["config"]]["file"]))
+    config_file = configs[w["config"]]["file"]
+    config = _read_json(os.path.join(root, config_file))
+    if not config.get("arch"):
+        raise ManifestError(
+            f"configuration {w['config']!r} ({config_file}) lacks the key "
+            f"\"arch\": the name of its architecture, archs/<arch>.py")
+    arch_file = _find(root, paths, "archs", config["arch"] + ".py")
+    if arch_file is None:
+        raise ManifestError(f"architecture {config['arch']!r}: no "
+                            f"archs/{config['arch']}.py under {paths}")
     traffic_path = _find(root, paths, "traffic", w["traffic"] + ".json")
     if traffic_path is None:
         raise ManifestError(f"traffic mix {w['traffic']!r}: no "
                             f"traffic/{w['traffic']}.json under {paths}")
-    cell = Cell(workload, int(w["chips"]), config, _read_json(traffic_path))
+    cell = Cell(workload, int(w["chips"]), config, _read_json(traffic_path),
+                arch_file)
     cell.end_to_end = [Metric(m["name"], m["unit"], m["source"])
                        for m in manifest["end_to_end"]
                        if _in_cell(m, workload)]

@@ -1,6 +1,11 @@
 """Runner for configurations of kind "serve": a decoder behind
 ``mxnet_tpu.serve.serve_decoder`` under a traffic mix.
 
+What is served - the symbol, its parameters, its plain reference with
+its tolerance, its costs - is the configuration's architecture
+(``archs/<arch>.py``; README.md, "The architecture interface"). Here
+are the entry point and its window.
+
 Set-up (all of it counted in ``setup_s``): weights made on the device
 from the seed in one jitted call, ``serve_decoder`` binds, autotunes
 (first run of a checkout only) and warms every rung's S=1 and window
@@ -18,18 +23,7 @@ import time
 
 import numpy as np
 
-from . import common, costs, stats, traffic as traffic_mod
-
-#: |served - reference| <= TOL + TOL * |reference| on every compared
-#: logit. The served path computes in bfloat16 (8 significant bits,
-#: relative step 2**-8 = 0.004) through 24 layers from float32 masters;
-#: the reference is float32 at the highest matmul precision. Measured
-#: on the v5e (PERF.md, Findings): max error 0.060-0.066 on logits of
-#: magnitude up to 8.7, i.e. 0.43-0.46 of this bound at its worst
-#: element. An 8-bit float compute path (3 significant bits, relative
-#: step 0.06) is 16 times coarser: its errors near a zero logit alone
-#: are several times the 0.12 allowed there.
-LOGIT_TOL = 0.12
+from . import common, manifest, stats, traffic as traffic_mod
 
 
 class _Record:
@@ -143,41 +137,6 @@ class _Load:
                                            timeout=timeout)
 
 
-def make_params(symbol, data_shapes, seed):
-    """Every parameter of ``symbol`` from the seed: N(0, 0.02) matrices
-    and embeddings, zero biases, unit LayerNorm gains (GPT-2's
-    initialisation), float32 as Module binds them. Made on the default
-    device in ONE jitted call, then handed over as host arrays - what a
-    loaded checkpoint is - and freed on the device: ``Module`` keeps its
-    own copy there, and two do not fit beside the KV pools."""
-    import jax
-    import jax.numpy as jnp
-    names = symbol.list_arguments()
-    shapes, _, _ = symbol.infer_shape(**data_shapes)
-    todo = [(n, tuple(s)) for n, s in zip(names, shapes)
-            if n not in data_shapes]
-
-    def gen(key):
-        out = {}
-        for i, (name, shape) in enumerate(todo):
-            if name.endswith("_gamma"):
-                out[name] = jnp.ones(shape, jnp.float32)
-            elif name.endswith(("_beta", "_bias")):
-                out[name] = jnp.zeros(shape, jnp.float32)
-            else:
-                out[name] = 0.02 * jax.random.normal(
-                    jax.random.fold_in(key, i), shape, jnp.float32)
-        return out
-
-    arrays = jax.jit(gen)(jax.random.PRNGKey(int(seed) % (1 << 31)))
-    host = {}
-    for name in list(arrays):
-        arr = arrays.pop(name)
-        host[name] = np.asarray(arr)
-        arr.delete()
-    return host
-
-
 def served_params(engine):
     """The parameter values the engine serves, by name (device arrays;
     no copy)."""
@@ -186,13 +145,14 @@ def served_params(engine):
             if n not in engine.data_names}
 
 
-def check_reference(engine, arrays, config, seed):
+def check_reference(engine, arrays, config, seed, arch):
     """Prefill through the top rung's window program, then decode
     through its S=1 program, on two seeded sequences; the logits of the
-    last 16 positions of each path against the plain float32 reference's
-    full forward. Returns ``(ok, report)``."""
+    last 16 positions of each path against the architecture's plain
+    float32 reference's full forward, within its ``LOGIT_TOL``:
+    |served - reference| <= TOL + TOL * |reference| on every compared
+    logit. Returns ``(ok, report)``."""
     import jax
-    from .reference import gpt2
     rung = engine.ladder.max
     drv = engine.driver(rung)
     S = max(drv.window_lens) if drv.window_lens else 1
@@ -223,25 +183,32 @@ def check_reference(engine, arrays, config, seed):
         drv.leave(slot)
     drv.rewind_many(list(range(rung)), [0] * rung)
 
-    fwd = jax.jit(functools.partial(gpt2.forward, config=config))
+    fwd = jax.jit(functools.partial(arch.reference_logits, cfg=config))
     want = np.asarray(fwd(arrays, seqs))[:, t_pre - n_cmp:t_pre + n_cmp]
     err = np.abs(got - want)
-    bound = LOGIT_TOL + LOGIT_TOL * np.abs(want)
+    tol = arch.LOGIT_TOL
+    bound = tol + tol * np.abs(want)
     ok = bool(np.all(err <= bound))       # a NaN fails
     return ok, {"sequences": n_seq, "tokens": int(t_pre + n_cmp),
                 "positions_compared": 2 * n_cmp,
                 "max_abs_err": float(np.max(err)),
                 "max_err_over_bound": float(np.max(err / bound)),
                 "max_abs_logit": float(np.max(np.abs(want))),
-                "tolerance": LOGIT_TOL}
+                "tolerance": tol}
 
 
-def _counter(name, model):
+def _counters(model):
+    """``{name: value}`` of every counter the program has registered
+    under the served model's label."""
     from mxnet_tpu import telemetry
-    m = telemetry.get_metric(name, model=model)
-    return m.value if m is not None else 0
+    out = {}
+    for m in telemetry.metrics.all_metrics():
+        if isinstance(m, telemetry.Counter) and ("model", model) in m.labels:
+            out[m.name] = out.get(m.name, 0) + m.value
+    return out
 
 
+#: the ``window`` line's counters; ``obs["counters"]`` holds all of them
 _COUNTERS = ("serve.decode.tokens", "serve.decode.iterations",
              "serve.decode.prefill.chunks", "serve.decode.requests",
              "serve.decode.responses", "serve.decode.errors",
@@ -252,29 +219,18 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
     import jax
     watch = common.CompileWatch()
     import mxnet_tpu as mx
-    from mxnet_tpu.models import transformer as tfm
     from mxnet_tpu.telemetry import flightrec
+    arch = manifest.load_arch(cell)
 
     cfg, mix = cell.config, cell.traffic
     phases = {"import_s": time.perf_counter() - t_start}
-    model = dict(vocab_size=cfg["vocab_size"], d_model=cfg["n_embd"],
-                 n_layer=cfg["n_layer"], n_head=cfg["n_head"],
-                 pos_embed=cfg["position_embedding"])
-    if cfg["n_inner"] != 4 * cfg["n_embd"]:
-        raise SystemExit("chipbench: models/transformer.py fixes the "
-                         "feed-forward at 4 * n_embd")
     capacity = cfg["capacity"]
     context = mx.cpu(0) if rehearse else mx.tpu(0)
-
-    def gen(step_len):
-        return tfm.get_decode_symbol(
-            capacity=capacity, per_slot=True, step_len=step_len,
-            max_seq_len=cfg["n_positions"], **model)
+    gen = functools.partial(arch.decode_symbol, cfg)    # step_len -> Symbol
 
     t = time.perf_counter()
     top = max(cfg["ladder"])
-    args = make_params(gen(1), {"data": (top, 1), "pos_ids": (top, 1)},
-                       seed)
+    args = arch.make_params(gen(1), arch.data_shapes(cfg, top, 1), seed, cfg)
     phases["weights_s"] = time.perf_counter() - t
 
     t = time.perf_counter()
@@ -316,7 +272,7 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
 
     # ------------------------------------------------------------ window
     name = cfg["name"]
-    before = {c: _counter(c, name) for c in _COUNTERS}
+    before = _counters(name)
     t_open = time.perf_counter()
     setup_s = t_open - t_start
     tracer = common.Tracer(cell.name) if trace else None
@@ -337,7 +293,7 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
     if remaining > 0:
         time.sleep(remaining)
     t_close = time.perf_counter()
-    after = {c: _counter(c, name) for c in _COUNTERS}
+    counters = {c: v - before.get(c, 0) for c, v in _counters(name).items()}
     ring = [r for r in flightrec.get_records()
             if t_open * 1e6 <= r.get("ts_us", 0) < t_close * 1e6]
     compiles_in_window = watch.backend_compiles(t_open, t_close)
@@ -390,7 +346,7 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
                         for q in (50, 75, 90, 95)} if ttft else None,
                tpot_ms={q: 1e3 * stats.percentile(gaps, q)
                         for q in (50, 75, 90, 95, 97, 99)} if gaps else None,
-               counters={c: after[c] - before[c] for c in _COUNTERS},
+               counters={c: counters.get(c, 0) for c in _COUNTERS},
                rung=stats_now["rung"], end_to_end=values, **device,
                rehearsal=rehearse)
     common.say("setup", setup_s=setup_s, **phases,
@@ -402,7 +358,7 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
 
     peak = common.memory_peak_bytes(cell.chips)   # before the reference's
     ok_ref, report = check_reference(engine, served_params(engine), cfg,
-                                     seed)
+                                     seed, arch)
     common.say("reference", ok=ok_ref, **report)
     correct = (ok_ref and not failed and not wrong_len
                and not compiles_in_window and n_tokens > 0)
@@ -411,19 +367,14 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
         live_rows = float(np.mean(pos_samples)) if pos_samples else 0.0
         top = engine.ladder.max
         obs = {
-            "counters": {c: after[c] - before[c] for c in _COUNTERS},
-            "ring": ring, "series": {"gap_s": gaps,
-                                     "ttft_s": [v for v in ttft
-                                                if math.isfinite(v)]},
+            "counters": counters, "ring": ring,
+            "series": {"gap_s": gaps,
+                       "ttft_s": [v for v in ttft if math.isfinite(v)]},
             "events": tracer.events, "device_kind": device["kind"],
             "chips": cell.chips,
-            "cost": {
-                "decode_step": costs.gpt_step(cfg, top, 1, live_rows),
-                "window_step": costs.gpt_step(cfg, top,
-                                              cfg["prefill_chunk"],
-                                              live_rows)},
+            "cost": arch.costs(cfg, top, cfg["prefill_chunk"], live_rows),
         }
-        common.say("traced", live_rows=live_rows,
+        common.say("traced", live_rows=live_rows, counters=counters,
                    ring_records=len(ring),
                    events=len(tracer.events or []))
         metrics = common.per_layer_metrics(cell, obs)

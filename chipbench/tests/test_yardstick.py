@@ -243,7 +243,8 @@ def test_new_files_and_entries_add_a_cell_without_an_edit(tmp_path):
               if p.is_file()}
     man = manifest.load()
     (root / "chipbench" / "configs" / "gpt-111m.json").write_text(
-        json.dumps({"name": "gpt-111m", "kind": "serve", "n_embd": 768}))
+        json.dumps({"name": "gpt-111m", "kind": "serve", "arch": "gpt2",
+                    "n_embd": 768}))
     (root / "chipbench" / "traffic" / "burst.json").write_text(
         json.dumps({"kind": "open_loop", "rate_rps": 5, "block": [[8, 8]]}))
     (root / "chipbench" / "layers" / "sched.migrations_per_iter.json") \
