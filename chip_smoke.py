@@ -209,6 +209,23 @@ def kernel_sites(w, seed=0):
                     [normal(q, bf) for _ in range(3)]
                     + [normal(kv, cache_dt), normal(kv, cache_dt),
                        cursors(step)])))
+    # the routed expert feed-forward at OLMoE's own expert count, at an
+    # S=1 step's rows and at a prefill window's (ragged groups, some
+    # empty at S=1)
+    E, top_k, F = 64, 8, 2 * D
+    for label, tokens in (("s1", slots), ("window", slots * win)):
+        sites.append((
+            f"moe_ffn_{label}", "MoEFFN",
+            {"num_experts": E, "num_hidden": F, "top_k": top_k},
+            [(tokens, D), (E, D), (E, D, F), (E, D, F), (E, F, D), (4,)],
+            [bf] * 5 + ["int32"], False,
+            lambda tokens=tokens: [
+                normal((tokens, D), bf),
+                (normal((E, D), f32) / np.sqrt(D)).astype(bf),
+                (normal((E, D, F), f32) / np.sqrt(D)).astype(bf),
+                (normal((E, D, F), f32) / np.sqrt(D)).astype(bf),
+                (normal((E, F, D), f32) / np.sqrt(F)).astype(bf),
+                jnp.zeros((4,), jnp.int32)]))
     for wdt in ("int8", "float8_e4m3fn"):
         rows, k, nh = slots * win, D, 4 * D
         sites.append((

@@ -471,27 +471,7 @@ class Executor:
         # also consult the process-wide program_cache so rebinds
         # (train→eval, force_rebind, bucketing over a shared_group)
         # reuse traces instead of recompiling per instance
-        self._jit_cache = {}
-        self._prog_cache_base = None
-        if self._mp_plan is None:
-            from .ops import layout as _layout_mod
-            try:
-                self._prog_cache_base = (
-                    _progcache.symbol_signature(symbol),
-                    tuple((nm, tuple(a.shape), str(a.dtype))
-                          for nm, a in zip(self.arg_names, self.arg_arrays)
-                          if a is not None),
-                    tuple((nm, tuple(a.shape), str(a.dtype))
-                          for nm, a in zip(self.aux_names, self.aux_arrays)
-                          if a is not None),
-                    ctx.device_type,
-                    self._mesh_token,
-                    bool(_layout_mod.layout_opt_enabled()),
-                    str(compute_dtype) if compute_dtype is not None else None,
-                    self._remat_segments,
-                )
-            except Exception:
-                pass           # uncacheable binding: per-instance only
+        self.refresh_program_key()
         self._tapped_runner = None   # eager monitored runner (per callback)
         self._naive_runner = None    # NaiveEngine serial replay runner
         self._pending = None      # recorded inputs awaiting execution
@@ -595,6 +575,35 @@ class Executor:
                 mp_plan=self._mp_plan,
                 compute_dtype=self._compute_dtype)
         return self._naive_runner
+
+    def refresh_program_key(self):
+        """(Re)derive the process-wide program-cache key from the bound
+        cells as they are now, and drop this binding's traced programs:
+        called at construction, and when a parameter cell changes dtype
+        after bind (executor_group.adopt_param_dtypes)."""
+        self._jit_cache = {}
+        self._prog_cache_base = None
+        if self._mp_plan is not None:
+            return
+        from .ops import layout as _layout_mod
+        compute_dtype = self._compute_dtype
+        try:
+            self._prog_cache_base = (
+                _progcache.symbol_signature(self._symbol),
+                tuple((nm, tuple(a.shape), str(a.dtype))
+                      for nm, a in zip(self.arg_names, self.arg_arrays)
+                      if a is not None),
+                tuple((nm, tuple(a.shape), str(a.dtype))
+                      for nm, a in zip(self.aux_names, self.aux_arrays)
+                      if a is not None),
+                self._ctx.device_type,
+                self._mesh_token,
+                bool(_layout_mod.layout_opt_enabled()),
+                str(compute_dtype) if compute_dtype is not None else None,
+                self._remat_segments,
+            )
+        except Exception:
+            pass           # uncacheable binding: per-instance only
 
     def program_cache_key(self, kind, *extras):
         """Process-wide cache key for one of this binding's programs, or

@@ -68,29 +68,52 @@ def _proj(x, num_hidden, name, no_bias=False):
 
 def _block(x, *, i, seq_len, d_model, n_head, dropout, pos_embed,
            rope_base, name, decode=False, capacity=None,
-           per_slot=False, cache_dtype=None):
-    """One pre-LN transformer block; ``decode=True`` swaps the full
-    ``attention`` for the KV-cache ``attention_decode`` path (same
-    parameter names either way, so one trained parameter set serves
-    both graphs). ``per_slot=True`` selects the slot-pooled decode
-    lowering: a (B, 1) cursor vector so every batch row decodes its own
-    sequence at its own position."""
+           per_slot=False, cache_dtype=None, moe=None):
+    """One pre-norm transformer block - the one place a block is built;
+    ``decode=True`` swaps the full ``attention`` for the KV-cache
+    ``attention_decode`` path (same parameter names either way, so one
+    trained parameter set serves both graphs). ``per_slot=True`` selects
+    the slot-pooled decode lowering: a (B, 1) cursor vector so every
+    batch row decodes its own sequence at its own position.
+
+    ``moe=None`` is the GPT-2 block: LayerNorm, fused q/k/v with bias,
+    a GeLU feed-forward of four times the width. A ``moe`` spec
+    (``_moe_spec``: ``n_expert``, ``top_k``, ``expert_width``,
+    ``norm_topk``, ``rms_eps``) is the OLMoE block (arXiv:2409.02060):
+    RMSNorm, fused q/k/v without bias, RMSNorm of the whole q and k
+    projections before the split into heads, and the routed expert
+    feed-forward ``MoEFFN``; no bias anywhere."""
     pfx = f"{name}_l{i}"
     dh = d_model // n_head
     T = seq_len
+    olmoe = moe is not None
 
-    ln1 = sym.LayerNorm(x, name=f"{pfx}_ln1")
-    qkv = _proj(ln1, 3 * d_model, f"{pfx}_qkv")          # (B*T, 3D)
-    qkv = sym.Reshape(qkv, shape=(-1, T, 3 * n_head, dh),
-                      name=f"{pfx}_qkv_split")
-    qkv = sym.transpose(qkv, axes=(0, 2, 1, 3),
-                        name=f"{pfx}_qkv_t")             # (B, 3H, T, dh)
-    q = sym.slice_axis(qkv, axis=1, begin=0, end=n_head,
-                       name=f"{pfx}_q")
-    k = sym.slice_axis(qkv, axis=1, begin=n_head, end=2 * n_head,
-                       name=f"{pfx}_k")
-    v = sym.slice_axis(qkv, axis=1, begin=2 * n_head, end=3 * n_head,
-                       name=f"{pfx}_v")
+    ln1 = _norm(x, f"{pfx}_ln1", moe)
+    qkv = _proj(ln1, 3 * d_model, f"{pfx}_qkv", no_bias=olmoe)  # (B*T, 3D)
+    if olmoe:
+        def head_split(v, nm):
+            v = sym.Reshape(v, shape=(-1, T, n_head, dh),
+                            name=f"{pfx}_{nm}_split")
+            return sym.transpose(v, axes=(0, 2, 1, 3),
+                                 name=f"{pfx}_{nm}")         # (B, H, T, dh)
+        q, k, v = (sym.slice_axis(qkv, axis=1, begin=j * d_model,
+                                  end=(j + 1) * d_model,
+                                  name=f"{pfx}_{nm}_rows")
+                   for j, nm in enumerate("qkv"))
+        q = head_split(_norm(q, f"{pfx}_q_norm", moe), "q")
+        k = head_split(_norm(k, f"{pfx}_k_norm", moe), "k")
+        v = head_split(v, "v")
+    else:
+        qkv = sym.Reshape(qkv, shape=(-1, T, 3 * n_head, dh),
+                          name=f"{pfx}_qkv_split")
+        qkv = sym.transpose(qkv, axes=(0, 2, 1, 3),
+                            name=f"{pfx}_qkv_t")             # (B, 3H, T, dh)
+        q = sym.slice_axis(qkv, axis=1, begin=0, end=n_head,
+                           name=f"{pfx}_q")
+        k = sym.slice_axis(qkv, axis=1, begin=n_head, end=2 * n_head,
+                           name=f"{pfx}_k")
+        v = sym.slice_axis(qkv, axis=1, begin=2 * n_head, end=3 * n_head,
+                           name=f"{pfx}_v")
     if decode:
         att = sym.attention_decode(
             q, k, v, capacity=capacity, rope=(pos_embed == "rotary"),
@@ -105,27 +128,52 @@ def _block(x, *, i, seq_len, d_model, n_head, dropout, pos_embed,
     att = sym.transpose(att, axes=(0, 2, 1, 3),
                         name=f"{pfx}_attn_t")            # (B, T, H, dh)
     att = sym.Reshape(att, shape=(-3, -3), name=f"{pfx}_attn_merge")
-    proj = sym.FullyConnected(att, num_hidden=d_model,
-                              name=f"{pfx}_proj")        # (B*T, D)
+    proj = sym.FullyConnected(att, num_hidden=d_model, name=f"{pfx}_proj",
+                              **({"no_bias": True} if olmoe else {}))
     proj = sym.Reshape(proj, shape=(-1, T, d_model),
                        name=f"{pfx}_proj_unfold")
     if dropout:
         proj = sym.Dropout(proj, p=dropout, name=f"{pfx}_drop1")
     x = x + proj
 
-    ln2 = sym.LayerNorm(x, name=f"{pfx}_ln2")
-    # dense -> GeLU as the fused epilogue pair: the matmul emits raw
-    # rows (no_bias) and FusedBiasGeLU folds bias+erf-GeLU in one pass
-    h = _proj(ln2, 4 * d_model, f"{pfx}_ffn1", no_bias=True)
-    h = sym.FusedBiasGeLU(h, name=f"{pfx}_ffn_gelu")
-    h = sym.FullyConnected(h, num_hidden=d_model, name=f"{pfx}_ffn2")
+    ln2 = _norm(x, f"{pfx}_ln2", moe)
+    if olmoe:
+        rows = sym.Reshape(ln2, shape=(-3, 0), name=f"{pfx}_moe_fold")
+        h = sym.MoEFFN(rows, num_experts=moe["n_expert"],
+                       num_hidden=moe["expert_width"],
+                       top_k=moe["top_k"], norm_topk=moe["norm_topk"],
+                       name=f"{pfx}_moe")                # (B*T, D)
+    else:
+        # dense -> GeLU as the fused epilogue pair: the matmul emits raw
+        # rows (no_bias) and FusedBiasGeLU folds bias+erf-GeLU in one pass
+        h = _proj(ln2, 4 * d_model, f"{pfx}_ffn1", no_bias=True)
+        h = sym.FusedBiasGeLU(h, name=f"{pfx}_ffn_gelu")
+        h = sym.FullyConnected(h, num_hidden=d_model, name=f"{pfx}_ffn2")
     h = sym.Reshape(h, shape=(-1, T, d_model), name=f"{pfx}_ffn_unfold")
     if dropout:
         h = sym.Dropout(h, p=dropout, name=f"{pfx}_drop2")
     return x + h
 
 
-def _validate(vocab_size, d_model, n_head, pos_embed):
+def _norm(x, name, moe):
+    """The block's normalisation: RMSNorm under a ``moe`` spec (the
+    OLMoE block), LayerNorm otherwise."""
+    if moe is not None:
+        return sym.RMSNorm(x, eps=moe["rms_eps"], name=name)
+    return sym.LayerNorm(x, name=name)
+
+
+def _validate(vocab_size, d_model, n_head, pos_embed, block="gpt2",
+              n_expert=None, top_k=None, expert_width=None):
+    if block not in ("gpt2", "olmoe"):
+        raise MXNetError(f"block {block!r}: 'gpt2' or 'olmoe'")
+    if block == "olmoe":
+        if pos_embed != "rotary":
+            raise MXNetError("block='olmoe' is rotary (no position table)")
+        if not (n_expert and top_k and expert_width) or top_k > n_expert:
+            raise MXNetError(
+                "block='olmoe' needs n_expert >= top_k >= 1 and "
+                f"expert_width (got {n_expert}, {top_k}, {expert_width})")
     if d_model % n_head:
         raise MXNetError(f"d_model {d_model} must divide n_head {n_head}")
     if (d_model // n_head) % 2:
@@ -135,16 +183,18 @@ def _validate(vocab_size, d_model, n_head, pos_embed):
 
 
 def _embed(data, tok_w, *, seq_len, vocab_size, d_model, pos_embed,
-           max_seq_len, name, pos_ids=None, per_slot=False):
-    """Token embedding (scaled by sqrt(D), transformer convention) plus
-    the learned position table when ``pos_embed='learned'``. Per-slot
-    decode feeds ``pos_ids`` shaped (B, S) — every slot at its own
-    absolute position — so the looked-up table rows already align with
-    ``x`` and add elementwise."""
+           max_seq_len, name, pos_ids=None, per_slot=False,
+           embed_scale=True):
+    """Token embedding (scaled by sqrt(D), transformer convention,
+    unless ``embed_scale=False``) plus the learned position table when
+    ``pos_embed='learned'``. Per-slot decode feeds ``pos_ids`` shaped
+    (B, S) — every slot at its own absolute position — so the
+    looked-up table rows already align with ``x`` and add
+    elementwise."""
+    scale = {"scale": float(np.sqrt(d_model))} if embed_scale else {}
     x = sym.Embedding(data=data, weight=tok_w, input_dim=vocab_size,
-                      output_dim=d_model,
-                      scale=float(np.sqrt(d_model)),
-                      name=f"{name}_tok_embed")          # (B, T, D)
+                      output_dim=d_model, name=f"{name}_tok_embed",
+                      **scale)                           # (B, T, D)
     if pos_embed == "learned":
         if pos_ids is None:
             pos_ids = sym._arange(start=0, stop=float(seq_len),
@@ -161,10 +211,34 @@ def _embed(data, tok_w, *, seq_len, vocab_size, d_model, pos_embed,
     return x
 
 
+def _moe_spec(block, n_expert, top_k, expert_width, norm_topk, rms_eps):
+    if block != "olmoe":
+        return None
+    return {"n_expert": int(n_expert), "top_k": int(top_k),
+            "expert_width": int(expert_width),
+            "norm_topk": bool(norm_topk), "rms_eps": float(rms_eps)}
+
+
+def _head(x, tok_w, *, moe, tie_head, vocab_size, name):
+    """Final norm and the output head over the folded (B*T, D) rows:
+    tied to the token embedding (one weight, two gradients), or the
+    untied ``{name}_head_weight`` (vocab, D)."""
+    x = _norm(x, f"{name}_ln_f", moe)
+    flat = sym.Reshape(x, shape=(-3, 0), name=f"{name}_head_fold")
+    if not tie_head:
+        return sym.FullyConnected(
+            flat, weight=sym.var(f"{name}_head_weight"),
+            num_hidden=vocab_size, no_bias=True, name=f"{name}_logits")
+    return sym.dot(flat, tok_w, transpose_b=True,
+                   name=f"{name}_logits")                # (B*T, V)
+
+
 def get_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                seq_len=32, pos_embed="rotary", rope_base=10000.0,
                dropout=0.0, include_loss=True, normalization="batch",
-               max_seq_len=None, name="lm"):
+               max_seq_len=None, name="lm", block="gpt2", n_expert=None,
+               top_k=None, expert_width=None, norm_topk=False,
+               rms_eps=1e-5, tie_head=True, embed_scale=True):
     """Training/full-sequence graph.
 
     data: ``(B, seq_len)`` token ids (bind the data iter with an int32
@@ -175,8 +249,16 @@ def get_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
 
     ``include_loss=False`` returns logits ``(B, seq_len, vocab)`` — the
     decode-parity reference the KV-cache gates compare against.
+
+    ``block="olmoe"`` builds the sparse-expert block (``_block``):
+    ``n_expert`` experts of ``expert_width``, ``top_k`` a token,
+    ``rms_eps``; OLMoE also unties the head (``tie_head=False``) and
+    leaves the embedding unscaled (``embed_scale=False``).
     """
-    _validate(vocab_size, d_model, n_head, pos_embed)
+    _validate(vocab_size, d_model, n_head, pos_embed, block, n_expert,
+              top_k, expert_width)
+    moe = _moe_spec(block, n_expert, top_k, expert_width, norm_topk,
+                    rms_eps)
     max_seq_len = max_seq_len or seq_len
     T = seq_len
 
@@ -184,17 +266,14 @@ def get_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     tok_w = sym.var(f"{name}_tok_embed_weight")
     x = _embed(data, tok_w, seq_len=T, vocab_size=vocab_size,
                d_model=d_model, pos_embed=pos_embed,
-               max_seq_len=max_seq_len, name=name)
+               max_seq_len=max_seq_len, name=name,
+               embed_scale=embed_scale)
     for i in range(n_layer):
         x = _block(x, i=i, seq_len=T, d_model=d_model, n_head=n_head,
                    dropout=dropout, pos_embed=pos_embed,
-                   rope_base=rope_base, name=name)
-    x = sym.LayerNorm(x, name=f"{name}_ln_f")
-    flat = sym.Reshape(x, shape=(-3, 0), name=f"{name}_head_fold")
-    # tied-embedding softmax head: logits = x @ E^T over the SAME
-    # variable the token embedding reads (one weight, two gradients)
-    logits = sym.dot(flat, tok_w, transpose_b=True,
-                     name=f"{name}_logits")              # (B*T, V)
+                   rope_base=rope_base, name=name, moe=moe)
+    logits = _head(x, tok_w, moe=moe, tie_head=tie_head,
+                   vocab_size=vocab_size, name=name)
     if not include_loss:
         return sym.Reshape(logits, shape=(-1, T, vocab_size),
                            name=f"{name}_logits_btv")
@@ -205,7 +284,10 @@ def get_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
 def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                       pos_embed="rotary", rope_base=10000.0,
                       capacity=None, step_len=1, max_seq_len=None,
-                      per_slot=False, cache_dtype=None, name="lm"):
+                      per_slot=False, cache_dtype=None, name="lm",
+                      block="gpt2", n_expert=None, top_k=None,
+                      expert_width=None, norm_topk=False, rms_eps=1e-5,
+                      tie_head=True, embed_scale=True):
     """Incremental KV-cache decoder: ``(B, step_len)`` new token ids in,
     logits ``(B, step_len, vocab)`` out, per-layer K/V caches of
     ``capacity`` positions riding executor aux state. Parameter names
@@ -230,8 +312,15 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     quantize on write and dequantize on read inside the pinned decode
     program, quartering cache HBM traffic and footprint. The cursor
     stays int32 and the default (None) keeps compute-width cells.
+
+    ``block``, ``n_expert``, ``top_k``, ``expert_width``, ``norm_topk``,
+    ``rms_eps``, ``tie_head`` and ``embed_scale`` are ``get_symbol``'s:
+    ``block="olmoe"`` is rotary, so the graph has no ``pos_ids`` input.
     """
-    _validate(vocab_size, d_model, n_head, pos_embed)
+    _validate(vocab_size, d_model, n_head, pos_embed, block, n_expert,
+              top_k, expert_width)
+    moe = _moe_spec(block, n_expert, top_k, expert_width, norm_topk,
+                    rms_eps)
     capacity = capacity or default_cache_capacity()
     cache_dtype = cache_dtype or default_cache_dtype()
     max_seq_len = max_seq_len or capacity
@@ -243,16 +332,14 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     x = _embed(data, tok_w, seq_len=S, vocab_size=vocab_size,
                d_model=d_model, pos_embed=pos_embed,
                max_seq_len=max_seq_len, name=name, pos_ids=pos_ids,
-               per_slot=per_slot)
+               per_slot=per_slot, embed_scale=embed_scale)
     for i in range(n_layer):
         x = _block(x, i=i, seq_len=S, d_model=d_model, n_head=n_head,
                    dropout=0.0, pos_embed=pos_embed, rope_base=rope_base,
                    name=name, decode=True, capacity=capacity,
-                   per_slot=per_slot, cache_dtype=cache_dtype)
-    x = sym.LayerNorm(x, name=f"{name}_ln_f")
-    flat = sym.Reshape(x, shape=(-3, 0), name=f"{name}_head_fold")
-    logits = sym.dot(flat, tok_w, transpose_b=True,
-                     name=f"{name}_logits")
+                   per_slot=per_slot, cache_dtype=cache_dtype, moe=moe)
+    logits = _head(x, tok_w, moe=moe, tie_head=tie_head,
+                   vocab_size=vocab_size, name=name)
     return sym.Reshape(logits, shape=(-1, S, vocab_size),
                        name=f"{name}_logits_bsv")
 
@@ -414,6 +501,11 @@ class BatchedKVCacheDecoder:
         self.pos = np.zeros(self.slots, np.int64)    # device-cursor mirror
         self.active = np.zeros(self.slots, bool)
         self._windows = {}                           # step_len -> module
+        # the routed feed-forwards' per-layer counts of the latest
+        # dispatch (ops/moe.py); empty for a dense decoder
+        exe = module._exec_group.executor
+        self._moe_cells = [cell for nm, cell in exe.aux_dict.items()
+                           if nm.endswith("moe_stats")]
 
     def add_window(self, step_len, module):
         """Register an S-token window module. It MUST have been bound
@@ -438,6 +530,34 @@ class BatchedKVCacheDecoder:
         exe = self._mod._exec_group.executor
         return [(nm, cell) for nm, cell in exe.aux_dict.items()
                 if nm.endswith("k_cache") or nm.endswith("v_cache")]
+
+    @property
+    def routed(self):
+        """Does the graph route tokens to experts (``MoEFFN``)?"""
+        return bool(self._moe_cells)
+
+    def moe_stats_begin(self):
+        """Start copying the latest dispatch's per-layer ``moe_stats``
+        cells to the host (a few int32 a layer) and return them; call
+        it right after ``step`` so that the copies ride behind the
+        program, beside the logits'. None for a dense decoder."""
+        if not self._moe_cells:
+            return None
+        arrays = [c.asjax() for c in self._moe_cells]
+        for a in arrays:
+            a.copy_to_host_async()
+        return arrays
+
+    @staticmethod
+    def moe_stats(arrays):
+        """int64 ``[layer_steps, assignments, experts_touched,
+        max_expert_load]`` summed over the layers of one dispatch (S=1
+        or window: the programs share the cells), from
+        ``moe_stats_begin``'s arrays. Read it once the dispatch's logits
+        are on the host: the program has then finished, and nothing
+        further is waited for."""
+        return np.sum(np.asarray([np.asarray(a) for a in arrays],
+                                 np.int64), axis=0)
 
     def free_slots(self):
         """Slot indices with no active sequence."""

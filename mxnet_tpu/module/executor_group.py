@@ -85,14 +85,37 @@ def _window_param_stats(health, w_start, w_end, watched):
     return out
 
 
+def compute_width_params(arg_params, compute_dtype):
+    """``{name: dtype}`` of the parameters in ``arg_params`` that are
+    handed over already at ``compute_dtype``, a float type narrower
+    than float32: the rule by which a parameter's cell is bound at the
+    dtype given instead of as a float32 master that every step casts
+    (docs/performance.md, "Mixed precision"). Empty for float32
+    parameters, for no ``compute_dtype`` and for the quantized tiers."""
+    if compute_dtype is None or not arg_params:
+        return {}
+    try:
+        want = jnp.dtype(compute_dtype)
+    except TypeError:
+        return {}
+    if not jnp.issubdtype(want, jnp.floating) or want.itemsize >= 4:
+        return {}
+    return {name: want for name, arr in arg_params.items()
+            if getattr(arr, "dtype", None) is not None
+            and jnp.dtype(arr.dtype) == want}
+
+
 class DataParallelExecutorGroup:
     def __init__(self, symbol, contexts, workload, data_shapes, label_shapes,
                  param_names, for_training, inputs_need_grad,
                  shared_group=None, logger=logging, fixed_param_names=None,
                  grad_req="write", state_names=None, compute_dtype=None,
-                 spmd=False, mesh_config=None):
+                 spmd=False, mesh_config=None, param_dtypes=None):
         self.symbol = symbol
         self.compute_dtype = compute_dtype
+        # parameters to bind at another dtype than float32: those handed
+        # over already at the compute width (compute_width_params)
+        self.param_dtypes = dict(param_dtypes or {})
         self.contexts = contexts
         self.workload = workload
         self.for_training = for_training
@@ -225,6 +248,8 @@ class DataParallelExecutorGroup:
             if n.is_variable and n._extra.get("__dtype__") and \
                     n.name not in arg_types:
                 arg_types[n.name] = np.dtype(n._extra["__dtype__"])
+        for name, dtype in self.param_dtypes.items():
+            arg_types.setdefault(name, np.dtype(dtype))
 
         if self._spmd_plan is not None:
             # lower ctx_group tags onto the model axis now that shapes
@@ -1258,6 +1283,27 @@ class DataParallelExecutorGroup:
                 for k in range(gn.shape[0])]
 
     # -------------------------------------------------------------- params
+    def adopt_param_dtypes(self, arg_params):
+        """Bind-at-the-dtype-given for a group that is already bound:
+        every float32 parameter cell whose value in ``arg_params`` is
+        handed over at the compute width (``compute_width_params``) is
+        re-allocated at it, so ``set_params`` stores it as given and
+        the step programs cast nothing. The executor's program-cache
+        key follows the new dtypes."""
+        names = compute_width_params(arg_params, self.compute_dtype)
+        ad = self.executor.arg_dict
+        changed = False
+        for name, dtype in names.items():
+            cell = ad.get(name)
+            if cell is None or name not in self.param_names \
+                    or cell.dtype != np.float32:
+                continue
+            cell._set(self._place(jnp.zeros(cell.shape, dtype), "param",
+                                  name))
+            changed = True
+        if changed:
+            self.executor.refresh_program_key()
+
     def set_params(self, arg_params, aux_params):
         """reference: executor_group.py set_params -> copy into the bound
         arrays, preserving sharded placement."""

@@ -33,9 +33,15 @@ class Module(BaseModule):
     def __init__(self, symbol, data_names=("data",),
                  label_names=("softmax_label",), logger=logging,
                  context=None, work_load_list=None, fixed_param_names=None,
-                 state_names=None, compute_dtype=None):
+                 state_names=None, compute_dtype=None, param_dtypes=None):
+        """``param_dtypes`` (``{name: dtype}``) binds those parameter
+        cells at another dtype than float32 - for a caller that knows
+        before ``bind`` that its parameters come at the compute width
+        (``executor_group.compute_width_params``); ``init_params`` finds
+        the same out after bind."""
         super().__init__(logger=logger)
         self._compute_dtype = compute_dtype
+        self._param_dtypes = dict(param_dtypes or {})
         context = context if context is not None else [current_context()]
         self._context = list(context) if isinstance(context, (list, tuple)) \
             else [context]
@@ -140,6 +146,9 @@ class Module(BaseModule):
             return
         assert self.binded, "bind() must run before init_params()"
 
+        # bind at the dtype given: a parameter handed over at the
+        # compute width is stored as it is, not as a float32 master
+        self._exec_group.adopt_param_dtypes(arg_params)
         exe = self._exec_group.executor
         if self._arg_params is None:
             self._arg_params = {
@@ -249,7 +258,8 @@ class Module(BaseModule):
             fixed_param_names=self._fixed_param_names, grad_req=grad_req,
             state_names=self._state_names,
             compute_dtype=self._compute_dtype,
-            spmd=self._spmd_active, mesh_config=self._mesh_config)
+            spmd=self._spmd_active, mesh_config=self._mesh_config,
+            param_dtypes=self._param_dtypes)
 
         if shared_module is not None:
             self.params_initialized = True

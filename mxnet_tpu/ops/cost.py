@@ -294,6 +294,28 @@ def _ew(flops_per_elem, reads=1, writes=1):
     return flops, nbytes
 
 
+def _moe_sizes(attrs, in_shapes):
+    (T, D), gate = in_shapes[0], in_shapes[2]
+    E, F = gate[0], gate[2]
+    k = int(attrs.get("top_k", 1))
+    # experts with at least one of T tokens' k assignments, under even
+    # routing: the weights a forward reads
+    touched = E * (1.0 - (1.0 - k / float(E)) ** T)
+    return T, D, E, F, k, touched
+
+
+def _moe_flops(attrs, in_shapes):
+    """MoEFFN: the router over every expert, three matmuls for each of
+    a token's top_k experts (ops/moe.py)."""
+    T, D, E, F, k, _ = _moe_sizes(attrs, in_shapes)
+    return 2.0 * T * D * E + 2.0 * T * k * 3 * D * F
+
+
+def _moe_bytes(attrs, in_shapes):
+    T, D, E, F, k, touched = _moe_sizes(attrs, in_shapes)
+    return _B * (2 * T * D + E * D + touched * 3 * D * F)
+
+
 def _move():
     """Pure data movement: 0 FLOPs, in+out bytes."""
     def flops(attrs, in_shapes):
@@ -364,6 +386,8 @@ _SPECIFIC = {
     "batch_dot": (_dot_flops, _dot_bytes),
     "BatchNorm": _ew(10.0, writes=1),
     "LayerNorm": _ew(8.0),
+    "RMSNorm": _ew(6.0),
+    "MoEFFN": (_moe_flops, _moe_bytes),
     "FusedBiasGeLU": _ew(10.0),          # erf ≈ several VPU ops
     "QuantizedFullyConnected": (_qfc_flops, _qfc_bytes),
     "QuantizedConvolution": (_qconv_flops, _qconv_bytes),

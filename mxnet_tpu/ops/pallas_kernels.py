@@ -56,7 +56,8 @@ from .registry import OP_REGISTRY, get_op, register
 
 __all__ = ["pallas_call", "pallas_sgd_mom_update", "pallas_adam_update",
            "fused_softmax_ce", "fused_conv_bn_relu", "fused_layernorm",
-           "fused_bias_gelu", "fused_embedding", "decode_attention"]
+           "fused_bias_gelu", "fused_embedding", "decode_attention",
+           "grouped_matmul", "grouped_expert_ffn"]
 
 
 def _interpret():
@@ -1117,6 +1118,202 @@ def decode_attention(q, k_cache, v_cache, pos, block_k=128):
     return out.reshape(B, H, S, Dh)
 
 
+# ==========================================================================
+# grouped expert feed-forward (the MoEFFN pallas variant - ops/moe.py owns
+# the op, the router, the sort and the weighted combine; the kernels here
+# are the grouped matmuls over the sorted rows, the tier's first ragged
+# shape: the group sizes are data)
+# ==========================================================================
+#: a weight tile streamed from HBM is at most this many bytes: long
+#: contiguous rows at a decode step, where the kernel is bandwidth-bound
+_GMM_TILE_BYTES = 1 << 20
+#: rows of the sorted assignments a grid step multiplies
+_GMM_ROWS = 128
+
+
+def _gmm_work_items(group_sizes, tiles_m, tm):
+    """Which (row tile, group) pairs a grouped matmul visits.
+
+    The sorted rows are cut into ``tiles_m`` tiles of ``tm`` rows; a
+    group visits every tile it has a row in, an empty group none, so a
+    tile that several groups share is visited once by each, in group
+    order (consecutively: an output tile is written back once). At most
+    ``tiles_m + E - 1`` visits; the static grid has that many steps and
+    the steps past the last visit repeat it, so that they move no data,
+    and compute nothing (``n_items`` tells them apart). Returns int32
+    ``(offsets (E+1,), item_group (W,), item_tile (W,), n_items (1,))``.
+    """
+    E = group_sizes.shape[0]
+    ends = jnp.cumsum(group_sizes)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    first = offsets[:-1] // tm
+    last = (jnp.maximum(ends, 1) - 1) // tm
+    n_tiles = jnp.where(group_sizes > 0, last - first + 1, 0)
+    item_ends = jnp.cumsum(n_tiles)
+    n_items = item_ends[-1]
+    W = tiles_m + E - 1
+    i = jnp.minimum(jnp.arange(W, dtype=jnp.int32),
+                    jnp.maximum(n_items, 1) - 1)
+    group = jnp.minimum(jnp.searchsorted(item_ends, i, side="right"),
+                        E - 1).astype(jnp.int32)
+    tile = first[group] + (i - (item_ends[group] - n_tiles[group]))
+    return (offsets.astype(jnp.int32), group,
+            jnp.clip(tile, 0, tiles_m - 1).astype(jnp.int32),
+            n_items.reshape((1,)).astype(jnp.int32))
+
+
+def _gmm_kernel(n_rhs, tm, epilogue):
+    """out[rows of the item's group in its tile] = epilogue(x @ w[g]
+    for each of ``n_rhs`` stacked weights), float32 accumulation over
+    the k steps."""
+    def kernel(off_ref, grp_ref, tile_ref, n_ref, x_ref, *refs):
+        w_refs, o_ref, accs = refs[:n_rhs], refs[n_rhs], refs[n_rhs + 1:]
+        i, kk = pl.program_id(0), pl.program_id(1)
+        live = i < n_ref[0]
+
+        @pl.when(kk == 0)
+        def _zero():
+            for acc in accs:
+                acc[...] = jnp.zeros(acc.shape, jnp.float32)
+
+        @pl.when(live)
+        def _accumulate():
+            x = x_ref[...]
+            for w_ref, acc in zip(w_refs, accs):
+                acc[...] += jnp.dot(x, w_ref[...],
+                                    preferred_element_type=jnp.float32)
+
+        @pl.when(kk == pl.num_programs(1) - 1)
+        def _store():
+            g = grp_ref[i]
+            rows = tile_ref[i] * tm + jax.lax.broadcasted_iota(
+                jnp.int32, o_ref.shape, 0)
+            mine = (rows >= off_ref[g]) & (rows < off_ref[g + 1]) & live
+            # the first visit of a tile finds whatever the buffer held
+            fresh = (i == 0) | (tile_ref[i]
+                                != tile_ref[jnp.maximum(i - 1, 0)])
+            kept = jnp.where(fresh, jnp.zeros(o_ref.shape, o_ref.dtype),
+                             o_ref[...])
+            val = epilogue(*[acc[...] for acc in accs]).astype(o_ref.dtype)
+            o_ref[...] = jnp.where(mine, val, kept)
+    return kernel
+
+
+def _gmm_block_k(K, N, itemsize):
+    """Rows of a (block_k, N) weight tile: the largest divisor of K
+    that is a multiple of 128 and keeps the tile within
+    ``_GMM_TILE_BYTES``; K itself where it has no such divisor."""
+    cap = max(128, _GMM_TILE_BYTES // (N * itemsize))
+    for b in range(min(K, cap) // 128 * 128, 0, -128):
+        if K % b == 0:
+            return b
+    return K
+
+
+def grouped_matmul(x, weights, items, tm, epilogue, out_dtype, name):
+    """``epilogue(x @ w[g] for w in weights)`` row group by row group.
+
+    ``x`` (M, K) holds the sorted rows, M a multiple of ``tm``;
+    ``weights`` are stacked (E, K, N) matrices read K-major; ``items``
+    is ``_gmm_work_items``' tuple. Rows that belong to no group come
+    out zero. ``name`` is the kernel's name in the device trace."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    M, K = x.shape
+    N = weights[0].shape[2]
+    bk = _gmm_block_k(K, N, weights[0].dtype.itemsize)
+    n_k = K // bk
+    W = items[1].shape[0]
+
+    def k_of(i, kk, n_ref):
+        # a step past the last visit stays on the block it holds
+        return jnp.where(i < n_ref[0], kk, n_k - 1)
+
+    def x_map(i, kk, off, grp, tile, n):
+        return tile[i], k_of(i, kk, n)
+
+    def w_map(i, kk, off, grp, tile, n):
+        return grp[i], k_of(i, kk, n), 0
+
+    def o_map(i, kk, off, grp, tile, n):
+        return tile[i], 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(W, n_k),
+        in_specs=[pl.BlockSpec((tm, bk), x_map)]
+        + [pl.BlockSpec((None, bk, N), w_map) for _ in weights],
+        out_specs=pl.BlockSpec((tm, N), o_map),
+        scratch_shapes=[pltpu.VMEM((tm, N), jnp.float32)
+                        for _ in weights])
+    return pallas_call(
+        _gmm_kernel(len(weights), tm, epilogue),
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        grid_spec=grid_spec, name=name)(*items, x, *weights)
+
+
+def grouped_expert_ffn(xs, group_sizes, gate, up, down):
+    """The experts of ``MoEFFN`` over the sorted assignment rows:
+    ``xs`` (M, D) in the compute dtype, expert ``e`` owning
+    ``group_sizes[e]`` consecutive rows -> (M, D) float32. Two kernels:
+    ``moe_gmm_gate_up`` (both matmuls over one read of the rows, SiLU
+    gate fused) and ``moe_gmm_down``. An expert's weights are read once
+    for every row tile it has a row in - once in all at a decode step,
+    whose rows fit one tile - and an expert without rows is not read."""
+    M = xs.shape[0]
+    sub = 16 if xs.dtype.itemsize < 4 else 8
+    tm = min(_GMM_ROWS, -(-M // sub) * sub)
+    tiles_m = -(-M // tm)
+    if tiles_m * tm != M:
+        xs = jnp.pad(xs, ((0, tiles_m * tm - M), (0, 0)))
+    items = _gmm_work_items(group_sizes.astype(jnp.int32), tiles_m, tm)
+    h = grouped_matmul(
+        xs, (gate.astype(xs.dtype), up.astype(xs.dtype)), items, tm,
+        lambda g, u: g * jax.nn.sigmoid(g) * u, xs.dtype,
+        "moe_gmm_gate_up")
+    y = grouped_matmul(h, (down.astype(xs.dtype),), items, tm,
+                       lambda y: y, jnp.float32, "moe_gmm_down")
+    return y[:M]
+
+
+def _moe_variant(attrs, inputs, aux, is_train, rng):
+    from .moe import moe_ffn
+    return moe_ffn(attrs, inputs, grouped_expert_ffn)
+
+
+def _moe_eligible(attrs, in_shapes, in_dtypes):
+    """Lane-aligned widths (any in interpret mode), float rows, and
+    weight tiles of full output width within the declared tile set."""
+    if len(in_shapes) < 5 or len(in_shapes[0]) != 2 \
+            or len(in_shapes[2]) != 3:
+        return False
+    if str(in_dtypes[0]) not in ("float32", "bfloat16", "float16"):
+        return False
+    D, F = in_shapes[2][1], in_shapes[2][2]
+    if max(D, F) > 2048:
+        return False
+    return (D % 128 == 0 and F % 128 == 0) or _interpret()
+
+
+#: worst case at the eligibility bounds (widths <= 2048, 1 MiB weight
+#: tiles): the row tile, two weight tiles and the output tile double
+#: buffered by the pipeline, and two float32 accumulators
+_MOE_KSPEC = {
+    "tiles": [((_GMM_ROWS, 2048), "float32")] * 2       # rows
+    + [((128, 2048), "float32")] * 4                    # gate, up tiles
+    + [((_GMM_ROWS, 2048), "float32")] * 2              # out tile
+    + [((_GMM_ROWS, 2048), "float32")] * 2,             # accumulators
+    "dtypes": ("float32", "bfloat16", "float16"),
+}
+
+
+def _register_moe_variant():
+    from . import moe  # noqa: F401 - registers RMSNorm and MoEFFN
+    op = get_op("MoEFFN")
+    if "pallas" not in op.variants:
+        op.add_variant("pallas", _moe_variant, eligible=_moe_eligible,
+                       kernel_spec=_MOE_KSPEC)
+
+
 def _register_opt_variants():
     sgd = get_op("sgd_mom_update")
     if "pallas" not in sgd.variants:
@@ -1143,3 +1340,4 @@ _register_opt_variants()
 _register_softmax_ce_variant()
 _register_layernorm_variant()
 _register_embedding_variant()
+_register_moe_variant()

@@ -321,6 +321,10 @@ class DecodeHandle:
             self._safe(fn)
 
 
+#: aux cells whose leading axis is the slot: what a rung switch carries
+_SLOT_CELLS = ("k_cache", "v_cache", "cache_pos")
+
+
 class DecodeEngine:
     """Slot-capacity rung ladder over a slot-pooled decode graph.
 
@@ -380,6 +384,11 @@ class DecodeEngine:
             logger=logger or log, context=self._context)
         if compute_dtype is not None:
             self._bm._module_kwargs["compute_dtype"] = compute_dtype
+            # parameters handed over at the compute width bind at it:
+            # no float32 master is allocated and no step casts them
+            from ..module.executor_group import compute_width_params
+            self._bm._module_kwargs["param_dtypes"] = \
+                compute_width_params(arg_params, compute_dtype)
         self._bm.bind(self._provide_data(self.ladder.max),
                       label_shapes=None, for_training=False)
         # straight to the leader with initializer=None: the decode
@@ -569,12 +578,22 @@ class DecodeEngine:
             si = np.asarray([p[0] for p in pairs])
             di = np.asarray([p[1] for p in pairs])
             for nm, cell in s_exe.aux_dict.items():
+                if not nm.endswith(_SLOT_CELLS):
+                    continue    # not a per-slot pool (MoEFFN's counts)
                 dcell = d_exe.aux_dict[nm]
                 dcell._set(dcell.asjax().at[di].set(cell.asjax()[si]))
             for s_row, d_row in pairs:
                 ddrv.pos[d_row] = sdrv.pos[s_row]
                 ddrv.active[d_row] = True
         sdrv.active[:] = False
+
+
+#: ``serve.decode.<name>`` counters of a routed decoder, in the order
+#: of ``BatchedKVCacheDecoder.moe_stats``: MoEFFN layer executions, the
+#: (token, expert) assignments they made, the experts that got at least
+#: one, and each execution's busiest expert's assignments
+_MOE_COUNTERS = ("moe.layer_steps", "moe.assignments",
+                 "moe.experts_touched", "moe.max_expert_load")
 
 
 class DecodeScheduler:
@@ -700,6 +719,9 @@ class DecodeScheduler:
         if self._iter_handles is None or self._iter_handles[0] != gen:
             handles = {k: self._counter(k) for k in
                        ("iterations", "tokens", "prefill.chunks")}
+            if self.engine.driver(self._rung).routed:
+                handles.update({k: self._counter(k)
+                                for k in _MOE_COUNTERS})
             handles.update({k: self._gauge(k) for k in
                             ("active", "occupancy", "queue.depth")})
             handles["step.seconds"] = _telemetry.histogram(
@@ -931,7 +953,15 @@ class DecodeScheduler:
             out = drv.step(tokens)
         t_launched = now()
         with _telemetry.span("serve.decode.iter.fetch"):
+            # where the tokens went, counted inside the program: the
+            # copies queue behind it beside the logits', so reading
+            # them afterwards waits for nothing further
+            routed = drv.moe_stats_begin()
             logits = out.asnumpy()
+            if routed is not None:
+                with _telemetry.span("serve.decode.iter.moe_stats"):
+                    phases["moe"] = phases.get("moe", 0) \
+                        + drv.moe_stats(routed)
         end = now()
         phases["dispatch"] += t_launched - t
         phases["fetch"] += end - t_launched
@@ -1102,6 +1132,10 @@ class DecodeScheduler:
                 m["tokens"].inc(emitted)
             if chunks:
                 m["prefill.chunks"].inc(chunks)
+            moe = phases.get("moe")     # a routed decoder's dispatches
+            if moe is not None:
+                for key, value in zip(_MOE_COUNTERS, moe):
+                    m[key].inc(int(value))
             m["step.seconds"].observe(step_s)
             m["active"].set(n_active)
             m["occupancy"].set(n_active / self._rung)
@@ -1117,7 +1151,10 @@ class DecodeScheduler:
                 fetch_us=_us(phases["fetch"]),
                 commit_us=_us(committed - end),
                 rewind_us=_us(rewound - committed), mode=mode, window=S,
-                compiles_since_warmup=compiles)
+                compiles_since_warmup=compiles,
+                **({} if moe is None else
+                   {"moe_layer_steps": int(moe[0]),
+                    "moe_touched": int(moe[2])}))
         return max(1, emitted)
 
     def _commit_window(self, meta, logits, S, t0, end, shared_sid,

@@ -1,0 +1,478 @@
+"""The sparse-expert block (ISSUE 28): ``RMSNorm`` and ``MoEFFN``
+against the plain reference's expert layer (chipbench/reference/
+olmoe.py: every expert for every token, masked), the Pallas grouped
+matmul against the XLA composition in interpret mode on ragged groups,
+the OLMoE decoder served through the window program and the cache
+against the reference's full forward, and the bind-at-the-dtype-given
+rule. Small sizes, CPU."""
+import hashlib
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu.models import transformer as tfm
+from mxnet_tpu.ops import moe, pallas_kernels
+from mxnet_tpu.ops.registry import get_op
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from chipbench.reference import olmoe as ref  # noqa: E402
+
+#: the issue's small size: 2 layers, width 64, 4 heads of 16, 8 experts
+#: top-2 of width 32, vocabulary 128
+CFG = {"hidden_size": 64, "num_hidden_layers": 2, "num_attention_heads": 4,
+       "num_key_value_heads": 4, "intermediate_size": 32, "num_experts": 8,
+       "num_experts_per_tok": 2, "norm_topk_prob": False,
+       "rms_norm_eps": 1e-5, "rope_theta": 10000, "vocab_size": 128}
+CAPACITY, WINDOW = 48, 8
+
+
+def _moe_inputs(rng, T, D, F, E, dtype=jnp.float32, router_scale=0.5):
+    return [jnp.asarray(rng.normal(size=(T, D)), dtype),
+            jnp.asarray(rng.normal(size=(E, D)) * router_scale, dtype),
+            jnp.asarray(rng.normal(size=(E, D, F)) * 0.1, dtype),
+            jnp.asarray(rng.normal(size=(E, D, F)) * 0.1, dtype),
+            jnp.asarray(rng.normal(size=(E, F, D)) * 0.1, dtype)]
+
+
+def _run(variant, attrs, inputs):
+    op = get_op("MoEFFN")
+    fn = op.forward if variant == "xla" else op.variant_fn(variant)
+    (out, experts), (stats,) = fn(op.normalize_attrs(attrs), inputs,
+                                  [jnp.zeros((4,), jnp.int32)], False, None)
+    return np.asarray(out), np.asarray(experts), np.asarray(stats)
+
+
+# ------------------------------------------------ the op against the reference
+@pytest.mark.parametrize("norm_topk", [False, True])
+@pytest.mark.parametrize("T,D,F,E,k", [(24, 64, 32, 8, 2),
+                                       (40, 64, 32, 64, 8),
+                                       (1, 64, 32, 8, 2)])
+def test_moe_ffn_matches_the_reference_expert_layer(T, D, F, E, k,
+                                                    norm_topk):
+    inputs = _moe_inputs(np.random.default_rng(T + E), T, D, F, E)
+    attrs = dict(num_experts=E, num_hidden=F, top_k=k, norm_topk=norm_topk)
+    out, experts, stats = _run("xla", attrs, inputs)
+    x, router, gate, up, down = inputs
+    with jax.default_matmul_precision("highest"):
+        free, chosen = ref.expert_layer(x, router, gate, up, down, k,
+                                        norm_topk)
+        forced, _ = ref.expert_layer(x, router, gate, up, down, k,
+                                     norm_topk, routing=experts)
+    # both sides route in float32 from the same rows: the same sets
+    assert float(ref.routing_flip_share(experts, chosen)) == 0.0
+    # float32 rounding of sums of 64-term products of O(1) values
+    np.testing.assert_allclose(out, np.asarray(free), atol=2e-5, rtol=0)
+    np.testing.assert_allclose(out, np.asarray(forced), atol=2e-5, rtol=0)
+    # every assignment counted once
+    assert stats[0] == 1 and stats[1] == T * k
+    assert experts.shape == (T, k)
+    assert all(len(set(row)) == k for row in experts.tolist())
+    sizes = np.bincount(experts.reshape(-1), minlength=E)
+    assert sizes.sum() == T * k
+    assert stats[2] == (sizes > 0).sum() and stats[3] == sizes.max()
+
+
+@pytest.mark.parametrize("case", ["one_expert_takes_all", "some_get_none"])
+def test_moe_ffn_under_skewed_routing(case):
+    T, D, F, E, k = 16, 64, 32, 8, 2
+    rng = np.random.default_rng(5)
+    inputs = _moe_inputs(rng, T, D, F, E, router_scale=0.01)
+    x = jnp.abs(inputs[0])                  # positive rows
+    router = np.asarray(inputs[1]).copy()
+    if case == "one_expert_takes_all":
+        router[3] += 1.0                    # every token's first choice
+        router[5] += 0.5                    # and every token's second
+    else:
+        router[[0, 2, 4, 6]] -= 1.0         # nobody's choice
+    inputs = [x, jnp.asarray(router)] + inputs[2:]
+    attrs = dict(num_experts=E, num_hidden=F, top_k=k)
+    out, experts, stats = _run("xla", attrs, inputs)
+    sizes = np.bincount(experts.reshape(-1), minlength=E)
+    if case == "one_expert_takes_all":
+        assert sizes[3] == T and sizes[5] == T and stats[2] == 2
+        assert stats[3] == T
+    else:
+        assert (sizes[[0, 2, 4, 6]] == 0).all() and stats[2] <= 4
+    assert stats[1] == T * k == sizes.sum()
+    with jax.default_matmul_precision("highest"):
+        want, _ = ref.expert_layer(*inputs, k, False)
+    np.testing.assert_allclose(out, np.asarray(want), atol=2e-5, rtol=0)
+    got, experts_p, stats_p = _run("pallas", attrs, inputs)
+    np.testing.assert_allclose(got, out, atol=2e-5, rtol=0)
+    assert (experts_p == experts).all() and (stats_p == stats).all()
+
+
+def test_rmsnorm_op_and_shapes():
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(3, 5, 64)),
+                    jnp.float32)
+    g = jnp.linspace(0.5, 1.5, 64)
+    out = mx.nd.RMSNorm(mx.nd.array(x), mx.nd.array(g), eps=1e-5).asnumpy()
+    want = x / np.sqrt(np.mean(np.square(x), -1, keepdims=True) + 1e-5) * g
+    np.testing.assert_allclose(out, want, atol=1e-6)
+    s = mx.sym.RMSNorm(mx.sym.var("data"), name="n")
+    args, outs, _ = s.infer_shape(data=(3, 5, 64))
+    assert args == [(3, 5, 64), (64,)] and outs == [(3, 5, 64)]
+    m = mx.sym.MoEFFN(mx.sym.var("data"), num_experts=8, num_hidden=32,
+                      top_k=2, name="m")
+    args, outs, aux = m.infer_shape(data=(10, 64))
+    assert args == [(10, 64), (8, 64), (8, 64, 32), (8, 64, 32),
+                    (8, 32, 64)]
+    assert outs == [(10, 64)] and aux == [(4,)]
+    for name in ("RMSNorm", "MoEFFN"):          # rule GV107, MF601
+        assert get_op(name).infer_shape is not None
+        assert get_op(name).has_cost()
+
+
+# ------------------------------------- the Pallas variant, interpret mode
+@pytest.mark.parametrize("sizes", [
+    [0, 1, 37, 0, 5, 16, 0, 21],            # empty groups, a group of one
+    [0, 0, 0, 0, 0, 0, 0, 8],               # one group only, the last
+    [130, 0, 1, 127, 0, 40, 2, 0],          # groups across row tiles
+    [1, 1, 1, 1, 1, 1, 1, 1]])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_grouped_expert_ffn_matches_the_xla_composition(sizes, dtype):
+    E, D, F = len(sizes), 64, 32
+    M = sum(sizes)
+    rng = np.random.default_rng(M)
+    _, _, gate, up, down = _moe_inputs(rng, 1, D, F, E, jnp.dtype(dtype))
+    xs = jnp.asarray(rng.normal(size=(M, D)), jnp.dtype(dtype))
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    want = moe._experts_ragged(xs, group_sizes, gate, up, down)
+    got = pallas_kernels.grouped_expert_ffn(xs, group_sizes, gate, up, down)
+    assert got.shape == (M, D) and got.dtype == jnp.float32
+    # the same products in another order of summation; bfloat16 rounds
+    # the gated activations once more on both sides
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=tol, rtol=0)
+
+
+def test_gmm_work_items_visit_each_group_once_a_tile():
+    sizes = jnp.asarray([130, 0, 1, 127, 0, 40, 2, 0], jnp.int32)
+    tm, tiles_m = 128, 3                    # 300 rows padded to 384
+    offs, grp, tile, n = (np.asarray(a) for a in
+                          pallas_kernels._gmm_work_items(sizes, tiles_m, tm))
+    assert offs.tolist() == [0, 130, 130, 131, 258, 258, 298, 300, 300]
+    visits = list(zip(grp[:n[0]].tolist(), tile[:n[0]].tolist()))
+    assert visits == [(0, 0), (0, 1), (2, 1), (3, 1), (3, 2), (5, 2),
+                      (6, 2)]               # no empty group, tiles in order
+    # the steps past the last visit repeat it: no new block is fetched
+    assert set(zip(grp[n[0]:].tolist(), tile[n[0]:].tolist())) == {(6, 2)}
+    assert len(grp) == tiles_m + len(sizes) - 1
+
+
+def test_pallas_variant_eligible_at_lane_aligned_widths(monkeypatch):
+    op = get_op("MoEFFN")
+    attrs = op.normalize_attrs(dict(num_experts=64, num_hidden=1024,
+                                    top_k=8))
+    def shapes(D, F):
+        return [(8, D), (64, D), (64, D, F), (64, D, F), (64, F, D), (4,)]
+    dtypes = ["bfloat16"] * 5 + ["int32"]
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    assert op.variant_eligible("pallas", attrs, shapes(2048, 1024), dtypes)
+    assert not op.variant_eligible("pallas", attrs, shapes(2048, 1000),
+                                   dtypes)
+    assert not op.variant_eligible("pallas", attrs, shapes(96, 1024), dtypes)
+    assert not op.variant_eligible("pallas", attrs, shapes(2048, 1024),
+                                   ["int8"] * 5 + ["int32"])
+
+
+# -------------------------- the decoder through the window and the cache
+def _builder_kwargs(cfg=CFG):
+    return dict(vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
+                n_layer=cfg["num_hidden_layers"],
+                n_head=cfg["num_attention_heads"], pos_embed="rotary",
+                rope_base=float(cfg["rope_theta"]), block="olmoe",
+                n_expert=cfg["num_experts"],
+                top_k=cfg["num_experts_per_tok"],
+                expert_width=cfg["intermediate_size"],
+                norm_topk=cfg["norm_topk_prob"],
+                rms_eps=cfg["rms_norm_eps"], tie_head=False,
+                embed_scale=False)
+
+
+def _decode_symbol(step_len, cfg=CFG, with_routing=True):
+    s = tfm.get_decode_symbol(capacity=CAPACITY, per_slot=True,
+                              step_len=step_len, **_builder_kwargs(cfg))
+    if not with_routing:
+        return s
+    inner = s.get_internals()
+    return mx.sym.Group([s] + [inner[f"lm_l{i}_moe_experts"]
+                               for i in range(cfg["num_hidden_layers"])])
+
+
+def _params(cfg=CFG, seed=11, dtype="float32"):
+    from chipbench import weights
+    return weights.normal_init(_decode_symbol(1, cfg, False),
+                               {"data": (2, 1)}, seed, dtype=dtype)
+
+
+def _driver(params, compute_dtype=None, slots=2, cfg=CFG):
+    """A two-slot pool with its window program, outputs = logits and
+    every layer's chosen experts."""
+    def bound(step_len, shared=None):
+        mod = mx.mod.Module(_decode_symbol(step_len, cfg),
+                            data_names=("data",), label_names=[],
+                            compute_dtype=compute_dtype)
+        mod.bind([mx.io.DataDesc("data", (slots, step_len), np.int32)],
+                 None, for_training=False, shared_module=shared)
+        if shared is None:
+            mod.init_params(initializer=None, arg_params=dict(params),
+                            aux_params={}, allow_missing=True)
+        return mod
+    base = bound(1)
+    drv = tfm.BatchedKVCacheDecoder(base, CAPACITY, slots=slots)
+    drv.add_window(WINDOW, bound(WINDOW, shared=base))
+    return drv, base
+
+
+def _serve(params, seqs, compute_dtype=None, cfg=CFG):
+    """Prefill through the window program, then S=1 decode through the
+    cache: slot 0 runs sequence 0 from position 0; slot 1 first takes a
+    window of other tokens, is rewound to 0, and runs sequence 1 one
+    window behind slot 0 - two slots at different positions, a rewind
+    in between. Returns ``(logits (2, T, V), experts (L, 2, T, k))``
+    of every position of both sequences."""
+    L, k = cfg["num_hidden_layers"], cfg["num_experts_per_tok"]
+    n_seq, T = seqs.shape
+    n_pre = (T // WINDOW - 1) * WINDOW       # the rest decodes at S=1
+    drv, base = _driver(params, compute_dtype, cfg=cfg)
+    mods = {1: base, WINDOW: drv._windows[WINDOW]}
+    logits = np.zeros((n_seq, T, cfg["vocab_size"]), np.float32)
+    experts = np.zeros((L, n_seq, T, k), np.int32)
+
+    def step(tokens, rows):                  # rows: {slot: first position}
+        S = tokens.shape[1]
+        out = drv.step(tokens).asnumpy().astype(np.float32)
+        routed = [o.asnumpy().reshape(2, S, k)
+                  for o in mods[S].get_outputs()[1:]]
+        for slot, t0 in rows.items():
+            logits[slot, t0:t0 + S] = out[slot]
+            for layer in range(L):
+                experts[layer, slot, t0:t0 + S] = routed[layer][slot]
+
+    drv.join(0), drv.join(1)
+    junk = np.full((WINDOW,), 7, np.int32)
+    step(np.stack([seqs[0, :WINDOW], junk]), {0: 0})
+    drv.rewind(1, 0)                         # slot 1 starts over
+    for w in range(1, n_pre // WINDOW + 1):
+        a = seqs[0, w * WINDOW:(w + 1) * WINDOW] if w * WINDOW < n_pre \
+            else None
+        b = seqs[1, (w - 1) * WINDOW:w * WINDOW]
+        if a is None:                        # slot 0 has left the windows:
+            step(np.stack([junk, b]), {1: (w - 1) * WINDOW})
+            drv.rewind(0, n_pre)             # it rode along; pull it back
+        else:
+            step(np.stack([a, b]), {0: w * WINDOW, 1: (w - 1) * WINDOW})
+    assert list(drv.pos) == [n_pre, n_pre]
+    for t in range(n_pre, T):
+        step(seqs[:, t:t + 1], {0: t, 1: t})
+    return logits, experts
+
+
+#: float32 served against the float32 reference, both summing 64- and
+#: 32-term products of O(1) values through 2 layers to logits of
+#: magnitude up to 0.8: a few float32 ulps of the largest partial sums
+#: (measured here: 1.5e-7 to 6e-7). Forced to the served path's routing
+#: the comparison keeps the same rounding but no discontinuity, so it is
+#: held several times tighter; free, a flipped decision would add an
+#: expert's share of the output (1e-3 and more), which neither bound
+#: lets through - at float32 no decision flips at this size.
+TOL_FREE, TOL_FORCED = 1e-5, 2e-6
+
+
+@pytest.mark.parametrize("big", [False, True],
+                         ids=["top2_of_8", "top8_of_64"])
+def test_prefill_then_decode_matches_the_reference_full_forward(big):
+    cfg = dict(CFG, num_experts=64, num_experts_per_tok=8) if big else CFG
+    params = _params(cfg)
+    rng = np.random.default_rng(3)
+    seqs = rng.integers(0, cfg["vocab_size"], (2, 4 * WINDOW)).astype(
+        np.int32)
+    got, routed = _serve(params, seqs, cfg=cfg)
+    want, chosen = ref.forward(params, jnp.asarray(seqs), cfg,
+                               return_routing=True)
+    forced = ref.forward(params, jnp.asarray(seqs), cfg,
+                         routing=jnp.asarray(routed))
+    flips = float(ref.routing_flip_share(routed, chosen))
+    err_free = np.abs(got - np.asarray(want)).max()
+    err_forced = np.abs(got - np.asarray(forced)).max()
+    print(f"routing decisions that differ: {flips:.4f}; max error free "
+          f"{err_free:.2e}, forced {err_forced:.2e}")
+    assert flips <= 0.01
+    assert err_forced <= TOL_FORCED
+    assert err_free <= TOL_FREE
+
+    # the control: the same parameters served at bfloat16 where float32
+    # is stated must NOT pass
+    low, low_routed = _serve(
+        {n: np.asarray(v).astype(jnp.bfloat16) for n, v in params.items()},
+        seqs, compute_dtype="bfloat16", cfg=cfg)
+    assert np.abs(low - np.asarray(want)).max() > 20 * TOL_FREE
+    forced_low = ref.forward(params, jnp.asarray(seqs), cfg,
+                             routing=jnp.asarray(low_routed))
+    assert np.abs(low - np.asarray(forced_low)).max() > 20 * TOL_FORCED
+
+
+def test_decode_symbol_matches_the_programs_own_full_forward():
+    """``get_symbol`` gains the same block: the cache path against the
+    program's own full-sequence graph (``attention`` + ``RoPE``)."""
+    params = _params()
+    seqs = np.random.default_rng(4).integers(
+        0, CFG["vocab_size"], (2, 4 * WINDOW)).astype(np.int32)
+    got, _ = _serve(params, seqs)
+    full = mx.mod.Module(
+        tfm.get_symbol(seq_len=seqs.shape[1], include_loss=False,
+                       **_builder_kwargs()),
+        data_names=("data",), label_names=[])
+    full.bind([mx.io.DataDesc("data", seqs.shape, np.int32)], None,
+              for_training=False)
+    full.init_params(initializer=None, arg_params=dict(params),
+                     aux_params={}, allow_missing=True)
+    full.forward(mx.io.DataBatch(data=[mx.nd.array(seqs)], label=[]),
+                 is_train=False)
+    want = full.get_outputs()[0].asnumpy()
+    assert "pos_ids" not in _decode_symbol(1, with_routing=False) \
+        .list_arguments()
+    np.testing.assert_allclose(got, want, atol=TOL_FREE, rtol=0)
+
+
+def test_the_gpt2_block_is_untouched_by_the_new_one():
+    base = dict(vocab_size=64, d_model=32, n_layer=2, n_head=4)
+    s = tfm.get_decode_symbol(capacity=16, per_slot=True, **base)
+    ops = {n.op for n in s._topo_nodes() if not n.is_variable}
+    assert "LayerNorm" in ops and "FusedBiasGeLU" in ops
+    assert not ops & {"RMSNorm", "MoEFFN"}
+    assert "lm_head_weight" not in s.list_arguments()
+    with pytest.raises(mx.base.MXNetError, match="olmoe"):
+        tfm.get_decode_symbol(block="olmoe", **base)       # no experts
+    with pytest.raises(mx.base.MXNetError, match="rotary"):
+        tfm.get_decode_symbol(block="olmoe", pos_embed="learned",
+                              n_expert=8, top_k=2, expert_width=16, **base)
+
+
+# --------------------------------------- counters through serve_decoder
+def test_serve_decoder_counts_where_the_tokens_went():
+    from mxnet_tpu import telemetry
+    gen = lambda s: _decode_symbol(s, with_routing=False)   # noqa: E731
+    sched = mx.serve.serve_decoder(
+        gen(1), _params(), name="tiny-olmoe-counts", capacity=CAPACITY,
+        ladder=[1, 2], context=mx.cpu(0), symbol_gen=gen,
+        prefill_chunk=WINDOW, start=False)
+    h = sched.submit(list(range(1, 21)), max_new_tokens=4)
+    sched.pump(max_iterations=20)
+    assert len(h.result(timeout=5)) == 4
+    sched.stop()
+    got = {m.name: m.value for m in telemetry.metrics.all_metrics()
+           if isinstance(m, telemetry.Counter)
+           and ("model", "tiny-olmoe-counts") in m.labels
+           and m.name.startswith("serve.decode.moe.")}
+    its = sched.iterations
+    L, k = CFG["num_hidden_layers"], CFG["num_experts_per_tok"]
+    assert got["serve.decode.moe.layer_steps"] == its * L
+    # three prefill windows of 8 and three S=1 steps on rung 1, pads
+    # riding along as tokens
+    assert got["serve.decode.moe.assignments"] == (3 * WINDOW + 3) * L * k
+    assert 0 < got["serve.decode.moe.experts_touched"] <= its * L * 8
+    assert got["serve.decode.moe.max_expert_load"] >= its * L
+    step = [r for r in telemetry.flightrec.get_records()
+            if r.get("kind") == "serve.decode.step"
+            and r.get("model") == "tiny-olmoe-counts"]
+    assert all(r["moe_layer_steps"] == L and 0 < r["moe_touched"] <= L * 8
+               for r in step) and len(step) == its
+
+
+# ------------------------------------------ bind at the dtype given
+def _lowered_s1(exe):
+    """The S=1 step program of a bound executor as StableHLO text."""
+    def prog(arg_vals, aux_vals):
+        return exe._runner(arg_vals, aux_vals, False, None)
+    return jax.jit(prog).lower(exe._arg_vals(), exe._aux_vals()).as_text()
+
+
+def _engine(params, compute_dtype, gen):
+    from mxnet_tpu.serve.decode import DecodeEngine
+    return DecodeEngine("bind-dtype", gen(1), params, capacity=CAPACITY,
+                        ladder=[2], context=mx.cpu(0),
+                        compute_dtype=compute_dtype, symbol_gen=gen,
+                        window_lens=(WINDOW,))
+
+
+def test_bfloat16_parameters_bind_at_bfloat16_and_are_not_cast():
+    gen = lambda s: _decode_symbol(s, with_routing=False)   # noqa: E731
+    eng = _engine(_params(dtype="bfloat16"), "bfloat16", gen)
+    for mod in [eng._bm._leader] + list(eng._window_mods.values()):
+        exe = mod._exec_group.executor
+        dtypes = {n: str(c.dtype) for n, c in exe.arg_dict.items()
+                  if n != "data"}
+        assert set(dtypes.values()) == {"bfloat16"}, dtypes
+    text = _lowered_s1(eng._bm._leader._exec_group.executor)
+    # no matrix among the arguments is converted: only the norm gains,
+    # upcast for their float32 statistics, are
+    converted = re.findall(
+        r"stablehlo\.convert %arg\d+ : \(tensor<([0-9x]*)x(?:bf16|f32)>\)",
+        text)
+    assert all("x" not in dims for dims in converted), converted
+    assert "xf32>" not in text.split("{", 1)[0].split("->")[0], \
+        "a float32 argument"
+
+
+def test_float32_parameters_bind_as_before():
+    gen = lambda s: _decode_symbol(s, with_routing=False)   # noqa: E731
+    eng = _engine(_params(), "bfloat16", gen)
+    exe = eng._bm._leader._exec_group.executor
+    assert {str(c.dtype) for n, c in exe.arg_dict.items()
+            if n != "data"} == {"float32"}
+    # and a module bound first and handed bfloat16 parameters afterwards
+    # re-allocates the cells (Module.init_params)
+    mod = mx.mod.Module(gen(1), data_names=("data",), label_names=[],
+                        compute_dtype="bfloat16")
+    mod.bind([mx.io.DataDesc("data", (2, 1), np.int32)], None,
+             for_training=False)
+    cells = mod._exec_group.executor.arg_dict
+    assert str(cells["lm_head_weight"].dtype) == "float32"
+    mod.init_params(initializer=None, arg_params=_params(dtype="bfloat16"),
+                    aux_params={}, allow_missing=True)
+    assert {str(c.dtype) for n, c in cells.items()
+            if n != "data"} == {"bfloat16"}
+    key = mod._exec_group.executor.program_cache_key("fwd_infer")
+    assert ("lm_head_weight", (128, 64), "bfloat16") in key[1]
+
+
+#: sha256 of the lowered S=1 program of the Cerebras-shaped tiny decoder
+#: below (learned positions, float32 masters, compute_dtype bfloat16) as
+#: the PARENT of PR 28 (d8b22cc) lowers it: the GPT-2 block's program
+#: is the same bytes, so the Cerebras cells hit their compile cache.
+#: A PR that changes that program on purpose computes it anew.
+GPT2_S1_SHA256 = \
+    "b55e9ecfedb869a71b2f803f010d8b2389dfdeabb70618e66877fe58fc132027"
+
+
+def _gpt2_lowered_s1():
+    kw = dict(vocab_size=96, d_model=64, n_layer=2, n_head=4,
+              pos_embed="learned", capacity=32, max_seq_len=32,
+              per_slot=True)
+    gen = lambda s: tfm.get_decode_symbol(step_len=s, **kw)  # noqa: E731
+    from chipbench import weights
+    params = weights.normal_init(gen(1), {"data": (2, 1),
+                                          "pos_ids": (2, 1)}, 5)
+    from mxnet_tpu.serve.decode import DecodeEngine
+    eng = DecodeEngine("gpt2-pin", gen(1), params, capacity=32, ladder=[2],
+                       context=mx.cpu(0), compute_dtype="bfloat16",
+                       symbol_gen=gen, window_lens=(8,))
+    return _lowered_s1(eng._bm._leader._exec_group.executor)
+
+
+def test_the_cerebras_shaped_step_program_is_the_parents_bytes():
+    text = _gpt2_lowered_s1()
+    assert hashlib.sha256(text.encode()).hexdigest() == GPT2_S1_SHA256

@@ -96,6 +96,7 @@ def test_deadline_flush_fake_clock():
     """A lone request must flush at deadline - exec_estimate (0 on the
     fake clock) in the SMALLEST covering bucket — never held past its
     deadline waiting for a fuller batch."""
+    mx.telemetry.reset()    # the counts below are this server's alone
     clock = FakeClock()
     sym = _mlp("dl")
     server = mx.serve.serve(_bound_module(sym), ladder=[1, 2, 4],
