@@ -481,26 +481,37 @@ class BatchedKVCacheDecoder:
     modules (``add_window``): same parameters, same shared aux cells,
     ``step_len=S`` graphs that advance every slot by S positions per
     dispatch — chunked prefill and speculative verify ride these.
-    ``step`` dispatches on ``tokens.shape[1]``. ``rewind`` pokes a
-    slot's device cursor to an arbitrary position (the join-style aux
-    update, never a compile) — the seam for padded final prefill
-    chunks, prefix-cache joins at cursor C, and speculative rollback.
+    ``step`` dispatches on ``tokens.shape[1]``. ``rewind`` sets a
+    slot's device cursor to an arbitrary position — the seam for padded
+    final prefill chunks, prefix-cache joins at cursor C, and
+    speculative rollback.
+
+    Every cursor move (``join``, ``rewind``, ``rewind_many``) is ONE
+    launch of one small jitted program over all layers' ``*cache_pos``
+    cells (``_set_cursors``). Its shapes are the pool's, never the
+    number of rows moved, so it compiles once per driver — at engine
+    warm-up — and no cursor move compiles afterwards. ``name`` is the
+    served model's label: a named driver counts its launches and the
+    rows they moved in ``serve.decode.cursor.updates`` / ``.rows``.
 
     ``serve.decode.DecodeScheduler`` builds the continuous-batching
     front end (admission, retirement, streaming, rung ladder) on top of
     one of these per slot rung.
     """
 
-    def __init__(self, module, capacity, slots=None, pos_embed="rotary"):
+    def __init__(self, module, capacity, slots=None, pos_embed="rotary",
+                 name=None):
         self._mod = module
         self.capacity = int(capacity)
         self.pos_embed = pos_embed
+        self.name = name
         if slots is None:
             slots = module.data_shapes[0].shape[0]
         self.slots = int(slots)
         self.pos = np.zeros(self.slots, np.int64)    # device-cursor mirror
         self.active = np.zeros(self.slots, bool)
         self._windows = {}                           # step_len -> module
+        self._cursor_program = None                  # built at first use
         # the routed feed-forwards' per-layer counts of the latest
         # dispatch (ops/moe.py); empty for a dense decoder
         exe = module._exec_group.executor
@@ -563,21 +574,67 @@ class BatchedKVCacheDecoder:
         """Slot indices with no active sequence."""
         return [i for i in range(self.slots) if not self.active[i]]
 
+    def _set_cursors(self, rows, positions):
+        """Set the device cursor of every slot in ``rows`` to its entry
+        of ``positions`` in every layer, and the host mirror with it:
+        one launch of one program that takes all ``*cache_pos`` cells
+        (donated), a (slots,) position vector and a (slots,) mask, and
+        returns ``where(mask, position, cell)`` for each. Rows not named
+        keep their value; every cell keeps its placement and dtype and
+        gets a buffer of its own. A row named twice is refused."""
+        rows = np.asarray(rows, np.int64).reshape(-1)
+        if not rows.size:
+            return
+        positions = np.asarray(positions, np.int32).reshape(-1)
+        if positions.shape != rows.shape:
+            raise MXNetError(f"{rows.size} cursor rows but "
+                             f"{positions.size} positions")
+        target = np.zeros(self.slots, np.int32)
+        mask = np.zeros(self.slots, bool)
+        target[rows] = positions
+        mask[rows] = True
+        if mask.sum() != rows.size:
+            raise MXNetError(f"slot named twice in one cursor update: "
+                             f"{rows.tolist()}")
+        cells = self._cursor_cells()
+        arrays = tuple(cell.asjax() for cell in cells)
+        if self._cursor_program is None:
+            import jax
+            import jax.numpy as jnp
+
+            def cursor_update(cells, pos, mask):
+                return tuple(
+                    jnp.where(mask[:, None],
+                              pos[:, None].astype(cell.dtype), cell)
+                    for cell in cells)
+
+            cursor_update.__name__ = f"cursor_update_{self.slots}"
+            self._cursor_program = jax.jit(
+                cursor_update, donate_argnums=0,
+                out_shardings=tuple(a.sharding for a in arrays))
+        for cell, new in zip(cells,
+                             self._cursor_program(arrays, target, mask)):
+            cell._set(new)
+        self.pos[rows] = positions
+        if self.name is not None:
+            from .. import telemetry
+            telemetry.counter("serve.decode.cursor.updates",
+                              model=self.name).inc()
+            telemetry.counter("serve.decode.cursor.rows",
+                              model=self.name).inc(int(rows.size))
+
     def join(self, slot):
-        """Claim ``slot`` for a new sequence: rewind its device cursor
-        to 0 across every layer (one tiny in-place aux update per layer
-        — never a program-cache compile) and mark it active. The cache
-        rows are NOT zeroed: every position a fresh sequence attends is
+        """Claim ``slot`` for a new sequence: set its device cursor to
+        0 in every layer (one launch of the cursor program — never a
+        compile after warm-up) and mark it active. The cache rows are
+        NOT zeroed: every position a fresh sequence attends is
         rewritten by it first, and masked positions carry exactly zero
         softmax weight, so reuse is bit-clean."""
-        import jax.numpy as jnp
         slot = int(slot)
         if self.active[slot]:
             raise MXNetError(f"slot {slot} already holds an active "
                              "sequence (leave() it first)")
-        for cell in self._cursor_cells():
-            cell._set(cell.asjax().at[slot, 0].set(jnp.int32(0)))
-        self.pos[slot] = 0
+        self._set_cursors([slot], [0])
         self.active[slot] = True
         return slot
 
@@ -587,27 +644,21 @@ class BatchedKVCacheDecoder:
         self.active[int(slot)] = False
 
     def rewind(self, slot, pos):
-        """Poke ``slot``'s device cursor to ``pos`` across every layer
-        (the same tiny in-place aux update as ``join`` — never a
-        compile). Used to discard the tail of a window after dispatch:
-        padded final prefill chunks, rejected speculative proposals, and
-        decoding slots riding a chunk dispatch all rewind to the stream
-        position they actually reached. Cache rows past ``pos`` become
-        garbage nobody attends (exp(-inf)-masked) and are rewritten
-        before first read — the same bit-clean contract as ``join``."""
-        self.rewind_many([slot], [pos])
+        """Set ``slot``'s device cursor to ``pos`` in every layer (one
+        launch of the cursor program, like ``join``). Used to discard
+        the tail of a window after dispatch: padded final prefill
+        chunks, rejected speculative proposals, and decoding slots
+        riding a chunk dispatch all rewind to the stream position they
+        actually reached. Cache rows past ``pos`` become garbage nobody
+        attends (exp(-inf)-masked) and are rewritten before first read —
+        the same bit-clean contract as ``join``."""
+        self._set_cursors([slot], [pos])
 
     def rewind_many(self, slots, positions):
-        """Batched ``rewind``: ONE aux update per layer for any number
-        of slots (the chunk-dispatch epilogue touches most of a rung)."""
-        import jax.numpy as jnp
-        if not len(slots):
-            return
-        idx = np.asarray(slots, np.int32)
-        val = np.asarray(positions, np.int32)
-        for cell in self._cursor_cells():
-            cell._set(cell.asjax().at[idx, 0].set(jnp.asarray(val)))
-        self.pos[idx] = val.astype(np.int64)
+        """Batched ``rewind``: still ONE launch of the cursor program,
+        for any number of distinct slots (the chunk-dispatch epilogue
+        touches most of a rung); an empty list launches nothing."""
+        self._set_cursors(slots, positions)
 
     def capture_rows(self, slot, length):
         """Snapshot ``slot``'s first ``length`` cache positions across

@@ -419,7 +419,7 @@ class DecodeEngine:
         self._drivers = {
             s: BatchedKVCacheDecoder(self._bm._buckets[s],
                                      self.capacity, slots=s,
-                                     pos_embed=self.pos_embed)
+                                     pos_embed=self.pos_embed, name=name)
             for s in self.ladder}
 
         self.window_lens = sorted(
@@ -479,7 +479,9 @@ class DecodeEngine:
         steady state on ``clock``), pin them all, record the compile
         delta. Warmup garbage stays harmless: afterwards every driver
         slot is free, every cursor is rewound to 0, and a join rewinds
-        again."""
+        again. Those rewinds also compile each rung's cursor program,
+        whose shapes are the rung's whatever rows a call names, so no
+        later ``join`` or ``rewind_many`` compiles."""
         mark = _progcache.compile_count()
         for rung in self.ladder:
             drv = self._drivers[rung]
@@ -520,9 +522,10 @@ class DecodeEngine:
     def compiles_since_warmup(self):
         """Traces that entered the process-wide program cache since
         warm-up (None before it): a new program of ``Module``/
-        ``Executor``. The eager one-operation programs of host
-        bookkeeping never pass through that cache;
-        ``backend_compiles_since_warmup`` sees those too."""
+        ``Executor``. The drivers' cursor programs (compiled during
+        warm-up) and ``migrate``'s eager one-operation copies never
+        pass through that cache; ``backend_compiles_since_warmup``
+        sees those too."""
         if self._warm_mark is None:
             return None
         return _progcache.compile_count() - self._warm_mark

@@ -365,6 +365,150 @@ def test_learned_positions_per_slot(trained):
                                        atol=2e-6)
 
 
+# ===================================== one program moves every cursor
+def _bound_pool(kind, slots):
+    """A bound (never stepped) slot pool: the dense learned-position
+    block, or the rotary block with routed experts."""
+    kw = dict(vocab_size=V, d_model=D, n_head=H, capacity=T,
+              per_slot=True, max_seq_len=T)
+    if kind == "dense-learned":
+        sym = tfm.get_decode_symbol(n_layer=3, pos_embed="learned", **kw)
+        names = ("data", "pos_ids")
+    else:
+        sym = tfm.get_decode_symbol(
+            n_layer=2, pos_embed="rotary", block="olmoe", n_expert=4,
+            top_k=2, expert_width=16, tie_head=False, embed_scale=False,
+            **kw)
+        names = ("data",)
+    mod = mx.mod.Module(sym, data_names=names, label_names=[])
+    mod.bind([mx.io.DataDesc("data", (slots, 1), np.int32)]
+             + [mx.io.DataDesc(n, (slots, 1), np.float32)
+                for n in names[1:]], None, for_training=False)
+    return tfm.BatchedKVCacheDecoder(
+        mod, capacity=T, pos_embed="learned" if len(names) > 1
+        else "rotary")
+
+
+_CURSOR_MOVES = {                 # op -> (rows, positions) of 5 slots
+    "join": ([3], [0]),
+    "rewind": ([1], [9]),
+    "rewind_many-1": ([4], [2]),
+    "rewind_many-some": ([3, 0, 2], [5, 11, 0]),
+    "rewind_many-all": ([0, 1, 2, 3, 4], [7, 0, 3, 12, 1]),
+}
+
+
+@pytest.mark.parametrize("move", sorted(_CURSOR_MOVES))
+@pytest.mark.parametrize("kind", ["dense-learned", "rotary-routed"])
+def test_cursor_moves_equal_eager_scatter(kind, move):
+    """ISSUE 29: ``join`` / ``rewind`` / ``rewind_many`` launch one
+    program over all layers' cursor cells. Afterwards every cell equals
+    what the eager ``.at[idx, 0].set(val)`` wrote, for the rows named
+    and for no other, the host mirror agrees, and each cell has its
+    own buffer, dtype and placement."""
+    slots = 5
+    drv = _bound_pool(kind, slots)
+    assert drv.routed == (kind == "rotary-routed")
+    cells = drv._cursor_cells()
+    assert len(cells) == (3 if kind == "dense-learned" else 2)
+    start = np.asarray([4, 6, 8, 10, 13], np.int32)
+    placed = [c.asjax().sharding for c in cells]
+    for cell, sharding in zip(cells, placed):      # as a step leaves them
+        cell._set(jax.device_put(start[:, None], sharding))
+    drv.pos[:] = start
+    rows, vals = _CURSOR_MOVES[move]
+    want = np.asarray(jnp.asarray(start[:, None]).at[
+        np.asarray(rows, np.int32), 0].set(jnp.asarray(vals, jnp.int32)))
+    if move == "join":
+        assert drv.join(rows[0]) == rows[0] and drv.active[rows[0]]
+    elif move == "rewind":
+        drv.rewind(rows[0], vals[0])
+    else:
+        drv.rewind_many(rows, vals)
+    untouched = [r for r in range(slots) if r not in rows]
+    for cell, sharding in zip(cells, placed):
+        got = cell.asjax()
+        assert got.dtype == jnp.int32 and got.sharding == sharding
+        np.testing.assert_array_equal(np.asarray(got), want)
+        np.testing.assert_array_equal(np.asarray(got)[untouched, 0],
+                                      start[untouched])
+    np.testing.assert_array_equal(drv.pos, want[:, 0])
+    assert len({c.asjax().unsafe_buffer_pointer() for c in cells}) \
+        == len(cells)
+    # any number of rows is the same program: no second compile
+    drv.rewind_many([0], [1])
+    drv.rewind_many([1, 2], [1, 1])
+    assert drv._cursor_program._cache_size() == 1
+    with pytest.raises(mx.base.MXNetError, match="named twice"):
+        drv.rewind_many([2, 2], [3, 4])
+    drv.rewind_many([], [])                        # nothing to move
+
+
+def test_cursor_program_never_compiles_after_warmup(trained):
+    """ISSUE 29: chunked prefill at every rung — joins, window rewinds
+    of 1..rung rows, retirements, rung switches — compiles nothing
+    after warm-up outside ``DecodeEngine.migrate`` (whose eager per-row
+    copies are not steady state), and ``cursor.updates`` / ``.rows``
+    count exactly the joins plus the non-empty rewinds."""
+    def gen(s):
+        return tfm.get_decode_symbol(
+            vocab_size=V, d_model=D, n_layer=L, n_head=H, capacity=T,
+            per_slot=True, step_len=s, max_seq_len=T)
+    sched = mx.serve.serve_decoder(
+        gen(1), _args_nd(trained), name="cursor29", capacity=T,
+        ladder=[1, 2, 4], clock=FakeClock(), start=False,
+        symbol_gen=gen, prefill_chunk=4)
+    eng = sched.engine
+    backend = mx.telemetry.core.backend_compiles
+
+    def counters():
+        return {k: mx.telemetry.counter(f"serve.decode.{k}",
+                                        model="cursor29").value
+                for k in ("cursor.updates", "cursor.rows", "joins",
+                          "prefill.chunks")}
+
+    moved, in_migrate = [], [0]
+    for rung in eng.ladder:
+        drv = eng.driver(rung)
+
+        def rewind_many(rows, positions, inner=drv.rewind_many):
+            if len(rows):
+                moved.append(len(rows))
+            inner(rows, positions)
+        drv.rewind_many = rewind_many
+    migrate = eng.migrate
+
+    def counted_migrate(*args):
+        before = backend()
+        migrate(*args)
+        in_migrate[0] += backend() - before
+    eng.migrate = counted_migrate
+
+    before, mark = counters(), backend()
+    rs = np.random.RandomState(29)
+    handles = [sched.submit(rs.randint(0, V, 6).tolist(),
+                            max_new_tokens=3)]
+    sched.pump()                                   # rung 1
+    for n in (4, 2, 3):          # grow to 4, drain through 2 to 1
+        handles += [sched.submit(rs.randint(0, V, 3 + 2 * i).tolist(),
+                                 max_new_tokens=2 + i) for i in range(n)]
+        sched.pump(max_iterations=3)
+        handles.append(sched.submit(rs.randint(0, V, 9).tolist(),
+                                    max_new_tokens=4))
+        sched.pump()
+    for h in handles:
+        h.result(timeout=5)
+    got = {k: v - before[k] for k, v in counters().items()}
+    assert sched.stats()["migrations"] >= 4
+    assert got["joins"] == len(handles) and got["prefill.chunks"] >= 10
+    assert set(moved) >= {1, 2, 3}                 # rows moved varied
+    assert got["cursor.updates"] == got["joins"] + len(moved)
+    assert got["cursor.rows"] == got["joins"] + sum(moved)
+    assert eng.compiles_since_warmup() == 0
+    assert backend() - mark == in_migrate[0]
+    assert eng.backend_compiles_since_warmup() == in_migrate[0]
+
+
 # ========================================== scheduler (FakeClock path)
 def test_scheduler_staggered_arrivals_deterministic(trained):
     """Acceptance: FakeClock-scripted staggered arrivals/finishes —
