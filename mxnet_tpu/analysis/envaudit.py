@@ -5,8 +5,7 @@ silently — a knob nobody can discover is a knob that ships
 half-supported. This audit keeps the two in lockstep, ast-based so it
 survives formatting:
 
-* **code scan** — every ``*.py`` under ``mxnet_tpu/`` (plus the repo's
-  ``bench.py``, which reads its own knobs) is parsed and
+* **code scan** — every ``*.py`` under ``mxnet_tpu/`` is parsed and
   every string constant that IS an ``MXNET_*`` name is collected: the
   codebase's convention is that such a literal is always an environ
   key — ``os.environ.get/[...]``, ``os.getenv``, the ``_env_int``-style
@@ -66,11 +65,8 @@ def _scan_file(path, exact, prefixes):
             _collect_prefix(node, prefixes)
 
 
-def scan_code(root, extra_files=()):
-    """(exact_names, prefixes) of MXNET_* environ keys under ``root``
-    plus any ``extra_files`` (bench.py reads knobs too — e.g. the
-    ``MXNET_SERVE_SPEC_DRAFT`` draft preset — and those must stay
-    documented like everything else)."""
+def scan_code(root):
+    """(exact_names, prefixes) of MXNET_* environ keys under ``root``."""
     exact, prefixes = set(), set()
     for dirpath, dirnames, filenames in os.walk(root):
         dirnames[:] = [d for d in dirnames if d != "__pycache__"]
@@ -78,8 +74,6 @@ def scan_code(root, extra_files=()):
             if not fname.endswith(".py"):
                 continue
             _scan_file(os.path.join(dirpath, fname), exact, prefixes)
-    for path in extra_files:
-        _scan_file(path, exact, prefixes)
     return exact, prefixes
 
 
@@ -99,9 +93,7 @@ def audit(repo_root):
     """
     code_root = os.path.join(repo_root, "mxnet_tpu")
     doc_path = os.path.join(repo_root, "docs", "env_var.md")
-    exact, prefixes = scan_code(
-        code_root,
-        extra_files=(os.path.join(repo_root, "bench.py"),))
+    exact, prefixes = scan_code(code_root)
     doc = scan_docs(doc_path)
 
     def doc_covers(name):

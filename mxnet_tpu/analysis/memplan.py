@@ -26,7 +26,11 @@ all three policies):
   ``(prob, label)`` pair) — plus every backward-reachable param;
 * ``dots`` — ``remat.DOT_SAVEABLE_OPS`` outputs + program inputs
   (params + batch): the static mirror of
-  ``jax.checkpoint_policies.dots_saveable``;
+  ``jax.checkpoint_policies.dots_saveable`` as read from jax 0.9.0
+  (it saves the outputs of ``dot_general``, ``conv_general_dilated``
+  and ``scaled_matmul_wrapper`` and nothing else). Each saved entry
+  counts once, as ``remat.residual_bytes`` counts it: a value that a
+  nested ``jit`` hands back unchanged is the entry that went in;
 * ``all`` — program inputs only (params + batch).
 
 Surfaces: ``mxlint --memory-plan <model> --policy dots --batch 256``,

@@ -90,9 +90,8 @@ def _symbol_memo(symbol, slot, key, compute):
     Binds repeat over the same (symbol, shapes) — train/eval pairs,
     force_rebind, every step of a bucketing cycle — and the graph walks
     (fixpoint inference, name/attr scans) are the only non-trivial
-    validation cost, so warm-bind validation runs at dict-lookup prices
-    (the <2% bind-time budget in benchmarks/lint_overhead.py). The memo
-    assumes the de-facto immutability of built graphs; mutating a
+    validation cost, so warm-bind validation runs at dict-lookup
+    prices. The memo assumes the de-facto immutability of built graphs; mutating a
     node's attrs after a lint serves stale findings for that symbol
     object.
     """

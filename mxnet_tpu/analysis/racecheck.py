@@ -52,8 +52,7 @@ the claim, the lint stops repeating it.
 CLI: ``python tools/mxlint.py --race-audit`` (and inside ``--check``);
 the scanned surface is ``serve/``, ``checkpoint/``, ``telemetry/`` and
 ``faults/``. The audit is test/CLI-time only — nothing here runs at
-bind time, so the <2% lint-overhead gate is untouched by construction
-(and re-measured anyway; benchmarks/lint_overhead.py).
+bind time.
 """
 from __future__ import annotations
 

@@ -483,8 +483,7 @@ class Executor:
         # bind-time static analysis (the NNVM InferShape/InferType
         # discipline, analysis/): validate="warn"|"raise" per call, or
         # process-wide via MXNET_GRAPH_VALIDATE. The span keeps the
-        # overhead visible (gated <2% of bind by
-        # benchmarks/lint_overhead.py).
+        # overhead visible.
         from . import analysis as _analysis
         vmode = _analysis.resolve_mode(validate)
         if vmode is not None:

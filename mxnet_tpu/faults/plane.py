@@ -41,9 +41,8 @@ the ``point:`` prefix; see docs/faults.md for the catalog):
 Design constraints, mirroring telemetry's:
 
 * **Compiled out when unarmed.** ``point()`` with no plane armed is one
-  module-global load, one ``is None`` branch and a return — gated <1%
-  on the K=8 fused-step hot path by benchmarks/fault_overhead.py (the
-  same discipline benchmarks/telemetry_overhead.py enforces).
+  module-global load, one ``is None`` branch and a return (the same
+  discipline as telemetry's disabled sites).
 * **Deterministic.** Every trigger is a pure function of its private
   call counter (and, for ``prob``, a seeded private rng) — the same
   armed spec produces the same fault sequence on every run, which is
@@ -300,8 +299,7 @@ def fired(name=None):
 
 def calls(name=None):
     """Point traversals seen by armed triggers (fired or not) — the
-    per-batch site count benchmarks/fault_overhead.py multiplies by the
-    disabled per-call cost."""
+    per-batch site count."""
     plane = _active
     trigs = plane.triggers if plane is not None else {}
     if name is not None:

@@ -473,10 +473,9 @@ def ImageRecordIter(path_imgrec, data_shape, batch_size, path_imgidx=None,
 
     When neither ``num_workers`` nor ``MXNET_DECODE_WORKERS`` picks a
     pipeline, the choice is *measured*: single-core hosts go straight to
-    the thread pool (the mp pipeline only adds IPC there — IO_BENCH_r05
-    measured 286 img/s mp vs 379 threaded on 1 core), and multi-core
+    the thread pool (the mp pipeline only adds IPC there), and multi-core
     hosts run a one-shot throughput probe of both pipelines, keeping the
-    faster (``MXNET_IO_AUTOTUNE=0`` skips the probe and trusts mp)."""
+    faster."""
     mean = None
     std = None
     if mean_r or mean_g or mean_b:
@@ -520,7 +519,7 @@ def ImageRecordIter(path_imgrec, data_shape, batch_size, path_imgidx=None,
     if mp_ok and num_workers is None:
         if (os.cpu_count() or 1) <= 1:
             mp_ok = False
-        elif os.environ.get("MXNET_IO_AUTOTUNE", "1") != "0":
+        else:
             if _AUTO_PIPELINE["choice"] is None:
                 probe_n = max(2, 128 // batch_size)
                 mp_it = _mp()

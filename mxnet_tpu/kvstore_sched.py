@@ -25,9 +25,8 @@ window between dispatch and the flush that consumed it — collective
 time that ran hidden behind other work; ``kvstore.exposed.seconds``
 accumulates the residual host wait at flush. Per-bucket dispatch/apply
 records land in the flight-recorder ring, and ``bucket_log`` keeps the
-most recent per-bucket timings for benchmarks
-(benchmarks/comm_overlap.py computes the exposed-comm fraction and the
-max number of buckets in flight from it).
+most recent per-bucket timings (the exposed-comm fraction and the
+max number of buckets in flight follow from it).
 """
 from __future__ import annotations
 
@@ -78,7 +77,7 @@ class BucketScheduler:
         self._staged = set()          # keys pending or in flight, unapplied
         self._inflight = []           # dispatched buckets, dispatch order
         self._seq = 0
-        # recent per-bucket timings for benchmarks/diagnostics
+        # recent per-bucket timings for diagnostics
         self.bucket_log = collections.deque(maxlen=1024)
         # order-audit trail for the static collective-order checker
         # (analysis rules CO301/DA204): which push call staged which key

@@ -24,7 +24,7 @@ Two graphs, one parameter set:
 ``KVCacheDecoder`` drives a bound decode module: host-side position
 tracking (capacity overflow raises before the program clamps), learned-
 position id feeding, cache reset. ``SyntheticLMIter`` is the synthetic
-next-token data source bench.py and the tests train against.
+next-token data source the tests train against.
 """
 from __future__ import annotations
 

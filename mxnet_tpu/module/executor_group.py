@@ -1225,9 +1225,8 @@ class DataParallelExecutorGroup:
         back only once the device reports them finished
         (``Array.is_ready()``) — the fit loop never hard-syncs
         mid-epoch, so an eager device_get here would block on in-flight
-        windows and serialize the host behind the device (measured
-        ~5-10% of a fit epoch on benchmarks/telemetry_overhead.py; the
-        readiness gate makes arming free). The backlog is bounded by
+        windows and serialize the host behind the device (the
+        readiness gate avoids that). The backlog is bounded by
         ``_HEALTH_LAG_MAX`` windows; ``flush=True`` drains everything —
         the epoch-end call, where the loop syncs anyway. ``cursor`` is
         ``(epoch, first_nbatch)`` of the just-dispatched window, handed

@@ -16,8 +16,8 @@ shared-memory staging slots and the parent assembles a batch with one
 memcpy per shard. Two slots per worker double-buffer, so batch k+1 is
 decoding across all cores while the training step consumes batch k.
 Decode throughput scales with cores — the design target is the
-reference bar of >=1000 img/s/host (benchmarks/io_bench.py records the
-measured number per box).
+reference bar of >=1000 img/s/host (not measured on the chip's host;
+no benchmark cell reads a ``.rec`` pack yet).
 
 ``ImageRecordIter`` (image.py) routes here automatically when its
 augmentation is the param-driven CreateAugmenter set; closure-based

@@ -87,18 +87,16 @@ def ring_attention(q, k, v, axis_name="seq", causal=False, scale=None,
     (rtc.flash_attention_partial) whenever the shard shape tiles —
     its unnormalized (acc, m, l) merges into the ring's online-softmax
     carry, so VMEM holds one K tile while FLOPs overlap the neighbor
-    transfer. Auto-selected on the TPU backend (``MXNET_RING_FLASH=0``
-    disables); on CPU the kernel runs in Pallas interpret mode, which
-    only composes with ``shard_map(check_vma=False)`` (as
+    transfer. Auto-selected on the TPU backend; on CPU the kernel runs
+    in Pallas interpret mode, which only composes with
+    ``shard_map(check_vma=False)`` (as
     ``ring_attention_sharded(use_flash=True)`` arranges), so the auto
     default there is the pure-XLA block update.
     """
-    import os
     T = q.shape[2]
     if use_flash is None:
         blk = min(128, T)
         use_flash = (jax.default_backend() == "tpu"
-                     and os.environ.get("MXNET_RING_FLASH", "1") != "0"
                      and T % blk == 0 and k.shape[2] == T)
     if use_flash:
         return _ring_attention_flash(q, k, v, axis_name, causal, scale)

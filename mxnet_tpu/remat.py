@@ -42,6 +42,7 @@ next-larger batch bucket (``telemetry.memory.batch_headroom``).
 """
 from __future__ import annotations
 
+import math
 import os
 
 __all__ = ["POLICIES", "DOT_SAVEABLE_OPS", "resolve", "active",
@@ -110,23 +111,44 @@ def wrap(f, policy):
 def residual_bytes(f, *args):
     """Bytes of the VJP residual set of ``f`` at ``args`` — the
     activations stored between the forward and backward halves, the
-    quantity a remat policy shrinks. Pure trace (``jax.eval_shape``):
+    quantity a remat policy shrinks. Pure trace (``jax.make_jaxpr``):
     nothing executes, so the number is exact and backend-independent.
+
+    Each saved value counts once. Under ``jax.checkpoint`` a nested
+    ``jit`` (``jnp.var`` inside BatchNorm) hands its own input back as
+    a residual of its known half (jax 0.9.0), so a conv output saved by
+    ``dots_saveable`` appears a second time in the residual list; in the
+    compiled step that is one buffer, and it is counted as one here.
     """
     import jax
+    from jax.extend.core import Var
 
     def res(*a):
         _out, vjp_fn = jax.vjp(f, *a)
         return vjp_fn            # a pytree whose leaves ARE the residuals
 
-    tree = jax.eval_shape(res, *args)
+    jaxpr = jax.make_jaxpr(res)(*args).jaxpr
+    producer = {v: eqn for eqn in jaxpr.eqns for v in eqn.outvars}
+
+    def source(v):
+        # follow a value handed back unchanged by a nested jit to the
+        # variable that went in
+        while v in producer and "jaxpr" in producer[v].params:
+            eqn = producer[v]
+            inner = eqn.params["jaxpr"].jaxpr
+            iv = inner.outvars[eqn.outvars.index(v)]
+            if iv not in inner.invars:
+                break
+            v = eqn.invars[inner.invars.index(iv)]
+        return v
+
     total = 0
-    for leaf in jax.tree_util.tree_leaves(tree):
-        shape = getattr(leaf, "shape", None)
-        if shape is None:
-            continue
-        n = 1
-        for d in shape:
-            n *= int(d)
-        total += n * leaf.dtype.itemsize
+    seen = set()
+    for v in jaxpr.outvars:
+        if isinstance(v, Var):
+            v = source(v)
+            if v in seen:
+                continue
+            seen.add(v)
+        total += math.prod(v.aval.shape) * v.aval.dtype.itemsize
     return total

@@ -19,8 +19,8 @@ scheduling and Clipper's deadline-aware adaptive batching:
   ``serve(model).submit(inputs)`` returns a thread-safe sync+async
   ``ResponseHandle``; a dispatch thread (or an explicit deterministic
   ``pump()``) drives the scheduler;
-* ``PoissonLoadGen`` (loadgen.py) — open-loop Poisson load generator
-  for the req/s-at-p99-SLO benchmark axis (bench.py ``serve`` row).
+* ``run_scripted`` (loadgen.py) — deterministic replay of scripted
+  arrivals against a FakeClock-driven server.
 
 Metrics (docs/serving.md has the catalog): ``serve.request.latency.
 seconds`` histograms, ``serve.queue.depth`` / ``serve.batch.occupancy``
@@ -42,7 +42,7 @@ from .engine import BucketEngine, PredictorEngine
 from .registry import ModelRegistry
 from .server import InferenceServer, serve
 from .warm import restore_server, save_server, server_payload
-from .loadgen import PoissonLoadGen, run_scripted
+from .loadgen import run_scripted
 from .decode import (DecodeEngine, DecodeHandle, DecodeScheduler,
                      default_prefill_chunk, default_slot_ladder,
                      default_spec_k, serve_decoder)
@@ -55,7 +55,7 @@ __all__ = ["MonotonicClock", "FakeClock", "BucketLadder",
            "default_ladder", "pad_rows", "slice_rows", "BucketEngine",
            "PredictorEngine", "ModelRegistry", "InferenceServer",
            "serve", "restore_server", "save_server", "server_payload",
-           "PoissonLoadGen", "run_scripted", "DecodeEngine",
+           "run_scripted", "DecodeEngine",
            "DecodeScheduler", "DecodeHandle", "default_slot_ladder",
            "default_prefill_chunk", "default_spec_k", "PrefixStore",
            "default_prefix_budget_bytes", "SamplingParams",

@@ -24,7 +24,7 @@ clock — a FakeClock measures 0, which the deterministic scheduler tests
 rely on), pins each rung's program in the program cache so a later
 training rebind storm cannot evict a serving program, and records the
 compile delta. After warmup, ``compiles_since_warmup()`` must stay 0 —
-the acceptance contract bench.py's serve row and the e2e test assert.
+the acceptance contract the e2e test asserts.
 """
 from __future__ import annotations
 

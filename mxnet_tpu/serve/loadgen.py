@@ -1,28 +1,20 @@
-"""Open-loop load generation: Poisson arrivals + scripted replays.
+"""Scripted load replay against a FakeClock-driven server.
 
-Open-loop means arrivals are scheduled from the arrival process alone —
-a slow server does NOT slow the generator down (closed-loop generators
-hide overload by self-throttling; the req/s-at-p99-SLO number bench.py
-reports is only honest open-loop). Two drivers over one summary:
-
-* ``PoissonLoadGen`` — real-clock Poisson process at ``rate`` req/s
-  against a started server; the bench ``serve`` row and the
-  ``@slow``-marked soak test use it;
 * ``run_scripted`` — deterministic replay of explicit arrival times
   against a FakeClock server via ``pump()``: zero wall-clock sleeps,
-  exact flush/deadline decisions, the tier-1 scheduler gate.
+  exact flush/deadline decisions, the tier-1 scheduler gate. Arrivals
+  are open-loop: a slow server does not slow the script down.
 
 ``summarize`` folds completed handles into the req/s + latency
-percentile + SLO-attainment dict both paths (and bench.py) report.
+percentile + SLO-attainment dict it reports.
 """
 from __future__ import annotations
 
 import numpy as np
 
 from ..base import MXNetError
-from .batching import QueueFullError
 
-__all__ = ["PoissonLoadGen", "run_scripted", "summarize"]
+__all__ = ["run_scripted", "summarize"]
 
 
 def summarize(handles, elapsed_s, slo_ms=None):
@@ -30,7 +22,7 @@ def summarize(handles, elapsed_s, slo_ms=None):
 
     ``elapsed_s``: generator-side wall (or virtual) span the requests
     were offered over — the req/s denominator. ``slo_ms`` adds
-    ``p99_within_slo`` (the bench gate: p99 latency <= SLO).
+    ``p99_within_slo`` (p99 latency <= SLO).
     """
     done = [h for h in handles if h.done() and h.exception() is None]
     lat = sorted(h.latency for h in done if h.latency is not None)
@@ -59,53 +51,6 @@ def summarize(handles, elapsed_s, slo_ms=None):
         out["p99_within_slo"] = (out["latency_ms"]["p99"] is not None
                                  and out["latency_ms"]["p99"] <= slo_ms)
     return out
-
-
-class PoissonLoadGen:
-    """Real-clock open-loop Poisson generator against a started server."""
-
-    def __init__(self, server, make_input, model=None, rate=50.0,
-                 n_requests=200, deadline_ms=None, seed=0):
-        """``make_input(i, rng)`` -> the inputs dict for request i (vary
-        row counts here to exercise mixed shapes); ``rate``: mean
-        arrivals/second of the exponential inter-arrival draw."""
-        if rate <= 0:
-            raise MXNetError("rate must be positive")
-        self.server = server
-        self.make_input = make_input
-        self.model = model
-        self.rate = float(rate)
-        self.n_requests = int(n_requests)
-        self.deadline_ms = deadline_ms
-        self.seed = seed
-
-    def run(self, slo_ms=None, result_timeout_s=60.0):
-        """Offer the full arrival schedule, wait for completions, and
-        return ``summarize(...)`` plus the offered-rate bookkeeping."""
-        rng = np.random.RandomState(self.seed)
-        gaps = rng.exponential(1.0 / self.rate, size=self.n_requests)
-        clock = self.server._clock
-        t0 = clock.now()
-        handles = []
-        next_at = t0
-        for i in range(self.n_requests):
-            next_at += gaps[i]
-            clock.sleep(next_at - clock.now())
-            try:
-                handles.append(self.server.submit(
-                    self.make_input(i, rng), model=self.model,
-                    deadline_ms=self.deadline_ms))
-            except QueueFullError:
-                handles.append(None)   # overload: counted as rejected
-        offered_span = clock.now() - t0
-        live = [h for h in handles if h is not None]
-        for h in live:
-            h.result(timeout=result_timeout_s)
-        out = summarize(live, clock.now() - t0, slo_ms=slo_ms)
-        out["rejected"] = sum(1 for h in handles if h is None)
-        out["offered_rate_req_s"] = round(
-            self.n_requests / offered_span, 2) if offered_span else None
-        return out
 
 
 def run_scripted(server, arrivals, make_input, model=None,

@@ -25,8 +25,8 @@ Naming conventions: dotted lowercase ``layer.what[.unit]`` —
 ``executor.compile``, ``kvstore.push.bytes``, ``io.next.seconds``,
 ``module.fit.batch.seconds``. Histograms end in a unit; counters of
 bytes end in ``.bytes``. Off by default: the disabled fast path is one
-branch per site (gated <2% on a small fit loop by
-benchmarks/telemetry_overhead.py).
+branch per site (0.43 us a disabled span site on the v5e host;
+PERF.md section 6, PR 25).
 
 On top of the tracer/registry sits the always-on diagnostics layer:
 

@@ -7,8 +7,7 @@ recorder is that something: a fixed-size ring (``collections.deque``
 with ``maxlen``) of the most recent activity — batch boundaries,
 executor dispatches, kvstore traffic, anomaly events — cheap enough to
 leave on for every production run (one dict build + deque append per
-record; gated <2% of a small fit loop by
-benchmarks/telemetry_overhead.py).
+record).
 
 Two feeds fill the ring:
 
@@ -24,7 +23,8 @@ On any exception escaping ``Executor.forward/backward``, ``Module.fit``,
 or KVStore push/pull, ``on_crash`` writes a crash report — ring
 contents, metrics-registry snapshot, per-context memory watermarks
 (telemetry.memory), jax device/backend info, filtered env — as one JSON
-file in ``MXNET_CRASH_DIR`` (default: the working directory), exactly
+file in ``MXNET_CRASH_DIR`` (default: ``mxnet_crash/`` under the
+system's temporary directory), exactly
 once per exception. ``tools/diagnose.py`` renders it human-readable.
 
 Pure stdlib at import time (jax is touched only inside dump_crash), so
@@ -37,6 +37,7 @@ import json
 import logging
 import os
 import sys
+import tempfile
 import threading
 import time
 import traceback
@@ -53,7 +54,8 @@ _DEFAULT_CAPACITY = 512
 _enabled = os.environ.get("MXNET_FLIGHT_RECORDER", "1") != "0"
 _ring = collections.deque(maxlen=max(1, int(os.environ.get(
     "MXNET_FLIGHT_RECORDER_CAPACITY", _DEFAULT_CAPACITY))))
-_dump_dir = os.environ.get("MXNET_CRASH_DIR", ".")
+_dump_dir = os.environ.get(
+    "MXNET_CRASH_DIR", os.path.join(tempfile.gettempdir(), "mxnet_crash"))
 _dump_lock = threading.Lock()
 _dump_seq = 0
 

@@ -17,7 +17,7 @@ module folds them over a bound graph into:
   ``tools/diagnose.py`` renders as a roofline section.
 
 MFU is only as honest as its denominator: peaks come from the device
-kind (same table bench.py uses); off-TPU there is no peak and only
+kind (``PEAKS``); off-TPU there is no peak and only
 achieved-FLOP/s is reported. Coverage below ~0.9 means the figure
 under-counts — run ``tools/mxlint.py --mfu-audit`` to see which ops
 need metadata (analysis rule MF601 flags them per graph, too).

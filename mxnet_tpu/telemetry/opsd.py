@@ -29,9 +29,7 @@ Routes (all GET, all read-only):
 
 Zero interaction with the dispatch path: handlers only *read* the
 registry/ring/trace buffers (GIL-consistent snapshots of plain Python
-state), never take framework locks, never touch jax. The <2% overhead
-bound with a scraper hammering ``/metrics`` during a fused-step loop is
-gated by benchmarks/telemetry_overhead.py.
+state), never take framework locks, never touch jax.
 """
 from __future__ import annotations
 

@@ -30,9 +30,10 @@ straggler``) so a stall names its phase, not just its existence.
 
 Arming: follows the telemetry switch (``telemetry.enable()``), or force
 with ``MXNET_STEP_ATTRIBUTION=1`` / off with ``=0`` independent of the
-tracer. Disabled cost is one module-attr read + branch per site (under
-the <2% budget benchmarks/telemetry_overhead.py gates); armed cost is
-gated by the same benchmark's armed-tracing A/B lap.
+tracer. Disabled cost is one module-attr read + branch per site; armed
+it blocks at every window boundary, each step at K=1 (the traced fit
+cells arm it: 1,913.9 against 2,380.9 samples/s on one chip, PERF.md
+section 5, PR 26).
 
 The clock is injectable (``use_clock``) so deterministic tests can
 script exact phase durations.

@@ -624,25 +624,6 @@ def test_mxlint_check_gate(capsys):
     assert "0 error(s)" in out
 
 
-def test_perfwatch_check_gate(capsys):
-    """The perf-trajectory CI gate, next to ``mxlint --check``: the
-    watchdog passes on the repo's real bench history and the recorded
-    benchmark gates (exit 0), in-process. A perf-shaped regression —
-    a doctored payload or a failing recorded gate — fails CI the same
-    way a lint rule does (tests/test_trace.py seeds both)."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    try:
-        import perfwatch
-    finally:
-        sys.path.pop(0)
-    assert perfwatch.main(["--check"]) == 0
-    out = capsys.readouterr().out
-    assert "perfwatch OK" in out and "0 regression(s)" in out
-
-
 def test_mxlint_json_file_exit_codes(tmp_path, capsys):
     main = _mxlint_main()
     good = _mlp()

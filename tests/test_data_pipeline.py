@@ -135,16 +135,37 @@ def test_fit_hands_its_placement_to_the_iterator(K):
         {s.args.get("placement") for s in spans if s.name == staging}
 
 
+def _img_per_sec(it):
+    for _ in it:            # warm epoch: workers, threads, caches
+        pass
+    it.reset()
+    tic = time.perf_counter()
+    seen = 0
+    for batch in it:
+        seen += batch.data[0].shape[0] - batch.pad
+    return seen / (time.perf_counter() - tic)
+
+
 def test_pipeline_throughput_floor(tmp_path):
     """Guards the no-device-round-trips invariant: even one CPU core must
-    sustain far more than single-digit img/s."""
-    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
-    import io_bench
-    prefix = str(tmp_path / "synth")
-    io_bench.make_synthetic_pack(prefix, 64, 128)
-    img_s = io_bench.measure_threads(prefix, 16, (3, 112, 112), epochs=1)
+    sustain far more than single-digit img/s, through the thread pool
+    and through the multiprocess decoders."""
+    from test_mp_decode import _make_pack
+    prefix = _make_pack(tmp_path, n=64, size=(128, 128))
+    shape = (3, 112, 112)
+    threads = mx.image.ImageIter(
+        16, shape, path_imgrec=prefix + ".rec",
+        aug_list=mx.image.CreateAugmenter(shape, rand_crop=True,
+                                          rand_mirror=True),
+        num_threads=os.cpu_count() or 4)
+    img_s = _img_per_sec(mx.io.PrefetchingIter(threads))
     assert img_s > 25, f"pipeline throughput collapsed: {img_s:.1f} img/s"
-    mp_res = io_bench.measure_mp(prefix, 16, (3, 112, 112), epochs=1,
-                                 num_workers=2)
-    assert mp_res is not None and mp_res[0] > 25, \
-        f"mp pipeline throughput collapsed: {mp_res}"
+    mp = mx.image.ImageRecordIter(
+        prefix + ".rec", shape, 16, path_imgidx=prefix + ".idx",
+        rand_crop=True, rand_mirror=True, num_workers=2, prefetch=False)
+    assert type(mp).__name__ == "MPImageRecordIter"
+    try:
+        img_s = _img_per_sec(mx.io.PrefetchingIter(mp))
+    finally:
+        mp.close()
+    assert img_s > 25, f"mp pipeline throughput collapsed: {img_s:.1f} img/s"

@@ -706,7 +706,7 @@ def test_slot_ladder_env(monkeypatch):
 
 def test_scheduler_thread_drive_mode(trained):
     """The real-clock dispatch thread serves submits end to end (the
-    production drive mode bench.py's decode_batch row uses)."""
+    production drive mode; the benchmark's serving cells use it)."""
     sched = _sched(trained, ladder=[1, 2],
                    clock=mx.serve.MonotonicClock())
     rs = np.random.RandomState(9)

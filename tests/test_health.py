@@ -63,19 +63,22 @@ def _tool(name):
 
 
 @pytest.fixture(autouse=True)
-def _clean_health(monkeypatch):
-    """Every test starts unarmed with a fresh monitor/registry and
-    leaves no forced arming, live endpoint, or resized ring behind."""
+def _clean_health(monkeypatch, tmp_path):
+    """Every test starts unarmed with a fresh monitor/registry, dumps
+    its crash reports under its own tmp_path, and leaves no forced
+    arming, live endpoint, or resized ring behind."""
     for var in _HEALTH_ENV:
         monkeypatch.delenv(var, raising=False)
     health.configure(armed=None)
     mx.telemetry.reset()
+    flightrec.configure(dump_dir=str(tmp_path))
     yield
     opsd.stop_ops()
     health.configure(armed=None)
     mx.telemetry.reset()
     mx.telemetry.disable()
-    flightrec.configure(capacity=512, dump_dir=".")
+    flightrec.configure(capacity=512,
+                        dump_dir=os.environ["MXNET_CRASH_DIR"])
 
 
 # ------------------------------------------------------------ fit helper

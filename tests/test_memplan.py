@@ -91,24 +91,17 @@ def test_planner_agrees_on_lenet(policy):
         policy, plan["residual_bytes"], measured)
 
 
-def test_planner_is_trace_free():
+def test_planner_is_trace_free(monkeypatch):
     """Zero compiles AND zero jax traces while planning: the plan is
     pure python over the symbol graph."""
     import jax
     before = mx.program_cache.compile_count()
     calls = []
-    orig = jax.eval_shape
-
-    def spy(*a, **k):
-        calls.append(a)
-        return orig(*a, **k)
-
-    jax.eval_shape = spy
-    try:
-        for policy in remat.POLICIES:
-            memplan.plan_symbol(_resnet20(), SHAPES, policy=policy)
-    finally:
-        jax.eval_shape = orig
+    for name in ("eval_shape", "make_jaxpr"):
+        monkeypatch.setattr(
+            jax, name, lambda *a, _name=name, **k: calls.append(_name))
+    for policy in remat.POLICIES:
+        memplan.plan_symbol(_resnet20(), SHAPES, policy=policy)
     assert mx.program_cache.compile_count() == before
     assert not calls
 

@@ -2,7 +2,7 @@
 donation, batch-bucket headroom.
 
 All on the CPU mesh: ``remat.residual_bytes`` is a pure trace
-(jax.eval_shape), so the memory gate is exact and backend-independent.
+(jax.make_jaxpr), so the memory gate is exact and backend-independent.
 """
 import numpy as np
 import jax.numpy as jnp
@@ -121,39 +121,71 @@ def test_headroom_admits_next_larger_bucket():
     assert batch_headroom(0, fixed, per_sample["all"], buckets) is None
 
 
+def _params(mod):
+    ap, xp = mod.get_params()
+    out = {k: v.asnumpy() for k, v in ap.items()}
+    out.update({f"aux:{k}": v.asnumpy() for k, v in xp.items()})
+    return out
+
+
+def _assert_params_close(ref, got, tol, what):
+    """Every array of ``got`` within ``tol`` of ``ref``, relative to
+    that array's largest magnitude (the betas start at zero and hold
+    nothing but summed gradients, so an element-wise relative error is
+    unbounded near their zeros)."""
+    for k, v in ref.items():
+        np.testing.assert_allclose(
+            got[k], v, rtol=0, atol=tol * np.abs(v).max(),
+            err_msg=f"{what} diverged at {k}")
+
+
 def test_fit_bit_identical_across_policies():
-    """Remat recomputes the same ops — trained params are bit-identical
-    under every policy (and donation of rng/aux changes nothing)."""
+    """Remat recomputes the same ops, so four steps of resnet8 end at
+    the same parameters and aux states under every policy (donation of
+    rng/aux changes nothing) - to float32 rounding, not to the bit: XLA
+    fuses the recomputed forward differently from the saved one, the
+    sums round differently and BatchNorm's 1/sqrt(var) carries that
+    into the next step. Read on jax 0.9.0 (CPU), per array and relative
+    to its largest magnitude: ``dots`` 2.1e-5 (bn_data_beta), ``all``
+    8.5e-4 (stage2_unit1_bn2_beta; 9.5e-5 on the worst weight). The
+    bound is 4x the worst reading; a policy that dropped or changed an
+    op is off by orders of magnitude more. What does hold to the bit
+    is one policy run twice from one seed."""
     digests = {}
     for policy in ("none", "dots", "all"):
-        mod = _fit_resnet(policy)
-        ap, xp = mod.get_params()
-        digests[policy] = {k: v.asnumpy() for k, v in ap.items()}
-        digests[policy].update(
-            {f"aux:{k}": v.asnumpy() for k, v in xp.items()})
+        digests[policy] = _params(_fit_resnet(policy))
         program_cache.clear()
+    again = _params(_fit_resnet("dots"))
+    program_cache.clear()
+    for k, v in digests["dots"].items():
+        np.testing.assert_array_equal(
+            v, again[k], err_msg=f"dots twice diverged at {k}")
     for policy in ("dots", "all"):
-        for k, v in digests["none"].items():
-            np.testing.assert_array_equal(
-                v, digests[policy][k],
-                err_msg=f"{policy} diverged at {k}")
+        _assert_params_close(digests["none"], digests[policy], 3.5e-3,
+                             policy)
 
 
 def test_scan_window_bit_identical_under_remat():
     """K-step scan inherits the policy through step_core: K=4 windows
-    under remat=all match K=4 under none bit for bit (same dispatch
-    shape — scan-vs-single is a separate, policy-independent program
-    and XLA's float scheduling differs between them)."""
+    under remat=all match K=4 under none (same dispatch shape —
+    scan-vs-single is a separate, policy-independent program and XLA's
+    float scheduling differs between them) to float32 rounding, for
+    the reason given above: 2.2e-5 of an array's largest magnitude at
+    worst on jax 0.9.0 (bn_data_beta), bound at 4x that; and to the
+    bit when ``all`` runs twice."""
     ref = _fit_resnet("none", batches=4, K=4)
     assert ref._exec_group._scan_K == 4
-    ap_ref, _ = ref.get_params()
+    p_ref = _params(ref)
     program_cache.clear()
     got = _fit_resnet("all", batches=4, K=4)
     assert got._exec_group._scan_K == 4
-    ap_got, _ = got.get_params()
-    for k in ap_ref:
-        np.testing.assert_array_equal(ap_ref[k].asnumpy(),
-                                      ap_got[k].asnumpy())
+    p_got = _params(got)
+    program_cache.clear()
+    p_again = _params(_fit_resnet("all", batches=4, K=4))
+    for k, v in p_got.items():
+        np.testing.assert_array_equal(
+            v, p_again[k], err_msg=f"all twice diverged at {k}")
+    _assert_params_close(p_ref, p_got, 1e-4, "all")
 
 
 def test_policy_keys_program_cache():

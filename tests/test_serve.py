@@ -388,27 +388,3 @@ def test_predictor_engine_serves_mxp(tmp_path):
     assert server.pump() == 1
     ref = mx.Predictor(path).forward(data=pad_rows(x, 4))[0].asnumpy()
     assert np.array_equal(h.result()[0].asnumpy(), ref[:2])
-
-
-@pytest.mark.slow
-def test_poisson_soak_open_loop():
-    """Real-clock soak: open-loop Poisson arrivals against a started
-    server; everything completes, p99 is finite, metrics accumulate."""
-    sym = _mlp("so")
-    server = mx.serve.serve(_bound_module(sym), ladder=[1, 2, 4, 8],
-                            default_deadline_ms=100)
-    gen = mx.serve.PoissonLoadGen(
-        server,
-        lambda i, rng: {"data": rng.rand(1 + i % 3, 6)
-                        .astype(np.float32)},
-        rate=200.0, n_requests=300, seed=4)
-    try:
-        out = gen.run(slo_ms=100)
-    finally:
-        server.stop()
-    assert out["completed"] == 300 and out["errors"] == 0
-    assert out["latency_ms"]["p99"] is not None
-    assert server.stats()["compiles_since_warmup"] == 0
-    stats = server.stats()["models"]["default"]
-    assert stats["dispatches"] >= 1
-    assert stats["batch_occupancy"] is not None

@@ -559,94 +559,3 @@ def test_jsonl_and_diagnose_render_traces_sections(tmp_path):
     assert "serve.request" in report2
     assert "step phases (per logical batch):" in report2
     assert "stragglers:" in report2
-
-
-# ------------------------------------------------------------- perfwatch
-def _perfwatch():
-    import importlib
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    return importlib.import_module("perfwatch")
-
-
-def test_perfwatch_passes_on_real_history(capsys):
-    """Acceptance: the watchdog passes on the repo's real BENCH history
-    and recorded benchmark gates."""
-    pw = _perfwatch()
-    assert pw.main(["--check"]) == 0
-    out = capsys.readouterr().out
-    assert "perfwatch OK" in out
-
-
-def test_perfwatch_flags_seeded_regression(tmp_path, capsys):
-    """Acceptance: a doctored bench payload (cpu-fallback shaped, rates
-    halved) exits nonzero naming the regressed metrics."""
-    pw = _perfwatch()
-    good = {"metric": "resnet20_cifar_b32_train_img_per_sec_cpu_fallback",
-            "value": 1000.0, "unit": "img/s", "vs_baseline": None,
-            "serve": {"req_per_sec": 140.0,
-                      "latency_ms": {"p99": 60.0}},
-            "lm": {"train_tokens_per_sec": 5000.0,
-                   "decode_tokens_per_sec": 800.0, "max_context": 262144}}
-    bad = json.loads(json.dumps(good))
-    bad["value"] = 400.0                      # past even the 50% fallback
-    bad["serve"]["req_per_sec"] = 30.0        # tolerance for these rows
-    bad["lm"]["max_context"] = 1024
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"parsed": good}))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(bad))
-    rc = pw.main(["--history", str(tmp_path), "--no-gates"])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "REGRESSION" in out
-    assert "serve.req_per_sec" in out
-    assert "lm.max_context" in out
-    assert out.count("REGRESSION") == 3
-
-
-def test_perfwatch_first_sample_and_nulls_pass(tmp_path):
-    pw = _perfwatch()
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"parsed": {"metric": "m_a", "value": None,
-                    "error": "backend unavailable"}}))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(
-        {"parsed": {"metric": "m_b", "value": 10.0}}))
-    rc = pw.main(["--history", str(tmp_path), "--no-gates"])
-    assert rc == 0                   # first sample of a series: vacuous
-
-
-def test_perfwatch_rechecks_recorded_gates(tmp_path, capsys):
-    pw = _perfwatch()
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"parsed": {"metric": "m", "value": 1.0}}))
-    results = tmp_path / "results"
-    results.mkdir()
-    (results / "someline.json").write_text(json.dumps({
-        "gate_pct": 2.0, "analytic_overhead_pct": 3.5,
-        "nested": {"gate_pass": False}}))
-    rc = pw.main(["--history", str(tmp_path),
-                  "--results", str(results)])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "GATE FAIL" in out
-    assert "analytic_overhead_pct" in out
-    assert "nested.gate_pass" in out
-
-
-def test_perfwatch_parses_bench_stdout_tail(tmp_path):
-    """--payload accepts a bench.py stdout capture: the last JSON line
-    is the payload (the one-JSON-line contract)."""
-    pw = _perfwatch()
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"parsed": {"metric": "m", "value": 100.0}}))
-    stdout = ("[bench +1s] warmup\nnot json\n" +
-              json.dumps({"metric": "m", "value": 10.0}) + "\n")
-    payload = tmp_path / "stdout.txt"
-    payload.write_text(stdout)
-    rc = pw.main(["--history", str(tmp_path), "--no-gates",
-                  "--payload", str(payload)])
-    assert rc == 1                   # 10 vs best prior 100: regression
-    rc2 = pw.main(["--history", str(tmp_path), "--no-gates",
-                   "--payload", str(payload), "--tolerance", "0.95"])
-    assert rc2 == 0                  # tolerance widens the gate

@@ -39,8 +39,8 @@ ranks, sorted series, no wall-clock reads):
 The registry merge itself (counter sums, gauge min/max/mean, bucket-wise
 histogram merge) is ``mxnet_tpu.telemetry.fleet.merge`` — this tool only
 adapts file formats onto it and renders text. ``--json`` emits the
-machine-readable document ``tools/perfwatch.py --fleet`` tracks
-(``step.wall.p99_over_p50`` as a regression series).
+same report as a machine-readable document (``series`` holds
+``step.wall.p99_over_p50``).
 """
 from __future__ import annotations
 
@@ -515,8 +515,8 @@ def build(ranks, z_threshold=DEFAULT_Z, gap_seconds=DEFAULT_GAP_S):
         doc["series"]["step.wall.p99_over_p50"] = \
             steps["spread_p99_over_p50"]
     if doc["train_health"]["by_rank"]:
-        # worst rank's health state as a tracked fleet series (0 ok /
-        # 1 degraded / 2 diverged) — perfwatch --fleet flags any climb
+        # worst rank's health state as a fleet series (0 ok /
+        # 1 degraded / 2 diverged)
         doc["series"]["train.health.state.max"] = float(max(
             rec["state"] for rec in doc["train_health"]["by_rank"].values()))
     return doc
@@ -677,8 +677,7 @@ def main(argv=None):
                    metavar="URL",
                    help="live ops endpoint base URL (repeatable)")
     p.add_argument("--json", action="store_true", dest="as_json",
-                   help="emit the machine-readable fleet document "
-                        "(perfwatch --fleet reads it)")
+                   help="emit the machine-readable fleet document")
     p.add_argument("--z-threshold", type=float, default=DEFAULT_Z,
                    help=f"divergence flag threshold "
                         f"(default {DEFAULT_Z})")
