@@ -494,6 +494,12 @@ class BatchedKVCacheDecoder:
     served model's label: a named driver counts its launches and the
     rows they moved in ``serve.decode.cursor.updates`` / ``.rows``.
 
+    ``step`` hands back the whole ``(slots, S, V)`` logits as they lie
+    on the device. A caller that samples one row a slot launches
+    ``select_rows`` behind it: one small jitted program per step length
+    that picks each slot's row and takes its argmax there, so that
+    token ids cross to the host and not the logits.
+
     ``serve.decode.DecodeScheduler`` builds the continuous-batching
     front end (admission, retirement, streaming, rung ladder) on top of
     one of these per slot rung.
@@ -512,6 +518,7 @@ class BatchedKVCacheDecoder:
         self.active = np.zeros(self.slots, bool)
         self._windows = {}                           # step_len -> module
         self._cursor_program = None                  # built at first use
+        self._select_programs = {}                   # step_len -> program
         # the routed feed-forwards' per-layer counts of the latest
         # dispatch (ops/moe.py); empty for a dense decoder
         exe = module._exec_group.executor
@@ -622,6 +629,37 @@ class BatchedKVCacheDecoder:
                               model=self.name).inc()
             telemetry.counter("serve.decode.cursor.rows",
                               model=self.name).inc(int(rows.size))
+
+    def select_rows(self, out, idx):
+        """From a step's ``(slots, S, V)`` output as it lies on the
+        device, ``rows = out[slot, idx[slot]]`` as ``(slots, V)`` (the
+        bytes the host would have indexed, untouched) and ``ids =
+        argmax(rows, -1)`` as ``(slots,)`` int32, the first maximum as
+        ``np.argmax`` takes it: one launch of ``select_rows_<slots>x<S>``
+        (its name in the trace), one program per step length whatever
+        ``idx`` holds. Both stay on the device; the copy of ``ids`` to
+        the host starts here, behind the step program. ``idx`` is
+        (slots,) ints in ``[0, S)``."""
+        arr = out.asjax()
+        S = arr.shape[1]
+        idx = np.asarray(idx, np.int32).reshape(-1)
+        if idx.shape != (self.slots,) or idx.min() < 0 or idx.max() >= S:
+            raise MXNetError(f"select_rows() wants ({self.slots},) row "
+                             f"indices in [0, {S}), got {idx.tolist()}")
+        program = self._select_programs.get(S)
+        if program is None:
+            import jax
+            import jax.numpy as jnp
+
+            def select_rows(out, idx):
+                rows = out[jnp.arange(out.shape[0]), idx]
+                return rows, jnp.argmax(rows, axis=-1).astype(jnp.int32)
+
+            select_rows.__name__ = f"select_rows_{self.slots}x{S}"
+            program = self._select_programs[S] = jax.jit(select_rows)
+        rows, ids = program(arr, idx)
+        ids.copy_to_host_async()
+        return rows, ids
 
     def join(self, slot):
         """Claim ``slot`` for a new sequence: set its device cursor to

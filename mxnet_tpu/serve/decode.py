@@ -23,7 +23,11 @@ pinned program per iteration:
   overflow — an overflowing slot fails ALONE, batchmates keep
   decoding), temperature/top-k/top-p sampling on a recorded
   per-request rng chain (``SamplingParams``; default greedy), and
-  streaming token delivery through ``DecodeHandle`` callbacks. Two
+  streaming token delivery through ``DecodeHandle`` callbacks. Token
+  ids cross to the host, not logits: a select program behind every
+  step picks each slot's last fed row and takes its argmax on the
+  device; the rows themselves are fetched only for an iteration in
+  which a slot that samples is not greedy. Two
   drive modes, same as the server: ``start()`` (dispatch thread, real
   clock) and ``pump()`` (explicit iterations, FakeClock-deterministic).
 
@@ -60,7 +64,8 @@ trace — the same shared-dispatch-span contract batched requests follow.
 Telemetry (always on, docs/serving.md has the catalog):
 ``serve.decode.slots``/``active``/``occupancy``/``queue.depth`` gauges,
 ``serve.decode.iterations``/``tokens``/``joins``/``leaves``/
-``migrations``/``requests``/``responses``/``errors`` counters,
+``migrations``/``requests``/``responses``/``errors``/``fetch.bytes``/
+``sample.device``/``sample.host`` counters,
 ``serve.decode.step.seconds`` + ``serve.decode.request.latency.seconds``
 histograms, and one flight-ring record per iteration.
 """
@@ -475,30 +480,38 @@ class DecodeEngine:
     # ------------------------------------------------------------- warmup
     def warmup(self, clock):
         """Compile every slot rung's S=1 program AND every window
-        program (two steps each: first pays the trace, second measures
-        steady state on ``clock``), pin them all, record the compile
-        delta. Warmup garbage stays harmless: afterwards every driver
-        slot is free, every cursor is rewound to 0, and a join rewinds
-        again. Those rewinds also compile each rung's cursor program,
-        whose shapes are the rung's whatever rows a call names, so no
-        later ``join`` or ``rewind_many`` compiles."""
+        program, each with its select program behind it (two steps
+        each: first pays the traces, second measures steady state on
+        ``clock`` the way an iteration runs it - step, select, token
+        ids on the host), pin them all, record the compile delta.
+        Warmup garbage stays harmless: afterwards every driver slot is
+        free, every cursor is rewound to 0, and a join rewinds again.
+        Those rewinds also compile each rung's cursor program, whose
+        shapes are the rung's whatever rows a call names, so no later
+        ``join`` or ``rewind_many`` compiles."""
         mark = _progcache.compile_count()
         for rung in self.ladder:
             drv = self._drivers[rung]
+            last = np.zeros(rung, np.int32)
+
+            def step_ids(tokens):
+                _rows, ids = drv.select_rows(drv.step(tokens), last)
+                return np.asarray(ids)
+
             zeros = np.zeros((rung, 1), np.int32)
-            drv.step(zeros).asnumpy()            # trace + compile
+            step_ids(zeros)                      # trace + compile
             t0 = clock.now()
-            drv.step(zeros).asnumpy()            # steady state
+            step_ids(zeros)                      # steady state
             self.exec_est[rung] = max(0.0, clock.now() - t0)
             for S in drv.window_lens:
                 wz = np.zeros((rung, S), np.int32)
                 # rewind first so even tiny caches never see the
                 # clamped dynamic_update_slice path during warmup
                 drv.rewind_many(list(range(rung)), [0] * rung)
-                drv.step(wz).asnumpy()           # trace + compile
+                step_ids(wz)                     # trace + compile
                 drv.rewind_many(list(range(rung)), [0] * rung)
                 t0 = clock.now()
-                drv.step(wz).asnumpy()           # steady state
+                step_ids(wz)                     # steady state
                 self.exec_est[(rung, S)] = max(0.0, clock.now() - t0)
             drv.active[:] = False
             drv.rewind_many(list(range(rung)), [0] * rung)
@@ -522,8 +535,8 @@ class DecodeEngine:
     def compiles_since_warmup(self):
         """Traces that entered the process-wide program cache since
         warm-up (None before it): a new program of ``Module``/
-        ``Executor``. The drivers' cursor programs (compiled during
-        warm-up) and ``migrate``'s eager one-operation copies never
+        ``Executor``. The drivers' cursor and select programs (compiled
+        during warm-up) and ``migrate``'s eager one-operation copies never
         pass through that cache; ``backend_compiles_since_warmup``
         sees those too."""
         if self._warm_mark is None:
@@ -721,7 +734,8 @@ class DecodeScheduler:
         gen = _telemetry.metrics.generation()
         if self._iter_handles is None or self._iter_handles[0] != gen:
             handles = {k: self._counter(k) for k in
-                       ("iterations", "tokens", "prefill.chunks")}
+                       ("iterations", "tokens", "prefill.chunks",
+                        "fetch.bytes", "sample.device", "sample.host")}
             if self.engine.driver(self._rung).routed:
                 handles.update({k: self._counter(k)
                                 for k in _MOE_COUNTERS})
@@ -941,34 +955,56 @@ class DecodeScheduler:
             return "spec", self.spec_k
         return "window", 1
 
-    def _step_fetch(self, drv, tokens, phases, t=None):
-        """One dispatch and its logits on the host, each under its own
-        annotation: ``serve.decode.iter.dispatch`` is ``drv.step`` alone
-        (staging and launch), ``serve.decode.iter.fetch`` the
-        ``.asnumpy()`` alone (wait for the device, copy, re-lay). Adds
-        both durations to ``phases`` on the scheduler's clock; ``t`` is
-        the reading that closed the previous phase (one read a
-        boundary), None reads it. Returns ``(logits, end)``."""
+    def _step_fetch(self, drv, tokens, phases, t=None, last=None,
+                    rows=False):
+        """One dispatch and what the host samples from, each under its
+        own annotation. ``serve.decode.iter.dispatch`` is the launches
+        alone: ``drv.step`` (staging and launch) and, where ``last``
+        names each slot's last fed row, ``drv.select_rows`` behind it,
+        which picks those rows and takes their argmax on the device.
+        ``serve.decode.iter.fetch`` waits for the device and copies:
+        the (rung,) int32 token ids, and the (rung, V) selected rows
+        besides only where ``rows`` says a slot that samples in this
+        iteration is not greedy. With ``last`` None (the speculative
+        path: the verifier reads every row of target and draft) it
+        copies the whole (rung, S, V) output. Adds both durations to
+        ``phases`` on the scheduler's clock and the bytes brought to the
+        host to ``phases["bytes"]``; ``t`` is the reading that closed
+        the previous phase (one read a boundary), None reads it.
+        Returns ``(ids, logits, end)``: ``logits`` is the selected rows,
+        the whole output, or None."""
         now = self._clock.now
         if t is None:
             t = now()
         with _telemetry.span("serve.decode.iter.dispatch"):
             out = drv.step(tokens)
+            if last is not None:
+                picked, ids = drv.select_rows(out, last)
+                if rows:
+                    picked.copy_to_host_async()
         t_launched = now()
         with _telemetry.span("serve.decode.iter.fetch"):
             # where the tokens went, counted inside the program: the
-            # copies queue behind it beside the logits', so reading
-            # them afterwards waits for nothing further
+            # copies queue behind it beside the ids', so reading them
+            # afterwards waits for nothing further
             routed = drv.moe_stats_begin()
-            logits = out.asnumpy()
+            if last is None:
+                ids, logits = None, out.asnumpy()
+                nbytes = logits.nbytes
+            else:
+                ids = np.asarray(ids)
+                logits = np.asarray(picked) if rows else None
+                nbytes = ids.nbytes + (logits.nbytes if rows else 0)
             if routed is not None:
                 with _telemetry.span("serve.decode.iter.moe_stats"):
                     phases["moe"] = phases.get("moe", 0) \
                         + drv.moe_stats(routed)
+                nbytes += sum(a.nbytes for a in routed)
         end = now()
         phases["dispatch"] += t_launched - t
         phases["fetch"] += end - t_launched
-        return logits, end
+        phases["bytes"] += nbytes
+        return ids, logits, end
 
     def _dispatch_spec(self, drv, ddrv, base_tokens, meta, K, phases):
         """One speculative iteration's device work (runs OUTSIDE the
@@ -983,7 +1019,7 @@ class DecodeScheduler:
         draft_rows = {row: [] for row, _seq in meta}
         feed = base_tokens.copy()
         for j in range(K):
-            dlog, _ = self._step_fetch(ddrv, feed, phases)  # (rung, 1, V)
+            _, dlog, _ = self._step_fetch(ddrv, feed, phases)  # (rung, 1, V)
             feed = np.zeros((rung, 1), np.int32)
             for row, seq in meta:
                 d = sample_token(dlog[row, 0], seq.sampling, seq.rng)
@@ -994,7 +1030,7 @@ class DecodeScheduler:
         window[:, 0] = base_tokens[:, 0]
         if K > 1:
             window[:, 1:] = proposals[:, :K - 1]
-        vlog, _ = self._step_fetch(drv, window, phases)     # (rung, K, V)
+        _, vlog, _ = self._step_fetch(drv, window, phases)  # (rung, K, V)
         out = {}
         for row, seq in meta:
             out[row] = speculative_verify(
@@ -1060,11 +1096,19 @@ class DecodeScheduler:
                     meta.append((row, seq))
             else:
                 tokens = np.zeros((self._rung, S), np.int32)
+                # each slot's last fed row (0 where nobody owns the
+                # row), and whether a slot that samples now needs the
+                # row itself on the host: a greedy one needs its id only
+                last = np.zeros(self._rung, np.int32)
+                want_rows = False
                 for row, seq in enumerate(self._slots):
                     if seq is None:
                         continue
                     n = min(S, seq.remaining())
                     tokens[row, :n] = seq.window(n)
+                    last[row] = n - 1
+                    if n == seq.remaining() and not seq.sampling.greedy:
+                        want_rows = True
                     meta.append((row, seq, n))
             for entry in meta:
                 seq = entry[1]
@@ -1081,18 +1125,23 @@ class DecodeScheduler:
         # dispatch outside the lock: submits stay non-blocking while
         # the program runs (only pump()/the dispatch thread iterates,
         # so the engine itself needs no second guard)
-        phases = {"dispatch": 0.0, "fetch": 0.0}
+        phases = {"dispatch": 0.0, "fetch": 0.0, "bytes": 0}
         if mode == "spec":
             verdicts = self._dispatch_spec(
                 drv, ddrv, tokens, [(r, s) for r, s in meta], S, phases)
             end = self._clock.now()
         else:
-            logits, end = self._step_fetch(drv, tokens, phases,
-                                           t=planned)  # (rung, S, V)
+            ids, picked, end = self._step_fetch(
+                drv, tokens, phases, t=planned, last=last, rows=want_rows)
             if ddrv is not None:
                 # the draft shadows every non-speculative dispatch so
-                # its cache tracks the same stream positions
-                _, end = self._step_fetch(ddrv, tokens, phases, t=end)
+                # its cache tracks the same stream positions; nobody
+                # reads its logits, so it is launched and not waited for
+                with span("serve.decode.iter.dispatch"):
+                    ddrv.step(tokens)
+                launched = self._clock.now()
+                phases["dispatch"] += launched - end
+                end = launched
 
         with self._lock:
             step_s = max(0.0, end - t0)
@@ -1107,7 +1156,7 @@ class DecodeScheduler:
                         rew_rows, rew_pos)
                 else:
                     emitted, chunks = self._commit_window(
-                        meta, logits, S, t0, end, shared_sid,
+                        meta, ids, picked, S, t0, end, shared_sid,
                         len(active), rew_rows, rew_pos)
             committed = self._clock.now()
             with span("serve.decode.iter.rewind"):
@@ -1135,6 +1184,7 @@ class DecodeScheduler:
                 m["tokens"].inc(emitted)
             if chunks:
                 m["prefill.chunks"].inc(chunks)
+            m["fetch.bytes"].inc(phases["bytes"])
             moe = phases.get("moe")     # a routed decoder's dispatches
             if moe is not None:
                 for key, value in zip(_MOE_COUNTERS, moe):
@@ -1160,14 +1210,19 @@ class DecodeScheduler:
                     "moe_touched": int(moe[2])}))
         return max(1, emitted)
 
-    def _commit_window(self, meta, logits, S, t0, end, shared_sid,
+    def _commit_window(self, meta, ids, picked, S, t0, end, shared_sid,
                        n_active, rew_rows, rew_pos):
-        """Apply one window (or S=1) iteration's logits (caller holds
-        the lock): sample where a slot's stream is exhausted, stream the
-        tokens, retire on EOS / max-new, and queue a cursor rewind for
-        every slot that fed fewer than S tokens. Returns ``(emitted,
-        prefill chunks)``."""
+        """Apply one window (or S=1) iteration's outcome (caller holds
+        the lock): ``ids[row]`` is the argmax of the slot's last fed
+        row, taken on the device, and ``picked[row]`` that row itself,
+        fetched only when a slot sampling now is not greedy. Where a
+        slot's stream is exhausted a greedy request takes its id and
+        any other hands its row to ``sample_token``; stream the tokens,
+        retire on EOS / max-new, and queue a cursor rewind for every
+        slot that fed fewer than S tokens. Returns ``(emitted, prefill
+        chunks)``."""
         emitted = chunks = 0
+        on_device = on_host = 0
         for row, seq, n in meta:
             if seq.slot is None:
                 continue
@@ -1186,9 +1241,13 @@ class DecodeScheduler:
                         pos=seq.fed, tokens=n, chunk=S)
             if was_prefilling:
                 chunks += 1
-            tok = sample_token(logits[row, n - 1],
-                               seq.sampling, seq.rng) \
-                if samples else None
+            tok = None
+            if samples and seq.sampling.greedy:
+                tok = int(ids[row])
+                on_device += 1
+            elif samples:
+                tok = sample_token(picked[row], seq.sampling, seq.rng)
+                on_host += 1
             seq.fed += n
             if n < S:
                 # the dispatch advanced the cursor by S; pull
@@ -1206,6 +1265,9 @@ class DecodeScheduler:
             emitted += 1
             if len(seq.generated) >= seq.max_new:
                 self._finish(seq, reason="length", now=end)
+        m = self._iter_metrics()
+        m["sample.device"].inc(on_device)
+        m["sample.host"].inc(on_host)
         return emitted, chunks
 
     def _commit_spec(self, meta, verdicts, K, t0, end, shared_sid,
@@ -1250,6 +1312,9 @@ class DecodeScheduler:
                     spec_k=K, accepted=accepted, committed=committed)
             if finish is not None:
                 self._finish(seq, reason=finish, now=end)
+        # the verifier drew these on the host, from the fetched logits
+        self._iter_metrics()["sample.host"].inc(
+            sum(len(verdicts[r][1]) for r, _ in meta))
         self._counter("spec.proposed").inc(K * len(meta))
         accepted_now = sum(verdicts[r][0] for r, _ in meta)
         if accepted_now:

@@ -2,10 +2,17 @@
 with a recorded per-request rng chain, plus the exact speculative
 rejection rule.
 
-Sampling runs HOST-side on the logits row the pinned program already
-returned — the device program stays sampling-free, so arming
-temperature/top-k/top-p (or switching a request between them) never
-mints a program-cache trace. Determinism contract:
+What crosses to the host is token ids, not logits: behind every step
+program the scheduler launches one small select program (``models.
+transformer.BatchedKVCacheDecoder.select_rows``) that picks each slot's
+last fed logits row and takes its argmax on the device - the first
+maximum, as ``np.argmax`` - and a greedy request takes that id. Every
+other request is sampled HOST-side, here, on its logits row: the
+selected rows are fetched besides the ids whenever a slot that samples
+in the iteration is not greedy. The select program is the same
+whatever the requests' sampling parameters and the step programs stay
+sampling-free, so arming temperature/top-k/top-p (or switching a
+request between them) never mints a trace. Determinism contract:
 
 * every request owns one ``numpy`` PCG64 chain seeded by
   ``SamplingParams.seed`` — draws happen in a fixed order (draft

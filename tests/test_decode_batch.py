@@ -366,20 +366,24 @@ def test_learned_positions_per_slot(trained):
 
 
 # ===================================== one program moves every cursor
-def _bound_pool(kind, slots):
-    """A bound (never stepped) slot pool: the dense learned-position
-    block, or the rotary block with routed experts."""
+def _pool_symbol(kind, step_len=1):
+    """``(symbol, data names)`` of a slot pool: the dense
+    learned-position block (3 layers), or the rotary block with routed
+    experts (2 layers)."""
     kw = dict(vocab_size=V, d_model=D, n_head=H, capacity=T,
-              per_slot=True, max_seq_len=T)
+              per_slot=True, max_seq_len=T, step_len=step_len)
     if kind == "dense-learned":
-        sym = tfm.get_decode_symbol(n_layer=3, pos_embed="learned", **kw)
-        names = ("data", "pos_ids")
-    else:
-        sym = tfm.get_decode_symbol(
-            n_layer=2, pos_embed="rotary", block="olmoe", n_expert=4,
-            top_k=2, expert_width=16, tie_head=False, embed_scale=False,
-            **kw)
-        names = ("data",)
+        return tfm.get_decode_symbol(n_layer=3, pos_embed="learned",
+                                     **kw), ("data", "pos_ids")
+    return tfm.get_decode_symbol(
+        n_layer=2, pos_embed="rotary", block="olmoe", n_expert=4,
+        top_k=2, expert_width=16, tie_head=False, embed_scale=False,
+        **kw), ("data",)
+
+
+def _bound_pool(kind, slots):
+    """A bound (never stepped) slot pool of ``_pool_symbol(kind)``."""
+    sym, names = _pool_symbol(kind)
     mod = mx.mod.Module(sym, data_names=names, label_names=[])
     mod.bind([mx.io.DataDesc("data", (slots, 1), np.int32)]
              + [mx.io.DataDesc(n, (slots, 1), np.float32)
@@ -507,6 +511,184 @@ def test_cursor_program_never_compiles_after_warmup(trained):
     assert eng.compiles_since_warmup() == 0
     assert backend() - mark == in_migrate[0]
     assert eng.backend_compiles_since_warmup() == in_migrate[0]
+
+
+# ================================= token ids cross to the host, not logits
+_S31 = 4                              # the fixtures' prefill window
+
+
+def _sched31(kind, name, ladder):
+    """``serve_decoder`` with a window program over ``_pool_symbol(kind)``,
+    weights from one seed (scaled up so that the logits spread)."""
+    from chipbench import weights
+
+    def gen(s):
+        return _pool_symbol(kind, s)[0]
+    shapes = {n: (1, 1) for n in _pool_symbol(kind)[1]}
+    params = {k: v if k.endswith(("_gamma", "_beta", "_bias")) else 12 * v
+              for k, v in weights.normal_init(gen(1), shapes, 31).items()}
+    return mx.serve.serve_decoder(
+        gen(1), params, name=name, capacity=T, ladder=list(ladder),
+        clock=FakeClock(), start=False, symbol_gen=gen,
+        prefill_chunk=_S31, prefix_cache_mb=0)
+
+
+def _fetch_whole_logits(sched):
+    """The reference loop: every dispatch's whole logits come to the
+    host through ``drv.step(...).asnumpy()`` and ``sample_token`` is
+    applied to ``[row, n - 1]``, greedy and sampled requests alike."""
+    from mxnet_tpu.serve.sampling import SamplingParams, sample_token
+    greedy = SamplingParams()
+
+    def step_fetch(drv, tokens, phases, t=None, last=None, rows=False):
+        logits = drv.step(tokens).asnumpy()
+        picked = logits[np.arange(len(last)), last]
+        ids = [sample_token(row, greedy, None) for row in picked]
+        return np.asarray(ids, np.int32), picked, sched._clock.now()
+    sched._step_fetch = step_fetch
+
+
+def _counters31(name):
+    return {k: mx.telemetry.counter(f"serve.decode.{k}", model=name).value
+            for k in ("fetch.bytes", "sample.device", "sample.host",
+                      "tokens", "iterations", "prefill.chunks")}
+
+
+@pytest.mark.parametrize("kind", ["dense-learned", "rotary-routed"])
+def test_token_streams_equal_host_sampling_of_whole_logits(kind):
+    """ISSUE 31 (a): greedy and seeded temperature / top-k / top-p
+    requests mixed in one batch, through S=1 iterations and window
+    iterations whose rows feed fewer than S tokens (last chunks, slots
+    decoding beside a prefill), across rung switches: the streams are
+    byte for byte those of a scheduler that fetches the whole logits
+    and samples every row on the host."""
+    from mxnet_tpu.serve import SamplingParams
+    policies = [None, SamplingParams(temperature=0.9, seed=5),
+                SamplingParams(temperature=1.3, top_k=7, seed=6), None,
+                SamplingParams(temperature=0.7, top_p=0.8, seed=7),
+                SamplingParams(temperature=1.0, top_k=20, top_p=0.9,
+                               seed=8), None]
+    lengths = [3, 5, 6, 9, 2, 7, 10]
+
+    def run(name, reference):
+        sched = _sched31(kind, name, ladder=(1, 2, 4))
+        if reference:
+            _fetch_whole_logits(sched)
+        rs = np.random.RandomState(31)
+        before, hs = _counters31(name), []
+        for i, (n, pol) in enumerate(zip(lengths, policies)):
+            hs.append(sched.submit(rs.randint(1, V, n).tolist(),
+                                   max_new_tokens=4 + i % 3,
+                                   sampling=pol))
+            sched.pump(max_iterations=1 + i % 3)   # arrivals staggered
+        sched.pump()
+        outs = [h.result(timeout=5).tobytes() for h in hs]
+        got = {k: v - before[k] for k, v in _counters31(name).items()}
+        return outs, got, sched
+
+    want, _, _ = run(f"ids31-{kind}-ref", True)
+    outs, got, sched = run(f"ids31-{kind}", False)
+    assert outs == want
+    assert got["sample.device"] > 0 and got["sample.host"] > 0
+    assert got["sample.device"] + got["sample.host"] == got["tokens"]
+    steps = [r for r in mx.telemetry.flightrec.get_records()
+             if r.get("kind") == "serve.decode.step"
+             and r.get("model") == f"ids31-{kind}"]
+    assert {r["window"] for r in steps} == {1, _S31}
+    # windows that fed some row fewer than S tokens were among them
+    assert mx.telemetry.counter("serve.decode.cursor.rows",
+                                model=f"ids31-{kind}").value \
+        > got["prefill.chunks"] // 2
+    assert sched.stats()["migrations"] >= 1
+    assert sched.engine.compiles_since_warmup() == 0
+
+
+def test_select_rows_is_numpys_index_and_argmax():
+    """ISSUE 31 (b): the rows are the bytes the host would have indexed
+    and the ids are ``np.argmax`` of them: the first of tied maxima
+    (signed zeros and infinities among them), the first NaN where a row
+    holds one; one program per step length whatever the indices."""
+    slots, S = 5, 3
+    drv = _bound_pool("dense-learned", slots)
+    rs = np.random.RandomState(2)
+    out = rs.randn(slots, S, V).astype(np.float32)
+    out[0, 1, [9, 40, 41]] = out[0, 1].max() + 1.0      # tied maxima
+    out[1, 2, :] = 0.25                                 # a flat row
+    out[2, 0, 7], out[2, 0, 33] = np.nan, np.nan        # NaN wins
+    out[2, 0, 50] = np.inf
+    out[3, 2, [3, 60]] = np.inf                         # tied infinities
+    out[4, 1, :] = -np.inf
+    out[4, 1, [12, 13]] = [-0.0, 0.0]                   # signed zeros tie
+    idx = np.asarray([1, 2, 0, 2, 1])
+    rows, ids = drv.select_rows(mx.nd.array(out), idx)
+    want = out[np.arange(slots), idx]
+    assert rows.dtype == jnp.float32 and ids.dtype == jnp.int32
+    assert np.asarray(rows).tobytes() == want.tobytes()
+    np.testing.assert_array_equal(np.asarray(ids), np.argmax(want, -1))
+    assert np.asarray(ids).tolist()[:3] == [9, 0, 7]
+    for other in ([0, 0, 0, 0, 0], [2, 1, 2, 0, 0]):
+        rows, ids = drv.select_rows(mx.nd.array(out), other)
+        want = out[np.arange(slots), other]
+        assert np.asarray(rows).tobytes() == want.tobytes()
+        np.testing.assert_array_equal(np.asarray(ids),
+                                      np.argmax(want, -1))
+    assert drv._select_programs[S]._cache_size() == 1
+    assert drv._select_programs[S].__name__ == f"select_rows_{slots}x{S}"
+    for bad in ([0, 0, 0, 0, S], [0, -1, 0, 0, 0], [0, 0]):
+        with pytest.raises(mx.base.MXNetError, match="select_rows"):
+            drv.select_rows(mx.nd.array(out), bad)
+
+
+@pytest.mark.parametrize("kind", ["dense-learned", "rotary-routed"])
+def test_fetch_bytes_and_no_compile_on_every_rung_and_window(kind):
+    """ISSUE 31 (c, d): after warm-up no iteration on any rung and
+    step length compiles, in the program cache or in the backend,
+    whether rows are fetched or not. An iteration brings 4 bytes a slot
+    to the host (+ 16 a layer of ``moe_stats``), and the selected rows
+    besides, 4 x V a slot, only when a slot that samples in it is not
+    greedy; every token is counted as sampled from ids or from rows."""
+    from mxnet_tpu.serve import SamplingParams
+    for rung in (1, 2, 4):
+        name = f"bytes31-{kind}-{rung}"
+        sched = _sched31(kind, name, ladder=[rung])   # nothing migrates
+        drv = sched.engine.driver(rung)
+        assert sorted(drv._select_programs) == [1, _S31]
+        ids_bytes = 4 * rung + (16 * 2 if kind == "rotary-routed" else 0)
+        rows_bytes = 4 * rung * V
+        mark = mx.program_cache.compile_count()
+        backend = mx.telemetry.core.backend_compiles()
+        rs = np.random.RandomState(rung)
+        # prompts of 6 = a full window and a window of 2 rows; the
+        # last request draws with a temperature, the others are greedy
+        hs = [sched.submit(rs.randint(1, V, 6).tolist(), max_new_tokens=3,
+                           sampling=SamplingParams(temperature=0.8, seed=i)
+                           if i == rung - 1 else None)
+              for i in range(rung)]
+        seen = []
+        while not all(h.done() for h in hs):
+            before = _counters31(name)
+            assert sched.pump(max_iterations=1) == 1
+            seen.append({k: v - before[k]
+                         for k, v in _counters31(name).items()})
+        # window (nobody samples), window of 2 (all sample), 2 x S=1
+        assert [d["fetch.bytes"] for d in seen] == \
+            [ids_bytes] + [ids_bytes + rows_bytes] * 3
+        assert [d["sample.host"] for d in seen] == [0, 1, 1, 1]
+        assert [d["sample.device"] for d in seen] == [0] + [rung - 1] * 3
+        # an all-greedy batch never fetches rows, at either step length
+        before = _counters31(name)
+        hs = [sched.submit(rs.randint(1, V, 6).tolist(), max_new_tokens=3)
+              for _ in range(rung)]
+        assert sched.pump() == 4
+        got = {k: v - before[k] for k, v in _counters31(name).items()}
+        assert got["fetch.bytes"] == 4 * ids_bytes
+        assert got["sample.host"] == 0
+        assert got["sample.device"] == got["tokens"] == 3 * rung
+        assert mx.program_cache.compile_count() == mark
+        assert mx.telemetry.core.backend_compiles() == backend
+        assert sched.engine.backend_compiles_since_warmup() == 0
+        assert all(prog._cache_size() == 1
+                   for prog in drv._select_programs.values())
 
 
 # ========================================== scheduler (FakeClock path)

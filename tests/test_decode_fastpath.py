@@ -301,6 +301,51 @@ def test_spec_greedy_bit_identical_with_foreign_draft(target_params,
     assert st["spec"]["rollbacks"] >= 0
 
 
+def test_draft_shadow_fetches_nothing_and_spec_fetches_every_row(
+        target_params, draft_params):
+    """ISSUE 31 (e): on a window or S=1 dispatch the draft is launched
+    behind the target and nothing of it is selected or brought to the
+    host (the iteration fetches the target's ids alone); a speculative
+    iteration still fetches every row of draft and target, and its
+    streams are the token-at-a-time reference's."""
+    sched = _sched(target_params, ladder=(2,), chunk=4,
+                   draft=draft_params, spec_k=3)
+    name, rung, K = sched.engine.name, 2, 3
+
+    def select_rows(out, idx):
+        raise AssertionError("the draft's output was selected from")
+    sched.draft.driver(rung).select_rows = select_rows
+
+    def count(key):
+        return mx.telemetry.counter(f"serve.decode.{key}",
+                                    model=name).value
+
+    prompts = _prompts(31, 2, lo=6, hi=9)
+    hs = [sched.submit(p, max_new_tokens=7) for p in prompts]
+    per_iter = []
+    while not all(h.done() for h in hs):
+        before = count("fetch.bytes"), count("sample.host")
+        assert sched.pump(max_iterations=1) == 1
+        per_iter.append((count("fetch.bytes") - before[0],
+                         count("sample.host") - before[1]))
+    steps = [r for r in mx.telemetry.flightrec.get_records()
+             if r.get("kind") == "serve.decode.step"
+             and r.get("model") == name]
+    assert len(steps) == len(per_iter)
+    modes = [r["mode"] for r in steps]
+    assert modes.count("window") >= 2 and modes.count("spec") >= 2
+    spec_bytes = 4 * rung * V * (K + K)     # K draft rows, K target rows
+    for r, (nbytes, on_host) in zip(steps, per_iter):
+        if r["mode"] == "spec":
+            assert nbytes == spec_bytes and on_host >= rung
+        else:
+            assert nbytes == 4 * rung and on_host == 0
+    assert sched.engine.compiles_since_warmup() == 0
+    assert sched.draft.backend_compiles_since_warmup() == 0
+    for p, h in zip(prompts, hs):
+        assert list(h.result(timeout=5)) == _ref_greedy(target_params, p, 7)
+
+
 def test_spec_self_draft_accepts_everything(target_params):
     """Draft == target weights: every proposal verifies, acceptance is
     1.0 and no rollbacks happen — the acceptance-telemetry fixture."""
