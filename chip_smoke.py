@@ -226,6 +226,31 @@ def kernel_sites(w, seed=0):
                 (normal((E, D, F), f32) / np.sqrt(D)).astype(bf),
                 (normal((E, F, D), f32) / np.sqrt(F)).astype(bf),
                 jnp.zeros((4,), jnp.int32)]))
+    # EVA attention with its state (ops/eva.py): a window of exact rows
+    # beside chunk summaries, four windows of context; ragged fed
+    # counts, cursors anywhere a dispatch has room
+    chunk = 16 if cap >= 1024 else 4
+    for step in (1, win):
+        q = (slots, h_dec, step, dh_dec)
+        ring = (slots, h_dec, cap, dh_dec)
+        pool = (slots, h_dec, 4 * cap // chunk, dh_dec)
+        vec = (h_dec, dh_dec)
+        sites.append((
+            f"eva_attention_decode_s{step}", "eva_attention_decode",
+            {"capacity": 4 * cap, "window": cap, "chunk": chunk,
+             "rope_base": 1e5},
+            [q, q, q, (slots,), vec, vec, ring, ring, pool, pool,
+             (slots, 1)],
+            [bf, bf, bf, "int32", bf, bf, bf, bf, bf, bf, "int32"], False,
+            lambda q=q, ring=ring, pool=pool, vec=vec, step=step: (
+                [normal(q, bf) for _ in range(3)]
+                + [jnp.asarray(rs.randint(0, step + 1, (slots,))
+                               .astype(np.int32)),
+                   normal(vec, bf), normal(vec, bf),
+                   normal(ring, bf), normal(ring, bf),
+                   normal(pool, bf), normal(pool, bf),
+                   jnp.asarray(rs.randint(0, 4 * cap - step + 1,
+                                          (slots, 1)).astype(np.int32))])))
     for wdt in ("int8", "float8_e4m3fn"):
         rows, k, nh = slots * win, D, 4 * D
         sites.append((

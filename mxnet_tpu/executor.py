@@ -662,13 +662,25 @@ class Executor:
         runner = self._naive_runner_fn() if naive else self._runner
         if kind in ("fwd_infer", "fwd_train"):
             is_train = kind == "fwd_train"
+            # a graph whose decode state is large and updated a few rows
+            # a step (OpDef.donate_aux) hands its aux arrays over to the
+            # inference program, which updates them in place; an aux the
+            # program does not rewrite goes back as it came
+            donate = kind == "fwd_infer" and not naive and any(
+                not n.is_variable and n.opdef().donate_aux
+                for n in self._symbol._topo_nodes())
 
             def prog(arg_vals, aux_vals, rng):
-                return runner(arg_vals, aux_vals, is_train, rng)
+                outs, new_aux = runner(arg_vals, aux_vals, is_train, rng)
+                if donate:
+                    new_aux = {**aux_vals, **new_aux}
+                return outs, new_aux
 
             prog.__name__ = self.program_name(kind)
             fn = _telemetry.wrap_dispatch(prog, kind, compiled=False) \
-                if naive else _telemetry.wrap_dispatch(jax.jit(prog), kind)
+                if naive else _telemetry.wrap_dispatch(
+                    jax.jit(prog, donate_argnums=(1,) if donate else ()),
+                    kind)
         elif kind == "fwd_bwd":
             watched = self._watched()
 

@@ -36,7 +36,7 @@ from .. import symbol as sym
 from ..base import MXNetError
 
 __all__ = ["get_symbol", "get_decode_symbol", "SyntheticLMIter",
-           "KVCacheDecoder", "BatchedKVCacheDecoder",
+           "KVCacheDecoder", "BatchedKVCacheDecoder", "slot_state",
            "default_cache_capacity", "default_cache_dtype"]
 
 
@@ -66,10 +66,26 @@ def _proj(x, num_hidden, name, no_bias=False):
                               no_bias=no_bias)
 
 
+def _qkv_heads(qkv, j, nm, pfx, T, n_head, d_model, norm=None):
+    """Row block ``j`` of a fused (B*T, 3D) q/k/v projection as
+    (B, H, T, dh) heads, ``norm`` applied to the whole projection
+    before the split."""
+    rows = sym.slice_axis(qkv, axis=1, begin=j * d_model,
+                          end=(j + 1) * d_model, name=f"{pfx}_{nm}_rows")
+    if norm is not None:
+        rows = norm(rows, f"{pfx}_{nm}_norm")
+    rows = sym.Reshape(rows, shape=(-1, T, n_head, d_model // n_head),
+                       name=f"{pfx}_{nm}_split")
+    return sym.transpose(rows, axes=(0, 2, 1, 3),
+                         name=f"{pfx}_{nm}")                 # (B, H, T, dh)
+
+
 def _block(x, *, i, seq_len, d_model, n_head, dropout, pos_embed,
            rope_base, name, decode=False, capacity=None,
            per_slot=False, cache_dtype=None, moe=None):
-    """One pre-norm transformer block - the one place a block is built;
+    """One pre-norm transformer block - the one place a GPT-2 or OLMoE
+    block is built (EvaByte's, whose residual stream is float32 and
+    whose attention exists as a decode op alone, is ``_eva_block``);
     ``decode=True`` swaps the full ``attention`` for the KV-cache
     ``attention_decode`` path (same parameter names either way, so one
     trained parameter set serves both graphs). ``per_slot=True`` selects
@@ -91,18 +107,10 @@ def _block(x, *, i, seq_len, d_model, n_head, dropout, pos_embed,
     ln1 = _norm(x, f"{pfx}_ln1", moe)
     qkv = _proj(ln1, 3 * d_model, f"{pfx}_qkv", no_bias=olmoe)  # (B*T, 3D)
     if olmoe:
-        def head_split(v, nm):
-            v = sym.Reshape(v, shape=(-1, T, n_head, dh),
-                            name=f"{pfx}_{nm}_split")
-            return sym.transpose(v, axes=(0, 2, 1, 3),
-                                 name=f"{pfx}_{nm}")         # (B, H, T, dh)
-        q, k, v = (sym.slice_axis(qkv, axis=1, begin=j * d_model,
-                                  end=(j + 1) * d_model,
-                                  name=f"{pfx}_{nm}_rows")
-                   for j, nm in enumerate("qkv"))
-        q = head_split(_norm(q, f"{pfx}_q_norm", moe), "q")
-        k = head_split(_norm(k, f"{pfx}_k_norm", moe), "k")
-        v = head_split(v, "v")
+        qk_norm = lambda rows, name: _norm(rows, name, moe)  # noqa: E731
+        q, k, v = (_qkv_heads(qkv, j, nm, pfx, T, n_head, d_model, norm)
+                   for j, (nm, norm) in enumerate(
+                       (("q", qk_norm), ("k", qk_norm), ("v", None))))
     else:
         qkv = sym.Reshape(qkv, shape=(-1, T, 3 * n_head, dh),
                           name=f"{pfx}_qkv_split")
@@ -163,10 +171,65 @@ def _norm(x, name, moe):
     return sym.LayerNorm(x, name=name)
 
 
+def _eva_norm(x, name, eva):
+    """EvaByte's normalisation: RMSNorm whose gain is stored as its
+    distance from one, handed on at the compute width."""
+    return sym.RMSNorm(x, eps=eva["rms_eps"], unit_offset=True,
+                       cast_to_gain=True, name=name)
+
+
+def _eva_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
+               capacity, eva):
+    """One EvaByte block (``eva``: ``_eva_spec``) of the slot-pooled
+    decode graph: ``h = x + Attn(N(x))``, ``y = h + FFN(N(h))`` with
+    the residual stream ``x`` and both adds in float32, the matmuls at
+    the compute width, EVA attention with its state
+    (``eva_attention_decode``: rotary inside the op, ``fed`` real
+    tokens a slot) and a dense gated-SiLU feed-forward whose gate and
+    up projections are one matmul; no bias anywhere."""
+    pfx = f"{name}_l{i}"
+    T = seq_len
+
+    qkv = _proj(_eva_norm(x, f"{pfx}_ln1", eva), 3 * d_model,
+                f"{pfx}_qkv", no_bias=True)                  # (B*T, 3D)
+
+    q, k, v = (_qkv_heads(qkv, j, nm, pfx, T, n_head, d_model)
+               for j, nm in enumerate("qkv"))
+    att = sym.eva_attention_decode(
+        q, k, v, fed, capacity=capacity,
+        window=eva["window"], chunk=eva["chunk"], rope_base=rope_base,
+        name=f"{pfx}_attn")
+    att = sym.transpose(att, axes=(0, 2, 1, 3), name=f"{pfx}_attn_t")
+    att = sym.Reshape(att, shape=(-3, -3), name=f"{pfx}_attn_merge")
+    proj = sym.FullyConnected(att, num_hidden=d_model, no_bias=True,
+                              name=f"{pfx}_proj")
+    proj = sym.Reshape(proj, shape=(-1, T, d_model),
+                       name=f"{pfx}_proj_unfold")
+    x = x + sym.Cast(proj, dtype="float32", name=f"{pfx}_proj_f32")
+
+    rows = sym.Reshape(_eva_norm(x, f"{pfx}_ln2", eva), shape=(-3, 0),
+                       name=f"{pfx}_ffn_fold")
+    h = sym.FullyConnected(rows, num_hidden=2 * eva["ffn_width"],
+                           no_bias=True, name=f"{pfx}_ffn_gate_up")
+    h = sym.GatedSiLU(h, name=f"{pfx}_ffn_act")
+    h = sym.FullyConnected(h, num_hidden=d_model, no_bias=True,
+                           name=f"{pfx}_ffn_down")
+    h = sym.Reshape(h, shape=(-1, T, d_model), name=f"{pfx}_ffn_unfold")
+    return x + sym.Cast(h, dtype="float32", name=f"{pfx}_ffn_f32")
+
+
+def _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps):
+    if block != "evabyte":
+        return None
+    return {"window": int(window), "chunk": int(chunk),
+            "n_pred_heads": int(n_pred_heads),
+            "ffn_width": int(ffn_width or 0), "rms_eps": float(rms_eps)}
+
+
 def _validate(vocab_size, d_model, n_head, pos_embed, block="gpt2",
-              n_expert=None, top_k=None, expert_width=None):
-    if block not in ("gpt2", "olmoe"):
-        raise MXNetError(f"block {block!r}: 'gpt2' or 'olmoe'")
+              n_expert=None, top_k=None, expert_width=None, eva=None):
+    if block not in ("gpt2", "olmoe", "evabyte"):
+        raise MXNetError(f"block {block!r}: 'gpt2', 'olmoe' or 'evabyte'")
     if block == "olmoe":
         if pos_embed != "rotary":
             raise MXNetError("block='olmoe' is rotary (no position table)")
@@ -174,6 +237,18 @@ def _validate(vocab_size, d_model, n_head, pos_embed, block="gpt2",
             raise MXNetError(
                 "block='olmoe' needs n_expert >= top_k >= 1 and "
                 f"expert_width (got {n_expert}, {top_k}, {expert_width})")
+    if block == "evabyte":
+        if eva is None:
+            raise MXNetError(
+                "block='evabyte' is served, not trained: its attention "
+                "exists as the decode op alone "
+                "(get_decode_symbol(per_slot=True))")
+        if pos_embed != "rotary":
+            raise MXNetError("block='evabyte' is rotary (no position "
+                             "table)")
+        if not eva["ffn_width"] or eva["n_pred_heads"] < 1:
+            raise MXNetError("block='evabyte' needs ffn_width and "
+                             "n_pred_heads >= 1")
     if d_model % n_head:
         raise MXNetError(f"d_model {d_model} must divide n_head {n_head}")
     if (d_model // n_head) % 2:
@@ -254,6 +329,9 @@ def get_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     ``n_expert`` experts of ``expert_width``, ``top_k`` a token,
     ``rms_eps``; OLMoE also unties the head (``tie_head=False``) and
     leaves the embedding unscaled (``embed_scale=False``).
+    ``block="evabyte"`` has no full-sequence graph (its attention is
+    the decode op; the plain full forward is the benchmark's
+    ``chipbench/reference/evabyte.py``).
     """
     _validate(vocab_size, d_model, n_head, pos_embed, block, n_expert,
               top_k, expert_width)
@@ -287,7 +365,9 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                       per_slot=False, cache_dtype=None, name="lm",
                       block="gpt2", n_expert=None, top_k=None,
                       expert_width=None, norm_topk=False, rms_eps=1e-5,
-                      tie_head=True, embed_scale=True):
+                      tie_head=True, embed_scale=True, window=2048,
+                      chunk=16, n_pred_heads=1, ffn_width=None,
+                      multibyte=False):
     """Incremental KV-cache decoder: ``(B, step_len)`` new token ids in,
     logits ``(B, step_len, vocab)`` out, per-layer K/V caches of
     ``capacity`` positions riding executor aux state. Parameter names
@@ -316,15 +396,36 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     ``block``, ``n_expert``, ``top_k``, ``expert_width``, ``norm_topk``,
     ``rms_eps``, ``tie_head`` and ``embed_scale`` are ``get_symbol``'s:
     ``block="olmoe"`` is rotary, so the graph has no ``pos_ids`` input.
+
+    ``block="evabyte"`` (per-slot only) builds EvaByte's block
+    (``_eva_block``): EVA attention over ``window`` exact positions and
+    one summary per ``chunk`` of everything older (``ops/eva.py``), a
+    dense gated-SiLU feed-forward of ``ffn_width``, and an untied head
+    of ``n_pred_heads`` consecutive blocks of ``vocab_size`` columns.
+    Its state is not a row per position, so the graph takes one more
+    input, ``fed`` ``(slots,)`` int32: how many of each slot's
+    ``step_len`` tokens are real. The program advances a slot's state
+    by exactly that. The output is head 0's ``(B, step_len, vocab)``
+    logits - the next byte, what a scheduler samples - or, with
+    ``multibyte``, all heads' ``(B, step_len, n_pred_heads, vocab)``.
     """
+    eva = _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps)
     _validate(vocab_size, d_model, n_head, pos_embed, block, n_expert,
-              top_k, expert_width)
+              top_k, expert_width, eva=eva)
     moe = _moe_spec(block, n_expert, top_k, expert_width, norm_topk,
                     rms_eps)
     capacity = capacity or default_cache_capacity()
     cache_dtype = cache_dtype or default_cache_dtype()
     max_seq_len = max_seq_len or capacity
     S = step_len
+    if eva is not None:
+        if not per_slot or cache_dtype:
+            raise MXNetError("block='evabyte' is the slot-pooled decode "
+                             "graph (per_slot=True) with state at the "
+                             "compute width (no cache_dtype)")
+        return _eva_decode_symbol(
+            vocab_size, d_model, n_layer, n_head, rope_base, capacity, S,
+            name, eva, multibyte)
 
     data = sym.var("data")
     tok_w = sym.var(f"{name}_tok_embed_weight")
@@ -340,6 +441,34 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                    per_slot=per_slot, cache_dtype=cache_dtype, moe=moe)
     logits = _head(x, tok_w, moe=moe, tie_head=tie_head,
                    vocab_size=vocab_size, name=name)
+    return sym.Reshape(logits, shape=(-1, S, vocab_size),
+                       name=f"{name}_logits_bsv")
+
+
+def _eva_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
+                       capacity, S, name, eva, multibyte):
+    data = sym.var("data")
+    fed = sym.var("fed")
+    x = sym.Embedding(data=data, weight=sym.var(f"{name}_tok_embed_weight"),
+                      input_dim=vocab_size, output_dim=d_model,
+                      name=f"{name}_tok_embed")              # (B, S, D)
+    x = sym.Cast(x, dtype="float32", name=f"{name}_embed_f32")
+    for i in range(n_layer):
+        x = _eva_block(x, fed, i=i, seq_len=S, d_model=d_model,
+                       n_head=n_head, rope_base=rope_base, name=name,
+                       capacity=capacity, eva=eva)
+    flat = sym.Reshape(_eva_norm(x, f"{name}_ln_f", eva), shape=(-3, 0),
+                       name=f"{name}_head_fold")
+    n_pred = eva["n_pred_heads"]
+    logits = sym.FullyConnected(
+        flat, weight=sym.var(f"{name}_head_weight"),
+        num_hidden=n_pred * vocab_size, no_bias=True,
+        name=f"{name}_logits")                               # (B*S, P*V)
+    if multibyte:
+        return sym.Reshape(logits, shape=(-1, S, n_pred, vocab_size),
+                           name=f"{name}_logits_bspv")
+    logits = sym.slice_axis(logits, axis=1, begin=0, end=vocab_size,
+                            name=f"{name}_next_byte")
     return sym.Reshape(logits, shape=(-1, S, vocab_size),
                        name=f"{name}_logits_bsv")
 
@@ -462,6 +591,27 @@ class KVCacheDecoder:
         return self._mod.get_outputs()[0]
 
 
+def slot_state(symbol):
+    """``{family: [aux cell names, in graph order]}`` of a slot-pooled
+    decode graph's per-slot state, as its ops declare it
+    (``OpDef.slot_state``): ``"cursor"`` cells, ``"rows"`` pools with a
+    row per position, and whatever further families an op keeps
+    (``ops/eva.py``: ``"window"``, ``"summary"``). A cell no op
+    declares (``MoEFFN``'s counts) is no slot's state."""
+    families = {}
+    for node in symbol._topo_nodes():
+        if node.is_variable or not node.opdef().slot_state:
+            continue
+        opdef = node.opdef()
+        aux = opdef.aux_names(node.attrs)
+        for (var, _), nm in zip(node.inputs[len(node.inputs) - len(aux):],
+                                aux):
+            if nm in opdef.slot_state:
+                families.setdefault(opdef.slot_state[nm], []).append(
+                    var.name)
+    return families
+
+
 class BatchedKVCacheDecoder:
     """Host-side driver for a bound SLOT-POOLED decode module.
 
@@ -500,6 +650,25 @@ class BatchedKVCacheDecoder:
     that picks each slot's row and takes its argmax there, so that
     token ids cross to the host and not the logits.
 
+    **Two kinds of state.** The graph's ops say which aux cells hold
+    per-slot state and of what family (``slot_state``). A graph whose
+    state is a cursor and ``"rows"`` pools alone is *positional*: any
+    position below the cursor is a row that is still there, which is
+    what ``rewind`` to an arbitrary position, ``capture_rows`` and
+    ``restore_rows`` rest on. EVA attention (``block="evabyte"``) keeps
+    a ring of the open window's exact rows beside one summary per
+    closed chunk: not positional. For such a graph ``step`` takes
+    ``fed``, the number of real tokens of each slot, and the program
+    advances each slot's state by exactly that, so nothing runs ahead
+    and nothing needs rewinding; ``join`` and ``leave`` are unchanged
+    (everything is read by the cursor, so a cursor at 0 is a clean
+    slot); ``rewind``/``rewind_many`` accept 0 or a position whose
+    window's rows are all still in the ring (no row of a later window
+    written over them) and raise ``MXNetError`` otherwise;
+    ``capture_rows``/``restore_rows`` raise; ``overflowing`` is about
+    the context (cursor + S against capacity), whatever the pools
+    hold; ``DecodeEngine.migrate`` copies every family.
+
     ``serve.decode.DecodeScheduler`` builds the continuous-batching
     front end (admission, retirement, streaming, rung ladder) on top of
     one of these per slot rung.
@@ -524,6 +693,17 @@ class BatchedKVCacheDecoder:
         exe = module._exec_group.executor
         self._moe_cells = [cell for nm, cell in exe.aux_dict.items()
                            if nm.endswith("moe_stats")]
+        # the per-slot state, by family, as the graph's ops declare it
+        self._state = slot_state(module.symbol)
+        self.positional = set(self._state) <= {"cursor", "rows"}
+        self.feeds = "fed" in module.symbol.list_arguments()
+        self.last_reads = None
+        if not self.positional:
+            ring = exe.aux_dict[self._state["window"][0]]
+            pool = exe.aux_dict[self._state["summary"][0]]
+            self.window = int(ring.shape[2])
+            self.chunk = self.capacity // int(pool.shape[2])
+            self.state_layers = len(self._state["window"]) // 2
 
     def add_window(self, step_len, module):
         """Register an S-token window module. It MUST have been bound
@@ -537,17 +717,30 @@ class BatchedKVCacheDecoder:
     def window_lens(self):
         return sorted(self._windows)
 
+    def _cells(self, family):
+        """(name, cell) of every aux cell of one state family, in graph
+        order."""
+        aux = self._mod._exec_group.executor.aux_dict
+        return [(nm, aux[nm]) for nm in self._state.get(family, ())]
+
+    def slot_cells(self):
+        """(name, cell) of every per-slot state cell, all families:
+        what a slot that moves to another pool takes along."""
+        return [nc for family in self._state for nc in self._cells(family)]
+
     def _cursor_cells(self):
-        exe = self._mod._exec_group.executor
-        return [cell for nm, cell in exe.aux_dict.items()
-                if nm.endswith("cache_pos")]
+        return [cell for _nm, cell in self._cells("cursor")]
 
     def _kv_cells(self):
         """(name, cell) for every layer's K and V cache, in graph
-        order — the prefix store snapshots/restores these rows."""
-        exe = self._mod._exec_group.executor
-        return [(nm, cell) for nm, cell in exe.aux_dict.items()
-                if nm.endswith("k_cache") or nm.endswith("v_cache")]
+        order — the prefix store snapshots/restores these rows. Only a
+        positional graph has them to give."""
+        if not self.positional:
+            raise MXNetError(
+                "this decoder's state is not a row per position "
+                f"(families {sorted(self._state)}): a prefix of it "
+                "cannot be captured or restored by row copy")
+        return self._cells("rows")
 
     @property
     def routed(self):
@@ -603,6 +796,19 @@ class BatchedKVCacheDecoder:
         if mask.sum() != rows.size:
             raise MXNetError(f"slot named twice in one cursor update: "
                              f"{rows.tolist()}")
+        if not self.positional:
+            cur = self.pos[rows]
+            ends = (positions // self.window + 1) * self.window
+            bad = (positions != 0) & ((positions > cur) | (cur > ends))
+            if bad.any():
+                raise MXNetError(
+                    f"cursor of slot(s) {rows[bad].tolist()} cannot move "
+                    f"from {cur[bad].tolist()} to "
+                    f"{positions[bad].tolist()}: this decoder keeps the "
+                    f"open window's {self.window} exact rows and "
+                    "summaries of everything older, so a cursor goes to "
+                    "0 or back inside the window whose rows are still "
+                    "there")
         cells = self._cursor_cells()
         arrays = tuple(cell.asjax() for cell in cells)
         if self._cursor_program is None:
@@ -723,14 +929,20 @@ class BatchedKVCacheDecoder:
         return [i for i in range(self.slots)
                 if self.active[i] and self.pos[i] + window > self.capacity]
 
-    def step(self, tokens):
+    def step(self, tokens, fed=None):
         """Advance every slot by one S-token window: ``tokens``
         (slots,) or (slots, S) int ids (retired slots ride any valid
         id, 0 by convention) -> logits (slots, S, V) NDArray. S=1 runs
         the steady-state decode program; S>1 dispatches the matching
         window module registered via ``add_window``. Raises per slot
         BEFORE dispatch when an active slot would overflow its cache —
-        batchmates are untouched (nothing was dispatched)."""
+        batchmates are untouched (nothing was dispatched).
+
+        A graph with a ``fed`` input (``self.feeds``) advances slot
+        ``b`` by ``fed[b]`` of its S tokens (0..S; None feeds every
+        slot all S) and leaves a slot with no room for S positions
+        where it is; any other graph advances every slot by S and takes
+        no ``fed``."""
         from .. import ndarray as nd
         from ..io import DataBatch
         tokens = np.asarray(tokens)
@@ -761,6 +973,40 @@ class BatchedKVCacheDecoder:
             pos = self.pos[:, None] + np.arange(S)[None, :]
             data.append(nd.array(
                 np.minimum(pos, self.capacity - 1).astype(np.float32)))
+        if not self.feeds:
+            if fed is not None:
+                raise MXNetError("step(fed=...): this graph has no fed "
+                                 "input; it advances every slot by S")
+            mod.forward(DataBatch(data=data, label=[]), is_train=False)
+            self.pos += S        # the program advances EVERY slot
+            return mod.get_outputs()[0]
+        fed = np.full(self.slots, S, np.int64) if fed is None \
+            else np.asarray(fed, np.int64).reshape(-1)
+        if fed.shape != (self.slots,) or fed.min() < 0 or fed.max() > S:
+            raise MXNetError(f"step() wants ({self.slots},) fed counts in "
+                             f"[0, {S}], got {fed.tolist()}")
+        # the program's own rule, mirrored: no room for S, nothing fed
+        fed = np.where(self.pos + S <= self.capacity, fed, 0)
+        data.append(nd.array(fed.astype(np.int32)))
+        self.last_reads = self._state_reads(fed)
         mod.forward(DataBatch(data=data, label=[]), is_train=False)
-        self.pos += S            # the program advances EVERY slot
+        self.pos += fed
         return mod.get_outputs()[0]
+
+    def _state_reads(self, fed):
+        """What one dispatch that feeds ``fed`` tokens a slot reads and
+        writes of a window-and-summaries state, from the cursors alone
+        (no fetch), summed over the slots that are fed and over the
+        layers: ``[layer executions, exact rows and summaries that each
+        slot's last real query attends, chunks summarised, windows
+        closed]``."""
+        if self.positional:
+            return None
+        W, C = self.window, self.chunk
+        live = fed > 0
+        start, end = self.pos[live], (self.pos + fed)[live]
+        last = end - 1
+        return self.state_layers * np.asarray(
+            [1, np.sum(last % W + 1), np.sum(last // W * (W // C)),
+             np.sum(end // C - start // C),
+             np.sum(end // W - start // W)], np.int64)

@@ -48,13 +48,18 @@ __all__ = ["rms_norm", "moe_route", "moe_sort", "moe_combine"]
 
 
 # ------------------------------------------------------------------ RMSNorm
-def rms_norm(x, gamma, eps):
+def rms_norm(x, gamma, eps, unit_offset=False, cast_to_gain=False):
     """x * rsqrt(mean(x^2) + eps) * gamma over the last axis; the
-    statistics in float32 whatever the input dtype (LayerNorm's rule)."""
+    statistics in float32 whatever the input dtype (LayerNorm's rule).
+    ``unit_offset`` scales by ``1 + gamma`` (a gain stored as its
+    distance from one); ``cast_to_gain`` hands the result over at the
+    gain's dtype, not the input's: a float32 residual stream is
+    normalised into the compute width the matmuls behind it run at."""
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    out = x32 * lax.rsqrt(var + eps) * gamma.astype(jnp.float32)
-    return out.astype(x.dtype)
+    gain = gamma.astype(jnp.float32)
+    out = x32 * lax.rsqrt(var + eps) * (1.0 + gain if unit_offset else gain)
+    return out.astype(gamma.dtype if cast_to_gain else x.dtype)
 
 
 def _rms_infer(attrs, in_shapes):
@@ -65,11 +70,16 @@ def _rms_infer(attrs, in_shapes):
 
 
 @register("RMSNorm", inputs=("data", "gamma"),
-          attr_spec={"eps": (parse_float, 1e-5)}, infer_shape=_rms_infer)
+          attr_spec={"eps": (parse_float, 1e-5),
+                     "unit_offset": (parse_bool, False),
+                     "cast_to_gain": (parse_bool, False)},
+          infer_shape=_rms_infer)
 def _rms_norm_op(attrs, data, gamma):
     """Root-mean-square normalisation over the last axis with a gain
     and no bias (arXiv:1910.07467)."""
-    return rms_norm(data, gamma, parse_float(attrs.get("eps", 1e-5)))
+    return rms_norm(data, gamma, parse_float(attrs.get("eps", 1e-5)),
+                    parse_bool(attrs.get("unit_offset", False)),
+                    parse_bool(attrs.get("cast_to_gain", False)))
 
 
 # ------------------------------------------------------------------- MoEFFN

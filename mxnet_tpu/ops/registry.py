@@ -96,6 +96,20 @@ class OpDef:
         auto-created aux variable (``__dtype__``), and the executor
         binds a cell of that dtype — which also exempts integer aux
         from the mixed-precision entry cast.
+    slot_state : for a slot-pooled decode op, ``{aux name: family}``:
+        which of its aux cells hold per-slot state and of what kind.
+        ``"cursor"`` is the (slots, 1) position every other family is
+        read by; ``"rows"`` is a pool with one row per position
+        (``[slot, :, position]``: a prefix of it can be captured, copied
+        back and reused, and the cursor may be set anywhere below it);
+        any other family (``ops/eva.py``'s ``"window"``, ``"summary"``)
+        is state the cursor alone does not index. The cache driver and
+        ``DecodeEngine.migrate`` find the cells through this, never by
+        their names.
+    donate_aux : the op's aux arrays are large and updated a few rows
+        at a time: the executor donates every aux array of a graph that
+        holds such an op to its inference program, so that they are
+        updated in place and not copied whole.
     """
 
     def __init__(self, name, forward, inputs=("data",), aux=(),
@@ -104,7 +118,7 @@ class OpDef:
                  is_loss=False, mutate_inputs=(), num_visible=None,
                  shape_passthrough=False, variants=None, flops=None,
                  bytes_moved=None, stateful_infer=False, aux_dtypes=None,
-                 doc=""):
+                 slot_state=None, donate_aux=False, doc=""):
         self.name = name
         self.forward = forward
         self.variants = {}
@@ -130,6 +144,8 @@ class OpDef:
         self.mutate_inputs = tuple(mutate_inputs)
         self.stateful_infer = bool(stateful_infer)
         self.aux_dtypes = dict(aux_dtypes or {})
+        self.slot_state = dict(slot_state or {})
+        self.donate_aux = bool(donate_aux)
         self.shape_passthrough = bool(shape_passthrough)
         self.doc = doc
         # arity check up front (it used to happen lazily at the first

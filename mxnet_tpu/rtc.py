@@ -917,6 +917,8 @@ def _register_attention_decode():
                              "k_cache": _cache_dtype_of,
                              "v_cache": _cache_dtype_of},
                  infer_shape=_attention_decode_infer,
+                 slot_state={"k_cache": "rows", "v_cache": "rows",
+                             "cache_pos": "cursor"},
                  attr_spec={"capacity": (int, 256),
                             "rope": (None, False),
                             "rope_base": (float, 10000.0),

@@ -327,7 +327,6 @@ class DecodeHandle:
 
 
 #: aux cells whose leading axis is the slot: what a rung switch carries
-_SLOT_CELLS = ("k_cache", "v_cache", "cache_pos")
 
 
 class DecodeEngine:
@@ -374,8 +373,13 @@ class DecodeEngine:
             else current_context()
         self.pos_embed = "learned" \
             if "pos_ids" in symbol.list_arguments() else "rotary"
+        # a graph whose state advances by the real tokens alone takes
+        # their count a slot beside the tokens (transformer.py,
+        # block="evabyte")
+        self.feeds = "fed" in symbol.list_arguments()
         self.data_names = ("data",) + (
-            ("pos_ids",) if self.pos_embed == "learned" else ())
+            ("pos_ids",) if self.pos_embed == "learned" else ()) + (
+            ("fed",) if self.feeds else ())
         if not any(getattr(n.opdef(), "stateful_infer", False)
                    for n in symbol._topo_nodes() if not n.is_variable):
             raise MXNetError(
@@ -411,13 +415,10 @@ class DecodeEngine:
             [(s, self._provide_data(s), None) for s in self.ladder])
 
         if capacity is None:
-            exe = self._bm._leader._exec_group.executor
-            caches = [cell for nm, cell in exe.aux_dict.items()
-                      if nm.endswith("k_cache")]
-            if not caches:
-                raise MXNetError(f"DecodeEngine({name!r}): no KV-cache "
-                                 "aux state in the bound graph")
-            capacity = caches[0].shape[2]
+            # what the graph's decode ops were built with
+            capacity = next(int(n.attrs["capacity"])
+                            for n in symbol._topo_nodes()
+                            if not n.is_variable and n.opdef().slot_state)
         self.capacity = int(capacity)
 
         from ..models.transformer import BatchedKVCacheDecoder
@@ -471,7 +472,16 @@ class DecodeEngine:
         descs = [DataDesc("data", (slots, step), np.int32)]
         if self.pos_embed == "learned":
             descs.append(DataDesc("pos_ids", (slots, step), np.float32))
+        if self.feeds:
+            descs.append(DataDesc("fed", (slots,), np.int32))
         return descs
+
+    @property
+    def positional(self):
+        """Is the decode state a row per position (the drivers'
+        ``positional``)? Rewinding to an arbitrary position, prefix
+        reuse by row copy and speculative rollback rest on it."""
+        return self._drivers[self.ladder.max].positional
 
     def driver(self, rung):
         """The rung's ``BatchedKVCacheDecoder``."""
@@ -579,7 +589,9 @@ class DecodeEngine:
     # ---------------------------------------------------------- migration
     def migrate(self, src_rung, dst_rung, pairs):
         """Carry live slots between rung pools: for every (src_row,
-        dst_row) pair, the slot's cache rows and cursor copy from the
+        dst_row) pair, the slot's state - every cell of every family
+        the graph's ops declare (K/V rows and cursor; or the open
+        window's rows, the summaries and the cursor) - copies from the
         ``src_rung`` aux arrays into ``dst_rung``'s, and the host
         mirrors follow. Eager per-row gathers/scatters — nothing lands
         in the program cache, so rung switches keep the zero-compile
@@ -587,15 +599,12 @@ class DecodeEngine:
         if src_rung == dst_rung:
             return
         sdrv, ddrv = self._drivers[src_rung], self._drivers[dst_rung]
-        s_exe = self._bm._buckets[src_rung]._exec_group.executor
         d_exe = self._bm._buckets[dst_rung]._exec_group.executor
         ddrv.active[:] = False
         if pairs:
             si = np.asarray([p[0] for p in pairs])
             di = np.asarray([p[1] for p in pairs])
-            for nm, cell in s_exe.aux_dict.items():
-                if not nm.endswith(_SLOT_CELLS):
-                    continue    # not a per-slot pool (MoEFFN's counts)
+            for nm, cell in sdrv.slot_cells():
                 dcell = d_exe.aux_dict[nm]
                 dcell._set(dcell.asjax().at[di].set(cell.asjax()[si]))
             for s_row, d_row in pairs:
@@ -610,6 +619,15 @@ class DecodeEngine:
 #: one, and each execution's busiest expert's assignments
 _MOE_COUNTERS = ("moe.layer_steps", "moe.assignments",
                  "moe.experts_touched", "moe.max_expert_load")
+
+#: ``serve.decode.<name>`` counters of a decoder whose state is a window
+#: of exact rows beside summaries, in the order of
+#: ``BatchedKVCacheDecoder._state_reads``: attention layer executions,
+#: the exact rows and the summaries that each fed slot's last real query
+#: attends (per slot, layer and dispatch), the chunks summarised and the
+#: windows closed (per slot and layer); from the host's cursors, no fetch
+_EVA_COUNTERS = ("eva.layer_steps", "eva.exact_rows", "eva.summary_rows",
+                 "eva.chunks_summarised", "eva.windows_closed")
 
 
 class DecodeScheduler:
@@ -659,6 +677,22 @@ class DecodeScheduler:
                     f"draft cache capacity {self.draft.capacity} < "
                     f"target capacity {engine.capacity}: the draft "
                     "tracks the same stream")
+        if not engine.positional:
+            # the state behind a closed window is summaries: no cursor
+            # move brings its rows back
+            if self.draft is not None:
+                raise MXNetError(
+                    f"decode {engine.name!r}: speculative decoding "
+                    "(draft_engine / spec_k) rolls the cursor back over "
+                    "rejected drafts, and this decoder's state cannot "
+                    "be rewound across a closed window")
+            if prefix_store is not None:
+                raise MXNetError(
+                    f"decode {engine.name!r}: a prefix_store reuses a "
+                    "prompt's cache by copying a row per position, and "
+                    "this decoder's state is a window of exact rows "
+                    "beside summaries (reuse needs a snapshot of the "
+                    "state at a window boundary)")
         chunk = int(prefill_chunk if prefill_chunk is not None
                     else default_prefill_chunk())
         chunk = min(chunk, engine.capacity)
@@ -739,6 +773,9 @@ class DecodeScheduler:
             if self.engine.driver(self._rung).routed:
                 handles.update({k: self._counter(k)
                                 for k in _MOE_COUNTERS})
+            if not self.engine.positional:
+                handles.update({k: self._counter(k)
+                                for k in _EVA_COUNTERS})
             handles.update({k: self._gauge(k) for k in
                             ("active", "occupancy", "queue.depth")})
             handles["step.seconds"] = _telemetry.histogram(
@@ -956,7 +993,7 @@ class DecodeScheduler:
         return "window", 1
 
     def _step_fetch(self, drv, tokens, phases, t=None, last=None,
-                    rows=False):
+                    rows=False, fed=None):
         """One dispatch and what the host samples from, each under its
         own annotation. ``serve.decode.iter.dispatch`` is the launches
         alone: ``drv.step`` (staging and launch) and, where ``last``
@@ -971,13 +1008,21 @@ class DecodeScheduler:
         ``phases`` on the scheduler's clock and the bytes brought to the
         host to ``phases["bytes"]``; ``t`` is the reading that closed
         the previous phase (one read a boundary), None reads it.
+        ``fed`` (a decoder that is fed: the real tokens of each slot)
+        rides in the same put as the tokens; what the dispatch reads of
+        a window-and-summaries state adds up in ``phases["eva"]``.
         Returns ``(ids, logits, end)``: ``logits`` is the selected rows,
         the whole output, or None."""
         now = self._clock.now
         if t is None:
             t = now()
         with _telemetry.span("serve.decode.iter.dispatch"):
-            out = drv.step(tokens)
+            if fed is None:
+                out = drv.step(tokens)
+            else:
+                out = drv.step(tokens, fed=fed)
+                if drv.last_reads is not None:
+                    phases["eva"] = phases.get("eva", 0) + drv.last_reads
             if last is not None:
                 picked, ids = drv.select_rows(out, last)
                 if rows:
@@ -1101,12 +1146,18 @@ class DecodeScheduler:
                 # row itself on the host: a greedy one needs its id only
                 last = np.zeros(self._rung, np.int32)
                 want_rows = False
+                # a fed decoder advances each slot by its real tokens
+                # alone: a row nobody owns is fed nothing
+                fed = np.zeros(self._rung, np.int32) \
+                    if self.engine.feeds else None
                 for row, seq in enumerate(self._slots):
                     if seq is None:
                         continue
                     n = min(S, seq.remaining())
                     tokens[row, :n] = seq.window(n)
                     last[row] = n - 1
+                    if fed is not None:
+                        fed[row] = n
                     if n == seq.remaining() and not seq.sampling.greedy:
                         want_rows = True
                     meta.append((row, seq, n))
@@ -1132,7 +1183,8 @@ class DecodeScheduler:
             end = self._clock.now()
         else:
             ids, picked, end = self._step_fetch(
-                drv, tokens, phases, t=planned, last=last, rows=want_rows)
+                drv, tokens, phases, t=planned, last=last, rows=want_rows,
+                fed=fed)
             if ddrv is not None:
                 # the draft shadows every non-speculative dispatch so
                 # its cache tracks the same stream positions; nobody
@@ -1189,6 +1241,10 @@ class DecodeScheduler:
             if moe is not None:
                 for key, value in zip(_MOE_COUNTERS, moe):
                     m[key].inc(int(value))
+            eva = phases.get("eva")     # a window-and-summaries state's
+            if eva is not None:
+                for key, value in zip(_EVA_COUNTERS, eva):
+                    m[key].inc(int(value))
             m["step.seconds"].observe(step_s)
             m["active"].set(n_active)
             m["occupancy"].set(n_active / self._rung)
@@ -1207,7 +1263,9 @@ class DecodeScheduler:
                 compiles_since_warmup=compiles,
                 **({} if moe is None else
                    {"moe_layer_steps": int(moe[0]),
-                    "moe_touched": int(moe[2])}))
+                    "moe_touched": int(moe[2])}),
+                **({} if eva is None else
+                   {"eva_exact": int(eva[1]), "eva_summary": int(eva[2])}))
         return max(1, emitted)
 
     def _commit_window(self, meta, ids, picked, S, t0, end, shared_sid,
@@ -1249,9 +1307,10 @@ class DecodeScheduler:
                 tok = sample_token(picked[row], seq.sampling, seq.rng)
                 on_host += 1
             seq.fed += n
-            if n < S:
+            if n < S and not self.engine.feeds:
                 # the dispatch advanced the cursor by S; pull
-                # it back to the stream position actually fed
+                # it back to the stream position actually fed (a fed
+                # decoder advanced by n: nothing ran ahead)
                 rew_rows.append(row)
                 rew_pos.append(seq.fed)
             self._capture_prefix(seq, end)
@@ -1538,6 +1597,9 @@ def serve_decoder(symbol, arg_params, name="decoder", capacity=None,
             symbol_gen=draft_symbol_gen, window_lens=window_lens)
     budget = None if prefix_cache_mb is None \
         else int(float(prefix_cache_mb) * (1 << 20))
+    if budget is None and not engine.positional:
+        budget = 0      # no default store for a state it cannot reuse
+        # (one asked for by ``prefix_cache_mb`` the scheduler refuses)
     store = None
     if budget is None or budget > 0:
         store = PrefixStore(budget_bytes=budget)

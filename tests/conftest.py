@@ -56,3 +56,9 @@ def _seed():
     # counter assertions stay deterministic regardless of test order
     # (tests exercising cross-bind reuse re-populate it themselves)
     mx.program_cache.clear()
+    # ``fit(health=...)`` pins the training-health plane's arming
+    # process-wide: a test file that armed it must not decide what the
+    # next file on the same worker records (which files share a worker
+    # changes whenever a test file is added)
+    from mxnet_tpu.telemetry import health
+    health.configure(armed=None)

@@ -99,6 +99,44 @@ def test_kernel_compiles_for_v5e(site, v5e):
             f"{name}: no Mosaic kernel in the compiled {prog.__name__}"
 
 
+@pytest.mark.parametrize("S", [1, 512], ids=["decode", "window"])
+def test_eva_attention_compiles_for_v5e_and_copies_no_pool(S, v5e):
+    """EvaByte's attention at the published sizes (8 slots, 32 heads of
+    128, a window of 2,048 rows, 2,048 summaries, bfloat16): the op's
+    Pallas lowering compiles for the chip, every pool access is a
+    kernel's, and with the aux arrays donated no pool is copied or
+    re-laid - the four pools (512 MB) come back in the buffers they
+    came in."""
+    import re
+    opdef = get_op("eva_attention_decode")
+    attrs = opdef.normalize_attrs({"capacity": 32768, "window": 2048,
+                                   "chunk": 16, "rope_base": 1e5})
+    B, H, d = 8, 32, 128
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    x = sds((B, H, S, d))
+    ins = [x, x, x, sds((B,), jnp.int32), sds((H, d)), sds((H, d))]
+    aux = [sds((B, H, 2048, d))] * 4 + [sds((B, 1), jnp.int32)]
+    fn = opdef.variant_fn("pallas")
+    assert opdef.variant_eligible("pallas", attrs,
+                                  [a.shape for a in ins + aux],
+                                  [str(a.dtype) for a in ins + aux])
+    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
+                       donate_argnums=(1,)).lower(ins, aux).compile()
+    text = compiled.as_text()
+    for kernel in ("eva_summarise", "eva_write",
+                   "eva_attn_decode" if S == 1 else "eva_attn_window"):
+        assert re.search(rf"%{kernel}[.\w]* = .*tpu_custom_call", text), \
+            kernel
+    assert not re.findall(r"= bf16\[8,32,2048,128\]\S* copy\(", text)
+    assert " scatter(" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= 4 * (
+        B * H * 2048 * d * 2)
+
+
 # ------------------------------------------- chip_smoke.py without a chip
 def test_chip_smoke_fails_at_device_check_without_a_chip():
     res = subprocess.run([sys.executable, os.path.join(ROOT,

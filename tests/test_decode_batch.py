@@ -540,7 +540,8 @@ def _fetch_whole_logits(sched):
     from mxnet_tpu.serve.sampling import SamplingParams, sample_token
     greedy = SamplingParams()
 
-    def step_fetch(drv, tokens, phases, t=None, last=None, rows=False):
+    def step_fetch(drv, tokens, phases, t=None, last=None, rows=False,
+                   fed=None):
         logits = drv.step(tokens).asnumpy()
         picked = logits[np.arange(len(last)), last]
         ids = [sample_token(row, greedy, None) for row in picked]
