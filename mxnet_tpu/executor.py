@@ -629,6 +629,16 @@ class Executor:
         dims = () if first is None else first.shape
         return kind + ("_" + "x".join(str(d) for d in dims) if dims else "")
 
+    @property
+    def donates_aux(self):
+        """Does the graph hold an op that asks for its aux arrays to be
+        donated to the inference program (``OpDef.donate_aux``)? Then a
+        compiled ``fwd_infer`` takes over every aux array of the
+        binding: an array read from a cell before a step is deleted by
+        it, and the cell holds the new one."""
+        return any(not n.is_variable and n.opdef().donate_aux
+                   for n in self._symbol._topo_nodes())
+
     def _get_program(self, kind):
         from . import remat as _remat
         naive = naive_engine_active()
@@ -666,9 +676,7 @@ class Executor:
             # a step (OpDef.donate_aux) hands its aux arrays over to the
             # inference program, which updates them in place; an aux the
             # program does not rewrite goes back as it came
-            donate = kind == "fwd_infer" and not naive and any(
-                not n.is_variable and n.opdef().donate_aux
-                for n in self._symbol._topo_nodes())
+            donate = kind == "fwd_infer" and not naive and self.donates_aux
 
             def prog(arg_vals, aux_vals, rng):
                 outs, new_aux = runner(arg_vals, aux_vals, is_train, rng)

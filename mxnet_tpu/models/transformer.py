@@ -642,7 +642,11 @@ class BatchedKVCacheDecoder:
     number of rows moved, so it compiles once per driver — at engine
     warm-up — and no cursor move compiles afterwards. ``name`` is the
     served model's label: a named driver counts its launches and the
-    rows they moved in ``serve.decode.cursor.updates`` / ``.rows``.
+    rows they moved in ``serve.decode.cursor.updates`` / ``.rows``, and
+    at every ``step`` the bytes of state that the step program takes
+    over and updates in place (``donated_bytes``: every aux array of a
+    graph whose ops ask for it, ``OpDef.donate_aux``) in
+    ``serve.decode.state.donated_bytes``.
 
     ``step`` hands back the whole ``(slots, S, V)`` logits as they lie
     on the device. A caller that samples one row a slot launches
@@ -693,6 +697,11 @@ class BatchedKVCacheDecoder:
         exe = module._exec_group.executor
         self._moe_cells = [cell for nm, cell in exe.aux_dict.items()
                            if nm.endswith("moe_stats")]
+        # what every step program takes over and updates in place (the
+        # window modules share these cells): 0 = the graph donates none
+        self.donated_bytes = sum(
+            cell.size * cell.dtype.itemsize
+            for cell in exe.aux_arrays) if exe.donates_aux else 0
         # the per-slot state, by family, as the graph's ops declare it
         self._state = slot_state(module.symbol)
         self.positional = set(self._state) <= {"cursor", "rows"}
@@ -973,6 +982,10 @@ class BatchedKVCacheDecoder:
             pos = self.pos[:, None] + np.arange(S)[None, :]
             data.append(nd.array(
                 np.minimum(pos, self.capacity - 1).astype(np.float32)))
+        if self.name is not None:
+            from .. import telemetry
+            telemetry.counter("serve.decode.state.donated_bytes",
+                              model=self.name).inc(self.donated_bytes)
         if not self.feeds:
             if fed is not None:
                 raise MXNetError("step(fed=...): this graph has no fed "

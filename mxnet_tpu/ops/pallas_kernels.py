@@ -1118,6 +1118,101 @@ def decode_attention(q, k_cache, v_cache, pos, block_k=128):
     return out.reshape(B, H, S, Dh)
 
 
+#: bytes of double-buffered blocks ``cache_write`` may keep in VMEM
+#: (under the 16 MiB a kernel gets with no limit of its own)
+_WRITE_BLOCK_BUDGET = 8 << 20
+
+
+def _cache_write_kernel(hb, S, bt, n_blocks, capacity, n_pools):
+    """Grid (slot, head group, target block): block ``cursor // bt + j``
+    of each pool comes in, the slot's new rows are laid at their
+    positions by a one-hot matrix product (exact in any dtype, and no
+    store is ever unaligned), and the block goes back where it came
+    from. A block index past the last wraps to a block whose positions
+    match no new row, and goes back as it came."""
+    def kernel(p_ref, *refs):
+        news, olds = refs[:n_pools], refs[n_pools:2 * n_pools]
+        outs = refs[2 * n_pools:]
+        b, j = pl.program_id(0), pl.program_id(2)
+        p = p_ref[b]
+        block = (p // bt + j) % n_blocks
+        at = block * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, S), 0)
+        select = (at - p == jax.lax.broadcasted_iota(
+            jnp.int32, (bt, S), 1)) & (p + S <= capacity)
+        hit = jnp.sum(select.astype(jnp.int32), axis=-1, keepdims=True) > 0
+        # an fp8 row is exact in bfloat16, a bfloat16 one as it is
+        wide = jnp.float32 if outs[0].dtype == jnp.float32 else jnp.bfloat16
+        exact = jax.lax.Precision.HIGHEST if wide == jnp.float32 else None
+        sel = select.astype(wide)
+
+        def head(h, carry):
+            for new, old, out in zip(news, olds, outs):
+                placed = jnp.dot(sel, new[h].astype(wide), precision=exact,
+                                 preferred_element_type=jnp.float32)
+                out[h] = jnp.where(hit, placed.astype(out.dtype), old[h])
+            return carry
+        # a loop, not hb copies of the body: what a step program spends
+        # lowering its kernels is set-up time
+        jax.lax.fori_loop(0, hb, head, 0)
+    return kernel
+
+
+def cache_write(news, pools, pos):
+    """Each slot's S new rows into its own ``[b, :, cursor:cursor + S]``
+    of every pool, in place: ``news`` are (B, H, S, Dh) arrays at the
+    pools' dtype, ``pools`` the matching (B, H, C, Dh) caches (K and V
+    of one layer ride one launch), ``pos`` the (B,) cursors. Only the
+    aligned blocks that hold those rows move (one of 32 rows at S=1,
+    two of 128 for a 64-row window), through ``input_output_aliases``:
+    with the pools donated to the step program nothing else of them is
+    read or written. A slot whose S rows do not fit below the capacity
+    writes nothing - ``rtc._write_rows``' rule, which this is the
+    kernel of. Returns the pools.
+
+    The call is a jitted function of its own, so that a step program
+    traces and lowers the kernel once and calls it from every layer:
+    lowered layer by layer, 24 layers of six programs spent a minute
+    of set-up on it."""
+    return list(_cache_write(pos.astype(jnp.int32), tuple(news),
+                             tuple(pools), interpret=_interpret()))
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _cache_write(pos, news, pools, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, S, Dh = news[0].shape
+    C = pools[0].shape[2]
+    n = len(pools)
+    bt = _divisor_block(C, 32 if S <= 32 else 128)
+    n_blocks = C // bt
+    per_head = n * Dh * pools[0].dtype.itemsize * (2 * S + 4 * bt)
+    hb = _divisor_block(H, max(1, _WRITE_BLOCK_BUDGET // per_head))
+    spanned = 1 if S == 1 else min(n_blocks, (S - 1) // bt + 2)
+
+    def _rows_map(b, g, j, pos_ref):
+        return (b, g, 0, 0)
+
+    def _block_map(b, g, j, pos_ref):
+        return (b, g, (pos_ref[b] // bt + j) % n_blocks, 0)
+
+    rows = pl.BlockSpec((None, hb, S, Dh), _rows_map)
+    block = pl.BlockSpec((None, hb, bt, Dh), _block_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(B, H // hb, spanned),
+        in_specs=[rows] * n + [block] * n, out_specs=tuple([block] * n))
+    kwargs = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))}
+    return pallas_call(
+        _cache_write_kernel(hb, S, bt, n_blocks, C, n),
+        out_shape=tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
+                        for p in pools),
+        grid_spec=grid_spec, name="cache_write", interpret=interpret,
+        input_output_aliases={1 + n + i: i for i in range(n)},
+        **kwargs)(pos, *news, *pools)
+
+
 # ==========================================================================
 # grouped expert feed-forward (the MoEFFN pallas variant - ops/moe.py owns
 # the op, the router, the sort and the weighted combine; the kernels here

@@ -108,8 +108,12 @@ class OpDef:
         their names.
     donate_aux : the op's aux arrays are large and updated a few rows
         at a time: the executor donates every aux array of a graph that
-        holds such an op to its inference program, so that they are
-        updated in place and not copied whole.
+        holds such an op to its inference program (``Executor
+        .donates_aux``), so that they are updated in place and not
+        copied whole. Both decode ops set it (``attention_decode``,
+        ``eva_attention_decode``). The contract it puts on the host: an
+        array read from an aux cell is deleted by the next step, so
+        whatever touches the state between steps reads the cell anew.
     """
 
     def __init__(self, name, forward, inputs=("data",), aux=(),

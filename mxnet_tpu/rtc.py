@@ -629,15 +629,19 @@ def _register_attention():
 #   sequence position (the single-session KVCacheDecoder path);
 # * ``per_slot=True`` — a (B, 1) int32 cursor VECTOR: each batch row is
 #   an independent decode *slot* at its own position in its own slice
-#   of the slot-pooled (B, H, C, Dh) cache. S=1 writes land per slot
-#   through a one-hot select (bit-exact: untouched positions keep their
-#   cache value verbatim); S>1 windows (chunked prefill, speculative
-#   verify) land through a per-row dynamic_update_slice. The causal
-#   mask is per slot AND per window offset
+#   of the slot-pooled (B, H, C, Dh) cache. Every write, the S=1 step's
+#   and the S>1 windows' (chunked prefill, speculative verify), is the
+#   same per-slot window update (``_write_rows``): slot b's S rows land
+#   at its own cursor inside its own ``[b]``, untouched positions keep
+#   their bytes, and a slot with no room for S rows writes nothing. The
+#   op donates its aux arrays to the step program (``donate_aux``), so
+#   the pools are updated in place: a step moves the rows it writes,
+#   not the pool. The causal mask is per slot AND per window offset
 #   (key_pos <= cursor[b] + s), and the softmax runs over each slot's
 #   own prefix — so ONE pinned program advances B independent staggered
-#   sequences by S tokens per dispatch. A retired slot keeps advancing harmlessly
-#   (its row is garbage nobody reads); rejoining resets only the
+#   sequences by S tokens per dispatch. A retired slot keeps advancing
+#   harmlessly (its row is garbage nobody reads, and past the capacity
+#   it writes nothing); rejoining resets only the
 #   cursor, because positions beyond a slot's prefix are exp(-inf)-
 #   masked to exactly zero weight and every attended position has been
 #   rewritten by the new sequence before its first read — slot reuse is
@@ -645,9 +649,10 @@ def _register_attention():
 # --------------------------------------------------------------------------
 def _decode_check_overflow(pos, S, capacity, per_slot):
     """Overflow raises cleanly whenever the cursor is concrete (eager
-    dispatch); jitted paths enforce it host-side via the decode driver
-    (models.transformer.KVCacheDecoder) — dynamic_update_slice would
-    otherwise silently clamp the write."""
+    dispatch); jitted paths enforce it host-side via the decode drivers
+    (models.transformer) for every live sequence. Inside a program the
+    scalar layout's dynamic_update_slice would clamp the write; the
+    per-slot write drops it (``_write_rows``)."""
     if isinstance(pos, jax.core.Tracer):
         return
     if per_slot:
@@ -665,17 +670,47 @@ def _decode_check_overflow(pos, S, capacity, per_slot):
             "capacity= or reset the cache")
 
 
-def _decode_rope_write(attrs, q, k, v, k_cache, v_cache, pos, per_slot):
-    """RoPE + cache write, shared verbatim by the XLA composition and
-    the Pallas decode variant (the kernel only replaces the attention
-    read) — so the write semantics stay bit-identical across tiers.
-    ``pos`` is a scalar (single-session) or a (B,) vector (slot pool).
-    Returns the rotated q and the updated caches."""
+def _write_rows(news, pools, pos):
+    """The per-slot cache write, for every S: land each slot's S new
+    rows ``news[i][b]`` at its own cursor ``pos[b]`` inside its own
+    ``pools[i][b]``, one window update a slot, and return the pools (a
+    layer's K and V ride one call). The pools are donated to the step
+    program (``donate_aux``), so the updates are in place and a step
+    moves S rows a slot, not the pool. A slot only ever writes its own
+    ``[b, :, :, :]``; a slot whose S rows do not fit below the capacity
+    writes nothing (``FILL_OR_DROP``: a ``dynamic_update_slice`` would
+    clamp the write onto live rows).
+
+    This is the composition's lowering. For the TPU the compiler turns
+    it into a loop of one ``dynamic-update-slice`` a slot (it re-lays no
+    pool, which it does for a gather of the old rows): in place, but 48
+    such loops cost a 24-layer S=1 step 2.1 ms on a v5e. The Pallas
+    variant lands the same rows by the same rule through one kernel a
+    layer (``pallas_kernels.cache_write``, 0.4 ms for those 24)."""
+    at = jnp.stack([jnp.arange(pos.shape[0], dtype=jnp.int32),
+                    pos.astype(jnp.int32)], axis=1)         # (B, 2)
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2, 3), inserted_window_dims=(0,),
+        scatter_dims_to_operand_dims=(0, 2))
+    return [jax.lax.scatter(pool, at, new, dnums, indices_are_sorted=True,
+                            unique_indices=True,
+                            mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+            for new, pool in zip(news, pools)]
+
+
+def _decode_rope_write(attrs, q, k, v, k_cache, v_cache, pos, per_slot,
+                       write=None):
+    """RoPE + cache write, shared by the XLA composition and the Pallas
+    decode variant, so the cache contents stay bit-identical across
+    tiers. ``pos`` is a scalar (single-session) or a (B,) vector (slot
+    pool); ``write`` is the tier's lowering of the per-slot write
+    (None: ``_write_rows``, the composition's; the Pallas variant hands
+    in its kernel of the same signature). Returns the rotated q and
+    the updated caches."""
     from .base import parse_bool, parse_float
     from .ops.nn import rope_apply
 
-    B, H, S, Dh = q.shape
-    capacity = k_cache.shape[2]
+    S = q.shape[2]
     if parse_bool(attrs.get("rope", False)):
         base = parse_float(attrs.get("rope_base", 10000.0))
         if per_slot:
@@ -684,34 +719,13 @@ def _decode_rope_write(attrs, q, k, v, k_cache, v_cache, pos, per_slot):
             positions = pos + jnp.arange(S)
         q = rope_apply(q, positions, base)
         k = rope_apply(k, positions, base)
-    if not per_slot:
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, 0, pos, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, 0, pos, 0))
-    elif S == 1:
-        # one-hot per-slot write: jnp.where keeps untouched cache
-        # positions bit-identical and lands each slot's token at its own
-        # cursor; a cursor past capacity matches nothing (no clamped
-        # write). Kept verbatim for S=1 so the steady-state decode
-        # program stays bit-identical to the pre-window pin.
-        key_pos = jnp.arange(capacity)                         # (C,)
-        write = (key_pos[None, :] == pos[:, None])[:, None, :, None]
-        k_cache = jnp.where(write, k.astype(k_cache.dtype), k_cache)
-        v_cache = jnp.where(write, v.astype(v_cache.dtype), v_cache)
+    k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+    if per_slot:
+        k_cache, v_cache = (write or _write_rows)(
+            [k, v], [k_cache, v_cache], pos)
     else:
-        # window write: each slot lands its S rows at its own cursor.
-        # vmap over B means a slot only ever writes its OWN cache row,
-        # so the clamp DUS applies near capacity can't corrupt a
-        # batchmate — the driver guards pos + S <= capacity for every
-        # slot that is still live.
-        def _write_row(cache_row, new_row, p):
-            return jax.lax.dynamic_update_slice(cache_row, new_row,
-                                                (0, p, 0))
-        k_cache = jax.vmap(_write_row)(k_cache,
-                                       k.astype(k_cache.dtype), pos)
-        v_cache = jax.vmap(_write_row)(v_cache,
-                                       v.astype(v_cache.dtype), pos)
+        k_cache = jax.lax.dynamic_update_slice(k_cache, k, (0, 0, pos, 0))
+        v_cache = jax.lax.dynamic_update_slice(v_cache, v, (0, 0, pos, 0))
     return q, k_cache, v_cache
 
 
@@ -754,12 +768,11 @@ def _attention_decode_fwd(attrs, inputs, aux, is_train, rng):
 
 def _attention_decode_per_slot(attrs, q, k, v, k_cache, v_cache, cursor):
     """The slot-pooled lowering: cursor (B, 1), an S-token window per
-    slot. S=1 is the steady-state decode program (one-hot cache write,
-    bit-pinned since the slot pool landed); S>1 is the chunked-prefill /
-    speculative-verify window — each slot writes its S tokens at its OWN
-    cursor via a per-row ``dynamic_update_slice`` and the causal mask
-    runs over ``cursor[b] + arange(S)``, so one pinned program advances
-    B staggered sequences by S positions per dispatch."""
+    slot. S=1 is the steady-state decode program, S>1 the chunked-
+    prefill / speculative-verify window; in both each slot writes its S
+    tokens at its OWN cursor (``_write_rows``) and the causal mask runs
+    over ``cursor[b] + arange(S)``, so one pinned program advances B
+    staggered sequences by S positions per dispatch."""
     B, H, S, Dh = q.shape
     capacity = k_cache.shape[2]
     pos = cursor.reshape((B,)).astype(jnp.int32)          # (B,)
@@ -787,14 +800,17 @@ def _attention_decode_per_slot(attrs, q, k, v, k_cache, v_cache, cursor):
 
 
 def _attention_decode_pallas_variant(attrs, inputs, aux, is_train, rng):
-    """Flash-decode lowering: RoPE + cache writes stay the exact shared
-    XLA helpers (bit-identical cache contents across tiers); only the
-    attention READ — the cache-bandwidth-bound part — runs the Pallas
-    kernel (ops/pallas_kernels.decode_attention), whose scalar-prefetched
-    cursor bounds the K/V blocks actually fetched from HBM to the live
-    prefix ``[0, cursor_b + S)`` instead of the full capacity."""
+    """Flash-decode lowering. RoPE is the shared XLA helper; the
+    per-slot cache write is ``pallas_kernels.cache_write`` (the rows
+    and the rule of ``_write_rows``, so the cache contents are
+    bit-identical across tiers; only the aligned blocks that hold the
+    new rows move); the attention READ — the cache-bandwidth-bound
+    part — is ``pallas_kernels.decode_attention``, whose
+    scalar-prefetched cursor bounds the K/V blocks actually fetched
+    from HBM to the live prefix ``[0, cursor_b + S)`` instead of the
+    full capacity."""
     from .base import parse_bool
-    from .ops.pallas_kernels import decode_attention
+    from .ops.pallas_kernels import cache_write, decode_attention
 
     q, k, v = inputs
     k_cache, v_cache, cursor = aux
@@ -813,7 +829,8 @@ def _attention_decode_pallas_variant(attrs, inputs, aux, is_train, rng):
     _decode_check_overflow(pos, S, capacity, per_slot=per_slot)
     q, k_cache, v_cache = _decode_rope_write(attrs, q, k, v, k_cache,
                                              v_cache, pos,
-                                             per_slot=per_slot)
+                                             per_slot=per_slot,
+                                             write=cache_write)
     # the kernel is row-cursor uniform: the scalar layout is the
     # per-slot layout with every row at the same position
     pos_rows = pos if per_slot else jnp.broadcast_to(pos, (B,))
@@ -875,6 +892,8 @@ _ATTENTION_DECODE_KSPEC = {
 #: bounds (S<=64, Dh<=512, 128-row cache blocks): q + one K + one V
 #: block + the f32 m/l/acc scratch + the out window. fp8 cache dtypes
 #: are in the gate set — the kernel dequantizes storage rows on read.
+#: (The write kernel before it sizes its own blocks, aligned to every
+#: dtype's sublanes, under ``pallas_kernels._WRITE_BLOCK_BUDGET``.)
 _ATTENTION_DECODE_PALLAS_KSPEC = {
     "tiles": [((64, 512), "float32"),      # q window
               ((128, 512), "float32"),     # k_cache block
@@ -912,7 +931,7 @@ def _register_attention_decode():
     _register_op("attention_decode", inputs=("q", "k", "v"),
                  aux=("k_cache", "v_cache", "cache_pos"),
                  full=_attention_decode_fwd,
-                 stateful_infer=True,
+                 stateful_infer=True, donate_aux=True,
                  aux_dtypes={"cache_pos": "int32",
                              "k_cache": _cache_dtype_of,
                              "v_cache": _cache_dtype_of},

@@ -65,7 +65,7 @@ Telemetry (always on, docs/serving.md has the catalog):
 ``serve.decode.slots``/``active``/``occupancy``/``queue.depth`` gauges,
 ``serve.decode.iterations``/``tokens``/``joins``/``leaves``/
 ``migrations``/``requests``/``responses``/``errors``/``fetch.bytes``/
-``sample.device``/``sample.host`` counters,
+``sample.device``/``sample.host``/``state.donated_bytes`` counters,
 ``serve.decode.step.seconds`` + ``serve.decode.request.latency.seconds``
 histograms, and one flight-ring record per iteration.
 """
@@ -515,8 +515,9 @@ class DecodeEngine:
             self.exec_est[rung] = max(0.0, clock.now() - t0)
             for S in drv.window_lens:
                 wz = np.zeros((rung, S), np.int32)
-                # rewind first so even tiny caches never see the
-                # clamped dynamic_update_slice path during warmup
+                # rewind first so that even a tiny cache has room for
+                # the window: warm-up runs the write steady state runs
+                # (a slot with no room for S rows writes nothing)
                 drv.rewind_many(list(range(rung)), [0] * rung)
                 step_ids(wz)                     # trace + compile
                 drv.rewind_many(list(range(rung)), [0] * rung)
@@ -1213,8 +1214,9 @@ class DecodeScheduler:
             committed = self._clock.now()
             with span("serve.decode.iter.rewind"):
                 # retired rows keep advancing one window per dispatch;
-                # pull any nearing capacity back to 0 so no dispatch
-                # ever sees a clamped window write for a row nobody owns
+                # pull any nearing capacity back to 0, so that a row
+                # nobody owns stays inside its pool (past the capacity
+                # its write is dropped, its cursor would grow for ever)
                 maxw = max([1] + list(drv.window_lens))
                 seen = set(rew_rows)
                 for row in range(self._rung):

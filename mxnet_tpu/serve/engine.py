@@ -9,10 +9,14 @@ padded) batch and returns the output NDArrays:
   ``for_training=False`` over a ``shared_module`` leader, so all rungs
   alias ONE set of parameter cells and each rung's forward program
   lands in the process-wide program cache under the normal executor
-  keys. The inference forward path never donates buffers (the
-  ``fwd_infer`` program is a plain jit with no ``donate_argnums``), so
-  a batch assembled from caller arrays is never invalidated by
-  dispatch — the donation-safe batched forward.
+  keys. The inference forward path never donates an input (arguments
+  are never in ``donate_argnums``), so a batch assembled from caller
+  arrays is never invalidated by dispatch — the donation-safe batched
+  forward. What a ``fwd_infer`` program may take over is the graph's
+  own aux state, and only where an op asks for it
+  (``OpDef.donate_aux``: the decode ops' cache pools, served by
+  ``serve/decode.py``); a graph without such an op, which is what this
+  engine serves, donates nothing.
 * ``PredictorEngine`` — an exported ``.mxp`` artifact served directly
   (predict.py): the ladder is the artifact's fixed exported batch size
   (re-export to change it) and the program is the deserialized

@@ -137,6 +137,45 @@ def test_eva_attention_compiles_for_v5e_and_copies_no_pool(S, v5e):
         B * H * 2048 * d * 2)
 
 
+@pytest.mark.parametrize("S", [1, 64], ids=["decode", "window"])
+@pytest.mark.parametrize("capacity", [2048, 4096],
+                         ids=["cerebras", "olmoe"])
+def test_attention_decode_compiles_for_v5e_and_writes_in_place(capacity, S,
+                                                               v5e):
+    """``attention_decode`` at the two serving configurations' shapes (8
+    slots, 16 heads of 128, bfloat16; capacity 2,048 with learned
+    positions, 4,096 with RoPE): with the aux arrays donated the cache
+    write moves S rows a slot and nothing else - no pool is copied,
+    re-laid for a scatter or rebuilt by a fusion, both pools come back
+    in the buffers they came in, and the read is still the kernel."""
+    import re
+    opdef = get_op("attention_decode")
+    attrs = opdef.normalize_attrs({"capacity": capacity, "per_slot": True,
+                                   "rope": capacity == 4096})
+    B, H, d = 8, 16, 128
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ins = [sds((B, H, S, d))] * 3
+    aux = [sds((B, H, capacity, d))] * 2 + [sds((B, 1), jnp.int32)]
+    fn = opdef.variant_fn("pallas")
+    assert opdef.donate_aux
+    assert opdef.variant_eligible("pallas", attrs,
+                                  [a.shape for a in ins + aux],
+                                  [str(a.dtype) for a in ins + aux])
+    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
+                       donate_argnums=(1,)).lower(ins, aux).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    pool = rf"= bf16\[{B},{H},{capacity},{d}\]\S* "
+    assert not re.findall(pool + r"copy\(", text)
+    assert not re.findall(pool + r"fusion\(", text)
+    assert " scatter(" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * (
+        B * H * capacity * d * 2)
+
+
 # ------------------------------------------- chip_smoke.py without a chip
 def test_chip_smoke_fails_at_device_check_without_a_chip():
     res = subprocess.run([sys.executable, os.path.join(ROOT,

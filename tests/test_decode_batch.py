@@ -1,8 +1,8 @@
 """Continuous decode batching (ISSUE 15, ROADMAP 3b).
 
 Pins the tentpole end to end: the per-slot ``attention_decode``
-lowering ((B, 1) cursor vector, per-slot masked softmax, one-hot slot
-writes), the ``BatchedKVCacheDecoder`` driver (staggered sequences
+lowering ((B, 1) cursor vector, per-slot masked softmax, per-slot
+writes in place), the ``BatchedKVCacheDecoder`` driver (staggered sequences
 reproduce independent ``KVCacheDecoder`` runs, bit-clean slot reuse,
 host-side per-slot overflow), the ``DecodeScheduler`` (FakeClock-
 deterministic staggered arrivals/finishes, streaming delivery,
@@ -180,6 +180,102 @@ def test_per_slot_eager_overflow_names_slots():
     with pytest.raises(mx.base.MXNetError, match=r"slot\(s\) \[0, 2\]"):
         op.forward({"capacity": 4, "per_slot": True}, [q, q, q],
                    [cache, cache, cur], False, None)
+
+
+def _one_hot_write(news, pools, pos):
+    """The per-slot write as it was until PR 33: ``jnp.where`` over the
+    whole pool, one selection a window row; a row at or past the
+    capacity matches nothing."""
+    key_pos = jnp.arange(pools[0].shape[2])
+    out = []
+    for new, cache in zip(news, pools):
+        for s in range(new.shape[2]):
+            write = (key_pos[None, :] == pos[:, None] + s)[:, None, :, None]
+            cache = jnp.where(write, new[:, :, s:s + 1], cache)
+        out.append(cache)
+    return out
+
+
+def _decode_op_case(S, cache_dtype, seed=7):
+    rs = np.random.RandomState(seed)
+    B, Hh, Dh, C = 4, 2, 8, 16
+    qkv = [jnp.asarray(rs.randn(B, Hh, S, Dh).astype(np.float32))
+           for _ in range(3)]
+    pools = [jnp.asarray(rs.randn(B, Hh, C, Dh).astype(np.float32))
+             .astype(cache_dtype) for _ in range(2)]
+    attrs = {"capacity": C, "per_slot": True, "rope": True}
+    if cache_dtype != "float32":
+        attrs["cache_dtype"] = cache_dtype
+    return get_op("attention_decode").normalize_attrs(attrs), qkv, pools, C
+
+
+@pytest.mark.parametrize("variant", ["xla", "pallas"])
+@pytest.mark.parametrize("cache_dtype", ["float32", "float8_e4m3fn"])
+@pytest.mark.parametrize("S", [1, 4])
+def test_in_place_write_equals_the_one_hot_write(S, cache_dtype, variant,
+                                                 monkeypatch):
+    """ISSUE 33: one per-slot write for every S. At ragged cursors it
+    leaves the pools and the outputs bit-equal to the one-hot
+    ``jnp.where`` rule it replaced, at the compute width and with an
+    fp8 ``cache_dtype``, in the composition and in the kernel's
+    variant."""
+    from mxnet_tpu import rtc
+    from mxnet_tpu.ops import pallas_kernels
+    op = get_op("attention_decode")
+    attrs, qkv, pools, C = _decode_op_case(S, cache_dtype)
+    cur = jnp.asarray([[0], [5], [C - S], [9]], jnp.int32)
+    fn = op.forward if variant == "xla" else op.variant_fn("pallas")
+    run = lambda: jax.jit(                                   # noqa: E731
+        lambda r, a: fn(attrs, r, a, False, None))(qkv, pools + [cur])
+    outs, (k2, v2, cur2) = run()
+    # the same op over the old rule, in place of either tier's write
+    monkeypatch.setattr(rtc, "_write_rows", _one_hot_write)
+    monkeypatch.setattr(pallas_kernels, "cache_write", _one_hot_write)
+    want_outs, (wk, wv, wcur) = run()
+    assert k2.dtype == wk.dtype == jnp.dtype(cache_dtype)
+    for got, want in ((outs[0], want_outs[0]), (k2, wk), (v2, wv),
+                      (cur2, wcur)):
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(want.astype(jnp.float32)))
+    assert np.array_equal(np.asarray(cur2)[:, 0],
+                          np.asarray(cur)[:, 0] + S)
+    for b, p in enumerate(np.asarray(cur)[:, 0]):   # and it did write
+        assert not np.array_equal(
+            np.asarray(k2[b, :, p:p + S].astype(jnp.float32)),
+            np.asarray(pools[0][b, :, p:p + S].astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("variant", ["xla", "pallas"])
+@pytest.mark.parametrize("S", [1, 4])
+def test_a_cursor_at_or_past_capacity_writes_nothing(S, variant):
+    """A slot whose S rows end one past the capacity (at S=1: a cursor
+    at the capacity) and a slot one past it (a free slot riding along
+    keeps advancing) leave every row of every slot as it was: nothing is
+    clamped onto a live row. The slots beside them, one of them a free
+    slot at a position of its own, write their own S rows and no
+    other."""
+    op = get_op("attention_decode")
+    attrs, qkv, pools, C = _decode_op_case(S, "float32", seed=11)
+    cur = np.asarray([[2], [C - S + 1], [C + 1], [C - S]], np.int32)
+    fn = op.forward if variant == "xla" else op.variant_fn("pallas")
+    _outs, (k2, v2, cur2) = jax.jit(
+        lambda r, a: fn(attrs, r, a, False, None))(
+            qkv, pools + [jnp.asarray(cur)])
+    assert np.array_equal(np.asarray(cur2), cur + S)
+    for new, old in ((k2, pools[0]), (v2, pools[1])):
+        new, old = np.asarray(new), np.asarray(old)
+        for b in (1, 2):
+            np.testing.assert_array_equal(new[b], old[b])
+        for b in (0, 3):
+            p = cur[b, 0]
+            keep = np.ones(C, bool)
+            keep[p:p + S] = False
+            np.testing.assert_array_equal(new[b][:, keep], old[b][:, keep])
+            assert not np.array_equal(new[b][:, ~keep], old[b][:, ~keep])
+    if S == 1:                  # where the one-hot rule says the same
+        want, = _one_hot_write([qkv[2]], [pools[1]], jnp.asarray(cur[:, 0]))
+        np.testing.assert_array_equal(np.asarray(v2), np.asarray(want))
 
 
 def test_rope_per_batch_positions():

@@ -563,3 +563,189 @@ def test_attention_decode_window_kernel_spec():
                tiles=[((64, 100), "float32")])      # lanes % 128 != 0
     with pytest.raises(mx.base.MXNetError, match="PK902"):
         validate_kernel_spec("attention_decode", "window", bad)
+
+
+# ======================== the step programs take over the pools (PR 33)
+def _lively(params, seed):
+    """``params`` with every matrix redrawn (N(0, 0.5), the embedding
+    N(0, 0.1)): the Xavier set of this file decodes to one token
+    whatever the cache holds, this one to a varied sequence that a
+    wrong cache row changes."""
+    rs = np.random.RandomState(seed)
+    return {k: rs.randn(*v.shape).astype(np.float32)
+            * (0.1 if "embed" in k else 0.5) if v.ndim == 2 else v
+            for k, v in params.items()}
+
+
+@pytest.fixture(scope="module")
+def lively_params(target_params):
+    return _lively(target_params, 4)
+
+
+def _engine33(target_params, ladder=(2, 4)):
+    _names[0] += 1
+    return mx.serve.DecodeEngine(
+        f"donate{_names[0]}", _gen()(1), _nd(target_params), capacity=T,
+        ladder=list(ladder), symbol_gen=_gen(), window_lens=(4,))
+
+
+def _greedy(drv, slot, cur, n):
+    """``n`` greedy tokens of ``slot`` from ``cur``, the token to feed
+    next, by S=1 steps (the other slots ride along with token 0)."""
+    out = []
+    for _ in range(n):
+        feed = np.zeros((drv.slots, 1), np.int32)
+        feed[slot, 0] = cur
+        cur = int(np.argmax(drv.step(feed).asnumpy()[slot, 0]))
+        out.append(cur)
+    return out
+
+
+def _prefill(drv, slot, prompt):
+    """Join ``slot`` and feed ``prompt[:-1]`` by S=1 steps."""
+    drv.join(slot)
+    for t in prompt[:-1]:
+        feed = np.zeros((drv.slots, 1), np.int32)
+        feed[slot, 0] = t
+        drv.step(feed)
+
+
+def _pool_arrays(drv):
+    return [cell.asjax() for _nm, cell in drv.slot_cells()]
+
+
+def _rewind_after_junk(target_params, prompt, n):
+    drv = _engine33(target_params).driver(2)
+    _prefill(drv, 0, prompt)
+    held = _pool_arrays(drv)
+    for junk in (9, 17, 4):                      # a rejected tail
+        drv.step(np.full((2, 1), junk, np.int32))
+    assert all(a.is_deleted() for a in held)
+    drv.rewind(0, len(prompt) - 1)
+    return _greedy(drv, 0, prompt[-1], n)
+
+
+def _rewind_many_after_a_padded_window(target_params, prompt, n):
+    drv = _engine33(target_params).driver(2)
+    other = [5, 6, 7]
+    drv.join(0), drv.join(1)
+    for start in range(0, len(prompt) - 1, 4):   # windows of 4, padded
+        win = np.zeros((2, 4), np.int32)
+        for row, toks in ((0, prompt[:-1]), (1, other)):
+            part = toks[start:start + 4]
+            win[row, :len(part)] = part
+        held = _pool_arrays(drv)
+        drv.step(win)
+        assert all(a.is_deleted() for a in held)
+        drv.rewind_many([0, 1], [min(start + 4, len(prompt) - 1),
+                                 min(start + 4, len(other))])
+    return _greedy(drv, 0, prompt[-1], n)
+
+
+def _restore_rows_into_another_slot(target_params, prompt, n):
+    drv = _engine33(target_params).driver(2)
+    _prefill(drv, 0, prompt)
+    rows = drv.capture_rows(0, len(prompt) - 1)
+    drv.step(np.zeros((2, 1), np.int32))         # the pools move on
+    drv.leave(0)
+    drv.join(1)
+    drv.restore_rows(1, rows)
+    drv.rewind(1, len(prompt) - 1)
+    return _greedy(drv, 1, prompt[-1], n)
+
+
+def _prefix_capture_and_hit(target_params, prompt, n):
+    sched = _sched(target_params, ladder=(1, 2), chunk=4, prefix_mb=4)
+    cold = sched.submit(prompt, max_new_tokens=n, prefix_id="sys")
+    sched.pump()
+    warm = sched.submit(prompt, max_new_tokens=n, prefix_id="sys")
+    sched.pump()
+    assert sched.prefix_store.hits >= 1
+    assert list(cold.result(timeout=5)) == list(warm.result(timeout=5))
+    return list(warm.result(timeout=5))
+
+
+def _rung_migration(target_params, prompt, n):
+    sched = _sched(target_params, ladder=(1, 2, 4), chunk=4)
+    first = sched.submit(prompt, max_new_tokens=n)
+    sched.pump(max_iterations=4)                 # decoding on rung 1
+    rest = [sched.submit(p, max_new_tokens=3) for p in _prompts(33, 3)]
+    sched.pump()
+    for h in rest:
+        h.result(timeout=5)
+    assert sched.stats()["migrations"] >= 1
+    return list(first.result(timeout=5))
+
+
+def _speculative_rollback(target_params, prompt, n):
+    sched = _sched(target_params, ladder=(1, 2), chunk=4,
+                   draft=_lively(_train_params(D, 1, seed=1), 12),
+                   spec_k=3)
+    h = sched.submit(prompt, max_new_tokens=n)
+    sched.pump()
+    st = sched.stats()["spec"]
+    assert st["proposed"] > 0 and st["rollbacks"] > 0
+    return list(h.result(timeout=5))
+
+
+def _a_second_warmup(target_params, prompt, n):
+    sched = _sched(target_params, ladder=(1, 2), chunk=4)
+    first = sched.submit(prompt[:3], max_new_tokens=2)
+    sched.pump()
+    assert len(first.result(timeout=5)) == 2
+    sched.engine.warmup(FakeClock())             # over pools in use
+    h = sched.submit(prompt, max_new_tokens=n)
+    sched.pump()
+    return list(h.result(timeout=5))
+
+
+def _one_slot_decoder_reset(target_params, prompt, n):
+    m = mx.mod.Module(
+        tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=L,
+                              n_head=H, capacity=T, max_seq_len=T),
+        label_names=[])
+    m.bind([("data", (1, 1))], None, for_training=False)
+    m.init_params(initializer=None, arg_params=_nd(target_params),
+                  aux_params={}, allow_missing=True)
+    d = tfm.KVCacheDecoder(m, capacity=T)
+    exe = m._exec_group.executor
+    held = [cell.asjax() for cell in exe.aux_arrays]
+    for t in (3, 1, 4, 1, 5):                    # another sequence first
+        d.step(np.asarray([[t]], np.int32))
+    assert all(a.is_deleted() for a in held)
+    d.reset()
+    for t in prompt[:-1]:
+        d.step(np.asarray([[t]], np.int32))
+    cur, out = int(prompt[-1]), []
+    for _ in range(n):
+        cur = int(np.argmax(d.step(np.asarray([[cur]], np.int32))
+                            .asnumpy()[0, 0]))
+        out.append(cur)
+    return out
+
+
+_AFTER_DONATED_STEPS = {
+    "rewind": _rewind_after_junk,
+    "rewind_many": _rewind_many_after_a_padded_window,
+    "restore_rows": _restore_rows_into_another_slot,
+    "prefix_store": _prefix_capture_and_hit,
+    "migrate": _rung_migration,
+    "spec_rollback": _speculative_rollback,
+    "warmup": _a_second_warmup,
+    "one_slot": _one_slot_decoder_reset,
+}
+
+
+@pytest.mark.parametrize("what", sorted(_AFTER_DONATED_STEPS))
+def test_the_host_reads_cells_never_arrays_held_across_a_step(
+        lively_params, what):
+    """ISSUE 33: every step program takes over the K and V pools and
+    the cursors (an array read from a cell before a step is deleted by
+    it), so whatever the host does to them between steps goes through
+    the cell. Each path, after donated steps, ends in the tokens of an
+    undisturbed token-at-a-time run."""
+    prompt = list(np.random.RandomState(41).randint(1, V, 10))
+    n = 8
+    want = _ref_greedy(lively_params, prompt, n)
+    assert len(set(want)) > 4                    # no fixed point
+    assert _AFTER_DONATED_STEPS[what](lively_params, prompt, n) == want

@@ -480,16 +480,22 @@ def test_serve_decoder_serves_the_block_with_no_side_script():
     assert tokens == np.argmax(want[40:], axis=-1).tolist()
 
 
-def test_the_older_blocks_step_programs_take_no_fed_and_donate_nothing():
-    """GPT-2's decode graph: no ``fed`` input, a positional state, and a
-    step program that donates no aux array (its cells' old buffers stay
-    readable after a step)."""
+def test_the_older_blocks_step_programs_take_no_fed_and_donate_their_pools():
+    """GPT-2's decode graph: no ``fed`` input and a positional state;
+    like this block's, its step program takes over every aux array (an
+    array read from a cell before the step is deleted by it) and the
+    cell holds the new one."""
     mod = _gpt2_module()
     exe = mod._exec_group.executor
-    kept = exe.aux_dict["lm_l0_attn_k_cache"].asjax()
-    tfm.BatchedKVCacheDecoder(mod, 8, slots=2).step(
-        np.zeros((2, 1), np.int32))
-    assert not kept.is_deleted()
+    drv = tfm.BatchedKVCacheDecoder(mod, 8, slots=2)
+    assert drv.positional and not drv.feeds and exe.donates_aux
+    assert drv.donated_bytes == 2 * (2 * 2 * 8 * 8 * 4) + 2 * 4
+    pool = exe.aux_dict["lm_l0_attn_k_cache"]
+    held = pool.asjax()
+    drv.step(np.zeros((2, 1), np.int32))
+    assert held.is_deleted() and not pool.asjax().is_deleted()
+    assert np.asarray(pool.asjax())[:, :, 0].any()      # row 0 written
+    assert not np.asarray(pool.asjax())[:, :, 1:].any()  # and no other
     eva_mod = _bound(1, slots=1)
     ring = eva_mod._exec_group.executor.aux_dict["lm_l0_attn_singles_k"]
     held = ring.asjax()

@@ -450,12 +450,14 @@ def test_float32_parameters_bind_as_before():
 
 
 #: sha256 of the lowered S=1 program of the Cerebras-shaped tiny decoder
-#: below (learned positions, float32 masters, compute_dtype bfloat16) as
-#: the PARENT of PR 28 (d8b22cc) lowers it: the GPT-2 block's program
-#: is the same bytes, so the Cerebras cells hit their compile cache.
-#: A PR that changes that program on purpose computes it anew.
+#: below (learned positions, float32 masters, compute_dtype bfloat16):
+#: a PR that leaves the GPT-2 block's program alone leaves these bytes
+#: alone, and the Cerebras cells hit their compile cache. A PR that
+#: changes that program on purpose computes it anew: PR 33 did (the
+#: cache write is one window update a slot, no longer a ``jnp.where``
+#: over the pool); before it the bytes were PR 28's parent's (d8b22cc).
 GPT2_S1_SHA256 = \
-    "b55e9ecfedb869a71b2f803f010d8b2389dfdeabb70618e66877fe58fc132027"
+    "9a63a96316cb1f04af6f20b01533bbe3ad9cc68bc5764b04ceb06b6895e367ab"
 
 
 def _gpt2_lowered_s1():
