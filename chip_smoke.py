@@ -251,6 +251,56 @@ def kernel_sites(w, seed=0):
                    normal(pool, bf), normal(pool, bf),
                    jnp.asarray(rs.randint(0, 4 * cap - step + 1,
                                           (slots, 1)).astype(np.int32))])))
+    # latent attention under a learned selection (ops/mla.py) at the
+    # published head sizes where the widths allow: the indexer with a
+    # top-k of the whole capacity (every position before the query is
+    # selected: a threshold has no rounding to flip), the attention
+    # under a random causal selection; ragged ``fed`` is the CPU tests'
+    # (tests/test_glm_dsa.py)
+    big = D >= 512
+    Hi, di, dr = (32, 128, 64) if big else (4, 32, 16)
+    dn, dv, rank = (192, 256, 512) if big else (24, 32, 64)
+    lat = -(-(rank + dr) // 128) * 128
+    for step in (1, win):
+        # every row fed: what the kernels leave of a query block past
+        # ``fed`` (zeros) and what the composition computes there are
+        # both don't-cares, and the gate compares every element
+        fed = lambda step=step: jnp.full((slots,), step,   # noqa: E731
+                                         jnp.int32)
+        sites.append((
+            f"dsa_index_select_s{step}", "dsa_index_select",
+            {"capacity": cap, "n_heads": Hi, "head_dim": di, "rope_dim": dr,
+             "topk": cap, "rope_base": 8e6},
+            [(slots, step, Hi * di), (slots, step, di), (slots, step, Hi),
+             (slots,), (slots, 1, cap, di), (slots, 1)],
+            [bf, bf, bf, "int32", bf, "int32"], False,
+            lambda step=step, fed=fed: [
+                normal((slots, step, Hi * di), bf),
+                normal((slots, step, di), bf), normal((slots, step, Hi), bf),
+                fed(), normal((slots, 1, cap, di), bf), cursors(step)]))
+
+        def mla_inputs(step=step, fed=fed):
+            at = cursors(step)
+            seen = (np.arange(cap)[None, None, :]
+                    <= np.asarray(at)[:, :, None] + np.arange(step)[None, :,
+                                                                    None])
+            keep = (rs.rand(slots, step, cap) < 0.5) | (np.arange(cap) == 0)
+            return [normal((slots, step, h_dec * (dn + dr)), bf),
+                    normal((slots, step, rank + dr), bf),
+                    jnp.asarray((seen & keep).astype(np.int8)), fed(),
+                    jnp.ones((rank,), bf),
+                    (normal((h_dec * (dn + dv), rank), f32)
+                     / np.sqrt(rank)).astype(bf),
+                    normal((slots, 1, cap, lat), bf), at]
+        sites.append((
+            f"mla_attention_decode_s{step}", "mla_attention_decode",
+            {"capacity": cap, "n_heads": h_dec, "nope_dim": dn,
+             "rope_dim": dr, "v_dim": dv, "kv_rank": rank, "rope_base": 8e6},
+            [(slots, step, h_dec * (dn + dr)), (slots, step, rank + dr),
+             (slots, step, cap), (slots,), (rank,),
+             (h_dec * (dn + dv), rank), (slots, 1, cap, lat), (slots, 1)],
+            [bf, bf, "int8", "int32", bf, bf, bf, "int32"], False,
+            mla_inputs))
     for wdt in ("int8", "float8_e4m3fn"):
         rows, k, nh = slots * win, D, 4 * D
         sites.append((

@@ -218,6 +218,128 @@ def _eva_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
     return x + sym.Cast(h, dtype="float32", name=f"{pfx}_ffn_f32")
 
 
+#: the keys of GLM-5.2's published ``config.json`` that
+#: ``block="glm_dsa"`` reads (``get_decode_symbol(glm=...)``), and
+#: ``held``: the (first, count) of the routed experts this graph holds
+GLM_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+            "qk_rope_head_dim", "v_head_dim", "index_n_heads",
+            "index_head_dim", "index_topk", "indexer_types",
+            "first_k_dense_replace", "intermediate_size",
+            "moe_intermediate_size", "n_routed_experts",
+            "num_experts_per_tok", "n_shared_experts",
+            "routed_scaling_factor", "norm_topk_prob")
+
+
+def _glm_spec(block, glm, n_layer, rms_eps):
+    if block != "glm_dsa":
+        return None
+    glm = dict(glm or {})
+    missing = [k for k in GLM_KEYS if k not in glm]
+    if missing:
+        raise MXNetError(f"block='glm_dsa' needs glm= with {missing}")
+    kinds = list(glm["indexer_types"])
+    if len(kinds) != n_layer or not kinds or kinds[0] != "full" \
+            or set(kinds) - {"full", "shared"}:
+        raise MXNetError(
+            f"block='glm_dsa': indexer_types {kinds} must name "
+            f"'full' or 'shared' for each of {n_layer} layers, the "
+            "first 'full' (a shared layer attends the set the nearest "
+            "earlier full layer chose)")
+    n_expert = int(glm["n_routed_experts"])
+    first, count = glm.get("held") or (0, n_expert)
+    glm.update(indexer_types=kinds, held=(int(first), int(count)),
+               rms_eps=float(rms_eps))
+    return glm
+
+
+def _glm_norm(x, name, glm):
+    return sym.RMSNorm(x, eps=glm["rms_eps"], name=name)
+
+
+def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
+               rope_base, name, capacity, glm):
+    """One GLM-5.2 block (``glm``: ``_glm_spec``) of the slot-pooled
+    decode graph, pre-norm, no bias anywhere but the indexer's
+    LayerNorm: multi-head latent attention over a latent cache
+    (``mla_attention_decode``) under the selection of positions that
+    this layer's indexer computes (``dsa_index_select``, layers whose
+    ``indexer_types`` entry is ``"full"``) or that ``selection``
+    brings from the nearest earlier such layer (``"shared"``:
+    IndexShare); then a dense gated-SiLU feed-forward (layers before
+    ``first_k_dense_replace``) or sigmoid-routed experts beside a
+    shared one (``MoEFFN``), of which this graph holds ``held``; the
+    pads of a window (rows past ``fed``) are routed nowhere.
+    Returns ``(x, selection)``: the selection crosses layers outside
+    the residual stream."""
+    pfx = f"{name}_l{i}"
+    T = seq_len
+    dq = glm["qk_nope_head_dim"] + glm["qk_rope_head_dim"]
+    unfold = lambda rows, n, nm: sym.Reshape(                # noqa: E731
+        rows, shape=(-1, T, n), name=f"{pfx}_{nm}_unfold")
+
+    rows = sym.Reshape(_glm_norm(x, f"{pfx}_ln1", glm), shape=(-3, 0),
+                       name=f"{pfx}_attn_fold")              # (B*T, D)
+    c_q = _glm_norm(
+        sym.FullyConnected(rows, num_hidden=glm["q_lora_rank"],
+                           no_bias=True, name=f"{pfx}_q_a"),
+        f"{pfx}_q_a_norm", glm)
+    q = sym.FullyConnected(c_q, num_hidden=n_head * dq, no_bias=True,
+                           name=f"{pfx}_q_b")
+    kv = sym.FullyConnected(
+        rows, num_hidden=glm["kv_lora_rank"] + glm["qk_rope_head_dim"],
+        no_bias=True, name=f"{pfx}_kv_a")
+    if glm["indexer_types"][i] == "full":
+        n_idx, d_idx = glm["index_n_heads"], glm["index_head_dim"]
+        q_idx = sym.FullyConnected(c_q, num_hidden=n_idx * d_idx,
+                                   no_bias=True, name=f"{pfx}_idx_q")
+        k_idx = sym.LayerNorm(
+            sym.FullyConnected(rows, num_hidden=d_idx, no_bias=True,
+                               name=f"{pfx}_idx_k"),
+            name=f"{pfx}_idx_k_norm")
+        w_idx = sym.FullyConnected(rows, num_hidden=n_idx, no_bias=True,
+                                   name=f"{pfx}_idx_w")
+        selection = sym.dsa_index_select(
+            unfold(q_idx, n_idx * d_idx, "idx_q"),
+            unfold(k_idx, d_idx, "idx_k"), unfold(w_idx, n_idx, "idx_w"),
+            fed, capacity=capacity, n_heads=n_idx, head_dim=d_idx,
+            rope_dim=glm["qk_rope_head_dim"], topk=glm["index_topk"],
+            rope_base=rope_base, name=f"{pfx}_idx")
+    att = sym.mla_attention_decode(
+        unfold(q, n_head * dq, "q"),
+        unfold(kv, glm["kv_lora_rank"] + glm["qk_rope_head_dim"], "kv"),
+        selection, fed, capacity=capacity, n_heads=n_head,
+        nope_dim=glm["qk_nope_head_dim"], rope_dim=glm["qk_rope_head_dim"],
+        v_dim=glm["v_head_dim"], kv_rank=glm["kv_lora_rank"],
+        rms_eps=glm["rms_eps"], rope_base=rope_base, name=f"{pfx}_attn")
+    proj = sym.FullyConnected(
+        sym.Reshape(att, shape=(-3, 0), name=f"{pfx}_attn_merge"),
+        num_hidden=d_model, no_bias=True, name=f"{pfx}_proj")
+    x = x + sym.Reshape(proj, shape=(-1, T, d_model),
+                        name=f"{pfx}_proj_unfold")
+
+    rows = sym.Reshape(_glm_norm(x, f"{pfx}_ln2", glm), shape=(-3, 0),
+                       name=f"{pfx}_ffn_fold")
+    if i < glm["first_k_dense_replace"]:
+        h = sym.FullyConnected(rows, num_hidden=2 * glm["intermediate_size"],
+                               no_bias=True, name=f"{pfx}_ffn_gate_up")
+        h = sym.GatedSiLU(h, name=f"{pfx}_ffn_act")
+        h = sym.FullyConnected(h, num_hidden=d_model, no_bias=True,
+                               name=f"{pfx}_ffn_down")
+    else:
+        first, count = glm["held"]
+        h = sym.MoEFFN(
+            rows, fed, step_len=T, num_experts=glm["n_routed_experts"],
+            num_hidden=glm["moe_intermediate_size"],
+            top_k=glm["num_experts_per_tok"],
+            norm_topk=glm["norm_topk_prob"], scoring="sigmoid",
+            router_bias=True, scaling=glm["routed_scaling_factor"],
+            held_first=first, held_count=count,
+            shared_hidden=glm["n_shared_experts"]
+            * glm["moe_intermediate_size"], name=f"{pfx}_moe")
+    h = sym.Reshape(h, shape=(-1, T, d_model), name=f"{pfx}_ffn_unfold")
+    return x + h, selection
+
+
 def _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps):
     if block != "evabyte":
         return None
@@ -227,9 +349,18 @@ def _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps):
 
 
 def _validate(vocab_size, d_model, n_head, pos_embed, block="gpt2",
-              n_expert=None, top_k=None, expert_width=None, eva=None):
-    if block not in ("gpt2", "olmoe", "evabyte"):
-        raise MXNetError(f"block {block!r}: 'gpt2', 'olmoe' or 'evabyte'")
+              n_expert=None, top_k=None, expert_width=None, eva=None,
+              glm=None):
+    if block not in ("gpt2", "olmoe", "evabyte", "glm_dsa"):
+        raise MXNetError(f"block {block!r}: 'gpt2', 'olmoe', 'evabyte' "
+                         "or 'glm_dsa'")
+    if block == "glm_dsa":
+        if glm is None:
+            raise MXNetError(
+                "block='glm_dsa' is served, not trained: its attention "
+                "exists as the decode ops alone "
+                "(get_decode_symbol(per_slot=True))")
+        return
     if block == "olmoe":
         if pos_embed != "rotary":
             raise MXNetError("block='olmoe' is rotary (no position table)")
@@ -367,7 +498,7 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                       expert_width=None, norm_topk=False, rms_eps=1e-5,
                       tie_head=True, embed_scale=True, window=2048,
                       chunk=16, n_pred_heads=1, ffn_width=None,
-                      multibyte=False):
+                      multibyte=False, glm=None):
     """Incremental KV-cache decoder: ``(B, step_len)`` new token ids in,
     logits ``(B, step_len, vocab)`` out, per-layer K/V caches of
     ``capacity`` positions riding executor aux state. Parameter names
@@ -408,10 +539,26 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     by exactly that. The output is head 0's ``(B, step_len, vocab)``
     logits - the next byte, what a scheduler samples - or, with
     ``multibyte``, all heads' ``(B, step_len, n_pred_heads, vocab)``.
+
+    ``block="glm_dsa"`` (per-slot only) builds GLM-5.2's block
+    (``_glm_block``) from ``glm``, the published config's keys
+    (``GLM_KEYS``) and optionally ``held``, the (first, count) of the
+    routed experts this graph holds of ``n_routed_experts``: latent
+    attention over one row of ``kv_lora_rank + qk_rope_head_dim``
+    numbers a position (``ops/mla.py``), attended under the
+    ``index_topk`` positions a learned indexer selects on the layers
+    ``indexer_types`` marks ``"full"`` and the layers marked
+    ``"shared"`` reuse; a dense feed-forward on the first
+    ``first_k_dense_replace`` layers and sigmoid-routed experts beside
+    a shared one after; untied head, unscaled embedding. The graph
+    takes ``fed`` like EvaByte's and advances by it, but its state is a
+    row per position in every pool (``"rows"``), so the driver rewinds,
+    captures and restores it as it does a K/V cache.
     """
     eva = _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps)
+    glm = _glm_spec(block, glm, n_layer, rms_eps)
     _validate(vocab_size, d_model, n_head, pos_embed, block, n_expert,
-              top_k, expert_width, eva=eva)
+              top_k, expert_width, eva=eva, glm=glm)
     moe = _moe_spec(block, n_expert, top_k, expert_width, norm_topk,
                     rms_eps)
     capacity = capacity or default_cache_capacity()
@@ -426,6 +573,13 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
         return _eva_decode_symbol(
             vocab_size, d_model, n_layer, n_head, rope_base, capacity, S,
             name, eva, multibyte)
+    if glm is not None:
+        if not per_slot or cache_dtype:
+            raise MXNetError("block='glm_dsa' is the slot-pooled decode "
+                             "graph (per_slot=True) with state at the "
+                             "compute width (no cache_dtype)")
+        return _glm_decode_symbol(vocab_size, d_model, n_layer, n_head,
+                                  rope_base, capacity, S, name, glm)
 
     data = sym.var("data")
     tok_w = sym.var(f"{name}_tok_embed_weight")
@@ -441,6 +595,28 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                    per_slot=per_slot, cache_dtype=cache_dtype, moe=moe)
     logits = _head(x, tok_w, moe=moe, tie_head=tie_head,
                    vocab_size=vocab_size, name=name)
+    return sym.Reshape(logits, shape=(-1, S, vocab_size),
+                       name=f"{name}_logits_bsv")
+
+
+def _glm_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
+                       capacity, S, name, glm):
+    data = sym.var("data")
+    fed = sym.var("fed")
+    x = sym.Embedding(data=data, weight=sym.var(f"{name}_tok_embed_weight"),
+                      input_dim=vocab_size, output_dim=d_model,
+                      name=f"{name}_tok_embed")              # (B, S, D)
+    selection = None
+    for i in range(n_layer):
+        x, selection = _glm_block(
+            x, fed, selection, i=i, seq_len=S, d_model=d_model,
+            n_head=n_head, rope_base=rope_base, name=name,
+            capacity=capacity, glm=glm)
+    flat = sym.Reshape(_glm_norm(x, f"{name}_ln_f", glm), shape=(-3, 0),
+                       name=f"{name}_head_fold")
+    logits = sym.FullyConnected(
+        flat, weight=sym.var(f"{name}_head_weight"), num_hidden=vocab_size,
+        no_bias=True, name=f"{name}_logits")
     return sym.Reshape(logits, shape=(-1, S, vocab_size),
                        name=f"{name}_logits_bsv")
 
@@ -612,6 +788,20 @@ def slot_state(symbol):
     return families
 
 
+def sparse_selection(symbol):
+    """``(attention layers, layers with an indexer, index_topk)`` of a
+    graph whose attention reads a selection of positions
+    (``block="glm_dsa"``: ``mla_attention_decode`` under
+    ``dsa_index_select``), None for any other graph."""
+    ops = [(n.op, n.attrs) for n in symbol._topo_nodes()
+           if not n.is_variable]
+    topk = [int(a["topk"]) for op, a in ops if op == "dsa_index_select"]
+    if not topk:
+        return None
+    return (sum(op == "mla_attention_decode" for op, _ in ops), len(topk),
+            topk[0])
+
+
 class BatchedKVCacheDecoder:
     """Host-side driver for a bound SLOT-POOLED decode module.
 
@@ -707,6 +897,11 @@ class BatchedKVCacheDecoder:
         self.positional = set(self._state) <= {"cursor", "rows"}
         self.feeds = "fed" in module.symbol.list_arguments()
         self.last_reads = None
+        # a graph that attends a learned selection of positions: what
+        # the latest dispatch read of it (``_selection_reads``)
+        self._sparse = sparse_selection(module.symbol)
+        self.selects = self._sparse is not None
+        self.last_selection = None
         if not self.positional:
             ring = exe.aux_dict[self._state["window"][0]]
             pool = exe.aux_dict[self._state["summary"][0]]
@@ -1002,9 +1197,27 @@ class BatchedKVCacheDecoder:
         fed = np.where(self.pos + S <= self.capacity, fed, 0)
         data.append(nd.array(fed.astype(np.int32)))
         self.last_reads = self._state_reads(fed)
+        self.last_selection = self._selection_reads(fed)
         mod.forward(DataBatch(data=data, label=[]), is_train=False)
         self.pos += fed
         return mod.get_outputs()[0]
+
+    def _selection_reads(self, fed):
+        """What one dispatch that feeds ``fed`` tokens a slot reads
+        under a learned selection, from the cursors alone (no fetch),
+        for each fed slot's last real query, summed over the slots and
+        the layers: ``[attention layer executions, positions at or
+        before the query (what attention without a selection would
+        read), positions attended (at most ``index_topk``), index keys
+        scored (layers with an indexer)]``."""
+        if self._sparse is None:
+            return None
+        layers, indexed, topk = self._sparse
+        live = (self.pos + fed)[fed > 0]
+        return np.asarray(
+            [layers, layers * np.sum(live),
+             layers * np.sum(np.minimum(live, topk)),
+             indexed * np.sum(live)], np.int64)
 
     def _state_reads(self, fed):
         """What one dispatch that feeds ``fed`` tokens a slot reads and

@@ -1,0 +1,669 @@
+"""Multi-head latent attention over a latent cache
+(``mla_attention_decode``) and the learned sparse selection that feeds
+it (``dsa_index_select``): the attention of DeepSeek-V2/V3 (MLA,
+arXiv:2405.04434) with DeepSeek-V3.2's sparse attention (DSA: a
+lightning indexer and a top-k) as GLM-5.2 runs it, one selection for a
+period of layers (IndexShare).
+
+**The latent cache.** A position's keys and values of all H heads are
+functions of one row ``[c_kv ; k_r]`` (``kv_rank`` + ``rope_dim``
+numbers): ``[k_n ; v]_h = W_kvb[h] c_kv`` and the rotary key ``k_r`` is
+shared by the heads. The op keeps that row and nothing else, as a
+``"rows"`` pool ``latent (slots, 1, capacity, width)`` beside a
+``cache_pos (slots, 1)`` cursor, so the driver's whole positional
+contract holds (rewind, row capture, migrate). ``width`` is
+``kv_rank + rope_dim`` rounded up to whole 128-lane tiles, the rest
+zeros (512 + 64 -> 640): the TPU lays an array whose last axis is not
+a multiple of 128 with another axis minor - here the capacity - and
+every kernel would copy the pool into rows and back. With
+``s = (nope_dim + rope_dim) ** -0.5``:
+
+    s_tj = s * (q_n[t,h] . k_n[j,h] + RoPE(q_r[t,h]) . RoPE(k_r[j]))
+    o_th = sum_{j in S_t} softmax_j(s_tj) v[j,h]
+
+``forward`` (the XLA composition, and the statement of the above)
+expands ``k_n`` and ``v`` of the whole pool. The ``pallas`` variant
+absorbs ``W_kvb``: ``q_n W_kb`` is scored against ``c_kv`` itself, the
+weighted sum of ``c_kv`` goes through ``W_vb`` afterwards, and one
+flash-style kernel (``mla_attn_decode`` at S = 1, ``mla_attn_window``
+beyond) walks the live blocks of the pool once for all heads.
+
+**The selection.** ``S_t`` arrives as ``selection (slots, S, capacity)``
+int8, 1 where position j is attended by query t - an input, because it
+crosses layers outside the residual stream: a layer with an indexer
+(``dsa_index_select``) computes it and the layers after it that have
+none take the same array. The indexer keeps its own ``"rows"`` pool
+``index_k (slots, 1, capacity, head_dim)`` and cursor, and computes
+
+    I_tj = sum_h w_th * relu(q_th . k_j)        j <= t
+    S_t  = {j : I_tj >= the topk-th largest of I_t.}    (all j <= t
+                                              while t < topk)
+
+The set is a mask over the pool and the attention kernel reads every
+live block under it: at a block of 512 rows a block without a selected
+row is rare, so a gather by block would read the same rows, and a
+gather by row is ``topk`` copies of a kilobyte a query. What the
+selection saves here is nothing but the softmax's support; the kernel's
+roofline share says so.
+
+Both ops take ``fed (slots,)``: how many of a slot's S tokens are real.
+All S rows are written at the cursor (a row past ``fed`` is overwritten
+by the next dispatch before anything attends it) and the cursor moves
+by ``fed``; a slot whose S rows do not fit below the capacity writes
+nothing and stays (``cache_write``'s rule).
+"""
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..base import MXNetError, parse_float, parse_int
+from . import pallas_kernels as _pk
+from .moe import cpu_wide, rms_norm
+from .registry import register
+
+__all__ = ["rope_interleaved", "dsa_scores", "dsa_threshold_mask",
+           "latent_width"]
+
+_F32 = jnp.float32
+_HI = lax.Precision.HIGHEST
+_VMEM_LIMIT = 96 << 20
+
+
+def rope_interleaved(x, positions, base):
+    """Rotate the adjacent pairs ``(x[2i], x[2i+1])`` of ``x``
+    (..., T, d) by ``positions[..., t] * base ** (-2i / d)``;
+    ``positions`` broadcasts against ``x``'s leading axes up to T. Trig
+    in float32, cast back."""
+    d = x.shape[-1]
+    inv = jnp.asarray(base, _F32) ** (
+        -jnp.arange(0, d // 2, dtype=_F32) * (2.0 / d))
+    ang = positions.astype(_F32)[..., None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    pair = x.astype(_F32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = pair[..., 0], pair[..., 1]
+    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _cursor(fed, cursor, S, capacity):
+    """Each slot's position and the rows it is really fed (module
+    docstring)."""
+    B = cursor.shape[0]
+    p = cursor.reshape((B,)).astype(jnp.int32)
+    fed = jnp.where(p + S <= capacity,
+                    jnp.clip(fed.reshape((B,)).astype(jnp.int32), 0, S), 0)
+    return p, (p + fed).reshape((B, 1)).astype(jnp.int32)
+
+
+def _positions(p, S):
+    return p[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]   # (B, S)
+
+
+def _mm(spec, a, b):
+    """``einsum`` of two arrays of one dtype with float32 accumulation
+    and result."""
+    return jnp.einsum(spec, *cpu_wide(a, b), preferred_element_type=_F32)
+
+
+def _compiler_params(semantics):
+    if _pk._interpret():
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)}
+
+
+# ------------------------------------------------------------ the selection
+def dsa_scores(q, w, keys, p):
+    """``I (B, S, C)`` float32 of the module docstring for queries
+    ``q (B, S, Hi, d)`` with weights ``w (B, S, Hi)`` against every row
+    of ``keys (B, C, d)``, ``-inf`` where ``j > t``. The plain form."""
+    S = q.shape[1]
+    dots = _mm("bshd,bkd->bshk", q, keys.astype(q.dtype))
+    score = jnp.einsum("bshk,bsh->bsk", jnp.maximum(dots, 0.0),
+                       w.astype(_F32), precision=_HI)
+    causal = jnp.arange(keys.shape[1])[None, None, :] \
+        <= _positions(p, S)[:, :, None]
+    return jnp.where(causal, score, -jnp.inf)
+
+
+def dsa_threshold_mask(score, topk):
+    """int8 ``(B, S, C)``: 1 where a finite score is at least the
+    ``topk``-th largest of its row (every finite one where the row has
+    fewer). Scores that tie with the ``topk``-th are all kept."""
+    k = min(int(topk), score.shape[-1])
+    kth = lax.top_k(score, k)[0][..., -1:]
+    return ((score >= kth) & (score > -jnp.inf)).astype(jnp.int8)
+
+
+def _index_geometry(attrs):
+    return (parse_int(attrs["capacity"]), parse_int(attrs["n_heads"]),
+            parse_int(attrs["head_dim"]), parse_int(attrs["rope_dim"]),
+            parse_int(attrs["topk"]))
+
+
+def _index_prologue(attrs, inputs, aux, is_train):
+    """What both lowerings share: the cursor, q and k rotated on their
+    first ``rope_dim`` dimensions, the weights with both scale factors
+    folded in, k at the pool's dtype as a pool-shaped row block."""
+    if is_train:
+        raise MXNetError("dsa_index_select is an inference op")
+    q, k, w, fed = inputs
+    pool, cursor = aux
+    capacity, Hi, d, dr, topk = _index_geometry(attrs)
+    B, S = q.shape[:2]
+    p, new_cursor = _cursor(fed, cursor, S, capacity)
+    base = parse_float(attrs.get("rope_base", 10000.0))
+    pos = _positions(p, S)
+    q = q.reshape(B, S, Hi, d)
+    q = jnp.concatenate(
+        [rope_interleaved(q[..., :dr], pos[:, :, None], base),
+         q[..., dr:]], axis=-1)
+    k = jnp.concatenate(
+        [rope_interleaved(k[..., :dr], pos, base), k[..., dr:]], axis=-1)
+    w = w.astype(_F32) * (float(Hi) ** -0.5 * float(d) ** -0.5)
+    return q, k.astype(pool.dtype)[:, None], w, p, new_cursor, topk
+
+
+def _index_fwd(attrs, inputs, aux, is_train, rng):
+    from ..rtc import _write_rows
+    q, k, w, p, new_cursor, topk = _index_prologue(attrs, inputs, aux,
+                                                   is_train)
+    pool, = _write_rows([k], [aux[0]], p)
+    score = dsa_scores(q, w, pool[:, 0], p)
+    return [dsa_threshold_mask(score, topk)], [pool, new_cursor]
+
+
+def _index_infer(attrs, in_shapes):
+    q_s = in_shapes[0]
+    if q_s is None:
+        return in_shapes, [None], [None, None]
+    capacity, Hi, d, _dr, _topk = _index_geometry(attrs)
+    B, S = q_s[:2]
+    return ([(B, S, Hi * d), (B, S, d), (B, S, Hi), (B,)],
+            [(B, S, capacity)], [(B, 1, capacity, d), (B, 1)])
+
+
+# ------------------------------------------------------ the selection, kernels
+def _index_window_kernel(Hi, tq, bk):
+    """Grid (slot, query block, key block): the scores of ``tq``
+    queries against ``bk`` keys, head by head; a block no query of the
+    block can see, or whose queries are all past ``fed`` (pads), is
+    ``-inf`` without a product."""
+    def kernel(p_ref, fed_ref, q_ref, w_ref, k_ref, o_ref):
+        b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        t0 = p_ref[b] + i * tq
+        live = (j * bk <= t0 + tq - 1) & (i * tq < fed_ref[b])
+
+        @pl.when(jnp.logical_not(live))
+        def _dead():
+            o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, _F32)
+
+        @pl.when(live)
+        def _live():
+            k = k_ref[...]
+            w = w_ref[...]
+            acc = jnp.zeros((tq, bk), _F32)
+            for h in range(Hi):
+                dots = lax.dot_general(q_ref[h], k, (((1,), (1,)), ((), ())),
+                                       preferred_element_type=_F32)
+                acc = acc + w[:, h:h + 1] * jnp.maximum(dots, 0.0)
+            t = t0 + lax.broadcasted_iota(jnp.int32, (tq, bk), 0)
+            key = j * bk + lax.broadcasted_iota(jnp.int32, (tq, bk), 1)
+            o_ref[...] = jnp.where(key <= t, acc, -jnp.inf)
+    return kernel
+
+
+def _index_decode_kernel(bk):
+    """Grid (slot, key block) at S = 1: the heads are the rows of one
+    product, weighted and summed over."""
+    def kernel(p_ref, fed_ref, q_ref, w_ref, k_ref, o_ref):
+        b, j = pl.program_id(0), pl.program_id(1)
+        t = p_ref[b]
+
+        @pl.when(j * bk > t)
+        def _dead():
+            o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, _F32)
+
+        @pl.when(j * bk <= t)
+        def _live():
+            dots = lax.dot_general(q_ref[...], k_ref[...],
+                                   (((1,), (1,)), ((), ())),
+                                   preferred_element_type=_F32)
+            acc = jnp.sum(w_ref[...] * jnp.maximum(dots, 0.0), axis=0,
+                          keepdims=True)
+            key = j * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            o_ref[...] = jnp.where(key <= t, acc, -jnp.inf)
+    return kernel
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _index_scores(p, fed, q, w, pool, interpret):
+    """The kernel ``dsa_index_scores``: ``dsa_scores`` over the live
+    blocks of the pool. A jitted function of its own, so that a step
+    program lowers it once and calls it from every layer that has an
+    indexer."""
+    B, S, Hi, d = q.shape
+    C = pool.shape[2]
+    keys = pool.reshape(B, C, d)
+    q = q.astype(pool.dtype)
+    if S == 1:
+        bk = _pk._divisor_block(C, 2048)
+
+        def live(b, j, p_ref, fed_ref):
+            return b, jnp.minimum(j, p_ref[b] // bk), 0
+
+        def fixed(b, j, p_ref, fed_ref):
+            return b, 0, 0
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, C // bk),
+            in_specs=[pl.BlockSpec((None, Hi, d), fixed),
+                      pl.BlockSpec((None, Hi, 1), fixed),
+                      pl.BlockSpec((None, bk, d), live)],
+            out_specs=pl.BlockSpec((None, 1, bk),
+                                   lambda b, j, p, f: (b, 0, j)))
+        return _pk.pallas_call(
+            _index_decode_kernel(bk), name="dsa_index_scores",
+            out_shape=jax.ShapeDtypeStruct((B, 1, C), _F32),
+            grid_spec=grid_spec, interpret=interpret,
+            **_compiler_params(("parallel", "arbitrary")))(
+                p, fed, q.reshape(B, Hi, d), w.reshape(B, Hi, 1), keys)
+    tq, bk = _pk._divisor_block(S, 256), _pk._divisor_block(C, 512)
+
+    def live(b, i, j, p_ref, fed_ref):
+        # a dead block re-references the last live one: no copy
+        last = jnp.where(i * tq < fed_ref[b],
+                         (p_ref[b] + (i + 1) * tq - 1) // bk, 0)
+        return b, jnp.minimum(j, last), 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B, S // tq, C // bk),
+        in_specs=[pl.BlockSpec((None, Hi, tq, d),
+                               lambda b, i, j, p, f: (b, 0, i, 0)),
+                  pl.BlockSpec((None, tq, Hi),
+                               lambda b, i, j, p, f: (b, i, 0)),
+                  pl.BlockSpec((None, bk, d), live)],
+        out_specs=pl.BlockSpec((None, tq, bk),
+                               lambda b, i, j, p, f: (b, i, j)))
+    return _pk.pallas_call(
+        _index_window_kernel(Hi, tq, bk), name="dsa_index_scores",
+        out_shape=jax.ShapeDtypeStruct((B, S, C), _F32),
+        grid_spec=grid_spec, interpret=interpret,
+        **_compiler_params(("parallel", "parallel", "arbitrary")))(
+            p, fed, q.transpose(0, 2, 1, 3), w, keys)
+
+
+def _sortable(x):
+    """float32 -> int32 whose order is the floats' (``-inf`` lowest)."""
+    u = lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(u < 0, u ^ jnp.int32(0x7FFFFFFF), u)
+
+
+def _topk_kernel(tq, C, chunk, topk):
+    """Grid (slot, query block): the ``topk``-th largest of each row by
+    a search over the bits of its sortable integer form - 32 counts of
+    the keys at or above a candidate, each over the chunks a query of
+    the block can see - and the mask at or above it."""
+    n_chunks = C // chunk
+    k = min(topk, C)
+
+    def search(last, s_ref, o_ref, key_s):
+        key_s[...] = _sortable(s_ref[...])
+
+        def enough(cand):
+            count = jnp.zeros((tq, 1), jnp.int32)
+            for c in range(n_chunks):
+                part = lax.cond(
+                    c * chunk <= last,
+                    lambda c=c: jnp.sum(
+                        (key_s[:, c * chunk:(c + 1) * chunk] >= cand)
+                        .astype(jnp.int32), axis=1, keepdims=True),
+                    lambda: jnp.zeros((tq, 1), jnp.int32))
+                count = count + part
+            return count >= k
+
+        lowest = jnp.full((tq, 1), -2 ** 31, jnp.int32)
+        thr = jnp.where(enough(jnp.zeros((tq, 1), jnp.int32)), 0, lowest)
+
+        def bit(n, thr):
+            cand = thr + lax.shift_left(jnp.int32(1), 30 - n)
+            return jnp.where(enough(cand), cand, thr)
+
+        thr = lax.fori_loop(0, 31, bit, thr)
+        keep = (key_s[...] >= thr) & (s_ref[...] > -jnp.inf)
+        o_ref[...] = keep.astype(jnp.int32).astype(jnp.int8)
+
+    def kernel(p_ref, fed_ref, s_ref, o_ref, key_s):
+        b, i = pl.program_id(0), pl.program_id(1)
+        last = p_ref[b] + (i + 1) * tq - 1
+
+        @pl.when(i * tq >= fed_ref[b])
+        def _pads():
+            o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+        pl.when(i * tq < fed_ref[b])(
+            lambda: search(last, s_ref, o_ref, key_s))
+
+    return kernel
+
+
+@partial(jax.jit, static_argnames=("topk", "interpret"))
+def _topk_mask(p, fed, score, topk, interpret):
+    """The kernel ``dsa_topk``: ``dsa_threshold_mask`` a block of rows
+    at a time, the rows resident in VMEM for all 32 passes."""
+    B, S, C = score.shape
+    tq = _pk._divisor_block(S, 32)
+    chunk = _pk._divisor_block(C, 2048)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B, S // tq),
+        in_specs=[pl.BlockSpec((None, tq, C), lambda b, i, p, f: (b, i, 0))],
+        out_specs=pl.BlockSpec((None, tq, C), lambda b, i, p, f: (b, i, 0)),
+        scratch_shapes=[pltpu.VMEM((tq, C), jnp.int32)])
+    return _pk.pallas_call(
+        _topk_kernel(tq, C, chunk, topk), name="dsa_topk",
+        out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.int8),
+        grid_spec=grid_spec, interpret=interpret,
+        **_compiler_params(("parallel", "parallel")))(p, fed, score)
+
+
+def _index_pallas(attrs, inputs, aux, is_train, rng):
+    q, k, w, p, new_cursor, topk = _index_prologue(attrs, inputs, aux,
+                                                   is_train)
+    interpret = _pk._interpret()
+    pool, = _pk._cache_write(p, (k,), (aux[0],), interpret=interpret,
+                             name="dsa_write")
+    # a slot at S = 1 is scored whether it is fed or not (one row)
+    fed = jnp.maximum(new_cursor.reshape(-1) - p, 1 if q.shape[1] == 1 else 0)
+    score = _index_scores(p, fed, q, w, pool, interpret=interpret)
+    return ([_topk_mask(p, fed, score, topk=topk, interpret=interpret)],
+            [pool, new_cursor])
+
+
+def _aligned(*sizes):
+    return all(s % 128 == 0 for s in sizes)
+
+
+def _index_eligible(attrs, in_shapes, in_dtypes):
+    if len(in_shapes) < 6 or len(in_shapes[0]) != 3:
+        return False
+    if str(in_dtypes[0]) not in ("float32", "bfloat16"):
+        return False
+    capacity, _Hi, d, _dr, _topk = _index_geometry(attrs)
+    return _pk._interpret() or _aligned(capacity, d)
+
+
+DSA_SLOT_STATE = {"index_k": "rows", "cache_pos": "cursor"}
+
+#: one head's blocks of the window kernel at the published sizes: 256
+#: queries, a key block of 512, the scores and their sum; the top-k's
+#: rows are declared by the chunk it counts at a time
+_DSA_KSPEC = {
+    "tiles": [((256, 128), "bfloat16")] * 2
+    + [((512, 128), "bfloat16")] * 2 + [((256, 512), "float32")] * 4
+    + [((32, 2048), "float32")] * 4,
+    "dtypes": ("float32", "bfloat16"),
+}
+
+register("dsa_index_select", inputs=("q", "k", "weights", "fed"),
+         aux=tuple(DSA_SLOT_STATE), full=_index_fwd, stateful_infer=True,
+         aux_dtypes={"cache_pos": "int32"}, infer_shape=_index_infer,
+         attr_spec={"capacity": (parse_int, None),
+                    "n_heads": (parse_int, None),
+                    "head_dim": (parse_int, None),
+                    "rope_dim": (parse_int, None),
+                    "topk": (parse_int, None),
+                    "rope_base": (parse_float, 10000.0)},
+         slot_state=DSA_SLOT_STATE, donate_aux=True,
+         variants={"pallas": (_index_pallas, _index_eligible, _DSA_KSPEC)},
+         doc="DSA lightning indexer over a per-slot pool of index keys: "
+             "the top-k positions of every query, as a mask (ops/mla.py).")
+
+
+# ------------------------------------------------------------ the attention
+def _mla_geometry(attrs):
+    return (parse_int(attrs["capacity"]), parse_int(attrs["n_heads"]),
+            parse_int(attrs["nope_dim"]), parse_int(attrs["rope_dim"]),
+            parse_int(attrs["v_dim"]), parse_int(attrs["kv_rank"]))
+
+
+def latent_width(kv_rank, rope_dim):
+    """Lanes of a latent row: ``kv_rank + rope_dim`` in whole tiles of
+    128 (module docstring)."""
+    return -(-(int(kv_rank) + int(rope_dim)) // 128) * 128
+
+
+def _lanes(x, width):
+    """``x`` with zeros appended to its last axis up to ``width``."""
+    grow = [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])]
+    return jnp.pad(x, grow) if width > x.shape[-1] else x
+
+
+def _mla_prologue(attrs, inputs, aux, is_train):
+    """What both lowerings share: the cursor, the new latent rows
+    (``c_kv`` normalised, ``k_r`` rotated) at the pool's dtype, the
+    queries split and rotated, ``W_kvb`` by head."""
+    if is_train:
+        raise MXNetError("mla_attention_decode is an inference op")
+    q, kv, selection, fed, gamma, kvb = inputs
+    pool, cursor = aux
+    capacity, H, dn, dr, dv, rank = _mla_geometry(attrs)
+    B, S = q.shape[:2]
+    p, new_cursor = _cursor(fed, cursor, S, capacity)
+    base = parse_float(attrs.get("rope_base", 10000.0))
+    pos = _positions(p, S)
+    c = rms_norm(kv[..., :rank], gamma,
+                 parse_float(attrs.get("rms_eps", 1e-5)))
+    row = _lanes(jnp.concatenate(
+        [c, rope_interleaved(kv[..., rank:], pos, base)], axis=-1),
+        pool.shape[-1])
+    q = q.reshape(B, S, H, dn + dr)
+    q_r = rope_interleaved(q[..., dn:], pos[:, :, None], base)
+    kvb = kvb.reshape(H, dn + dv, rank)
+    return (q[..., :dn], q_r, row.astype(pool.dtype)[:, None], selection,
+            kvb[:, :dn], kvb[:, dn:], p, new_cursor,
+            float(dn + dr) ** -0.5)
+
+
+def _mla_fwd(attrs, inputs, aux, is_train, rng):
+    """The expanded form: every pool row's ``k_n`` and ``v`` of every
+    head, dense scores, the selection as the softmax's mask."""
+    from ..rtc import _write_rows
+    q_n, q_r, row, sel, w_kb, w_vb, p, new_cursor, scale = _mla_prologue(
+        attrs, inputs, aux, is_train)
+    pool, = _write_rows([row], [aux[0]], p)
+    rank = w_kb.shape[-1]
+    B, S, H, _ = q_n.shape
+    c_all = pool[:, 0, :, :rank].astype(q_n.dtype)
+    r_all = pool[:, 0, :, rank:rank + q_r.shape[-1]].astype(q_n.dtype)
+    dtype = q_n.dtype
+    k_n = _mm("bkc,hnc->bhkn", c_all, w_kb.astype(dtype)).astype(dtype)
+    v = _mm("bkc,hvc->bhkv", c_all, w_vb.astype(dtype)).astype(dtype)
+    logits = (_mm("bshn,bhkn->bhsk", q_n, k_n)
+              + _mm("bshr,bkr->bhsk", q_r, r_all)) * scale
+    probs = jax.nn.softmax(
+        jnp.where(sel[:, None] != 0, logits, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhsk,bhkv->bshv", probs, v.astype(_F32),
+                     precision=_HI)
+    return [out.reshape(B, S, -1).astype(q_n.dtype)], [pool, new_cursor]
+
+
+def _mla_infer(attrs, in_shapes):
+    q_s = in_shapes[0]
+    if q_s is None:
+        return in_shapes, [None], [None, None]
+    capacity, H, dn, dr, dv, rank = _mla_geometry(attrs)
+    B, S = q_s[:2]
+    return ([(B, S, H * (dn + dr)), (B, S, rank + dr), (B, S, capacity),
+             (B,), (rank,), (H * (dn + dv), rank)],
+            [(B, S, H * dv)],
+            [(B, 1, capacity, latent_width(rank, dr)), (B, 1)])
+
+
+def _mla_attn_kernel(hg, R, bk, rank, scale, window):
+    """Grid (slot, head group, query block, key block): online softmax
+    of ``hg`` groups of ``R`` query rows against a block of latent rows
+    under the selection. In a window a group is a head and its rows
+    are ``R`` positions, masked row by row, and a block whose positions
+    are all past ``fed`` (pads) comes out zero without a product; at
+    S = 1 the one group's rows are the heads, which share the query's
+    mask row."""
+    def kernel(p_ref, fed_ref, q_ref, k_ref, sel_ref, o_ref, m_s, l_s,
+               acc_s):
+        b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+        last = p_ref[b] + ((i + 1) * R - 1 if window else 0)
+        fed = (i * R < fed_ref[b]) if window else True
+
+        @pl.when(j == 0)
+        def _init():
+            m_s[...] = jnp.full(m_s.shape, -jnp.inf, _F32)
+            l_s[...] = jnp.zeros(l_s.shape, _F32)
+            acc_s[...] = jnp.zeros(acc_s.shape, _F32)
+
+        @pl.when((j * bk <= last) & fed)
+        def _block():
+            k = k_ref[...]
+            c = k[:, :rank]
+            mask = sel_ref[...].astype(jnp.int32) != 0
+
+            def group(g, carry):
+                s = lax.dot_general(q_ref[g], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=_F32) * scale
+                s = jnp.where(mask, s, -jnp.inf)
+                m = m_s[g]
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+                e = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
+                corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+                m_s[g] = m_new
+                l_s[g] = l_s[g] * corr + jnp.sum(e, axis=-1, keepdims=True)
+                acc_s[g] = acc_s[g] * corr + jnp.dot(
+                    e.astype(c.dtype), c, preferred_element_type=_F32)
+                return carry
+            lax.fori_loop(0, hg, group, 0)
+
+        @pl.when(j == pl.num_programs(3) - 1)
+        def _emit():
+            o_ref[...] = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)) \
+                .astype(o_ref.dtype)
+    return kernel
+
+
+@partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def _mla_attend(p, fed, q, pool, sel, rank, scale, interpret):
+    """The kernel ``mla_attn_decode`` / ``mla_attn_window``: queries
+    ``q (B, H, S, rank + rope_dim)`` in the latent space against the
+    pool under ``sel`` -> the weighted sums of ``c_kv``, (B, H, S, rank)
+    at ``q``'s dtype. Blocks past a query block's last position are
+    neither fetched nor computed."""
+    B, H, S, dq = q.shape
+    C = pool.shape[2]
+    keys = pool.reshape(B, C, dq)
+    if S == 1:
+        q = q.reshape(B, 1, H, dq)
+        G, hg, R, bk = 1, 1, H, _pk._divisor_block(C, 2048)
+    else:
+        G, hg = H, _pk._divisor_block(H, 16)
+        R, bk = _pk._divisor_block(S, 256), _pk._divisor_block(C, 512)
+    n_q = 1 if S == 1 else S // R
+    window = S > 1
+
+    def q_map(b, g, i, j, p_ref, fed_ref):
+        return b, g, i, 0
+
+    def last_block(b, i, p_ref, fed_ref):
+        # a dead block re-references the last live one: no copy
+        last = p_ref[b] + ((i + 1) * R - 1 if window else 0)
+        if window:
+            last = jnp.where(i * R < fed_ref[b], last, 0)
+        return last // bk
+
+    def k_map(b, g, i, j, p_ref, fed_ref):
+        return b, jnp.minimum(j, last_block(b, i, p_ref, fed_ref)), 0
+
+    def sel_map(b, g, i, j, p_ref, fed_ref):
+        return b, i, jnp.minimum(j, last_block(b, i, p_ref, fed_ref))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B, G // hg, n_q, C // bk),
+        in_specs=[pl.BlockSpec((None, hg, R, dq), q_map),
+                  pl.BlockSpec((None, bk, dq), k_map),
+                  pl.BlockSpec((None, R if window else 1, bk), sel_map)],
+        out_specs=pl.BlockSpec((None, hg, R, rank), q_map),
+        scratch_shapes=[pltpu.VMEM((hg, R, 1), _F32),
+                        pltpu.VMEM((hg, R, 1), _F32),
+                        pltpu.VMEM((hg, R, rank), _F32)])
+    out = _pk.pallas_call(
+        _mla_attn_kernel(hg, R, bk, rank, scale, window),
+        name="mla_attn_window" if window else "mla_attn_decode",
+        out_shape=jax.ShapeDtypeStruct(q.shape[:3] + (rank,), q.dtype),
+        grid_spec=grid_spec, interpret=interpret,
+        **_compiler_params(("parallel", "parallel", "parallel",
+                            "arbitrary")))(p, fed, q, keys, sel)
+    return out.reshape(B, H, S, rank)
+
+
+def _mla_pallas(attrs, inputs, aux, is_train, rng):
+    """The absorbed form (module docstring): one pass over the live
+    blocks of the pool for all heads."""
+    q_n, q_r, row, sel, w_kb, w_vb, p, new_cursor, scale = _mla_prologue(
+        attrs, inputs, aux, is_train)
+    interpret = _pk._interpret()
+    pool, = _pk._cache_write(p, (row,), (aux[0],), interpret=interpret,
+                             name="mla_write")
+    B, S, H, _ = q_n.shape
+    rank = w_kb.shape[-1]
+    dtype = q_n.dtype
+    q_c = _mm("bshn,hnc->bhsc", q_n, w_kb.astype(dtype))
+    q = _lanes(jnp.concatenate(
+        [q_c.astype(pool.dtype),
+         q_r.transpose(0, 2, 1, 3).astype(pool.dtype)], axis=-1),
+        pool.shape[-1])
+    o_c = _mla_attend(p, new_cursor.reshape(-1) - p, q, pool, sel,
+                      rank=rank, scale=scale, interpret=interpret)
+    out = _mm("bhsc,hvc->bshv", o_c.astype(dtype), w_vb.astype(dtype))
+    return [out.reshape(B, S, -1).astype(dtype)], [pool, new_cursor]
+
+
+def _mla_eligible(attrs, in_shapes, in_dtypes):
+    if len(in_shapes) < 8 or len(in_shapes[0]) != 3:
+        return False
+    if str(in_dtypes[0]) not in ("float32", "bfloat16"):
+        return False
+    capacity, _H, _dn, dr, _dv, rank = _mla_geometry(attrs)
+    return _pk._interpret() or (_aligned(capacity, rank) and dr % 64 == 0)
+
+
+MLA_SLOT_STATE = {"latent": "rows", "cache_pos": "cursor"}
+
+#: one head's blocks at the published sizes (256 queries, latent rows
+#: of 576 in 640 lanes, a key block of 512, scores and accumulator)
+_MLA_KSPEC = {
+    "tiles": [((256, 640), "bfloat16")] * 2
+    + [((512, 640), "bfloat16")] * 2 + [((256, 512), "bfloat16")] * 2
+    + [((256, 512), "float32")] * 5,
+    "dtypes": ("float32", "bfloat16"),
+}
+
+register("mla_attention_decode",
+         inputs=("q", "kv", "selection", "fed", "kv_norm_weight",
+                 "kv_b_weight"),
+         aux=tuple(MLA_SLOT_STATE), full=_mla_fwd, stateful_infer=True,
+         aux_dtypes={"cache_pos": "int32"}, infer_shape=_mla_infer,
+         attr_spec={"capacity": (parse_int, None),
+                    "n_heads": (parse_int, None),
+                    "nope_dim": (parse_int, None),
+                    "rope_dim": (parse_int, None),
+                    "v_dim": (parse_int, None),
+                    "kv_rank": (parse_int, None),
+                    "rms_eps": (parse_float, 1e-5),
+                    "rope_base": (parse_float, 10000.0)},
+         slot_state=MLA_SLOT_STATE, donate_aux=True,
+         variants={"pallas": (_mla_pallas, _mla_eligible, _MLA_KSPEC)},
+         doc="Multi-head latent attention over a per-slot pool of latent "
+             "rows, under a selection of positions (ops/mla.py).")

@@ -29,13 +29,44 @@ rows through two grouped-matmul kernels named ``moe_gmm_*`` in the
 device trace; at a decode step it reads each touched expert's weights
 once and no untouched expert's.
 
+**A chip's share of a wider layer** (the DeepSeek-V3 family as GLM-5.2
+runs it; expert parallelism without its exchange). With
+``scoring="sigmoid"`` the router is ``noaux_tc``: ``sc = sigmoid(float32(
+x @ router_weight.T))``, the ``top_k`` largest of ``sc + router_bias``
+are chosen (``router_bias``), and their weights are ``sc`` itself,
+normalised over the chosen (``norm_topk``) and times ``scaling`` - the
+bias steers the choice and never the weights. ``held_first`` /
+``held_count`` say which experts this layer holds: ``num_experts`` stays
+the router's published width and every token is routed over all of
+them, but the stacked weights are the held experts' alone
+(``(held_count, D, F)``) and the grouped matmuls run over the
+assignments that landed on them; what the absent experts would add is
+left out. ``shared_hidden`` adds a shared expert of that width
+(``shared_gate_weight`` / ``shared_up_weight`` (D, Fs),
+``shared_down_weight`` (Fs, D)) that every token passes through.
+``step_len`` (with the input ``fed`` (slots,) after the rows: how many
+of each slot's ``step_len`` rows are real tokens, the decode ops'
+``fed``) keeps the pads of a window out of the routed experts: a pad's
+choices land nowhere and are not counted (its output is the shared
+expert's alone, a don't-care). Without it every pad is a token, and
+the pads of one window, all alike, pile onto the same experts. The
+sorted held rows are taken ``_segment_rows`` at a time in a loop of as
+many trips as the load needs - one at the expected load - so that no
+assignment is dropped and no buffer is sized for all of them. With none
+of these set the op is the one above, to the bit.
+
 Where the tokens went is counted on the device: the op's ``moe_stats``
 aux cell (int32 ``[1, assignments, experts_touched, max_expert_load]``
 of the latest forward) is overwritten by every forward, inference
 included (``stateful_infer``); ``serve.decode`` reads it after the
 fetch it already makes and feeds the ``serve.decode.moe.*`` counters.
+A layer that holds a share counts its held experts there, all the
+assignments the router made, and fifth the assignments that landed
+here.
 """
 from __future__ import annotations
+
+from collections import namedtuple
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +75,18 @@ from jax import lax
 from ..base import parse_bool, parse_float, parse_int
 from .registry import register
 
-__all__ = ["rms_norm", "moe_route", "moe_sort", "moe_combine"]
+__all__ = ["rms_norm", "moe_route", "moe_sort", "moe_combine",
+           "moe_route_sigmoid", "cpu_wide"]
+
+
+def cpu_wide(*arrays):
+    """Operands of a product that accumulates in float32
+    (``preferred_element_type``): as they are, but float32 on the CPU
+    backend, which has no bfloat16 product of that kind - the same
+    numbers, since a bfloat16 product is exact in float32."""
+    if jax.default_backend() == "cpu":
+        return [a.astype(jnp.float32) for a in arrays]
+    return list(arrays)
 
 
 # ------------------------------------------------------------------ RMSNorm
@@ -128,6 +170,136 @@ def moe_stats(group_sizes):
                       jnp.max(group_sizes)]).astype(jnp.int32)
 
 
+def moe_route_sigmoid(x, router_weight, router_bias, top_k, norm_topk,
+                      scaling):
+    """``(weights (T, k) float32, experts (T, k) int32)`` of the
+    ``noaux_tc`` router (module docstring): scores in float32 from
+    float32-accumulated logits, the bias in the choice alone."""
+    rows, router = cpu_wide(x, router_weight.astype(x.dtype))
+    score = jax.nn.sigmoid(jnp.dot(rows, router.T,
+                                   preferred_element_type=jnp.float32))
+    choice = score if router_bias is None \
+        else score + router_bias.astype(jnp.float32)
+    _, experts = lax.top_k(choice, top_k)
+    weights = jnp.take_along_axis(score, experts, axis=-1)
+    if norm_topk:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
+    return weights * scaling, experts.astype(jnp.int32)
+
+
+def _segment_rows(assignments):
+    """Sorted held rows a trip of ``_held_experts``' loop takes: all of
+    them up to 1,024, an eighth beyond (a sixteenth is the expected
+    load of a sixteenth of the experts)."""
+    return max(min(assignments, 1024), assignments // 8)
+
+
+def _held_experts(x, weights, experts, real, first, count, gate, up, down,
+                  experts_fn):
+    """The held experts' part of every real token's weighted sum,
+    float32 (T, D), and the held group sizes (count,); ``real`` (T,)
+    bool, or None for every row."""
+    T, k = experts.shape
+    M = T * k
+    local = experts - first
+    here = (local >= 0) & (local < count)
+    if real is not None:
+        here = here & real[:, None]
+    local = jnp.where(here, local, count)
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[flat].add(1)[:count]
+    ends = jnp.cumsum(sizes)
+    starts, total = ends - sizes, ends[-1]
+    R = _segment_rows(M)
+    order = jnp.pad(order, (0, R))
+    flat_w = weights.reshape(-1)
+
+    def trip(i, out):
+        lo = i * R
+        rows = lax.dynamic_slice(order, (lo,), (R,))
+        token = rows // k
+        part = jnp.clip(ends, lo, lo + R) - jnp.clip(starts, lo, lo + R)
+        y = experts_fn(x[token], part, gate, up, down)
+        live = (lo + jnp.arange(R, dtype=jnp.int32) < total)[:, None]
+        y = jnp.where(live, y * flat_w[rows][:, None], 0.0)
+        return out.at[token].add(y)
+
+    out = lax.fori_loop(0, (total + R - 1) // R, trip,
+                        jnp.zeros((T, x.shape[1]), jnp.float32))
+    return out, sizes
+
+
+def _dense_expert(x, gate, up, down):
+    """One gated-SiLU expert over every row, float32 accumulation."""
+    f32 = jnp.float32
+    rows, gate, up, down = cpu_wide(x, *(w.astype(x.dtype)
+                                         for w in (gate, up, down)))
+    g = jnp.dot(rows, gate, preferred_element_type=f32)
+    u = jnp.dot(rows, up, preferred_element_type=f32)
+    h, = cpu_wide((jax.nn.silu(g) * u).astype(x.dtype))
+    return jnp.dot(h, down, preferred_element_type=f32)
+
+
+_Share = namedtuple("_Share",
+                    "sigmoid bias scaling first count shared step_len")
+
+
+def _share_spec(attrs):
+    """What makes a layer a share of a wider one (module docstring),
+    None for the plain layer."""
+    E = parse_int(attrs["num_experts"])
+    sigmoid = str(attrs.get("scoring", "softmax")) == "sigmoid"
+    bias = parse_bool(attrs.get("router_bias", False))
+    scaling = parse_float(attrs.get("scaling", 1.0))
+    first = parse_int(attrs.get("held_first", 0))
+    count = parse_int(attrs.get("held_count", 0)) or E
+    shared = parse_int(attrs.get("shared_hidden", 0))
+    step_len = parse_int(attrs.get("step_len", 0))
+    if not (sigmoid or bias or shared or step_len or scaling != 1.0
+            or (first, count) != (0, E)):
+        return None
+    if first < 0 or first + count > E:
+        raise ValueError(f"MoEFFN holds experts {first}..{first + count} "
+                         f"of {E}")
+    return _Share(sigmoid, bias, scaling, first, count, shared, step_len)
+
+
+def moe_share_ffn(attrs, inputs, experts_fn):
+    """``moe_ffn`` for a layer that is a share of a wider one (module
+    docstring): ``([out, experts], [stats])``, ``stats`` with the
+    assignments that landed here fifth."""
+    share = _share_spec(attrs)
+    x, rest = inputs[0], list(inputs[1:])
+    real = None
+    if share.step_len:
+        fed = rest.pop(0).astype(jnp.int32)
+        real = (jnp.arange(share.step_len, dtype=jnp.int32)[None, :]
+                < fed[:, None]).reshape(-1)
+    router = rest.pop(0)
+    router_bias = rest.pop(0) if share.bias else None
+    gate, up, down = rest[:3]
+    top_k = parse_int(attrs.get("top_k", 1))
+    norm_topk = parse_bool(attrs.get("norm_topk", False))
+    if share.sigmoid:
+        weights, experts = moe_route_sigmoid(x, router, router_bias, top_k,
+                                             norm_topk, share.scaling)
+    else:
+        weights, experts = moe_route(x, router, top_k, norm_topk)
+        weights = weights * share.scaling
+    out, sizes = _held_experts(x, weights, experts, real, share.first,
+                               share.count, gate, up, down, experts_fn)
+    if share.shared:
+        out = out + _dense_expert(x, *rest[3:6])
+    routed = experts.size if real is None \
+        else top_k * jnp.sum(real.astype(jnp.int32))
+    stats = jnp.stack([jnp.int32(1), jnp.asarray(routed, jnp.int32),
+                       jnp.sum((sizes > 0).astype(jnp.int32)),
+                       jnp.max(sizes), jnp.sum(sizes)]).astype(jnp.int32)
+    return [out.astype(x.dtype), experts], [stats]
+
+
 def _experts_ragged(xs, group_sizes, gate, up, down):
     """(M, D) sorted rows -> (M, D) float32: the three grouped matmuls
     over exactly the routed rows, float32 accumulation."""
@@ -145,6 +317,8 @@ def moe_ffn(attrs, inputs, experts_fn):
     """The op's body with the grouped expert computation supplied:
     ``experts_fn(xs, group_sizes, gate, up, down) -> (M, D) float32``.
     Returns ``([out, experts], [stats])``."""
+    if _share_spec(attrs) is not None:
+        return moe_share_ffn(attrs, inputs, experts_fn)
     x, router, gate, up, down = inputs
     num_experts = gate.shape[0]
     top_k = parse_int(attrs.get("top_k", 1))
@@ -165,25 +339,63 @@ def _moe_infer(attrs, in_shapes):
     E = parse_int(attrs["num_experts"])
     F = parse_int(attrs["num_hidden"])
     k = parse_int(attrs.get("top_k", 1))
+    share = _share_spec(attrs)
+    stats = [(4,) if share is None else (5,)]
     if data_s is None:
-        return in_shapes, [None, None], [(4,)]
+        return in_shapes, [None, None], stats
     if len(data_s) != 2:
         raise ValueError(f"MoEFFN takes (tokens, width) rows, got {data_s}")
     T, D = data_s
-    return ([data_s, (E, D), (E, D, F), (E, D, F), (E, F, D)],
-            [data_s, (T, k)], [(4,)])
+    held = E if share is None else share.count
+    shared = 0 if share is None else share.shared
+    shapes = [data_s]
+    if share is not None and share.step_len:
+        if T % share.step_len:
+            raise ValueError(f"MoEFFN: {T} rows are no whole slots of "
+                             f"step_len {share.step_len}")
+        shapes.append((T // share.step_len,))
+    shapes.append((E, D))
+    if share is not None and share.bias:
+        shapes.append((E,))
+    shapes += [(held, D, F), (held, D, F), (held, F, D)]
+    if shared:
+        shapes += [(D, shared), (D, shared), (shared, D)]
+    return shapes, [data_s, (T, k)], stats
 
 
-_MOE_INPUTS = ("data", "router_weight", "gate_weight", "up_weight",
-               "down_weight")
+def _moe_inputs(attrs):
+    """The op's inputs: the plain layer's five, ``fed`` behind the
+    rows where pads are kept out (``step_len``), a ``router_bias``
+    where the router has one, a shared expert's three weights where it
+    has one."""
+    share = _share_spec(attrs) if attrs.get("num_experts") else None
+    names = ["data"]
+    if share is not None and share.step_len:
+        names.append("fed")
+    names.append("router_weight")
+    if share is not None and share.bias:
+        names.append("router_bias")
+    names += ["gate_weight", "up_weight", "down_weight"]
+    if share is not None and share.shared:
+        names += ["shared_gate_weight", "shared_up_weight",
+                  "shared_down_weight"]
+    return names
 
-register("MoEFFN", inputs=_MOE_INPUTS, aux=("moe_stats",), full=_moe_fwd,
+
+register("MoEFFN", inputs=_moe_inputs, aux=("moe_stats",), full=_moe_fwd,
          num_outputs=2, output_names=["output", "experts"], num_visible=1,
          stateful_infer=True, aux_dtypes={"moe_stats": "int32"},
          attr_spec={"num_experts": (parse_int, None),
                     "num_hidden": (parse_int, None),
                     "top_k": (parse_int, 1),
-                    "norm_topk": (parse_bool, False)},
+                    "norm_topk": (parse_bool, False),
+                    "scoring": (None, "softmax"),
+                    "router_bias": (parse_bool, False),
+                    "scaling": (parse_float, 1.0),
+                    "held_first": (parse_int, 0),
+                    "held_count": (parse_int, 0),
+                    "shared_hidden": (parse_int, 0),
+                    "step_len": (parse_int, 0)},
          infer_shape=_moe_infer,
          doc="Routed expert feed-forward: top_k of num_experts gated-SiLU "
              "experts of width num_hidden per token (ops/moe.py).")

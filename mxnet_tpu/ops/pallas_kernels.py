@@ -1177,8 +1177,11 @@ def cache_write(news, pools, pos):
                              tuple(pools), interpret=_interpret()))
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def _cache_write(pos, news, pools, interpret):
+@partial(jax.jit, static_argnames=("interpret", "name"))
+def _cache_write(pos, news, pools, interpret, name="cache_write"):
+    """``cache_write`` as one jitted program; ``name`` is the kernel's
+    in the device trace (``ops/mla.py`` lands its latent rows and index
+    keys through this under names of its own)."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, Dh = news[0].shape
@@ -1208,7 +1211,7 @@ def _cache_write(pos, news, pools, interpret):
         _cache_write_kernel(hb, S, bt, n_blocks, C, n),
         out_shape=tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
                         for p in pools),
-        grid_spec=grid_spec, name="cache_write", interpret=interpret,
+        grid_spec=grid_spec, name=name, interpret=interpret,
         input_output_aliases={1 + n + i: i for i in range(n)},
         **kwargs)(pos, *news, *pools)
 
@@ -1340,10 +1343,19 @@ def grouped_matmul(x, weights, items, tm, epilogue, out_dtype, name):
         out_specs=pl.BlockSpec((tm, N), o_map),
         scratch_shapes=[pltpu.VMEM((tm, N), jnp.float32)
                         for _ in weights])
+    # double-buffered blocks and the accumulators; past the 16 MiB a
+    # kernel gets unasked (rows of 6,144: a 3 MiB output tile) it asks
+    itemsize = weights[0].dtype.itemsize
+    resident = 2 * tm * bk * itemsize + len(weights) * (
+        2 * bk * N * itemsize + tm * N * 4) \
+        + 2 * tm * N * jnp.dtype(out_dtype).itemsize
+    kwargs = {} if _interpret() or resident <= (10 << 20) else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=4 * resident)}
     return pallas_call(
         _gmm_kernel(len(weights), tm, epilogue),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        grid_spec=grid_spec, name=name)(*items, x, *weights)
+        grid_spec=grid_spec, name=name, **kwargs)(*items, x, *weights)
 
 
 def grouped_expert_ffn(xs, group_sizes, gate, up, down):
@@ -1378,25 +1390,28 @@ def _moe_variant(attrs, inputs, aux, is_train, rng):
 def _moe_eligible(attrs, in_shapes, in_dtypes):
     """Lane-aligned widths (any in interpret mode), float rows, and
     weight tiles of full output width within the declared tile set."""
-    if len(in_shapes) < 5 or len(in_shapes[0]) != 2 \
-            or len(in_shapes[2]) != 3:
+    # the gate weights follow the router's (fed before it and its bias
+    # behind it, if any)
+    gate = next((s for s in in_shapes[2:5] if len(s) == 3), None)
+    if len(in_shapes) < 5 or len(in_shapes[0]) != 2 or gate is None:
         return False
     if str(in_dtypes[0]) not in ("float32", "bfloat16", "float16"):
         return False
-    D, F = in_shapes[2][1], in_shapes[2][2]
-    if max(D, F) > 2048:
+    D, F = gate[1], gate[2]
+    if max(D, F) > 6144:
         return False
     return (D % 128 == 0 and F % 128 == 0) or _interpret()
 
 
-#: worst case at the eligibility bounds (widths <= 2048, 1 MiB weight
-#: tiles): the row tile, two weight tiles and the output tile double
-#: buffered by the pipeline, and two float32 accumulators
+#: worst case at the eligibility bounds (widths <= 6144, the down
+#: matmul of a 6,144-wide model: weight tiles of 128 rows): the row
+#: tile, the weight tile and the output tile double buffered by the
+#: pipeline, and the float32 accumulator
 _MOE_KSPEC = {
-    "tiles": [((_GMM_ROWS, 2048), "float32")] * 2       # rows
-    + [((128, 2048), "float32")] * 4                    # gate, up tiles
-    + [((_GMM_ROWS, 2048), "float32")] * 2              # out tile
-    + [((_GMM_ROWS, 2048), "float32")] * 2,             # accumulators
+    "tiles": [((_GMM_ROWS, 128), "float32")] * 2        # rows
+    + [((128, 6144), "bfloat16")] * 2                   # down tiles
+    + [((_GMM_ROWS, 6144), "float32")] * 2              # out tile
+    + [((_GMM_ROWS, 6144), "float32")],                 # accumulator
     "dtypes": ("float32", "bfloat16", "float16"),
 }
 

@@ -617,9 +617,12 @@ class DecodeEngine:
 #: ``serve.decode.<name>`` counters of a routed decoder, in the order
 #: of ``BatchedKVCacheDecoder.moe_stats``: MoEFFN layer executions, the
 #: (token, expert) assignments they made, the experts that got at least
-#: one, and each execution's busiest expert's assignments
+#: one, each execution's busiest expert's assignments and, where the
+#: layer holds a share of its experts (the counts are then of the held
+#: ones), the assignments that landed on them
 _MOE_COUNTERS = ("moe.layer_steps", "moe.assignments",
-                 "moe.experts_touched", "moe.max_expert_load")
+                 "moe.experts_touched", "moe.max_expert_load",
+                 "moe.held_assignments")
 
 #: ``serve.decode.<name>`` counters of a decoder whose state is a window
 #: of exact rows beside summaries, in the order of
@@ -629,6 +632,16 @@ _MOE_COUNTERS = ("moe.layer_steps", "moe.assignments",
 #: windows closed (per slot and layer); from the host's cursors, no fetch
 _EVA_COUNTERS = ("eva.layer_steps", "eva.exact_rows", "eva.summary_rows",
                  "eva.chunks_summarised", "eva.windows_closed")
+
+
+#: ``serve.decode.<name>`` counters of a decoder that attends a learned
+#: selection of positions, in the order of ``BatchedKVCacheDecoder
+#: ._selection_reads``: attention layer executions, the positions at or
+#: before each fed slot's last real query, those it attends (at most
+#: ``index_topk``) and the index keys scored for it (per slot, layer
+#: and dispatch); from the host's cursors, no fetch
+_DSA_COUNTERS = ("dsa.layer_steps", "dsa.live_rows", "dsa.selected_rows",
+                 "dsa.scored_rows")
 
 
 class DecodeScheduler:
@@ -777,6 +790,9 @@ class DecodeScheduler:
             if not self.engine.positional:
                 handles.update({k: self._counter(k)
                                 for k in _EVA_COUNTERS})
+            if self.engine.driver(self._rung).selects:
+                handles.update({k: self._counter(k)
+                                for k in _DSA_COUNTERS})
             handles.update({k: self._gauge(k) for k in
                             ("active", "occupancy", "queue.depth")})
             handles["step.seconds"] = _telemetry.histogram(
@@ -1024,6 +1040,9 @@ class DecodeScheduler:
                 out = drv.step(tokens, fed=fed)
                 if drv.last_reads is not None:
                     phases["eva"] = phases.get("eva", 0) + drv.last_reads
+                if drv.last_selection is not None:
+                    phases["dsa"] = phases.get("dsa", 0) \
+                        + drv.last_selection
             if last is not None:
                 picked, ids = drv.select_rows(out, last)
                 if rows:
@@ -1239,13 +1258,13 @@ class DecodeScheduler:
             if chunks:
                 m["prefill.chunks"].inc(chunks)
             m["fetch.bytes"].inc(phases["bytes"])
-            moe = phases.get("moe")     # a routed decoder's dispatches
-            if moe is not None:
-                for key, value in zip(_MOE_COUNTERS, moe):
-                    m[key].inc(int(value))
-            eva = phases.get("eva")     # a window-and-summaries state's
-            if eva is not None:
-                for key, value in zip(_EVA_COUNTERS, eva):
+            # what the dispatches counted: a routed decoder's experts, a
+            # window-and-summaries state's reads, a learned selection's
+            moe, eva, dsa = (phases.get(k) for k in ("moe", "eva", "dsa"))
+            for names, counts in ((_MOE_COUNTERS, moe), (_EVA_COUNTERS, eva),
+                                  (_DSA_COUNTERS, dsa)):
+                for key, value in zip(names, () if counts is None
+                                      else counts):
                     m[key].inc(int(value))
             m["step.seconds"].observe(step_s)
             m["active"].set(n_active)
@@ -1267,7 +1286,9 @@ class DecodeScheduler:
                    {"moe_layer_steps": int(moe[0]),
                     "moe_touched": int(moe[2])}),
                 **({} if eva is None else
-                   {"eva_exact": int(eva[1]), "eva_summary": int(eva[2])}))
+                   {"eva_exact": int(eva[1]), "eva_summary": int(eva[2])}),
+                **({} if dsa is None else
+                   {"dsa_selected": int(dsa[2]), "dsa_scored": int(dsa[3])}))
         return max(1, emitted)
 
     def _commit_window(self, meta, ids, picked, S, t0, end, shared_sid,

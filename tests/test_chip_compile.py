@@ -176,6 +176,61 @@ def test_attention_decode_compiles_for_v5e_and_writes_in_place(capacity, S,
         B * H * capacity * d * 2)
 
 
+@pytest.mark.parametrize("S", [1, 1024], ids=["decode", "window"])
+@pytest.mark.parametrize("op", ["dsa_index_select", "mla_attention_decode"])
+def test_latent_attention_compiles_for_v5e_and_copies_no_pool(op, S, v5e):
+    """GLM-5.2's two decode ops at the published sizes (8 slots, a
+    capacity of 32,768, bfloat16; 32 index heads of 128 over 128-wide
+    keys, 64 heads over latent rows of 512 + 64 in 640 lanes): the
+    Pallas lowerings compile for the chip, every pool access is a
+    kernel's, and with the aux arrays donated the pool of either state
+    family - 67 MB of index keys, 336 MB of latent rows - comes back in
+    the buffer it came in, neither copied nor re-laid."""
+    import re
+    B, C = 8, 32768
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    opdef = get_op(op)
+    if op == "dsa_index_select":
+        attrs = dict(capacity=C, n_heads=32, head_dim=128, rope_dim=64,
+                     topk=2048, rope_base=8e6)
+        ins = [sds((B, S, 32 * 128)), sds((B, S, 128)), sds((B, S, 32)),
+               sds((B,), jnp.int32)]
+        width, kernels = 128, ("dsa_write", "dsa_index_scores", "dsa_topk")
+    else:
+        attrs = dict(capacity=C, n_heads=64, nope_dim=192, rope_dim=64,
+                     v_dim=256, kv_rank=512, rope_base=8e6)
+        ins = [sds((B, S, 64 * 256)), sds((B, S, 576)),
+               sds((B, S, C), jnp.int8), sds((B,), jnp.int32), sds((512,)),
+               sds((64 * 448, 512))]
+        width = 640
+        kernels = ("mla_write",
+                   "mla_attn_decode" if S == 1 else "mla_attn_window")
+    attrs = opdef.normalize_attrs(attrs)
+    aux = [sds((B, 1, C, width)), sds((B, 1), jnp.int32)]
+    assert opdef.donate_aux and set(opdef.slot_state.values()) == {
+        "rows", "cursor"}
+    assert opdef.variant_eligible("pallas", attrs,
+                                  [a.shape for a in ins + aux],
+                                  [str(a.dtype) for a in ins + aux])
+    fn = opdef.variant_fn("pallas")
+    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
+                       donate_argnums=(1,)).lower(ins, aux).compile()
+    text = compiled.as_text()
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}[.\w]* = .*tpu_custom_call", text), \
+            kernel
+    pool = rf"= bf16\[{B},1,{C},{width}\]\S* "
+    assert not re.findall(pool + r"copy\(", text)
+    assert not re.findall(pool + r"fusion\(", text)
+    assert " scatter(" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        B * C * width * 2
+
+
 # ------------------------------------------- chip_smoke.py without a chip
 def test_chip_smoke_fails_at_device_check_without_a_chip():
     res = subprocess.run([sys.executable, os.path.join(ROOT,

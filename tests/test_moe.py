@@ -478,3 +478,159 @@ def _gpt2_lowered_s1():
 def test_the_cerebras_shaped_step_program_is_the_parents_bytes():
     text = _gpt2_lowered_s1()
     assert hashlib.sha256(text.encode()).hexdigest() == GPT2_S1_SHA256
+
+
+# --------------------------------------- a chip's share of a wider layer
+def _share_layer(rng, T, D, F, E, Fs):
+    """Inputs of the whole sigmoid-routed layer with a shared expert,
+    and the reference's parameter names for them."""
+    x, router, gate, up, down = _moe_inputs(rng, T, D, F, E)
+    bias = jnp.asarray(rng.normal(size=(E,)) * 0.3, jnp.float32)
+    shared = [jnp.asarray(rng.normal(size=s) * 0.1, jnp.float32)
+              for s in ((D, Fs), (D, Fs), (Fs, D))]
+    named = dict(zip(
+        ["p_moe_router_weight", "p_moe_router_bias", "p_moe_gate_weight",
+         "p_moe_up_weight", "p_moe_down_weight", "p_moe_shared_gate_weight",
+         "p_moe_shared_up_weight", "p_moe_shared_down_weight"],
+        [router, bias, gate, up, down] + shared))
+    return x, router, bias, (gate, up, down), shared, named
+
+
+_SHARE = dict(top_k=4, norm_topk=True, scoring="sigmoid", router_bias=True,
+              scaling=2.5)
+
+
+@pytest.mark.parametrize("variant", ["xla", "pallas"])
+def test_the_sixteen_shares_add_up_to_the_uncut_layer(variant):
+    """Thirty-two experts divided over sixteen shares of two: every
+    share routes over all 32, computes its own experts' part and adds
+    the shared expert; the parts, with the shared expert counted once,
+    equal the reference's uncut layer (chipbench/reference/glm_dsa.py).
+    The held assignments of all shares are all the assignments."""
+    from chipbench.reference import glm_dsa
+    rng = np.random.default_rng(3)
+    T, D, F, E, Fs = 40, 64, 32, 32, 48
+    x, router, bias, experts, shared, named = _share_layer(rng, T, D, F, E,
+                                                           Fs)
+    cfg = {"n_routed_experts": E, "num_experts_per_tok": 4,
+           "norm_topk_prob": True, "routed_scaling_factor": 2.5}
+    with jax.default_matmul_precision("highest"):
+        routed, alone, chosen = glm_dsa.expert_layer(
+            x, "p", named, cfg, lambda a: a, held=(0, E))
+    total, landed = np.zeros((T, D), np.float32), 0
+    for first in range(0, E, 2):
+        held = [w[first:first + 2] for w in experts]
+        out, picked, stats = _run(
+            variant, dict(num_experts=E, num_hidden=F, held_first=first,
+                          held_count=2, shared_hidden=Fs, **_SHARE),
+            [x, router, bias] + held + shared)
+        total += out
+        landed += stats[4]
+        assert stats[1] == T * 4 and stats[2] <= 2
+        np.testing.assert_array_equal(np.sort(picked, -1),
+                                      np.sort(np.asarray(chosen), -1))
+    assert landed == T * 4
+    np.testing.assert_allclose(total - 15 * np.asarray(alone),
+                               np.asarray(routed + alone), atol=2e-4,
+                               rtol=2e-4)
+
+
+def test_the_bias_steers_the_choice_and_never_the_weights():
+    """A bias of +10 on expert 5 puts it among every token's choices;
+    the weights are the sigmoid scores of the chosen, normalised and
+    scaled, with no trace of the bias."""
+    rng = np.random.default_rng(4)
+    T, D, E, k = 16, 64, 8, 2
+    x = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(E, D)) * 0.5, jnp.float32)
+    bias = jnp.zeros((E,), jnp.float32).at[5].set(10.0)
+    w, chosen = moe.moe_route_sigmoid(x, router, bias, k, True, 2.5)
+    assert np.all(np.any(np.asarray(chosen) == 5, axis=1))
+    score = 1.0 / (1.0 + np.exp(-np.asarray(x) @ np.asarray(router).T))
+    picked = np.take_along_axis(score, np.asarray(chosen), axis=1)
+    np.testing.assert_allclose(
+        np.asarray(w), 2.5 * picked / picked.sum(1, keepdims=True),
+        rtol=1e-5)
+    # without the bias, expert 5 is chosen only where its score says so
+    _, free = moe.moe_route_sigmoid(x, router, None, k, True, 2.5)
+    assert not np.all(np.any(np.asarray(free) == 5, axis=1))
+
+
+def test_holding_all_experts_without_a_shared_one_is_the_old_layer():
+    """The OLMoE guard: the share attributes at their neutral values
+    (all experts held, softmax scores, no bias, no shared expert, a
+    scaling of 1) take the layer's old path - the same program, the
+    same output to the bit, four counts."""
+    rng = np.random.default_rng(5)
+    inputs = _moe_inputs(rng, 24, 64, 32, 8)
+    plain = dict(num_experts=8, num_hidden=32, top_k=2)
+    spelt = dict(plain, held_first=0, held_count=8, scoring="softmax",
+                 router_bias=False, scaling=1.0, shared_hidden=0)
+    op = get_op("MoEFFN")
+    assert moe._share_spec(op.normalize_attrs(spelt)) is None
+    assert op.input_names(op.normalize_attrs(spelt)) == [
+        "data", "router_weight", "gate_weight", "up_weight", "down_weight"]
+    for variant in ("xla", "pallas"):
+        a, b = _run(variant, plain, inputs), _run(variant, spelt, inputs)
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(x, y)
+        assert a[2].shape == (4,)
+
+    def lowered(attrs):
+        attrs = op.normalize_attrs(attrs)
+        return jax.jit(lambda i: op.forward(
+            attrs, i, [jnp.zeros((4,), jnp.int32)], False, None)
+        ).lower(inputs).as_text()
+    assert lowered(plain) == lowered(spelt)
+
+
+def test_a_load_past_one_segment_takes_further_trips():
+    """Every token routed to the two held experts (8 x the expected
+    load): the loop over segments of the sorted held rows takes as many
+    trips as the load needs and drops nothing."""
+    rng = np.random.default_rng(6)
+    T, D, F, E = 1024, 64, 32, 32
+    x, router, gate, up, down = _moe_inputs(rng, T, D, F, E)
+    bias = jnp.zeros((E,), jnp.float32).at[jnp.asarray([6, 7])].set(10.0)
+    attrs = dict(num_experts=E, num_hidden=F, top_k=2, norm_topk=True,
+                 scoring="sigmoid", router_bias=True, held_first=6,
+                 held_count=2)
+    assert moe._segment_rows(T * 2) == 1024
+    out, picked, stats = _run("xla", attrs,
+                              [x, router, bias, gate[6:8], up[6:8],
+                               down[6:8]])
+    assert stats[4] == 2 * T and set(np.unique(picked)) == {6, 7}
+    score = 1.0 / (1.0 + np.exp(-np.asarray(x) @ np.asarray(router).T))
+    w = score[:, 6:8] / score[:, 6:8].sum(1, keepdims=True)
+    want = sum(w[:, e:e + 1] * np.asarray(
+        (jax.nn.silu(x @ gate[6 + e]) * (x @ up[6 + e])) @ down[6 + e])
+        for e in range(2))
+    np.testing.assert_allclose(out, want, atol=2e-4, rtol=2e-4)
+
+
+def test_pads_of_a_window_are_routed_nowhere():
+    """``step_len`` with ``fed``: the rows past each slot's real tokens
+    take no routed expert and count nowhere - their output is the
+    shared expert's alone -, and the real rows' outputs are what they
+    are without the pads."""
+    rng = np.random.default_rng(7)
+    slots, S, D, F, E, Fs = 3, 8, 64, 32, 16, 48
+    x, router, bias, experts, shared, _ = _share_layer(rng, slots * S, D, F,
+                                                       E, Fs)
+    held = [w[4:8] for w in experts]
+    attrs = dict(num_experts=E, num_hidden=F, held_first=4, held_count=4,
+                 shared_hidden=Fs, **_SHARE)
+    fed = jnp.asarray([8, 3, 0], jnp.int32)
+    out, _, stats = _run("xla", dict(attrs, step_len=S),
+                         [x, fed, router, bias] + held + shared)
+    every, _, all_stats = _run("xla", attrs, [x, router, bias] + held
+                               + shared)
+    real = (np.arange(S)[None, :] < np.asarray(fed)[:, None]).reshape(-1)
+    np.testing.assert_array_equal(out[real], every[real])
+    alone = np.asarray(moe._dense_expert(x, *shared))
+    np.testing.assert_allclose(out[~real], alone[~real], atol=1e-6)
+    assert stats[1] == 11 * 4 and all_stats[1] == slots * S * 4
+    assert 0 < stats[4] < all_stats[4]
+    op = get_op("MoEFFN")
+    assert op.input_names(op.normalize_attrs(dict(attrs, step_len=S)))[:3] \
+        == ["data", "fed", "router_weight"]
