@@ -902,6 +902,14 @@ class BatchedKVCacheDecoder:
         self._sparse = sparse_selection(module.symbol)
         self.selects = self._sparse is not None
         self.last_selection = None
+        # ``attention_decode`` layers, whose read walks a slot's pool up
+        # to its cursor: what the latest dispatch read of the pools
+        # (``_attention_reads``)
+        self._attn_layers = sum(
+            not n.is_variable and n.op == "attention_decode"
+            for n in module.symbol._topo_nodes())
+        self.attends = self._attn_layers > 0
+        self.last_attention = None
         if not self.positional:
             ring = exe.aux_dict[self._state["window"][0]]
             pool = exe.aux_dict[self._state["summary"][0]]
@@ -1185,6 +1193,8 @@ class BatchedKVCacheDecoder:
             if fed is not None:
                 raise MXNetError("step(fed=...): this graph has no fed "
                                  "input; it advances every slot by S")
+            self.last_attention = self._attention_reads(
+                np.where(self.active, S, 0))
             mod.forward(DataBatch(data=data, label=[]), is_train=False)
             self.pos += S        # the program advances EVERY slot
             return mod.get_outputs()[0]
@@ -1198,9 +1208,23 @@ class BatchedKVCacheDecoder:
         data.append(nd.array(fed.astype(np.int32)))
         self.last_reads = self._state_reads(fed)
         self.last_selection = self._selection_reads(fed)
+        self.last_attention = self._attention_reads(fed)
         mod.forward(DataBatch(data=data, label=[]), is_train=False)
         self.pos += fed
         return mod.get_outputs()[0]
+
+    def _attention_reads(self, fed):
+        """What one dispatch that feeds ``fed`` tokens a slot reads of
+        the ``attention_decode`` pools, from the cursors alone (no
+        fetch), summed over the fed slots and the layers: ``[rows at or
+        before each slot's last query, rows the pools hold (slots x
+        capacity)]``. Their ratio is the share of a pool that a
+        dispatch has any use for. None for a graph without the op."""
+        if not self.attends:
+            return None
+        live = np.minimum((self.pos + fed)[fed > 0], self.capacity)
+        return self._attn_layers * np.asarray(
+            [np.sum(live), self.slots * self.capacity], np.int64)
 
     def _selection_reads(self, fed):
         """What one dispatch that feeds ``fed`` tokens a slot reads

@@ -1013,53 +1013,164 @@ def _register_embedding_variant():
 # owns the op, the RoPE/cache-write prologue, and the registration; the
 # kernel here is only the cursor-bounded attention READ)
 # ==========================================================================
-def _decode_attn_kernel(block_k, s_len, scale):
+#: bytes the read may keep in VMEM: the double-buffered K and V blocks,
+#: the q and out windows, the float32 scratch and one head's float32
+#: working set (under the 16 MiB a kernel gets with no limit of its own)
+_READ_VMEM_BUDGET = 12 << 20
+#: keys a grid step covers at most: a slot's live prefix is read in
+#: whole blocks, so a longer one reads more dead rows than it saves steps
+_READ_BLOCK_K = 512
+#: heads one turn of the read's loop covers: a head is a chain of two
+#: small products with reductions between them, and the chains of a
+#: turn's heads overlap where one head's would wait on itself
+_READ_UNROLL = 8
+
+
+def _narrow(dtype):
+    """Is every value of ``dtype`` a bfloat16 value?"""
+    return jnp.dtype(dtype).itemsize == 1 or dtype == jnp.bfloat16
+
+
+def _decode_attn_resident(S, Dh, block_k, q_dtype, cache_dtype):
+    """``(fixed, per_head)`` bytes of VMEM one grid step of the read
+    holds at a key block of ``block_k``: what does not grow with the
+    head group (one head's working set: its K and V block widened, a few
+    (S, block_k) float32 arrays of scores) and what each head of the
+    group adds (K and V blocks, q and out windows, all double-buffered,
+    and the m, l, acc scratch, an (S, 1) array lying in 128 lanes)."""
+    q_size = jnp.dtype(q_dtype).itemsize
+    rows = -(-S // 8) * 8                    # sublanes a window fills
+    q_rows = -(-S // (32 // q_size)) * (32 // q_size)
+    wide = 2 if _narrow(cache_dtype) else 4
+    fixed = 2 * block_k * Dh * wide + 8 * max(rows, 8) * block_k * 4
+    per_head = (2 * 2 * block_k * Dh * jnp.dtype(cache_dtype).itemsize
+                + 2 * q_rows * Dh * q_size + 2 * rows * Dh * 4
+                + rows * (Dh + 2 * 128) * 4)
+    return fixed, per_head
+
+
+def _decode_attn_blocks(H, S, Dh, C, q_dtype, cache_dtype):
+    """``(hb, block_k)``: the heads and the keys one grid step of the
+    read covers, from the shapes and the dtypes alone. The longest key
+    block first (whole 128-row tiles where the capacity is made of
+    them, at most ``_READ_BLOCK_K``), halved while one head does not
+    fit; then as many heads as ``_READ_VMEM_BUDGET`` holds, a divisor of
+    ``H`` (12 heads go as 12, 6, 4...). At 16 heads of 128 in bfloat16
+    that is all 16 heads x 512 keys at S=1 and 8 x 512 at S=64."""
+    unit = 128 if C % 128 == 0 else 1
+    keys = max(_READ_BLOCK_K, unit)
+    while True:
+        block_k = unit * _divisor_block(C // unit, keys // unit)
+        fixed, per_head = _decode_attn_resident(S, Dh, block_k, q_dtype,
+                                                cache_dtype)
+        if fixed + per_head <= _READ_VMEM_BUDGET or keys <= unit:
+            break
+        keys //= 2
+    hb = _divisor_block(H, max(1, (_READ_VMEM_BUDGET - fixed) // per_head))
+    return hb, block_k
+
+
+def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv):
+    """Grid (slot, head group, key block); the group's heads in a loop
+    of ``_READ_UNROLL`` heads a turn. ``narrow_q``: q and the cache
+    rows are bfloat16 values, so q.K is one bfloat16 product with
+    float32 accumulation, exact as the composition's; ``narrow_kv``:
+    the rows are, so p.V is the float32 p split in three against the
+    bfloat16 rows - the three passes of a full-precision float32
+    product that are not multiplications by zero. Anything else is
+    widened to float32 and multiplied at HIGHEST."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    exact = jax.lax.Precision.HIGHEST
+    turn = _divisor_block(hb, _READ_UNROLL)
+
+    def widen(ref, h, to):
+        x = ref[h]
+        if x.dtype.itemsize == 1 and to != f32:   # fp8: dequantise on read
+            x = x.astype(f32)
+        return x.astype(to)
+
+    def p_times_v(p, v_ref, h):
+        if not narrow_kv:
+            return jnp.dot(p, widen(v_ref, h, f32), precision=exact)
+        v = widen(v_ref, h, bf16)
+        if s_len == 1:
+            p = jnp.broadcast_to(p, (8, block_k))
+        # p = bf16(p) + bf16(rest) + bf16(last), to float32's last bit
+        rest = p - p.astype(bf16).astype(f32)
+        last = rest - rest.astype(bf16).astype(f32)
+        # the three parts ride one product as rows of one operand where
+        # they stack on whole sublane tiles: V crosses to the MXU once
+        if s_len == 1:
+            row = jax.lax.broadcasted_iota(jnp.int32, (8, block_k), 0)
+            lhs = jnp.where(row == 0, p, jnp.where(
+                row == 1, rest, jnp.where(row == 2, last, 0.0)))
+            return jnp.sum(jnp.dot(lhs.astype(bf16), v,
+                                   preferred_element_type=f32),
+                           axis=0, keepdims=True)
+        parts = [x.astype(bf16) for x in (p, rest, last)]
+        if s_len % 16:
+            return sum(jnp.dot(part, v, preferred_element_type=f32)
+                       for part in parts)
+        out = jnp.dot(jnp.concatenate(parts, axis=0), v,
+                      preferred_element_type=f32)
+        return out[:s_len] + out[s_len:2 * s_len] + out[2 * s_len:]
+
     def kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s):
         b = pl.program_id(0)
-        kb = pl.program_id(1)
-        n_kb = pl.num_programs(1)
+        kb = pl.program_id(2)
+        n_kb = pl.num_programs(2)
 
         @pl.when(kb == 0)
         def _init():
-            m_s[...] = jnp.full(m_s.shape, -jnp.inf, jnp.float32)
-            l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
-            acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+            m_s[...] = jnp.full(m_s.shape, -jnp.inf, f32)
+            l_s[...] = jnp.zeros(l_s.shape, f32)
+            acc_s[...] = jnp.zeros(acc_s.shape, f32)
 
-        cursor = pos_ref[b]                  # this row's write position
+        cursor = pos_ref[b]                  # this slot's write position
         k_start = kb * block_k
 
         def update():
-            q = q_ref[...].astype(jnp.float32) * scale     # (S, Dh)
-            k = k_ref[...].astype(jnp.float32)             # (block_k, Dh)
-            v = v_ref[...].astype(jnp.float32)
-            # HIGHEST: match the XLA composition's f32 accumulation;
-            # the astype above is also the fp8-cache dequant on read
-            s = jnp.dot(q, k.T, precision=jax.lax.Precision.HIGHEST)
             # query row i sits at stream position cursor + i and attends
             # key positions <= that (the same comparison as the XLA mask)
             q_pos = cursor + jax.lax.broadcasted_iota(
                 jnp.int32, (s_len, block_k), 0)
             k_pos = k_start + jax.lax.broadcasted_iota(
                 jnp.int32, (s_len, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, -jnp.inf)
-            m = m_s[...]                     # (S, 1) f32
-            m_blk = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m, m_blk)
-            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            p = jnp.exp(s - m_safe)
-            p = jnp.where(jnp.isfinite(s), p, 0.0)
-            corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-            m_s[...] = m_new
-            l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1,
-                                                 keepdims=True)
-            acc_s[...] = acc_s[...] * corr + jnp.dot(
-                p, v, precision=jax.lax.Precision.HIGHEST)
+            attends = k_pos <= q_pos
+
+            def head(h):
+                if narrow_q:
+                    s = jax.lax.dot_general(
+                        q_ref[h], widen(k_ref, h, bf16),
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=f32)
+                else:
+                    s = jax.lax.dot_general(
+                        q_ref[h].astype(f32), widen(k_ref, h, f32),
+                        (((1,), (1,)), ((), ())), precision=exact)
+                s = jnp.where(attends, s * scale, -jnp.inf)
+                m = m_s[h]                   # (S, 1) f32
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+                p = jnp.exp(s - m_safe)      # exp(-inf) = 0: masked keys
+                corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+                m_s[h] = m_new
+                l_s[h] = l_s[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+                acc_s[h] = acc_s[h] * corr + p_times_v(p, v_ref, h)
+
+            def heads(i, carry):
+                for r in range(turn):
+                    head(i * turn + r)
+                return carry
+            # a loop of turns, not hb copies of the body: what a step
+            # program spends lowering its kernels is set-up time
+            jax.lax.fori_loop(0, hb // turn, heads, 0)
 
         # blocks wholly past the live prefix [0, cursor + S) mask to
-        # nothing: skip their FLOPs (their index map also re-points at
-        # the last live block, so they cost no HBM traffic either).
-        # Block 0 always runs — cursor >= 0 keys at least one position,
-        # so l is never zero at emit.
+        # nothing: skip their FLOPs (their index map names the block the
+        # next live step reads, so they cost no HBM traffic of their
+        # own either). Block 0 always runs — cursor >= 0 keys at least
+        # one position, so l is never zero at emit.
         pl.when(k_start <= cursor + s_len - 1)(update)
 
         @pl.when(kb == n_kb - 1)
@@ -1069,53 +1180,76 @@ def _decode_attn_kernel(block_k, s_len, scale):
     return kernel
 
 
-def decode_attention(q, k_cache, v_cache, pos, block_k=128):
+def decode_attention(q, k_cache, v_cache, pos):
     """Cursor-bounded flash-decode read over a fixed-capacity KV cache.
 
     ``q`` is (B, H, S, Dh) already-rotated queries, the caches are
     (B, H, C, Dh) with the step's rows already written, and ``pos`` is
     the (B,) per-row cursor (a scalar-cursor engine broadcasts before
-    calling). The per-(b, h) grid row walks C // block_k cache blocks,
-    but the scalar-prefetched cursor clamps the K/V index maps to the
-    last live block — dead blocks re-reference an already-resident
-    index, so HBM traffic is proportional to the live prefix
-    ``[0, cursor_b + S)``, not the capacity. Online-softmax (m, l, acc)
+    calling). The grid is (B, H // hb, C // block_k) over the pools as
+    they lie: a step takes a group of ``hb`` heads of one slot and
+    ``block_k`` keys (``_decode_attn_blocks``: all 16 heads x 512 keys
+    at the serving shapes, 32-64 steps a layer), the heads in a loop.
+    The scalar-prefetched cursor bounds the K/V index maps to the live
+    blocks — a dead step names the block the next live step reads
+    (fetched once, behind the last live step's work) and is skipped, so
+    HBM traffic is proportional to the live prefix ``[0, cursor_b + S)``
+    in whole blocks, not the capacity, and a dead step costs a step's
+    overhead alone. Online-softmax (m, l, acc)
     accumulates in f32 VMEM scratch; fp8 cache rows dequantize on read
     inside the kernel. Returns f32 (B, H, S, Dh) — the caller casts.
-    """
+
+    The call is a jitted function of its own (the kernel is
+    ``decode_attn`` in the device trace), so that a step program lowers
+    it once and calls it from every layer."""
+    return _decode_attention(pos.astype(jnp.int32), q, k_cache, v_cache,
+                             interpret=_interpret())
+
+
+@partial(jax.jit, static_argnames=("interpret", "name"))
+def _decode_attention(pos, q, k_cache, v_cache, interpret,
+                      name="decode_attn"):
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, Dh = q.shape
     C = k_cache.shape[2]
-    block_k = _divisor_block(C, block_k)
-    scale = float(Dh) ** -0.5
-    qf = q.reshape(B * H, S, Dh)
-    kf = k_cache.reshape(B * H, C, Dh)
-    vf = v_cache.reshape(B * H, C, Dh)
-    # row cursor per (b, h) pair, b-major to match the reshape order
-    pos_bh = jnp.repeat(pos.astype(jnp.int32), H)
+    hb, block_k = _decode_attn_blocks(H, S, Dh, C, q.dtype, k_cache.dtype)
+    narrow_kv = _narrow(k_cache.dtype)
 
-    def _kv_map(b, j, pos_ref):
+    def _rows_map(b, g, j, pos_ref):
+        return (b, g, 0, 0)
+
+    def _kv_map(b, g, j, pos_ref):
+        """A live step's block; a dead step points at what the next
+        live step reads - block 0 of the next head group or slot - so
+        that its copy rides behind the last live step's work and is
+        there when the dead steps, which take no time, are over. The
+        last group of the last slot stays on its last live block."""
         last_live = (pos_ref[b] + (S - 1)) // block_k
-        return (b, jnp.minimum(j, last_live), 0)
+        more = g + 1 < H // hb
+        ahead = (j > last_live) & (more | (b + 1 < B))
+        return (jnp.where(ahead & ~more, b + 1, b),
+                jnp.where(ahead, jnp.where(more, g + 1, 0), g),
+                jnp.where(ahead, 0, jnp.minimum(j, last_live)), 0)
 
+    rows = pl.BlockSpec((None, hb, S, Dh), _rows_map)
+    block = pl.BlockSpec((None, hb, block_k, Dh), _kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(B * H, C // block_k),
-        in_specs=[
-            pl.BlockSpec((None, S, Dh), lambda b, j, pos_ref: (b, 0, 0)),
-            pl.BlockSpec((None, block_k, Dh), _kv_map),
-            pl.BlockSpec((None, block_k, Dh), _kv_map),
-        ],
-        out_specs=pl.BlockSpec((None, S, Dh),
-                               lambda b, j, pos_ref: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((S, 1), jnp.float32),
-                        pltpu.VMEM((S, 1), jnp.float32),
-                        pltpu.VMEM((S, Dh), jnp.float32)])
-    out = pallas_call(
-        _decode_attn_kernel(block_k, S, scale),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, Dh), jnp.float32),
-        grid_spec=grid_spec)(pos_bh, qf, kf, vf)
-    return out.reshape(B, H, S, Dh)
+        num_scalar_prefetch=1, grid=(B, H // hb, C // block_k),
+        in_specs=[rows, block, block], out_specs=rows,
+        scratch_shapes=[pltpu.VMEM((hb, S, 1), jnp.float32),
+                        pltpu.VMEM((hb, S, 1), jnp.float32),
+                        pltpu.VMEM((hb, S, Dh), jnp.float32)])
+    kwargs = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))}
+    return pallas_call(
+        _decode_attn_kernel(hb, block_k, S, float(Dh) ** -0.5,
+                            narrow_kv and q.dtype == jnp.bfloat16,
+                            narrow_kv),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, Dh), jnp.float32),
+        grid_spec=grid_spec, name=name, interpret=interpret,
+        **kwargs)(pos, q, k_cache, v_cache)
 
 
 #: bytes of double-buffered blocks ``cache_write`` may keep in VMEM

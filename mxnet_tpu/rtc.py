@@ -805,7 +805,9 @@ def _attention_decode_pallas_variant(attrs, inputs, aux, is_train, rng):
     and the rule of ``_write_rows``, so the cache contents are
     bit-identical across tiers; only the aligned blocks that hold the
     new rows move); the attention READ — the cache-bandwidth-bound
-    part — is ``pallas_kernels.decode_attention``, whose
+    part — is ``pallas_kernels.decode_attention`` (``decode_attn`` in
+    the device trace, lowered once a step program), whose grid step
+    takes a group of heads and a long key block of one slot and whose
     scalar-prefetched cursor bounds the K/V blocks actually fetched
     from HBM to the live prefix ``[0, cursor_b + S)`` instead of the
     full capacity."""
@@ -839,8 +841,9 @@ def _attention_decode_pallas_variant(attrs, inputs, aux, is_train, rng):
 
 
 def _attention_decode_eligible(attrs, in_shapes, in_dtypes):
-    """Decode windows up to the declared kspec bounds: S <= 64 head
-    rows resident, Dh <= 512, cache blocks tiling the capacity. The
+    """Decode windows up to the declared kspec bounds: S <= 64 rows of
+    a head group resident, Dh <= 512, cache blocks tiling the capacity
+    (the kernel sizes the group and the block inside them). The
     cache may be the compute width or an fp8 storage dtype (dequantized
     in-kernel on read). On a real TPU the head dim must be
     lane-aligned; interpret mode (off-TPU parity tests) takes any."""
@@ -889,18 +892,26 @@ _ATTENTION_DECODE_KSPEC = {
 }
 
 #: the flash-decode kernel's worst-case VMEM set at the eligibility
-#: bounds (S<=64, Dh<=512, 128-row cache blocks): q + one K + one V
-#: block + the f32 m/l/acc scratch + the out window. fp8 cache dtypes
-#: are in the gate set — the kernel dequantizes storage rows on read.
-#: (The write kernel before it sizes its own blocks, aligned to every
-#: dtype's sublanes, under ``pallas_kernels._WRITE_BLOCK_BUDGET``.)
+#: bounds (S<=64, Dh<=512, a float32 cache), where
+#: ``pallas_kernels._decode_attn_blocks`` shrinks the head group to one
+#: head and keeps 512 keys a step under ``_READ_VMEM_BUDGET``: the q
+#: window, a K and a V block and the out window, each double-buffered,
+#: the f32 m/l/acc scratch, and one head's working set (its K and V
+#: block widened, the scores, the mask, p and its parts). Narrower
+#: dtypes and smaller shapes trade the room for more heads a step (16
+#: heads x 512 keys of bfloat16 at Dh 128: 8.8 MiB at S=1). fp8 cache
+#: dtypes are in the gate set — the kernel dequantizes storage rows on
+#: read. (The write kernel before it sizes its own blocks, aligned to
+#: every dtype's sublanes, under ``pallas_kernels._WRITE_BLOCK_BUDGET``.)
 _ATTENTION_DECODE_PALLAS_KSPEC = {
-    "tiles": [((64, 512), "float32"),      # q window
-              ((128, 512), "float32"),     # k_cache block
-              ((128, 512), "float32"),     # v_cache block
-              ((64, 512), "float32"),      # acc scratch
-              ((64, 128), "float32"),      # m + l scratch (lane-padded)
-              ((64, 512), "float32")],     # out window
+    "tiles": [((2, 64, 512), "float32"),     # q window, both buffers
+              ((2, 512, 512), "float32"),    # k_cache block, both buffers
+              ((2, 512, 512), "float32"),    # v_cache block, both buffers
+              ((64, 512), "float32"),        # acc scratch
+              ((2, 64, 128), "float32"),     # m + l scratch (lane-padded)
+              ((2, 64, 512), "float32"),     # out window, both buffers
+              ((2, 512, 512), "float32"),    # one head's K and V, widened
+              ((8, 64, 512), "float32")],    # its scores, mask, p, parts
     "dtypes": ("float32", "bfloat16", "float16",
                "float8_e4m3fn", "float8_e5m2"),
 }

@@ -644,6 +644,14 @@ _DSA_COUNTERS = ("dsa.layer_steps", "dsa.live_rows", "dsa.selected_rows",
                  "dsa.scored_rows")
 
 
+#: ``serve.decode.<name>`` counters of a decoder with ``attention_decode``
+#: layers, in the order of ``BatchedKVCacheDecoder._attention_reads``:
+#: the pool rows at or before each fed slot's last query and the rows the
+#: pools hold (per slot, layer and dispatch); from the host's cursors, no
+#: fetch. Their ratio is the share of the capacity the traffic keeps live
+_ATTN_COUNTERS = ("attn.live_rows", "attn.capacity_rows")
+
+
 class DecodeScheduler:
     """Iteration-level continuous batching over one ``DecodeEngine``.
 
@@ -793,6 +801,9 @@ class DecodeScheduler:
             if self.engine.driver(self._rung).selects:
                 handles.update({k: self._counter(k)
                                 for k in _DSA_COUNTERS})
+            if self.engine.driver(self._rung).attends:
+                handles.update({k: self._counter(k)
+                                for k in _ATTN_COUNTERS})
             handles.update({k: self._gauge(k) for k in
                             ("active", "occupancy", "queue.depth")})
             handles["step.seconds"] = _telemetry.histogram(
@@ -1027,7 +1038,9 @@ class DecodeScheduler:
         the previous phase (one read a boundary), None reads it.
         ``fed`` (a decoder that is fed: the real tokens of each slot)
         rides in the same put as the tokens; what the dispatch reads of
-        a window-and-summaries state adds up in ``phases["eva"]``.
+        a window-and-summaries state adds up in ``phases["eva"]``, of a
+        learned selection in ``phases["dsa"]``, of ``attention_decode``
+        pools in ``phases["attn"]``.
         Returns ``(ids, logits, end)``: ``logits`` is the selected rows,
         the whole output, or None."""
         now = self._clock.now
@@ -1043,6 +1056,8 @@ class DecodeScheduler:
                 if drv.last_selection is not None:
                     phases["dsa"] = phases.get("dsa", 0) \
                         + drv.last_selection
+            if drv.last_attention is not None:
+                phases["attn"] = phases.get("attn", 0) + drv.last_attention
             if last is not None:
                 picked, ids = drv.select_rows(out, last)
                 if rows:
@@ -1262,7 +1277,8 @@ class DecodeScheduler:
             # window-and-summaries state's reads, a learned selection's
             moe, eva, dsa = (phases.get(k) for k in ("moe", "eva", "dsa"))
             for names, counts in ((_MOE_COUNTERS, moe), (_EVA_COUNTERS, eva),
-                                  (_DSA_COUNTERS, dsa)):
+                                  (_DSA_COUNTERS, dsa),
+                                  (_ATTN_COUNTERS, phases.get("attn"))):
                 for key, value in zip(names, () if counts is None
                                       else counts):
                     m[key].inc(int(value))

@@ -147,7 +147,10 @@ def test_attention_decode_compiles_for_v5e_and_writes_in_place(capacity, S,
     positions, 4,096 with RoPE): with the aux arrays donated the cache
     write moves S rows a slot and nothing else - no pool is copied,
     re-laid for a scatter or rebuilt by a fusion, both pools come back
-    in the buffers they came in, and the read is still the kernel."""
+    in the buffers they came in, and the read is the kernel: two layers
+    hold one lowering of ``decode_attn`` and call it twice, it takes
+    the pools as they lie and nothing of a pool's shape comes out of
+    it or is made for it."""
     import re
     opdef = get_op("attention_decode")
     attrs = opdef.normalize_attrs({"capacity": capacity, "per_slot": True,
@@ -164,15 +167,32 @@ def test_attention_decode_compiles_for_v5e_and_writes_in_place(capacity, S,
     assert opdef.variant_eligible("pallas", attrs,
                                   [a.shape for a in ins + aux],
                                   [str(a.dtype) for a in ins + aux])
-    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
-                       donate_argnums=(1,)).lower(ins, aux).compile()
+
+    def two_layers(r, a1, a2):
+        o1, n1 = fn(attrs, r, a1, False, None)
+        o2, n2 = fn(attrs, [o1[0], r[1], r[2]], a2, False, None)
+        return o2, n1, n2
+
+    lowered = jax.jit(two_layers, donate_argnums=(1, 2)).lower(ins, aux, aux)
+    assert re.findall(r'kernel_name = "(\w+)"', lowered.as_text()) == [
+        "cache_write", "decode_attn"]
+    compiled = lowered.compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    reads = re.findall(r"%decode_attn[.\w]* = (\S+) custom-call\(([^)]*)\)",
+                       text)
+    assert len(reads) == 2
+    pool_shape = f"bf16[{B},{H},{capacity},{d}]"
+    for out, operands in reads:
+        assert out.startswith(f"f32[{B},{H},{S},{d}]")
+        # K and V go in as the write handed them over, nothing between
+        assert len(re.findall(r"%get-tuple-element[.\w]*", operands)) == 2
     pool = rf"= bf16\[{B},{H},{capacity},{d}\]\S* "
     assert not re.findall(pool + r"copy\(", text)
     assert not re.findall(pool + r"fusion\(", text)
+    assert not re.findall(pool + r"(reshape|bitcast|transpose)\(", text)
     assert " scatter(" not in text
-    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * (
+    assert pool_shape in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= 4 * (
         B * H * capacity * d * 2)
 
 

@@ -42,14 +42,15 @@ def _attrs(per_slot=False, cache_dtype="", rope=False, capacity=C):
 
 
 def _state(S=1, dtype="float32", per_slot=False, cursors=None,
-           cache_dtype=None, seed=0, capacity=C):
+           cache_dtype=None, seed=0, capacity=C, heads=H, head_dim=DH):
     """Random q/k/v + a cache whose live prefix holds real rows."""
     rng = np.random.RandomState(seed)
     dt = np.dtype(dtype)
-    q, k, v = (jnp.asarray(rng.randn(B, H, S, DH), dt) for _ in range(3))
+    q, k, v = (jnp.asarray(rng.randn(B, heads, S, head_dim), dt)
+               for _ in range(3))
     cdt = np.dtype(cache_dtype) if cache_dtype else dt
-    k_cache = jnp.asarray(rng.randn(B, H, capacity, DH), cdt)
-    v_cache = jnp.asarray(rng.randn(B, H, capacity, DH), cdt)
+    k_cache = jnp.asarray(rng.randn(B, heads, capacity, head_dim), cdt)
+    v_cache = jnp.asarray(rng.randn(B, heads, capacity, head_dim), cdt)
     if cursors is None:
         cursors = [3] * B if per_slot else 3
     cur = jnp.asarray(np.reshape(cursors, (B, 1)), jnp.int32) \
@@ -57,15 +58,19 @@ def _state(S=1, dtype="float32", per_slot=False, cursors=None,
     return [q, k, v], [k_cache, v_cache, cur]
 
 
-def _both(attrs, inputs, aux):
-    ref_o, ref_a = OP.forward(attrs, inputs, aux, False, None)
-    pal_o, pal_a = OP.variants["pallas"]["fn"](attrs, inputs, aux,
-                                               False, None)
+def _both(attrs, inputs, aux, jit=False):
+    """``jit``: as a step program runs them, where a cursor is data and
+    a slot past its capacity drops its write instead of raising."""
+    wrap = jax.jit if jit else (lambda f: f)
+    ref_o, ref_a = wrap(lambda i, a: OP.forward(
+        attrs, i, a, False, None))(inputs, aux)
+    pal_o, pal_a = wrap(lambda i, a: OP.variants["pallas"]["fn"](
+        attrs, i, a, False, None))(inputs, aux)
     return ref_o[0], ref_a, pal_o[0], pal_a
 
 
-def _assert_parity(attrs, inputs, aux, tol):
-    ref, ref_aux, pal, pal_aux = _both(attrs, inputs, aux)
+def _assert_parity(attrs, inputs, aux, tol, jit=False):
+    ref, ref_aux, pal, pal_aux = _both(attrs, inputs, aux, jit=jit)
     assert ref.dtype == pal.dtype
     np.testing.assert_allclose(np.asarray(ref, np.float32),
                                np.asarray(pal, np.float32), atol=tol,
@@ -132,6 +137,95 @@ def test_decode_kernel_fp8_cache():
                                atol=2e-4, rtol=2e-4)
     assert np.array_equal(np.asarray(ref_aux[0], np.float32),
                           np.asarray(pal_aux[0], np.float32))
+
+
+# a step of the read takes a group of heads and up to 512 keys: several
+# key blocks a slot, cursors in the first block, astride a block edge, in
+# the last block and at capacity - S; head counts the group does not
+# divide evenly; a retired slot that ran past the capacity (its write is
+# dropped, every key is read) beside a live one
+_BLOCK_CASES = {
+    # id: (heads, S, head_dim, capacity, dtype, cache_dtype, cursors, tol)
+    "first_block_12_heads": (12, 1, 128, 2048, "bfloat16", None,
+                             [5, 511], 2e-2),
+    "astride_an_edge_S4": (12, 4, 128, 2048, "bfloat16", None,
+                           [510, 1022], 2e-2),
+    "last_block_float32": (12, 1, 128, 2048, "float32", None,
+                           [1600, 2047], 2e-4),
+    "capacity_less_S_S64": (16, 64, 128, 2048, "bfloat16", None,
+                            [2048 - 64, 0], 2e-2),
+    "5_heads_of_512_S64": (5, 64, 512, 1024, "float32", None,
+                           [500, 960], 2e-4),
+    "fp8_cache_three_blocks": (12, 1, 128, 1536, "float32",
+                               "float8_e4m3fn", [700, 1535], 2e-4),
+    "fp8_cache_bfloat16_S64": (5, 64, 128, 1024, "bfloat16",
+                               "float8_e4m3fn", [300, 513], 2e-2),
+    "retired_slot_past_capacity": (12, 4, 128, 1024, "float32", None,
+                                   [1022, 100], 2e-4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_CASES))
+def test_decode_kernel_parity_over_blocks(case):
+    heads, S, head_dim, capacity, dtype, cache_dtype, cursors, tol = \
+        _BLOCK_CASES[case]
+    from mxnet_tpu.ops.pallas_kernels import _decode_attn_blocks
+    hb, block_k = _decode_attn_blocks(
+        heads, S, head_dim, capacity, jnp.dtype(dtype),
+        jnp.dtype(cache_dtype or dtype))
+    assert capacity // block_k >= 2 and heads % hb == 0
+    inputs, aux = _state(S=S, dtype=dtype, per_slot=True, cursors=cursors,
+                         cache_dtype=cache_dtype, capacity=capacity,
+                         heads=heads, head_dim=head_dim)
+    attrs = _attrs(per_slot=True, capacity=capacity,
+                   cache_dtype="fp8" if cache_dtype else "")
+    _assert_parity(attrs, inputs, aux, tol, jit=case.startswith("retired"))
+
+
+@pytest.mark.parametrize("budget,block_k,S,group", [(24000, 8, 1, 2),
+                                                    (40000, 16, 4, 3),
+                                                    (14000, 8, 8, 1)])
+def test_decode_kernel_parity_small_groups(monkeypatch, budget, block_k, S,
+                                           group):
+    """The same walk at toy sizes: a budget that holds ``group`` of a
+    slot's 6 heads and key blocks of 8 or 16 over a capacity of 32, so
+    every (head group, key block) boundary is crossed."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "_READ_VMEM_BUDGET", budget)
+    monkeypatch.setattr(pk, "_READ_BLOCK_K", block_k)
+    pk._decode_attention.clear_cache()
+    try:
+        for dtype, tol in (("float32", 2e-4), ("bfloat16", 2e-2)):
+            assert pk._decode_attn_blocks(
+                6, S, DH, C, jnp.dtype(dtype), jnp.dtype(dtype)) == (
+                    group, block_k)
+            inputs, aux = _state(S=S, dtype=dtype, per_slot=True,
+                                 cursors=[block_k - 1, C - S], heads=6)
+            _assert_parity(_attrs(per_slot=True), inputs, aux, tol)
+    finally:
+        pk._decode_attention.clear_cache()
+
+
+def test_decode_attn_blocks_fit_the_budget():
+    """What a grid step covers, from the shapes and the dtype alone: at
+    the serving shapes a layer's read is at most 128 steps (it was
+    2,048 and 4,096) and its resident set is under the budget; at the
+    eligibility bounds one head still fits."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    bf16, f32 = jnp.dtype("bfloat16"), jnp.dtype("float32")
+    for slots, heads, S, dh, cap, dt in ((8, 16, 1, 128, 2048, bf16),
+                                         (8, 16, 64, 128, 4096, bf16),
+                                         (1, 1, 64, 512, 128, f32),
+                                         (8, 12, 64, 512, 4096, f32),
+                                         (8, 5, 4, 256, 640, bf16)):
+        hb, bk = pk._decode_attn_blocks(heads, S, dh, cap, dt, dt)
+        assert heads % hb == 0 and cap % bk == 0
+        if dh == 128:
+            assert slots * (heads // hb) * (cap // bk) <= 128
+        fixed, per_head = pk._decode_attn_resident(S, dh, bk, dt, dt)
+        assert fixed + hb * per_head <= pk._READ_VMEM_BUDGET
+    assert pk._decode_attn_blocks(16, 1, 128, 2048, bf16, bf16) == (16, 512)
+    assert pk._READ_VMEM_BUDGET < 16 << 20
 
 
 def test_pallas_variant_rejects_training():
@@ -256,3 +350,56 @@ def test_decode_driver_kernel_vs_xla_logits(monkeypatch):
     for a, b in zip(base, forced):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
         assert np.array_equal(a.argmax(-1), b.argmax(-1))
+
+
+# ------------------------------------ what a dispatch reads of the pools
+def test_driver_counts_live_rows_from_its_cursors(monkeypatch):
+    """``serve.decode.attn.live_rows`` / ``.capacity_rows``: per
+    dispatch, over the fed slots and the ``attention_decode`` layers,
+    the rows at or before each slot's last query and the rows the pools
+    hold - from the cursors the host mirrors, whichever tier reads."""
+    from mxnet_tpu.models import transformer as tfm
+    args = _decoder_args()
+    dsym = tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=L,
+                                 n_head=NH, capacity=CAP, per_slot=True,
+                                 max_seq_len=CAP)
+    dec = mx.mod.Module(dsym, label_names=[])
+    dec.bind([("data", (3, 1))], None, for_training=False)
+    dec.init_params(initializer=None, arg_params=args, aux_params={},
+                    allow_missing=True)
+    drv = tfm.BatchedKVCacheDecoder(dec, capacity=CAP, slots=3)
+    assert drv.attends and drv.last_attention is None
+    drv.join(0)
+    drv.join(2)
+    drv.rewind(2, 9)
+    drv.step(np.zeros((3, 1), np.int32))
+    # slot 0 reads row 0, slot 2 rows 0-9; slot 1 is nobody's
+    assert drv.last_attention.tolist() == [L * (1 + 10), L * 3 * CAP]
+    drv.leave(0)
+    drv.step(np.zeros((3, 1), np.int32))
+    assert drv.last_attention.tolist() == [L * 11, L * 3 * CAP]
+
+
+def test_scheduler_registers_the_attention_counters(monkeypatch):
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.models import transformer as tfm
+    args = _decoder_args()
+    dsym = tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=L,
+                                 n_head=NH, capacity=CAP, per_slot=True,
+                                 max_seq_len=CAP)
+    sched = mx.serve.serve_decoder(dsym, args, name="attn-count",
+                                   ladder=[2], start=True)
+    try:
+        handles = [sched.submit([1, 2, 3], max_new_tokens=4)
+                   for _ in range(2)]
+        assert all(len(h.result(timeout=600)) == 4 for h in handles)
+    finally:
+        sched.stop()
+    counters = {m.name: m.value for m in telemetry.metrics.all_metrics()
+                if isinstance(m, telemetry.Counter)
+                and ("model", "attn-count") in m.labels}
+    live = counters["serve.decode.attn.live_rows"]
+    held = counters["serve.decode.attn.capacity_rows"]
+    assert held % (L * 2 * CAP) == 0 and 0 < live < held
+    # two requests of 3 + 4 tokens: no slot ever reads past row 6
+    assert live <= held // CAP * 7

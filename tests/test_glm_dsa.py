@@ -373,3 +373,5 @@ def test_engine_migrates_both_pools_across_rungs_and_counts_reads():
     assert 0 < counters["serve.decode.moe.held_assignments"] \
         < counters["serve.decode.moe.assignments"]
     assert counters["serve.decode.state.donated_bytes"] > 0
+    # no attention_decode layer in this graph: its counters do not exist
+    assert not [k for k in counters if k.startswith("serve.decode.attn.")]
