@@ -33,6 +33,7 @@ import os
 import numpy as np
 
 from .. import symbol as sym
+from .. import telemetry as _telemetry
 from ..base import MXNetError
 
 __all__ = ["get_symbol", "get_decode_symbol", "SyntheticLMIter",
@@ -910,6 +911,11 @@ class BatchedKVCacheDecoder:
             for n in module.symbol._topo_nodes())
         self.attends = self._attn_layers > 0
         self.last_attention = None
+        # seconds the latest ``step`` spent staging and launching and
+        # the latest ``select_rows`` took, on the clock its caller
+        # handed it (``now=``); None where the caller handed none
+        self.last_stage = self.last_launch = self.last_select = None
+        self._donated_handle = None      # (registry generation, counter)
         if not self.positional:
             ring = exe.aux_dict[self._state["window"][0]]
             pool = exe.aux_dict[self._state["summary"][0]]
@@ -1042,13 +1048,12 @@ class BatchedKVCacheDecoder:
             cell._set(new)
         self.pos[rows] = positions
         if self.name is not None:
-            from .. import telemetry
-            telemetry.counter("serve.decode.cursor.updates",
-                              model=self.name).inc()
-            telemetry.counter("serve.decode.cursor.rows",
-                              model=self.name).inc(int(rows.size))
+            _telemetry.counter("serve.decode.cursor.updates",
+                               model=self.name).inc()
+            _telemetry.counter("serve.decode.cursor.rows",
+                               model=self.name).inc(int(rows.size))
 
-    def select_rows(self, out, idx):
+    def select_rows(self, out, idx, now=None):
         """From a step's ``(slots, S, V)`` output as it lies on the
         device, ``rows = out[slot, idx[slot]]`` as ``(slots, V)`` (the
         bytes the host would have indexed, untouched) and ``ids =
@@ -1057,26 +1062,33 @@ class BatchedKVCacheDecoder:
         (its name in the trace), one program per step length whatever
         ``idx`` holds. Both stay on the device; the copy of ``ids`` to
         the host starts here, behind the step program. ``idx`` is
-        (slots,) ints in ``[0, S)``."""
-        arr = out.asjax()
-        S = arr.shape[1]
-        idx = np.asarray(idx, np.int32).reshape(-1)
-        if idx.shape != (self.slots,) or idx.min() < 0 or idx.max() >= S:
-            raise MXNetError(f"select_rows() wants ({self.slots},) row "
-                             f"indices in [0, {S}), got {idx.tolist()}")
-        program = self._select_programs.get(S)
-        if program is None:
-            import jax
-            import jax.numpy as jnp
+        (slots,) ints in ``[0, S)``. All of it is the annotation
+        ``decode.select_rows``; ``now`` (a clock's read) makes
+        ``last_select`` its seconds."""
+        t0 = None if now is None else now()
+        with _telemetry.span("decode.select_rows"):
+            arr = out.asjax()
+            S = arr.shape[1]
+            idx = np.asarray(idx, np.int32).reshape(-1)
+            if idx.shape != (self.slots,) or idx.min() < 0 \
+                    or idx.max() >= S:
+                raise MXNetError(
+                    f"select_rows() wants ({self.slots},) row "
+                    f"indices in [0, {S}), got {idx.tolist()}")
+            program = self._select_programs.get(S)
+            if program is None:
+                import jax
+                import jax.numpy as jnp
 
-            def select_rows(out, idx):
-                rows = out[jnp.arange(out.shape[0]), idx]
-                return rows, jnp.argmax(rows, axis=-1).astype(jnp.int32)
+                def select_rows(out, idx):
+                    rows = out[jnp.arange(out.shape[0]), idx]
+                    return rows, jnp.argmax(rows, axis=-1).astype(jnp.int32)
 
-            select_rows.__name__ = f"select_rows_{self.slots}x{S}"
-            program = self._select_programs[S] = jax.jit(select_rows)
-        rows, ids = program(arr, idx)
-        ids.copy_to_host_async()
+                select_rows.__name__ = f"select_rows_{self.slots}x{S}"
+                program = self._select_programs[S] = jax.jit(select_rows)
+            rows, ids = program(arr, idx)
+            ids.copy_to_host_async()
+        self.last_select = None if now is None else now() - t0
         return rows, ids
 
     def join(self, slot):
@@ -1141,7 +1153,18 @@ class BatchedKVCacheDecoder:
         return [i for i in range(self.slots)
                 if self.active[i] and self.pos[i] + window > self.capacity]
 
-    def step(self, tokens, fed=None):
+    def _count_donated(self):
+        """Add this step's donated bytes to
+        ``serve.decode.state.donated_bytes``; the handle is looked up
+        once (a lookup is a lock and a key tuple) and again after the
+        registry resets."""
+        gen = _telemetry.metrics.generation()
+        if self._donated_handle is None or self._donated_handle[0] != gen:
+            self._donated_handle = (gen, _telemetry.counter(
+                "serve.decode.state.donated_bytes", model=self.name))
+        self._donated_handle[1].inc(self.donated_bytes)
+
+    def step(self, tokens, fed=None, now=None):
         """Advance every slot by one S-token window: ``tokens``
         (slots,) or (slots, S) int ids (retired slots ride any valid
         id, 0 by convention) -> logits (slots, S, V) NDArray. S=1 runs
@@ -1154,64 +1177,80 @@ class BatchedKVCacheDecoder:
         ``b`` by ``fed[b]`` of its S tokens (0..S; None feeds every
         slot all S) and leaves a slot with no room for S positions
         where it is; any other graph advances every slot by S and takes
-        no ``fed``."""
+        no ``fed``.
+
+        Two annotations: ``decode.step.stage`` (the checks, the host
+        arrays and their puts, what the dispatch reads of the state)
+        and ``decode.step.launch`` (``forward`` to ``get_outputs``: the
+        jitted call). ``now`` (a clock's read, the scheduler's) makes
+        ``last_stage`` and ``last_launch`` their seconds; without it no
+        clock is read."""
         from .. import ndarray as nd
         from ..io import DataBatch
-        tokens = np.asarray(tokens)
-        if tokens.ndim == 1:
-            tokens = tokens[:, None]
-        S = tokens.shape[1]
-        if tokens.shape != (self.slots, S) or S < 1:
-            raise MXNetError(f"step() wants ({self.slots}, S) tokens, "
-                             f"got {tokens.shape}")
-        if S == 1:
-            mod = self._mod
-        else:
-            mod = self._windows.get(S)
-            if mod is None:
+        t0 = None if now is None else now()
+        with _telemetry.span("decode.step.stage"):
+            tokens = np.asarray(tokens)
+            if tokens.ndim == 1:
+                tokens = tokens[:, None]
+            S = tokens.shape[1]
+            if tokens.shape != (self.slots, S) or S < 1:
+                raise MXNetError(f"step() wants ({self.slots}, S) tokens, "
+                                 f"got {tokens.shape}")
+            if S == 1:
+                mod = self._mod
+            else:
+                mod = self._windows.get(S)
+                if mod is None:
+                    raise MXNetError(
+                        f"no window module for step_len={S} (have "
+                        f"{self.window_lens}); add_window() it at engine "
+                        "warmup — steady-state dispatch never compiles")
+            over = self.overflowing(S)
+            if over:
                 raise MXNetError(
-                    f"no window module for step_len={S} (have "
-                    f"{self.window_lens}); add_window() it at engine "
-                    "warmup — steady-state dispatch never compiles")
-        over = self.overflowing(S)
-        if over:
-            raise MXNetError(
-                f"KV cache overflow in slot(s) {over}: position "
-                f"{[int(self.pos[i]) for i in over]} + {S} exceeds "
-                f"capacity {self.capacity}; retire the sequence(s) or "
-                "re-bind with a larger capacity")
-        data = [nd.array(tokens.astype(np.int32))]
-        if self.pos_embed == "learned":
-            pos = self.pos[:, None] + np.arange(S)[None, :]
-            data.append(nd.array(
-                np.minimum(pos, self.capacity - 1).astype(np.float32)))
-        if self.name is not None:
-            from .. import telemetry
-            telemetry.counter("serve.decode.state.donated_bytes",
-                              model=self.name).inc(self.donated_bytes)
-        if not self.feeds:
-            if fed is not None:
-                raise MXNetError("step(fed=...): this graph has no fed "
-                                 "input; it advances every slot by S")
-            self.last_attention = self._attention_reads(
-                np.where(self.active, S, 0))
+                    f"KV cache overflow in slot(s) {over}: position "
+                    f"{[int(self.pos[i]) for i in over]} + {S} exceeds "
+                    f"capacity {self.capacity}; retire the sequence(s) or "
+                    "re-bind with a larger capacity")
+            if not self.feeds:
+                if fed is not None:
+                    raise MXNetError("step(fed=...): this graph has no fed "
+                                     "input; it advances every slot by S")
+                advance = S          # the program advances EVERY slot
+                self.last_attention = self._attention_reads(
+                    np.where(self.active, S, 0))
+            else:
+                fed = np.full(self.slots, S, np.int64) if fed is None \
+                    else np.asarray(fed, np.int64).reshape(-1)
+                if fed.shape != (self.slots,) or fed.min() < 0 \
+                        or fed.max() > S:
+                    raise MXNetError(
+                        f"step() wants ({self.slots},) fed counts in "
+                        f"[0, {S}], got {fed.tolist()}")
+                # the program's own rule, mirrored: no room for S,
+                # nothing fed
+                advance = fed = np.where(self.pos + S <= self.capacity,
+                                         fed, 0)
+                self.last_reads = self._state_reads(fed)
+                self.last_selection = self._selection_reads(fed)
+                self.last_attention = self._attention_reads(fed)
+            if self.name is not None:
+                self._count_donated()
+            data = [nd.array(tokens.astype(np.int32))]
+            if self.pos_embed == "learned":
+                pos = self.pos[:, None] + np.arange(S)[None, :]
+                data.append(nd.array(
+                    np.minimum(pos, self.capacity - 1).astype(np.float32)))
+            if self.feeds:
+                data.append(nd.array(fed.astype(np.int32)))
+        t1 = None if now is None else now()
+        with _telemetry.span("decode.step.launch"):
             mod.forward(DataBatch(data=data, label=[]), is_train=False)
-            self.pos += S        # the program advances EVERY slot
-            return mod.get_outputs()[0]
-        fed = np.full(self.slots, S, np.int64) if fed is None \
-            else np.asarray(fed, np.int64).reshape(-1)
-        if fed.shape != (self.slots,) or fed.min() < 0 or fed.max() > S:
-            raise MXNetError(f"step() wants ({self.slots},) fed counts in "
-                             f"[0, {S}], got {fed.tolist()}")
-        # the program's own rule, mirrored: no room for S, nothing fed
-        fed = np.where(self.pos + S <= self.capacity, fed, 0)
-        data.append(nd.array(fed.astype(np.int32)))
-        self.last_reads = self._state_reads(fed)
-        self.last_selection = self._selection_reads(fed)
-        self.last_attention = self._attention_reads(fed)
-        mod.forward(DataBatch(data=data, label=[]), is_train=False)
-        self.pos += fed
-        return mod.get_outputs()[0]
+            out = mod.get_outputs()[0]
+        self.pos += advance
+        self.last_stage, self.last_launch = (None, None) if now is None \
+            else (t1 - t0, now() - t1)
+        return out
 
     def _attention_reads(self, fed):
         """What one dispatch that feeds ``fed`` tokens a slot reads of

@@ -750,6 +750,9 @@ class DecodeScheduler:
         self.iterations = 0
         self.migrations = 0
         self._iter_handles = None       # (registry generation, handles)
+        # the last clock read of the iteration before (its rewind's end),
+        # None where none ran straight before; the iterating thread's own
+        self._turn_from = None
         # draft first: the target's post-warmup compile mark is the
         # zero-compile gate stats() reports, so it must be taken LAST
         if self.draft is not None:
@@ -1035,7 +1038,12 @@ class DecodeScheduler:
         copies the whole (rung, S, V) output. Adds both durations to
         ``phases`` on the scheduler's clock and the bytes brought to the
         host to ``phases["bytes"]``; ``t`` is the reading that closed
-        the previous phase (one read a boundary), None reads it.
+        the previous phase (one read a boundary), None reads it. Inside
+        ``dispatch`` the driver times its own parts on the same clock
+        (``phases["stage"]``, ``["launch"]``, ``["select"]``); inside
+        ``fetch``, ``serve.decode.iter.fetch.ids`` is ``np.asarray(ids)``
+        alone (``phases["ids"]``), so that the rows and ``moe_stats``
+        are what is left of it.
         ``fed`` (a decoder that is fed: the real tokens of each slot)
         rides in the same put as the tokens; what the dispatch reads of
         a window-and-summaries state adds up in ``phases["eva"]``, of a
@@ -1048,9 +1056,9 @@ class DecodeScheduler:
             t = now()
         with _telemetry.span("serve.decode.iter.dispatch"):
             if fed is None:
-                out = drv.step(tokens)
+                out = drv.step(tokens, now=now)
             else:
-                out = drv.step(tokens, fed=fed)
+                out = drv.step(tokens, fed=fed, now=now)
                 if drv.last_reads is not None:
                     phases["eva"] = phases.get("eva", 0) + drv.last_reads
                 if drv.last_selection is not None:
@@ -1058,8 +1066,11 @@ class DecodeScheduler:
                         + drv.last_selection
             if drv.last_attention is not None:
                 phases["attn"] = phases.get("attn", 0) + drv.last_attention
+            phases["stage"] += drv.last_stage
+            phases["launch"] += drv.last_launch
             if last is not None:
-                picked, ids = drv.select_rows(out, last)
+                picked, ids = drv.select_rows(out, last, now=now)
+                phases["select"] += drv.last_select
                 if rows:
                     picked.copy_to_host_async()
         t_launched = now()
@@ -1072,7 +1083,11 @@ class DecodeScheduler:
                 ids, logits = None, out.asnumpy()
                 nbytes = logits.nbytes
             else:
-                ids = np.asarray(ids)
+                # the wait for the device, and 4 bytes a slot
+                t_ids = now()
+                with _telemetry.span("serve.decode.iter.fetch.ids"):
+                    ids = np.asarray(ids)
+                phases["ids"] += now() - t_ids
                 logits = np.asarray(picked) if rows else None
                 nbytes = ids.nbytes + (logits.nbytes if rows else 0)
             if routed is not None:
@@ -1122,7 +1137,7 @@ class DecodeScheduler:
         """One scheduling iteration; returns tokens emitted (0 = no
         work was ready). ``serve.decode.iter`` in the profiler's trace,
         enclosing its phases ``.plan``, ``.dispatch``, ``.fetch``,
-        ``.commit`` and ``.rewind``; the ring record
+        ``.commit``, ``.rewind`` and ``.account``; the ring record
         ``serve.decode.step`` carries the same ``iter`` number and the
         phases' durations on the scheduler's clock."""
         # read without the lock: only this, the iterating thread, ever
@@ -1133,8 +1148,12 @@ class DecodeScheduler:
 
     def _run_iteration(self, iter_span):
         span = _telemetry.span
+        # a submit (a closed-loop caller's done callback) holds the lock
+        # this waits for: read the clock on both sides of it
+        arrived = self._clock.now()
         with span("serve.decode.iter.plan"), self._lock:
             now = self._clock.now()
+            turn_from, self._turn_from = self._turn_from, None
             # retirement BEFORE dispatch: deadline-expired sequences
             # complete with their partial output; a slot whose next
             # token would overflow its cache slice fails ALONE — the
@@ -1211,7 +1230,8 @@ class DecodeScheduler:
         # dispatch outside the lock: submits stay non-blocking while
         # the program runs (only pump()/the dispatch thread iterates,
         # so the engine itself needs no second guard)
-        phases = {"dispatch": 0.0, "fetch": 0.0, "bytes": 0}
+        phases = {"dispatch": 0.0, "fetch": 0.0, "bytes": 0, "stage": 0.0,
+                  "launch": 0.0, "select": 0.0, "ids": 0.0}
         if mode == "spec":
             verdicts = self._dispatch_spec(
                 drv, ddrv, tokens, [(r, s) for r, s in meta], S, phases)
@@ -1225,7 +1245,9 @@ class DecodeScheduler:
                 # its cache tracks the same stream positions; nobody
                 # reads its logits, so it is launched and not waited for
                 with span("serve.decode.iter.dispatch"):
-                    ddrv.step(tokens)
+                    ddrv.step(tokens, now=self._clock.now)
+                phases["stage"] += ddrv.last_stage
+                phases["launch"] += ddrv.last_launch
                 launched = self._clock.now()
                 phases["dispatch"] += launched - end
                 end = launched
@@ -1263,48 +1285,64 @@ class DecodeScheduler:
                     if ddrv is not None:
                         ddrv.rewind_many(rew_rows, rew_pos)
             rewound = self._clock.now()
-            it = self.iterations
-            self.iterations += 1
-            n_active = len(self._active())
-            m = self._iter_metrics()
-            m["iterations"].inc()
-            if emitted:
-                m["tokens"].inc(emitted)
-            if chunks:
-                m["prefill.chunks"].inc(chunks)
-            m["fetch.bytes"].inc(phases["bytes"])
-            # what the dispatches counted: a routed decoder's experts, a
-            # window-and-summaries state's reads, a learned selection's
-            moe, eva, dsa = (phases.get(k) for k in ("moe", "eva", "dsa"))
-            for names, counts in ((_MOE_COUNTERS, moe), (_EVA_COUNTERS, eva),
-                                  (_DSA_COUNTERS, dsa),
-                                  (_ATTN_COUNTERS, phases.get("attn"))):
-                for key, value in zip(names, () if counts is None
-                                      else counts):
-                    m[key].inc(int(value))
-            m["step.seconds"].observe(step_s)
-            m["active"].set(n_active)
-            m["occupancy"].set(n_active / self._rung)
-            m["queue.depth"].set(len(self._queue))
-            compiles = self.engine.compiles_since_warmup()
-            m["compiles_since_warmup"].set(compiles or 0)
+            # the next iteration's ``turn_us`` runs from here: the
+            # account below, the loop, and up to its read before the lock
+            self._turn_from = rewound
+            with span("serve.decode.iter.account"):
+                it = self.iterations
+                self.iterations += 1
+                n_active = len(self._active())
+                m = self._iter_metrics()
+                m["iterations"].inc()
+                if emitted:
+                    m["tokens"].inc(emitted)
+                if chunks:
+                    m["prefill.chunks"].inc(chunks)
+                m["fetch.bytes"].inc(phases["bytes"])
+                # what the dispatches counted: a routed decoder's
+                # experts, a window-and-summaries state's reads, a
+                # learned selection's
+                moe, eva, dsa = (phases.get(k)
+                                 for k in ("moe", "eva", "dsa"))
+                for names, counts in (
+                        (_MOE_COUNTERS, moe), (_EVA_COUNTERS, eva),
+                        (_DSA_COUNTERS, dsa),
+                        (_ATTN_COUNTERS, phases.get("attn"))):
+                    for key, value in zip(names, () if counts is None
+                                          else counts):
+                        m[key].inc(int(value))
+                m["step.seconds"].observe(step_s)
+                m["active"].set(n_active)
+                m["occupancy"].set(n_active / self._rung)
+                m["queue.depth"].set(len(self._queue))
+                compiles = self.engine.compiles_since_warmup()
+                m["compiles_since_warmup"].set(compiles or 0)
 
-            _telemetry.flightrec.note(
-                "serve.decode.step", model=self.engine.name, iter=it,
-                rung=self._rung, active=n_active, emitted=emitted,
-                step_us=_us(step_s), plan_us=_us(planned - t0),
-                dispatch_us=_us(phases["dispatch"]),
-                fetch_us=_us(phases["fetch"]),
-                commit_us=_us(committed - end),
-                rewind_us=_us(rewound - committed), mode=mode, window=S,
-                compiles_since_warmup=compiles,
-                **({} if moe is None else
-                   {"moe_layer_steps": int(moe[0]),
-                    "moe_touched": int(moe[2])}),
-                **({} if eva is None else
-                   {"eva_exact": int(eva[1]), "eva_summary": int(eva[2])}),
-                **({} if dsa is None else
-                   {"dsa_selected": int(dsa[2]), "dsa_scored": int(dsa[3])}))
+                _telemetry.flightrec.note(
+                    "serve.decode.step", model=self.engine.name, iter=it,
+                    rung=self._rung, active=n_active,
+                    step_us=_us(step_s), plan_us=_us(planned - t0),
+                    dispatch_us=_us(phases["dispatch"]),
+                    stage_us=_us(phases["stage"]),
+                    launch_us=_us(phases["launch"]),
+                    select_us=_us(phases["select"]),
+                    fetch_us=_us(phases["fetch"]),
+                    ids_us=_us(phases["ids"]),
+                    commit_us=_us(committed - end),
+                    rewind_us=_us(rewound - committed),
+                    turn_us=0 if turn_from is None
+                    else _us(arrived - turn_from),
+                    lock_us=_us(now - arrived), mode=mode, window=S,
+                    compiles_since_warmup=compiles,
+                    **({} if moe is None else
+                       {"moe_layer_steps": int(moe[0]),
+                        "moe_touched": int(moe[2])}),
+                    **({} if eva is None else
+                       {"eva_exact": int(eva[1]),
+                        "eva_summary": int(eva[2])}),
+                    **({} if dsa is None else
+                       {"dsa_selected": int(dsa[2]),
+                        "dsa_scored": int(dsa[3])}))
         return max(1, emitted)
 
     def _commit_window(self, meta, ids, picked, S, t0, end, shared_sid,
@@ -1469,6 +1507,7 @@ class DecodeScheduler:
                     # noticed; a submit notifies sooner
                     with _telemetry.span("serve.decode.idle"):
                         self._cond.wait(timeout=0.05)
+                    self._turn_from = None    # a wait is nobody's turn
                     continue
             self._iterate()
 

@@ -1,8 +1,10 @@
 """Tier-1 guard of the yardstick itself (chipbench/README.md): the quick
-cases of ``chipbench/tests/test_spans.py`` and ``test_yardstick.py`` run
+cases of ``chipbench/tests/test_spans.py``, ``test_critical_path.py`` and
+``test_yardstick.py`` run
 here as they stand - the traffic generator's totals, nearest-rank
 percentiles, tokens by timestamp, the trace reducer on a scripted trace
-and on recorded v5e slices, every layer reader, the span readers,
+and on recorded v5e slices, every layer reader, the span readers, the
+critical path of a decode iteration (``test_critical_path.py``),
 ``costs`` against the published sizes, and "new files and entries add a
 cell without an edit". They are the measuring code every PR is judged
 by. The CPU rehearsals (``test_rehearsal.py``, ``test_olmoe.py``) stay
@@ -14,6 +16,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from chipbench.tests.test_critical_path import (  # noqa: E402,F401
+    test_a_skewed_device_plane_is_tied_back_by_its_markers,
+    test_a_trace_without_the_new_spans_reads_as_nothing,
+    test_an_impossible_order_reads_as_none_with_its_count,
+    test_recorded_chat_slice_closes_the_account,
+    test_the_eight_layer_files_read_ring_and_trace,
+    test_three_iterations_by_hand,
+    test_without_a_marker_the_crossing_latencies_read_as_none)
 from chipbench.tests.test_spans import (  # noqa: E402,F401
     test_a_program_without_the_spans_reads_as_nothing,
     test_idle_ns_against_merged_busy_intervals,

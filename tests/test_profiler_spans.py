@@ -141,7 +141,7 @@ def _assert_inside(events, child, parent):
                    for p in parents), (child, "outside every", parent)
 
 
-DECODE_PHASES = ("plan", "dispatch", "fetch", "commit", "rewind")
+DECODE_PHASES = ("plan", "dispatch", "fetch", "commit", "rewind", "account")
 
 
 @pytest.mark.parametrize("phase", DECODE_PHASES)
@@ -168,6 +168,69 @@ def test_decode_iter_annotation_carries_the_plan(decode_trace):
         {"fwd_infer"}
     _assert_inside(decode_trace, "executor.run",
                    "serve.decode.iter.dispatch")
+
+
+# ISSUE 36: the parts of dispatch and fetch, where the work happens
+DRIVER_SPANS = ("decode.step.stage", "decode.step.launch",
+                "decode.select_rows")
+
+
+@pytest.mark.parametrize("name, parent", [
+    (n, "serve.decode.iter.dispatch") for n in DRIVER_SPANS] + [
+    ("serve.decode.iter.fetch.ids", "serve.decode.iter.fetch")])
+def test_decode_part_once_per_iteration_inside_its_phase(decode_trace, name,
+                                                         parent):
+    assert len(_named(decode_trace, name)) == ITERS
+    _assert_inside(decode_trace, name, parent)
+
+
+def test_decode_parts_follow_each_other(decode_trace):
+    """stage, launch (around the executor's own span) and select_rows in
+    that order inside dispatch; ``account`` after ``rewind``, the last
+    thing of the iteration."""
+    def starts(name):
+        return sorted((e[2], e[3]) for e in _named(decode_trace, name))
+    order = DRIVER_SPANS + ("serve.decode.iter.fetch.ids",
+                            "serve.decode.iter.commit",
+                            "serve.decode.iter.rewind",
+                            "serve.decode.iter.account")
+    for k in range(ITERS):
+        spans = [starts(n)[k] for n in order]
+        for (_a, end), (start, _b) in zip(spans, spans[1:]):
+            assert end <= start, (k, order)
+    _assert_inside(decode_trace, "executor.run", "decode.step.launch")
+    _assert_inside(decode_trace, "io.load_batch", "decode.step.launch")
+
+
+def test_driver_alone_writes_its_spans_and_reads_no_clock(tmp_path):
+    """A caller that drives ``BatchedKVCacheDecoder`` itself (the
+    benchmark's reference check, a test) gets the three driver spans
+    under no scheduler phase and no duration: durations exist only on
+    the clock a caller hands in."""
+    sched = _scheduler("spans-driver", FakeClock())
+    drv = sched.engine.driver(2)
+    tokens, idx = np.zeros((2, 1), np.int32), np.zeros(2, np.int32)
+
+    def body():
+        drv.select_rows(drv.step(tokens), idx)
+
+    events = _profiled(tmp_path, body)
+    for name in DRIVER_SPANS:
+        assert len(_named(events, name)) == 1, name
+    assert not _named(events, "serve.decode.iter.dispatch")
+    assert drv.last_stage is None and drv.last_launch is None \
+        and drv.last_select is None
+    reads = []
+
+    def now():
+        reads.append(len(reads))
+        return float(len(reads))
+
+    drv.select_rows(drv.step(tokens, now=now), idx, now=now)
+    assert (drv.last_stage, drv.last_launch, drv.last_select) == (1, 1, 1)
+    assert len(reads) == 5
+    drv.step(tokens)
+    assert drv.last_stage is None and drv.last_launch is None
 
 
 # name -> events a 3-batch epoch leaves: the producer's and the loop's
@@ -220,9 +283,56 @@ def test_ring_record_phase_fields(clock):
             parts = r["plan_us"] + r["dispatch_us"] + r["fetch_us"]
             # three truncations to whole microseconds
             assert abs(parts - r["step_us"]) <= max(3, r["step_us"] // 100)
+        # ISSUE 36: the same boundaries as the new annotations
+        parts = ("stage_us", "launch_us", "select_us", "ids_us", "lock_us",
+                 "turn_us")
+        assert all(r[f] >= 0 for f in parts), r
+        assert r["stage_us"] + r["launch_us"] + r["select_us"] \
+            <= r["dispatch_us"]
+        assert r["ids_us"] <= r["fetch_us"]
+        if clock == "fake":
+            assert all(r[f] == 0 for f in parts)
+    assert recs[0]["turn_us"] == 0      # no iteration ran before it
     stats = sched.stats()
     assert stats["compiles_since_warmup"] == 0
     assert stats["backend_compiles_since_warmup"] >= 0
+
+
+class _TickClock(FakeClock):
+    """2**-10 s (976 whole microseconds, and exact in binary) pass at
+    every read: a duration counts the reads between its two."""
+
+    def now(self):
+        return self.advance(2.0 ** -10)
+
+
+def test_ring_record_fields_count_the_clock_reads():
+    """Under a clock that ticks once a read the ring record is the same
+    in every run and says where the reads are: one inside each of
+    stage, launch, select_rows, fetch.ids and the lock wait, six from
+    the plan's end to the launches' (``dispatch_us``), three in
+    ``fetch``, one from an iteration's rewind to the next one's arrival
+    at the lock (``turn_us``). Fourteen reads an iteration, eight of
+    them ISSUE 36's."""
+    flightrec.configure(capacity=4096)
+    flightrec.clear()
+    clock = _TickClock()
+    sched = _scheduler("spans-ring-tick", clock)
+    sched.submit(np.arange(1, 10), max_new_tokens=4)
+    before = clock.now()
+    sched.pump()
+    recs = [r for r in flightrec.get_records()
+            if r["kind"] == "serve.decode.step"]
+    assert len(recs) >= ITERS
+    assert (clock.now() - before) * 2 ** 10 == 14 * len(recs) + 1
+    for k, r in enumerate(recs):
+        got = {f: r[f] for f in r if f.endswith("_us") and f != "ts_us"}
+        assert got == {
+            "lock_us": 976, "plan_us": 976, "stage_us": 976,
+            "launch_us": 976, "select_us": 976, "dispatch_us": 5859,
+            "ids_us": 976, "fetch_us": 2929, "step_us": 9765,
+            "commit_us": 976, "rewind_us": 976,
+            "turn_us": 976 if k else 0}, (k, got)
 
 
 def test_span_without_jax_is_the_null_span():
