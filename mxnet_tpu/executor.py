@@ -89,6 +89,21 @@ class _LazyOutputs:
         return repr(self._mat())
 
 
+def loss_label_names(symbol):
+    """The variables fed straight into a loss head's label slot: they
+    keep their dtype under mixed precision (class ids must stay exact).
+    Every other floating variable is what ``_load_var`` casts to the
+    compute dtype in the step program - the set a serving binding
+    narrows once, at bind (executor_group.serving_width_params)."""
+    names = set()
+    for node in symbol._topo_nodes():
+        if not node.is_variable and node.opdef().is_loss:
+            for inp, _ in node.inputs[1:]:
+                if inp.is_variable:
+                    names.add(inp.name)
+    return names
+
+
 def _build_graph_runner(symbol, shape_overrides=None, tap=None, mp_plan=None,
                         compute_dtype=None, remat_segments=0,
                         spmd_plan=None, n_devices=1):
@@ -148,16 +163,10 @@ def _build_graph_runner(symbol, shape_overrides=None, tap=None, mp_plan=None,
         loss_mask.append(bool(not node.is_variable and
                               node.opdef().is_loss))
 
-    # variables fed straight into a loss head's label slot keep their
-    # dtype under mixed precision (class ids must stay exact)
     label_names = set()
     if compute_dtype is not None:
         compute_dtype = np.dtype(compute_dtype)
-        for node in nodes:
-            if not node.is_variable and node.opdef().is_loss:
-                for inp, _ in node.inputs[1:]:
-                    if inp.is_variable:
-                        label_names.add(inp.name)
+        label_names = loss_label_names(symbol)
 
     def _load_var(val, name):
         if (compute_dtype is not None and name not in label_names

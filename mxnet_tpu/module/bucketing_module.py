@@ -112,7 +112,7 @@ class BucketingModule(BaseModule):
             arg_params=arg_params, aux_params=aux_params,
             allow_missing=allow_missing, force_init=force_init)
         self.params_initialized = True
-        self._params_dirty = False
+        self._params_dirty = self._leader._params_dirty
 
     def set_params(self, arg_params, aux_params, allow_missing=False,
                    force_init=True):

@@ -85,24 +85,54 @@ def _window_param_stats(health, w_start, w_end, watched):
     return out
 
 
-def compute_width_params(arg_params, compute_dtype):
-    """``{name: dtype}`` of the parameters in ``arg_params`` that are
-    handed over already at ``compute_dtype``, a float type narrower
-    than float32: the rule by which a parameter's cell is bound at the
-    dtype given instead of as a float32 master that every step casts
-    (docs/performance.md, "Mixed precision"). Empty for float32
-    parameters, for no ``compute_dtype`` and for the quantized tiers."""
-    if compute_dtype is None or not arg_params:
-        return {}
+def _narrow_float(compute_dtype):
+    """``compute_dtype`` as a dtype if it is a float type narrower than
+    float32, else None (no ``compute_dtype``, float32, the quantized
+    tiers)."""
+    if compute_dtype is None:
+        return None
     try:
         want = jnp.dtype(compute_dtype)
     except TypeError:
-        return {}
+        return None
     if not jnp.issubdtype(want, jnp.floating) or want.itemsize >= 4:
+        return None
+    return want
+
+
+def compute_width_params(arg_params, compute_dtype):
+    """``{name: dtype}`` of the parameters in ``arg_params`` that are
+    handed over already at ``compute_dtype``, a float type narrower
+    than float32: the rule by which a binding that may train binds a
+    parameter's cell at the dtype given instead of as a float32 master
+    that every step casts (docs/performance.md, "Mixed precision").
+    Empty for float32 parameters, for no ``compute_dtype`` and for the
+    quantized tiers."""
+    want = _narrow_float(compute_dtype)
+    if want is None or not arg_params:
         return {}
     return {name: want for name, arr in arg_params.items()
             if getattr(arr, "dtype", None) is not None
             and jnp.dtype(arr.dtype) == want}
+
+
+def serving_width_params(symbol, input_names, compute_dtype):
+    """``{name: dtype}`` for a binding that only ever serves
+    (``for_training=False``: no optimizer, no gradient, nothing that
+    needs a master): every parameter of ``symbol`` that the step
+    programs would cast to ``compute_dtype`` - ``executor._load_var``'s
+    set: not an input, not a loss head's label - binds at it, whatever
+    dtype it is handed at, and ``set_params`` casts once as it stores.
+    A variable that declares its dtype keeps it (``_bind_exec``).
+    Empty where ``compute_width_params`` is: no ``compute_dtype``,
+    float32, the quantized tiers."""
+    want = _narrow_float(compute_dtype)
+    if want is None:
+        return {}
+    from ..executor import loss_label_names
+    skip = set(input_names) | loss_label_names(symbol)
+    return {name: want for name in symbol.list_arguments()
+            if name not in skip}
 
 
 class DataParallelExecutorGroup:
@@ -114,7 +144,8 @@ class DataParallelExecutorGroup:
         self.symbol = symbol
         self.compute_dtype = compute_dtype
         # parameters to bind at another dtype than float32: those handed
-        # over already at the compute width (compute_width_params)
+        # over already at the compute width (compute_width_params) or,
+        # for a serving binding, all it would cast (serving_width_params)
         self.param_dtypes = dict(param_dtypes or {})
         self.contexts = contexts
         self.workload = workload
@@ -1312,6 +1343,12 @@ class DataParallelExecutorGroup:
             if name in ad:
                 val = arr.asjax() if isinstance(arr, NDArray) \
                     else jnp.asarray(arr)
+                if val.dtype != ad[name].dtype:
+                    # a value wider than its cell (a serving binding's,
+                    # serving_width_params) lands on the device first,
+                    # one parameter at a time: the cast below is then
+                    # the convert the step programs ran, not the host's
+                    val = self._place(val, "param", name)
                 val = self._place(val.astype(ad[name].dtype), "param",
                                   name)
                 if fused and name in self._fused_watched:

@@ -37,8 +37,9 @@ class Module(BaseModule):
         """``param_dtypes`` (``{name: dtype}``) binds those parameter
         cells at another dtype than float32 - for a caller that knows
         before ``bind`` that its parameters come at the compute width
-        (``executor_group.compute_width_params``); ``init_params`` finds
-        the same out after bind."""
+        (``executor_group.compute_width_params``; ``init_params`` finds
+        the same out after bind) or that it only ever serves them
+        (``executor_group.serving_width_params``: ``DecodeEngine``)."""
         super().__init__(logger=logger)
         self._compute_dtype = compute_dtype
         self._param_dtypes = dict(param_dtypes or {})
@@ -183,8 +184,13 @@ class Module(BaseModule):
             fill(name, self._aux_params[name], aux_params)
 
         self.params_initialized = True
-        self._params_dirty = False
         self._exec_group.set_params(self._arg_params, self._aux_params)
+        # a value stored into a cell of another dtype (a serving
+        # binding's cells at the compute width) is no longer what the
+        # cache holds: the next get_params reads the cells back
+        self._params_dirty = any(
+            exe.arg_dict[n].dtype != a.dtype
+            for n, a in self._arg_params.items())
 
     def set_params(self, arg_params, aux_params, allow_missing=False,
                    force_init=True):

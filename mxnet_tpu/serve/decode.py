@@ -77,6 +77,7 @@ import os
 import threading
 
 import numpy as np
+import jax.numpy as jnp
 
 from .. import program_cache as _progcache
 from .. import telemetry as _telemetry
@@ -393,11 +394,13 @@ class DecodeEngine:
             logger=logger or log, context=self._context)
         if compute_dtype is not None:
             self._bm._module_kwargs["compute_dtype"] = compute_dtype
-            # parameters handed over at the compute width bind at it:
-            # no float32 master is allocated and no step casts them
-            from ..module.executor_group import compute_width_params
+            # a serving binding keeps no masters: every parameter the
+            # step programs would cast binds at the compute width,
+            # whatever it is handed at, and is cast once as it is stored
+            from ..module.executor_group import serving_width_params
             self._bm._module_kwargs["param_dtypes"] = \
-                compute_width_params(arg_params, compute_dtype)
+                serving_width_params(symbol, self.data_names,
+                                     compute_dtype)
         self._bm.bind(self._provide_data(self.ladder.max),
                       label_shapes=None, for_training=False)
         # straight to the leader with initializer=None: the decode
@@ -410,7 +413,8 @@ class DecodeEngine:
                                      aux_params=dict(aux_params or {}),
                                      allow_missing=True)
         self._bm.params_initialized = True
-        self._bm._params_dirty = False
+        self._bm._params_dirty = self._bm._leader._params_dirty
+        self._note_params(arg_params)
         self._bm.warm_buckets(
             [(s, self._provide_data(s), None) for s in self.ladder])
 
@@ -467,6 +471,32 @@ class DecodeEngine:
                             "different step_len")
                 self._drivers[rung].add_window(S, mod)
                 self._window_mods[(rung, S)] = mod
+
+    def _note_params(self, arg_params):
+        """What the binding holds, set once at bind: the bytes of the
+        parameter cells by dtype (every rung and window shares the
+        leader's: gauge ``serve.decode.params.bytes``) and how many of
+        the parameters handed over were wider than their cell, cast
+        once as they were stored (counter
+        ``serve.decode.params.narrowed``)."""
+        exe = self._bm._leader._exec_group.executor
+        handed = arg_params or {}
+        self.params_bytes, self.params_narrowed = {}, 0
+        for nm, cell in exe.arg_dict.items():
+            if nm in self.data_names:
+                continue
+            dt = cell.dtype
+            self.params_bytes[str(dt)] = \
+                self.params_bytes.get(str(dt), 0) + cell.size * dt.itemsize
+            given = getattr(handed.get(nm), "dtype", None)
+            if given is not None and jnp.issubdtype(given, jnp.floating) \
+                    and jnp.dtype(given).itemsize > dt.itemsize:
+                self.params_narrowed += 1
+        for dt, n in self.params_bytes.items():
+            _telemetry.gauge("serve.decode.params.bytes",
+                             model=self.name, dtype=dt).set(n)
+        _telemetry.counter("serve.decode.params.narrowed",
+                           model=self.name).inc(self.params_narrowed)
 
     def _provide_data(self, slots, step=1):
         descs = [DataDesc("data", (slots, step), np.int32)]
@@ -1599,6 +1629,8 @@ class DecodeScheduler:
                 "mean": round(h.mean * 1e3, 3)},
             "exec_est_ms": dict(sorted(exec_est.items())),
             "capacity": self.engine.capacity,
+            "params_bytes": dict(self.engine.params_bytes),
+            "params_narrowed": self.engine.params_narrowed,
             "compiles_since_warmup": self.engine.compiles_since_warmup(),
             "backend_compiles_since_warmup":
             self.engine.backend_compiles_since_warmup(),

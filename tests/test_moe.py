@@ -394,45 +394,69 @@ def test_serve_decoder_counts_where_the_tokens_went():
 
 # ------------------------------------------ bind at the dtype given
 def _lowered_s1(exe):
-    """The S=1 step program of a bound executor as StableHLO text."""
+    """The step program of a bound executor (the S=1 one, or a window's)
+    as StableHLO text."""
     def prog(arg_vals, aux_vals):
         return exe._runner(arg_vals, aux_vals, False, None)
     return jax.jit(prog).lower(exe._arg_vals(), exe._aux_vals()).as_text()
 
 
-def _engine(params, compute_dtype, gen):
+def _engine(params, compute_dtype, gen, name="bind-dtype"):
     from mxnet_tpu.serve.decode import DecodeEngine
-    return DecodeEngine("bind-dtype", gen(1), params, capacity=CAPACITY,
+    return DecodeEngine(name, gen(1), params, capacity=CAPACITY,
                         ladder=[2], context=mx.cpu(0),
                         compute_dtype=compute_dtype, symbol_gen=gen,
                         window_lens=(WINDOW,))
 
 
-def test_bfloat16_parameters_bind_at_bfloat16_and_are_not_cast():
+def _param_dtypes(mod, inputs=("data",)):
+    return {n: str(c.dtype)
+            for n, c in mod._exec_group.executor.arg_dict.items()
+            if n not in inputs}
+
+
+@pytest.mark.parametrize("handed", ["bfloat16", "float32"])
+def test_bfloat16_parameters_bind_at_bfloat16_and_are_not_cast(handed):
+    """A serving binding holds every floating parameter at the compute
+    width: handed at it (PR 28) or handed float32 and cast once at bind
+    (PR 37) - the leader's cells and every window module's, and neither
+    the S=1 program nor a window program takes a float32 argument or
+    converts a matrix."""
     gen = lambda s: _decode_symbol(s, with_routing=False)   # noqa: E731
-    eng = _engine(_params(dtype="bfloat16"), "bfloat16", gen)
-    for mod in [eng._bm._leader] + list(eng._window_mods.values()):
-        exe = mod._exec_group.executor
-        dtypes = {n: str(c.dtype) for n, c in exe.arg_dict.items()
-                  if n != "data"}
+    eng = _engine(_params(dtype=handed), "bfloat16", gen)
+    mods = [eng._bm._leader] + list(eng._window_mods.values())
+    assert len(mods) == 2
+    for mod in mods:
+        dtypes = _param_dtypes(mod)
         assert set(dtypes.values()) == {"bfloat16"}, dtypes
-    text = _lowered_s1(eng._bm._leader._exec_group.executor)
-    # no matrix among the arguments is converted: only the norm gains,
-    # upcast for their float32 statistics, are
-    converted = re.findall(
-        r"stablehlo\.convert %arg\d+ : \(tensor<([0-9x]*)x(?:bf16|f32)>\)",
-        text)
-    assert all("x" not in dims for dims in converted), converted
-    assert "xf32>" not in text.split("{", 1)[0].split("->")[0], \
-        "a float32 argument"
+        text = _lowered_s1(mod._exec_group.executor)
+        # no matrix among the arguments is converted: only the norm
+        # gains, upcast for their float32 statistics, are
+        converted = re.findall(
+            r"stablehlo\.convert %arg\d+ : "
+            r"\(tensor<([0-9x]*)x(?:bf16|f32)>\)", text)
+        assert all("x" not in dims for dims in converted), converted
+        signature = text[text.index("@main("):].split(") -> ", 1)[0]
+        assert "xbf16>" in signature and "xf32>" not in signature, \
+            "a float32 argument"
+    # what the module hands back is what it serves
+    args, _aux = eng._bm.get_params()
+    assert {str(v.dtype) for v in args.values()} == {"bfloat16"}
 
 
 def test_float32_parameters_bind_as_before():
+    """The training contract (PR 28's rule): a ``Module`` keeps float32
+    masters for float32 parameters and re-allocates a cell only for a
+    parameter handed over at the compute width. ``DecodeEngine`` alone
+    narrows what it is handed (the test above)."""
     gen = lambda s: _decode_symbol(s, with_routing=False)   # noqa: E731
-    eng = _engine(_params(), "bfloat16", gen)
-    exe = eng._bm._leader._exec_group.executor
-    assert {str(c.dtype) for n, c in exe.arg_dict.items()
-            if n != "data"} == {"float32"}
+    mod = mx.mod.Module(gen(1), data_names=("data",), label_names=[],
+                        compute_dtype="bfloat16")
+    mod.bind([mx.io.DataDesc("data", (2, 1), np.int32)], None,
+             for_training=False)
+    mod.init_params(initializer=None, arg_params=_params(),
+                    aux_params={}, allow_missing=True)
+    assert set(_param_dtypes(mod).values()) == {"float32"}
     # and a module bound first and handed bfloat16 parameters afterwards
     # re-allocates the cells (Module.init_params)
     mod = mx.mod.Module(gen(1), data_names=("data",), label_names=[],
@@ -449,35 +473,175 @@ def test_float32_parameters_bind_as_before():
     assert ("lm_head_weight", (128, 64), "bfloat16") in key[1]
 
 
+def test_a_training_binding_keeps_its_float32_masters():
+    """``for_training=True`` under ``compute_dtype="bfloat16"``, handed
+    float32: the cells stay float32 (the optimizer updates masters; the
+    cast has a gradient) and so do the gradients."""
+    net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=8,
+                                name="fc")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    mod = mx.mod.Module(net, compute_dtype="bfloat16")
+    mod.bind([("data", (4, 16))], [("softmax_label", (4,))],
+             for_training=True)
+    rng = np.random.default_rng(0)
+    mod.init_params(initializer=None, arg_params={
+        "fc_weight": rng.normal(size=(8, 16)).astype(np.float32),
+        "fc_bias": np.zeros(8, np.float32)}, aux_params={})
+    exe = mod._exec_group.executor
+    assert _param_dtypes(mod, ("data", "softmax_label")) == \
+        {"fc_weight": "float32", "fc_bias": "float32"}
+    assert {str(g.dtype) for g in exe.grad_dict.values()
+            if g is not None} == {"float32"}
+    args, _aux = mod.get_params()
+    assert {str(v.dtype) for v in args.values()} == {"float32"}
+
+
 #: sha256 of the lowered S=1 program of the Cerebras-shaped tiny decoder
-#: below (learned positions, float32 masters, compute_dtype bfloat16):
-#: a PR that leaves the GPT-2 block's program alone leaves these bytes
-#: alone, and the Cerebras cells hit their compile cache. A PR that
-#: changes that program on purpose computes it anew: PR 33 did (the
-#: cache write is one window update a slot, no longer a ``jnp.where``
-#: over the pool); before it the bytes were PR 28's parent's (d8b22cc).
+#: below (learned positions, parameters handed float32, compute_dtype
+#: bfloat16): a PR that leaves the GPT-2 block's program alone leaves
+#: these bytes alone, and the Cerebras cells hit their compile cache. A
+#: PR that changes that program on purpose computes it anew: PR 33 did
+#: (the cache write is one window update a slot, no longer a
+#: ``jnp.where`` over the pool); before it the bytes were PR 28's
+#: parent's (d8b22cc). PR 37 did (a serving binding narrows its
+#: parameters once, at bind: every parameter argument is ``bf16`` and
+#: the converts of the float32 masters are gone; until then
+#: 9a63a96316cb...e367ab).
 GPT2_S1_SHA256 = \
-    "9a63a96316cb1f04af6f20b01533bbe3ad9cc68bc5764b04ceb06b6895e367ab"
+    "8cee1c781915d90e11617a882f8fcba94c1ff7bc8cb8dfe12a3fc045776b3eda"
+
+_GPT2_KW = dict(vocab_size=96, d_model=64, n_layer=2, n_head=4,
+                pos_embed="learned", capacity=32, max_seq_len=32,
+                per_slot=True)
+
+
+def _gpt2_gen(step_len):
+    return tfm.get_decode_symbol(step_len=step_len, **_GPT2_KW)
+
+
+def _gpt2_params(seed=5):
+    from chipbench import weights
+    return weights.normal_init(_gpt2_gen(1), {"data": (2, 1),
+                                              "pos_ids": (2, 1)}, seed)
+
+
+def _gpt2_engine(params, name="gpt2-pin"):
+    from mxnet_tpu.serve.decode import DecodeEngine
+    return DecodeEngine(name, _gpt2_gen(1), params, capacity=32,
+                        ladder=[2], context=mx.cpu(0),
+                        compute_dtype="bfloat16", symbol_gen=_gpt2_gen,
+                        window_lens=(8,))
 
 
 def _gpt2_lowered_s1():
-    kw = dict(vocab_size=96, d_model=64, n_layer=2, n_head=4,
-              pos_embed="learned", capacity=32, max_seq_len=32,
-              per_slot=True)
-    gen = lambda s: tfm.get_decode_symbol(step_len=s, **kw)  # noqa: E731
-    from chipbench import weights
-    params = weights.normal_init(gen(1), {"data": (2, 1),
-                                          "pos_ids": (2, 1)}, 5)
-    from mxnet_tpu.serve.decode import DecodeEngine
-    eng = DecodeEngine("gpt2-pin", gen(1), params, capacity=32, ladder=[2],
-                       context=mx.cpu(0), compute_dtype="bfloat16",
-                       symbol_gen=gen, window_lens=(8,))
+    eng = _gpt2_engine(_gpt2_params())
     return _lowered_s1(eng._bm._leader._exec_group.executor)
 
 
 def test_the_cerebras_shaped_step_program_is_the_parents_bytes():
     text = _gpt2_lowered_s1()
     assert hashlib.sha256(text.encode()).hexdigest() == GPT2_S1_SHA256
+
+
+def _gpt2_logits(eng, seed=3):
+    """A window of 8 tokens, then three S=1 steps, on both slots: the
+    raw bytes of every step's logits."""
+    drv = eng.driver(2)
+    drv.leave(0), drv.leave(1)
+    drv.join(0), drv.join(1)
+    rng = np.random.default_rng(seed)
+    toks = rng.integers(0, _GPT2_KW["vocab_size"], (2, 11)).astype(np.int32)
+    outs = [drv.step(toks[:, :8]).asnumpy()]
+    outs += [drv.step(toks[:, t:t + 1]).asnumpy() for t in range(8, 11)]
+    return outs
+
+
+def _precast(params):
+    """The parameters as PR 28's path takes them: cast on the device by
+    the convert a step program ran."""
+    return {n: jnp.asarray(v).astype(jnp.bfloat16)
+            for n, v in params.items()}
+
+
+def test_narrowing_at_bind_serves_the_same_bits():
+    """Float32 parameters narrowed at bind against the same values
+    handed over pre-cast with ``astype(bfloat16)``: the programs are
+    the same text and every logit, through a window and at S=1, is
+    bit-equal."""
+    params = _gpt2_params()
+    narrowed = _gpt2_engine(params, name="gpt2-narrowed")
+    handed = _gpt2_engine(_precast(params), name="gpt2-handed")
+    assert narrowed.params_narrowed == len(params)
+    assert handed.params_narrowed == 0
+    for a, b in zip([narrowed._bm._leader, narrowed._window_mods[2, 8]],
+                    [handed._bm._leader, handed._window_mods[2, 8]]):
+        assert _lowered_s1(a._exec_group.executor) == \
+            _lowered_s1(b._exec_group.executor)
+    got, want = _gpt2_logits(narrowed), _gpt2_logits(handed)
+    assert [g.shape for g in got] == [(2, 8, 96)] + [(2, 1, 96)] * 3
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.tobytes() == w.tobytes()
+    assert np.abs(got[-1].astype(np.float32)).max() > 0
+
+
+@pytest.mark.parametrize("swapped_in", ["float32", "bfloat16"])
+def test_a_hot_swap_stores_into_the_narrow_cells(swapped_in):
+    """New values into a warm engine, float32 or pre-cast: cast as they
+    are stored, no cell re-allocated at float32, no program re-keyed,
+    and the new values are what is served."""
+    eng = _gpt2_engine(_gpt2_params(5), name=f"gpt2-swap-{swapped_in}")
+    leader = eng._bm._leader
+    keys = eng.program_keys()
+    cells = dict(leader._exec_group.executor.arg_dict)
+    before = _gpt2_logits(eng)
+    new = _gpt2_params(6)
+    handed = new if swapped_in == "float32" else _precast(new)
+    leader._exec_group.adopt_param_dtypes(handed)     # init_params' step
+    leader.set_params(handed, {}, allow_missing=True)
+    after_cells = leader._exec_group.executor.arg_dict
+    assert all(after_cells[n] is c for n, c in cells.items())
+    for mod in [leader] + list(eng._window_mods.values()):
+        assert set(_param_dtypes(mod, eng.data_names).values()) == \
+            {"bfloat16"}
+    assert eng.program_keys() == keys
+    got = _gpt2_logits(eng)
+    want = _gpt2_logits(_gpt2_engine(_precast(new),
+                                     name=f"gpt2-swapped-{swapped_in}"))
+    for g, w, b in zip(got, want, before):
+        assert g.tobytes() == w.tobytes() and g.tobytes() != b.tobytes()
+    args, _aux = leader.get_params()
+    assert {str(v.dtype) for v in args.values()} == {"bfloat16"}
+
+
+@pytest.mark.parametrize("handed,narrowed", [("bfloat16", 0),
+                                             ("float32", None)])
+def test_the_engine_says_what_it_holds_and_what_it_narrowed(handed,
+                                                            narrowed):
+    """``serve.decode.params.bytes{dtype=}`` and
+    ``serve.decode.params.narrowed``, set once at bind and shown by
+    ``stats()``: nothing narrowed for parameters handed at the compute
+    width, every floating parameter for float32 ones."""
+    from mxnet_tpu import telemetry
+    gen = lambda s: _decode_symbol(s, with_routing=False)   # noqa: E731
+    params = _params(dtype=handed)
+    name = f"tiny-olmoe-params-{handed}"
+    sched = mx.serve.serve_decoder(
+        gen(1), params, name=name, capacity=CAPACITY, ladder=[2],
+        context=mx.cpu(0), compute_dtype="bfloat16", symbol_gen=gen,
+        prefill_chunk=WINDOW, start=False)
+    sched.stop()
+    n_bytes = 2 * sum(int(np.prod(v.shape)) for v in params.values())
+    want = len(params) if narrowed is None else narrowed
+    stats = sched.stats()
+    assert stats["params_bytes"] == {"bfloat16": n_bytes}
+    assert stats["params_narrowed"] == want
+    gauge = telemetry.get_metric("serve.decode.params.bytes", model=name,
+                                 dtype="bfloat16")
+    assert gauge.value == n_bytes
+    assert telemetry.get_metric("serve.decode.params.bytes", model=name,
+                                dtype="float32") is None
+    assert telemetry.get_metric("serve.decode.params.narrowed",
+                                model=name).value == want
 
 
 # --------------------------------------- a chip's share of a wider layer

@@ -239,6 +239,9 @@ def test_breaker_reject_stamps_trace_id():
 
 
 def test_stats_surfaces_exemplar_and_slowest_trace():
+    # the registry is the process's: a "default" model served by a file
+    # that ran earlier on this worker would own the p99 exemplar
+    tm.metrics.reset()
     clock = FakeClock()
     sym = _mlp("st")
     server = mx.serve.serve(_bound_module(sym), ladder=[1, 2],
