@@ -209,6 +209,29 @@ def kernel_sites(w, seed=0):
                     [normal(q, bf) for _ in range(3)]
                     + [normal(kv, cache_dt), normal(kv, cache_dt),
                        cursors(step)])))
+    # grouped K/V heads under a window, the pools as rings (rtc.py:
+    # 8 query heads on one K/V head, a window of a quarter of ``cap``
+    # in a context of four times it): the S=1 read (``decode_attn``)
+    # and a window of twice ``win`` rows (``window_attn`` at the smoke's
+    # widths); every row fed, as for the latent ops below
+    from mxnet_tpu.models.transformer import ring_rows
+    swa_w = cap // 4
+    swa_ring = ring_rows(swa_w, 2 * win)
+    for step in (1, 2 * win):
+        q, kv = (slots, 8, step, dh_dec), (slots, 1, step, dh_dec)
+        ring = (slots, 1, swa_ring, dh_dec)
+        sites.append((
+            f"attention_decode_gqa_ring_s{step}", "attention_decode",
+            {"capacity": 4 * cap, "rope": True, "per_slot": True,
+             "kv_heads": 1, "window": swa_w, "ring": swa_ring, "fed": True},
+            [q, kv, kv, (slots,), ring, ring, (slots, 1)],
+            [bf, bf, bf, "int32", bf, bf, "int32"], False,
+            lambda q=q, kv=kv, ring=ring, step=step: [
+                normal(q, bf), normal(kv, bf), normal(kv, bf),
+                jnp.full((slots,), step, jnp.int32), normal(ring, bf),
+                normal(ring, bf),
+                jnp.asarray(rs.randint(0, 4 * cap - step + 1, (slots, 1))
+                            .astype(np.int32))]))
     # the routed expert feed-forward at OLMoE's own expert count, at an
     # S=1 step's rows and at a prefill window's (ragged groups, some
     # empty at S=1)

@@ -341,6 +341,125 @@ def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
     return x + h, selection
 
 
+#: the keys of Trinity's published ``config.json`` (``model_type
+#: afmoe``) that ``block="afmoe"`` reads (``get_decode_symbol(afmoe=...)``);
+#: ``layer_types`` names the layers that are run, one entry each
+AFMOE_KEYS = ("num_key_value_heads", "head_dim", "sliding_window",
+              "layer_types", "num_dense_layers", "intermediate_size",
+              "moe_intermediate_size", "num_experts", "num_experts_per_tok",
+              "num_shared_experts", "route_norm", "route_scale")
+
+
+def ring_rows(window, step_len):
+    """Rows of a sliding layer's ring: the window and the largest
+    dispatch's rows, rounded up to the read's key block (512 rows; 8
+    at sizes under that)."""
+    rows = int(window) + int(step_len)
+    unit = 512 if rows >= 512 else 8
+    return -(-rows // unit) * unit
+
+
+def _afmoe_spec(block, afmoe, n_layer, rms_eps, capacity, max_step_len):
+    if block != "afmoe":
+        return None
+    afmoe = dict(afmoe or {})
+    missing = [k for k in AFMOE_KEYS if k not in afmoe]
+    if missing:
+        raise MXNetError(f"block='afmoe' needs afmoe= with {missing}")
+    kinds = list(afmoe["layer_types"])
+    if len(kinds) != n_layer or \
+            set(kinds) - {"sliding_attention", "full_attention"}:
+        raise MXNetError(
+            f"block='afmoe': layer_types {kinds} must name "
+            "'sliding_attention' or 'full_attention' for each of "
+            f"{n_layer} layers")
+    window = int(afmoe["sliding_window"])
+    ring = ring_rows(window, max_step_len)
+    # a ring as long as the context would hold a row per position
+    afmoe.update(layer_types=kinds, rms_eps=float(rms_eps),
+                 ring=ring if ring < capacity else 0)
+    return afmoe
+
+
+def _afmoe_norm(x, name, afmoe):
+    return sym.RMSNorm(x, eps=afmoe["rms_eps"], name=name)
+
+
+def _afmoe_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
+                 capacity, afmoe):
+    """One Trinity block (``afmoe``: ``_afmoe_spec``) of the slot-pooled
+    decode graph, no bias anywhere: ``x = x + N(Attn(N(x)))``, then
+    ``x = x + N(FF(N(x)))`` - each sub-layer's output is normed before
+    it is added. Attention over grouped K/V heads (``n_head`` query
+    heads on ``num_key_value_heads``, q and k normed per head), on a
+    layer ``layer_types`` marks ``sliding_attention`` with rotary
+    positions and a window whose pools are rings, on a
+    ``full_attention`` layer with neither; its output is gated by a
+    sigmoid of a projection of the layer's input. Then a dense
+    gated-SiLU feed-forward (layers before ``num_dense_layers``) or
+    sigmoid-routed experts beside a shared one (``MoEFFN``); the pads
+    of a window (rows past ``fed``) are routed nowhere."""
+    pfx = f"{name}_l{i}"
+    T = seq_len
+    n_kv, dh = afmoe["num_key_value_heads"], afmoe["head_dim"]
+    sliding = afmoe["layer_types"][i] == "sliding_attention"
+
+    rows = sym.Reshape(_afmoe_norm(x, f"{pfx}_ln1", afmoe), shape=(-3, 0),
+                       name=f"{pfx}_attn_fold")              # (B*T, D)
+    # q, k, v and the gate as the row blocks of one projection
+    wide = sym.FullyConnected(rows, num_hidden=2 * (n_head + n_kv) * dh,
+                              no_bias=True, name=f"{pfx}_qkvg")
+    at, heads = 0, {}
+    for nm, n in (("q", n_head), ("k", n_kv), ("v", n_kv)):
+        part = sym.slice_axis(wide, axis=1, begin=at, end=at + n * dh,
+                              name=f"{pfx}_{nm}_rows")
+        at += n * dh
+        part = sym.Reshape(part, shape=(-1, T, n, dh),
+                           name=f"{pfx}_{nm}_split")
+        if nm != "v":                      # normed per head, over dh
+            part = _afmoe_norm(part, f"{pfx}_{nm}_norm", afmoe)
+        heads[nm] = sym.transpose(part, axes=(0, 2, 1, 3),
+                                  name=f"{pfx}_{nm}")        # (B, n, T, dh)
+    gate = sym.slice_axis(wide, axis=1, begin=at, end=at + n_head * dh,
+                          name=f"{pfx}_gate_rows")
+    att = sym.attention_decode(
+        heads["q"], heads["k"], heads["v"], fed, capacity=capacity,
+        rope=sliding, rope_base=rope_base, per_slot=True, kv_heads=n_kv,
+        fed=True, name=f"{pfx}_attn",
+        **({"window": afmoe["sliding_window"], "ring": afmoe["ring"]}
+           if sliding else {}))
+    att = sym.transpose(att, axes=(0, 2, 1, 3), name=f"{pfx}_attn_t")
+    att = sym.Reshape(att, shape=(-3, -3), name=f"{pfx}_attn_merge")
+    att = att * sym.Activation(gate, act_type="sigmoid",
+                               name=f"{pfx}_gate")
+    proj = sym.FullyConnected(att, num_hidden=d_model, no_bias=True,
+                              name=f"{pfx}_proj")
+    proj = sym.Reshape(proj, shape=(-1, T, d_model),
+                       name=f"{pfx}_proj_unfold")
+    x = x + _afmoe_norm(proj, f"{pfx}_post_attn_ln", afmoe)
+
+    rows = sym.Reshape(_afmoe_norm(x, f"{pfx}_ln2", afmoe), shape=(-3, 0),
+                       name=f"{pfx}_ffn_fold")
+    if i < afmoe["num_dense_layers"]:
+        h = sym.FullyConnected(rows,
+                               num_hidden=2 * afmoe["intermediate_size"],
+                               no_bias=True, name=f"{pfx}_ffn_gate_up")
+        h = sym.GatedSiLU(h, name=f"{pfx}_ffn_act")
+        h = sym.FullyConnected(h, num_hidden=d_model, no_bias=True,
+                               name=f"{pfx}_ffn_down")
+    else:
+        h = sym.MoEFFN(
+            rows, fed, step_len=T, num_experts=afmoe["num_experts"],
+            num_hidden=afmoe["moe_intermediate_size"],
+            top_k=afmoe["num_experts_per_tok"],
+            norm_topk=afmoe["route_norm"], scoring="sigmoid",
+            router_bias=True, scaling=afmoe["route_scale"],
+            shared_hidden=afmoe["num_shared_experts"]
+            * afmoe["moe_intermediate_size"], name=f"{pfx}_moe")
+    h = sym.Reshape(h, shape=(-1, T, d_model), name=f"{pfx}_ffn_unfold")
+    return x + _afmoe_norm(h, f"{pfx}_post_ffn_ln", afmoe)
+
+
 def _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps):
     if block != "evabyte":
         return None
@@ -351,10 +470,23 @@ def _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps):
 
 def _validate(vocab_size, d_model, n_head, pos_embed, block="gpt2",
               n_expert=None, top_k=None, expert_width=None, eva=None,
-              glm=None):
-    if block not in ("gpt2", "olmoe", "evabyte", "glm_dsa"):
-        raise MXNetError(f"block {block!r}: 'gpt2', 'olmoe', 'evabyte' "
-                         "or 'glm_dsa'")
+              glm=None, afmoe=None):
+    if block not in ("gpt2", "olmoe", "evabyte", "glm_dsa", "afmoe"):
+        raise MXNetError(f"block {block!r}: 'gpt2', 'olmoe', 'evabyte', "
+                         "'glm_dsa' or 'afmoe'")
+    if block == "afmoe":
+        if afmoe is None:
+            raise MXNetError(
+                "block='afmoe' is served, not trained: its attention "
+                "(grouped K/V heads, a window over rings) exists as the "
+                "decode op alone (get_decode_symbol(per_slot=True))")
+        if n_head % afmoe["num_key_value_heads"] or afmoe["head_dim"] % 2:
+            raise MXNetError(
+                f"block='afmoe': {n_head} query heads on "
+                f"{afmoe['num_key_value_heads']} K/V heads of "
+                f"{afmoe['head_dim']}: the K/V heads divide the query "
+                "heads, and a head's width is even (rotary pairs)")
+        return
     if block == "glm_dsa":
         if glm is None:
             raise MXNetError(
@@ -499,7 +631,8 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                       expert_width=None, norm_topk=False, rms_eps=1e-5,
                       tie_head=True, embed_scale=True, window=2048,
                       chunk=16, n_pred_heads=1, ffn_width=None,
-                      multibyte=False, glm=None):
+                      multibyte=False, glm=None, afmoe=None,
+                      max_step_len=None):
     """Incremental KV-cache decoder: ``(B, step_len)`` new token ids in,
     logits ``(B, step_len, vocab)`` out, per-layer K/V caches of
     ``capacity`` positions riding executor aux state. Parameter names
@@ -555,14 +688,37 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     takes ``fed`` like EvaByte's and advances by it, but its state is a
     row per position in every pool (``"rows"``), so the driver rewinds,
     captures and restores it as it does a K/V cache.
+
+    ``block="afmoe"`` (per-slot only) builds Trinity's block
+    (``_afmoe_block``) from ``afmoe``, the published config's keys
+    (``AFMOE_KEYS``; ``layer_types`` one entry a layer that is run):
+    ``n_head`` query heads on ``num_key_value_heads`` K/V heads of
+    ``head_dim``, q and k normed per head, the attention output gated;
+    layers marked ``sliding_attention`` are rotary and attend a window
+    of ``sliding_window`` positions, layers marked ``full_attention``
+    have no positions and attend everything; four norms a layer, a
+    scaled embedding (``embed_scale``), a dense feed-forward on the
+    first ``num_dense_layers`` layers and sigmoid-routed experts, all
+    held, beside a shared one after; untied head. **Capacity per kind
+    of layer**: a full layer's pools hold ``capacity`` rows
+    (``"rows"``); a sliding layer's are rings of ``ring_rows(
+    sliding_window, max_step_len)`` rows, whatever the capacity
+    (``"ring"``; a ring that would be as long as the capacity is a pool
+    of a row per position instead). ``max_step_len`` is the largest
+    ``step_len`` of the graphs that share the pools (default: this
+    graph's): every graph of one engine names the same. The graph takes
+    ``fed`` and advances by it; with a ring it is not positional (see
+    ``BatchedKVCacheDecoder``).
     """
     eva = _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps)
     glm = _glm_spec(block, glm, n_layer, rms_eps)
+    capacity = capacity or default_cache_capacity()
+    afmoe = _afmoe_spec(block, afmoe, n_layer, rms_eps, capacity,
+                        max(step_len, max_step_len or 1))
     _validate(vocab_size, d_model, n_head, pos_embed, block, n_expert,
-              top_k, expert_width, eva=eva, glm=glm)
+              top_k, expert_width, eva=eva, glm=glm, afmoe=afmoe)
     moe = _moe_spec(block, n_expert, top_k, expert_width, norm_topk,
                     rms_eps)
-    capacity = capacity or default_cache_capacity()
     cache_dtype = cache_dtype or default_cache_dtype()
     max_seq_len = max_seq_len or capacity
     S = step_len
@@ -581,6 +737,14 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                              "compute width (no cache_dtype)")
         return _glm_decode_symbol(vocab_size, d_model, n_layer, n_head,
                                   rope_base, capacity, S, name, glm)
+    if afmoe is not None:
+        if not per_slot or cache_dtype:
+            raise MXNetError("block='afmoe' is the slot-pooled decode "
+                             "graph (per_slot=True) with state at the "
+                             "compute width (no cache_dtype)")
+        return _afmoe_decode_symbol(vocab_size, d_model, n_layer, n_head,
+                                    rope_base, capacity, S, name, afmoe,
+                                    embed_scale)
 
     data = sym.var("data")
     tok_w = sym.var(f"{name}_tok_embed_weight")
@@ -615,6 +779,27 @@ def _glm_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
             capacity=capacity, glm=glm)
     flat = sym.Reshape(_glm_norm(x, f"{name}_ln_f", glm), shape=(-3, 0),
                        name=f"{name}_head_fold")
+    logits = sym.FullyConnected(
+        flat, weight=sym.var(f"{name}_head_weight"), num_hidden=vocab_size,
+        no_bias=True, name=f"{name}_logits")
+    return sym.Reshape(logits, shape=(-1, S, vocab_size),
+                       name=f"{name}_logits_bsv")
+
+
+def _afmoe_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
+                         capacity, S, name, afmoe, embed_scale):
+    data = sym.var("data")
+    fed = sym.var("fed")
+    scale = {"scale": float(np.sqrt(d_model))} if embed_scale else {}
+    x = sym.Embedding(data=data, weight=sym.var(f"{name}_tok_embed_weight"),
+                      input_dim=vocab_size, output_dim=d_model,
+                      name=f"{name}_tok_embed", **scale)     # (B, S, D)
+    for i in range(n_layer):
+        x = _afmoe_block(x, fed, i=i, seq_len=S, d_model=d_model,
+                         n_head=n_head, rope_base=rope_base, name=name,
+                         capacity=capacity, afmoe=afmoe)
+    flat = sym.Reshape(_afmoe_norm(x, f"{name}_ln_f", afmoe),
+                       shape=(-3, 0), name=f"{name}_head_fold")
     logits = sym.FullyConnected(
         flat, weight=sym.var(f"{name}_head_weight"), num_hidden=vocab_size,
         no_bias=True, name=f"{name}_logits")
@@ -864,6 +1049,15 @@ class BatchedKVCacheDecoder:
     the context (cursor + S against capacity), whatever the pools
     hold; ``DecodeEngine.migrate`` copies every family.
 
+    A graph with sliding layers (``block="afmoe"``) keeps their K and V
+    in rings of the window and one dispatch's rows (family ``"ring"``)
+    beside the full layers' ``"rows"``: not positional either, by the
+    families and not by any cell's name. It is fed like the other; a
+    cursor goes to 0 or back by as many positions as the ring holds
+    beyond its window and a dispatch's rows (``ring_slack``);
+    ``capture_rows``/``restore_rows`` raise, naming the families.
+    ``state_bytes`` is the state's bytes by family.
+
     ``serve.decode.DecodeScheduler`` builds the continuous-batching
     front end (admission, retirement, streaming, rung ladder) on top of
     one of these per slot rung.
@@ -896,6 +1090,12 @@ class BatchedKVCacheDecoder:
         # the per-slot state, by family, as the graph's ops declare it
         self._state = slot_state(module.symbol)
         self.positional = set(self._state) <= {"cursor", "rows"}
+        # a window of exact rows beside summaries (EVA attention)
+        self.summarises = "summary" in self._state
+        self.state_bytes = {
+            family: sum(cell.size * cell.dtype.itemsize
+                        for _nm, cell in self._cells(family))
+            for family in self._state}
         self.feeds = "fed" in module.symbol.list_arguments()
         self.last_reads = None
         # a graph that attends a learned selection of positions: what
@@ -906,17 +1106,28 @@ class BatchedKVCacheDecoder:
         # ``attention_decode`` layers, whose read walks a slot's pool up
         # to its cursor: what the latest dispatch read of the pools
         # (``_attention_reads``)
-        self._attn_layers = sum(
-            not n.is_variable and n.op == "attention_decode"
-            for n in module.symbol._topo_nodes())
-        self.attends = self._attn_layers > 0
+        # (window or 0, rows of its pools) of each such layer
+        layers = [(int(n.attrs.get("window") or 0),
+                   int(n.attrs.get("ring") or 0) or self.capacity)
+                  for n in module.symbol._topo_nodes()
+                  if not n.is_variable and n.op == "attention_decode"]
+        self.attends = bool(layers)
         self.last_attention = None
+        # what ``_attention_reads`` needs of them: how many, the rows
+        # their pools hold a slot, and the layers of each window
+        windows = [w for w, _rows in layers]
+        self._attn_shape = (
+            len(layers), sum(rows for _w, rows in layers),
+            sorted((w, windows.count(w)) for w in set(windows)))
+        # rings: the shortest one's rows and its window
+        self._ring = min(((rows, w) for w, rows in layers
+                          if rows != self.capacity), default=None)
         # seconds the latest ``step`` spent staging and launching and
         # the latest ``select_rows`` took, on the clock its caller
         # handed it (``now=``); None where the caller handed none
         self.last_stage = self.last_launch = self.last_select = None
         self._donated_handle = None      # (registry generation, counter)
-        if not self.positional:
+        if self.summarises:
             ring = exe.aux_dict[self._state["window"][0]]
             pool = exe.aux_dict[self._state["summary"][0]]
             self.window = int(ring.shape[2])
@@ -934,6 +1145,17 @@ class BatchedKVCacheDecoder:
     @property
     def window_lens(self):
         return sorted(self._windows)
+
+    @property
+    def ring_slack(self):
+        """How far back of its cursor a slot of a graph with rings can
+        be rewound: what the shortest ring holds beyond its window and
+        the rows of the largest dispatch (whose pads may have been
+        written behind the cursor), and one. None without a ring."""
+        if self._ring is None:
+            return None
+        rows, window = self._ring
+        return rows - window - max([1] + self.window_lens) + 1
 
     def _cells(self, family):
         """(name, cell) of every aux cell of one state family, in graph
@@ -1014,7 +1236,21 @@ class BatchedKVCacheDecoder:
         if mask.sum() != rows.size:
             raise MXNetError(f"slot named twice in one cursor update: "
                              f"{rows.tolist()}")
-        if not self.positional:
+        if self._ring is not None:
+            cur = self.pos[rows]
+            back = cur - positions
+            bad = (positions != 0) & ((back < 0) | (back > self.ring_slack))
+            if bad.any():
+                raise MXNetError(
+                    f"cursor of slot(s) {rows[bad].tolist()} cannot move "
+                    f"from {cur[bad].tolist()} to "
+                    f"{positions[bad].tolist()}: this decoder's sliding "
+                    f"layers keep rings (families {sorted(self._state)}; "
+                    f"{self._ring[0]} rows for a window of "
+                    f"{self._ring[1]}), so a cursor goes to 0 or back by "
+                    f"at most {self.ring_slack}, inside what the ring "
+                    "still holds")
+        if self.summarises:
             cur = self.pos[rows]
             ends = (positions // self.window + 1) * self.window
             bad = (positions != 0) & ((positions > cur) | (cur > ends))
@@ -1255,15 +1491,22 @@ class BatchedKVCacheDecoder:
     def _attention_reads(self, fed):
         """What one dispatch that feeds ``fed`` tokens a slot reads of
         the ``attention_decode`` pools, from the cursors alone (no
-        fetch), summed over the fed slots and the layers: ``[rows at or
-        before each slot's last query, rows the pools hold (slots x
-        capacity)]``. Their ratio is the share of a pool that a
-        dispatch has any use for. None for a graph without the op."""
+        fetch), summed over the fed slots and the layers: ``[positions
+        at or before each slot's last query, rows the pools hold (slots
+        x capacity, or x a ring's rows), positions that query attends
+        (on a sliding layer at most its window)]``. The first over the
+        second is the share of a pool that a dispatch has any use for;
+        the third over the first the share of the keys that the windows
+        leave. None for a graph without the op."""
         if not self.attends:
             return None
         live = np.minimum((self.pos + fed)[fed > 0], self.capacity)
-        return self._attn_layers * np.asarray(
-            [np.sum(live), self.slots * self.capacity], np.int64)
+        layers, pool_rows, by_window = self._attn_shape
+        total = np.sum(live)
+        return np.asarray(
+            [layers * total, self.slots * pool_rows,
+             sum(n * (np.sum(np.minimum(live, w)) if w else total)
+                 for w, n in by_window)], np.int64)
 
     def _selection_reads(self, fed):
         """What one dispatch that feeds ``fed`` tokens a slot reads
@@ -1289,7 +1532,7 @@ class BatchedKVCacheDecoder:
         layers: ``[layer executions, exact rows and summaries that each
         slot's last real query attends, chunks summarised, windows
         closed]``."""
-        if self.positional:
+        if not self.summarises:
             return None
         W, C = self.window, self.chunk
         live = fed > 0

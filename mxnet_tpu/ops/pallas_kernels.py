@@ -1070,9 +1070,44 @@ def _decode_attn_blocks(H, S, Dh, C, q_dtype, cache_dtype):
     return hb, block_k
 
 
-def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv):
+def _live_blocks(lo, hi, block_k, n_blocks, ring):
+    """``(first, count)`` of the key blocks that hold the positions
+    ``lo..hi`` of one slot: of a pool with a row per position the blocks
+    ``lo // block_k .. hi // block_k``; of a ring (``ring`` rows, a
+    position at its value modulo that) the ``count`` blocks from
+    ``first`` on, modulo ``n_blocks`` - at most all of them, each once,
+    whatever the wrap."""
+    if not ring:
+        first = lo // block_k
+        return first, hi // block_k - first + 1
+    at = jax.lax.rem(lo, ring)
+    count = (jax.lax.rem(at, block_k) + hi - lo) // block_k + 1
+    return at // block_k, jnp.minimum(count, n_blocks)
+
+
+def _key_positions(block, block_k, last, shape, ring):
+    """The stream position of every key of a block, along ``shape``'s
+    last axis: its address in a pool with a row per position; in a ring
+    the newest position at or before ``last`` (the newest row written)
+    that lies at that address - negative while the ring has not been
+    filled that far, which no query attends."""
+    at = block * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, shape, len(shape) - 1)
+    if not ring:
+        return at
+    return last - jax.lax.rem(last - at + ring, ring)
+
+
+def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv,
+                        period=0, window=0, ring=0):
     """Grid (slot, head group, key block); the group's heads in a loop
-    of ``_READ_UNROLL`` heads a turn. ``narrow_q``: q and the cache
+    of ``_READ_UNROLL`` heads a turn. ``period``: a head of the pool is
+    read by a group of query heads, whose rows lie one head after
+    another, ``period`` positions each (row ``r`` is the query at
+    ``cursor + r % period``); 0: a row a position. ``window``: a query
+    at ``t`` attends ``j > t - window`` alone, and the steps walk the
+    blocks from the first that holds such a key (``_live_blocks``);
+    ``ring``: the pool is a ring of that many rows. ``narrow_q``: q and the cache
     rows are bfloat16 values, so q.K is one bfloat16 product with
     float32 accumulation, exact as the composition's; ``narrow_kv``:
     the rows are, so p.V is the float32 p split in three against the
@@ -1128,15 +1163,30 @@ def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv):
 
         cursor = pos_ref[b]                  # this slot's write position
         k_start = kb * block_k
+        if window:
+            last = cursor + ((period or s_len) - 1)   # the newest row
+            first, count = _live_blocks(jnp.maximum(cursor - window + 1, 0),
+                                        last, block_k, n_kb, ring)
 
         def update():
             # query row i sits at stream position cursor + i and attends
             # key positions <= that (the same comparison as the XLA mask)
-            q_pos = cursor + jax.lax.broadcasted_iota(
-                jnp.int32, (s_len, block_k), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (s_len, block_k), 1)
-            attends = k_pos <= q_pos
+            row = jax.lax.broadcasted_iota(jnp.int32, (s_len, block_k), 0)
+            if period:
+                row = jax.lax.rem(row, period)
+            q_pos = cursor + row
+            if window:
+                block = first + kb
+                if ring:
+                    block = jax.lax.rem(block, n_kb)
+                k_pos = _key_positions(block, block_k, last,
+                                       (s_len, block_k), ring)
+                attends = (k_pos <= q_pos) & (k_pos > q_pos - window) \
+                    & (k_pos >= 0)
+            else:
+                k_pos = k_start + jax.lax.broadcasted_iota(
+                    jnp.int32, (s_len, block_k), 1)
+                attends = k_pos <= q_pos
 
             def head(h):
                 if narrow_q:
@@ -1171,7 +1221,8 @@ def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv):
         # next live step reads, so they cost no HBM traffic of their
         # own either). Block 0 always runs — cursor >= 0 keys at least
         # one position, so l is never zero at emit.
-        pl.when(k_start <= cursor + s_len - 1)(update)
+        pl.when(kb < count if window
+                else k_start <= cursor + (period or s_len) - 1)(update)
 
         @pl.when(kb == n_kb - 1)
         def _emit():
@@ -1180,14 +1231,14 @@ def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv):
     return kernel
 
 
-def decode_attention(q, k_cache, v_cache, pos):
+def decode_attention(q, k_cache, v_cache, pos, window=0, ring=False):
     """Cursor-bounded flash-decode read over a fixed-capacity KV cache.
 
     ``q`` is (B, H, S, Dh) already-rotated queries, the caches are
-    (B, H, C, Dh) with the step's rows already written, and ``pos`` is
-    the (B,) per-row cursor (a scalar-cursor engine broadcasts before
-    calling). The grid is (B, H // hb, C // block_k) over the pools as
-    they lie: a step takes a group of ``hb`` heads of one slot and
+    (B, H_kv, C, Dh) with the step's rows already written, and ``pos``
+    is the (B,) per-row cursor (a scalar-cursor engine broadcasts before
+    calling). The grid is (B, H_kv // hb, C // block_k) over the pools
+    as they lie: a step takes a group of ``hb`` heads of one slot and
     ``block_k`` keys (``_decode_attn_blocks``: all 16 heads x 512 keys
     at the serving shapes, 32-64 steps a layer), the heads in a loop.
     The scalar-prefetched cursor bounds the K/V index maps to the live
@@ -1199,21 +1250,38 @@ def decode_attention(q, k_cache, v_cache, pos):
     accumulates in f32 VMEM scratch; fp8 cache rows dequantize on read
     inside the kernel. Returns f32 (B, H, S, Dh) — the caller casts.
 
+    **Grouped heads**: with ``H_kv < H`` query head ``i`` reads K/V
+    head ``i // (H // H_kv)``, and the group's query heads go as the
+    rows of one head of the pool: one fetched K/V block serves
+    ``H // H_kv x S`` query rows. **A window** (``window`` > 0): the
+    query at ``t`` attends ``t - window < j <= t``, and the live blocks
+    start at the first that holds such a key, so the traffic is the
+    window's, whatever the context. ``ring``: the pools are rings, a
+    position at its value modulo ``C`` (``C >= window + S``).
+
     The call is a jitted function of its own (the kernel is
     ``decode_attn`` in the device trace), so that a step program lowers
     it once and calls it from every layer."""
     return _decode_attention(pos.astype(jnp.int32), q, k_cache, v_cache,
-                             interpret=_interpret())
+                             interpret=_interpret(), window=int(window),
+                             ring=bool(ring))
 
 
-@partial(jax.jit, static_argnames=("interpret", "name"))
+@partial(jax.jit, static_argnames=("interpret", "name", "window", "ring"))
 def _decode_attention(pos, q, k_cache, v_cache, interpret,
-                      name="decode_attn"):
+                      name="decode_attn", window=0, ring=False):
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, S, Dh = q.shape
-    C = k_cache.shape[2]
-    hb, block_k = _decode_attn_blocks(H, S, Dh, C, q.dtype, k_cache.dtype)
+    B, heads, S, Dh = q.shape
+    H, C = k_cache.shape[1:3]
+    period = 0
+    if heads != H:
+        # the group's query heads as the rows of their K/V head
+        period, q = S, q.reshape(B, H, heads // H * S, Dh)
+    rows_n = q.shape[2]
+    hb, block_k = _decode_attn_blocks(H, rows_n, Dh, C, q.dtype,
+                                      k_cache.dtype)
+    n_kb = C // block_k
     narrow_kv = _narrow(k_cache.dtype)
 
     def _rows_map(b, g, j, pos_ref):
@@ -1232,24 +1300,203 @@ def _decode_attention(pos, q, k_cache, v_cache, interpret,
                 jnp.where(ahead, jnp.where(more, g + 1, 0), g),
                 jnp.where(ahead, 0, jnp.minimum(j, last_live)), 0)
 
-    rows = pl.BlockSpec((None, hb, S, Dh), _rows_map)
-    block = pl.BlockSpec((None, hb, block_k, Dh), _kv_map)
+    def _window_map(b, g, j, pos_ref):
+        """Under a window: the live blocks from the first on, a dead
+        step on the last live one (no copy)."""
+        cursor = pos_ref[b]
+        first, count = _live_blocks(
+            jnp.maximum(cursor - window + 1, 0), cursor + (S - 1), block_k,
+            n_kb, C if ring else 0)
+        block = first + jnp.minimum(j, count - 1)
+        return (b, g, jax.lax.rem(block, n_kb) if ring else block, 0)
+
+    rows = pl.BlockSpec((None, hb, rows_n, Dh), _rows_map)
+    block = pl.BlockSpec((None, hb, block_k, Dh),
+                         _window_map if window else _kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(B, H // hb, C // block_k),
+        num_scalar_prefetch=1, grid=(B, H // hb, n_kb),
         in_specs=[rows, block, block], out_specs=rows,
-        scratch_shapes=[pltpu.VMEM((hb, S, 1), jnp.float32),
-                        pltpu.VMEM((hb, S, 1), jnp.float32),
-                        pltpu.VMEM((hb, S, Dh), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((hb, rows_n, 1), jnp.float32),
+                        pltpu.VMEM((hb, rows_n, 1), jnp.float32),
+                        pltpu.VMEM((hb, rows_n, Dh), jnp.float32)])
     kwargs = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))}
-    return pallas_call(
-        _decode_attn_kernel(hb, block_k, S, float(Dh) ** -0.5,
+    geometry = {} if not (period or window) else {
+        "period": period, "window": window, "ring": C if ring else 0}
+    out = pallas_call(
+        _decode_attn_kernel(hb, block_k, rows_n, float(Dh) ** -0.5,
                             narrow_kv and q.dtype == jnp.bfloat16,
-                            narrow_kv),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, Dh), jnp.float32),
+                            narrow_kv, **geometry),
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         grid_spec=grid_spec, name=name, interpret=interpret,
         **kwargs)(pos, q, k_cache, v_cache)
+    return out.reshape(B, heads, S, Dh) if period else out
+
+
+#: query rows one grid step of the window read holds (a group's heads x
+#: a block of positions): its scores against a key block are a float32
+#: array of that many rows, and a few of them live at once
+_WINDOW_ROWS = 1024
+#: what the window read may keep in VMEM (a v5e core has 128 MiB)
+_WINDOW_VMEM_LIMIT = 64 << 20
+
+
+def _window_attn_blocks(G, S, C):
+    """``(block_q, block_k)`` of the window read, from the shapes
+    alone: positions a query block so that a group's ``G`` heads of
+    them are at most ``_WINDOW_ROWS`` rows (a divisor of ``S``, whole
+    sublane tiles of 16 where ``S`` is made of them), and the longest
+    key block of whole 128-row tiles up to ``_READ_BLOCK_K``."""
+    unit_q = 16 if S % 16 == 0 else 1
+    block_q = unit_q * _divisor_block(
+        S // unit_q, max(1, min(_WINDOW_ROWS // G, 512) // unit_q))
+    unit = 128 if C % 128 == 0 else 1
+    return block_q, unit * _divisor_block(C // unit,
+                                          max(1, _READ_BLOCK_K // unit))
+
+
+def _window_attn_kernel(G, block_q, block_k, scale, narrow, window, ring):
+    """Grid (slot, K/V head, query block, key block): the online softmax
+    of one query block - ``G`` query heads x ``block_q`` positions as
+    the rows of one product - against one key block of their K/V head.
+    The steps of a query block walk the key blocks that hold a key one
+    of its queries attends (``_live_blocks``: bounded below by the
+    window, above by causality), and a query block wholly past ``fed``
+    (pads) walks none and comes out zero."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    exact = jax.lax.Precision.HIGHEST
+    rows = G * block_q
+
+    def kernel(pos_ref, fed_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s,
+               acc_s):
+        b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+        n_kb, n_q = pl.num_programs(3), pl.num_programs(2)
+        cursor = pos_ref[b]
+        q_lo = cursor + i * block_q
+        q_hi = q_lo + (block_q - 1)
+        last = cursor + (n_q * block_q - 1)      # the newest row written
+        lo = jnp.maximum(q_lo - window + 1, 0) if window else 0
+        first, count = _live_blocks(lo, q_hi, block_k, n_kb, ring)
+        count = jnp.where(i * block_q < fed_ref[b], count, 0)
+
+        @pl.when(j == 0)
+        def _init():
+            m_s[...] = jnp.full(m_s.shape, -jnp.inf, f32)
+            l_s[...] = jnp.zeros(l_s.shape, f32)
+            acc_s[...] = jnp.zeros(acc_s.shape, f32)
+
+        @pl.when(j < count)
+        def _update():
+            block = first + j
+            if ring:
+                block = jax.lax.rem(block, n_kb)
+            k_pos = _key_positions(block, block_k, last, (1, block_k), ring)
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            q_pos = q_lo + (jax.lax.rem(row, block_q) if G > 1 else row)
+            attends = (k_pos <= q_pos) & (k_pos >= 0)
+            if window:
+                attends = attends & (k_pos > q_pos - window)
+            q = q_ref[...].reshape(rows, q_ref.shape[-1])
+            k, v = k_ref[...], v_ref[...]
+            if narrow:
+                s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=f32)
+            else:
+                s = jax.lax.dot_general(
+                    q.astype(f32), k.astype(f32), (((1,), (1,)), ((), ())),
+                    precision=exact)
+            s = jnp.where(attends, s * scale, -jnp.inf)
+            m = m_s[...]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            p = jnp.exp(s - m_safe)          # exp(-inf) = 0: masked keys
+            corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+            m_s[...] = m_new
+            l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            if narrow:
+                pv = jnp.dot(p.astype(bf16), v, preferred_element_type=f32)
+            else:
+                pv = jnp.dot(p, v.astype(f32), precision=exact)
+            acc_s[...] = acc_s[...] * corr + pv
+
+        @pl.when(j == n_kb - 1)
+        def _emit():
+            out = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+            o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
+    return kernel
+
+
+def window_attention(q, k_cache, v_cache, pos, fed, window=0, ring=False):
+    """The read of a long window (chunked prefill): ``q`` (B, H, S, Dh)
+    already-rotated queries of a slot's ``S`` positions from its cursor
+    ``pos`` (B,) on, of which ``fed`` (B,) are real; the caches (B,
+    H_kv, C, Dh) with those rows already written. Flash-style over key
+    blocks bounded on both sides: a query block of ``block_q`` positions
+    (all the ``H // H_kv`` query heads of a K/V head at once, so one
+    fetched K/V block serves them all) visits the key blocks from the
+    one that holds its first query's oldest attended key (position 0,
+    or ``t - window + 1``) to the one that holds its last query's own
+    position, and no other is fetched or computed. ``ring``: the pools
+    are rings of ``C >= window + S`` rows. p.V is one bfloat16 product
+    where the rows are bfloat16 values (float32 at HIGHEST otherwise).
+    Returns (B, H, S, Dh) at ``q``'s dtype.
+
+    A jitted function of its own: the kernel is ``window_attn`` in the
+    device trace, lowered once a step program."""
+    return _window_attention(pos.astype(jnp.int32), fed.astype(jnp.int32),
+                             q, k_cache, v_cache, interpret=_interpret(),
+                             window=int(window), ring=bool(ring))
+
+
+@partial(jax.jit, static_argnames=("interpret", "window", "ring"))
+def _window_attention(pos, fed, q, k_cache, v_cache, interpret, window,
+                      ring):
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, heads, S, Dh = q.shape
+    H, C = k_cache.shape[1:3]
+    G = heads // H
+    block_q, block_k = _window_attn_blocks(G, S, C)
+    n_kb = C // block_k
+    qg = q.reshape(B, H, G, S, Dh)
+
+    def _q_map(b, h, i, j, pos_ref, fed_ref):
+        return (b, h, 0, i, 0)
+
+    def _kv_map(b, h, i, j, pos_ref, fed_ref):
+        """The live blocks from the first on; a dead step stays on the
+        last live one (no copy), a query block of pads on its first."""
+        q_lo = pos_ref[b] + i * block_q
+        lo = jnp.maximum(q_lo - window + 1, 0) if window else 0
+        first, count = _live_blocks(lo, q_lo + (block_q - 1), block_k,
+                                    n_kb, C if ring else 0)
+        block = first + jnp.minimum(j, count - 1)
+        return (b, h, jax.lax.rem(block, n_kb) if ring else block, 0)
+
+    tile = pl.BlockSpec((None, None, G, block_q, Dh), _q_map)
+    block = pl.BlockSpec((None, None, block_k, Dh), _kv_map)
+    rows = G * block_q
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B, H, S // block_q, n_kb),
+        in_specs=[tile, block, block], out_specs=tile,
+        scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, Dh), jnp.float32)])
+    kwargs = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_WINDOW_VMEM_LIMIT)}
+    narrow = _narrow(k_cache.dtype) and q.dtype == jnp.bfloat16 \
+        and k_cache.dtype == jnp.bfloat16
+    out = pallas_call(
+        _window_attn_kernel(G, block_q, block_k, float(Dh) ** -0.5, narrow,
+                            window, C if ring else 0),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        grid_spec=grid_spec, name="window_attn", interpret=interpret,
+        **kwargs)(pos, fed, qg, k_cache, v_cache)
+    return out.reshape(B, heads, S, Dh)
 
 
 #: bytes of double-buffered blocks ``cache_write`` may keep in VMEM
@@ -1257,22 +1504,31 @@ def _decode_attention(pos, q, k_cache, v_cache, interpret,
 _WRITE_BLOCK_BUDGET = 8 << 20
 
 
-def _cache_write_kernel(hb, S, bt, n_blocks, capacity, n_pools):
+def _cache_write_kernel(hb, S, bt, n_blocks, capacity, n_pools,
+                        ring=False):
     """Grid (slot, head group, target block): block ``cursor // bt + j``
     of each pool comes in, the slot's new rows are laid at their
     positions by a one-hot matrix product (exact in any dtype, and no
     store is ever unaligned), and the block goes back where it came
     from. A block index past the last wraps to a block whose positions
-    match no new row, and goes back as it came."""
+    match no new row, and goes back as it came. ``ring``: the pool is a
+    ring of ``capacity`` rows, a position lies at its value modulo
+    that, the rows wrap with it and every slot's rows land."""
     def kernel(p_ref, *refs):
         news, olds = refs[:n_pools], refs[n_pools:2 * n_pools]
         outs = refs[2 * n_pools:]
         b, j = pl.program_id(0), pl.program_id(2)
         p = p_ref[b]
+        if ring:
+            p = jax.lax.rem(p, capacity)
         block = (p // bt + j) % n_blocks
         at = block * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, S), 0)
-        select = (at - p == jax.lax.broadcasted_iota(
-            jnp.int32, (bt, S), 1)) & (p + S <= capacity)
+        if ring:
+            select = jax.lax.rem(at - p + capacity, capacity) \
+                == jax.lax.broadcasted_iota(jnp.int32, (bt, S), 1)
+        else:
+            select = (at - p == jax.lax.broadcasted_iota(
+                jnp.int32, (bt, S), 1)) & (p + S <= capacity)
         hit = jnp.sum(select.astype(jnp.int32), axis=-1, keepdims=True) > 0
         # an fp8 row is exact in bfloat16, a bfloat16 one as it is
         wide = jnp.float32 if outs[0].dtype == jnp.float32 else jnp.bfloat16
@@ -1291,7 +1547,7 @@ def _cache_write_kernel(hb, S, bt, n_blocks, capacity, n_pools):
     return kernel
 
 
-def cache_write(news, pools, pos):
+def cache_write(news, pools, pos, ring=False):
     """Each slot's S new rows into its own ``[b, :, cursor:cursor + S]``
     of every pool, in place: ``news`` are (B, H, S, Dh) arrays at the
     pools' dtype, ``pools`` the matching (B, H, C, Dh) caches (K and V
@@ -1301,18 +1557,22 @@ def cache_write(news, pools, pos):
     with the pools donated to the step program nothing else of them is
     read or written. A slot whose S rows do not fit below the capacity
     writes nothing - ``rtc._write_rows``' rule, which this is the
-    kernel of. Returns the pools.
+    kernel of. ``ring``: the pools are rings (``rtc._write_ring``' rule:
+    position ``t`` at row ``t % C``, every slot writes). Returns the
+    pools.
 
     The call is a jitted function of its own, so that a step program
     traces and lowers the kernel once and calls it from every layer:
     lowered layer by layer, 24 layers of six programs spent a minute
     of set-up on it."""
     return list(_cache_write(pos.astype(jnp.int32), tuple(news),
-                             tuple(pools), interpret=_interpret()))
+                             tuple(pools), interpret=_interpret(),
+                             ring=bool(ring)))
 
 
-@partial(jax.jit, static_argnames=("interpret", "name"))
-def _cache_write(pos, news, pools, interpret, name="cache_write"):
+@partial(jax.jit, static_argnames=("interpret", "name", "ring"))
+def _cache_write(pos, news, pools, interpret, name="cache_write",
+                 ring=False):
     """``cache_write`` as one jitted program; ``name`` is the kernel's
     in the device trace (``ops/mla.py`` lands its latent rows and index
     keys through this under names of its own)."""
@@ -1331,7 +1591,8 @@ def _cache_write(pos, news, pools, interpret, name="cache_write"):
         return (b, g, 0, 0)
 
     def _block_map(b, g, j, pos_ref):
-        return (b, g, (pos_ref[b] // bt + j) % n_blocks, 0)
+        p = jax.lax.rem(pos_ref[b], C) if ring else pos_ref[b]
+        return (b, g, (p // bt + j) % n_blocks, 0)
 
     rows = pl.BlockSpec((None, hb, S, Dh), _rows_map)
     block = pl.BlockSpec((None, hb, bt, Dh), _block_map)
@@ -1342,7 +1603,8 @@ def _cache_write(pos, news, pools, interpret, name="cache_write"):
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))}
     return pallas_call(
-        _cache_write_kernel(hb, S, bt, n_blocks, C, n),
+        _cache_write_kernel(hb, S, bt, n_blocks, C, n,
+                            **({"ring": True} if ring else {})),
         out_shape=tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
                         for p in pools),
         grid_spec=grid_spec, name=name, interpret=interpret,

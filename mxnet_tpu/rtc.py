@@ -20,6 +20,8 @@ ops/optimizer_op.py).
 """
 from __future__ import annotations
 
+from collections import namedtuple
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -646,7 +648,61 @@ def _register_attention():
 #   masked to exactly zero weight and every attended position has been
 #   rewritten by the new sequence before its first read — slot reuse is
 #   bit-clean without touching the cache rows.
+#
+# Three more things a slot-pooled graph can ask of the op, each by an
+# attribute whose default leaves the programs above as they are:
+#
+# * ``kv_heads`` — grouped K/V heads: q has H heads, k and v and the
+#   pools ``kv_heads`` of them, and query head i reads K/V head
+#   ``i // (H // kv_heads)``;
+# * ``window`` — a sliding layer: the query at t attends
+#   ``t - window < j <= t``. With ``ring`` (rows) its pools are RINGS of
+#   that many rows, position t at row ``t % ring``, whatever the
+#   ``capacity`` (which stays the context's bound): family ``"ring"``,
+#   cells ``k_ring`` / ``v_ring``. ``ring`` is at least ``window`` + the
+#   largest dispatch's rows, so that the S rows a dispatch writes fall
+#   on rows no query of it, or after it, attends;
+# * ``fed`` — a fourth input ``fed`` (B,) int32: how many of each
+#   slot's S rows are real. The cursor advances by that (by 0 where S
+#   rows do not fit under the capacity), so nothing runs ahead and
+#   nothing is rewound after a window; the pads' rows are written
+#   behind the cursor, where the next dispatch writes over them.
 # --------------------------------------------------------------------------
+_Geometry = namedtuple("_Geometry", "groups window ring fed capacity")
+
+
+def _decode_geometry(attrs, q, k_cache):
+    """What the attributes ask beyond a pool of a row per position and
+    head (the comment above)."""
+    from .base import parse_bool
+    H, Hkv = q.shape[1], k_cache.shape[1]
+    window, ring = (int(attrs.get(k) or 0) for k in ("window", "ring"))
+    fed = parse_bool(attrs.get("fed") or False)
+    if H % Hkv or (ring and not window) \
+            or (ring and ring < window + q.shape[2]):
+        raise MXNetError(
+            f"attention_decode: {H} query heads on {Hkv} K/V heads, "
+            f"window {window}, ring {ring}, {q.shape[2]} rows a dispatch: "
+            "the K/V heads divide the query heads, and a ring holds its "
+            "window and one dispatch's rows")
+    if (H != Hkv or window or fed) \
+            and not parse_bool(attrs.get("per_slot", False)):
+        raise MXNetError("attention_decode: kv_heads, window and fed are "
+                         "the slot-pooled lowering's (per_slot=True)")
+    return _Geometry(H // Hkv, window, ring, fed,
+                     int(attrs.get("capacity", 256)))
+
+
+def _fed_cursor(geo, pos, S, fed):
+    """``(rows really fed (B,), the new cursor (B, 1))`` of a graph
+    with a ``fed`` input: each slot's count, none where S rows do not
+    fit under the capacity - the write's own rule."""
+    fed = jnp.where(pos + S <= geo.capacity,
+                    jnp.clip(fed.reshape(pos.shape).astype(jnp.int32), 0, S),
+                    0)
+    return fed, (pos + fed).reshape((-1, 1)).astype(jnp.int32)
+
+
 def _decode_check_overflow(pos, S, capacity, per_slot):
     """Overflow raises cleanly whenever the cursor is concrete (eager
     dispatch); jitted paths enforce it host-side via the decode drivers
@@ -698,6 +754,20 @@ def _write_rows(news, pools, pos):
             for new, pool in zip(news, pools)]
 
 
+def _write_ring(news, pools, pos):
+    """The write of a sliding layer's rings (the composition's
+    lowering; ``pallas_kernels.cache_write(ring=True)`` is the kernel of
+    it): slot b's row s lands at ``(pos[b] + s) % ring`` of its own
+    ``pools[i][b]``, row by row since the rows wrap. Every slot writes:
+    what its S rows cover is S positions older than any window reaches."""
+    B, S = news[0].shape[0], news[0].shape[2]
+    at = (pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]) \
+        % pools[0].shape[2]                                    # (B, S)
+    slot = jnp.arange(B, dtype=jnp.int32)[:, None]
+    return [pool.at[slot, :, at].set(new.transpose(0, 2, 1, 3))
+            for new, pool in zip(news, pools)]
+
+
 def _decode_rope_write(attrs, q, k, v, k_cache, v_cache, pos, per_slot,
                        write=None):
     """RoPE + cache write, shared by the XLA composition and the Pallas
@@ -721,7 +791,8 @@ def _decode_rope_write(attrs, q, k, v, k_cache, v_cache, pos, per_slot,
         k = rope_apply(k, positions, base)
     k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
     if per_slot:
-        k_cache, v_cache = (write or _write_rows)(
+        k_cache, v_cache = (write or (
+            _write_ring if int(attrs.get("ring") or 0) else _write_rows))(
             [k, v], [k_cache, v_cache], pos)
     else:
         k_cache = jax.lax.dynamic_update_slice(k_cache, k, (0, 0, pos, 0))
@@ -732,14 +803,16 @@ def _decode_rope_write(attrs, q, k, v, k_cache, v_cache, pos, per_slot,
 def _attention_decode_fwd(attrs, inputs, aux, is_train, rng):
     from .base import parse_bool
 
-    q, k, v = inputs                       # (B, H, S, Dh), S new tokens
+    q, k, v = inputs[:3]                   # (B, H, S, Dh), S new tokens
     k_cache, v_cache, cursor = aux         # (B,H,C,Dh) x2 + cursor
     if is_train:
         raise MXNetError("attention_decode is an inference op (train "
                          "with the full-sequence `attention` graph)")
+    geo = _decode_geometry(attrs, q, k_cache)
     if parse_bool(attrs.get("per_slot", False)):
-        return _attention_decode_per_slot(attrs, q, k, v, k_cache,
-                                          v_cache, cursor)
+        return _attention_decode_per_slot(
+            attrs, q, k, v, k_cache, v_cache, cursor, geo,
+            inputs[3] if geo.fed else None)
     B, H, S, Dh = q.shape
     capacity = k_cache.shape[2]
     pos = cursor.reshape(()).astype(jnp.int32)
@@ -766,21 +839,28 @@ def _attention_decode_fwd(attrs, inputs, aux, is_train, rng):
     return [out.astype(q.dtype)], [k_cache, v_cache, new_cursor]
 
 
-def _attention_decode_per_slot(attrs, q, k, v, k_cache, v_cache, cursor):
+def _attention_decode_per_slot(attrs, q, k, v, k_cache, v_cache, cursor,
+                               geo, fed=None):
     """The slot-pooled lowering: cursor (B, 1), an S-token window per
     slot. S=1 is the steady-state decode program, S>1 the chunked-
     prefill / speculative-verify window; in both each slot writes its S
     tokens at its OWN cursor (``_write_rows``) and the causal mask runs
     over ``cursor[b] + arange(S)``, so one pinned program advances B
-    staggered sequences by S positions per dispatch."""
+    staggered sequences by S positions per dispatch. ``geo``
+    (``_decode_geometry``): a group's query heads go as further rows of
+    their K/V head, a window adds the mask's lower bound, a ring reads
+    each row's position off the newest one written."""
     B, H, S, Dh = q.shape
-    capacity = k_cache.shape[2]
+    pool_rows = k_cache.shape[2]
     pos = cursor.reshape((B,)).astype(jnp.int32)          # (B,)
-    _decode_check_overflow(pos, S, capacity, per_slot=True)
+    _decode_check_overflow(pos, S, geo.capacity if geo.ring else pool_rows,
+                           per_slot=True)
     scale = 1.0 / float(np.sqrt(Dh))
     q, k_cache, v_cache = _decode_rope_write(attrs, q, k, v, k_cache,
                                              v_cache, pos, per_slot=True)
-    key_pos = jnp.arange(capacity)                         # (C,)
+    if geo.groups > 1:
+        q = q.reshape(B, k_cache.shape[1], geo.groups * S, Dh)
+    key_pos = jnp.arange(pool_rows)                        # (C,)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k_cache.astype(q.dtype),
                         precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=jnp.float32) * scale
@@ -788,14 +868,31 @@ def _attention_decode_per_slot(attrs, q, k, v, k_cache, v_cache, cursor):
     # cursor[b] + s and attends key_pos <= that — within-window
     # causality falls out of the same comparison
     q_pos = pos[:, None] + jnp.arange(S)[None, :]          # (B, S)
-    mask = (key_pos[None, None, :] <= q_pos[:, :, None])[:, None]
+    if geo.groups > 1:
+        q_pos = jnp.tile(q_pos, (1, geo.groups))
+    if geo.ring:
+        # row a of a ring holds the newest position at or before the
+        # last one written that lies there (negative: not yet filled)
+        last = (pos + (S - 1))[:, None]
+        key_pos = (last - (last - key_pos[None, :]) % geo.ring)[:, None, :]
+        mask = (key_pos <= q_pos[:, :, None]) & (key_pos >= 0)
+    else:
+        mask = key_pos[None, None, :] <= q_pos[:, :, None]
+    if geo.window:
+        mask = mask & (key_pos > q_pos[:, :, None] - geo.window)
+    mask = mask[:, None]
     logits = jnp.where(mask, logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs,
                      v_cache.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST,
                      preferred_element_type=jnp.float32)
-    new_cursor = (pos + S).reshape((B, 1)).astype(jnp.int32)
+    if geo.groups > 1:
+        out = out.reshape(B, H, S, Dh)
+    if geo.fed:
+        new_cursor = _fed_cursor(geo, pos, S, fed)[1]
+    else:
+        new_cursor = (pos + S).reshape((B, 1)).astype(jnp.int32)
     return [out.astype(q.dtype)], [k_cache, v_cache, new_cursor]
 
 
@@ -811,54 +908,86 @@ def _attention_decode_pallas_variant(attrs, inputs, aux, is_train, rng):
     scalar-prefetched cursor bounds the K/V blocks actually fetched
     from HBM to the live prefix ``[0, cursor_b + S)`` instead of the
     full capacity."""
+    from functools import partial
     from .base import parse_bool
-    from .ops.pallas_kernels import cache_write, decode_attention
+    from .ops.pallas_kernels import (cache_write, decode_attention,
+                                     window_attention)
 
-    q, k, v = inputs
+    q, k, v = inputs[:3]
     k_cache, v_cache, cursor = aux
     if is_train:
         raise MXNetError("attention_decode is an inference op (train "
                          "with the full-sequence `attention` graph)")
     B, H, S, Dh = q.shape
-    capacity = k_cache.shape[2]
+    geo = _decode_geometry(attrs, q, k_cache)
+    capacity = geo.capacity if geo.ring else k_cache.shape[2]
     per_slot = parse_bool(attrs.get("per_slot", False))
+    fed = None
     if per_slot:
         pos = cursor.reshape((B,)).astype(jnp.int32)
-        new_cursor = (pos + S).reshape((B, 1)).astype(jnp.int32)
+        if geo.fed:
+            fed, new_cursor = _fed_cursor(geo, pos, S, inputs[3])
+        else:
+            new_cursor = (pos + S).reshape((B, 1)).astype(jnp.int32)
     else:
         pos = cursor.reshape(()).astype(jnp.int32)
         new_cursor = (pos + S).reshape((1,)).astype(jnp.int32)
     _decode_check_overflow(pos, S, capacity, per_slot=per_slot)
-    q, k_cache, v_cache = _decode_rope_write(attrs, q, k, v, k_cache,
-                                             v_cache, pos,
-                                             per_slot=per_slot,
-                                             write=cache_write)
+    q, k_cache, v_cache = _decode_rope_write(
+        attrs, q, k, v, k_cache, v_cache, pos, per_slot=per_slot,
+        write=partial(cache_write, ring=True) if geo.ring else cache_write)
     # the kernel is row-cursor uniform: the scalar layout is the
     # per-slot layout with every row at the same position
     pos_rows = pos if per_slot else jnp.broadcast_to(pos, (B,))
-    out = decode_attention(q, k_cache, v_cache, pos_rows)
+    if geo.groups * S <= _DECODE_ROWS:
+        out = decode_attention(q, k_cache, v_cache, pos_rows,
+                               **_read_geometry(geo))
+    else:
+        # a long window: the read that tiles the queries too
+        out = window_attention(
+            q, k_cache, v_cache, pos_rows,
+            jnp.full((B,), S, jnp.int32) if fed is None else fed,
+            **_read_geometry(geo))
     return [out.astype(q.dtype)], [k_cache, v_cache, new_cursor]
 
 
+#: query rows of one K/V head (a group's heads x S) that ``decode_attn``
+#: keeps resident; a longer window goes to ``window_attn``
+_DECODE_ROWS = 64
+
+
+def _read_geometry(geo):
+    """The reads' keywords: none where the pool is a row per position
+    and every key at or before the query is attended."""
+    return {"window": geo.window, "ring": bool(geo.ring)} \
+        if geo.window else {}
+
+
 def _attention_decode_eligible(attrs, in_shapes, in_dtypes):
-    """Decode windows up to the declared kspec bounds: S <= 64 rows of
-    a head group resident, Dh <= 512, cache blocks tiling the capacity
-    (the kernel sizes the group and the block inside them). The
-    cache may be the compute width or an fp8 storage dtype (dequantized
-    in-kernel on read). On a real TPU the head dim must be
-    lane-aligned; interpret mode (off-TPU parity tests) takes any."""
+    """Decode windows up to the declared kspec bounds: up to 64 query
+    rows of a K/V head (its group's heads x S) resident, Dh <= 512,
+    cache blocks tiling the capacity (``decode_attn`` sizes the group
+    and the block inside them); past that, a window of whole sublane
+    tiles at a lane-aligned head (``window_attn``, which tiles the
+    queries too). The cache may be the compute width or an fp8 storage
+    dtype (dequantized in-kernel on read). On a real TPU the head dim
+    must be lane-aligned; interpret mode (off-TPU parity tests) takes
+    any."""
     from .ops.pallas_kernels import _interpret
-    if len(in_shapes) < 6 or len(in_shapes[0]) != 4 \
-            or len(in_shapes[3]) != 4:
+    n_in = len(in_shapes) - 3
+    if n_in < 3 or len(in_shapes[0]) != 4 or len(in_shapes[n_in]) != 4:
         return False
     b, h, s, dh = in_shapes[0]
-    c = in_shapes[3][2]
-    if s > 64 or dh > 512 or c < 1:
+    hkv, c = in_shapes[n_in][1:3]
+    if dh > 512 or c < 1 or h % hkv:
+        return False
+    if h // hkv * s > _DECODE_ROWS and (
+            s % (8 if _interpret() else 128) or dh > 256):
         return False
     if str(in_dtypes[0]) not in ("float32", "bfloat16", "float16"):
         return False
-    if str(in_dtypes[3]) not in ("float32", "bfloat16", "float16",
-                                 "float8_e4m3fn", "float8_e5m2"):
+    if str(in_dtypes[n_in]) not in ("float32", "bfloat16", "float16",
+                                    "float8_e4m3fn", "float8_e5m2"):
         return False
     return (dh % 128 == 0 and c % 128 == 0) or _interpret()
 
@@ -866,15 +995,33 @@ def _attention_decode_eligible(attrs, in_shapes, in_dtypes):
 def _attention_decode_infer(attrs, in_shapes):
     from .base import parse_bool
     q_s = in_shapes[0]
-    c = int(attrs.get("capacity", 256))
+    c = int(attrs.get("ring") or 0) or int(attrs.get("capacity", 256))
     per_slot = parse_bool(attrs.get("per_slot", False))
+    fed = parse_bool(attrs.get("fed") or False)
     if q_s is None:
         return in_shapes, [None], [None, None,
                                    None if per_slot else (1,)]
-    b, h, _s, dh = q_s
-    cache = (b, h, c, dh)
+    b, h, s, dh = q_s
+    kv_s = (b, int(attrs.get("kv_heads") or 0) or h, s, dh)
+    cache = kv_s[:2] + (c, dh)
     cur = (b, 1) if per_slot else (1,)
-    return [q_s, q_s, q_s], [q_s], [cache, cache, cur]
+    return [q_s, kv_s, kv_s] + ([(b,)] if fed else []), [q_s], \
+        [cache, cache, cur]
+
+
+def _attention_decode_inputs(attrs):
+    from .base import parse_bool
+    return ("q", "k", "v") + (
+        ("fed",) if parse_bool((attrs or {}).get("fed") or False) else ())
+
+
+def _attention_decode_aux(attrs):
+    """A sliding layer's rings are cells of another name, and so of
+    another family (``slot_state``), than a pool of a row per
+    position."""
+    if int((attrs or {}).get("ring") or 0):
+        return ("k_ring", "v_ring", "cache_pos")
+    return ("k_cache", "v_cache", "cache_pos")
 
 
 #: the S>1 window path (chunked prefill / speculative verify): q and
@@ -939,21 +1086,31 @@ def _register_attention_decode():
     from .analysis.kernelcheck import validate_kernel_spec
     validate_kernel_spec("attention_decode", "window",
                          _ATTENTION_DECODE_KSPEC)
-    _register_op("attention_decode", inputs=("q", "k", "v"),
-                 aux=("k_cache", "v_cache", "cache_pos"),
+    _register_op("attention_decode", inputs=_attention_decode_inputs,
+                 aux=_attention_decode_aux,
                  full=_attention_decode_fwd,
                  stateful_infer=True, donate_aux=True,
                  aux_dtypes={"cache_pos": "int32",
                              "k_cache": _cache_dtype_of,
-                             "v_cache": _cache_dtype_of},
+                             "v_cache": _cache_dtype_of,
+                             "k_ring": _cache_dtype_of,
+                             "v_ring": _cache_dtype_of},
                  infer_shape=_attention_decode_infer,
                  slot_state={"k_cache": "rows", "v_cache": "rows",
+                             "k_ring": "ring", "v_ring": "ring",
                              "cache_pos": "cursor"},
                  attr_spec={"capacity": (int, 256),
                             "rope": (None, False),
                             "rope_base": (float, 10000.0),
                             "per_slot": (None, False),
-                            "cache_dtype": (str, "")},
+                            "cache_dtype": (str, ""),
+                            # absent unless a graph asks: an
+                            # existing graph's attributes stay as
+                            # they are
+                            "kv_heads": (int, None),
+                            "window": (int, None),
+                            "ring": (int, None),
+                            "fed": (None, None)},
                  variants={"pallas": (_attention_decode_pallas_variant,
                                       _attention_decode_eligible,
                                       _ATTENTION_DECODE_PALLAS_KSPEC)})

@@ -444,6 +444,9 @@ class DecodeEngine:
                     "step_len -> per-slot decode symbol factory)")
             self._build_windows(symbol_gen, compute_dtype,
                                 logger or log)
+        for family, n in self.state_bytes.items():
+            _telemetry.gauge("serve.decode.state.bytes", model=self.name,
+                             family=family).set(n)
 
     def _build_windows(self, symbol_gen, compute_dtype, logger):
         from ..module import Module
@@ -512,6 +515,17 @@ class DecodeEngine:
         ``positional``)? Rewinding to an arbitrary position, prefix
         reuse by row copy and speculative rollback rest on it."""
         return self._drivers[self.ladder.max].positional
+
+    @property
+    def state_bytes(self):
+        """The per-slot state's bytes by family (``"cursor"``,
+        ``"rows"``, ``"ring"``, ...), every rung's pools together: gauge
+        ``serve.decode.state.bytes``."""
+        out = {}
+        for drv in self._drivers.values():
+            for family, n in drv.state_bytes.items():
+                out[family] = out.get(family, 0) + n
+        return out
 
     def driver(self, rung):
         """The rung's ``BatchedKVCacheDecoder``."""
@@ -676,10 +690,14 @@ _DSA_COUNTERS = ("dsa.layer_steps", "dsa.live_rows", "dsa.selected_rows",
 
 #: ``serve.decode.<name>`` counters of a decoder with ``attention_decode``
 #: layers, in the order of ``BatchedKVCacheDecoder._attention_reads``:
-#: the pool rows at or before each fed slot's last query and the rows the
-#: pools hold (per slot, layer and dispatch); from the host's cursors, no
-#: fetch. Their ratio is the share of the capacity the traffic keeps live
-_ATTN_COUNTERS = ("attn.live_rows", "attn.capacity_rows")
+#: the positions at or before each fed slot's last query, the rows the
+#: pools hold and the positions that query attends - all of them, or on
+#: a sliding layer at most its window - (per slot, layer and dispatch);
+#: from the host's cursors, no fetch. live / capacity is the share of
+#: the pools the traffic keeps live, attended / live what the windows
+#: leave of the keys
+_ATTN_COUNTERS = ("attn.live_rows", "attn.capacity_rows",
+                  "attn.attended_rows")
 
 
 class DecodeScheduler:
@@ -730,21 +748,25 @@ class DecodeScheduler:
                     f"target capacity {engine.capacity}: the draft "
                     "tracks the same stream")
         if not engine.positional:
-            # the state behind a closed window is summaries: no cursor
-            # move brings its rows back
+            # the state behind a closed window is summaries, the rows
+            # a ring has written over are gone: no cursor move brings
+            # them back
+            families = sorted(engine.state_bytes)
             if self.draft is not None:
                 raise MXNetError(
                     f"decode {engine.name!r}: speculative decoding "
                     "(draft_engine / spec_k) rolls the cursor back over "
-                    "rejected drafts, and this decoder's state cannot "
-                    "be rewound across a closed window")
+                    "rejected drafts, and this decoder's state "
+                    f"(families {families}) cannot be rewound across a "
+                    "closed window or behind what a ring holds")
             if prefix_store is not None:
                 raise MXNetError(
                     f"decode {engine.name!r}: a prefix_store reuses a "
                     "prompt's cache by copying a row per position, and "
-                    "this decoder's state is a window of exact rows "
-                    "beside summaries (reuse needs a snapshot of the "
-                    "state at a window boundary)")
+                    f"this decoder's state (families {families}) is a "
+                    "window of exact rows beside summaries, or rings "
+                    "(reuse needs a snapshot of the state at a window "
+                    "boundary)")
         chunk = int(prefill_chunk if prefill_chunk is not None
                     else default_prefill_chunk())
         chunk = min(chunk, engine.capacity)
@@ -828,7 +850,7 @@ class DecodeScheduler:
             if self.engine.driver(self._rung).routed:
                 handles.update({k: self._counter(k)
                                 for k in _MOE_COUNTERS})
-            if not self.engine.positional:
+            if self.engine.driver(self._rung).summarises:
                 handles.update({k: self._counter(k)
                                 for k in _EVA_COUNTERS})
             if self.engine.driver(self._rung).selects:
@@ -1332,12 +1354,11 @@ class DecodeScheduler:
                 # what the dispatches counted: a routed decoder's
                 # experts, a window-and-summaries state's reads, a
                 # learned selection's
-                moe, eva, dsa = (phases.get(k)
-                                 for k in ("moe", "eva", "dsa"))
+                moe, eva, dsa, attn = (phases.get(k) for k in
+                                       ("moe", "eva", "dsa", "attn"))
                 for names, counts in (
                         (_MOE_COUNTERS, moe), (_EVA_COUNTERS, eva),
-                        (_DSA_COUNTERS, dsa),
-                        (_ATTN_COUNTERS, phases.get("attn"))):
+                        (_DSA_COUNTERS, dsa), (_ATTN_COUNTERS, attn)):
                     for key, value in zip(names, () if counts is None
                                           else counts):
                         m[key].inc(int(value))
@@ -1372,7 +1393,10 @@ class DecodeScheduler:
                         "eva_summary": int(eva[2])}),
                     **({} if dsa is None else
                        {"dsa_selected": int(dsa[2]),
-                        "dsa_scored": int(dsa[3])}))
+                        "dsa_scored": int(dsa[3])}),
+                    **({} if attn is None else
+                       {"attn_live": int(attn[0]),
+                        "attn_attended": int(attn[2])}))
         return max(1, emitted)
 
     def _commit_window(self, meta, ids, picked, S, t0, end, shared_sid,
@@ -1630,6 +1654,7 @@ class DecodeScheduler:
             "exec_est_ms": dict(sorted(exec_est.items())),
             "capacity": self.engine.capacity,
             "params_bytes": dict(self.engine.params_bytes),
+            "state_bytes": self.engine.state_bytes,
             "params_narrowed": self.engine.params_narrowed,
             "compiles_since_warmup": self.engine.compiles_since_warmup(),
             "backend_compiles_since_warmup":

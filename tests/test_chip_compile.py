@@ -196,6 +196,115 @@ def test_attention_decode_compiles_for_v5e_and_writes_in_place(capacity, S,
         B * H * capacity * d * 2)
 
 
+#: sha256 of the lowered text, the kernels' bodies printed without their
+#: source locations, of two layers of ``attention_decode``'s Pallas
+#: lowering at the Cerebras and OLMoE serving shapes (capacity, S): the
+#: parent's (2800d77), computed there. A graph with as many K/V heads
+#: as query heads and no window lowers to the text it had; a Mosaic
+#: body as serialised also carries the file's path and line numbers,
+#: which move whenever a line is added above a kernel, so the bytes of
+#: the whole text are not what is compared.
+_PARENT_TEXT_SHA256 = {
+    (2048, 1): "b72cf2bf51649865",
+    (2048, 64): "0713e04f0f954bfb",
+    (4096, 1): "59376acb9cab38bf",
+    (4096, 64): "e4d1f6bf71e96f49",
+}
+
+
+def _text_without_locations(text):
+    import base64
+    import re
+    from jax._src.lib.mlir import ir
+    bodies = []
+    for body in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', text):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(body))
+            bodies.append(module.operation.get_asm(enable_debug_info=False))
+    assert len(bodies) == 2                 # cache_write, decode_attn
+    return "".join(bodies) + re.sub(
+        r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', "BODY", text)
+
+
+@pytest.mark.parametrize("capacity,S", sorted(_PARENT_TEXT_SHA256))
+def test_ungrouped_unwindowed_graphs_lower_to_the_parents_text(capacity, S,
+                                                               v5e):
+    import hashlib
+    opdef = get_op("attention_decode")
+    attrs = opdef.normalize_attrs({"capacity": capacity, "per_slot": True,
+                                   "rope": capacity == 4096})
+    assert not {"kv_heads", "window", "ring", "fed"} & set(attrs)
+    B, H, d = 8, 16, 128
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ins = [sds((B, H, S, d))] * 3
+    aux = [sds((B, H, capacity, d))] * 2 + [sds((B, 1), jnp.int32)]
+    fn = opdef.variant_fn("pallas")
+
+    def two_layers(r, a1, a2):
+        o1, n1 = fn(attrs, r, a1, False, None)
+        o2, n2 = fn(attrs, [o1[0], r[1], r[2]], a2, False, None)
+        return o2, n1, n2
+
+    text = jax.jit(two_layers, donate_argnums=(1, 2)).lower(
+        ins, aux, aux).as_text()
+    digest = hashlib.sha256(
+        _text_without_locations(text).encode()).hexdigest()
+    assert digest[:16] == _PARENT_TEXT_SHA256[(capacity, S)]
+
+
+@pytest.mark.parametrize("S", [1, 1024], ids=["decode", "window"])
+@pytest.mark.parametrize("kind", ["full", "sliding"])
+def test_grouped_window_attention_compiles_for_v5e_and_copies_no_pool(kind, S,
+                                                                      v5e):
+    """Trinity-Mini's ``attention_decode`` at the published sizes (8
+    slots, 32 query heads on 4 K/V heads of 128, bfloat16, a capacity
+    of 32,768): a full layer's pools of a row per position and a
+    sliding layer's rings of 2,048 + 1,024 rows, in the S=1 program and
+    the window program of 1,024. The write is ``cache_write``, the read
+    ``decode_attn`` (8 rows a K/V head) or ``window_attn`` (8,192), and
+    with the aux arrays donated every pool comes back in the buffer it
+    came in, neither copied nor re-laid."""
+    import re
+    from mxnet_tpu.models.transformer import ring_rows
+    B, H, Hkv, d, C = 8, 32, 4, 128, 32768
+    ring = ring_rows(2048, 1024) if kind == "sliding" else 0
+    assert ring in (0, 3072)
+    opdef = get_op("attention_decode")
+    attrs = opdef.normalize_attrs(dict(
+        capacity=C, per_slot=True, rope=kind == "sliding", kv_heads=Hkv,
+        fed=True, **({"window": 2048, "ring": ring} if ring else {})))
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ins = [sds((B, H, S, d)), sds((B, Hkv, S, d)), sds((B, Hkv, S, d)),
+           sds((B,), jnp.int32)]
+    rows = ring or C
+    aux = [sds((B, Hkv, rows, d))] * 2 + [sds((B, 1), jnp.int32)]
+    assert opdef.aux_names(attrs)[0] == ("k_ring" if ring else "k_cache")
+    assert opdef.variant_eligible("pallas", attrs,
+                                  [a.shape for a in ins + aux],
+                                  [str(a.dtype) for a in ins + aux])
+    fn = opdef.variant_fn("pallas")
+    lowered = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
+                      donate_argnums=(1,)).lower(ins, aux)
+    assert re.findall(r'kernel_name = "(\w+)"', lowered.as_text()) == [
+        "cache_write", "decode_attn" if S == 1 else "window_attn"]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    pool = rf"= bf16\[{B},{Hkv},{rows},{d}\]\S* "
+    assert not re.findall(pool + r"copy\(", text)
+    assert not re.findall(pool + r"fusion\(", text)
+    assert " scatter(" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        2 * B * Hkv * rows * d * 2
+
+
 @pytest.mark.parametrize("S", [1, 1024], ids=["decode", "window"])
 @pytest.mark.parametrize("op", ["dsa_index_select", "mla_attention_decode"])
 def test_latent_attention_compiles_for_v5e_and_copies_no_pool(op, S, v5e):

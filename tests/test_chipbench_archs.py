@@ -26,6 +26,14 @@ from chipbench.tests.test_archs import (  # noqa: E402,F401
     test_resnet_pool_is_seeded_and_shaped,
     test_the_moved_costs_are_the_yardsticks)
 
+# the quick cases of the benchmark's own tests of archs/afmoe.py run
+# here as they stand (its CPU rehearsals stay by hand)
+from chipbench.tests.test_afmoe import (  # noqa: E402,F401
+    test_costs_against_a_count_by_hand as
+    test_afmoe_costs_against_a_count_by_hand,
+    test_the_configuration_is_the_catalogs_but_for_what_reduced_lists,
+    test_the_three_controls_are_further_than_the_emulation)
+
 CELL = "olmoe-1b-7b-serve-chat-closed"
 
 

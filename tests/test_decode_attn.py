@@ -100,6 +100,131 @@ def test_decode_kernel_parity_rope():
     _assert_parity(_attrs(per_slot=True, rope=True), inputs, aux, 2e-4)
 
 
+# ------------------------------- grouped K/V heads, a window, rings
+#: (S, window, ring, context capacity, cursors, fed): 8 query heads on 2
+#: K/V heads of 8, rotary. S=1 and S=4 are ``decode_attn``'s (at most 64
+#: rows a K/V head), S=16 and 32 ``window_attn``'s; cursors before the
+#: window fills, past it and past several turns of the ring
+_GROUPED_CASES = {
+    "full-s1": (1, 0, 0, 64, [3, 40], None),
+    "window-rows-s1": (1, 16, 0, 64, [3, 40], None),
+    "ring-s1-unfilled": (1, 16, 32, 256, [3, 17], None),
+    "ring-s1-wrapped": (1, 16, 32, 256, [70, 200], None),
+    "ring-s4-ragged-fed": (4, 16, 32, 256, [70, 30], [2, 4]),
+    "ring-s16-window-attn": (16, 16, 32, 256, [70, 129], [16, 5]),
+    "ring-s16-from-zero": (16, 16, 32, 256, [0, 20], None),
+    "full-s16-window-attn": (16, 0, 0, 256, [0, 120], None),
+    "window-rows-s16": (16, 16, 0, 256, [0, 120], None),
+    "ring-s32-two-query-blocks": (32, 16, 48, 256, [90, 200], [32, 0]),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-4),
+                                       ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case", sorted(_GROUPED_CASES))
+def test_grouped_window_ring_parity(case, dtype, tol):
+    """``kv_heads``, ``window``, ``ring`` and ``fed``: the Pallas reads
+    (``decode_attn`` with a group's heads as rows and a first live
+    block, ``window_attn``) and the ring write against the composition:
+    outputs within the tier tolerance, pools bit-identical, the cursor
+    advanced by ``fed``. A window of pads alone (fed 0) comes out zero
+    from ``window_attn`` and is compared nowhere."""
+    S, window, ring, capacity, cursors, fed = _GROUPED_CASES[case]
+    heads, kv_heads = 8, 2
+    attrs = OP.normalize_attrs({
+        "capacity": capacity, "per_slot": True, "rope": True,
+        "kv_heads": kv_heads, "fed": True,
+        **({"window": window} if window else {}),
+        **({"ring": ring} if ring else {})})
+    rng = np.random.RandomState(1)
+    dt = np.dtype(dtype)
+    q = jnp.asarray(rng.randn(B, heads, S, DH), dt)
+    k, v = (jnp.asarray(rng.randn(B, kv_heads, S, DH), dt) for _ in "kv")
+    pools = [jnp.asarray(rng.randn(B, kv_heads, ring or capacity, DH), dt)
+             for _ in "kv"]
+    cur = jnp.asarray(np.reshape(cursors, (B, 1)), jnp.int32)
+    fed = [S] * B if fed is None else fed
+    inputs = [q, k, v, jnp.asarray(fed, jnp.int32)]
+    assert OP.aux_names(attrs)[0] == ("k_ring" if ring else "k_cache")
+    assert OP.variants["pallas"]["eligible"](
+        attrs, [x.shape for x in inputs + pools + [cur]],
+        [str(x.dtype) for x in inputs + pools + [cur]])
+    ref, ref_aux, pal, pal_aux = _both(attrs, inputs, pools + [cur],
+                                       jit=True)
+    assert ref.dtype == pal.dtype and ref.shape == (B, heads, S, DH)
+    for slot, n in enumerate(fed):
+        np.testing.assert_allclose(
+            np.asarray(ref[slot, :, :n], np.float32),
+            np.asarray(pal[slot, :, :n], np.float32), atol=tol, rtol=tol)
+    for r, p in zip(ref_aux[:2], pal_aux[:2]):
+        assert r.dtype == p.dtype and r.shape[2] == (ring or capacity)
+        assert np.array_equal(np.asarray(r, np.float32),
+                              np.asarray(p, np.float32))
+    assert np.asarray(pal_aux[2]).ravel().tolist() == \
+        np.asarray(ref_aux[2]).ravel().tolist() == \
+        [c + n for c, n in zip(cursors, fed)]
+
+
+def test_the_window_is_a_lower_bound_and_the_ring_forgets_nothing_in_it():
+    """Against a plain softmax over the keys a window keeps: a sliding
+    layer fed 70 positions one dispatch of 4 at a time through a ring
+    of 24 rows attends, at every position, exactly the 16 newest keys -
+    the ring has then turned almost three times."""
+    window, ring, S, heads, kv_heads = 16, 24, 4, 4, 2
+    attrs = OP.normalize_attrs({
+        "capacity": 128, "per_slot": True, "kv_heads": kv_heads,
+        "window": window, "ring": ring, "fed": True})
+    rng = np.random.RandomState(2)
+    T = 72
+    q = rng.randn(1, heads, T, DH).astype("f")
+    k, v = (rng.randn(1, kv_heads, T, DH).astype("f") for _ in "kv")
+    aux = [jnp.zeros((1, kv_heads, ring, DH), jnp.float32)] * 2 \
+        + [jnp.zeros((1, 1), jnp.int32)]
+    step = jax.jit(lambda i, a: OP.variants["pallas"]["fn"](
+        attrs, i, a, False, None))
+    outs = []
+    for t in range(0, T, S):
+        out, aux = step([jnp.asarray(x[:, :, t:t + S]) for x in (q, k, v)]
+                        + [jnp.asarray([S], jnp.int32)], aux)
+        outs.append(np.asarray(out[0]))
+    got = np.concatenate(outs, axis=2)[0]                 # (heads, T, DH)
+    for h in range(heads):
+        kh, vh = k[0, h // 2], v[0, h // 2]
+        for t in range(T):
+            lo = max(0, t - window + 1)
+            s = q[0, h, t] @ kh[lo:t + 1].T / np.sqrt(DH)
+            p = np.exp(s - s.max())
+            want = (p / p.sum()) @ vh[lo:t + 1]
+            np.testing.assert_allclose(got[h, t], want, atol=2e-4, rtol=2e-4)
+    assert int(np.asarray(aux[2])[0, 0]) == T
+
+
+def test_grouped_heads_and_rings_are_the_slot_pools_and_check_their_sizes():
+    qs, kvs = (B, 8, 1, DH), (B, 2, 1, DH)
+    with pytest.raises(MXNetError, match="per_slot"):
+        OP.forward(OP.normalize_attrs({"capacity": C, "kv_heads": 2}),
+                   [jnp.zeros(qs), jnp.zeros(kvs), jnp.zeros(kvs)],
+                   [jnp.zeros((B, 2, C, DH))] * 2
+                   + [jnp.zeros((1,), jnp.int32)], False, None)
+    with pytest.raises(MXNetError, match="ring holds"):
+        OP.forward(OP.normalize_attrs({"capacity": C, "kv_heads": 2,
+                                       "per_slot": True, "window": 16,
+                                       "ring": 16}),
+                   [jnp.zeros(qs), jnp.zeros(kvs), jnp.zeros(kvs)],
+                   [jnp.zeros((B, 2, 16, DH))] * 2
+                   + [jnp.zeros((B, 1), jnp.int32)], False, None)
+    # the op's shapes: k, v and the pools at kv_heads, a ring's rows
+    attrs = OP.normalize_attrs({"capacity": 4 * C, "kv_heads": 2,
+                                "per_slot": True, "window": 16, "ring": 24,
+                                "fed": True})
+    ins, outs, aux = OP.infer_shape(attrs, [qs, None, None, None])
+    assert ins == [qs, kvs, kvs, (B,)] and outs == [qs]
+    assert aux == [(B, 2, 24, DH), (B, 2, 24, DH), (B, 1)]
+    assert OP.input_names(attrs) == ["q", "k", "v", "fed"]
+    assert [OP.slot_state[n] for n in OP.aux_names(attrs)] == [
+        "ring", "ring", "cursor"]
+
+
 def test_decode_kernel_parity_staggered_and_edge_cursors():
     """Slots at position 0, mid-stream, and at the last legal window
     start — the cursor-bounded HBM read must still cover exactly the
@@ -354,10 +479,12 @@ def test_decode_driver_kernel_vs_xla_logits(monkeypatch):
 
 # ------------------------------------ what a dispatch reads of the pools
 def test_driver_counts_live_rows_from_its_cursors(monkeypatch):
-    """``serve.decode.attn.live_rows`` / ``.capacity_rows``: per
-    dispatch, over the fed slots and the ``attention_decode`` layers,
-    the rows at or before each slot's last query and the rows the pools
-    hold - from the cursors the host mirrors, whichever tier reads."""
+    """``serve.decode.attn.live_rows`` / ``.capacity_rows`` /
+    ``.attended_rows``: per dispatch, over the fed slots and the
+    ``attention_decode`` layers, the rows at or before each slot's last
+    query, the rows the pools hold and the rows that query attends (all
+    of the live ones: no layer here has a window) - from the cursors
+    the host mirrors, whichever tier reads."""
     from mxnet_tpu.models import transformer as tfm
     args = _decoder_args()
     dsym = tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=L,
@@ -374,10 +501,11 @@ def test_driver_counts_live_rows_from_its_cursors(monkeypatch):
     drv.rewind(2, 9)
     drv.step(np.zeros((3, 1), np.int32))
     # slot 0 reads row 0, slot 2 rows 0-9; slot 1 is nobody's
-    assert drv.last_attention.tolist() == [L * (1 + 10), L * 3 * CAP]
+    assert drv.last_attention.tolist() == [L * (1 + 10), L * 3 * CAP,
+                                           L * (1 + 10)]
     drv.leave(0)
     drv.step(np.zeros((3, 1), np.int32))
-    assert drv.last_attention.tolist() == [L * 11, L * 3 * CAP]
+    assert drv.last_attention.tolist() == [L * 11, L * 3 * CAP, L * 11]
 
 
 def test_scheduler_registers_the_attention_counters(monkeypatch):
@@ -401,5 +529,6 @@ def test_scheduler_registers_the_attention_counters(monkeypatch):
     live = counters["serve.decode.attn.live_rows"]
     held = counters["serve.decode.attn.capacity_rows"]
     assert held % (L * 2 * CAP) == 0 and 0 < live < held
+    assert counters["serve.decode.attn.attended_rows"] == live
     # two requests of 3 + 4 tokens: no slot ever reads past row 6
     assert live <= held // CAP * 7
