@@ -8,7 +8,8 @@ it survives formatting:
 
 * **code scan** — every ``*.py`` under ``mxnet_tpu/`` is parsed and
   every ``counter(...)``/``gauge(...)``/``histogram(...)`` call site
-  contributes its metric name. Names are resolved best-effort within
+  contributes its metric name (``held_counters(...)`` every name it
+  lists). Names are resolved best-effort within
   the enclosing function scope: plain literals, ``name + ".seconds"``
   concatenations, and ``a if cond else b`` literal ternaries all
   resolve to exact names; f-string names (``f"serve.decode.{key}"``)
@@ -42,6 +43,7 @@ __all__ = ["scan_code", "scan_docs", "audit", "CATALOG_HEADING"]
 CATALOG_HEADING = "## Metric catalog"
 
 _METRIC_FNS = {"counter", "gauge", "histogram"}
+_HELD_FNS = {"held_counters"}      # every positional argument is a name
 _HIST_KWARGS = {"hist", "_hist"}
 
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)+$")
@@ -135,18 +137,17 @@ def _scan_scope(scope, exact, prefixes):
             elif kw.arg == "metric_prefix":
                 for v in _resolve(kw.value, env) or ():
                     prefixes.add(v + ".")
-        if _call_fn_name(node) not in _METRIC_FNS or not node.args:
+        fn = _call_fn_name(node)
+        if fn not in _METRIC_FNS | _HELD_FNS:
             continue
-        arg0 = node.args[0]
-        resolved = _resolve(arg0, env)
-        if resolved:
-            for v in resolved:
-                if _NAME_RE.match(v):
-                    exact.add(v)
-            continue
-        prefix = _joined_prefix(arg0)
-        if prefix is not None and _PREFIX_RE.match(prefix):
-            prefixes.add(prefix)
+        for arg in node.args if fn in _HELD_FNS else node.args[:1]:
+            resolved = _resolve(arg, env)
+            if resolved:
+                exact.update(v for v in resolved if _NAME_RE.match(v))
+                continue
+            prefix = _joined_prefix(arg)
+            if prefix is not None and _PREFIX_RE.match(prefix):
+                prefixes.add(prefix)
 
 
 def scan_code(root):

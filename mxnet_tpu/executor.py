@@ -30,6 +30,8 @@ coherent because NDArray is a mutable cell (see ndarray.py).
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -367,6 +369,26 @@ def _segmented_runner(nodes, node_index, compute_idx, out_entries,
     return run
 
 
+def reads_rng(symbol):
+    """Does any op of the graph read the random key? ``_exec_node``
+    folds the key in for an op that declares ``need_rng`` and hands
+    every other op None, so the declaration is the whole answer; a node
+    whose op cannot be looked up counts as reading it."""
+    try:
+        return any(not n.is_variable and n.opdef().need_rng
+                   for n in symbol._topo_nodes())
+    except MXNetError:
+        return True
+
+
+@functools.lru_cache(maxsize=None)
+def _unread_key():
+    """The key argument of a program none of whose ops reads one: made
+    once, of a fresh key's shape, dtype and (un)placement, so that the
+    program's signature is what it is under a drawn key."""
+    return jax.random.PRNGKey(0)
+
+
 class Executor:
     """reference: include/mxnet/executor.h + python/mxnet/executor.py."""
 
@@ -456,6 +478,10 @@ class Executor:
                                     n_devices=n_devices
                                     if self._mp_plan is None
                                     else self._mp_plan.mesh.size)
+        # learned once: a graph without an op that reads the key draws
+        # none (``forward``), as the imperative path does for such an op
+        self._reads_rng = reads_rng(symbol)
+        self._draws = _telemetry.metrics.held_counters("executor.rng.draws")
         self.aux_arrays = self._normalize_args(aux_states, self.aux_names,
                                                "aux_states", allow_none=True)
         self.grad_req = self._normalize_req(grad_req)
@@ -741,7 +767,7 @@ class Executor:
                     ad[nm]._set(val.asjax().astype(ad[nm].dtype))
                 else:
                     ad[nm]._set(jnp.asarray(val, dtype=ad[nm].dtype))
-        rng = _random.next_key()
+        rng = self._next_key()
         self._pending = ("fwd_train" if is_train else "fwd_infer", rng)
         self._outputs = None
         if not is_train:
@@ -750,6 +776,18 @@ class Executor:
         # training: stay lazy so backward() costs exactly one fused
         # fwd+bwd execution; the returned view materializes on access
         return _LazyOutputs(self)
+
+    def _next_key(self):
+        """The key of one forward: a fresh one off ``mx.random``'s host
+        chain (``executor.rng.draws``) where an op of the graph reads
+        it, training or not; else one constant, and the chain stays
+        where it was - a split is two one-operation programs on the
+        device."""
+        if not self._reads_rng:
+            return _unread_key()
+        if _telemetry.enabled():
+            self._draws()[0].inc()
+        return _random.next_key()
 
     def _arg_vals(self):
         return {nm: a.asjax() for nm, a in zip(self.arg_names,

@@ -1075,6 +1075,8 @@ class BatchedKVCacheDecoder:
         self.pos = np.zeros(self.slots, np.int64)    # device-cursor mirror
         self.active = np.zeros(self.slots, bool)
         self._windows = {}                           # step_len -> module
+        # step_len -> how a step's host arrays reach that module's cells
+        self._stagers = {1: module._exec_group.input_stager()}
         self._cursor_program = None                  # built at first use
         self._select_programs = {}                   # step_len -> program
         # the routed feed-forwards' per-layer counts of the latest
@@ -1126,7 +1128,8 @@ class BatchedKVCacheDecoder:
         # the latest ``select_rows`` took, on the clock its caller
         # handed it (``now=``); None where the caller handed none
         self.last_stage = self.last_launch = self.last_select = None
-        self._donated_handle = None      # (registry generation, counter)
+        self._donated = _telemetry.metrics.held_counters(
+            "serve.decode.state.donated_bytes", model=name)
         if self.summarises:
             ring = exe.aux_dict[self._state["window"][0]]
             pool = exe.aux_dict[self._state["summary"][0]]
@@ -1141,6 +1144,7 @@ class BatchedKVCacheDecoder:
         cache/cursor cells — the executor-group aux-sharing rule makes
         that automatic when slot count and capacity agree."""
         self._windows[int(step_len)] = module
+        self._stagers[int(step_len)] = module._exec_group.input_stager()
 
     @property
     def window_lens(self):
@@ -1389,17 +1393,6 @@ class BatchedKVCacheDecoder:
         return [i for i in range(self.slots)
                 if self.active[i] and self.pos[i] + window > self.capacity]
 
-    def _count_donated(self):
-        """Add this step's donated bytes to
-        ``serve.decode.state.donated_bytes``; the handle is looked up
-        once (a lookup is a lock and a key tuple) and again after the
-        registry resets."""
-        gen = _telemetry.metrics.generation()
-        if self._donated_handle is None or self._donated_handle[0] != gen:
-            self._donated_handle = (gen, _telemetry.counter(
-                "serve.decode.state.donated_bytes", model=self.name))
-        self._donated_handle[1].inc(self.donated_bytes)
-
     def step(self, tokens, fed=None, now=None):
         """Advance every slot by one S-token window: ``tokens``
         (slots,) or (slots, S) int ids (retired slots ride any valid
@@ -1421,7 +1414,6 @@ class BatchedKVCacheDecoder:
         jitted call). ``now`` (a clock's read, the scheduler's) makes
         ``last_stage`` and ``last_launch`` their seconds; without it no
         clock is read."""
-        from .. import ndarray as nd
         from ..io import DataBatch
         t0 = None if now is None else now()
         with _telemetry.span("decode.step.stage"):
@@ -1470,15 +1462,15 @@ class BatchedKVCacheDecoder:
                 self.last_reads = self._state_reads(fed)
                 self.last_selection = self._selection_reads(fed)
                 self.last_attention = self._attention_reads(fed)
-            if self.name is not None:
-                self._count_donated()
-            data = [nd.array(tokens.astype(np.int32))]
+            if self.name is not None:    # the pools' bytes a dispatch
+                self._donated()[0].inc(self.donated_bytes)
+            hosts = [tokens]
             if self.pos_embed == "learned":
                 pos = self.pos[:, None] + np.arange(S)[None, :]
-                data.append(nd.array(
-                    np.minimum(pos, self.capacity - 1).astype(np.float32)))
+                hosts.append(np.minimum(pos, self.capacity - 1))
             if self.feeds:
-                data.append(nd.array(fed.astype(np.int32)))
+                hosts.append(fed)
+            data = self._stagers[S](hosts)
         t1 = None if now is None else now()
         with _telemetry.span("decode.step.launch"):
             mod.forward(DataBatch(data=data, label=[]), is_train=False)

@@ -37,7 +37,8 @@ import os
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from ..base import MXNetError
 from ..ndarray import NDArray, zeros as nd_zeros
@@ -156,6 +157,8 @@ class DataParallelExecutorGroup:
         self.state_names = state_names or []
         self.param_names = param_names
         self._zero_plan = None          # set by setup_fused_step
+        self._load_counters = _telemetry.metrics.held_counters(
+            "io.load_batch.aliased", "io.load_batch.puts")
         self._state_layout = None       # flat-shard state transport
 
         self.arg_names = symbol.list_arguments()
@@ -225,7 +228,9 @@ class DataParallelExecutorGroup:
             self._n_data = self._n_dev
         else:
             self._mesh = None
-            self._data_sharding = None
+            # what `_place` on the one device gives; read by
+            # `_lies_as_data` alone, the puts name the device
+            self._data_sharding = SingleDeviceSharding(devices[0])
             self._repl_sharding = None
             self._stacked_sharding = None
             self._n_data = 1
@@ -1397,24 +1402,70 @@ class DataParallelExecutorGroup:
         self._load_batch(data_batch)
         self.executor.forward(is_train=is_train)
 
+    def _lies_as_data(self, val):
+        """Does the array lie where ``_place(val, "data")`` would put
+        it: committed to the one device, or under a sharding over the
+        mesh that lays the same rows on the same chips?"""
+        if not val.committed:
+            return False
+        want = self._data_sharding
+        if self._spmd_plan is not None:
+            want = self._spmd_plan.data_sharding_for(val.shape)
+        have = val.sharding
+        return have == want or have.is_equivalent_to(want, val.ndim)
+
+    def input_stager(self):
+        """``stage(hosts) -> [NDArray]`` for a caller that makes its
+        batch on the host every step (the decode drivers): each host
+        array, in ``data_names``' order, put once - at its input cell's
+        width (read here, once) and where ``forward`` places its batch
+        - so that ``_load_batch`` takes it as it is and the step's
+        launch is the jitted call."""
+        cells = self.executor.arg_dict
+        dtypes = [cells[nm].dtype for nm in self.data_names]
+        place, ctx = self._place, self.contexts[0]
+
+        def stage(hosts):
+            return [NDArray(place(h.astype(dt), "data"), ctx=ctx)
+                    for h, dt in zip(hosts, dtypes)]
+        return stage
+
     def _load_batch(self, data_batch):
         """Shard the batch's data (and labels, which eval graphs read)
-        into the bound input arrays."""
+        into the bound input arrays. An input that is already a device
+        array of its cell's dtype under the cell's placement is taken as
+        it is (the cell aliases the caller's buffer, which no program
+        donates: see ``setup_fused_step``); anything else is converted
+        and put. ``io.load_batch.aliased`` / ``.puts`` count the two."""
         load_span = _telemetry.span("io.load_batch")
+        cells = self.executor.arg_dict
+        aliased = puts = 0
 
         def load(names, arrays):
+            nonlocal aliased, puts
             for name, arr in zip(names, arrays):
-                dst = self.executor.arg_dict.get(name)
+                dst = cells.get(name)
                 if dst is None:
                     continue
-                val = arr.asjax() if isinstance(arr, NDArray) else \
-                    jnp.asarray(np.asarray(arr))
+                val = arr.asjax() if isinstance(arr, NDArray) else arr
+                if isinstance(val, jax.Array) and val.dtype == dst.dtype \
+                        and not val.weak_type and self._lies_as_data(val):
+                    dst._set(val)
+                    aliased += 1
+                    continue
+                if not isinstance(val, jax.Array):
+                    val = jnp.asarray(np.asarray(val))
                 dst._set(self._place(val.astype(dst.dtype), "data"))
+                puts += 1
 
         with load_span:
             load(self.data_names, data_batch.data)
             if self.label_names and data_batch.label:
                 load(self.label_names, data_batch.label)
+        if _telemetry.enabled():
+            n_aliased, n_puts = self._load_counters()
+            n_aliased.inc(aliased)
+            n_puts.inc(puts)
 
     def backward(self, out_grads=None):
         assert self.for_training, "re-bind with for_training=True"

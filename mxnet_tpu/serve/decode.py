@@ -100,6 +100,11 @@ _seq_ids = itertools.count()
 
 _GREEDY = SamplingParams()
 
+# what a forward does besides its program (``Module.forward``'s own
+# counters, process-wide, counted while ``telemetry.enabled()``)
+_LAUNCH_COUNTERS = ("io.load_batch.puts", "io.load_batch.aliased",
+                    "executor.rng.draws")
+
 
 def _env_int(name, default):
     try:
@@ -368,6 +373,7 @@ class DecodeEngine:
         self.exec_est = {}              # rung -> EMA'd step seconds
         self._warm_mark = None
         self._warm_backend_mark = None
+        self._warm_launch_mark = None
         self.warmup_compiles = None
         self._symbol = symbol
         self._context = context if context is not None \
@@ -573,6 +579,7 @@ class DecodeEngine:
         self._pin_programs()
         self._warm_mark = _progcache.compile_count()
         self._warm_backend_mark = _telemetry.core.backend_compiles()
+        self._warm_launch_mark = self._launch_counts()
         self.warmup_compiles = self._warm_mark - mark
         return dict(self.exec_est)
 
@@ -606,6 +613,27 @@ class DecodeEngine:
         if self._warm_backend_mark is None:
             return None
         return _telemetry.core.backend_compiles() - self._warm_backend_mark
+
+    @staticmethod
+    def _launch_counts():
+        return (_telemetry.metrics.generation(),
+                {nm: getattr(_telemetry.get_metric(nm), "value", 0)
+                 for nm in _LAUNCH_COUNTERS})
+
+    def launch_work_since_warmup(self):
+        """What the forwards of this PROCESS did on the way to their
+        programs since warm-up (None before it): inputs ``_load_batch``
+        converted or placed (``io.load_batch.puts``) and took as they
+        were (``.aliased``), keys ``Executor.forward`` drew
+        (``executor.rng.draws``). A serving step puts nothing and draws
+        nothing. Counted only while ``telemetry.enabled()``: with it
+        off all three stand still."""
+        if self._warm_launch_mark is None:
+            return None
+        gen, marks = self._warm_launch_mark
+        now_gen, now = self._launch_counts()
+        return {nm: n - (marks[nm] if gen == now_gen else 0)
+                for nm, n in now.items()}
 
     def program_keys(self):
         keys = []
@@ -1659,6 +1687,8 @@ class DecodeScheduler:
             "compiles_since_warmup": self.engine.compiles_since_warmup(),
             "backend_compiles_since_warmup":
             self.engine.backend_compiles_since_warmup(),
+            "launch_work_since_warmup":
+            self.engine.launch_work_since_warmup(),
             "programs_resident": self.engine.programs_resident(),
         }
         if self.spec_k:

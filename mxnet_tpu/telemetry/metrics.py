@@ -244,6 +244,25 @@ def generation():
     return _gen
 
 
+class held_counters:
+    """Counters of fixed names and labels for a hot path: calling it
+    gives them as a tuple, looked up once (a lookup is a lock and a key
+    tuple) and again after the registry resets."""
+
+    __slots__ = ("_names", "_labels", "_gen", "_held")
+
+    def __init__(self, *names, **labels):
+        self._names, self._labels = names, labels
+        self._gen, self._held = None, ()
+
+    def __call__(self):
+        if self._gen != _gen:
+            self._held = tuple(counter(nm, **self._labels)
+                               for nm in self._names)
+            self._gen = _gen
+        return self._held
+
+
 def all_metrics():
     with _lock:
         return list(_registry.values())

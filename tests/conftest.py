@@ -62,3 +62,14 @@ def _seed():
     # changes whenever a test file is added)
     from mxnet_tpu.telemetry import health
     health.configure(armed=None)
+
+
+@pytest.fixture
+def counting():
+    """Telemetry on for one test: the counters that count only while
+    ``telemetry.enabled()`` (``io.load_batch.*``, ``executor.rng.draws``,
+    ``executor.jit_cache.*``) move."""
+    from mxnet_tpu import telemetry as tm
+    tm.enable()
+    yield
+    tm.disable()

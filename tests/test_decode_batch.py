@@ -1086,3 +1086,138 @@ def test_scalar_decode_unchanged(trained):
     d = _scalar_decoder(trained)
     out = d.step(np.asarray([[1]], np.int32))
     assert out.shape == (1, 1, V)
+
+
+# ------------------------------------------------------------------------
+# a step's launch is one jitted call (ISSUE 39): the driver puts a step's
+# inputs once, where and as wide as its module's cells want them, and a
+# decode graph draws no key
+# ------------------------------------------------------------------------
+_LAUNCH_S = 4                                    # the window program
+
+
+def _launch_symbol(kind, step_len):
+    if kind == "learned":
+        return tfm.get_decode_symbol(
+            vocab_size=V, d_model=D, n_layer=L, n_head=H, capacity=T,
+            per_slot=True, pos_embed="learned", step_len=step_len,
+            max_seq_len=T)
+    return tfm.get_decode_symbol(           # rotary, with a ``fed`` input
+        vocab_size=40, d_model=32, n_layer=1, n_head=2,
+        pos_embed="rotary", rope_base=1e5, capacity=64,
+        step_len=step_len, per_slot=True, block="evabyte", window=32,
+        chunk=4, n_pred_heads=1, ffn_width=48, tie_head=False,
+        embed_scale=False)
+
+
+def _launch_driver(kind, slots=2):
+    """A two-slot pool with its S=4 window program, bound as
+    ``DecodeEngine`` binds (tokens and ``fed`` int32, learned positions
+    float32), every parameter drawn from one seed."""
+    inputs = {"learned": ("data", "pos_ids"), "fed": ("data", "fed")}[kind]
+
+    def bound(step_len, shared=None):
+        descs = [mx.io.DataDesc("data", (slots, step_len), np.int32)]
+        if kind == "learned":
+            descs.append(mx.io.DataDesc("pos_ids", (slots, step_len),
+                                        np.float32))
+        else:
+            descs.append(mx.io.DataDesc("fed", (slots,), np.int32))
+        symbol = _launch_symbol(kind, step_len)
+        mod = mx.mod.Module(symbol, data_names=inputs, label_names=[])
+        mod.bind(descs, None, for_training=False, shared_module=shared)
+        if shared is None:
+            shapes, _, _ = symbol.infer_shape(
+                **{d.name: d.shape for d in descs})
+            rng = np.random.default_rng(3)
+            mod.init_params(initializer=None, aux_params={}, arg_params={
+                nm: mx.nd.array(
+                    (0.25 * rng.standard_normal(shp)).astype(np.float32))
+                for nm, shp in zip(symbol.list_arguments(), shapes)
+                if nm not in inputs}, allow_missing=True)
+        return mod
+
+    base = bound(1)
+    capacity = T if kind == "learned" else 64
+    drv = tfm.BatchedKVCacheDecoder(base, capacity, slots=slots,
+                                    pos_embed="learned"
+                                    if kind == "learned" else "rotary")
+    drv.add_window(_LAUNCH_S, bound(_LAUNCH_S, shared=base))
+    return drv
+
+
+def _launch_schedule(drv):
+    """Two joins, S=1 steps, a window (padded: one slot feeds 2 of its
+    4), a rewind, S=1 steps again: every step's logits."""
+    for slot in range(drv.slots):
+        if drv.active[slot]:
+            drv.leave(slot)
+        drv.join(slot)
+    rs = np.random.RandomState(8)
+    outs = []
+
+    def step(S, fed=None):
+        tokens = rs.randint(1, 40, (drv.slots, S))
+        outs.append(drv.step(tokens, fed=fed if drv.feeds else None)
+                    .asnumpy())
+
+    for _ in range(3):
+        step(1)
+    step(_LAUNCH_S, fed=[_LAUNCH_S, 2])
+    if not drv.feeds:
+        drv.rewind(1, 5)                 # the window's two pads
+    drv.rewind(0, 6)                     # and one real token taken back
+    for _ in range(2):
+        step(1)
+    return outs
+
+
+def _launch_counts():
+    from mxnet_tpu import telemetry as tm
+    out = []
+    for nm in ("io.load_batch.aliased", "io.load_batch.puts",
+               "executor.rng.draws"):
+        m = tm.get_metric(nm)
+        out.append(0 if m is None else m.value)
+    return out
+
+
+@pytest.mark.parametrize("kind", ["learned", "fed"])
+def test_a_decode_steps_launch_puts_nothing_and_draws_no_key(kind):
+    """After warm-up, over a join, S=1 steps, a window and a rewind:
+    ``io.load_batch.puts`` and ``executor.rng.draws`` stay 0,
+    ``io.load_batch.aliased`` counts inputs x steps, ``mx.random``'s
+    chain stays where it was, and the logits are bit for bit those of a
+    driver whose inputs arrive as numpy through ``_load_batch``'s
+    convert-and-put branch."""
+    from mxnet_tpu import telemetry as tm
+    drv = _launch_driver(kind)
+    _launch_schedule(drv)                        # warm-up
+    chain = mx.random.get_state()["key"]
+    tm.enable()
+    try:
+        before = _launch_counts()
+        got = _launch_schedule(drv)
+        aliased, puts, draws = (
+            a - b for a, b in zip(_launch_counts(), before))
+    finally:
+        tm.disable()
+    assert (puts, draws) == (0, 0)
+    assert aliased == 2 * len(got)               # two inputs, six steps
+    after = mx.random.get_state()["key"]
+    assert chain is after or np.array_equal(chain, after)
+
+    old = _launch_driver(kind)
+    old._stagers = {S: list for S in old._stagers}    # numpy, as handed
+    tm.enable()
+    try:
+        before = _launch_counts()
+        want = _launch_schedule(old)
+        aliased, puts, draws = (
+            a - b for a, b in zip(_launch_counts(), before))
+    finally:
+        tm.disable()
+    assert (aliased, puts, draws) == (0, 2 * len(want), 0)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert np.abs(got[-1]).max() > 0

@@ -494,6 +494,37 @@ def test_zero_compiles_all_fastpaths_across_every_rung(target_params,
     assert len(sched.engine.program_keys()) == 9
 
 
+def test_stats_show_a_serving_window_puts_nothing_and_draws_no_key(
+        target_params):
+    """ISSUE 39: with telemetry on, ``stats()`` shows what the
+    forwards did besides their programs since warm-up - windows, S=1
+    steps, joins and a rung migration put no input a second time and
+    draw no key; every input of every dispatch is taken as it is."""
+    from mxnet_tpu import telemetry as tm
+    tm.enable()
+    try:
+        sched = _sched(target_params, ladder=(1, 2), chunk=4)
+        assert sched.stats()["launch_work_since_warmup"] == {
+            "io.load_batch.puts": 0, "io.load_batch.aliased": 0,
+            "executor.rng.draws": 0}
+        chain = mx.random.get_state()["key"]
+        for h in [sched.submit(p, max_new_tokens=4)
+                  for p in _prompts(3, 3, lo=5, hi=9)]:
+            sched.pump()
+            h.result(timeout=5)
+        st = sched.stats()
+    finally:
+        tm.disable()
+    work = st["launch_work_since_warmup"]
+    assert work["io.load_batch.puts"] == 0
+    assert work["executor.rng.draws"] == 0
+    # one input (the tokens: rotary positions) a dispatch
+    assert work["io.load_batch.aliased"] == st["iterations"] > 0
+    after = mx.random.get_state()["key"]
+    assert chain is after or np.array_equal(chain, after)
+    assert st["compiles_since_warmup"] == 0
+
+
 def test_window_aux_cells_are_shared(target_params):
     """The S>1 window module advances the SAME device cache/cursor
     cells as the rung's S=1 module — the seam everything above rides."""

@@ -1,5 +1,6 @@
 """Executor tests (mirrors reference tests/python/unittest/test_executor.py)."""
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu.test_utils import assert_almost_equal
@@ -194,3 +195,155 @@ def test_naive_engine_disables_fused_fit(monkeypatch):
     mod = mx.mod.Module(net, label_names=("sm_label",))
     mod.fit(it, num_epoch=1, optimizer_params={"learning_rate": 0.1})
     assert not mod._fused_armed
+
+
+# ------------------------------------------------------------------------
+# the key of a forward (ISSUE 39): a graph without an op that reads the
+# key draws none; a graph with one draws a fresh key a call, as before
+# ------------------------------------------------------------------------
+def _keyless_exec():
+    x = mx.sym.var("x")
+    net = mx.sym.Activation(mx.sym.FullyConnected(x, num_hidden=4,
+                                                  name="fc"),
+                            act_type="tanh")
+    return net.simple_bind(mx.cpu(), x=(3, 5))
+
+
+def _dropout_exec():
+    net = mx.sym.Dropout(mx.sym.var("x") * 2.0, p=0.5)
+    ex = net.simple_bind(mx.cpu(), x=(16, 16))
+    ex.arg_dict["x"][:] = 1.0
+    return ex
+
+
+def _sampler_exec():
+    # one sampler among ops that read no key
+    net = mx.sym.var("x") * 2.0 + mx.sym.random_uniform(shape=(4, 4))
+    ex = net.simple_bind(mx.cpu(), x=(4, 4))
+    ex.arg_dict["x"][:] = 0.0
+    return ex
+
+
+def _chain():
+    st = mx.random.get_state()
+    return None if st["key"] is None else st["key"].tolist()
+
+
+def _draws():
+    from mxnet_tpu import telemetry as tm
+    m = tm.get_metric("executor.rng.draws")
+    return 0 if m is None else m.value
+
+
+def _programs_launched(tmp, body):
+    """Names of the jitted calls (``PjitFunction(<fn>)`` host events of
+    the JAX profiler) made while ``body()`` runs."""
+    import glob
+    import os
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(
+        str(tmp), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    names = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            end = -1
+            for ev in sorted(line.events, key=lambda e: e.start_ns):
+                # the call's own event nests a second of its name
+                if ev.name.startswith("PjitFunction(") \
+                        and ev.start_ns >= end:
+                    names.append(ev.name)
+                    end = ev.start_ns + ev.duration_ns
+    return names
+
+
+@pytest.mark.parametrize("is_train", [False, True],
+                         ids=["infer", "train"])
+def test_keyless_graph_draws_no_key(is_train, counting, tmp_path):
+    """No op of the graph reads the key: N forwards of either mode
+    leave ``mx.random``'s host chain where it was, count no draw, and a
+    forward launches one program - its own (a drawn key is two more:
+    ``_threefry_split`` and ``_unstack``)."""
+    ex = _keyless_exec()
+    assert not ex._reads_rng
+    mx.random.seed(11)
+    before, draws = _chain(), _draws()
+
+    def forward():
+        ex.forward(is_train=is_train)
+        return ex.outputs[0].asnumpy()
+
+    outs = [forward() for _ in range(4)]
+    assert _chain() == before
+    assert _draws() == draws
+    for o in outs[1:]:
+        np.testing.assert_array_equal(o, outs[0])
+    launched = _programs_launched(tmp_path, forward)
+    assert len(launched) == 1 and "fwd_" in launched[0], launched
+    if is_train:                 # the gradient program takes that key too
+        ex.backward([mx.nd.ones((3, 4))])
+        assert _chain() == before
+
+
+def test_keyless_graph_takes_the_program_a_drawn_key_compiled():
+    """The constant key has a drawn key's shape, dtype and placement:
+    the program compiled under one is the program called under the
+    other (no second trace)."""
+    import jax
+    from mxnet_tpu import executor as _executor
+    drawn, const = mx.random.next_key(), _executor._unread_key()
+    assert (drawn.shape, drawn.dtype, drawn.committed) == \
+        (const.shape, const.dtype, const.committed)
+    ex = _keyless_exec()
+    ex.forward()
+    prog = ex._get_program("fwd_infer").__wrapped__
+    misses = prog._cache_size()
+    prog(ex._arg_vals(), ex._aux_vals(), drawn)
+    assert prog._cache_size() == misses == 1
+
+
+@pytest.mark.parametrize("make,is_train", [
+    (_dropout_exec, True), (_dropout_exec, False), (_sampler_exec, False),
+    (_sampler_exec, True)],
+    ids=["dropout-train", "dropout-infer", "sampler-infer", "sampler-train"])
+def test_graph_with_a_key_reader_draws_a_key_a_call(make, is_train,
+                                                    counting, tmp_path):
+    """``Dropout``, or one sampler among key-less ops: a fresh key
+    every forward, training or not - two calls under one seed differ
+    (where the mode uses the key) and the pair repeats under
+    ``mx.random.seed``; the host chain advances by one split a call."""
+    import jax
+    ex = make()
+    assert ex._reads_rng
+    uses_key = is_train or make is _sampler_exec
+
+    def forward():
+        ex.forward(is_train=is_train)
+        return ex.outputs[0].asnumpy()
+
+    forward()                                   # compile
+    runs = []
+    for _ in range(2):
+        mx.random.seed(5)
+        start, draws = _chain(), _draws()
+        runs.append([forward(), forward()])
+        assert _draws() == draws + 2
+        key = jax.numpy.asarray(np.asarray(start, np.uint32))
+        for _i in range(2):
+            key = jax.random.split(key)[0]
+        assert _chain() == np.asarray(key).tolist()
+    (a1, a2), (b1, b2) = runs
+    np.testing.assert_array_equal(a1, b1)
+    np.testing.assert_array_equal(a2, b2)
+    assert np.array_equal(a1, a2) == (not uses_key)
+    launched = _programs_launched(tmp_path, forward)
+    assert len(launched) == 3, launched          # the split's two + its own

@@ -446,3 +446,159 @@ def test_fused_step_matches_staged_with_scheduler():
     for k in fused:
         np.testing.assert_allclose(fused[k], staged[k], rtol=2e-5,
                                    atol=2e-6, err_msg=k)
+
+
+# ------------------------------------------------------------------------
+# _load_batch: an input that already lies where the executor wants it is
+# taken as it is; everything else is converted and put (ISSUE 39)
+# ------------------------------------------------------------------------
+def _infer_module(contexts, batch=8, dtype=np.float32):
+    net = mx.sym.FullyConnected(mx.sym.var("data"), num_hidden=3, name="fc")
+    mod = mx.mod.Module(net, label_names=[], context=contexts)
+    mod.bind([mx.io.DataDesc("data", (batch, 6), dtype)], None,
+             for_training=False)
+    mod.init_params(mx.initializer.Xavier())
+    return mod
+
+
+def _load_counts():
+    from mxnet_tpu import telemetry as tm
+    out = []
+    for nm in ("io.load_batch.aliased", "io.load_batch.puts"):
+        m = tm.get_metric(nm)
+        out.append(0 if m is None else m.value)
+    return out
+
+
+def _one_device_inputs():
+    import jax
+    import jax.numpy as jnp
+    host = np.random.RandomState(5).rand(8, 6)
+    dev0, dev1 = jax.devices()[:2]
+    return {
+        # (what the batch holds, is it taken as it is)
+        "ndarray_in_place": (lambda: mx.nd.array(host.astype("f")), True),
+        "jax_array_in_place": (
+            lambda: jax.device_put(host.astype("f"), dev0), True),
+        "numpy": (lambda: host.astype("f"), False),
+        "numpy_float64": (lambda: host, False),
+        "wrong_dtype": (lambda: mx.nd.array(host, dtype=np.float16), False),
+        "other_device": (
+            lambda: mx.nd.NDArray(jax.device_put(host.astype("f"), dev1)),
+            False),
+        "uncommitted": (lambda: jnp.asarray(host.astype("f")), False),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_one_device_inputs()))
+def test_load_batch_takes_an_input_in_place_as_it_is(case, counting):
+    """One device: an array of the cell's dtype committed to the
+    executor's device becomes the cell's buffer (same pointer, counted
+    as aliased); a numpy array, another dtype, another device or an
+    uncommitted array goes through astype + device_put as before. The
+    values the program reads are the caller's either way."""
+    make, in_place = _one_device_inputs()[case]
+    mod = _infer_module(mx.cpu(0))
+    arr = make()
+    before = _load_counts()
+    mod.forward(mx.io.DataBatch(data=[arr], label=[]), is_train=False)
+    aliased, puts = (a - b for a, b in zip(_load_counts(), before))
+    assert (aliased, puts) == ((1, 0) if in_place else (0, 1))
+    cell = mod._exec_group.executor.arg_dict["data"]
+    given = arr.asjax() if isinstance(arr, mx.nd.NDArray) else arr
+    if in_place:
+        assert cell.asjax() is given
+        assert cell.asjax().unsafe_buffer_pointer() == \
+            given.unsafe_buffer_pointer()
+    else:
+        assert cell.asjax() is not given
+        assert cell.dtype == np.float32
+        dev = next(iter(cell.asjax().devices()))
+        assert dev == mx.cpu(0).jax_device() and cell.asjax().committed
+    want = np.asarray(given).astype(np.float32)
+    np.testing.assert_array_equal(cell.asnumpy(), want)
+    w = mod.get_params()[0]
+    np.testing.assert_allclose(
+        mod.get_outputs()[0].asnumpy(),
+        want @ w["fc_weight"].asnumpy().T + w["fc_bias"].asnumpy(),
+        rtol=1e-5, atol=1e-6)
+
+
+def _mesh_inputs(group):
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    host = np.random.RandomState(6).rand(8, 6).astype("f")
+    mesh = group._mesh
+    return {
+        "data_sharding": (group._data_sharding, True),
+        "equivalent_sharding": (NamedSharding(mesh, P("data", None)), True),
+        "replicated": (NamedSharding(mesh, P()), False),
+        "one_device": (jax.devices()[0], False),
+    }, host
+
+
+@pytest.mark.parametrize("case", ["data_sharding", "equivalent_sharding",
+                                  "replicated", "one_device"])
+def test_load_batch_on_a_mesh_takes_an_equivalent_sharding(case, counting):
+    """The dp tests' CPU mesh: rows already laid over the mesh's data
+    axis are taken as they are; a replicated array or one on a single
+    device is sharded as before."""
+    import jax
+    mod = _infer_module([mx.cpu(i) for i in range(4)])
+    group = mod._exec_group
+    cases, host = _mesh_inputs(group)
+    placement, in_place = cases[case]
+    given = jax.device_put(host, placement)
+    before = _load_counts()
+    mod.forward(mx.io.DataBatch(data=[mx.nd.NDArray(given)], label=[]),
+                is_train=False)
+    aliased, puts = (a - b for a, b in zip(_load_counts(), before))
+    assert (aliased, puts) == ((1, 0) if in_place else (0, 1))
+    cell = group.executor.arg_dict["data"].asjax()
+    assert (cell is given) == in_place
+    assert cell.sharding.is_equivalent_to(group._data_sharding, 2)
+    np.testing.assert_array_equal(np.asarray(cell), host)
+    w = mod.get_params()[0]
+    np.testing.assert_allclose(
+        mod.get_outputs()[0].asnumpy(),
+        host @ w["fc_weight"].asnumpy().T + w["fc_bias"].asnumpy(),
+        rtol=1e-5, atol=1e-6)
+
+
+def test_load_batch_counts_nothing_while_telemetry_is_off():
+    from mxnet_tpu import telemetry as tm
+    tm.disable()
+    mod = _infer_module(mx.cpu(0))
+    before = _load_counts()
+    mod.forward(mx.io.DataBatch(
+        data=[mx.nd.array(np.ones((8, 6), "f"))], label=[]), is_train=False)
+    assert _load_counts() == before
+
+
+def test_aliased_input_outlives_a_forward_that_donates_its_aux():
+    """A decode graph's ``fwd_infer`` donates every aux array
+    (``donate_argnums=(1,)``) and no data entry: the caller's array
+    that a cell aliased is alive after the next forwards and reads as
+    it was written."""
+    from mxnet_tpu.models import transformer as tfm
+    sym = tfm.get_decode_symbol(vocab_size=32, d_model=16, n_layer=1,
+                                n_head=2, capacity=8, per_slot=True,
+                                max_seq_len=8)
+    mod = mx.mod.Module(sym, label_names=[])
+    mod.bind([mx.io.DataDesc("data", (2, 1), np.int32)], None,
+             for_training=False)
+    exe = mod._exec_group.executor
+    mod.init_params(mx.initializer.Xavier(), aux_params={
+        nm: mx.nd.zeros(c.shape, dtype=c.dtype)
+        for nm, c in exe.aux_dict.items()})
+    assert exe.donates_aux
+    ids = mx.nd.array(np.array([[3], [5]], np.int32))
+    held = ids.asjax()
+    pools = [c.asjax() for c in exe.aux_arrays]
+    for _ in range(3):
+        mod.forward(mx.io.DataBatch(data=[ids], label=[]), is_train=False)
+        assert exe.arg_dict["data"].asjax() is held
+    assert all(p.is_deleted() for p in pools)     # the aux WAS donated
+    assert not held.is_deleted()
+    np.testing.assert_array_equal(np.asarray(held), [[3], [5]])
+    np.testing.assert_array_equal(ids.asnumpy(), [[3], [5]])

@@ -106,6 +106,21 @@ def test_counter_math_and_labels():
     assert c2.key == 'widgets{kind="blue"}'
 
 
+def test_held_counters_survive_a_registry_reset():
+    """A hot path's handles: looked up once, and again after
+    ``reset()`` - an increment never lands on a counter the registry
+    has dropped."""
+    held = tm.metrics.held_counters("t.held.a", "t.held.b", model="m")
+    a, b = held()
+    assert held() == (a, b) and a is tm.counter("t.held.a", model="m")
+    a.inc(2)
+    tm.metrics.reset()
+    a2, _b2 = held()
+    assert a2 is not a and a2.value == 0
+    a2.inc()
+    assert tm.get_metric("t.held.a", model="m").value == 1
+
+
 def test_gauge_set_inc_dec():
     g = tm.gauge("depth")
     g.set(3.5)
