@@ -34,7 +34,7 @@ import numpy as np
 
 from .. import symbol as sym
 from .. import telemetry as _telemetry
-from ..base import MXNetError
+from ..base import MXNetError, parse_bool
 
 __all__ = ["get_symbol", "get_decode_symbol", "SyntheticLMIter",
            "KVCacheDecoder", "BatchedKVCacheDecoder", "slot_state",
@@ -231,25 +231,61 @@ GLM_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
             "routed_scaling_factor", "norm_topk_prob")
 
 
-def _glm_spec(block, glm, n_layer, rms_eps):
-    if block != "glm_dsa":
+#: the keys of A.X-K1's published ``config.json`` (``model_type axk1``)
+#: that ``block="axk1"`` reads (``get_decode_symbol(axk1=...)``): the
+#: latent-attention block's without an indexer, the router's groups and
+#: the rotary's scaling (``rope_scaling``: YaRN's ``factor``,
+#: ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+#: ``mscale``, ``mscale_all_dim``, or None); ``held`` as above
+AXK1_KEYS = tuple(k for k in GLM_KEYS if not k.startswith("index")) \
+    + ("n_group", "topk_group", "rope_scaling")
+
+
+def _glm_spec(block, given, n_layer, rms_eps):
+    """The latent-attention block's settings, for ``_glm_block``: the
+    published keys as given, and what the two models differ in -
+    ``indexer_types`` (``"none"`` on every layer of a model without an
+    indexer), ``router`` (``MoEFFN``'s attributes of the choice) and
+    ``rope`` (``mla_attention_decode``'s rotary scaling, empty for the
+    plain rotary)."""
+    if block not in ("glm_dsa", "axk1"):
         return None
-    glm = dict(glm or {})
-    missing = [k for k in GLM_KEYS if k not in glm]
+    arg, keys = ("glm", GLM_KEYS) if block == "glm_dsa" \
+        else ("axk1", AXK1_KEYS)
+    glm = dict(given or {})
+    missing = [k for k in keys if k not in glm]
     if missing:
-        raise MXNetError(f"block='glm_dsa' needs glm= with {missing}")
-    kinds = list(glm["indexer_types"])
-    if len(kinds) != n_layer or not kinds or kinds[0] != "full" \
-            or set(kinds) - {"full", "shared"}:
-        raise MXNetError(
-            f"block='glm_dsa': indexer_types {kinds} must name "
-            f"'full' or 'shared' for each of {n_layer} layers, the "
-            "first 'full' (a shared layer attends the set the nearest "
-            "earlier full layer chose)")
+        raise MXNetError(f"block={block!r} needs {arg}= with {missing}")
+    if block == "glm_dsa":
+        kinds = list(glm["indexer_types"])
+        if len(kinds) != n_layer or not kinds or kinds[0] != "full" \
+                or set(kinds) - {"full", "shared"}:
+            raise MXNetError(
+                f"block='glm_dsa': indexer_types {kinds} must name "
+                f"'full' or 'shared' for each of {n_layer} layers, the "
+                "first 'full' (a shared layer attends the set the nearest "
+                "earlier full layer chose)")
+        router, rope = {"router_bias": True}, {}
+    else:
+        kinds = ["none"] * n_layer
+        router = {"router_bias": False, "n_group": int(glm["n_group"]),
+                  "topk_group": int(glm["topk_group"])}
+        yarn = dict(glm["rope_scaling"] or {})
+        if yarn and yarn.get("type", yarn.get("rope_type")) != "yarn":
+            raise MXNetError(f"block='axk1': rope_scaling {yarn} is not "
+                             "YaRN's")
+        rope = {} if not yarn else {
+            "rope_factor": float(yarn["factor"]),
+            "rope_original_positions":
+                int(yarn["original_max_position_embeddings"]),
+            "rope_beta_fast": float(yarn.get("beta_fast", 32)),
+            "rope_beta_slow": float(yarn.get("beta_slow", 1)),
+            "rope_mscale": float(yarn.get("mscale", 1)),
+            "rope_mscale_all_dim": float(yarn.get("mscale_all_dim", 0))}
     n_expert = int(glm["n_routed_experts"])
     first, count = glm.get("held") or (0, n_expert)
     glm.update(indexer_types=kinds, held=(int(first), int(count)),
-               rms_eps=float(rms_eps))
+               rms_eps=float(rms_eps), router=router, rope=rope)
     return glm
 
 
@@ -259,17 +295,20 @@ def _glm_norm(x, name, glm):
 
 def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
                rope_base, name, capacity, glm):
-    """One GLM-5.2 block (``glm``: ``_glm_spec``) of the slot-pooled
-    decode graph, pre-norm, no bias anywhere but the indexer's
-    LayerNorm: multi-head latent attention over a latent cache
-    (``mla_attention_decode``) under the selection of positions that
-    this layer's indexer computes (``dsa_index_select``, layers whose
-    ``indexer_types`` entry is ``"full"``) or that ``selection``
-    brings from the nearest earlier such layer (``"shared"``:
-    IndexShare); then a dense gated-SiLU feed-forward (layers before
-    ``first_k_dense_replace``) or sigmoid-routed experts beside a
-    shared one (``MoEFFN``), of which this graph holds ``held``; the
-    pads of a window (rows past ``fed``) are routed nowhere.
+    """One latent-attention block (``glm``: ``_glm_spec``; GLM-5.2's
+    and A.X-K1's) of the slot-pooled decode graph, pre-norm, no bias
+    anywhere but the indexer's LayerNorm: multi-head latent attention
+    over a latent cache (``mla_attention_decode``) under the selection
+    of positions that this layer's indexer computes
+    (``dsa_index_select``, layers whose ``indexer_types`` entry is
+    ``"full"``) or that ``selection`` brings from the nearest earlier
+    such layer (``"shared"``: IndexShare), or over every position at or
+    before the query (``"none"``: a model without an indexer; the
+    rotary then under ``glm["rope"]``'s scaling); then a dense
+    gated-SiLU feed-forward (layers before ``first_k_dense_replace``)
+    or sigmoid-routed experts beside a shared one (``MoEFFN``, the
+    choice by ``glm["router"]``), of which this graph holds ``held``;
+    the pads of a window (rows past ``fed``) are routed nowhere.
     Returns ``(x, selection)``: the selection crosses layers outside
     the residual stream."""
     pfx = f"{name}_l{i}"
@@ -305,13 +344,16 @@ def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
             fed, capacity=capacity, n_heads=n_idx, head_dim=d_idx,
             rope_dim=glm["qk_rope_head_dim"], topk=glm["index_topk"],
             rope_base=rope_base, name=f"{pfx}_idx")
+    dense = glm["indexer_types"][i] == "none"
     att = sym.mla_attention_decode(
         unfold(q, n_head * dq, "q"),
         unfold(kv, glm["kv_lora_rank"] + glm["qk_rope_head_dim"], "kv"),
-        selection, fed, capacity=capacity, n_heads=n_head,
-        nope_dim=glm["qk_nope_head_dim"], rope_dim=glm["qk_rope_head_dim"],
-        v_dim=glm["v_head_dim"], kv_rank=glm["kv_lora_rank"],
-        rms_eps=glm["rms_eps"], rope_base=rope_base, name=f"{pfx}_attn")
+        *(() if dense else (selection,)), fed, capacity=capacity,
+        n_heads=n_head, nope_dim=glm["qk_nope_head_dim"],
+        rope_dim=glm["qk_rope_head_dim"], v_dim=glm["v_head_dim"],
+        kv_rank=glm["kv_lora_rank"], rms_eps=glm["rms_eps"],
+        rope_base=rope_base, name=f"{pfx}_attn",
+        **({"selected": False} if dense else {}), **glm["rope"])
     proj = sym.FullyConnected(
         sym.Reshape(att, shape=(-3, 0), name=f"{pfx}_attn_merge"),
         num_hidden=d_model, no_bias=True, name=f"{pfx}_proj")
@@ -333,10 +375,11 @@ def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
             num_hidden=glm["moe_intermediate_size"],
             top_k=glm["num_experts_per_tok"],
             norm_topk=glm["norm_topk_prob"], scoring="sigmoid",
-            router_bias=True, scaling=glm["routed_scaling_factor"],
+            scaling=glm["routed_scaling_factor"],
             held_first=first, held_count=count,
             shared_hidden=glm["n_shared_experts"]
-            * glm["moe_intermediate_size"], name=f"{pfx}_moe")
+            * glm["moe_intermediate_size"], name=f"{pfx}_moe",
+            **glm["router"])
     h = sym.Reshape(h, shape=(-1, T, d_model), name=f"{pfx}_ffn_unfold")
     return x + h, selection
 
@@ -471,9 +514,10 @@ def _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps):
 def _validate(vocab_size, d_model, n_head, pos_embed, block="gpt2",
               n_expert=None, top_k=None, expert_width=None, eva=None,
               glm=None, afmoe=None):
-    if block not in ("gpt2", "olmoe", "evabyte", "glm_dsa", "afmoe"):
+    if block not in ("gpt2", "olmoe", "evabyte", "glm_dsa", "afmoe",
+                     "axk1"):
         raise MXNetError(f"block {block!r}: 'gpt2', 'olmoe', 'evabyte', "
-                         "'glm_dsa' or 'afmoe'")
+                         "'glm_dsa', 'afmoe' or 'axk1'")
     if block == "afmoe":
         if afmoe is None:
             raise MXNetError(
@@ -487,10 +531,10 @@ def _validate(vocab_size, d_model, n_head, pos_embed, block="gpt2",
                 f"{afmoe['head_dim']}: the K/V heads divide the query "
                 "heads, and a head's width is even (rotary pairs)")
         return
-    if block == "glm_dsa":
+    if block in ("glm_dsa", "axk1"):
         if glm is None:
             raise MXNetError(
-                "block='glm_dsa' is served, not trained: its attention "
+                f"block={block!r} is served, not trained: its attention "
                 "exists as the decode ops alone "
                 "(get_decode_symbol(per_slot=True))")
         return
@@ -632,7 +676,7 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                       tie_head=True, embed_scale=True, window=2048,
                       chunk=16, n_pred_heads=1, ffn_width=None,
                       multibyte=False, glm=None, afmoe=None,
-                      max_step_len=None):
+                      max_step_len=None, axk1=None):
     """Incremental KV-cache decoder: ``(B, step_len)`` new token ids in,
     logits ``(B, step_len, vocab)`` out, per-layer K/V caches of
     ``capacity`` positions riding executor aux state. Parameter names
@@ -689,6 +733,15 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     row per position in every pool (``"rows"``), so the driver rewinds,
     captures and restores it as it does a K/V cache.
 
+    ``block="axk1"`` (per-slot only) builds the same block without an
+    indexer from ``axk1``, A.X-K1's published keys (``AXK1_KEYS``) and
+    optionally ``held``: latent attention over every position at or
+    before the query, its rotary under ``rope_scaling`` (YaRN: blended
+    frequencies and a larger softmax scale), and a sigmoid router
+    without a correction bias that chooses inside the ``topk_group``
+    best of ``n_group`` groups of experts. Inputs, state and driver
+    contract are ``glm_dsa``'s.
+
     ``block="afmoe"`` (per-slot only) builds Trinity's block
     (``_afmoe_block``) from ``afmoe``, the published config's keys
     (``AFMOE_KEYS``; ``layer_types`` one entry a layer that is run):
@@ -711,7 +764,8 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     ``BatchedKVCacheDecoder``).
     """
     eva = _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps)
-    glm = _glm_spec(block, glm, n_layer, rms_eps)
+    glm = _glm_spec(block, axk1 if block == "axk1" else glm, n_layer,
+                    rms_eps)
     capacity = capacity or default_cache_capacity()
     afmoe = _afmoe_spec(block, afmoe, n_layer, rms_eps, capacity,
                         max(step_len, max_step_len or 1))
@@ -732,7 +786,7 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
             name, eva, multibyte)
     if glm is not None:
         if not per_slot or cache_dtype:
-            raise MXNetError("block='glm_dsa' is the slot-pooled decode "
+            raise MXNetError(f"block={block!r} is the slot-pooled decode "
                              "graph (per_slot=True) with state at the "
                              "compute width (no cache_dtype)")
         return _glm_decode_symbol(vocab_size, d_model, n_layer, n_head,
@@ -988,6 +1042,46 @@ def sparse_selection(symbol):
             topk[0])
 
 
+def row_programs(slots, block, shardings):
+    """``(capture, restore)``: two jitted programs over the ``"rows"``
+    pools of a ``slots``-slot driver, of the pools' shapes and ``block``
+    rows whatever slot, position or length a call names, so each
+    compiles once a driver. ``capture_rows_<slots>(pools, slot, start)``
+    reads ``block`` rows of one slot from every pool;
+    ``restore_rows_<slots>(pools, rows, slot, start)`` takes the pools
+    over (donated), writes ``block`` rows into one slot of each in place
+    and hands them back in their buffers (``shardings``): neither
+    copies a pool."""
+    import jax
+    from jax import lax
+
+    def capture_rows(pools, slot, start):
+        return tuple(lax.dynamic_slice(
+            p, (slot, 0, start, 0),
+            (1, p.shape[1], block, p.shape[3]))[0] for p in pools)
+
+    def restore_rows(pools, rows, slot, start):
+        return tuple(lax.dynamic_update_slice(
+            p, r[None].astype(p.dtype), (slot, 0, start, 0))
+            for p, r in zip(pools, rows))
+
+    capture_rows.__name__ = f"capture_rows_{slots}"
+    restore_rows.__name__ = f"restore_rows_{slots}"
+    return (jax.jit(capture_rows),
+            jax.jit(restore_rows, donate_argnums=0,
+                    out_shardings=tuple(shardings)))
+
+
+def row_blocks(length, block, capacity):
+    """``(start, skip, n)`` of each launch of ``row_programs`` that
+    covers rows ``[0, length)``: it moves the pool's rows ``[start,
+    start + block)``, of which ``[skip, skip + n)`` are wanted (a block
+    that would pass the capacity starts earlier instead)."""
+    for at in range(0, int(length), block):
+        start = min(at, capacity - block)
+        yield start, at - start, min(block - (at - start), int(length) - at)
+
+
 class BatchedKVCacheDecoder:
     """Host-side driver for a bound SLOT-POOLED decode module.
 
@@ -1078,6 +1172,7 @@ class BatchedKVCacheDecoder:
         # step_len -> how a step's host arrays reach that module's cells
         self._stagers = {1: module._exec_group.input_stager()}
         self._cursor_program = None                  # built at first use
+        self._row_progs = None                       # capture, restore
         self._select_programs = {}                   # step_len -> program
         # the routed feed-forwards' per-layer counts of the latest
         # dispatch (ops/moe.py); empty for a dense decoder
@@ -1109,10 +1204,19 @@ class BatchedKVCacheDecoder:
         # to its cursor: what the latest dispatch read of the pools
         # (``_attention_reads``)
         # (window or 0, rows of its pools) of each such layer
+        nodes = [n for n in module.symbol._topo_nodes()
+                 if not n.is_variable]
         layers = [(int(n.attrs.get("window") or 0),
                    int(n.attrs.get("ring") or 0) or self.capacity)
-                  for n in module.symbol._topo_nodes()
-                  if not n.is_variable and n.op == "attention_decode"]
+                  for n in nodes if n.op == "attention_decode"]
+        # latent-attention layers that take no selection read a slot's
+        # pool up to its cursor too, like a layer without a window (the
+        # selected ones are ``_selection_reads``')
+        self._latent_layers = sum(
+            n.op == "mla_attention_decode"
+            and not parse_bool(n.attrs.get("selected", True))
+            for n in nodes)
+        layers += [(0, self.capacity)] * self._latent_layers
         self.attends = bool(layers)
         self.last_attention = None
         # what ``_attention_reads`` needs of them: how many, the rows
@@ -1368,24 +1472,97 @@ class BatchedKVCacheDecoder:
         touches most of a rung); an empty list launches nothing."""
         self._set_cursors(slots, positions)
 
+    @property
+    def row_block(self):
+        """Rows a slot that one launch of the capture and restore
+        programs moves: the largest window's, so that a join lands in
+        as many launches as its prefill would have taken dispatches."""
+        return min(self.capacity, max(self.window_lens or [256]))
+
+    def _row_programs(self):
+        """This driver's ``row_programs``, built at first use."""
+        if self._row_progs is None:
+            self._row_progs = row_programs(
+                self.slots, self.row_block,
+                [cell.asjax().sharding for _nm, cell in self._kv_cells()])
+        return self._row_progs
+
+    def _row_slot(self, slot):
+        # the programs clamp an index they cannot reach: refuse it here
+        if not 0 <= int(slot) < self.slots:
+            raise MXNetError(f"slot {slot} of a pool of {self.slots}")
+        return np.int32(slot)
+
     def capture_rows(self, slot, length):
         """Snapshot ``slot``'s first ``length`` cache positions across
-        every layer: ``{cell_name: (length, ...) np.ndarray}``. The
-        prefix store keeps these host-side under its byte budget."""
-        slot = int(slot)
-        return {nm: np.asarray(cell.asjax()[slot, :, :int(length)])
-                for nm, cell in self._kv_cells()}
+        every layer: ``{cell_name: (heads, length, width) np.ndarray}``.
+        The prefix store keeps these host-side under its byte budget.
+        ``row_block`` rows a launch of one program, whatever ``slot``
+        and ``length`` (no compile after ``warm_rows``); only the
+        slot's rows are read and brought to the host, never a pool."""
+        slot = self._row_slot(slot)
+        if not 0 <= int(length) <= self.capacity:
+            raise MXNetError(f"capture_rows: {length} rows of a capacity "
+                             f"of {self.capacity}")
+        cells = self._kv_cells()
+        capture, _ = self._row_programs()
+        pools = tuple(cell.asjax() for _nm, cell in cells)
+        parts = []
+        for start, skip, n in row_blocks(length, self.row_block,
+                                         self.capacity):
+            out = capture(pools, slot, np.int32(start))
+            for arr in out:
+                arr.copy_to_host_async()
+            parts.append((out, skip, n))
+        return {nm: np.concatenate(
+            [np.asarray(out[i])[:, skip:skip + n]
+             for out, skip, n in parts], axis=1)
+            if parts else np.zeros(
+                (cell.shape[1], 0, cell.shape[3]), str(cell.dtype))
+            for i, (nm, cell) in enumerate(cells)}
 
     def restore_rows(self, slot, rows):
-        """Write captured rows back into ``slot`` (prefix-cache join):
-        one in-place aux update per layer cache, bitwise the values
-        ``capture_rows`` saw. The caller rewinds/sets the cursor."""
-        slot = int(slot)
-        for nm, cell in self._kv_cells():
-            row = rows[nm]
-            arr = cell.asjax()
-            cell._set(arr.at[slot, :, :row.shape[1]].set(
-                np.asarray(row, dtype=str(arr.dtype))))
+        """Write captured rows back into ``slot`` (prefix-cache join),
+        bitwise the values ``capture_rows`` saw: ``row_block`` rows a
+        launch of one donated program (``_row_programs``), the last
+        block padded, so that a join of any length compiles nothing and
+        copies no pool. Rows of the slot past the restored ones are
+        don't-cares, as after a ``rewind``. The caller sets the cursor.
+        Returns the bytes put to the device."""
+        slot = self._row_slot(slot)
+        cells = self._kv_cells()
+        _, restore = self._row_programs()
+        block = self.row_block
+        length = {rows[nm].shape[1] for nm, _cell in cells}
+        if len(length) != 1 or max(length) > self.capacity:
+            raise MXNetError(f"restore_rows: rows of lengths "
+                             f"{sorted(length)} for pools of "
+                             f"{self.capacity}")
+        put = 0
+        host = [np.asarray(rows[nm], dtype=str(cell.dtype))
+                for nm, cell in cells]
+        for start, skip, n in row_blocks(length.pop(), block,
+                                         self.capacity):
+            at = start + skip
+            chunk = []
+            for src in host:
+                part = src[:, start:at + n]
+                if part.shape[1] < block:
+                    part = np.concatenate([part, np.zeros(
+                        (part.shape[0], block - part.shape[1],
+                         part.shape[2]), part.dtype)], axis=1)
+                chunk.append(part)
+                put += part.nbytes
+            pools = tuple(cell.asjax() for _nm, cell in cells)
+            for (_nm, cell), new in zip(cells, restore(
+                    pools, tuple(chunk), slot, np.int32(start))):
+                cell._set(new)
+        return put
+
+    def warm_rows(self):
+        """Compile ``capture_rows`` and ``restore_rows`` (one block of
+        slot 0 out and back in: warm-up's slots are free)."""
+        self.restore_rows(0, self.capture_rows(0, self.row_block))
 
     def overflowing(self, window=1):
         """Active slots whose next ``window``-token dispatch would pass
@@ -1482,23 +1659,33 @@ class BatchedKVCacheDecoder:
 
     def _attention_reads(self, fed):
         """What one dispatch that feeds ``fed`` tokens a slot reads of
-        the ``attention_decode`` pools, from the cursors alone (no
-        fetch), summed over the fed slots and the layers: ``[positions
-        at or before each slot's last query, rows the pools hold (slots
-        x capacity, or x a ring's rows), positions that query attends
-        (on a sliding layer at most its window)]``. The first over the
-        second is the share of a pool that a dispatch has any use for;
-        the third over the first the share of the keys that the windows
-        leave. None for a graph without the op."""
+        the ``attention_decode`` pools and of the latent pools of
+        ``mla_attention_decode`` layers without a selection, from the
+        cursors alone (no fetch), summed over the fed slots and the
+        layers: ``[positions at or before each slot's last query, rows
+        the pools hold (slots x capacity, or x a ring's rows), positions
+        that query attends (on a sliding layer at most its window), of
+        those the latent rows, the (query, key) pairs of ALL the fed
+        queries on the latent layers - query t of a slot at position p
+        attends p + t + 1 keys]``. The first over the second is the
+        share of a pool that a dispatch has any use for; the third over
+        the first the share of the keys that the windows leave; the
+        fourth and fifth are there only where the graph has such latent
+        layers (at S = 1 they are equal). None for a graph without
+        either kind."""
         if not self.attends:
             return None
         live = np.minimum((self.pos + fed)[fed > 0], self.capacity)
         layers, pool_rows, by_window = self._attn_shape
         total = np.sum(live)
-        return np.asarray(
-            [layers * total, self.slots * pool_rows,
-             sum(n * (np.sum(np.minimum(live, w)) if w else total)
-                 for w, n in by_window)], np.int64)
+        reads = [layers * total, self.slots * pool_rows,
+                 sum(n * (np.sum(np.minimum(live, w)) if w else total)
+                     for w, n in by_window)]
+        if self._latent_layers:
+            n = np.asarray(fed, np.int64)[fed > 0]
+            reads += [self._latent_layers * total, self._latent_layers
+                      * np.sum(n * self.pos[fed > 0] + n * (n + 1) // 2)]
+        return np.asarray(reads, np.int64)
 
     def _selection_reads(self, fed):
         """What one dispatch that feeds ``fed`` tokens a slot reads

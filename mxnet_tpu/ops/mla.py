@@ -54,37 +54,86 @@ nothing and stays (``cache_write``'s rule).
 """
 from __future__ import annotations
 
+import math
+from collections import namedtuple
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..base import MXNetError, parse_float, parse_int
+from ..base import MXNetError, parse_bool, parse_float, parse_int
 from . import pallas_kernels as _pk
 from .moe import cpu_wide, rms_norm
 from .registry import register
 
-__all__ = ["rope_interleaved", "dsa_scores", "dsa_threshold_mask",
-           "latent_width"]
+__all__ = ["rope_interleaved", "yarn_inv_freq", "yarn_mscale",
+           "RopeScaling", "dsa_scores", "dsa_threshold_mask", "latent_width"]
 
 _F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
 _VMEM_LIMIT = 96 << 20
 
 
-def rope_interleaved(x, positions, base):
+def yarn_mscale(factor, mscale):
+    """YaRN's attention-magnitude factor ``m(a) = 0.1 a ln(factor) + 1``
+    (1 without scaling)."""
+    if factor <= 1.0 or not mscale:
+        return 1.0
+    return 0.1 * float(mscale) * math.log(float(factor)) + 1.0
+
+
+def yarn_inv_freq(d, base, factor, original_positions, beta_fast=32.0,
+                  beta_slow=1.0):
+    """The ``d // 2`` rotary frequencies under YaRN (arXiv:2309.00071,
+    as the DeepSeek-V3 family computes it) and its ramp's ``(low,
+    high)``: ``theta_i = base ** (-2i / d)``; a pair that turns more
+    than ``beta_fast`` times over ``original_positions`` keeps
+    ``theta_i``, one that turns less than ``beta_slow`` times gets
+    ``theta_i / factor``, and the pairs between ``low`` and ``high``
+    blend the two linearly. float64 on the host, so that whoever states
+    the same formula rounds to the same float32."""
+    i = np.arange(d // 2, dtype=np.float64)
+    theta = float(base) ** (-2.0 * i / d)
+
+    def pair_turning(turns):
+        return d * math.log(original_positions / (turns * 2.0 * math.pi)) \
+            / (2.0 * math.log(float(base)))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), d - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return theta * (1.0 - ramp) + theta / float(factor) * ramp, (low, high)
+
+
+#: ``(factor, original_positions, beta_fast, beta_slow, trig_scale)`` of
+#: a rotary under YaRN; None is the plain rotary
+RopeScaling = namedtuple(
+    "RopeScaling", "factor original_positions beta_fast beta_slow trig_scale")
+
+
+def rope_interleaved(x, positions, base, scaling=None):
     """Rotate the adjacent pairs ``(x[2i], x[2i+1])`` of ``x``
     (..., T, d) by ``positions[..., t] * base ** (-2i / d)``;
     ``positions`` broadcasts against ``x``'s leading axes up to T. Trig
-    in float32, cast back."""
+    in float32, cast back. ``scaling`` (``RopeScaling``) turns the
+    pairs by YaRN's blended frequencies instead and scales cos and sin
+    by its ``trig_scale``."""
     d = x.shape[-1]
-    inv = jnp.asarray(base, _F32) ** (
-        -jnp.arange(0, d // 2, dtype=_F32) * (2.0 / d))
+    if scaling is None:
+        inv = jnp.asarray(base, _F32) ** (
+            -jnp.arange(0, d // 2, dtype=_F32) * (2.0 / d))
+    else:
+        inv = jnp.asarray(yarn_inv_freq(
+            d, base, scaling.factor, scaling.original_positions,
+            scaling.beta_fast, scaling.beta_slow)[0], _F32)
     ang = positions.astype(_F32)[..., None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None and scaling.trig_scale != 1.0:
+        cos, sin = cos * scaling.trig_scale, sin * scaling.trig_scale
     pair = x.astype(_F32).reshape(x.shape[:-1] + (d // 2, 2))
     a, b = pair[..., 0], pair[..., 1]
     out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
@@ -444,35 +493,66 @@ def _lanes(x, width):
     return jnp.pad(x, grow) if width > x.shape[-1] else x
 
 
+def _mla_selected(attrs):
+    """Does the op attend under a selection (an input), or every
+    position at or before the query (``selected=False``)?"""
+    return parse_bool(attrs.get("selected", True))
+
+
+def _mla_rope(attrs):
+    """``(base, RopeScaling or None, softmax scale factor)`` of the op's
+    rotary: YaRN by the ``rope_*`` attributes, as the DeepSeek-V3 family
+    applies it - cos and sin by ``m(mscale) / m(mscale_all_dim)``, the
+    softmax scale by ``m(mscale_all_dim) ** 2``. ``rope_factor`` 1 is
+    the plain rotary and the plain scale."""
+    base = parse_float(attrs.get("rope_base", 10000.0))
+    factor = parse_float(attrs.get("rope_factor", 1.0))
+    if factor == 1.0:
+        return base, None, 1.0
+    all_dim = yarn_mscale(factor, parse_float(
+        attrs.get("rope_mscale_all_dim", 0.0)))
+    trig = yarn_mscale(factor, parse_float(attrs.get("rope_mscale", 1.0))) \
+        / all_dim
+    return base, RopeScaling(
+        factor, parse_int(attrs.get("rope_original_positions", 4096)),
+        parse_float(attrs.get("rope_beta_fast", 32.0)),
+        parse_float(attrs.get("rope_beta_slow", 1.0)), trig), all_dim ** 2
+
+
 def _mla_prologue(attrs, inputs, aux, is_train):
     """What both lowerings share: the cursor, the new latent rows
     (``c_kv`` normalised, ``k_r`` rotated) at the pool's dtype, the
-    queries split and rotated, ``W_kvb`` by head."""
+    queries split and rotated, ``W_kvb`` by head; ``selection`` is None
+    for an op that takes none."""
     if is_train:
         raise MXNetError("mla_attention_decode is an inference op")
-    q, kv, selection, fed, gamma, kvb = inputs
+    if _mla_selected(attrs):
+        q, kv, selection, fed, gamma, kvb = inputs
+    else:
+        (q, kv, fed, gamma, kvb), selection = inputs, None
     pool, cursor = aux
     capacity, H, dn, dr, dv, rank = _mla_geometry(attrs)
     B, S = q.shape[:2]
     p, new_cursor = _cursor(fed, cursor, S, capacity)
-    base = parse_float(attrs.get("rope_base", 10000.0))
+    base, scaling, softmax_factor = _mla_rope(attrs)
     pos = _positions(p, S)
     c = rms_norm(kv[..., :rank], gamma,
                  parse_float(attrs.get("rms_eps", 1e-5)))
     row = _lanes(jnp.concatenate(
-        [c, rope_interleaved(kv[..., rank:], pos, base)], axis=-1),
+        [c, rope_interleaved(kv[..., rank:], pos, base, scaling)], axis=-1),
         pool.shape[-1])
     q = q.reshape(B, S, H, dn + dr)
-    q_r = rope_interleaved(q[..., dn:], pos[:, :, None], base)
+    q_r = rope_interleaved(q[..., dn:], pos[:, :, None], base, scaling)
     kvb = kvb.reshape(H, dn + dv, rank)
     return (q[..., :dn], q_r, row.astype(pool.dtype)[:, None], selection,
             kvb[:, :dn], kvb[:, dn:], p, new_cursor,
-            float(dn + dr) ** -0.5)
+            float(dn + dr) ** -0.5 * softmax_factor)
 
 
 def _mla_fwd(attrs, inputs, aux, is_train, rng):
     """The expanded form: every pool row's ``k_n`` and ``v`` of every
-    head, dense scores, the selection as the softmax's mask."""
+    head, dense scores, the selection - or, without one, ``j <= t`` -
+    as the softmax's mask."""
     from ..rtc import _write_rows
     q_n, q_r, row, sel, w_kb, w_vb, p, new_cursor, scale = _mla_prologue(
         attrs, inputs, aux, is_train)
@@ -486,8 +566,11 @@ def _mla_fwd(attrs, inputs, aux, is_train, rng):
     v = _mm("bkc,hvc->bhkv", c_all, w_vb.astype(dtype)).astype(dtype)
     logits = (_mm("bshn,bhkn->bhsk", q_n, k_n)
               + _mm("bshr,bkr->bhsk", q_r, r_all)) * scale
+    attended = sel != 0 if sel is not None else \
+        jnp.arange(pool.shape[2])[None, None, :] \
+        <= _positions(p, S)[:, :, None]
     probs = jax.nn.softmax(
-        jnp.where(sel[:, None] != 0, logits, -jnp.inf), axis=-1)
+        jnp.where(attended[:, None], logits, -jnp.inf), axis=-1)
     out = jnp.einsum("bhsk,bhkv->bshv", probs, v.astype(_F32),
                      precision=_HI)
     return [out.reshape(B, S, -1).astype(q_n.dtype)], [pool, new_cursor]
@@ -499,25 +582,58 @@ def _mla_infer(attrs, in_shapes):
         return in_shapes, [None], [None, None]
     capacity, H, dn, dr, dv, rank = _mla_geometry(attrs)
     B, S = q_s[:2]
-    return ([(B, S, H * (dn + dr)), (B, S, rank + dr), (B, S, capacity),
-             (B,), (rank,), (H * (dn + dv), rank)],
+    selection = [(B, S, capacity)] if _mla_selected(attrs) else []
+    return ([(B, S, H * (dn + dr)), (B, S, rank + dr)] + selection
+            + [(B,), (rank,), (H * (dn + dv), rank)],
             [(B, S, H * dv)],
             [(B, 1, capacity, latent_width(rank, dr)), (B, 1)])
 
 
-def _mla_attn_kernel(hg, R, bk, rank, scale, window):
+def _mla_attn_kernel(hg, R, bk, rank, scale, window, selected):
     """Grid (slot, head group, query block, key block): online softmax
     of ``hg`` groups of ``R`` query rows against a block of latent rows
     under the selection. In a window a group is a head and its rows
     are ``R`` positions, masked row by row, and a block whose positions
     are all past ``fed`` (pads) comes out zero without a product; at
     S = 1 the one group's rows are the heads, which share the query's
-    mask row."""
-    def kernel(p_ref, fed_ref, q_ref, k_ref, sel_ref, o_ref, m_s, l_s,
-               acc_s):
+    mask row. Without a selection (``selected`` False: no mask operand)
+    a row attends the keys at or before its own position, told from the
+    cursor: a block that lies wholly before the query block's first
+    position takes no mask at all, and since key 0 is at or before
+    every query no row's running maximum is ever infinite."""
+    def attend(q_ref, k_ref, mask, m_s, l_s, acc_s):
+        k = k_ref[...]
+        c = k[:, :rank]
+
+        def group(g, carry):
+            s = lax.dot_general(q_ref[g], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=_F32) * scale
+            if mask is not None:
+                s = jnp.where(mask, s, -jnp.inf)
+            m = m_s[g]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            if selected:
+                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+                e = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
+                corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+            else:
+                e = jnp.exp(s - m_new)
+                corr = jnp.exp(m - m_new)
+            m_s[g] = m_new
+            l_s[g] = l_s[g] * corr + jnp.sum(e, axis=-1, keepdims=True)
+            acc_s[g] = acc_s[g] * corr + jnp.dot(
+                e.astype(c.dtype), c, preferred_element_type=_F32)
+            return carry
+        lax.fori_loop(0, hg, group, 0)
+
+    def kernel(p_ref, fed_ref, q_ref, k_ref, *rest):
+        sel_ref = rest[0] if selected else None
+        o_ref, m_s, l_s, acc_s = rest[-4:]
         b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-        last = p_ref[b] + ((i + 1) * R - 1 if window else 0)
+        first = p_ref[b] + (i * R if window else 0)
+        last = first + (R - 1 if window else 0)
         fed = (i * R < fed_ref[b]) if window else True
+        live = (j * bk <= last) & fed
 
         @pl.when(j == 0)
         def _init():
@@ -525,27 +641,25 @@ def _mla_attn_kernel(hg, R, bk, rank, scale, window):
             l_s[...] = jnp.zeros(l_s.shape, _F32)
             acc_s[...] = jnp.zeros(acc_s.shape, _F32)
 
-        @pl.when((j * bk <= last) & fed)
-        def _block():
-            k = k_ref[...]
-            c = k[:, :rank]
-            mask = sel_ref[...].astype(jnp.int32) != 0
+        if selected:
+            @pl.when(live)
+            def _block():
+                attend(q_ref, k_ref, sel_ref[...].astype(jnp.int32) != 0,
+                       m_s, l_s, acc_s)
+        else:
+            before = (j + 1) * bk - 1 <= first
 
-            def group(g, carry):
-                s = lax.dot_general(q_ref[g], k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=_F32) * scale
-                s = jnp.where(mask, s, -jnp.inf)
-                m = m_s[g]
-                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-                e = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
-                corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-                m_s[g] = m_new
-                l_s[g] = l_s[g] * corr + jnp.sum(e, axis=-1, keepdims=True)
-                acc_s[g] = acc_s[g] * corr + jnp.dot(
-                    e.astype(c.dtype), c, preferred_element_type=_F32)
-                return carry
-            lax.fori_loop(0, hg, group, 0)
+            @pl.when(live & before)
+            def _whole():
+                attend(q_ref, k_ref, None, m_s, l_s, acc_s)
+
+            @pl.when(live & jnp.logical_not(before))
+            def _diagonal():
+                rows = R if window else 1
+                key = j * bk + lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+                t = first + (lax.broadcasted_iota(jnp.int32, (rows, bk), 0)
+                             if window else 0)
+                attend(q_ref, k_ref, key <= t, m_s, l_s, acc_s)
 
         @pl.when(j == pl.num_programs(3) - 1)
         def _emit():
@@ -558,8 +672,9 @@ def _mla_attn_kernel(hg, R, bk, rank, scale, window):
 def _mla_attend(p, fed, q, pool, sel, rank, scale, interpret):
     """The kernel ``mla_attn_decode`` / ``mla_attn_window``: queries
     ``q (B, H, S, rank + rope_dim)`` in the latent space against the
-    pool under ``sel`` -> the weighted sums of ``c_kv``, (B, H, S, rank)
-    at ``q``'s dtype. Blocks past a query block's last position are
+    pool under ``sel`` (None: every position at or before the query, no
+    mask operand) -> the weighted sums of ``c_kv``, (B, H, S, rank) at
+    ``q``'s dtype. Blocks past a query block's last position are
     neither fetched nor computed."""
     B, H, S, dq = q.shape
     C = pool.shape[2]
@@ -572,6 +687,7 @@ def _mla_attend(p, fed, q, pool, sel, rank, scale, interpret):
         R, bk = _pk._divisor_block(S, 256), _pk._divisor_block(C, 512)
     n_q = 1 if S == 1 else S // R
     window = S > 1
+    selected = sel is not None
 
     def q_map(b, g, i, j, p_ref, fed_ref):
         return b, g, i, 0
@@ -589,22 +705,26 @@ def _mla_attend(p, fed, q, pool, sel, rank, scale, interpret):
     def sel_map(b, g, i, j, p_ref, fed_ref):
         return b, i, jnp.minimum(j, last_block(b, i, p_ref, fed_ref))
 
+    in_specs = [pl.BlockSpec((None, hg, R, dq), q_map),
+                pl.BlockSpec((None, bk, dq), k_map)]
+    if selected:
+        in_specs.append(pl.BlockSpec((None, R if window else 1, bk),
+                                     sel_map))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(B, G // hg, n_q, C // bk),
-        in_specs=[pl.BlockSpec((None, hg, R, dq), q_map),
-                  pl.BlockSpec((None, bk, dq), k_map),
-                  pl.BlockSpec((None, R if window else 1, bk), sel_map)],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((None, hg, R, rank), q_map),
         scratch_shapes=[pltpu.VMEM((hg, R, 1), _F32),
                         pltpu.VMEM((hg, R, 1), _F32),
                         pltpu.VMEM((hg, R, rank), _F32)])
     out = _pk.pallas_call(
-        _mla_attn_kernel(hg, R, bk, rank, scale, window),
+        _mla_attn_kernel(hg, R, bk, rank, scale, window, selected),
         name="mla_attn_window" if window else "mla_attn_decode",
         out_shape=jax.ShapeDtypeStruct(q.shape[:3] + (rank,), q.dtype),
         grid_spec=grid_spec, interpret=interpret,
         **_compiler_params(("parallel", "parallel", "parallel",
-                            "arbitrary")))(p, fed, q, keys, sel)
+                            "arbitrary")))(
+            p, fed, q, keys, *((sel,) if selected else ()))
     return out.reshape(B, H, S, rank)
 
 
@@ -630,8 +750,15 @@ def _mla_pallas(attrs, inputs, aux, is_train, rng):
     return [out.reshape(B, S, -1).astype(dtype)], [pool, new_cursor]
 
 
+def _mla_inputs(attrs):
+    """The op's inputs: ``selection`` only where it attends under one."""
+    return ["q", "kv"] + (["selection"] if _mla_selected(attrs) else []) \
+        + ["fed", "kv_norm_weight", "kv_b_weight"]
+
+
 def _mla_eligible(attrs, in_shapes, in_dtypes):
-    if len(in_shapes) < 8 or len(in_shapes[0]) != 3:
+    if len(in_shapes) < len(_mla_inputs(attrs)) + 2 \
+            or len(in_shapes[0]) != 3:
         return False
     if str(in_dtypes[0]) not in ("float32", "bfloat16"):
         return False
@@ -650,9 +777,7 @@ _MLA_KSPEC = {
     "dtypes": ("float32", "bfloat16"),
 }
 
-register("mla_attention_decode",
-         inputs=("q", "kv", "selection", "fed", "kv_norm_weight",
-                 "kv_b_weight"),
+register("mla_attention_decode", inputs=_mla_inputs,
          aux=tuple(MLA_SLOT_STATE), full=_mla_fwd, stateful_infer=True,
          aux_dtypes={"cache_pos": "int32"}, infer_shape=_mla_infer,
          attr_spec={"capacity": (parse_int, None),
@@ -662,8 +787,16 @@ register("mla_attention_decode",
                     "v_dim": (parse_int, None),
                     "kv_rank": (parse_int, None),
                     "rms_eps": (parse_float, 1e-5),
-                    "rope_base": (parse_float, 10000.0)},
+                    "rope_base": (parse_float, 10000.0),
+                    "selected": (parse_bool, True),
+                    "rope_factor": (parse_float, 1.0),
+                    "rope_original_positions": (parse_int, 4096),
+                    "rope_beta_fast": (parse_float, 32.0),
+                    "rope_beta_slow": (parse_float, 1.0),
+                    "rope_mscale": (parse_float, 1.0),
+                    "rope_mscale_all_dim": (parse_float, 0.0)},
          slot_state=MLA_SLOT_STATE, donate_aux=True,
          variants={"pallas": (_mla_pallas, _mla_eligible, _MLA_KSPEC)},
          doc="Multi-head latent attention over a per-slot pool of latent "
-             "rows, under a selection of positions (ops/mla.py).")
+             "rows, under a selection of positions or over every position "
+             "at or before the query (ops/mla.py).")

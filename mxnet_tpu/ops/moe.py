@@ -35,7 +35,11 @@ runs it; expert parallelism without its exchange). With
 x @ router_weight.T))``, the ``top_k`` largest of ``sc + router_bias``
 are chosen (``router_bias``), and their weights are ``sc`` itself,
 normalised over the chosen (``norm_topk``) and times ``scaling`` - the
-bias steers the choice and never the weights. ``held_first`` /
+bias steers the choice and never the weights; ``router_bias=False`` is
+the same router without a bias (an auxiliary-loss router). ``n_group`` /
+``topk_group`` limit the choice to the experts of the ``topk_group``
+best of ``n_group`` consecutive groups (``moe_route_sigmoid``; 1 / 1:
+no limit). ``held_first`` /
 ``held_count`` say which experts this layer holds: ``num_experts`` stays
 the router's published width and every token is routed over all of
 them, but the stacked weights are the held experts' alone
@@ -171,15 +175,29 @@ def moe_stats(group_sizes):
 
 
 def moe_route_sigmoid(x, router_weight, router_bias, top_k, norm_topk,
-                      scaling):
+                      scaling, n_group=1, topk_group=1):
     """``(weights (T, k) float32, experts (T, k) int32)`` of the
     ``noaux_tc`` router (module docstring): scores in float32 from
-    float32-accumulated logits, the bias in the choice alone."""
+    float32-accumulated logits, the bias in the choice alone. With
+    ``n_group`` > 1 the choice is group-limited: the experts lie in
+    ``n_group`` consecutive groups, a group's score is the sum of its
+    two largest choice scores, and the ``top_k`` are taken among the
+    experts of the ``topk_group`` best groups alone (ties: the lowest
+    index, of groups and of experts)."""
     rows, router = cpu_wide(x, router_weight.astype(x.dtype))
     score = jax.nn.sigmoid(jnp.dot(rows, router.T,
                                    preferred_element_type=jnp.float32))
     choice = score if router_bias is None \
         else score + router_bias.astype(jnp.float32)
+    if n_group > 1:
+        T, E = choice.shape
+        groups = choice.reshape(T, n_group, E // n_group)
+        best = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)     # (T, n_group)
+        _, kept = lax.top_k(best, topk_group)
+        keep = jnp.any(kept[:, :, None]
+                       == jnp.arange(n_group, dtype=kept.dtype), axis=1)
+        choice = jnp.where(keep[:, :, None], groups, -jnp.inf) \
+            .reshape(T, E)
     _, experts = lax.top_k(choice, top_k)
     weights = jnp.take_along_axis(score, experts, axis=-1)
     if norm_topk:
@@ -242,8 +260,8 @@ def _dense_expert(x, gate, up, down):
     return jnp.dot(h, down, preferred_element_type=f32)
 
 
-_Share = namedtuple("_Share",
-                    "sigmoid bias scaling first count shared step_len")
+_Share = namedtuple("_Share", "sigmoid bias scaling first count shared "
+                    "step_len n_group topk_group")
 
 
 def _share_spec(attrs):
@@ -257,13 +275,26 @@ def _share_spec(attrs):
     count = parse_int(attrs.get("held_count", 0)) or E
     shared = parse_int(attrs.get("shared_hidden", 0))
     step_len = parse_int(attrs.get("step_len", 0))
+    n_group = parse_int(attrs.get("n_group", 1))
+    topk_group = parse_int(attrs.get("topk_group", 1))
     if not (sigmoid or bias or shared or step_len or scaling != 1.0
-            or (first, count) != (0, E)):
+            or (first, count) != (0, E) or n_group > 1):
         return None
     if first < 0 or first + count > E:
         raise ValueError(f"MoEFFN holds experts {first}..{first + count} "
                          f"of {E}")
-    return _Share(sigmoid, bias, scaling, first, count, shared, step_len)
+    if n_group > 1:
+        top_k = parse_int(attrs.get("top_k", 1))
+        if not sigmoid or E % n_group or E // n_group < 2 \
+                or not 1 <= topk_group <= n_group \
+                or top_k > topk_group * (E // n_group):
+            raise ValueError(
+                f"MoEFFN: group-limited routing (n_group {n_group}, "
+                f"topk_group {topk_group}) is the sigmoid router's, over "
+                f"{E} experts in whole groups of at least 2, with top_k "
+                f"{top_k} experts inside the groups kept")
+    return _Share(sigmoid, bias, scaling, first, count, shared, step_len,
+                  n_group, topk_group)
 
 
 def moe_share_ffn(attrs, inputs, experts_fn):
@@ -283,8 +314,9 @@ def moe_share_ffn(attrs, inputs, experts_fn):
     top_k = parse_int(attrs.get("top_k", 1))
     norm_topk = parse_bool(attrs.get("norm_topk", False))
     if share.sigmoid:
-        weights, experts = moe_route_sigmoid(x, router, router_bias, top_k,
-                                             norm_topk, share.scaling)
+        weights, experts = moe_route_sigmoid(
+            x, router, router_bias, top_k, norm_topk, share.scaling,
+            share.n_group, share.topk_group)
     else:
         weights, experts = moe_route(x, router, top_k, norm_topk)
         weights = weights * share.scaling
@@ -395,7 +427,9 @@ register("MoEFFN", inputs=_moe_inputs, aux=("moe_stats",), full=_moe_fwd,
                     "held_first": (parse_int, 0),
                     "held_count": (parse_int, 0),
                     "shared_hidden": (parse_int, 0),
-                    "step_len": (parse_int, 0)},
+                    "step_len": (parse_int, 0),
+                    "n_group": (parse_int, 1),
+                    "topk_group": (parse_int, 1)},
          infer_shape=_moe_infer,
          doc="Routed expert feed-forward: top_k of num_experts gated-SiLU "
              "experts of width num_hidden per token (ops/moe.py).")

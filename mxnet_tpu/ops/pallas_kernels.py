@@ -1623,6 +1623,9 @@ def _cache_write(pos, news, pools, interpret, name="cache_write",
 _GMM_TILE_BYTES = 1 << 20
 #: rows of the sorted assignments a grid step multiplies
 _GMM_ROWS = 128
+#: the widest model (D) or expert (F) the grouped kernels are offered:
+#: an output tile of that many float32 columns beside its accumulator
+_GMM_MAX_WIDTH = 7168
 
 
 def _gmm_work_items(group_sizes, tiles_m, tm):
@@ -1794,20 +1797,20 @@ def _moe_eligible(attrs, in_shapes, in_dtypes):
     if str(in_dtypes[0]) not in ("float32", "bfloat16", "float16"):
         return False
     D, F = gate[1], gate[2]
-    if max(D, F) > 6144:
+    if max(D, F) > _GMM_MAX_WIDTH:
         return False
     return (D % 128 == 0 and F % 128 == 0) or _interpret()
 
 
-#: worst case at the eligibility bounds (widths <= 6144, the down
-#: matmul of a 6,144-wide model: weight tiles of 128 rows): the row
-#: tile, the weight tile and the output tile double buffered by the
-#: pipeline, and the float32 accumulator
+#: worst case at the eligibility bounds (widths <= ``_GMM_MAX_WIDTH``,
+#: the down matmul of a 7,168-wide model: weight tiles of 128 rows):
+#: the row tile, the weight tile and the output tile double buffered by
+#: the pipeline, and the float32 accumulator
 _MOE_KSPEC = {
     "tiles": [((_GMM_ROWS, 128), "float32")] * 2        # rows
-    + [((128, 6144), "bfloat16")] * 2                   # down tiles
-    + [((_GMM_ROWS, 6144), "float32")] * 2              # out tile
-    + [((_GMM_ROWS, 6144), "float32")],                 # accumulator
+    + [((128, _GMM_MAX_WIDTH), "bfloat16")] * 2         # down tiles
+    + [((_GMM_ROWS, _GMM_MAX_WIDTH), "float32")] * 2    # out tile
+    + [((_GMM_ROWS, _GMM_MAX_WIDTH), "float32")],       # accumulator
     "dtypes": ("float32", "bfloat16", "float16"),
 }
 

@@ -538,7 +538,7 @@ class DecodeEngine:
         return self._drivers[rung]
 
     # ------------------------------------------------------------- warmup
-    def warmup(self, clock):
+    def warmup(self, clock, rows=False):
         """Compile every slot rung's S=1 program AND every window
         program, each with its select program behind it (two steps
         each: first pays the traces, second measures steady state on
@@ -548,7 +548,10 @@ class DecodeEngine:
         free, every cursor is rewound to 0, and a join rewinds again.
         Those rewinds also compile each rung's cursor program, whose
         shapes are the rung's whatever rows a call names, so no later
-        ``join`` or ``rewind_many`` compiles."""
+        ``join`` or ``rewind_many`` compiles. ``rows`` (a scheduler
+        with a prefix store) also compiles each rung's row capture and
+        restore programs, whose shapes are the rung's too whatever
+        length a join has."""
         mark = _progcache.compile_count()
         for rung in self.ladder:
             drv = self._drivers[rung]
@@ -574,6 +577,8 @@ class DecodeEngine:
                 t0 = clock.now()
                 step_ids(wz)                     # steady state
                 self.exec_est[(rung, S)] = max(0.0, clock.now() - t0)
+            if rows and drv.positional:
+                drv.warm_rows()
             drv.active[:] = False
             drv.rewind_many(list(range(rung)), [0] * rung)
         self._pin_programs()
@@ -723,7 +728,11 @@ _DSA_COUNTERS = ("dsa.layer_steps", "dsa.live_rows", "dsa.selected_rows",
 #: a sliding layer at most its window - (per slot, layer and dispatch);
 #: from the host's cursors, no fetch. live / capacity is the share of
 #: the pools the traffic keeps live, attended / live what the windows
-#: leave of the keys
+#: leave of the keys. Latent-attention layers without a selection are
+#: counted here too (they read a slot's pool up to its cursor), and the
+#: ring record carries their share of the attended rows as
+#: ``mla_attended`` and the (query, key) pairs of all their fed queries
+#: as ``mla_pairs``
 _ATTN_COUNTERS = ("attn.live_rows", "attn.capacity_rows",
                   "attn.attended_rows")
 
@@ -838,10 +847,12 @@ class DecodeScheduler:
         if self.draft is not None:
             with _telemetry.span("serve.decode.warmup",
                                  model=self.draft.name):
-                self.draft.warmup(self._clock)
+                self.draft.warmup(self._clock,
+                                  rows=prefix_store is not None)
         with _telemetry.span("serve.decode.warmup",
                              model=self.engine.name):
-            est = self.engine.warmup(self._clock)
+            est = self.engine.warmup(self._clock,
+                                     rows=prefix_store is not None)
         if self.draft is not None:
             # the target's warmup compiles landed after the draft's
             # mark; refresh it so BOTH gates read 0 in steady state
@@ -1039,6 +1050,7 @@ class DecodeScheduler:
             seq.slot = row
             self._slots[row] = seq
             self._counter("joins").inc()
+            self._counter("prompt_tokens").inc(len(seq.prompt))
             if seq.prefix_id is not None and \
                     self.prefix_store is not None:
                 self._prefix_admit(row, seq, now)
@@ -1049,37 +1061,44 @@ class DecodeScheduler:
 
     def _prefix_admit(self, row, seq, now):
         """Prefix-store hit test for one freshly joined sequence: on a
-        hit the slot *joins at cursor C* — the stored rows write back
-        into its cache slice (bitwise what a cold prefill of those
-        positions computes) and the cursor rewinds forward to C, so
-        prefill starts at the first unshared token. A miss marks the
-        sequence cold: its prompt rows snapshot into the store the
-        iteration its prefill completes."""
+        hit the slot *joins at cursor C*, the longest head its prompt
+        shares with the stored one (a head shorter than one dispatch is
+        a miss) — the stored rows write back into its cache slice
+        (bitwise what a cold prefill of those positions computes) and
+        the cursor rewinds forward to C, so prefill starts at the first
+        unshared token. ``serve.decode.prefix.join`` runs from before
+        the rows' restore to after the rewind, with the bytes put to
+        the device; ``serve.decode.prefix.joined_tokens`` counts C. A
+        miss marks the sequence cold: its prompt rows snapshot into the
+        store the iteration its prefill completes."""
         tags = ("target", "draft") if self.draft is not None \
             else ("target",)
-        c, entry = self.prefix_store.lookup(seq.prefix_id, seq.prompt,
-                                            tags=tags)
+        c, entry = self.prefix_store.lookup(
+            seq.prefix_id, seq.prompt, tags=tags, least=self.prefill_chunk)
         if entry is None:
             seq.prefix_cold = True
             self._counter("prefix.misses").inc()
             return
-        drv = self.engine.driver(self._rung)
-        drv.restore_rows(row, {nm: r[:, :c]
-                               for nm, r in entry.payloads["target"]
-                               .items()})
-        drv.rewind(row, c)
-        if self.draft is not None:
-            ddrv = self.draft.driver(self._rung)
-            ddrv.restore_rows(row, {nm: r[:, :c]
-                                    for nm, r in entry.payloads["draft"]
-                                    .items()})
-            ddrv.rewind(row, c)
+        t_join = self._clock.now()
+        with _telemetry.span("serve.decode.prefix.join"):
+            drv = self.engine.driver(self._rung)
+            put = drv.restore_rows(
+                row, {nm: r[:, :c]
+                      for nm, r in entry.payloads["target"].items()})
+            drv.rewind(row, c)
+            if self.draft is not None:
+                ddrv = self.draft.driver(self._rung)
+                put += ddrv.restore_rows(
+                    row, {nm: r[:, :c]
+                          for nm, r in entry.payloads["draft"].items()})
+                ddrv.rewind(row, c)
         seq.fed = c
         self._counter("prefix.hits").inc()
+        self._counter("prefix.joined_tokens").inc(c)
         if seq.trace is not None:
             _trace.record(seq.trace, "serve.decode.prefix.join",
-                          now, now, parent=seq.root_sid, slot=row,
-                          cursor=c)
+                          t_join, self._clock.now(), parent=seq.root_sid,
+                          slot=row, cursor=c, bytes=put)
 
     def _plan_dispatch(self):
         """Pick this iteration's dispatch shape (caller holds the
@@ -1424,7 +1443,10 @@ class DecodeScheduler:
                         "dsa_scored": int(dsa[3])}),
                     **({} if attn is None else
                        {"attn_live": int(attn[0]),
-                        "attn_attended": int(attn[2])}))
+                        "attn_attended": int(attn[2])}),
+                    **({} if attn is None or len(attn) < 5 else
+                       {"mla_attended": int(attn[3]),
+                        "mla_pairs": int(attn[4])}))
         return max(1, emitted)
 
     def _commit_window(self, meta, ids, picked, S, t0, end, shared_sid,

@@ -41,7 +41,7 @@ def default_prefix_budget_bytes():
 
 
 class _Entry:
-    __slots__ = ("tokens", "payloads", "nbytes", "hits")
+    __slots__ = ("tokens", "payloads", "nbytes", "hits", "mismatches")
 
     def __init__(self, tokens, payloads):
         self.tokens = np.asarray(tokens, np.int64).reshape(-1)
@@ -50,6 +50,7 @@ class _Entry:
             arr.nbytes for rows in payloads.values()
             for arr in rows.values())
         self.hits = 0
+        self.mismatches = 0
 
 
 class PrefixStore:
@@ -72,21 +73,26 @@ class PrefixStore:
     def used_bytes(self):
         return sum(e.nbytes for e in self._entries.values())
 
-    def lookup(self, prefix_id, prompt, tags=()):
+    def lookup(self, prefix_id, prompt, tags=(), least=1):
         """Hit test for one admission: returns ``(C, entry)`` — the
-        usable cursor (capped at ``len(prompt) - 1`` so the join always
-        has at least one token left to feed, which the first dispatch
-        samples from) — or ``(0, None)`` on miss. ``tags`` names the
-        engine payloads the caller needs (e.g. the draft engine's rows
-        when speculation is armed): an entry missing one is a miss, not
-        a half-join."""
+        usable cursor: the length of the longest head that ``prompt``
+        and the stored tokens share (capped at ``len(prompt) - 1`` so
+        the join always has at least one token left to feed, which the
+        first dispatch samples from) — or ``(0, None)`` on miss: no
+        entry, or a common head shorter than ``least``. ``tags`` names
+        the engine payloads the caller needs (e.g. the draft engine's
+        rows when speculation is armed): an entry missing one is a
+        miss, not a half-join."""
         entry = self._entries.get(prefix_id)
         if entry is None:
             self.misses += 1
             return 0, None
         prompt = np.asarray(prompt, np.int64).reshape(-1)
-        c = min(entry.tokens.shape[0], prompt.shape[0] - 1)
-        if c < 1 or not np.array_equal(entry.tokens[:c], prompt[:c]):
+        n = min(entry.tokens.shape[0], prompt.shape[0] - 1)
+        differ = np.flatnonzero(entry.tokens[:n] != prompt[:n])
+        c = int(differ[0]) if differ.size else n
+        if c < max(1, least):
+            entry.mismatches += 1
             self.mismatches += 1
             self.misses += 1
             return 0, None
@@ -100,10 +106,15 @@ class PrefixStore:
 
     def put(self, prefix_id, tokens, payloads):
         """Store (or refresh) one prefix. Oversized entries are
-        dropped whole; otherwise LRU entries evict until the budget
-        holds. Returns True when stored."""
+        dropped whole, and an entry under the same id that has served
+        more prompts than it has failed stays (module docstring);
+        otherwise LRU entries evict until the budget holds. Returns
+        True when stored."""
         entry = _Entry(tokens, payloads)
         if self.budget_bytes <= 0 or entry.nbytes > self.budget_bytes:
+            return False
+        old = self._entries.get(prefix_id)
+        if old is not None and old.hits > old.mismatches:
             return False
         self._entries.pop(prefix_id, None)
         while self._entries and \

@@ -306,7 +306,8 @@ def test_grouped_window_attention_compiles_for_v5e_and_copies_no_pool(kind, S,
 
 
 @pytest.mark.parametrize("S", [1, 1024], ids=["decode", "window"])
-@pytest.mark.parametrize("op", ["dsa_index_select", "mla_attention_decode"])
+@pytest.mark.parametrize("op", ["dsa_index_select", "mla_attention_decode",
+                                "mla_attention_decode_unselected"])
 def test_latent_attention_compiles_for_v5e_and_copies_no_pool(op, S, v5e):
     """GLM-5.2's two decode ops at the published sizes (8 slots, a
     capacity of 32,768, bfloat16; 32 index heads of 128 over 128-wide
@@ -314,7 +315,11 @@ def test_latent_attention_compiles_for_v5e_and_copies_no_pool(op, S, v5e):
     Pallas lowerings compile for the chip, every pool access is a
     kernel's, and with the aux arrays donated the pool of either state
     family - 67 MB of index keys, 336 MB of latent rows - comes back in
-    the buffer it came in, neither copied nor re-laid."""
+    the buffer it came in, neither copied nor re-laid. And A.X-K1's
+    ``mla_attention_decode`` at its published sizes (64 heads of 128 +
+    64 with values of 128, YaRN of factor 32): no selection, so no mask
+    operand of 268 MB a window - the kernels take the pool, the queries
+    and two cursors."""
     import re
     B, C = 8, 32768
     bf16 = jnp.bfloat16
@@ -322,8 +327,20 @@ def test_latent_attention_compiles_for_v5e_and_copies_no_pool(op, S, v5e):
     def sds(shape, dtype=bf16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
-    opdef = get_op(op)
-    if op == "dsa_index_select":
+    opdef = get_op(op.replace("_unselected", ""))
+    if op == "mla_attention_decode_unselected":
+        attrs = dict(capacity=C, n_heads=64, nope_dim=128, rope_dim=64,
+                     v_dim=128, kv_rank=512, rope_base=1e4, rms_eps=1e-6,
+                     selected=False, rope_factor=32.0,
+                     rope_original_positions=4096, rope_beta_fast=32.0,
+                     rope_beta_slow=1.0, rope_mscale=1.0,
+                     rope_mscale_all_dim=1.0)
+        ins = [sds((B, S, 64 * 192)), sds((B, S, 576)),
+               sds((B,), jnp.int32), sds((512,)), sds((64 * 256, 512))]
+        width = 640
+        kernels = ("mla_write",
+                   "mla_attn_decode" if S == 1 else "mla_attn_window")
+    elif op == "dsa_index_select":
         attrs = dict(capacity=C, n_heads=32, head_dim=128, rope_dim=64,
                      topk=2048, rope_base=8e6)
         ins = [sds((B, S, 32 * 128)), sds((B, S, 128)), sds((B, S, 32)),
@@ -358,6 +375,80 @@ def test_latent_attention_compiles_for_v5e_and_copies_no_pool(op, S, v5e):
     assert " scatter(" not in text
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         B * C * width * 2
+    if op.endswith("_unselected"):
+        assert "s8[" not in text            # no mask anywhere
+
+
+@pytest.mark.parametrize("S", [1, 1024], ids=["decode", "window"])
+def test_group_limited_share_compiles_for_v5e_with_the_grouped_kernels(S, v5e):
+    """A.X-K1's ``MoEFFN`` at the published sizes (8 slots, rows of
+    7,168, 192 experts in 8 groups, 12 held of width 2,048 beside a
+    shared expert): rows of 7,168 are inside the grouped kernels' widths,
+    so the Pallas lowering is eligible and both ``moe_gmm_*`` kernels
+    compile for the chip."""
+    import re
+    B, D, F, E, held = 8, 7168, 2048, 192, 12
+    opdef = get_op("MoEFFN")
+    attrs = opdef.normalize_attrs(dict(
+        num_experts=E, num_hidden=F, top_k=8, norm_topk=True,
+        scoring="sigmoid", scaling=2.5, held_first=0, held_count=held,
+        shared_hidden=F, step_len=S, n_group=8, topk_group=4))
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ins = [sds((B * S, D)), sds((B,), jnp.int32), sds((E, D)),
+           sds((held, D, F)), sds((held, D, F)), sds((held, F, D)),
+           sds((D, F)), sds((D, F)), sds((F, D))]
+    assert opdef.input_names(attrs) == [
+        "data", "fed", "router_weight", "gate_weight", "up_weight",
+        "down_weight", "shared_gate_weight", "shared_up_weight",
+        "shared_down_weight"]
+    aux = [sds((5,), jnp.int32)]
+    assert opdef.variant_eligible("pallas", attrs,
+                                  [a.shape for a in ins + aux],
+                                  [str(a.dtype) for a in ins + aux])
+    fn = opdef.variant_fn("pallas")
+    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None)) \
+        .lower(ins, aux).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_gmm_gate_up", "moe_gmm_down"):
+        assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
+
+
+def test_a_prefix_join_compiles_for_v5e_and_copies_no_pool(v5e):
+    """``BatchedKVCacheDecoder``'s row programs at A.X-K1's sizes (five
+    latent pools of 8 x 32,768 rows of 640 lanes, 1,024 rows a launch):
+    ``restore_rows`` takes the pools over and hands every one back in
+    its buffer - a dynamic-update-slice in place, no copy of 335 MB -
+    and ``capture_rows`` reads 1,024 rows of one slot, not a pool."""
+    import re
+    from mxnet_tpu.models.transformer import row_blocks, row_programs
+    B, C, W, L, block = 8, 32768, 640, 5, 1024
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    assert list(row_blocks(16384 + 5, block, C)) == [
+        (i * block, 0, block) for i in range(16)] + [(16384, 0, 5)]
+    assert list(row_blocks(C, block, C))[-1] == (C - block, 0, block)
+    capture, restore = row_programs(B, block, [v5e] * L)
+    pools = tuple(sds((B, 1, C, W)) for _ in range(L))
+    rows = tuple(sds((1, block, W)) for _ in range(L))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e)
+    compiled = restore.lower(pools, rows, scalar, scalar).compile()
+    text = compiled.as_text()
+    pool = rf"= bf16\[{B},1,{C},{W}\]\S* "
+    assert not re.findall(pool + r"copy\(", text)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= L * B * C * W * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    compiled = capture.lower(pools, scalar, scalar).compile()
+    assert not re.findall(pool + r"copy\(", compiled.as_text())
+    assert L * block * W * 2 \
+        <= compiled.memory_analysis().output_size_in_bytes \
+        < L * block * W * 2 + 4096
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 # ------------------------------------------- chip_smoke.py without a chip
