@@ -26,7 +26,13 @@ expands ``k_n`` and ``v`` of the whole pool. The ``pallas`` variant
 absorbs ``W_kvb``: ``q_n W_kb`` is scored against ``c_kv`` itself, the
 weighted sum of ``c_kv`` goes through ``W_vb`` afterwards, and one
 flash-style kernel (``mla_attn_decode`` at S = 1, ``mla_attn_window``
-beyond) walks the live blocks of the pool once for all heads.
+beyond) walks the live blocks of the pool once for all heads. The
+kernel has two geometries: at S = 1 the 64 heads of a slot's one query
+are the rows of one product a key block; in a window a head's 256
+positions are. A slot that a window feeds ONE row (a decoding slot
+riding another's prefill: most slots of most windows) would be a block
+of 255 pads in the second, so it is dead there and takes the first,
+inside the window program (``mla_attn_ride``); ``fed`` says which.
 
 **The selection.** ``S_t`` arrives as ``selection (slots, S, capacity)``
 int8, 1 where position j is attended by query t - an input, because it
@@ -589,18 +595,23 @@ def _mla_infer(attrs, in_shapes):
             [(B, 1, capacity, latent_width(rank, dr)), (B, 1)])
 
 
-def _mla_attn_kernel(hg, R, bk, rank, scale, window, selected):
+def _mla_attn_kernel(hg, R, bk, rank, scale, form, selected):
     """Grid (slot, head group, query block, key block): online softmax
     of ``hg`` groups of ``R`` query rows against a block of latent rows
-    under the selection. In a window a group is a head and its rows
-    are ``R`` positions, masked row by row, and a block whose positions
-    are all past ``fed`` (pads) comes out zero without a product; at
-    S = 1 the one group's rows are the heads, which share the query's
-    mask row. Without a selection (``selected`` False: no mask operand)
-    a row attends the keys at or before its own position, told from the
-    cursor: a block that lies wholly before the query block's first
-    position takes no mask at all, and since key 0 is at or before
-    every query no row's running maximum is ever infinite."""
+    under the selection. In the ``"window"`` form a group is a head and
+    its rows are ``R`` positions, masked row by row, and a block whose
+    positions are all past ``fed`` (pads) comes out zero without a
+    product. In the ``"decode"`` form (S = 1) the one group's rows are
+    the heads, which share the query's mask row; the ``"ride"`` form is
+    the same for the one query of a slot that a window feeds a single
+    row, live where ``fed`` is 1 and zero elsewhere. Without a
+    selection (``selected`` False: no mask operand) a row attends the
+    keys at or before its own position, told from the cursor: a block
+    that lies wholly before the query block's first position takes no
+    mask at all, and since key 0 is at or before every query no row's
+    running maximum is ever infinite."""
+    window = form == "window"
+
     def attend(q_ref, k_ref, mask, m_s, l_s, acc_s):
         k = k_ref[...]
         c = k[:, :rank]
@@ -632,7 +643,10 @@ def _mla_attn_kernel(hg, R, bk, rank, scale, window, selected):
         b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
         first = p_ref[b] + (i * R if window else 0)
         last = first + (R - 1 if window else 0)
-        fed = (i * R < fed_ref[b]) if window else True
+        if window:
+            fed = i * R < fed_ref[b]
+        else:
+            fed = fed_ref[b] == 1 if form == "ride" else True
         live = (j * bk <= last) & fed
 
         @pl.when(j == 0)
@@ -668,25 +682,19 @@ def _mla_attn_kernel(hg, R, bk, rank, scale, window, selected):
     return kernel
 
 
-@partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
-def _mla_attend(p, fed, q, pool, sel, rank, scale, interpret):
-    """The kernel ``mla_attn_decode`` / ``mla_attn_window``: queries
-    ``q (B, H, S, rank + rope_dim)`` in the latent space against the
-    pool under ``sel`` (None: every position at or before the query, no
-    mask operand) -> the weighted sums of ``c_kv``, (B, H, S, rank) at
-    ``q``'s dtype. Blocks past a query block's last position are
-    neither fetched nor computed."""
+def _mla_launch(p, fed, q, keys, sel, rank, scale, interpret, form):
+    """One launch of the attention kernel in one of its forms
+    (``_mla_attn_kernel``) over ``q (B, H, S, rank + rope_dim)`` ->
+    (B, H, S, rank); ``"decode"`` and ``"ride"`` take S = 1."""
     B, H, S, dq = q.shape
-    C = pool.shape[2]
-    keys = pool.reshape(B, C, dq)
-    if S == 1:
-        q = q.reshape(B, 1, H, dq)
-        G, hg, R, bk = 1, 1, H, _pk._divisor_block(C, 2048)
-    else:
+    C = keys.shape[1]
+    window = form == "window"
+    if window:
         G, hg = H, _pk._divisor_block(H, 16)
         R, bk = _pk._divisor_block(S, 256), _pk._divisor_block(C, 512)
-    n_q = 1 if S == 1 else S // R
-    window = S > 1
+    else:
+        q = q.reshape(B, 1, H, dq)
+        G, hg, R, bk = 1, 1, H, _pk._divisor_block(C, 2048)
     selected = sel is not None
 
     def q_map(b, g, i, j, p_ref, fed_ref):
@@ -697,6 +705,8 @@ def _mla_attend(p, fed, q, pool, sel, rank, scale, interpret):
         last = p_ref[b] + ((i + 1) * R - 1 if window else 0)
         if window:
             last = jnp.where(i * R < fed_ref[b], last, 0)
+        elif form == "ride":
+            last = jnp.where(fed_ref[b] == 1, last, 0)
         return last // bk
 
     def k_map(b, g, i, j, p_ref, fed_ref):
@@ -711,21 +721,52 @@ def _mla_attend(p, fed, q, pool, sel, rank, scale, interpret):
         in_specs.append(pl.BlockSpec((None, R if window else 1, bk),
                                      sel_map))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B, G // hg, n_q, C // bk),
+        num_scalar_prefetch=2,
+        grid=(B, G // hg, S // R if window else 1, C // bk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, hg, R, rank), q_map),
         scratch_shapes=[pltpu.VMEM((hg, R, 1), _F32),
                         pltpu.VMEM((hg, R, 1), _F32),
                         pltpu.VMEM((hg, R, rank), _F32)])
     out = _pk.pallas_call(
-        _mla_attn_kernel(hg, R, bk, rank, scale, window, selected),
-        name="mla_attn_window" if window else "mla_attn_decode",
+        _mla_attn_kernel(hg, R, bk, rank, scale, form, selected),
+        name="mla_attn_" + form,
         out_shape=jax.ShapeDtypeStruct(q.shape[:3] + (rank,), q.dtype),
         grid_spec=grid_spec, interpret=interpret,
         **_compiler_params(("parallel", "parallel", "parallel",
                             "arbitrary")))(
             p, fed, q, keys, *((sel,) if selected else ()))
     return out.reshape(B, H, S, rank)
+
+
+@partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def _mla_attend(p, fed, q, q_ride, pool, sel, rank, scale, interpret):
+    """The kernels ``mla_attn_decode`` (S = 1) and ``mla_attn_window``
+    with ``mla_attn_ride`` (beyond): queries ``q (B, H, S, rank +
+    rope_dim)`` in the latent space against the pool under ``sel``
+    (None: every position at or before the query, no mask operand) ->
+    the weighted sums of ``c_kv``, (B, H, S, rank) at ``q``'s dtype.
+    Blocks past a query block's last position are neither fetched nor
+    computed. Which form a slot of a window takes is read from ``fed``:
+    a slot fed one row (a decoding slot riding a prefill window) is
+    dead to the window form, whose query block of 256 positions would
+    hold 255 pads, and its one query - ``q_ride (B, H, 1, .)``, row 0
+    of ``q``, handed over apart because a slice of ``q`` itself makes
+    the compiler lay all of ``q`` out for the slice and copy it for the
+    window - goes through the decode form, the heads as the rows of one
+    group; the window's zeros in that slot's row 0 take the result, in
+    place."""
+    B, H, S, dq = q.shape
+    launch = partial(_mla_launch, p, keys=pool.reshape(B, pool.shape[2], dq),
+                     rank=rank, scale=scale, interpret=interpret)
+    if S == 1:
+        return launch(fed, q, sel=sel, form="decode")
+    riding = fed == 1
+    out = launch(jnp.where(riding, 0, fed), q, sel=sel, form="window")
+    ride = launch(fed, q_ride, sel=None if sel is None else sel[:, :1],
+                  form="ride")
+    row = jnp.where(riding[:, None, None, None], ride, out[:, :, :1])
+    return lax.dynamic_update_slice(out, row, (0, 0, 0, 0))
 
 
 def _mla_pallas(attrs, inputs, aux, is_train, rng):
@@ -739,12 +780,17 @@ def _mla_pallas(attrs, inputs, aux, is_train, rng):
     B, S, H, _ = q_n.shape
     rank = w_kb.shape[-1]
     dtype = q_n.dtype
-    q_c = _mm("bshn,hnc->bhsc", q_n, w_kb.astype(dtype))
-    q = _lanes(jnp.concatenate(
-        [q_c.astype(pool.dtype),
-         q_r.transpose(0, 2, 1, 3).astype(pool.dtype)], axis=-1),
-        pool.shape[-1])
-    o_c = _mla_attend(p, new_cursor.reshape(-1) - p, q, pool, sel,
+
+    def latent(q_n, q_r):
+        q_c = _mm("bshn,hnc->bhsc", q_n, w_kb.astype(dtype))
+        return _lanes(jnp.concatenate(
+            [q_c.astype(pool.dtype),
+             q_r.transpose(0, 2, 1, 3).astype(pool.dtype)], axis=-1),
+            pool.shape[-1])
+
+    q = latent(q_n, q_r)
+    q_ride = latent(q_n[:, :1], q_r[:, :1]) if S > 1 else None
+    o_c = _mla_attend(p, new_cursor.reshape(-1) - p, q, q_ride, pool, sel,
                       rank=rank, scale=scale, interpret=interpret)
     out = _mm("bhsc,hvc->bshv", o_c.astype(dtype), w_vb.astype(dtype))
     return [out.reshape(B, S, -1).astype(dtype)], [pool, new_cursor]
@@ -769,7 +815,9 @@ def _mla_eligible(attrs, in_shapes, in_dtypes):
 MLA_SLOT_STATE = {"latent": "rows", "cache_pos": "cursor"}
 
 #: one head's blocks at the published sizes (256 queries, latent rows
-#: of 576 in 640 lanes, a key block of 512, scores and accumulator)
+#: of 576 in 640 lanes, a key block of 512, scores and accumulator);
+#: the riding form's blocks are the S = 1 program's (64 heads as rows, a
+#: key block of 2,048), which fit the same budget
 _MLA_KSPEC = {
     "tiles": [((256, 640), "bfloat16")] * 2
     + [((512, 640), "bfloat16")] * 2 + [((256, 512), "bfloat16")] * 2

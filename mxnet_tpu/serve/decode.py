@@ -737,6 +737,14 @@ _ATTN_COUNTERS = ("attn.live_rows", "attn.capacity_rows",
                   "attn.attended_rows")
 
 
+#: ``serve.decode.<name>`` counters of the S > 1 window dispatches, from
+#: the plan (no fetch): the slots fed at least one row, and those fed
+#: exactly one - a decoding slot riding a window in which another
+#: prefills, its other S - 1 rows pads. riding / fed is the traffic's,
+#: whatever kernel serves it
+_WINDOW_COUNTERS = ("window.fed_slots", "window.riding_slots")
+
+
 class DecodeScheduler:
     """Iteration-level continuous batching over one ``DecodeEngine``.
 
@@ -885,7 +893,8 @@ class DecodeScheduler:
         if self._iter_handles is None or self._iter_handles[0] != gen:
             handles = {k: self._counter(k) for k in
                        ("iterations", "tokens", "prefill.chunks",
-                        "fetch.bytes", "sample.device", "sample.host")}
+                        "fetch.bytes", "sample.device", "sample.host")
+                       + _WINDOW_COUNTERS}
             if self.engine.driver(self._rung).routed:
                 handles.update({k: self._counter(k)
                                 for k in _MOE_COUNTERS})
@@ -1398,6 +1407,10 @@ class DecodeScheduler:
                 if chunks:
                     m["prefill.chunks"].inc(chunks)
                 m["fetch.bytes"].inc(phases["bytes"])
+                if S > 1 and mode != "spec":
+                    rows = [n for _row, _seq, n in meta]
+                    m["window.fed_slots"].inc(sum(n >= 1 for n in rows))
+                    m["window.riding_slots"].inc(sum(n == 1 for n in rows))
                 # what the dispatches counted: a routed decoder's
                 # experts, a window-and-summaries state's reads, a
                 # learned selection's

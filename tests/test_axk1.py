@@ -28,6 +28,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from chipbench.reference import axk1 as ref  # noqa: E402
+import mla_window_cases  # noqa: E402
 # the quick cases of the benchmark's own tests of the architecture file
 # run here as they stand (its CPU rehearsals stay by hand)
 from chipbench.tests.test_axk1 import (  # noqa: E402,F401
@@ -309,6 +310,20 @@ def test_the_unselected_kernel_equals_the_composition_and_an_all_ones_selection(
             rtol=0 if variant == "xla" else tol / 10)
 
 
+@pytest.mark.parametrize("case", sorted(mla_window_cases.CASES))
+def test_a_window_attends_a_slot_fed_one_row_as_the_s1_program_does(case):
+    """No selection, YaRN: a window whose slots are fed a whole window,
+    one row, none and a ragged few (``tests/mla_window_cases.py``)
+    equals the expanded form at every fed position; the row of a slot
+    fed one - attended heads-as-rows by ``mla_attn_ride`` - is the S = 1
+    dispatch's to the bit, at cursors inside, at the end of and past a
+    key block; a window in which every slot rides and one in which none
+    does."""
+    riding = mla_window_cases.check(case, selected=False, **_YARN_ATTRS)
+    assert len(riding) == {"mixed": 3, "all_riding": 6,
+                           "none_riding": 0}[case]
+
+
 def test_yarn_at_the_published_values():
     """``low`` / ``high`` 10 / 23, the blended frequencies and the
     softmax scale 0.130861 of A.X-K1's ``rope_scaling``; ``factor`` 1 is
@@ -567,5 +582,36 @@ def test_a_join_at_a_common_head_is_a_cold_prefill_and_compiles_once():
         for r in spans:
             assert r["cursor"] == 24 and r["dur_us"] > 0
             assert r["bytes"] == 3 * 3 * 8 * mla.latent_width(64, 16) * 4
+    finally:
+        _restore(old)
+
+
+def test_the_scheduler_counts_the_slots_a_window_feeds_one_row():
+    """A request decodes while another prefills 20 tokens in windows of
+    8: three windows feed the decoding slot one row each - the riding
+    form of the kernel, interpreted - and ``serve.decode.window.*``
+    count them from the plan; its answer is the one it gives alone."""
+    from mxnet_tpu import telemetry
+    old = _tier("pallas")
+    try:
+        rng = np.random.default_rng(21)
+        short, long_ = (rng.integers(0, CFG["vocab_size"], n)
+                        .astype(np.int32) for n in (3, 20))
+        server = _tiny_server("axk1-tiny-ride", 0)
+        riding = server.submit(short, max_new_tokens=10)
+        assert server.pump(max_iterations=2) == 2   # a window of 3, a step
+        server.submit(long_, max_new_tokens=2)
+        server.pump()
+        counters = {m.name: m.value for m in telemetry.metrics.all_metrics()
+                    if isinstance(m, telemetry.Counter)
+                    and ("model", "axk1-tiny-ride") in m.labels}
+        # windows feed (3), (1, 8), (1, 8), (1, 4) rows; the rest are steps
+        assert counters["serve.decode.window.fed_slots"] == 7
+        assert counters["serve.decode.window.riding_slots"] == 3
+        assert counters["serve.decode.prefill.chunks"] == 4
+        alone = server.submit(short, max_new_tokens=10)
+        server.pump()
+        assert list(riding.result(timeout=60)) \
+            == list(alone.result(timeout=60))
     finally:
         _restore(old)

@@ -305,6 +305,26 @@ def test_grouped_window_attention_compiles_for_v5e_and_copies_no_pool(kind, S,
         2 * B * Hkv * rows * d * 2
 
 
+def _mla_published(selected, B, C, S, sds):
+    """``mla_attention_decode``'s attributes and inputs at the published
+    sizes: GLM-5.2's under a selection, A.X-K1's (YaRN of factor 32,
+    values of 128) without one."""
+    if selected:
+        return (dict(capacity=C, n_heads=64, nope_dim=192, rope_dim=64,
+                     v_dim=256, kv_rank=512, rope_base=8e6),
+                [sds((B, S, 64 * 256)), sds((B, S, 576)),
+                 sds((B, S, C), jnp.int8), sds((B,), jnp.int32),
+                 sds((512,)), sds((64 * 448, 512))])
+    return (dict(capacity=C, n_heads=64, nope_dim=128, rope_dim=64,
+                 v_dim=128, kv_rank=512, rope_base=1e4, rms_eps=1e-6,
+                 selected=False, rope_factor=32.0,
+                 rope_original_positions=4096, rope_beta_fast=32.0,
+                 rope_beta_slow=1.0, rope_mscale=1.0,
+                 rope_mscale_all_dim=1.0),
+            [sds((B, S, 64 * 192)), sds((B, S, 576)),
+             sds((B,), jnp.int32), sds((512,)), sds((64 * 256, 512))])
+
+
 @pytest.mark.parametrize("S", [1, 1024], ids=["decode", "window"])
 @pytest.mark.parametrize("op", ["dsa_index_select", "mla_attention_decode",
                                 "mla_attention_decode_unselected"])
@@ -328,33 +348,18 @@ def test_latent_attention_compiles_for_v5e_and_copies_no_pool(op, S, v5e):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
     opdef = get_op(op.replace("_unselected", ""))
-    if op == "mla_attention_decode_unselected":
-        attrs = dict(capacity=C, n_heads=64, nope_dim=128, rope_dim=64,
-                     v_dim=128, kv_rank=512, rope_base=1e4, rms_eps=1e-6,
-                     selected=False, rope_factor=32.0,
-                     rope_original_positions=4096, rope_beta_fast=32.0,
-                     rope_beta_slow=1.0, rope_mscale=1.0,
-                     rope_mscale_all_dim=1.0)
-        ins = [sds((B, S, 64 * 192)), sds((B, S, 576)),
-               sds((B,), jnp.int32), sds((512,)), sds((64 * 256, 512))]
-        width = 640
-        kernels = ("mla_write",
-                   "mla_attn_decode" if S == 1 else "mla_attn_window")
-    elif op == "dsa_index_select":
+    if op == "dsa_index_select":
         attrs = dict(capacity=C, n_heads=32, head_dim=128, rope_dim=64,
                      topk=2048, rope_base=8e6)
         ins = [sds((B, S, 32 * 128)), sds((B, S, 128)), sds((B, S, 32)),
                sds((B,), jnp.int32)]
         width, kernels = 128, ("dsa_write", "dsa_index_scores", "dsa_topk")
     else:
-        attrs = dict(capacity=C, n_heads=64, nope_dim=192, rope_dim=64,
-                     v_dim=256, kv_rank=512, rope_base=8e6)
-        ins = [sds((B, S, 64 * 256)), sds((B, S, 576)),
-               sds((B, S, C), jnp.int8), sds((B,), jnp.int32), sds((512,)),
-               sds((64 * 448, 512))]
+        attrs, ins = _mla_published(not op.endswith("_unselected"),
+                                    B, C, S, sds)
         width = 640
-        kernels = ("mla_write",
-                   "mla_attn_decode" if S == 1 else "mla_attn_window")
+        kernels = ("mla_write",) + (("mla_attn_decode",) if S == 1 else
+                                    ("mla_attn_window", "mla_attn_ride"))
     attrs = opdef.normalize_attrs(attrs)
     aux = [sds((B, 1, C, width)), sds((B, 1), jnp.int32)]
     assert opdef.donate_aux and set(opdef.slot_state.values()) == {
@@ -377,6 +382,46 @@ def test_latent_attention_compiles_for_v5e_and_copies_no_pool(op, S, v5e):
         B * C * width * 2
     if op.endswith("_unselected"):
         assert "s8[" not in text            # no mask anywhere
+    if "mla_attn_ride" in kernels:
+        # the riding slots' rows go into the window's result where it
+        # lies, and the window's queries (671 MB) are not laid out anew
+        # for the one row the riding pass takes of them
+        assert re.search(r"dynamic-update-slice\(%mla_attn_window", text)
+        assert not re.findall(rf"= bf16\[{B},64,{S},640\]\S* copy\(", text)
+        assert not re.findall(rf"^\s*%\S+ = bf16\[{B},64,{S},512\]\S* "
+                              r"copy\(", text.split("ENTRY")[1], re.M)
+
+
+#: the first 16 hex digits of the sha256 of the S = 1 lowering of
+#: ``mla_attention_decode`` at the published sizes, Mosaic bodies without
+#: their source locations: the parent's (aec2c22), computed there
+_PARENT_MLA_S1_SHA256 = {
+    "selected": "c1ceba25c83b862c",
+    "unselected": "7ecaf31ef216901f",
+}
+
+
+@pytest.mark.parametrize("lowering", sorted(_PARENT_MLA_S1_SHA256))
+def test_the_s1_latent_programs_lower_to_the_parents_text(lowering, v5e):
+    """A slot fed one row in a window takes the S = 1 form of the
+    kernel; the S = 1 programs themselves - GLM-5.2's under a selection,
+    A.X-K1's without - lower to the text they had."""
+    import hashlib
+    B, C, S = 8, 32768, 1
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    opdef = get_op("mla_attention_decode")
+    attrs, ins = _mla_published(lowering == "selected", B, C, S, sds)
+    attrs = opdef.normalize_attrs(attrs)
+    aux = [sds((B, 1, C, 640)), sds((B, 1), jnp.int32)]
+    fn = opdef.variant_fn("pallas")
+    text = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
+                   donate_argnums=(1,)).lower(ins, aux).as_text()
+    digest = hashlib.sha256(
+        _text_without_locations(text).encode()).hexdigest()
+    assert digest[:16] == _PARENT_MLA_S1_SHA256[lowering]
 
 
 @pytest.mark.parametrize("S", [1, 1024], ids=["decode", "window"])

@@ -52,6 +52,9 @@ def _clean_fleet(monkeypatch):
     endpoint, and leaves nothing behind for the rest of the suite."""
     for var in _FLEET_ENV:
         monkeypatch.delenv(var, raising=False)
+    # a dist kvstore that an earlier file of this worker left open is an
+    # identity source too (xdist decides which files share a process)
+    monkeypatch.setattr(fleet, "_kv_ref", None)
     fleet.configure()
     mx.telemetry.reset()
     yield

@@ -29,6 +29,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from chipbench.reference import glm_dsa as ref  # noqa: E402
+import mla_window_cases  # noqa: E402
 # the quick cases of the benchmark's own tests of the architecture file
 # run here as they stand (its CPU rehearsals stay by hand)
 from chipbench.tests.test_glm_dsa import (  # noqa: E402,F401
@@ -279,6 +280,19 @@ def test_absorbed_kernels_equal_the_expanded_composition(S, dtype):
                                atol=tol, rtol=tol)
     np.testing.assert_array_equal(np.asarray(aux[0], np.float32),
                                   np.asarray(aux_k[0], np.float32))
+
+
+@pytest.mark.parametrize("case", sorted(mla_window_cases.CASES))
+def test_a_window_attends_a_slot_fed_one_row_as_the_s1_program_does(case):
+    """Under a selection: a window whose slots are fed a whole window,
+    one row, none and a ragged few (``tests/mla_window_cases.py``)
+    equals the expanded form at every fed position; the row of a slot
+    fed one - ``mla_attn_ride`` under row 0 of the selection - is the
+    S = 1 dispatch's to the bit; a window in which every slot rides and
+    one in which none does."""
+    riding = mla_window_cases.check(case, selected=True, rope_base=8e6)
+    assert len(riding) == {"mixed": 3, "all_riding": 6,
+                           "none_riding": 0}[case]
 
 
 def test_a_shared_layer_attends_the_set_its_full_layer_chose():
