@@ -67,18 +67,46 @@ def _proj(x, num_hidden, name, no_bias=False):
                               no_bias=no_bias)
 
 
-def _qkv_heads(qkv, j, nm, pfx, T, n_head, d_model, norm=None):
+def _qkv_heads(qkv, j, nm, pfx, split, d_model, norm=None):
     """Row block ``j`` of a fused (B*T, 3D) q/k/v projection as
     (B, H, T, dh) heads, ``norm`` applied to the whole projection
-    before the split."""
+    before ``split(rows, name)`` lays it out as (B, T, H, dh)."""
     rows = sym.slice_axis(qkv, axis=1, begin=j * d_model,
                           end=(j + 1) * d_model, name=f"{pfx}_{nm}_rows")
     if norm is not None:
         rows = norm(rows, f"{pfx}_{nm}_norm")
-    rows = sym.Reshape(rows, shape=(-1, T, n_head, d_model // n_head),
-                       name=f"{pfx}_{nm}_split")
-    return sym.transpose(rows, axes=(0, 2, 1, 3),
+    return sym.transpose(split(rows, f"{pfx}_{nm}_split"), axes=(0, 2, 1, 3),
                          name=f"{pfx}_{nm}")                 # (B, H, T, dh)
+
+
+def _slots(rows, fed, T, name, shape=None):
+    """The rows of a fed window graph as ``(slots, T, ...)``, where
+    attention needs a slot's rows side by side (``ops/rows.py``; the
+    trailing dimensions as ``shape`` where given)."""
+    return sym.unpack_rows(rows, fed, step_len=T, name=name,
+                           **({"shape": shape} if shape else {}))
+
+
+def _packed_rows(x, fed, name, fold=(-3, 0)):
+    """Attention's ``(slots, T, ...)`` back among the rows of a fed
+    window graph, folded to ``(rows, width)`` by ``Reshape``'s ``fold``."""
+    return sym.Reshape(sym.pack_rows(x, fed, name=f"{name}_pack")[0],
+                       shape=fold, name=name)
+
+
+def _fed_inputs(vocab_size, d_model, name, **embed):
+    """The head of a fed graph: ``(x, fed, fed_rows)`` - the embedded
+    tokens in the view the row-wise operations run in (``ops/rows.py``:
+    ``(slots, S, D)``, or one block of the real rows under a budget),
+    ``fed`` as the decode ops take it and as that view's ``MoEFFN``
+    does."""
+    fed = sym.var("fed")
+    packed = sym.pack_rows(sym.var("data"), fed, name=f"{name}_rows")
+    x = sym.Embedding(data=packed[0],
+                      weight=sym.var(f"{name}_tok_embed_weight"),
+                      input_dim=vocab_size, output_dim=d_model,
+                      name=f"{name}_tok_embed", **embed)
+    return x, fed, packed[1]
 
 
 def _block(x, *, i, seq_len, d_model, n_head, dropout, pos_embed,
@@ -109,7 +137,9 @@ def _block(x, *, i, seq_len, d_model, n_head, dropout, pos_embed,
     qkv = _proj(ln1, 3 * d_model, f"{pfx}_qkv", no_bias=olmoe)  # (B*T, 3D)
     if olmoe:
         qk_norm = lambda rows, name: _norm(rows, name, moe)  # noqa: E731
-        q, k, v = (_qkv_heads(qkv, j, nm, pfx, T, n_head, d_model, norm)
+        split = lambda rows, name: sym.Reshape(              # noqa: E731
+            rows, shape=(-1, T, n_head, dh), name=name)
+        q, k, v = (_qkv_heads(qkv, j, nm, pfx, split, d_model, norm)
                    for j, (nm, norm) in enumerate(
                        (("q", qk_norm), ("k", qk_norm), ("v", None))))
     else:
@@ -187,25 +217,28 @@ def _eva_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
     the compute width, EVA attention with its state
     (``eva_attention_decode``: rotary inside the op, ``fed`` real
     tokens a slot) and a dense gated-SiLU feed-forward whose gate and
-    up projections are one matmul; no bias anywhere."""
+    up projections are one matmul; no bias anywhere. ``x`` is in the
+    packed view of the window's rows (``_glm_block``); q, k and v are
+    unpacked for the attention op and its result packed again."""
     pfx = f"{name}_l{i}"
     T = seq_len
 
     qkv = _proj(_eva_norm(x, f"{pfx}_ln1", eva), 3 * d_model,
                 f"{pfx}_qkv", no_bias=True)                  # (B*T, 3D)
 
-    q, k, v = (_qkv_heads(qkv, j, nm, pfx, T, n_head, d_model)
+    split = lambda rows, name: _slots(                       # noqa: E731
+        rows, fed, T, name, shape=(n_head, d_model // n_head))
+    q, k, v = (_qkv_heads(qkv, j, nm, pfx, split, d_model)
                for j, nm in enumerate("qkv"))
     att = sym.eva_attention_decode(
         q, k, v, fed, capacity=capacity,
         window=eva["window"], chunk=eva["chunk"], rope_base=rope_base,
         name=f"{pfx}_attn")
     att = sym.transpose(att, axes=(0, 2, 1, 3), name=f"{pfx}_attn_t")
-    att = sym.Reshape(att, shape=(-3, -3), name=f"{pfx}_attn_merge")
+    att = _packed_rows(att, fed, f"{pfx}_attn_merge", fold=(-3, -3))
     proj = sym.FullyConnected(att, num_hidden=d_model, no_bias=True,
                               name=f"{pfx}_proj")
-    proj = sym.Reshape(proj, shape=(-1, T, d_model),
-                       name=f"{pfx}_proj_unfold")
+    proj = sym.reshape_like(proj, x, name=f"{pfx}_proj_unfold")
     x = x + sym.Cast(proj, dtype="float32", name=f"{pfx}_proj_f32")
 
     rows = sym.Reshape(_eva_norm(x, f"{pfx}_ln2", eva), shape=(-3, 0),
@@ -215,7 +248,7 @@ def _eva_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
     h = sym.GatedSiLU(h, name=f"{pfx}_ffn_act")
     h = sym.FullyConnected(h, num_hidden=d_model, no_bias=True,
                            name=f"{pfx}_ffn_down")
-    h = sym.Reshape(h, shape=(-1, T, d_model), name=f"{pfx}_ffn_unfold")
+    h = sym.reshape_like(h, x, name=f"{pfx}_ffn_unfold")
     return x + sym.Cast(h, dtype="float32", name=f"{pfx}_ffn_f32")
 
 
@@ -293,7 +326,7 @@ def _glm_norm(x, name, glm):
     return sym.RMSNorm(x, eps=glm["rms_eps"], name=name)
 
 
-def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
+def _glm_block(x, fed, fed_rows, selection, *, i, seq_len, d_model, n_head,
                rope_base, name, capacity, glm):
     """One latent-attention block (``glm``: ``_glm_spec``; GLM-5.2's
     and A.X-K1's) of the slot-pooled decode graph, pre-norm, no bias
@@ -309,13 +342,18 @@ def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
     or sigmoid-routed experts beside a shared one (``MoEFFN``, the
     choice by ``glm["router"]``), of which this graph holds ``held``;
     the pads of a window (rows past ``fed``) are routed nowhere.
-    Returns ``(x, selection)``: the selection crosses layers outside
-    the residual stream."""
+    ``x`` and every row-wise operation are in the packed view of the
+    window's rows (``ops/rows.py``: all ``slots x S`` of them, or the
+    real ones under a budget, ``fed_rows`` their count as ``MoEFFN``
+    takes it); the operands of the two decode ops alone are laid out
+    ``(slots, S, .)`` (``_slots``) and attention's result is packed
+    again (``_packed_rows``). Returns ``(x, selection)``: the selection
+    crosses layers outside the residual stream."""
     pfx = f"{name}_l{i}"
     T = seq_len
     dq = glm["qk_nope_head_dim"] + glm["qk_rope_head_dim"]
-    unfold = lambda rows, n, nm: sym.Reshape(                # noqa: E731
-        rows, shape=(-1, T, n), name=f"{pfx}_{nm}_unfold")
+    unfold = lambda rows, nm: _slots(                        # noqa: E731
+        rows, fed, T, f"{pfx}_{nm}_unfold")
 
     rows = sym.Reshape(_glm_norm(x, f"{pfx}_ln1", glm), shape=(-3, 0),
                        name=f"{pfx}_attn_fold")              # (B*T, D)
@@ -339,15 +377,14 @@ def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
         w_idx = sym.FullyConnected(rows, num_hidden=n_idx, no_bias=True,
                                    name=f"{pfx}_idx_w")
         selection = sym.dsa_index_select(
-            unfold(q_idx, n_idx * d_idx, "idx_q"),
-            unfold(k_idx, d_idx, "idx_k"), unfold(w_idx, n_idx, "idx_w"),
+            unfold(q_idx, "idx_q"), unfold(k_idx, "idx_k"),
+            unfold(w_idx, "idx_w"),
             fed, capacity=capacity, n_heads=n_idx, head_dim=d_idx,
             rope_dim=glm["qk_rope_head_dim"], topk=glm["index_topk"],
             rope_base=rope_base, name=f"{pfx}_idx")
     dense = glm["indexer_types"][i] == "none"
     att = sym.mla_attention_decode(
-        unfold(q, n_head * dq, "q"),
-        unfold(kv, glm["kv_lora_rank"] + glm["qk_rope_head_dim"], "kv"),
+        unfold(q, "q"), unfold(kv, "kv"),
         *(() if dense else (selection,)), fed, capacity=capacity,
         n_heads=n_head, nope_dim=glm["qk_nope_head_dim"],
         rope_dim=glm["qk_rope_head_dim"], v_dim=glm["v_head_dim"],
@@ -355,10 +392,9 @@ def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
         rope_base=rope_base, name=f"{pfx}_attn",
         **({"selected": False} if dense else {}), **glm["rope"])
     proj = sym.FullyConnected(
-        sym.Reshape(att, shape=(-3, 0), name=f"{pfx}_attn_merge"),
+        _packed_rows(att, fed, f"{pfx}_attn_merge"),
         num_hidden=d_model, no_bias=True, name=f"{pfx}_proj")
-    x = x + sym.Reshape(proj, shape=(-1, T, d_model),
-                        name=f"{pfx}_proj_unfold")
+    x = x + sym.reshape_like(proj, x, name=f"{pfx}_proj_unfold")
 
     rows = sym.Reshape(_glm_norm(x, f"{pfx}_ln2", glm), shape=(-3, 0),
                        name=f"{pfx}_ffn_fold")
@@ -371,7 +407,8 @@ def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
     else:
         first, count = glm["held"]
         h = sym.MoEFFN(
-            rows, fed, step_len=T, num_experts=glm["n_routed_experts"],
+            rows, fed_rows, step_len=T,
+            num_experts=glm["n_routed_experts"],
             num_hidden=glm["moe_intermediate_size"],
             top_k=glm["num_experts_per_tok"],
             norm_topk=glm["norm_topk_prob"], scoring="sigmoid",
@@ -380,8 +417,7 @@ def _glm_block(x, fed, selection, *, i, seq_len, d_model, n_head,
             shared_hidden=glm["n_shared_experts"]
             * glm["moe_intermediate_size"], name=f"{pfx}_moe",
             **glm["router"])
-    h = sym.Reshape(h, shape=(-1, T, d_model), name=f"{pfx}_ffn_unfold")
-    return x + h, selection
+    return x + sym.reshape_like(h, x, name=f"{pfx}_ffn_unfold"), selection
 
 
 #: the keys of Trinity's published ``config.json`` (``model_type
@@ -428,8 +464,8 @@ def _afmoe_norm(x, name, afmoe):
     return sym.RMSNorm(x, eps=afmoe["rms_eps"], name=name)
 
 
-def _afmoe_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
-                 capacity, afmoe):
+def _afmoe_block(x, fed, fed_rows, *, i, seq_len, d_model, n_head,
+                 rope_base, name, capacity, afmoe):
     """One Trinity block (``afmoe``: ``_afmoe_spec``) of the slot-pooled
     decode graph, no bias anywhere: ``x = x + N(Attn(N(x)))``, then
     ``x = x + N(FF(N(x)))`` - each sub-layer's output is normed before
@@ -441,7 +477,10 @@ def _afmoe_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
     sigmoid of a projection of the layer's input. Then a dense
     gated-SiLU feed-forward (layers before ``num_dense_layers``) or
     sigmoid-routed experts beside a shared one (``MoEFFN``); the pads
-    of a window (rows past ``fed``) are routed nowhere."""
+    of a window (rows past ``fed``) are routed nowhere. ``x`` is in the
+    packed view of the window's rows (``_glm_block``); q, k and v are
+    unpacked before their per-head norms, where the S = 1 program's
+    text has them."""
     pfx = f"{name}_l{i}"
     T = seq_len
     n_kv, dh = afmoe["num_key_value_heads"], afmoe["head_dim"]
@@ -457,8 +496,7 @@ def _afmoe_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
         part = sym.slice_axis(wide, axis=1, begin=at, end=at + n * dh,
                               name=f"{pfx}_{nm}_rows")
         at += n * dh
-        part = sym.Reshape(part, shape=(-1, T, n, dh),
-                           name=f"{pfx}_{nm}_split")
+        part = _slots(part, fed, T, f"{pfx}_{nm}_split", shape=(n, dh))
         if nm != "v":                      # normed per head, over dh
             part = _afmoe_norm(part, f"{pfx}_{nm}_norm", afmoe)
         heads[nm] = sym.transpose(part, axes=(0, 2, 1, 3),
@@ -472,13 +510,12 @@ def _afmoe_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
         **({"window": afmoe["sliding_window"], "ring": afmoe["ring"]}
            if sliding else {}))
     att = sym.transpose(att, axes=(0, 2, 1, 3), name=f"{pfx}_attn_t")
-    att = sym.Reshape(att, shape=(-3, -3), name=f"{pfx}_attn_merge")
+    att = _packed_rows(att, fed, f"{pfx}_attn_merge", fold=(-3, -3))
     att = att * sym.Activation(gate, act_type="sigmoid",
                                name=f"{pfx}_gate")
     proj = sym.FullyConnected(att, num_hidden=d_model, no_bias=True,
                               name=f"{pfx}_proj")
-    proj = sym.Reshape(proj, shape=(-1, T, d_model),
-                       name=f"{pfx}_proj_unfold")
+    proj = sym.reshape_like(proj, x, name=f"{pfx}_proj_unfold")
     x = x + _afmoe_norm(proj, f"{pfx}_post_attn_ln", afmoe)
 
     rows = sym.Reshape(_afmoe_norm(x, f"{pfx}_ln2", afmoe), shape=(-3, 0),
@@ -492,14 +529,14 @@ def _afmoe_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
                                name=f"{pfx}_ffn_down")
     else:
         h = sym.MoEFFN(
-            rows, fed, step_len=T, num_experts=afmoe["num_experts"],
+            rows, fed_rows, step_len=T, num_experts=afmoe["num_experts"],
             num_hidden=afmoe["moe_intermediate_size"],
             top_k=afmoe["num_experts_per_tok"],
             norm_topk=afmoe["route_norm"], scoring="sigmoid",
             router_bias=True, scaling=afmoe["route_scale"],
             shared_hidden=afmoe["num_shared_experts"]
             * afmoe["moe_intermediate_size"], name=f"{pfx}_moe")
-    h = sym.Reshape(h, shape=(-1, T, d_model), name=f"{pfx}_ffn_unfold")
+    h = sym.reshape_like(h, x, name=f"{pfx}_ffn_unfold")
     return x + _afmoe_norm(h, f"{pfx}_post_ffn_ln", afmoe)
 
 
@@ -733,6 +770,13 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     row per position in every pool (``"rows"``), so the driver rewinds,
     captures and restores it as it does a K/V cache.
 
+    The graphs that take ``fed`` (this one, ``axk1``, ``afmoe``,
+    ``evabyte``) pass between the rows their row-wise operations run
+    over and ``(slots, step_len, .)`` through ``pack_rows`` /
+    ``unpack_rows`` (``ops/rows.py``), which keep all ``slots x
+    step_len`` rows as built here; ``packed_window`` derives the form
+    of a window graph that runs over a budget of real rows.
+
     ``block="axk1"`` (per-slot only) builds the same block without an
     indexer from ``axk1``, A.X-K1's published keys (``AXK1_KEYS``) and
     optionally ``held``: latent attention over every position at or
@@ -820,15 +864,11 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
 
 def _glm_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
                        capacity, S, name, glm):
-    data = sym.var("data")
-    fed = sym.var("fed")
-    x = sym.Embedding(data=data, weight=sym.var(f"{name}_tok_embed_weight"),
-                      input_dim=vocab_size, output_dim=d_model,
-                      name=f"{name}_tok_embed")              # (B, S, D)
+    x, fed, fed_rows = _fed_inputs(vocab_size, d_model, name)
     selection = None
     for i in range(n_layer):
         x, selection = _glm_block(
-            x, fed, selection, i=i, seq_len=S, d_model=d_model,
+            x, fed, fed_rows, selection, i=i, seq_len=S, d_model=d_model,
             n_head=n_head, rope_base=rope_base, name=name,
             capacity=capacity, glm=glm)
     flat = sym.Reshape(_glm_norm(x, f"{name}_ln_f", glm), shape=(-3, 0),
@@ -836,20 +876,15 @@ def _glm_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
     logits = sym.FullyConnected(
         flat, weight=sym.var(f"{name}_head_weight"), num_hidden=vocab_size,
         no_bias=True, name=f"{name}_logits")
-    return sym.Reshape(logits, shape=(-1, S, vocab_size),
-                       name=f"{name}_logits_bsv")
+    return _slots(logits, fed, S, f"{name}_logits_bsv")
 
 
 def _afmoe_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
                          capacity, S, name, afmoe, embed_scale):
-    data = sym.var("data")
-    fed = sym.var("fed")
     scale = {"scale": float(np.sqrt(d_model))} if embed_scale else {}
-    x = sym.Embedding(data=data, weight=sym.var(f"{name}_tok_embed_weight"),
-                      input_dim=vocab_size, output_dim=d_model,
-                      name=f"{name}_tok_embed", **scale)     # (B, S, D)
+    x, fed, fed_rows = _fed_inputs(vocab_size, d_model, name, **scale)
     for i in range(n_layer):
-        x = _afmoe_block(x, fed, i=i, seq_len=S, d_model=d_model,
+        x = _afmoe_block(x, fed, fed_rows, i=i, seq_len=S, d_model=d_model,
                          n_head=n_head, rope_base=rope_base, name=name,
                          capacity=capacity, afmoe=afmoe)
     flat = sym.Reshape(_afmoe_norm(x, f"{name}_ln_f", afmoe),
@@ -857,17 +892,12 @@ def _afmoe_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
     logits = sym.FullyConnected(
         flat, weight=sym.var(f"{name}_head_weight"), num_hidden=vocab_size,
         no_bias=True, name=f"{name}_logits")
-    return sym.Reshape(logits, shape=(-1, S, vocab_size),
-                       name=f"{name}_logits_bsv")
+    return _slots(logits, fed, S, f"{name}_logits_bsv")
 
 
 def _eva_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
                        capacity, S, name, eva, multibyte):
-    data = sym.var("data")
-    fed = sym.var("fed")
-    x = sym.Embedding(data=data, weight=sym.var(f"{name}_tok_embed_weight"),
-                      input_dim=vocab_size, output_dim=d_model,
-                      name=f"{name}_tok_embed")              # (B, S, D)
+    x, fed, _fed_rows = _fed_inputs(vocab_size, d_model, name)
     x = sym.Cast(x, dtype="float32", name=f"{name}_embed_f32")
     for i in range(n_layer):
         x = _eva_block(x, fed, i=i, seq_len=S, d_model=d_model,
@@ -881,12 +911,11 @@ def _eva_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
         num_hidden=n_pred * vocab_size, no_bias=True,
         name=f"{name}_logits")                               # (B*S, P*V)
     if multibyte:
-        return sym.Reshape(logits, shape=(-1, S, n_pred, vocab_size),
-                           name=f"{name}_logits_bspv")
+        return _slots(logits, fed, S, f"{name}_logits_bspv",
+                      shape=(n_pred, vocab_size))
     logits = sym.slice_axis(logits, axis=1, begin=0, end=vocab_size,
                             name=f"{name}_next_byte")
-    return sym.Reshape(logits, shape=(-1, S, vocab_size),
-                       name=f"{name}_logits_bsv")
+    return _slots(logits, fed, S, f"{name}_logits_bsv")
 
 
 class SyntheticLMIter:
@@ -1042,6 +1071,37 @@ def sparse_selection(symbol):
             topk[0])
 
 
+def packed_rows(slots, step_len):
+    """The row budget R of a packed window program: one slot's whole
+    prefill chunk and one token for every other slot, rounded up to the
+    matmuls' tile (128 rows; 8 at sizes under that)."""
+    rows = int(step_len) + int(slots)
+    unit = 128 if rows >= 128 else 8
+    return -(-rows // unit) * unit
+
+
+def packed_window(symbol, slots):
+    """``(graph, R)``: a fed window graph with its rows packed to the
+    budget ``R = packed_rows(slots, S)`` - a copy of ``symbol`` whose
+    ``pack_rows`` / ``unpack_rows`` nodes carry it (``ops/rows.py``), so
+    that every row-wise operation runs over R rows and not ``slots x
+    S``. None where the graph has no such nodes (a block without
+    ``fed``) or packing would not halve the rows (S = 1, rung 1)."""
+    steps = {int(n.attrs["step_len"]) for n in symbol._topo_nodes()
+             if n.op == "unpack_rows"}
+    if len(steps) != 1:
+        return None
+    step_len = steps.pop()
+    rows = packed_rows(slots, step_len)
+    if slots * step_len < 2 * rows:
+        return None
+    packed = symbol._substitute({})
+    for node in packed._topo_nodes():
+        if node.op in ("pack_rows", "unpack_rows"):
+            node.attrs["rows"] = rows
+    return packed, rows
+
+
 def row_programs(slots, block, shardings):
     """``(capture, restore)``: two jitted programs over the ``"rows"``
     pools of a ``slots``-slot driver, of the pools' shapes and ``block``
@@ -1171,6 +1231,12 @@ class BatchedKVCacheDecoder:
         self._windows = {}                           # step_len -> module
         # step_len -> how a step's host arrays reach that module's cells
         self._stagers = {1: module._exec_group.input_stager()}
+        # step_len -> (module, its stager, R): the window program over
+        # the real rows alone, where there is one (``add_window``)
+        self._packed = {}
+        # rows the latest step's program ran its row-wise operations
+        # over: slots x S, or R where it was the packed one
+        self.last_program_rows = None
         self._cursor_program = None                  # built at first use
         self._row_progs = None                       # capture, restore
         self._select_programs = {}                   # step_len -> program
@@ -1241,14 +1307,31 @@ class BatchedKVCacheDecoder:
             self.chunk = self.capacity // int(pool.shape[2])
             self.state_layers = len(self._state["window"]) // 2
 
-    def add_window(self, step_len, module):
+    def add_window(self, step_len, module, packed=None):
         """Register an S-token window module. It MUST have been bound
         with ``shared_module=`` this driver's S=1 module (or a module
         sharing its cells) so both programs advance the SAME device
         cache/cursor cells — the executor-group aux-sharing rule makes
-        that automatic when slot count and capacity agree."""
+        that automatic when slot count and capacity agree.
+
+        ``packed`` is ``(module, R)``: a module bound the same way over
+        ``packed_window``'s form of the same graph, whose row-wise
+        operations run over R packed rows. ``step`` launches it for a
+        window whose slots are fed no more than R rows between them,
+        and ``module`` for any other: same outputs, same state."""
         self._windows[int(step_len)] = module
         self._stagers[int(step_len)] = module._exec_group.input_stager()
+        if packed is not None:
+            form, rows = packed
+            self._packed[int(step_len)] = (
+                form, form._exec_group.input_stager(), int(rows))
+
+    def window_budget(self, step_len):
+        """R, the rows that the slots of one ``step_len`` window may be
+        fed between them and still take the packed program; None where
+        the driver has none for that length."""
+        packed = self._packed.get(int(step_len))
+        return None if packed is None else packed[2]
 
     @property
     def window_lens(self):
@@ -1583,7 +1666,11 @@ class BatchedKVCacheDecoder:
         ``b`` by ``fed[b]`` of its S tokens (0..S; None feeds every
         slot all S) and leaves a slot with no room for S positions
         where it is; any other graph advances every slot by S and takes
-        no ``fed``.
+        no ``fed``. Where the window has a packed program
+        (``add_window(packed=)``) and ``fed`` is given and sums to no
+        more than its budget, that is the program launched: its
+        row-wise operations run over the budget's rows, not ``slots x
+        S`` (``last_program_rows`` says which ran).
 
         Two annotations: ``decode.step.stage`` (the checks, the host
         arrays and their puts, what the dispatch reads of the state)
@@ -1601,6 +1688,8 @@ class BatchedKVCacheDecoder:
             if tokens.shape != (self.slots, S) or S < 1:
                 raise MXNetError(f"step() wants ({self.slots}, S) tokens, "
                                  f"got {tokens.shape}")
+            stage = self._stagers.get(S)
+            self.last_program_rows = self.slots * S
             if S == 1:
                 mod = self._mod
             else:
@@ -1625,6 +1714,7 @@ class BatchedKVCacheDecoder:
                 self.last_attention = self._attention_reads(
                     np.where(self.active, S, 0))
             else:
+                packed = None if fed is None else self._packed.get(S)
                 fed = np.full(self.slots, S, np.int64) if fed is None \
                     else np.asarray(fed, np.int64).reshape(-1)
                 if fed.shape != (self.slots,) or fed.min() < 0 \
@@ -1636,6 +1726,8 @@ class BatchedKVCacheDecoder:
                 # nothing fed
                 advance = fed = np.where(self.pos + S <= self.capacity,
                                          fed, 0)
+                if packed is not None and fed.sum() <= packed[2]:
+                    mod, stage, self.last_program_rows = packed
                 self.last_reads = self._state_reads(fed)
                 self.last_selection = self._selection_reads(fed)
                 self.last_attention = self._attention_reads(fed)
@@ -1647,7 +1739,7 @@ class BatchedKVCacheDecoder:
                 hosts.append(np.minimum(pos, self.capacity - 1))
             if self.feeds:
                 hosts.append(fed)
-            data = self._stagers[S](hosts)
+            data = stage(hosts)
         t1 = None if now is None else now()
         with _telemetry.span("decode.step.launch"):
             mod.forward(DataBatch(data=data, label=[]), is_train=False)

@@ -8,6 +8,7 @@ from . import rnn_op  # noqa: F401 — registers the fused RNN
 from . import moe  # noqa: F401 — registers RMSNorm and MoEFFN
 from . import eva  # noqa: F401 — registers eva_attention_decode, GatedSiLU
 from . import mla  # noqa: F401 — registers mla_attention_decode, dsa_index_select
+from . import rows  # noqa: F401 — registers pack_rows, unpack_rows
 from .. import operator as _custom_op  # noqa: F401 — registers Custom
 from . import pallas_kernels  # noqa: F401 — Pallas kernel-tier variants
 from . import quant  # noqa: F401 — int8 PTQ ops + graph rewrite

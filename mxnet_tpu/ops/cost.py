@@ -435,7 +435,8 @@ _UNARY_XCENDENTAL = {
     "tan", "tanh", "smooth_l1",
 }
 _MOVEMENT = {
-    "Reshape", "reshape", "Flatten", "flatten", "transpose", "Cast",
+    "Reshape", "reshape", "reshape_like", "pack_rows", "unpack_rows",
+    "Flatten", "flatten", "transpose", "Cast",
     "cast", "_copy", "identity", "BlockGrad", "stop_gradient",
     "make_loss", "Concat", "concat", "SliceChannel", "split", "slice",
     "slice_axis", "Crop", "expand_dims", "repeat", "tile", "reverse",

@@ -305,8 +305,13 @@ def moe_share_ffn(attrs, inputs, experts_fn):
     x, rest = inputs[0], list(inputs[1:])
     real = None
     if share.step_len:
+        # the rows are whole slots of as many rows as ``fed`` says
+        # there are slots: ``step_len`` of a window, or all of them
+        # under one count where the window's rows are packed
+        # (``ops/rows.py``)
         fed = rest.pop(0).astype(jnp.int32)
-        real = (jnp.arange(share.step_len, dtype=jnp.int32)[None, :]
+        real = (jnp.arange(x.shape[0] // fed.shape[0],
+                           dtype=jnp.int32)[None, :]
                 < fed[:, None]).reshape(-1)
     router = rest.pop(0)
     router_bias = rest.pop(0) if share.bias else None
@@ -382,10 +387,14 @@ def _moe_infer(attrs, in_shapes):
     shared = 0 if share is None else share.shared
     shapes = [data_s]
     if share is not None and share.step_len:
-        if T % share.step_len:
+        # as many slots as ``fed`` has entries; ``step_len`` rows each
+        # where its shape is not known yet
+        slots = in_shapes[1][0] if in_shapes[1] is not None \
+            else T // share.step_len
+        if T % slots or (in_shapes[1] is None and T % share.step_len):
             raise ValueError(f"MoEFFN: {T} rows are no whole slots of "
-                             f"step_len {share.step_len}")
-        shapes.append((T // share.step_len,))
+                             f"step_len {share.step_len} ({slots} slots)")
+        shapes.append((slots,))
     shapes.append((E, D))
     if share is not None and share.bias:
         shapes.append((E,))

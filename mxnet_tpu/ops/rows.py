@@ -1,0 +1,184 @@
+"""The rows of a window, packed: ``pack_rows`` and ``unpack_rows``.
+
+A window graph of a fed decoder (``models/transformer.py``: the blocks
+that take ``fed``) gives every slot S rows, of which ``fed[b]`` are real
+tokens and the rest pads. Its row-wise operations - norms, projections,
+feed-forwards, the router, the head - do not care which slot a row
+belongs to; attention does. The two ops stand where the graph passes
+from one view to the other, and carry the row budget ``rows``:
+
+* ``rows = 0`` (the default; every S = 1 graph, and the whole-window
+  program): all ``slots x S`` rows are kept. ``pack_rows`` hands its
+  inputs on as they are and ``unpack_rows`` is the reshape
+  ``(slots * S, ...) -> (slots, S, ...)``: the program's text is what it
+  was before the ops existed.
+* ``rows = R`` (``DecodeEngine`` sets it on a copy of a window graph):
+  ``pack_rows`` lays slot ``b``'s ``fed[b]`` real rows at ``offset[b] =
+  fed[0] + ... + fed[b-1]`` of one ``(1, R, ...)`` block - one
+  pseudo-slot of R rows, so that the graph's own folds and norms take it
+  as they take ``(slots, S, ...)`` - and ``unpack_rows`` lays them back
+  as ``(slots, S, ...)``, pads zero. Whoever launches the program keeps
+  ``fed.sum() <= R``; rows past the budget would be dropped.
+
+Both are copies of contiguous blocks and no gather: a slot's real rows
+are a prefix of its S, so packing copies each slot's real rows in place,
+``_chunk`` rows a copy and as many copies as the slot has real rows for
+(one loop whose trips follow ``fed``: a riding slot costs one copy, an
+unfed one none), in slot order - each later block lands on the pad tail
+of the one before - and unpacking copies them back the same way under a
+row mask. Neither touches a pad row beyond a copy's tail.
+
+``pack_rows`` also returns ``fed`` in the packed view: itself at ``rows
+= 0``, the one pseudo-slot's count of real rows ``(1,)`` under a budget
+- what ``MoEFFN`` keeps the pads out of its experts by.
+"""
+from __future__ import annotations
+
+import jax.numpy as jnp
+from jax import lax
+
+from ..base import parse_int, parse_tuple
+from .registry import register
+
+__all__ = ["pack", "unpack"]
+
+
+def _chunk(step_len):
+    """Rows a copy: 128 where a slot's rows are whole chunks of that,
+    else all of a slot's."""
+    return 128 if step_len % 128 == 0 else step_len
+
+
+def _offsets(fed, step_len, rows):
+    """Each slot's count of real rows and where its block starts, both
+    inside the budget whatever ``fed`` holds, and the real rows in all."""
+    fed = jnp.clip(fed.astype(jnp.int32), 0, step_len)
+    ends = jnp.cumsum(fed)
+    return fed, jnp.minimum(ends - fed, rows), jnp.minimum(ends[-1:], rows)
+
+
+def _copy_real(out, fed, chunk, copy):
+    """``out`` after ``copy(out, b, i)`` for every chunk ``i`` that slot
+    ``b`` has a real row in, slot after slot: one loop of as many trips
+    as there are such chunks, ``out`` updated in place."""
+    chunks = (fed + chunk - 1) // chunk
+    ends = jnp.cumsum(chunks)
+
+    def trip(t, out):
+        b = jnp.sum((ends <= t).astype(jnp.int32))     # the slot of copy t
+        first = _at(ends, b) - _at(chunks, b)
+        return copy(out, b, t - first)
+
+    return lax.fori_loop(0, ends[-1], trip, out)
+
+
+def _at(values, b):
+    return lax.dynamic_index_in_dim(values, b, keepdims=False)
+
+
+def pack(x, fed, rows):
+    """``x (slots, S, ...)`` -> ``(1, rows, ...)`` and the packed view's
+    ``fed (1,)`` (module docstring)."""
+    step_len = x.shape[1]
+    chunk = _chunk(step_len)
+    fed, starts, total = _offsets(fed, step_len, rows)
+    zero = (0,) * (x.ndim - 2)
+
+    def copy(out, b, i):
+        block = lax.dynamic_slice(x, (b, i * chunk) + zero,
+                                  (1, chunk) + x.shape[2:])
+        return lax.dynamic_update_slice(
+            out, block, (0, _at(starts, b) + i * chunk) + zero)
+
+    # a chunk of spare rows: the last copy's pad tail (and every copy
+    # of a dispatch over the budget) lands there and is cut off
+    out = jnp.zeros((1, rows + chunk) + x.shape[2:], x.dtype)
+    return _copy_real(out, fed, chunk, copy)[:, :rows], total
+
+
+def unpack(x, fed, step_len, rows, tail):
+    """``x (rows, ...)`` -> ``(slots, step_len) + tail`` (module
+    docstring)."""
+    slots = fed.shape[0]
+    chunk = _chunk(step_len)
+    fed, starts, _ = _offsets(fed, step_len, rows)
+    zero = (0,) * len(tail)
+    # a chunk of spare rows, so that no copy is clamped back onto
+    # another slot's rows
+    x = jnp.pad(x.reshape((rows,) + tail),
+                ((0, chunk),) + ((0, 0),) * len(tail))
+    at = jnp.arange(chunk, dtype=jnp.int32) \
+        .reshape((chunk,) + (1,) * len(tail))
+
+    def copy(out, b, i):
+        block = lax.dynamic_slice(
+            x, (_at(starts, b) + i * chunk,) + zero, (chunk,) + tail)
+        block = jnp.where(i * chunk + at < _at(fed, b), block,
+                          jnp.zeros((), x.dtype))
+        return lax.dynamic_update_slice(out, block[None],
+                                        (b, i * chunk) + zero)
+
+    out = jnp.zeros((slots, step_len) + tail, x.dtype)
+    return _copy_real(out, fed, chunk, copy)
+
+
+def _pack_infer(attrs, in_shapes):
+    data_s, fed_s = in_shapes
+    rows = parse_int(attrs.get("rows", 0))
+    if data_s is None:
+        return in_shapes, [None, (1,) if rows else fed_s], []
+    fed_s = (data_s[0],)
+    if not rows:
+        return [data_s, fed_s], [data_s, fed_s], []
+    return [data_s, fed_s], [(1, rows) + tuple(data_s[2:]), (1,)], []
+
+
+@register("pack_rows", inputs=("data", "fed"), num_outputs=2,
+          output_names=["output", "fed"],
+          attr_spec={"rows": (parse_int, 0)}, infer_shape=_pack_infer)
+def _pack_rows(attrs, data, fed):
+    """``(slots, S, ...)`` rows and ``fed (slots,)`` in the packed view
+    of ``rows`` rows; both as they are at ``rows = 0``."""
+    rows = parse_int(attrs.get("rows", 0))
+    return pack(data, fed, rows) if rows else (data, fed)
+
+
+def _unpack_tail(attrs, data_s):
+    return tuple(parse_tuple(attrs.get("shape")) or data_s[1:])
+
+
+def _unpack_infer(attrs, in_shapes):
+    data_s, fed_s = in_shapes
+    step_len = parse_int(attrs["step_len"])
+    rows = parse_int(attrs.get("rows", 0))
+    if data_s is None:
+        return in_shapes, [None], []
+    if rows and data_s[0] != rows:
+        raise ValueError(f"unpack_rows: {data_s[0]} rows under a budget "
+                         f"of {rows}")
+    if fed_s is None:
+        if rows:
+            return in_shapes, [None], []
+        fed_s = (data_s[0] // step_len,)
+    if not rows and fed_s[0] * step_len != data_s[0]:
+        raise ValueError(f"unpack_rows: {data_s[0]} rows are not "
+                         f"{fed_s[0]} slots of step_len {step_len}")
+    return [data_s, fed_s], \
+        [(fed_s[0], step_len) + _unpack_tail(attrs, data_s)], []
+
+
+@register("unpack_rows", inputs=("data", "fed"),
+          attr_spec={"step_len": (parse_int, None),
+                     "rows": (parse_int, 0),
+                     "shape": (parse_tuple, None)},
+          infer_shape=_unpack_infer)
+def _unpack_rows(attrs, data, fed):
+    """Rows ``(N, ...)`` as ``(slots, step_len, ...)``, the trailing
+    dimensions as ``shape`` where given: a reshape at ``rows = 0``, each
+    slot's ``fed`` rows out of a packed block of ``rows`` otherwise."""
+    step_len = parse_int(attrs["step_len"])
+    rows = parse_int(attrs.get("rows", 0))
+    tail = _unpack_tail(attrs, data.shape)
+    if not rows:
+        return jnp.reshape(data, (-1, step_len) + tail)
+    return unpack(data, fed, step_len, rows, tail)

@@ -398,6 +398,17 @@ def _reshape(attrs, x):
 alias("reshape", "Reshape")
 
 
+def _reshape_like_infer(attrs, in_shapes):
+    return in_shapes, [in_shapes[1]], []
+
+
+@register("reshape_like", inputs=("lhs", "rhs"),
+          infer_shape=_reshape_like_infer)
+def _reshape_like(attrs, lhs, rhs):
+    """``lhs`` in the shape of ``rhs`` (reference: matrix_op.cc)."""
+    return jnp.reshape(lhs, rhs.shape)
+
+
 def _flatten_infer(attrs, in_shapes):
     # pure-python inference keeps Flatten off the jax.eval_shape
     # fallback — the static memory planner's trace-free guarantee

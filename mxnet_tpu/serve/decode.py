@@ -41,7 +41,14 @@ compiled and pinned at warmup):
   T and TTFT goes near-flat in prompt length. Slots mid-decode ride a
   chunk dispatch with one real token plus pads and REWIND their cursor
   afterwards (a join-style aux poke), so mixed prefill/decode
-  iterations lose nothing.
+  iterations lose nothing. A graph that is fed its real tokens a slot
+  (``fed``) also gets, at rungs 4 and 8, the *packed* form of its window
+  program, whose row-wise operations run over ``R = S + slots`` rows
+  and not ``slots x S`` (``models.transformer.packed_window``); the
+  scheduler then plans every window inside R - a token for each
+  decoding slot, the rest to the prefilling slots oldest first
+  (``DecodeScheduler._plan_window``) - so the chunk is prefill tokens a
+  dispatch, not a slot.
 * **Prefix-cache reuse** — ``submit(prefix_id=...)`` names a shared
   prompt prefix; the first completion snapshots its cache rows into a
   ``PrefixStore`` (LRU under ``MXNET_SERVE_PREFIX_CACHE_MB``, charged
@@ -455,31 +462,45 @@ class DecodeEngine:
                              family=family).set(n)
 
     def _build_windows(self, symbol_gen, compute_dtype, logger):
+        """Every rung's window modules: for each length the graph that
+        ``symbol_gen`` builds and, where the graph is a fed one and the
+        rung's rows would halve, its packed form beside it
+        (``packed_window``: the same graph with a row budget that
+        follows from the rung and the length) - two programs over the
+        same cells, of which the rung's driver launches the one that a
+        dispatch's ``fed`` fits."""
         from ..module import Module
-        for rung in self.ladder:
+        from ..models.transformer import packed_window
+
+        def bind(symbol, rung, S):
+            mod = Module(symbol, data_names=list(self.data_names),
+                         label_names=[], logger=logger,
+                         context=self._context,
+                         compute_dtype=compute_dtype)
             base = self._bm._buckets[rung]
-            b_exe = base._exec_group.executor
+            mod.bind(self._provide_data(rung, S), label_shapes=None,
+                     for_training=False, shared_module=base)
+            b_aux = base._exec_group.executor.aux_dict
+            for nm, cell in mod._exec_group.executor.aux_dict.items():
+                if b_aux.get(nm) is not cell:
+                    raise MXNetError(
+                        f"DecodeEngine({self.name!r}): window "
+                        f"step_len={S} did not share aux cell "
+                        f"{nm!r} with the rung-{rung} decode "
+                        "module — symbol_gen must rebuild the SAME "
+                        "graph (names, capacity, slot count) at a "
+                        "different step_len")
+            return mod
+
+        for rung in self.ladder:
             for S in self.window_lens:
-                mod = Module(symbol_gen(S),
-                             data_names=list(self.data_names),
-                             label_names=[], logger=logger,
-                             context=self._context,
-                             compute_dtype=compute_dtype)
-                mod.bind(self._provide_data(rung, S),
-                         label_shapes=None, for_training=False,
-                         shared_module=base)
-                w_exe = mod._exec_group.executor
-                for nm, cell in w_exe.aux_dict.items():
-                    if b_exe.aux_dict.get(nm) is not cell:
-                        raise MXNetError(
-                            f"DecodeEngine({self.name!r}): window "
-                            f"step_len={S} did not share aux cell "
-                            f"{nm!r} with the rung-{rung} decode "
-                            "module — symbol_gen must rebuild the SAME "
-                            "graph (names, capacity, slot count) at a "
-                            "different step_len")
-                self._drivers[rung].add_window(S, mod)
-                self._window_mods[(rung, S)] = mod
+                symbol = symbol_gen(S)
+                mod = self._window_mods[(rung, S)] = bind(symbol, rung, S)
+                form = packed_window(symbol, rung)
+                if form is not None:
+                    form = (bind(form[0], rung, S), form[1])
+                    self._window_mods[(rung, S, "packed")] = form[0]
+                self._drivers[rung].add_window(S, mod, packed=form)
 
     def _note_params(self, arg_params):
         """What the binding holds, set once at bind: the bytes of the
@@ -537,6 +558,13 @@ class DecodeEngine:
         """The rung's ``BatchedKVCacheDecoder``."""
         return self._drivers[rung]
 
+    def window_budget(self, rung, step_len):
+        """The rows that one ``step_len`` window of ``rung`` slots may
+        feed between them: R where the rung has a packed program for
+        that length (``BatchedKVCacheDecoder.window_budget``), None
+        where a window is every slot's ``step_len`` rows."""
+        return self._drivers[rung].window_budget(step_len)
+
     # ------------------------------------------------------------- warmup
     def warmup(self, clock, rows=False):
         """Compile every slot rung's S=1 program AND every window
@@ -544,6 +572,12 @@ class DecodeEngine:
         each: first pays the traces, second measures steady state on
         ``clock`` the way an iteration runs it - step, select, token
         ids on the host), pin them all, record the compile delta.
+        Where a window has a packed program (``window_budget``) that is
+        the one a scheduler dispatches, and the one warmed: fed as a
+        serving window is, one slot its whole chunk and a token for
+        each other. The whole-window program of that rung stays bound
+        and compiles at its first direct call (``step`` without
+        ``fed``, or fed past the budget).
         Warmup garbage stays harmless: afterwards every driver slot is
         free, every cursor is rewound to 0, and a join rewinds again.
         Those rewinds also compile each rung's cursor program, whose
@@ -557,8 +591,9 @@ class DecodeEngine:
             drv = self._drivers[rung]
             last = np.zeros(rung, np.int32)
 
-            def step_ids(tokens):
-                _rows, ids = drv.select_rows(drv.step(tokens), last)
+            def step_ids(tokens, fed=None):
+                _rows, ids = drv.select_rows(drv.step(tokens, fed=fed),
+                                             last)
                 return np.asarray(ids)
 
             zeros = np.zeros((rung, 1), np.int32)
@@ -568,14 +603,16 @@ class DecodeEngine:
             self.exec_est[rung] = max(0.0, clock.now() - t0)
             for S in drv.window_lens:
                 wz = np.zeros((rung, S), np.int32)
+                fed = None if drv.window_budget(S) is None \
+                    else np.asarray([S] + [1] * (rung - 1))
                 # rewind first so that even a tiny cache has room for
                 # the window: warm-up runs the write steady state runs
                 # (a slot with no room for S rows writes nothing)
                 drv.rewind_many(list(range(rung)), [0] * rung)
-                step_ids(wz)                     # trace + compile
+                step_ids(wz, fed)                # trace + compile
                 drv.rewind_many(list(range(rung)), [0] * rung)
                 t0 = clock.now()
-                step_ids(wz)                     # steady state
+                step_ids(wz, fed)                # steady state
                 self.exec_est[(rung, S)] = max(0.0, clock.now() - t0)
             if rows and drv.positional:
                 drv.warm_rows()
@@ -646,7 +683,9 @@ class DecodeEngine:
             key = mod._exec_group.executor.program_cache_key("fwd_infer")
             if key is not None:
                 keys.append(key)
-        for (_rung, _S), mod in self._window_mods.items():
+        for at, mod in self._window_mods.items():
+            if at + ("packed",) in self._window_mods:
+                continue        # warmed as its packed form (``warmup``)
             key = mod._exec_group.executor.program_cache_key("fwd_infer")
             if key is not None:
                 keys.append(key)
@@ -741,8 +780,12 @@ _ATTN_COUNTERS = ("attn.live_rows", "attn.capacity_rows",
 #: the plan (no fetch): the slots fed at least one row, and those fed
 #: exactly one - a decoding slot riding a window in which another
 #: prefills, its other S - 1 rows pads. riding / fed is the traffic's,
-#: whatever kernel serves it
-_WINDOW_COUNTERS = ("window.fed_slots", "window.riding_slots")
+#: whatever kernel serves it. Then the rows that were real tokens, and
+#: the rows that the launched program ran its row-wise operations over
+#: (slots x S, or the budget R of a packed program): real / program is
+#: the share of a window's dense work that was not pads
+_WINDOW_COUNTERS = ("window.fed_slots", "window.riding_slots",
+                    "window.real_rows", "window.program_rows")
 
 
 class DecodeScheduler:
@@ -1131,6 +1174,38 @@ class DecodeScheduler:
             return "spec", self.spec_k
         return "window", 1
 
+    def _plan_window(self, S):
+        """``(row, seq, n)`` of every slot that this S-row dispatch
+        feeds, ``n`` >= 1 stream tokens each (caller holds the lock).
+        Without a budget every active slot takes ``min(S, remaining)``.
+        Where the engine has a packed program for this rung and length
+        (``window_budget``: R rows between the slots; a fed engine
+        alone, and none that a draft shadows), the window is planned
+        inside it: decoding slots take their one token first, then the
+        prefilling slots ``min(S, remaining, what is left of R)``,
+        oldest admission first. A prefilling slot for which nothing is
+        left is fed nothing this window and is not in the plan: the
+        program leaves it where it is. R holds a whole chunk beside a
+        token a slot, so the oldest prefilling slot always moves."""
+        seqs = [(row, seq) for row, seq in enumerate(self._slots)
+                if seq is not None]
+        budget = None if S == 1 or self.draft is not None \
+            else self.engine.window_budget(self._rung, S)
+        if budget is None:
+            return [(row, seq, min(S, seq.remaining()))
+                    for row, seq in seqs]
+        left = budget - sum(seq.remaining() == 1 for _row, seq in seqs)
+        plan = []
+        # a sequence's id counts submissions, and admission is in order
+        for row, seq in sorted(seqs, key=lambda rs: rs[1].id):
+            n = 1
+            if seq.remaining() > 1:
+                n = min(S, seq.remaining(), left)
+                left -= n
+            if n:
+                plan.append((row, seq, n))
+        return sorted(plan, key=lambda entry: entry[0])
+
     def _step_fetch(self, drv, tokens, phases, t=None, last=None,
                     rows=False, fed=None):
         """One dispatch and what the host samples from, each under its
@@ -1312,10 +1387,7 @@ class DecodeScheduler:
                 # alone: a row nobody owns is fed nothing
                 fed = np.zeros(self._rung, np.int32) \
                     if self.engine.feeds else None
-                for row, seq in enumerate(self._slots):
-                    if seq is None:
-                        continue
-                    n = min(S, seq.remaining())
+                for row, seq, n in self._plan_window(S):
                     tokens[row, :n] = seq.window(n)
                     last[row] = n - 1
                     if fed is not None:
@@ -1411,6 +1483,8 @@ class DecodeScheduler:
                     rows = [n for _row, _seq, n in meta]
                     m["window.fed_slots"].inc(sum(n >= 1 for n in rows))
                     m["window.riding_slots"].inc(sum(n == 1 for n in rows))
+                    m["window.real_rows"].inc(sum(rows))
+                    m["window.program_rows"].inc(drv.last_program_rows)
                 # what the dispatches counted: a routed decoder's
                 # experts, a window-and-summaries state's reads, a
                 # learned selection's

@@ -424,6 +424,152 @@ def test_the_s1_latent_programs_lower_to_the_parents_text(lowering, v5e):
     assert digest[:16] == _PARENT_MLA_S1_SHA256[lowering]
 
 
+#: the first 16 hex digits of the sha256 of the text that the whole
+#: inference program of each fed block lowers to at the tiny sizes of
+#: ``tests/window_pack_cases.py`` (4 slots; S = 1 and the whole-window
+#: program of 16 rows a slot; the XLA compositions, on the CPU): the
+#: parent's (7112c03), computed on a copy of it. The graphs now pass
+#: through ``pack_rows`` / ``unpack_rows``; without a row budget those
+#: lower to nothing and to the reshape that stood there
+_PARENT_PROGRAM_SHA256 = {
+    ("glm_dsa", 1): "9461136fdd6eaa09",
+    ("glm_dsa", 16): "96061a4da742fd99",
+    ("axk1", 1): "2927901ccdca78df",
+    ("axk1", 16): "785026fe1e56d2a1",
+    ("afmoe", 1): "aaabd06ad6dfea04",
+    ("afmoe", 16): "005db05c189522c6",
+    ("evabyte", 1): "3507d30cfe287fb6",
+    ("evabyte", 16): "88305a7d0fb20b40",
+}
+
+
+@pytest.mark.parametrize("block,S", sorted(_PARENT_PROGRAM_SHA256))
+def test_fed_programs_without_a_budget_lower_to_the_parents_text(
+        block, S, monkeypatch):
+    """The S = 1 program of every block that takes ``fed``, and its
+    whole-window program (what ``step`` without ``fed`` and
+    ``check_reference`` run), lower to the text they had before a
+    window's rows could be packed."""
+    import hashlib
+    import window_pack_cases as cases
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
+    kernel_tier.clear()
+    try:
+        text = cases.lowered_text(cases.symbol(block, S), cases.SLOTS, S)
+    finally:
+        kernel_tier.clear()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _PARENT_PROGRAM_SHA256[(block, S)]
+
+
+@pytest.mark.parametrize("op", ["pack", "unpack"])
+def test_packing_compiles_for_v5e_to_block_copies_in_place(op, v5e):
+    """``ops/rows.py`` at GLM-5.2's widest operand (8 slots of 1,024
+    rows of 64 x 256 queries, a budget of 1,152): packing and unpacking
+    are one loop each of ``dynamic-slice`` / ``dynamic-update-slice``
+    over one buffer - no gather, no scatter, and the 268 MB block of all the
+    slots' rows is neither copied nor laid out anew."""
+    import re
+    from mxnet_tpu.ops import rows
+    B, S, R, n = 8, 1024, 1152, 16384
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    if op == "pack":
+        # a computed operand, as the attention's result is
+        fn = lambda x, fed, w: jnp.dot(             # noqa: E731
+            rows.pack(x * 2, fed, R)[0][0], w)
+        args = (sds((B, S, n)), sds((B,), jnp.int32), sds((n, 128)))
+    else:
+        fn = lambda x, fed, w: rows.unpack(         # noqa: E731
+            jnp.dot(x, w), fed, S, R, (n,))
+        args = (sds((R, 128)), sds((B,), jnp.int32), sds((128, n)))
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text and " scatter(" not in text
+    assert len(re.findall(r" while\(", text)) == 1
+    assert "dynamic-update-slice(" in text and "dynamic-slice(" in text
+    assert not re.findall(r"= bf16\[[\d,]+\]\S* copy\(", text)
+    # nothing beside the operand (pack: 268 MB) or the result (unpack)
+    block = B * S * n * 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (block if op == "pack" else 0) + (8 << 20)
+
+
+def test_packed_window_program_compiles_for_v5e_and_copies_no_pool(
+        v5e, monkeypatch):
+    """One layer of GLM-5.2 at the published widths (an indexer, latent
+    attention under its selection, 16 held experts beside a shared one;
+    8 slots, windows of 1,024, a capacity of 32,768, bfloat16) in the
+    packed form of its window program: it compiles for the chip, its
+    dense products run over the budget's 1,152 rows, nothing that packs
+    or unpacks is a gather or a scatter, and with the aux arrays donated
+    both pools come back in the buffers they came in."""
+    import re
+    from mxnet_tpu.executor import _build_graph_runner
+    from mxnet_tpu.models import transformer as tfm
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "pallas")
+    kernel_tier.clear()
+    B, S, C, D, V = 8, 1024, 32768, 6144, 2048
+    glm = dict(q_lora_rank=2048, kv_lora_rank=512, qk_nope_head_dim=192,
+               qk_rope_head_dim=64, v_head_dim=256, index_n_heads=32,
+               index_head_dim=128, index_topk=2048, indexer_types=["full"],
+               first_k_dense_replace=0, intermediate_size=12288,
+               moe_intermediate_size=2048, n_routed_experts=256,
+               num_experts_per_tok=8, n_shared_experts=1,
+               routed_scaling_factor=2.5, norm_topk_prob=True, held=(0, 16))
+    whole = tfm.get_decode_symbol(
+        vocab_size=V, d_model=D, n_layer=1, n_head=64, rope_base=8e6,
+        capacity=C, step_len=S, per_slot=True, block="glm_dsa",
+        tie_head=False, embed_scale=False, glm=glm)
+    symbol, R = tfm.packed_window(whole, B)
+    assert R == 1152
+    try:
+        runner, arg_names, aux_names, _ = _build_graph_runner(
+            symbol, compute_dtype="bfloat16")
+        arg_shapes, _, aux_shapes = symbol.infer_shape(data=(B, S),
+                                                       fed=(B,))
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e)
+
+        args = {nm: sds(s, jnp.int32 if nm in ("data", "fed")
+                        else jnp.bfloat16)
+                for nm, s in zip(arg_names, arg_shapes)}
+        aux = {nm: sds(s, jnp.int32 if len(s) < 4 else jnp.bfloat16)
+               for nm, s in zip(aux_names, aux_shapes)}
+
+        def prog(arg_vals, aux_vals):
+            outs, new_aux = runner(arg_vals, aux_vals, False, None)
+            return outs, {**aux_vals, **new_aux}
+
+        compiled = jax.jit(prog, donate_argnums=(1,)).lower(args, aux) \
+            .compile()
+    finally:
+        kernel_tier.clear()
+    text = compiled.as_text()
+    for kernel in ("mla_attn_window", "mla_attn_ride", "dsa_index_scores",
+                   "moe_gmm_gate_up"):
+        assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
+    # the projections' products are R rows tall, none is slots x S
+    assert re.search(rf"= bf16\[{R},\d+\]\S* (fusion|convolution)\(", text)
+    assert not re.search(rf"= bf16\[{B * S},{D}\]", text)
+    # packing and unpacking: the nodes' names are the operations' scopes
+    moved = [line for line in text.splitlines()
+             if re.search(r'op_name="[^"]*(_pack|_unfold|_split|_rows|'
+                          r'logits_bsv)/', line)]
+    assert moved and not [line for line in moved
+                          if " gather(" in line or " scatter(" in line]
+    pools = [s for s in aux_shapes if len(s) == 4]
+    assert sorted(p[-1] for p in pools) == [128, 640]
+    for p in pools:
+        pool = rf"= bf16\[{','.join(map(str, p))}\]\S* "
+        assert not re.findall(pool + r"(copy|fusion)\(", text)
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        2 * int(np.prod(p)) for p in pools)
+
+
 @pytest.mark.parametrize("S", [1, 1024], ids=["decode", "window"])
 def test_group_limited_share_compiles_for_v5e_with_the_grouped_kernels(S, v5e):
     """A.X-K1's ``MoEFFN`` at the published sizes (8 slots, rows of

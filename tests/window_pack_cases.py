@@ -1,0 +1,110 @@
+"""The four fed decoders at tiny sizes - GLM-5.2's block, A.X-K1's,
+Trinity's and EvaByte's - for the tests of a window's packed rows
+(``tests/test_decode_pack.py``) and of the text their programs lower to
+(``tests/test_chip_compile.py``): graphs, parameters, bound drivers with
+the whole-window and the packed program, and a program's lowered text."""
+import numpy as np
+
+import jax
+
+import mxnet_tpu as mx
+from mxnet_tpu.models import transformer as tfm
+
+CAPACITY, WINDOW, SLOTS = 128, 16, 4            # WINDOW: the S > 1 program
+
+_LATENT = {"q_lora_rank": 48, "kv_lora_rank": 64, "qk_nope_head_dim": 24,
+           "qk_rope_head_dim": 16, "first_k_dense_replace": 1,
+           "intermediate_size": 96, "moe_intermediate_size": 32,
+           "num_experts_per_tok": 4, "n_shared_experts": 1,
+           "routed_scaling_factor": 2.5, "norm_topk_prob": True}
+_GLM = dict(_LATENT, v_head_dim=32, index_n_heads=16, index_head_dim=32,
+            index_topk=16, indexer_types=["full", "full", "shared"],
+            n_routed_experts=16, held=(4, 4))
+_AXK1 = dict(_LATENT, v_head_dim=16, n_routed_experts=24,
+             num_experts_per_tok=8, n_group=4, topk_group=2, held=(3, 3),
+             rope_scaling={"type": "yarn", "factor": 8,
+                           "original_max_position_embeddings": 16,
+                           "beta_fast": 4, "beta_slow": 1, "mscale": 1,
+                           "mscale_all_dim": 1})
+_AFMOE = {"num_key_value_heads": 2, "head_dim": 16, "sliding_window": 16,
+          "layer_types": ["sliding_attention", "full_attention",
+                          "sliding_attention"],
+          "num_dense_layers": 1, "intermediate_size": 96,
+          "moe_intermediate_size": 32, "num_experts": 16,
+          "num_experts_per_tok": 4, "num_shared_experts": 1,
+          "route_norm": True, "route_scale": 2.826}
+
+#: block -> ``get_decode_symbol``'s arguments beside the step length
+BLOCKS = {
+    "glm_dsa": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
+                    rope_base=8e6, glm=_GLM),
+    "axk1": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
+                 rope_base=1e4, rms_eps=1e-6, axk1=_AXK1),
+    "afmoe": dict(vocab_size=48, d_model=64, n_layer=3, n_head=8,
+                  rope_base=1e4, afmoe=_AFMOE, max_step_len=WINDOW),
+    "evabyte": dict(vocab_size=40, d_model=32, n_layer=2, n_head=2,
+                    rope_base=1e5, window=32, chunk=4, n_pred_heads=2,
+                    ffn_width=48),
+}
+
+
+def symbol(block, step_len):
+    return tfm.get_decode_symbol(
+        block=block, step_len=step_len, capacity=CAPACITY, per_slot=True,
+        pos_embed="rotary", tie_head=False, embed_scale=block == "afmoe",
+        **BLOCKS[block])
+
+
+def params(block, seed=5):
+    sym = symbol(block, 1)
+    shapes, _, _ = sym.infer_shape(data=(SLOTS, 1), fed=(SLOTS,))
+    rng = np.random.default_rng(seed)
+    out = {}
+    for name, shape in zip(sym.list_arguments(), shapes):
+        if name in ("data", "fed"):
+            continue
+        draw = rng.standard_normal(shape)
+        gain = name.endswith(("_gamma", "_kv_norm_weight"))
+        out[name] = ((0.3 * draw if block == "evabyte" else 1.0 + 0.3 * draw)
+                     if gain else 0.25 * draw).astype(np.float32)
+    return out
+
+
+def bound(sym, step_len, shared=None, arg_params=None, slots=SLOTS):
+    mod = mx.mod.Module(sym, data_names=("data", "fed"), label_names=[])
+    mod.bind([mx.io.DataDesc("data", (slots, step_len), np.int32),
+              mx.io.DataDesc("fed", (slots,), np.int32)],
+             None, for_training=False, shared_module=shared)
+    if shared is None:
+        mod.init_params(initializer=None, arg_params=dict(arg_params),
+                        aux_params={}, allow_missing=True)
+    return mod
+
+
+def driver(block, packed=True, slots=SLOTS):
+    """A ``slots``-slot driver of ``block`` with its window program of
+    ``WINDOW`` rows a slot and, with ``packed``, the packed form of it
+    beside (``tfm.packed_window``)."""
+    base = bound(symbol(block, 1), 1, arg_params=params(block), slots=slots)
+    drv = tfm.BatchedKVCacheDecoder(base, CAPACITY, slots=slots)
+    window = symbol(block, WINDOW)
+    form = tfm.packed_window(window, slots) if packed else None
+    drv.add_window(
+        WINDOW, bound(window, WINDOW, shared=base, slots=slots),
+        packed=form and (bound(form[0], WINDOW, shared=base, slots=slots),
+                         form[1]))
+    return drv
+
+
+def lowered_text(sym, slots, step_len):
+    """The text that the inference program of ``sym`` bound at ``(slots,
+    step_len)`` lowers to (the function ``Executor`` jits, on the CPU
+    under whatever kernel tier is set)."""
+    exe = bound(sym, step_len, arg_params={}, slots=slots) \
+        ._exec_group.executor
+
+    def prog(arg_vals, aux_vals, rng):
+        return exe._runner(arg_vals, aux_vals, False, rng)
+
+    return jax.jit(prog).lower(exe._arg_vals(), exe._aux_vals(),
+                               jax.random.PRNGKey(0)).as_text()

@@ -1,0 +1,189 @@
+#!/usr/bin/env python3
+"""By hand, on the chip: a window inside the row budget through the
+PACKED window program, at a fed configuration's published widths.
+
+``chipbench/serve_runner.py::check_reference`` feeds its sequences whole
+windows (``step`` without ``fed``), so the comparison that decides
+``correct`` reaches only the whole-window program; a serving window -
+one slot prefilling, the others riding with a token each - runs the
+packed form of the same graph (``models/transformer.py``'s
+``packed_window``, ``ops/rows.py``). Here slot 0 prefills a sequence to
+16,384 positions and one window more while every other slot of the top
+rung rides each window with one token of its own; once through the
+packed program (``fed`` sums to S + slots - 1 <= R) and once, from
+cursor 0 again, through the whole-window program fed the same. The last
+sixteen rows of slot 0's last window and the riders' rows, packed
+against whole, and slot 0's against the architecture's plain float32
+reference under its ``LOGIT_TOL``; whether the rows each path wrote to
+the positional pools are equal; and the median window's time on the
+host's clock in either form. Prints one JSON line.
+
+    python3 tools/window_pack_check.py --config a.x-k1|glm-5.2
+                                       [--seed N] [--rehearse]
+
+``--rehearse`` runs the configuration's tiny fixture on the CPU
+(chipbench/tests/fixtures: a context of 64, windows of 16); no number of
+it is a device number."""
+import argparse
+import functools
+import gc
+import json
+import os
+import statistics
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+_TINY = {"a.x-k1": ("axk1", "tiny-axk1.json"),
+         "glm-5.2": ("glm_dsa", "tiny-glm.json")}
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--config", choices=sorted(_TINY), required=True)
+    ap.add_argument("--seed", type=int, default=2147480243)
+    ap.add_argument("--rehearse", action="store_true")
+    ns = ap.parse_args(argv)
+    from chipbench import common, manifest
+    common.set_caches()
+    fixture, tiny = _TINY[ns.config]
+    path = os.path.join(ROOT, "chipbench", "tests", "fixtures", fixture,
+                        "configs", tiny) if ns.rehearse else \
+        os.path.join(ROOT, "chipbench", "configs", ns.config + ".json")
+    with open(path) as f:
+        cfg = json.load(f)
+    os.environ.update(cfg.get("env", {}))
+    import jax
+    import numpy as np
+    import mxnet_tpu as mx
+    from chipbench import serve_runner
+    arch = manifest._load_file(
+        "arch", os.path.join(ROOT, "chipbench", "archs", cfg["arch"] + ".py"))
+
+    S, n_cmp = cfg["prefill_chunk"], 16
+    ctx = 16384 if not ns.rehearse else 3 * S
+    windows = ctx // S + 1
+    assert ctx % S == 0 and windows * S <= cfg["capacity"]
+    gen = functools.partial(arch.decode_symbol, cfg)
+    top = max(cfg["ladder"])
+    t0 = time.perf_counter()
+    args = arch.make_params(gen(1), arch.data_shapes(cfg, top, 1), ns.seed,
+                            cfg)
+    sched = mx.serve.serve_decoder(
+        gen(1), args, name=cfg["name"], capacity=cfg["capacity"],
+        ladder=[top], context=mx.cpu(0) if ns.rehearse else mx.tpu(0),
+        compute_dtype=cfg["compute_dtype"], symbol_gen=gen,
+        prefill_chunk=S, start=False)
+    del args
+    drv = sched.engine.driver(top)
+    budget = drv.window_budget(S)
+    assert budget is not None and S + top - 1 <= budget
+    setup_s = time.perf_counter() - t0
+
+    rng = np.random.default_rng([ns.seed % (1 << 32), 43])
+    seq = rng.integers(0, cfg["vocab_size"], (1, windows * S)) \
+        .astype(np.int32)
+    riders = rng.integers(0, cfg["vocab_size"], (top, windows)) \
+        .astype(np.int32)
+    fed = np.asarray([S] + [1] * (top - 1), np.int32)
+
+    def run(whole):
+        """Every window of the schedule through one form of the window
+        program: ``(slot 0's last rows, the riders' rows of the last
+        window, the rows written, the windows' seconds, the rows the
+        program ran over)``."""
+        drv.active[:] = False
+        drv.rewind_many(list(range(top)), [0] * top)
+        for slot in range(top):
+            drv.join(slot)
+        # the driver takes the packed program whenever ``fed`` fits its
+        # budget: hide it for the whole-window pass (nothing public
+        # chooses, by design)
+        hidden = drv._packed.pop(S) if whole else None
+        seconds = []
+        try:
+            for w in range(windows):
+                tokens = np.zeros((top, S), np.int32)
+                tokens[0] = seq[0, w * S:(w + 1) * S]
+                tokens[1:, 0] = riders[1:, w]
+                t = time.perf_counter()
+                out = drv.step(tokens, fed=fed)
+                out.asjax().block_until_ready()
+                seconds.append(time.perf_counter() - t)
+                ran = drv.last_program_rows
+        finally:
+            if hidden is not None:
+                drv._packed[S] = hidden
+        logits = out.asnumpy().astype(np.float32)
+        written = [np.asarray(a, np.float32)[..., ctx:, :]
+                   for a in drv.capture_rows(0, windows * S).values()]
+        return (logits[0, S - n_cmp:], logits[1:, 0], written, seconds,
+                ran)
+
+    tail_p, ride_p, rows_p, s_p, ran_p = run(whole=False)
+    tail_w, ride_w, rows_w, s_w, ran_w = run(whole=True)
+    assert (ran_p, ran_w) == (budget, top * S), (ran_p, ran_w)
+
+    # the reference beside the parameters alone: the engine's pools and
+    # programs go first, 16 k positions in float32 do not fit beside them
+    params = serve_runner.served_params(sched.engine)
+    drv = None
+    sched = None
+    gc.collect()
+    rcfg = arch._reference_cfg(cfg) if hasattr(arch, "_reference_cfg") \
+        else cfg
+    tol = arch.LOGIT_TOL
+
+    def against(want, got):
+        err = np.abs(got - want)
+        bound = tol + tol * np.abs(want)
+        return {"max_abs_err": float(err.max()),
+                "max_err_over_bound": float((err / bound).max())}
+
+    try:
+        fwd = jax.jit(functools.partial(arch._reference.forward, config=rcfg,
+                                        tail=n_cmp))
+        want = np.asarray(fwd(params, seq))[0]
+        report = {"packed_vs_reference": against(want, tail_p),
+                  "whole_vs_reference": against(want, tail_w),
+                  "max_abs_logit": float(np.abs(want).max())}
+        ok_ref = report["packed_vs_reference"]["max_err_over_bound"] <= 1.0
+    except jax.errors.JaxRuntimeError as e:     # the device's memory
+        report = {"reference": f"failed: {type(e).__name__}: "
+                               f"{str(e)[:300]}"}
+        ok_ref = False
+    device = jax.devices()[0]
+    # the first window of either pass may compile (the whole-window
+    # program is not warmed where there is a packed one)
+    print(json.dumps({
+        "window_pack_check": cfg["name"], "seed": ns.seed, "context": ctx,
+        "positions_compared": n_cmp, "window": S, "slots": top,
+        "budget_rows": budget, "fed": fed.tolist(),
+        "packed_vs_whole_max_abs_diff": float(np.abs(tail_p - tail_w).max()),
+        "packed_vs_whole_in_tol_units": float(
+            (np.abs(tail_p - tail_w) / (tol + tol * np.abs(tail_w))).max()),
+        "packed_vs_whole_argmax_equal": int(
+            (tail_p.argmax(1) == tail_w.argmax(1)).sum()),
+        "riders_packed_vs_whole_max_abs_diff": float(
+            np.abs(ride_p - ride_w).max()),
+        "riders_argmax_equal": int(
+            (ride_p.argmax(1) == ride_w.argmax(1)).sum()),
+        "rows_written_equal": bool(all(
+            np.array_equal(a, b) for a, b in zip(rows_p, rows_w))),
+        "rows_written_max_abs_diff": float(max(
+            np.abs(a - b).max() for a, b in zip(rows_p, rows_w))),
+        "packed_window_ms_p50": 1e3 * statistics.median(s_p[1:]),
+        "whole_window_ms_p50": 1e3 * statistics.median(s_w[1:]),
+        "packed_window_ms_last": 1e3 * s_p[-1],
+        "whole_window_ms_last": 1e3 * s_w[-1],
+        "tolerance": tol, **report, "setup_s": setup_s, "ok": bool(ok_ref),
+        "platform": device.platform, "device_kind": device.device_kind,
+        "rehearsal": ns.rehearse}), flush=True)
+    return 0 if ok_ref else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
