@@ -425,41 +425,124 @@ def test_the_s1_latent_programs_lower_to_the_parents_text(lowering, v5e):
 
 
 #: the first 16 hex digits of the sha256 of the text that the whole
-#: inference program of each fed block lowers to at the tiny sizes of
-#: ``tests/window_pack_cases.py`` (4 slots; S = 1 and the whole-window
-#: program of 16 rows a slot; the XLA compositions, on the CPU): the
-#: parent's (7112c03), computed on a copy of it. The graphs now pass
-#: through ``pack_rows`` / ``unpack_rows``; without a row budget those
-#: lower to nothing and to the reshape that stood there
+#: inference program of each block lowers to at the tiny sizes of
+#: ``tests/window_pack_cases.py`` (4 slots; S = 1 and the window program
+#: of 16 rows a slot; the XLA compositions, on the CPU). The fed blocks'
+#: S = 1 and whole-window digests are PR 43's parent's (7112c03),
+#: computed on a copy of it: those graphs pass through ``pack_rows`` /
+#: ``unpack_rows``, which without a row budget lower to nothing and to
+#: the reshape that stood there. Their packed forms (``packed_window``)
+#: and the two blocks without ``fed`` are PR 44's parent's (7b8989b),
+#: computed on it before ``models/transformer.py`` built every block
+#: from one record: the builder changed, no program did.
 _PARENT_PROGRAM_SHA256 = {
-    ("glm_dsa", 1): "9461136fdd6eaa09",
-    ("glm_dsa", 16): "96061a4da742fd99",
-    ("axk1", 1): "2927901ccdca78df",
-    ("axk1", 16): "785026fe1e56d2a1",
-    ("afmoe", 1): "aaabd06ad6dfea04",
-    ("afmoe", 16): "005db05c189522c6",
-    ("evabyte", 1): "3507d30cfe287fb6",
-    ("evabyte", 16): "88305a7d0fb20b40",
+    ("glm_dsa", 1, "whole"): "9461136fdd6eaa09",
+    ("glm_dsa", 16, "whole"): "96061a4da742fd99",
+    ("axk1", 1, "whole"): "2927901ccdca78df",
+    ("axk1", 16, "whole"): "785026fe1e56d2a1",
+    ("afmoe", 1, "whole"): "aaabd06ad6dfea04",
+    ("afmoe", 16, "whole"): "005db05c189522c6",
+    ("evabyte", 1, "whole"): "3507d30cfe287fb6",
+    ("evabyte", 16, "whole"): "88305a7d0fb20b40",
+    ("glm_dsa", 16, "packed"): "80b76ebb24689395",
+    ("axk1", 16, "packed"): "5f5862a33ccc44fc",
+    ("afmoe", 16, "packed"): "87f3395671e7b8aa",
+    ("evabyte", 16, "packed"): "b06c559512c898cd",
+    ("gpt2", 1, "whole"): "e20dacc2cf812172",
+    ("gpt2", 16, "whole"): "a13e4ed537bdc517",
+    ("olmoe", 1, "whole"): "99256b25c8affc72",
+    ("olmoe", 16, "whole"): "60daa79edf026f83",
 }
 
 
-@pytest.mark.parametrize("block,S", sorted(_PARENT_PROGRAM_SHA256))
-def test_fed_programs_without_a_budget_lower_to_the_parents_text(
-        block, S, monkeypatch):
-    """The S = 1 program of every block that takes ``fed``, and its
-    whole-window program (what ``step`` without ``fed`` and
-    ``check_reference`` run), lower to the text they had before a
-    window's rows could be packed."""
+@pytest.mark.parametrize("block,S,form", sorted(_PARENT_PROGRAM_SHA256))
+def test_decode_programs_lower_to_the_parents_text(block, S, form,
+                                                   monkeypatch):
+    """The S = 1 program of every block, its whole-window program (for
+    a block that takes ``fed``: what ``step`` without ``fed`` and
+    ``check_reference`` run) and the packed form of that (what a
+    serving window runs) lower to the text they had."""
     import hashlib
     import window_pack_cases as cases
+    from mxnet_tpu.models import transformer as tfm
     monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
     kernel_tier.clear()
     try:
-        text = cases.lowered_text(cases.symbol(block, S), cases.SLOTS, S)
+        sym = cases.symbol(block, S)
+        if form == "packed":
+            sym, budget = tfm.packed_window(sym, cases.SLOTS)
+            assert budget == 24
+        text = cases.lowered_text(sym, cases.SLOTS, S)
     finally:
         kernel_tier.clear()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == _PARENT_PROGRAM_SHA256[(block, S)]
+        == _PARENT_PROGRAM_SHA256[(block, S, form)]
+
+
+#: the first 16 hex digits of the sha256 of ``Symbol.tojson()`` - every
+#: node's name, operation, attributes and inputs - of each block's decode
+#: graph at the same sizes, and of the two training graphs there are
+#: (``get_symbol``: with its loss, and ``include_loss=False``), of
+#: ``KVCacheDecoder``'s graph (one cursor for the batch, rotary), of a
+#: graph with fp8 pools and of EvaByte's with every head's logits:
+#: PR 44's parent's (7b8989b), computed on it. The nodes that ``+`` and ``*``
+#: make are named from a counter, so each graph is built under a name
+#: manager of its own.
+_PARENT_GRAPH_SHA256 = {
+    ("glm_dsa", 1): "8562f0a3f8b916d7",
+    ("glm_dsa", 16): "29129fe7d994fe2c",
+    ("axk1", 1): "64a8301c7b5af164",
+    ("axk1", 16): "9fcc866dd738526e",
+    ("afmoe", 1): "e1061652c1bf8d38",
+    ("afmoe", 16): "17df5a2e3b2f960c",
+    ("evabyte", 1): "0e294d777b975dbf",
+    ("evabyte", 16): "988ab43155750348",
+    ("gpt2", 1): "9d0b16baf0ccae00",
+    ("gpt2", 16): "c8ced922302c849b",
+    ("olmoe", 1): "8e930426538a9771",
+    ("olmoe", 16): "f334ca8be1f1cc19",
+    ("gpt2", "loss"): "f3eafb9ae1e1305d",
+    ("gpt2", "logits"): "454a7d008d7a8bd4",
+    ("olmoe", "loss"): "bce42d5f0c8d936c",
+    ("olmoe", "logits"): "8b27129cb4c804ab",
+    ("gpt2", "scalar_cursor"): "7f8953d42678de28",
+    ("gpt2", "fp8_cache"): "2f38e8d1f41dce91",
+    ("olmoe", "scalar_cursor"): "e3acb29dc5352a82",
+    ("evabyte", "multibyte"): "a016ce6b612f26cc",
+}
+
+
+def _pinned_graph(block, form):
+    import window_pack_cases as cases
+    from mxnet_tpu.models import transformer as tfm
+    with mx.name.NameManager():
+        if isinstance(form, int):
+            return cases.symbol(block, form)
+        if form == "scalar_cursor":         # ``KVCacheDecoder``'s graph
+            return tfm.get_decode_symbol(
+                block=block, step_len=4, capacity=cases.CAPACITY,
+                **dict(cases.UNFED[block], pos_embed="rotary"))
+        if form == "fp8_cache":
+            return tfm.get_decode_symbol(
+                block=block, capacity=cases.CAPACITY, per_slot=True,
+                cache_dtype="fp8", **cases.UNFED[block])
+        if form == "multibyte":
+            return tfm.get_decode_symbol(
+                block=block, step_len=16, capacity=cases.CAPACITY,
+                per_slot=True, tie_head=False, embed_scale=False,
+                multibyte=True, **cases.BLOCKS[block])
+        kw = {k: v for k, v in cases.UNFED[block].items()
+              if k != "max_seq_len"}
+        return tfm.get_symbol(block=block, seq_len=16, dropout=0.1,
+                              include_loss=form == "loss", **kw)
+
+
+@pytest.mark.parametrize("block,form", sorted(_PARENT_GRAPH_SHA256, key=str))
+def test_every_block_builds_the_parents_graph(block, form):
+    import hashlib
+    text = _pinned_graph(block, form).tojson()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _PARENT_GRAPH_SHA256[(block, form)]
 
 
 @pytest.mark.parametrize("op", ["pack", "unpack"])

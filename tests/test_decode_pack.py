@@ -17,6 +17,7 @@ from mxnet_tpu.serve.clock import FakeClock
 from mxnet_tpu.serve.decode import DecodeEngine, DecodeScheduler
 
 import window_pack_cases as cases
+from decode_counts_parent import COUNTS
 
 S, SLOTS = cases.WINDOW, cases.SLOTS
 R = tfm.packed_rows(SLOTS, S)                   # 24 of 64
@@ -302,3 +303,55 @@ def test_an_engine_without_fed_is_planned_as_before():
     windows = mx.telemetry.get_metric("serve.decode.prefill.chunks",
                                       model="pack-plain").value
     assert ran % (SLOTS * S) == 0 and ran and windows
+
+
+# ------------------------------------------- what a dispatch reads, counted
+def _counts_script(block):
+    """One fixed script through the scheduler for any block: three
+    prompts admitted together (windows with ragged ``fed``, a slot that
+    retires early and rides on), two more joined mid-flight (one of a
+    single token), the S = 1 steps they decode in, and after an idle
+    moment a last request into a used slot at the smallest rung. On a
+    block without ``fed`` every window is followed by a rewind of the
+    slots it ran ahead of; every join sets a cursor. Returns the model's
+    whole ``serve.decode.*`` counter set and what every iteration's
+    ring record says beside its times."""
+    name = f"counts-{block}"
+    gen = lambda s: cases.symbol(block, s)          # noqa: E731
+    engine = DecodeEngine(name, gen(1), cases.params(block),
+                          capacity=cases.CAPACITY, ladder=[1, SLOTS],
+                          symbol_gen=gen, window_lens=[S])
+    sched = DecodeScheduler(engine, clock=FakeClock(), prefill_chunk=S,
+                            prefix_store=None)
+    mx.telemetry.flightrec.clear()
+    rs = np.random.RandomState(11)
+    prompt = lambda n: rs.randint(0, 40, n)         # noqa: E731
+    handles = [sched.submit(prompt(n), max_new_tokens=m)
+               for n, m in ((60, 10), (23, 12), (5, 2))]
+    sched.pump(max_iterations=3)
+    handles += [sched.submit(prompt(n), max_new_tokens=m)
+                for n, m in ((1, 3), (17, 5))]
+    sched.pump()
+    handles.append(sched.submit(prompt(9), max_new_tokens=2))
+    sched.pump()
+    assert [len(h.result(timeout=0)) for h in handles] == [10, 12, 2, 3, 5, 2]
+    label = f'model="{name}"'
+    counters = {
+        key.split("{")[0][len("serve.decode."):]: value
+        for key, value in mx.telemetry.snapshot()["counters"].items()
+        if key.startswith("serve.decode.") and label in key
+        and "dtype=" not in key}
+    steps = [r for r in mx.telemetry.flightrec.get_records()
+             if r["kind"] == "serve.decode.step" and r["model"] == name]
+    fields = lambda r: {                            # noqa: E731
+        k: v for k, v in r.items()
+        if not k.endswith("_us") and k not in ("kind", "model", "mode",
+                                               "compiles_since_warmup")}
+    return counters, [fields(r) for r in steps]
+
+
+@pytest.mark.parametrize("block", sorted(COUNTS))
+def test_the_counters_and_the_ring_fields_are_the_parents(block):
+    counters, ring = _counts_script(block)
+    assert counters == COUNTS[block][0]
+    assert ring == COUNTS[block][1]

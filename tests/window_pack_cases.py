@@ -2,7 +2,11 @@
 Trinity's and EvaByte's - for the tests of a window's packed rows
 (``tests/test_decode_pack.py``) and of the text their programs lower to
 (``tests/test_chip_compile.py``): graphs, parameters, bound drivers with
-the whole-window and the packed program, and a program's lowered text."""
+the whole-window and the packed program, and a program's lowered text.
+Beside them the two blocks without ``fed`` (``UNFED``: GPT-2's with
+learned positions, OLMoE's), for the tests that hold all six to the
+graphs, programs and counts they had (``tests/test_chip_compile.py``,
+``tests/test_decode_pack.py``)."""
 import numpy as np
 
 import jax
@@ -47,21 +51,46 @@ BLOCKS = {
                     ffn_width=48),
 }
 
+#: the blocks without ``fed``, likewise (every slot advances by S)
+UNFED = {
+    "gpt2": dict(vocab_size=48, d_model=32, n_layer=2, n_head=2,
+                 pos_embed="learned", max_seq_len=CAPACITY),
+    "olmoe": dict(vocab_size=48, d_model=32, n_layer=2, n_head=2,
+                  pos_embed="rotary", rope_base=1e4, n_expert=8, top_k=2,
+                  expert_width=24, norm_topk=False, rms_eps=1e-5,
+                  tie_head=False, embed_scale=False),
+}
+
 
 def symbol(block, step_len):
+    if block in UNFED:
+        return tfm.get_decode_symbol(
+            block=block, step_len=step_len, capacity=CAPACITY,
+            per_slot=True, **UNFED[block])
     return tfm.get_decode_symbol(
         block=block, step_len=step_len, capacity=CAPACITY, per_slot=True,
         pos_embed="rotary", tie_head=False, embed_scale=block == "afmoe",
         **BLOCKS[block])
 
 
+def inputs(sym, slots, step_len):
+    """The data descriptions of a decode graph, in the order the
+    drivers stage them: tokens, learned positions, ``fed``."""
+    shapes = {"data": ((slots, step_len), np.int32),
+              "pos_ids": ((slots, step_len), np.float32),
+              "fed": ((slots,), np.int32)}
+    return [mx.io.DataDesc(nm, *shapes[nm]) for nm in shapes
+            if nm in sym.list_arguments()]
+
+
 def params(block, seed=5):
     sym = symbol(block, 1)
-    shapes, _, _ = sym.infer_shape(data=(SLOTS, 1), fed=(SLOTS,))
+    given = {d.name: d.shape for d in inputs(sym, SLOTS, 1)}
+    shapes, _, _ = sym.infer_shape(**given)
     rng = np.random.default_rng(seed)
     out = {}
     for name, shape in zip(sym.list_arguments(), shapes):
-        if name in ("data", "fed"):
+        if name in given:
             continue
         draw = rng.standard_normal(shape)
         gain = name.endswith(("_gamma", "_kv_norm_weight"))
@@ -71,10 +100,10 @@ def params(block, seed=5):
 
 
 def bound(sym, step_len, shared=None, arg_params=None, slots=SLOTS):
-    mod = mx.mod.Module(sym, data_names=("data", "fed"), label_names=[])
-    mod.bind([mx.io.DataDesc("data", (slots, step_len), np.int32),
-              mx.io.DataDesc("fed", (slots,), np.int32)],
-             None, for_training=False, shared_module=shared)
+    descs = inputs(sym, slots, step_len)
+    mod = mx.mod.Module(sym, data_names=[d.name for d in descs],
+                        label_names=[])
+    mod.bind(descs, None, for_training=False, shared_module=shared)
     if shared is None:
         mod.init_params(initializer=None, arg_params=dict(arg_params),
                         aux_params={}, allow_missing=True)
