@@ -34,7 +34,7 @@ import numpy as np
 
 from .. import symbol as sym
 from .. import telemetry as _telemetry
-from ..base import MXNetError, parse_bool
+from ..base import MXNetError
 
 __all__ = ["get_symbol", "get_decode_symbol", "SyntheticLMIter",
            "KVCacheDecoder", "BatchedKVCacheDecoder", "slot_state",
@@ -94,162 +94,368 @@ def _packed_rows(x, fed, name, fold=(-3, 0)):
                        shape=fold, name=name)
 
 
-def _fed_inputs(vocab_size, d_model, name, **embed):
-    """The head of a fed graph: ``(x, fed, fed_rows)`` - the embedded
-    tokens in the view the row-wise operations run in (``ops/rows.py``:
-    ``(slots, S, D)``, or one block of the real rows under a budget),
-    ``fed`` as the decode ops take it and as that view's ``MoEFFN``
-    does."""
-    fed = sym.var("fed")
-    packed = sym.pack_rows(sym.var("data"), fed, name=f"{name}_rows")
-    x = sym.Embedding(data=packed[0],
-                      weight=sym.var(f"{name}_tok_embed_weight"),
-                      input_dim=vocab_size, output_dim=d_model,
-                      name=f"{name}_tok_embed", **embed)
-    return x, fed, packed[1]
+# ---------------------------------------------------------------- a block
+# A block is a record (``_spec``): what the caller asked for and, as data
+# or as one function, what a model's layers differ in - the norm, the
+# attention of layer i, its feed-forward, how a sub-layer's output joins
+# the stream, the ends of the graph. ``_layer`` and ``_logits`` walk
+# it; nothing below the spec constructors knows a model by its name.
+
+def _norm(x, name, spec):
+    """The block's normalisation: ``spec["norm"]``, an operation and its
+    attributes."""
+    op, attrs = spec["norm"]
+    return getattr(sym, op)(x, name=name, **attrs)
 
 
-def _block(x, *, i, seq_len, d_model, n_head, dropout, pos_embed,
-           rope_base, name, decode=False, capacity=None,
-           per_slot=False, cache_dtype=None, moe=None):
-    """One pre-norm transformer block - the one place a GPT-2 or OLMoE
-    block is built (EvaByte's, whose residual stream is float32 and
-    whose attention exists as a decode op alone, is ``_eva_block``);
-    ``decode=True`` swaps the full ``attention`` for the KV-cache
-    ``attention_decode`` path (same parameter names either way, so one
-    trained parameter set serves both graphs). ``per_slot=True`` selects
-    the slot-pooled decode lowering: a (B, 1) cursor vector so every
-    batch row decodes its own sequence at its own position.
-
-    ``moe=None`` is the GPT-2 block: LayerNorm, fused q/k/v with bias,
-    a GeLU feed-forward of four times the width. A ``moe`` spec
-    (``_moe_spec``: ``n_expert``, ``top_k``, ``expert_width``,
-    ``norm_topk``, ``rms_eps``) is the OLMoE block (arXiv:2409.02060):
-    RMSNorm, fused q/k/v without bias, RMSNorm of the whole q and k
-    projections before the split into heads, and the routed expert
-    feed-forward ``MoEFFN``; no bias anywhere."""
-    pfx = f"{name}_l{i}"
-    dh = d_model // n_head
-    T = seq_len
-    olmoe = moe is not None
-
-    ln1 = _norm(x, f"{pfx}_ln1", moe)
-    qkv = _proj(ln1, 3 * d_model, f"{pfx}_qkv", no_bias=olmoe)  # (B*T, 3D)
-    if olmoe:
-        qk_norm = lambda rows, name: _norm(rows, name, moe)  # noqa: E731
+def _fused_attention(x, fed, carry, i, spec):
+    """GPT-2's and OLMoE's attention, the one kind with a training
+    form: q, k and v as one projection of ``3 x d_model`` (OLMoE's
+    without bias and with the whole q and k projections normed before
+    the split into heads), then the KV-cache ``attention_decode`` in a
+    decode graph (``per_slot``: a (B, 1) cursor vector, every batch row
+    at its own position) or the full causal ``attention`` in a training
+    one, under the same parameter names."""
+    pfx, T = f"{spec['name']}_l{i}", spec["T"]
+    d_model, n_head = spec["d_model"], spec["n_head"]
+    rotary = spec["pos_embed"] == "rotary"
+    qkv = _proj(x, 3 * d_model, f"{pfx}_qkv",
+                no_bias=not spec["bias"])                    # (B*T, 3D)
+    if spec["qk_norm"]:
+        qk_norm = lambda rows, name: _norm(rows, name, spec)  # noqa: E731
         split = lambda rows, name: sym.Reshape(              # noqa: E731
-            rows, shape=(-1, T, n_head, dh), name=name)
+            rows, shape=(-1, T, n_head, d_model // n_head), name=name)
         q, k, v = (_qkv_heads(qkv, j, nm, pfx, split, d_model, norm)
                    for j, (nm, norm) in enumerate(
                        (("q", qk_norm), ("k", qk_norm), ("v", None))))
     else:
-        qkv = sym.Reshape(qkv, shape=(-1, T, 3 * n_head, dh),
+        qkv = sym.Reshape(qkv, shape=(-1, T, 3 * n_head, d_model // n_head),
                           name=f"{pfx}_qkv_split")
         qkv = sym.transpose(qkv, axes=(0, 2, 1, 3),
                             name=f"{pfx}_qkv_t")             # (B, 3H, T, dh)
-        q = sym.slice_axis(qkv, axis=1, begin=0, end=n_head,
-                           name=f"{pfx}_q")
-        k = sym.slice_axis(qkv, axis=1, begin=n_head, end=2 * n_head,
-                           name=f"{pfx}_k")
-        v = sym.slice_axis(qkv, axis=1, begin=2 * n_head, end=3 * n_head,
-                           name=f"{pfx}_v")
-    if decode:
+        q, k, v = (sym.slice_axis(qkv, axis=1, begin=j * n_head,
+                                  end=(j + 1) * n_head, name=f"{pfx}_{nm}")
+                   for j, nm in enumerate("qkv"))
+    if spec["decode"]:
         att = sym.attention_decode(
-            q, k, v, capacity=capacity, rope=(pos_embed == "rotary"),
-            rope_base=rope_base, per_slot=per_slot,
-            cache_dtype=cache_dtype or "",
-            name=f"{pfx}_attn")
+            q, k, v, capacity=spec["capacity"], rope=rotary,
+            rope_base=spec["rope_base"], per_slot=spec["per_slot"],
+            cache_dtype=spec["cache_dtype"] or "", name=f"{pfx}_attn")
     else:
-        if pos_embed == "rotary":
-            q = sym.RoPE(q, base=rope_base, name=f"{pfx}_rope_q")
-            k = sym.RoPE(k, base=rope_base, name=f"{pfx}_rope_k")
+        if rotary:
+            q = sym.RoPE(q, base=spec["rope_base"], name=f"{pfx}_rope_q")
+            k = sym.RoPE(k, base=spec["rope_base"], name=f"{pfx}_rope_k")
         att = sym.attention(q, k, v, causal=True, name=f"{pfx}_attn")
     att = sym.transpose(att, axes=(0, 2, 1, 3),
-                        name=f"{pfx}_attn_t")            # (B, T, H, dh)
-    att = sym.Reshape(att, shape=(-3, -3), name=f"{pfx}_attn_merge")
-    proj = sym.FullyConnected(att, num_hidden=d_model, name=f"{pfx}_proj",
-                              **({"no_bias": True} if olmoe else {}))
-    proj = sym.Reshape(proj, shape=(-1, T, d_model),
-                       name=f"{pfx}_proj_unfold")
-    if dropout:
-        proj = sym.Dropout(proj, p=dropout, name=f"{pfx}_drop1")
-    x = x + proj
-
-    ln2 = _norm(x, f"{pfx}_ln2", moe)
-    if olmoe:
-        rows = sym.Reshape(ln2, shape=(-3, 0), name=f"{pfx}_moe_fold")
-        h = sym.MoEFFN(rows, num_experts=moe["n_expert"],
-                       num_hidden=moe["expert_width"],
-                       top_k=moe["top_k"], norm_topk=moe["norm_topk"],
-                       name=f"{pfx}_moe")                # (B*T, D)
-    else:
-        # dense -> GeLU as the fused epilogue pair: the matmul emits raw
-        # rows (no_bias) and FusedBiasGeLU folds bias+erf-GeLU in one pass
-        h = _proj(ln2, 4 * d_model, f"{pfx}_ffn1", no_bias=True)
-        h = sym.FusedBiasGeLU(h, name=f"{pfx}_ffn_gelu")
-        h = sym.FullyConnected(h, num_hidden=d_model, name=f"{pfx}_ffn2")
-    h = sym.Reshape(h, shape=(-1, T, d_model), name=f"{pfx}_ffn_unfold")
-    if dropout:
-        h = sym.Dropout(h, p=dropout, name=f"{pfx}_drop2")
-    return x + h
+                        name=f"{pfx}_attn_t")                # (B, T, H, dh)
+    return sym.Reshape(att, shape=(-3, -3), name=f"{pfx}_attn_merge"), None
 
 
-def _norm(x, name, moe):
-    """The block's normalisation: RMSNorm under a ``moe`` spec (the
-    OLMoE block), LayerNorm otherwise."""
-    if moe is not None:
-        return sym.RMSNorm(x, eps=moe["rms_eps"], name=name)
-    return sym.LayerNorm(x, name=name)
-
-
-def _eva_norm(x, name, eva):
-    """EvaByte's normalisation: RMSNorm whose gain is stored as its
-    distance from one, handed on at the compute width."""
-    return sym.RMSNorm(x, eps=eva["rms_eps"], unit_offset=True,
-                       cast_to_gain=True, name=name)
-
-
-def _eva_block(x, fed, *, i, seq_len, d_model, n_head, rope_base, name,
-               capacity, eva):
-    """One EvaByte block (``eva``: ``_eva_spec``) of the slot-pooled
-    decode graph: ``h = x + Attn(N(x))``, ``y = h + FFN(N(h))`` with
-    the residual stream ``x`` and both adds in float32, the matmuls at
-    the compute width, EVA attention with its state
-    (``eva_attention_decode``: rotary inside the op, ``fed`` real
-    tokens a slot) and a dense gated-SiLU feed-forward whose gate and
-    up projections are one matmul; no bias anywhere. ``x`` is in the
-    packed view of the window's rows (``_glm_block``); q, k and v are
-    unpacked for the attention op and its result packed again."""
-    pfx = f"{name}_l{i}"
-    T = seq_len
-
-    qkv = _proj(_eva_norm(x, f"{pfx}_ln1", eva), 3 * d_model,
-                f"{pfx}_qkv", no_bias=True)                  # (B*T, 3D)
-
+def _eva_attention(x, fed, carry, i, spec):
+    """EvaByte's: EVA attention with its state (``eva_attention_decode``:
+    rotary inside the op, ``fed`` real tokens a slot) over q, k and v
+    of one projection, unpacked for the op and its result packed
+    again."""
+    pfx, d_model, n_head = f"{spec['name']}_l{i}", spec["d_model"], \
+        spec["n_head"]
+    qkv = _proj(x, 3 * d_model, f"{pfx}_qkv", no_bias=True)  # (B*T, 3D)
     split = lambda rows, name: _slots(                       # noqa: E731
-        rows, fed, T, name, shape=(n_head, d_model // n_head))
+        rows, fed, spec["T"], name, shape=(n_head, d_model // n_head))
     q, k, v = (_qkv_heads(qkv, j, nm, pfx, split, d_model)
                for j, nm in enumerate("qkv"))
     att = sym.eva_attention_decode(
-        q, k, v, fed, capacity=capacity,
-        window=eva["window"], chunk=eva["chunk"], rope_base=rope_base,
+        q, k, v, fed, capacity=spec["capacity"], window=spec["window"],
+        chunk=spec["chunk"], rope_base=spec["rope_base"],
         name=f"{pfx}_attn")
     att = sym.transpose(att, axes=(0, 2, 1, 3), name=f"{pfx}_attn_t")
-    att = _packed_rows(att, fed, f"{pfx}_attn_merge", fold=(-3, -3))
-    proj = sym.FullyConnected(att, num_hidden=d_model, no_bias=True,
-                              name=f"{pfx}_proj")
-    proj = sym.reshape_like(proj, x, name=f"{pfx}_proj_unfold")
-    x = x + sym.Cast(proj, dtype="float32", name=f"{pfx}_proj_f32")
+    return _packed_rows(att, fed, f"{pfx}_attn_merge", fold=(-3, -3)), None
 
-    rows = sym.Reshape(_eva_norm(x, f"{pfx}_ln2", eva), shape=(-3, 0),
-                       name=f"{pfx}_ffn_fold")
-    h = sym.FullyConnected(rows, num_hidden=2 * eva["ffn_width"],
-                           no_bias=True, name=f"{pfx}_ffn_gate_up")
+
+def _latent_attention(x, fed, selection, i, spec):
+    """GLM-5.2's and A.X-K1's (``spec["cfg"]``: ``_latent_spec``), no
+    bias anywhere but the indexer's LayerNorm: multi-head latent
+    attention over a latent cache (``mla_attention_decode``) under the
+    selection of positions that this layer's indexer computes
+    (``dsa_index_select``, layers whose ``indexer_types`` entry is
+    ``"full"``) or that ``selection`` brings from the nearest earlier
+    such layer (``"shared"``: IndexShare), or over every position at or
+    before the query (``"none"``: a model without an indexer; the
+    rotary then under ``cfg["rope"]``'s scaling). The operands of the
+    two decode ops alone are laid out ``(slots, S, .)`` (``_slots``)
+    and attention's result is packed again (``_packed_rows``). The
+    selection is what this kind carries to the next layer: it crosses
+    layers outside the residual stream."""
+    pfx, cfg, n_head = f"{spec['name']}_l{i}", spec["cfg"], spec["n_head"]
+    capacity, rope_base = spec["capacity"], spec["rope_base"]
+    dq = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+    unfold = lambda rows, nm: _slots(                        # noqa: E731
+        rows, fed, spec["T"], f"{pfx}_{nm}_unfold")
+
+    rows = sym.Reshape(x, shape=(-3, 0), name=f"{pfx}_attn_fold")  # (B*T, D)
+    c_q = _norm(
+        sym.FullyConnected(rows, num_hidden=cfg["q_lora_rank"],
+                           no_bias=True, name=f"{pfx}_q_a"),
+        f"{pfx}_q_a_norm", spec)
+    q = sym.FullyConnected(c_q, num_hidden=n_head * dq, no_bias=True,
+                           name=f"{pfx}_q_b")
+    kv = sym.FullyConnected(
+        rows, num_hidden=cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"],
+        no_bias=True, name=f"{pfx}_kv_a")
+    if cfg["indexer_types"][i] == "full":
+        n_idx, d_idx = cfg["index_n_heads"], cfg["index_head_dim"]
+        q_idx = sym.FullyConnected(c_q, num_hidden=n_idx * d_idx,
+                                   no_bias=True, name=f"{pfx}_idx_q")
+        k_idx = sym.LayerNorm(
+            sym.FullyConnected(rows, num_hidden=d_idx, no_bias=True,
+                               name=f"{pfx}_idx_k"),
+            name=f"{pfx}_idx_k_norm")
+        w_idx = sym.FullyConnected(rows, num_hidden=n_idx, no_bias=True,
+                                   name=f"{pfx}_idx_w")
+        selection = sym.dsa_index_select(
+            unfold(q_idx, "idx_q"), unfold(k_idx, "idx_k"),
+            unfold(w_idx, "idx_w"),
+            fed, capacity=capacity, n_heads=n_idx, head_dim=d_idx,
+            rope_dim=cfg["qk_rope_head_dim"], topk=cfg["index_topk"],
+            rope_base=rope_base, name=f"{pfx}_idx")
+    dense = cfg["indexer_types"][i] == "none"
+    att = sym.mla_attention_decode(
+        unfold(q, "q"), unfold(kv, "kv"),
+        *(() if dense else (selection,)), fed, capacity=capacity,
+        n_heads=n_head, nope_dim=cfg["qk_nope_head_dim"],
+        rope_dim=cfg["qk_rope_head_dim"], v_dim=cfg["v_head_dim"],
+        kv_rank=cfg["kv_lora_rank"], rms_eps=spec["rms_eps"],
+        rope_base=rope_base, name=f"{pfx}_attn",
+        **({"selected": False} if dense else {}), **cfg["rope"])
+    return _packed_rows(att, fed, f"{pfx}_attn_merge"), selection
+
+
+def _grouped_attention(x, fed, carry, i, spec):
+    """Trinity's (``spec["cfg"]``: ``_afmoe_spec``): attention over
+    grouped K/V heads (``n_head`` query heads on ``num_key_value_heads``,
+    q and k normed per head), on a layer ``layer_types`` marks
+    ``sliding_attention`` with rotary positions and a window whose
+    pools are rings, on a ``full_attention`` layer with neither; its
+    output gated by a sigmoid of a projection of the layer's input. q,
+    k and v are unpacked before their per-head norms, where the S = 1
+    program's text has them."""
+    pfx, cfg, n_head = f"{spec['name']}_l{i}", spec["cfg"], spec["n_head"]
+    n_kv, dh = cfg["num_key_value_heads"], cfg["head_dim"]
+    sliding = cfg["layer_types"][i] == "sliding_attention"
+
+    rows = sym.Reshape(x, shape=(-3, 0), name=f"{pfx}_attn_fold")  # (B*T, D)
+    # q, k, v and the gate as the row blocks of one projection
+    wide = sym.FullyConnected(rows, num_hidden=2 * (n_head + n_kv) * dh,
+                              no_bias=True, name=f"{pfx}_qkvg")
+    at, heads = 0, {}
+    for nm, n in (("q", n_head), ("k", n_kv), ("v", n_kv)):
+        part = sym.slice_axis(wide, axis=1, begin=at, end=at + n * dh,
+                              name=f"{pfx}_{nm}_rows")
+        at += n * dh
+        part = _slots(part, fed, spec["T"], f"{pfx}_{nm}_split",
+                      shape=(n, dh))
+        if nm != "v":                      # normed per head, over dh
+            part = _norm(part, f"{pfx}_{nm}_norm", spec)
+        heads[nm] = sym.transpose(part, axes=(0, 2, 1, 3),
+                                  name=f"{pfx}_{nm}")        # (B, n, T, dh)
+    gate = sym.slice_axis(wide, axis=1, begin=at, end=at + n_head * dh,
+                          name=f"{pfx}_gate_rows")
+    att = sym.attention_decode(
+        heads["q"], heads["k"], heads["v"], fed, capacity=spec["capacity"],
+        rope=sliding, rope_base=spec["rope_base"], per_slot=True,
+        kv_heads=n_kv, fed=True, name=f"{pfx}_attn",
+        **({"window": cfg["sliding_window"], "ring": cfg["ring"]}
+           if sliding else {}))
+    att = sym.transpose(att, axes=(0, 2, 1, 3), name=f"{pfx}_attn_t")
+    att = _packed_rows(att, fed, f"{pfx}_attn_merge", fold=(-3, -3))
+    return att * sym.Activation(gate, act_type="sigmoid",
+                                name=f"{pfx}_gate"), None
+
+
+def _ffn(x, fed_rows, i, spec):
+    """Layer ``i``'s feed-forward over the rows of the normed stream
+    ``x``: on the first ``spec["dense_layers"]`` layers the dense one
+    (``spec["dense"]``: GPT-2's GeLU pair, or the gated-SiLU triple
+    whose gate and up projections are one matmul), after them the
+    routed experts (``MoEFFN`` with ``spec["moe"]``'s attributes; in a
+    fed graph it takes ``fed_rows``, so that the pads of a window are
+    routed nowhere)."""
+    pfx, d_model = f"{spec['name']}_l{i}", spec["d_model"]
+    if i >= spec["dense_layers"]:
+        rows = sym.Reshape(x, shape=(-3, 0),
+                           name=f"{pfx}_{spec['moe_fold']}")
+        return sym.MoEFFN(rows, *(() if fed_rows is None else (fed_rows,)),
+                          name=f"{pfx}_moe", **spec["moe"])  # (B*T, D)
+    kind, width = spec["dense"]
+    if kind == "gelu":
+        # dense -> GeLU as the fused epilogue pair: the matmul emits raw
+        # rows (no_bias) and FusedBiasGeLU folds bias+erf-GeLU in one pass
+        h = _proj(x, width, f"{pfx}_ffn1", no_bias=True)
+        h = sym.FusedBiasGeLU(h, name=f"{pfx}_ffn_gelu")
+        return sym.FullyConnected(h, num_hidden=d_model, name=f"{pfx}_ffn2")
+    rows = sym.Reshape(x, shape=(-3, 0), name=f"{pfx}_ffn_fold")
+    h = sym.FullyConnected(rows, num_hidden=2 * width, no_bias=True,
+                           name=f"{pfx}_ffn_gate_up")
     h = sym.GatedSiLU(h, name=f"{pfx}_ffn_act")
-    h = sym.FullyConnected(h, num_hidden=d_model, no_bias=True,
-                           name=f"{pfx}_ffn_down")
-    h = sym.reshape_like(h, x, name=f"{pfx}_ffn_unfold")
-    return x + sym.Cast(h, dtype="float32", name=f"{pfx}_ffn_f32")
+    return sym.FullyConnected(h, num_hidden=d_model, no_bias=True,
+                              name=f"{pfx}_ffn_down")
+
+
+#: a sub-layer's names: its output projection or last matmul, its dropout
+#: and, where a block norms what it adds, that norm
+_SUB_LAYERS = {"proj": ("drop1", "post_attn_ln"),
+               "ffn": ("drop2", "post_ffn_ln")}
+
+
+def _residual(x, h, pfx, sub, spec):
+    """``x + h`` for the rows ``h`` of sub-layer ``sub``: back in the
+    stream's shape (``reshape_like`` in a fed graph, whose view of the
+    rows only ``ops/rows.py`` knows), through dropout in a training
+    graph, then added as they are, after a cast to a float32 stream
+    (``spec["residual"] == "float32"``: EvaByte) or normed
+    (``"normed"``: Trinity)."""
+    drop, post_norm = _SUB_LAYERS[sub]
+    h = sym.reshape_like(h, x, name=f"{pfx}_{sub}_unfold") if spec["fed"] \
+        else sym.Reshape(h, shape=(-1, spec["T"], spec["d_model"]),
+                         name=f"{pfx}_{sub}_unfold")
+    if spec["dropout"]:
+        h = sym.Dropout(h, p=spec["dropout"], name=f"{pfx}_{drop}")
+    if spec["residual"] == "float32":
+        h = sym.Cast(h, dtype="float32", name=f"{pfx}_{sub}_f32")
+    elif spec["residual"] == "normed":
+        h = _norm(h, f"{pfx}_{post_norm}", spec)
+    return x + h
+
+
+def _layer(x, fed, fed_rows, carry, i, spec):
+    """Layer ``i`` of any block, pre-norm: ``x = x + proj(attention(
+    norm(x)))``, then ``x = x + ffn(norm(x))``. In a fed graph ``x``
+    and every row-wise operation are in the packed view of the window's
+    rows (``ops/rows.py``: all ``slots x S`` of them, or the real ones
+    under a budget, ``fed_rows`` their count as ``MoEFFN`` takes it).
+    Returns ``(x, carry)``: what the attention hands its next layer."""
+    pfx = f"{spec['name']}_l{i}"
+    att, carry = spec["attention"](_norm(x, f"{pfx}_ln1", spec), fed,
+                                   carry, i, spec)
+    proj = sym.FullyConnected(att, num_hidden=spec["d_model"],
+                              name=f"{pfx}_proj",
+                              **({} if spec["bias"] else {"no_bias": True}))
+    x = _residual(x, proj, pfx, "proj", spec)
+    h = _ffn(_norm(x, f"{pfx}_ln2", spec), fed_rows, i, spec)
+    return _residual(x, h, pfx, "ffn", spec), carry
+
+
+def _head(x, tok_w, spec):
+    """Final norm and the output head over the folded (B*T, D) rows:
+    tied to the token embedding (one weight, two gradients), or the
+    untied ``{name}_head_weight`` of ``spec["heads"]`` consecutive
+    blocks of ``vocab_size`` rows."""
+    name = spec["name"]
+    flat = sym.Reshape(_norm(x, f"{name}_ln_f", spec), shape=(-3, 0),
+                       name=f"{name}_head_fold")
+    if spec["tie_head"]:
+        return sym.dot(flat, tok_w, transpose_b=True,
+                       name=f"{name}_logits")                # (B*T, V)
+    return sym.FullyConnected(
+        flat, weight=sym.var(f"{name}_head_weight"),
+        num_hidden=spec["heads"] * spec["vocab_size"], no_bias=True,
+        name=f"{name}_logits")                               # (B*T, P*V)
+
+
+# -------------------------------------------------- the spec constructors
+def _rms(spec, **more):
+    return ("RMSNorm", {"eps": float(spec["rms_eps"]), **more})
+
+
+def _check_heads(spec):
+    d_model, n_head, pos_embed = (spec[k] for k in
+                                  ("d_model", "n_head", "pos_embed"))
+    if d_model % n_head:
+        raise MXNetError(f"d_model {d_model} must divide n_head {n_head}")
+    if (d_model // n_head) % 2:
+        raise MXNetError("head dim must be even (RoPE rotates pairs)")
+    if pos_embed not in ("rotary", "learned"):
+        raise MXNetError(f"pos_embed {pos_embed!r}: 'rotary' or 'learned'")
+
+
+def _check_served(spec):
+    """The blocks whose attention exists as decode ops alone: the
+    slot-pooled decode graph and nothing else builds them."""
+    block = spec["block"]
+    if not spec["decode"]:
+        raise MXNetError(
+            f"block={block!r} is served, not trained: its attention "
+            "exists as decode ops alone (get_decode_symbol(per_slot=True); "
+            "the plain full forward is the benchmark's reference)")
+    if not spec["per_slot"] or spec["cache_dtype"]:
+        raise MXNetError(f"block={block!r} is the slot-pooled decode "
+                         "graph (per_slot=True) with state at the "
+                         "compute width (no cache_dtype)")
+
+
+def _gpt2_spec(spec):
+    """GPT-2's block: LayerNorm, fused q/k/v with bias, a GeLU
+    feed-forward of four times the width; learned or rotary positions,
+    tied or untied head."""
+    _check_heads(spec)
+    return dict(spec, norm=("LayerNorm", {}), attention=_fused_attention,
+                bias=True, qk_norm=False, dense=("gelu", 4 * spec["d_model"]),
+                dense_layers=spec["n_layer"])
+
+
+def _olmoe_spec(spec):
+    """OLMoE's block (arXiv:2409.02060): RMSNorm, fused q/k/v without
+    bias, RMSNorm of the whole q and k projections before the split
+    into heads, and on every layer the routed expert feed-forward
+    ``MoEFFN`` (``n_expert`` experts of ``expert_width``, ``top_k`` a
+    token); no bias anywhere, rotary, so the graph has no ``pos_ids``
+    input. OLMoE also unties the head (``tie_head=False``) and leaves
+    the embedding unscaled (``embed_scale=False``)."""
+    n_expert, top_k, width = (spec[k] for k in
+                              ("n_expert", "top_k", "expert_width"))
+    if spec["pos_embed"] != "rotary":
+        raise MXNetError("block='olmoe' is rotary (no position table)")
+    if not (n_expert and top_k and width) or top_k > n_expert:
+        raise MXNetError(
+            "block='olmoe' needs n_expert >= top_k >= 1 and "
+            f"expert_width (got {n_expert}, {top_k}, {width})")
+    _check_heads(spec)
+    return dict(spec, norm=_rms(spec), attention=_fused_attention,
+                bias=False, qk_norm=True, dense_layers=0,
+                moe_fold="moe_fold",
+                moe=dict(num_experts=int(n_expert), num_hidden=int(width),
+                         top_k=int(top_k),
+                         norm_topk=bool(spec["norm_topk"])))
+
+
+def _eva_spec(spec):
+    """EvaByte's block (per-slot only): ``h = x + Attn(N(x))``, ``y = h
+    + FFN(N(h))`` with the residual stream and both adds in float32,
+    the matmuls at the compute width; RMSNorm whose gain is stored as
+    its distance from one, handed on at the compute width; EVA
+    attention over ``window`` exact positions and one summary per
+    ``chunk`` of everything older (``ops/eva.py``); a dense gated-SiLU
+    feed-forward of ``ffn_width``; an untied head of ``n_pred_heads``
+    consecutive blocks of ``vocab_size`` columns; no bias anywhere. Its
+    state is not a row per position, so the graph takes one more input,
+    ``fed`` ``(slots,)`` int32: how many of each slot's ``step_len``
+    tokens are real. The program advances a slot's state by exactly
+    that. The output is head 0's ``(B, step_len, vocab)`` logits - the
+    next byte, what a scheduler samples - or, with ``multibyte``, all
+    heads' ``(B, step_len, n_pred_heads, vocab)``."""
+    _check_served(spec)
+    if spec["pos_embed"] != "rotary":
+        raise MXNetError("block='evabyte' is rotary (no position table)")
+    if not spec["ffn_width"] or int(spec["n_pred_heads"]) < 1:
+        raise MXNetError("block='evabyte' needs ffn_width and "
+                         "n_pred_heads >= 1")
+    _check_heads(spec)
+    return dict(spec, norm=_rms(spec, unit_offset=True, cast_to_gain=True),
+                attention=_eva_attention, window=int(spec["window"]),
+                chunk=int(spec["chunk"]), bias=False,
+                dense=("gated", int(spec["ffn_width"])),
+                dense_layers=spec["n_layer"], fed=True, residual="float32",
+                tie_head=False, embed_scale=False,
+                heads=int(spec["n_pred_heads"]), next_byte=True)
 
 
 #: the keys of GLM-5.2's published ``config.json`` that
@@ -274,150 +480,96 @@ AXK1_KEYS = tuple(k for k in GLM_KEYS if not k.startswith("index")) \
     + ("n_group", "topk_group", "rope_scaling")
 
 
-def _glm_spec(block, given, n_layer, rms_eps):
-    """The latent-attention block's settings, for ``_glm_block``: the
-    published keys as given, and what the two models differ in -
-    ``indexer_types`` (``"none"`` on every layer of a model without an
-    indexer), ``router`` (``MoEFFN``'s attributes of the choice) and
-    ``rope`` (``mla_attention_decode``'s rotary scaling, empty for the
-    plain rotary)."""
-    if block not in ("glm_dsa", "axk1"):
-        return None
-    arg, keys = ("glm", GLM_KEYS) if block == "glm_dsa" \
-        else ("axk1", AXK1_KEYS)
-    glm = dict(given or {})
-    missing = [k for k in keys if k not in glm]
+def _latent_spec(spec, given, kinds, router, rope):
+    """The latent-attention block (per-slot only), one block for two
+    models: pre-norm, ``_latent_attention`` over one row of
+    ``kv_lora_rank + qk_rope_head_dim`` numbers a position
+    (``ops/mla.py``), a dense gated-SiLU feed-forward on the first
+    ``first_k_dense_replace`` layers and sigmoid-routed experts beside
+    a shared one after (``MoEFFN``, the choice by ``router``), of which
+    this graph holds ``held``; untied head, unscaled embedding. What
+    the two models differ in arrives as ``kinds`` (``indexer_types``),
+    ``router`` (``MoEFFN``'s attributes of the choice) and ``rope``
+    (``mla_attention_decode``'s rotary scaling, empty for the plain
+    rotary). The graph takes ``fed`` like EvaByte's and advances by it,
+    but its state is a row per position in every pool (``"rows"``), so
+    the driver rewinds, captures and restores it as it does a K/V
+    cache."""
+    first, count = given.get("held") or (0, int(given["n_routed_experts"]))
+    cfg = dict(given, indexer_types=kinds, rope=rope,
+               held=(int(first), int(count)))
+    return dict(
+        spec, cfg=cfg, norm=_rms(spec), attention=_latent_attention,
+        bias=False, dense=("gated", cfg["intermediate_size"]),
+        dense_layers=cfg["first_k_dense_replace"], moe_fold="ffn_fold",
+        moe=dict(step_len=spec["T"], num_experts=cfg["n_routed_experts"],
+                 num_hidden=cfg["moe_intermediate_size"],
+                 top_k=cfg["num_experts_per_tok"],
+                 norm_topk=cfg["norm_topk_prob"], scoring="sigmoid",
+                 scaling=cfg["routed_scaling_factor"],
+                 held_first=int(first), held_count=int(count),
+                 shared_hidden=cfg["n_shared_experts"]
+                 * cfg["moe_intermediate_size"], **router),
+        fed=True, pos_embed="rotary", tie_head=False, embed_scale=False)
+
+
+def _given_keys(spec, arg, keys):
+    """The published keys a served block was handed as ``arg``, once
+    the graph asked for is one it has (``_check_served``)."""
+    _check_served(spec)
+    given = dict(spec[arg] or {})
+    missing = [k for k in keys if k not in given]
     if missing:
-        raise MXNetError(f"block={block!r} needs {arg}= with {missing}")
-    if block == "glm_dsa":
-        kinds = list(glm["indexer_types"])
-        if len(kinds) != n_layer or not kinds or kinds[0] != "full" \
-                or set(kinds) - {"full", "shared"}:
-            raise MXNetError(
-                f"block='glm_dsa': indexer_types {kinds} must name "
-                f"'full' or 'shared' for each of {n_layer} layers, the "
-                "first 'full' (a shared layer attends the set the nearest "
-                "earlier full layer chose)")
-        router, rope = {"router_bias": True}, {}
-    else:
-        kinds = ["none"] * n_layer
-        router = {"router_bias": False, "n_group": int(glm["n_group"]),
-                  "topk_group": int(glm["topk_group"])}
-        yarn = dict(glm["rope_scaling"] or {})
-        if yarn and yarn.get("type", yarn.get("rope_type")) != "yarn":
-            raise MXNetError(f"block='axk1': rope_scaling {yarn} is not "
-                             "YaRN's")
-        rope = {} if not yarn else {
-            "rope_factor": float(yarn["factor"]),
-            "rope_original_positions":
-                int(yarn["original_max_position_embeddings"]),
-            "rope_beta_fast": float(yarn.get("beta_fast", 32)),
-            "rope_beta_slow": float(yarn.get("beta_slow", 1)),
-            "rope_mscale": float(yarn.get("mscale", 1)),
-            "rope_mscale_all_dim": float(yarn.get("mscale_all_dim", 0))}
-    n_expert = int(glm["n_routed_experts"])
-    first, count = glm.get("held") or (0, n_expert)
-    glm.update(indexer_types=kinds, held=(int(first), int(count)),
-               rms_eps=float(rms_eps), router=router, rope=rope)
-    return glm
+        raise MXNetError(
+            f"block={spec['block']!r} needs {arg}= with {missing}")
+    return given
 
 
-def _glm_norm(x, name, glm):
-    return sym.RMSNorm(x, eps=glm["rms_eps"], name=name)
+def _glm_spec(spec):
+    """GLM-5.2's block from ``glm``, the published config's keys
+    (``GLM_KEYS``) and optionally ``held``: the latent block
+    (``_latent_spec``) attended under the ``index_topk`` positions a
+    learned indexer selects on the layers ``indexer_types`` marks
+    ``"full"`` and the layers marked ``"shared"`` reuse; a sigmoid
+    router with a correction bias."""
+    glm = _given_keys(spec, "glm", GLM_KEYS)
+    kinds, n_layer = list(glm["indexer_types"]), spec["n_layer"]
+    if len(kinds) != n_layer or not kinds or kinds[0] != "full" \
+            or set(kinds) - {"full", "shared"}:
+        raise MXNetError(
+            f"block='glm_dsa': indexer_types {kinds} must name "
+            f"'full' or 'shared' for each of {n_layer} layers, the "
+            "first 'full' (a shared layer attends the set the nearest "
+            "earlier full layer chose)")
+    return _latent_spec(spec, glm, kinds, {"router_bias": True}, {})
 
 
-def _glm_block(x, fed, fed_rows, selection, *, i, seq_len, d_model, n_head,
-               rope_base, name, capacity, glm):
-    """One latent-attention block (``glm``: ``_glm_spec``; GLM-5.2's
-    and A.X-K1's) of the slot-pooled decode graph, pre-norm, no bias
-    anywhere but the indexer's LayerNorm: multi-head latent attention
-    over a latent cache (``mla_attention_decode``) under the selection
-    of positions that this layer's indexer computes
-    (``dsa_index_select``, layers whose ``indexer_types`` entry is
-    ``"full"``) or that ``selection`` brings from the nearest earlier
-    such layer (``"shared"``: IndexShare), or over every position at or
-    before the query (``"none"``: a model without an indexer; the
-    rotary then under ``glm["rope"]``'s scaling); then a dense
-    gated-SiLU feed-forward (layers before ``first_k_dense_replace``)
-    or sigmoid-routed experts beside a shared one (``MoEFFN``, the
-    choice by ``glm["router"]``), of which this graph holds ``held``;
-    the pads of a window (rows past ``fed``) are routed nowhere.
-    ``x`` and every row-wise operation are in the packed view of the
-    window's rows (``ops/rows.py``: all ``slots x S`` of them, or the
-    real ones under a budget, ``fed_rows`` their count as ``MoEFFN``
-    takes it); the operands of the two decode ops alone are laid out
-    ``(slots, S, .)`` (``_slots``) and attention's result is packed
-    again (``_packed_rows``). Returns ``(x, selection)``: the selection
-    crosses layers outside the residual stream."""
-    pfx = f"{name}_l{i}"
-    T = seq_len
-    dq = glm["qk_nope_head_dim"] + glm["qk_rope_head_dim"]
-    unfold = lambda rows, nm: _slots(                        # noqa: E731
-        rows, fed, T, f"{pfx}_{nm}_unfold")
-
-    rows = sym.Reshape(_glm_norm(x, f"{pfx}_ln1", glm), shape=(-3, 0),
-                       name=f"{pfx}_attn_fold")              # (B*T, D)
-    c_q = _glm_norm(
-        sym.FullyConnected(rows, num_hidden=glm["q_lora_rank"],
-                           no_bias=True, name=f"{pfx}_q_a"),
-        f"{pfx}_q_a_norm", glm)
-    q = sym.FullyConnected(c_q, num_hidden=n_head * dq, no_bias=True,
-                           name=f"{pfx}_q_b")
-    kv = sym.FullyConnected(
-        rows, num_hidden=glm["kv_lora_rank"] + glm["qk_rope_head_dim"],
-        no_bias=True, name=f"{pfx}_kv_a")
-    if glm["indexer_types"][i] == "full":
-        n_idx, d_idx = glm["index_n_heads"], glm["index_head_dim"]
-        q_idx = sym.FullyConnected(c_q, num_hidden=n_idx * d_idx,
-                                   no_bias=True, name=f"{pfx}_idx_q")
-        k_idx = sym.LayerNorm(
-            sym.FullyConnected(rows, num_hidden=d_idx, no_bias=True,
-                               name=f"{pfx}_idx_k"),
-            name=f"{pfx}_idx_k_norm")
-        w_idx = sym.FullyConnected(rows, num_hidden=n_idx, no_bias=True,
-                                   name=f"{pfx}_idx_w")
-        selection = sym.dsa_index_select(
-            unfold(q_idx, "idx_q"), unfold(k_idx, "idx_k"),
-            unfold(w_idx, "idx_w"),
-            fed, capacity=capacity, n_heads=n_idx, head_dim=d_idx,
-            rope_dim=glm["qk_rope_head_dim"], topk=glm["index_topk"],
-            rope_base=rope_base, name=f"{pfx}_idx")
-    dense = glm["indexer_types"][i] == "none"
-    att = sym.mla_attention_decode(
-        unfold(q, "q"), unfold(kv, "kv"),
-        *(() if dense else (selection,)), fed, capacity=capacity,
-        n_heads=n_head, nope_dim=glm["qk_nope_head_dim"],
-        rope_dim=glm["qk_rope_head_dim"], v_dim=glm["v_head_dim"],
-        kv_rank=glm["kv_lora_rank"], rms_eps=glm["rms_eps"],
-        rope_base=rope_base, name=f"{pfx}_attn",
-        **({"selected": False} if dense else {}), **glm["rope"])
-    proj = sym.FullyConnected(
-        _packed_rows(att, fed, f"{pfx}_attn_merge"),
-        num_hidden=d_model, no_bias=True, name=f"{pfx}_proj")
-    x = x + sym.reshape_like(proj, x, name=f"{pfx}_proj_unfold")
-
-    rows = sym.Reshape(_glm_norm(x, f"{pfx}_ln2", glm), shape=(-3, 0),
-                       name=f"{pfx}_ffn_fold")
-    if i < glm["first_k_dense_replace"]:
-        h = sym.FullyConnected(rows, num_hidden=2 * glm["intermediate_size"],
-                               no_bias=True, name=f"{pfx}_ffn_gate_up")
-        h = sym.GatedSiLU(h, name=f"{pfx}_ffn_act")
-        h = sym.FullyConnected(h, num_hidden=d_model, no_bias=True,
-                               name=f"{pfx}_ffn_down")
-    else:
-        first, count = glm["held"]
-        h = sym.MoEFFN(
-            rows, fed_rows, step_len=T,
-            num_experts=glm["n_routed_experts"],
-            num_hidden=glm["moe_intermediate_size"],
-            top_k=glm["num_experts_per_tok"],
-            norm_topk=glm["norm_topk_prob"], scoring="sigmoid",
-            scaling=glm["routed_scaling_factor"],
-            held_first=first, held_count=count,
-            shared_hidden=glm["n_shared_experts"]
-            * glm["moe_intermediate_size"], name=f"{pfx}_moe",
-            **glm["router"])
-    return x + sym.reshape_like(h, x, name=f"{pfx}_ffn_unfold"), selection
+def _axk1_spec(spec):
+    """A.X-K1's block from ``axk1``, its published keys (``AXK1_KEYS``)
+    and optionally ``held``: the same latent block without an indexer
+    - latent attention over every position at or before the query, its
+    rotary under ``rope_scaling`` (YaRN: blended frequencies and a
+    larger softmax scale) - and a sigmoid router without a correction
+    bias that chooses inside the ``topk_group`` best of ``n_group``
+    groups of experts. Inputs, state and driver contract are
+    ``glm_dsa``'s."""
+    axk1 = _given_keys(spec, "axk1", AXK1_KEYS)
+    yarn = dict(axk1["rope_scaling"] or {})
+    if yarn and yarn.get("type", yarn.get("rope_type")) != "yarn":
+        raise MXNetError(f"block='axk1': rope_scaling {yarn} is not "
+                         "YaRN's")
+    rope = {} if not yarn else {
+        "rope_factor": float(yarn["factor"]),
+        "rope_original_positions":
+            int(yarn["original_max_position_embeddings"]),
+        "rope_beta_fast": float(yarn.get("beta_fast", 32)),
+        "rope_beta_slow": float(yarn.get("beta_slow", 1)),
+        "rope_mscale": float(yarn.get("mscale", 1)),
+        "rope_mscale_all_dim": float(yarn.get("mscale_all_dim", 0))}
+    return _latent_spec(
+        spec, axk1, ["none"] * spec["n_layer"],
+        {"router_bias": False, "n_group": int(axk1["n_group"]),
+         "topk_group": int(axk1["topk_group"])}, rope)
 
 
 #: the keys of Trinity's published ``config.json`` (``model_type
@@ -438,219 +590,144 @@ def ring_rows(window, step_len):
     return -(-rows // unit) * unit
 
 
-def _afmoe_spec(block, afmoe, n_layer, rms_eps, capacity, max_step_len):
-    if block != "afmoe":
-        return None
-    afmoe = dict(afmoe or {})
-    missing = [k for k in AFMOE_KEYS if k not in afmoe]
-    if missing:
-        raise MXNetError(f"block='afmoe' needs afmoe= with {missing}")
-    kinds = list(afmoe["layer_types"])
+def _afmoe_spec(spec):
+    """Trinity's block (per-slot only) from ``afmoe``, the published
+    config's keys (``AFMOE_KEYS``; ``layer_types`` one entry a layer
+    that is run), no bias anywhere: ``x = x + N(Attn(N(x)))``, then
+    ``x = x + N(FF(N(x)))`` - each sub-layer's output is normed before
+    it is added, four norms a layer. ``_grouped_attention``: layers
+    marked ``sliding_attention`` are rotary and attend a window of
+    ``sliding_window`` positions, layers marked ``full_attention`` have
+    no positions and attend everything. A scaled embedding
+    (``embed_scale``), a dense gated-SiLU feed-forward on the first
+    ``num_dense_layers`` layers and sigmoid-routed experts, all held,
+    beside a shared one after; untied head. **Capacity per kind of
+    layer**: a full layer's pools hold ``capacity`` rows (``"rows"``);
+    a sliding layer's are rings of ``ring_rows(sliding_window,
+    max_step_len)`` rows, whatever the capacity (``"ring"``; a ring
+    that would be as long as the capacity is a pool of a row per
+    position instead). ``max_step_len`` is the largest ``step_len`` of
+    the graphs that share the pools (default: this graph's): every
+    graph of one engine names the same. The graph takes ``fed`` and
+    advances by it; with a ring it is not positional (see
+    ``BatchedKVCacheDecoder``)."""
+    cfg = _given_keys(spec, "afmoe", AFMOE_KEYS)
+    kinds, n_layer, n_head = list(cfg["layer_types"]), spec["n_layer"], \
+        spec["n_head"]
     if len(kinds) != n_layer or \
             set(kinds) - {"sliding_attention", "full_attention"}:
         raise MXNetError(
             f"block='afmoe': layer_types {kinds} must name "
             "'sliding_attention' or 'full_attention' for each of "
             f"{n_layer} layers")
-    window = int(afmoe["sliding_window"])
-    ring = ring_rows(window, max_step_len)
+    if n_head % cfg["num_key_value_heads"] or cfg["head_dim"] % 2:
+        raise MXNetError(
+            f"block='afmoe': {n_head} query heads on "
+            f"{cfg['num_key_value_heads']} K/V heads of "
+            f"{cfg['head_dim']}: the K/V heads divide the query "
+            "heads, and a head's width is even (rotary pairs)")
+    ring = ring_rows(cfg["sliding_window"],
+                     max(spec["T"], spec["max_step_len"] or 1))
     # a ring as long as the context would hold a row per position
-    afmoe.update(layer_types=kinds, rms_eps=float(rms_eps),
-                 ring=ring if ring < capacity else 0)
-    return afmoe
+    cfg.update(layer_types=kinds, ring=ring if ring < spec["capacity"] else 0)
+    return dict(
+        spec, cfg=cfg, norm=_rms(spec), attention=_grouped_attention,
+        bias=False, dense=("gated", cfg["intermediate_size"]),
+        dense_layers=cfg["num_dense_layers"], moe_fold="ffn_fold",
+        moe=dict(step_len=spec["T"], num_experts=cfg["num_experts"],
+                 num_hidden=cfg["moe_intermediate_size"],
+                 top_k=cfg["num_experts_per_tok"],
+                 norm_topk=cfg["route_norm"], scoring="sigmoid",
+                 router_bias=True, scaling=cfg["route_scale"],
+                 shared_hidden=cfg["num_shared_experts"]
+                 * cfg["moe_intermediate_size"]),
+        fed=True, pos_embed="rotary", residual="normed", tie_head=False)
 
 
-def _afmoe_norm(x, name, afmoe):
-    return sym.RMSNorm(x, eps=afmoe["rms_eps"], name=name)
+#: ``block=`` -> its spec constructor: the one place a block is chosen
+#: by its name. A seventh architecture is one more entry and, only if
+#: its attention is new, one more attention function
+_SPECS = {"gpt2": _gpt2_spec, "olmoe": _olmoe_spec, "evabyte": _eva_spec,
+          "glm_dsa": _glm_spec, "axk1": _axk1_spec, "afmoe": _afmoe_spec}
 
 
-def _afmoe_block(x, fed, fed_rows, *, i, seq_len, d_model, n_head,
-                 rope_base, name, capacity, afmoe):
-    """One Trinity block (``afmoe``: ``_afmoe_spec``) of the slot-pooled
-    decode graph, no bias anywhere: ``x = x + N(Attn(N(x)))``, then
-    ``x = x + N(FF(N(x)))`` - each sub-layer's output is normed before
-    it is added. Attention over grouped K/V heads (``n_head`` query
-    heads on ``num_key_value_heads``, q and k normed per head), on a
-    layer ``layer_types`` marks ``sliding_attention`` with rotary
-    positions and a window whose pools are rings, on a
-    ``full_attention`` layer with neither; its output is gated by a
-    sigmoid of a projection of the layer's input. Then a dense
-    gated-SiLU feed-forward (layers before ``num_dense_layers``) or
-    sigmoid-routed experts beside a shared one (``MoEFFN``); the pads
-    of a window (rows past ``fed``) are routed nowhere. ``x`` is in the
-    packed view of the window's rows (``_glm_block``); q, k and v are
-    unpacked before their per-head norms, where the S = 1 program's
-    text has them."""
-    pfx = f"{name}_l{i}"
-    T = seq_len
-    n_kv, dh = afmoe["num_key_value_heads"], afmoe["head_dim"]
-    sliding = afmoe["layer_types"][i] == "sliding_attention"
-
-    rows = sym.Reshape(_afmoe_norm(x, f"{pfx}_ln1", afmoe), shape=(-3, 0),
-                       name=f"{pfx}_attn_fold")              # (B*T, D)
-    # q, k, v and the gate as the row blocks of one projection
-    wide = sym.FullyConnected(rows, num_hidden=2 * (n_head + n_kv) * dh,
-                              no_bias=True, name=f"{pfx}_qkvg")
-    at, heads = 0, {}
-    for nm, n in (("q", n_head), ("k", n_kv), ("v", n_kv)):
-        part = sym.slice_axis(wide, axis=1, begin=at, end=at + n * dh,
-                              name=f"{pfx}_{nm}_rows")
-        at += n * dh
-        part = _slots(part, fed, T, f"{pfx}_{nm}_split", shape=(n, dh))
-        if nm != "v":                      # normed per head, over dh
-            part = _afmoe_norm(part, f"{pfx}_{nm}_norm", afmoe)
-        heads[nm] = sym.transpose(part, axes=(0, 2, 1, 3),
-                                  name=f"{pfx}_{nm}")        # (B, n, T, dh)
-    gate = sym.slice_axis(wide, axis=1, begin=at, end=at + n_head * dh,
-                          name=f"{pfx}_gate_rows")
-    att = sym.attention_decode(
-        heads["q"], heads["k"], heads["v"], fed, capacity=capacity,
-        rope=sliding, rope_base=rope_base, per_slot=True, kv_heads=n_kv,
-        fed=True, name=f"{pfx}_attn",
-        **({"window": afmoe["sliding_window"], "ring": afmoe["ring"]}
-           if sliding else {}))
-    att = sym.transpose(att, axes=(0, 2, 1, 3), name=f"{pfx}_attn_t")
-    att = _packed_rows(att, fed, f"{pfx}_attn_merge", fold=(-3, -3))
-    att = att * sym.Activation(gate, act_type="sigmoid",
-                               name=f"{pfx}_gate")
-    proj = sym.FullyConnected(att, num_hidden=d_model, no_bias=True,
-                              name=f"{pfx}_proj")
-    proj = sym.reshape_like(proj, x, name=f"{pfx}_proj_unfold")
-    x = x + _afmoe_norm(proj, f"{pfx}_post_attn_ln", afmoe)
-
-    rows = sym.Reshape(_afmoe_norm(x, f"{pfx}_ln2", afmoe), shape=(-3, 0),
-                       name=f"{pfx}_ffn_fold")
-    if i < afmoe["num_dense_layers"]:
-        h = sym.FullyConnected(rows,
-                               num_hidden=2 * afmoe["intermediate_size"],
-                               no_bias=True, name=f"{pfx}_ffn_gate_up")
-        h = sym.GatedSiLU(h, name=f"{pfx}_ffn_act")
-        h = sym.FullyConnected(h, num_hidden=d_model, no_bias=True,
-                               name=f"{pfx}_ffn_down")
-    else:
-        h = sym.MoEFFN(
-            rows, fed_rows, step_len=T, num_experts=afmoe["num_experts"],
-            num_hidden=afmoe["moe_intermediate_size"],
-            top_k=afmoe["num_experts_per_tok"],
-            norm_topk=afmoe["route_norm"], scoring="sigmoid",
-            router_bias=True, scaling=afmoe["route_scale"],
-            shared_hidden=afmoe["num_shared_experts"]
-            * afmoe["moe_intermediate_size"], name=f"{pfx}_moe")
-    h = sym.reshape_like(h, x, name=f"{pfx}_ffn_unfold")
-    return x + _afmoe_norm(h, f"{pfx}_post_ffn_ln", afmoe)
+def _spec(given, decode):
+    """The record that ``_layer`` and ``_logits`` walk, from the
+    keywords ``get_symbol`` / ``get_decode_symbol`` were called with:
+    those, ``decode``, ``T`` (the rows a slot or sequence has in this
+    graph), the defaults of what most blocks do not have (no ``fed``
+    input, a plain residual add at the compute width, one head, no
+    dropout) and what the block's own constructor
+    (``_SPECS``) makes of them and checks."""
+    make = _SPECS.get(given["block"])
+    if make is None:
+        raise MXNetError(f"block {given['block']!r}: "
+                         + ", ".join(map(repr, _SPECS)))
+    T = given["step_len"] if decode else given["seq_len"]
+    capacity = (given["capacity"] or default_cache_capacity()) if decode \
+        else None
+    return make({
+        # what one of the two graphs has no keyword for
+        "dropout": 0.0, "per_slot": False, **given,
+        "decode": decode, "T": T, "capacity": capacity,
+        "cache_dtype": (given["cache_dtype"] or default_cache_dtype())
+        if decode else None,
+        "max_seq_len": given["max_seq_len"] or capacity or T,
+        "rms_eps": float(given["rms_eps"]),
+        # what most blocks do not have
+        "fed": False, "residual": "plain", "heads": 1, "next_byte": False,
+        "moe": None, "dense": None})
 
 
-def _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps):
-    if block != "evabyte":
-        return None
-    return {"window": int(window), "chunk": int(chunk),
-            "n_pred_heads": int(n_pred_heads),
-            "ffn_width": int(ffn_width or 0), "rms_eps": float(rms_eps)}
-
-
-def _validate(vocab_size, d_model, n_head, pos_embed, block="gpt2",
-              n_expert=None, top_k=None, expert_width=None, eva=None,
-              glm=None, afmoe=None):
-    if block not in ("gpt2", "olmoe", "evabyte", "glm_dsa", "afmoe",
-                     "axk1"):
-        raise MXNetError(f"block {block!r}: 'gpt2', 'olmoe', 'evabyte', "
-                         "'glm_dsa', 'afmoe' or 'axk1'")
-    if block == "afmoe":
-        if afmoe is None:
-            raise MXNetError(
-                "block='afmoe' is served, not trained: its attention "
-                "(grouped K/V heads, a window over rings) exists as the "
-                "decode op alone (get_decode_symbol(per_slot=True))")
-        if n_head % afmoe["num_key_value_heads"] or afmoe["head_dim"] % 2:
-            raise MXNetError(
-                f"block='afmoe': {n_head} query heads on "
-                f"{afmoe['num_key_value_heads']} K/V heads of "
-                f"{afmoe['head_dim']}: the K/V heads divide the query "
-                "heads, and a head's width is even (rotary pairs)")
-        return
-    if block in ("glm_dsa", "axk1"):
-        if glm is None:
-            raise MXNetError(
-                f"block={block!r} is served, not trained: its attention "
-                "exists as the decode ops alone "
-                "(get_decode_symbol(per_slot=True))")
-        return
-    if block == "olmoe":
-        if pos_embed != "rotary":
-            raise MXNetError("block='olmoe' is rotary (no position table)")
-        if not (n_expert and top_k and expert_width) or top_k > n_expert:
-            raise MXNetError(
-                "block='olmoe' needs n_expert >= top_k >= 1 and "
-                f"expert_width (got {n_expert}, {top_k}, {expert_width})")
-    if block == "evabyte":
-        if eva is None:
-            raise MXNetError(
-                "block='evabyte' is served, not trained: its attention "
-                "exists as the decode op alone "
-                "(get_decode_symbol(per_slot=True))")
-        if pos_embed != "rotary":
-            raise MXNetError("block='evabyte' is rotary (no position "
-                             "table)")
-        if not eva["ffn_width"] or eva["n_pred_heads"] < 1:
-            raise MXNetError("block='evabyte' needs ffn_width and "
-                             "n_pred_heads >= 1")
-    if d_model % n_head:
-        raise MXNetError(f"d_model {d_model} must divide n_head {n_head}")
-    if (d_model // n_head) % 2:
-        raise MXNetError("head dim must be even (RoPE rotates pairs)")
-    if pos_embed not in ("rotary", "learned"):
-        raise MXNetError(f"pos_embed {pos_embed!r}: 'rotary' or 'learned'")
-
-
-def _embed(data, tok_w, *, seq_len, vocab_size, d_model, pos_embed,
-           max_seq_len, name, pos_ids=None, per_slot=False,
-           embed_scale=True):
-    """Token embedding (scaled by sqrt(D), transformer convention,
-    unless ``embed_scale=False``) plus the learned position table when
-    ``pos_embed='learned'``. Per-slot decode feeds ``pos_ids`` shaped
-    (B, S) — every slot at its own absolute position — so the
-    looked-up table rows already align with ``x`` and add
+def _embedded(spec):
+    """The head of a graph: ``(x, tok_w, fed, fed_rows)`` - the token
+    embedding (scaled by sqrt(D), transformer convention, unless
+    ``embed_scale=False``), plus the learned position table when
+    ``pos_embed='learned'``, cast where the block's stream is float32.
+    In a fed graph the tokens are embedded in the view the row-wise
+    operations run in (``ops/rows.py``: ``(slots, S, D)``, or
+    one block of the real rows under a budget), ``fed`` as the decode
+    ops take it and ``fed_rows`` as that view's ``MoEFFN`` does. A
+    decode graph with learned positions takes ``pos_ids``; per-slot
+    they are shaped (B, S) - every slot at its own absolute position -
+    so the looked-up table rows already align with ``x`` and add
     elementwise."""
-    scale = {"scale": float(np.sqrt(d_model))} if embed_scale else {}
-    x = sym.Embedding(data=data, weight=tok_w, input_dim=vocab_size,
+    name, d_model = spec["name"], spec["d_model"]
+    data, tok_w = sym.var("data"), sym.var(f"{name}_tok_embed_weight")
+    fed = fed_rows = None
+    if spec["fed"]:
+        fed = sym.var("fed")
+        data, fed_rows = sym.pack_rows(data, fed, name=f"{name}_rows")
+    x = sym.Embedding(data=data, weight=tok_w, input_dim=spec["vocab_size"],
                       output_dim=d_model, name=f"{name}_tok_embed",
-                      **scale)                           # (B, T, D)
-    if pos_embed == "learned":
-        if pos_ids is None:
-            pos_ids = sym._arange(start=0, stop=float(seq_len),
-                                  name=f"{name}_pos_ids")
-        pos_w = sym.var(f"{name}_pos_embed_weight")
-        pos = sym.Embedding(data=pos_ids, weight=pos_w,
-                            input_dim=max_seq_len, output_dim=d_model,
-                            name=f"{name}_pos_embed")    # (T, D) /
-        if per_slot:                                     # (B, S, D)
+                      **({"scale": float(np.sqrt(d_model))}
+                         if spec["embed_scale"] else {}))    # (B, T, D)
+    if spec["pos_embed"] == "learned":
+        pos_ids = sym.var("pos_ids") if spec["decode"] else sym._arange(
+            start=0, stop=float(spec["T"]), name=f"{name}_pos_ids")
+        pos = sym.Embedding(data=pos_ids,
+                            weight=sym.var(f"{name}_pos_embed_weight"),
+                            input_dim=spec["max_seq_len"],
+                            output_dim=d_model,
+                            name=f"{name}_pos_embed")        # (T, D) /
+        if spec["per_slot"]:                                 # (B, S, D)
             x = x + pos
         else:
             pos = sym.expand_dims(pos, axis=0, name=f"{name}_pos_b")
             x = sym.broadcast_add(x, pos, name=f"{name}_add_pos")
-    return x
+    if spec["residual"] == "float32":
+        x = sym.Cast(x, dtype="float32", name=f"{name}_embed_f32")
+    return x, tok_w, fed, fed_rows
 
 
-def _moe_spec(block, n_expert, top_k, expert_width, norm_topk, rms_eps):
-    if block != "olmoe":
-        return None
-    return {"n_expert": int(n_expert), "top_k": int(top_k),
-            "expert_width": int(expert_width),
-            "norm_topk": bool(norm_topk), "rms_eps": float(rms_eps)}
-
-
-def _head(x, tok_w, *, moe, tie_head, vocab_size, name):
-    """Final norm and the output head over the folded (B*T, D) rows:
-    tied to the token embedding (one weight, two gradients), or the
-    untied ``{name}_head_weight`` (vocab, D)."""
-    x = _norm(x, f"{name}_ln_f", moe)
-    flat = sym.Reshape(x, shape=(-3, 0), name=f"{name}_head_fold")
-    if not tie_head:
-        return sym.FullyConnected(
-            flat, weight=sym.var(f"{name}_head_weight"),
-            num_hidden=vocab_size, no_bias=True, name=f"{name}_logits")
-    return sym.dot(flat, tok_w, transpose_b=True,
-                   name=f"{name}_logits")                # (B*T, V)
+def _logits(spec):
+    """Inputs -> embedding -> layers -> final norm -> head: ``(logits
+    over the graph's rows, fed)`` of any block's graph."""
+    x, tok_w, fed, fed_rows = _embedded(spec)
+    carry = None
+    for i in range(spec["n_layer"]):
+        x, carry = _layer(x, fed, fed_rows, carry, i, spec)
+    return _head(x, tok_w, spec), fed
 
 
 def get_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
@@ -670,35 +747,15 @@ def get_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     ``include_loss=False`` returns logits ``(B, seq_len, vocab)`` — the
     decode-parity reference the KV-cache gates compare against.
 
-    ``block="olmoe"`` builds the sparse-expert block (``_block``):
-    ``n_expert`` experts of ``expert_width``, ``top_k`` a token,
-    ``rms_eps``; OLMoE also unties the head (``tie_head=False``) and
-    leaves the embedding unscaled (``embed_scale=False``).
-    ``block="evabyte"`` has no full-sequence graph (its attention is
-    the decode op; the plain full forward is the benchmark's
-    ``chipbench/reference/evabyte.py``).
+    ``block`` is ``"gpt2"`` (``_gpt2_spec``) or ``"olmoe"``
+    (``_olmoe_spec``: ``n_expert`` experts of ``expert_width``,
+    ``top_k`` a token, ``rms_eps``); the other blocks have no
+    full-sequence graph (their attention is decode ops alone; the plain
+    full forward is the benchmark's, ``chipbench/reference/``).
     """
-    _validate(vocab_size, d_model, n_head, pos_embed, block, n_expert,
-              top_k, expert_width)
-    moe = _moe_spec(block, n_expert, top_k, expert_width, norm_topk,
-                    rms_eps)
-    max_seq_len = max_seq_len or seq_len
-    T = seq_len
-
-    data = sym.var("data")
-    tok_w = sym.var(f"{name}_tok_embed_weight")
-    x = _embed(data, tok_w, seq_len=T, vocab_size=vocab_size,
-               d_model=d_model, pos_embed=pos_embed,
-               max_seq_len=max_seq_len, name=name,
-               embed_scale=embed_scale)
-    for i in range(n_layer):
-        x = _block(x, i=i, seq_len=T, d_model=d_model, n_head=n_head,
-                   dropout=dropout, pos_embed=pos_embed,
-                   rope_base=rope_base, name=name, moe=moe)
-    logits = _head(x, tok_w, moe=moe, tie_head=tie_head,
-                   vocab_size=vocab_size, name=name)
+    logits, _fed = _logits(_spec(dict(locals()), decode=False))
     if not include_loss:
-        return sym.Reshape(logits, shape=(-1, T, vocab_size),
+        return sym.Reshape(logits, shape=(-1, seq_len, vocab_size),
                            name=f"{name}_logits_btv")
     return sym.SoftmaxOutput(logits, name="softmax",
                              normalization=normalization)
@@ -740,182 +797,35 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     stays int32 and the default (None) keeps compute-width cells.
 
     ``block``, ``n_expert``, ``top_k``, ``expert_width``, ``norm_topk``,
-    ``rms_eps``, ``tie_head`` and ``embed_scale`` are ``get_symbol``'s:
-    ``block="olmoe"`` is rotary, so the graph has no ``pos_ids`` input.
+    ``rms_eps``, ``tie_head`` and ``embed_scale`` are ``get_symbol``'s.
+    The blocks that are served and not trained (per-slot only; each
+    described at its spec constructor): ``"evabyte"`` (``_eva_spec``:
+    ``window``, ``chunk``, ``n_pred_heads``, ``ffn_width``,
+    ``multibyte``), ``"glm_dsa"`` (``_glm_spec``: ``glm``, the published
+    config's ``GLM_KEYS``), ``"axk1"`` (``_axk1_spec``: ``axk1``,
+    ``AXK1_KEYS``) and ``"afmoe"`` (``_afmoe_spec``: ``afmoe``,
+    ``AFMOE_KEYS``; ``max_step_len``).
 
-    ``block="evabyte"`` (per-slot only) builds EvaByte's block
-    (``_eva_block``): EVA attention over ``window`` exact positions and
-    one summary per ``chunk`` of everything older (``ops/eva.py``), a
-    dense gated-SiLU feed-forward of ``ffn_width``, and an untied head
-    of ``n_pred_heads`` consecutive blocks of ``vocab_size`` columns.
-    Its state is not a row per position, so the graph takes one more
-    input, ``fed`` ``(slots,)`` int32: how many of each slot's
-    ``step_len`` tokens are real. The program advances a slot's state
-    by exactly that. The output is head 0's ``(B, step_len, vocab)``
-    logits - the next byte, what a scheduler samples - or, with
-    ``multibyte``, all heads' ``(B, step_len, n_pred_heads, vocab)``.
-
-    ``block="glm_dsa"`` (per-slot only) builds GLM-5.2's block
-    (``_glm_block``) from ``glm``, the published config's keys
-    (``GLM_KEYS``) and optionally ``held``, the (first, count) of the
-    routed experts this graph holds of ``n_routed_experts``: latent
-    attention over one row of ``kv_lora_rank + qk_rope_head_dim``
-    numbers a position (``ops/mla.py``), attended under the
-    ``index_topk`` positions a learned indexer selects on the layers
-    ``indexer_types`` marks ``"full"`` and the layers marked
-    ``"shared"`` reuse; a dense feed-forward on the first
-    ``first_k_dense_replace`` layers and sigmoid-routed experts beside
-    a shared one after; untied head, unscaled embedding. The graph
-    takes ``fed`` like EvaByte's and advances by it, but its state is a
-    row per position in every pool (``"rows"``), so the driver rewinds,
-    captures and restores it as it does a K/V cache.
-
-    The graphs that take ``fed`` (this one, ``axk1``, ``afmoe``,
-    ``evabyte``) pass between the rows their row-wise operations run
-    over and ``(slots, step_len, .)`` through ``pack_rows`` /
-    ``unpack_rows`` (``ops/rows.py``), which keep all ``slots x
-    step_len`` rows as built here; ``packed_window`` derives the form
-    of a window graph that runs over a budget of real rows.
-
-    ``block="axk1"`` (per-slot only) builds the same block without an
-    indexer from ``axk1``, A.X-K1's published keys (``AXK1_KEYS``) and
-    optionally ``held``: latent attention over every position at or
-    before the query, its rotary under ``rope_scaling`` (YaRN: blended
-    frequencies and a larger softmax scale), and a sigmoid router
-    without a correction bias that chooses inside the ``topk_group``
-    best of ``n_group`` groups of experts. Inputs, state and driver
-    contract are ``glm_dsa``'s.
-
-    ``block="afmoe"`` (per-slot only) builds Trinity's block
-    (``_afmoe_block``) from ``afmoe``, the published config's keys
-    (``AFMOE_KEYS``; ``layer_types`` one entry a layer that is run):
-    ``n_head`` query heads on ``num_key_value_heads`` K/V heads of
-    ``head_dim``, q and k normed per head, the attention output gated;
-    layers marked ``sliding_attention`` are rotary and attend a window
-    of ``sliding_window`` positions, layers marked ``full_attention``
-    have no positions and attend everything; four norms a layer, a
-    scaled embedding (``embed_scale``), a dense feed-forward on the
-    first ``num_dense_layers`` layers and sigmoid-routed experts, all
-    held, beside a shared one after; untied head. **Capacity per kind
-    of layer**: a full layer's pools hold ``capacity`` rows
-    (``"rows"``); a sliding layer's are rings of ``ring_rows(
-    sliding_window, max_step_len)`` rows, whatever the capacity
-    (``"ring"``; a ring that would be as long as the capacity is a pool
-    of a row per position instead). ``max_step_len`` is the largest
-    ``step_len`` of the graphs that share the pools (default: this
-    graph's): every graph of one engine names the same. The graph takes
-    ``fed`` and advances by it; with a ring it is not positional (see
-    ``BatchedKVCacheDecoder``).
+    Their graphs take one more input, ``fed`` ``(slots,)`` int32 - how
+    many of each slot's ``step_len`` tokens are real - and advance a
+    slot's state by exactly that. They pass between the rows their
+    row-wise operations run over and ``(slots, step_len, .)`` through
+    ``pack_rows`` / ``unpack_rows`` (``ops/rows.py``), which keep all
+    ``slots x step_len`` rows as built here; ``packed_window`` derives
+    the form of a window graph that runs over a budget of real rows.
     """
-    eva = _eva_spec(block, window, chunk, n_pred_heads, ffn_width, rms_eps)
-    glm = _glm_spec(block, axk1 if block == "axk1" else glm, n_layer,
-                    rms_eps)
-    capacity = capacity or default_cache_capacity()
-    afmoe = _afmoe_spec(block, afmoe, n_layer, rms_eps, capacity,
-                        max(step_len, max_step_len or 1))
-    _validate(vocab_size, d_model, n_head, pos_embed, block, n_expert,
-              top_k, expert_width, eva=eva, glm=glm, afmoe=afmoe)
-    moe = _moe_spec(block, n_expert, top_k, expert_width, norm_topk,
-                    rms_eps)
-    cache_dtype = cache_dtype or default_cache_dtype()
-    max_seq_len = max_seq_len or capacity
-    S = step_len
-    if eva is not None:
-        if not per_slot or cache_dtype:
-            raise MXNetError("block='evabyte' is the slot-pooled decode "
-                             "graph (per_slot=True) with state at the "
-                             "compute width (no cache_dtype)")
-        return _eva_decode_symbol(
-            vocab_size, d_model, n_layer, n_head, rope_base, capacity, S,
-            name, eva, multibyte)
-    if glm is not None:
-        if not per_slot or cache_dtype:
-            raise MXNetError(f"block={block!r} is the slot-pooled decode "
-                             "graph (per_slot=True) with state at the "
-                             "compute width (no cache_dtype)")
-        return _glm_decode_symbol(vocab_size, d_model, n_layer, n_head,
-                                  rope_base, capacity, S, name, glm)
-    if afmoe is not None:
-        if not per_slot or cache_dtype:
-            raise MXNetError("block='afmoe' is the slot-pooled decode "
-                             "graph (per_slot=True) with state at the "
-                             "compute width (no cache_dtype)")
-        return _afmoe_decode_symbol(vocab_size, d_model, n_layer, n_head,
-                                    rope_base, capacity, S, name, afmoe,
-                                    embed_scale)
-
-    data = sym.var("data")
-    tok_w = sym.var(f"{name}_tok_embed_weight")
-    pos_ids = sym.var("pos_ids") if pos_embed == "learned" else None
-    x = _embed(data, tok_w, seq_len=S, vocab_size=vocab_size,
-               d_model=d_model, pos_embed=pos_embed,
-               max_seq_len=max_seq_len, name=name, pos_ids=pos_ids,
-               per_slot=per_slot, embed_scale=embed_scale)
-    for i in range(n_layer):
-        x = _block(x, i=i, seq_len=S, d_model=d_model, n_head=n_head,
-                   dropout=0.0, pos_embed=pos_embed, rope_base=rope_base,
-                   name=name, decode=True, capacity=capacity,
-                   per_slot=per_slot, cache_dtype=cache_dtype, moe=moe)
-    logits = _head(x, tok_w, moe=moe, tie_head=tie_head,
-                   vocab_size=vocab_size, name=name)
-    return sym.Reshape(logits, shape=(-1, S, vocab_size),
-                       name=f"{name}_logits_bsv")
-
-
-def _glm_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
-                       capacity, S, name, glm):
-    x, fed, fed_rows = _fed_inputs(vocab_size, d_model, name)
-    selection = None
-    for i in range(n_layer):
-        x, selection = _glm_block(
-            x, fed, fed_rows, selection, i=i, seq_len=S, d_model=d_model,
-            n_head=n_head, rope_base=rope_base, name=name,
-            capacity=capacity, glm=glm)
-    flat = sym.Reshape(_glm_norm(x, f"{name}_ln_f", glm), shape=(-3, 0),
-                       name=f"{name}_head_fold")
-    logits = sym.FullyConnected(
-        flat, weight=sym.var(f"{name}_head_weight"), num_hidden=vocab_size,
-        no_bias=True, name=f"{name}_logits")
-    return _slots(logits, fed, S, f"{name}_logits_bsv")
-
-
-def _afmoe_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
-                         capacity, S, name, afmoe, embed_scale):
-    scale = {"scale": float(np.sqrt(d_model))} if embed_scale else {}
-    x, fed, fed_rows = _fed_inputs(vocab_size, d_model, name, **scale)
-    for i in range(n_layer):
-        x = _afmoe_block(x, fed, fed_rows, i=i, seq_len=S, d_model=d_model,
-                         n_head=n_head, rope_base=rope_base, name=name,
-                         capacity=capacity, afmoe=afmoe)
-    flat = sym.Reshape(_afmoe_norm(x, f"{name}_ln_f", afmoe),
-                       shape=(-3, 0), name=f"{name}_head_fold")
-    logits = sym.FullyConnected(
-        flat, weight=sym.var(f"{name}_head_weight"), num_hidden=vocab_size,
-        no_bias=True, name=f"{name}_logits")
-    return _slots(logits, fed, S, f"{name}_logits_bsv")
-
-
-def _eva_decode_symbol(vocab_size, d_model, n_layer, n_head, rope_base,
-                       capacity, S, name, eva, multibyte):
-    x, fed, _fed_rows = _fed_inputs(vocab_size, d_model, name)
-    x = sym.Cast(x, dtype="float32", name=f"{name}_embed_f32")
-    for i in range(n_layer):
-        x = _eva_block(x, fed, i=i, seq_len=S, d_model=d_model,
-                       n_head=n_head, rope_base=rope_base, name=name,
-                       capacity=capacity, eva=eva)
-    flat = sym.Reshape(_eva_norm(x, f"{name}_ln_f", eva), shape=(-3, 0),
-                       name=f"{name}_head_fold")
-    n_pred = eva["n_pred_heads"]
-    logits = sym.FullyConnected(
-        flat, weight=sym.var(f"{name}_head_weight"),
-        num_hidden=n_pred * vocab_size, no_bias=True,
-        name=f"{name}_logits")                               # (B*S, P*V)
-    if multibyte:
-        return _slots(logits, fed, S, f"{name}_logits_bspv",
-                      shape=(n_pred, vocab_size))
-    logits = sym.slice_axis(logits, axis=1, begin=0, end=vocab_size,
-                            name=f"{name}_next_byte")
-    return _slots(logits, fed, S, f"{name}_logits_bsv")
+    spec = _spec(dict(locals()), decode=True)
+    logits, fed = _logits(spec)
+    if fed is None:
+        return sym.Reshape(logits, shape=(-1, step_len, vocab_size),
+                           name=f"{name}_logits_bsv")
+    if multibyte and spec["next_byte"]:
+        return _slots(logits, fed, step_len, f"{name}_logits_bspv",
+                      shape=(spec["heads"], vocab_size))
+    if spec["next_byte"]:
+        logits = sym.slice_axis(logits, axis=1, begin=0, end=vocab_size,
+                                name=f"{name}_next_byte")
+    return _slots(logits, fed, step_len, f"{name}_logits_bsv")
 
 
 class SyntheticLMIter:
@@ -1036,6 +946,21 @@ class KVCacheDecoder:
         return self._mod.get_outputs()[0]
 
 
+def _stateful_nodes(symbol):
+    """``(node, its OpDef, [(aux name, aux variable's name)])`` of every
+    node of a graph whose op declares per-slot state or what it reads
+    (``OpDef.slot_state``, ``state_reads``), in graph order."""
+    for node in symbol._topo_nodes():
+        if node.is_variable:
+            continue
+        opdef = node.opdef()
+        if opdef.slot_state or opdef.state_reads:
+            aux = opdef.aux_names(node.attrs)
+            yield node, opdef, [
+                (nm, var.name) for (var, _), nm in zip(
+                    node.inputs[len(node.inputs) - len(aux):], aux)]
+
+
 def slot_state(symbol):
     """``{family: [aux cell names, in graph order]}`` of a slot-pooled
     decode graph's per-slot state, as its ops declare it
@@ -1044,31 +969,11 @@ def slot_state(symbol):
     (``ops/eva.py``: ``"window"``, ``"summary"``). A cell no op
     declares (``MoEFFN``'s counts) is no slot's state."""
     families = {}
-    for node in symbol._topo_nodes():
-        if node.is_variable or not node.opdef().slot_state:
-            continue
-        opdef = node.opdef()
-        aux = opdef.aux_names(node.attrs)
-        for (var, _), nm in zip(node.inputs[len(node.inputs) - len(aux):],
-                                aux):
+    for _node, opdef, cells in _stateful_nodes(symbol):
+        for nm, var in cells:
             if nm in opdef.slot_state:
-                families.setdefault(opdef.slot_state[nm], []).append(
-                    var.name)
+                families.setdefault(opdef.slot_state[nm], []).append(var)
     return families
-
-
-def sparse_selection(symbol):
-    """``(attention layers, layers with an indexer, index_topk)`` of a
-    graph whose attention reads a selection of positions
-    (``block="glm_dsa"``: ``mla_attention_decode`` under
-    ``dsa_index_select``), None for any other graph."""
-    ops = [(n.op, n.attrs) for n in symbol._topo_nodes()
-           if not n.is_variable]
-    topk = [int(a["topk"]) for op, a in ops if op == "dsa_index_select"]
-    if not topk:
-        return None
-    return (sum(op == "mla_attention_decode" for op, _ in ops), len(topk),
-            topk[0])
 
 
 def packed_rows(slots, step_len):
@@ -1240,11 +1145,7 @@ class BatchedKVCacheDecoder:
         self._cursor_program = None                  # built at first use
         self._row_progs = None                       # capture, restore
         self._select_programs = {}                   # step_len -> program
-        # the routed feed-forwards' per-layer counts of the latest
-        # dispatch (ops/moe.py); empty for a dense decoder
         exe = module._exec_group.executor
-        self._moe_cells = [cell for nm, cell in exe.aux_dict.items()
-                           if nm.endswith("moe_stats")]
         # what every step program takes over and updates in place (the
         # window modules share these cells): 0 = the graph donates none
         self.donated_bytes = sum(
@@ -1260,40 +1161,46 @@ class BatchedKVCacheDecoder:
                         for _nm, cell in self._cells(family))
             for family in self._state}
         self.feeds = "fed" in module.symbol.list_arguments()
+        # what a dispatch reads of the state, as the graph's ops say it
+        # (``OpDef.state_reads``): ``read_counts`` names everything
+        # they count, ``_reads`` holds one ``(executions, f(pos, fed))``
+        # for each (op, attributes) the graph has, however many layers
+        # (a Cerebras graph is one group of 24, GLM-5.2's two), and
+        # ``last_reads`` is their sum for the latest dispatch:
+        # ``{count: integer}``. An op that counts on the device
+        # instead (``MoEFFN``) keeps a cell a layer, ``_moe_cells``,
+        # of ``_moe_counts``' entries.
+        self.read_counts, self._moe_cells, self._moe_counts = {}, [], ()
         self.last_reads = None
-        # a graph that attends a learned selection of positions: what
-        # the latest dispatch read of it (``_selection_reads``)
-        self._sparse = sparse_selection(module.symbol)
-        self.selects = self._sparse is not None
-        self.last_selection = None
-        # ``attention_decode`` layers, whose read walks a slot's pool up
-        # to its cursor: what the latest dispatch read of the pools
-        # (``_attention_reads``)
-        # (window or 0, rows of its pools) of each such layer
-        nodes = [n for n in module.symbol._topo_nodes()
-                 if not n.is_variable]
-        layers = [(int(n.attrs.get("window") or 0),
-                   int(n.attrs.get("ring") or 0) or self.capacity)
-                  for n in nodes if n.op == "attention_decode"]
-        # latent-attention layers that take no selection read a slot's
-        # pool up to its cursor too, like a layer without a window (the
-        # selected ones are ``_selection_reads``')
-        self._latent_layers = sum(
-            n.op == "mla_attention_decode"
-            and not parse_bool(n.attrs.get("selected", True))
-            for n in nodes)
-        layers += [(0, self.capacity)] * self._latent_layers
-        self.attends = bool(layers)
-        self.last_attention = None
-        # what ``_attention_reads`` needs of them: how many, the rows
-        # their pools hold a slot, and the layers of each window
-        windows = [w for w, _rows in layers]
-        self._attn_shape = (
-            len(layers), sum(rows for _w, rows in layers),
-            sorted((w, windows.count(w)) for w in set(windows)))
-        # rings: the shortest one's rows and its window
-        self._ring = min(((rows, w) for w, rows in layers
-                          if rows != self.capacity), default=None)
+        groups, rings = {}, []
+        for node, opdef, cells in _stateful_nodes(module.symbol):
+            for nm, var in cells:
+                if opdef.slot_state.get(nm) == "ring":
+                    rings.append((int(exe.aux_dict[var].shape[2]),
+                                  int(node.attrs["window"])))
+            if opdef.state_reads is None:
+                continue
+            counts, reads = opdef.state_reads
+            if callable(counts):
+                counts = counts(node.attrs)
+            self.read_counts.update(counts)
+            if reads is None:
+                self._moe_counts = tuple(counts)
+                self._moe_cells += [exe.aux_dict[var] for nm, var in cells
+                                    if nm not in opdef.slot_state]
+                continue
+            sources = {
+                nm: src.attrs for nm, (src, _out) in zip(
+                    opdef.input_names(node.attrs), node.inputs)
+                if not src.is_variable and src.opdef().state_reads}
+            key = (node.op, repr(sorted(node.attrs.items())),
+                   repr(sorted(sources.items())))
+            if key not in groups:
+                groups[key] = [0, reads(node.attrs, self.capacity, sources)]
+            groups[key][0] += 1
+        self._reads = [tuple(group) for group in groups.values()]
+        # rings: the shortest one's rows and the window it serves
+        self._ring = min(rings, default=None)
         # seconds the latest ``step`` spent staging and launching and
         # the latest ``select_rows`` took, on the clock its caller
         # handed it (``now=``); None where the caller handed none
@@ -1301,11 +1208,8 @@ class BatchedKVCacheDecoder:
         self._donated = _telemetry.metrics.held_counters(
             "serve.decode.state.donated_bytes", model=name)
         if self.summarises:
-            ring = exe.aux_dict[self._state["window"][0]]
-            pool = exe.aux_dict[self._state["summary"][0]]
-            self.window = int(ring.shape[2])
-            self.chunk = self.capacity // int(pool.shape[2])
-            self.state_layers = len(self._state["window"]) // 2
+            self.window = int(
+                exe.aux_dict[self._state["window"][0]].shape[2])
 
     def add_window(self, step_len, module, packed=None):
         """Register an S-token window module. It MUST have been bound
@@ -1390,16 +1294,17 @@ class BatchedKVCacheDecoder:
             a.copy_to_host_async()
         return arrays
 
-    @staticmethod
-    def moe_stats(arrays):
-        """int64 ``[layer_steps, assignments, experts_touched,
-        max_expert_load]`` summed over the layers of one dispatch (S=1
-        or window: the programs share the cells), from
+    def moe_stats(self, arrays):
+        """``{count: integer}`` (the op's ``state_reads`` names: layer
+        executions, assignments, experts touched, the busiest expert's
+        load, ...) summed over the layers of one dispatch (S=1 or
+        window: the programs share the cells), from
         ``moe_stats_begin``'s arrays. Read it once the dispatch's logits
         are on the host: the program has then finished, and nothing
         further is waited for."""
-        return np.sum(np.asarray([np.asarray(a) for a in arrays],
-                                 np.int64), axis=0)
+        total = np.sum(np.asarray([np.asarray(a) for a in arrays],
+                                  np.int64), axis=0)
+        return dict(zip(self._moe_counts, total.tolist()))
 
     def free_slots(self):
         """Slot indices with no active sequence."""
@@ -1711,7 +1616,7 @@ class BatchedKVCacheDecoder:
                     raise MXNetError("step(fed=...): this graph has no fed "
                                      "input; it advances every slot by S")
                 advance = S          # the program advances EVERY slot
-                self.last_attention = self._attention_reads(
+                self.last_reads = self._dispatch_reads(
                     np.where(self.active, S, 0))
             else:
                 packed = None if fed is None else self._packed.get(S)
@@ -1728,9 +1633,7 @@ class BatchedKVCacheDecoder:
                                          fed, 0)
                 if packed is not None and fed.sum() <= packed[2]:
                     mod, stage, self.last_program_rows = packed
-                self.last_reads = self._state_reads(fed)
-                self.last_selection = self._selection_reads(fed)
-                self.last_attention = self._attention_reads(fed)
+                self.last_reads = self._dispatch_reads(fed)
             if self.name is not None:    # the pools' bytes a dispatch
                 self._donated()[0].inc(self.donated_bytes)
             hosts = [tokens]
@@ -1749,67 +1652,14 @@ class BatchedKVCacheDecoder:
             else (t1 - t0, now() - t1)
         return out
 
-    def _attention_reads(self, fed):
+    def _dispatch_reads(self, fed):
         """What one dispatch that feeds ``fed`` tokens a slot reads of
-        the ``attention_decode`` pools and of the latent pools of
-        ``mla_attention_decode`` layers without a selection, from the
-        cursors alone (no fetch), summed over the fed slots and the
-        layers: ``[positions at or before each slot's last query, rows
-        the pools hold (slots x capacity, or x a ring's rows), positions
-        that query attends (on a sliding layer at most its window), of
-        those the latent rows, the (query, key) pairs of ALL the fed
-        queries on the latent layers - query t of a slot at position p
-        attends p + t + 1 keys]``. The first over the second is the
-        share of a pool that a dispatch has any use for; the third over
-        the first the share of the keys that the windows leave; the
-        fourth and fifth are there only where the graph has such latent
-        layers (at S = 1 they are equal). None for a graph without
-        either kind."""
-        if not self.attends:
-            return None
-        live = np.minimum((self.pos + fed)[fed > 0], self.capacity)
-        layers, pool_rows, by_window = self._attn_shape
-        total = np.sum(live)
-        reads = [layers * total, self.slots * pool_rows,
-                 sum(n * (np.sum(np.minimum(live, w)) if w else total)
-                     for w, n in by_window)]
-        if self._latent_layers:
-            n = np.asarray(fed, np.int64)[fed > 0]
-            reads += [self._latent_layers * total, self._latent_layers
-                      * np.sum(n * self.pos[fed > 0] + n * (n + 1) // 2)]
-        return np.asarray(reads, np.int64)
-
-    def _selection_reads(self, fed):
-        """What one dispatch that feeds ``fed`` tokens a slot reads
-        under a learned selection, from the cursors alone (no fetch),
-        for each fed slot's last real query, summed over the slots and
-        the layers: ``[attention layer executions, positions at or
-        before the query (what attention without a selection would
-        read), positions attended (at most ``index_topk``), index keys
-        scored (layers with an indexer)]``."""
-        if self._sparse is None:
-            return None
-        layers, indexed, topk = self._sparse
-        live = (self.pos + fed)[fed > 0]
-        return np.asarray(
-            [layers, layers * np.sum(live),
-             layers * np.sum(np.minimum(live, topk)),
-             indexed * np.sum(live)], np.int64)
-
-    def _state_reads(self, fed):
-        """What one dispatch that feeds ``fed`` tokens a slot reads and
-        writes of a window-and-summaries state, from the cursors alone
-        (no fetch), summed over the slots that are fed and over the
-        layers: ``[layer executions, exact rows and summaries that each
-        slot's last real query attends, chunks summarised, windows
-        closed]``."""
-        if not self.summarises:
-            return None
-        W, C = self.window, self.chunk
-        live = fed > 0
-        start, end = self.pos[live], (self.pos + fed)[live]
-        last = end - 1
-        return self.state_layers * np.asarray(
-            [1, np.sum(last % W + 1), np.sum(last // W * (W // C)),
-             np.sum(end // C - start // C),
-             np.sum(end // W - start // W)], np.int64)
+        the state, from the cursors alone (no fetch): the sum over the
+        graph's stateful layers of what each op declares
+        (``OpDef.state_reads``), a few numpy operations for each kind
+        of layer and none for each layer."""
+        total = {}
+        for executions, reads in self._reads:
+            for count, value in reads(self.pos, fed).items():
+                total[count] = total.get(count, 0) + executions * value
+        return total

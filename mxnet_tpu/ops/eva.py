@@ -67,13 +67,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..base import MXNetError, parse_float, parse_int
 from . import pallas_kernels as _pk
-from .registry import register
+from .registry import read_counts, register
 
 __all__ = ["eva_pool", "eva_attend", "gated_silu"]
 
@@ -673,6 +674,32 @@ EVA_SLOT_STATE = {"singles_k": "window", "singles_v": "window",
                   "summary_k": "summary", "summary_v": "summary",
                   "cache_pos": "cursor"}
 
+#: what one execution reads and writes of the state
+#: (``OpDef.state_reads``): the exact rows and the summaries that each
+#: fed slot's last real query attends, the chunks summarised and the
+#: windows closed
+_EVA_COUNTS = read_counts(
+    ("eva.layer_steps", None), ("eva.exact_rows", "eva_exact"),
+    ("eva.summary_rows", "eva_summary"), ("eva.chunks_summarised", None),
+    ("eva.windows_closed", None))
+
+
+def _eva_reads(attrs, capacity, sources):
+    _, W, C = _geometry(attrs)
+
+    def reads(pos, fed):
+        live = fed > 0
+        start, end = pos[live], (pos + fed)[live]
+        last = end - 1
+        return {"eva.layer_steps": 1,
+                "eva.exact_rows": int(np.sum(last % W + 1)),
+                "eva.summary_rows": int(np.sum(last // W * (W // C))),
+                "eva.chunks_summarised": int(np.sum(end // C - start // C)),
+                "eva.windows_closed": int(np.sum(end // W - start // W))}
+
+    return reads
+
+
 register("eva_attention_decode",
          inputs=("q", "k", "v", "fed", "phi", "mu"),
          aux=tuple(EVA_SLOT_STATE), full=_eva_fwd, stateful_infer=True,
@@ -681,7 +708,8 @@ register("eva_attention_decode",
                     "window": (parse_int, None),
                     "chunk": (parse_int, None),
                     "rope_base": (parse_float, 10000.0)},
-         slot_state=EVA_SLOT_STATE, donate_aux=True,
+         slot_state=EVA_SLOT_STATE, state_reads=(_EVA_COUNTS, _eva_reads),
+         donate_aux=True,
          variants={"pallas": (_eva_pallas, _eva_eligible, _EVA_KSPEC)},
          doc="EVA chunked linearized attention over a per-slot decode "
              "state of exact window rows and chunk summaries "

@@ -74,7 +74,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..base import MXNetError, parse_bool, parse_float, parse_int
 from . import pallas_kernels as _pk
 from .moe import cpu_wide, rms_norm
-from .registry import register
+from .registry import read_counts, register
 
 __all__ = ["rope_interleaved", "yarn_inv_freq", "yarn_mscale",
            "RopeScaling", "dsa_scores", "dsa_threshold_mask", "latent_width"]
@@ -465,6 +465,25 @@ _DSA_KSPEC = {
     "dtypes": ("float32", "bfloat16"),
 }
 
+#: what a dispatch reads under a learned selection
+#: (``OpDef.state_reads``), for each fed slot's last real query: the
+#: index keys the indexer scores (here), and on every attention layer
+#: under the selection (``mla_attention_decode``) the positions at or
+#: before the query - what attention without a selection would read -
+#: and those it attends, at most the ``topk`` of the indexer that made
+#: the selection
+_DSA_COUNTS = read_counts(
+    ("dsa.layer_steps", None), ("dsa.live_rows", None),
+    ("dsa.selected_rows", "dsa_selected"), ("dsa.scored_rows", "dsa_scored"))
+
+
+def _index_reads(attrs, capacity, sources):
+    def reads(pos, fed):
+        return {"dsa.scored_rows": int(np.sum((pos + fed)[fed > 0]))}
+
+    return reads
+
+
 register("dsa_index_select", inputs=("q", "k", "weights", "fed"),
          aux=tuple(DSA_SLOT_STATE), full=_index_fwd, stateful_infer=True,
          aux_dtypes={"cache_pos": "int32"}, infer_shape=_index_infer,
@@ -474,7 +493,8 @@ register("dsa_index_select", inputs=("q", "k", "weights", "fed"),
                     "rope_dim": (parse_int, None),
                     "topk": (parse_int, None),
                     "rope_base": (parse_float, 10000.0)},
-         slot_state=DSA_SLOT_STATE, donate_aux=True,
+         slot_state=DSA_SLOT_STATE,
+         state_reads=(_DSA_COUNTS, _index_reads), donate_aux=True,
          variants={"pallas": (_index_pallas, _index_eligible, _DSA_KSPEC)},
          doc="DSA lightning indexer over a per-slot pool of index keys: "
              "the top-k positions of every query, as a mask (ops/mla.py).")
@@ -825,6 +845,41 @@ _MLA_KSPEC = {
     "dtypes": ("float32", "bfloat16"),
 }
 
+#: a latent layer without a selection reads a slot's pool up to its
+#: cursor like ``attention_decode`` without a window and counts under
+#: the same names; the ring record carries its share of the attended
+#: rows as ``mla_attended`` and the (query, key) pairs of ALL its fed
+#: queries as ``mla_pairs`` (query t of a slot at position p attends
+#: p + t + 1 keys; at S = 1 the two are equal)
+_MLA_COUNTS = read_counts(
+    ("attn.live_rows", "attn_live"), ("attn.capacity_rows", None),
+    ("attn.attended_rows", "attn_attended"), (None, "mla_attended"),
+    (None, "mla_pairs"))
+
+
+def _mla_reads(attrs, capacity, sources):
+    if _mla_selected(attrs):
+        topk = parse_int(sources["selection"]["topk"])
+
+        def reads(pos, fed):
+            live = (pos + fed)[fed > 0]
+            return {"dsa.layer_steps": 1, "dsa.live_rows": int(live.sum()),
+                    "dsa.selected_rows": int(np.minimum(live, topk).sum())}
+
+        return reads
+
+    def reads(pos, fed):
+        on = fed > 0
+        total = int(np.minimum((pos + fed)[on], capacity).sum())
+        n = fed[on]
+        return {"attn.live_rows": total,
+                "attn.capacity_rows": pos.size * capacity,
+                "attn.attended_rows": total, "mla_attended": total,
+                "mla_pairs": int(np.sum(n * pos[on] + n * (n + 1) // 2))}
+
+    return reads
+
+
 register("mla_attention_decode", inputs=_mla_inputs,
          aux=tuple(MLA_SLOT_STATE), full=_mla_fwd, stateful_infer=True,
          aux_dtypes={"cache_pos": "int32"}, infer_shape=_mla_infer,
@@ -843,7 +898,10 @@ register("mla_attention_decode", inputs=_mla_inputs,
                     "rope_beta_slow": (parse_float, 1.0),
                     "rope_mscale": (parse_float, 1.0),
                     "rope_mscale_all_dim": (parse_float, 0.0)},
-         slot_state=MLA_SLOT_STATE, donate_aux=True,
+         slot_state=MLA_SLOT_STATE,
+         state_reads=(lambda attrs: _DSA_COUNTS if _mla_selected(attrs)
+                      else _MLA_COUNTS, _mla_reads),
+         donate_aux=True,
          variants={"pallas": (_mla_pallas, _mla_eligible, _MLA_KSPEC)},
          doc="Multi-head latent attention over a per-slot pool of latent "
              "rows, under a selection of positions or over every position "

@@ -77,7 +77,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..base import parse_bool, parse_float, parse_int
-from .registry import register
+from .registry import read_counts, register
 
 __all__ = ["rms_norm", "moe_route", "moe_sort", "moe_combine",
            "moe_route_sigmoid", "cpu_wide"]
@@ -423,9 +423,21 @@ def _moe_inputs(attrs):
     return names
 
 
+#: the entries of ``moe_stats``, in its order (``OpDef.state_reads``):
+#: layer executions, the (token, expert) assignments they made, the
+#: experts that got at least one, each execution's busiest expert's
+#: assignments and, where the layer holds a share of its experts (the
+#: counts are then of the held ones), the assignments that landed on
+#: them
+_MOE_COUNTS = read_counts(
+    ("moe.layer_steps", "moe_layer_steps"), ("moe.assignments", None),
+    ("moe.experts_touched", "moe_touched"), ("moe.max_expert_load", None),
+    ("moe.held_assignments", None))
+
 register("MoEFFN", inputs=_moe_inputs, aux=("moe_stats",), full=_moe_fwd,
          num_outputs=2, output_names=["output", "experts"], num_visible=1,
          stateful_infer=True, aux_dtypes={"moe_stats": "int32"},
+         state_reads=(_MOE_COUNTS, None),
          attr_spec={"num_experts": (parse_int, None),
                     "num_hidden": (parse_int, None),
                     "top_k": (parse_int, 1),

@@ -32,7 +32,8 @@ from __future__ import annotations
 
 from ..base import MXNetError
 
-__all__ = ["OpDef", "register", "get_op", "list_ops", "OP_REGISTRY"]
+__all__ = ["OpDef", "register", "get_op", "list_ops", "OP_REGISTRY",
+           "read_counts"]
 
 OP_REGISTRY = {}
 
@@ -102,10 +103,32 @@ class OpDef:
         read by; ``"rows"`` is a pool with one row per position
         (``[slot, :, position]``: a prefix of it can be captured, copied
         back and reused, and the cursor may be set anywhere below it);
-        any other family (``ops/eva.py``'s ``"window"``, ``"summary"``)
+        any other family (``ops/eva.py``'s ``"window"``, ``"summary"``;
+        ``"ring"``, the last rows of an op whose ``window`` attribute
+        says how many of them it attends)
         is state the cursor alone does not index. The cache driver and
         ``DecodeEngine.migrate`` find the cells through this, never by
         their names.
+    state_reads : beside ``slot_state``, ``(counts, reads)``: what one
+        execution of the op reads of that state, so that neither the
+        cache driver nor the scheduler knows an attention by its name.
+        ``counts`` (``read_counts``; or callable(attrs) -> that, as
+        ``inputs`` may be) names each count once, with the counter
+        ``serve.decode.<counter>`` it increments and the field of the
+        ring's ``serve.decode.step`` record it is written to.
+        ``reads(attrs, capacity, sources)`` gives ``f(pos, fed) ->
+        {count: integer}`` for one execution over pools of ``capacity``
+        positions, vectorised over the slots - ``pos`` the (slots,)
+        cursors before the dispatch, ``fed`` the tokens each slot is
+        fed (0: it reads nothing) - from the host's mirror of the
+        cursors alone: no fetch, no synchronisation. ``sources`` is
+        ``{input name: attributes}`` of the inputs that are the output
+        of another op with ``state_reads`` (a selection and the
+        ``topk`` of the indexer that made it). The driver adds the
+        dictionaries of a graph's nodes up a dispatch
+        (``BatchedKVCacheDecoder.last_reads``). ``reads=None``: the op
+        counts on the device, into its one aux cell that is no slot's
+        state, a vector in the order of ``counts`` (``MoEFFN``).
     donate_aux : the op's aux arrays are large and updated a few rows
         at a time: the executor donates every aux array of a graph that
         holds such an op to its inference program (``Executor
@@ -122,7 +145,8 @@ class OpDef:
                  is_loss=False, mutate_inputs=(), num_visible=None,
                  shape_passthrough=False, variants=None, flops=None,
                  bytes_moved=None, stateful_infer=False, aux_dtypes=None,
-                 slot_state=None, donate_aux=False, doc=""):
+                 slot_state=None, state_reads=None, donate_aux=False,
+                 doc=""):
         self.name = name
         self.forward = forward
         self.variants = {}
@@ -149,6 +173,7 @@ class OpDef:
         self.stateful_infer = bool(stateful_infer)
         self.aux_dtypes = dict(aux_dtypes or {})
         self.slot_state = dict(slot_state or {})
+        self.state_reads = state_reads
         self.donate_aux = bool(donate_aux)
         self.shape_passthrough = bool(shape_passthrough)
         self.doc = doc
@@ -283,6 +308,13 @@ class OpDef:
 
     def __repr__(self):
         return f"OpDef({self.name})"
+
+
+def read_counts(*counts):
+    """``OpDef.state_reads``' table ``{count: (counter, ring field)}``
+    from ``(counter or None, ring field or None)`` pairs: a count goes
+    by its counter's name, or by its ring field where it has none."""
+    return {counter or field: (counter, field) for counter, field in counts}
 
 
 def _validate_infer_signature(op_name, what, fn):

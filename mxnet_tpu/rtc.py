@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .base import MXNetError
-from .ops.registry import register as _register_op, OP_REGISTRY
+from .ops.registry import (register as _register_op, OP_REGISTRY,
+                           read_counts)
 # the production kernels (and the shared interpret-gated pallas_call)
 # live in ops/pallas_kernels.py; rtc re-exports the public surfaces so
 # the reference-shaped mx.rtc API is unchanged
@@ -1080,6 +1081,35 @@ def _cache_dtype_of(attrs):
     return _CACHE_DTYPE_ALIASES.get(val, val)
 
 
+#: what one execution reads of its K/V pools, for each fed slot's last
+#: query (``OpDef.state_reads``): the positions at or before it, the
+#: rows the pools hold and the positions it attends. live / capacity is
+#: the share of the pools the traffic keeps live, attended / live what
+#: the windows leave of the keys
+_ATTENTION_DECODE_COUNTS = read_counts(
+    ("attn.live_rows", "attn_live"), ("attn.capacity_rows", None),
+    ("attn.attended_rows", "attn_attended"))
+
+
+def _attention_decode_reads(attrs, capacity, sources):
+    """Live rows are clipped to the capacity (a slot with no room
+    writes nothing and stays); the pools' rows are counted for every
+    slot, fed or not: ``slots x capacity``, or ``x`` a ring's rows; a
+    sliding layer attends at most its window."""
+    window = int(attrs.get("window") or 0)
+    rows = int(attrs.get("ring") or 0) or capacity
+
+    def reads(pos, fed):
+        live = np.minimum((pos + fed)[fed > 0], capacity)
+        total = int(live.sum())
+        return {"attn.live_rows": total,
+                "attn.capacity_rows": pos.size * rows,
+                "attn.attended_rows":
+                    int(np.minimum(live, window).sum()) if window else total}
+
+    return reads
+
+
 def _register_attention_decode():
     if "attention_decode" in OP_REGISTRY:
         return
@@ -1099,6 +1129,8 @@ def _register_attention_decode():
                  slot_state={"k_cache": "rows", "v_cache": "rows",
                              "k_ring": "ring", "v_ring": "ring",
                              "cache_pos": "cursor"},
+                 state_reads=(_ATTENTION_DECODE_COUNTS,
+                              _attention_decode_reads),
                  attr_spec={"capacity": (int, 256),
                             "rope": (None, False),
                             "rope_base": (float, 10000.0),

@@ -78,6 +78,7 @@ histograms, and one flight-ring record per iteration.
 """
 from __future__ import annotations
 
+import collections
 import itertools
 import logging
 import os
@@ -730,52 +731,6 @@ class DecodeEngine:
         sdrv.active[:] = False
 
 
-#: ``serve.decode.<name>`` counters of a routed decoder, in the order
-#: of ``BatchedKVCacheDecoder.moe_stats``: MoEFFN layer executions, the
-#: (token, expert) assignments they made, the experts that got at least
-#: one, each execution's busiest expert's assignments and, where the
-#: layer holds a share of its experts (the counts are then of the held
-#: ones), the assignments that landed on them
-_MOE_COUNTERS = ("moe.layer_steps", "moe.assignments",
-                 "moe.experts_touched", "moe.max_expert_load",
-                 "moe.held_assignments")
-
-#: ``serve.decode.<name>`` counters of a decoder whose state is a window
-#: of exact rows beside summaries, in the order of
-#: ``BatchedKVCacheDecoder._state_reads``: attention layer executions,
-#: the exact rows and the summaries that each fed slot's last real query
-#: attends (per slot, layer and dispatch), the chunks summarised and the
-#: windows closed (per slot and layer); from the host's cursors, no fetch
-_EVA_COUNTERS = ("eva.layer_steps", "eva.exact_rows", "eva.summary_rows",
-                 "eva.chunks_summarised", "eva.windows_closed")
-
-
-#: ``serve.decode.<name>`` counters of a decoder that attends a learned
-#: selection of positions, in the order of ``BatchedKVCacheDecoder
-#: ._selection_reads``: attention layer executions, the positions at or
-#: before each fed slot's last real query, those it attends (at most
-#: ``index_topk``) and the index keys scored for it (per slot, layer
-#: and dispatch); from the host's cursors, no fetch
-_DSA_COUNTERS = ("dsa.layer_steps", "dsa.live_rows", "dsa.selected_rows",
-                 "dsa.scored_rows")
-
-
-#: ``serve.decode.<name>`` counters of a decoder with ``attention_decode``
-#: layers, in the order of ``BatchedKVCacheDecoder._attention_reads``:
-#: the positions at or before each fed slot's last query, the rows the
-#: pools hold and the positions that query attends - all of them, or on
-#: a sliding layer at most its window - (per slot, layer and dispatch);
-#: from the host's cursors, no fetch. live / capacity is the share of
-#: the pools the traffic keeps live, attended / live what the windows
-#: leave of the keys. Latent-attention layers without a selection are
-#: counted here too (they read a slot's pool up to its cursor), and the
-#: ring record carries their share of the attended rows as
-#: ``mla_attended`` and the (query, key) pairs of all their fed queries
-#: as ``mla_pairs``
-_ATTN_COUNTERS = ("attn.live_rows", "attn.capacity_rows",
-                  "attn.attended_rows")
-
-
 #: ``serve.decode.<name>`` counters of the S > 1 window dispatches, from
 #: the plan (no fetch): the slots fed at least one row, and those fed
 #: exactly one - a decoding slot riding a window in which another
@@ -938,18 +893,11 @@ class DecodeScheduler:
                        ("iterations", "tokens", "prefill.chunks",
                         "fetch.bytes", "sample.device", "sample.host")
                        + _WINDOW_COUNTERS}
-            if self.engine.driver(self._rung).routed:
-                handles.update({k: self._counter(k)
-                                for k in _MOE_COUNTERS})
-            if self.engine.driver(self._rung).summarises:
-                handles.update({k: self._counter(k)
-                                for k in _EVA_COUNTERS})
-            if self.engine.driver(self._rung).selects:
-                handles.update({k: self._counter(k)
-                                for k in _DSA_COUNTERS})
-            if self.engine.driver(self._rung).attends:
-                handles.update({k: self._counter(k)
-                                for k in _ATTN_COUNTERS})
+            # what the graph's ops count of a dispatch (the driver's
+            # ``read_counts``: ``OpDef.state_reads``)
+            handles.update({k: self._counter(k) for k, _field in
+                            self.engine.driver(self._rung)
+                            .read_counts.values() if k})
             handles.update({k: self._gauge(k) for k in
                             ("active", "occupancy", "queue.depth")})
             handles["step.seconds"] = _telemetry.histogram(
@@ -1229,26 +1177,21 @@ class DecodeScheduler:
         are what is left of it.
         ``fed`` (a decoder that is fed: the real tokens of each slot)
         rides in the same put as the tokens; what the dispatch reads of
-        a window-and-summaries state adds up in ``phases["eva"]``, of a
-        learned selection in ``phases["dsa"]``, of ``attention_decode``
-        pools in ``phases["attn"]``.
+        the state, as the graph's ops count it (``drv.last_reads``,
+        ``drv.moe_stats``), adds up in ``phases["reads"]``, a
+        ``Counter``.
         Returns ``(ids, logits, end)``: ``logits`` is the selected rows,
         the whole output, or None."""
         now = self._clock.now
         if t is None:
             t = now()
+        reads = phases["reads"]
         with _telemetry.span("serve.decode.iter.dispatch"):
             if fed is None:
                 out = drv.step(tokens, now=now)
             else:
                 out = drv.step(tokens, fed=fed, now=now)
-                if drv.last_reads is not None:
-                    phases["eva"] = phases.get("eva", 0) + drv.last_reads
-                if drv.last_selection is not None:
-                    phases["dsa"] = phases.get("dsa", 0) \
-                        + drv.last_selection
-            if drv.last_attention is not None:
-                phases["attn"] = phases.get("attn", 0) + drv.last_attention
+            reads.update(drv.last_reads)
             phases["stage"] += drv.last_stage
             phases["launch"] += drv.last_launch
             if last is not None:
@@ -1275,8 +1218,7 @@ class DecodeScheduler:
                 nbytes = ids.nbytes + (logits.nbytes if rows else 0)
             if routed is not None:
                 with _telemetry.span("serve.decode.iter.moe_stats"):
-                    phases["moe"] = phases.get("moe", 0) \
-                        + drv.moe_stats(routed)
+                    reads.update(drv.moe_stats(routed))
                 nbytes += sum(a.nbytes for a in routed)
         end = now()
         phases["dispatch"] += t_launched - t
@@ -1411,7 +1353,8 @@ class DecodeScheduler:
         # the program runs (only pump()/the dispatch thread iterates,
         # so the engine itself needs no second guard)
         phases = {"dispatch": 0.0, "fetch": 0.0, "bytes": 0, "stage": 0.0,
-                  "launch": 0.0, "select": 0.0, "ids": 0.0}
+                  "launch": 0.0, "select": 0.0, "ids": 0.0,
+                  "reads": collections.Counter()}
         if mode == "spec":
             verdicts = self._dispatch_spec(
                 drv, ddrv, tokens, [(r, s) for r, s in meta], S, phases)
@@ -1485,17 +1428,15 @@ class DecodeScheduler:
                     m["window.riding_slots"].inc(sum(n == 1 for n in rows))
                     m["window.real_rows"].inc(sum(rows))
                     m["window.program_rows"].inc(drv.last_program_rows)
-                # what the dispatches counted: a routed decoder's
-                # experts, a window-and-summaries state's reads, a
-                # learned selection's
-                moe, eva, dsa, attn = (phases.get(k) for k in
-                                       ("moe", "eva", "dsa", "attn"))
-                for names, counts in (
-                        (_MOE_COUNTERS, moe), (_EVA_COUNTERS, eva),
-                        (_DSA_COUNTERS, dsa), (_ATTN_COUNTERS, attn)):
-                    for key, value in zip(names, () if counts is None
-                                          else counts):
-                        m[key].inc(int(value))
+                # what the dispatches read of the state, under the
+                # names its ops gave: a counter, a ring field, or both
+                read_fields = {}
+                for key, value in phases["reads"].items():
+                    counter, field = drv.read_counts[key]
+                    if counter:
+                        m[counter].inc(value)
+                    if field:
+                        read_fields[field] = value
                 m["step.seconds"].observe(step_s)
                 m["active"].set(n_active)
                 m["occupancy"].set(n_active / self._rung)
@@ -1518,22 +1459,7 @@ class DecodeScheduler:
                     turn_us=0 if turn_from is None
                     else _us(arrived - turn_from),
                     lock_us=_us(now - arrived), mode=mode, window=S,
-                    compiles_since_warmup=compiles,
-                    **({} if moe is None else
-                       {"moe_layer_steps": int(moe[0]),
-                        "moe_touched": int(moe[2])}),
-                    **({} if eva is None else
-                       {"eva_exact": int(eva[1]),
-                        "eva_summary": int(eva[2])}),
-                    **({} if dsa is None else
-                       {"dsa_selected": int(dsa[2]),
-                        "dsa_scored": int(dsa[3])}),
-                    **({} if attn is None else
-                       {"attn_live": int(attn[0]),
-                        "attn_attended": int(attn[2])}),
-                    **({} if attn is None or len(attn) < 5 else
-                       {"mla_attended": int(attn[3]),
-                        "mla_pairs": int(attn[4])}))
+                    compiles_since_warmup=compiles, **read_fields)
         return max(1, emitted)
 
     def _commit_window(self, meta, ids, picked, S, t0, end, shared_sid,

@@ -227,8 +227,10 @@ def test_attended_rows_are_counted_from_the_cursors(driver):
     # slots at 32, 32, 24 fed 1, 0, 1: last queries see 33 and 25 keys,
     # of which a sliding layer attends 16
     live, attended = 33 + 25, 3 * 2 * 16 + 33 + 25
-    assert list(driver.last_attention) == [
-        4 * live, SLOTS * (3 * RING + CAPACITY), attended]
+    assert driver.last_reads == {
+        "attn.live_rows": 4 * live,
+        "attn.capacity_rows": SLOTS * (3 * RING + CAPACITY),
+        "attn.attended_rows": attended}
 
 
 def test_a_sliced_heads_logits_are_the_uncut_heads_first_columns():

@@ -220,7 +220,11 @@ def test_the_graph_has_no_indexer_and_no_selection():
     args = symbol.list_arguments()
     assert not [a for a in args if "idx" in a or a.endswith("router_bias")]
     assert "lm_l1_moe_router_weight" in args
-    assert tfm.sparse_selection(symbol) is None
+    # no layer of it counts under a selection
+    assert not [count for node, opdef, _cells in tfm._stateful_nodes(symbol)
+                if node.op == "mla_attention_decode"
+                for count in opdef.state_reads[0](node.attrs)
+                if count.startswith("dsa.")]
     with pytest.raises(mx.MXNetError, match="n_group"):
         tfm.get_decode_symbol(per_slot=True, block="axk1", axk1={})
 
@@ -231,18 +235,22 @@ def test_latent_rows_attended_are_counted_from_the_cursors(driver):
     # slots at 32, 32, 16 fed 1, 0, 1: last queries see 33 and 17 keys,
     # and at S = 1 the pairs of all fed queries are those keys
     layers = 3
-    assert driver.last_selection is None and not driver.selects
-    assert list(driver.last_attention) == [
-        layers * (33 + 17), SLOTS * layers * CAPACITY, layers * (33 + 17),
-        layers * (33 + 17), layers * (33 + 17)]
-    assert driver.attends and driver.positional and driver.feeds
+    assert driver.last_reads == {
+        "attn.live_rows": layers * (33 + 17),
+        "attn.capacity_rows": SLOTS * layers * CAPACITY,
+        "attn.attended_rows": layers * (33 + 17),
+        "mla_attended": layers * (33 + 17), "mla_pairs": layers * (33 + 17)}
+    assert driver.read_counts["mla_pairs"] == (None, "mla_pairs")
+    assert not [c for c in driver.read_counts if c.startswith("dsa.")]
+    assert driver.positional and driver.feeds
     # a window that feeds 16, 0 and 5 rows at 33, 32 and 17: the last
     # queries see 49 and 22 keys; query t of a slot at p sees p + t + 1
     driver.step(np.zeros((SLOTS, WINDOW), np.int32), fed=[16, 0, 5])
     pairs = sum(33 + t + 1 for t in range(16)) \
         + sum(17 + t + 1 for t in range(5))
-    assert list(driver.last_attention)[3:] == [layers * (49 + 22),
-                                               layers * pairs]
+    assert (driver.last_reads["mla_attended"],
+            driver.last_reads["mla_pairs"]) == (layers * (49 + 22),
+                                                layers * pairs)
     # the row programs clamp what they cannot reach: the host refuses it
     with pytest.raises(mx.MXNetError, match="slot"):
         driver.capture_rows(SLOTS, 4)

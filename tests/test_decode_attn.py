@@ -495,17 +495,19 @@ def test_driver_counts_live_rows_from_its_cursors(monkeypatch):
     dec.init_params(initializer=None, arg_params=args, aux_params={},
                     allow_missing=True)
     drv = tfm.BatchedKVCacheDecoder(dec, capacity=CAP, slots=3)
-    assert drv.attends and drv.last_attention is None
+    assert drv.last_reads is None and sorted(drv.read_counts) == [
+        "attn.attended_rows", "attn.capacity_rows", "attn.live_rows"]
     drv.join(0)
     drv.join(2)
     drv.rewind(2, 9)
     drv.step(np.zeros((3, 1), np.int32))
     # slot 0 reads row 0, slot 2 rows 0-9; slot 1 is nobody's
-    assert drv.last_attention.tolist() == [L * (1 + 10), L * 3 * CAP,
-                                           L * (1 + 10)]
+    assert drv.last_reads == {"attn.live_rows": L * (1 + 10),
+                              "attn.capacity_rows": L * 3 * CAP,
+                              "attn.attended_rows": L * (1 + 10)}
     drv.leave(0)
     drv.step(np.zeros((3, 1), np.int32))
-    assert drv.last_attention.tolist() == [L * 11, L * 3 * CAP, L * 11]
+    assert list(drv.last_reads.values()) == [L * 11, L * 3 * CAP, L * 11]
 
 
 def test_scheduler_registers_the_attention_counters(monkeypatch):

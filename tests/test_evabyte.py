@@ -259,7 +259,8 @@ def test_a_slot_without_room_is_fed_nothing():
 def test_the_ops_declare_their_state_families(driver):
     assert sorted(driver._state) == ["cursor", "summary", "window"]
     assert not driver.positional and driver.feeds
-    assert (driver.window, driver.chunk, driver.state_layers) == (W, C, 2)
+    assert driver.window == W and driver.summarises
+    assert [n for n, _reads in driver._reads] == [2]    # two layers alike
     assert len(driver.slot_cells()) == 5 * CFG["num_hidden_layers"]
     # the K/V decoder's families, by the same declaration
     sym = tfm.get_decode_symbol(vocab_size=16, d_model=16, n_layer=2,

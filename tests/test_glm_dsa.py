@@ -224,9 +224,13 @@ def test_selection_reads_are_counted_from_the_cursors(driver):
     driver.step(np.zeros((SLOTS, 1), np.int32), fed=[1, 0, 1])
     # slots at 32, 32, 16 fed 1, 0, 1: last queries see 33 and 17 keys
     layers, indexed, topk = 3, 2, 16
-    assert list(driver.last_selection) == [
-        layers, layers * (33 + 17), layers * 2 * topk, indexed * (33 + 17)]
-    assert driver.selects and driver.positional and driver.feeds
+    assert driver.last_reads == {
+        "dsa.layer_steps": layers, "dsa.live_rows": layers * (33 + 17),
+        "dsa.selected_rows": layers * 2 * topk,
+        "dsa.scored_rows": indexed * (33 + 17)}
+    assert driver.read_counts["dsa.selected_rows"] == (
+        "dsa.selected_rows", "dsa_selected")
+    assert driver.positional and driver.feeds
 
 
 # ----------------------------------------------------------- the two ops
@@ -308,8 +312,113 @@ def test_a_shared_layer_attends_the_set_its_full_layer_chose():
                          "lm_l2_attn": "lm_l1_idx"}
     assert not [n for n in symbol.list_arguments() if n.startswith("lm_l2_idx")]
     assert "lm_l2_idx_index_k" not in symbol.list_auxiliary_states()
-    assert tfm.sparse_selection(symbol) == (3, 2, 16)
-    assert tfm.sparse_selection(tfm.get_decode_symbol(per_slot=True)) is None
+    # three attention layers under the selections of two indexers
+    stateful = [(node.op, node.attrs.get("topk"))
+                for node, _opdef, _cells in tfm._stateful_nodes(symbol)
+                if node.op != "MoEFFN"]
+    assert stateful == [("dsa_index_select", 16),
+                        ("mla_attention_decode", None)] * 2 \
+        + [("mla_attention_decode", None)]
+    # and the ops of a graph without a selection count nothing under one
+    assert not [count for _node, opdef, _cells in tfm._stateful_nodes(
+        tfm.get_decode_symbol(per_slot=True))
+        for count in opdef.state_reads[0] if count.startswith("dsa.")]
+
+
+# ----------------------------------------------------------- the two ops
+def _op_inputs(S, dtype, seed=0):
+    rs = np.random.RandomState(seed)
+    B, H, dn, dr, dv, rank = 2, 4, 24, 16, 32, 64
+    Hi, d = 16, 32
+    f = lambda *s: jnp.asarray(rs.randn(*s), dtype)      # noqa: E731
+    fed = jnp.asarray([S, max(S - 1, 1)], jnp.int32)
+    cur = jnp.asarray([[40], [7]], jnp.int32)
+    idx = ([f(B, S, Hi * d), f(B, S, d), f(B, S, Hi), fed],
+           [f(B, 1, CAPACITY, d), cur])
+    att = ([f(B, S, H * (dn + dr)), f(B, S, rank + dr), None, fed,
+            jnp.ones((rank,), dtype), f(H * (dn + dv), rank) * 0.2],
+           [f(B, 1, CAPACITY, mla.latent_width(rank, dr)), cur])
+    return idx, att
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("S", [1, 16], ids=["decode", "window"])
+def test_absorbed_kernels_equal_the_expanded_composition(S, dtype):
+    """``dsa_index_select``: the kernels choose the composition's set,
+    write its rows and move its cursor. ``mla_attention_decode``: the
+    absorbed path through the kernel equals the expanded composition
+    (in bfloat16 within the rounding of q W_kb and of the latent sum)."""
+    index, attend = get_op("dsa_index_select"), \
+        get_op("mla_attention_decode")
+    ia = index.normalize_attrs(dict(
+        capacity=CAPACITY, n_heads=16, head_dim=32, rope_dim=16, topk=16,
+        rope_base=8e6))
+    aa = attend.normalize_attrs(dict(
+        capacity=CAPACITY, n_heads=4, nope_dim=24, rope_dim=16, v_dim=32,
+        kv_rank=64, rope_base=8e6))
+    (i_in, i_aux), (a_in, a_aux) = _op_inputs(S, jnp.dtype(dtype))
+    sel, aux = index.variant_fn("xla")(ia, i_in, i_aux, False, None)
+    sel_k, aux_k = index.variant_fn("pallas")(ia, i_in, i_aux, False, None)
+    assert sel[0].dtype == jnp.int8 and sel[0].shape == (2, S, CAPACITY)
+    np.testing.assert_array_equal(np.asarray(sel[0]), np.asarray(sel_k[0]))
+    for a, b in zip(aux, aux_k):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    kept = np.asarray(sel[0]).sum(-1)
+    assert kept[0].max() == 16 and kept[1, 0] == 8      # t = 7: all 8
+    assert list(np.asarray(aux[1]).ravel()) == [40 + S, 7 + max(S - 1, 1)]
+    a_in[2] = sel[0]
+    out, aux = attend.variant_fn("xla")(aa, a_in, a_aux, False, None)
+    out_k, aux_k = attend.variant_fn("pallas")(aa, a_in, a_aux, False, None)
+    tol = 1e-5 if dtype == "float32" else 0.04
+    np.testing.assert_allclose(np.asarray(out[0], np.float32),
+                               np.asarray(out_k[0], np.float32),
+                               atol=tol, rtol=tol)
+    np.testing.assert_array_equal(np.asarray(aux[0], np.float32),
+                                  np.asarray(aux_k[0], np.float32))
+
+
+@pytest.mark.parametrize("case", sorted(mla_window_cases.CASES))
+def test_a_window_attends_a_slot_fed_one_row_as_the_s1_program_does(case):
+    """Under a selection: a window whose slots are fed a whole window,
+    one row, none and a ragged few (``tests/mla_window_cases.py``)
+    equals the expanded form at every fed position; the row of a slot
+    fed one - ``mla_attn_ride`` under row 0 of the selection - is the
+    S = 1 dispatch's to the bit; a window in which every slot rides and
+    one in which none does."""
+    riding = mla_window_cases.check(case, selected=True, rope_base=8e6)
+    assert len(riding) == {"mixed": 3, "all_riding": 6,
+                           "none_riding": 0}[case]
+
+
+def test_a_shared_layer_attends_the_set_its_full_layer_chose():
+    """The graph hands layer 1's selection to layer 2: one
+    ``dsa_index_select`` output feeds both ``mla_attention_decode``
+    nodes, and layer 2 has no indexer parameters and no index pool."""
+    symbol = _symbol(4)
+    consumers = {}
+    for node in symbol._topo_nodes():
+        if not node.is_variable and node.op == "mla_attention_decode":
+            consumers[node.name] = node.inputs[2][0].name
+    assert consumers == {"lm_l0_attn": "lm_l0_idx", "lm_l1_attn": "lm_l1_idx",
+                         "lm_l2_attn": "lm_l1_idx"}
+    assert not [n for n in symbol.list_arguments() if n.startswith("lm_l2_idx")]
+    assert "lm_l2_idx_index_k" not in symbol.list_auxiliary_states()
+    # three attention layers under the selections of two indexers, each
+    # of which says what a dispatch reads (``OpDef.state_reads``)
+    declared = [(node.op, node.attrs.get("topk"), sorted(
+        opdef.state_reads[1](node.attrs, CAPACITY, {"selection": {"topk": 16}})(
+            np.zeros(1, np.int64), np.ones(1, np.int64))))
+        for node, opdef, _cells in tfm._stateful_nodes(symbol)
+        if node.op != "MoEFFN"]
+    assert declared == [
+        ("dsa_index_select", 16, ["dsa.scored_rows"]),
+        ("mla_attention_decode", None,
+         ["dsa.layer_steps", "dsa.live_rows", "dsa.selected_rows"])] * 2 \
+        + [declared[-1]] and declared[-1][0] == "mla_attention_decode"
+    assert not [c for _n, opdef, _c in tfm._stateful_nodes(
+        tfm.get_decode_symbol(per_slot=True)) for c in opdef.state_reads[0]
+        if c.startswith("dsa.")]
 
 
 def test_bfloat16_serving_is_inside_a_bound_the_dense_control_is_not():
