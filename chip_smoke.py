@@ -324,6 +324,29 @@ def kernel_sites(w, seed=0):
              (h_dec * (dn + dv), rank), (slots, 1, cap, lat), (slots, 1)],
             [bf, bf, "int8", "int32", bf, bf, bf, "int32"], False,
             mla_inputs))
+    # hyper-connections (ops/mhc.py): the read and the join of a stream
+    # of four copies of d_model, at the S = 1 program's handful of rows
+    # (the mapping down the sublanes) and a window's whole tiles (along
+    # the lanes); mapping weights under which its logits have Xing4.0's
+    # deviation of 2.4
+    for rows in (slots, slots * win):
+        def stream(rows=rows):
+            return [normal((1, rows, 4 * D), bf),
+                    (normal((24, 4 * D), f32) * 2.4 / np.sqrt(4 * D))
+                    .astype(bf), normal((24,), bf) * 0.02,
+                    jnp.ones((3,), bf)]
+        sites.append((
+            f"mhc_pre_{rows}", "mhc_pre", {"n": 4},
+            [(1, rows, 4 * D), (24, 4 * D), (24,), (3,)], [bf] * 4, False,
+            stream))
+        sites.append((
+            f"mhc_post_{rows}", "mhc_post", {"n": 4},
+            [(1, rows, 4 * D), (rows, D), (rows, 4), (rows, 16)],
+            [bf, bf, f32, f32], False,
+            lambda rows=rows: [
+                normal((1, rows, 4 * D), bf), normal((rows, D), bf),
+                jnp.abs(normal((rows, 4), f32)),
+                jnp.abs(normal((rows, 16), f32)) / 4]))
     for wdt in ("int8", "float8_e4m3fn"):
         rows, k, nh = slots * win, D, 4 * D
         sites.append((

@@ -864,6 +864,17 @@ class Executor:
         self._materialize_outputs()
         return self._outputs if self._outputs is not None else []
 
+    def release_outputs(self):
+        """Let go of the latest forward's outputs: whoever read them
+        owns them now, and their device memory goes when that reader
+        drops them, not at this executor's next forward
+        (``BatchedKVCacheDecoder.release_outputs``: a window program's
+        logits over a vocabulary of 131,072 are 2 GB, and an engine
+        holds a program for every rung). ``outputs`` is empty until the
+        next ``forward``."""
+        self._outputs = None
+        self._pending = None
+
     # -------------------------------------------------------------- backward
     def backward(self, out_grads=None):
         """Propagate gradients (fused fwd+bwd XLA program).

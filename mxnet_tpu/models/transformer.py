@@ -326,30 +326,57 @@ def _residual(x, h, pfx, sub, spec):
     return x + h
 
 
+def _sub_layer(x, pfx, sub, spec):
+    """``(u, join)`` of sub-layer ``sub`` over the stream ``x``, as the
+    record's ``residual`` has it: ``u`` is what the sub-layer reads and
+    ``join(h)`` the stream after its output ``h``. Every value but one
+    reads the stream itself and adds to it (``_residual``). ``"hyper"``
+    (``spec["hyper"]``: Xing4.0's manifold-constrained hyper-
+    connections, ``ops/mhc.py``): the stream is ``n`` copies a row, the
+    sub-layer reads ``mhc_pre``'s mix of them and ``mhc_post`` writes
+    its output back beside their doubly-stochastic mix, both by the
+    sub-layer's own mapping of the token's whole stream."""
+    if spec["residual"] != "hyper":
+        return x, lambda h: _residual(x, h, pfx, sub, spec)
+    hyper = spec["hyper"]
+    u, post, res = sym.mhc_pre(x, name=f"{pfx}_{sub}_mhc", **hyper)
+    return u, lambda h: sym.mhc_post(x, h, post, res, n=hyper["n"],
+                                     name=f"{pfx}_{sub}_mhc_join")
+
+
 def _layer(x, fed, fed_rows, carry, i, spec):
     """Layer ``i`` of any block, pre-norm: ``x = x + proj(attention(
-    norm(x)))``, then ``x = x + ffn(norm(x))``. In a fed graph ``x``
-    and every row-wise operation are in the packed view of the window's
-    rows (``ops/rows.py``: all ``slots x S`` of them, or the real ones
-    under a budget, ``fed_rows`` their count as ``MoEFFN`` takes it).
-    Returns ``(x, carry)``: what the attention hands its next layer."""
+    norm(x)))``, then ``x = x + ffn(norm(x))``, what a sub-layer reads
+    and how its output joins the stream as ``_sub_layer`` has them. In
+    a fed graph ``x`` and every row-wise operation are in the packed
+    view of the window's rows (``ops/rows.py``: all ``slots x S`` of
+    them, or the real ones under a budget, ``fed_rows`` their count as
+    ``MoEFFN`` takes it). Returns ``(x, carry)``: what the attention
+    hands its next layer."""
     pfx = f"{spec['name']}_l{i}"
-    att, carry = spec["attention"](_norm(x, f"{pfx}_ln1", spec), fed,
+    u, join = _sub_layer(x, pfx, "proj", spec)
+    att, carry = spec["attention"](_norm(u, f"{pfx}_ln1", spec), fed,
                                    carry, i, spec)
     proj = sym.FullyConnected(att, num_hidden=spec["d_model"],
                               name=f"{pfx}_proj",
                               **({} if spec["bias"] else {"no_bias": True}))
-    x = _residual(x, proj, pfx, "proj", spec)
-    h = _ffn(_norm(x, f"{pfx}_ln2", spec), fed_rows, i, spec)
-    return _residual(x, h, pfx, "ffn", spec), carry
+    x = join(proj)
+    u, join = _sub_layer(x, pfx, "ffn", spec)
+    h = _ffn(_norm(u, f"{pfx}_ln2", spec), fed_rows, i, spec)
+    return join(h), carry
 
 
 def _head(x, tok_w, spec):
     """Final norm and the output head over the folded (B*T, D) rows:
     tied to the token embedding (one weight, two gradients), or the
     untied ``{name}_head_weight`` of ``spec["heads"]`` consecutive
-    blocks of ``vocab_size`` rows."""
+    blocks of ``vocab_size`` rows. A stream of copies (``"hyper"``) is
+    summed to one first."""
     name = spec["name"]
+    if spec["residual"] == "hyper":     # the copies summed: one stream
+        x = sym.sum(sym.Reshape(x, shape=(0, 0, spec["hyper"]["n"], -1),
+                                name=f"{name}_copies"),
+                    axis=2, name=f"{name}_copies_sum")
     flat = sym.Reshape(_norm(x, f"{name}_ln_f", spec), shape=(-3, 0),
                        name=f"{name}_head_fold")
     if spec["tie_head"]:
@@ -544,6 +571,23 @@ def _glm_spec(spec):
     return _latent_spec(spec, glm, kinds, {"router_bias": True}, {})
 
 
+def _yarn_rope(given, block):
+    """``mla_attention_decode``'s rotary attributes from a published
+    ``rope_scaling`` (YaRN's, or None: the plain rotary)."""
+    yarn = dict(given["rope_scaling"] or {})
+    if yarn and yarn.get("type", yarn.get("rope_type")) != "yarn":
+        raise MXNetError(f"block={block!r}: rope_scaling {yarn} is not "
+                         "YaRN's")
+    return {} if not yarn else {
+        "rope_factor": float(yarn["factor"]),
+        "rope_original_positions":
+            int(yarn["original_max_position_embeddings"]),
+        "rope_beta_fast": float(yarn.get("beta_fast", 32)),
+        "rope_beta_slow": float(yarn.get("beta_slow", 1)),
+        "rope_mscale": float(yarn.get("mscale", 1)),
+        "rope_mscale_all_dim": float(yarn.get("mscale_all_dim", 0))}
+
+
 def _axk1_spec(spec):
     """A.X-K1's block from ``axk1``, its published keys (``AXK1_KEYS``)
     and optionally ``held``: the same latent block without an indexer
@@ -554,22 +598,45 @@ def _axk1_spec(spec):
     groups of experts. Inputs, state and driver contract are
     ``glm_dsa``'s."""
     axk1 = _given_keys(spec, "axk1", AXK1_KEYS)
-    yarn = dict(axk1["rope_scaling"] or {})
-    if yarn and yarn.get("type", yarn.get("rope_type")) != "yarn":
-        raise MXNetError(f"block='axk1': rope_scaling {yarn} is not "
-                         "YaRN's")
-    rope = {} if not yarn else {
-        "rope_factor": float(yarn["factor"]),
-        "rope_original_positions":
-            int(yarn["original_max_position_embeddings"]),
-        "rope_beta_fast": float(yarn.get("beta_fast", 32)),
-        "rope_beta_slow": float(yarn.get("beta_slow", 1)),
-        "rope_mscale": float(yarn.get("mscale", 1)),
-        "rope_mscale_all_dim": float(yarn.get("mscale_all_dim", 0))}
     return _latent_spec(
         spec, axk1, ["none"] * spec["n_layer"],
         {"router_bias": False, "n_group": int(axk1["n_group"]),
-         "topk_group": int(axk1["topk_group"])}, rope)
+         "topk_group": int(axk1["topk_group"])}, _yarn_rope(axk1, "axk1"))
+
+
+#: the keys of Xing4.0's published ``config.json`` (``model_type
+#: xing4_0``) that ``block="xing4"`` reads (``get_decode_symbol(xing4=
+#: ...)``): A.X-K1's and the hyper-connections' - the copies of the
+#: stream, the Sinkhorn iterations and their epsilon, the clamp of the
+#: mix's logits; ``held`` as above
+XING4_KEYS = AXK1_KEYS + ("hc_mult", "hc_sinkhorn_iters", "hc_eps",
+                          "mhc_h_res_clamp_min", "mhc_h_res_clamp_max")
+
+
+def _xing4_spec(spec):
+    """Xing4.0's block from ``xing4``, its published keys
+    (``XING4_KEYS``): the latent block without an indexer under YaRN as
+    A.X-K1 has it, a sigmoid router with a correction bias (GLM-5.2's)
+    over ``n_group`` groups (1 as published: an ungrouped choice), and
+    the one thing neither has - a residual stream of ``hc_mult`` copies
+    a token, read and joined through manifold-constrained
+    hyper-connections (``residual="hyper"``: ``_sub_layer``,
+    ``ops/mhc.py``), copied in from the embedding and summed before the
+    final norm. Inputs, state and driver contract are ``glm_dsa``'s."""
+    xing4 = _given_keys(spec, "xing4", XING4_KEYS)
+    if int(xing4["hc_mult"]) < 1:
+        raise MXNetError(f"block='xing4': hc_mult {xing4['hc_mult']} "
+                         "copies of the stream")
+    latent = _latent_spec(
+        spec, xing4, ["none"] * spec["n_layer"],
+        {"router_bias": True, "n_group": int(xing4["n_group"]),
+         "topk_group": int(xing4["topk_group"])},
+        _yarn_rope(xing4, "xing4"))
+    return dict(latent, residual="hyper", hyper=dict(
+        n=int(xing4["hc_mult"]), iters=int(xing4["hc_sinkhorn_iters"]),
+        eps=float(xing4["hc_eps"]), rms_eps=float(spec["rms_eps"]),
+        clamp_min=float(xing4["mhc_h_res_clamp_min"]),
+        clamp_max=float(xing4["mhc_h_res_clamp_max"])))
 
 
 #: the keys of Trinity's published ``config.json`` (``model_type
@@ -645,10 +712,11 @@ def _afmoe_spec(spec):
 
 
 #: ``block=`` -> its spec constructor: the one place a block is chosen
-#: by its name. A seventh architecture is one more entry and, only if
+#: by its name. An eighth architecture is one more entry and, only if
 #: its attention is new, one more attention function
 _SPECS = {"gpt2": _gpt2_spec, "olmoe": _olmoe_spec, "evabyte": _eva_spec,
-          "glm_dsa": _glm_spec, "axk1": _axk1_spec, "afmoe": _afmoe_spec}
+          "glm_dsa": _glm_spec, "axk1": _axk1_spec, "afmoe": _afmoe_spec,
+          "xing4": _xing4_spec}
 
 
 def _spec(given, decode):
@@ -683,7 +751,8 @@ def _embedded(spec):
     """The head of a graph: ``(x, tok_w, fed, fed_rows)`` - the token
     embedding (scaled by sqrt(D), transformer convention, unless
     ``embed_scale=False``), plus the learned position table when
-    ``pos_embed='learned'``, cast where the block's stream is float32.
+    ``pos_embed='learned'``, cast where the block's stream is float32,
+    laid ``n`` times side by side where it is ``n`` copies (``"hyper"``).
     In a fed graph the tokens are embedded in the view the row-wise
     operations run in (``ops/rows.py``: ``(slots, S, D)``, or
     one block of the real rows under a budget), ``fed`` as the decode
@@ -717,6 +786,9 @@ def _embedded(spec):
             x = sym.broadcast_add(x, pos, name=f"{name}_add_pos")
     if spec["residual"] == "float32":
         x = sym.Cast(x, dtype="float32", name=f"{name}_embed_f32")
+    elif spec["residual"] == "hyper":   # every copy starts as the row
+        x = sym.tile(x, reps=(1, 1, spec["hyper"]["n"]),
+                     name=f"{name}_embed_copies")
     return x, tok_w, fed, fed_rows
 
 
@@ -770,7 +842,7 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                       tie_head=True, embed_scale=True, window=2048,
                       chunk=16, n_pred_heads=1, ffn_width=None,
                       multibyte=False, glm=None, afmoe=None,
-                      max_step_len=None, axk1=None):
+                      max_step_len=None, axk1=None, xing4=None):
     """Incremental KV-cache decoder: ``(B, step_len)`` new token ids in,
     logits ``(B, step_len, vocab)`` out, per-layer K/V caches of
     ``capacity`` positions riding executor aux state. Parameter names
@@ -803,7 +875,8 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     ``window``, ``chunk``, ``n_pred_heads``, ``ffn_width``,
     ``multibyte``), ``"glm_dsa"`` (``_glm_spec``: ``glm``, the published
     config's ``GLM_KEYS``), ``"axk1"`` (``_axk1_spec``: ``axk1``,
-    ``AXK1_KEYS``) and ``"afmoe"`` (``_afmoe_spec``: ``afmoe``,
+    ``AXK1_KEYS``), ``"xing4"`` (``_xing4_spec``: ``xing4``,
+    ``XING4_KEYS``) and ``"afmoe"`` (``_afmoe_spec``: ``afmoe``,
     ``AFMOE_KEYS``; ``max_step_len``).
 
     Their graphs take one more input, ``fed`` ``(slots,)`` int32 - how
@@ -1084,7 +1157,10 @@ class BatchedKVCacheDecoder:
     ``serve.decode.state.donated_bytes``.
 
     ``step`` hands back the whole ``(slots, S, V)`` logits as they lie
-    on the device. A caller that samples one row a slot launches
+    on the device; ``release_outputs`` makes them the caller's alone (a
+    scheduler calls it behind every step: a window's logits over a
+    vocabulary of 131,072 are 2 GB, and every rung's programs would
+    each keep their latest). A caller that samples one row a slot launches
     ``select_rows`` behind it: one small jitted program per step length
     that picks each slot's row and takes its argmax there, so that
     token ids cross to the host and not the logits.
@@ -1142,6 +1218,7 @@ class BatchedKVCacheDecoder:
         # rows the latest step's program ran its row-wise operations
         # over: slots x S, or R where it was the packed one
         self.last_program_rows = None
+        self._stepped = None          # the module the latest step ran
         self._cursor_program = None                  # built at first use
         self._row_progs = None                       # capture, restore
         self._select_programs = {}                   # step_len -> program
@@ -1552,6 +1629,15 @@ class BatchedKVCacheDecoder:
         slot 0 out and back in: warm-up's slots are free)."""
         self.restore_rows(0, self.capture_rows(0, self.row_block))
 
+    def release_outputs(self):
+        """Make the latest ``step``'s outputs the caller's alone: the
+        program that ran lets go of them (``Executor.release_outputs``),
+        so their device memory goes when the caller drops what ``step``
+        handed it, not at that program's next run."""
+        if self._stepped is not None:
+            self._stepped._exec_group.executor.release_outputs()
+            self._stepped = None
+
     def overflowing(self, window=1):
         """Active slots whose next ``window``-token dispatch would pass
         capacity — the scheduler retires these (alone) before dispatch."""
@@ -1647,6 +1733,7 @@ class BatchedKVCacheDecoder:
         with _telemetry.span("decode.step.launch"):
             mod.forward(DataBatch(data=data, label=[]), is_train=False)
             out = mod.get_outputs()[0]
+        self._stepped = mod
         self.pos += advance
         self.last_stage, self.last_launch = (None, None) if now is None \
             else (t1 - t0, now() - t1)

@@ -595,6 +595,7 @@ class DecodeEngine:
             def step_ids(tokens, fed=None):
                 _rows, ids = drv.select_rows(drv.step(tokens, fed=fed),
                                              last)
+                drv.release_outputs()   # no rung keeps warm-up's logits
                 return np.asarray(ids)
 
             zeros = np.zeros((rung, 1), np.int32)
@@ -1191,6 +1192,7 @@ class DecodeScheduler:
                 out = drv.step(tokens, now=now)
             else:
                 out = drv.step(tokens, fed=fed, now=now)
+            drv.release_outputs()       # ``out`` is this call's alone
             reads.update(drv.last_reads)
             phases["stage"] += drv.last_stage
             phases["launch"] += drv.last_launch

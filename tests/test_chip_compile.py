@@ -545,6 +545,66 @@ def test_every_block_builds_the_parents_graph(block, form):
         == _PARENT_GRAPH_SHA256[(block, form)]
 
 
+@pytest.mark.parametrize("S,form", [(1, "whole"), (16, "whole"),
+                                    (16, "packed")])
+def test_hyper_connected_programs_lower_at_tiny_sizes(S, form, monkeypatch):
+    """Xing4.0's block (``residual="hyper"``): its S = 1 program, its
+    whole-window program and the packed form of that lower, the stream
+    as rows of four copies (256 numbers) over the rows the program
+    runs - 4 slots, 4 x 16 of a whole window, the budget's 24 - and
+    never as ``(rows, 4, 64)``."""
+    import window_pack_cases as cases
+    from mxnet_tpu.models import transformer as tfm
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
+    kernel_tier.clear()
+    try:
+        sym = cases.symbol("xing4", S)
+        rows = cases.SLOTS * S
+        if form == "packed":
+            sym, rows = tfm.packed_window(sym, cases.SLOTS)
+            assert rows == 24
+        text = cases.lowered_text(sym, cases.SLOTS, S)
+    finally:
+        kernel_tier.clear()
+    ops = [n.op for n in sym._topo_nodes() if not n.is_variable]
+    assert ops.count("mhc_pre") == ops.count("mhc_post") == 6
+    assert f"tensor<{rows}x256xf32>" in text        # the stream's rows
+    assert f"tensor<{rows}x16xf32>" in text         # Hres, 16 a row
+    assert f"tensor<{rows}x4x64xf32>" not in text
+
+
+@pytest.mark.parametrize("rows", [8, 1152, 8192],
+                         ids=["decode", "packed", "whole"])
+@pytest.mark.parametrize("op", ["mhc_pre", "mhc_post"])
+def test_hyper_connection_kernels_compile_for_v5e(op, rows, v5e):
+    """``ops/mhc.py``'s two kernels at Xing4.0's published sizes (four
+    copies of 3,584, bfloat16) over the rows of the top rung's three
+    programs: the S = 1 step's 8 (the mapping down the sublanes), the
+    packed window's 1,152 and the whole window's 8,192 (whole tiles,
+    along the lanes). One Mosaic kernel each, and nothing beside it that
+    passes over the stream: no fusion reads or writes ``(rows,
+    14336)``."""
+    import re
+    opdef = get_op(op)
+    attrs = opdef.normalize_attrs({"n": 4})
+    n, C = 4, 3584
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ins = [sds((1, rows, n * C)), sds((24, n * C)), sds((24,)), sds((3,))] \
+        if op == "mhc_pre" else \
+        [sds((1, rows, n * C)), sds((rows, C)), sds((rows, 4), jnp.float32),
+         sds((rows, 16), jnp.float32)]
+    assert opdef.variant_eligible("pallas", attrs, [i.shape for i in ins],
+                                  [i.dtype for i in ins])
+    fn = opdef.variant_fn("pallas")
+    text = jax.jit(lambda r: fn(attrs, list(r), [], False, None)[0]) \
+        .lower(ins).compile().as_text()
+    assert len(re.findall(rf"{op}[.\w]* = .*tpu_custom_call", text)) == 1
+    assert not re.findall(rf"bf16\[(1,)?{rows},{n * C}\]\S* fusion\(", text)
+
+
 @pytest.mark.parametrize("op", ["pack", "unpack"])
 def test_packing_compiles_for_v5e_to_block_copies_in_place(op, v5e):
     """``ops/rows.py`` at GLM-5.2's widest operand (8 slots of 1,024

@@ -39,8 +39,10 @@ MIXES = {
 #: picks its matmul by the operands' shape, and ``_dense_expert``'s
 #: products over 24 rows and over 64 round a last bit differently
 #: (4e-7 on one layer's output, measured by itself; up to 4e-6 on the
-#: logits, of magnitude 2)
-TOL = {"evabyte": 0.0, "glm_dsa": 2e-5, "axk1": 2e-5, "afmoe": 2e-5}
+#: logits, of magnitude 2; Xing4.0's mappings - an exp and 20 Sinkhorn
+#: rounds of the stream a sub-layer - carry such a bit ten times as far)
+TOL = {"evabyte": 0.0, "glm_dsa": 2e-5, "axk1": 2e-5, "afmoe": 2e-5,
+       "xing4": 2e-4}
 
 
 def test_the_budget_follows_from_the_shapes():

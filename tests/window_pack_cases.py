@@ -1,5 +1,5 @@
-"""The four fed decoders at tiny sizes - GLM-5.2's block, A.X-K1's,
-Trinity's and EvaByte's - for the tests of a window's packed rows
+"""The five fed decoders at tiny sizes - GLM-5.2's block, A.X-K1's,
+Xing4.0's, Trinity's and EvaByte's - for the tests of a window's packed rows
 (``tests/test_decode_pack.py``) and of the text their programs lower to
 (``tests/test_chip_compile.py``): graphs, parameters, bound drivers with
 the whole-window and the packed program, and a program's lowered text.
@@ -30,6 +30,10 @@ _AXK1 = dict(_LATENT, v_head_dim=16, n_routed_experts=24,
                            "original_max_position_embeddings": 16,
                            "beta_fast": 4, "beta_slow": 1, "mscale": 1,
                            "mscale_all_dim": 1})
+_XING4 = dict(_AXK1, n_routed_experts=16, num_experts_per_tok=4, n_group=1,
+              topk_group=1, routed_scaling_factor=2.0, held=None,
+              hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+              mhc_h_res_clamp_min=-30, mhc_h_res_clamp_max=30)
 _AFMOE = {"num_key_value_heads": 2, "head_dim": 16, "sliding_window": 16,
           "layer_types": ["sliding_attention", "full_attention",
                           "sliding_attention"],
@@ -44,6 +48,8 @@ BLOCKS = {
                     rope_base=8e6, glm=_GLM),
     "axk1": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
                  rope_base=1e4, rms_eps=1e-6, axk1=_AXK1),
+    "xing4": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
+                  rope_base=1e4, rms_eps=1e-6, xing4=_XING4),
     "afmoe": dict(vocab_size=48, d_model=64, n_layer=3, n_head=8,
                   rope_base=1e4, afmoe=_AFMOE, max_step_len=WINDOW),
     "evabyte": dict(vocab_size=40, d_model=32, n_layer=2, n_head=2,

@@ -8,7 +8,8 @@ windows (``step`` without ``fed``), so the comparison that decides
 one slot prefilling, the others riding with a token each - runs the
 packed form of the same graph (``models/transformer.py``'s
 ``packed_window``, ``ops/rows.py``). Here slot 0 prefills a sequence to
-16,384 positions and one window more while every other slot of the top
+16,384 positions (or as many whole windows as leave one more inside
+the configuration's capacity) and one window more while every other slot of the top
 rung rides each window with one token of its own; once through the
 packed program (``fed`` sums to S + slots - 1 <= R) and once, from
 cursor 0 again, through the whole-window program fed the same. The last
@@ -18,8 +19,8 @@ reference under its ``LOGIT_TOL``; whether the rows each path wrote to
 the positional pools are equal; and the median window's time on the
 host's clock in either form. Prints one JSON line.
 
-    python3 tools/window_pack_check.py --config a.x-k1|glm-5.2
-                                       [--seed N] [--rehearse]
+    python3 tools/window_pack_check.py
+        --config a.x-k1|glm-5.2|xing4.0-29b-a4b [--seed N] [--rehearse]
 
 ``--rehearse`` runs the configuration's tiny fixture on the CPU
 (chipbench/tests/fixtures: a context of 64, windows of 16); no number of
@@ -38,7 +39,8 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 _TINY = {"a.x-k1": ("axk1", "tiny-axk1.json"),
-         "glm-5.2": ("glm_dsa", "tiny-glm.json")}
+         "glm-5.2": ("glm_dsa", "tiny-glm.json"),
+         "xing4.0-29b-a4b": ("xing4", "tiny-xing4.json")}
 
 
 def main(argv=None):
@@ -64,7 +66,7 @@ def main(argv=None):
         "arch", os.path.join(ROOT, "chipbench", "archs", cfg["arch"] + ".py"))
 
     S, n_cmp = cfg["prefill_chunk"], 16
-    ctx = 16384 if not ns.rehearse else 3 * S
+    ctx = min(16384, cfg["capacity"] - S) if not ns.rehearse else 3 * S
     windows = ctx // S + 1
     assert ctx % S == 0 and windows * S <= cfg["capacity"]
     gen = functools.partial(arch.decode_symbol, cfg)
@@ -103,15 +105,17 @@ def main(argv=None):
         # budget: hide it for the whole-window pass (nothing public
         # chooses, by design)
         hidden = drv._packed.pop(S) if whole else None
-        seconds = []
+        seconds, out = [], None
         try:
             for w in range(windows):
+                out = None      # the window before's logits go first
                 tokens = np.zeros((top, S), np.int32)
                 tokens[0] = seq[0, w * S:(w + 1) * S]
                 tokens[1:, 0] = riders[1:, w]
                 t = time.perf_counter()
                 out = drv.step(tokens, fed=fed)
-                out.asjax().block_until_ready()
+                drv.release_outputs()   # 2 GB of logits at a whole
+                out.asjax().block_until_ready()     # vocabulary
                 seconds.append(time.perf_counter() - t)
                 ran = drv.last_program_rows
         finally:
