@@ -1222,6 +1222,7 @@ class BatchedKVCacheDecoder:
         self._cursor_program = None                  # built at first use
         self._row_progs = None                       # capture, restore
         self._select_programs = {}                   # step_len -> program
+        self._moe_program = None                     # built at first use
         exe = module._exec_group.executor
         # what every step program takes over and updates in place (the
         # window modules share these cells): 0 = the graph donates none
@@ -1361,26 +1362,38 @@ class BatchedKVCacheDecoder:
 
     def moe_stats_begin(self):
         """Start copying the latest dispatch's per-layer ``moe_stats``
-        cells to the host (a few int32 a layer) and return them; call
-        it right after ``step`` so that the copies ride behind the
-        program, beside the logits'. None for a dense decoder."""
+        cells to the host (a few int32 a layer) and return what
+        ``moe_stats`` reads; call it right after ``step`` so that the
+        copy rides behind the program, beside the ids'. The cells go
+        through one small program (``moe_stats_<slots>``: a stack, one
+        array and one copy whatever the layers) because the next step
+        takes every aux array over (``donates_aux``): what comes back
+        here is the caller's, and outlives a step launched before it is
+        read. None for a dense decoder."""
         if not self._moe_cells:
             return None
-        arrays = [c.asjax() for c in self._moe_cells]
-        for a in arrays:
-            a.copy_to_host_async()
-        return arrays
+        if self._moe_program is None:
+            import jax
+            import jax.numpy as jnp
 
-    def moe_stats(self, arrays):
+            def moe_stats(cells):
+                return jnp.stack(cells)
+
+            moe_stats.__name__ = f"moe_stats_{self.slots}"
+            self._moe_program = jax.jit(moe_stats)
+        stats = self._moe_program(tuple(c.asjax() for c in self._moe_cells))
+        stats.copy_to_host_async()
+        return stats
+
+    def moe_stats(self, stats):
         """``{count: integer}`` (the op's ``state_reads`` names: layer
         executions, assignments, experts touched, the busiest expert's
         load, ...) summed over the layers of one dispatch (S=1 or
         window: the programs share the cells), from
-        ``moe_stats_begin``'s arrays. Read it once the dispatch's logits
+        ``moe_stats_begin``'s array. Read it once the dispatch's ids
         are on the host: the program has then finished, and nothing
         further is waited for."""
-        total = np.sum(np.asarray([np.asarray(a) for a in arrays],
-                                  np.int64), axis=0)
+        total = np.sum(np.asarray(stats, np.int64), axis=0)
         return dict(zip(self._moe_counts, total.tolist()))
 
     def free_slots(self):
@@ -1462,18 +1475,23 @@ class BatchedKVCacheDecoder:
             _telemetry.counter("serve.decode.cursor.rows",
                                model=self.name).inc(int(rows.size))
 
-    def select_rows(self, out, idx, now=None):
+    def select_rows(self, out, idx, feed=None, now=None):
         """From a step's ``(slots, S, V)`` output as it lies on the
         device, ``rows = out[slot, idx[slot]]`` as ``(slots, V)`` (the
-        bytes the host would have indexed, untouched) and ``ids =
+        bytes the host would have indexed, untouched), ``ids =
         argmax(rows, -1)`` as ``(slots,)`` int32, the first maximum as
-        ``np.argmax`` takes it: one launch of ``select_rows_<slots>x<S>``
-        (its name in the trace), one program per step length whatever
-        ``idx`` holds. Both stay on the device; the copy of ``ids`` to
-        the host starts here, behind the step program. ``idx`` is
-        (slots,) ints in ``[0, S)``. All of it is the annotation
-        ``decode.select_rows``; ``now`` (a clock's read) makes
-        ``last_select`` its seconds."""
+        ``np.argmax`` takes it, and ``tokens``, the same ids as the S=1
+        program takes its token input (``(slots, 1)``, the data cell's
+        dtype, where a step's batch lies, so that ``step(tokens)``
+        puts nothing): one launch of ``select_rows_<slots>x<S>`` (its
+        name in the trace), one program per step length whatever
+        ``idx`` holds. All three stay on the device; the copy of
+        ``ids`` to the host starts here, behind the step program.
+        ``idx`` is (slots,) ints in ``[0, S)``; ``feed`` (slots,) bools
+        says whose id ``tokens`` carries (None: every slot's), and the
+        others ride 0, as a row nobody owns does when the host builds
+        the tokens. All of it is the annotation ``decode.select_rows``;
+        ``now`` (a clock's read) makes ``last_select`` its seconds."""
         t0 = None if now is None else now()
         with _telemetry.span("decode.select_rows"):
             arr = out.asjax()
@@ -1484,21 +1502,30 @@ class BatchedKVCacheDecoder:
                 raise MXNetError(
                     f"select_rows() wants ({self.slots},) row "
                     f"indices in [0, {S}), got {idx.tolist()}")
+            feed = np.ones(self.slots, bool) if feed is None \
+                else np.asarray(feed, bool).reshape(-1)
+            if feed.shape != (self.slots,):
+                raise MXNetError(f"select_rows() wants ({self.slots},) "
+                                 f"feed flags, got {feed.shape}")
             program = self._select_programs.get(S)
             if program is None:
                 import jax
                 import jax.numpy as jnp
+                token_dtype = self._mod._exec_group.executor \
+                    .arg_dict[self._mod.data_names[0]].dtype
 
-                def select_rows(out, idx):
+                def select_rows(out, idx, feed):
                     rows = out[jnp.arange(out.shape[0]), idx]
-                    return rows, jnp.argmax(rows, axis=-1).astype(jnp.int32)
+                    ids = jnp.argmax(rows, axis=-1).astype(jnp.int32)
+                    tokens = jnp.where(feed, ids, 0)[:, None]
+                    return rows, ids, tokens.astype(token_dtype)
 
                 select_rows.__name__ = f"select_rows_{self.slots}x{S}"
                 program = self._select_programs[S] = jax.jit(select_rows)
-            rows, ids = program(arr, idx)
+            rows, ids, tokens = program(arr, idx, feed)
             ids.copy_to_host_async()
         self.last_select = None if now is None else now() - t0
-        return rows, ids
+        return rows, ids, tokens
 
     def join(self, slot):
         """Claim ``slot`` for a new sequence: set its device cursor to
@@ -1653,6 +1680,13 @@ class BatchedKVCacheDecoder:
         BEFORE dispatch when an active slot would overflow its cache —
         batchmates are untouched (nothing was dispatched).
 
+        ``tokens`` may be a device array, ``select_rows``' third result:
+        the ids of the step before as they lie on the chip, which the
+        program takes as they are (no put) whether or not that step has
+        run yet. Everything else a dispatch needs - positions, ``fed``,
+        the overflow check, ``last_reads``, ``pos`` - comes from the
+        host's cursor mirror and never from the ids.
+
         A graph with a ``fed`` input (``self.feeds``) advances slot
         ``b`` by ``fed[b]`` of its S tokens (0..S; None feeds every
         slot all S) and leaves a slot with no room for S positions
@@ -1669,10 +1703,12 @@ class BatchedKVCacheDecoder:
         jitted call). ``now`` (a clock's read, the scheduler's) makes
         ``last_stage`` and ``last_launch`` their seconds; without it no
         clock is read."""
+        import jax
         from ..io import DataBatch
         t0 = None if now is None else now()
         with _telemetry.span("decode.step.stage"):
-            tokens = np.asarray(tokens)
+            if not isinstance(tokens, jax.Array):
+                tokens = np.asarray(tokens)
             if tokens.ndim == 1:
                 tokens = tokens[:, None]
             S = tokens.shape[1]

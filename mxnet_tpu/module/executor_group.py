@@ -1420,13 +1420,17 @@ class DataParallelExecutorGroup:
         array, in ``data_names``' order, put once - at its input cell's
         width (read here, once) and where ``forward`` places its batch
         - so that ``_load_batch`` takes it as it is and the step's
-        launch is the jitted call."""
+        launch is the jitted call. An entry that lies on the device
+        already (the ids an earlier step's select program left there)
+        is handed on untouched: no put, and ``_load_batch`` judges it
+        like any other."""
         cells = self.executor.arg_dict
         dtypes = [cells[nm].dtype for nm in self.data_names]
         place, ctx = self._place, self.contexts[0]
 
         def stage(hosts):
-            return [NDArray(place(h.astype(dt), "data"), ctx=ctx)
+            return [NDArray(h if isinstance(h, jax.Array)
+                            else place(h.astype(dt), "data"), ctx=ctx)
                     for h, dt in zip(hosts, dtypes)]
         return stage
 

@@ -27,7 +27,14 @@ pinned program per iteration:
   ids cross to the host, not logits: a select program behind every
   step picks each slot's last fed row and takes its argmax on the
   device; the rows themselves are fetched only for an iteration in
-  which a slot that samples is not greedy. Two
+  which a slot that samples is not greedy. One dispatch may be in
+  flight ahead of the host: where the next dispatch is an S=1 step
+  that the scheduler can plan without the ids of the one on the chip
+  (everybody greedy and decoding, nobody finishing by length, nobody
+  to admit), it is launched before those ids are fetched, fed from the
+  chip by the select program, and committed an iteration later
+  (``DecodeScheduler._plan_ahead``); every other dispatch is planned
+  after its predecessor's commit. Two
   drive modes, same as the server: ``start()`` (dispatch thread, real
   clock) and ``pump()`` (explicit iterations, FakeClock-deterministic).
 
@@ -72,7 +79,8 @@ Telemetry (always on, docs/serving.md has the catalog):
 ``serve.decode.slots``/``active``/``occupancy``/``queue.depth`` gauges,
 ``serve.decode.iterations``/``tokens``/``joins``/``leaves``/
 ``migrations``/``requests``/``responses``/``errors``/``fetch.bytes``/
-``sample.device``/``sample.host``/``state.donated_bytes`` counters,
+``sample.device``/``sample.host``/``state.donated_bytes``/
+``runahead.launched``/``runahead.dropped`` counters,
 ``serve.decode.step.seconds`` + ``serve.decode.request.latency.seconds``
 histograms, and one flight-ring record per iteration.
 """
@@ -572,7 +580,10 @@ class DecodeEngine:
         program, each with its select program behind it (two steps
         each: first pays the traces, second measures steady state on
         ``clock`` the way an iteration runs it - step, select, token
-        ids on the host), pin them all, record the compile delta.
+        ids on the host - and a third S=1 step fed the second's ids as
+        they lie on the device, the form of a dispatch that the
+        scheduler launches ahead), pin them all, record the compile
+        delta.
         Where a window has a packed program (``window_budget``) that is
         the one a scheduler dispatches, and the one warmed: fed as a
         serving window is, one slot its whole chunk and a token for
@@ -592,17 +603,24 @@ class DecodeEngine:
             drv = self._drivers[rung]
             last = np.zeros(rung, np.int32)
 
-            def step_ids(tokens, fed=None):
-                _rows, ids = drv.select_rows(drv.step(tokens, fed=fed),
-                                             last)
+            def launch(tokens, fed=None):
+                _rows, ids, nxt = drv.select_rows(
+                    drv.step(tokens, fed=fed), last)
                 drv.release_outputs()   # no rung keeps warm-up's logits
-                return np.asarray(ids)
+                drv.moe_stats_begin()
+                return ids, nxt
+
+            def step_ids(tokens, fed=None):
+                return np.asarray(launch(tokens, fed)[0])
 
             zeros = np.zeros((rung, 1), np.int32)
             step_ids(zeros)                      # trace + compile
             t0 = clock.now()
-            step_ids(zeros)                      # steady state
+            ids, nxt = launch(zeros)             # steady state
+            np.asarray(ids)
             self.exec_est[rung] = max(0.0, clock.now() - t0)
+            # a dispatch that runs ahead takes its tokens from the chip
+            step_ids(nxt)
             for S in drv.window_lens:
                 wz = np.zeros((rung, S), np.int32)
                 fed = None if drv.window_budget(S) is None \
@@ -744,6 +762,42 @@ _WINDOW_COUNTERS = ("window.fed_slots", "window.riding_slots",
                     "window.real_rows", "window.program_rows")
 
 
+#: what one dispatch's launches left on the device (``_launch``): the
+#: whole output (the speculative path alone), the selected rows, their
+#: ids, the ids as the next S=1 step's token input, the ``moe_stats``
+#: stack
+_Launched = collections.namedtuple(
+    "_Launched", "out picked ids tokens routed")
+
+
+class _Dispatch:
+    """One dispatch from its plan to its commit: what the plan fixed
+    (``mode``, ``S``, ``meta``: ``(row, seq[, n_fed])`` of the slots it
+    feeds; the ``tokens``, each slot's ``last`` fed row, ``fed``, and
+    ``feed``, the rows somebody owns), what ``_launch`` left on the
+    device (``launched``), and its own clock: ``t0`` where its step
+    starts (the plan's first reading, or for a dispatch launched
+    ``ahead`` the moment its predecessor's ids were on the host),
+    ``plan_s`` and ``phases``."""
+
+    __slots__ = ("mode", "S", "meta", "tokens", "last", "fed", "feed",
+                 "want_rows", "n_active", "shared_sid", "t0", "plan_s",
+                 "phases", "ahead", "rewound", "launched")
+
+    def __init__(self, mode, S, t0, ahead=False):
+        self.mode, self.S, self.t0, self.ahead = mode, S, t0, ahead
+        self.meta = []
+        self.tokens = self.last = self.fed = self.feed = None
+        self.want_rows = self.rewound = False
+        self.n_active = 0
+        self.shared_sid = self.launched = None
+        self.plan_s = 0.0
+        self.phases = {"dispatch": 0.0, "fetch": 0.0, "bytes": 0,
+                       "stage": 0.0, "launch": 0.0, "select": 0.0,
+                       "ids": 0.0, "program_rows": 0,
+                       "reads": collections.Counter()}
+
+
 class DecodeScheduler:
     """Iteration-level continuous batching over one ``DecodeEngine``.
 
@@ -849,6 +903,10 @@ class DecodeScheduler:
         # the last clock read of the iteration before (its rewind's end),
         # None where none ran straight before; the iterating thread's own
         self._turn_from = None
+        # the dispatch launched before its predecessor's ids were on the
+        # host, until the next iteration commits it; the iterating
+        # thread's own too
+        self._ahead = None
         # draft first: the target's post-warmup compile mark is the
         # zero-compile gate stats() reports, so it must be taken LAST
         if self.draft is not None:
@@ -892,7 +950,8 @@ class DecodeScheduler:
         if self._iter_handles is None or self._iter_handles[0] != gen:
             handles = {k: self._counter(k) for k in
                        ("iterations", "tokens", "prefill.chunks",
-                        "fetch.bytes", "sample.device", "sample.host")
+                        "fetch.bytes", "sample.device", "sample.host",
+                        "runahead.launched", "runahead.dropped")
                        + _WINDOW_COUNTERS}
             # what the graph's ops count of a dispatch (the driver's
             # ``read_counts``: ``OpDef.state_reads``)
@@ -1155,60 +1214,78 @@ class DecodeScheduler:
                 plan.append((row, seq, n))
         return sorted(plan, key=lambda entry: entry[0])
 
-    def _step_fetch(self, drv, tokens, phases, t=None, last=None,
-                    rows=False, fed=None):
-        """One dispatch and what the host samples from, each under its
-        own annotation. ``serve.decode.iter.dispatch`` is the launches
-        alone: ``drv.step`` (staging and launch) and, where ``last``
-        names each slot's last fed row, ``drv.select_rows`` behind it,
-        which picks those rows and takes their argmax on the device.
-        ``serve.decode.iter.fetch`` waits for the device and copies:
-        the (rung,) int32 token ids, and the (rung, V) selected rows
-        besides only where ``rows`` says a slot that samples in this
-        iteration is not greedy. With ``last`` None (the speculative
-        path: the verifier reads every row of target and draft) it
-        copies the whole (rung, S, V) output. Adds both durations to
-        ``phases`` on the scheduler's clock and the bytes brought to the
-        host to ``phases["bytes"]``; ``t`` is the reading that closed
-        the previous phase (one read a boundary), None reads it. Inside
-        ``dispatch`` the driver times its own parts on the same clock
-        (``phases["stage"]``, ``["launch"]``, ``["select"]``); inside
-        ``fetch``, ``serve.decode.iter.fetch.ids`` is ``np.asarray(ids)``
-        alone (``phases["ids"]``), so that the rows and ``moe_stats``
-        are what is left of it.
-        ``fed`` (a decoder that is fed: the real tokens of each slot)
-        rides in the same put as the tokens; what the dispatch reads of
-        the state, as the graph's ops count it (``drv.last_reads``,
-        ``drv.moe_stats``), adds up in ``phases["reads"]``, a
-        ``Counter``.
-        Returns ``(ids, logits, end)``: ``logits`` is the selected rows,
-        the whole output, or None."""
+    def _launch(self, drv, tokens, phases, t=None, last=None, rows=False,
+                fed=None, feed=None):
+        """One dispatch's launches, ``serve.decode.iter.dispatch``:
+        ``drv.step`` (staging and launch), where ``last`` names each
+        slot's last fed row ``drv.select_rows`` behind it, which picks
+        those rows and takes their argmax on the device, and where the
+        graph routes the stack of its ``moe_stats`` cells
+        (``drv.moe_stats_begin``): everything of this dispatch that the
+        next step's launch would take away, so that another dispatch
+        may be launched before this one is fetched. ``tokens`` are the
+        host's, or the ids of the dispatch before as they lie on the
+        chip; ``feed`` says whose id this dispatch's own device tokens
+        carry (``select_rows``). ``fed`` (a decoder that is fed: the
+        real tokens of each slot) rides in the same put as the tokens.
+        Adds the duration to ``phases["dispatch"]`` on the scheduler's
+        clock; ``t`` is the reading that closed the previous phase (one
+        read a boundary), None reads it. Inside, the driver times its
+        own parts on the same clock (``phases["stage"]``, ``["launch"]``,
+        ``["select"]``), and what the dispatch reads of the state, as
+        the graph's ops count it (``drv.last_reads``), adds up in
+        ``phases["reads"]``, a ``Counter``, the rows its program ran
+        over in ``phases["program_rows"]``. Returns what ``_fetch``
+        takes - a ``_Launched``, all of it on the device, ``out`` the
+        whole output where ``last`` is None (the speculative path: the
+        verifier reads every row of target and draft) and None
+        otherwise - and the reading that closed the phase."""
         now = self._clock.now
         if t is None:
             t = now()
-        reads = phases["reads"]
+        picked = ids = nxt = None
         with _telemetry.span("serve.decode.iter.dispatch"):
-            if fed is None:
-                out = drv.step(tokens, now=now)
-            else:
-                out = drv.step(tokens, fed=fed, now=now)
+            out = drv.step(tokens, fed=fed, now=now)
             drv.release_outputs()       # ``out`` is this call's alone
-            reads.update(drv.last_reads)
+            phases["reads"].update(drv.last_reads)
             phases["stage"] += drv.last_stage
             phases["launch"] += drv.last_launch
+            phases["program_rows"] += drv.last_program_rows
             if last is not None:
-                picked, ids = drv.select_rows(out, last, now=now)
+                picked, ids, nxt = drv.select_rows(out, last, feed=feed,
+                                                   now=now)
+                out = None
                 phases["select"] += drv.last_select
                 if rows:
                     picked.copy_to_host_async()
-        t_launched = now()
-        with _telemetry.span("serve.decode.iter.fetch"):
             # where the tokens went, counted inside the program: the
-            # copies queue behind it beside the ids', so reading them
+            # copy queues behind it beside the ids', so reading it
             # afterwards waits for nothing further
             routed = drv.moe_stats_begin()
-            if last is None:
-                ids, logits = None, out.asnumpy()
+        t_launched = now()
+        phases["dispatch"] += t_launched - t
+        return _Launched(out, picked, ids, nxt, routed), t_launched
+
+    def _fetch(self, drv, launched, phases, t, rows=False):
+        """What the host samples from, ``serve.decode.iter.fetch``: waits
+        for the device and copies the (rung,) int32 token ids of one
+        ``_launch``, and the (rung, V) selected rows besides only where
+        ``rows`` says a slot that samples in this iteration is not
+        greedy; with no ids (the speculative path) the whole (rung, S,
+        V) output. ``t`` is the reading that closed the phase before:
+        the launches' own, or those of the dispatch launched behind
+        this one. Adds the duration to ``phases["fetch"]`` and the
+        bytes brought to the host to ``phases["bytes"]``;
+        ``serve.decode.iter.fetch.ids`` is ``np.asarray(ids)`` alone
+        (``phases["ids"]``), so that the rows and ``moe_stats``
+        (``phases["reads"]``) are what is left of it. Returns ``(ids,
+        logits, end)``: ``logits`` is the selected rows, the whole
+        output, or None."""
+        now = self._clock.now
+        out, picked, ids, _nxt, routed = launched
+        with _telemetry.span("serve.decode.iter.fetch"):
+            if ids is None:
+                logits = out.asnumpy()
                 nbytes = logits.nbytes
             else:
                 # the wait for the device, and 4 bytes a slot
@@ -1220,13 +1297,19 @@ class DecodeScheduler:
                 nbytes = ids.nbytes + (logits.nbytes if rows else 0)
             if routed is not None:
                 with _telemetry.span("serve.decode.iter.moe_stats"):
-                    reads.update(drv.moe_stats(routed))
-                nbytes += sum(a.nbytes for a in routed)
+                    phases["reads"].update(drv.moe_stats(routed))
+                nbytes += routed.nbytes
         end = now()
-        phases["dispatch"] += t_launched - t
-        phases["fetch"] += end - t_launched
+        phases["fetch"] += end - t
         phases["bytes"] += nbytes
         return ids, logits, end
+
+    def _step_fetch(self, drv, tokens, phases):
+        """One dispatch whose whole output comes to the host, launched
+        and fetched in one go (``_launch``, ``_fetch``): ``(None, logits,
+        end)``."""
+        launched, t = self._launch(drv, tokens, phases)
+        return self._fetch(drv, launched, phases, t)
 
     def _dispatch_spec(self, drv, ddrv, base_tokens, meta, K, phases):
         """One speculative iteration's device work (runs OUTSIDE the
@@ -1261,124 +1344,229 @@ class DecodeScheduler:
         return out
 
     def _iterate(self):
-        """One scheduling iteration; returns tokens emitted (0 = no
-        work was ready). ``serve.decode.iter`` in the profiler's trace,
-        enclosing its phases ``.plan``, ``.dispatch``, ``.fetch``,
-        ``.commit``, ``.rewind`` and ``.account``; the ring record
+        """One scheduling iteration, the commit of one dispatch;
+        returns tokens emitted (0 = no work was ready).
+        ``serve.decode.iter`` in the profiler's trace, enclosing its
+        phases ``.plan``, ``.dispatch``, ``.fetch``, ``.commit``,
+        ``.rewind`` and ``.account``; the ring record
         ``serve.decode.step`` carries the same ``iter`` number and the
-        phases' durations on the scheduler's clock."""
+        dispatch's durations on the scheduler's clock (``ahead`` 1:
+        its plan and launches lie an iteration back)."""
         # read without the lock: only this, the iterating thread, ever
         # writes the count (pump() and the dispatch thread never overlap)
         it = self.iterations  # mxlint: guarded-by(gil)
         with _telemetry.span("serve.decode.iter", iter=it) as iter_span:
             return self._run_iteration(iter_span)
 
+    def _retire_expired(self, now):
+        """Complete the active sequences whose deadline has passed with
+        their partial output (caller holds the lock)."""
+        for seq in self._active():
+            if seq.deadline is not None and now > seq.deadline:
+                self._finish(seq, reason="deadline", now=now)
+
+    def _plan_locked(self, now):
+        """Retire, admit, pick the rung and plan one dispatch from the
+        state as it stands (caller holds the lock): the ``_Dispatch``
+        to launch, or None where nothing is active."""
+        # retirement BEFORE dispatch: deadline-expired sequences
+        # complete with their partial output; a slot whose next
+        # token would overflow its cache slice fails ALONE — the
+        # program was never dispatched for it, batchmates continue
+        self._retire_expired(now)
+        for row in self.engine.driver(self._rung).overflowing():
+            seq = self._slots[row]
+            if seq is None:          # retired row still advancing
+                continue
+            self._finish(seq, error=MXNetError(
+                f"decode {self.engine.name!r}: sequence {seq.id} "
+                f"overflowed its KV-cache slice (slot {row}, "
+                f"capacity {self.engine.capacity}); shorten the "
+                "prompt/max_new_tokens or re-bind with a larger "
+                "capacity"), now=now)
+        self._admit_locked(now)
+        active = self._active()
+        if not active:
+            return None
+        # shrink to the smallest rung covering the live set (frees
+        # the larger pool's compute for the next iterations)
+        target = self.engine.ladder.bucket_for(len(active))
+        if target is not None and target < self._rung:
+            self._switch_rung(target)
+        d = _Dispatch(*self._plan_dispatch(), t0=now)
+        if d.mode == "spec":
+            d.tokens = np.zeros((self._rung, 1), np.int32)
+            for row, seq in enumerate(self._slots):
+                if seq is None:
+                    continue
+                d.tokens[row, 0] = seq.stream_token(seq.fed)
+                d.meta.append((row, seq))
+        else:
+            d.tokens = np.zeros((self._rung, d.S), np.int32)
+            # each slot's last fed row (0 where nobody owns the
+            # row), and whether a slot that samples now needs the
+            # row itself on the host: a greedy one needs its id only
+            d.last = np.zeros(self._rung, np.int32)
+            # the rows somebody owns: the ids a step after this one
+            # could be fed from the chip
+            d.feed = np.zeros(self._rung, bool)
+            # a fed decoder advances each slot by its real tokens
+            # alone: a row nobody owns is fed nothing
+            d.fed = np.zeros(self._rung, np.int32) \
+                if self.engine.feeds else None
+            for row, seq, n in self._plan_window(d.S):
+                d.tokens[row, :n] = seq.window(n)
+                d.last[row] = n - 1
+                d.feed[row] = True
+                if d.fed is not None:
+                    d.fed[row] = n
+                if n == seq.remaining() and not seq.sampling.greedy:
+                    d.want_rows = True
+                d.meta.append((row, seq, n))
+        for entry in d.meta:
+            seq = entry[1]
+            if seq.first_dispatch_at is None:
+                seq.first_dispatch_at = now
+        d.n_active = len(active)
+        if any(s.trace is not None for s in active):
+            d.shared_sid = _trace.next_span_id()
+        return d
+
+    def _plan_ahead(self, d, now):
+        """The S=1 dispatch behind ``d``, planned while ``d`` is still
+        on the chip (caller holds the lock), or None where the host
+        cannot know it without ``d``'s ids. It can where
+        ``_plan_locked`` after ``d``'s commit would plan ``("window",
+        1)`` over the same slots with nothing else to do: every active
+        slot samples at ``d`` (none has stream tokens left) and is
+        greedy, no draft shadows the dispatch, nobody finishes at ``d``
+        by length, no deadline has passed (the caller has retired the
+        active sequences whose has; a queued one waits for a plan), no
+        slot would overflow its cache, the rung stays, and admission
+        would admit nobody. Then the dispatch feeds each slot the id
+        ``d``'s select program left on the chip. What the host cannot
+        know is an EOS: a slot that retires at ``d``'s commit has had
+        one token computed for it, which the commit of this dispatch
+        drops. ``d`` need not be launched yet: the tokens are ``d``'s
+        to give once it is."""
+        if d.mode != "window" or self.draft is not None:
+            return None
+        if self._queue and (
+                None in self._slots
+                or self._rung < self.engine.ladder.max
+                or any(s.deadline is not None and now > s.deadline
+                       for s in self._queue)):
+            return None
+        fed_by = {row: (seq, n) for row, seq, n in d.meta}
+        nxt = _Dispatch("window", 1, t0=None, ahead=True)
+        nxt.last = np.zeros(self._rung, np.int32)
+        nxt.feed = np.zeros(self._rung, bool)
+        nxt.fed = np.zeros(self._rung, np.int32) \
+            if self.engine.feeds else None
+        for row, seq in enumerate(self._slots):
+            if seq is None:
+                continue
+            fed_seq, n = fed_by.get(row, (None, 0))
+            if fed_seq is not seq or not seq.sampling.greedy \
+                    or seq.fed + n != seq.stream_len() \
+                    or len(seq.generated) + 1 >= seq.max_new \
+                    or seq.fed + n + 1 > self.engine.capacity:
+                return None
+            nxt.feed[row] = True
+            if nxt.fed is not None:
+                nxt.fed[row] = 1
+            nxt.meta.append((row, seq, 1))
+        if not nxt.meta or \
+                self.engine.ladder.bucket_for(len(nxt.meta)) != self._rung:
+            return None         # nobody left, or a smaller rung is due
+        nxt.n_active = len(nxt.meta)
+        if any(seq.trace is not None for _row, seq, _n in nxt.meta):
+            nxt.shared_sid = _trace.next_span_id()
+        return nxt
+
     def _run_iteration(self, iter_span):
+        """Commit one dispatch: the one launched an iteration ago
+        (``self._ahead``), or one planned and launched here. Between
+        its launch and the fetch of its ids the dispatch behind it is
+        launched too where the host can plan it without them
+        (``_plan_ahead``): the chip then goes from one S=1 program to
+        the next while the host fetches, commits and plans."""
         span = _telemetry.span
+        clock = self._clock.now
         # a submit (a closed-loop caller's done callback) holds the lock
         # this waits for: read the clock on both sides of it
-        arrived = self._clock.now()
+        arrived = clock()
         with span("serve.decode.iter.plan"), self._lock:
-            now = self._clock.now()
+            locked = clock()
             turn_from, self._turn_from = self._turn_from, None
-            # retirement BEFORE dispatch: deadline-expired sequences
-            # complete with their partial output; a slot whose next
-            # token would overflow its cache slice fails ALONE — the
-            # program was never dispatched for it, batchmates continue
-            for seq in list(self._active()):
-                if seq.deadline is not None and now > seq.deadline:
-                    self._finish(seq, reason="deadline", now=now)
-            for row in self.engine.driver(self._rung).overflowing():
-                seq = self._slots[row]
-                if seq is None:          # retired row still advancing
-                    continue
-                self._finish(seq, error=MXNetError(
-                    f"decode {self.engine.name!r}: sequence {seq.id} "
-                    f"overflowed its KV-cache slice (slot {row}, "
-                    f"capacity {self.engine.capacity}); shorten the "
-                    "prompt/max_new_tokens or re-bind with a larger "
-                    "capacity"), now=now)
-            self._admit_locked(now)
-            active = self._active()
-            if not active:
-                self._gauge("active").set(0)
-                self._gauge("occupancy").set(0.0)
-                return 0
-            # shrink to the smallest rung covering the live set (frees
-            # the larger pool's compute for the next iterations)
-            target = self.engine.ladder.bucket_for(len(active))
-            if target is not None and target < self._rung:
-                self._switch_rung(target)
+            d, self._ahead = self._ahead, None
+            fresh = d is None
+            if fresh:
+                d = self._plan_locked(locked)
+                if d is None:
+                    self._gauge("active").set(0)
+                    self._gauge("occupancy").set(0.0)
+                    return 0
+            else:
+                # launched an iteration ago: a sequence whose time ran
+                # out since leaves here, as before any plan, and the
+                # token on its way is dropped at the commit
+                self._retire_expired(locked)
+            nxt = self._plan_ahead(d, locked)
             drv = self.engine.driver(self._rung)
             ddrv = self.draft.driver(self._rung) if self.draft else None
-            mode, S = self._plan_dispatch()
-            meta = []                    # (row, seq[, n_fed]) rows
-            if mode == "spec":
-                tokens = np.zeros((self._rung, 1), np.int32)
-                for row, seq in enumerate(self._slots):
-                    if seq is None:
-                        continue
-                    tokens[row, 0] = seq.stream_token(seq.fed)
-                    meta.append((row, seq))
-            else:
-                tokens = np.zeros((self._rung, S), np.int32)
-                # each slot's last fed row (0 where nobody owns the
-                # row), and whether a slot that samples now needs the
-                # row itself on the host: a greedy one needs its id only
-                last = np.zeros(self._rung, np.int32)
-                want_rows = False
-                # a fed decoder advances each slot by its real tokens
-                # alone: a row nobody owns is fed nothing
-                fed = np.zeros(self._rung, np.int32) \
-                    if self.engine.feeds else None
-                for row, seq, n in self._plan_window(S):
-                    tokens[row, :n] = seq.window(n)
-                    last[row] = n - 1
-                    if fed is not None:
-                        fed[row] = n
-                    if n == seq.remaining() and not seq.sampling.greedy:
-                        want_rows = True
-                    meta.append((row, seq, n))
-            for entry in meta:
-                seq = entry[1]
-                if seq.first_dispatch_at is None:
-                    seq.first_dispatch_at = now
-            active = list(self._active())
-            shared_sid = _trace.next_span_id() \
-                if any(s.trace is not None for s in active) else None
-            iter_span.set(mode=mode, window=S, rung=self._rung,
-                          active=len(active))
-            t0 = now
-            planned = self._clock.now()
+            iter_span.set(mode=d.mode, window=d.S, rung=self._rung,
+                          active=d.n_active)
+            planned = clock()
+            # the section's time is the plan of what it planned first
+            # (of the dispatch it commits where it planned nothing)
+            (d if fresh else nxt or d).plan_s += planned - locked
 
         # dispatch outside the lock: submits stay non-blocking while
         # the program runs (only pump()/the dispatch thread iterates,
         # so the engine itself needs no second guard)
-        phases = {"dispatch": 0.0, "fetch": 0.0, "bytes": 0, "stage": 0.0,
-                  "launch": 0.0, "select": 0.0, "ids": 0.0,
-                  "reads": collections.Counter()}
-        if mode == "spec":
+        if d.mode == "spec":
             verdicts = self._dispatch_spec(
-                drv, ddrv, tokens, [(r, s) for r, s in meta], S, phases)
-            end = self._clock.now()
+                drv, ddrv, d.tokens, d.meta, d.S, d.phases)
+            end = clock()
         else:
-            ids, picked, end = self._step_fetch(
-                drv, tokens, phases, t=planned, last=last, rows=want_rows,
-                fed=fed)
+            if fresh:
+                d.launched, planned = self._launch(
+                    drv, d.tokens, d.phases, t=planned, last=d.last,
+                    rows=d.want_rows, fed=d.fed, feed=d.feed)
+            if nxt is not None:
+                if d.S > 1 and not self.engine.feeds:
+                    # the window advanced every cursor by S: the slots
+                    # that fed fewer go back before the next step reads
+                    # them, and not again in the commit
+                    behind = [(row, seq.fed + n) for row, seq, n in d.meta
+                              if n < d.S]
+                    if behind:
+                        with span("serve.decode.iter.rewind"):
+                            drv.rewind_many(*zip(*behind))
+                    d.rewound = True
+                nxt.launched, planned = self._launch(
+                    drv, d.launched.tokens, nxt.phases, t=planned,
+                    last=nxt.last, fed=nxt.fed, feed=nxt.feed)
+            ids, picked, end = self._fetch(drv, d.launched, d.phases,
+                                           planned, rows=d.want_rows)
+            if nxt is not None:
+                nxt.t0 = end    # a token a slot from here to its own ids
             if ddrv is not None:
                 # the draft shadows every non-speculative dispatch so
                 # its cache tracks the same stream positions; nobody
                 # reads its logits, so it is launched and not waited for
                 with span("serve.decode.iter.dispatch"):
-                    ddrv.step(tokens, now=self._clock.now)
-                phases["stage"] += ddrv.last_stage
-                phases["launch"] += ddrv.last_launch
-                launched = self._clock.now()
-                phases["dispatch"] += launched - end
+                    ddrv.step(d.tokens, now=clock)
+                d.phases["stage"] += ddrv.last_stage
+                d.phases["launch"] += ddrv.last_launch
+                launched = clock()
+                d.phases["dispatch"] += launched - end
                 end = launched
 
+        mode, S, phases = d.mode, d.S, d.phases
         with self._lock:
-            step_s = max(0.0, end - t0)
+            step_s = max(0.0, end - d.t0)
             self.engine.note_exec(self._rung if S == 1
                                   else (self._rung, S), step_s)
             chunks = 0
@@ -1386,13 +1574,12 @@ class DecodeScheduler:
             with span("serve.decode.iter.commit"):
                 if mode == "spec":
                     emitted = self._commit_spec(
-                        meta, verdicts, S, t0, end, shared_sid,
+                        d.meta, verdicts, S, d.t0, end, d.shared_sid,
                         rew_rows, rew_pos)
                 else:
                     emitted, chunks = self._commit_window(
-                        meta, ids, picked, S, t0, end, shared_sid,
-                        len(active), rew_rows, rew_pos)
-            committed = self._clock.now()
+                        d, ids, picked, end, rew_rows, rew_pos)
+            committed = clock()
             with span("serve.decode.iter.rewind"):
                 # retired rows keep advancing one window per dispatch;
                 # pull any nearing capacity back to 0, so that a row
@@ -1409,10 +1596,11 @@ class DecodeScheduler:
                     drv.rewind_many(rew_rows, rew_pos)
                     if ddrv is not None:
                         ddrv.rewind_many(rew_rows, rew_pos)
-            rewound = self._clock.now()
+            rewound = clock()
             # the next iteration's ``turn_us`` runs from here: the
             # account below, the loop, and up to its read before the lock
             self._turn_from = rewound
+            self._ahead = nxt
             with span("serve.decode.iter.account"):
                 it = self.iterations
                 self.iterations += 1
@@ -1423,13 +1611,15 @@ class DecodeScheduler:
                     m["tokens"].inc(emitted)
                 if chunks:
                     m["prefill.chunks"].inc(chunks)
+                if nxt is not None:
+                    m["runahead.launched"].inc()
                 m["fetch.bytes"].inc(phases["bytes"])
                 if S > 1 and mode != "spec":
-                    rows = [n for _row, _seq, n in meta]
+                    rows = [n for _row, _seq, n in d.meta]
                     m["window.fed_slots"].inc(sum(n >= 1 for n in rows))
                     m["window.riding_slots"].inc(sum(n == 1 for n in rows))
                     m["window.real_rows"].inc(sum(rows))
-                    m["window.program_rows"].inc(drv.last_program_rows)
+                    m["window.program_rows"].inc(phases["program_rows"])
                 # what the dispatches read of the state, under the
                 # names its ops gave: a counter, a ring field, or both
                 read_fields = {}
@@ -1449,7 +1639,7 @@ class DecodeScheduler:
                 _telemetry.flightrec.note(
                     "serve.decode.step", model=self.engine.name, iter=it,
                     rung=self._rung, active=n_active,
-                    step_us=_us(step_s), plan_us=_us(planned - t0),
+                    step_us=_us(step_s), plan_us=_us(d.plan_s),
                     dispatch_us=_us(phases["dispatch"]),
                     stage_us=_us(phases["stage"]),
                     launch_us=_us(phases["launch"]),
@@ -1460,25 +1650,32 @@ class DecodeScheduler:
                     rewind_us=_us(rewound - committed),
                     turn_us=0 if turn_from is None
                     else _us(arrived - turn_from),
-                    lock_us=_us(now - arrived), mode=mode, window=S,
+                    lock_us=_us(locked - arrived), mode=mode, window=S,
+                    ahead=int(d.ahead),
                     compiles_since_warmup=compiles, **read_fields)
         return max(1, emitted)
 
-    def _commit_window(self, meta, ids, picked, S, t0, end, shared_sid,
-                       n_active, rew_rows, rew_pos):
-        """Apply one window (or S=1) iteration's outcome (caller holds
-        the lock): ``ids[row]`` is the argmax of the slot's last fed
-        row, taken on the device, and ``picked[row]`` that row itself,
-        fetched only when a slot sampling now is not greedy. Where a
-        slot's stream is exhausted a greedy request takes its id and
-        any other hands its row to ``sample_token``; stream the tokens,
-        retire on EOS / max-new, and queue a cursor rewind for every
-        slot that fed fewer than S tokens. Returns ``(emitted, prefill
-        chunks)``."""
+    def _commit_window(self, d, ids, picked, end, rew_rows, rew_pos):
+        """Apply the outcome of one window (or S=1) dispatch ``d``
+        (caller holds the lock): ``ids[row]`` is the argmax of the
+        slot's last fed row, taken on the device, and ``picked[row]``
+        that row itself, fetched only when a slot sampling now is not
+        greedy. Where a slot's stream is exhausted a greedy request
+        takes its id and any other hands its row to ``sample_token``;
+        stream the tokens, retire on EOS / max-new, and queue a cursor
+        rewind for every slot that fed fewer than S tokens (unless they
+        were rewound before the dispatch behind ``d`` was launched). A
+        slot that retired while ``d``, launched ahead, was on the chip
+        has its token dropped and counted
+        (``serve.decode.runahead.dropped``). Returns ``(emitted,
+        prefill chunks)``."""
+        S, t0, shared_sid, n_active = d.S, d.t0, d.shared_sid, d.n_active
+        dropped = 0
         emitted = chunks = 0
         on_device = on_host = 0
-        for row, seq, n in meta:
+        for row, seq, n in d.meta:
             if seq.slot is None:
+                dropped += d.ahead
                 continue
             was_prefilling = seq.remaining() > 1
             samples = seq.fed + n == seq.stream_len()
@@ -1503,7 +1700,7 @@ class DecodeScheduler:
                 tok = sample_token(picked[row], seq.sampling, seq.rng)
                 on_host += 1
             seq.fed += n
-            if n < S and not self.engine.feeds:
+            if n < S and not self.engine.feeds and not d.rewound:
                 # the dispatch advanced the cursor by S; pull
                 # it back to the stream position actually fed (a fed
                 # decoder advanced by n: nothing ran ahead)
@@ -1523,6 +1720,8 @@ class DecodeScheduler:
         m = self._iter_metrics()
         m["sample.device"].inc(on_device)
         m["sample.host"].inc(on_host)
+        if dropped:
+            m["runahead.dropped"].inc(dropped)
         return emitted, chunks
 
     def _commit_spec(self, meta, verdicts, K, t0, end, shared_sid,
@@ -1597,7 +1796,7 @@ class DecodeScheduler:
 
     # ----------------------------------------------------------- drive modes
     def _has_work(self):
-        return bool(self._queue) or any(
+        return bool(self._queue) or self._ahead is not None or any(
             s is not None for s in self._slots)
 
     def pump(self, max_iterations=None):
@@ -1660,6 +1859,7 @@ class DecodeScheduler:
                     self._finish(seq, error=MXNetError(
                         "decode scheduler stopped"), now=now)
                 self._queue = []
+                self._ahead = None      # nobody is left to take its ids
 
     def __enter__(self):
         return self.start()
@@ -1712,6 +1912,8 @@ class DecodeScheduler:
             "migrations": c("migrations"),
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunks": c("prefill.chunks"),
+            "runahead": {"launched": c("runahead.launched"),
+                         "dropped": c("runahead.dropped")},
             "latency_ms": None if h is None or not h.count else {
                 "p50": round((h.quantile(0.50) or 0) * 1e3, 3),
                 "p99": round((h.quantile(0.99) or 0) * 1e3, 3),

@@ -613,20 +613,25 @@ def test_cursor_program_never_compiles_after_warmup(trained):
 _S31 = 4                              # the fixtures' prefill window
 
 
-def _sched31(kind, name, ladder):
-    """``serve_decoder`` with a window program over ``_pool_symbol(kind)``,
-    weights from one seed (scaled up so that the logits spread)."""
+def _served(gen, name, ladder, seed, capacity=T, chunk=_S31):
+    """``serve_decoder`` with a window program over ``gen(step_len)``'s
+    graph, weights from one seed (scaled up so that the logits
+    spread)."""
     from chipbench import weights
-
-    def gen(s):
-        return _pool_symbol(kind, s)[0]
-    shapes = {n: (1, 1) for n in _pool_symbol(kind)[1]}
+    shapes = {n: (1,) if n == "fed" else (1, 1)
+              for n in ("data", "pos_ids", "fed")
+              if n in gen(1).list_arguments()}
     params = {k: v if k.endswith(("_gamma", "_beta", "_bias")) else 12 * v
-              for k, v in weights.normal_init(gen(1), shapes, 31).items()}
+              for k, v in weights.normal_init(gen(1), shapes, seed).items()}
     return mx.serve.serve_decoder(
-        gen(1), params, name=name, capacity=T, ladder=list(ladder),
+        gen(1), params, name=name, capacity=capacity, ladder=list(ladder),
         clock=FakeClock(), start=False, symbol_gen=gen,
-        prefill_chunk=_S31, prefix_cache_mb=0)
+        prefill_chunk=chunk, prefix_cache_mb=0)
+
+
+def _sched31(kind, name, ladder):
+    """``_served`` over ``_pool_symbol(kind)``."""
+    return _served(lambda s: _pool_symbol(kind, s)[0], name, ladder, 31)
 
 
 def _fetch_whole_logits(sched):
@@ -636,13 +641,18 @@ def _fetch_whole_logits(sched):
     from mxnet_tpu.serve.sampling import SamplingParams, sample_token
     greedy = SamplingParams()
 
-    def step_fetch(drv, tokens, phases, t=None, last=None, rows=False,
-                   fed=None):
+    def launch(drv, tokens, phases, t=None, last=None, rows=False,
+               fed=None, feed=None):
         logits = drv.step(tokens).asnumpy()
         picked = logits[np.arange(len(last)), last]
         ids = [sample_token(row, greedy, None) for row in picked]
-        return np.asarray(ids, np.int32), picked, sched._clock.now()
-    sched._step_fetch = step_fetch
+        return (np.asarray(ids, np.int32), picked), t
+
+    def fetch(drv, launched, phases, t, rows=False):
+        return launched + (sched._clock.now(),)
+    sched._launch, sched._fetch = launch, fetch
+    # every token through the host: no dispatch is fed from the chip
+    sched._plan_ahead = lambda d, now: None
 
 
 def _counters31(name):
@@ -717,14 +727,14 @@ def test_select_rows_is_numpys_index_and_argmax():
     out[4, 1, :] = -np.inf
     out[4, 1, [12, 13]] = [-0.0, 0.0]                   # signed zeros tie
     idx = np.asarray([1, 2, 0, 2, 1])
-    rows, ids = drv.select_rows(mx.nd.array(out), idx)
+    rows, ids, _tokens = drv.select_rows(mx.nd.array(out), idx)
     want = out[np.arange(slots), idx]
     assert rows.dtype == jnp.float32 and ids.dtype == jnp.int32
     assert np.asarray(rows).tobytes() == want.tobytes()
     np.testing.assert_array_equal(np.asarray(ids), np.argmax(want, -1))
     assert np.asarray(ids).tolist()[:3] == [9, 0, 7]
     for other in ([0, 0, 0, 0, 0], [2, 1, 2, 0, 0]):
-        rows, ids = drv.select_rows(mx.nd.array(out), other)
+        rows, ids, _tokens = drv.select_rows(mx.nd.array(out), other)
         want = out[np.arange(slots), other]
         assert np.asarray(rows).tobytes() == want.tobytes()
         np.testing.assert_array_equal(np.asarray(ids),
@@ -1221,3 +1231,290 @@ def test_a_decode_steps_launch_puts_nothing_and_draws_no_key(kind):
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
     assert np.abs(got[-1]).max() > 0
+
+
+# ============ ISSUE 46: the next S=1 step is launched before the last
+# one's ids reach the host
+_S46 = 4                              # the fixtures' prefill window
+_T46 = {"gpt2": T, "fed": 64, "routed": T}
+_V46 = {"gpt2": V, "fed": 40, "routed": V}
+
+
+def _symbol46(kind, step_len=1):
+    """A ``gpt2`` block with learned positions, a graph that is fed
+    (EVA attention), or the routed block of ``_pool_symbol``."""
+    if kind == "fed":
+        return _launch_symbol("fed", step_len)
+    return _pool_symbol({"gpt2": "dense-learned",
+                         "routed": "rotary-routed"}[kind], step_len)[0]
+
+
+_SCHEDS46 = {}
+
+
+def _sched46(kind, order, ladder=(4,)):
+    """One scheduler a ``(kind, order, ladder)``, built once: ``"ahead"`` is the
+    scheduler as it is, ``"sync"`` the same with every dispatch planned
+    after its predecessor's commit, ``"plain"`` a pool of one for the
+    plain loop. All of a kind serve the same weights."""
+    key = (kind, order, tuple(ladder))
+    if key in _SCHEDS46:
+        return _SCHEDS46[key]
+    if order != "plain":
+        # nothing may compile behind a scheduler's warm-up mark but what
+        # it serves with: the plain loop's pool is bound first
+        _sched46(kind, "plain")
+    sched = _served(lambda s: _symbol46(kind, s),
+                    f"ahead46-{kind}-{order}-{max(ladder)}",
+                    [1] if order == "plain" else ladder, 46,
+                    capacity=_T46[kind], chunk=_S46)
+    if order == "sync":
+        sched._plan_ahead = lambda d, now: None
+    _SCHEDS46[key] = sched
+    return sched
+
+
+def _plain_greedy(kind, prompt, max_new, eos_id=None):
+    """A plain greedy loop over ``drv.step`` with host tokens: one
+    sequence in a pool of one, a token a step, every id through
+    ``np.argmax`` on the host."""
+    drv = _sched46(kind, "plain").engine.driver(1)
+    drv.join(0)
+    fed = [1] if drv.feeds else None
+    for t in prompt[:-1]:
+        drv.step(np.asarray([[t]], np.int32), fed=fed)
+    cur, out = int(prompt[-1]), []
+    for _ in range(max_new):
+        cur = int(np.argmax(drv.step(np.asarray([[cur]], np.int32),
+                                     fed=fed).asnumpy()[0, 0]))
+        if cur == eos_id:
+            break
+        out.append(cur)
+    drv.leave(0)
+    return out
+
+
+def _count46(sched, key):
+    return mx.telemetry.counter(f"serve.decode.{key}",
+                                model=sched.engine.name).value
+
+
+def _steps46(sched, since=0):
+    """The scheduler's ring records from iteration ``since`` on."""
+    return [r for r in mx.telemetry.flightrec.get_records()
+            if r.get("kind") == "serve.decode.step"
+            and r.get("model") == sched.engine.name and r["iter"] >= since]
+
+
+def _prompts46(kind, seed, n, lo=3, hi=7):
+    rs = np.random.RandomState(seed)
+    return [rs.randint(1, _V46[kind], rs.randint(lo, hi)).tolist()
+            for _ in range(n)]
+
+
+@pytest.mark.parametrize("kind", ["gpt2", "fed", "routed"])
+def test_a_long_s1_run_is_fed_from_the_chip(kind):
+    """ISSUE 46 (a): three greedy requests decode side by side; every
+    S=1 dispatch but the first after the windows is launched before its
+    predecessor's ids are on the host, and the tokens are those of the
+    plain loop and of the synchronous order. One ring record a
+    dispatch, ``ahead`` set on those launched so; nothing compiles."""
+    mx.telemetry.flightrec.configure(capacity=4096)
+    prompts = _prompts46(kind, 1, 3)
+    outs = {}
+    for order in ("ahead", "sync"):
+        sched = _sched46(kind, order)
+        n_rec, before = sched.iterations, {
+            k: _count46(sched, k) for k in
+            ("runahead.launched", "runahead.dropped", "iterations")}
+        hs = [sched.submit(p, max_new_tokens=9) for p in prompts]
+        sched.pump()
+        outs[order] = [h.result(timeout=5).tolist() for h in hs]
+        got = {k: _count46(sched, k) - v for k, v in before.items()}
+        recs = _steps46(sched, n_rec)
+        assert len(recs) == got["iterations"]
+        assert [r["iter"] for r in recs] == sorted(r["iter"] for r in recs)
+        assert sum(r["ahead"] for r in recs) == got["runahead.launched"]
+        assert all(r["window"] == 1 for r in recs if r["ahead"])
+        assert got["runahead.dropped"] == 0
+        if order == "sync":
+            assert got["runahead.launched"] == 0
+        else:
+            # every S=1 dispatch is launched ahead but one whose
+            # predecessor finishes somebody (by length: known ahead)
+            s1 = [r for r in recs if r["window"] == 1]
+            assert len(s1) >= got["runahead.launched"] >= len(s1) - 2 > 4
+            assert sched.stats()["runahead"]["launched"] == \
+                _count46(sched, "runahead.launched")
+        assert sched.engine.compiles_since_warmup() == 0
+        assert sched.engine.backend_compiles_since_warmup() == 0
+    assert outs["ahead"] == outs["sync"]
+    assert outs["ahead"] == [_plain_greedy(kind, p, 9) for p in prompts]
+
+
+@pytest.mark.parametrize("kind", ["gpt2", "fed"])
+def test_a_finish_by_length_is_known_ahead_and_the_caller_resubmits(kind):
+    """ISSUE 46 (b): a request finishes by length in mid-run and its
+    done callback submits the next one (a closed loop): the dispatch
+    that finishes it has nothing launched behind it, the newcomer's
+    window is planned after the commit, and every request's tokens are
+    the plain loop's."""
+    prompts = _prompts46(kind, 2, 4)
+    lens = [3, 11, 7, 5]
+    sched = _sched46(kind, "ahead", ladder=(2,))
+    dropped = _count46(sched, "runahead.dropped")
+    launched = _count46(sched, "runahead.launched")
+    handles = {}
+
+    def send(i):
+        h = handles[i] = sched.submit(prompts[i], max_new_tokens=lens[i])
+        if i + 2 < len(prompts):
+            h.add_done_callback(lambda _h, i=i: send(i + 2))
+
+    send(0), send(1)
+    sched.pump()
+    assert len(handles) == 4
+    for i, h in handles.items():
+        assert h.finish_reason == "length"
+        assert h.result(timeout=5).tolist() == \
+            _plain_greedy(kind, prompts[i], lens[i])
+    assert _count46(sched, "runahead.dropped") == dropped
+    assert _count46(sched, "runahead.launched") > launched
+    assert sched.engine.compiles_since_warmup() == 0
+
+
+@pytest.mark.parametrize("kind", ["gpt2", "fed"])
+def test_an_eos_drops_the_token_computed_past_it(kind):
+    """ISSUE 46 (c): the host cannot know an EOS ahead. The slot that
+    hits ``eos_id`` at dispatch n has had a token computed at n+1: it
+    is dropped and counted, the neighbour's stream is untouched, and
+    the next request in that slot decodes clean."""
+    # a prompt whose stream shows, some tokens in, an id it has not
+    # shown before: nothing ends earlier
+    for seed in range(3, 40):
+        prompts = _prompts46(kind, seed, 3)
+        free = _plain_greedy(kind, prompts[0], 9)
+        k = next((i for i in range(3, 9) if free[i] not in free[:i]), None)
+        if k is not None:
+            break
+    eos = free[k]
+    sched = _sched46(kind, "ahead", ladder=(2,))
+    dropped = _count46(sched, "runahead.dropped")
+    h0 = sched.submit(prompts[0], max_new_tokens=9, eos_id=eos)
+    h1 = sched.submit(prompts[1], max_new_tokens=10)
+    after = []
+    h0.add_done_callback(lambda _h: after.append(
+        sched.submit(prompts[2], max_new_tokens=6)))
+    sched.pump()
+    assert h0.finish_reason == "eos"
+    assert h0.result(timeout=5).tolist() == free[:k]
+    assert _count46(sched, "runahead.dropped") == dropped + 1
+    assert sched.stats()["runahead"]["dropped"] == dropped + 1
+    assert h1.result(timeout=5).tolist() == \
+        _plain_greedy(kind, prompts[1], 10)
+    assert after[0].result(timeout=5).tolist() == \
+        _plain_greedy(kind, prompts[2], 6)
+
+
+@pytest.mark.parametrize("kind", ["gpt2", "fed"])
+def test_nothing_runs_ahead_beside_a_slot_that_samples_on_the_host(kind):
+    """ISSUE 46 (d): one request that is not greedy among greedy ones:
+    while it is active no dispatch is launched ahead (its row has to be
+    on the host first); once it has left, the others are."""
+    from mxnet_tpu.serve import SamplingParams
+    prompts = _prompts46(kind, 4, 2)
+    sched = _sched46(kind, "ahead", ladder=(2,))
+    launched = _count46(sched, "runahead.launched")
+    hot = sched.submit(prompts[0], max_new_tokens=4,
+                       sampling=SamplingParams(temperature=0.8, seed=3))
+    cold = sched.submit(prompts[1], max_new_tokens=10)
+    while not hot.done():
+        sched.pump(max_iterations=1)
+        assert sched._ahead is None or hot.done()
+    before_left = _count46(sched, "runahead.launched")
+    assert before_left == launched
+    sched.pump()
+    assert _count46(sched, "runahead.launched") > before_left
+    assert cold.result(timeout=5).tolist() == \
+        _plain_greedy(kind, prompts[1], 10)
+    assert len(hot.result(timeout=5)) == 4
+
+
+@pytest.mark.parametrize("kind", ["gpt2", "fed"])
+def test_a_submit_while_a_dispatch_is_in_flight(kind):
+    """ISSUE 46 (e): a request arrives while a dispatch launched ahead
+    is on the chip: that dispatch commits first, the newcomer is
+    admitted by the plan after it, and nobody's tokens change."""
+    prompts = _prompts46(kind, 5, 2)
+    sched = _sched46(kind, "ahead", ladder=(2,))
+    first = sched.submit(prompts[0], max_new_tokens=10)
+    while sched._ahead is None:
+        assert sched.pump(max_iterations=1) == 1
+    joins = _count46(sched, "joins")
+    late = sched.submit(prompts[1], max_new_tokens=8)
+    assert sched.pump(max_iterations=1) == 1     # commits what was ahead
+    assert sched._ahead is None and _count46(sched, "joins") == joins
+    sched.pump()
+    assert _count46(sched, "joins") == joins + 1
+    assert first.result(timeout=5).tolist() == \
+        _plain_greedy(kind, prompts[0], 10)
+    assert late.result(timeout=5).tolist() == \
+        _plain_greedy(kind, prompts[1], 8)
+
+
+@pytest.mark.parametrize("room", ["fits", "overflows"])
+@pytest.mark.parametrize("kind", ["gpt2", "fed"])
+def test_a_slot_one_position_short_of_capacity(kind, room):
+    """ISSUE 46 (f): a request whose last token is fed at the last
+    position of its cache finishes by length with the plain loop's
+    tokens; one token more and it fails alone where the synchronous
+    order fails it, its neighbour untouched."""
+    cap = _T46[kind]
+    prompts = _prompts46(kind, 6, 2, lo=5, hi=6)
+    n = cap - len(prompts[0]) + (1 if room == "fits" else 2)
+    outs = {}
+    for order in ("ahead", "sync"):
+        sched = _sched46(kind, order, ladder=(2,))
+        long = sched.submit(prompts[0], max_new_tokens=n)
+        short = sched.submit(prompts[1], max_new_tokens=6)
+        sched.pump()
+        assert short.result(timeout=5).tolist() == \
+            _plain_greedy(kind, prompts[1], 6)
+        if room == "fits":
+            outs[order] = long.result(timeout=5).tolist()
+        else:
+            with pytest.raises(mx.base.MXNetError, match="overflowed"):
+                long.result(timeout=5)
+            outs[order] = long.tokens
+            assert len(long.tokens) == n - 1
+    assert outs["ahead"] == outs["sync"]
+    if room == "fits":
+        assert outs["ahead"] == _plain_greedy(kind, prompts[0], n)
+
+
+def test_the_routed_counters_follow_their_dispatch():
+    """ISSUE 46 (g): ``moe_stats`` belongs to the dispatch that wrote
+    it. With the next step launched before it is read (and every aux
+    array taken over by that step), the ``serve.decode.moe.*`` counters
+    and the ring's fields equal the synchronous order's, with slots
+    free beside the busy ones."""
+    mx.telemetry.flightrec.configure(capacity=4096)
+    prompts = _prompts46("routed", 7, 3)
+    got = {}
+    for order in ("ahead", "sync"):
+        sched = _sched46("routed", order)
+        keys = sorted(k for k, _f in sched.engine.driver(4)
+                      .read_counts.values() if k)
+        assert any(k.startswith("moe.") for k in keys)
+        before = {k: _count46(sched, k) for k in keys}
+        n_rec = sched.iterations
+        hs = [sched.submit(p, max_new_tokens=8) for p in prompts]
+        sched.pump()
+        fields = sorted(f for _k, f in sched.engine.driver(4)
+                        .read_counts.values() if f)
+        got[order] = (
+            [h.result(timeout=5).tolist() for h in hs],
+            {k: _count46(sched, k) - v for k, v in before.items()},
+            [[r.get(f) for f in fields] for r in _steps46(sched, n_rec)])
+    assert got["ahead"] == got["sync"]
+    assert got["ahead"][1]["moe.assignments"] > 0

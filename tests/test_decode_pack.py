@@ -316,8 +316,9 @@ def _counts_script(block):
     moment a last request into a used slot at the smallest rung. On a
     block without ``fed`` every window is followed by a rewind of the
     slots it ran ahead of; every join sets a cursor. Returns the model's
-    whole ``serve.decode.*`` counter set and what every iteration's
-    ring record says beside its times."""
+    whole ``serve.decode.*`` counter set, what every iteration's
+    ring record says beside its times, and the bytes that one S = 1
+    step of every rung takes over."""
     name = f"counts-{block}"
     gen = lambda s: cases.symbol(block, s)          # noqa: E731
     engine = DecodeEngine(name, gen(1), cases.params(block),
@@ -349,11 +350,22 @@ def _counts_script(block):
         k: v for k, v in r.items()
         if not k.endswith("_us") and k not in ("kind", "model", "mode",
                                                "compiles_since_warmup")}
-    return counters, [fields(r) for r in steps]
+    return counters, [fields(r) for r in steps], sum(
+        engine.driver(rung).donated_bytes for rung in engine.ladder)
 
 
 @pytest.mark.parametrize("block", sorted(COUNTS))
 def test_the_counters_and_the_ring_fields_are_the_parents(block):
-    counters, ring = _counts_script(block)
-    assert counters == COUNTS[block][0]
+    """What PR 44's parent counted, and beside it what ISSUE 46 added:
+    the two ``runahead`` counters, the ring's ``ahead``, and in
+    ``state.donated_bytes`` the one S = 1 step a rung more that warm-up
+    runs (fed from the chip). The script has no EOS, so every dispatch
+    launched ahead is committed: same dispatches, same counts."""
+    counters, ring, step_bytes = _counts_script(block)
+    launched = counters.pop("runahead.launched")
+    assert counters.pop("runahead.dropped") == 0
+    assert launched == sum(r.pop("ahead") for r in ring) > 0
+    want = dict(COUNTS[block][0])
+    want["state.donated_bytes"] += step_bytes
+    assert counters == want
     assert ring == COUNTS[block][1]

@@ -72,7 +72,11 @@ def _decode_symbol(step_len):
         per_slot=True, step_len=step_len, max_seq_len=T)
 
 
-def _scheduler(name, clock):
+def _scheduler(name, clock, ahead=False):
+    """A tiny scheduler of one rung. Every dispatch is planned after
+    its predecessor's commit (the synchronous order, which these tests
+    pinned before ISSUE 46 and which stays the order of every dispatch
+    that cannot run ahead) unless ``ahead``."""
     sym = tfm.get_symbol(vocab_size=V, d_model=D, n_layer=L, n_head=H,
                          seq_len=8, include_loss=False, max_seq_len=T)
     mod = mx.mod.Module(sym, label_names=[])
@@ -80,10 +84,13 @@ def _scheduler(name, clock):
     mod.init_params(mx.initializer.Xavier(rnd_type="gaussian",
                                           magnitude=2))
     args, _ = mod.get_params()
-    return mx.serve.serve_decoder(
+    sched = mx.serve.serve_decoder(
         _decode_symbol(1), args, name=name, capacity=T, ladder=[2],
         symbol_gen=_decode_symbol, prefill_chunk=S, start=False,
         clock=clock)
+    if not ahead:
+        sched._plan_ahead = lambda d, now: None
+    return sched
 
 
 @pytest.fixture(scope="module")
@@ -333,6 +340,114 @@ def test_ring_record_fields_count_the_clock_reads():
             "ids_us": 976, "fetch_us": 2929, "step_us": 9765,
             "commit_us": 976, "rewind_us": 976,
             "turn_us": 976 if k else 0}, (k, got)
+
+
+# ISSUE 46: the next S=1 step is launched before the last one's ids
+# reach the host
+AHEAD_ITERS = 5
+
+
+@pytest.fixture(scope="module")
+def ahead_trace(tmp_path_factory):
+    """Five ``pump()`` iterations of the same request with nothing
+    held back: two windows, the S=1 step that samples the first token
+    and launches the next step behind itself, and two iterations that
+    each commit a step launched an iteration ago and launch another."""
+    tm.disable()
+    sched = _scheduler("spans-ahead", MonotonicClock(), ahead=True)
+    sched.submit(np.arange(1, 10), max_new_tokens=4)
+    events = _profiled(tmp_path_factory.mktemp("ahead"),
+                       lambda: sched.pump(max_iterations=AHEAD_ITERS))
+    assert sched._ahead is not None
+    sched.pump()
+    return events
+
+
+@pytest.mark.parametrize("name", [
+    "serve.decode.iter." + p for p in DECODE_PHASES]
+    + list(DRIVER_SPANS) + ["serve.decode.iter.fetch.ids", "executor.run"])
+def test_ahead_spans_keep_their_names_and_their_parents(ahead_trace, name):
+    """One plan, fetch, commit, rewind and account an iteration whatever
+    the order; the launches are one a dispatch, so one more than the
+    iterations while a dispatch is in flight."""
+    launches = name.endswith(".dispatch") or name in DRIVER_SPANS \
+        or name == "executor.run"
+    assert len(_named(ahead_trace, "serve.decode.iter")) == AHEAD_ITERS
+    assert len(_named(ahead_trace, name)) == AHEAD_ITERS + launches
+    parent = "serve.decode.iter"
+    if name in DRIVER_SPANS or name == "executor.run":
+        parent = "serve.decode.iter.dispatch"
+    elif name.endswith("fetch.ids"):
+        parent = "serve.decode.iter.fetch"
+    _assert_inside(ahead_trace, name, parent)
+
+
+def test_a_step_is_launched_before_its_predecessors_ids_are_fetched(
+        ahead_trace):
+    """From the S=1 step that samples the first token on, every
+    iteration launches the next step between its plan and its fetch."""
+    iters = sorted((e[2], e[3]) for e in
+                   _named(ahead_trace, "serve.decode.iter"))
+    per_iter = []
+    for lo, hi in iters:
+        inside = sorted(
+            (e[2], e[3], e[1].rsplit(".", 1)[-1]) for e in ahead_trace
+            if e[1] in ("serve.decode.iter.plan",
+                        "serve.decode.iter.dispatch",
+                        "serve.decode.iter.fetch",
+                        "serve.decode.iter.commit") and lo <= e[2] < hi)
+        for (_a, end, _n), (start, _b, _m) in zip(inside, inside[1:]):
+            assert end <= start
+        per_iter.append([n for _a, _b, n in inside])
+    assert per_iter == [
+        ["plan", "dispatch", "fetch", "commit"]] * 2 + [
+        ["plan", "dispatch", "dispatch", "fetch", "commit"]] + [
+        ["plan", "dispatch", "fetch", "commit"]] * 2
+    stats = [e[4] for e in sorted(_named(ahead_trace, "serve.decode.iter"),
+                                  key=lambda e: e[2])]
+    assert [int(st["window"]) for st in stats] == [S, S, 1, 1, 1]
+
+
+def test_ring_record_of_a_dispatch_launched_ahead_counts_the_clock_reads():
+    """Under the clock that ticks once a read: a dispatch launched
+    ahead has its own launches in ``dispatch_us`` / ``stage_us`` /
+    ``launch_us`` / ``select_us``, the time the host was blocked on its
+    ids in ``fetch_us``, and in ``step_us`` the reads from its
+    predecessor's ids on the host to its own - what one token costs a
+    caller - whichever iteration they fall in."""
+    flightrec.configure(capacity=4096)
+    flightrec.clear()
+    clock = _TickClock()
+    sched = _scheduler("spans-ring-ahead", clock, ahead=True)
+    sched.submit(np.arange(1, 10), max_new_tokens=4)
+    before = clock.now()
+    sched.pump()
+    recs = [r for r in flightrec.get_records()
+            if r["kind"] == "serve.decode.step"]
+    assert [(r["window"], r["ahead"]) for r in recs] == [
+        (S, 0), (S, 0), (1, 0), (1, 1), (1, 1), (1, 1)]
+    tick = 2.0 ** -10 * 1e6
+    # the two windows as ever (14 reads each); the step that launches
+    # one behind itself reads the launch's six more; one that commits a
+    # step launched an iteration ago and launches another reads 14, the
+    # last, which launches nothing, 8
+    assert (clock.now() - before) * 2 ** 10 == 14 + 14 + 20 + 14 + 14 + 8 + 1
+    for r in recs:
+        assert r["dispatch_us"] == int(6 * tick) and \
+            r["fetch_us"] == int(3 * tick) and r["ids_us"] == int(tick)
+        assert r["stage_us"] == r["launch_us"] == r["select_us"] \
+            == r["lock_us"] == r["commit_us"] == r["rewind_us"] == int(tick)
+    # first clock read under the lock -> ids on the host, the launch of
+    # the step behind included
+    assert recs[2]["step_us"] == int(16 * tick)
+    # from the predecessor's ids to its own: that iteration's commit
+    # and rewind, then arrival, lock, plan, a launch, the fetch
+    assert [r["step_us"] for r in recs[3:]] == [
+        int(14 * tick), int(14 * tick), int(8 * tick)]
+    # a plan section's time is charged to what it planned first, and
+    # to the dispatch it commits where it planned nothing
+    assert [r["plan_us"] for r in recs[2:]] == [
+        int(tick), 0, int(tick), int(2 * tick)]
 
 
 def test_span_without_jax_is_the_null_span():
