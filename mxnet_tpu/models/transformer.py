@@ -115,22 +115,31 @@ def _fused_attention(x, fed, carry, i, spec):
     the split into heads), then the KV-cache ``attention_decode`` in a
     decode graph (``per_slot``: a (B, 1) cursor vector, every batch row
     at its own position) or the full causal ``attention`` in a training
-    one, under the same parameter names."""
+    one, under the same parameter names. A slot-pooled decode graph is
+    a fed one (``fed``; None in the other two): the projection and the
+    whole-width norms run over the packed rows, q, k and v are unpacked
+    where they are split into heads, ``attention_decode`` advances each
+    slot by its ``fed`` and its result is packed again."""
     pfx, T = f"{spec['name']}_l{i}", spec["T"]
     d_model, n_head = spec["d_model"], spec["n_head"]
     rotary = spec["pos_embed"] == "rotary"
     qkv = _proj(x, 3 * d_model, f"{pfx}_qkv",
                 no_bias=not spec["bias"])                    # (B*T, 3D)
+
+    def heads(rows, n, name):                                # (B, T, n, dh)
+        if fed is not None:
+            return _slots(rows, fed, T, name, shape=(n, d_model // n_head))
+        return sym.Reshape(rows, shape=(-1, T, n, d_model // n_head),
+                           name=name)
+
     if spec["qk_norm"]:
         qk_norm = lambda rows, name: _norm(rows, name, spec)  # noqa: E731
-        split = lambda rows, name: sym.Reshape(              # noqa: E731
-            rows, shape=(-1, T, n_head, d_model // n_head), name=name)
+        split = lambda rows, name: heads(rows, n_head, name)  # noqa: E731
         q, k, v = (_qkv_heads(qkv, j, nm, pfx, split, d_model, norm)
                    for j, (nm, norm) in enumerate(
                        (("q", qk_norm), ("k", qk_norm), ("v", None))))
     else:
-        qkv = sym.Reshape(qkv, shape=(-1, T, 3 * n_head, d_model // n_head),
-                          name=f"{pfx}_qkv_split")
+        qkv = heads(qkv, 3 * n_head, f"{pfx}_qkv_split")
         qkv = sym.transpose(qkv, axes=(0, 2, 1, 3),
                             name=f"{pfx}_qkv_t")             # (B, 3H, T, dh)
         q, k, v = (sym.slice_axis(qkv, axis=1, begin=j * n_head,
@@ -138,9 +147,11 @@ def _fused_attention(x, fed, carry, i, spec):
                    for j, nm in enumerate("qkv"))
     if spec["decode"]:
         att = sym.attention_decode(
-            q, k, v, capacity=spec["capacity"], rope=rotary,
+            q, k, v, *(() if fed is None else (fed,)),
+            capacity=spec["capacity"], rope=rotary,
             rope_base=spec["rope_base"], per_slot=spec["per_slot"],
-            cache_dtype=spec["cache_dtype"] or "", name=f"{pfx}_attn")
+            cache_dtype=spec["cache_dtype"] or "", name=f"{pfx}_attn",
+            **({} if fed is None else {"fed": True}))
     else:
         if rotary:
             q = sym.RoPE(q, base=spec["rope_base"], name=f"{pfx}_rope_q")
@@ -148,6 +159,9 @@ def _fused_attention(x, fed, carry, i, spec):
         att = sym.attention(q, k, v, causal=True, name=f"{pfx}_attn")
     att = sym.transpose(att, axes=(0, 2, 1, 3),
                         name=f"{pfx}_attn_t")                # (B, T, H, dh)
+    if fed is not None:
+        return _packed_rows(att, fed, f"{pfx}_attn_merge", fold=(-3, -3)), \
+            None
     return sym.Reshape(att, shape=(-3, -3), name=f"{pfx}_attn_merge"), None
 
 
@@ -426,7 +440,7 @@ def _gpt2_spec(spec):
     _check_heads(spec)
     return dict(spec, norm=("LayerNorm", {}), attention=_fused_attention,
                 bias=True, qk_norm=False, dense=("gelu", 4 * spec["d_model"]),
-                dense_layers=spec["n_layer"])
+                dense_layers=spec["n_layer"], fed=spec["per_slot"])
 
 
 def _olmoe_spec(spec):
@@ -446,10 +460,12 @@ def _olmoe_spec(spec):
             "block='olmoe' needs n_expert >= top_k >= 1 and "
             f"expert_width (got {n_expert}, {top_k}, {width})")
     _check_heads(spec)
+    fed = spec["per_slot"]
     return dict(spec, norm=_rms(spec), attention=_fused_attention,
                 bias=False, qk_norm=True, dense_layers=0,
-                moe_fold="moe_fold",
-                moe=dict(num_experts=int(n_expert), num_hidden=int(width),
+                moe_fold="moe_fold", fed=fed,
+                moe=dict(**({"step_len": spec["T"]} if fed else {}),
+                         num_experts=int(n_expert), num_hidden=int(width),
                          top_k=int(top_k),
                          norm_topk=bool(spec["norm_topk"])))
 
@@ -774,6 +790,9 @@ def _embedded(spec):
     if spec["pos_embed"] == "learned":
         pos_ids = sym.var("pos_ids") if spec["decode"] else sym._arange(
             start=0, stop=float(spec["T"]), name=f"{name}_pos_ids")
+        if fed is not None:             # a row's position goes with it
+            pos_ids = sym.pack_rows(pos_ids, fed,
+                                    name=f"{name}_pos_rows")[0]
         pos = sym.Embedding(data=pos_ids,
                             weight=sym.var(f"{name}_pos_embed_weight"),
                             input_dim=spec["max_seq_len"],
@@ -879,13 +898,16 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     ``XING4_KEYS``) and ``"afmoe"`` (``_afmoe_spec``: ``afmoe``,
     ``AFMOE_KEYS``; ``max_step_len``).
 
-    Their graphs take one more input, ``fed`` ``(slots,)`` int32 - how
-    many of each slot's ``step_len`` tokens are real - and advance a
-    slot's state by exactly that. They pass between the rows their
-    row-wise operations run over and ``(slots, step_len, .)`` through
-    ``pack_rows`` / ``unpack_rows`` (``ops/rows.py``), which keep all
-    ``slots x step_len`` rows as built here; ``packed_window`` derives
-    the form of a window graph that runs over a budget of real rows.
+    Every slot-pooled graph (``per_slot=True``, whatever the block)
+    takes one more input, ``fed`` ``(slots,)`` int32 - how many of each
+    slot's ``step_len`` tokens are real - and advances a slot's state
+    by exactly that: nothing runs ahead, nothing is rewound after a
+    window. It passes between the rows its row-wise operations run over
+    and ``(slots, step_len, .)`` through ``pack_rows`` / ``unpack_rows``
+    (``ops/rows.py``), which keep all ``slots x step_len`` rows as built
+    here; ``packed_window`` derives the form of a window graph that runs
+    over a budget of real rows. The one-cursor graph (``per_slot=False``:
+    ``KVCacheDecoder``'s) has no such input.
     """
     spec = _spec(dict(locals()), decode=True)
     logits, fed = _logits(spec)
@@ -962,6 +984,16 @@ class KVCacheDecoder:
         self.capacity = int(capacity)
         self.pos_embed = pos_embed
         self.pos = 0
+        exe = module._exec_group.executor
+        if pos_embed == "learned" and exe._compute_dtype is not None \
+                and np.issubdtype(exe.arg_dict["pos_ids"].dtype,
+                                  np.floating):
+            # a float cell is cast to the compute width at graph entry,
+            # and bfloat16 holds no odd position past 256
+            raise MXNetError(
+                "a decode module that computes in "
+                f"{np.dtype(exe._compute_dtype).name} takes its positions "
+                "as whole numbers: bind pos_ids as an int32 DataDesc")
         self._new_session_trace()
 
     def _new_session_trace(self):
@@ -1002,7 +1034,7 @@ class KVCacheDecoder:
         data = [nd.array(tokens.astype(np.int32))]
         if self.pos_embed == "learned":
             data.append(nd.array(
-                np.arange(self.pos, self.pos + S, dtype=np.float32)))
+                np.arange(self.pos, self.pos + S, dtype=np.int32)))
         t0 = time.perf_counter()
         if self.trace.start_s is None:
             self.trace.start_s = t0
@@ -1049,13 +1081,36 @@ def slot_state(symbol):
     return families
 
 
+def ridge_rows():
+    """The rows a bfloat16 matmul carries for the price of reading its
+    weights: up to peak / bandwidth rows (two operations and two bytes
+    a weight and row) the product is bound by the weights' bytes, so a
+    row more costs nothing. From the one table of peaks
+    (``telemetry.mfu.PEAKS``), the entry of the chip the programs are
+    written for whatever host builds the graph: the v5e's 197e12 /
+    819e9 = 240, rounded up to the matmuls' tile: 256."""
+    from ..telemetry.mfu import PEAKS
+    chip = PEAKS["TPU v5e"]
+    return -(-int(chip["bf16"] / chip["hbm"]) // 128) * 128
+
+
 def packed_rows(slots, step_len):
     """The row budget R of a packed window program: one slot's whole
     prefill chunk and one token for every other slot, rounded up to the
-    matmuls' tile (128 rows; 8 at sizes under that)."""
+    matmuls' tile (128 rows; 8 at sizes under that) - and never under
+    the rows that cost nothing (``ridge_rows``; the whole window where
+    that is the smaller): a budget under them would shorten no matmul
+    and feed fewer prompt tokens a window. 8 x 64 packs to 256 and not
+    to 72; a whole window of no more than the ridge (4 x 64) gets its
+    own rows and so no packed form (``packed_window``); from 248 rows a
+    slot on the chunk and the riders are the larger and nothing
+    changes. Up to one tile the whole window (the tests' sizes) a chunk
+    and the riders it is."""
+    whole = int(slots) * int(step_len)
     rows = int(step_len) + int(slots)
     unit = 128 if rows >= 128 else 8
-    return -(-rows // unit) * unit
+    rows = -(-rows // unit) * unit
+    return rows if whole <= 128 else max(rows, min(ridge_rows(), whole))
 
 
 def packed_window(symbol, slots):
@@ -1170,12 +1225,15 @@ class BatchedKVCacheDecoder:
     state is a cursor and ``"rows"`` pools alone is *positional*: any
     position below the cursor is a row that is still there, which is
     what ``rewind`` to an arbitrary position, ``capture_rows`` and
-    ``restore_rows`` rest on. EVA attention (``block="evabyte"``) keeps
-    a ring of the open window's exact rows beside one summary per
-    closed chunk: not positional. For such a graph ``step`` takes
-    ``fed``, the number of real tokens of each slot, and the program
-    advances each slot's state by exactly that, so nothing runs ahead
-    and nothing needs rewinding; ``join`` and ``leave`` are unchanged
+    ``restore_rows`` rest on. Every graph ``get_decode_symbol(per_slot=
+    True)`` builds takes ``fed``, the number of real tokens of each
+    slot, as ``step`` hands it, and the program advances each slot's
+    state by exactly that, so nothing runs ahead and nothing needs
+    rewinding after a window (a graph built by hand without the input
+    advances every slot by S, and its caller rewinds). EVA attention
+    (``block="evabyte"``) keeps a ring of the open window's exact rows
+    beside one summary per closed chunk: not positional. For such a
+    graph ``join`` and ``leave`` are unchanged
     (everything is read by the cursor, so a cursor at 0 is a clean
     slot); ``rewind``/``rewind_many`` accept 0 or a position whose
     window's rows are all still in the ring (no row of a later window
@@ -1239,6 +1297,13 @@ class BatchedKVCacheDecoder:
                         for _nm, cell in self._cells(family))
             for family in self._state}
         self.feeds = "fed" in module.symbol.list_arguments()
+        if self.feeds and "fed" not in module.data_names:
+            # bound as a parameter it would stay what it was set to,
+            # and every step would advance the slots by that
+            raise MXNetError(
+                "this decode graph takes fed, the real tokens of each "
+                "slot, beside its tokens: bind it as data (data_names "
+                f"{list(module.data_names)}; a (slots,) int32 DataDesc)")
         # what a dispatch reads of the state, as the graph's ops say it
         # (``OpDef.state_reads``): ``read_counts`` names everything
         # they count, ``_reads`` holds one ``(executions, f(pos, fed))``

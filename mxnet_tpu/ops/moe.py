@@ -59,6 +59,11 @@ many trips as the load needs - one at the expected load - so that no
 assignment is dropped and no buffer is sized for all of them. With none
 of these set the op is the one above, to the bit.
 
+``step_len`` alone (OLMoE's slot-pooled graph) makes no share: the
+plain layer takes ``fed`` too, sends a pad's choices to a dead group
+behind the last expert - sorted last, no expert's rows, weight zero -
+and is otherwise the sort, the grouped matmuls and the combine above.
+
 Where the tokens went is counted on the device: the op's ``moe_stats``
 aux cell (int32 ``[1, assignments, experts_touched, max_expert_load]``
 of the latest forward) is overwritten by every forward, inference
@@ -261,7 +266,13 @@ def _dense_expert(x, gate, up, down):
 
 
 _Share = namedtuple("_Share", "sigmoid bias scaling first count shared "
-                    "step_len n_group topk_group")
+                    "n_group topk_group")
+
+
+def _step_len(attrs):
+    """The rows a slot has where the layer takes ``fed`` behind its
+    rows and keeps a window's pads out of its experts, else 0."""
+    return parse_int(attrs.get("step_len", 0))
 
 
 def _share_spec(attrs):
@@ -274,10 +285,11 @@ def _share_spec(attrs):
     first = parse_int(attrs.get("held_first", 0))
     count = parse_int(attrs.get("held_count", 0)) or E
     shared = parse_int(attrs.get("shared_hidden", 0))
-    step_len = parse_int(attrs.get("step_len", 0))
     n_group = parse_int(attrs.get("n_group", 1))
     topk_group = parse_int(attrs.get("topk_group", 1))
-    if not (sigmoid or bias or shared or step_len or scaling != 1.0
+    # ``step_len`` alone makes no share: the plain layer keeps the pads
+    # out by itself (``moe_ffn``)
+    if not (sigmoid or bias or shared or scaling != 1.0
             or (first, count) != (0, E) or n_group > 1):
         return None
     if first < 0 or first + count > E:
@@ -293,8 +305,18 @@ def _share_spec(attrs):
                 f"topk_group {topk_group}) is the sigmoid router's, over "
                 f"{E} experts in whole groups of at least 2, with top_k "
                 f"{top_k} experts inside the groups kept")
-    return _Share(sigmoid, bias, scaling, first, count, shared, step_len,
-                  n_group, topk_group)
+    return _Share(sigmoid, bias, scaling, first, count, shared, n_group,
+                  topk_group)
+
+
+def _real_rows(x, fed):
+    """(T,) bool: which of the rows ``x`` are real tokens. The rows are
+    whole slots of as many rows as ``fed`` says there are slots:
+    ``step_len`` of a window, or all of them under one count where the
+    window's rows are packed (``ops/rows.py``)."""
+    fed = fed.astype(jnp.int32)
+    return (jnp.arange(x.shape[0] // fed.shape[0], dtype=jnp.int32)[None, :]
+            < fed[:, None]).reshape(-1)
 
 
 def moe_share_ffn(attrs, inputs, experts_fn):
@@ -303,16 +325,7 @@ def moe_share_ffn(attrs, inputs, experts_fn):
     assignments that landed here fifth."""
     share = _share_spec(attrs)
     x, rest = inputs[0], list(inputs[1:])
-    real = None
-    if share.step_len:
-        # the rows are whole slots of as many rows as ``fed`` says
-        # there are slots: ``step_len`` of a window, or all of them
-        # under one count where the window's rows are packed
-        # (``ops/rows.py``)
-        fed = rest.pop(0).astype(jnp.int32)
-        real = (jnp.arange(x.shape[0] // fed.shape[0],
-                           dtype=jnp.int32)[None, :]
-                < fed[:, None]).reshape(-1)
+    real = _real_rows(x, rest.pop(0)) if _step_len(attrs) else None
     router = rest.pop(0)
     router_bias = rest.pop(0) if share.bias else None
     gate, up, down = rest[:3]
@@ -356,13 +369,28 @@ def moe_ffn(attrs, inputs, experts_fn):
     Returns ``([out, experts], [stats])``."""
     if _share_spec(attrs) is not None:
         return moe_share_ffn(attrs, inputs, experts_fn)
-    x, router, gate, up, down = inputs
+    x, *rest = inputs
+    real = _real_rows(x, rest.pop(0)) if _step_len(attrs) else None
+    router, gate, up, down = rest
     num_experts = gate.shape[0]
     top_k = parse_int(attrs.get("top_k", 1))
     weights, experts = moe_route(x, router, top_k,
                                  parse_bool(attrs.get("norm_topk", False)))
-    token_of_row, inverse, group_sizes = moe_sort(experts, num_experts)
+    if real is None:
+        token_of_row, inverse, group_sizes = moe_sort(experts, num_experts)
+    else:
+        # a pad's choices go to a dead group behind the last expert:
+        # sorted last, no expert's rows, and of weight zero
+        token_of_row, inverse, group_sizes = moe_sort(
+            jnp.where(real[:, None], experts, num_experts), num_experts + 1)
+        group_sizes = group_sizes[:num_experts]
+        weights = jnp.where(real[:, None], weights, 0.0)
     y = experts_fn(x[token_of_row], group_sizes, gate, up, down)
+    if real is not None:
+        # what the grouped matmuls leave in rows of no group is not read
+        routed = jnp.arange(y.shape[0], dtype=jnp.int32) \
+            < jnp.sum(group_sizes)
+        y = jnp.where(routed[:, None], y, 0.0)
     out = moe_combine(y, inverse, weights, x.dtype)
     return [out, experts], [moe_stats(group_sizes)]
 
@@ -386,14 +414,15 @@ def _moe_infer(attrs, in_shapes):
     held = E if share is None else share.count
     shared = 0 if share is None else share.shared
     shapes = [data_s]
-    if share is not None and share.step_len:
+    step_len = _step_len(attrs)
+    if step_len:
         # as many slots as ``fed`` has entries; ``step_len`` rows each
         # where its shape is not known yet
         slots = in_shapes[1][0] if in_shapes[1] is not None \
-            else T // share.step_len
-        if T % slots or (in_shapes[1] is None and T % share.step_len):
+            else T // step_len
+        if T % slots or (in_shapes[1] is None and T % step_len):
             raise ValueError(f"MoEFFN: {T} rows are no whole slots of "
-                             f"step_len {share.step_len} ({slots} slots)")
+                             f"step_len {step_len} ({slots} slots)")
         shapes.append((slots,))
     shapes.append((E, D))
     if share is not None and share.bias:
@@ -411,7 +440,7 @@ def _moe_inputs(attrs):
     has one."""
     share = _share_spec(attrs) if attrs.get("num_experts") else None
     names = ["data"]
-    if share is not None and share.step_len:
+    if _step_len(attrs):
         names.append("fed")
     names.append("router_weight")
     if share is not None and share.bias:

@@ -46,16 +46,20 @@ compiled and pinned at warmup):
   (``MXNET_SERVE_PREFILL_CHUNK``, default 64) next to its S=1 decode
   program, so a T-token prompt prefills in ⌈T/S⌉ dispatches instead of
   T and TTFT goes near-flat in prompt length. Slots mid-decode ride a
-  chunk dispatch with one real token plus pads and REWIND their cursor
-  afterwards (a join-style aux poke), so mixed prefill/decode
-  iterations lose nothing. A graph that is fed its real tokens a slot
-  (``fed``) also gets, at rungs 4 and 8, the *packed* form of its window
-  program, whose row-wise operations run over ``R = S + slots`` rows
-  and not ``slots x S`` (``models.transformer.packed_window``); the
-  scheduler then plans every window inside R - a token for each
-  decoding slot, the rest to the prefilling slots oldest first
-  (``DecodeScheduler._plan_window``) - so the chunk is prefill tokens a
-  dispatch, not a slot.
+  chunk dispatch with one real token plus pads. A graph that is fed its
+  real tokens a slot (``fed``: every graph ``get_decode_symbol(per_slot=
+  True)`` builds) advances each slot by those alone; a graph without
+  the input (built by hand) advances every slot by S, and the slots
+  that fed fewer REWIND their cursor afterwards (a join-style aux
+  poke): either way mixed prefill/decode iterations lose nothing. A
+  fed graph also gets, where that at least halves the rows, the
+  *packed* form of its window program, whose row-wise operations run
+  over a budget R and not ``slots x S`` (``models.transformer
+  .packed_rows``: a chunk and a token a slot, and never under the rows
+  a weight-bound matmul carries for free); the scheduler then plans
+  every window inside R - a token for each decoding slot, the rest to
+  the prefilling slots oldest first (``DecodeScheduler._plan_window``)
+  - so the chunk is prefill tokens a dispatch, not a slot.
 * **Prefix-cache reuse** — ``submit(prefix_id=...)`` names a shared
   prompt prefix; the first completion snapshots its cache rows into a
   ``PrefixStore`` (LRU under ``MXNET_SERVE_PREFIX_CACHE_MB``, charged
@@ -540,7 +544,12 @@ class DecodeEngine:
     def _provide_data(self, slots, step=1):
         descs = [DataDesc("data", (slots, step), np.int32)]
         if self.pos_embed == "learned":
-            descs.append(DataDesc("pos_ids", (slots, step), np.float32))
+            # whole numbers like the tokens: a float cell is cast to the
+            # compute width at graph entry, and bfloat16 holds no odd
+            # position past 256 (a program in which the compiler drops
+            # that cast reads the right row; a packed window, which
+            # copies the positions, reads its neighbour's)
+            descs.append(DataDesc("pos_ids", (slots, step), np.int32))
         if self.feeds:
             descs.append(DataDesc("fed", (slots,), np.int32))
         return descs
@@ -814,7 +823,8 @@ class DecodeScheduler:
     Fast paths (each armed only when its programs were built at engine
     construction, so steady state never compiles): ``prefill_chunk``
     S>1 window dispatches while any slot is prefilling (decoding slots
-    ride along with one real token + pads and rewind after);
+    ride along with one real token + pads; where the graph takes no
+    ``fed`` they rewind after);
     ``draft_engine`` + ``spec_k`` speculative iterations when every
     active slot is in steady state (K draft proposals, one S=K target
     verify, exact rejection, cursor rollback on both engines);
@@ -845,6 +855,12 @@ class DecodeScheduler:
                     f"draft cache capacity {self.draft.capacity} < "
                     f"target capacity {engine.capacity}: the draft "
                     "tracks the same stream")
+            if self.draft.feeds != engine.feeds:
+                raise MXNetError(
+                    "draft and target are stepped alike, a slot's real "
+                    "tokens named to both or to neither: one graph takes "
+                    f"fed (draft {self.draft.feeds}, target "
+                    f"{engine.feeds}) and the other was built without")
         if not engine.positional:
             # the state behind a closed window is summaries, the rows
             # a ring has written over are gone: no cursor move brings
@@ -914,6 +930,15 @@ class DecodeScheduler:
                                  model=self.draft.name):
                 self.draft.warmup(self._clock,
                                   rows=prefix_store is not None)
+            # a verify window feeds every slot all K rows, which is the
+            # whole-window program's to run: where the engine's warm-up
+            # compiles a packed form in its place, compile it here
+            for rung in engine.ladder:
+                drv = engine.driver(rung)
+                if drv.window_budget(self.spec_k) is not None:
+                    drv.step(np.zeros((rung, self.spec_k), np.int32))
+                    drv.release_outputs()
+                    drv.rewind_many(list(range(rung)), [0] * rung)
         with _telemetry.span("serve.decode.warmup",
                              model=self.engine.name):
             est = self.engine.warmup(self._clock,
@@ -1188,16 +1213,16 @@ class DecodeScheduler:
         Without a budget every active slot takes ``min(S, remaining)``.
         Where the engine has a packed program for this rung and length
         (``window_budget``: R rows between the slots; a fed engine
-        alone, and none that a draft shadows), the window is planned
-        inside it: decoding slots take their one token first, then the
-        prefilling slots ``min(S, remaining, what is left of R)``,
-        oldest admission first. A prefilling slot for which nothing is
-        left is fed nothing this window and is not in the plan: the
-        program leaves it where it is. R holds a whole chunk beside a
-        token a slot, so the oldest prefilling slot always moves."""
+        alone), the window is planned inside it: decoding slots take
+        their one token first, then the prefilling slots ``min(S,
+        remaining, what is left of R)``, oldest admission first. A
+        prefilling slot for which nothing is left is fed nothing this
+        window and is not in the plan: the program leaves it where it
+        is. R holds a whole chunk beside a token a slot, so the oldest
+        prefilling slot always moves."""
         seqs = [(row, seq) for row, seq in enumerate(self._slots)
                 if seq is not None]
-        budget = None if S == 1 or self.draft is not None \
+        budget = None if S == 1 \
             else self.engine.window_budget(self._rung, S)
         if budget is None:
             return [(row, seq, min(S, seq.remaining()))
@@ -1557,7 +1582,7 @@ class DecodeScheduler:
                 # its cache tracks the same stream positions; nobody
                 # reads its logits, so it is launched and not waited for
                 with span("serve.decode.iter.dispatch"):
-                    ddrv.step(d.tokens, now=clock)
+                    ddrv.step(d.tokens, fed=d.fed, now=clock)
                 d.phases["stage"] += ddrv.last_stage
                 d.phases["launch"] += ddrv.last_launch
                 launched = clock()

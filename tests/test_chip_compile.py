@@ -16,6 +16,7 @@ section 2), kept as tests:
 import argparse
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -434,7 +435,13 @@ def test_the_s1_latent_programs_lower_to_the_parents_text(lowering, v5e):
 #: the reshape that stood there. Their packed forms (``packed_window``)
 #: and the two blocks without ``fed`` are PR 44's parent's (7b8989b),
 #: computed on it before ``models/transformer.py`` built every block
-#: from one record: the builder changed, no program did.
+#: from one record: the builder changed, no program did. Since ISSUE 47
+#: the slot-pooled GPT-2 and OLMoE graphs take ``fed``: the parent's
+#: digests stand under ``*_unfed``, for the same graphs built by hand
+#: without the input (``window_pack_cases.unfed_symbol``: the builder
+#: still makes the parent's text where it is not handed ``fed``), and
+#: the fed graphs' own - S = 1, whole window, packed - were recorded on
+#: ISSUE 47's tree; ``gpt2_rotary`` joined then.
 _PARENT_PROGRAM_SHA256 = {
     ("glm_dsa", 1, "whole"): "9461136fdd6eaa09",
     ("glm_dsa", 16, "whole"): "96061a4da742fd99",
@@ -448,11 +455,27 @@ _PARENT_PROGRAM_SHA256 = {
     ("axk1", 16, "packed"): "5f5862a33ccc44fc",
     ("afmoe", 16, "packed"): "87f3395671e7b8aa",
     ("evabyte", 16, "packed"): "b06c559512c898cd",
-    ("gpt2", 1, "whole"): "e20dacc2cf812172",
-    ("gpt2", 16, "whole"): "a13e4ed537bdc517",
-    ("olmoe", 1, "whole"): "99256b25c8affc72",
-    ("olmoe", 16, "whole"): "60daa79edf026f83",
+    ("gpt2_unfed", 1, "whole"): "e20dacc2cf812172",
+    ("gpt2_unfed", 16, "whole"): "a13e4ed537bdc517",
+    ("olmoe_unfed", 1, "whole"): "99256b25c8affc72",
+    ("olmoe_unfed", 16, "whole"): "60daa79edf026f83",
+    ("gpt2", 1, "whole"): "21ee45bab5a96eb9",
+    ("gpt2", 16, "whole"): "450794b962ca88af",
+    ("gpt2", 16, "packed"): "db1262b4d233f0b9",
+    ("gpt2_rotary", 1, "whole"): "27a812ab4885915c",
+    ("gpt2_rotary", 16, "whole"): "10f9b19f6dbac22d",
+    ("gpt2_rotary", 16, "packed"): "04e970fba471bf45",
+    ("olmoe", 1, "whole"): "afab9133b8a82585",
+    ("olmoe", 16, "whole"): "c801f808ba5a429b",
+    ("olmoe", 16, "packed"): "b2d49a46a123cc68",
 }
+
+
+def _pinned_symbol(block, S):
+    import window_pack_cases as cases
+    if block.endswith("_unfed"):
+        return cases.unfed_symbol(block[:-len("_unfed")], S)
+    return cases.symbol(block, S)
 
 
 @pytest.mark.parametrize("block,S,form", sorted(_PARENT_PROGRAM_SHA256))
@@ -468,7 +491,7 @@ def test_decode_programs_lower_to_the_parents_text(block, S, form,
     monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
     kernel_tier.clear()
     try:
-        sym = cases.symbol(block, S)
+        sym = _pinned_symbol(block, S)
         if form == "packed":
             sym, budget = tfm.packed_window(sym, cases.SLOTS)
             assert budget == 24
@@ -487,7 +510,10 @@ def test_decode_programs_lower_to_the_parents_text(block, S, form,
 #: graph with fp8 pools and of EvaByte's with every head's logits:
 #: PR 44's parent's (7b8989b), computed on it. The nodes that ``+`` and ``*``
 #: make are named from a counter, so each graph is built under a name
-#: manager of its own.
+#: manager of its own. The slot-pooled GPT-2 and OLMoE graphs as above:
+#: the parent's under ``*_unfed``, built by hand, the fed ones' own
+#: recorded on ISSUE 47's tree (``fp8_cache`` is a slot-pooled graph
+#: too); the training and one-cursor graphs are the parent's still.
 _PARENT_GRAPH_SHA256 = {
     ("glm_dsa", 1): "8562f0a3f8b916d7",
     ("glm_dsa", 16): "29129fe7d994fe2c",
@@ -497,16 +523,22 @@ _PARENT_GRAPH_SHA256 = {
     ("afmoe", 16): "17df5a2e3b2f960c",
     ("evabyte", 1): "0e294d777b975dbf",
     ("evabyte", 16): "988ab43155750348",
-    ("gpt2", 1): "9d0b16baf0ccae00",
-    ("gpt2", 16): "c8ced922302c849b",
-    ("olmoe", 1): "8e930426538a9771",
-    ("olmoe", 16): "f334ca8be1f1cc19",
+    ("gpt2_unfed", 1): "9d0b16baf0ccae00",
+    ("gpt2_unfed", 16): "c8ced922302c849b",
+    ("olmoe_unfed", 1): "8e930426538a9771",
+    ("olmoe_unfed", 16): "f334ca8be1f1cc19",
+    ("gpt2", 1): "e13693f6a86a2957",
+    ("gpt2", 16): "c8cf5a65db6de372",
+    ("gpt2_rotary", 1): "942fe74ea9b1182b",
+    ("gpt2_rotary", 16): "ba38ab3f681ca623",
+    ("olmoe", 1): "e2aeb2264db3a8f5",
+    ("olmoe", 16): "2b430acdadc4d962",
     ("gpt2", "loss"): "f3eafb9ae1e1305d",
     ("gpt2", "logits"): "454a7d008d7a8bd4",
     ("olmoe", "loss"): "bce42d5f0c8d936c",
     ("olmoe", "logits"): "8b27129cb4c804ab",
     ("gpt2", "scalar_cursor"): "7f8953d42678de28",
-    ("gpt2", "fp8_cache"): "2f38e8d1f41dce91",
+    ("gpt2", "fp8_cache"): "43cf3716b98942f8",
     ("olmoe", "scalar_cursor"): "e3acb29dc5352a82",
     ("evabyte", "multibyte"): "a016ce6b612f26cc",
 }
@@ -517,21 +549,21 @@ def _pinned_graph(block, form):
     from mxnet_tpu.models import transformer as tfm
     with mx.name.NameManager():
         if isinstance(form, int):
-            return cases.symbol(block, form)
+            return _pinned_symbol(block, form)
         if form == "scalar_cursor":         # ``KVCacheDecoder``'s graph
             return tfm.get_decode_symbol(
                 block=block, step_len=4, capacity=cases.CAPACITY,
-                **dict(cases.UNFED[block], pos_embed="rotary"))
+                **dict(cases.FUSED[block], pos_embed="rotary"))
         if form == "fp8_cache":
             return tfm.get_decode_symbol(
                 block=block, capacity=cases.CAPACITY, per_slot=True,
-                cache_dtype="fp8", **cases.UNFED[block])
+                cache_dtype="fp8", **cases.FUSED[block])
         if form == "multibyte":
             return tfm.get_decode_symbol(
                 block=block, step_len=16, capacity=cases.CAPACITY,
                 per_slot=True, tie_head=False, embed_scale=False,
                 multibyte=True, **cases.BLOCKS[block])
-        kw = {k: v for k, v in cases.UNFED[block].items()
+        kw = {k: v for k, v in cases.FUSED[block].items()
               if k != "max_seq_len"}
         return tfm.get_symbol(block=block, seq_len=16, dropout=0.1,
                               include_loss=form == "loss", **kw)
@@ -571,6 +603,83 @@ def test_hyper_connected_programs_lower_at_tiny_sizes(S, form, monkeypatch):
     assert f"tensor<{rows}x256xf32>" in text        # the stream's rows
     assert f"tensor<{rows}x16xf32>" in text         # Hres, 16 a row
     assert f"tensor<{rows}x4x64xf32>" not in text
+
+
+#: two layers of the Cerebras and the OLMoE configuration at their
+#: published widths, as the doc and chat cells serve them (8 slots,
+#: windows of 64): the keywords, and the shape of a result that only the
+#: row-wise operations of a window compute - the first feed-forward
+#: product, the experts' gated rows (8 assignments a row)
+_SERVED_WIDTHS = {
+    "gpt2": (dict(vocab_size=50257, d_model=2048, n_layer=2, n_head=16,
+                  pos_embed="learned", max_seq_len=2048, capacity=2048),
+             lambda rows: f"{rows},8192"),
+    "olmoe": (dict(block="olmoe", vocab_size=50304, d_model=2048, n_layer=2,
+                   n_head=16, pos_embed="rotary", rope_base=1e4,
+                   capacity=4096, n_expert=64, top_k=8, expert_width=1024,
+                   tie_head=False, embed_scale=False),
+              lambda rows: f"{rows * 8},1024"),
+}
+
+
+@pytest.mark.parametrize("block", sorted(_SERVED_WIDTHS))
+def test_fused_blocks_pack_a_window_of_8x64_to_the_ridge_on_v5e(
+        block, v5e, monkeypatch):
+    """ISSUE 47: the slot-pooled GPT-2 and OLMoE graphs take ``fed``, so
+    their window of 8 x 64 has a packed form, over the 256 rows a
+    weight-bound matmul carries for free. It compiles for the chip with
+    the block's kernels, its row-wise operations run over 256 rows and
+    none over the whole window's 512, and the whole-window form of the
+    same graph runs them over 512."""
+    from mxnet_tpu.executor import _build_graph_runner
+    from mxnet_tpu.models import transformer as tfm
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "pallas")
+    kernel_tier.clear()
+    kw, row_wise = _SERVED_WIDTHS[block]
+    B, S = 8, 64
+    whole = tfm.get_decode_symbol(step_len=S, per_slot=True, **kw)
+    packed, R = tfm.packed_window(whole, B)
+    assert R == tfm.ridge_rows() == 256
+    assert tfm.packed_window(whole, 4) is None      # 4 x 64: free as it is
+    texts = {}
+    try:
+        for rows, symbol in ((R, packed), (B * S, whole)):
+            runner, arg_names, aux_names, _ = _build_graph_runner(
+                symbol, compute_dtype="bfloat16")
+            given = {nm: (B, S) for nm in ("data", "pos_ids")
+                     if nm in arg_names}
+            arg_shapes, _, aux_shapes = symbol.infer_shape(fed=(B,), **given)
+
+            def sds(shape, dtype):
+                return jax.ShapeDtypeStruct(tuple(shape), dtype,
+                                            sharding=v5e)
+
+            args = {nm: sds(s, jnp.int32 if nm in ("data", "fed")
+                            else jnp.bfloat16)
+                    for nm, s in zip(arg_names, arg_shapes)}
+            aux = {nm: sds(s, jnp.int32 if len(s) < 4 else jnp.bfloat16)
+                   for nm, s in zip(aux_names, aux_shapes)}
+
+            def prog(arg_vals, aux_vals):
+                outs, new_aux = runner(arg_vals, aux_vals, False, None)
+                return outs, {**aux_vals, **new_aux}
+
+            texts[rows] = jax.jit(prog, donate_argnums=(1,)) \
+                .lower(args, aux).compile().as_text()
+    finally:
+        kernel_tier.clear()
+    for text in texts.values():
+        for kernel in ("decode_attn", "cache_write") + (
+                ("moe_gmm_gate_up", "moe_gmm_down") if block == "olmoe"
+                else ()):
+            assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), \
+                kernel
+    def computes(rows, text):
+        return re.search(rf"= bf16\[{row_wise(rows)}\]\S* "
+                         r"(fusion|convolution|custom-call)\(", text)
+
+    assert computes(R, texts[R]) and not computes(B * S, texts[R])
+    assert computes(B * S, texts[B * S])
 
 
 @pytest.mark.parametrize("rows", [8, 1152, 8192],

@@ -490,8 +490,10 @@ def test_driver_counts_live_rows_from_its_cursors(monkeypatch):
     dsym = tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=L,
                                  n_head=NH, capacity=CAP, per_slot=True,
                                  max_seq_len=CAP)
-    dec = mx.mod.Module(dsym, label_names=[])
-    dec.bind([("data", (3, 1))], None, for_training=False)
+    dec = mx.mod.Module(dsym, data_names=("data", "fed"), label_names=[])
+    dec.bind([mx.io.DataDesc("data", (3, 1), np.int32),
+              mx.io.DataDesc("fed", (3,), np.int32)], None,
+             for_training=False)
     dec.init_params(initializer=None, arg_params=args, aux_params={},
                     allow_missing=True)
     drv = tfm.BatchedKVCacheDecoder(dec, capacity=CAP, slots=3)
@@ -500,13 +502,13 @@ def test_driver_counts_live_rows_from_its_cursors(monkeypatch):
     drv.join(0)
     drv.join(2)
     drv.rewind(2, 9)
-    drv.step(np.zeros((3, 1), np.int32))
+    drv.step(np.zeros((3, 1), np.int32), fed=[1, 0, 1])
     # slot 0 reads row 0, slot 2 rows 0-9; slot 1 is nobody's
     assert drv.last_reads == {"attn.live_rows": L * (1 + 10),
                               "attn.capacity_rows": L * 3 * CAP,
                               "attn.attended_rows": L * (1 + 10)}
     drv.leave(0)
-    drv.step(np.zeros((3, 1), np.int32))
+    drv.step(np.zeros((3, 1), np.int32), fed=[0, 0, 1])
     assert list(drv.last_reads.values()) == [L * 11, L * 3 * CAP, L * 11]
 
 

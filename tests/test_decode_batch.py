@@ -24,6 +24,8 @@ from mxnet_tpu.models import transformer as tfm
 from mxnet_tpu.ops.registry import get_op
 from mxnet_tpu.serve import FakeClock, QueueFullError
 
+import window_pack_cases as cases
+
 V, D, L, H, T = 64, 32, 2, 4, 16      # tiny LM; T doubles as capacity
 
 
@@ -46,16 +48,14 @@ def _args_nd(trained):
 
 def _pooled_module(trained, slots, compute_dtype=None,
                    pos_embed="rotary", capacity=T):
-    dec = mx.mod.Module(
-        tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=L,
-                              n_head=H, capacity=capacity,
-                              per_slot=True, pos_embed=pos_embed,
-                              max_seq_len=capacity),
-        data_names=("data", "pos_ids") if pos_embed == "learned"
-        else ("data",), label_names=[], compute_dtype=compute_dtype)
-    shapes = [("data", (slots, 1))] + (
-        [("pos_ids", (slots, 1))] if pos_embed == "learned" else [])
-    dec.bind(shapes, None, for_training=False)
+    sym = tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=L,
+                                n_head=H, capacity=capacity,
+                                per_slot=True, pos_embed=pos_embed,
+                                max_seq_len=capacity)
+    descs = cases.inputs(sym, slots, 1)     # tokens, positions, ``fed``
+    dec = mx.mod.Module(sym, data_names=[d.name for d in descs],
+                        label_names=[], compute_dtype=compute_dtype)
+    dec.bind(descs, None, for_training=False)
     dec.init_params(initializer=None, arg_params=_args_nd(trained),
                     aux_params={}, allow_missing=True)
     return dec
@@ -423,13 +423,14 @@ def test_learned_positions_per_slot(trained):
         return [d.step(np.asarray([[t]], np.int32)).asnumpy()[0, 0]
                 for t in tokens]
 
-    dec = mx.mod.Module(
-        tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=1,
-                              n_head=H, capacity=T, per_slot=True,
-                              pos_embed="learned", max_seq_len=T),
-        data_names=("data", "pos_ids"), label_names=[])
-    dec.bind([("data", (2, 1)), ("pos_ids", (2, 1))], None,
-             for_training=False)
+    sym = tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=1,
+                                n_head=H, capacity=T, per_slot=True,
+                                pos_embed="learned", max_seq_len=T)
+    descs = cases.inputs(sym, 2, 1)
+    assert [d.name for d in descs] == ["data", "pos_ids", "fed"]
+    dec = mx.mod.Module(sym, data_names=[d.name for d in descs],
+                        label_names=[])
+    dec.bind(descs, None, for_training=False)
     dec.init_params(initializer=None,
                     arg_params={k: mx.nd.array(v)
                                 for k, v in args.items()},
@@ -470,22 +471,22 @@ def _pool_symbol(kind, step_len=1):
               per_slot=True, max_seq_len=T, step_len=step_len)
     if kind == "dense-learned":
         return tfm.get_decode_symbol(n_layer=3, pos_embed="learned",
-                                     **kw), ("data", "pos_ids")
+                                     **kw), ("data", "pos_ids", "fed")
     return tfm.get_decode_symbol(
         n_layer=2, pos_embed="rotary", block="olmoe", n_expert=4,
         top_k=2, expert_width=16, tie_head=False, embed_scale=False,
-        **kw), ("data",)
+        **kw), ("data", "fed")
 
 
 def _bound_pool(kind, slots):
     """A bound (never stepped) slot pool of ``_pool_symbol(kind)``."""
     sym, names = _pool_symbol(kind)
+    descs = cases.inputs(sym, slots, 1)
+    assert tuple(d.name for d in descs) == names
     mod = mx.mod.Module(sym, data_names=names, label_names=[])
-    mod.bind([mx.io.DataDesc("data", (slots, 1), np.int32)]
-             + [mx.io.DataDesc(n, (slots, 1), np.float32)
-                for n in names[1:]], None, for_training=False)
+    mod.bind(descs, None, for_training=False)
     return tfm.BatchedKVCacheDecoder(
-        mod, capacity=T, pos_embed="learned" if len(names) > 1
+        mod, capacity=T, pos_embed="learned" if "pos_ids" in names
         else "rotary")
 
 
@@ -549,11 +550,12 @@ def test_cursor_program_never_compiles_after_warmup(trained):
     of 1..rung rows, retirements, rung switches — compiles nothing
     after warm-up outside ``DecodeEngine.migrate`` (whose eager per-row
     copies are not steady state), and ``cursor.updates`` / ``.rows``
-    count exactly the joins plus the non-empty rewinds."""
+    count exactly the joins plus the non-empty rewinds. A graph without
+    ``fed``, built by hand: a fed one rewinds nobody after a window."""
     def gen(s):
-        return tfm.get_decode_symbol(
-            vocab_size=V, d_model=D, n_layer=L, n_head=H, capacity=T,
-            per_slot=True, step_len=s, max_seq_len=T)
+        return cases.unfed_symbol(
+            "gpt2_rotary", s, vocab_size=V, d_model=D, n_layer=L, n_head=H,
+            capacity=T, max_seq_len=T, rope_base=10000.0)
     sched = mx.serve.serve_decoder(
         gen(1), _args_nd(trained), name="cursor29", capacity=T,
         ladder=[1, 2, 4], clock=FakeClock(), start=False,
@@ -643,7 +645,7 @@ def _fetch_whole_logits(sched):
 
     def launch(drv, tokens, phases, t=None, last=None, rows=False,
                fed=None, feed=None):
-        logits = drv.step(tokens).asnumpy()
+        logits = drv.step(tokens, fed=fed).asnumpy()
         picked = logits[np.arange(len(last)), last]
         ids = [sample_token(row, greedy, None) for row in picked]
         return (np.asarray(ids, np.int32), picked), t
@@ -702,10 +704,12 @@ def test_token_streams_equal_host_sampling_of_whole_logits(kind):
              if r.get("kind") == "serve.decode.step"
              and r.get("model") == f"ids31-{kind}"]
     assert {r["window"] for r in steps} == {1, _S31}
-    # windows that fed some row fewer than S tokens were among them
-    assert mx.telemetry.counter("serve.decode.cursor.rows",
-                                model=f"ids31-{kind}").value \
-        > got["prefill.chunks"] // 2
+    # windows that fed some slot fewer than S tokens were among them
+    fed_slots, real_rows = (
+        mx.telemetry.counter(f"serve.decode.window.{k}",
+                             model=f"ids31-{kind}").value
+        for k in ("fed_slots", "real_rows"))
+    assert 0 < real_rows < _S31 * fed_slots
     assert sched.stats()["migrations"] >= 1
     assert sched.engine.compiles_since_warmup() == 0
 
@@ -755,7 +759,9 @@ def test_fetch_bytes_and_no_compile_on_every_rung_and_window(kind):
     besides, 4 x V a slot, only when a slot that samples in it is not
     greedy; every token is counted as sampled from ids or from rows."""
     from mxnet_tpu.serve import SamplingParams
-    for rung in (1, 2, 4):
+    # rungs whose windows are not packed (3 x 4 rows under twice the
+    # budget of 8): every slot is fed its chunk in the same window
+    for rung in (1, 2, 3):
         name = f"bytes31-{kind}-{rung}"
         sched = _sched31(kind, name, ladder=[rung])   # nothing migrates
         drv = sched.engine.driver(rung)
@@ -1029,10 +1035,11 @@ def test_slot_pooled_export_artifact(trained, tmp_path):
         tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=L,
                               n_head=H, capacity=T, per_slot=True,
                               max_seq_len=T),
-        _args_nd(trained), {}, {"data": (slots, 1)},
-        data_dtypes={"data": np.int32})
+        _args_nd(trained), {}, {"data": (slots, 1), "fed": (slots,)},
+        data_dtypes={"data": np.int32, "fed": np.int32})
     p = mx.Predictor(path)
     assert p.stateful
+    fed = np.ones(slots, np.int32)          # a token a slot and step
 
     dec = _pooled_module(trained, slots)
     drv = tfm.BatchedKVCacheDecoder(dec, capacity=T)
@@ -1042,7 +1049,7 @@ def test_slot_pooled_export_artifact(trained, tmp_path):
     toks = rs.randint(0, V, (slots, 6)).astype(np.int32)
     for t in range(4):
         ref = drv.step(toks[:, t:t + 1]).asnumpy()
-        got = p.forward(data=toks[:, t:t + 1])[0].asnumpy()
+        got = p.forward(data=toks[:, t:t + 1], fed=fed)[0].asnumpy()
         np.testing.assert_allclose(got, ref, rtol=1e-5, atol=2e-6)
 
     # reset slot 1 only: slot 1 restarts from position 0 while slots
@@ -1052,7 +1059,7 @@ def test_slot_pooled_export_artifact(trained, tmp_path):
     drv.join(1)
     step5 = toks[:, 4:5].copy()
     ref = drv.step(step5).asnumpy()
-    got = p.forward(data=step5)[0].asnumpy()
+    got = p.forward(data=step5, fed=fed)[0].asnumpy()
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=2e-6)
 
 
@@ -1108,10 +1115,10 @@ _LAUNCH_S = 4                                    # the window program
 
 def _launch_symbol(kind, step_len):
     if kind == "learned":
-        return tfm.get_decode_symbol(
-            vocab_size=V, d_model=D, n_layer=L, n_head=H, capacity=T,
-            per_slot=True, pos_embed="learned", step_len=step_len,
-            max_seq_len=T)
+        # built by hand without ``fed``: every slot advances by S
+        return cases.unfed_symbol(
+            "gpt2", step_len, vocab_size=V, d_model=D, n_layer=L, n_head=H,
+            capacity=T, max_seq_len=T)
     return tfm.get_decode_symbol(           # rotary, with a ``fed`` input
         vocab_size=40, d_model=32, n_layer=1, n_head=2,
         pos_embed="rotary", rope_base=1e5, capacity=64,

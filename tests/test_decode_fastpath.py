@@ -518,8 +518,8 @@ def test_stats_show_a_serving_window_puts_nothing_and_draws_no_key(
     work = st["launch_work_since_warmup"]
     assert work["io.load_batch.puts"] == 0
     assert work["executor.rng.draws"] == 0
-    # one input (the tokens: rotary positions) a dispatch
-    assert work["io.load_batch.aliased"] == st["iterations"] > 0
+    # two inputs (the tokens and ``fed``: rotary positions) a dispatch
+    assert work["io.load_batch.aliased"] == 2 * st["iterations"] > 0
     after = mx.random.get_state()["key"]
     assert chain is after or np.array_equal(chain, after)
     assert st["compiles_since_warmup"] == 0

@@ -2,9 +2,10 @@
 ``packed_window`` and ``BatchedKVCacheDecoder.step``'s choice between
 the two forms of a window program, ``DecodeScheduler._plan_window``):
 the packed program against the whole-window program of the same graph,
-for each block that takes ``fed``, at tiny sizes on the CPU in float32;
-and the scheduler's plan inside the budget against the plan without
-one."""
+for each block - all take ``fed`` -, at tiny sizes on the CPU in
+float32; the two blocks that took none before ISSUE 47 against the
+graphs they were, built by hand; and the scheduler's plan inside the
+budget against the plan without one."""
 import numpy as np
 import pytest
 
@@ -42,7 +43,56 @@ MIXES = {
 #: logits, of magnitude 2; Xing4.0's mappings - an exp and 20 Sinkhorn
 #: rounds of the stream a sub-layer - carry such a bit ten times as far)
 TOL = {"evabyte": 0.0, "glm_dsa": 2e-5, "axk1": 2e-5, "afmoe": 2e-5,
-       "xing4": 2e-4}
+       "xing4": 2e-4, "gpt2": 0.0, "gpt2_rotary": 0.0, "olmoe": 0.0}
+
+#: ``packed_rows`` before ISSUE 47: a chunk and a token a slot, rounded
+#: up to the tile (128 rows; 8 under that)
+_CHUNK_AND_RIDERS = {
+    (2, 16): 24, (4, 16): 24, (8, 16): 24,
+    (2, 64): 72, (4, 64): 72, (8, 64): 72,
+    (2, 248): 256, (4, 248): 256, (8, 248): 256,
+    (2, 256): 384, (4, 256): 384, (8, 256): 384,
+    (2, 512): 640, (4, 512): 640, (8, 512): 640,
+    (2, 1024): 1152, (4, 1024): 1152, (8, 1024): 1152,
+}
+
+
+#: what ISSUE 47 made of them: where the whole window is more than a
+#: tile, never under the rows that cost nothing - the ridge, or the
+#: whole window where that is under the ridge
+_FREE_ROWS = {(8, 64): 256, (4, 64): 256}
+
+
+@pytest.mark.parametrize("slots,step_len", sorted(_CHUNK_AND_RIDERS))
+def test_the_budget_is_a_chunk_and_the_riders_or_the_rows_that_are_free(
+        slots, step_len):
+    """Every entry is what it was but those whose chunk and riders are
+    under the rows that cost nothing (256: the v5e's peak over its
+    bandwidth, rounded up to the tile, from the table of peaks whatever
+    the host): 8 x 64 packs to the ridge; 4 x 64 - a whole window of no
+    more than the ridge - gets its own rows and so no packed form at
+    all (as 6 x 32 does, 192 rows); up to one tile (the tests' 4 x 16
+    and 8 x 16) a chunk and the riders stand."""
+    assert tfm.ridge_rows() == 256
+    want = _FREE_ROWS.get((slots, step_len),
+                          _CHUNK_AND_RIDERS[slots, step_len])
+    assert tfm.packed_rows(slots, step_len) == want
+    assert tfm.packed_rows(6, 32) == 192 and tfm.packed_rows(8, 32) == 256
+    gen = lambda s: cases.symbol("gpt2_rotary", s)      # noqa: E731
+    form = tfm.packed_window(gen(step_len), slots)
+    if slots * step_len >= 2 * want:
+        assert form is not None and form[1] == want
+    else:
+        assert form is None
+
+
+def test_the_ridge_is_the_tables_whatever_chip_is_here(monkeypatch):
+    """The budget is read off ``telemetry.mfu.PEAKS``' entry of the chip
+    the programs are written for, never off ``jax.devices()``: a graph,
+    its budget and its digest are the same on every host."""
+    import jax
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: 1 / 0)
+    assert tfm.ridge_rows() == 256 and tfm.packed_rows(8, 64) == 256
 
 
 def test_the_budget_follows_from_the_shapes():
@@ -58,13 +108,12 @@ def test_the_budget_follows_from_the_shapes():
     assert not any(n.attrs.get("rows") for n in sym._topo_nodes()
                    if not n.is_variable)
     # nobody rides at rung 1, an S = 1 graph has nothing to pack, and a
-    # block without ``fed`` has no such nodes
+    # graph without ``fed`` (built by hand) has no such nodes
     assert tfm.packed_window(sym, 1) is None
     assert tfm.packed_window(cases.symbol("glm_dsa", 1), SLOTS) is None
-    plain = tfm.get_decode_symbol(vocab_size=32, d_model=16, n_layer=1,
-                                  n_head=2, capacity=32, step_len=S,
-                                  per_slot=True)
-    assert tfm.packed_window(plain, SLOTS) is None
+    assert tfm.packed_window(cases.unfed_symbol("gpt2", S), SLOTS) is None
+    for case in cases.FUSED:
+        assert tfm.packed_window(cases.symbol(case, S), SLOTS)[1] == R
 
 
 @pytest.mark.parametrize("fed", sorted(MIXES.values()),
@@ -110,7 +159,7 @@ def test_rows_are_copied_a_chunk_at_a_time_where_a_slot_holds_several(fed):
     np.testing.assert_array_equal(back, np.where(real[:, :, None], x, 0.0))
 
 
-@pytest.fixture(scope="module", params=sorted(cases.BLOCKS))
+@pytest.fixture(scope="module", params=cases.FED)
 def pair(request):
     """``(block, whole, packed)``: two drivers of one block and one
     parameter set, the second with the packed form of its window
@@ -138,7 +187,7 @@ def test_packed_program_equals_the_whole_window_program(pair, mix):
     block, whole, packed = pair
     fed = np.asarray(MIXES[mix])
     rs = np.random.RandomState(len(mix))
-    vocab = cases.BLOCKS[block]["vocab_size"]
+    vocab = cases.config(block)["vocab_size"]
     tokens = rs.randint(0, vocab, (SLOTS, 3 + S + 1))
     got = []
     for drv in (whole, packed):
@@ -191,13 +240,10 @@ def test_a_step_without_fed_takes_the_whole_window_program(pair):
 
 
 # ------------------------------------------------------------- the scheduler
-def _engine(block, name):
-    kw = dict(cases.BLOCKS[block], block=block, capacity=cases.CAPACITY,
-              per_slot=True, pos_embed="rotary", tie_head=False,
-              embed_scale=block == "afmoe")
-    gen = lambda s: tfm.get_decode_symbol(step_len=s, **kw)  # noqa: E731
+def _engine(block, name, ladder=(1, SLOTS), gen=None):
+    gen = gen or (lambda s: cases.symbol(block, s))
     return DecodeEngine(name, gen(1), cases.params(block),
-                        capacity=cases.CAPACITY, ladder=[1, SLOTS],
+                        capacity=cases.CAPACITY, ladder=list(ladder),
                         symbol_gen=gen, window_lens=[S])
 
 
@@ -233,10 +279,10 @@ def _serve(engine, prompts, max_new, budget=True):
         [first[i] for i in range(len(handles))], sched
 
 
-@pytest.mark.parametrize("block", ["evabyte", "axk1"])
+@pytest.mark.parametrize("block", ["evabyte", "axk1", "gpt2", "olmoe"])
 def test_the_scheduler_plans_inside_the_budget_oldest_first(block):
     rs = np.random.RandomState(7)
-    vocab = cases.BLOCKS[block]["vocab_size"]
+    vocab = cases.config(block)["vocab_size"]
     # three prompts of three chunks and a short one, admitted together
     prompts = [rs.randint(0, vocab, n) for n in (40, 40, 40, 5)]
     engine = _engine(block, f"pack-{block}")
@@ -275,22 +321,17 @@ def test_the_scheduler_plans_inside_the_budget_oldest_first(block):
 
 
 def test_an_engine_without_fed_is_planned_as_before():
-    kw = dict(vocab_size=32, d_model=16, n_layer=1, n_head=2, capacity=64,
-              per_slot=True)
-    gen = lambda s: tfm.get_decode_symbol(step_len=s, **kw)  # noqa: E731
-    shapes, _, _ = gen(1).infer_shape(data=(2, 1))
-    rs = np.random.RandomState(0)
-    params = {nm: (0.2 * rs.randn(*shape)).astype(np.float32)
-              for nm, shape in zip(gen(1).list_arguments(), shapes)
-              if nm != "data"}
-    engine = DecodeEngine("pack-plain", gen(1), params, capacity=64,
-                          ladder=[1, SLOTS], symbol_gen=gen,
-                          window_lens=[S])
+    """A graph built by hand without the input: every active slot is
+    fed its chunk, every cursor advances by S and the slots that fed
+    fewer are rewound after the window."""
+    gen = lambda s: cases.unfed_symbol("gpt2", s)       # noqa: E731
+    engine = _engine("gpt2", "pack-plain", gen=gen)
     assert not engine.feeds
     assert engine.window_budget(SLOTS, S) is None
     assert not [k for k in engine._window_mods if len(k) == 3]
     sched = DecodeScheduler(engine, clock=FakeClock(), prefill_chunk=S,
                             prefix_store=None)
+    rs = np.random.RandomState(0)
     handles = [sched.submit(rs.randint(0, 32, n), max_new_tokens=3)
                for n in (40, 40, 20, 5)]
     with sched._lock:
@@ -298,13 +339,293 @@ def test_an_engine_without_fed_is_planned_as_before():
         plan = sched._plan_window(S)
     assert [(row, n) for row, _seq, n in plan] == [(0, S), (1, S), (2, S),
                                                    (3, 5)]
+    rewound = _spy_on_rewinds(engine)
     sched.pump()
     assert [len(h.result(timeout=0)) for h in handles] == [3] * 4
+    assert rewound and all(pos for _rows, positions in rewound
+                           for pos in positions)
     ran = mx.telemetry.get_metric("serve.decode.window.program_rows",
                                   model="pack-plain").value
     windows = mx.telemetry.get_metric("serve.decode.prefill.chunks",
                                       model="pack-plain").value
     assert ran % (SLOTS * S) == 0 and ran and windows
+
+
+def _spy_on_rewinds(engine):
+    """Every ``rewind_many`` of every rung's driver from here on:
+    ``[(rows, positions)]`` of the calls that moved a cursor."""
+    calls = []
+    for rung in engine.ladder:
+        drv = engine.driver(rung)
+
+        def rewind_many(rows, positions, _inner=drv.rewind_many):
+            if len(rows):
+                calls.append((list(rows), list(positions)))
+            _inner(rows, positions)
+
+        drv.rewind_many = rewind_many
+    return calls
+
+
+@pytest.mark.parametrize("block", sorted(cases.FUSED))
+def test_a_fed_graph_serves_the_tokens_of_the_graph_it_was(block):
+    """ISSUE 47: the slot-pooled GPT-2 and OLMoE graphs take ``fed``.
+    The same requests through the fed graph (windows planned inside the
+    budget, cursors advanced by ``fed``) and through the graph as it
+    was, built by hand (every slot S rows, the riders rewound): the
+    same tokens, request by request, and the fed engine rewinds nobody."""
+    rs = np.random.RandomState(47)
+    vocab = cases.config(block)["vocab_size"]
+    prompts = [rs.randint(0, vocab, n) for n in (40, 23, 5, 1, 17)]
+    streams = {}
+    for kind, gen in (("fed", lambda s: cases.symbol(block, s)),
+                      ("unfed", lambda s: cases.unfed_symbol(block, s))):
+        engine = _engine(block, f"was-{block}-{kind}", gen=gen)
+        assert engine.feeds == (kind == "fed")
+        sched = DecodeScheduler(engine, clock=FakeClock(), prefill_chunk=S,
+                                prefix_store=None)
+        rewound = _spy_on_rewinds(engine)
+        handles = [sched.submit(p, max_new_tokens=6) for p in prompts[:3]]
+        sched.pump(max_iterations=2)
+        handles += [sched.submit(p, max_new_tokens=6) for p in prompts[3:]]
+        sched.pump()
+        streams[kind] = [h.result(timeout=0).tolist() for h in handles]
+        assert bool(rewound) == (kind == "unfed")
+        assert sched.stats()["compiles_since_warmup"] == 0
+    assert streams["fed"] == streams["unfed"]
+    assert [len(t) for t in streams["fed"]] == [6] * 5
+
+
+@pytest.mark.parametrize("block", ["gpt2", "olmoe"])
+def test_a_draft_shadows_a_window_with_the_targets_fed(block):
+    """A draft engine is stepped with the target's ``fed``: a window of
+    a drafted scheduler is planned inside the budget and runs the packed
+    program of both engines, both cursors advance by the real tokens
+    alone and neither is rewound after it. The verify window of a
+    speculative iteration feeds every slot all K rows, which is the
+    whole-window program's: the scheduler compiled it before the
+    engine's warm-up, which compiles the packed form of that length in
+    its place, so nothing compiles in steady state. The tokens are the
+    undrafted scheduler's."""
+    K = 4
+    gen = lambda s: cases.symbol(block, s)              # noqa: E731
+
+    def engine(name):
+        return DecodeEngine(name, gen(1), cases.params(block),
+                            capacity=cases.CAPACITY, ladder=[SLOTS],
+                            symbol_gen=gen, window_lens=[S, K])
+
+    rs = np.random.RandomState(48)
+    vocab = cases.config(block)["vocab_size"]
+    prompts = [rs.randint(0, vocab, n) for n in (40, 23, 5, 17)]
+    plain = DecodeScheduler(engine(f"undrafted-{block}"), clock=FakeClock(),
+                            prefill_chunk=S, prefix_store=None)
+    want = [plain.submit(p, max_new_tokens=9) for p in prompts]
+    plain.pump()
+
+    target, draft = engine(f"drafted-{block}"), engine(f"draft-{block}")
+    assert target.window_budget(SLOTS, S) == R
+    assert target.window_budget(SLOTS, K) == 8      # a packed verify form
+    sched = DecodeScheduler(target, clock=FakeClock(), draft_engine=draft,
+                            prefill_chunk=S, spec_k=K, prefix_store=None)
+    rewound = _spy_on_rewinds(target) + _spy_on_rewinds(draft)
+    drv, ddrv = target.driver(SLOTS), draft.driver(SLOTS)
+    mx.telemetry.flightrec.configure(capacity=4096)
+    handles = [sched.submit(p, max_new_tokens=9) for p in prompts]
+    modes = []
+    while not all(h.done() for h in handles):
+        assert sched.pump(max_iterations=1) == 1
+        rec = [r for r in mx.telemetry.flightrec.get_records()
+               if r.get("kind") == "serve.decode.step"
+               and r.get("model") == target.name][-1]
+        modes.append((rec["mode"], rec["window"]))
+        if modes[-1] == ("window", S):
+            assert drv.last_program_rows == ddrv.last_program_rows == R
+            assert not rewound
+        elif rec["mode"] == "spec":
+            assert drv.last_program_rows == SLOTS * K
+        live = [seq.slot for seq in sched._active()]
+        np.testing.assert_array_equal(drv.pos[live], ddrv.pos[live])
+    assert modes.count(("window", S)) >= 3 and modes.count(("spec", K)) >= 1
+    assert target.compiles_since_warmup() == 0
+    assert draft.compiles_since_warmup() == 0
+    assert draft.backend_compiles_since_warmup() == 0
+    assert [h.result(timeout=0).tolist() for h in handles] == \
+        [h.result(timeout=0).tolist() for h in want]
+
+
+def test_a_draft_built_without_fed_is_refused_beside_a_fed_target():
+    """One plan names each slot's real tokens to both engines or to
+    neither: a pair of which one graph takes ``fed`` and the other was
+    built by hand without would need a rewind of its own after every
+    window."""
+    unfed = lambda s: cases.unfed_symbol("gpt2", s)     # noqa: E731
+    for kinds in ((None, unfed), (unfed, None)):
+        target, draft = (
+            _engine("gpt2", f"alike-{i}-{gen is None}", ladder=[SLOTS],
+                    gen=gen) for i, gen in enumerate(kinds))
+        with pytest.raises(mx.base.MXNetError, match="stepped alike"):
+            DecodeScheduler(target, clock=FakeClock(), draft_engine=draft,
+                            prefill_chunk=S, spec_k=S, prefix_store=None)
+
+
+#: eight slots of one window: two prefilling, five riding, one idle
+_PLAN8 = [12, 7, 1, 1, 1, 1, 1, 0]
+
+
+@pytest.mark.parametrize("block", sorted(cases.FUSED))
+def test_a_packed_window_equals_the_whole_window_and_s1_steps(block):
+    """The plan of a serving window at rung 8 - two prefilling slots,
+    five riders, one idle slot, 24 rows of 128 real - through the
+    packed program, through the whole-window program fed the same,
+    token by token through the S = 1 program, and through the graph as
+    it was before ISSUE 47 (no ``fed``: every cursor advances by S and
+    is rewound): the same logits on every real row within the block's
+    tolerance, the same greedy tokens, the same cursors, and the same
+    logits from the S = 1 step after."""
+    slots, fed = len(_PLAN8), np.asarray(_PLAN8)
+    assert tfm.packed_rows(slots, S) == int(fed.sum()) == 24
+    rs = np.random.RandomState(8)
+    vocab = cases.config(block)["vocab_size"]
+    tokens = rs.randint(0, vocab, (slots, 2 + S + 1))
+    start = np.asarray([2, 1, 2, 0, 1, 2, 0, 1])
+
+    def walk(drv, window):
+        """Slots joined at staggered cursors, the window, one more
+        S = 1 step: the window's real rows, the cursors after it, the
+        step's logits."""
+        for slot in range(slots):
+            drv.join(slot)
+        for step in range(2):
+            drv.step(tokens[:, step], **(
+                {"fed": (start > step).astype(np.int64)} if drv.feeds
+                else {}))
+        if not drv.feeds:
+            drv.rewind_many(list(range(slots)), start)
+        out = window(drv)
+        after = drv.pos.copy()
+        live = fed > 0
+        nxt = drv.step(tokens[:, -1], **(
+            {"fed": live.astype(np.int64)} if drv.feeds else {})).asnumpy()
+        return out, after, nxt[live]
+
+    def by_window(drv):
+        out = drv.step(tokens[:, 2:2 + S], fed=fed).asnumpy()
+        return [out[slot, :n] for slot, n in enumerate(fed)]
+
+    def by_steps(drv):
+        rows = [[] for _ in range(slots)]
+        for t in range(int(fed.max())):
+            out = drv.step(tokens[:, 2 + t],
+                           fed=(fed > t).astype(np.int64)).asnumpy()
+            for slot in np.nonzero(fed > t)[0]:
+                rows[slot].append(out[slot, 0])
+        return [np.asarray(r).reshape(-1, vocab) for r in rows]
+
+    def as_it_was(drv):
+        out = drv.step(tokens[:, 2:2 + S]).asnumpy()
+        drv.rewind_many(list(range(slots)), start + fed)
+        return [out[slot, :n] for slot, n in enumerate(fed)]
+
+    packed = cases.driver(block, slots=slots)
+    whole = cases.driver(block, packed=False, slots=slots)
+    unfed = cases.unfed_driver(block, slots=slots)
+    got = {"packed": walk(packed, by_window), "whole": walk(whole, by_window),
+           "steps": walk(cases.driver(block, slots=slots), by_steps),
+           "as_it_was": walk(unfed, as_it_was)}
+    assert packed.last_program_rows == whole.last_program_rows == slots
+    assert packed.window_budget(S) == 24 and whole.window_budget(S) is None
+    want_rows, want_pos, want_next = got.pop("as_it_was")
+    np.testing.assert_array_equal(want_pos, start + fed)
+    # against the S = 1 program a window differs by the order of its sums
+    tol = {"packed": 0.0, "whole": 0.0, "steps": 2e-5}
+    for form, (rows, pos, nxt) in got.items():
+        np.testing.assert_array_equal(pos, want_pos, err_msg=form)
+        for slot, n in enumerate(fed):
+            assert rows[slot].shape == (n, vocab)
+            np.testing.assert_allclose(rows[slot], want_rows[slot], rtol=0,
+                                       atol=tol[form], err_msg=form)
+            np.testing.assert_array_equal(
+                rows[slot].argmax(-1), want_rows[slot].argmax(-1), form)
+        np.testing.assert_allclose(nxt, want_next, rtol=0, atol=tol[form],
+                                   err_msg=form)
+
+
+@pytest.mark.parametrize("block", ["gpt2_rotary", "olmoe"])
+def test_two_chunks_and_the_riders_fit_one_window_at_rung_8(block):
+    """The Cerebras and OLMoE cells' shapes, 8 slots of 64 rows: the
+    budget is the 256 rows a weight-bound matmul carries for free, so
+    two prefilling slots get a whole chunk each beside six riders in
+    one packed window; nobody is rewound after it; and the first S = 1
+    step behind the last window is launched before the window's ids are
+    on the host (ISSUE 46), with no rewind before it. The graph as it
+    was (no ``fed``) serves the same tokens, rewinds the riders before
+    that step is launched and not again at the commit."""
+    chunk, slots, capacity = 64, 8, 320
+    kw = dict(cases.config(block), capacity=capacity, per_slot=True)
+    gens = {"fed": lambda s: tfm.get_decode_symbol(step_len=s, **kw),
+            "unfed": lambda s: cases.unfed_symbol(block, s,
+                                                  capacity=capacity)}
+    rs = np.random.RandomState(64)
+    vocab = kw["vocab_size"]
+    riders = [rs.randint(0, vocab, n) for n in (3, 4, 5, 3, 4, 5)]
+    long = [rs.randint(0, vocab, 150) for _ in range(2)]
+    mx.telemetry.flightrec.configure(capacity=4096)
+    streams = {}
+    for kind, gen in gens.items():
+        name = f"rung8-{block}-{kind}"
+        engine = DecodeEngine(name, gen(1), cases.params(block),
+                              capacity=capacity, ladder=[slots],
+                              symbol_gen=gen, window_lens=[chunk])
+        assert engine.window_budget(slots, chunk) == \
+            (256 if kind == "fed" else None)
+        sched = DecodeScheduler(engine, clock=FakeClock(),
+                                prefill_chunk=chunk, prefix_store=None)
+        drv = engine.driver(slots)
+        windows = []
+
+        def step(tokens, fed=None, now=None, _step=drv.step, _drv=drv):
+            out = _step(tokens, fed=fed, now=now)
+            if tokens.shape[1:] == (chunk,):
+                windows.append((None if fed is None else sorted(fed),
+                                _drv.last_program_rows))
+            return out
+
+        drv.step = step
+        handles = [sched.submit(p, max_new_tokens=30) for p in riders]
+        sched.pump(max_iterations=3)             # the riders decode
+        rewound = _spy_on_rewinds(engine)
+        handles += [sched.submit(p, max_new_tokens=4) for p in long]
+        sched.pump()
+        streams[kind] = [h.result(timeout=0).tolist() for h in handles]
+        ring = [r for r in mx.telemetry.flightrec.get_records()
+                if r["kind"] == "serve.decode.step" and r["model"] == name]
+        last = max(i for i, r in enumerate(ring) if r["window"] == chunk)
+        # the step behind the last window was launched ahead of its ids
+        assert ring[last + 1]["window"] == 1 and ring[last + 1]["ahead"] == 1
+        assert sched.stats()["compiles_since_warmup"] == 0
+        if kind == "fed":
+            assert not rewound
+            assert windows[1:] == [([1] * 6 + [64, 64], 256)] * 2 \
+                + [([1] * 6 + [22, 22], 256)]
+        else:
+            # three windows, the riders back by 63 after each, the long
+            # prompts by 42 after the last: once a window
+            assert [len(rows) for rows, _pos in rewound] == [6, 6, 8]
+            assert windows[1:] == [(None, slots * chunk)] * 3
+    assert streams["fed"] == streams["unfed"]
+    assert [len(t) for t in streams["fed"]] == [30] * 6 + [4] * 2
+
+
+def test_a_fed_graph_bound_without_fed_is_refused():
+    """``fed`` left among the parameters would stay what it was set to
+    and every step would advance the slots by that."""
+    sym = cases.symbol("gpt2_rotary", 1)
+    mod = mx.mod.Module(sym, data_names=["data"], label_names=[])
+    mod.bind([mx.io.DataDesc("data", (SLOTS, 1), np.int32)], None,
+             for_training=False)
+    with pytest.raises(mx.base.MXNetError, match="bind it as data"):
+        tfm.BatchedKVCacheDecoder(mod, cases.CAPACITY, slots=SLOTS)
 
 
 # ------------------------------------------- what a dispatch reads, counted
@@ -369,3 +690,58 @@ def test_the_counters_and_the_ring_fields_are_the_parents(block):
     want["state.donated_bytes"] += step_bytes
     assert counters == want
     assert ring == COUNTS[block][1]
+
+
+def test_learned_positions_past_256_are_exact_at_bfloat16():
+    """ISSUE 47, found on the chip: ``DecodeEngine`` bound ``pos_ids``
+    as float32, every float input is cast to the compute width at graph
+    entry, and bfloat16 holds only every second position past 256 and
+    every eighth past 1,024. The S = 1 and whole-window programs read
+    the right row of the table on the chip all the same (its compiler
+    drops the cast in front of the gather), the packed window, which
+    copies the positions into its block, did not: 0.14 on logits of 8
+    from position 257 on. The positions are int32 now, like the tokens:
+    at far cursors a bfloat16 engine is within rounding of a float32
+    one, in both forms of the window and at S = 1."""
+    capacity, slots = 2048, 8
+    kw = dict(vocab_size=64, d_model=32, n_layer=2, n_head=2,
+              pos_embed="learned", max_seq_len=capacity, capacity=capacity,
+              per_slot=True)
+    gen = lambda s: tfm.get_decode_symbol(step_len=s, **kw)  # noqa: E731
+    shapes, _, _ = gen(1).infer_shape(data=(slots, 1), pos_ids=(slots, 1),
+                                      fed=(slots,))
+    rs = np.random.RandomState(2)
+    params = {nm: (0.3 * rs.randn(*shape)).astype(np.float32)
+              for nm, shape in zip(gen(1).list_arguments(), shapes)
+              if nm not in ("data", "pos_ids", "fed")}
+    tokens = rs.randint(0, 64, (slots, S))
+    cursors = [257, 701, 1203, 1999, 5, 0, 0, 0]
+    fed = np.asarray([S, 1, 1, 1, 1, 0, 0, 0])
+    got = {}
+    for dtype in (None, "bfloat16"):
+        engine = DecodeEngine(f"pos-{dtype}", gen(1), params,
+                              capacity=capacity, ladder=[slots],
+                              symbol_gen=gen, window_lens=[S],
+                              compute_dtype=dtype)
+        drv = engine.driver(slots)
+        cells = drv._mod._exec_group.executor.arg_dict
+        assert str(cells["pos_ids"].dtype) == "int32"
+        for form in ("packed", "whole"):
+            drv.rewind_many(list(range(slots)), cursors)
+            out = drv.step(tokens, fed=fed if form == "packed"
+                           else np.where(fed, S, 0)).asnumpy()
+            assert drv.last_program_rows == \
+                (R if form == "packed" else slots * S)
+            got[dtype, form] = np.stack(
+                [out[slot, 0] for slot in range(5)]).astype(np.float32)
+        drv.rewind_many(list(range(slots)), cursors)
+        got[dtype, "s1"] = drv.step(
+            tokens[:, :1], fed=(fed > 0).astype(np.int64)) \
+            .asnumpy()[:5, 0].astype(np.float32)
+    for form in ("packed", "whole", "s1"):
+        np.testing.assert_allclose(got[None, form], got[None, "s1"],
+                                   rtol=0, atol=1e-5, err_msg=form)
+        # bfloat16 against float32: rounding, not a neighbour's row
+        # (which reads 0.5 and more on these logits of 3)
+        np.testing.assert_allclose(got["bfloat16", form], got[None, form],
+                                   rtol=0, atol=0.08, err_msg=form)

@@ -317,10 +317,14 @@ def test_join_after_leave_starts_clean(driver):
 
 
 def _gpt2_module():
-    """GPT-2's two-slot decode graph, bound and initialised."""
+    """GPT-2's two-slot decode graph as it was before it took ``fed``
+    (built by hand: ``window_pack_cases.unfed_symbol``), bound and
+    initialised."""
     from chipbench import weights
-    sym = tfm.get_decode_symbol(vocab_size=16, d_model=16, n_layer=1,
-                                n_head=2, capacity=8, per_slot=True)
+    import window_pack_cases as cases
+    sym = cases.unfed_symbol("gpt2_rotary", 1, vocab_size=16, d_model=16,
+                             n_layer=1, n_head=2, capacity=8,
+                             rope_base=10000.0)
     assert "fed" not in sym.list_arguments()
     mod = mx.mod.Module(sym, data_names=("data",), label_names=[])
     mod.bind([mx.io.DataDesc("data", (2, 1), np.int32)], None,
@@ -482,7 +486,8 @@ def test_serve_decoder_serves_the_block_with_no_side_script():
 
 
 def test_the_older_blocks_step_programs_take_no_fed_and_donate_their_pools():
-    """GPT-2's decode graph: no ``fed`` input and a positional state;
+    """GPT-2's decode graph without the ``fed`` input it has had since
+    ISSUE 47, and a positional state;
     like this block's, its step program takes over every aux array (an
     array read from a cell before the step is deleted by it) and the
     cell holds the new one."""

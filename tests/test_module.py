@@ -584,8 +584,9 @@ def test_aliased_input_outlives_a_forward_that_donates_its_aux():
     sym = tfm.get_decode_symbol(vocab_size=32, d_model=16, n_layer=1,
                                 n_head=2, capacity=8, per_slot=True,
                                 max_seq_len=8)
-    mod = mx.mod.Module(sym, label_names=[])
-    mod.bind([mx.io.DataDesc("data", (2, 1), np.int32)], None,
+    mod = mx.mod.Module(sym, data_names=("data", "fed"), label_names=[])
+    mod.bind([mx.io.DataDesc("data", (2, 1), np.int32),
+              mx.io.DataDesc("fed", (2,), np.int32)], None,
              for_training=False)
     exe = mod._exec_group.executor
     mod.init_params(mx.initializer.Xavier(), aux_params={
@@ -595,8 +596,10 @@ def test_aliased_input_outlives_a_forward_that_donates_its_aux():
     ids = mx.nd.array(np.array([[3], [5]], np.int32))
     held = ids.asjax()
     pools = [c.asjax() for c in exe.aux_arrays]
+    fed = mx.nd.array(np.ones(2, np.int32))
     for _ in range(3):
-        mod.forward(mx.io.DataBatch(data=[ids], label=[]), is_train=False)
+        mod.forward(mx.io.DataBatch(data=[ids, fed], label=[]),
+                    is_train=False)
         assert exe.arg_dict["data"].asjax() is held
     assert all(p.is_deleted() for p in pools)     # the aux WAS donated
     assert not held.is_deleted()

@@ -214,7 +214,8 @@ def _decode_symbol(step_len, cfg=CFG, with_routing=True):
 def _params(cfg=CFG, seed=11, dtype="float32"):
     from chipbench import weights
     return weights.normal_init(_decode_symbol(1, cfg, False),
-                               {"data": (2, 1)}, seed, dtype=dtype)
+                               {"data": (2, 1), "fed": (2,)}, seed,
+                               dtype=dtype)
 
 
 def _driver(params, compute_dtype=None, slots=2, cfg=CFG):
@@ -222,9 +223,10 @@ def _driver(params, compute_dtype=None, slots=2, cfg=CFG):
     every layer's chosen experts."""
     def bound(step_len, shared=None):
         mod = mx.mod.Module(_decode_symbol(step_len, cfg),
-                            data_names=("data",), label_names=[],
+                            data_names=("data", "fed"), label_names=[],
                             compute_dtype=compute_dtype)
-        mod.bind([mx.io.DataDesc("data", (slots, step_len), np.int32)],
+        mod.bind([mx.io.DataDesc("data", (slots, step_len), np.int32),
+                  mx.io.DataDesc("fed", (slots,), np.int32)],
                  None, for_training=False, shared_module=shared)
         if shared is None:
             mod.init_params(initializer=None, arg_params=dict(params),
@@ -380,9 +382,9 @@ def test_serve_decoder_counts_where_the_tokens_went():
     its = sched.iterations
     L, k = CFG["num_hidden_layers"], CFG["num_experts_per_tok"]
     assert got["serve.decode.moe.layer_steps"] == its * L
-    # three prefill windows of 8 and three S=1 steps on rung 1, pads
-    # riding along as tokens
-    assert got["serve.decode.moe.assignments"] == (3 * WINDOW + 3) * L * k
+    # three prefill windows of 8 and three S=1 steps on rung 1: the
+    # prompt's 20 tokens and the three fed back, no pad among them
+    assert got["serve.decode.moe.assignments"] == (20 + 3) * L * k
     assert 0 < got["serve.decode.moe.experts_touched"] <= its * L * 8
     assert got["serve.decode.moe.max_expert_load"] >= its * L
     step = [r for r in telemetry.flightrec.get_records()
@@ -409,7 +411,7 @@ def _engine(params, compute_dtype, gen, name="bind-dtype"):
                         window_lens=(WINDOW,))
 
 
-def _param_dtypes(mod, inputs=("data",)):
+def _param_dtypes(mod, inputs=("data", "fed")):
     return {n: str(c.dtype)
             for n, c in mod._exec_group.executor.arg_dict.items()
             if n not in inputs}
@@ -450,25 +452,27 @@ def test_float32_parameters_bind_as_before():
     parameter handed over at the compute width. ``DecodeEngine`` alone
     narrows what it is handed (the test above)."""
     gen = lambda s: _decode_symbol(s, with_routing=False)   # noqa: E731
-    mod = mx.mod.Module(gen(1), data_names=("data",), label_names=[],
+    mod = mx.mod.Module(gen(1), data_names=("data", "fed"), label_names=[],
                         compute_dtype="bfloat16")
-    mod.bind([mx.io.DataDesc("data", (2, 1), np.int32)], None,
+    mod.bind([mx.io.DataDesc("data", (2, 1), np.int32),
+              mx.io.DataDesc("fed", (2,), np.int32)], None,
              for_training=False)
     mod.init_params(initializer=None, arg_params=_params(),
                     aux_params={}, allow_missing=True)
     assert set(_param_dtypes(mod).values()) == {"float32"}
     # and a module bound first and handed bfloat16 parameters afterwards
     # re-allocates the cells (Module.init_params)
-    mod = mx.mod.Module(gen(1), data_names=("data",), label_names=[],
+    mod = mx.mod.Module(gen(1), data_names=("data", "fed"), label_names=[],
                         compute_dtype="bfloat16")
-    mod.bind([mx.io.DataDesc("data", (2, 1), np.int32)], None,
+    mod.bind([mx.io.DataDesc("data", (2, 1), np.int32),
+              mx.io.DataDesc("fed", (2,), np.int32)], None,
              for_training=False)
     cells = mod._exec_group.executor.arg_dict
     assert str(cells["lm_head_weight"].dtype) == "float32"
     mod.init_params(initializer=None, arg_params=_params(dtype="bfloat16"),
                     aux_params={}, allow_missing=True)
     assert {str(c.dtype) for n, c in cells.items()
-            if n != "data"} == {"bfloat16"}
+            if n not in ("data", "fed")} == {"bfloat16"}
     key = mod._exec_group.executor.program_cache_key("fwd_infer")
     assert ("lm_head_weight", (128, 64), "bfloat16") in key[1]
 
@@ -506,9 +510,13 @@ def test_a_training_binding_keeps_its_float32_masters():
 #: parent's (d8b22cc). PR 37 did (a serving binding narrows its
 #: parameters once, at bind: every parameter argument is ``bf16`` and
 #: the converts of the float32 masters are gone; until then
-#: 9a63a96316cb...e367ab).
+#: 9a63a96316cb...e367ab). ISSUE 47 did (the slot-pooled graph takes
+#: ``fed``: one more ``(slots,)`` argument, the cursor advanced by it;
+#: and ``DecodeEngine`` binds ``pos_ids`` as int32, no longer a float32
+#: that the graph's entry cast to bfloat16; until then
+#: 8cee1c781915...b3eda).
 GPT2_S1_SHA256 = \
-    "8cee1c781915d90e11617a882f8fcba94c1ff7bc8cb8dfe12a3fc045776b3eda"
+    "0f159ffaae0ac3ad050d47a44dd6d08b004415e200d2660a15e80b3e450c7b13"
 
 _GPT2_KW = dict(vocab_size=96, d_model=64, n_layer=2, n_head=4,
                 pos_embed="learned", capacity=32, max_seq_len=32,
@@ -521,8 +529,8 @@ def _gpt2_gen(step_len):
 
 def _gpt2_params(seed=5):
     from chipbench import weights
-    return weights.normal_init(_gpt2_gen(1), {"data": (2, 1),
-                                              "pos_ids": (2, 1)}, seed)
+    return weights.normal_init(
+        _gpt2_gen(1), {"data": (2, 1), "pos_ids": (2, 1), "fed": (2,)}, seed)
 
 
 def _gpt2_engine(params, name="gpt2-pin"):
@@ -770,6 +778,40 @@ def test_a_load_past_one_segment_takes_further_trips():
         (jax.nn.silu(x @ gate[6 + e]) * (x @ up[6 + e])) @ down[6 + e])
         for e in range(2))
     np.testing.assert_allclose(out, want, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("variant", ["xla", "pallas"])
+@pytest.mark.parametrize("fed,rows", [([8, 3, 0], 8), ([11], 24),
+                                      ([1, 1, 1], 1)])
+def test_the_plain_layer_routes_the_pads_of_a_window_to_a_dead_group(
+        variant, fed, rows):
+    """ISSUE 47: ``step_len`` alone (OLMoE's slot-pooled graph) makes no
+    share - the plain layer takes ``fed``, keeps ``moe_sort`` and
+    ``moe_combine``, and sends a pad's choices to a group behind the
+    last expert: the real rows come out as they do without pads, bit
+    for bit, a pad's row is zero, and only the real rows' assignments
+    are counted. Slots of ``rows`` rows (a window, or an S = 1 step),
+    or one block of packed rows under one count."""
+    rng = np.random.default_rng(47)
+    D, F, E, k = 64, 32, 8, 2
+    T = rows * len(fed)
+    inputs = _moe_inputs(rng, T, D, F, E)
+    attrs = dict(num_experts=E, num_hidden=F, top_k=k)
+    op = get_op("MoEFFN")
+    fed_attrs = op.normalize_attrs(dict(attrs, step_len=rows))
+    assert op.input_names(fed_attrs)[:3] == ["data", "fed", "router_weight"]
+    assert moe._share_spec(fed_attrs) is None
+    every, picked_every, stats_every = _run(variant, attrs, inputs)
+    out, picked, stats = _run(
+        variant, dict(attrs, step_len=rows),
+        inputs[:1] + [jnp.asarray(fed, jnp.int32)] + inputs[1:])
+    real = (np.arange(rows)[None, :] < np.asarray(fed)[:, None]).reshape(-1)
+    np.testing.assert_array_equal(out[real], every[real])
+    assert not out[~real].any() and np.isfinite(out).all()
+    np.testing.assert_array_equal(picked, picked_every)   # the router's
+    assert stats.shape == (4,) and stats[1] == k * real.sum()
+    assert stats_every[1] == k * T and stats[2] <= stats_every[2]
+    assert stats[3] == np.bincount(picked[real].reshape(-1)).max()
 
 
 def test_pads_of_a_window_are_routed_nowhere():

@@ -302,6 +302,45 @@ def test_decode_learned_positions():
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=2e-6)
 
 
+@pytest.mark.parametrize("bound,ok", [(np.float32, False), (np.int32, True)],
+                         ids=["float32_refused", "int32_exact"])
+def test_one_cursor_decoder_takes_whole_positions_at_bfloat16(bound, ok):
+    """A float ``pos_ids`` cell is cast to bfloat16 at graph entry and
+    no odd position past 256 survives that: ``KVCacheDecoder`` refuses
+    such a binding, and with an int32 cell (what ``step`` feeds) position
+    301 reads its own row of the table and not 300's."""
+    capacity = 320
+    sym = tfm.get_decode_symbol(vocab_size=V, d_model=D, n_layer=1, n_head=H,
+                                capacity=capacity, pos_embed="learned",
+                                max_seq_len=capacity)
+    dec = mx.mod.Module(sym, data_names=("data", "pos_ids"), label_names=[],
+                        compute_dtype="bfloat16")
+    dec.bind([mx.io.DataDesc("data", (1, 1), np.int32),
+              mx.io.DataDesc("pos_ids", (1,), bound)], None,
+             for_training=False)
+    shapes, _, _ = sym.infer_shape(data=(1, 1), pos_ids=(1,))
+    rs = np.random.RandomState(5)
+    dec.init_params(initializer=None, aux_params={}, allow_missing=True,
+                    arg_params={
+                        nm: mx.nd.array(0.3 * rs.randn(*shape))
+                        for nm, shape in zip(sym.list_arguments(), shapes)
+                        if nm not in ("data", "pos_ids")})
+    if not ok:
+        with pytest.raises(mx.base.MXNetError, match="int32 DataDesc"):
+            tfm.KVCacheDecoder(dec, capacity=capacity, pos_embed="learned")
+        return
+    drv = tfm.KVCacheDecoder(dec, capacity=capacity, pos_embed="learned")
+    cell = dec._exec_group.executor.arg_dict["pos_ids"]
+    rows = {}
+    for pos in (300, 301):
+        drv.reset()
+        drv.pos = pos       # an empty cache: the row depends on the
+        rows[pos] = drv.step(np.asarray([[3]])).asnumpy()   # position alone
+        assert str(cell.dtype) == "int32" and int(cell.asnumpy()[0]) == pos
+    assert np.abs(rows[300].astype(np.float32)
+                  - rows[301].astype(np.float32)).max() > 0
+
+
 def test_decode_cache_overflow_raises():
     _full, dec, _ = _trained_pair(n_layer=1)
     tokens = np.zeros((B, 1), np.int32)

@@ -1,12 +1,11 @@
-"""The five fed decoders at tiny sizes - GLM-5.2's block, A.X-K1's,
-Xing4.0's, Trinity's and EvaByte's - for the tests of a window's packed rows
-(``tests/test_decode_pack.py``) and of the text their programs lower to
-(``tests/test_chip_compile.py``): graphs, parameters, bound drivers with
-the whole-window and the packed program, and a program's lowered text.
-Beside them the two blocks without ``fed`` (``UNFED``: GPT-2's with
-learned positions, OLMoE's), for the tests that hold all six to the
-graphs, programs and counts they had (``tests/test_chip_compile.py``,
-``tests/test_decode_pack.py``)."""
+"""The slot-pooled decoders at tiny sizes - GLM-5.2's block, A.X-K1's,
+Xing4.0's, Trinity's and EvaByte's, which are served alone (``BLOCKS``),
+and the two that are trained too (``FUSED``: GPT-2's with learned and
+with rotary positions, OLMoE's), fed like the others since ISSUE 47 -
+for the tests of a window's packed rows (``tests/test_decode_pack.py``)
+and of the text their programs lower to (``tests/test_chip_compile.py``):
+graphs, parameters, bound drivers with the whole-window and the packed
+program, and a program's lowered text."""
 import numpy as np
 
 import jax
@@ -57,26 +56,59 @@ BLOCKS = {
                     ffn_width=48),
 }
 
-#: the blocks without ``fed``, likewise (every slot advances by S)
-UNFED = {
+#: the blocks with a training form (``_fused_attention``), likewise:
+#: every keyword of the graph (``block=`` where the case's name is not
+#: the block's)
+FUSED = {
     "gpt2": dict(vocab_size=48, d_model=32, n_layer=2, n_head=2,
                  pos_embed="learned", max_seq_len=CAPACITY),
+    "gpt2_rotary": dict(block="gpt2", vocab_size=48, d_model=32, n_layer=2,
+                        n_head=2, pos_embed="rotary", rope_base=1e4),
     "olmoe": dict(vocab_size=48, d_model=32, n_layer=2, n_head=2,
                   pos_embed="rotary", rope_base=1e4, n_expert=8, top_k=2,
                   expert_width=24, norm_topk=False, rms_eps=1e-5,
                   tie_head=False, embed_scale=False),
 }
 
+#: every case that takes ``fed``: all of them
+FED = sorted(BLOCKS) + sorted(FUSED)
 
-def symbol(block, step_len):
-    if block in UNFED:
-        return tfm.get_decode_symbol(
-            block=block, step_len=step_len, capacity=CAPACITY,
-            per_slot=True, **UNFED[block])
-    return tfm.get_decode_symbol(
-        block=block, step_len=step_len, capacity=CAPACITY, per_slot=True,
-        pos_embed="rotary", tie_head=False, embed_scale=block == "afmoe",
-        **BLOCKS[block])
+
+def config(case):
+    """``get_decode_symbol``'s keywords of a case beside the step
+    length, the capacity and ``per_slot``."""
+    if case in FUSED:
+        return dict({"block": case}, **FUSED[case])
+    return dict(BLOCKS[case], block=case, pos_embed="rotary",
+                tie_head=False, embed_scale=case == "afmoe")
+
+
+def symbol(case, step_len):
+    return tfm.get_decode_symbol(step_len=step_len, capacity=CAPACITY,
+                                 per_slot=True, **config(case))
+
+
+def unfed_symbol(case, step_len, **kw):
+    """A slot-pooled graph built by hand, as ``get_decode_symbol`` built
+    the ``FUSED`` blocks before ISSUE 47: no ``fed`` input, every slot
+    advances by ``step_len`` and whoever drives it rewinds the slots
+    that fed fewer. What the scheduler's and the driver's unfed
+    branches still serve, and the reference that the fed graphs are
+    held to. ``kw`` over the case's keywords."""
+    import inspect
+    given = {k: p.default for k, p in inspect.signature(
+        tfm.get_decode_symbol).parameters.items()}
+    given.update(config(case), step_len=step_len, capacity=CAPACITY,
+                 per_slot=True)
+    given.update(kw)
+    spec = dict(tfm._spec(given, decode=True), fed=False)
+    if spec["moe"]:
+        spec["moe"] = {k: v for k, v in spec["moe"].items()
+                       if k != "step_len"}
+    logits, fed = tfm._logits(spec)
+    assert fed is None
+    return mx.sym.Reshape(logits, shape=(-1, step_len, given["vocab_size"]),
+                          name=f"{given['name']}_logits_bsv")
 
 
 def inputs(sym, slots, step_len):
@@ -121,13 +153,26 @@ def driver(block, packed=True, slots=SLOTS):
     ``WINDOW`` rows a slot and, with ``packed``, the packed form of it
     beside (``tfm.packed_window``)."""
     base = bound(symbol(block, 1), 1, arg_params=params(block), slots=slots)
-    drv = tfm.BatchedKVCacheDecoder(base, CAPACITY, slots=slots)
+    drv = tfm.BatchedKVCacheDecoder(base, CAPACITY, slots=slots,
+                                    pos_embed=config(block)["pos_embed"])
     window = symbol(block, WINDOW)
     form = tfm.packed_window(window, slots) if packed else None
     drv.add_window(
         WINDOW, bound(window, WINDOW, shared=base, slots=slots),
         packed=form and (bound(form[0], WINDOW, shared=base, slots=slots),
                          form[1]))
+    return drv
+
+
+def unfed_driver(block, slots=SLOTS):
+    """``driver`` over ``unfed_symbol``'s graphs: every slot advances
+    by S, the caller rewinds."""
+    base = bound(unfed_symbol(block, 1), 1, arg_params=params(block),
+                 slots=slots)
+    drv = tfm.BatchedKVCacheDecoder(base, CAPACITY, slots=slots,
+                                    pos_embed=config(block)["pos_embed"])
+    drv.add_window(WINDOW, bound(unfed_symbol(block, WINDOW), WINDOW,
+                                 shared=base, slots=slots))
     return drv
 
 

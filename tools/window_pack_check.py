@@ -20,14 +20,17 @@ the positional pools are equal; and the median window's time on the
 host's clock in either form. Prints one JSON line.
 
     python3 tools/window_pack_check.py
-        --config a.x-k1|glm-5.2|xing4.0-29b-a4b [--seed N] [--rehearse]
+        --config a.x-k1|glm-5.2|xing4.0-29b-a4b|cerebras-gpt-1.3b|olmoe-1b-7b
+        [--seed N] [--rehearse]
 
 ``--rehearse`` runs the configuration's tiny fixture on the CPU
-(chipbench/tests/fixtures: a context of 64, windows of 16); no number of
-it is a device number."""
+(chipbench/tests/fixtures: a context of 64, windows of 16; for the
+Cerebras configuration, which has no fixture there, ``_TINY_GPT2``
+below); no number of it is a device number."""
 import argparse
 import functools
 import gc
+import inspect
 import json
 import os
 import statistics
@@ -40,7 +43,17 @@ if ROOT not in sys.path:
 
 _TINY = {"a.x-k1": ("axk1", "tiny-axk1.json"),
          "glm-5.2": ("glm_dsa", "tiny-glm.json"),
-         "xing4.0-29b-a4b": ("xing4", "tiny-xing4.json")}
+         "xing4.0-29b-a4b": ("xing4", "tiny-xing4.json"),
+         "olmoe-1b-7b": ("olmoe", "tiny-olmoe.json"),
+         "cerebras-gpt-1.3b": None}
+
+#: the Cerebras configuration cut to a rehearsal's size (learned
+#: positions, a tied head; 4 slots of 8 rows pack to 16)
+_TINY_GPT2 = {"name": "tiny-gpt2", "arch": "gpt2", "n_embd": 64,
+              "n_layer": 2, "n_head": 4, "n_inner": 256, "vocab_size": 128,
+              "n_positions": 128, "position_embedding": "learned",
+              "compute_dtype": "bfloat16", "capacity": 128,
+              "prefill_chunk": 8, "ladder": [1, 2, 4]}
 
 
 def main(argv=None):
@@ -51,12 +64,15 @@ def main(argv=None):
     ns = ap.parse_args(argv)
     from chipbench import common, manifest
     common.set_caches()
-    fixture, tiny = _TINY[ns.config]
-    path = os.path.join(ROOT, "chipbench", "tests", "fixtures", fixture,
-                        "configs", tiny) if ns.rehearse else \
-        os.path.join(ROOT, "chipbench", "configs", ns.config + ".json")
-    with open(path) as f:
-        cfg = json.load(f)
+    if ns.rehearse and _TINY[ns.config] is None:
+        cfg = dict(_TINY_GPT2)
+    else:
+        path = os.path.join(ROOT, "chipbench", "tests", "fixtures",
+                            _TINY[ns.config][0], "configs",
+                            _TINY[ns.config][1]) if ns.rehearse else \
+            os.path.join(ROOT, "chipbench", "configs", ns.config + ".json")
+        with open(path) as f:
+            cfg = json.load(f)
     os.environ.update(cfg.get("env", {}))
     import jax
     import numpy as np
@@ -65,7 +81,8 @@ def main(argv=None):
     arch = manifest._load_file(
         "arch", os.path.join(ROOT, "chipbench", "archs", cfg["arch"] + ".py"))
 
-    S, n_cmp = cfg["prefill_chunk"], 16
+    S = cfg["prefill_chunk"]
+    n_cmp = min(16, S)
     ctx = min(16384, cfg["capacity"] - S) if not ns.rehearse else 3 * S
     windows = ctx // S + 1
     assert ctx % S == 0 and windows * S <= cfg["capacity"]
@@ -148,9 +165,13 @@ def main(argv=None):
                 "max_err_over_bound": float((err / bound).max())}
 
     try:
-        fwd = jax.jit(functools.partial(arch._reference.forward, config=rcfg,
-                                        tail=n_cmp))
-        want = np.asarray(fwd(params, seq))[0]
+        forward = arch._reference.forward
+        if "tail" in inspect.signature(forward).parameters:
+            fwd = functools.partial(forward, config=rcfg, tail=n_cmp)
+        else:       # a reference of every position: its last rows
+            fwd = lambda p, t: forward(               # noqa: E731
+                p, t, config=rcfg)[:, -n_cmp:]
+        want = np.asarray(jax.jit(fwd)(params, seq))[0]
         report = {"packed_vs_reference": against(want, tail_p),
                   "whole_vs_reference": against(want, tail_w),
                   "max_abs_logit": float(np.abs(want).max())}
