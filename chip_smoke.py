@@ -347,6 +347,38 @@ def kernel_sites(w, seed=0):
                 normal((1, rows, 4 * D), bf), normal((rows, D), bf),
                 jnp.abs(normal((rows, 4), f32)),
                 jnp.abs(normal((rows, 16), f32)) / 4]))
+    # the recurrent mixer (ops/ssm.py): heads of 64 with a state of 128
+    # where the widths allow (two heads fill the state's 128 lanes), an
+    # S = 1 step and a window whose slots are fed ragged counts - one
+    # step for a slot fed one row, chunks for the others - over a state
+    # that a last occupant left (cursor 0 reads it as zeros)
+    sH, sP, sN = (2 * D // 64, 64, 128) if big else (8, D // 4, 16)
+    s_in, s_c = sH * sP, sH * sP + 2 * sN
+    s_w = min(128, s_in)                # ops.ssm.lane_width(sH, sP)
+    for step in (1, win):
+        shapes = [(slots * step, 2 * s_in + 2 * sN + sH), (slots,),
+                  (s_c, 4), (s_c,), (sH,), (sH,), (sH,),
+                  (slots, 3, s_c), (slots, s_in // s_w, sN, s_w), (slots, 1)]
+        sites.append((
+            f"ssm_mixer_decode_s{step}", "ssm_mixer_decode",
+            {"heads": sH, "head_dim": sP, "d_state": sN, "d_conv": 4,
+             "chunk": min(256, max(2, win // 2)), "step_len": step,
+             "capacity": 4 * cap},
+            shapes, [bf, "int32", bf, bf, f32, f32, f32, f32, f32, "int32"],
+            False,
+            lambda shapes=shapes, step=step: [
+                normal(shapes[0], bf),
+                jnp.asarray(rs.randint(0, step + 1, (slots,))
+                            .astype(np.int32)),
+                normal(shapes[2], bf) / 2, normal(shapes[3], bf) / 4,
+                normal(shapes[4], f32) - 3.0,
+                jnp.log(jnp.asarray(rs.uniform(1, 16, shapes[5])
+                                    .astype("f"))),
+                jnp.ones(shapes[6], f32), normal(shapes[7], f32),
+                normal(shapes[8], f32),
+                jnp.asarray(rs.randint(0, 2, (slots, 1)).astype(np.int32)
+                            * rs.randint(1, cap, (slots, 1))
+                            .astype(np.int32))]))
     for wdt in ("int8", "float8_e4m3fn"):
         rows, k, nh = slots * win, D, 4 * D
         sites.append((

@@ -168,7 +168,12 @@ def _build_graph_runner(symbol, shape_overrides=None, tap=None, mp_plan=None,
     label_names = set()
     if compute_dtype is not None:
         compute_dtype = np.dtype(compute_dtype)
-        label_names = loss_label_names(symbol)
+        # labels, and the state an op declares float32 (a recurrence's:
+        # ``OpDef.aux_dtypes``), stay at their own width
+        label_names = loss_label_names(symbol) | {
+            n.name for n in nodes if n.is_variable
+            and n._extra.get("__is_aux__")
+            and n._extra.get("__dtype__") == "float32"}
 
     def _load_var(val, name):
         if (compute_dtype is not None and name not in label_names

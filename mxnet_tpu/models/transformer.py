@@ -285,6 +285,107 @@ def _grouped_attention(x, fed, carry, i, spec):
                                 name=f"{pfx}_gate"), None
 
 
+def _paired_heads(q, n_pairs, group, dh, name):
+    """Query heads ``(B, T, 2 n_pairs group, dh)`` for K/V heads that
+    lie two to a row of ``2 dh`` numbers (``[k_2p | k_2p+1]``, as the
+    projection has them): each query widened to ``2 dh`` with its own
+    numbers on its K/V head's half and zeros on the other, so that a
+    score against the pair's row is the score against its own head."""
+    q = sym.Reshape(q, shape=(0, 0, n_pairs, 2, group, dh),
+                    name=f"{name}_pairs")
+    halves = []
+    for j, pad in enumerate(((0, dh), (dh, 0))):
+        half = sym.slice_axis(q, axis=3, begin=j, end=j + 1,
+                              name=f"{name}_half{j}")
+        halves.append(sym.Pad(half, mode="constant",
+                              pad_width=(0,) * 10 + pad,
+                              name=f"{name}_half{j}_wide"))
+    wide = sym.Concat(*halves, dim=3, name=f"{name}_wide")
+    return sym.Reshape(wide, shape=(0, 0, -1, 2 * dh), name=f"{name}_heads")
+
+
+def _unpaired_heads(att, n_pairs, group, dh, name):
+    """Attention's ``(B, T, heads, 2 dh)`` over paired K/V heads back as
+    ``(B, T, heads, dh)``: of each head's result the half its own V
+    head lies on."""
+    att = sym.Reshape(att, shape=(0, 0, n_pairs, 2, group, 2 * dh),
+                      name=f"{name}_pairs")
+    halves = [sym.slice_axis(
+        sym.slice_axis(att, axis=3, begin=j, end=j + 1,
+                       name=f"{name}_half{j}"),
+        axis=5, begin=j * dh, end=(j + 1) * dh, name=f"{name}_half{j}_own")
+        for j in range(2)]
+    own = sym.Concat(*halves, dim=3, name=f"{name}_own")
+    return sym.Reshape(own, shape=(0, 0, -1, dh), name=f"{name}_heads")
+
+
+def _nope_attention(x, fed, i, spec):
+    """Granite 4.0-H's attention layers (``spec["cfg"]``:
+    ``_granite_spec``): ``n_head`` query heads on ``num_key_value_heads``
+    K/V heads, no bias, no norm, **no positions of any kind**, the
+    scores times ``attention_multiplier`` in place of ``1 / sqrt(head
+    width)``; q, k and v the row blocks of one projection. Where two
+    K/V heads fit a row of 128 lanes or less (the published 8 heads of
+    64) the pools hold them side by side - half the heads of twice the
+    width, the same bytes - and each query is widened with zeros
+    (``_paired_heads``): the decode kernels read whole lanes."""
+    pfx, cfg, n_head = f"{spec['name']}_l{i}", spec["cfg"], spec["n_head"]
+    n_kv, dh = cfg["num_key_value_heads"], cfg["head_dim"]
+    paired = n_kv % 2 == 0 and 2 * dh <= 128
+    rows = sym.Reshape(x, shape=(-3, 0), name=f"{pfx}_attn_fold")  # (B*T, D)
+    wide = sym.FullyConnected(rows, num_hidden=(n_head + 2 * n_kv) * dh,
+                              no_bias=True, name=f"{pfx}_qkv")
+    at, heads = 0, {}
+    for nm, n in (("q", n_head), ("k", n_kv), ("v", n_kv)):
+        part = sym.slice_axis(wide, axis=1, begin=at, end=at + n * dh,
+                              name=f"{pfx}_{nm}_rows")
+        at += n * dh
+        shape = (n // 2, 2 * dh) if paired and nm != "q" else (n, dh)
+        part = _slots(part, fed, spec["T"], f"{pfx}_{nm}_split", shape=shape)
+        if paired and nm == "q":
+            part = _paired_heads(part, n_kv // 2, n_head // n_kv, dh,
+                                 f"{pfx}_q")
+        heads[nm] = sym.transpose(part, axes=(0, 2, 1, 3),
+                                  name=f"{pfx}_{nm}")        # (B, n, T, dh)
+    att = sym.attention_decode(
+        heads["q"], heads["k"], heads["v"], fed, capacity=spec["capacity"],
+        rope=False, per_slot=True, kv_heads=n_kv // 2 if paired else n_kv,
+        fed=True, scale=cfg["attention_multiplier"], name=f"{pfx}_attn")
+    att = sym.transpose(att, axes=(0, 2, 1, 3), name=f"{pfx}_attn_t")
+    if paired:
+        att = _unpaired_heads(att, n_kv // 2, n_head // n_kv, dh,
+                              f"{pfx}_attn")
+    return _packed_rows(att, fed, f"{pfx}_attn_merge", fold=(-3, -3))
+
+
+def _mamba_mixer(x, fed, i, spec):
+    """Granite 4.0-H's Mamba-2 layers: one projection of the normed
+    rows to ``[z | xBC | dt]``, the convolution and the selective state
+    update over the rows as they lie (``ssm_mixer_decode``,
+    ``ops/ssm.py``: it finds each slot's rows by ``fed`` in either view
+    and costs by the real ones), and RMSNorm with a gain over all
+    ``heads x head_dim`` gated numbers (one group); the layer's output
+    projection follows in ``_layer``."""
+    pfx, cfg = f"{spec['name']}_l{i}", spec["cfg"]
+    H, P, N = cfg["mamba_n_heads"], cfg["mamba_d_head"], cfg["mamba_d_state"]
+    rows = sym.Reshape(x, shape=(-3, 0), name=f"{pfx}_mamba_fold")   # (B*T, D)
+    wide = sym.FullyConnected(rows, num_hidden=2 * H * P + 2 * N + H,
+                              no_bias=True, name=f"{pfx}_mamba_in")
+    y = sym.ssm_mixer_decode(
+        wide, fed, heads=H, head_dim=P, d_state=N,
+        d_conv=cfg["mamba_d_conv"], chunk=cfg["mamba_chunk_size"],
+        step_len=spec["T"], capacity=spec["capacity"], name=f"{pfx}_mamba")
+    return _norm(y, f"{pfx}_mamba_norm", spec)
+
+
+def _hybrid_mixer(x, fed, carry, i, spec):
+    """Layer ``i``'s mixer by ``layer_types[i]``: ``"mamba"`` or
+    ``"attention"``."""
+    mixer = _mamba_mixer if spec["cfg"]["layer_types"][i] == "mamba" \
+        else _nope_attention
+    return mixer(x, fed, i, spec), None
+
+
 def _ffn(x, fed_rows, i, spec):
     """Layer ``i``'s feed-forward over the rows of the normed stream
     ``x``: on the first ``spec["dense_layers"]`` layers the dense one
@@ -326,7 +427,8 @@ def _residual(x, h, pfx, sub, spec):
     rows only ``ops/rows.py`` knows), through dropout in a training
     graph, then added as they are, after a cast to a float32 stream
     (``spec["residual"] == "float32"``: EvaByte) or normed
-    (``"normed"``: Trinity)."""
+    (``"normed"``: Trinity), and times the block's residual multiplier
+    where it has one (``spec["multipliers"]``: Granite)."""
     drop, post_norm = _SUB_LAYERS[sub]
     h = sym.reshape_like(h, x, name=f"{pfx}_{sub}_unfold") if spec["fed"] \
         else sym.Reshape(h, shape=(-1, spec["T"], spec["d_model"]),
@@ -337,6 +439,8 @@ def _residual(x, h, pfx, sub, spec):
         h = sym.Cast(h, dtype="float32", name=f"{pfx}_{sub}_f32")
     elif spec["residual"] == "normed":
         h = _norm(h, f"{pfx}_{post_norm}", spec)
+    if spec["multipliers"]:             # Granite's residual_multiplier
+        h = h * spec["multipliers"]["residual"]
     return x + h
 
 
@@ -385,7 +489,8 @@ def _head(x, tok_w, spec):
     tied to the token embedding (one weight, two gradients), or the
     untied ``{name}_head_weight`` of ``spec["heads"]`` consecutive
     blocks of ``vocab_size`` rows. A stream of copies (``"hyper"``) is
-    summed to one first."""
+    summed to one first; a block with multipliers (Granite: tied)
+    divides the logits by its ``logits_scaling``."""
     name = spec["name"]
     if spec["residual"] == "hyper":     # the copies summed: one stream
         x = sym.sum(sym.Reshape(x, shape=(0, 0, spec["hyper"]["n"], -1),
@@ -393,6 +498,10 @@ def _head(x, tok_w, spec):
                     axis=2, name=f"{name}_copies_sum")
     flat = sym.Reshape(_norm(x, f"{name}_ln_f", spec), shape=(-3, 0),
                        name=f"{name}_head_fold")
+    if spec["multipliers"]:             # Granite's logits_scaling
+        return sym.dot(flat, tok_w, transpose_b=True,
+                       name=f"{name}_logits_raw") \
+            / spec["multipliers"]["logits"]
     if spec["tie_head"]:
         return sym.dot(flat, tok_w, transpose_b=True,
                        name=f"{name}_logits")                # (B*T, V)
@@ -727,12 +836,74 @@ def _afmoe_spec(spec):
         fed=True, pos_embed="rotary", residual="normed", tie_head=False)
 
 
+#: the keys of Granite 4.0-H's published ``config.json`` (``model_type
+#: granitemoehybrid``) that ``block="granite_hybrid"`` reads
+#: (``get_decode_symbol(granite=...)``); ``layer_types`` one entry a
+#: layer that is run, ``"mamba"`` or ``"attention"``
+GRANITE_KEYS = ("num_key_value_heads", "layer_types", "mamba_n_heads",
+                "mamba_d_head", "mamba_d_state", "mamba_n_groups",
+                "mamba_d_conv", "mamba_expand", "mamba_chunk_size",
+                "mamba_conv_bias", "mamba_proj_bias",
+                "shared_intermediate_size", "num_local_experts",
+                "position_embedding_type", "embedding_multiplier",
+                "residual_multiplier", "attention_multiplier",
+                "logits_scaling")
+
+
+def _granite_spec(spec):
+    """Granite 4.0-H's block (per-slot only) from ``granite``, the
+    published config's keys (``GRANITE_KEYS``), no bias but the
+    convolution's: ``x = x + m Mixer(N(x))``, ``x = x + m FF(N(x))``
+    with ``m = residual_multiplier``, RMSNorm, a dense gated-SiLU
+    feed-forward of ``shared_intermediate_size`` on every layer, the
+    embedding times ``embedding_multiplier``, a tied head whose logits
+    are divided by ``logits_scaling``. **A mixer per layer**
+    (``layer_types``): ``"mamba"`` is a Mamba-2 mixer whose state is a
+    convolution's tail and one matrix a head, constant in the context
+    (``_mamba_mixer``; families ``"conv"`` and ``"recurrent"``);
+    ``"attention"`` is grouped attention without positions under
+    ``attention_multiplier`` (``_nope_attention``; ``"rows"``, the only
+    pools that grow with ``capacity``). What this graph does not build
+    is refused: more than one group of B and C, a bias on the mixer's
+    projections, routed experts, positions."""
+    cfg = _given_keys(spec, "granite", GRANITE_KEYS)
+    kinds, n_layer, n_head = list(cfg["layer_types"]), spec["n_layer"], \
+        spec["n_head"]
+    if len(kinds) != n_layer or set(kinds) - {"mamba", "attention"}:
+        raise MXNetError(
+            f"block='granite_hybrid': layer_types {kinds} must name "
+            f"'mamba' or 'attention' for each of {n_layer} layers")
+    d_in = cfg["mamba_n_heads"] * cfg["mamba_d_head"]
+    if cfg["mamba_n_groups"] != 1 or cfg["mamba_proj_bias"] \
+            or not cfg["mamba_conv_bias"] or cfg["num_local_experts"] \
+            or cfg["position_embedding_type"] != "nope" \
+            or d_in != cfg["mamba_expand"] * spec["d_model"]:
+        raise MXNetError(
+            "block='granite_hybrid' builds one group of B and C, a "
+            "convolution with a bias, projections without, a dense "
+            "feed-forward, no positions and mamba_n_heads x mamba_d_head "
+            f"= mamba_expand x d_model (got {cfg})")
+    if n_head % cfg["num_key_value_heads"] or spec["d_model"] % n_head:
+        raise MXNetError(
+            f"block='granite_hybrid': {n_head} query heads on "
+            f"{cfg['num_key_value_heads']} K/V heads at d_model "
+            f"{spec['d_model']}")
+    cfg.update(layer_types=kinds, head_dim=spec["d_model"] // n_head)
+    return dict(
+        spec, cfg=cfg, norm=_rms(spec), attention=_hybrid_mixer, bias=False,
+        dense=("gated", int(cfg["shared_intermediate_size"])),
+        dense_layers=n_layer, fed=True, pos_embed="rotary", tie_head=True,
+        multipliers={"embedding": float(cfg["embedding_multiplier"]),
+                     "residual": float(cfg["residual_multiplier"]),
+                     "logits": float(cfg["logits_scaling"])})
+
+
 #: ``block=`` -> its spec constructor: the one place a block is chosen
-#: by its name. An eighth architecture is one more entry and, only if
+#: by its name. A ninth architecture is one more entry and, only if
 #: its attention is new, one more attention function
 _SPECS = {"gpt2": _gpt2_spec, "olmoe": _olmoe_spec, "evabyte": _eva_spec,
           "glm_dsa": _glm_spec, "axk1": _axk1_spec, "afmoe": _afmoe_spec,
-          "xing4": _xing4_spec}
+          "xing4": _xing4_spec, "granite_hybrid": _granite_spec}
 
 
 def _spec(given, decode):
@@ -741,7 +912,7 @@ def _spec(given, decode):
     those, ``decode``, ``T`` (the rows a slot or sequence has in this
     graph), the defaults of what most blocks do not have (no ``fed``
     input, a plain residual add at the compute width, one head, no
-    dropout) and what the block's own constructor
+    dropout, no multipliers) and what the block's own constructor
     (``_SPECS``) makes of them and checks."""
     make = _SPECS.get(given["block"])
     if make is None:
@@ -760,13 +931,14 @@ def _spec(given, decode):
         "rms_eps": float(given["rms_eps"]),
         # what most blocks do not have
         "fed": False, "residual": "plain", "heads": 1, "next_byte": False,
-        "moe": None, "dense": None})
+        "moe": None, "dense": None, "multipliers": None})
 
 
 def _embedded(spec):
     """The head of a graph: ``(x, tok_w, fed, fed_rows)`` - the token
     embedding (scaled by sqrt(D), transformer convention, unless
-    ``embed_scale=False``), plus the learned position table when
+    ``embed_scale=False``; by the block's own multiplier where it has
+    one), plus the learned position table when
     ``pos_embed='learned'``, cast where the block's stream is float32,
     laid ``n`` times side by side where it is ``n`` copies (``"hyper"``).
     In a fed graph the tokens are embedded in the view the row-wise
@@ -785,7 +957,9 @@ def _embedded(spec):
         data, fed_rows = sym.pack_rows(data, fed, name=f"{name}_rows")
     x = sym.Embedding(data=data, weight=tok_w, input_dim=spec["vocab_size"],
                       output_dim=d_model, name=f"{name}_tok_embed",
-                      **({"scale": float(np.sqrt(d_model))}
+                      **({"scale": float(spec["multipliers"]["embedding"])}
+                         if spec["multipliers"]
+                         else {"scale": float(np.sqrt(d_model))}
                          if spec["embed_scale"] else {}))    # (B, T, D)
     if spec["pos_embed"] == "learned":
         pos_ids = sym.var("pos_ids") if spec["decode"] else sym._arange(
@@ -861,7 +1035,8 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                       tie_head=True, embed_scale=True, window=2048,
                       chunk=16, n_pred_heads=1, ffn_width=None,
                       multibyte=False, glm=None, afmoe=None,
-                      max_step_len=None, axk1=None, xing4=None):
+                      max_step_len=None, axk1=None, xing4=None,
+                      granite=None):
     """Incremental KV-cache decoder: ``(B, step_len)`` new token ids in,
     logits ``(B, step_len, vocab)`` out, per-layer K/V caches of
     ``capacity`` positions riding executor aux state. Parameter names
@@ -895,8 +1070,9 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     ``multibyte``), ``"glm_dsa"`` (``_glm_spec``: ``glm``, the published
     config's ``GLM_KEYS``), ``"axk1"`` (``_axk1_spec``: ``axk1``,
     ``AXK1_KEYS``), ``"xing4"`` (``_xing4_spec``: ``xing4``,
-    ``XING4_KEYS``) and ``"afmoe"`` (``_afmoe_spec``: ``afmoe``,
-    ``AFMOE_KEYS``; ``max_step_len``).
+    ``XING4_KEYS``), ``"afmoe"`` (``_afmoe_spec``: ``afmoe``,
+    ``AFMOE_KEYS``; ``max_step_len``) and ``"granite_hybrid"``
+    (``_granite_spec``: ``granite``, ``GRANITE_KEYS``).
 
     Every slot-pooled graph (``per_slot=True``, whatever the block)
     takes one more input, ``fed`` ``(slots,)`` int32 - how many of each
@@ -1081,6 +1257,15 @@ def slot_state(symbol):
     return families
 
 
+#: the families of per-slot state that something indexes: the cursor,
+#: a row per position, a ring by the position's remainder, EVA's window
+#: and summaries by the position's window and chunk. Any other family
+#: (``ops/ssm.py``: a convolution's tail, a recurrence's state) is
+#: carried from token to token and holds no position to go back to
+_INDEXED_FAMILIES = frozenset(("cursor", "rows", "ring", "window",
+                               "summary"))
+
+
 def ridge_rows():
     """The rows a bfloat16 matmul carries for the price of reading its
     weights: up to peak / bandwidth rows (two operations and two bytes
@@ -1251,6 +1436,15 @@ class BatchedKVCacheDecoder:
     ``capture_rows``/``restore_rows`` raise, naming the families.
     ``state_bytes`` is the state's bytes by family.
 
+    A graph with a recurrent mixer (``block="granite_hybrid"``) keeps,
+    beside its attention layers' ``"rows"``, state that nothing indexes
+    (families ``"conv"`` and ``"recurrent"``, ``ops/ssm.py``: constant
+    in the context, rewritten whole by every dispatch): every token
+    since the slot joined is in it and none can be taken out, so a
+    cursor goes to 0 - where the program reads the state as zeros - or
+    stays where it is; ``capture_rows``/``restore_rows`` raise, naming
+    the families; ``DecodeEngine.migrate`` copies it like any other.
+
     ``serve.decode.DecodeScheduler`` builds the continuous-batching
     front end (admission, retirement, streaming, rung ladder) on top of
     one of these per slot rung.
@@ -1290,6 +1484,8 @@ class BatchedKVCacheDecoder:
         # the per-slot state, by family, as the graph's ops declare it
         self._state = slot_state(module.symbol)
         self.positional = set(self._state) <= {"cursor", "rows"}
+        # state carried from token to token, which no position indexes
+        self._carried = sorted(set(self._state) - _INDEXED_FAMILIES)
         # a window of exact rows beside summaries (EVA attention)
         self.summarises = "summary" in self._state
         self.state_bytes = {
@@ -1501,6 +1697,18 @@ class BatchedKVCacheDecoder:
                     f"{self._ring[1]}), so a cursor goes to 0 or back by "
                     f"at most {self.ring_slack}, inside what the ring "
                     "still holds")
+        if self._carried:
+            cur = self.pos[rows]
+            bad = (positions != 0) & (positions != cur)
+            if bad.any():
+                raise MXNetError(
+                    f"cursor of slot(s) {rows[bad].tolist()} cannot move "
+                    f"from {cur[bad].tolist()} to "
+                    f"{positions[bad].tolist()}: this decoder carries "
+                    f"state from token to token (families "
+                    f"{self._carried}) that holds every token since the "
+                    "slot joined and no position to go back to, so a "
+                    "cursor goes to 0 or stays")
         if self.summarises:
             cur = self.pos[rows]
             ends = (positions // self.window + 1) * self.window

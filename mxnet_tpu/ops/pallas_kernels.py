@@ -1231,7 +1231,8 @@ def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv,
     return kernel
 
 
-def decode_attention(q, k_cache, v_cache, pos, window=0, ring=False):
+def decode_attention(q, k_cache, v_cache, pos, window=0, ring=False,
+                     scale=None):
     """Cursor-bounded flash-decode read over a fixed-capacity KV cache.
 
     ``q`` is (B, H, S, Dh) already-rotated queries, the caches are
@@ -1262,14 +1263,16 @@ def decode_attention(q, k_cache, v_cache, pos, window=0, ring=False):
     The call is a jitted function of its own (the kernel is
     ``decode_attn`` in the device trace), so that a step program lowers
     it once and calls it from every layer."""
+    more = {} if scale is None else {"scale": float(scale)}
     return _decode_attention(pos.astype(jnp.int32), q, k_cache, v_cache,
                              interpret=_interpret(), window=int(window),
-                             ring=bool(ring))
+                             ring=bool(ring), **more)
 
 
-@partial(jax.jit, static_argnames=("interpret", "name", "window", "ring"))
+@partial(jax.jit, static_argnames=("interpret", "name", "window", "ring",
+                                    "scale"))
 def _decode_attention(pos, q, k_cache, v_cache, interpret,
-                      name="decode_attn", window=0, ring=False):
+                      name="decode_attn", window=0, ring=False, scale=None):
     from jax.experimental.pallas import tpu as pltpu
 
     B, heads, S, Dh = q.shape
@@ -1325,7 +1328,8 @@ def _decode_attention(pos, q, k_cache, v_cache, interpret,
     geometry = {} if not (period or window) else {
         "period": period, "window": window, "ring": C if ring else 0}
     out = pallas_call(
-        _decode_attn_kernel(hb, block_k, rows_n, float(Dh) ** -0.5,
+        _decode_attn_kernel(hb, block_k, rows_n,
+                            float(Dh) ** -0.5 if scale is None else scale,
                             narrow_kv and q.dtype == jnp.bfloat16,
                             narrow_kv, **geometry),
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
@@ -1427,7 +1431,8 @@ def _window_attn_kernel(G, block_q, block_k, scale, narrow, window, ring):
     return kernel
 
 
-def window_attention(q, k_cache, v_cache, pos, fed, window=0, ring=False):
+def window_attention(q, k_cache, v_cache, pos, fed, window=0, ring=False,
+                     scale=None):
     """The read of a long window (chunked prefill): ``q`` (B, H, S, Dh)
     already-rotated queries of a slot's ``S`` positions from its cursor
     ``pos`` (B,) on, of which ``fed`` (B,) are real; the caches (B,
@@ -1444,14 +1449,15 @@ def window_attention(q, k_cache, v_cache, pos, fed, window=0, ring=False):
 
     A jitted function of its own: the kernel is ``window_attn`` in the
     device trace, lowered once a step program."""
+    more = {} if scale is None else {"scale": float(scale)}
     return _window_attention(pos.astype(jnp.int32), fed.astype(jnp.int32),
                              q, k_cache, v_cache, interpret=_interpret(),
-                             window=int(window), ring=bool(ring))
+                             window=int(window), ring=bool(ring), **more)
 
 
-@partial(jax.jit, static_argnames=("interpret", "window", "ring"))
+@partial(jax.jit, static_argnames=("interpret", "window", "ring", "scale"))
 def _window_attention(pos, fed, q, k_cache, v_cache, interpret, window,
-                      ring):
+                      ring, scale=None):
     from jax.experimental.pallas import tpu as pltpu
 
     B, heads, S, Dh = q.shape
@@ -1491,8 +1497,9 @@ def _window_attention(pos, fed, q, k_cache, v_cache, interpret, window,
     narrow = _narrow(k_cache.dtype) and q.dtype == jnp.bfloat16 \
         and k_cache.dtype == jnp.bfloat16
     out = pallas_call(
-        _window_attn_kernel(G, block_q, block_k, float(Dh) ** -0.5, narrow,
-                            window, C if ring else 0),
+        _window_attn_kernel(G, block_q, block_k,
+                            float(Dh) ** -0.5 if scale is None else scale,
+                            narrow, window, C if ring else 0),
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         grid_spec=grid_spec, name="window_attn", interpret=interpret,
         **kwargs)(pos, fed, qg, k_cache, v_cache)

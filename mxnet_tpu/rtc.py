@@ -650,12 +650,14 @@ def _register_attention():
 #   rewritten by the new sequence before its first read — slot reuse is
 #   bit-clean without touching the cache rows.
 #
-# Three more things a slot-pooled graph can ask of the op, each by an
+# Four more things a slot-pooled graph can ask of the op, each by an
 # attribute whose default leaves the programs above as they are:
 #
 # * ``kv_heads`` — grouped K/V heads: q has H heads, k and v and the
 #   pools ``kv_heads`` of them, and query head i reads K/V head
 #   ``i // (H // kv_heads)``;
+# * ``scale`` — the scores' multiplier where a model states one in place
+#   of ``1 / sqrt(head width)``;
 # * ``window`` — a sliding layer: the query at t attends
 #   ``t - window < j <= t``. With ``ring`` (rows) its pools are RINGS of
 #   that many rows, position t at row ``t % ring``, whatever the
@@ -669,7 +671,8 @@ def _register_attention():
 #   nothing is rewound after a window; the pads' rows are written
 #   behind the cursor, where the next dispatch writes over them.
 # --------------------------------------------------------------------------
-_Geometry = namedtuple("_Geometry", "groups window ring fed capacity")
+_Geometry = namedtuple("_Geometry",
+                       "groups window ring fed capacity scale")
 
 
 def _decode_geometry(attrs, q, k_cache):
@@ -686,12 +689,14 @@ def _decode_geometry(attrs, q, k_cache):
             f"window {window}, ring {ring}, {q.shape[2]} rows a dispatch: "
             "the K/V heads divide the query heads, and a ring holds its "
             "window and one dispatch's rows")
-    if (H != Hkv or window or fed) \
+    if (H != Hkv or window or fed or attrs.get("scale") is not None) \
             and not parse_bool(attrs.get("per_slot", False)):
-        raise MXNetError("attention_decode: kv_heads, window and fed are "
-                         "the slot-pooled lowering's (per_slot=True)")
+        raise MXNetError("attention_decode: kv_heads, window, fed and scale "
+                         "are the slot-pooled lowering's (per_slot=True)")
+    scale = attrs.get("scale")
     return _Geometry(H // Hkv, window, ring, fed,
-                     int(attrs.get("capacity", 256)))
+                     int(attrs.get("capacity", 256)),
+                     None if scale is None else float(scale))
 
 
 def _fed_cursor(geo, pos, S, fed):
@@ -856,7 +861,7 @@ def _attention_decode_per_slot(attrs, q, k, v, k_cache, v_cache, cursor,
     pos = cursor.reshape((B,)).astype(jnp.int32)          # (B,)
     _decode_check_overflow(pos, S, geo.capacity if geo.ring else pool_rows,
                            per_slot=True)
-    scale = 1.0 / float(np.sqrt(Dh))
+    scale = 1.0 / float(np.sqrt(Dh)) if geo.scale is None else geo.scale
     q, k_cache, v_cache = _decode_rope_write(attrs, q, k, v, k_cache,
                                              v_cache, pos, per_slot=True)
     if geo.groups > 1:
@@ -958,10 +963,12 @@ _DECODE_ROWS = 64
 
 
 def _read_geometry(geo):
-    """The reads' keywords: none where the pool is a row per position
-    and every key at or before the query is attended."""
-    return {"window": geo.window, "ring": bool(geo.ring)} \
-        if geo.window else {}
+    """The reads' keywords: none where the pool is a row per position,
+    every key at or before the query is attended and the scores are
+    scaled by the head's width."""
+    more = {} if geo.scale is None else {"scale": geo.scale}
+    return {"window": geo.window, "ring": bool(geo.ring), **more} \
+        if geo.window else more
 
 
 def _attention_decode_eligible(attrs, in_shapes, in_dtypes):
@@ -1142,7 +1149,8 @@ def _register_attention_decode():
                             "kv_heads": (int, None),
                             "window": (int, None),
                             "ring": (int, None),
-                            "fed": (None, None)},
+                            "fed": (None, None),
+                            "scale": (float, None)},
                  variants={"pallas": (_attention_decode_pallas_variant,
                                       _attention_decode_eligible,
                                       _ATTENTION_DECODE_PALLAS_KSPEC)})

@@ -138,6 +138,49 @@ def test_eva_attention_compiles_for_v5e_and_copies_no_pool(S, v5e):
         B * H * 2048 * d * 2)
 
 
+@pytest.mark.parametrize("S,rows", [(1, 32), (256, 384)],
+                         ids=["decode", "packed_window"])
+def test_ssm_mixer_compiles_for_v5e_and_copies_no_state(S, rows, v5e):
+    """Granite 4.0-H's mixer at the published sizes (32 slots, 64 heads
+    of 64, a state of 128, a convolution over 4, chunks of 256; the S =
+    1 program's 32 rows and the packed window's 384): the Pallas
+    lowering compiles for the chip - the step's kernel, and in a window
+    the chunk's inside XLA's loop over the trips -, and with the aux
+    arrays donated the 67 MB state comes back in the buffer it came in,
+    never copied."""
+    import re
+    opdef = get_op("ssm_mixer_decode")
+    H, P, N, K = 64, 64, 128, 4
+    d_in, C = H * P, H * P + 2 * N
+    attrs = opdef.normalize_attrs(dict(
+        heads=H, head_dim=P, d_state=N, d_conv=K, chunk=256, step_len=S,
+        capacity=4096))
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    ins = [sds((rows, 2 * d_in + 2 * N + H)), sds((32,), "int32"),
+           sds((C, K)), sds((C,)), sds((H,)), sds((H,)), sds((H,))]
+    aux = [sds((32, K - 1, C), "float32"),
+           sds((32, d_in // 128, N, 128), "float32"), sds((32, 1), "int32")]
+    assert opdef.donate_aux
+    assert opdef.variant_eligible("pallas", attrs,
+                                  [a.shape for a in ins + aux],
+                                  [str(a.dtype) for a in ins + aux])
+    fn = opdef.variant_fn("pallas")
+    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
+                       donate_argnums=(1,)).lower(ins, aux).compile()
+    text = compiled.as_text()
+    kernels = ["ssm_update"] + (["ssm_scan"] if S > 1 else [])
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}[.\w]* = .*tpu_custom_call", text), \
+            kernel
+    assert len(re.findall("tpu_custom_call", text)) == len(kernels)
+    assert not re.findall(r"= f32\[32,32,128,128\]\S* copy\(", text)
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        32 * d_in * N * 4
+
+
 @pytest.mark.parametrize("S", [1, 64], ids=["decode", "window"])
 @pytest.mark.parametrize("capacity", [2048, 4096],
                          ids=["cerebras", "olmoe"])
@@ -603,6 +646,46 @@ def test_hyper_connected_programs_lower_at_tiny_sizes(S, form, monkeypatch):
     assert f"tensor<{rows}x256xf32>" in text        # the stream's rows
     assert f"tensor<{rows}x16xf32>" in text         # Hres, 16 a row
     assert f"tensor<{rows}x4x64xf32>" not in text
+
+
+@pytest.mark.parametrize("S, form", [(1, "whole"), (16, "whole"),
+                                     (16, "packed")])
+def test_granite_hybrid_programs_lower_over_the_rows_they_run(
+        S, form, monkeypatch):
+    """Granite 4.0-H's tiny graph (``window_pack_cases``: mamba,
+    attention, mamba) lowers as its S = 1, whole-window and packed
+    programs: two recurrent mixers whose state keeps its shape whatever
+    the rows, the mixer's projection over the rows the program runs -
+    4 slots, 4 x 16 of a whole window, the budget's 24 - and the
+    attention layer's K/V heads paired in one row."""
+    import window_pack_cases as cases
+    from mxnet_tpu.models import transformer as tfm
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
+    kernel_tier.clear()
+    try:
+        sym = cases.symbol("granite_hybrid", S)
+        rows = cases.SLOTS * S
+        if form == "packed":
+            sym, rows = tfm.packed_window(sym, cases.SLOTS)
+            assert rows == 24
+        text = cases.lowered_text(sym, cases.SLOTS, S)
+    finally:
+        kernel_tier.clear()
+    ops = [n.op for n in sym._topo_nodes() if not n.is_variable]
+    assert ops.count("ssm_mixer_decode") == 2
+    assert ops.count("attention_decode") == 1
+    # [z | xBC | dt] = 128 + 160 + 8 numbers a row, over the rows alone
+    assert f"tensor<{rows}x296xf32>" in text
+    # the state (4 slots, one lane group, 16 down, 128 across) and the
+    # convolution's tail, float32 in and out
+    assert text.count("tensor<4x1x16x128xf32>") >= 4
+    assert "tensor<4x3x160xf32>" in text
+    # two K/V heads of 16 in one row of 32, 128 positions
+    assert "tensor<4x1x128x32xf32>" in text
+    if S > 1:       # the chunks' loop; an S = 1 program has none
+        assert "stablehlo.while" in text
+    families = tfm.slot_state(sym)
+    assert sorted(families) == ["conv", "cursor", "recurrent", "rows"]
 
 
 #: two layers of the Cerebras and the OLMoE configuration at their

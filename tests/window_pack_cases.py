@@ -1,5 +1,6 @@
 """The slot-pooled decoders at tiny sizes - GLM-5.2's block, A.X-K1's,
-Xing4.0's, Trinity's and EvaByte's, which are served alone (``BLOCKS``),
+Xing4.0's, Trinity's, Granite 4.0-H's and EvaByte's, which are served
+alone (``BLOCKS``),
 and the two that are trained too (``FUSED``: GPT-2's with learned and
 with rotary positions, OLMoE's), fed like the others since ISSUE 47 -
 for the tests of a window's packed rows (``tests/test_decode_pack.py``)
@@ -41,6 +42,16 @@ _AFMOE = {"num_key_value_heads": 2, "head_dim": 16, "sliding_window": 16,
           "num_experts_per_tok": 4, "num_shared_experts": 1,
           "route_norm": True, "route_scale": 2.826}
 
+_GRANITE = {"num_key_value_heads": 2,
+            "layer_types": ["mamba", "attention", "mamba"],
+            "mamba_n_heads": 8, "mamba_d_head": 16, "mamba_d_state": 16,
+            "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_expand": 2,
+            "mamba_chunk_size": 8, "mamba_conv_bias": True,
+            "mamba_proj_bias": False, "shared_intermediate_size": 96,
+            "num_local_experts": 0, "position_embedding_type": "nope",
+            "embedding_multiplier": 12.0, "residual_multiplier": 0.22,
+            "attention_multiplier": 0.125, "logits_scaling": 8.0}
+
 #: block -> ``get_decode_symbol``'s arguments beside the step length
 BLOCKS = {
     "glm_dsa": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
@@ -51,6 +62,8 @@ BLOCKS = {
                   rope_base=1e4, rms_eps=1e-6, xing4=_XING4),
     "afmoe": dict(vocab_size=48, d_model=64, n_layer=3, n_head=8,
                   rope_base=1e4, afmoe=_AFMOE, max_step_len=WINDOW),
+    "granite_hybrid": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
+                           granite=_GRANITE),
     "evabyte": dict(vocab_size=40, d_model=32, n_layer=2, n_head=2,
                     rope_base=1e5, window=32, chunk=4, n_pred_heads=2,
                     ffn_width=48),

@@ -9,19 +9,26 @@ one slot prefilling, the others riding with a token each - runs the
 packed form of the same graph (``models/transformer.py``'s
 ``packed_window``, ``ops/rows.py``). Here slot 0 prefills a sequence to
 16,384 positions (or as many whole windows as leave one more inside
-the configuration's capacity) and one window more while every other slot of the top
-rung rides each window with one token of its own; once through the
+the configuration's capacity) and one window more while every other slot
+of the top rung rides each window with one token of its own; once
+through the
 packed program (``fed`` sums to S + slots - 1 <= R) and once, from
 cursor 0 again, through the whole-window program fed the same. The last
 sixteen rows of slot 0's last window and the riders' rows, packed
 against whole, and slot 0's against the architecture's plain float32
 reference under its ``LOGIT_TOL``; whether the rows each path wrote to
-the positional pools are equal; and the median window's time on the
-host's clock in either form. Prints one JSON line.
+the positional pools are equal (for a graph that carries state no
+position indexes - ``granite-4.0-h-micro``'s convolution tails and
+recurrent states - slot 0's whole state after the last window, every
+family); and the median window's time on the host's clock in either
+form. ``--part N`` feeds slot 1 N rows of a sequence of its own a
+window in place of one token, so that a whole chunk, a part of a chunk
+and riders share one dispatch. Prints one JSON line.
 
     python3 tools/window_pack_check.py
         --config a.x-k1|glm-5.2|xing4.0-29b-a4b|cerebras-gpt-1.3b|olmoe-1b-7b
-        [--seed N] [--rehearse]
+                 |granite-4.0-h-micro
+        [--seed N] [--part N] [--rehearse]
 
 ``--rehearse`` runs the configuration's tiny fixture on the CPU
 (chipbench/tests/fixtures: a context of 64, windows of 16; for the
@@ -45,6 +52,7 @@ _TINY = {"a.x-k1": ("axk1", "tiny-axk1.json"),
          "glm-5.2": ("glm_dsa", "tiny-glm.json"),
          "xing4.0-29b-a4b": ("xing4", "tiny-xing4.json"),
          "olmoe-1b-7b": ("olmoe", "tiny-olmoe.json"),
+         "granite-4.0-h-micro": ("granite_hybrid", "tiny-granite.json"),
          "cerebras-gpt-1.3b": None}
 
 #: the Cerebras configuration cut to a rehearsal's size (learned
@@ -60,6 +68,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", choices=sorted(_TINY), required=True)
     ap.add_argument("--seed", type=int, default=2147480243)
+    ap.add_argument("--part", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
     ns = ap.parse_args(argv)
     from chipbench import common, manifest
@@ -108,6 +117,13 @@ def main(argv=None):
     riders = rng.integers(0, cfg["vocab_size"], (top, windows)) \
         .astype(np.int32)
     fed = np.asarray([S] + [1] * (top - 1), np.int32)
+    part = None
+    if ns.part:
+        assert top > 1 and 1 < ns.part <= S \
+            and S + ns.part + top - 2 <= budget, (ns.part, budget)
+        fed[1] = ns.part
+        part = rng.integers(0, cfg["vocab_size"], windows * ns.part) \
+            .astype(np.int32)
 
     def run(whole):
         """Every window of the schedule through one form of the window
@@ -129,6 +145,8 @@ def main(argv=None):
                 tokens = np.zeros((top, S), np.int32)
                 tokens[0] = seq[0, w * S:(w + 1) * S]
                 tokens[1:, 0] = riders[1:, w]
+                if part is not None:
+                    tokens[1, :ns.part] = part[w * ns.part:(w + 1) * ns.part]
                 t = time.perf_counter()
                 out = drv.step(tokens, fed=fed)
                 drv.release_outputs()   # 2 GB of logits at a whole
@@ -139,10 +157,18 @@ def main(argv=None):
             if hidden is not None:
                 drv._packed[S] = hidden
         logits = out.asnumpy().astype(np.float32)
-        written = [np.asarray(a, np.float32)[..., ctx:, :]
-                   for a in drv.capture_rows(0, windows * S).values()]
-        return (logits[0, S - n_cmp:], logits[1:, 0], written, seconds,
-                ran)
+        if drv.positional:
+            written = [np.asarray(a, np.float32)[..., ctx:, :]
+                       for a in drv.capture_rows(0, windows * S).values()]
+        else:       # state no position indexes: slot 0's, every family
+            written = [np.asarray(cell.asjax()[0], np.float32)
+                       for family in sorted(drv.state_bytes)
+                       if family != "cursor"
+                       for _nm, cell in drv._cells(family)]
+        # a slot fed a part of a chunk is compared at its last real row
+        last = np.maximum(fed[1:] - 1, 0)
+        return (logits[0, S - n_cmp:],
+                logits[np.arange(1, top), last], written, seconds, ran)
 
     tail_p, ride_p, rows_p, s_p, ran_p = run(whole=False)
     tail_w, ride_w, rows_w, s_w, ran_w = run(whole=True)
