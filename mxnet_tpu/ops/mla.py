@@ -23,16 +23,37 @@ every kernel would copy the pool into rows and back. With
 
 ``forward`` (the XLA composition, and the statement of the above)
 expands ``k_n`` and ``v`` of the whole pool. The ``pallas`` variant
-absorbs ``W_kvb``: ``q_n W_kb`` is scored against ``c_kv`` itself, the
-weighted sum of ``c_kv`` goes through ``W_vb`` afterwards, and one
-flash-style kernel (``mla_attn_decode`` at S = 1, ``mla_attn_window``
-beyond) walks the live blocks of the pool once for all heads. The
-kernel has two geometries: at S = 1 the 64 heads of a slot's one query
-are the rows of one product a key block; in a window a head's 256
-positions are. A slot that a window feeds ONE row (a decoding slot
-riding another's prefill: most slots of most windows) would be a block
-of 255 pads in the second, so it is dead there and takes the first,
-inside the window program (``mla_attn_ride``); ``fed`` says which.
+walks the live blocks of the pool once for all heads with a flash-style
+kernel, in one of two forms of the same sums, and takes for each
+geometry the one that geometry pays least for:
+
+* **absorbed**, at S = 1 (``mla_attn_decode``): ``q_n W_kb`` is scored
+  against ``c_kv`` itself and the weighted sum of ``c_kv`` goes through
+  ``W_vb`` afterwards. A (query, key) pair of a head costs 2 x (640 +
+  512) FLOP where the expanded widths would cost 2 x (192 + 128), but
+  the 64 heads of a slot's one query are the rows of ONE product a key
+  block, nothing is expanded, and the step is the read of the pool.
+* **expanded**, for a window's chunks (``mla_attn_window``): a key
+  block is expanded once a head to ``k_n = c_kv W_kb[h]^T`` and ``v =
+  c_kv W_vb[h]^T``, rounded to the pool's dtype as the composition
+  rounds them, and every query block of the slot's chunk - whose
+  softmax state stays in VMEM across the key blocks - is scored against
+  it at ``nope_dim + rope_dim`` lanes and sums ``v``. The expansion is
+  2 x 512 x (``nope_dim`` + ``v_dim``) FLOP a key a head whatever the
+  chunk, so over ``n`` fed rows a pair costs 640 + 262,144 / n at the
+  DeepSeek-V3 widths (128 + 64, 128) against the absorbed 2,176 at 576
+  real lanes, and 1,024 + 458,752 / n at GLM-5.2's (192 + 64, 256)
+  against 2,304: even at 171 and 358 fed rows, 2.4 and 1.56 times
+  cheaper at 1,024. Measured on a v5e the whole launch is ahead from
+  128 fed rows on at 12 k and 17 k keys at all three published widths
+  (``PERF.md`` section 6, PR 49), so the form is unconditional.
+
+A slot that a window feeds ONE row (a decoding slot riding another's
+prefill: most slots of most windows) has nothing to amortise an
+expansion of its whole context over, so it is dead to the window form -
+its steps re-reference their live neighbour's blocks and move nothing
+but the zeros they write - and takes the absorbed one inside the window
+program (``mla_attn_ride``, the S = 1 kernel); ``fed`` says which.
 
 **The selection.** ``S_t`` arrives as ``selection (slots, S, capacity)``
 int8, 1 where position j is attended by query t - an input, because it
@@ -615,23 +636,44 @@ def _mla_infer(attrs, in_shapes):
             [(B, 1, capacity, latent_width(rank, dr)), (B, 1)])
 
 
-def _mla_attn_kernel(hg, R, bk, rank, scale, form, selected):
-    """Grid (slot, head group, query block, key block): online softmax
-    of ``hg`` groups of ``R`` query rows against a block of latent rows
-    under the selection. In the ``"window"`` form a group is a head and
-    its rows are ``R`` positions, masked row by row, and a block whose
-    positions are all past ``fed`` (pads) comes out zero without a
-    product. In the ``"decode"`` form (S = 1) the one group's rows are
-    the heads, which share the query's mask row; the ``"ride"`` form is
-    the same for the one query of a slot that a window feeds a single
-    row, live where ``fed`` is 1 and zero elsewhere. Without a
-    selection (``selected`` False: no mask operand) a row attends the
-    keys at or before its own position, told from the cursor: a block
-    that lies wholly before the query block's first position takes no
-    mask at all, and since key 0 is at or before every query no row's
-    running maximum is ever infinite."""
-    window = form == "window"
+def _softmax_step(s, mask, v, m_s, l_s, acc_s, at, selected):
+    """One block of the online softmax: float32 scores ``s`` (rows,
+    keys) under ``mask`` (None: every key attended) against the values
+    ``v`` (keys, .) into the running maximum, sum and weighted sum at
+    index ``at`` of the scratch. Under a selection a row may have
+    attended nothing yet, so its maximum may be infinite; without one
+    key 0 is at or before every query and it never is."""
+    if mask is not None:
+        s = jnp.where(mask, s, -jnp.inf)
+    m = m_s[at]
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    if selected:
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        e = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
+        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+    else:
+        e = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+    m_s[at] = m_new
+    l_s[at] = l_s[at] * corr + jnp.sum(e, axis=-1, keepdims=True)
+    acc_s[at] = acc_s[at] * corr + jnp.dot(
+        e.astype(v.dtype), v, preferred_element_type=_F32)
 
+
+def _mla_attn_kernel(bk, rank, scale, form, selected):
+    """The absorbed form, grid (slot, 1, 1, key block): online softmax
+    of one query's heads - the rows of one product, which share the
+    query's mask row - against a block of latent rows, the weighted
+    sum of ``c_kv`` itself. ``"decode"`` is the S = 1 program's;
+    ``"ride"`` is the same for the one query of a slot that a window
+    feeds a single row, live where ``fed`` is 1 and zero elsewhere.
+    Without a selection (``selected`` False: no mask operand) the query
+    attends the keys at or before its own position, told from the
+    cursor: a block that lies wholly before it takes no mask at all.
+    The S = 1 program's text is pinned to its parent's
+    (``tests/test_chip_compile.py``), which this kernel shared with a
+    window form: the loop of one trip, the additions of 0 and the two
+    grid axes of one step are what that form left behind."""
     def attend(q_ref, k_ref, mask, m_s, l_s, acc_s):
         k = k_ref[...]
         c = k[:, :rank]
@@ -639,34 +681,17 @@ def _mla_attn_kernel(hg, R, bk, rank, scale, form, selected):
         def group(g, carry):
             s = lax.dot_general(q_ref[g], k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=_F32) * scale
-            if mask is not None:
-                s = jnp.where(mask, s, -jnp.inf)
-            m = m_s[g]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            if selected:
-                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-                e = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
-                corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-            else:
-                e = jnp.exp(s - m_new)
-                corr = jnp.exp(m - m_new)
-            m_s[g] = m_new
-            l_s[g] = l_s[g] * corr + jnp.sum(e, axis=-1, keepdims=True)
-            acc_s[g] = acc_s[g] * corr + jnp.dot(
-                e.astype(c.dtype), c, preferred_element_type=_F32)
+            _softmax_step(s, mask, c, m_s, l_s, acc_s, g, selected)
             return carry
-        lax.fori_loop(0, hg, group, 0)
+        lax.fori_loop(0, 1, group, 0)
 
     def kernel(p_ref, fed_ref, q_ref, k_ref, *rest):
         sel_ref = rest[0] if selected else None
         o_ref, m_s, l_s, acc_s = rest[-4:]
-        b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-        first = p_ref[b] + (i * R if window else 0)
-        last = first + (R - 1 if window else 0)
-        if window:
-            fed = i * R < fed_ref[b]
-        else:
-            fed = fed_ref[b] == 1 if form == "ride" else True
+        b, j = pl.program_id(0), pl.program_id(3)
+        first = p_ref[b] + 0
+        last = first + 0
+        fed = fed_ref[b] == 1 if form == "ride" else True
         live = (j * bk <= last) & fed
 
         @pl.when(j == 0)
@@ -689,11 +714,8 @@ def _mla_attn_kernel(hg, R, bk, rank, scale, form, selected):
 
             @pl.when(live & jnp.logical_not(before))
             def _diagonal():
-                rows = R if window else 1
-                key = j * bk + lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
-                t = first + (lax.broadcasted_iota(jnp.int32, (rows, bk), 0)
-                             if window else 0)
-                attend(q_ref, k_ref, key <= t, m_s, l_s, acc_s)
+                key = j * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+                attend(q_ref, k_ref, key <= first + 0, m_s, l_s, acc_s)
 
         @pl.when(j == pl.num_programs(3) - 1)
         def _emit():
@@ -703,53 +725,43 @@ def _mla_attn_kernel(hg, R, bk, rank, scale, form, selected):
 
 
 def _mla_launch(p, fed, q, keys, sel, rank, scale, interpret, form):
-    """One launch of the attention kernel in one of its forms
-    (``_mla_attn_kernel``) over ``q (B, H, S, rank + rope_dim)`` ->
-    (B, H, S, rank); ``"decode"`` and ``"ride"`` take S = 1."""
+    """One launch of the absorbed kernel (``_mla_attn_kernel``) over the
+    one query a slot ``q (B, H, 1, rank + rope_dim)`` -> (B, H, 1,
+    rank)."""
     B, H, S, dq = q.shape
     C = keys.shape[1]
-    window = form == "window"
-    if window:
-        G, hg = H, _pk._divisor_block(H, 16)
-        R, bk = _pk._divisor_block(S, 256), _pk._divisor_block(C, 512)
-    else:
-        q = q.reshape(B, 1, H, dq)
-        G, hg, R, bk = 1, 1, H, _pk._divisor_block(C, 2048)
+    q = q.reshape(B, 1, H, dq)
+    bk = _pk._divisor_block(C, 2048)
     selected = sel is not None
 
     def q_map(b, g, i, j, p_ref, fed_ref):
         return b, g, i, 0
 
-    def last_block(b, i, p_ref, fed_ref):
+    def last_block(b, p_ref, fed_ref):
         # a dead block re-references the last live one: no copy
-        last = p_ref[b] + ((i + 1) * R - 1 if window else 0)
-        if window:
-            last = jnp.where(i * R < fed_ref[b], last, 0)
-        elif form == "ride":
+        last = p_ref[b] + 0
+        if form == "ride":
             last = jnp.where(fed_ref[b] == 1, last, 0)
         return last // bk
 
     def k_map(b, g, i, j, p_ref, fed_ref):
-        return b, jnp.minimum(j, last_block(b, i, p_ref, fed_ref)), 0
+        return b, jnp.minimum(j, last_block(b, p_ref, fed_ref)), 0
 
     def sel_map(b, g, i, j, p_ref, fed_ref):
-        return b, i, jnp.minimum(j, last_block(b, i, p_ref, fed_ref))
+        return b, i, jnp.minimum(j, last_block(b, p_ref, fed_ref))
 
-    in_specs = [pl.BlockSpec((None, hg, R, dq), q_map),
+    in_specs = [pl.BlockSpec((None, 1, H, dq), q_map),
                 pl.BlockSpec((None, bk, dq), k_map)]
     if selected:
-        in_specs.append(pl.BlockSpec((None, R if window else 1, bk),
-                                     sel_map))
+        in_specs.append(pl.BlockSpec((None, 1, bk), sel_map))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, G // hg, S // R if window else 1, C // bk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, hg, R, rank), q_map),
-        scratch_shapes=[pltpu.VMEM((hg, R, 1), _F32),
-                        pltpu.VMEM((hg, R, 1), _F32),
-                        pltpu.VMEM((hg, R, rank), _F32)])
+        num_scalar_prefetch=2, grid=(B, 1, 1, C // bk), in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, 1, H, rank), q_map),
+        scratch_shapes=[pltpu.VMEM((1, H, 1), _F32),
+                        pltpu.VMEM((1, H, 1), _F32),
+                        pltpu.VMEM((1, H, rank), _F32)])
     out = _pk.pallas_call(
-        _mla_attn_kernel(hg, R, bk, rank, scale, form, selected),
+        _mla_attn_kernel(bk, rank, scale, form, selected),
         name="mla_attn_" + form,
         out_shape=jax.ShapeDtypeStruct(q.shape[:3] + (rank,), q.dtype),
         grid_spec=grid_spec, interpret=interpret,
@@ -759,39 +771,241 @@ def _mla_launch(p, fed, q, keys, sel, rank, scale, interpret, form):
     return out.reshape(B, H, S, rank)
 
 
+#: the window form's blocks: the rows of a query block - what a chunk's
+#: pads are skipped by; two that both hold real rows are the rows of one
+#: product - and the latent rows of a key block. The row-wise part of a
+#: softmax step is paid a product, so a wide one is cheaper: a layer of
+#: 1,024 rows at 17 k keys at A.X-K1's widths took 12.7 ms at 256 x
+#: 512, 9.3 at 256 x 1,024, 8.6 at 512 x 1,024 and at 256 x 2,048 (v5e;
+#: PERF.md section 6, PR 49) - but Mosaic unrolls a product's body, so
+#: the pairs are a loop, and a key block of 2,048 would compute twice
+#: the keys past a chunk's diagonal
+_WINDOW_BLOCKS = (256, 1024)
+
+
+def _mla_window_kernel(hg, S, R, bk, rank, dn, dv, scale, selected):
+    """The expanded form, grid (slot, head group, key block): a block
+    of latent rows is expanded to a head's ``k_n`` and ``v`` once -
+    ``c_kv W_kb[h]^T`` and ``c_kv W_vb[h]^T`` at the pool's dtype, as
+    the composition rounds them, beside the row's own ``k_r`` lanes -
+    and every query block of the slot's chunk that can see it is scored
+    against it at ``nope_dim + rope_dim`` lanes and sums ``v``, two
+    blocks at a time where both hold real rows. The chunk's softmax
+    state - all ``S`` rows of the group's ``hg`` heads - stays in
+    scratch across the key blocks. A query block whose positions are
+    all past ``fed`` (pads) comes out zero without a product, and a
+    slot fed nothing (or riding) costs its steps and the zeros it
+    writes. Under a selection the mask is the selection's;
+    without one a row attends the keys at or before its own position,
+    told from the cursor, and a key block wholly before a query block
+    takes no mask at all."""
+    contract_last = (((1,), (1,)), ((), ()))
+
+    def kernel(p_ref, fed_ref, src_ref, q_ref, w_ref, k_ref, *rest):
+        sel_ref = rest[0] if selected else None
+        o_ref, m_s, l_s, acc_s, k_s, v_s = rest[-6:]
+        b, j = pl.program_id(0), pl.program_id(2)
+        p, n = p_ref[b], fed_ref[b]
+
+        @pl.when((j == 0) & (n > 0))
+        def _init():
+            m_s[...] = jnp.full(m_s.shape, -jnp.inf, _F32)
+            l_s[...] = jnp.zeros(l_s.shape, _F32)
+            acc_s[...] = jnp.zeros(acc_s.shape, _F32)
+
+        def attend(h, rows, mask):
+            s = lax.dot_general(q_ref[h, rows], k_s[...], contract_last,
+                                preferred_element_type=_F32) * scale
+            _softmax_step(s, mask, v_s[...], m_s, l_s, acc_s, (h, rows),
+                          selected)
+
+        def span(h, start, count, live):
+            """``count`` query rows from row ``start`` of the chunk as
+            the rows of one product, where ``live`` and they see the
+            key block."""
+            rows = pl.ds(start, count)
+            first = p + start
+            seen = live & (j * bk <= first + count - 1)
+            if selected:
+                pl.when(seen)(lambda: attend(
+                    h, rows, sel_ref[rows].astype(jnp.int32) != 0))
+                return
+            before = (j + 1) * bk - 1 <= first
+            pl.when(seen & before)(lambda: attend(h, rows, None))
+
+            @pl.when(seen & jnp.logical_not(before))
+            def _diagonal():
+                key = j * bk + lax.broadcasted_iota(jnp.int32, (count, bk), 1)
+                t = first + lax.broadcasted_iota(jnp.int32, (count, bk), 0)
+                attend(h, rows, key <= t)
+
+        def pair(h, i, carry):
+            # two query blocks are the rows of one product where the
+            # second has a real row (the key block's tiles are loaded
+            # once for both), else the first alone
+            start = pl.multiple_of(i * 2 * R, 2 * R)
+            both = start + R < n
+            span(h, start, 2 * R, both)
+            span(h, start, R, (start < n) & jnp.logical_not(both))
+            return carry
+
+        def head(h, carry):
+            c = k_ref[:, :rank]
+            k_s[:, :dn] = lax.dot_general(
+                c, w_ref[h, :dn], contract_last,
+                preferred_element_type=_F32).astype(k_s.dtype)
+            v_s[...] = lax.dot_general(
+                c, w_ref[h, dn:], contract_last,
+                preferred_element_type=_F32).astype(v_s.dtype)
+            if S // R > 1:      # a loop of no trips is traced all the same
+                lax.fori_loop(0, S // R // 2, partial(pair, h), 0)
+            if S // R % 2:
+                span(h, S - R, R, S - R < n)
+            return carry
+
+        @pl.when((n > 0) & (j * bk <= p + n - 1))
+        def _block():
+            # the shared rotary key and zeros up to q's width, then head
+            # by head k_n before them
+            tail = k_s.shape[1] - dn
+            rope = k_ref[:, rank:rank + tail]
+            k_s[:, dn:dn + rope.shape[1]] = rope
+            if rope.shape[1] < tail:
+                k_s[:, dn + rope.shape[1]:] = jnp.zeros(
+                    (bk, tail - rope.shape[1]), k_s.dtype)
+            lax.fori_loop(0, hg, head, 0)
+
+        @pl.when(j == pl.num_programs(2) - 1)
+        def _emit():
+            @pl.when(n == 0)
+            def _dead():
+                o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+            @pl.when(n > 0)
+            def _live():
+                for h in range(hg):
+                    o_ref[:, h * dv:(h + 1) * dv] = (
+                        acc_s[h] / jnp.maximum(l_s[h], 1e-30)) \
+                        .astype(o_ref.dtype)
+    return kernel
+
+
+def _mla_window_launch(p, fed, q, w_kvb, dn, keys, sel, scale, interpret,
+                       blocks):
+    """One launch of the expanded kernel (``_mla_window_kernel``):
+    ``q (B, H, S, .)`` holding ``[q_n ; q_r]`` in whole tiles of lanes
+    and ``w_kvb (H, nope_dim + v_dim, rank)``, a head's ``[W_kb ;
+    W_vb]`` with ``nope_dim`` = ``dn``, against ``keys (B, C, width)``
+    -> (B, S, H * v_dim), head-major as the op hands it on. A slot
+    with ``fed`` 0 is dead: its steps re-reference the blocks of the
+    live step before them (ahead of the first live slot: after them),
+    so they move nothing but the zeros they write."""
+    B, H, S, dqk = q.shape
+    C, width = keys.shape[1:]
+    rank, dv = w_kvb.shape[2], w_kvb.shape[1] - dn
+    hg = _pk._divisor_block(H, 8)
+    R, bk = _pk._divisor_block(S, blocks[0]), _pk._divisor_block(C, blocks[1])
+    G = H // hg
+    selected = sel is not None
+    live = fed > 0
+    before = lax.cummax(jnp.where(live, jnp.arange(B), -1))
+    src = jnp.where(before >= 0, before, jnp.argmax(live)).astype(jnp.int32)
+
+    def step(b, g, j, p_ref, fed_ref, src_ref):
+        """The (slot, head group, key block) whose blocks a step takes:
+        its own, a key block past the slot's last live one being that
+        one again; a dead slot's are its neighbour's."""
+        s = src_ref[b]
+        last = jnp.maximum(p_ref[s] + fed_ref[s] - 1, 0) // bk
+        dead, after = fed_ref[b] == 0, s < b
+        return (s, jnp.where(dead, jnp.where(after, G - 1, 0), g),
+                jnp.where(dead, jnp.where(after, last, 0),
+                          jnp.minimum(j, last)))
+
+    def q_map(*at):
+        s, g, _ = step(*at)
+        return s, g, 0, 0
+
+    def w_map(*at):
+        return step(*at)[1], 0, 0
+
+    def k_map(*at):
+        s, _, j = step(*at)
+        return s, j, 0
+
+    def sel_map(*at):
+        s, _, j = step(*at)
+        return s, 0, j
+
+    in_specs = [pl.BlockSpec((None, hg, S, dqk), q_map),
+                pl.BlockSpec((hg, dn + dv, rank), w_map),
+                pl.BlockSpec((None, bk, width), k_map)]
+    if selected:
+        in_specs.append(pl.BlockSpec((None, S, bk), sel_map))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(B, G, C // bk), in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, S, hg * dv),
+                               lambda b, g, j, *_: (b, 0, g)),
+        scratch_shapes=[pltpu.VMEM((hg, S, 1), _F32),
+                        pltpu.VMEM((hg, S, 1), _F32),
+                        pltpu.VMEM((hg, S, dv), _F32),
+                        pltpu.VMEM((bk, dqk), keys.dtype),
+                        pltpu.VMEM((bk, dv), keys.dtype)])
+    return _pk.pallas_call(
+        _mla_window_kernel(hg, S, R, bk, rank, dn, dv, scale, selected),
+        name="mla_attn_window",
+        out_shape=jax.ShapeDtypeStruct((B, S, H * dv), q.dtype),
+        grid_spec=grid_spec, interpret=interpret,
+        **_compiler_params(("parallel", "parallel", "arbitrary")))(
+            p, fed, src, q, w_kvb, keys, *((sel,) if selected else ()))
+
+
 @partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
-def _mla_attend(p, fed, q, q_ride, pool, sel, rank, scale, interpret):
-    """The kernels ``mla_attn_decode`` (S = 1) and ``mla_attn_window``
-    with ``mla_attn_ride`` (beyond): queries ``q (B, H, S, rank +
-    rope_dim)`` in the latent space against the pool under ``sel``
-    (None: every position at or before the query, no mask operand) ->
-    the weighted sums of ``c_kv``, (B, H, S, rank) at ``q``'s dtype.
-    Blocks past a query block's last position are neither fetched nor
-    computed. Which form a slot of a window takes is read from ``fed``:
-    a slot fed one row (a decoding slot riding a prefill window) is
-    dead to the window form, whose query block of 256 positions would
-    hold 255 pads, and its one query - ``q_ride (B, H, 1, .)``, row 0
-    of ``q``, handed over apart because a slice of ``q`` itself makes
-    the compiler lay all of ``q`` out for the slice and copy it for the
-    window - goes through the decode form, the heads as the rows of one
-    group; the window's zeros in that slot's row 0 take the result, in
-    place."""
-    B, H, S, dq = q.shape
-    launch = partial(_mla_launch, p, keys=pool.reshape(B, pool.shape[2], dq),
-                     rank=rank, scale=scale, interpret=interpret)
-    if S == 1:
-        return launch(fed, q, sel=sel, form="decode")
+def _mla_attend(p, fed, q, pool, sel, rank, scale, interpret):
+    """The kernel ``mla_attn_decode``, the S = 1 program's: queries
+    ``q (B, H, 1, rank + rope_dim)`` in the latent space against the
+    pool under ``sel`` (None: every position at or before the query, no
+    mask operand) -> the weighted sums of ``c_kv``, (B, H, 1, rank) at
+    ``q``'s dtype. Blocks past the query's position are neither fetched
+    nor computed."""
+    B, _, _, dq = q.shape
+    return _mla_launch(p, fed, q, pool.reshape(B, pool.shape[2], dq), sel,
+                       rank, scale, interpret, "decode")
+
+
+@partial(jax.jit, static_argnames=("dn", "scale", "interpret", "blocks"))
+def _mla_attend_window(p, fed, q, q_ride, pool, sel, w_kvb, dn, scale,
+                       interpret, blocks):
+    """The kernels ``mla_attn_window`` and ``mla_attn_ride`` of a window
+    program: ``q (B, H, S, .)`` in the expanded widths and ``q_ride (B,
+    H, 1, rank + rope_dim)``, every slot's first query in the latent
+    space, against the pool under ``sel``, with ``w_kvb (H, nope_dim +
+    v_dim, rank)`` (``nope_dim`` = ``dn``) -> (B, S, H * v_dim) at
+    ``q``'s dtype. Which form a slot takes is read from ``fed``: a slot
+    fed one row (a decoding slot riding a prefill window) is dead to
+    the window form, where the expansion of its whole context would
+    serve one query, and goes through the absorbed one, the heads as the
+    rows of one group, as in the S = 1 program; the window's zeros in
+    that slot's row 0 take the result, in place."""
+    B = q.shape[0]
+    keys = pool.reshape(B, pool.shape[2], pool.shape[3])
     riding = fed == 1
-    out = launch(jnp.where(riding, 0, fed), q, sel=sel, form="window")
-    ride = launch(fed, q_ride, sel=None if sel is None else sel[:, :1],
-                  form="ride")
-    row = jnp.where(riding[:, None, None, None], ride, out[:, :, :1])
-    return lax.dynamic_update_slice(out, row, (0, 0, 0, 0))
+    out = _mla_window_launch(p, jnp.where(riding, 0, fed), q,
+                             w_kvb.astype(pool.dtype), dn, keys, sel, scale,
+                             interpret, blocks)
+    ride = _mla_launch(p, fed, q_ride, keys,
+                       None if sel is None else sel[:, :1], w_kvb.shape[-1],
+                       scale, interpret, "ride")
+    ride = _mm("bhsc,hvc->bshv", ride, w_kvb[:, dn:].astype(ride.dtype)) \
+        .reshape(B, 1, -1).astype(out.dtype)
+    row = jnp.where(riding[:, None, None], ride, out[:, :1])
+    return lax.dynamic_update_slice(out, row, (0, 0, 0))
 
 
 def _mla_pallas(attrs, inputs, aux, is_train, rng):
-    """The absorbed form (module docstring): one pass over the live
-    blocks of the pool for all heads."""
+    """One pass over the live blocks of the pool for all heads, in the
+    form the geometry pays least for (module docstring): absorbed at
+    S = 1 and for a window's riding slots, expanded for its chunks."""
     q_n, q_r, row, sel, w_kb, w_vb, p, new_cursor, scale = _mla_prologue(
         attrs, inputs, aux, is_train)
     interpret = _pk._interpret()
@@ -808,12 +1022,20 @@ def _mla_pallas(attrs, inputs, aux, is_train, rng):
              q_r.transpose(0, 2, 1, 3).astype(pool.dtype)], axis=-1),
             pool.shape[-1])
 
-    q = latent(q_n, q_r)
-    q_ride = latent(q_n[:, :1], q_r[:, :1]) if S > 1 else None
-    o_c = _mla_attend(p, new_cursor.reshape(-1) - p, q, q_ride, pool, sel,
-                      rank=rank, scale=scale, interpret=interpret)
-    out = _mm("bhsc,hvc->bshv", o_c.astype(dtype), w_vb.astype(dtype))
-    return [out.reshape(B, S, -1).astype(dtype)], [pool, new_cursor]
+    if S == 1:
+        q = latent(q_n, q_r)
+        o_c = _mla_attend(p, new_cursor.reshape(-1) - p, q, pool, sel,
+                          rank=rank, scale=scale, interpret=interpret)
+        out = _mm("bhsc,hvc->bshv", o_c.astype(dtype), w_vb.astype(dtype))
+        return [out.reshape(B, S, -1).astype(dtype)], [pool, new_cursor]
+    q = jnp.concatenate([q_n, q_r], axis=-1)
+    q = _lanes(q, -(-q.shape[-1] // 128) * 128).transpose(0, 2, 1, 3)
+    out = _mla_attend_window(
+        p, new_cursor.reshape(-1) - p, q.astype(pool.dtype),
+        latent(q_n[:, :1], q_r[:, :1]), pool, sel,
+        inputs[-1].reshape(H, -1, rank).astype(dtype), dn=q_n.shape[-1],
+        scale=scale, interpret=interpret, blocks=_WINDOW_BLOCKS)
+    return [out.astype(dtype)], [pool, new_cursor]
 
 
 def _mla_inputs(attrs):
@@ -834,14 +1056,21 @@ def _mla_eligible(attrs, in_shapes, in_dtypes):
 
 MLA_SLOT_STATE = {"latent": "rows", "cache_pos": "cursor"}
 
-#: one head's blocks at the published sizes (256 queries, latent rows
-#: of 576 in 640 lanes, a key block of 512, scores and accumulator);
-#: the riding form's blocks are the S = 1 program's (64 heads as rows, a
-#: key block of 2,048), which fit the same budget
+#: one head's blocks of the window form at GLM-5.2's widths, the widest
+#: published: the chunk's 1,024 queries of 192 + 64 and its values of
+#: 256 (both buffers), their accumulator and the running maximum and sum
+#: (a lane each, laid in tiles of 128), the head's ``[W_kb ; W_vb]``
+#: (both buffers), a key block of 1,024 latent rows of 576 in 640 lanes
+#: (both buffers) and its mask, the block expanded to k and v, and the
+#: scores of two query blocks with their exponentials. The kernel holds
+#: eight heads of the first five at once, inside ``_VMEM_LIMIT``; the
+#: S = 1 and riding forms' blocks (64 heads as rows, a key block of
+#: 2,048) are smaller than one head's here
 _MLA_KSPEC = {
-    "tiles": [((256, 640), "bfloat16")] * 2
-    + [((512, 640), "bfloat16")] * 2 + [((256, 512), "bfloat16")] * 2
-    + [((256, 512), "float32")] * 5,
+    "tiles": [((1024, 256), "bfloat16")] * 4 + [((1024, 256), "float32")]
+    + [((1024, 128), "float32")] * 2 + [((448, 512), "bfloat16")] * 2
+    + [((1024, 640), "bfloat16")] * 2 + [((1024, 1024), "int8")] * 2
+    + [((1024, 256), "bfloat16")] * 2 + [((512, 1024), "float32")] * 2,
     "dtypes": ("float32", "bfloat16"),
 }
 

@@ -281,11 +281,12 @@ _YARN_ATTRS = dict(rope_factor=8.0, rope_original_positions=16,
 @pytest.mark.parametrize("S", [1, 16], ids=["decode", "window"])
 def test_the_unselected_kernel_equals_the_composition_and_an_all_ones_selection(
         S, dtype):
-    """``mla_attention_decode(selected=False)``: the absorbed path
-    through the kernel that reads no mask equals the expanded
-    composition (in bfloat16 within the rounding of q W_kb and of the
-    latent sum), and both equal the selected op under a selection of
-    every position at or before the query."""
+    """``mla_attention_decode(selected=False)``: the kernels that read
+    no mask - absorbed at S = 1, in the expanded widths in a window -
+    equal the expanded composition (in bfloat16 within the rounding of
+    q W_kb and of the latent sum, or of the softmax's weights), and
+    both equal the selected op under a selection of every position at
+    or before the query."""
     op = get_op("mla_attention_decode")
     dense = op.normalize_attrs(dict(_GEOMETRY, selected=False,
                                     **_YARN_ATTRS))
@@ -330,6 +331,21 @@ def test_a_window_attends_a_slot_fed_one_row_as_the_s1_program_does(case):
     riding = mla_window_cases.check(case, selected=False, **_YARN_ATTRS)
     assert len(riding) == {"mixed": 3, "all_riding": 6,
                            "none_riding": 0}[case]
+
+
+@pytest.mark.parametrize("case", sorted(mla_window_cases.WINDOW_CASES))
+def test_the_window_form_attends_in_the_expanded_widths(case):
+    """No selection, YaRN: ``mla_attn_window`` - a key block expanded
+    once a head, every query block of the chunk scored against it -
+    equals the expanded composition at every fed position
+    (``mla_window_cases.WINDOW_CASES``): several query blocks against
+    tiny key blocks with cursors on a block's last and first row, a slot
+    fed 2 rows beside one fed all of them, dead slots around the live
+    ones, unequal ``nope_dim`` and ``v_dim``."""
+    fed, blocks, geometry = mla_window_cases.WINDOW_CASES[case]
+    mla_window_cases.check_window(
+        fed, False, blocks, dict(mla_window_cases._GEOMETRY, **geometry),
+        **_YARN_ATTRS)
 
 
 def test_yarn_at_the_published_values():

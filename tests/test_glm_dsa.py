@@ -254,8 +254,9 @@ def _op_inputs(S, dtype, seed=0):
 def test_absorbed_kernels_equal_the_expanded_composition(S, dtype):
     """``dsa_index_select``: the kernels choose the composition's set,
     write its rows and move its cursor. ``mla_attention_decode``: the
-    absorbed path through the kernel equals the expanded composition
-    (in bfloat16 within the rounding of q W_kb and of the latent sum)."""
+    kernels - absorbed at S = 1, in the expanded widths in a window -
+    equal the expanded composition (in bfloat16 within the rounding of
+    q W_kb and of the latent sum, or of the softmax's weights)."""
     index, attend = get_op("dsa_index_select"), \
         get_op("mla_attention_decode")
     ia = index.normalize_attrs(dict(
@@ -299,96 +300,19 @@ def test_a_window_attends_a_slot_fed_one_row_as_the_s1_program_does(case):
                            "none_riding": 0}[case]
 
 
-def test_a_shared_layer_attends_the_set_its_full_layer_chose():
-    """The graph hands layer 1's selection to layer 2: one
-    ``dsa_index_select`` output feeds both ``mla_attention_decode``
-    nodes, and layer 2 has no indexer parameters and no index pool."""
-    symbol = _symbol(4)
-    consumers = {}
-    for node in symbol._topo_nodes():
-        if not node.is_variable and node.op == "mla_attention_decode":
-            consumers[node.name] = node.inputs[2][0].name
-    assert consumers == {"lm_l0_attn": "lm_l0_idx", "lm_l1_attn": "lm_l1_idx",
-                         "lm_l2_attn": "lm_l1_idx"}
-    assert not [n for n in symbol.list_arguments() if n.startswith("lm_l2_idx")]
-    assert "lm_l2_idx_index_k" not in symbol.list_auxiliary_states()
-    # three attention layers under the selections of two indexers
-    stateful = [(node.op, node.attrs.get("topk"))
-                for node, _opdef, _cells in tfm._stateful_nodes(symbol)
-                if node.op != "MoEFFN"]
-    assert stateful == [("dsa_index_select", 16),
-                        ("mla_attention_decode", None)] * 2 \
-        + [("mla_attention_decode", None)]
-    # and the ops of a graph without a selection count nothing under one
-    assert not [count for _node, opdef, _cells in tfm._stateful_nodes(
-        tfm.get_decode_symbol(per_slot=True))
-        for count in opdef.state_reads[0] if count.startswith("dsa.")]
-
-
-# ----------------------------------------------------------- the two ops
-def _op_inputs(S, dtype, seed=0):
-    rs = np.random.RandomState(seed)
-    B, H, dn, dr, dv, rank = 2, 4, 24, 16, 32, 64
-    Hi, d = 16, 32
-    f = lambda *s: jnp.asarray(rs.randn(*s), dtype)      # noqa: E731
-    fed = jnp.asarray([S, max(S - 1, 1)], jnp.int32)
-    cur = jnp.asarray([[40], [7]], jnp.int32)
-    idx = ([f(B, S, Hi * d), f(B, S, d), f(B, S, Hi), fed],
-           [f(B, 1, CAPACITY, d), cur])
-    att = ([f(B, S, H * (dn + dr)), f(B, S, rank + dr), None, fed,
-            jnp.ones((rank,), dtype), f(H * (dn + dv), rank) * 0.2],
-           [f(B, 1, CAPACITY, mla.latent_width(rank, dr)), cur])
-    return idx, att
-
-
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("S", [1, 16], ids=["decode", "window"])
-def test_absorbed_kernels_equal_the_expanded_composition(S, dtype):
-    """``dsa_index_select``: the kernels choose the composition's set,
-    write its rows and move its cursor. ``mla_attention_decode``: the
-    absorbed path through the kernel equals the expanded composition
-    (in bfloat16 within the rounding of q W_kb and of the latent sum)."""
-    index, attend = get_op("dsa_index_select"), \
-        get_op("mla_attention_decode")
-    ia = index.normalize_attrs(dict(
-        capacity=CAPACITY, n_heads=16, head_dim=32, rope_dim=16, topk=16,
-        rope_base=8e6))
-    aa = attend.normalize_attrs(dict(
-        capacity=CAPACITY, n_heads=4, nope_dim=24, rope_dim=16, v_dim=32,
-        kv_rank=64, rope_base=8e6))
-    (i_in, i_aux), (a_in, a_aux) = _op_inputs(S, jnp.dtype(dtype))
-    sel, aux = index.variant_fn("xla")(ia, i_in, i_aux, False, None)
-    sel_k, aux_k = index.variant_fn("pallas")(ia, i_in, i_aux, False, None)
-    assert sel[0].dtype == jnp.int8 and sel[0].shape == (2, S, CAPACITY)
-    np.testing.assert_array_equal(np.asarray(sel[0]), np.asarray(sel_k[0]))
-    for a, b in zip(aux, aux_k):
-        np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                      np.asarray(b, np.float32))
-    kept = np.asarray(sel[0]).sum(-1)
-    assert kept[0].max() == 16 and kept[1, 0] == 8      # t = 7: all 8
-    assert list(np.asarray(aux[1]).ravel()) == [40 + S, 7 + max(S - 1, 1)]
-    a_in[2] = sel[0]
-    out, aux = attend.variant_fn("xla")(aa, a_in, a_aux, False, None)
-    out_k, aux_k = attend.variant_fn("pallas")(aa, a_in, a_aux, False, None)
-    tol = 1e-5 if dtype == "float32" else 0.04
-    np.testing.assert_allclose(np.asarray(out[0], np.float32),
-                               np.asarray(out_k[0], np.float32),
-                               atol=tol, rtol=tol)
-    np.testing.assert_array_equal(np.asarray(aux[0], np.float32),
-                                  np.asarray(aux_k[0], np.float32))
-
-
-@pytest.mark.parametrize("case", sorted(mla_window_cases.CASES))
-def test_a_window_attends_a_slot_fed_one_row_as_the_s1_program_does(case):
-    """Under a selection: a window whose slots are fed a whole window,
-    one row, none and a ragged few (``tests/mla_window_cases.py``)
-    equals the expanded form at every fed position; the row of a slot
-    fed one - ``mla_attn_ride`` under row 0 of the selection - is the
-    S = 1 dispatch's to the bit; a window in which every slot rides and
-    one in which none does."""
-    riding = mla_window_cases.check(case, selected=True, rope_base=8e6)
-    assert len(riding) == {"mixed": 3, "all_riding": 6,
-                           "none_riding": 0}[case]
+@pytest.mark.parametrize("case", sorted(mla_window_cases.WINDOW_CASES))
+def test_the_window_form_attends_in_the_expanded_widths(case):
+    """Under a selection: ``mla_attn_window`` - a key block expanded
+    once a head, every query block of the chunk scored against it under
+    its rows of the mask - equals the expanded composition at every fed
+    position (``mla_window_cases.WINDOW_CASES``): several query blocks
+    against tiny key blocks, a slot fed 2 rows beside one fed all of
+    them, dead slots around the live ones, GLM-5.2's unequal
+    ``nope_dim`` and ``v_dim``."""
+    fed, blocks, geometry = mla_window_cases.WINDOW_CASES[case]
+    mla_window_cases.check_window(
+        fed, True, blocks, dict(mla_window_cases._GEOMETRY, **geometry),
+        rope_base=8e6)
 
 
 def test_a_shared_layer_attends_the_set_its_full_layer_chose():

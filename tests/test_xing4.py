@@ -25,6 +25,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import mla_window_cases  # noqa: E402
 from chipbench.reference import xing4 as ref  # noqa: E402
 # the quick cases of the benchmark's own tests of the architecture file
 # run here as they stand (its CPU rehearsals stay by hand)
@@ -186,6 +187,19 @@ def test_windows_packed_windows_with_riders_then_decode_equal_the_reference(
     assert driver.read_counts["mhc.rows"] == ("mhc.rows", "mhc_rows")
     assert sorted(driver._state) == ["cursor", "rows"]
     assert driver.positional and driver.feeds and driver.routed
+
+
+@pytest.mark.parametrize("case", sorted(mla_window_cases.WINDOW_CASES))
+def test_the_latent_window_form_attends_in_the_expanded_widths(case):
+    """Xing4.0's latent attention (no selection, YaRN as the fixture
+    scales it) in a window: ``mla_attn_window`` equals the expanded
+    composition at every fed position, case by case
+    (``mla_window_cases.WINDOW_CASES``)."""
+    fed, blocks, geometry = mla_window_cases.WINDOW_CASES[case]
+    mla_window_cases.check_window(
+        fed, False, blocks, dict(mla_window_cases._GEOMETRY, **geometry),
+        rope_base=float(CFG["rope_theta"]),
+        **tfm._yarn_rope({"rope_scaling": YARN}, "xing4"))
 
 
 def test_a_mapping_rounded_to_bfloat16_misses_the_tolerance():
