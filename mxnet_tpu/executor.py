@@ -44,6 +44,7 @@ from . import kernel_tier as _kernel_tier
 from . import program_cache as _progcache
 from . import random as _random
 from . import telemetry as _telemetry
+from .telemetry import optable as _optable
 
 __all__ = ["Executor", "naive_engine_active"]
 
@@ -145,7 +146,9 @@ def _build_graph_runner(symbol, shape_overrides=None, tap=None, mp_plan=None,
     Every op executes under ``jax.named_scope(node.name)``, so compiled
     HLO instructions carry Symbol node names into xplane/profiler traces —
     the analog of the reference's PROFILER_MESSAGE per-op naming
-    (threaded_engine.h:296-307).
+    (threaded_engine.h:296-307). ``telemetry/optable.py`` reads them back
+    out of the compiled text: ``mx.profiler.operator_table`` is a
+    program's device time by these names.
     """
     nodes = symbol._topo_nodes()
     node_index = {id(n): i for i, n in enumerate(nodes)}
@@ -214,15 +217,6 @@ def _build_graph_runner(symbol, shape_overrides=None, tap=None, mp_plan=None,
         # real per-op wall time, the reference's per-op profile records.
         if _telemetry.enabled():
             _telemetry.counter("executor.op_dispatch", op=node.op).inc()
-            # cost attribution rides the same trace-time hook: per-op
-            # FLOPs/bytes totals for one program execution accumulate
-            # under the op label (telemetry/mfu.py reads them back)
-            op_cost = opdef.cost(attrs, [tuple(v.shape) for v in regular])
-            if op_cost is not None:
-                _telemetry.counter("executor.op_flops",
-                                   op=node.op).inc(op_cost[0])
-                _telemetry.counter("executor.op_bytes",
-                                   op=node.op).inc(op_cost[1])
             op_span = _telemetry.span("op." + node.op, node=node.name)
         else:
             op_span = _telemetry.null_span
@@ -522,13 +516,11 @@ class Executor:
 
         # bind-time static analysis (the NNVM InferShape/InferType
         # discipline, analysis/): validate="warn"|"raise" per call, or
-        # process-wide via MXNET_GRAPH_VALIDATE. The span keeps the
-        # overhead visible.
+        # process-wide via MXNET_GRAPH_VALIDATE.
         from . import analysis as _analysis
         vmode = _analysis.resolve_mode(validate)
         if vmode is not None:
-            with _telemetry.span("executor.validate"):
-                _analysis.validate_executor(self, vmode)
+            _analysis.validate_executor(self, vmode)
 
     # ------------------------------------------------------------ normalize
     def _normalize_args(self, args, names, what, allow_none=False):
@@ -706,6 +698,8 @@ class Executor:
                     if _telemetry.enabled():
                         _telemetry.counter("executor.jit_cache.hit").inc()
                     self._jit_cache[cache_key] = fn
+                    _optable.register_program(self.program_name(kind),
+                                              self, kind)
                     return fn
         if _telemetry.enabled():
             _telemetry.counter("executor.jit_cache.miss").inc()
@@ -755,7 +749,37 @@ class Executor:
         if gkey is not None:
             _progcache.put(gkey, fn)
         self._jit_cache[cache_key] = fn
+        if not naive:
+            _optable.register_program(self.program_name(kind), self, kind)
         return fn
+
+    def _default_heads(self, arg_vals):
+        """Head gradients where the caller gives none: ones for loss
+        heads (their custom_vjp ignores the value), zeros for data
+        heads -> no spurious gradient."""
+        outs_struct = jax.eval_shape(
+            lambda a, x, r: self._runner(a, x, True, r)[0],
+            arg_vals, self._aux_vals(), jax.random.PRNGKey(0))
+        return [jnp.ones(o.shape, o.dtype) if is_loss
+                else jnp.zeros(o.shape, o.dtype)
+                for o, is_loss in zip(outs_struct, self._loss_mask)]
+
+    def lower_program(self, kind):
+        """``jax.stages.Lowered`` of this binding's program ``kind``
+        (``fwd_infer``, ``fwd_train``, ``fwd_bwd``) at the bound cells'
+        shapes and placements - for the compiled text
+        (``telemetry/optable.py``'s op index) and for cost and memory
+        analysis. Nothing runs and no cell changes."""
+        prog = self._get_program(kind)
+        if not hasattr(prog, "lower"):
+            raise MXNetError("a NaiveEngine program is not compiled: "
+                             "there is nothing to lower")
+        arg_vals = self._arg_vals()
+        rng = self._pending[1] if self._pending else _unread_key()
+        args = [arg_vals, self._aux_vals(), rng]
+        if kind == "fwd_bwd":
+            args.append(self._default_heads(arg_vals))
+        return prog.lower(*args)
 
     # -------------------------------------------------------------- forward
     def forward(self, is_train=False, **kwargs):
@@ -901,14 +925,7 @@ class Executor:
         arg_vals = self._arg_vals()
         out_shapes = None
         if heads is None:
-            # ones for loss heads (their custom_vjp ignores the value),
-            # zeros for data heads -> no spurious gradient
-            outs_struct = jax.eval_shape(
-                lambda a, x, r: self._runner(a, x, True, r)[0],
-                arg_vals, self._aux_vals(), jax.random.PRNGKey(0))
-            heads = [jnp.ones(o.shape, o.dtype) if is_loss
-                     else jnp.zeros(o.shape, o.dtype)
-                     for o, is_loss in zip(outs_struct, self._loss_mask)]
+            heads = self._default_heads(arg_vals)
         else:
             heads = [h.asjax() if isinstance(h, NDArray) else jnp.asarray(h)
                      for h in heads]

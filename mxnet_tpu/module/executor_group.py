@@ -530,61 +530,68 @@ class DataParallelExecutorGroup:
                      for o, is_loss in zip(outs, loss_mask)]
             (grads,) = vjp_fn(heads)
             new_w, new_states = {}, {}
-            for i, nm in enumerate(watched):
-                g = grads[nm].astype(w[nm].dtype)
-                if spmd_plan is not None:
-                    # spec-driven: the plan's PartitionSpecs pin the
-                    # gradient (the psum/reduce-scatter XLA emits), the
-                    # update layout, and the new weights (donation needs
-                    # input sharding == output sharding)
-                    if spmd_plan.zero:
-                        nw, ns = _zero_mod.apply_spec_update(
-                            update, w[nm], g, states[nm],
-                            lr_arr[i], wd_arr[i], spmd_plan.mesh,
-                            spmd_plan.state_spec(nm),
-                            out_spec=spmd_plan.param_spec(nm))
-                    else:
-                        p_sh = spmd_plan.param_sharding(nm)
-                        g = jax.lax.with_sharding_constraint(g, p_sh)
+            # the optimizer's part of the program, under a scope of its
+            # own: a named scope exists at trace time only, and
+            # ``profiler.operator_table`` reads the phase ``update``
+            # from it
+            with jax.named_scope("update"):
+                for i, nm in enumerate(watched):
+                    g = grads[nm].astype(w[nm].dtype)
+                    if spmd_plan is not None:
+                        # spec-driven: the plan's PartitionSpecs pin the
+                        # gradient (the psum/reduce-scatter XLA emits), the
+                        # update layout, and the new weights (donation needs
+                        # input sharding == output sharding)
+                        if spmd_plan.zero:
+                            nw, ns = _zero_mod.apply_spec_update(
+                                update, w[nm], g, states[nm],
+                                lr_arr[i], wd_arr[i], spmd_plan.mesh,
+                                spmd_plan.state_spec(nm),
+                                out_spec=spmd_plan.param_spec(nm))
+                        else:
+                            p_sh = spmd_plan.param_sharding(nm)
+                            g = jax.lax.with_sharding_constraint(g, p_sh)
+                            nw, ns = update(w[nm], g, states[nm],
+                                            lr_arr[i], wd_arr[i])
+                            nw = jax.lax.with_sharding_constraint(nw, p_sh)
+                            ns = jax.tree.map(
+                                lambda x: jax.lax.with_sharding_constraint(
+                                    x, p_sh) if x.shape == nw.shape else x,
+                                ns)
+                    elif zero_plan is None:
                         nw, ns = update(w[nm], g, states[nm],
                                         lr_arr[i], wd_arr[i])
-                        nw = jax.lax.with_sharding_constraint(nw, p_sh)
-                        ns = jax.tree.map(
-                            lambda x: jax.lax.with_sharding_constraint(
-                                x, p_sh) if x.shape == nw.shape else x,
-                            ns)
-                elif zero_plan is None:
-                    nw, ns = update(w[nm], g, states[nm],
-                                    lr_arr[i], wd_arr[i])
-                else:
-                    nw, ns = zero_plan.apply(update, w[nm], g,
-                                             states[nm],
-                                             lr_arr[i], wd_arr[i])
-                new_w[nm] = nw
-                new_states[nm] = ns
+                    else:
+                        nw, ns = zero_plan.apply(update, w[nm], g,
+                                                 states[nm],
+                                                 lr_arr[i], wd_arr[i])
+                    new_w[nm] = nw
+                    new_states[nm] = ns
             # top-1 correct counts per (label, output) pair, computed
             # inside the program: the Accuracy metric then costs zero
             # extra dispatches per batch (its own device-side argmax
             # was one more dispatch and round trip)
             mets = []
-            for i, nm in metric_pairs:
-                if i >= len(outs):
-                    break
-                o, lab = outs[i], rest[nm]
-                if o.ndim > 1 and o.shape != lab.shape:
-                    # classification semantics only: prediction classes
-                    # must align 1:1 with label elements after argmax
-                    # (detection-style structured labels skip the
-                    # in-step count and take the general metric path)
-                    if int(np.prod(o.shape[:-1])) != lab.size:
+            with jax.named_scope("metric"):
+                for i, nm in metric_pairs:
+                    if i >= len(outs):
                         break
-                    p = jnp.argmax(o, axis=-1)
-                elif o.shape == lab.shape:
-                    p = o
-                else:
-                    break
-                l = lab.astype(jnp.int32).ravel()
-                mets.append(jnp.sum(p.astype(jnp.int32).ravel() == l))
+                    o, lab = outs[i], rest[nm]
+                    if o.ndim > 1 and o.shape != lab.shape:
+                        # classification semantics only: prediction
+                        # classes must align 1:1 with label elements
+                        # after argmax (detection-style structured
+                        # labels skip the in-step count and take the
+                        # general metric path)
+                        if int(np.prod(o.shape[:-1])) != lab.size:
+                            break
+                        p = jnp.argmax(o, axis=-1)
+                    elif o.shape == lab.shape:
+                        p = o
+                    else:
+                        break
+                    l = lab.astype(jnp.int32).ravel()
+                    mets.append(jnp.sum(p.astype(jnp.int32).ravel() == l))
             health = None
             if health_armed:
                 f32 = jnp.float32
@@ -701,9 +708,13 @@ class DataParallelExecutorGroup:
                 jax.jit(prog_fn, donate_argnums=donate), "fused_step")
             if self._fused_cache_key is not None:
                 _progcache.put(self._fused_cache_key, self._fused_prog)
+        _telemetry.optable.register_program(
+            exe.program_name("fused_step"), self, "fused_step")
         self._scan_prog = None      # K-step lax.scan program (lazy)
         self._scan_K = 0
         self._scan_failed = False
+        self._attr_prev = None      # armed step attribution: a result of
+                                    # the dispatch before, waited on next
         self._scan_results = collections.deque()
         self._scan_lrwd = (None, None, None)
         self._fused_watched = watched
@@ -783,6 +794,34 @@ class DataParallelExecutorGroup:
         return self._fused_prog.lower(w, rest, exe._aux_vals(),
                                       self._fused_key, self._fused_states,
                                       hyper, hyper)
+
+    def lower_scan_step(self):
+        """``jax.stages.Lowered`` of the armed K-step scan program: the
+        window's stacked inputs enter as shapes with the placement
+        ``_place_stacked`` gives them, nothing is allocated."""
+        exe = self.executor
+        K = self._scan_K
+        arg_vals = exe._arg_vals()
+        w = {nm: arg_vals.pop(nm) for nm in self._fused_watched}
+        xs_in = {}
+        for nm in list(self.data_names) + list(self.label_names):
+            if nm in arg_vals:
+                cell = arg_vals.pop(nm)
+                shape = (K,) + tuple(cell.shape)
+                xs_in[nm] = jax.ShapeDtypeStruct(
+                    shape, cell.dtype,
+                    sharding=self._stacked_placement(shape))
+        hyper = jnp.zeros((K, len(self._fused_watched)), jnp.float32)
+        return self._scan_prog.lower(
+            w, self._fused_states, self._fused_key, exe._aux_vals(),
+            arg_vals, {"in": xs_in, "lr": hyper, "wd": hyper})
+
+    def lower_program(self, kind):
+        """The registry's way back to a step program
+        (``telemetry/optable.py``): ``fused_step`` or ``scan_step``."""
+        if kind == "scan_step":
+            return self.lower_scan_step()
+        return self.lower_fused_step()
 
     def fused_memory_report(self):
         """Byte accounting of the armed fused step under the active
@@ -952,6 +991,21 @@ class DataParallelExecutorGroup:
         self._fused_key = self._place(jnp.asarray(np.asarray(key)), "param")
         self._fused_rng_gen = _random.generation()
 
+    def _wait_for_step_before(self, result):
+        """Armed step attribution's ``device`` wait: block until the
+        dispatch BEFORE the one just made has finished, and keep
+        ``result`` - an in-step metric scalar or an output of the new
+        dispatch, which no later step takes over as the donated
+        parameters and states are - to wait on next time. All of a
+        program's results are ready when it ends and step n cannot end
+        before step n-1, so the wait is device time on the host's
+        clock, completion to completion, and the chip always has the
+        next step queued, as in a run nobody measures. The first armed
+        step waits on nothing."""
+        prev, self._attr_prev = self._attr_prev, result
+        if prev is not None:
+            jax.block_until_ready(prev)
+
     def fused_step(self, data_batch, lrs, wds):
         """Run one fused train step; swap new params/state/outputs in
         (gradients are emitted and written back only under
@@ -959,9 +1013,9 @@ class DataParallelExecutorGroup:
 
         Step attribution (telemetry/stepattr.py, armed fit loops only):
         host batch staging counts as ``assemble``, the async program
-        call as ``dispatch``, and — every single step being its own
-        window boundary — a block-until-ready on the advanced params as
-        ``device``."""
+        call as ``dispatch``, and the wait for the step BEFORE this one
+        to finish (``_wait_for_step_before``) as ``device``: the loop
+        stays one dispatch ahead of the chip, as it is unarmed."""
         from .. import random as _random
         _sa = _telemetry.stepattr
         sa_on = _sa.active()
@@ -1000,7 +1054,7 @@ class DataParallelExecutorGroup:
         if sa_on:
             sa_t2 = _sa.clock()
             _sa.note("dispatch", sa_t2 - sa_t1)
-            jax.block_until_ready(new_w)
+            self._wait_for_step_before(mets[0] if mets else outs[0])
             _sa.note("device", _sa.clock() - sa_t2)
         self._fused_states = new_states
         self._fused_metric_scalars = [
@@ -1092,6 +1146,7 @@ class DataParallelExecutorGroup:
                 if _telemetry.enabled():
                     _telemetry.counter("executor.jit_cache.hit").inc()
                 self._scan_prog, self._scan_K = fn, K
+                self._register_scan(K)
                 return
         if _telemetry.enabled():
             _telemetry.counter("executor.jit_cache.miss").inc()
@@ -1108,17 +1163,26 @@ class DataParallelExecutorGroup:
         if gkey is not None:
             _progcache.put(gkey, fn)
         self._scan_prog, self._scan_K = fn, K
+        self._register_scan(K)
 
-    def _place_stacked(self, arr):
-        """Device-place a (K, batch, ...) stacked array: the scan axis
+    def _register_scan(self, K):
+        _telemetry.optable.register_program(
+            self.executor.program_name(f"scan{K}_step"), self,
+            "scan_step", steps=K)
+
+    def _stacked_placement(self, shape):
+        """Where a (K, batch, ...) stacked array goes: the scan axis
         stays unsharded, the batch axis shards over the mesh."""
         if self._mesh is None:
-            return jax.device_put(arr, self.contexts[0].jax_device())
+            return jax.sharding.SingleDeviceSharding(
+                self.contexts[0].jax_device())
         if self._spmd_plan is not None:
-            return jax.device_put(
-                arr, self._spmd_plan.data_sharding_for(arr.shape,
-                                                       stacked=True))
-        return jax.device_put(arr, self._stacked_sharding)
+            return self._spmd_plan.data_sharding_for(shape, stacked=True)
+        return self._stacked_sharding
+
+    def _place_stacked(self, arr):
+        """Device-place a (K, batch, ...) stacked array."""
+        return jax.device_put(arr, self._stacked_placement(arr.shape))
 
     def _stack_window(self, window, K):
         """Per-step input dict {name: (K, batch, ...)} + per-step label
@@ -1204,10 +1268,8 @@ class DataParallelExecutorGroup:
         if sa_on:
             sa_t2 = _sa.clock()
             _sa.note("dispatch", sa_t2 - sa_t1)
-            # the window boundary IS the step-attribution sync point:
-            # one block per K batches, so the scan fast path keeps its
-            # async pipeline shape while device time still attributes
-            jax.block_until_ready(new_w)
+            # one wait per K batches, for the window before this one
+            self._wait_for_step_before(mets_s[0] if mets_s else outs_s[0])
             _sa.note("device", _sa.clock() - sa_t2)
         self._fused_states = new_states
         ad = exe.arg_dict

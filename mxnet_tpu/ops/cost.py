@@ -2,9 +2,9 @@
 
 Every ``OpDef`` may carry ``flops(attrs, in_shapes)`` and
 ``bytes_moved(attrs, in_shapes)`` estimators for ONE forward execution
-(telemetry/mfu.py turns them into per-op roofline positions and a
-model-level MFU figure; the executor mirrors them into the
-``executor.op_flops``/``executor.op_bytes`` counters at trace time).
+(telemetry/mfu.py turns them into per-op roofline positions, a
+model-level MFU figure and, one node at a time, the costs that
+``mx.profiler.operator_table`` sets against measured device time).
 This module attaches estimators to every op that matters for the
 flagship workloads — the convolution/dense/batchnorm/softmax/optimizer
 set that dominates ResNet-50 and LSTM step time — plus blanket

@@ -12,6 +12,15 @@ surfaced by MXDumpProfile. Two trace sources serve that contract here:
   viewable in TensorBoard/Perfetto — strictly richer than the
   reference's records at the op level.
 
+The reference's **per-operator records** are the join of the two:
+``operator_table()`` sums the xplane trace's device operations by the
+Symbol node that each compiled instruction came from (the executor's
+per-node named scopes, read back out of the compiled text;
+telemetry/optable.py), with phase (forward, backward, update, ...),
+FLOPs, bytes and roofline share a row, and ``dump_profile()`` writes
+it under ``otherData.operators`` of the JSON whenever a JAX trace was
+taken.
+
 API kept: profiler_set_config, profiler_set_state, dump_profile.
 ``profiler_set_state("run")`` turns the telemetry tracer on (so spans
 from every instrumented layer start recording) and starts a JAX trace;
@@ -71,6 +80,21 @@ def profiler_set_state(state="stop"):
         raise ValueError("state must be 'run' or 'stop'")
 
 
+def operator_table(events=None, trace_dir=None, device_kind=None):
+    """Device time by Symbol node, phase and cost of every program of a
+    live binding that ran in a profiler trace: ``events`` (flat events
+    ``{"plane", "line", "name", "start_ns", "dur_ns"}``), else the
+    newest trace under ``trace_dir``, else the last trace
+    ``profiler_set_state`` took. ``telemetry.optable.operator_table``
+    has the rows' fields; docs/telemetry.md, "Operator table", a worked
+    reading."""
+    from .telemetry import optable
+    if events is None and trace_dir is None and _STATE["jax_trace"]:
+        trace_dir = _STATE["trace_dir"]
+    return optable.operator_table(events=events, trace_dir=trace_dir,
+                                  device_kind=device_kind)
+
+
 def dump_profile():
     """Serialize collected spans to chrome://tracing JSON at the
     configured filename and return that path (reference: MXDumpProfile).
@@ -84,7 +108,9 @@ def dump_profile():
     Always returns the written file's path — including when no trace was
     ever started (the file then just carries an empty/partial span set),
     never a silent None. The JAX xplane trace dir (when one ran) is
-    recorded in the JSON's ``otherData.jax_trace_dir``.
+    recorded in the JSON's ``otherData.jax_trace_dir``, and the
+    operator table of that trace (``operator_table()``) in
+    ``otherData.operators``.
     """
     if _STATE["running"]:
         profiler_set_state("stop")
@@ -96,4 +122,11 @@ def dump_profile():
     meta = {"mode": _STATE["mode"]}
     if _STATE["trace_dir"]:
         meta["jax_trace_dir"] = os.path.abspath(_STATE["trace_dir"])
+        if _STATE["jax_trace"]:
+            try:
+                meta["operators"] = operator_table()
+            except Exception as exc:    # the dump is written all the same
+                logging.warning("operator table unavailable (%r)", exc,
+                                exc_info=True)
+                meta["operators"] = {"error": repr(exc)}
     return telemetry.chrome_trace.dump(path, metadata=meta)

@@ -52,6 +52,7 @@ from . import fleet
 from . import flightrec
 from . import memory
 from . import mfu
+from . import optable
 from . import sentinel
 from . import trace
 from . import stepattr
@@ -66,7 +67,8 @@ __all__ = ["span", "event", "record_event", "enable", "disable", "enabled",
            "clear", "get_spans", "get_events", "null_span", "wrap_dispatch",
            "Counter", "Gauge", "Histogram", "counter", "gauge", "histogram",
            "get_metric", "snapshot", "reset", "NanSentinel", "AnomalyError",
-           "fleet", "flightrec", "memory", "mfu", "sentinel", "trace",
+           "fleet", "flightrec", "memory", "mfu", "optable", "sentinel",
+           "trace",
            "stepattr", "health", "chrome_trace", "prometheus", "jsonl",
            "opsd", "serve_ops"]
 

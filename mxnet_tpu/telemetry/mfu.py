@@ -114,6 +114,10 @@ def cost_table(symbol, shapes, train=True):
 
     ``per_op``        op -> {flops, bytes, train_flops, train_bytes,
                              nodes}
+    ``per_node``      node name -> {op, flops, bytes, train_flops,
+                      train_bytes, in_shapes}: the same estimators one
+                      node at a time (``profiler.operator_table`` joins
+                      them to the device trace)
     ``flops/bytes``   forward totals; ``train_flops/train_bytes`` with
                       the backward multiplier applied
     ``uncovered``     op names with nodes in this graph but no metadata
@@ -125,6 +129,7 @@ def cost_table(symbol, shapes, train=True):
     entry_shapes = symbol._infer_entry_shapes(known)
 
     per_op = {}
+    per_node = {}
     uncovered = {}
     covered = 0
     compute = 0
@@ -163,10 +168,15 @@ def cost_table(symbol, shapes, train=True):
         rec["train_flops"] += cost[0] * f
         rec["train_bytes"] += cost[1] * f
         rec["nodes"] += 1
+        per_node[node.name] = {
+            "op": node.op, "flops": cost[0], "bytes": cost[1],
+            "train_flops": cost[0] * f, "train_bytes": cost[1] * f,
+            "in_shapes": in_shapes}
 
     key = "train_flops" if train else "flops"
     return {
         "per_op": per_op,
+        "per_node": per_node,
         "flops": sum(r["flops"] for r in per_op.values()),
         "bytes": sum(r["bytes"] for r in per_op.values()),
         "train_flops": sum(r["train_flops"] for r in per_op.values()),

@@ -11,10 +11,10 @@ compute. This module splits every ``Module.fit`` step into phases:
 ``assemble``    host-side batch staging: ``_load_batch`` /
                 ``_stack_window`` + lr/wd and arg-dict preparation
 ``dispatch``    the jitted program call (async — returns at submit)
-``device``      block-until-ready delta, measured at *window
-                boundaries only* so the K-step scan fast path is not
-                de-async'd (one block per K batches; K=1 blocks per
-                step, which is what attribution means there)
+``device``      the wait for the chip: behind each dispatch (one per
+                K batches on the scan path) the loop blocks until the
+                dispatch BEFORE it has finished, so the chip always
+                has the next step queued, as in a run nobody measures
 ``other``       the remainder of the step wall (metric update,
                 callbacks, Python loop) — kept explicit so the phases
                 always sum to the measured wall time
@@ -30,10 +30,19 @@ straggler``) so a stall names its phase, not just its existence.
 
 Arming: follows the telemetry switch (``telemetry.enable()``), or force
 with ``MXNET_STEP_ATTRIBUTION=1`` / off with ``=0`` independent of the
-tracer. Disabled cost is one module-attr read + branch per site; armed
-it blocks at every window boundary, each step at K=1 (the traced fit
-cells arm it: 1,913.9 against 2,380.9 samples/s on one chip, PERF.md
-section 5, PR 26).
+tracer. Disabled cost is one module-attr read + branch per site.
+
+``device`` is a lagged wait (``executor_group._wait_for_step_before``):
+the parameters and optimizer states a dispatch returns are donated to
+the next one, so the loop holds a metric scalar or an output of each
+dispatch and waits on it behind the NEXT dispatch. Step n cannot end
+before step n-1: the wait is device time on the host's clock,
+completion to completion. It reads "the loop waited this long for the
+chip" - in a chip-bound loop the step's device time less the host's
+own work, in a host-bound loop zero - and the first armed step waits
+on nothing. An armed loop therefore runs one dispatch ahead of the
+chip exactly as an unarmed one does; until PR 50 it drained the chip
+after every dispatch and a traced run measured a loop nobody runs.
 
 The clock is injectable (``use_clock``) so deterministic tests can
 script exact phase durations.
