@@ -1298,25 +1298,57 @@ def packed_rows(slots, step_len):
     return rows if whole <= 128 else max(rows, min(ridge_rows(), whole))
 
 
+def _head_entry(out):
+    """Where the head of a graph whose output node is ``out`` starts:
+    ``(node, k)``, the stream the ``k``-th input of ``node``. From the
+    output back through every node that reads ONE computed value - the
+    head's own: the copies' sum, the final norm, the fold, the product,
+    its scale or slice, each beside parameters or ``fed`` alone and each
+    a row's own affair - to the first that reads what a node of several
+    computed inputs made: the last layer's join."""
+    node = out
+    while True:
+        (k,) = [i for i, (src, _) in enumerate(node.inputs)
+                if not src.is_variable]
+        src = node.inputs[k][0]
+        if sum(not s.is_variable for s, _ in src.inputs) != 1:
+            return node, k
+        node = src
+
+
 def packed_window(symbol, slots):
     """``(graph, R)``: a fed window graph with its rows packed to the
     budget ``R = packed_rows(slots, S)`` - a copy of ``symbol`` whose
     ``pack_rows`` / ``unpack_rows`` nodes carry it (``ops/rows.py``), so
     that every row-wise operation runs over R rows and not ``slots x
-    S``. None where the graph has no such nodes (a block without
+    S`` - and its head over fewer still: ``last_rows`` stands in front
+    of it (``_head_entry``), so the copies' sum, the final norm, the
+    product with the vocabulary and what scales or slices it run over
+    each slot's last fed row, the one row of a serving window that
+    anybody reads, and the graph's output - the logits' ``unpack_rows``,
+    here the reshape of ``slots`` rows - is ``(slots, 1, V)``
+    (``(slots, 1, heads, V)`` with every head's logits): no array of a
+    window's ``slots x S`` rows by the vocabulary exists in the
+    program. None where the graph has no such nodes (a block without
     ``fed``) or packing would not halve the rows (S = 1, rung 1)."""
     steps = {int(n.attrs["step_len"]) for n in symbol._topo_nodes()
              if n.op == "unpack_rows"}
-    if len(steps) != 1:
+    if len(steps) != 1 or symbol._outputs[0][0].op != "unpack_rows":
         return None
     step_len = steps.pop()
     rows = packed_rows(slots, step_len)
     if slots * step_len < 2 * rows:
         return None
     packed = symbol._substitute({})
+    out = packed._outputs[0][0]
     for node in packed._topo_nodes():
-        if node.op in ("pack_rows", "unpack_rows"):
+        if node.op in ("pack_rows", "unpack_rows") and node is not out:
             node.attrs["rows"] = rows
+    out.attrs["step_len"] = 1           # a row a slot, as an S = 1 graph's
+    node, k = _head_entry(out)
+    node.inputs[k] = sym.last_rows(
+        sym.Symbol([node.inputs[k]]), sym.Symbol([out.inputs[1]]),
+        step_len=step_len, rows=rows, name=f"{out.name}_last")._outputs[0]
     return packed, rows
 
 
@@ -1468,8 +1500,9 @@ class BatchedKVCacheDecoder:
         # the real rows alone, where there is one (``add_window``)
         self._packed = {}
         # rows the latest step's program ran its row-wise operations
-        # over: slots x S, or R where it was the packed one
-        self.last_program_rows = None
+        # over: slots x S, or R where it was the packed one; and those
+        # its head ran over: the same, but a packed window's ``slots``
+        self.last_program_rows = self.last_head_rows = None
         self._stepped = None          # the module the latest step ran
         self._cursor_program = None                  # built at first use
         self._row_progs = None                       # capture, restore
@@ -1559,9 +1592,11 @@ class BatchedKVCacheDecoder:
 
         ``packed`` is ``(module, R)``: a module bound the same way over
         ``packed_window``'s form of the same graph, whose row-wise
-        operations run over R packed rows. ``step`` launches it for a
-        window whose slots are fed no more than R rows between them,
-        and ``module`` for any other: same outputs, same state."""
+        operations run over R packed rows and whose output is each
+        slot's last fed row alone. ``step`` launches it for a window
+        whose slots are fed no more than R rows between them, and
+        ``module`` for any other: the same state, and of the outputs
+        the row that a serving window's caller reads."""
         self._windows[int(step_len)] = module
         self._stagers[int(step_len)] = module._exec_group.input_stager()
         if packed is not None:
@@ -1751,7 +1786,10 @@ class BatchedKVCacheDecoder:
     def select_rows(self, out, idx, feed=None, now=None):
         """From a step's ``(slots, S, V)`` output as it lies on the
         device, ``rows = out[slot, idx[slot]]`` as ``(slots, V)`` (the
-        bytes the host would have indexed, untouched), ``ids =
+        bytes the host would have indexed, untouched; of an output of
+        one row a slot - an S=1 step's, or a packed window's, which is
+        each slot's last fed row already - that row, whatever row of
+        the window ``idx`` names), ``ids =
         argmax(rows, -1)`` as ``(slots,)`` int32, the first maximum as
         ``np.argmax`` takes it, and ``tokens``, the same ids as the S=1
         program takes its token input (``(slots, 1)``, the data cell's
@@ -1760,7 +1798,8 @@ class BatchedKVCacheDecoder:
         name in the trace), one program per step length whatever
         ``idx`` holds. All three stay on the device; the copy of
         ``ids`` to the host starts here, behind the step program.
-        ``idx`` is (slots,) ints in ``[0, S)``; ``feed`` (slots,) bools
+        ``idx`` is (slots,) ints in ``[0, S)`` of an S-row output and
+        not below 0 of any; ``feed`` (slots,) bools
         says whose id ``tokens`` carries (None: every slot's), and the
         others ride 0, as a row nobody owns does when the host builds
         the tokens. All of it is the annotation ``decode.select_rows``;
@@ -1771,10 +1810,12 @@ class BatchedKVCacheDecoder:
             S = arr.shape[1]
             idx = np.asarray(idx, np.int32).reshape(-1)
             if idx.shape != (self.slots,) or idx.min() < 0 \
-                    or idx.max() >= S:
+                    or S > 1 and idx.max() >= S:
                 raise MXNetError(
                     f"select_rows() wants ({self.slots},) row "
                     f"indices in [0, {S}), got {idx.tolist()}")
+            if S == 1:
+                idx = np.zeros(self.slots, np.int32)
             feed = np.ones(self.slots, bool) if feed is None \
                 else np.asarray(feed, bool).reshape(-1)
             if feed.shape != (self.slots,):
@@ -1947,7 +1988,8 @@ class BatchedKVCacheDecoder:
     def step(self, tokens, fed=None, now=None):
         """Advance every slot by one S-token window: ``tokens``
         (slots,) or (slots, S) int ids (retired slots ride any valid
-        id, 0 by convention) -> logits (slots, S, V) NDArray. S=1 runs
+        id, 0 by convention) -> logits (slots, S, V) NDArray, or of a
+        packed window (below) (slots, 1, V). S=1 runs
         the steady-state decode program; S>1 dispatches the matching
         window module registered via ``add_window``. Raises per slot
         BEFORE dispatch when an active slot would overflow its cache —
@@ -1968,7 +2010,14 @@ class BatchedKVCacheDecoder:
         (``add_window(packed=)``) and ``fed`` is given and sums to no
         more than its budget, that is the program launched: its
         row-wise operations run over the budget's rows, not ``slots x
-        S`` (``last_program_rows`` says which ran).
+        S`` (``last_program_rows`` says which ran), its head over each
+        slot's last fed row (``last_head_rows``: ``slots``), and what
+        it returns is that row alone, ``(slots, 1, V)``: row
+        ``fed[b] - 1`` of slot ``b``'s window, the row a serving window
+        samples from (for a slot fed nothing a finite row nobody
+        reads). ``select_rows`` takes it as it takes an S=1 step's. Who
+        wants every row of a window steps without ``fed``, or reads a
+        driver that has no packed form.
 
         Two annotations: ``decode.step.stage`` (the checks, the host
         arrays and their puts, what the dispatch reads of the state)
@@ -1989,7 +2038,7 @@ class BatchedKVCacheDecoder:
                 raise MXNetError(f"step() wants ({self.slots}, S) tokens, "
                                  f"got {tokens.shape}")
             stage = self._stagers.get(S)
-            self.last_program_rows = self.slots * S
+            self.last_program_rows = self.last_head_rows = self.slots * S
             if S == 1:
                 mod = self._mod
             else:
@@ -2028,6 +2077,7 @@ class BatchedKVCacheDecoder:
                                          fed, 0)
                 if packed is not None and fed.sum() <= packed[2]:
                     mod, stage, self.last_program_rows = packed
+                    self.last_head_rows = self.slots
                 self.last_reads = self._dispatch_reads(fed)
             if self.name is not None:    # the pools' bytes a dispatch
                 self._donated()[0].inc(self.donated_bytes)

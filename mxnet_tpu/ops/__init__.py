@@ -8,7 +8,7 @@ from . import rnn_op  # noqa: F401 — registers the fused RNN
 from . import moe  # noqa: F401 — registers RMSNorm and MoEFFN
 from . import eva  # noqa: F401 — registers eva_attention_decode, GatedSiLU
 from . import mla  # noqa: F401 — registers mla_attention_decode, dsa_index_select
-from . import rows  # noqa: F401 — registers pack_rows, unpack_rows
+from . import rows  # noqa: F401 — registers pack_rows, unpack_rows, last_rows
 from . import mhc  # noqa: F401 — registers mhc_pre, mhc_post
 from . import ssm  # noqa: F401 — registers ssm_mixer_decode
 from .. import operator as _custom_op  # noqa: F401 — registers Custom

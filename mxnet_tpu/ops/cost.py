@@ -436,6 +436,7 @@ _UNARY_XCENDENTAL = {
 }
 _MOVEMENT = {
     "Reshape", "reshape", "reshape_like", "pack_rows", "unpack_rows",
+    "last_rows",
     "Flatten", "flatten", "transpose", "Cast",
     "cast", "_copy", "identity", "BlockGrad", "stop_gradient",
     "make_loss", "Concat", "concat", "SliceChannel", "split", "slice",

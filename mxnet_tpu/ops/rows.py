@@ -1,4 +1,5 @@
-"""The rows of a window, packed: ``pack_rows`` and ``unpack_rows``.
+"""The rows of a window, packed: ``pack_rows``, ``unpack_rows`` and
+``last_rows``.
 
 A window graph of a fed decoder (``models/transformer.py``: the blocks
 that take ``fed``) gives every slot S rows, of which ``fed[b]`` are real
@@ -31,6 +32,18 @@ row mask. Neither touches a pad row beyond a copy's tail.
 ``pack_rows`` also returns ``fed`` in the packed view: itself at ``rows
 = 0``, the one pseudo-slot's count of real rows ``(1,)`` under a budget
 - what ``MoEFFN`` keeps the pads out of its experts by.
+
+``last_rows`` reads, from the view the row-wise operations run in, the
+one row of each slot that a serving window's caller reads: its last fed
+row, as ``(slots, 1, ...)`` - row ``fed[b] - 1`` of slot ``b`` at ``rows
+= 0``, row ``offset[b] + fed[b] - 1`` of the one block under a budget. A
+gather of ``slots`` rows and no loop. A slot fed nothing takes a row
+that nobody reads: its own row 0, or under a budget the row before its
+offset (row 0 for the first) - a real row or a zero pad, finite either
+way. ``packed_window`` puts it in front of a packed window graph's head,
+so that the final norm and the product with the vocabulary run over
+``slots`` rows and the program hands back ``(slots, 1, V)``: no whole
+window graph has the node (``models/transformer.py``).
 """
 from __future__ import annotations
 
@@ -40,7 +53,7 @@ from jax import lax
 from ..base import parse_int, parse_tuple
 from .registry import register
 
-__all__ = ["pack", "unpack"]
+__all__ = ["pack", "unpack", "last"]
 
 
 def _chunk(step_len):
@@ -122,6 +135,17 @@ def unpack(x, fed, step_len, rows, tail):
     return _copy_real(out, fed, chunk, copy)
 
 
+def last(x, fed, step_len=None, rows=0):
+    """``x (slots, S, ...)``, or the packed ``(1, rows, ...)`` of windows
+    of ``step_len`` under a budget, -> each slot's last fed row
+    ``(slots, 1, ...)`` (module docstring)."""
+    if not rows:
+        at = jnp.clip(fed.astype(jnp.int32), 1, x.shape[1]) - 1
+        return x[jnp.arange(x.shape[0]), at][:, None]
+    fed, starts, _ = _offsets(fed, step_len, rows)
+    return x[0, jnp.clip(starts + fed - 1, 0, rows - 1)][:, None]
+
+
 def _pack_infer(attrs, in_shapes):
     data_s, fed_s = in_shapes
     rows = parse_int(attrs.get("rows", 0))
@@ -182,3 +206,30 @@ def _unpack_rows(attrs, data, fed):
     if not rows:
         return jnp.reshape(data, (-1, step_len) + tail)
     return unpack(data, fed, step_len, rows, tail)
+
+
+def _last_infer(attrs, in_shapes):
+    data_s, fed_s = in_shapes
+    rows = parse_int(attrs.get("rows", 0))
+    if data_s is None:
+        return in_shapes, [None], []
+    if not rows:
+        fed_s = (data_s[0],)
+    elif tuple(data_s[:2]) != (1, rows):
+        raise ValueError(f"last_rows: {tuple(data_s[:2])} rows under a "
+                         f"budget of {rows}: the packed view is (1, {rows})")
+    if fed_s is None:
+        return in_shapes, [None], []
+    return [data_s, fed_s], [(fed_s[0], 1) + tuple(data_s[2:])], []
+
+
+@register("last_rows", inputs=("data", "fed"),
+          attr_spec={"step_len": (parse_int, None),
+                     "rows": (parse_int, 0)},
+          infer_shape=_last_infer)
+def _last_rows(attrs, data, fed):
+    """Each slot's last fed row ``(slots, 1, ...)`` of ``(slots, S,
+    ...)`` rows, or under a budget (``rows``, with the windows'
+    ``step_len``) of the packed ``(1, rows, ...)``."""
+    rows = parse_int(attrs.get("rows", 0))
+    return last(data, fed, rows and parse_int(attrs["step_len"]), rows)

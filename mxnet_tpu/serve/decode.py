@@ -766,9 +766,14 @@ class DecodeEngine:
 #: whatever kernel serves it. Then the rows that were real tokens, and
 #: the rows that the launched program ran its row-wise operations over
 #: (slots x S, or the budget R of a packed program): real / program is
-#: the share of a window's dense work that was not pads
+#: the share of a window's dense work that was not pads. Last the rows
+#: its head ran over - the final norm and the product with the
+#: vocabulary: the same, but of a packed program each slot's last fed
+#: row alone (``slots``) - so head / program says how often the form
+#: that selects before the head engaged
 _WINDOW_COUNTERS = ("window.fed_slots", "window.riding_slots",
-                    "window.real_rows", "window.program_rows")
+                    "window.real_rows", "window.program_rows",
+                    "window.head_rows")
 
 
 #: what one dispatch's launches left on the device (``_launch``): the
@@ -803,7 +808,7 @@ class _Dispatch:
         self.plan_s = 0.0
         self.phases = {"dispatch": 0.0, "fetch": 0.0, "bytes": 0,
                        "stage": 0.0, "launch": 0.0, "select": 0.0,
-                       "ids": 0.0, "program_rows": 0,
+                       "ids": 0.0, "program_rows": 0, "head_rows": 0,
                        "reads": collections.Counter()}
 
 
@@ -1259,8 +1264,9 @@ class DecodeScheduler:
         own parts on the same clock (``phases["stage"]``, ``["launch"]``,
         ``["select"]``), and what the dispatch reads of the state, as
         the graph's ops count it (``drv.last_reads``), adds up in
-        ``phases["reads"]``, a ``Counter``, the rows its program ran
-        over in ``phases["program_rows"]``. Returns what ``_fetch``
+        ``phases["reads"]``, a ``Counter``, the rows its program and
+        its head ran over in ``phases["program_rows"]`` and
+        ``["head_rows"]``. Returns what ``_fetch``
         takes - a ``_Launched``, all of it on the device, ``out`` the
         whole output where ``last`` is None (the speculative path: the
         verifier reads every row of target and draft) and None
@@ -1276,6 +1282,7 @@ class DecodeScheduler:
             phases["stage"] += drv.last_stage
             phases["launch"] += drv.last_launch
             phases["program_rows"] += drv.last_program_rows
+            phases["head_rows"] += drv.last_head_rows
             if last is not None:
                 picked, ids, nxt = drv.select_rows(out, last, feed=feed,
                                                    now=now)
@@ -1645,6 +1652,7 @@ class DecodeScheduler:
                     m["window.riding_slots"].inc(sum(n == 1 for n in rows))
                     m["window.real_rows"].inc(sum(rows))
                     m["window.program_rows"].inc(phases["program_rows"])
+                    m["window.head_rows"].inc(phases["head_rows"])
                 # what the dispatches read of the state, under the
                 # names its ops gave: a counter, a ring field, or both
                 read_fields = {}

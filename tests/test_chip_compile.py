@@ -490,7 +490,11 @@ def test_the_s1_latent_programs_lower_to_the_parents_text(lowering, v5e):
 #: without the input (``window_pack_cases.unfed_symbol``: the builder
 #: still makes the parent's text where it is not handed ``fed``), and
 #: the fed graphs' own - S = 1, whole window, packed - were recorded on
-#: ISSUE 47's tree; ``gpt2_rotary`` joined then.
+#: ISSUE 47's tree; ``gpt2_rotary`` joined then. ISSUE 51 changed the
+#: packed forms and nothing else: ``packed_window`` selects each slot's
+#: last fed row in front of the head and the program returns ``(slots,
+#: 1, V)``, so the seven ``"packed"`` digests were recorded anew on its
+#: tree; every S = 1 and whole-window digest stands as it was.
 _PARENT_PROGRAM_SHA256 = {
     ("glm_dsa", 1, "whole"): "9461136fdd6eaa09",
     ("glm_dsa", 16, "whole"): "96061a4da742fd99",
@@ -500,23 +504,23 @@ _PARENT_PROGRAM_SHA256 = {
     ("afmoe", 16, "whole"): "005db05c189522c6",
     ("evabyte", 1, "whole"): "3507d30cfe287fb6",
     ("evabyte", 16, "whole"): "88305a7d0fb20b40",
-    ("glm_dsa", 16, "packed"): "80b76ebb24689395",
-    ("axk1", 16, "packed"): "5f5862a33ccc44fc",
-    ("afmoe", 16, "packed"): "87f3395671e7b8aa",
-    ("evabyte", 16, "packed"): "b06c559512c898cd",
+    ("glm_dsa", 16, "packed"): "edfd90ea26b02ac1",
+    ("axk1", 16, "packed"): "8a92ce89c1fc83d9",
+    ("afmoe", 16, "packed"): "d1f0d55c4b980ee2",
+    ("evabyte", 16, "packed"): "b04035b7d3c443b1",
     ("gpt2_unfed", 1, "whole"): "e20dacc2cf812172",
     ("gpt2_unfed", 16, "whole"): "a13e4ed537bdc517",
     ("olmoe_unfed", 1, "whole"): "99256b25c8affc72",
     ("olmoe_unfed", 16, "whole"): "60daa79edf026f83",
     ("gpt2", 1, "whole"): "21ee45bab5a96eb9",
     ("gpt2", 16, "whole"): "450794b962ca88af",
-    ("gpt2", 16, "packed"): "db1262b4d233f0b9",
+    ("gpt2", 16, "packed"): "a0175b9779668163",
     ("gpt2_rotary", 1, "whole"): "27a812ab4885915c",
     ("gpt2_rotary", 16, "whole"): "10f9b19f6dbac22d",
-    ("gpt2_rotary", 16, "packed"): "04e970fba471bf45",
+    ("gpt2_rotary", 16, "packed"): "3e3cc32ee1bfdedd",
     ("olmoe", 1, "whole"): "afab9133b8a82585",
     ("olmoe", 16, "whole"): "c801f808ba5a429b",
-    ("olmoe", 16, "packed"): "b2d49a46a123cc68",
+    ("olmoe", 16, "packed"): "44ac33c89bb0b8c2",
 }
 
 
@@ -549,6 +553,66 @@ def test_decode_programs_lower_to_the_parents_text(block, S, form,
         kernel_tier.clear()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == _PARENT_PROGRAM_SHA256[(block, S, form)]
+
+
+@pytest.mark.parametrize("block", [
+    "afmoe", "axk1", "evabyte", "evabyte_multibyte", "glm_dsa", "gpt2",
+    "gpt2_rotary", "granite_hybrid", "olmoe", "xing4"])
+def test_a_packed_window_program_holds_no_window_of_logits(block,
+                                                           monkeypatch):
+    """ISSUE 51, from the lowered text: the packed window program of
+    every block computes its head over ``slots`` rows - no array of the
+    vocabulary's width is as long as the window (``slots x S`` rows, or
+    ``(slots, S, V)``) or as the budget (R rows), as an output or in
+    between - and returns ``(slots, 1, V)``; the whole-window program
+    of the same graph holds the window's logits as it did. At 5 slots
+    of 16 rows (80 a whole window, a budget of 24) and a vocabulary of
+    53, numbers that no width of the tiny blocks shares."""
+    import window_pack_cases as cases
+    from mxnet_tpu.models import transformer as tfm
+    slots, S, V = 5, 16, 53
+    kw = dict(cases.config(block.split("_multibyte")[0]), vocab_size=V)
+    heads = 1
+    if block.endswith("_multibyte"):
+        kw.update(multibyte=True)
+        heads = kw["n_pred_heads"]
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
+    kernel_tier.clear()
+    try:
+        whole = tfm.get_decode_symbol(step_len=S, capacity=cases.CAPACITY,
+                                      per_slot=True, **kw)
+        packed, budget = tfm.packed_window(whole, slots)
+        assert budget == 24
+        texts = {form: cases.lowered_text(sym, slots, S)
+                 for form, sym in (("whole", whole), ("packed", packed))}
+    finally:
+        kernel_tier.clear()
+
+    def wide(text):
+        """The shapes of every array as wide as the vocabulary (or as
+        all heads' vocabularies side by side), as tuples."""
+        shapes = {tuple(map(int, dims.split("x")))
+                  for dims in re.findall(r"tensor<((?:\d+x)*\d+)x[a-z]", text)}
+        return {shape for shape in shapes
+                if shape[-1] in (V, heads * V)
+                or shape[-2:] == (heads, V)}
+
+    def rows(shape):
+        lead = shape[:-2] if shape[-2:] == (heads, V) and heads > 1 \
+            else shape[:-1]
+        return int(np.prod(lead))
+
+    out = (slots, 1, heads, V) if heads > 1 else (slots, 1, V)
+    assert out in wide(texts["packed"])
+    # the head's product, the logits and whatever scales or slices
+    # them are a row a slot; nothing of that width is as long as the
+    # window or the budget (what else is as wide is a weight, read as
+    # d_model rows: 64 or 32)
+    lengths = set(map(rows, wide(texts["packed"])))
+    assert slots in lengths and not lengths & {slots * S, budget}
+    window = (slots, S, heads, V) if heads > 1 else (slots, S, V)
+    assert window in wide(texts["whole"])
+    assert slots * S in set(map(rows, wide(texts["whole"])))
 
 
 #: the first 16 hex digits of the sha256 of ``Symbol.tojson()`` - every

@@ -639,14 +639,17 @@ def _sched31(kind, name, ladder):
 def _fetch_whole_logits(sched):
     """The reference loop: every dispatch's whole logits come to the
     host through ``drv.step(...).asnumpy()`` and ``sample_token`` is
-    applied to ``[row, n - 1]``, greedy and sampled requests alike."""
+    applied to ``[row, n - 1]`` (of a packed window's output, which is
+    that row alone, to ``[row, 0]``), greedy and sampled requests
+    alike."""
     from mxnet_tpu.serve.sampling import SamplingParams, sample_token
     greedy = SamplingParams()
 
     def launch(drv, tokens, phases, t=None, last=None, rows=False,
                fed=None, feed=None):
         logits = drv.step(tokens, fed=fed).asnumpy()
-        picked = logits[np.arange(len(last)), last]
+        picked = logits[np.arange(len(last)),
+                        np.minimum(last, logits.shape[1] - 1)]
         ids = [sample_token(row, greedy, None) for row in picked]
         return (np.asarray(ids, np.int32), picked), t
 

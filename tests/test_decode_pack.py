@@ -1,8 +1,10 @@
 """A window's rows, packed (``ops/rows.py``, ``models/transformer.py``'s
 ``packed_window`` and ``BatchedKVCacheDecoder.step``'s choice between
 the two forms of a window program, ``DecodeScheduler._plan_window``):
-the packed program against the whole-window program of the same graph,
-for each block - all take ``fed`` -, at tiny sizes on the CPU in
+the packed program - whose head runs over each slot's last fed row and
+which returns that row alone, ISSUE 51 - against the whole-window
+program of the same graph at that row, for each block - all take
+``fed`` -, at tiny sizes on the CPU in
 float32; the two blocks that took none before ISSUE 47 against the
 graphs they were, built by hand; and the scheduler's plan inside the
 budget against the plan without one."""
@@ -23,7 +25,10 @@ from decode_counts_parent import COUNTS
 S, SLOTS = cases.WINDOW, cases.SLOTS
 R = tfm.packed_rows(SLOTS, S)                   # 24 of 64
 
-#: rows fed to each of the four slots of one window
+#: rows fed to each of the four slots of one window: between them every
+#: row of a chunk is some slot's last fed row inside the budget (fed 1
+#: to S), in the first slot, in the last, behind a slot fed nothing and
+#: in front of one
 MIXES = {
     "one_prefilling_rest_riding": [S, 1, 1, 1],
     "two_short_prompts_share": [7, 9, 1, 1],
@@ -32,7 +37,15 @@ MIXES = {
     "all_riding": [1, 1, 1, 1],
     "one_over_the_budget": [S, R - S - 1, 1, 1],            # sum == R + 1
     "every_slot_a_whole_chunk": [S, S, S, S],
+    "four_short_prompts": [2, 3, 4, 5],
+    "unfed_first_and_last": [0, 8, 10, 0],
+    "two_long_one_unfed_between": [11, 12, 0, 1],
+    "unfed_in_the_middle": [13, 0, 0, 11],                  # sum == R
+    "a_chunk_short_by_two": [S - 2, 5, 3, 2],               # sum == R
+    "a_chunk_short_by_one": [S - 1, 4, 2, 3],               # sum == R
 }
+assert {n for fed in MIXES.values() if sum(fed) <= R for n in fed} \
+    >= set(range(S + 1))
 
 #: float32 on the CPU. A row's product does not depend on its
 #: neighbours in exact arithmetic, and EvaByte's block comes out equal
@@ -43,7 +56,9 @@ MIXES = {
 #: logits, of magnitude 2; Xing4.0's mappings - an exp and 20 Sinkhorn
 #: rounds of the stream a sub-layer - carry such a bit ten times as far)
 TOL = {"evabyte": 0.0, "glm_dsa": 2e-5, "axk1": 2e-5, "afmoe": 2e-5,
-       "xing4": 2e-4, "gpt2": 0.0, "gpt2_rotary": 0.0, "olmoe": 0.0,
+       # ISSUE 51: a tied head's product over 4 rows and over 64 (4e-7
+       # on logits of 3; the untied heads, ``FullyConnected``, are equal)
+       "xing4": 2e-4, "gpt2": 2e-6, "gpt2_rotary": 2e-6, "olmoe": 0.0,
        # the segmented convolution and the one-hot products sum the
        # same terms over 24 rows and over 64
        "granite_hybrid": 2e-5}
@@ -104,12 +119,21 @@ def test_the_budget_follows_from_the_shapes():
     sym = cases.symbol("glm_dsa", S)
     packed, budget = tfm.packed_window(sym, SLOTS)
     assert budget == R
+    out = packed._outputs[0][0]
     marked = {n.op: n.attrs["rows"] for n in packed._topo_nodes()
-              if n.op in ("pack_rows", "unpack_rows")}
-    assert marked == {"pack_rows": R, "unpack_rows": R}
+              if n.op in ("pack_rows", "unpack_rows", "last_rows")
+              and n is not out}
+    assert marked == {"pack_rows": R, "unpack_rows": R, "last_rows": R}
+    # the logits leave as a row a slot: the reshape of an S = 1 graph
+    assert (out.op, out.attrs["step_len"], out.attrs.get("rows", 0)) \
+        == ("unpack_rows", 1, 0)
+    assert packed.infer_shape(data=(SLOTS, S), fed=(SLOTS,))[1] \
+        == [(SLOTS, 1, 48)]
     # the graph it was derived from is untouched
-    assert not any(n.attrs.get("rows") for n in sym._topo_nodes()
-                   if not n.is_variable)
+    assert not any(n.attrs.get("rows") or n.op == "last_rows"
+                   for n in sym._topo_nodes() if not n.is_variable)
+    assert sym.infer_shape(data=(SLOTS, S), fed=(SLOTS,))[1] \
+        == [(SLOTS, S, 48)]
     # nobody rides at rung 1, an S = 1 graph has nothing to pack, and a
     # graph without ``fed`` (built by hand) has no such nodes
     assert tfm.packed_window(sym, 1) is None
@@ -162,6 +186,93 @@ def test_rows_are_copied_a_chunk_at_a_time_where_a_slot_holds_several(fed):
     np.testing.assert_array_equal(back, np.where(real[:, :, None], x, 0.0))
 
 
+@pytest.mark.parametrize("fed", sorted(MIXES.values()),
+                         ids=sorted(MIXES, key=MIXES.get))
+def test_last_rows_reads_each_slots_last_fed_row_in_both_views(fed):
+    """``last_rows`` alone: from all ``slots x S`` rows (``rows = 0``)
+    row ``fed - 1`` of each slot, and from the packed block under a
+    budget the same row where ``pack_rows`` laid it; a slot fed nothing
+    takes its own row 0 in the one view and, in the other, a row of the
+    block - the row before its offset, or row 0."""
+    rs = np.random.RandomState(3)
+    x = jnp.asarray(rs.randn(SLOTS, S, 3, 5), jnp.float32)
+    fed = np.asarray(fed)
+    want = np.stack([x[b, max(n - 1, 0)] for b, n in enumerate(fed)])
+    got = rows.last(x, jnp.asarray(fed, jnp.int32))
+    assert got.shape == (SLOTS, 1, 3, 5)
+    np.testing.assert_array_equal(got[:, 0], want)
+    if fed.sum() > R:
+        return
+    packed, _total = rows.pack(x, jnp.asarray(fed, jnp.int32), R)
+    got = np.asarray(rows.last(packed, jnp.asarray(fed, jnp.int32), S, R))
+    assert got.shape == (SLOTS, 1, 3, 5)
+    ends = np.cumsum(fed)
+    for b, n in enumerate(fed):
+        np.testing.assert_array_equal(
+            got[b, 0], want[b] if n else packed[0, max(ends[b] - 1, 0)])
+
+
+def test_last_rows_is_an_op_of_both_views_and_stays_in_range():
+    """The registered op: its shapes in either view, the budget's view
+    checked, and counts that no host would send (past S, below 0, a
+    sum past the budget) clipped as ``pack_rows`` clips them - a row of
+    the block whatever ``fed`` holds."""
+    x = mx.sym.var("x")
+    fed = mx.sym.var("fed")
+    whole = mx.sym.last_rows(x, fed)
+    assert whole.infer_shape(x=(SLOTS, S, 6))[1] == [(SLOTS, 1, 6)]
+    packed = mx.sym.last_rows(x, fed, step_len=S, rows=R)
+    assert packed.infer_shape(x=(1, R, 6), fed=(SLOTS,))[1] \
+        == [(SLOTS, 1, 6)]
+    with pytest.raises(Exception, match="budget of 24"):
+        packed.infer_shape(x=(SLOTS, S, 6), fed=(SLOTS,))
+    block = jnp.arange(R * 2, dtype=jnp.float32).reshape(1, R, 2)
+    for counts, at in (([S + 9, -3, 1, 0], [S - 1, S - 1, S, S]),
+                       ([S, S, S, S], [S - 1, R - 1, R - 1, R - 1]),
+                       ([0, 0, 0, 0], [0, 0, 0, 0])):
+        got = rows.last(block, jnp.asarray(counts, jnp.int32), S, R)
+        np.testing.assert_array_equal(got[:, 0], block[0, np.asarray(at)])
+    every = jnp.arange(SLOTS * S, dtype=jnp.float32).reshape(SLOTS, S)
+    got = rows.last(every, jnp.asarray([S + 9, -3, 1, 0], jnp.int32))
+    np.testing.assert_array_equal(got[:, 0], every[np.arange(SLOTS),
+                                                   [S - 1, 0, 0, 0]])
+
+
+@pytest.mark.parametrize("block", cases.FED + ["evabyte_multibyte"])
+def test_the_packed_form_selects_in_front_of_the_head(block):
+    """``packed_window`` puts ``last_rows`` where ``_head`` starts - the
+    final norm, or for a stream of copies their sum - whatever the
+    block: everything behind it runs over ``slots`` rows, nothing in
+    front of it does, and the graph's output is a row a slot."""
+    if block == "evabyte_multibyte":
+        sym = tfm.get_decode_symbol(
+            block="evabyte", step_len=S, capacity=cases.CAPACITY,
+            per_slot=True, tie_head=False, embed_scale=False,
+            multibyte=True, **cases.BLOCKS["evabyte"])
+        want = (SLOTS, 1, 2, 40)
+    else:
+        sym = cases.symbol(block, S)
+        want = (SLOTS, 1, cases.config(block)["vocab_size"])
+    packed, budget = tfm.packed_window(sym, SLOTS)
+    nodes = packed._topo_nodes()
+    (last,) = [n for n in nodes if n.op == "last_rows"]
+    assert (last.attrs["rows"], last.attrs["step_len"]) == (budget, S)
+    readers = [n.name for n in nodes
+               if any(src is last for src, _ in n.inputs)]
+    assert readers == ["lm_copies" if block == "xing4" else "lm_ln_f"]
+    # what it reads is the last layer's join, a node of several
+    # computed inputs: the head's own nodes read one each
+    join = last.inputs[0][0]
+    assert sum(not src.is_variable for src, _ in join.inputs) > 1
+    given = {d.name: d.shape for d in cases.inputs(packed, SLOTS, S)}
+    shapes = dict(zip(packed.get_internals().list_outputs(),
+                      packed.get_internals().infer_shape(**given)[1]))
+    assert shapes[f"{last.name}_output"] == (SLOTS, 1) \
+        + shapes[f"{join.name}_output"][2:]
+    assert shapes[f"{join.name}_output"][:2] == (1, budget)
+    assert packed.infer_shape(**given)[1] == [want]
+
+
 @pytest.fixture(scope="module", params=cases.FED)
 def pair(request):
     """``(block, whole, packed)``: two drivers of one block and one
@@ -187,6 +298,12 @@ def _fresh(drv, tokens):
 
 @pytest.mark.parametrize("mix", sorted(MIXES))
 def test_packed_program_equals_the_whole_window_program(pair, mix):
+    """Inside the budget the packed program returns ``(slots, 1, V)``:
+    of every slot the whole-window program's row ``fed - 1`` (ISSUE 51:
+    the head runs over that row alone; this stands where the comparison
+    of every real row stood), finite for a slot fed nothing; past the
+    budget the driver launches the whole-window program and hands back
+    every row. Cursors, pools and the S = 1 step behind it as before."""
     block, whole, packed = pair
     fed = np.asarray(MIXES[mix])
     rs = np.random.RandomState(len(mix))
@@ -197,7 +314,7 @@ def test_packed_program_equals_the_whole_window_program(pair, mix):
         _fresh(drv, tokens)
         start = drv.pos.copy()
         out = drv.step(tokens[:, 3:3 + S], fed=fed).asnumpy()
-        ran = drv.last_program_rows
+        ran = drv.last_program_rows, drv.last_head_rows
         after = drv.pos.copy()
         cursors = [np.asarray(c.asjax()).reshape(-1)
                    for c in drv._cursor_cells()]
@@ -209,9 +326,11 @@ def test_packed_program_equals_the_whole_window_program(pair, mix):
         got.append((out, after, cursors, state, nxt, ran, start))
     (out_w, pos_w, cur_w, rows_w, next_w, ran_w, start), \
         (out_p, pos_p, cur_p, rows_p, next_p, ran_p, _) = got
-    # the driver's switch: the packed program inside the budget
-    assert ran_w == SLOTS * S
-    assert ran_p == (R if fed.sum() <= R else SLOTS * S)
+    # the driver's switch: the packed program inside the budget, and
+    # its head over a row a slot
+    inside = fed.sum() <= R
+    assert ran_w == (SLOTS * S, SLOTS * S)
+    assert ran_p == ((R, SLOTS) if inside else (SLOTS * S, SLOTS * S))
     assert packed.window_budget(S) == R and whole.window_budget(S) is None
     np.testing.assert_array_equal(pos_p, start + fed)
     np.testing.assert_array_equal(pos_w, pos_p)
@@ -219,14 +338,56 @@ def test_packed_program_equals_the_whole_window_program(pair, mix):
         np.testing.assert_array_equal(a, b)
         np.testing.assert_array_equal(a, pos_p)
     tol = TOL[block]
+    assert out_w.shape == (SLOTS, S, vocab)
+    assert out_p.shape == ((SLOTS, 1, vocab) if inside else out_w.shape)
+    assert np.isfinite(out_p).all()
     for slot, n in enumerate(fed):
-        np.testing.assert_allclose(out_p[slot, :n], out_w[slot, :n],
-                                   rtol=0, atol=tol)
+        if n:
+            np.testing.assert_allclose(
+                out_p[slot, 0 if inside else n - 1], out_w[slot, n - 1],
+                rtol=0, atol=tol)
     # every pool's live rows, and what the next S = 1 step reads of the
     # state whatever family it is
     for a, b in zip(rows_w, rows_p):
         np.testing.assert_allclose(b, a, rtol=0, atol=tol)
     np.testing.assert_allclose(next_p, next_w, rtol=0, atol=tol)
+
+
+@pytest.mark.parametrize("mix", ["one_prefilling_rest_riding",
+                                 "unfed_first_and_last",
+                                 "a_chunk_short_by_one"])
+def test_select_rows_takes_a_packed_window_as_an_s1_step(pair, mix):
+    """What the scheduler does behind a window: ``select_rows`` at each
+    slot's last fed row. Of the packed program's one row a slot it
+    picks that row - through ``select_rows_<slots>x1``, the S = 1
+    step's program, no other - and hands on the ids, the rows and the
+    next step's tokens that the whole-window program's ``(slots, S,
+    V)`` gives."""
+    block, whole, packed = pair
+    fed = np.asarray(MIXES[mix])
+    last = np.maximum(fed - 1, 0)
+    rs = np.random.RandomState(len(mix))
+    tokens = rs.randint(0, cases.config(block)["vocab_size"],
+                        (SLOTS, 3 + S))
+    got = {}
+    for name, drv in (("whole", whole), ("packed", packed)):
+        _fresh(drv, tokens)
+        drv.select_rows(drv.step(tokens[:, 0], fed=np.zeros(SLOTS, int)),
+                        np.zeros(SLOTS, int))          # the S = 1 program
+        out = drv.step(tokens[:, 3:], fed=fed)
+        picked, ids, nxt = drv.select_rows(out, last, feed=fed > 0)
+        got[name] = (np.asarray(picked), np.asarray(ids), np.asarray(nxt))
+        assert set(drv._select_programs) == \
+            ({1, S} if name == "whole" else {1})
+    live = fed > 0
+    np.testing.assert_allclose(got["packed"][0][live], got["whole"][0][live],
+                               rtol=0, atol=TOL[block])
+    np.testing.assert_array_equal(got["packed"][1][live],
+                                  got["whole"][1][live])
+    np.testing.assert_array_equal(got["packed"][2], got["whole"][2])
+    with pytest.raises(mx.base.MXNetError, match="row indices"):
+        whole.select_rows(whole.step(tokens[:, 3:], fed=fed * 0),
+                          last + S)
 
 
 def test_a_step_without_fed_takes_the_whole_window_program(pair):
@@ -309,8 +470,12 @@ def test_the_scheduler_plans_inside_the_budget_oldest_first(block):
                                    model=engine.name).value
     ran = mx.telemetry.get_metric("serve.decode.window.program_rows",
                                   model=engine.name).value
+    heads = mx.telemetry.get_metric("serve.decode.window.head_rows",
+                                    model=engine.name).value
     assert real == sum(sum(fed) for fed, _ in windows)
     assert ran == R * len(windows)
+    # every window selected in front of its head: a row a slot
+    assert heads == SLOTS * len(windows)
 
     # the same requests planned without a budget (every active slot
     # min(S, remaining) a window): the same tokens, in fewer and wider
@@ -321,6 +486,12 @@ def test_the_scheduler_plans_inside_the_budget_oldest_first(block):
     assert tokens0 == tokens
     assert feds0[0][0] == [S, S, S, 5] and feds0[0][1] == SLOTS * S
     assert len(feds0) < len(windows)
+    # a window past the budget runs its head over every row
+    whole = f"pack-{block}-whole"
+    assert mx.telemetry.get_metric("serve.decode.window.head_rows",
+                                   model=whole).value \
+        == mx.telemetry.get_metric("serve.decode.window.program_rows",
+                                   model=whole).value > 0
 
 
 def test_an_engine_without_fed_is_planned_as_before():
@@ -483,9 +654,10 @@ def test_a_packed_window_equals_the_whole_window_and_s1_steps(block):
     packed program, through the whole-window program fed the same,
     token by token through the S = 1 program, and through the graph as
     it was before ISSUE 47 (no ``fed``: every cursor advances by S and
-    is rewound): the same logits on every real row within the block's
-    tolerance, the same greedy tokens, the same cursors, and the same
-    logits from the S = 1 step after."""
+    is rewound): the same logits on every real row - of the packed
+    program, which hands back a row a slot, on each slot's last -
+    within the block's tolerance, the same greedy tokens, the same
+    cursors, and the same logits from the S = 1 step after."""
     slots, fed = len(_PLAN8), np.asarray(_PLAN8)
     assert tfm.packed_rows(slots, S) == int(fed.sum()) == 24
     rs = np.random.RandomState(8)
@@ -514,6 +686,8 @@ def test_a_packed_window_equals_the_whole_window_and_s1_steps(block):
 
     def by_window(drv):
         out = drv.step(tokens[:, 2:2 + S], fed=fed).asnumpy()
+        if out.shape[1] == 1:       # the packed program's row a slot
+            return [out[slot, :min(n, 1)] for slot, n in enumerate(fed)]
         return [out[slot, :n] for slot, n in enumerate(fed)]
 
     def by_steps(drv):
@@ -540,16 +714,20 @@ def test_a_packed_window_equals_the_whole_window_and_s1_steps(block):
     assert packed.window_budget(S) == 24 and whole.window_budget(S) is None
     want_rows, want_pos, want_next = got.pop("as_it_was")
     np.testing.assert_array_equal(want_pos, start + fed)
-    # against the S = 1 program a window differs by the order of its sums
-    tol = {"packed": 0.0, "whole": 0.0, "steps": 2e-5}
+    # against the S = 1 program a window differs by the order of its
+    # sums, the packed program's head (over 8 rows) by the block's bit
+    tol = {"packed": TOL[block], "whole": 0.0, "steps": 2e-5}
     for form, (rows, pos, nxt) in got.items():
         np.testing.assert_array_equal(pos, want_pos, err_msg=form)
         for slot, n in enumerate(fed):
-            assert rows[slot].shape == (n, vocab)
-            np.testing.assert_allclose(rows[slot], want_rows[slot], rtol=0,
+            # of the packed program each slot's last fed row alone
+            want = want_rows[slot][-1:] if form == "packed" \
+                else want_rows[slot]
+            assert rows[slot].shape == want.shape
+            np.testing.assert_allclose(rows[slot], want, rtol=0,
                                        atol=tol[form], err_msg=form)
             np.testing.assert_array_equal(
-                rows[slot].argmax(-1), want_rows[slot].argmax(-1), form)
+                rows[slot].argmax(-1), want.argmax(-1), form)
         np.testing.assert_allclose(nxt, want_next, rtol=0, atol=tol[form],
                                    err_msg=form)
 
@@ -686,6 +864,13 @@ def test_the_counters_and_the_ring_fields_are_the_parents(block):
     runs (fed from the chip). The script has no EOS, so every dispatch
     launched ahead is committed: same dispatches, same counts."""
     counters, ring, step_bytes = _counts_script(block)
+    # ISSUE 51: the rows the windows' heads ran over, a row a slot of
+    # a packed launch (rung 4: the script's windows are all inside the
+    # budget but those at rung 1, which has no packed form)
+    heads = counters.pop("window.head_rows")
+    windows = [r for r in ring if r["window"] > 1]
+    assert heads == sum(SLOTS if r["rung"] == SLOTS else S
+                        for r in windows) > 0
     launched = counters.pop("runahead.launched")
     assert counters.pop("runahead.dropped") == 0
     assert launched == sum(r.pop("ahead") for r in ring) > 0
@@ -735,14 +920,21 @@ def test_learned_positions_past_256_are_exact_at_bfloat16():
                            else np.where(fed, S, 0)).asnumpy()
             assert drv.last_program_rows == \
                 (R if form == "packed" else slots * S)
+            # the riders' one row (a packed window hands back each
+            # slot's last fed row alone, ISSUE 51), and beside it the
+            # last row of the slot that is fed its whole chunk
             got[dtype, form] = np.stack(
-                [out[slot, 0] for slot in range(5)]).astype(np.float32)
+                [out[slot, 0] for slot in range(1, 5)]
+                + [out[0, 0 if form == "packed" else S - 1]]
+            ).astype(np.float32)
         drv.rewind_many(list(range(slots)), cursors)
         got[dtype, "s1"] = drv.step(
             tokens[:, :1], fed=(fed > 0).astype(np.int64)) \
-            .asnumpy()[:5, 0].astype(np.float32)
+            .asnumpy()[1:5, 0].astype(np.float32)
+    np.testing.assert_allclose(got[None, "packed"], got[None, "whole"],
+                               rtol=0, atol=1e-5)
     for form in ("packed", "whole", "s1"):
-        np.testing.assert_allclose(got[None, form], got[None, "s1"],
+        np.testing.assert_allclose(got[None, form][:4], got[None, "s1"],
                                    rtol=0, atol=1e-5, err_msg=form)
         # bfloat16 against float32: rounding, not a neighbour's row
         # (which reads 0.5 and more on these logits of 3)

@@ -7,15 +7,19 @@ windows (``step`` without ``fed``), so the comparison that decides
 ``correct`` reaches only the whole-window program; a serving window -
 one slot prefilling, the others riding with a token each - runs the
 packed form of the same graph (``models/transformer.py``'s
-``packed_window``, ``ops/rows.py``). Here slot 0 prefills a sequence to
+``packed_window``, ``ops/rows.py``), whose head runs over each slot's
+last fed row and which returns that row alone, ``(slots, 1, V)``. Here
+slot 0 prefills a sequence to
 16,384 positions (or as many whole windows as leave one more inside
 the configuration's capacity) and one window more while every other slot
 of the top rung rides each window with one token of its own; once
 through the
 packed program (``fed`` sums to S + slots - 1 <= R) and once, from
-cursor 0 again, through the whole-window program fed the same. The last
-sixteen rows of slot 0's last window and the riders' rows, packed
-against whole, and slot 0's against the architecture's plain float32
+cursor 0 again, through the whole-window program fed the same. Each
+slot's last fed row - all that the packed program hands back - packed
+against whole: slot 0's in every window of the schedule, the riders' in
+the last; slot 0's row at the last position (and of the whole-window
+program its last sixteen) against the architecture's plain float32
 reference under its ``LOGIT_TOL``; whether the rows each path wrote to
 the positional pools are equal (for a graph that carries state no
 position indexes - ``granite-4.0-h-micro``'s convolution tails and
@@ -125,11 +129,16 @@ def main(argv=None):
         part = rng.integers(0, cfg["vocab_size"], windows * ns.part) \
             .astype(np.int32)
 
+    # each slot's last fed row: all that a packed window hands back
+    last = np.maximum(fed - 1, 0)
+
     def run(whole):
         """Every window of the schedule through one form of the window
-        program: ``(slot 0's last rows, the riders' rows of the last
-        window, the rows written, the windows' seconds, the rows the
-        program ran over)``."""
+        program: ``(slot 0's last fed row of every window, every slot's
+        of the last window, the last rows of slot 0's last window that
+        this form returns - sixteen whole, one packed -, the rows
+        written, the windows' seconds, the rows the program and its
+        head ran over)``."""
         drv.active[:] = False
         drv.rewind_many(list(range(top)), [0] * top)
         for slot in range(top):
@@ -138,7 +147,7 @@ def main(argv=None):
         # budget: hide it for the whole-window pass (nothing public
         # chooses, by design)
         hidden = drv._packed.pop(S) if whole else None
-        seconds, out = [], None
+        seconds, firsts, out = [], [], None
         try:
             for w in range(windows):
                 out = None      # the window before's logits go first
@@ -152,10 +161,13 @@ def main(argv=None):
                 drv.release_outputs()   # 2 GB of logits at a whole
                 out.asjax().block_until_ready()     # vocabulary
                 seconds.append(time.perf_counter() - t)
-                ran = drv.last_program_rows
+                ran = drv.last_program_rows, drv.last_head_rows
+                firsts.append(np.asarray(
+                    out.asjax()[0, S - 1 if whole else 0], np.float32))
         finally:
             if hidden is not None:
                 drv._packed[S] = hidden
+        assert out.shape[:2] == ((top, S) if whole else (top, 1)), out.shape
         logits = out.asnumpy().astype(np.float32)
         if drv.positional:
             written = [np.asarray(a, np.float32)[..., ctx:, :]
@@ -165,14 +177,15 @@ def main(argv=None):
                        for family in sorted(drv.state_bytes)
                        if family != "cursor"
                        for _nm, cell in drv._cells(family)]
-        # a slot fed a part of a chunk is compared at its last real row
-        last = np.maximum(fed[1:] - 1, 0)
-        return (logits[0, S - n_cmp:],
-                logits[np.arange(1, top), last], written, seconds, ran)
+        return (np.stack(firsts),
+                logits[np.arange(top), last] if whole else logits[:, 0],
+                logits[0, S - n_cmp:] if whole else logits[0],
+                written, seconds, ran)
 
-    tail_p, ride_p, rows_p, s_p, ran_p = run(whole=False)
-    tail_w, ride_w, rows_w, s_w, ran_w = run(whole=True)
-    assert (ran_p, ran_w) == (budget, top * S), (ran_p, ran_w)
+    first_p, ends_p, tail_p, rows_p, s_p, ran_p = run(whole=False)
+    first_w, ends_w, tail_w, rows_w, s_w, ran_w = run(whole=True)
+    assert (ran_p, ran_w) == ((budget, top), (top * S, top * S)), \
+        (ran_p, ran_w)
 
     # the reference beside the parameters alone: the engine's pools and
     # programs go first, 16 k positions in float32 do not fit beside them
@@ -198,7 +211,7 @@ def main(argv=None):
             fwd = lambda p, t: forward(               # noqa: E731
                 p, t, config=rcfg)[:, -n_cmp:]
         want = np.asarray(jax.jit(fwd)(params, seq))[0]
-        report = {"packed_vs_reference": against(want, tail_p),
+        report = {"packed_vs_reference": against(want[-1:], tail_p),
                   "whole_vs_reference": against(want, tail_w),
                   "max_abs_logit": float(np.abs(want).max())}
         ok_ref = report["packed_vs_reference"]["max_err_over_bound"] <= 1.0
@@ -211,17 +224,23 @@ def main(argv=None):
     # program is not warmed where there is a packed one)
     print(json.dumps({
         "window_pack_check": cfg["name"], "seed": ns.seed, "context": ctx,
-        "positions_compared": n_cmp, "window": S, "slots": top,
-        "budget_rows": budget, "fed": fed.tolist(),
-        "packed_vs_whole_max_abs_diff": float(np.abs(tail_p - tail_w).max()),
+        "rows_vs_whole": windows + top - 1, "rows_vs_reference": 1,
+        "window": S, "slots": top, "budget_rows": budget,
+        "head_rows": ran_p[1], "fed": fed.tolist(),
+        "packed_vs_whole_max_abs_diff": float(
+            np.abs(first_p - first_w).max()),
         "packed_vs_whole_in_tol_units": float(
-            (np.abs(tail_p - tail_w) / (tol + tol * np.abs(tail_w))).max()),
+            (np.abs(first_p - first_w)
+             / (tol + tol * np.abs(first_w))).max()),
         "packed_vs_whole_argmax_equal": int(
-            (tail_p.argmax(1) == tail_w.argmax(1)).sum()),
+            (first_p.argmax(1) == first_w.argmax(1)).sum()),
         "riders_packed_vs_whole_max_abs_diff": float(
-            np.abs(ride_p - ride_w).max()),
+            np.abs(ends_p[1:] - ends_w[1:]).max()),
+        "riders_packed_vs_whole_in_tol_units": float(
+            (np.abs(ends_p[1:] - ends_w[1:])
+             / (tol + tol * np.abs(ends_w[1:]))).max()),
         "riders_argmax_equal": int(
-            (ride_p.argmax(1) == ride_w.argmax(1)).sum()),
+            (ends_p[1:].argmax(1) == ends_w[1:].argmax(1)).sum()),
         "rows_written_equal": bool(all(
             np.array_equal(a, b) for a, b in zip(rows_p, rows_w))),
         "rows_written_max_abs_diff": float(max(
