@@ -379,6 +379,36 @@ def kernel_sites(w, seed=0):
                 jnp.asarray(rs.randint(0, 2, (slots, 1)).astype(np.int32)
                             * rs.randint(1, cap, (slots, 1))
                             .astype(np.int32))]))
+    # Kimi Delta Attention's recurrent part (ops/kda.py): heads of 128 x
+    # 128 where the widths allow, an S = 1 step and a window whose slots
+    # are fed ragged counts - one step of the delta rule for a slot fed
+    # one row, chunks of the WY form for the others - over a state that
+    # a last occupant left
+    kH, kD = (D // 128, 128) if big else (4, D // 4)
+    k_in = kH * kD
+    for step in (1, win):
+        shapes = [(slots * step, 5 * k_in + kH), (slots,), (3 * k_in, 4),
+                  (kH,), (k_in,), (kD,), (slots, 3, 3 * k_in),
+                  (slots, kH, kD, kD), (slots, 1)]
+        sites.append((
+            f"kda_mixer_decode_s{step}", "kda_mixer_decode",
+            {"heads": kH, "head_dim": kD, "d_conv": 4,
+             "chunk": 16 * max(1, win // 32) if win >= 16 else 2,
+             "step_len": step, "capacity": 4 * cap, "lower_bound": -5.0,
+             "rms_eps": 1e-6},
+            shapes, [bf, "int32", bf, f32, f32, f32, f32, f32, "int32"],
+            False,
+            lambda shapes=shapes, step=step: [
+                normal(shapes[0], bf),
+                jnp.asarray(rs.randint(0, step + 1, (slots,))
+                            .astype(np.int32)),
+                normal(shapes[2], bf) / 2, normal(shapes[3], f32) / 4,
+                4.0 * normal(shapes[4], f32) - 2.0,
+                jnp.ones(shapes[5], f32), normal(shapes[6], f32),
+                normal(shapes[7], f32),
+                jnp.asarray(rs.randint(0, 2, (slots, 1)).astype(np.int32)
+                            * rs.randint(1, cap, (slots, 1))
+                            .astype(np.int32))]))
     for wdt in ("int8", "float8_e4m3fn"):
         rows, k, nh = slots * win, D, 4 * D
         sites.append((

@@ -186,8 +186,12 @@ def _eva_attention(x, fed, carry, i, spec):
 
 
 def _latent_attention(x, fed, selection, i, spec):
-    """GLM-5.2's and A.X-K1's (``spec["cfg"]``: ``_latent_spec``), no
-    bias anywhere but the indexer's LayerNorm: multi-head latent
+    """GLM-5.2's, A.X-K1's and Ling-3.0's (``spec["cfg"]``:
+    ``_latent_spec``), no bias anywhere but the indexer's LayerNorm - the
+    query through a normed bottleneck of ``q_lora_rank``, or where that
+    is None one projection, and where ``cfg["head_gate"]`` is set the
+    heads' outputs times a sigmoid of one more projection of the rows
+    (one number a head) -: multi-head latent
     attention over a latent cache (``mla_attention_decode``) under the
     selection of positions that this layer's indexer computes
     (``dsa_index_select``, layers whose ``indexer_types`` entry is
@@ -206,12 +210,16 @@ def _latent_attention(x, fed, selection, i, spec):
         rows, fed, spec["T"], f"{pfx}_{nm}_unfold")
 
     rows = sym.Reshape(x, shape=(-3, 0), name=f"{pfx}_attn_fold")  # (B*T, D)
-    c_q = _norm(
-        sym.FullyConnected(rows, num_hidden=cfg["q_lora_rank"],
-                           no_bias=True, name=f"{pfx}_q_a"),
-        f"{pfx}_q_a_norm", spec)
-    q = sym.FullyConnected(c_q, num_hidden=n_head * dq, no_bias=True,
-                           name=f"{pfx}_q_b")
+    if cfg["q_lora_rank"] is None:      # the query projected directly
+        q = sym.FullyConnected(rows, num_hidden=n_head * dq, no_bias=True,
+                               name=f"{pfx}_q")
+    else:
+        c_q = _norm(
+            sym.FullyConnected(rows, num_hidden=cfg["q_lora_rank"],
+                               no_bias=True, name=f"{pfx}_q_a"),
+            f"{pfx}_q_a_norm", spec)
+        q = sym.FullyConnected(c_q, num_hidden=n_head * dq, no_bias=True,
+                               name=f"{pfx}_q_b")
     kv = sym.FullyConnected(
         rows, num_hidden=cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"],
         no_bias=True, name=f"{pfx}_kv_a")
@@ -240,7 +248,21 @@ def _latent_attention(x, fed, selection, i, spec):
         kv_rank=cfg["kv_lora_rank"], rms_eps=spec["rms_eps"],
         rope_base=rope_base, name=f"{pfx}_attn",
         **({"selected": False} if dense else {}), **cfg["rope"])
-    return _packed_rows(att, fed, f"{pfx}_attn_merge"), selection
+    att = _packed_rows(att, fed, f"{pfx}_attn_merge")
+    if cfg.get("head_gate"):            # one sigmoid a head and row
+        gate = sym.Activation(
+            sym.FullyConnected(rows, num_hidden=n_head, no_bias=True,
+                               name=f"{pfx}_gate"),
+            act_type="sigmoid", name=f"{pfx}_gate_sigmoid")
+        att = sym.Reshape(
+            sym.broadcast_mul(
+                sym.Reshape(att, shape=(0, n_head, -1),
+                            name=f"{pfx}_attn_heads"),
+                sym.Reshape(gate, shape=(0, n_head, 1),
+                            name=f"{pfx}_gate_heads"),
+                name=f"{pfx}_attn_gated"),
+            shape=(0, -1), name=f"{pfx}_attn_gated_merge")
+    return att, selection
 
 
 def _grouped_attention(x, fed, carry, i, spec):
@@ -384,6 +406,34 @@ def _hybrid_mixer(x, fed, carry, i, spec):
     mixer = _mamba_mixer if spec["cfg"]["layer_types"][i] == "mamba" \
         else _nope_attention
     return mixer(x, fed, i, spec), None
+
+
+def _kda_mixer(x, fed, i, spec):
+    """Ling-3.0's linear layers, Kimi Delta Attention: one projection
+    of the normed rows to ``[q | k | v | f | g | b]`` - query, key,
+    value, the decay's and the output gate's full projections, one
+    ``b`` a head -, then the convolutions, the delta-rule update and the
+    gated per-head norm over the rows as they lie (``kda_mixer_decode``,
+    ``ops/kda.py``); the layer's output projection follows in
+    ``_layer``."""
+    pfx, cfg = f"{spec['name']}_l{i}", spec["cfg"]
+    H, D = spec["n_head"], cfg["head_dim"]
+    rows = sym.Reshape(x, shape=(-3, 0), name=f"{pfx}_kda_fold")    # (B*T, D)
+    wide = sym.FullyConnected(rows, num_hidden=5 * H * D + H, no_bias=True,
+                              name=f"{pfx}_kda_in")
+    return sym.kda_mixer_decode(
+        wide, fed, heads=H, head_dim=D, d_conv=cfg["short_conv_kernel_size"],
+        chunk=cfg["kda_chunk"], step_len=spec["T"],
+        capacity=spec["capacity"], lower_bound=cfg["kda_lower_bound"],
+        rms_eps=spec["rms_eps"], name=f"{pfx}_kda")
+
+
+def _ling_mixer(x, fed, carry, i, spec):
+    """Layer ``i``'s mixer by ``layer_types[i]``: ``"kda"`` or
+    ``"mla"`` (latent attention over every earlier position)."""
+    if spec["cfg"]["layer_types"][i] == "kda":
+        return _kda_mixer(x, fed, i, spec), None
+    return _latent_attention(x, fed, None, i, spec)
 
 
 def _ffn(x, fed_rows, i, spec):
@@ -898,12 +948,102 @@ def _granite_spec(spec):
                      "logits": float(cfg["logits_scaling"])})
 
 
+#: the keys of Ling-3.0's published ``config.json`` (``model_type
+#: bailing_hybrid``) that ``block="ling_hybrid"`` reads
+#: (``get_decode_symbol(ling=...)``). ``layer_types`` and the two
+#: ``*_swiglu_limit_list`` have one entry a layer that is run
+#: (``"kda"`` or ``"mla"``; the published rule is ``"mla"`` where
+#: ``(i + 1) % layer_group_size == 0``); optionally ``held`` and
+#: ``kda_chunk`` (the rows of a trip of the chunked form, 64)
+LING_KEYS = ("layer_types", "head_dim", "short_conv_kernel_size",
+             "kda_lower_bound", "kda_safe_gate", "no_kda_lora",
+             "use_kda_lora", "linear_silu", "group_norm_size",
+             "num_kv_heads_for_linear_attn", "q_lora_rank", "kv_lora_rank",
+             "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+             "rope_scaling", "gated_attention_proj_granularity_type",
+             "use_mla_nope", "first_k_dense_replace", "intermediate_size",
+             "moe_intermediate_size", "moe_shared_expert_intermediate_size",
+             "num_experts", "num_experts_per_tok", "num_shared_experts",
+             "routed_scaling_factor", "norm_topk_prob", "n_group",
+             "topk_group", "moe_router_enable_expert_bias",
+             "scale_router_input", "expert_swiglu_limit_list",
+             "share_expert_swiglu_limit_list", "up_proj_norm", "value_norm",
+             "use_nGPT")
+
+
+def _ling_spec(spec):
+    """Ling-3.0's block (per-slot only) from ``ling``, the published
+    config's keys (``LING_KEYS``), no bias anywhere: pre-norm RMSNorm,
+    **a mixer per layer** (``layer_types``) - ``"kda"`` is Kimi Delta
+    Attention, whose state is three convolutions' tails and one matrix
+    a head, constant in the context (``_kda_mixer``; families ``"conv"``
+    and ``"recurrent"``), ``"mla"`` the latent block's attention over
+    every earlier position under the plain rotary, its query one
+    projection (``q_lora_rank`` None) and its heads' outputs gated by a
+    sigmoid a head (``_latent_attention``; ``"rows"``, the only pool
+    that grows with ``capacity``) -, and the latent block's
+    feed-forward (``_latent_spec``): dense gated SiLU on the first
+    ``first_k_dense_replace`` layers, then sigmoid-routed experts with a
+    correction bias chosen inside ``topk_group`` of ``n_group`` groups,
+    of which this graph holds ``held``, beside a shared one. What this
+    graph does not build is refused, by key."""
+    cfg = _given_keys(spec, "ling", LING_KEYS)
+    kinds, n_layer = list(cfg["layer_types"]), spec["n_layer"]
+    if len(kinds) != n_layer or set(kinds) - {"kda", "mla"}:
+        raise MXNetError(
+            f"block='ling_hybrid': layer_types {kinds} must name 'kda' or "
+            f"'mla' for each of {n_layer} layers")
+    limits = list(cfg["expert_swiglu_limit_list"]) \
+        + list(cfg["share_expert_swiglu_limit_list"])
+    refused = {
+        "q_lora_rank": cfg["q_lora_rank"] is not None,
+        "gated_attention_proj_granularity_type":
+            cfg["gated_attention_proj_granularity_type"] != "head_wise",
+        "expert_swiglu_limit_list / share_expert_swiglu_limit_list":
+            len(limits) != 2 * n_layer or any(limits),
+        "num_kv_heads_for_linear_attn": cfg["num_kv_heads_for_linear_attn"],
+        "kda_safe_gate": not cfg["kda_safe_gate"],
+        "no_kda_lora / use_kda_lora":
+            not cfg["no_kda_lora"] or cfg["use_kda_lora"],
+        "linear_silu": not cfg["linear_silu"],
+        "group_norm_size": cfg["group_norm_size"] != 1,
+        "moe_router_enable_expert_bias":
+            not cfg["moe_router_enable_expert_bias"],
+        "moe_shared_expert_intermediate_size":
+            cfg["moe_shared_expert_intermediate_size"]
+            != cfg["moe_intermediate_size"],
+        **{k: cfg[k] for k in ("use_mla_nope", "scale_router_input",
+                               "up_proj_norm", "value_norm", "use_nGPT")}}
+    refused = [k for k, bad in refused.items() if bad]
+    if refused:
+        raise MXNetError(
+            "block='ling_hybrid' builds the published block - a query "
+            "projected directly beside a head-wise output gate, no swiglu "
+            "clamp on a layer that is run (one entry a layer), KDA heads "
+            "as the query's, the bounded decay with full projections, "
+            "SiLU after the convolutions, one norm a head, a router with "
+            "a correction bias, a shared expert of the routed width - and "
+            f"not what {refused} ask(s) for (got "
+            f"{ {k: cfg.get(k) for k in LING_KEYS} })")
+    given = dict(cfg, n_routed_experts=cfg["num_experts"],
+                 n_shared_experts=cfg["num_shared_experts"])
+    latent = _latent_spec(
+        spec, given, ["none"] * n_layer,
+        {"router_bias": True, "n_group": int(cfg["n_group"]),
+         "topk_group": int(cfg["topk_group"])},
+        _yarn_rope(cfg, "ling_hybrid"))
+    latent["cfg"].update(layer_types=kinds, head_gate=True,
+                         kda_chunk=int(cfg.get("kda_chunk", 64)))
+    return dict(latent, attention=_ling_mixer)
+
+
 #: ``block=`` -> its spec constructor: the one place a block is chosen
-#: by its name. A ninth architecture is one more entry and, only if
+#: by its name. A tenth architecture is one more entry and, only if
 #: its attention is new, one more attention function
 _SPECS = {"gpt2": _gpt2_spec, "olmoe": _olmoe_spec, "evabyte": _eva_spec,
           "glm_dsa": _glm_spec, "axk1": _axk1_spec, "afmoe": _afmoe_spec,
-          "xing4": _xing4_spec, "granite_hybrid": _granite_spec}
+          "xing4": _xing4_spec, "granite_hybrid": _granite_spec,
+          "ling_hybrid": _ling_spec}
 
 
 def _spec(given, decode):
@@ -1036,7 +1176,7 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                       chunk=16, n_pred_heads=1, ffn_width=None,
                       multibyte=False, glm=None, afmoe=None,
                       max_step_len=None, axk1=None, xing4=None,
-                      granite=None):
+                      granite=None, ling=None):
     """Incremental KV-cache decoder: ``(B, step_len)`` new token ids in,
     logits ``(B, step_len, vocab)`` out, per-layer K/V caches of
     ``capacity`` positions riding executor aux state. Parameter names
@@ -1071,8 +1211,9 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     config's ``GLM_KEYS``), ``"axk1"`` (``_axk1_spec``: ``axk1``,
     ``AXK1_KEYS``), ``"xing4"`` (``_xing4_spec``: ``xing4``,
     ``XING4_KEYS``), ``"afmoe"`` (``_afmoe_spec``: ``afmoe``,
-    ``AFMOE_KEYS``; ``max_step_len``) and ``"granite_hybrid"``
-    (``_granite_spec``: ``granite``, ``GRANITE_KEYS``).
+    ``AFMOE_KEYS``; ``max_step_len``), ``"granite_hybrid"``
+    (``_granite_spec``: ``granite``, ``GRANITE_KEYS``) and
+    ``"ling_hybrid"`` (``_ling_spec``: ``ling``, ``LING_KEYS``).
 
     Every slot-pooled graph (``per_slot=True``, whatever the block)
     takes one more input, ``fed`` ``(slots,)`` int32 - how many of each
@@ -1468,9 +1609,11 @@ class BatchedKVCacheDecoder:
     ``capture_rows``/``restore_rows`` raise, naming the families.
     ``state_bytes`` is the state's bytes by family.
 
-    A graph with a recurrent mixer (``block="granite_hybrid"``) keeps,
+    A graph with a recurrent mixer (``block="granite_hybrid"``,
+    ``block="ling_hybrid"``) keeps,
     beside its attention layers' ``"rows"``, state that nothing indexes
-    (families ``"conv"`` and ``"recurrent"``, ``ops/ssm.py``: constant
+    (families ``"conv"`` and ``"recurrent"``, ``ops/ssm.py``,
+    ``ops/kda.py``: constant
     in the context, rewritten whole by every dispatch): every token
     since the slot joined is in it and none can be taken out, so a
     cursor goes to 0 - where the program reads the state as zeros - or

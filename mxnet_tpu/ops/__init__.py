@@ -11,6 +11,7 @@ from . import mla  # noqa: F401 — registers mla_attention_decode, dsa_index_se
 from . import rows  # noqa: F401 — registers pack_rows, unpack_rows, last_rows
 from . import mhc  # noqa: F401 — registers mhc_pre, mhc_post
 from . import ssm  # noqa: F401 — registers ssm_mixer_decode
+from . import kda  # noqa: F401 — registers kda_mixer_decode
 from .. import operator as _custom_op  # noqa: F401 — registers Custom
 from . import pallas_kernels  # noqa: F401 — Pallas kernel-tier variants
 from . import quant  # noqa: F401 — int8 PTQ ops + graph rewrite

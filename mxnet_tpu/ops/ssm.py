@@ -79,7 +79,8 @@ from . import pallas_kernels as _pk
 from .registry import read_counts, register
 from .rows import _at
 
-__all__ = ["lane_width", "ssm_recurrence"]
+__all__ = ["lane_width", "ssm_recurrence", "fed_rows_layout",
+           "causal_conv_rows"]
 
 _F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
@@ -108,20 +109,14 @@ def _dot(a, b):
     return jnp.dot(a, b, precision=_HI, preferred_element_type=_F32)
 
 
-def _prologue(attrs, inputs, aux, is_train):
-    """What both lowerings share before they touch the state
-    (``ssm_conv``): each slot's cursor, the rows it is really fed and
-    where they lie, the convolution over those rows and the slot's tail,
-    the new tail, and per row ``x``, ``B``, ``C`` and ``dlt`` (0 on a
-    pad) in float32."""
-    if is_train:
-        raise MXNetError("ssm_mixer_decode is an inference op")
-    data, fed, conv_w, conv_b, dt_bias, a_log, _d = inputs
-    tail, _state, cursor = aux
-    H, P, N, K, _chunk, S, capacity = _geometry(attrs)
-    d_in, C = H * P, H * P + 2 * N
+def fed_rows_layout(fed, cursor, NR, S, capacity):
+    """Where each slot's real rows lie among ``NR`` folded rows (module
+    docstring, **Rows**): ``pos`` the cursors, ``fed`` the rows a slot
+    is really fed (none where S rows have no room under ``capacity``),
+    ``off`` a slot's first row, and per row its slot ``seg``, its index
+    ``t`` inside the slot's rows and whether it is real (``valid``);
+    ``fresh``: the slots whose cursor is 0."""
     slots = cursor.shape[0]
-    NR = data.shape[0]
     pos = cursor.reshape((slots,)).astype(jnp.int32)
     fed = jnp.where(pos + S <= capacity,
                     jnp.clip(fed.reshape((slots,)).astype(jnp.int32), 0, S),
@@ -137,10 +132,20 @@ def _prologue(attrs, inputs, aux, is_train):
                           slots - 1).astype(jnp.int32)
     t = idx - off[seg]                  # a row's index inside its slot's
     valid = (t >= 0) & (t < fed[seg])
-    fresh = pos == 0
-    tail = jnp.where(fresh[:, None, None], 0.0, tail.astype(_F32))
+    return dict(pos=pos, fed=fed, off=off, idx=idx, seg=seg, t=t,
+                valid=valid, fresh=pos == 0)
 
-    xbc = data[:, d_in:d_in + C].astype(_F32)
+
+def causal_conv_rows(xbc, conv_w, tail, lay):
+    """The causal depthwise convolution of the rows ``xbc (NR, C)``
+    under ``conv_w (C, K)``, each slot's first taps reaching into its
+    ``tail (slots, K - 1, C)`` (zeros already where the slot is fresh),
+    before any bias or activation, and the new tail: the last K - 1 of
+    (old tail, the fed rows). ``lay``: ``fed_rows_layout``."""
+    NR, C = xbc.shape
+    slots, K = tail.shape[0], tail.shape[1] + 1
+    fed, off, seg, t, valid = (lay[k] for k in (
+        "fed", "off", "seg", "t", "valid"))
     w = conv_w.astype(_F32)                                  # (C, K)
     # the taps inside the slot's own rows: row i - (K-1) + k, where that
     # is not before the slot's first
@@ -158,7 +163,6 @@ def _prologue(attrs, inputs, aux, is_train):
     key = jnp.where(valid & (t < K - 1), seg * (K - 1) + t, -1)
     first = key[:, None] == jnp.arange(slots * (K - 1))[None, :]
     conv = conv + _dot(first.astype(_F32), corr.reshape(-1, C))
-    act = jax.nn.silu(conv + conv_b.astype(_F32)[None, :])
 
     # the new tail: the last K-1 of (old tail, the fed rows)
     src = jnp.concatenate([tail.reshape(-1, C), xbc], axis=0)
@@ -169,6 +173,29 @@ def _prologue(attrs, inputs, aux, is_train):
                      slots * (K - 1) + off[:, None] + at - (K - 1))
     pick = take.reshape(-1)[:, None] == jnp.arange(src.shape[0])[None, :]
     new_tail = _dot(pick.astype(_F32), src).reshape(slots, K - 1, C)
+    return conv, new_tail
+
+
+def _prologue(attrs, inputs, aux, is_train):
+    """What both lowerings share before they touch the state
+    (``ssm_conv``): each slot's cursor, the rows it is really fed and
+    where they lie, the convolution over those rows and the slot's tail,
+    the new tail, and per row ``x``, ``B``, ``C`` and ``dlt`` (0 on a
+    pad) in float32."""
+    if is_train:
+        raise MXNetError("ssm_mixer_decode is an inference op")
+    data, fed, conv_w, conv_b, dt_bias, a_log, _d = inputs
+    tail, _state, cursor = aux
+    H, P, N, K, _chunk, S, capacity = _geometry(attrs)
+    d_in, C = H * P, H * P + 2 * N
+    NR = data.shape[0]
+    lay = fed_rows_layout(fed, cursor, NR, S, capacity)
+    pos, fed, off, idx, valid = (lay[k] for k in (
+        "pos", "fed", "off", "idx", "valid"))
+    tail = jnp.where(lay["fresh"][:, None, None], 0.0, tail.astype(_F32))
+    xbc = data[:, d_in:d_in + C].astype(_F32)
+    conv, new_tail = causal_conv_rows(xbc, conv_w, tail, lay)
+    act = jax.nn.silu(conv + conv_b.astype(_F32)[None, :])
 
     dlt = jax.nn.softplus(data[:, d_in + C:].astype(_F32)
                           + dt_bias.astype(_F32)[None, :])

@@ -181,6 +181,49 @@ def test_ssm_mixer_compiles_for_v5e_and_copies_no_state(S, rows, v5e):
         32 * d_in * N * 4
 
 
+@pytest.mark.parametrize("S,rows", [(1, 32), (256, 384)],
+                         ids=["decode", "packed_window"])
+def test_kda_mixer_compiles_for_v5e_and_copies_no_state(S, rows, v5e):
+    """Ling-3.0-flash's KDA mixer at the published sizes (32 slots, 32
+    heads of 128 x 128, convolutions over 4, chunks of 64; the S = 1
+    program's 32 rows and the packed window's 384): the Pallas lowering
+    compiles for the chip - the step's kernel, and in a window the
+    chunk's inside XLA's loop over the trips -, and with the aux arrays
+    donated the 67 MB state comes back in the buffer it came in, never
+    copied."""
+    import re
+    opdef = get_op("kda_mixer_decode")
+    H, D, K = 32, 128, 4
+    HD = H * D
+    attrs = opdef.normalize_attrs(dict(
+        heads=H, head_dim=D, d_conv=K, chunk=64, step_len=S,
+        capacity=16384, lower_bound=-5.0, rms_eps=1e-6))
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    ins = [sds((rows, 5 * HD + H)), sds((32,), "int32"), sds((3 * HD, K)),
+           sds((H,)), sds((HD,)), sds((D,))]
+    aux = [sds((32, K - 1, 3 * HD), "float32"),
+           sds((32, H, D, D), "float32"), sds((32, 1), "int32")]
+    assert opdef.donate_aux
+    assert opdef.variant_eligible("pallas", attrs,
+                                  [a.shape for a in ins + aux],
+                                  [str(a.dtype) for a in ins + aux])
+    fn = opdef.variant_fn("pallas")
+    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
+                       donate_argnums=(1,)).lower(ins, aux).compile()
+    text = compiled.as_text()
+    kernels = ["kda_update"] + (["kda_chunk"] if S > 1 else [])
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}[.\w]* = .*tpu_custom_call", text), \
+            kernel
+    assert len(re.findall("tpu_custom_call", text)) == len(kernels)
+    assert not re.findall(r"= f32\[32,32,128,128\]\S* copy\(", text)
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        32 * H * D * D * 4
+
+
 @pytest.mark.parametrize("S", [1, 64], ids=["decode", "window"])
 @pytest.mark.parametrize("capacity", [2048, 4096],
                          ids=["cerebras", "olmoe"])

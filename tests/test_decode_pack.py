@@ -61,7 +61,10 @@ TOL = {"evabyte": 0.0, "glm_dsa": 2e-5, "axk1": 2e-5, "afmoe": 2e-5,
        "xing4": 2e-4, "gpt2": 2e-6, "gpt2_rotary": 2e-6, "olmoe": 0.0,
        # the segmented convolution and the one-hot products sum the
        # same terms over 24 rows and over 64
-       "granite_hybrid": 2e-5}
+       "granite_hybrid": 2e-5,
+       # the same, and the shared expert's (the state itself goes through
+       # the same chunks in either form)
+       "ling_hybrid": 2e-5}
 
 #: ``packed_rows`` before ISSUE 47: a chunk and a token a slot, rounded
 #: up to the tile (128 rows; 8 under that)

@@ -1,6 +1,6 @@
 """The slot-pooled decoders at tiny sizes - GLM-5.2's block, A.X-K1's,
-Xing4.0's, Trinity's, Granite 4.0-H's and EvaByte's, which are served
-alone (``BLOCKS``),
+Xing4.0's, Trinity's, Granite 4.0-H's, Ling-3.0's and EvaByte's, which
+are served alone (``BLOCKS``),
 and the two that are trained too (``FUSED``: GPT-2's with learned and
 with rotary positions, OLMoE's), fed like the others since ISSUE 47 -
 for the tests of a window's packed rows (``tests/test_decode_pack.py``)
@@ -52,6 +52,26 @@ _GRANITE = {"num_key_value_heads": 2,
             "embedding_multiplier": 12.0, "residual_multiplier": 0.22,
             "attention_multiplier": 0.125, "logits_scaling": 8.0}
 
+_LING = {"layer_types": ["kda", "kda", "mla"], "head_dim": 16,
+         "short_conv_kernel_size": 4, "kda_lower_bound": -5,
+         "kda_safe_gate": True, "no_kda_lora": True, "use_kda_lora": False,
+         "linear_silu": True, "group_norm_size": 1,
+         "num_kv_heads_for_linear_attn": 0, "q_lora_rank": None,
+         "kv_lora_rank": 64, "qk_nope_head_dim": 24, "qk_rope_head_dim": 16,
+         "v_head_dim": 16, "rope_scaling": None,
+         "gated_attention_proj_granularity_type": "head_wise",
+         "use_mla_nope": False, "first_k_dense_replace": 1,
+         "intermediate_size": 96, "moe_intermediate_size": 32,
+         "moe_shared_expert_intermediate_size": 32, "num_experts": 16,
+         "num_experts_per_tok": 4, "num_shared_experts": 1,
+         "routed_scaling_factor": 2.5, "norm_topk_prob": True, "n_group": 4,
+         "topk_group": 2, "moe_router_enable_expert_bias": True,
+         "scale_router_input": False,
+         "expert_swiglu_limit_list": [0, 0, 0],
+         "share_expert_swiglu_limit_list": [0, 0, 0], "up_proj_norm": False,
+         "value_norm": False, "use_nGPT": False, "held": (4, 8),
+         "kda_chunk": 8}
+
 #: block -> ``get_decode_symbol``'s arguments beside the step length
 BLOCKS = {
     "glm_dsa": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
@@ -64,6 +84,8 @@ BLOCKS = {
                   rope_base=1e4, afmoe=_AFMOE, max_step_len=WINDOW),
     "granite_hybrid": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
                            granite=_GRANITE),
+    "ling_hybrid": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
+                        rope_base=6e6, rms_eps=1e-6, ling=_LING),
     "evabyte": dict(vocab_size=40, d_model=32, n_layer=2, n_head=2,
                     rope_base=1e5, window=32, chunk=4, n_pred_heads=2,
                     ffn_width=48),

@@ -22,16 +22,16 @@ the last; slot 0's row at the last position (and of the whole-window
 program its last sixteen) against the architecture's plain float32
 reference under its ``LOGIT_TOL``; whether the rows each path wrote to
 the positional pools are equal (for a graph that carries state no
-position indexes - ``granite-4.0-h-micro``'s convolution tails and
-recurrent states - slot 0's whole state after the last window, every
-family); and the median window's time on the host's clock in either
-form. ``--part N`` feeds slot 1 N rows of a sequence of its own a
+position indexes - ``granite-4.0-h-micro``'s and ``ling-3.0-flash``'s
+convolution tails and recurrent states - slot 0's whole state after the
+last window, every family); and the median window's time on the
+host's clock in either form. ``--part N`` feeds slot 1 N rows of a sequence of its own a
 window in place of one token, so that a whole chunk, a part of a chunk
 and riders share one dispatch. Prints one JSON line.
 
     python3 tools/window_pack_check.py
         --config a.x-k1|glm-5.2|xing4.0-29b-a4b|cerebras-gpt-1.3b|olmoe-1b-7b
-                 |granite-4.0-h-micro
+                 |granite-4.0-h-micro|ling-3.0-flash
         [--seed N] [--part N] [--rehearse]
 
 ``--rehearse`` runs the configuration's tiny fixture on the CPU
@@ -57,6 +57,7 @@ _TINY = {"a.x-k1": ("axk1", "tiny-axk1.json"),
          "xing4.0-29b-a4b": ("xing4", "tiny-xing4.json"),
          "olmoe-1b-7b": ("olmoe", "tiny-olmoe.json"),
          "granite-4.0-h-micro": ("granite_hybrid", "tiny-granite.json"),
+         "ling-3.0-flash": ("ling_hybrid", "tiny-ling.json"),
          "cerebras-gpt-1.3b": None}
 
 #: the Cerebras configuration cut to a rehearsal's size (learned
