@@ -1650,6 +1650,7 @@ class BatchedKVCacheDecoder:
         self._cursor_program = None                  # built at first use
         self._row_progs = None                       # capture, restore
         self._select_programs = {}                   # step_len -> program
+        self._merge_programs = {}                    # step_len -> program
         self._moe_program = None                     # built at first use
         exe = module._exec_group.executor
         # what every step program takes over and updates in place (the
@@ -1983,6 +1984,41 @@ class BatchedKVCacheDecoder:
             ids.copy_to_host_async()
         self.last_select = None if now is None else now() - t0
         return rows, ids, tokens
+
+    def merge_tokens(self, tokens, ids, chip):
+        """The token input of a step that takes some slots' first token
+        from the chip: the host's ``(slots, S)`` ``tokens`` with column
+        0 of the slots that ``chip`` (``(slots,)`` bools) names taken
+        from ``ids``, ``select_rows``' third result of the step before
+        as it lies on the device. One launch of
+        ``merge_tokens_<slots>x<S>`` (its name in the trace), one
+        program a step length whatever ``chip`` holds; the result stays
+        on the device, in the data cell's dtype and where a step's
+        batch lies, so that ``step`` puts nothing and launches the
+        program it launches for the host's tokens."""
+        tokens = np.asarray(tokens)
+        chip = np.asarray(chip, bool).reshape(-1)
+        S = tokens.shape[-1]
+        if tokens.shape != (self.slots, S) or \
+                chip.shape != (self.slots,) or \
+                ids.shape != (self.slots, 1):
+            raise MXNetError(
+                f"merge_tokens() wants ({self.slots}, S) tokens, "
+                f"({self.slots}, 1) ids and ({self.slots},) flags, "
+                f"got {tokens.shape}, {ids.shape}, {chip.shape}")
+        program = self._merge_programs.get(S)
+        if program is None:
+            import jax
+            import jax.numpy as jnp
+
+            def merge_tokens(tokens, ids, chip):
+                first = jnp.arange(tokens.shape[1]) == 0
+                return jnp.where(chip[:, None] & first[None, :],
+                                 ids, tokens.astype(ids.dtype))
+
+            merge_tokens.__name__ = f"merge_tokens_{self.slots}x{S}"
+            program = self._merge_programs[S] = jax.jit(merge_tokens)
+        return program(tokens, ids, chip)
 
     def join(self, slot):
         """Claim ``slot`` for a new sequence: set its device cursor to

@@ -28,11 +28,13 @@ pinned program per iteration:
   step picks each slot's last fed row and takes its argmax on the
   device; the rows themselves are fetched only for an iteration in
   which a slot that samples is not greedy. One dispatch may be in
-  flight ahead of the host: where the next dispatch is an S=1 step
-  that the scheduler can plan without the ids of the one on the chip
-  (everybody greedy and decoding, nobody finishing by length, nobody
-  to admit), it is launched before those ids are fetched, fed from the
-  chip by the select program, and committed an iteration later
+  flight ahead of the host: where the scheduler can plan the next
+  dispatch, window or S=1 step, without the ids of the one on the chip
+  (whoever samples there and stays is greedy; whoever waits is
+  admitted into a free slot of the same rung), it is launched before
+  those ids are fetched, the slots
+  that sampled fed from the chip by the select program and the slots
+  that still prefill by the host, and committed an iteration later
   (``DecodeScheduler._plan_ahead``); every other dispatch is planned
   after its predecessor's commit. Two
   drive modes, same as the server: ``start()`` (dispatch thread, real
@@ -84,7 +86,8 @@ Telemetry (always on, docs/serving.md has the catalog):
 ``serve.decode.iterations``/``tokens``/``joins``/``leaves``/
 ``migrations``/``requests``/``responses``/``errors``/``fetch.bytes``/
 ``sample.device``/``sample.host``/``state.donated_bytes``/
-``runahead.launched``/``runahead.dropped`` counters,
+``runahead.launched``/``runahead.windows``/``runahead.dropped``/
+``window.dispatches`` counters,
 ``serve.decode.step.seconds`` + ``serve.decode.request.latency.seconds``
 histograms, and one flight-ring record per iteration.
 """
@@ -219,9 +222,9 @@ class _Sequence:
         prefilling)."""
         return self.stream_len() - self.fed
 
-    def window(self, n):
-        """The next ``n`` stream tokens to feed."""
-        return [self.stream_token(self.fed + j) for j in range(n)]
+    def window(self, at, n):
+        """The ``n`` stream tokens from position ``at`` on."""
+        return [self.stream_token(at + j) for j in range(n)]
 
 
 class DecodeHandle:
@@ -589,9 +592,12 @@ class DecodeEngine:
         program, each with its select program behind it (two steps
         each: first pays the traces, second measures steady state on
         ``clock`` the way an iteration runs it - step, select, token
-        ids on the host - and a third S=1 step fed the second's ids as
-        they lie on the device, the form of a dispatch that the
-        scheduler launches ahead), pin them all, record the compile
+        ids on the host - and one more of each length fed the ids of
+        the step before from the device, the form of a dispatch that
+        the scheduler launches ahead: an S=1 step fed them as they lie
+        there, and a step of every length fed the host's tokens with
+        the riders' first column merged in from the chip,
+        ``drv.merge_tokens``), pin them all, record the compile
         delta.
         Where a window has a packed program (``window_budget``) that is
         the one a scheduler dispatches, and the one warmed: fed as a
@@ -623,13 +629,16 @@ class DecodeEngine:
                 return np.asarray(launch(tokens, fed)[0])
 
             zeros = np.zeros((rung, 1), np.int32)
+            riders = np.arange(rung) > 0
             step_ids(zeros)                      # trace + compile
             t0 = clock.now()
             ids, nxt = launch(zeros)             # steady state
             np.asarray(ids)
             self.exec_est[rung] = max(0.0, clock.now() - t0)
-            # a dispatch that runs ahead takes its tokens from the chip
-            step_ids(nxt)
+            # a dispatch that runs ahead takes its tokens from the chip:
+            # as they lie there, or merged into the host's
+            _, nxt = launch(nxt)
+            step_ids(drv.merge_tokens(zeros, nxt, riders))
             for S in drv.window_lens:
                 wz = np.zeros((rung, S), np.int32)
                 fed = None if drv.window_budget(S) is None \
@@ -641,8 +650,11 @@ class DecodeEngine:
                 step_ids(wz, fed)                # trace + compile
                 drv.rewind_many(list(range(rung)), [0] * rung)
                 t0 = clock.now()
-                step_ids(wz, fed)                # steady state
+                ids, nxt = launch(wz, fed)       # steady state
+                np.asarray(ids)
                 self.exec_est[(rung, S)] = max(0.0, clock.now() - t0)
+                drv.rewind_many(list(range(rung)), [0] * rung)
+                step_ids(drv.merge_tokens(wz, nxt, riders), fed)
             if rows and drv.positional:
                 drv.warm_rows()
             drv.active[:] = False
@@ -760,7 +772,9 @@ class DecodeEngine:
 
 
 #: ``serve.decode.<name>`` counters of the S > 1 window dispatches, from
-#: the plan (no fetch): the slots fed at least one row, and those fed
+#: the plan (no fetch): the dispatches themselves (beside them
+#: ``runahead.windows``, those launched before their predecessor's ids
+#: were on the host), the slots fed at least one row, and those fed
 #: exactly one - a decoding slot riding a window in which another
 #: prefills, its other S - 1 rows pads. riding / fed is the traffic's,
 #: whatever kernel serves it. Then the rows that were real tokens, and
@@ -771,9 +785,9 @@ class DecodeEngine:
 #: vocabulary: the same, but of a packed program each slot's last fed
 #: row alone (``slots``) - so head / program says how often the form
 #: that selects before the head engaged
-_WINDOW_COUNTERS = ("window.fed_slots", "window.riding_slots",
-                    "window.real_rows", "window.program_rows",
-                    "window.head_rows")
+_WINDOW_COUNTERS = ("window.dispatches", "window.fed_slots",
+                    "window.riding_slots", "window.real_rows",
+                    "window.program_rows", "window.head_rows")
 
 
 #: what one dispatch's launches left on the device (``_launch``): the
@@ -787,21 +801,25 @@ _Launched = collections.namedtuple(
 class _Dispatch:
     """One dispatch from its plan to its commit: what the plan fixed
     (``mode``, ``S``, ``meta``: ``(row, seq[, n_fed])`` of the slots it
-    feeds; the ``tokens``, each slot's ``last`` fed row, ``fed``, and
-    ``feed``, the rows somebody owns), what ``_launch`` left on the
+    feeds; the host's ``tokens``, each slot's ``last`` fed row, ``fed``,
+    and ``feed``, the slots that sample at it; of a dispatch launched
+    ``ahead`` also ``chip``, the slots whose one token is the id that
+    its predecessor left on the chip - None where nobody's is, and
+    ``tokens`` None where everybody's is and those ids are the tokens
+    as they lie), what ``_launch`` left on the
     device (``launched``), and its own clock: ``t0`` where its step
     starts (the plan's first reading, or for a dispatch launched
     ``ahead`` the moment its predecessor's ids were on the host),
     ``plan_s`` and ``phases``."""
 
     __slots__ = ("mode", "S", "meta", "tokens", "last", "fed", "feed",
-                 "want_rows", "n_active", "shared_sid", "t0", "plan_s",
-                 "phases", "ahead", "rewound", "launched")
+                 "chip", "want_rows", "n_active", "shared_sid", "t0",
+                 "plan_s", "phases", "ahead", "rewound", "launched")
 
     def __init__(self, mode, S, t0, ahead=False):
         self.mode, self.S, self.t0, self.ahead = mode, S, t0, ahead
         self.meta = []
-        self.tokens = self.last = self.fed = self.feed = None
+        self.tokens = self.last = self.fed = self.feed = self.chip = None
         self.want_rows = self.rewound = False
         self.n_active = 0
         self.shared_sid = self.launched = None
@@ -981,7 +999,8 @@ class DecodeScheduler:
             handles = {k: self._counter(k) for k in
                        ("iterations", "tokens", "prefill.chunks",
                         "fetch.bytes", "sample.device", "sample.host",
-                        "runahead.launched", "runahead.dropped")
+                        "runahead.launched", "runahead.dropped",
+                        "runahead.windows")
                        + _WINDOW_COUNTERS}
             # what the graph's ops count of a dispatch (the driver's
             # ``read_counts``: ``OpDef.state_reads``)
@@ -1190,62 +1209,128 @@ class DecodeScheduler:
                           t_join, self._clock.now(), parent=seq.root_sid,
                           slot=row, cursor=c, bytes=put)
 
-    def _plan_dispatch(self):
-        """Pick this iteration's dispatch shape (caller holds the
-        lock): ``("window", S)`` — every active slot feeds up to S
-        stream tokens (S = prefill chunk while anyone prefills and
-        every live cursor has room, else 1) — or ``("spec", K)`` when
-        speculation is armed and every active slot is in steady state
-        with K positions of cache headroom on both engines."""
-        drv = self.engine.driver(self._rung)
+    def _cursors(self, after=None):
+        """``(row, seq, at, left)`` of every active slot (caller holds
+        the lock): the stream position its next token is fed at and the
+        stream tokens it has left to feed, as they stand (``seq.fed``,
+        ``seq.remaining()``) or as the commit of the dispatch ``after``
+        will leave them: ``at`` further by what ``after`` feeds the
+        slot's owner (nothing where it is not in ``after.meta``, or
+        joined a row that ``after`` feeds for somebody gone), and one
+        token left, the id on its way, where the slot samples at
+        ``after`` - unless that token is its last by length, which
+        takes the slot out. Beside them the rows whose owner samples at
+        ``after`` and stays, and the cursors of those who leave."""
+        fed_by = {} if after is None else {
+            row: n for row, seq, n in after.meta
+            if self._slots[row] is seq}
+        cursors, sampled, leaving = [], set(), []
+        for row, seq in enumerate(self._slots):
+            if seq is None:
+                continue
+            n = fed_by.get(row, 0)
+            left = seq.remaining() - n
+            if left == 0:       # samples at ``after``
+                if len(seq.generated) + 1 >= seq.max_new:
+                    leaving.append(seq.fed + n)
+                    continue
+                sampled.add(row)
+            cursors.append((row, seq, seq.fed + n, left or 1))
+        return cursors, sampled, leaving
+
+    def _plan_dispatch(self, cursors):
+        """Pick a dispatch's shape from ``cursors`` (``_cursors``;
+        caller holds the lock): ``("window", S)`` — every active slot
+        feeds up to S stream tokens (S = prefill chunk while anyone
+        prefills and every live cursor has room, else 1) — or
+        ``("spec", K)`` when speculation is armed and every active slot
+        is in steady state with K positions of cache headroom on both
+        engines."""
         ddrv = self.draft.driver(self._rung) if self.draft else None
-        prefilling = any(s.remaining() > 1 for s in self._active())
-        if prefilling:
+
+        def room(S):
+            return all(at + S <= self.engine.capacity
+                       for _row, _seq, at, _left in cursors) and \
+                (ddrv is None or not ddrv.overflowing(S))
+
+        if any(left > 1 for _row, _seq, _at, left in cursors):
             S = self.prefill_chunk
-            if S > 1 and not drv.overflowing(S) and \
-                    (ddrv is None or not ddrv.overflowing(S)):
-                return "window", S
-            return "window", 1
-        if self.spec_k and ddrv is not None and \
-                not drv.overflowing(self.spec_k) and \
-                not ddrv.overflowing(self.spec_k):
+            return "window", S if S > 1 and room(S) else 1
+        if self.spec_k and ddrv is not None and room(self.spec_k):
             return "spec", self.spec_k
         return "window", 1
 
-    def _plan_window(self, S):
+    def _plan_window(self, S, cursors):
         """``(row, seq, n)`` of every slot that this S-row dispatch
-        feeds, ``n`` >= 1 stream tokens each (caller holds the lock).
-        Without a budget every active slot takes ``min(S, remaining)``.
+        feeds, ``n`` >= 1 stream tokens each, from ``cursors``
+        (``_cursors``; caller holds the lock).
+        Without a budget every active slot takes ``min(S, left)``.
         Where the engine has a packed program for this rung and length
         (``window_budget``: R rows between the slots; a fed engine
         alone), the window is planned inside it: decoding slots take
         their one token first, then the prefilling slots ``min(S,
-        remaining, what is left of R)``, oldest admission first. A
+        left, what is left of R)``, oldest admission first. A
         prefilling slot for which nothing is left is fed nothing this
         window and is not in the plan: the program leaves it where it
         is. R holds a whole chunk beside a token a slot, so the oldest
         prefilling slot always moves."""
-        seqs = [(row, seq) for row, seq in enumerate(self._slots)
-                if seq is not None]
         budget = None if S == 1 \
             else self.engine.window_budget(self._rung, S)
         if budget is None:
-            return [(row, seq, min(S, seq.remaining()))
-                    for row, seq in seqs]
-        left = budget - sum(seq.remaining() == 1 for _row, seq in seqs)
+            return [(row, seq, min(S, left))
+                    for row, seq, _at, left in cursors]
+        room = budget - sum(left == 1 for _row, _seq, _at, left in cursors)
         plan = []
         # a sequence's id counts submissions, and admission is in order
-        for row, seq in sorted(seqs, key=lambda rs: rs[1].id):
+        for row, seq, _at, left in sorted(cursors, key=lambda c: c[1].id):
             n = 1
-            if seq.remaining() > 1:
-                n = min(S, seq.remaining(), left)
-                left -= n
+            if left > 1:
+                n = min(S, left, room)
+                room -= n
             if n:
                 plan.append((row, seq, n))
         return sorted(plan, key=lambda entry: entry[0])
 
+    def _fill_window(self, d, cursors, now, chip=()):
+        """Fill the window (or S=1) dispatch ``d`` from the plan of its
+        length over ``cursors`` (caller holds the lock): the tokens,
+        each slot's last fed row, ``fed``, ``feed``, whether a row has
+        to come to the host, ``meta``. A slot among ``chip`` takes as
+        its one token the id that the dispatch before leaves on the
+        chip (``_launch`` merges it in): the host's entry stays 0."""
+        at = {row: (pos, left) for row, _seq, pos, left in cursors}
+        d.tokens = np.zeros((self._rung, d.S), np.int32)
+        # each slot's last fed row (0 where nobody owns the row), and
+        # whether a slot that samples now needs the row itself on the
+        # host: a greedy one needs its id only
+        d.last = np.zeros(self._rung, np.int32)
+        # the slots that sample at this dispatch: the ids a dispatch
+        # behind it is fed from the chip (a row in mid-prompt has an
+        # argmax too, which nobody may be fed)
+        d.feed = np.zeros(self._rung, bool)
+        # a fed decoder advances each slot by its real tokens alone: a
+        # row nobody owns, or one the budget left out, is fed nothing
+        d.fed = np.zeros(self._rung, np.int32) \
+            if self.engine.feeds else None
+        for row, seq, n in self._plan_window(d.S, cursors):
+            pos, left = at[row]
+            if row not in chip:
+                d.tokens[row, :n] = seq.window(pos, n)
+            d.last[row] = n - 1
+            d.feed[row] = n == left
+            if d.fed is not None:
+                d.fed[row] = n
+            if n == left and not seq.sampling.greedy:
+                d.want_rows = True
+            if seq.first_dispatch_at is None:
+                seq.first_dispatch_at = now
+            d.meta.append((row, seq, n))
+        d.n_active = len(cursors)
+        if any(seq.trace is not None for _row, seq, _at, _left in cursors):
+            d.shared_sid = _trace.next_span_id()
+
     def _launch(self, drv, tokens, phases, t=None, last=None, rows=False,
-                fed=None, feed=None):
+                fed=None, feed=None, chip=None):
         """One dispatch's launches, ``serve.decode.iter.dispatch``:
         ``drv.step`` (staging and launch), where ``last`` names each
         slot's last fed row ``drv.select_rows`` behind it, which picks
@@ -1255,7 +1340,10 @@ class DecodeScheduler:
         next step's launch would take away, so that another dispatch
         may be launched before this one is fetched. ``tokens`` are the
         host's, or the ids of the dispatch before as they lie on the
-        chip; ``feed`` says whose id this dispatch's own device tokens
+        chip, or with ``chip`` (those ids, and whose they are) the
+        host's with column 0 of those slots taken from the chip
+        (``drv.merge_tokens``, one small launch in front of the step);
+        ``feed`` says whose id this dispatch's own device tokens
         carry (``select_rows``). ``fed`` (a decoder that is fed: the
         real tokens of each slot) rides in the same put as the tokens.
         Adds the duration to ``phases["dispatch"]`` on the scheduler's
@@ -1276,6 +1364,8 @@ class DecodeScheduler:
             t = now()
         picked = ids = nxt = None
         with _telemetry.span("serve.decode.iter.dispatch"):
+            if chip is not None:
+                tokens = drv.merge_tokens(tokens, *chip)
             out = drv.step(tokens, fed=fed, now=now)
             drv.release_outputs()       # ``out`` is this call's alone
             phases["reads"].update(drv.last_reads)
@@ -1425,38 +1515,15 @@ class DecodeScheduler:
         target = self.engine.ladder.bucket_for(len(active))
         if target is not None and target < self._rung:
             self._switch_rung(target)
-        d = _Dispatch(*self._plan_dispatch(), t0=now)
-        if d.mode == "spec":
-            d.tokens = np.zeros((self._rung, 1), np.int32)
-            for row, seq in enumerate(self._slots):
-                if seq is None:
-                    continue
-                d.tokens[row, 0] = seq.stream_token(seq.fed)
-                d.meta.append((row, seq))
-        else:
-            d.tokens = np.zeros((self._rung, d.S), np.int32)
-            # each slot's last fed row (0 where nobody owns the
-            # row), and whether a slot that samples now needs the
-            # row itself on the host: a greedy one needs its id only
-            d.last = np.zeros(self._rung, np.int32)
-            # the rows somebody owns: the ids a step after this one
-            # could be fed from the chip
-            d.feed = np.zeros(self._rung, bool)
-            # a fed decoder advances each slot by its real tokens
-            # alone: a row nobody owns is fed nothing
-            d.fed = np.zeros(self._rung, np.int32) \
-                if self.engine.feeds else None
-            for row, seq, n in self._plan_window(d.S):
-                d.tokens[row, :n] = seq.window(n)
-                d.last[row] = n - 1
-                d.feed[row] = True
-                if d.fed is not None:
-                    d.fed[row] = n
-                if n == seq.remaining() and not seq.sampling.greedy:
-                    d.want_rows = True
-                d.meta.append((row, seq, n))
-        for entry in d.meta:
-            seq = entry[1]
+        cursors = self._cursors()[0]
+        d = _Dispatch(*self._plan_dispatch(cursors), t0=now)
+        if d.mode != "spec":
+            self._fill_window(d, cursors, now)
+            return d
+        d.tokens = np.zeros((self._rung, 1), np.int32)
+        for row, seq, at, _left in cursors:
+            d.tokens[row, 0] = seq.stream_token(at)
+            d.meta.append((row, seq))
             if seq.first_dispatch_at is None:
                 seq.first_dispatch_at = now
         d.n_active = len(active)
@@ -1465,55 +1532,79 @@ class DecodeScheduler:
         return d
 
     def _plan_ahead(self, d, now):
-        """The S=1 dispatch behind ``d``, planned while ``d`` is still
-        on the chip (caller holds the lock), or None where the host
-        cannot know it without ``d``'s ids. It can where
-        ``_plan_locked`` after ``d``'s commit would plan ``("window",
-        1)`` over the same slots with nothing else to do: every active
-        slot samples at ``d`` (none has stream tokens left) and is
-        greedy, no draft shadows the dispatch, nobody finishes at ``d``
-        by length, no deadline has passed (the caller has retired the
-        active sequences whose has; a queued one waits for a plan), no
-        slot would overflow its cache, the rung stays, and admission
-        would admit nobody. Then the dispatch feeds each slot the id
-        ``d``'s select program left on the chip. What the host cannot
-        know is an EOS: a slot that retires at ``d``'s commit has had
+        """The dispatch behind ``d``, of whatever length, planned while
+        ``d`` is still on the chip (caller holds the lock), or None
+        where the host cannot know it without ``d``'s ids. What
+        ``_plan_locked`` plans after ``d``'s commit follows from every
+        slot's cursor, prompt and ``max_new``, the queue, the deadlines
+        and the rung, which the host has now; only the value of the id
+        that a slot samples at ``d`` it has not, and ``d``'s select
+        program leaves that on the chip. So this admits whom
+        ``_admit_locked`` admits (a join behind ``d`` on the device)
+        and plans with ``_plan_dispatch`` and ``_plan_window`` over the
+        cursors as ``d``'s commit will leave them
+        (``_cursors(after=d)``): a slot whose last token by length is
+        ``d``'s is gone, a slot that still prefills takes the host's
+        tokens, a slot that samples at ``d`` the chip's id as its one
+        token (``_fill_window(chip=)``).
+
+        None - plan after the commit - where ``_plan_locked`` would do
+        what cannot be done behind ``d``'s back: a draft shadows the
+        dispatch; a slot that samples at ``d`` is not greedy (its row
+        has to be on the host first); somebody waits in the queue and a
+        deadline there has passed (the caller has retired the active
+        sequences whose has), a slot that ``d``'s commit frees would be
+        theirs, a larger rung would, or one names a prefix that a store
+        might join at a cursor; a smaller rung is due; a slot would
+        overflow its
+        cache. And where the dispatch behind is a window that ``d``'s
+        state is not ready for: an engine that is not fed advances
+        every cursor by S and is rewound in between. (A prefix captured
+        at ``d``'s commit reads rows below the cursor, which a dispatch
+        behind only appends to where the state is a row a position -
+        all a prefix store is built over, ``__init__`` refuses any
+        other: ``docs/serving.md``.) What
+        the host cannot know is an EOS, or whom a caller submits from
+        ``d``'s callbacks: a slot that retires at ``d``'s commit has had
         one token computed for it, which the commit of this dispatch
-        drops. ``d`` need not be launched yet: the tokens are ``d``'s
-        to give once it is."""
+        drops, and a request that arrives while this dispatch is on its
+        way is admitted by the plan behind it. ``d`` need not be
+        launched yet: the ids are ``d``'s to give once it is."""
         if d.mode != "window" or self.draft is not None:
             return None
-        if self._queue and (
-                None in self._slots
-                or self._rung < self.engine.ladder.max
-                or any(s.deadline is not None and now > s.deadline
-                       for s in self._queue)):
+        cursors, sampled, leaving = self._cursors(after=d)
+        if any(at + 1 > self.engine.capacity
+               for _row, _seq, at, _left in cursors) or \
+                not all(self._slots[row].sampling.greedy for row in sampled):
             return None
-        fed_by = {row: (seq, n) for row, seq, n in d.meta}
-        nxt = _Dispatch("window", 1, t0=None, ahead=True)
-        nxt.last = np.zeros(self._rung, np.int32)
-        nxt.feed = np.zeros(self._rung, bool)
-        nxt.fed = np.zeros(self._rung, np.int32) \
-            if self.engine.feeds else None
-        for row, seq in enumerate(self._slots):
-            if seq is None:
-                continue
-            fed_seq, n = fed_by.get(row, (None, 0))
-            if fed_seq is not seq or not seq.sampling.greedy \
-                    or seq.fed + n != seq.stream_len() \
-                    or len(seq.generated) + 1 >= seq.max_new \
-                    or seq.fed + n + 1 > self.engine.capacity:
+        if self._queue:
+            want = min(len(self._active()) + len(self._queue),
+                       self.engine.ladder.max)
+            if leaving or self.engine.ladder.bucket_for(want) > self._rung \
+                    or any(s.deadline is not None and now > s.deadline
+                           or s.prefix_id is not None
+                           and self.prefix_store is not None
+                           for s in self._queue):
                 return None
-            nxt.feed[row] = True
-            if nxt.fed is not None:
-                nxt.fed[row] = 1
-            nxt.meta.append((row, seq, 1))
-        if not nxt.meta or \
-                self.engine.ladder.bucket_for(len(nxt.meta)) != self._rung:
+            if None in self._slots:
+                self._admit_locked(now)
+                cursors, sampled, leaving = self._cursors(after=d)
+        if not cursors or \
+                self.engine.ladder.bucket_for(len(cursors)) != self._rung:
             return None         # nobody left, or a smaller rung is due
-        nxt.n_active = len(nxt.meta)
-        if any(seq.trace is not None for _row, seq, _n in nxt.meta):
-            nxt.shared_sid = _trace.next_span_id()
+        mode, S = self._plan_dispatch(cursors)
+        # until ``d``'s commit whoever leaves there is the driver's to
+        # guard: it refuses a step that their cursor has no room for
+        if S > 1 and not self.engine.feeds or \
+                any(at + S > self.engine.capacity for at in leaving):
+            return None
+        nxt = _Dispatch(mode, S, t0=None, ahead=True)
+        self._fill_window(nxt, cursors, now, chip=sampled)
+        if S == 1 and len(sampled) == len(nxt.meta):
+            nxt.tokens = None   # ``d``'s ids as they lie on the chip
+        elif sampled:
+            nxt.chip = np.zeros(self._rung, bool)
+            nxt.chip[sorted(sampled)] = True
         return nxt
 
     def _run_iteration(self, iter_span):
@@ -1521,8 +1612,9 @@ class DecodeScheduler:
         (``self._ahead``), or one planned and launched here. Between
         its launch and the fetch of its ids the dispatch behind it is
         launched too where the host can plan it without them
-        (``_plan_ahead``): the chip then goes from one S=1 program to
-        the next while the host fetches, commits and plans."""
+        (``_plan_ahead``): the chip then goes from one program to the
+        next, window or S=1 step, while the host fetches, commits and
+        plans."""
         span = _telemetry.span
         clock = self._clock.now
         # a submit (a closed-loop caller's done callback) holds the lock
@@ -1577,9 +1669,12 @@ class DecodeScheduler:
                         with span("serve.decode.iter.rewind"):
                             drv.rewind_many(*zip(*behind))
                     d.rewound = True
+                ids = d.launched.tokens
                 nxt.launched, planned = self._launch(
-                    drv, d.launched.tokens, nxt.phases, t=planned,
-                    last=nxt.last, fed=nxt.fed, feed=nxt.feed)
+                    drv, ids if nxt.tokens is None else nxt.tokens,
+                    nxt.phases, t=planned, last=nxt.last,
+                    rows=nxt.want_rows, fed=nxt.fed, feed=nxt.feed,
+                    chip=None if nxt.chip is None else (ids, nxt.chip))
             ids, picked, end = self._fetch(drv, d.launched, d.phases,
                                            planned, rows=d.want_rows)
             if nxt is not None:
@@ -1647,6 +1742,9 @@ class DecodeScheduler:
                     m["runahead.launched"].inc()
                 m["fetch.bytes"].inc(phases["bytes"])
                 if S > 1 and mode != "spec":
+                    m["window.dispatches"].inc()
+                    if d.ahead:
+                        m["runahead.windows"].inc()
                     rows = [n for _row, _seq, n in d.meta]
                     m["window.fed_slots"].inc(sum(n >= 1 for n in rows))
                     m["window.riding_slots"].inc(sum(n == 1 for n in rows))
@@ -1946,6 +2044,7 @@ class DecodeScheduler:
             "prefill_chunk": self.prefill_chunk,
             "prefill_chunks": c("prefill.chunks"),
             "runahead": {"launched": c("runahead.launched"),
+                         "windows": c("runahead.windows"),
                          "dropped": c("runahead.dropped")},
             "latency_ms": None if h is None or not h.count else {
                 "p50": round((h.quantile(0.50) or 0) * 1e3, 3),

@@ -1246,15 +1246,17 @@ def test_a_decode_steps_launch_puts_nothing_and_draws_no_key(kind):
 # ============ ISSUE 46: the next S=1 step is launched before the last
 # one's ids reach the host
 _S46 = 4                              # the fixtures' prefill window
-_T46 = {"gpt2": T, "fed": 64, "routed": T}
-_V46 = {"gpt2": V, "fed": 40, "routed": V}
+_T46 = {"gpt2": T, "fed": 64, "routed": T, "unfed": T}
+_V46 = {"gpt2": V, "fed": 40, "routed": V, "unfed": V}
 
 
 def _symbol46(kind, step_len=1):
     """A ``gpt2`` block with learned positions, a graph that is fed
-    (EVA attention), or the routed block of ``_pool_symbol``."""
-    if kind == "fed":
-        return _launch_symbol("fed", step_len)
+    (EVA attention), the routed block of ``_pool_symbol``, or the
+    ``gpt2`` block built by hand without ``fed``."""
+    if kind in ("fed", "unfed"):
+        return _launch_symbol({"fed": "fed", "unfed": "learned"}[kind],
+                              step_len)
     return _pool_symbol({"gpt2": "dense-learned",
                          "routed": "rotary-routed"}[kind], step_len)[0]
 
@@ -1336,7 +1338,8 @@ def test_a_long_s1_run_is_fed_from_the_chip(kind):
         sched = _sched46(kind, order)
         n_rec, before = sched.iterations, {
             k: _count46(sched, k) for k in
-            ("runahead.launched", "runahead.dropped", "iterations")}
+            ("runahead.launched", "runahead.dropped", "iterations",
+             "runahead.windows", "window.dispatches")}
         hs = [sched.submit(p, max_new_tokens=9) for p in prompts]
         sched.pump()
         outs[order] = [h.result(timeout=5).tolist() for h in hs]
@@ -1345,7 +1348,11 @@ def test_a_long_s1_run_is_fed_from_the_chip(kind):
         assert len(recs) == got["iterations"]
         assert [r["iter"] for r in recs] == sorted(r["iter"] for r in recs)
         assert sum(r["ahead"] for r in recs) == got["runahead.launched"]
-        assert all(r["window"] == 1 for r in recs if r["ahead"])
+        # a window runs ahead like an S=1 step (ISSUE 53), and is
+        # counted beside every window there was
+        windows = [r for r in recs if r["window"] > 1]
+        assert len(windows) == got["window.dispatches"] > 0
+        assert sum(r["ahead"] for r in windows) == got["runahead.windows"]
         assert got["runahead.dropped"] == 0
         if order == "sync":
             assert got["runahead.launched"] == 0
@@ -1353,7 +1360,7 @@ def test_a_long_s1_run_is_fed_from_the_chip(kind):
             # every S=1 dispatch is launched ahead but one whose
             # predecessor finishes somebody (by length: known ahead)
             s1 = [r for r in recs if r["window"] == 1]
-            assert len(s1) >= got["runahead.launched"] >= len(s1) - 2 > 4
+            assert len(s1) >= sum(r["ahead"] for r in s1) >= len(s1) - 2 > 4
             assert sched.stats()["runahead"]["launched"] == \
                 _count46(sched, "runahead.launched")
         assert sched.engine.compiles_since_warmup() == 0
@@ -1429,8 +1436,10 @@ def test_an_eos_drops_the_token_computed_past_it(kind):
 @pytest.mark.parametrize("kind", ["gpt2", "fed"])
 def test_nothing_runs_ahead_beside_a_slot_that_samples_on_the_host(kind):
     """ISSUE 46 (d): one request that is not greedy among greedy ones:
-    while it is active no dispatch is launched ahead (its row has to be
-    on the host first); once it has left, the others are."""
+    nothing is launched behind a dispatch at which it samples (its row
+    has to be on the host first) and stays; behind one in which it
+    still prefills, or samples its last token by length, the next is
+    (ISSUE 53), and once it has left every one is."""
     from mxnet_tpu.serve import SamplingParams
     prompts = _prompts46(kind, 4, 2)
     sched = _sched46(kind, "ahead", ladder=(2,))
@@ -1438,11 +1447,14 @@ def test_nothing_runs_ahead_beside_a_slot_that_samples_on_the_host(kind):
     hot = sched.submit(prompts[0], max_new_tokens=4,
                        sampling=SamplingParams(temperature=0.8, seed=3))
     cold = sched.submit(prompts[1], max_new_tokens=10)
+    quiet = 0
     while not hot.done():
+        had = len(hot.tokens)
         sched.pump(max_iterations=1)
-        assert sched._ahead is None or hot.done()
+        assert sched._ahead is None or len(hot.tokens) == had or hot.done()
+        quiet += sched._ahead is not None
     before_left = _count46(sched, "runahead.launched")
-    assert before_left == launched
+    assert before_left == launched + quiet
     sched.pump()
     assert _count46(sched, "runahead.launched") > before_left
     assert cold.result(timeout=5).tolist() == \
@@ -1453,17 +1465,24 @@ def test_nothing_runs_ahead_beside_a_slot_that_samples_on_the_host(kind):
 @pytest.mark.parametrize("kind", ["gpt2", "fed"])
 def test_a_submit_while_a_dispatch_is_in_flight(kind):
     """ISSUE 46 (e): a request arrives while a dispatch launched ahead
-    is on the chip: that dispatch commits first, the newcomer is
-    admitted by the plan after it, and nobody's tokens change."""
+    is on the chip: that dispatch commits first and feeds the newcomer
+    nothing; the plan behind it admits the newcomer into the free slot
+    (ISSUE 53: while that dispatch is still on the chip, the join
+    behind it on the device) and its first window is launched ahead
+    too; nobody's tokens change."""
     prompts = _prompts46(kind, 5, 2)
     sched = _sched46(kind, "ahead", ladder=(2,))
     first = sched.submit(prompts[0], max_new_tokens=10)
     while sched._ahead is None:
         assert sched.pump(max_iterations=1) == 1
     joins = _count46(sched, "joins")
+    in_flight = sched._ahead
     late = sched.submit(prompts[1], max_new_tokens=8)
+    assert late.request not in [seq for _row, seq, _n in in_flight.meta]
     assert sched.pump(max_iterations=1) == 1     # commits what was ahead
-    assert sched._ahead is None and _count46(sched, "joins") == joins
+    assert _count46(sched, "joins") == joins + 1
+    assert late.request in [seq for _row, seq, _n in sched._ahead.meta]
+    assert not late.tokens
     sched.pump()
     assert _count46(sched, "joins") == joins + 1
     assert first.result(timeout=5).tolist() == \
@@ -1528,3 +1547,253 @@ def test_the_routed_counters_follow_their_dispatch():
             [[r.get(f) for f in fields] for r in _steps46(sched, n_rec)])
     assert got["ahead"] == got["sync"]
     assert got["ahead"][1]["moe.assignments"] > 0
+
+
+# ============ ISSUE 53: a window is launched before its predecessor's
+# ids reach the host
+_KEYS53 = ("runahead.launched", "runahead.windows", "runahead.dropped",
+           "window.dispatches", "iterations", "joins")
+
+
+def _orders53(kind, script, ladder=(4,)):
+    """``script(sched)`` (submits, pumps to the end, returns the
+    handles) through the scheduler as it is and through the synchronous
+    one: ``{order: (each handle's tokens and finish reason, [(window,
+    fed, ahead)] of every dispatch, the counters' increase)}``.
+    Nothing may compile."""
+    mx.telemetry.flightrec.configure(capacity=4096)
+    out = {}
+    # the count of compiles is the process's: both are built before
+    # either's is read, and read as an increase (a scheduler of another
+    # test, built since this one warmed, has compiled its own)
+    scheds = {order: _sched46(kind, order, ladder)
+              for order in ("ahead", "sync")}
+    for order, sched in scheds.items():
+        drv = sched.engine.driver(max(ladder))
+        feds, step = [], drv.step
+        compiled = (sched.engine.compiles_since_warmup(),
+                    sched.engine.backend_compiles_since_warmup())
+
+        def spy(tokens, fed=None, now=None):
+            feds.append(None if fed is None else [int(n) for n in fed])
+            return step(tokens, fed=fed, now=now)
+
+        before = {k: _count46(sched, k) for k in _KEYS53}
+        n_rec = sched.iterations
+        drv.step = spy
+        try:
+            handles = script(sched)
+        finally:
+            del drv.step
+        assert sched._ahead is None and not sched._active()
+        recs = _steps46(sched, n_rec)
+        assert len(recs) == len(feds)
+        out[order] = (
+            [(h.tokens, h.finish_reason) for h in handles],
+            [(r["window"], fed, r["ahead"]) for r, fed in zip(recs, feds)],
+            {k: _count46(sched, k) - v for k, v in before.items()})
+        assert compiled == (sched.engine.compiles_since_warmup(),
+                            sched.engine.backend_compiles_since_warmup())
+    sync = out["sync"][2]
+    assert sync["runahead.launched"] == sync["runahead.windows"] == 0
+    return out["ahead"], out["sync"]
+
+
+def _same_dispatches53(ahead, sync):
+    """The same dispatches in the same order, each feeding every slot
+    what the synchronous order feeds it: only when they were launched
+    differs."""
+    assert [(w, fed) for w, fed, _a in ahead[1]] == \
+        [(w, fed) for w, fed, _a in sync[1]]
+    assert ahead[2]["iterations"] == sync[2]["iterations"]
+    assert ahead[2]["window.dispatches"] == sync[2]["window.dispatches"]
+
+
+def _prompt53(kind, seed, n):
+    return np.random.RandomState(seed).randint(1, _V46[kind], n).tolist()
+
+
+@pytest.mark.parametrize("kind", ["fed", "routed"])
+def test_windows_follow_windows_without_the_host(kind):
+    """ISSUE 53 (a): four prompts of one to three chunks, admitted
+    together and planned inside the budget of 8 rows: every window but
+    the first is launched while its predecessor is on the chip - slots
+    in mid-prompt fed by the host, slots that sampled there by the
+    chip - and so is the S=1 step behind the last. The dispatches and
+    the tokens are the synchronous order's, and the plain loop's."""
+    prompts = [_prompt53(kind, 53 + i, n) for i, n in enumerate((11, 9, 6, 3))]
+
+    def script(sched):
+        assert sched.engine.window_budget(4, _S46) == 8
+        hs = [sched.submit(p, max_new_tokens=4) for p in prompts]
+        sched.pump()
+        return hs
+
+    ahead, sync = _orders53(kind, script)
+    assert ahead[0] == sync[0]
+    _same_dispatches53(ahead, sync)
+    assert [t for t, _why in ahead[0]] == \
+        [_plain_greedy(kind, p, 4) for p in prompts]
+    windows = [a for w, _fed, a in ahead[1] if w > 1]
+    assert len(windows) >= 4 and windows == [0] + [1] * (len(windows) - 1)
+    assert ahead[2]["runahead.windows"] == len(windows) - 1
+    # a window fed two prefilling slots, and one fed a chunk beside
+    # riders, ran ahead
+    fed_ahead = [fed for w, fed, a in ahead[1] if w > 1 and a]
+    assert any(sorted(fed)[-2] > 1 for fed in fed_ahead)
+    assert any(1 in fed and max(fed) > 1 for fed in fed_ahead)
+    # the first S=1 step lies behind a window, and runs ahead too
+    first = next(i for i, (w, _f, _a) in enumerate(ahead[1]) if w == 1)
+    assert ahead[1][first - 1][0] == _S46 and ahead[1][first][2] == 1
+
+
+@pytest.mark.parametrize("tail", [0, 1])
+@pytest.mark.parametrize("kind", ["fed", "routed"])
+def test_a_prompts_last_chunk_is_followed_by_an_s1_step(kind, tail):
+    """ISSUE 53 (c): a prompt of two chunks (``tail`` 0: its first
+    token is sampled at the second window) or of two chunks and a token
+    (``tail`` 1: at the S=1 step behind them, fed that token by the
+    host) prefills beside a slot that decodes from the first window on:
+    the S=1 step behind the last window is launched ahead, the rider's
+    token from the chip and, with a ``tail``, merged into the host's."""
+    rider = _prompt53(kind, 60, 3)
+    prompt = _prompt53(kind, 61 + tail, 2 * _S46 + tail)
+
+    def script(sched):
+        hs = [sched.submit(rider, max_new_tokens=8),
+              sched.submit(prompt, max_new_tokens=3)]
+        sched.pump()
+        return hs
+
+    ahead, sync = _orders53(kind, script)
+    assert ahead[0] == sync[0]
+    _same_dispatches53(ahead, sync)
+    assert [t for t, _why in ahead[0]] == [
+        _plain_greedy(kind, rider, 8), _plain_greedy(kind, prompt, 3)]
+    last = max(i for i, (w, _f, _a) in enumerate(ahead[1]) if w > 1)
+    assert ahead[1][last][:2] == (_S46, [1, _S46, 0, 0])
+    assert ahead[1][last + 1] == (1, [1, 1, 0, 0], 1)
+    assert ahead[2]["runahead.windows"] >= 1
+
+
+@pytest.mark.parametrize("kind", ["fed", "routed"])
+def test_a_finish_by_length_between_windows_and_the_caller_resubmits(kind):
+    """ISSUE 53 (d): a short request finishes by length while a long
+    prompt prefills, and its done callback submits the next (a closed
+    loop). That it finishes the host knows ahead: the window behind is
+    planned without it and launched before its last token is on the
+    host; whom the callback submits the host cannot know, so the
+    newcomer is admitted by the plan behind that window - a join behind
+    it on the device, ahead as well. No window beside the long prompt
+    waits for a commit but the first, every request's tokens are the
+    synchronous order's and
+    the plain loop's, and the newcomers' first chunks come one window
+    later than where every plan waits for a commit."""
+    n_long = {"fed": 30, "routed": 11}[kind]
+    long = _prompt53(kind, 70, n_long)
+    shorts = [_prompt53(kind, 71 + i, 3) for i in range(3)]
+    handles = {}
+
+    def script(sched):
+        handles.clear()
+
+        def send(i):
+            h = handles[i] = sched.submit(shorts[i], max_new_tokens=2)
+            if i + 1 < len(shorts):
+                h.add_done_callback(lambda _h: send(i + 1))
+
+        hs = [sched.submit(long, max_new_tokens=3)]
+        send(0)
+        sched.pump()
+        return hs + [handles[i] for i in range(len(shorts))]
+
+    ahead, sync = _orders53(kind, script)
+    assert ahead[0] == sync[0]
+    assert [t for t, _why in ahead[0]] == [_plain_greedy(kind, long, 3)] + [
+        _plain_greedy(kind, p, 2) for p in shorts]
+    assert {why for _t, why in ahead[0]} == {"length"}
+    assert ahead[2]["joins"] == 4 and ahead[2]["runahead.dropped"] == 0
+    windows = [a for w, _fed, a in ahead[1] if w > 1]
+    # (a window planned with nobody left beside a newcomer waits too)
+    assert windows[:4] == [0, 1, 1, 1]
+    assert ahead[2]["runahead.windows"] == sum(windows) >= len(windows) - 2
+    # synchronous: a short one's second token and its successor's first
+    # chunk lie in consecutive windows; ahead: a window between them in
+    # which the freed slot is fed nothing
+    assert [fed[1] for _w, fed, _a in sync[1][:4]] == [3, 1, 3, 1]
+    assert [fed[1] for _w, fed, _a in ahead[1][:5]] == [3, 1, 0, 3, 1]
+
+
+def _eos53(kind):
+    """A rider's prompt whose second token differs from its first, and
+    that token: the stream ends where it shows."""
+    for seed in range(80, 120):
+        rider = _prompt53(kind, seed, 3)
+        free = _plain_greedy(kind, rider, 4)
+        if free[1] != free[0]:
+            return rider, free
+    raise AssertionError("no such prompt")
+
+
+@pytest.mark.parametrize("how", ["eos", "deadline"])
+@pytest.mark.parametrize("kind", ["fed", "routed"])
+def test_a_slot_retires_while_a_window_launched_ahead_is_on_the_chip(kind,
+                                                                     how):
+    """ISSUE 53 (e): the host cannot know an EOS ahead, nor a deadline
+    that passes while the chip works. A rider that retires at a
+    window's commit, or before it, has been fed a row of the window
+    behind: that row's token is dropped and counted, the prompt beside
+    it prefills on, and every stream is the synchronous order's."""
+    rider, free = _eos53(kind)
+    long = _prompt53(kind, 79, 11)
+
+    def script(sched):
+        hs = [sched.submit(long, max_new_tokens=3),
+              sched.submit(rider, max_new_tokens=4,
+                           eos_id=free[1] if how == "eos" else None,
+                           deadline_ms=500 if how == "deadline" else None)]
+        sched.pump(max_iterations=1)
+        if how == "deadline":
+            # with the second window on the chip (or, synchronous, not
+            # yet planned) the rider's time runs out
+            sched._clock.advance(1.0)
+        sched.pump()
+        return hs
+
+    ahead, sync = _orders53(kind, script)
+    assert ahead[0] == sync[0]
+    assert ahead[0][0] == (_plain_greedy(kind, long, 3), "length")
+    assert ahead[0][1] == (free[:1], how)
+    assert [w for w, _f, _a in ahead[1]] == [w for w, _f, _a in sync[1]]
+    # the rider's row of the window behind: fed ahead, not otherwise
+    assert ahead[2]["runahead.dropped"] == 1
+    assert sync[2]["runahead.dropped"] == 0
+    assert ahead[2]["runahead.windows"] >= 2
+    at, n = (2, 3) if how == "eos" else (1, _S46)
+    assert ahead[1][at] == (_S46, [n, 1, 0, 0], 1)
+    assert sync[1][at] == (_S46, [n, 0, 0, 0], 0)
+
+
+def test_an_engine_that_is_not_fed_launches_no_window_ahead():
+    """ISSUE 53 (f): a graph built by hand without ``fed`` advances
+    every cursor by S and is rewound before the next dispatch reads
+    it: its windows wait for their predecessor's commit as ever, the
+    S=1 steps behind them run ahead as since ISSUE 46."""
+    prompts = [_prompt53("unfed", 90 + i, n) for i, n in enumerate((10, 7, 3))]
+
+    def script(sched):
+        assert not sched.engine.feeds
+        hs = [sched.submit(p, max_new_tokens=4) for p in prompts]
+        sched.pump()
+        return hs
+
+    ahead, sync = _orders53("unfed", script)
+    assert ahead[0] == sync[0]
+    _same_dispatches53(ahead, sync)
+    assert [t for t, _why in ahead[0]] == \
+        [_plain_greedy("unfed", p, 4) for p in prompts]
+    assert ahead[2]["window.dispatches"] >= 3
+    assert ahead[2]["runahead.windows"] == 0
+    assert not any(a for w, _fed, a in ahead[1] if w > 1)
+    assert ahead[2]["runahead.launched"] > 0
+

@@ -430,6 +430,58 @@ def test_prefix_join_rows_bitwise_equal_cold_prefill(target_params):
                            rtol=1e-4, atol=1e-5), nm
 
 
+def test_a_prefix_is_captured_behind_a_window_and_joined_after_a_commit(
+        target_params):
+    """ISSUE 53: with a dispatch in flight ahead of the host, a prompt's
+    rows are captured at the commit of its last window while the step
+    behind is on the chip - rows below the cursor, which that step only
+    appends to - and are bitwise the synchronous order's. A request
+    that names a prefix to the store waits for a commit (its join at a
+    cursor restores rows); one that names none is admitted behind the
+    dispatch on the chip."""
+    prompt = list(np.random.RandomState(9).randint(1, V, 11))
+    rider = list(np.random.RandomState(10).randint(1, V, 3))
+    rows, outs = {}, {}
+    for order in ("ahead", "sync"):
+        sched = _sched(target_params, ladder=(2,), chunk=4, prefix_mb=4)
+        if order == "sync":
+            sched._plan_ahead = lambda d, now: None
+        joins = lambda: sched.stats()["joins"]              # noqa: E731
+        first = sched.submit(rider, max_new_tokens=12)
+        sched.pump(max_iterations=2)
+        assert (sched._ahead is not None) == (order == "ahead")
+        cold = sched.submit(prompt, max_new_tokens=3, prefix_id="sys")
+        sched.pump(max_iterations=1)
+        if order == "ahead":        # what was on the chip commits first
+            assert joins() == 1 and sched._ahead is None
+        sched.pump()
+        assert sched.prefix_store.misses == 1 and len(sched.prefix_store) == 1
+        if order == "ahead":
+            assert sched.stats()["runahead"]["windows"] >= 2
+        entry = sched.prefix_store.lookup(
+            "sys", np.asarray(prompt + [0]), tags=("target",))[1]
+        rows[order] = entry.payloads["target"]
+        # a warm join, and beside it a request that names no prefix
+        again = sched.submit(rider, max_new_tokens=12)
+        sched.pump(max_iterations=2)
+        warm = sched.submit(prompt, max_new_tokens=3, prefix_id="sys")
+        sched.pump(max_iterations=1)
+        assert joins() == (3 if order == "ahead" else 4)
+        sched.pump()
+        plain = sched.submit(prompt, max_new_tokens=3)
+        late = sched.submit(rider, max_new_tokens=2)
+        sched.pump()
+        assert sched.prefix_store.hits == 2      # this test's, the join's
+        outs[order] = [list(h.result(timeout=5)) for h in
+                       (first, cold, again, warm, plain, late)]
+        assert outs[order][1] == outs[order][3] == outs[order][4]
+        assert sched.stats()["compiles_since_warmup"] == 0
+    assert outs["ahead"] == outs["sync"]
+    assert outs["ahead"][1] == _ref_greedy(target_params, prompt, 3)
+    for nm, ref in rows["sync"].items():
+        assert np.array_equal(rows["ahead"][nm], ref), nm
+
+
 def test_prefix_store_lru_mismatch_and_budget():
     rows = {"target": {"c": np.zeros((2, 8, 4), np.float32)}}
     entry_bytes = 2 * 8 + 2 * 8 * 4 * 4       # 2 int64 tokens + rows

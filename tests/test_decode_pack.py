@@ -513,7 +513,7 @@ def test_an_engine_without_fed_is_planned_as_before():
                for n in (40, 40, 20, 5)]
     with sched._lock:
         sched._admit_locked(0.0)
-        plan = sched._plan_window(S)
+        plan = sched._plan_window(S, sched._cursors()[0])
     assert [(row, n) for row, _seq, n in plan] == [(0, S), (1, S), (2, S),
                                                    (3, 5)]
     rewound = _spy_on_rewinds(engine)
@@ -740,11 +740,15 @@ def test_two_chunks_and_the_riders_fit_one_window_at_rung_8(block):
     """The Cerebras and OLMoE cells' shapes, 8 slots of 64 rows: the
     budget is the 256 rows a weight-bound matmul carries for free, so
     two prefilling slots get a whole chunk each beside six riders in
-    one packed window; nobody is rewound after it; and the first S = 1
-    step behind the last window is launched before the window's ids are
-    on the host (ISSUE 46), with no rewind before it. The graph as it
-    was (no ``fed``) serves the same tokens, rewinds the riders before
-    that step is launched and not again at the commit."""
+    one packed window; nobody is rewound after it; the second and the
+    third such window are launched before their predecessor's ids are
+    on the host (ISSUE 53: the chunks from the host, the riders' tokens
+    from the chip), and so is the first S = 1 step behind the last
+    (ISSUE 46), with no rewind before it. The synchronous order runs
+    the same windows and serves the same tokens. The graph as it
+    was (no ``fed``) serves them too, launches no window ahead, rewinds
+    the riders before that step is launched and not again at the
+    commit."""
     chunk, slots, capacity = 64, 8, 320
     kw = dict(cases.config(block), capacity=capacity, per_slot=True)
     gens = {"fed": lambda s: tfm.get_decode_symbol(step_len=s, **kw),
@@ -756,49 +760,73 @@ def test_two_chunks_and_the_riders_fit_one_window_at_rung_8(block):
     long = [rs.randint(0, vocab, 150) for _ in range(2)]
     mx.telemetry.flightrec.configure(capacity=4096)
     streams = {}
-    for kind, gen in gens.items():
+    for kind, order in (("fed", "ahead"), ("fed", "sync"),
+                        ("unfed", "ahead")):
         name = f"rung8-{block}-{kind}"
-        engine = DecodeEngine(name, gen(1), cases.params(block),
-                              capacity=capacity, ladder=[slots],
-                              symbol_gen=gen, window_lens=[chunk])
-        assert engine.window_budget(slots, chunk) == \
-            (256 if kind == "fed" else None)
-        sched = DecodeScheduler(engine, clock=FakeClock(),
-                                prefill_chunk=chunk, prefix_store=None)
+        if order == "sync":
+            # the same scheduler, every dispatch planned after its
+            # predecessor's commit
+            sched._plan_ahead = lambda d, now: None
+        else:
+            gen = gens[kind]
+            engine = DecodeEngine(name, gen(1), cases.params(block),
+                                  capacity=capacity, ladder=[slots],
+                                  symbol_gen=gen, window_lens=[chunk])
+            assert engine.window_budget(slots, chunk) == \
+                (256 if kind == "fed" else None)
+            sched = DecodeScheduler(engine, clock=FakeClock(),
+                                    prefill_chunk=chunk, prefix_store=None)
         drv = engine.driver(slots)
         windows = []
 
-        def step(tokens, fed=None, now=None, _step=drv.step, _drv=drv):
-            out = _step(tokens, fed=fed, now=now)
+        def step(tokens, fed=None, now=None, _step=type(drv).step,
+                 _drv=drv):
+            out = _step(_drv, tokens, fed=fed, now=now)
             if tokens.shape[1:] == (chunk,):
                 windows.append((None if fed is None else sorted(fed),
                                 _drv.last_program_rows))
             return out
 
         drv.step = step
+        n_rec = sched.iterations
         handles = [sched.submit(p, max_new_tokens=30) for p in riders]
         sched.pump(max_iterations=3)             # the riders decode
         rewound = _spy_on_rewinds(engine)
         handles += [sched.submit(p, max_new_tokens=4) for p in long]
         sched.pump()
-        streams[kind] = [h.result(timeout=0).tolist() for h in handles]
+        streams[kind, order] = [h.result(timeout=0).tolist()
+                                for h in handles]
         ring = [r for r in mx.telemetry.flightrec.get_records()
-                if r["kind"] == "serve.decode.step" and r["model"] == name]
+                if r["kind"] == "serve.decode.step" and r["model"] == name
+                and r["iter"] >= n_rec]
         last = max(i for i, r in enumerate(ring) if r["window"] == chunk)
-        # the step behind the last window was launched ahead of its ids
-        assert ring[last + 1]["window"] == 1 and ring[last + 1]["ahead"] == 1
+        ahead = [r["ahead"] for r in ring if r["window"] == chunk]
         assert sched.stats()["compiles_since_warmup"] == 0
+        if order == "sync":
+            assert not any(r["ahead"] for r in ring)
+        else:
+            # the step behind the last window was launched ahead of
+            # its ids
+            assert ring[last + 1]["window"] == 1 and \
+                ring[last + 1]["ahead"] == 1
         if kind == "fed":
             assert not rewound
             assert windows[1:] == [([1] * 6 + [64, 64], 256)] * 2 \
                 + [([1] * 6 + [22, 22], 256)]
+            # the long prompts are admitted while an S = 1 step is on
+            # the chip, their first window launched behind it; the two
+            # behind that need no id's value either
+            assert ahead[1:] == [int(order == "ahead")] * 3
         else:
             # three windows, the riders back by 63 after each, the long
             # prompts by 42 after the last: once a window
             assert [len(rows) for rows, _pos in rewound] == [6, 6, 8]
             assert windows[1:] == [(None, slots * chunk)] * 3
-    assert streams["fed"] == streams["unfed"]
-    assert [len(t) for t in streams["fed"]] == [30] * 6 + [4] * 2
+            assert not any(ahead)
+    assert sched.stats()["runahead"]["windows"] == 0
+    assert streams["fed", "ahead"] == streams["fed", "sync"] \
+        == streams["unfed", "ahead"]
+    assert [len(t) for t in streams["fed", "ahead"]] == [30] * 6 + [4] * 2
 
 
 def test_a_fed_graph_bound_without_fed_is_refused():
@@ -836,7 +864,11 @@ def _counts_script(block):
     prompt = lambda n: rs.randint(0, 40, n)         # noqa: E731
     handles = [sched.submit(prompt(n), max_new_tokens=m)
                for n, m in ((60, 10), (23, 12), (5, 2))]
-    sched.pump(max_iterations=3)
+    # until three dispatches have been launched: the third is on the
+    # chip, so the newcomers are admitted by the fourth's plan whether
+    # or not that one could have been launched ahead
+    while sched.iterations + (sched._ahead is not None) < 3:
+        sched.pump(max_iterations=1)
     handles += [sched.submit(prompt(n), max_new_tokens=m)
                 for n, m in ((1, 3), (17, 5))]
     sched.pump()
@@ -861,11 +893,13 @@ def _counts_script(block):
 
 @pytest.mark.parametrize("block", sorted(COUNTS))
 def test_the_counters_and_the_ring_fields_are_the_parents(block):
-    """What PR 44's parent counted, and beside it what ISSUE 46 added:
-    the two ``runahead`` counters, the ring's ``ahead``, and in
-    ``state.donated_bytes`` the one S = 1 step a rung more that warm-up
-    runs (fed from the chip). The script has no EOS, so every dispatch
-    launched ahead is committed: same dispatches, same counts."""
+    """What PR 44's parent counted, and beside it what ISSUEs 46 and 53
+    added: the ``runahead`` counters, ``window.dispatches``, the ring's
+    ``ahead``, and in ``state.donated_bytes`` the steps a rung more
+    that warm-up runs fed from the chip (two S = 1 steps and a window,
+    in ``cursor.updates`` and ``cursor.rows`` the rewind before it).
+    The script has no EOS, so every dispatch launched ahead, window or
+    S = 1 step, is committed: same dispatches, same counts."""
     counters, ring, step_bytes = _counts_script(block)
     # ISSUE 51: the rows the windows' heads ran over, a row a slot of
     # a packed launch (rung 4: the script's windows are all inside the
@@ -876,10 +910,21 @@ def test_the_counters_and_the_ring_fields_are_the_parents(block):
                         for r in windows) > 0
     launched = counters.pop("runahead.launched")
     assert counters.pop("runahead.dropped") == 0
+    assert counters.pop("window.dispatches") == len(windows)
+    assert counters.pop("runahead.windows") == \
+        sum(r["ahead"] for r in windows) > 0
     assert launched == sum(r.pop("ahead") for r in ring) > 0
     want = dict(COUNTS[block][0])
-    want["state.donated_bytes"] += step_bytes
+    want["state.donated_bytes"] += 3 * step_bytes
+    # the rewind in front of warm-up's window fed from the chip: every
+    # slot of each rung
+    want["cursor.updates"] += 2
+    want["cursor.rows"] += 1 + SLOTS
     assert counters == want
+    # the fourth request is admitted by the plan behind the third
+    # dispatch, while that is on the chip, and is active at its commit:
+    # a dispatch early (its first chunk lies where the parent's did)
+    ring[2]["active"] -= 1
     assert ring == COUNTS[block][1]
 
 

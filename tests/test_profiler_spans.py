@@ -343,16 +343,18 @@ def test_ring_record_fields_count_the_clock_reads():
 
 
 # ISSUE 46: the next S=1 step is launched before the last one's ids
-# reach the host
+# reach the host; ISSUE 53: so is the next window
 AHEAD_ITERS = 5
 
 
 @pytest.fixture(scope="module")
 def ahead_trace(tmp_path_factory):
     """Five ``pump()`` iterations of the same request with nothing
-    held back: two windows, the S=1 step that samples the first token
-    and launches the next step behind itself, and two iterations that
-    each commit a step launched an iteration ago and launch another."""
+    held back: the first window, which launches the second behind
+    itself, and four iterations that each commit a dispatch launched an
+    iteration ago - the second window, then S=1 steps - and launch
+    another: the S=1 step that feeds the prompt's last token from the
+    host, then steps fed from the chip."""
     tm.disable()
     sched = _scheduler("spans-ahead", MonotonicClock(), ahead=True)
     sched.submit(np.arange(1, 10), max_new_tokens=4)
@@ -384,8 +386,8 @@ def test_ahead_spans_keep_their_names_and_their_parents(ahead_trace, name):
 
 def test_a_step_is_launched_before_its_predecessors_ids_are_fetched(
         ahead_trace):
-    """From the S=1 step that samples the first token on, every
-    iteration launches the next step between its plan and its fetch."""
+    """From the first window on, every iteration launches the next
+    dispatch between its plan and its fetch."""
     iters = sorted((e[2], e[3]) for e in
                    _named(ahead_trace, "serve.decode.iter"))
     per_iter = []
@@ -400,12 +402,29 @@ def test_a_step_is_launched_before_its_predecessors_ids_are_fetched(
             assert end <= start
         per_iter.append([n for _a, _b, n in inside])
     assert per_iter == [
-        ["plan", "dispatch", "fetch", "commit"]] * 2 + [
         ["plan", "dispatch", "dispatch", "fetch", "commit"]] + [
-        ["plan", "dispatch", "fetch", "commit"]] * 2
+        ["plan", "dispatch", "fetch", "commit"]] * 4
     stats = [e[4] for e in sorted(_named(ahead_trace, "serve.decode.iter"),
                                   key=lambda e: e[2])]
     assert [int(st["window"]) for st in stats] == [S, S, 1, 1, 1]
+
+
+def test_a_windows_launch_opens_before_its_predecessors_ids_close(
+        ahead_trace):
+    """ISSUE 53: the second window's ``decode.step.launch`` opens before
+    the first one's ``serve.decode.iter.fetch.ids`` closes, as every
+    later dispatch's does before its predecessor's: the chip has the
+    next program while the host waits for the ids of the last."""
+    launches = sorted((e[2], e[3]) for e in
+                      _named(ahead_trace, "decode.step.launch"))
+    ids = sorted((e[2], e[3]) for e in
+                 _named(ahead_trace, "serve.decode.iter.fetch.ids"))
+    assert len(launches) == AHEAD_ITERS + 1 and len(ids) == AHEAD_ITERS
+    for k, (_opened, closed) in enumerate(ids):
+        assert launches[k][1] <= closed          # its own, launched
+        assert launches[k + 1][0] < closed       # and the one behind
+    runs = sorted(_named(ahead_trace, "executor.run"), key=lambda e: e[2])
+    assert [e[4]["kind"] for e in runs] == ["fwd_infer"] * (AHEAD_ITERS + 1)
 
 
 def test_ring_record_of_a_dispatch_launched_ahead_counts_the_clock_reads():
@@ -425,29 +444,30 @@ def test_ring_record_of_a_dispatch_launched_ahead_counts_the_clock_reads():
     recs = [r for r in flightrec.get_records()
             if r["kind"] == "serve.decode.step"]
     assert [(r["window"], r["ahead"]) for r in recs] == [
-        (S, 0), (S, 0), (1, 0), (1, 1), (1, 1), (1, 1)]
+        (S, 0), (S, 1), (1, 1), (1, 1), (1, 1), (1, 1)]
     tick = 2.0 ** -10 * 1e6
-    # the two windows as ever (14 reads each); the step that launches
-    # one behind itself reads the launch's six more; one that commits a
-    # step launched an iteration ago and launches another reads 14, the
-    # last, which launches nothing, 8
-    assert (clock.now() - before) * 2 ** 10 == 14 + 14 + 20 + 14 + 14 + 8 + 1
+    # the first window launches the second behind itself: an
+    # iteration's 14 reads and the launch's six more; an iteration that
+    # commits a dispatch launched an iteration ago and launches another
+    # reads 14, the last, which launches nothing, 8
+    assert (clock.now() - before) * 2 ** 10 == 20 + 14 * 4 + 8 + 1
     for r in recs:
         assert r["dispatch_us"] == int(6 * tick) and \
             r["fetch_us"] == int(3 * tick) and r["ids_us"] == int(tick)
         assert r["stage_us"] == r["launch_us"] == r["select_us"] \
             == r["lock_us"] == r["commit_us"] == r["rewind_us"] == int(tick)
     # first clock read under the lock -> ids on the host, the launch of
-    # the step behind included
-    assert recs[2]["step_us"] == int(16 * tick)
-    # from the predecessor's ids to its own: that iteration's commit
-    # and rewind, then arrival, lock, plan, a launch, the fetch
-    assert [r["step_us"] for r in recs[3:]] == [
-        int(14 * tick), int(14 * tick), int(8 * tick)]
+    # the window behind included
+    assert recs[0]["step_us"] == int(16 * tick)
+    # from the predecessor's ids to its own, a window's like a step's:
+    # that iteration's commit and rewind, then arrival, lock, plan, a
+    # launch, the fetch
+    assert [r["step_us"] for r in recs[1:]] == [int(14 * tick)] * 4 + [
+        int(8 * tick)]
     # a plan section's time is charged to what it planned first, and
     # to the dispatch it commits where it planned nothing
-    assert [r["plan_us"] for r in recs[2:]] == [
-        int(tick), 0, int(tick), int(2 * tick)]
+    assert [r["plan_us"] for r in recs] == [
+        int(tick), 0, int(tick), int(tick), int(tick), int(2 * tick)]
 
 
 def test_span_without_jax_is_the_null_span():
