@@ -15,6 +15,12 @@ the repo documents, and checks what comes out by the repo's own means.
   serve    ``mx.serve.serve_decoder`` on the documented transformer
            (docs/models.md: vocab 32000, d_model 512, 8 layers, 8 heads)
            answering 8 requests, against a one-slot KVCacheDecoder chain
+  pair     Granite 4.0-H Small's block at its published widths in one
+           layer pair (a Mamba-2 layer of 128 heads and the attention
+           layer, each with 72 routed experts of which 36 are held
+           beside the shared feed-forward): its S = 1 program and its
+           packed window program compile and run once each, and a slot
+           riding the window reads what the S = 1 step reads
 
 ``--multichip`` (four chips; run by hand) runs only the device check,
 the train model on ``--gpus 0,1,2,3`` for three steps, and the same three
@@ -53,6 +59,20 @@ TRAIN_ARGV = ["--network", "resnet", "--num-layers", "50",
               "--batch-size", "256", "--num-epochs", "1", "--lr", "0.1",
               "--mom", "0.9"]
 SERVE_MODEL = dict(vocab_size=32000, d_model=512, n_layer=8, n_head=8)
+#: ibm-granite/granite-4.0-h-small's config.json in two layers (a mamba
+#: layer and the attention layer), this chip's half of the experts held
+#: and a vocabulary of 2,048: 0.88 B parameters
+GRANITE_SMALL_PAIR = dict(
+    vocab_size=2048, d_model=4096, n_layer=2, n_head=32, granite=dict(
+        num_key_value_heads=8, layer_types=["mamba", "attention"],
+        mamba_n_heads=128, mamba_d_head=64, mamba_d_state=128,
+        mamba_n_groups=1, mamba_d_conv=4, mamba_expand=2,
+        mamba_chunk_size=256, mamba_conv_bias=True, mamba_proj_bias=False,
+        shared_intermediate_size=1536, num_local_experts=72,
+        num_experts_per_tok=10, intermediate_size=768,
+        position_embedding_type="nope", embedding_multiplier=12,
+        residual_multiplier=0.22, attention_multiplier=0.0078125,
+        logits_scaling=16, held=(0, 36)))
 
 
 def say(phase, **fields):
@@ -769,6 +789,125 @@ def serve_phase(model, capacity, ladder, prompt_lens, n_requests, max_new,
     return answers
 
 
+# -------------------------------------------------------------- layer pair
+def layer_pair_phase(model, slots, window, capacity, context, compute_dtype,
+                     seed):
+    """Granite 4.0-H's block with routed experts (``GRANITE_SMALL_PAIR``)
+    through ``BatchedKVCacheDecoder``: one packed window - slot 0
+    prefills, every other slot rides it with one row - and, from fresh
+    slots again, one S = 1 step of the same tokens. A rider at cursor 0
+    and an S = 1 step at cursor 0 read the same row through two
+    programs: their logits agree within the dtype's tolerance."""
+    import numpy as np
+    import mxnet_tpu as mx
+    from mxnet_tpu import kernel_tier
+    from mxnet_tpu.models import transformer as tfm
+
+    fail = functools.partial(_fail, "pair")
+    t0, c0 = time.perf_counter(), compile_clock().seconds
+    tol = kernel_tier.NUMERIC_TOL[str(np.dtype(compute_dtype or "float32"))]
+    rs = np.random.RandomState(seed)
+
+    def gen(step_len):
+        return tfm.get_decode_symbol(capacity=capacity, per_slot=True,
+                                     step_len=step_len,
+                                     block="granite_hybrid", **model)
+
+    base_sym = gen(1)
+    shapes, _, _ = base_sym.infer_shape(data=(slots, 1), fed=(slots,))
+    args = {}
+    for name, shape in zip(base_sym.list_arguments(), shapes):
+        if name in ("data", "fed"):
+            continue
+        if name.endswith(("_gamma", "_mamba_D")):
+            draw = np.ones(shape, np.float32)
+        elif name.endswith("_mamba_A_log"):
+            draw = np.log(rs.uniform(1, 16, shape)).astype(np.float32)
+        elif name.endswith("_mamba_dt_bias"):
+            draw = (rs.standard_normal(shape) - 3.0).astype(np.float32)
+        elif name.endswith(("_mamba_conv_weight", "_mamba_conv_bias")):
+            draw = rs.uniform(-0.5, 0.5, shape).astype(np.float32)
+        else:
+            draw = (0.02 * rs.standard_normal(shape)).astype(np.float32)
+        args[name] = draw
+
+    def bound(symbol, step_len, shared=None):
+        mod = mx.mod.Module(symbol, data_names=("data", "fed"),
+                            label_names=[], context=context,
+                            compute_dtype=compute_dtype)
+        mod.bind([mx.io.DataDesc("data", (slots, step_len), np.int32),
+                  mx.io.DataDesc("fed", (slots,), np.int32)],
+                 None, for_training=False, shared_module=shared)
+        if shared is None:
+            mod.init_params(initializer=None, arg_params=args,
+                            aux_params={}, allow_missing=True)
+        return mod
+
+    base = bound(base_sym, 1)
+    drv = tfm.BatchedKVCacheDecoder(base, capacity, slots=slots)
+    whole = gen(window)
+    packed, budget = tfm.packed_window(whole, slots)
+    drv.add_window(window, bound(whole, window, shared=base),
+                   packed=(bound(packed, window, shared=base), budget))
+    ops = [n.op for n in base_sym._topo_nodes() if not n.is_variable]
+    if (ops.count("ssm_mixer_decode"), ops.count("attention_decode"),
+            ops.count("MoEFFN")) != (1, 1, 2):
+        fail(f"the pair is not a mamba layer and an attention layer with "
+             f"routed experts: {sorted(set(ops))}")
+
+    V = model["vocab_size"]
+    tokens = rs.randint(0, V, (slots, window)).astype(np.int32)
+    fed = np.ones((slots,), np.int32)
+    fed[0] = min(window, budget - (slots - 1))
+    for slot in range(slots):
+        drv.join(slot)
+    t1 = time.perf_counter()
+    rode = drv.step(tokens, fed=fed).asnumpy().astype(np.float32)
+    window_s = time.perf_counter() - t1
+    if drv.last_program_rows != budget or rode.shape != (slots, 1, V):
+        fail(f"the window ran over {drv.last_program_rows} rows (budget "
+             f"{budget}) and returned {rode.shape}")
+    if list(drv.pos) != list(fed):
+        fail(f"cursors {list(drv.pos)} after feeding {list(fed)}")
+    routed = drv.moe_stats(drv.moe_stats_begin())
+    top_k = model["granite"]["num_experts_per_tok"]
+    if routed["moe.assignments"] != 2 * top_k * int(fed.sum()) \
+            or not 0 < routed["moe.held_assignments"] \
+            < routed["moe.assignments"]:
+        fail(f"the routed layers counted {routed} for {int(fed.sum())} "
+             "real rows")
+    for slot in range(slots):
+        drv.leave(slot)
+        drv.join(slot)
+    t1 = time.perf_counter()
+    stepped = drv.step(tokens[:, :1], fed=np.ones((slots,), np.int32)) \
+        .asnumpy().astype(np.float32)
+    step_s = time.perf_counter() - t1
+    if not (np.isfinite(rode).all() and np.isfinite(stepped).all()):
+        fail("logits are not finite")
+    err = float(np.max(np.abs(rode[1:] - stepped[1:])))
+    if slots > 1 and not np.allclose(rode[1:], stepped[1:], rtol=tol,
+                                     atol=tol):
+        fail(f"a rider's logits differ from the S = 1 step's by {err:.4g} "
+             f"(tolerance {tol} abs+rel)")
+    for slot in range(slots):
+        drv.leave(slot)
+    say("pair", ok=True, slots=slots, window=window, packed_rows=budget,
+        prefill_rows=int(fed[0]), riders=slots - 1,
+        rider_vs_step_max_abs_err=err, tolerance=tol,
+        max_abs_logit=float(np.max(np.abs(stepped))),
+        assignments=routed["moe.assignments"],
+        held_assignments=routed["moe.held_assignments"],
+        experts_touched=routed["moe.experts_touched"],
+        state_bytes=dict(drv.state_bytes),
+        parameters=int(sum(a.size for a in args.values())),
+        kernel_tier=sorted({(d["op"], d.get("variant"))
+                            for d in kernel_tier.decisions()}),
+        window_s_info=round(window_s, 2), step_s_info=round(step_s, 2),
+        elapsed_s=round(time.perf_counter() - t0, 2),
+        compile_s=round(compile_clock().seconds - c0, 2))
+
+
 # --------------------------------------------------------------- multichip
 def multichip_phase(build, gpus, seed, rtol):
     """The train model over every chip in ``gpus`` and, from the same
@@ -841,6 +980,9 @@ def main(argv=None):
                     prompt_lens=(16, 128), n_requests=8, max_new=32,
                     context=mx.tpu(0), compute_dtype="bfloat16",
                     seed=opts.seed)
+        layer_pair_phase(GRANITE_SMALL_PAIR, slots=32, window=256,
+                         capacity=1024, context=mx.tpu(0),
+                         compute_dtype="bfloat16", seed=opts.seed)
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

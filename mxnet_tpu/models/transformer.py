@@ -889,12 +889,16 @@ def _afmoe_spec(spec):
 #: the keys of Granite 4.0-H's published ``config.json`` (``model_type
 #: granitemoehybrid``) that ``block="granite_hybrid"`` reads
 #: (``get_decode_symbol(granite=...)``); ``layer_types`` one entry a
-#: layer that is run, ``"mamba"`` or ``"attention"``
+#: layer that is run, ``"mamba"`` or ``"attention"``;
+#: ``intermediate_size`` is the width of one routed expert (read where
+#: ``num_local_experts`` > 0); optionally ``held``, the (first, count)
+#: of the routed experts this graph holds
 GRANITE_KEYS = ("num_key_value_heads", "layer_types", "mamba_n_heads",
                 "mamba_d_head", "mamba_d_state", "mamba_n_groups",
                 "mamba_d_conv", "mamba_expand", "mamba_chunk_size",
                 "mamba_conv_bias", "mamba_proj_bias",
                 "shared_intermediate_size", "num_local_experts",
+                "num_experts_per_tok", "intermediate_size",
                 "position_embedding_type", "embedding_multiplier",
                 "residual_multiplier", "attention_multiplier",
                 "logits_scaling")
@@ -904,18 +908,26 @@ def _granite_spec(spec):
     """Granite 4.0-H's block (per-slot only) from ``granite``, the
     published config's keys (``GRANITE_KEYS``), no bias but the
     convolution's: ``x = x + m Mixer(N(x))``, ``x = x + m FF(N(x))``
-    with ``m = residual_multiplier``, RMSNorm, a dense gated-SiLU
-    feed-forward of ``shared_intermediate_size`` on every layer, the
-    embedding times ``embedding_multiplier``, a tied head whose logits
-    are divided by ``logits_scaling``. **A mixer per layer**
-    (``layer_types``): ``"mamba"`` is a Mamba-2 mixer whose state is a
-    convolution's tail and one matrix a head, constant in the context
-    (``_mamba_mixer``; families ``"conv"`` and ``"recurrent"``);
-    ``"attention"`` is grouped attention without positions under
-    ``attention_multiplier`` (``_nope_attention``; ``"rows"``, the only
-    pools that grow with ``capacity``). What this graph does not build
-    is refused: more than one group of B and C, a bias on the mixer's
-    projections, routed experts, positions."""
+    with ``m = residual_multiplier``, RMSNorm, the embedding times
+    ``embedding_multiplier``, a tied head whose logits are divided by
+    ``logits_scaling``. **A mixer per layer** (``layer_types``):
+    ``"mamba"`` is a Mamba-2 mixer whose state is a convolution's tail
+    and one matrix a head, constant in the context (``_mamba_mixer``;
+    families ``"conv"`` and ``"recurrent"``); ``"attention"`` is grouped
+    attention without positions under ``attention_multiplier``
+    (``_nope_attention``; ``"rows"``, the only pools that grow with
+    ``capacity``). **The feed-forward of every layer** by
+    ``num_local_experts``: 0 (Micro) is one dense gated SiLU of
+    ``shared_intermediate_size``; above 0 (Small) it is that many
+    routed experts of ``intermediate_size``, ``num_experts_per_tok`` a
+    token under a softmax over the chosen logits, beside a shared
+    gated SiLU of ``shared_intermediate_size`` that every token passes
+    (``MoEFFN``: ``norm_topk`` over a softmax of all is the softmax
+    over the chosen), of which this graph holds ``held`` (first, count;
+    default all). What this graph does not build is refused: more than
+    one group of B and C, a bias on the mixer's projections, positions,
+    a choice of no expert or of more than the router has, a share
+    outside the router's width."""
     cfg = _given_keys(spec, "granite", GRANITE_KEYS)
     kinds, n_layer, n_head = list(cfg["layer_types"]), spec["n_layer"], \
         spec["n_head"]
@@ -925,24 +937,45 @@ def _granite_spec(spec):
             f"'mamba' or 'attention' for each of {n_layer} layers")
     d_in = cfg["mamba_n_heads"] * cfg["mamba_d_head"]
     if cfg["mamba_n_groups"] != 1 or cfg["mamba_proj_bias"] \
-            or not cfg["mamba_conv_bias"] or cfg["num_local_experts"] \
+            or not cfg["mamba_conv_bias"] \
             or cfg["position_embedding_type"] != "nope" \
             or d_in != cfg["mamba_expand"] * spec["d_model"]:
         raise MXNetError(
             "block='granite_hybrid' builds one group of B and C, a "
-            "convolution with a bias, projections without, a dense "
-            "feed-forward, no positions and mamba_n_heads x mamba_d_head "
-            f"= mamba_expand x d_model (got {cfg})")
+            "convolution with a bias, projections without, no positions "
+            "and mamba_n_heads x mamba_d_head = mamba_expand x d_model "
+            f"(got {cfg})")
     if n_head % cfg["num_key_value_heads"] or spec["d_model"] % n_head:
         raise MXNetError(
             f"block='granite_hybrid': {n_head} query heads on "
             f"{cfg['num_key_value_heads']} K/V heads at d_model "
             f"{spec['d_model']}")
     cfg.update(layer_types=kinds, head_dim=spec["d_model"] // n_head)
+    shared = int(cfg["shared_intermediate_size"])
+    experts = int(cfg["num_local_experts"])
+    if not experts:
+        feed_forward = dict(dense=("gated", shared), dense_layers=n_layer)
+    else:
+        top_k = int(cfg["num_experts_per_tok"])
+        first, count = map(int, cfg.get("held") or (0, experts))
+        if not 1 <= top_k <= experts:
+            raise MXNetError(
+                f"block='granite_hybrid': num_experts_per_tok {top_k} of "
+                f"num_local_experts {experts} routed experts")
+        if first < 0 or count < 1 or first + count > experts:
+            raise MXNetError(
+                f"block='granite_hybrid': held experts {first}.."
+                f"{first + count} of num_local_experts {experts}")
+        cfg.update(held=(first, count))
+        feed_forward = dict(
+            dense_layers=0, moe_fold="ffn_fold",
+            moe=dict(step_len=spec["T"], num_experts=experts,
+                     num_hidden=int(cfg["intermediate_size"]), top_k=top_k,
+                     norm_topk=True, held_first=first, held_count=count,
+                     shared_hidden=shared))
     return dict(
         spec, cfg=cfg, norm=_rms(spec), attention=_hybrid_mixer, bias=False,
-        dense=("gated", int(cfg["shared_intermediate_size"])),
-        dense_layers=n_layer, fed=True, pos_embed="rotary", tie_head=True,
+        fed=True, pos_embed="rotary", tie_head=True, **feed_forward,
         multipliers={"embedding": float(cfg["embedding_multiplier"]),
                      "residual": float(cfg["residual_multiplier"]),
                      "logits": float(cfg["logits_scaling"])})

@@ -57,7 +57,11 @@ the pads of one window, all alike, pile onto the same experts. The
 sorted held rows are taken ``_segment_rows`` at a time in a loop of as
 many trips as the load needs - one at the expected load - so that no
 assignment is dropped and no buffer is sized for all of them. With none
-of these set the op is the one above, to the bit.
+of these set the op is the one above, to the bit. The softmax router
+takes a share and a shared expert alike (Granite 4.0-H Small: ``top_k``
+10 of 72 under ``norm_topk`` - the published softmax over the chosen
+logits -, 36 held, ``shared_hidden`` 1,536): ``moe_route``'s choice and
+weights over all experts, then the held experts' part as above.
 
 ``step_len`` alone (OLMoE's slot-pooled graph) makes no share: the
 plain layer takes ``fed`` too, sends a pad's choices to a dead group
@@ -457,11 +461,13 @@ def _moe_inputs(attrs):
 #: experts that got at least one, each execution's busiest expert's
 #: assignments and, where the layer holds a share of its experts (the
 #: counts are then of the held ones), the assignments that landed on
-#: them
+#: them - in the ring too (``moe_held``), so that a window's record says
+#: how many rows its grouped matmuls ran over beside the experts they
+#: read
 _MOE_COUNTS = read_counts(
     ("moe.layer_steps", "moe_layer_steps"), ("moe.assignments", None),
     ("moe.experts_touched", "moe_touched"), ("moe.max_expert_load", None),
-    ("moe.held_assignments", None))
+    ("moe.held_assignments", "moe_held"))
 
 register("MoEFFN", inputs=_moe_inputs, aux=("moe_stats",), full=_moe_fwd,
          num_outputs=2, output_names=["output", "experts"], num_visible=1,

@@ -140,17 +140,19 @@ def test_eva_attention_compiles_for_v5e_and_copies_no_pool(S, v5e):
 
 @pytest.mark.parametrize("S,rows", [(1, 32), (256, 384)],
                          ids=["decode", "packed_window"])
-def test_ssm_mixer_compiles_for_v5e_and_copies_no_state(S, rows, v5e):
+@pytest.mark.parametrize("H", [64, 128], ids=["micro", "small"])
+def test_ssm_mixer_compiles_for_v5e_and_copies_no_state(H, S, rows, v5e):
     """Granite 4.0-H's mixer at the published sizes (32 slots, 64 heads
-    of 64, a state of 128, a convolution over 4, chunks of 256; the S =
-    1 program's 32 rows and the packed window's 384): the Pallas
-    lowering compiles for the chip - the step's kernel, and in a window
-    the chunk's inside XLA's loop over the trips -, and with the aux
-    arrays donated the 67 MB state comes back in the buffer it came in,
-    never copied."""
+    of 64 - Micro - or 128 - Small, ISSUE 54: 64 lane groups, rows of
+    8,448 channels -, a state of 128, a convolution over 4, chunks of
+    256; the S = 1 program's 32 rows and the packed window's 384): the
+    Pallas lowering compiles for the chip - the step's kernel, and in a
+    window the chunk's inside XLA's loop over the trips -, and with the
+    aux arrays donated the 67 MB (134 MB) state comes back in the
+    buffer it came in, never copied."""
     import re
     opdef = get_op("ssm_mixer_decode")
-    H, P, N, K = 64, 64, 128, 4
+    P, N, K = 64, 128, 4
     d_in, C = H * P, H * P + 2 * N
     attrs = opdef.normalize_attrs(dict(
         heads=H, head_dim=P, d_state=N, d_conv=K, chunk=256, step_len=S,
@@ -176,7 +178,7 @@ def test_ssm_mixer_compiles_for_v5e_and_copies_no_state(S, rows, v5e):
         assert re.search(rf"%{kernel}[.\w]* = .*tpu_custom_call", text), \
             kernel
     assert len(re.findall("tpu_custom_call", text)) == len(kernels)
-    assert not re.findall(r"= f32\[32,32,128,128\]\S* copy\(", text)
+    assert not re.findall(rf"= f32\[32,{H // 2},128,128\]\S* copy\(", text)
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         32 * d_in * N * 4
 
@@ -538,7 +540,14 @@ def test_the_s1_latent_programs_lower_to_the_parents_text(lowering, v5e):
 #: last fed row in front of the head and the program returns ``(slots,
 #: 1, V)``, so the seven ``"packed"`` digests were recorded anew on its
 #: tree; every S = 1 and whole-window digest stands as it was.
+#: ``granite_hybrid`` (``num_local_experts`` 0, Micro's dense block)
+#: joined with ISSUE 54, which taught ``_granite_spec`` the routed
+#: layer: its three digests are PR 54's parent's (60de662), computed on
+#: a copy of it.
 _PARENT_PROGRAM_SHA256 = {
+    ("granite_hybrid", 1, "whole"): "e339ed008af6b332",
+    ("granite_hybrid", 16, "whole"): "c603e7f61d202afb",
+    ("granite_hybrid", 16, "packed"): "539fd786d5a6ebaa",
     ("glm_dsa", 1, "whole"): "9461136fdd6eaa09",
     ("glm_dsa", 16, "whole"): "96061a4da742fd99",
     ("axk1", 1, "whole"): "2927901ccdca78df",
@@ -671,6 +680,8 @@ def test_a_packed_window_program_holds_no_window_of_logits(block,
 #: recorded on ISSUE 47's tree (``fp8_cache`` is a slot-pooled graph
 #: too); the training and one-cursor graphs are the parent's still.
 _PARENT_GRAPH_SHA256 = {
+    ("granite_hybrid", 1): "98ba507ff8be6d3b",      # PR 54's parent's
+    ("granite_hybrid", 16): "c66f2e97f9e26b0b",
     ("glm_dsa", 1): "8562f0a3f8b916d7",
     ("glm_dsa", 16): "29129fe7d994fe2c",
     ("axk1", 1): "64a8301c7b5af164",
@@ -1055,6 +1066,50 @@ def test_group_limited_share_compiles_for_v5e_with_the_grouped_kernels(S, v5e):
         assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
 
 
+@pytest.mark.parametrize("step_len,rows,slots", [
+    (1, 32, 32), (256, 384, 1), (256, 8192, 32)],
+    ids=["decode", "packed_window", "whole_window"])
+def test_softmax_share_compiles_for_v5e_with_the_grouped_kernels(
+        step_len, rows, slots, v5e):
+    """Granite 4.0-H Small's ``MoEFFN`` at the published sizes (rows of
+    4,096, a softmax router over 72 experts, 10 a token, 36 held of
+    width 768 beside a shared feed-forward of 1,536; ISSUE 54): the
+    softmax branch of the share - ``moe_route`` under ``held_first`` /
+    ``held_count`` - is eligible for the Pallas lowering and both
+    ``moe_gmm_*`` kernels compile for the chip inside the loop over the
+    held rows' segments, over an S = 1 step's 32 rows, a packed
+    window's 384 (one count for all of them) and the whole window's
+    8,192 that the benchmark's ``check_reference`` runs."""
+    import re
+    D, F, Fs, E, held = 4096, 768, 1536, 72, 36
+    opdef = get_op("MoEFFN")
+    attrs = opdef.normalize_attrs(dict(
+        num_experts=E, num_hidden=F, top_k=10, norm_topk=True,
+        held_first=0, held_count=held, shared_hidden=Fs,
+        step_len=step_len))
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ins = [sds((rows, D)), sds((slots,), jnp.int32), sds((E, D)),
+           sds((held, D, F)), sds((held, D, F)), sds((held, F, D)),
+           sds((D, Fs)), sds((D, Fs)), sds((Fs, D))]
+    assert opdef.input_names(attrs) == [
+        "data", "fed", "router_weight", "gate_weight", "up_weight",
+        "down_weight", "shared_gate_weight", "shared_up_weight",
+        "shared_down_weight"]
+    aux = [sds((5,), jnp.int32)]
+    assert opdef.variant_eligible("pallas", attrs,
+                                  [a.shape for a in ins + aux],
+                                  [str(a.dtype) for a in ins + aux])
+    fn = opdef.variant_fn("pallas")
+    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None)) \
+        .lower(ins, aux).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_gmm_gate_up", "moe_gmm_down"):
+        assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
+
+
 def test_a_prefix_join_compiles_for_v5e_and_copies_no_pool(v5e):
     """``BatchedKVCacheDecoder``'s row programs at A.X-K1's sizes (five
     latent pools of 8 x 32,768 rows of 640 lanes, 1,024 rows a launch):
@@ -1164,6 +1219,39 @@ def test_serve_phase_body_tiny(capsys):
     assert line["ok"] and line["compiles_since_warmup"] == 0
     assert line["agreed_tokens"] == [5, 5, 5] and line["windows"] == [4]
     assert [len(a) for a in answers] == [5, 5, 5]
+
+
+def test_layer_pair_phase_body_tiny(capsys, monkeypatch):
+    """The smoke's layer pair (ISSUE 54: a mamba layer and the attention
+    layer with routed experts, half held) at tiny widths on the CPU,
+    float32: a packed window with riders, then the S = 1 step they are
+    held to."""
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
+    kernel_tier.clear()
+    model = dict(vocab_size=64, d_model=32, n_layer=2, n_head=4,
+                 granite=dict(chip_smoke.GRANITE_SMALL_PAIR["granite"],
+                              num_key_value_heads=1, mamba_n_heads=8,
+                              mamba_d_head=8, mamba_d_state=16,
+                              mamba_chunk_size=8,
+                              shared_intermediate_size=24,
+                              num_local_experts=8, num_experts_per_tok=3,
+                              intermediate_size=16, held=(0, 4)))
+    try:
+        chip_smoke.layer_pair_phase(model, slots=4, window=16, capacity=64,
+                                    context=mx.cpu(), compute_dtype=None,
+                                    seed=0)
+    finally:
+        kernel_tier.clear()
+    line = _phase_line(capsys, "pair")
+    assert line["ok"] and line["packed_rows"] == 24
+    assert (line["prefill_rows"], line["riders"]) == (16, 3)
+    assert line["assignments"] == 2 * 3 * 19
+    assert 0 < line["held_assignments"] < line["assignments"]
+    assert line["rider_vs_step_max_abs_err"] <= line["tolerance"]
+    pair = chip_smoke.GRANITE_SMALL_PAIR
+    assert (pair["d_model"], pair["granite"]["mamba_n_heads"],
+            pair["granite"]["num_local_experts"],
+            pair["granite"]["held"]) == (4096, 128, 72, (0, 36))
 
 
 def test_multichip_phase_body_on_virtual_devices(capsys, monkeypatch):

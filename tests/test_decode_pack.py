@@ -925,6 +925,13 @@ def test_the_counters_and_the_ring_fields_are_the_parents(block):
     # dispatch, while that is on the chip, and is active at its commit:
     # a dispatch early (its first chunk lies where the parent's did)
     ring[2]["active"] -= 1
+    # ISSUE 54: a layer that holds a share says in the ring too how many
+    # assignments landed here (``moe_held``), dispatch by dispatch what
+    # the counter sums; the plain layer (OLMoE's) counts none and its
+    # counter stays 0
+    held = [r.pop("moe_held") for r in ring if "moe_held" in r]
+    assert sum(held) == want.get("moe.held_assignments", 0)
+    assert len(held) == (len(ring) if sum(held) else 0)
     assert ring == COUNTS[block][1]
 
 

@@ -42,13 +42,20 @@ from chipbench.tests import test_granite_hybrid as _bench  # noqa: E402
 
 def test_the_traffic_is_the_issues(monkeypatch):
     """The benchmark's own case as it stands, over the manifest up to
-    Granite's cell: it counts the cells (eleven when PR 48 wrote it),
-    and a later cell is appended behind Granite's - the file is the
-    benchmark's and a `benchmark` PR's to edit (`PERF.md` section 7)."""
+    Granite's cell: it counts the cells (eleven when PR 48 wrote it) and
+    holds the ``ssm.*`` metrics to this cell alone, and a later cell is
+    appended behind Granite's and may join their lists (PR 54's does) -
+    the file is the benchmark's and a `benchmark` PR's to edit
+    (`PERF.md` section 7)."""
     from chipbench import manifest
     whole = manifest.load()
     at = [w["name"] for w in whole["workloads"]].index(_bench.REAL_CELL)
-    trimmed = dict(whole, workloads=whole["workloads"][:at + 1])
+    kept = whole["workloads"][:at + 1]
+    names = {w["name"] for w in kept}
+    trimmed = dict(whole, workloads=kept, **{
+        part: [dict(m, workloads=[w for w in m["workloads"] if w in names])
+               if "workloads" in m else m for m in whole[part]]
+        for part in ("end_to_end", "per_layer")})
     monkeypatch.setattr(manifest, "load", lambda *a, **kw: trimmed)
     _bench.test_the_traffic_is_the_issues()
 
@@ -60,7 +67,8 @@ CFG = {"vocab_size": 96, "hidden_size": 32, "num_attention_heads": 4,
        "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_expand": 2,
        "mamba_chunk_size": 8, "mamba_conv_bias": True,
        "mamba_proj_bias": False, "shared_intermediate_size": 48,
-       "num_local_experts": 0, "position_embedding_type": "nope",
+       "num_local_experts": 0, "num_experts_per_tok": 0,
+       "intermediate_size": 48, "position_embedding_type": "nope",
        "embedding_multiplier": 12.0, "residual_multiplier": 0.22,
        "attention_multiplier": 0.125, "logits_scaling": 8.0,
        "rms_norm_eps": 1e-5}
@@ -472,16 +480,53 @@ def test_a_bfloat16_state_misses_the_tolerance():
     assert (np.abs(again - want) / bound).max() <= 0.01
 
 
-def test_the_builder_refuses_what_the_block_is_not():
-    for wrong in ({"mamba_n_groups": 2}, {"mamba_proj_bias": True},
-                  {"num_local_experts": 4},
-                  {"position_embedding_type": "rope"},
-                  {"layer_types": ["mamba", "attention"]},
-                  {"layer_types": ["mamba", "mlp", "mamba"]}):
-        with pytest.raises(MXNetError, match="granite_hybrid"):
-            _symbol(1, dict(CFG, **wrong))
+#: what the block refuses, by key (``_granite_spec``); with routed
+#: experts on (ISSUE 54: ``num_local_experts`` > 0 builds ``MoEFFN``) a
+#: choice of no expert or of more than the router has, and a held range
+#: outside the router's width
+_ROUTED = {"num_local_experts": 4, "num_experts_per_tok": 2,
+           "intermediate_size": 16}
+_REFUSED = {
+    "two_groups": {"mamba_n_groups": 2},
+    "projection_bias": {"mamba_proj_bias": True},
+    "positions": {"position_embedding_type": "rope"},
+    "a_layer_short": {"layer_types": ["mamba", "attention"]},
+    "a_layer_of_another_kind": {"layer_types": ["mamba", "mlp", "mamba"]},
+    "no_expert_a_token": dict(_ROUTED, num_experts_per_tok=0),
+    "more_experts_a_token_than_the_router_has":
+        dict(_ROUTED, num_experts_per_tok=5),
+    "held_past_the_router": dict(_ROUTED, held=(2, 3)),
+    "held_before_the_router": dict(_ROUTED, held=(-1, 2)),
+    "nothing_held": dict(_ROUTED, held=(0, 0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_REFUSED))
+def test_the_builder_refuses_what_the_block_is_not(case):
+    with pytest.raises(MXNetError, match="granite_hybrid"):
+        tfm.get_decode_symbol(
+            vocab_size=96, d_model=32, n_layer=3, n_head=4, capacity=CAPACITY,
+            per_slot=True, block="granite_hybrid",
+            granite=dict({k: CFG[k] for k in tfm.GRANITE_KEYS},
+                         **_REFUSED[case]))
+
+
+def test_the_builder_refuses_a_graph_it_does_not_have():
     with pytest.raises(MXNetError, match="served, not trained"):
         tfm.get_symbol(block="granite_hybrid")
+    with pytest.raises(MXNetError, match="needs granite="):
+        tfm.get_decode_symbol(block="granite_hybrid", per_slot=True,
+                              granite={"num_local_experts": 0})
+    # routed experts are built now, every one held or a share of them
+    for held in (None, (1, 2)):
+        sym = tfm.get_decode_symbol(
+            vocab_size=96, d_model=32, n_layer=3, n_head=4, capacity=CAPACITY,
+            per_slot=True, block="granite_hybrid",
+            granite=dict({k: CFG[k] for k in tfm.GRANITE_KEYS}, **_ROUTED,
+                         **({"held": held} if held else {})))
+        moe = [n for n in sym._topo_nodes() if n.op == "MoEFFN"]
+        assert len(moe) == 3
+        assert int(moe[0].attrs["held_count"]) == (2 if held else 4)
 
 
 # --------------------------------------------------- engine and scheduler

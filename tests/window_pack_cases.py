@@ -48,7 +48,8 @@ _GRANITE = {"num_key_value_heads": 2,
             "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_expand": 2,
             "mamba_chunk_size": 8, "mamba_conv_bias": True,
             "mamba_proj_bias": False, "shared_intermediate_size": 96,
-            "num_local_experts": 0, "position_embedding_type": "nope",
+            "num_local_experts": 0, "num_experts_per_tok": 0,
+            "intermediate_size": 96, "position_embedding_type": "nope",
             "embedding_multiplier": 12.0, "residual_multiplier": 0.22,
             "attention_multiplier": 0.125, "logits_scaling": 8.0}
 

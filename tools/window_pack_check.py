@@ -31,8 +31,13 @@ and riders share one dispatch. Prints one JSON line.
 
     python3 tools/window_pack_check.py
         --config a.x-k1|glm-5.2|xing4.0-29b-a4b|cerebras-gpt-1.3b|olmoe-1b-7b
-                 |granite-4.0-h-micro|ling-3.0-flash
-        [--seed N] [--part N] [--rehearse]
+                 |granite-4.0-h-micro|ling-3.0-flash|granite-4.0-h-small
+        [--seed N] [--part N] [--context N] [--rehearse]
+
+``--context N`` prefills N positions (whole windows) in place of all the
+capacity allows: ``granite-4.0-h-small``'s reference attends all
+positions at once in float32, 8.6 GB at its capacity of 8,192 beside
+9.5 GB of parameters, so it is held to ``--context 3840``.
 
 ``--rehearse`` runs the configuration's tiny fixture on the CPU
 (chipbench/tests/fixtures: a context of 64, windows of 16; for the
@@ -58,6 +63,8 @@ _TINY = {"a.x-k1": ("axk1", "tiny-axk1.json"),
          "olmoe-1b-7b": ("olmoe", "tiny-olmoe.json"),
          "granite-4.0-h-micro": ("granite_hybrid", "tiny-granite.json"),
          "ling-3.0-flash": ("ling_hybrid", "tiny-ling.json"),
+         "granite-4.0-h-small": ("granite_moe_hybrid",
+                                 "tiny-granite-small.json"),
          "cerebras-gpt-1.3b": None}
 
 #: the Cerebras configuration cut to a rehearsal's size (learned
@@ -74,6 +81,7 @@ def main(argv=None):
     ap.add_argument("--config", choices=sorted(_TINY), required=True)
     ap.add_argument("--seed", type=int, default=2147480243)
     ap.add_argument("--part", type=int, default=0)
+    ap.add_argument("--context", type=int, default=16384)
     ap.add_argument("--rehearse", action="store_true")
     ns = ap.parse_args(argv)
     from chipbench import common, manifest
@@ -97,7 +105,7 @@ def main(argv=None):
 
     S = cfg["prefill_chunk"]
     n_cmp = min(16, S)
-    ctx = min(16384, cfg["capacity"] - S) if not ns.rehearse else 3 * S
+    ctx = min(ns.context, cfg["capacity"] - S) if not ns.rehearse else 3 * S
     windows = ctx // S + 1
     assert ctx % S == 0 and windows * S <= cfg["capacity"]
     gen = functools.partial(arch.decode_symbol, cfg)
