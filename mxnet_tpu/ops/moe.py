@@ -26,8 +26,9 @@ exactly the routed rows: no expert is computed for a token not routed
 to it, no assignment is dropped (no capacity factor). The ``pallas``
 variant (ops/pallas_kernels.grouped_expert_ffn) runs the same sorted
 rows through two grouped-matmul kernels named ``moe_gmm_*`` in the
-device trace; at a decode step it reads each touched expert's weights
-once and no untouched expert's.
+device trace; a call reads each touched expert's weights once wherever
+its rows lie (once more for every 112 rows past its first 112) and no
+untouched expert's.
 
 **A chip's share of a wider layer** (the DeepSeek-V3 family as GLM-5.2
 runs it; expert parallelism without its exchange). With

@@ -1628,78 +1628,139 @@ def _cache_write(pos, news, pools, interpret, name="cache_write",
 #: a weight tile streamed from HBM is at most this many bytes: long
 #: contiguous rows at a decode step, where the kernel is bandwidth-bound
 _GMM_TILE_BYTES = 1 << 20
-#: rows of the sorted assignments a grid step multiplies
+#: rows of the sorted assignments a grid step multiplies, and the rows
+#: of an output tile
 _GMM_ROWS = 128
+#: rows are addressed in granules of this many: a whole tile of the
+#: narrowest dtype the kernels take (bfloat16: 16 sublanes), which is
+#: what the chip's compiler lets a block or a slice start on
+_GMM_GRANULE = 16
 #: the widest model (D) or expert (F) the grouped kernels are offered:
 #: an output tile of that many float32 columns beside its accumulator
 _GMM_MAX_WIDTH = 7168
 
 
-def _gmm_work_items(group_sizes, tiles_m, tm):
-    """Which (row tile, group) pairs a grouped matmul visits.
+def _gmm_geometry(M, E):
+    """The static sizes of a grouped matmul over ``M`` sorted rows of
+    ``E`` groups: ``(rows, window, chunk, W)``. The rows are padded to
+    whole granules; a work item multiplies a ``window`` of rows that
+    starts on a granule, so it carries ``chunk = window - granule``
+    rows of one group wherever they begin (all of them where one window
+    spans the call); the output is cut into tiles of ``window`` rows;
+    ``W`` bounds the work items (a group has one, and one more for
+    every ``chunk`` rows past the first)."""
+    rows = -(-M // _GMM_GRANULE) * _GMM_GRANULE
+    window = min(_GMM_ROWS, rows)
+    chunk = window if rows == window else window - _GMM_GRANULE
+    W = min(E, M) + (M - 1) // chunk
+    return rows, window, chunk, W
 
-    The sorted rows are cut into ``tiles_m`` tiles of ``tm`` rows; a
-    group visits every tile it has a row in, an empty group none, so a
-    tile that several groups share is visited once by each, in group
-    order (consecutively: an output tile is written back once). At most
-    ``tiles_m + E - 1`` visits; the static grid has that many steps and
-    the steps past the last visit repeat it, so that they move no data,
-    and compute nothing (``n_items`` tells them apart). Returns int32
-    ``(offsets (E+1,), item_group (W,), item_tile (W,), n_items (1,))``.
-    """
+
+def _gmm_work_items(group_sizes, M):
+    """The work items of a grouped matmul: which rows of which group a
+    grid step multiplies.
+
+    A group's rows are cut, from the group's own first row, into chunks
+    of ``chunk`` rows (``_gmm_geometry``), and a chunk is an item; an
+    empty group has none, so the items are in row order and each begins
+    where the one before ends. Returns int32 ``(items (4, W + 1),
+    n_items (1,))``; the rows of ``items`` are the item's group, the
+    granule its window of rows starts on (the one its first row lies
+    in, or as far back as keeps the window inside the rows), and its
+    rows ``[lo, hi)``. The grid runs ``n_items + 1`` items: the entries
+    from ``n_items`` on repeat the last item's group and window, so
+    that the one behind the last item moves no data, with the last
+    item's last row as theirs, which puts them on its last output
+    tile."""
     E = group_sizes.shape[0]
+    rows, window, chunk, W = _gmm_geometry(M, E)
     ends = jnp.cumsum(group_sizes)
-    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
-    first = offsets[:-1] // tm
-    last = (jnp.maximum(ends, 1) - 1) // tm
-    n_tiles = jnp.where(group_sizes > 0, last - first + 1, 0)
-    item_ends = jnp.cumsum(n_tiles)
-    n_items = item_ends[-1]
-    W = tiles_m + E - 1
-    i = jnp.minimum(jnp.arange(W, dtype=jnp.int32),
-                    jnp.maximum(n_items, 1) - 1)
-    group = jnp.minimum(jnp.searchsorted(item_ends, i, side="right"),
-                        E - 1).astype(jnp.int32)
-    tile = first[group] + (i - (item_ends[group] - n_tiles[group]))
-    return (offsets.astype(jnp.int32), group,
-            jnp.clip(tile, 0, tiles_m - 1).astype(jnp.int32),
-            n_items.reshape((1,)).astype(jnp.int32))
+    chunks = -(-group_sizes // chunk)
+    chunk_ends = jnp.cumsum(chunks)
+    n_items = chunk_ends[-1]
+    j = jnp.arange(W + 1, dtype=jnp.int32)
+    live = j < n_items
+    i = jnp.minimum(j, jnp.maximum(n_items, 1) - 1)
+    # a handful of items against a handful of groups: one comparison of
+    # all with all, where a binary search is a loop of launches
+    group = jnp.minimum(
+        jnp.searchsorted(chunk_ends, i, side="right", method="compare_all"),
+        E - 1).astype(jnp.int32)
+    nth = i - (chunk_ends[group] - chunks[group])
+    first = ends[group] - group_sizes[group] + nth * chunk
+    hi = jnp.minimum(first + chunk, ends[group])
+    lo = jnp.where(live, first, hi - 1)
+    start = jnp.minimum(first, rows - window) // _GMM_GRANULE
+    items = jnp.stack([group, start, jnp.maximum(lo, 0), hi])
+    return items.astype(jnp.int32), n_items.reshape((1,)).astype(jnp.int32)
 
 
-def _gmm_kernel(n_rhs, tm, epilogue):
-    """out[rows of the item's group in its tile] = epilogue(x @ w[g]
-    for each of ``n_rhs`` stacked weights), float32 accumulation over
-    the k steps."""
-    def kernel(off_ref, grp_ref, tile_ref, n_ref, x_ref, *refs):
+def _silu_gate(g, u):
+    return g * jax.nn.sigmoid(g) * u
+
+
+def _identity(y):
+    return y
+
+
+def _gmm_kernel(n_rhs, window, epilogue):
+    """One work item's ``k`` step (``_gmm_work_items``): the window's
+    rows times the group's ``(bk, N)`` block of each of ``n_rhs``
+    stacked weights, float32 accumulation over the k steps; at the last
+    one ``epilogue`` of the accumulators goes to the item's rows of the
+    output tile its first row lies in, granule by granule, and to no
+    other row. Where the item's rows reach into the next tile, the next
+    item - the one behind the last included, which does nothing else -
+    begins in that tile and writes them from the accumulators before it
+    takes them over."""
+    G = _GMM_GRANULE
+
+    def kernel(items_ref, n_ref, x_ref, *refs):
         w_refs, o_ref, accs = refs[:n_rhs], refs[n_rhs], refs[n_rhs + 1:]
         i, kk = pl.program_id(0), pl.program_id(1)
-        live = i < n_ref[0]
+        tile_row = items_ref[2, i] // window * window
 
-        @pl.when(kk == 0)
-        def _zero():
-            for acc in accs:
-                acc[...] = jnp.zeros(acc.shape, jnp.float32)
+        def write(item):
+            """``item``'s rows of this step's output tile."""
+            first_row = items_ref[1, item] * G      # of the accumulators
+            lo, hi = items_ref[2, item], items_ref[3, item]
+            g0 = jnp.maximum(lo, tile_row) // G
+            g1 = (jnp.minimum(hi, tile_row + window) + G - 1) // G
+
+            def granule(g, carry):
+                src = pl.multiple_of(g * G - first_row, G)
+                dst = pl.multiple_of(g * G - tile_row, G)
+                rows = g * G + jax.lax.broadcasted_iota(
+                    jnp.int32, (G, o_ref.shape[1]), 0)
+                val = epilogue(*[acc[pl.ds(src, G), :] for acc in accs])
+                o_ref[pl.ds(dst, G), :] = jnp.where(
+                    (rows >= lo) & (rows < hi), val.astype(o_ref.dtype),
+                    o_ref[pl.ds(dst, G), :])
+                return carry
+
+            jax.lax.fori_loop(g0, g1, granule, 0)
+
+        before = jnp.maximum(i - 1, 0)
+
+        @pl.when((kk == 0) & (i > 0) & (items_ref[2, before] < tile_row)
+                 & (items_ref[3, before] > tile_row))
+        def _reach():
+            write(before)
+
+        live = i < n_ref[0]
 
         @pl.when(live)
         def _accumulate():
             x = x_ref[...]
             for w_ref, acc in zip(w_refs, accs):
-                acc[...] += jnp.dot(x, w_ref[...],
-                                    preferred_element_type=jnp.float32)
+                part = jnp.dot(x, w_ref[...],
+                               preferred_element_type=jnp.float32)
+                # the first step finds whatever the accumulator held
+                acc[...] = jnp.where(kk == 0, part, acc[...] + part)
 
-        @pl.when(kk == pl.num_programs(1) - 1)
+        @pl.when(live & (kk == pl.num_programs(1) - 1))
         def _store():
-            g = grp_ref[i]
-            rows = tile_ref[i] * tm + jax.lax.broadcasted_iota(
-                jnp.int32, o_ref.shape, 0)
-            mine = (rows >= off_ref[g]) & (rows < off_ref[g + 1]) & live
-            # the first visit of a tile finds whatever the buffer held
-            fresh = (i == 0) | (tile_ref[i]
-                                != tile_ref[jnp.maximum(i - 1, 0)])
-            kept = jnp.where(fresh, jnp.zeros(o_ref.shape, o_ref.dtype),
-                             o_ref[...])
-            val = epilogue(*[acc[...] for acc in accs]).astype(o_ref.dtype)
-            o_ref[...] = jnp.where(mine, val, kept)
+            write(i)
     return kernel
 
 
@@ -1714,54 +1775,70 @@ def _gmm_block_k(K, N, itemsize):
     return K
 
 
-def grouped_matmul(x, weights, items, tm, epilogue, out_dtype, name):
+def grouped_matmul(x, weights, items, window, epilogue, out_dtype, name,
+                   interpret):
     """``epilogue(x @ w[g] for w in weights)`` row group by row group.
 
-    ``x`` (M, K) holds the sorted rows, M a multiple of ``tm``;
+    ``x`` (rows, K) holds the sorted rows, whole granules of them;
     ``weights`` are stacked (E, K, N) matrices read K-major; ``items``
-    is ``_gmm_work_items``' tuple. Rows that belong to no group come
-    out zero. ``name`` is the kernel's name in the device trace."""
+    is ``_gmm_work_items``' pair. A work item is a chunk of one group's
+    own rows - ``window`` less a granule of them, counted from the
+    group's first row, wherever that lies - so **each ``(bk, N)`` block
+    of a group's weights crosses HBM once for every such chunk the
+    group has**: once a call for a group of up to 112 rows (up to 128
+    where the call has no more), and never for a group without rows.
+    The grid has as many items as the groups' rows make, and one: its
+    first bound is data, and no step is spent on an item that is not
+    there. The rows reach the kernel as a window that starts on the
+    granule the chunk starts in (an element-indexed block), the output
+    as tiles of ``window`` rows of which the items write their own
+    rows: a row of no group keeps what the buffer held (both callers
+    mask them), and a tile without a group's row is not written at
+    all. Returns ``(tiles_m * window, N)``; ``name`` is the kernel's
+    name in the device trace."""
     from jax.experimental.pallas import tpu as pltpu
 
-    M, K = x.shape
+    rows, K = x.shape
     N = weights[0].shape[2]
-    bk = _gmm_block_k(K, N, weights[0].dtype.itemsize)
+    itemsize = weights[0].dtype.itemsize
+    bk = _gmm_block_k(K, N, itemsize)
     n_k = K // bk
-    W = items[1].shape[0]
+    tiles_m = -(-rows // window)
+    table, n_items = items
 
-    def k_of(i, kk, n_ref):
-        # a step past the last visit stays on the block it holds
-        return jnp.where(i < n_ref[0], kk, n_k - 1)
+    def k_of(i, kk, n):
+        # the item behind the last stays on the block that one held
+        return jnp.where(i < n[0], kk, n_k - 1)
 
-    def x_map(i, kk, off, grp, tile, n):
-        return tile[i], k_of(i, kk, n)
+    def x_map(i, kk, item, n):
+        return item[1, i] * _GMM_GRANULE, k_of(i, kk, n) * bk
 
-    def w_map(i, kk, off, grp, tile, n):
-        return grp[i], k_of(i, kk, n), 0
+    def w_map(i, kk, item, n):
+        return item[0, i], k_of(i, kk, n), 0
 
-    def o_map(i, kk, off, grp, tile, n):
-        return tile[i], 0
+    def o_map(i, kk, item, n):
+        return item[2, i] // window, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4, grid=(W, n_k),
-        in_specs=[pl.BlockSpec((tm, bk), x_map)]
+        num_scalar_prefetch=2, grid=(n_items[0] + 1, n_k),
+        in_specs=[pl.BlockSpec((pl.Element(window), pl.Element(bk)), x_map)]
         + [pl.BlockSpec((None, bk, N), w_map) for _ in weights],
-        out_specs=pl.BlockSpec((tm, N), o_map),
-        scratch_shapes=[pltpu.VMEM((tm, N), jnp.float32)
+        out_specs=pl.BlockSpec((window, N), o_map),
+        scratch_shapes=[pltpu.VMEM((window, N), jnp.float32)
                         for _ in weights])
     # double-buffered blocks and the accumulators; past the 16 MiB a
     # kernel gets unasked (rows of 6,144: a 3 MiB output tile) it asks
-    itemsize = weights[0].dtype.itemsize
-    resident = 2 * tm * bk * itemsize + len(weights) * (
-        2 * bk * N * itemsize + tm * N * 4) \
-        + 2 * tm * N * jnp.dtype(out_dtype).itemsize
-    kwargs = {} if _interpret() or resident <= (10 << 20) else {
+    resident = 2 * window * bk * itemsize + len(weights) * (
+        2 * bk * N * itemsize + window * N * 4) \
+        + 2 * window * N * jnp.dtype(out_dtype).itemsize
+    kwargs = {} if interpret or resident <= (10 << 20) else {
         "compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=4 * resident)}
     return pallas_call(
-        _gmm_kernel(len(weights), tm, epilogue),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        grid_spec=grid_spec, name=name, **kwargs)(*items, x, *weights)
+        _gmm_kernel(len(weights), window, epilogue),
+        out_shape=jax.ShapeDtypeStruct((tiles_m * window, N), out_dtype),
+        grid_spec=grid_spec, name=name, interpret=interpret,
+        **kwargs)(table, n_items, x, *weights)
 
 
 def grouped_expert_ffn(xs, group_sizes, gate, up, down):
@@ -1770,21 +1847,28 @@ def grouped_expert_ffn(xs, group_sizes, gate, up, down):
     ``group_sizes[e]`` consecutive rows -> (M, D) float32. Two kernels:
     ``moe_gmm_gate_up`` (both matmuls over one read of the rows, SiLU
     gate fused) and ``moe_gmm_down``. An expert's weights are read once
-    for every row tile it has a row in - once in all at a decode step,
-    whose rows fit one tile - and an expert without rows is not read."""
+    a call and kernel wherever its rows lie - once more for every 112
+    rows past the first 112 (``grouped_matmul``) - and an expert without
+    rows is not read. Rows past ``sum(group_sizes)`` come out undefined.
+
+    The pair is a jitted function of its own, so that a step program
+    lowers the two kernels once and calls them from every layer."""
+    return _grouped_expert_ffn(xs, group_sizes, gate, up, down,
+                               interpret=_interpret())
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _grouped_expert_ffn(xs, group_sizes, gate, up, down, interpret):
     M = xs.shape[0]
-    sub = 16 if xs.dtype.itemsize < 4 else 8
-    tm = min(_GMM_ROWS, -(-M // sub) * sub)
-    tiles_m = -(-M // tm)
-    if tiles_m * tm != M:
-        xs = jnp.pad(xs, ((0, tiles_m * tm - M), (0, 0)))
-    items = _gmm_work_items(group_sizes.astype(jnp.int32), tiles_m, tm)
+    rows, window = _gmm_geometry(M, group_sizes.shape[0])[:2]
+    if rows != M:
+        xs = jnp.pad(xs, ((0, rows - M), (0, 0)))
+    items = _gmm_work_items(group_sizes.astype(jnp.int32), M)
     h = grouped_matmul(
-        xs, (gate.astype(xs.dtype), up.astype(xs.dtype)), items, tm,
-        lambda g, u: g * jax.nn.sigmoid(g) * u, xs.dtype,
-        "moe_gmm_gate_up")
-    y = grouped_matmul(h, (down.astype(xs.dtype),), items, tm,
-                       lambda y: y, jnp.float32, "moe_gmm_down")
+        xs, (gate.astype(xs.dtype), up.astype(xs.dtype)), items, window,
+        _silu_gate, xs.dtype, "moe_gmm_gate_up", interpret)
+    y = grouped_matmul(h, (down.astype(xs.dtype),), items, window,
+                       _identity, jnp.float32, "moe_gmm_down", interpret)
     return y[:M]
 
 

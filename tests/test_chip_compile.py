@@ -1110,6 +1110,50 @@ def test_softmax_share_compiles_for_v5e_with_the_grouped_kernels(
         assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
 
 
+@pytest.mark.parametrize("form", ["plain", "held"])
+def test_two_expert_layers_lower_the_grouped_kernels_once(form, v5e,
+                                                          monkeypatch):
+    """A graph of two ``MoEFFN`` layers of equal shapes, lowered for the
+    chip: ``grouped_expert_ffn`` is a jitted function, so the program
+    holds one body of ``moe_gmm_gate_up`` and one of ``moe_gmm_down``
+    and calls them from both layers (inside the held experts' loop over
+    the segments too) - what a bind pays to turn the kernels into text
+    does not grow with the depth."""
+    import re
+    from mxnet_tpu.executor import _build_graph_runner
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "pallas")
+    kernel_tier.clear()
+    T, D, F, E = 256, 256, 128, 8
+    kw = dict(num_experts=E, num_hidden=F, top_k=2)
+    if form == "held":
+        kw.update(scoring="sigmoid", norm_topk=True, held_first=2,
+                  held_count=4)
+    x = mx.sym.var("data")
+    for layer in range(2):
+        x = mx.sym.MoEFFN(x, name=f"moe{layer}", **kw)
+    try:
+        runner, arg_names, aux_names, _ = _build_graph_runner(
+            x, compute_dtype="bfloat16")
+        arg_shapes, _, aux_shapes = x.infer_shape(data=(T, D))
+        args = {nm: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=v5e)
+                for nm, s in zip(arg_names, arg_shapes)}
+        aux = {nm: jax.ShapeDtypeStruct(s, jnp.int32, sharding=v5e)
+               for nm, s in zip(aux_names, aux_shapes)}
+        lowered = jax.jit(lambda a, st: runner(a, st, False, None)) \
+            .lower(args, aux)
+    finally:
+        kernel_tier.clear()
+    text = lowered.as_text()
+    assert text.count("@tpu_custom_call") == 2
+    for kernel in ("moe_gmm_gate_up", "moe_gmm_down"):
+        assert len(re.findall(kernel, text)) == 1, kernel
+    assert len(re.findall(r"call @_grouped_expert_ffn", text)) == 2
+    # and the chip's compiler takes both calls of the one body
+    compiled = lowered.compile().as_text()
+    for kernel in ("moe_gmm_gate_up", "moe_gmm_down"):
+        assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", compiled)
+
+
 def test_a_prefix_join_compiles_for_v5e_and_copies_no_pool(v5e):
     """``BatchedKVCacheDecoder``'s row programs at A.X-K1's sizes (five
     latent pools of 8 x 32,768 rows of 640 lanes, 1,024 rows a launch):

@@ -134,15 +134,35 @@ def test_rmsnorm_op_and_shapes():
 
 
 # ------------------------------------- the Pallas variant, interpret mode
-@pytest.mark.parametrize("sizes", [
-    [0, 1, 37, 0, 5, 16, 0, 21],            # empty groups, a group of one
-    [0, 0, 0, 0, 0, 0, 0, 8],               # one group only, the last
-    [130, 0, 1, 127, 0, 40, 2, 0],          # groups across row tiles
-    [1, 1, 1, 1, 1, 1, 1, 1]])
+def _spread(rows, groups, seed):
+    """``rows`` assignments over ``groups`` experts, uneven."""
+    rng = np.random.default_rng(seed)
+    return rng.multinomial(rows, rng.dirichlet(np.ones(groups))).tolist()
+
+
+#: (group sizes, rows behind the last group's)
+_GMM_CASES = {
+    "empty_groups_and_one_row": ([0, 1, 37, 0, 5, 16, 0, 21], 0),
+    "the_last_group_only": ([0, 0, 0, 0, 0, 0, 0, 8], 0),
+    "groups_across_row_tiles": ([130, 0, 1, 127, 0, 40, 2, 0], 0),
+    "a_row_each": ([1, 1, 1, 1, 1, 1, 1, 1], 0),
+    "longer_than_two_tiles": ([7, 300, 0, 5, 20, 0, 0, 1], 0),
+    "ends_on_a_tile_edge": ([100, 28, 0, 128, 3, 0, 125, 0], 0),
+    "all_in_the_first_group": ([200, 0, 0, 0, 0, 0, 0, 0], 0),
+    "all_in_the_last_group": ([0, 0, 0, 0, 0, 0, 0, 200], 0),
+    "a_window_of_2048_rows_over_64": (_spread(2048, 64, 7), 0),
+    "rows_past_the_groups": ([9, 0, 120, 31, 0, 0, 2, 0], 75),
+    "no_row_in_any_group": ([0, 0, 0, 0], 40),
+}
+
+
+@pytest.mark.parametrize("case", list(_GMM_CASES))
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_grouped_expert_ffn_matches_the_xla_composition(sizes, dtype):
+def test_grouped_expert_ffn_matches_the_xla_composition(case, dtype):
+    sizes, behind = _GMM_CASES[case]
     E, D, F = len(sizes), 64, 32
-    M = sum(sizes)
+    routed = sum(sizes)
+    M = routed + behind
     rng = np.random.default_rng(M)
     _, _, gate, up, down = _moe_inputs(rng, 1, D, F, E, jnp.dtype(dtype))
     xs = jnp.asarray(rng.normal(size=(M, D)), jnp.dtype(dtype))
@@ -151,24 +171,137 @@ def test_grouped_expert_ffn_matches_the_xla_composition(sizes, dtype):
     got = pallas_kernels.grouped_expert_ffn(xs, group_sizes, gate, up, down)
     assert got.shape == (M, D) and got.dtype == jnp.float32
     # the same products in another order of summation; bfloat16 rounds
-    # the gated activations once more on both sides
+    # the gated activations once more on both sides. Rows of no group
+    # are the callers' to mask
     tol = 2e-5 if dtype == "float32" else 2e-2
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=tol, rtol=0)
+    np.testing.assert_allclose(np.asarray(got)[:routed],
+                               np.asarray(want)[:routed], atol=tol, rtol=0)
 
 
-def test_gmm_work_items_visit_each_group_once_a_tile():
-    sizes = jnp.asarray([130, 0, 1, 127, 0, 40, 2, 0], jnp.int32)
-    tm, tiles_m = 128, 3                    # 300 rows padded to 384
-    offs, grp, tile, n = (np.asarray(a) for a in
-                          pallas_kernels._gmm_work_items(sizes, tiles_m, tm))
-    assert offs.tolist() == [0, 130, 130, 131, 258, 258, 298, 300, 300]
-    visits = list(zip(grp[:n[0]].tolist(), tile[:n[0]].tolist()))
-    assert visits == [(0, 0), (0, 1), (2, 1), (3, 1), (3, 2), (5, 2),
-                      (6, 2)]               # no empty group, tiles in order
-    # the steps past the last visit repeat it: no new block is fetched
-    assert set(zip(grp[n[0]:].tolist(), tile[n[0]:].tolist())) == {(6, 2)}
-    assert len(grp) == tiles_m + len(sizes) - 1
+def _work_items(sizes, M):
+    items, n = pallas_kernels._gmm_work_items(
+        jnp.asarray(sizes, jnp.int32), M)
+    grp, win, lo, hi = np.asarray(items)
+    return grp, win, lo, hi, int(n[0])
+
+
+def test_gmm_work_items_cut_the_rows_at_the_groups_edges():
+    sizes = [130, 0, 1, 127, 0, 40, 2, 0]
+    rows, window, chunk, W = pallas_kernels._gmm_geometry(300, 8)
+    assert (rows, window, chunk) == (304, 128, 112)
+    grp, win, lo, hi, n = _work_items(sizes, 300)
+    live = list(zip(grp[:n].tolist(), lo[:n].tolist(), hi[:n].tolist()))
+    assert live == [
+        (0, 0, 112), (0, 112, 130),         # chunks of the group's own
+        (2, 130, 131),                      # no empty group
+        (3, 131, 243), (3, 243, 258),       # across a tile's edge: one
+        (5, 258, 298), (6, 298, 300)]
+    # a window starts on the granule its chunk starts in, and never so
+    # late that it would leave the rows
+    assert win[:n].tolist() == [0, 7, 8, 8, 11, 11, 11]
+    # the entries behind repeat the last item's group and window, on the
+    # tile of its last row
+    assert set(zip(grp[n:].tolist(), win[n:].tolist(), lo[n:].tolist(),
+                   hi[n:].tolist())) == {(6, 11, 299, 300)}
+    assert len(grp) == W + 1 == 8 + 299 // 112 + 1
+
+
+@pytest.mark.parametrize("sizes,behind", [
+    ([130, 0, 1, 127, 0, 40, 2, 0], 0),
+    ([0, 0, 0, 0, 0, 0, 0, 8], 0),
+    ([1, 1, 1, 1, 1, 1, 1, 1], 5),
+    ([7, 300, 0, 5, 20, 0, 0, 1], 0),
+    ([100, 28, 0, 128, 3, 0, 125, 0], 0),
+    ([0, 0, 0, 0, 0, 0, 0, 1000], 24),
+    (_spread(2048, 64, 7), 0),
+    (_spread(512, 64, 3), 0),               # an S=1 step: 64 slots x 8
+    (_spread(1100, 128, 5), 52),            # a held segment, dead rows
+    ([0, 0, 0, 0], 40)],
+    ids=["across_tiles", "last_only", "a_row_each", "long_group",
+         "on_a_tile_edge", "one_long_group", "window_2048", "decode_512",
+         "segment_1152", "no_rows"])
+def test_gmm_work_items_read_a_touched_group_once_a_chunk(sizes, behind):
+    """What the kernels' grid does with the items, step by step: the
+    block indices the index maps hand the pipeline (a block is fetched
+    when its index changes) and the rows the items write."""
+    E, M = len(sizes), sum(sizes) + behind
+    rows, window, chunk, W = pallas_kernels._gmm_geometry(M, E)
+    grp, win, lo, hi, n = _work_items(sizes, M)
+    assert len(grp) == W + 1 and n <= W
+    n_k = 4
+    weights, row_blocks, tiles, at = [], [], [], [None] * 3
+    for i in range(n + 1):                  # the grid: the items and one
+        for kk in range(n_k):
+            k = kk if i < n else n_k - 1
+            for seen, idx, to in ((0, (grp[i], k), weights),
+                                  (1, (win[i], k), row_blocks),
+                                  (2, lo[i] // window, tiles)):
+                if idx != at[seen]:
+                    at[seen] = idx
+                    to.append(idx)
+    offs = np.concatenate([[0], np.cumsum(sizes)])
+    chunks = [-(-s // chunk) for s in sizes]
+    # every block of a touched group once for each chunk of its rows,
+    # in group order, and no block of a group without rows; the item
+    # behind the last fetches nothing and stays on the last output tile
+    want = [(g, k) for g in range(E) for _ in range(chunks[g])
+            for k in range(n_k)]
+    if n == 0:
+        assert weights == [(grp[0], n_k - 1)]   # where the grid starts
+    else:
+        assert weights == want and n == sum(chunks)
+        assert len(row_blocks) == n * n_k
+        assert (grp[n], win[n]) == (grp[n - 1], win[n - 1])
+        assert lo[n] // window == (hi[n - 1] - 1) // window
+    # an output tile is taken up once, the tiles in order
+    assert tiles == sorted(set(tiles)) and max(tiles) < -(-rows // window)
+    # each routed row is written once: by its item into the tile the
+    # item begins in, or by the next item into the tile that one begins
+    # in - which is the one the rows reach into
+    written = np.zeros(rows, int)
+    for i in range(n):
+        g = grp[i]
+        assert offs[g] <= lo[i] < hi[i] <= offs[g + 1]
+        assert hi[i] - lo[i] <= chunk and lo[i] == (hi[i - 1] if i else 0)
+        assert 0 <= win[i] * 16 <= lo[i] and hi[i] <= win[i] * 16 + window
+        assert win[i] * 16 + window <= rows
+        edge = (lo[i] // window + 1) * window
+        written[lo[i]:min(hi[i], edge)] += 1
+        if hi[i] > edge:
+            assert lo[i + 1] // window * window == edge
+            assert hi[i] <= edge + window
+            written[edge:hi[i]] += 1
+    assert (written[:sum(sizes)] == 1).all() and not written[sum(sizes):].any()
+
+
+@pytest.mark.parametrize("M,E,want", [
+    (512, 64, (512, 128, 112, 64 + 4)),     # OLMoE, S=1 at rung 64
+    (2048, 64, (2048, 128, 112, 64 + 18)),  # ... a window
+    (1152, 128, (1152, 128, 112, 128 + 10)),    # a held segment
+    (64, 64, (64, 64, 64, 64)),             # one window spans the call
+    (8, 64, (16, 16, 16, 8)),
+    (300, 8, (304, 128, 112, 8 + 2))])
+def test_gmm_geometry_follows_the_rows_of_a_call(M, E, want):
+    assert pallas_kernels._gmm_geometry(M, E) == want
+
+
+def test_grouped_expert_ffn_is_traced_once_for_equal_shapes():
+    """The pair is entered through ``jax.jit``: a second call with the
+    same shapes finds the first one's trace, whoever calls."""
+    rng = np.random.default_rng(0)
+    _, _, gate, up, down = _moe_inputs(rng, 1, 64, 32, 4, jnp.float32)
+    xs = jnp.asarray(rng.normal(size=(24, 64)), jnp.float32)
+    sizes = jnp.asarray([3, 0, 20, 1], jnp.int32)
+
+    def two_layers(xs, sizes):
+        y = pallas_kernels.grouped_expert_ffn(xs, sizes, gate, up, down)
+        return pallas_kernels.grouped_expert_ffn(
+            y.astype(xs.dtype), sizes, gate, up, down)
+
+    text = jax.jit(two_layers).lower(xs, sizes).as_text()
+    assert text.count("call @_grouped_expert_ffn") == 2
+    assert len([line for line in text.splitlines()
+                if "func.func private @_grouped_expert_ffn" in line]) == 1
 
 
 def test_pallas_variant_eligible_at_lane_aligned_widths(monkeypatch):
