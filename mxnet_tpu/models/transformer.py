@@ -1620,8 +1620,8 @@ class BatchedKVCacheDecoder:
     True)`` builds takes ``fed``, the number of real tokens of each
     slot, as ``step`` hands it, and the program advances each slot's
     state by exactly that, so nothing runs ahead and nothing needs
-    rewinding after a window (a graph built by hand without the input
-    advances every slot by S, and its caller rewinds). EVA attention
+    rewinding after a window (a graph without the input is refused
+    at construction). EVA attention
     (``block="evabyte"``) keeps a ring of the open window's exact rows
     beside one summary per closed chunk: not positional. For such a
     graph ``join`` and ``leave`` are unchanged
@@ -1702,8 +1702,13 @@ class BatchedKVCacheDecoder:
             family: sum(cell.size * cell.dtype.itemsize
                         for _nm, cell in self._cells(family))
             for family in self._state}
-        self.feeds = "fed" in module.symbol.list_arguments()
-        if self.feeds and "fed" not in module.data_names:
+        if "fed" not in module.symbol.list_arguments():
+            raise MXNetError(
+                "a slot-pooled decode graph takes fed, the real tokens "
+                "of each slot, beside its tokens, and this one has no "
+                "such input: build it with get_decode_symbol("
+                "per_slot=True)")
+        if "fed" not in module.data_names:
             # bound as a parameter it would stay what it was set to,
             # and every step would advance the slots by that
             raise MXNetError(
@@ -2214,12 +2219,10 @@ class BatchedKVCacheDecoder:
         the overflow check, ``last_reads``, ``pos`` - comes from the
         host's cursor mirror and never from the ids.
 
-        A graph with a ``fed`` input (``self.feeds``) advances slot
-        ``b`` by ``fed[b]`` of its S tokens (0..S; None feeds every
-        slot all S) and leaves a slot with no room for S positions
-        where it is; any other graph advances every slot by S and takes
-        no ``fed``. Where the window has a packed program
-        (``add_window(packed=)``) and ``fed`` is given and sums to no
+        The program advances slot ``b`` by ``fed[b]`` of its S tokens
+        (0..S; None feeds every slot all S) and leaves a slot with no
+        room for S positions where it is. Where the window has a packed
+        program (``add_window(packed=)``) and ``fed`` is given and sums to no
         more than its budget, that is the program launched: its
         row-wise operations run over the budget's rows, not ``slots x
         S`` (``last_program_rows`` says which ran), its head over each
@@ -2267,45 +2270,35 @@ class BatchedKVCacheDecoder:
                     f"{[int(self.pos[i]) for i in over]} + {S} exceeds "
                     f"capacity {self.capacity}; retire the sequence(s) or "
                     "re-bind with a larger capacity")
-            if not self.feeds:
-                if fed is not None:
-                    raise MXNetError("step(fed=...): this graph has no fed "
-                                     "input; it advances every slot by S")
-                advance = S          # the program advances EVERY slot
-                self.last_reads = self._dispatch_reads(
-                    np.where(self.active, S, 0))
-            else:
-                packed = None if fed is None else self._packed.get(S)
-                fed = np.full(self.slots, S, np.int64) if fed is None \
-                    else np.asarray(fed, np.int64).reshape(-1)
-                if fed.shape != (self.slots,) or fed.min() < 0 \
-                        or fed.max() > S:
-                    raise MXNetError(
-                        f"step() wants ({self.slots},) fed counts in "
-                        f"[0, {S}], got {fed.tolist()}")
-                # the program's own rule, mirrored: no room for S,
-                # nothing fed
-                advance = fed = np.where(self.pos + S <= self.capacity,
-                                         fed, 0)
-                if packed is not None and fed.sum() <= packed[2]:
-                    mod, stage, self.last_program_rows = packed
-                    self.last_head_rows = self.slots
-                self.last_reads = self._dispatch_reads(fed)
+            packed = None if fed is None else self._packed.get(S)
+            fed = np.full(self.slots, S, np.int64) if fed is None \
+                else np.asarray(fed, np.int64).reshape(-1)
+            if fed.shape != (self.slots,) or fed.min() < 0 \
+                    or fed.max() > S:
+                raise MXNetError(
+                    f"step() wants ({self.slots},) fed counts in "
+                    f"[0, {S}], got {fed.tolist()}")
+            # the program's own rule, mirrored: no room for S, nothing
+            # fed
+            fed = np.where(self.pos + S <= self.capacity, fed, 0)
+            if packed is not None and fed.sum() <= packed[2]:
+                mod, stage, self.last_program_rows = packed
+                self.last_head_rows = self.slots
+            self.last_reads = self._dispatch_reads(fed)
             if self.name is not None:    # the pools' bytes a dispatch
                 self._donated()[0].inc(self.donated_bytes)
             hosts = [tokens]
             if self.pos_embed == "learned":
                 pos = self.pos[:, None] + np.arange(S)[None, :]
                 hosts.append(np.minimum(pos, self.capacity - 1))
-            if self.feeds:
-                hosts.append(fed)
+            hosts.append(fed)
             data = stage(hosts)
         t1 = None if now is None else now()
         with _telemetry.span("decode.step.launch"):
             mod.forward(DataBatch(data=data, label=[]), is_train=False)
             out = mod.get_outputs()[0]
         self._stepped = mod
-        self.pos += advance
+        self.pos += fed
         self.last_stage, self.last_launch = (None, None) if now is None \
             else (t1 - t0, now() - t1)
         return out

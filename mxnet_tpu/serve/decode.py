@@ -48,15 +48,13 @@ compiled and pinned at warmup):
   (``MXNET_SERVE_PREFILL_CHUNK``, default 64) next to its S=1 decode
   program, so a T-token prompt prefills in ⌈T/S⌉ dispatches instead of
   T and TTFT goes near-flat in prompt length. Slots mid-decode ride a
-  chunk dispatch with one real token plus pads. A graph that is fed its
+  chunk dispatch with one real token plus pads. The graph is fed its
   real tokens a slot (``fed``: every graph ``get_decode_symbol(per_slot=
-  True)`` builds) advances each slot by those alone; a graph without
-  the input (built by hand) advances every slot by S, and the slots
-  that fed fewer REWIND their cursor afterwards (a join-style aux
-  poke): either way mixed prefill/decode iterations lose nothing. A
-  fed graph also gets, where that at least halves the rows, the
-  *packed* form of its window program, whose row-wise operations run
-  over a budget R and not ``slots x S`` (``models.transformer
+  True)`` builds, and a graph without the input is refused) and
+  advances each slot by those alone, so mixed prefill/decode iterations
+  lose nothing and nobody rewinds after a window. It also gets, where
+  that at least halves the rows, the *packed* form of its window
+  program, whose row-wise operations run over a budget R and not ``slots x S`` (``models.transformer
   .packed_rows``: a chunk and a token a slot, and never under the rows
   a weight-bound matmul carries for free); the scheduler then plans
   every window inside R - a token for each decoding slot, the rest to
@@ -403,19 +401,21 @@ class DecodeEngine:
             else current_context()
         self.pos_embed = "learned" \
             if "pos_ids" in symbol.list_arguments() else "rotary"
-        # a graph whose state advances by the real tokens alone takes
-        # their count a slot beside the tokens (transformer.py,
-        # block="evabyte")
-        self.feeds = "fed" in symbol.list_arguments()
+        # the state advances by the real tokens alone: their count a
+        # slot goes in beside the tokens
         self.data_names = ("data",) + (
-            ("pos_ids",) if self.pos_embed == "learned" else ()) + (
-            ("fed",) if self.feeds else ())
+            ("pos_ids",) if self.pos_embed == "learned" else ()) + ("fed",)
         if not any(getattr(n.opdef(), "stateful_infer", False)
                    for n in symbol._topo_nodes() if not n.is_variable):
             raise MXNetError(
                 f"DecodeEngine({name!r}): the symbol has no stateful "
                 "decode op (build it with get_decode_symbol("
                 "per_slot=True))")
+        if "fed" not in symbol.list_arguments():
+            raise MXNetError(
+                f"DecodeEngine({name!r}): the symbol takes no fed, the "
+                "real tokens of each slot (build it with "
+                "get_decode_symbol(per_slot=True))")
 
         self._bm = BucketingModule(
             sym_gen=lambda slots: (symbol, list(self.data_names), []),
@@ -553,8 +553,7 @@ class DecodeEngine:
             # that cast reads the right row; a packed window, which
             # copies the positions, reads its neighbour's)
             descs.append(DataDesc("pos_ids", (slots, step), np.int32))
-        if self.feeds:
-            descs.append(DataDesc("fed", (slots,), np.int32))
+        descs.append(DataDesc("fed", (slots,), np.int32))
         return descs
 
     @property
@@ -814,13 +813,13 @@ class _Dispatch:
 
     __slots__ = ("mode", "S", "meta", "tokens", "last", "fed", "feed",
                  "chip", "want_rows", "n_active", "shared_sid", "t0",
-                 "plan_s", "phases", "ahead", "rewound", "launched")
+                 "plan_s", "phases", "ahead", "launched")
 
     def __init__(self, mode, S, t0, ahead=False):
         self.mode, self.S, self.t0, self.ahead = mode, S, t0, ahead
         self.meta = []
         self.tokens = self.last = self.fed = self.feed = self.chip = None
-        self.want_rows = self.rewound = False
+        self.want_rows = False
         self.n_active = 0
         self.shared_sid = self.launched = None
         self.plan_s = 0.0
@@ -846,8 +845,7 @@ class DecodeScheduler:
     Fast paths (each armed only when its programs were built at engine
     construction, so steady state never compiles): ``prefill_chunk``
     S>1 window dispatches while any slot is prefilling (decoding slots
-    ride along with one real token + pads; where the graph takes no
-    ``fed`` they rewind after);
+    ride along with one real token + pads, fed 1);
     ``draft_engine`` + ``spec_k`` speculative iterations when every
     active slot is in steady state (K draft proposals, one S=K target
     verify, exact rejection, cursor rollback on both engines);
@@ -878,12 +876,6 @@ class DecodeScheduler:
                     f"draft cache capacity {self.draft.capacity} < "
                     f"target capacity {engine.capacity}: the draft "
                     "tracks the same stream")
-            if self.draft.feeds != engine.feeds:
-                raise MXNetError(
-                    "draft and target are stepped alike, a slot's real "
-                    "tokens named to both or to neither: one graph takes "
-                    f"fed (draft {self.draft.feeds}, target "
-                    f"{engine.feeds}) and the other was built without")
         if not engine.positional:
             # the state behind a closed window is summaries, the rows
             # a ring has written over are gone: no cursor move brings
@@ -1308,18 +1300,16 @@ class DecodeScheduler:
         # behind it is fed from the chip (a row in mid-prompt has an
         # argmax too, which nobody may be fed)
         d.feed = np.zeros(self._rung, bool)
-        # a fed decoder advances each slot by its real tokens alone: a
+        # the decoder advances each slot by its real tokens alone: a
         # row nobody owns, or one the budget left out, is fed nothing
-        d.fed = np.zeros(self._rung, np.int32) \
-            if self.engine.feeds else None
+        d.fed = np.zeros(self._rung, np.int32)
         for row, seq, n in self._plan_window(d.S, cursors):
             pos, left = at[row]
             if row not in chip:
                 d.tokens[row, :n] = seq.window(pos, n)
             d.last[row] = n - 1
             d.feed[row] = n == left
-            if d.fed is not None:
-                d.fed[row] = n
+            d.fed[row] = n
             if n == left and not seq.sampling.greedy:
                 d.want_rows = True
             if seq.first_dispatch_at is None:
@@ -1344,8 +1334,8 @@ class DecodeScheduler:
         host's with column 0 of those slots taken from the chip
         (``drv.merge_tokens``, one small launch in front of the step);
         ``feed`` says whose id this dispatch's own device tokens
-        carry (``select_rows``). ``fed`` (a decoder that is fed: the
-        real tokens of each slot) rides in the same put as the tokens.
+        carry (``select_rows``). ``fed`` (the real tokens of each
+        slot) rides in the same put as the tokens.
         Adds the duration to ``phases["dispatch"]`` on the scheduler's
         clock; ``t`` is the reading that closed the previous phase (one
         read a boundary), None reads it. Inside, the driver times its
@@ -1557,9 +1547,7 @@ class DecodeScheduler:
         theirs, a larger rung would, or one names a prefix that a store
         might join at a cursor; a smaller rung is due; a slot would
         overflow its
-        cache. And where the dispatch behind is a window that ``d``'s
-        state is not ready for: an engine that is not fed advances
-        every cursor by S and is rewound in between. (A prefix captured
+        cache. (A prefix captured
         at ``d``'s commit reads rows below the cursor, which a dispatch
         behind only appends to where the state is a row a position -
         all a prefix store is built over, ``__init__`` refuses any
@@ -1595,8 +1583,7 @@ class DecodeScheduler:
         mode, S = self._plan_dispatch(cursors)
         # until ``d``'s commit whoever leaves there is the driver's to
         # guard: it refuses a step that their cursor has no room for
-        if S > 1 and not self.engine.feeds or \
-                any(at + S > self.engine.capacity for at in leaving):
+        if any(at + S > self.engine.capacity for at in leaving):
             return None
         nxt = _Dispatch(mode, S, t0=None, ahead=True)
         self._fill_window(nxt, cursors, now, chip=sampled)
@@ -1659,16 +1646,6 @@ class DecodeScheduler:
                     drv, d.tokens, d.phases, t=planned, last=d.last,
                     rows=d.want_rows, fed=d.fed, feed=d.feed)
             if nxt is not None:
-                if d.S > 1 and not self.engine.feeds:
-                    # the window advanced every cursor by S: the slots
-                    # that fed fewer go back before the next step reads
-                    # them, and not again in the commit
-                    behind = [(row, seq.fed + n) for row, seq, n in d.meta
-                              if n < d.S]
-                    if behind:
-                        with span("serve.decode.iter.rewind"):
-                            drv.rewind_many(*zip(*behind))
-                    d.rewound = True
                 ids = d.launched.tokens
                 nxt.launched, planned = self._launch(
                     drv, ids if nxt.tokens is None else nxt.tokens,
@@ -1705,7 +1682,7 @@ class DecodeScheduler:
                         rew_rows, rew_pos)
                 else:
                     emitted, chunks = self._commit_window(
-                        d, ids, picked, end, rew_rows, rew_pos)
+                        d, ids, picked, end)
             committed = clock()
             with span("serve.decode.iter.rewind"):
                 # retired rows keep advancing one window per dispatch;
@@ -1786,16 +1763,15 @@ class DecodeScheduler:
                     compiles_since_warmup=compiles, **read_fields)
         return max(1, emitted)
 
-    def _commit_window(self, d, ids, picked, end, rew_rows, rew_pos):
+    def _commit_window(self, d, ids, picked, end):
         """Apply the outcome of one window (or S=1) dispatch ``d``
         (caller holds the lock): ``ids[row]`` is the argmax of the
         slot's last fed row, taken on the device, and ``picked[row]``
         that row itself, fetched only when a slot sampling now is not
         greedy. Where a slot's stream is exhausted a greedy request
         takes its id and any other hands its row to ``sample_token``;
-        stream the tokens, retire on EOS / max-new, and queue a cursor
-        rewind for every slot that fed fewer than S tokens (unless they
-        were rewound before the dispatch behind ``d`` was launched). A
+        stream the tokens and retire on EOS / max-new (the program
+        advanced each slot by what it was fed: nobody is rewound). A
         slot that retired while ``d``, launched ahead, was on the chip
         has its token dropped and counted
         (``serve.decode.runahead.dropped``). Returns ``(emitted,
@@ -1831,12 +1807,6 @@ class DecodeScheduler:
                 tok = sample_token(picked[row], seq.sampling, seq.rng)
                 on_host += 1
             seq.fed += n
-            if n < S and not self.engine.feeds and not d.rewound:
-                # the dispatch advanced the cursor by S; pull
-                # it back to the stream position actually fed (a fed
-                # decoder advanced by n: nothing ran ahead)
-                rew_rows.append(row)
-                rew_pos.append(seq.fed)
             self._capture_prefix(seq, end)
             if not samples:
                 continue              # still prefilling

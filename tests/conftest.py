@@ -94,3 +94,32 @@ def counting():
     tm.enable()
     yield
     tm.disable()
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described v5e device. The persistent compile cache cannot
+    read such executables back without a chip, so it stays off
+    (above). ``tests/test_chip_compile.py`` and
+    ``test_chip_compile_programs.py`` compile for it."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # nothing is attached, so several test workers may load libtpu at once
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def v5e(v5e_chip, monkeypatch):
+    """The described chip, with Pallas steered off interpret mode: the
+    program picks the mode from ``jax.default_backend()``, which is the
+    CPU here."""
+    from mxnet_tpu.ops import pallas_kernels
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    return v5e_chip

@@ -1,15 +1,15 @@
-"""A.X-K1's block behind the serving path (the unselected lowering and
-YaRN of ops/mla.py, the group-limited router of ops/moe.py,
-block="axk1" of models/transformer.py, the latent rows that
-BatchedKVCacheDecoder counts, the prefix store's join at a common head)
-against the plain reference chipbench/reference/axk1.py, at small widths
-on the CPU: three layers (dense, sparse, sparse) holding experts 3-5 of
-24 in 4 groups of 6, 8 a token inside 2 groups; YaRN of factor 8 over 16
-original positions, so that contexts of some 80 positions turn on the
-blended frequencies."""
-import os
-import sys
-
+"""A.X-K1's block behind the serving path (``block="axk1"`` of
+models/transformer.py: GLM-5.2's latent attention without an indexer,
+under YaRN, and a sigmoid router that chooses inside groups). What every
+served block does is ``tests/decode_block_suite.py``'s, over the row
+``axk1`` of ``tests/decode_blocks.py`` against the plain reference
+chipbench/reference/axk1.py: three layers (dense, sparse, sparse) of 24
+experts in 4 groups, 8 a token inside 2 of them, 3 held from the 3rd;
+YaRN of factor 8 over 16 original positions. Below that the block's
+own: each part of YaRN, the graph without an indexer, the latent rows a
+dispatch counts, the unselected kernel, the window form, YaRN at the
+published values, the group-limited choice, the shares that add up, and
+the reuse plane (a prefix joined at a common head, a rider counted)."""
 import numpy as np
 import pytest
 
@@ -17,197 +17,50 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu import kernel_tier
 from mxnet_tpu.models import transformer as tfm
 from mxnet_tpu.ops import mla, moe
 from mxnet_tpu.ops.registry import get_op
 from mxnet_tpu.serve.prefix import PrefixStore
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import decode_blocks as blocks
+from decode_blocks import CAPACITY, SLOTS, WINDOW
+from decode_block_suite import *  # noqa: F401,F403
 
+import mla_window_cases  # noqa: E402
 from chipbench.reference import axk1 as ref  # noqa: E402
 import mla_window_cases  # noqa: E402
-# the quick cases of the benchmark's own tests of the architecture file
-# run here as they stand (its CPU rehearsals stay by hand)
-from chipbench.tests.test_axk1 import (  # noqa: E402,F401
-    test_costs_against_a_count_by_hand,
-    test_every_new_reader_on_a_scripted_trace,
-    test_the_architecture_file_has_the_interface_and_builds_the_block,
-    test_the_configuration_is_the_catalogs_but_for_what_reduced_lists,
-    test_the_controls_are_further_than_the_emulation,
-    test_the_traffic_is_the_issues_and_shares_three_documents)
 
-YARN = {"type": "yarn", "factor": 8, "original_max_position_embeddings": 16,
-        "beta_fast": 4, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}
-CFG = {"vocab_size": 48, "hidden_size": 64, "num_attention_heads": 4,
-       "num_hidden_layers": 3, "q_lora_rank": 48, "kv_lora_rank": 64,
-       "qk_nope_head_dim": 24, "qk_rope_head_dim": 16, "v_head_dim": 16,
-       "first_k_dense_replace": 1, "intermediate_size": 96,
-       "moe_intermediate_size": 32, "n_routed_experts": 24,
-       "num_experts_per_tok": 8, "n_shared_experts": 1, "n_group": 4,
-       "topk_group": 2, "routed_scaling_factor": 2.5,
-       "norm_topk_prob": True, "n_routed_experts_held": 3, "held_first": 3,
-       "rope_theta": 10000, "rope_scaling": YARN, "rms_norm_eps": 1e-6}
-CAPACITY, WINDOW, SLOTS = 128, 16, 3            # WINDOW: the S > 1 program
-#: float32 served against the float32 reference through 3 layers, on
-#: logits of magnitude about 2 (measured here: 2e-6 to 8e-6)
-TOL = 5e-5
-
-
-def _axk1(held=None):
-    spec = {k: CFG[k] for k in tfm.AXK1_KEYS}
-    spec["held"] = held or (CFG["held_first"], CFG["n_routed_experts_held"])
-    return spec
-
-
-def _symbol(step_len, capacity=CAPACITY, held=None):
-    return tfm.get_decode_symbol(
-        vocab_size=CFG["vocab_size"], d_model=CFG["hidden_size"],
-        n_layer=CFG["num_hidden_layers"],
-        n_head=CFG["num_attention_heads"], pos_embed="rotary",
-        rope_base=float(CFG["rope_theta"]), capacity=capacity,
-        step_len=step_len, per_slot=True, block="axk1",
-        rms_eps=CFG["rms_norm_eps"], tie_head=False, embed_scale=False,
-        axk1=_axk1(held))
-
-
-def _params(seed=5, held=None):
-    symbol = _symbol(1, held=held)
-    shapes, _, _ = symbol.infer_shape(data=(SLOTS, 1), fed=(SLOTS,))
-    rng = np.random.default_rng(seed)
-    out = {}
-    for name, shape in zip(symbol.list_arguments(), shapes):
-        if name in ("data", "fed"):
-            continue
-        draw = rng.standard_normal(shape)
-        if name.endswith(("_gamma", "_kv_norm_weight")):
-            draw = 1.0 + 0.3 * draw
-        out[name] = (draw if "gamma" in name or "norm_weight" in name
-                     else 0.25 * draw).astype(np.float32)
-    return out
-
-
-PARAMS = _params()
-
-
-def _bound(step_len, shared=None, slots=SLOTS, params=None, dtype=None):
-    mod = mx.mod.Module(_symbol(step_len), data_names=("data", "fed"),
-                        label_names=[], compute_dtype=dtype)
-    mod.bind([mx.io.DataDesc("data", (slots, step_len), np.int32),
-              mx.io.DataDesc("fed", (slots,), np.int32)],
-             None, for_training=False, shared_module=shared)
-    if shared is None:
-        mod.init_params(initializer=None,
-                        arg_params=dict(params or PARAMS), aux_params={},
-                        allow_missing=True)
-    return mod
-
-
-def _tier(name):
-    old = os.environ.get("MXNET_KERNEL_TIER")
-    os.environ["MXNET_KERNEL_TIER"] = name
-    kernel_tier.clear()
-    return old
-
-
-def _restore(old):
-    if old is None:
-        os.environ.pop("MXNET_KERNEL_TIER", None)
-    else:
-        os.environ["MXNET_KERNEL_TIER"] = old
-    kernel_tier.clear()
-
-
-@pytest.fixture(scope="module", params=["xla", "pallas"])
-def driver(request):
-    """A three-slot pool with its S = 16 window program under one
-    kernel tier (the Pallas kernels in interpret mode)."""
-    old = _tier(request.param)
-    base = _bound(1)
-    drv = tfm.BatchedKVCacheDecoder(base, CAPACITY, slots=SLOTS)
-    drv.add_window(WINDOW, _bound(WINDOW, shared=base))
-    yield drv
-    _restore(old)
-
-
-def _reference(seqs, **kw):
-    fwd = jax.jit(lambda p, t: ref.forward(p, t, CFG, **kw))
-    return np.asarray(fwd(PARAMS, jnp.asarray(seqs)))
-
-
-def _run(drv, seqs, schedule, start=None):
-    """Feed ``seqs`` (slots, T) through ``schedule``, a list of (S, fed
-    counts a slot): the logits of every fed position, (slots, T, V)."""
-    if start is None:
-        for slot in range(drv.slots):
-            if drv.active[slot]:
-                drv.leave(slot)
-            drv.join(slot)
-        start = [0] * drv.slots
-    got = np.zeros(seqs.shape + (CFG["vocab_size"],), np.float32)
-    at = np.asarray(start)
-    for S, fed in schedule:
-        tokens = np.full((drv.slots, S), 7, np.int32)
-        for slot, n in enumerate(fed):
-            tokens[slot, :n] = seqs[slot, at[slot]:at[slot] + n]
-        out = drv.step(tokens, fed=fed).asnumpy()
-        for slot, n in enumerate(fed):
-            got[slot, at[slot]:at[slot] + n] = out[slot, :n]
-        at = at + np.asarray(fed)
-        assert list(drv.pos) == list(at)
-    return got, at
-
-
-def _seqs(T, seed=1):
-    return np.random.default_rng(seed).integers(
-        0, CFG["vocab_size"], (SLOTS, T)).astype(np.int32)
+BLOCK = "axk1"
+AXK1 = blocks.config(BLOCK)["axk1"]
+YARN = AXK1["rope_scaling"]
+VOCAB = blocks.config(BLOCK)["vocab_size"]
+TOL = blocks.TOL[BLOCK]
 
 
 # ------------------------------------------------- the block, end to end
-def test_prefill_in_windows_then_decode_equals_the_reference(driver):
+def test_each_part_of_yarn_matters_past_the_original_positions(driver):
     """Four windows and sixteen S = 1 steps, 80 positions past YaRN's 16
-    original ones: the latent cache, both lowerings of the unselected
-    attention, the blended rotary and its softmax scale, the
-    group-limited choice, the share of the experts."""
-    seqs = _seqs(80)
-    got, at = _run(driver, seqs, [(WINDOW, [WINDOW] * SLOTS)] * 4
-                   + [(1, [1] * SLOTS)] * 16)
+    original ones: the served logits are the reference's, and the
+    reference without the softmax scale or with the plain rotary is not
+    correct at these positions."""
+    seqs = blocks.seqs(BLOCK, 80)
+    got, at, _ = blocks.run(driver, seqs, [(WINDOW, [WINDOW] * SLOTS)] * 4
+                            + [(1, [1] * SLOTS)] * 16)
     assert list(at) == [80] * SLOTS
-    want = _reference(seqs)
+    want = blocks.reference(BLOCK, seqs)
     assert np.max(np.abs(want)) > 0.5
     np.testing.assert_allclose(got, want, atol=TOL, rtol=TOL)
     # each part of YaRN matters at these positions: without it, not correct
     for control in ("no_scale", "plain"):
-        other = _reference(seqs, yarn=control)
+        other = blocks.reference(BLOCK, seqs, yarn=control)
         assert np.max(np.abs(other[:, 40:] - want[:, 40:])) > 100 * TOL
-
-
-def test_ragged_slots_and_fed_keep_the_pools_right(driver):
-    """Slots at their own lengths, windows that feed 16, 5 and 0 real
-    tokens, a slot that decodes while another prefills: every fed
-    position equals the reference, the cursors move by ``fed`` alone."""
-    seqs = _seqs(60, seed=2)
-    schedule = [(WINDOW, [16, 5, 0]), (1, [1, 1, 1]), (WINDOW, [16, 16, 9]),
-                (WINDOW, [1, 16, 16]), (1, [1, 0, 1]), (WINDOW, [7, 3, 16])]
-    got, at = _run(driver, seqs, schedule)
-    want = _reference(seqs)
-    for slot in range(SLOTS):
-        np.testing.assert_allclose(got[slot, :at[slot]],
-                                   want[slot, :at[slot]], atol=TOL, rtol=TOL)
-    exe = driver._mod._exec_group.executor
-    for name in driver._state["cursor"]:
-        assert list(exe.aux_dict[name].asnumpy().ravel()) == list(at), name
-    assert sorted(driver._state) == ["cursor", "rows"]
-    assert len(driver._state["rows"]) == 3          # 3 latent pools, no index
 
 
 def test_the_graph_has_no_indexer_and_no_selection():
     """One block for both models: the same nodes as ``glm_dsa``'s but
     the indexer's, no ``selection`` input, no router bias; the driver
     finds no selection to mirror and counts the latent rows attended."""
-    symbol = _symbol(4)
+    symbol = blocks.symbol(BLOCK, 4)
     nodes = [n for n in symbol._topo_nodes() if not n.is_variable]
     ops = [n.op for n in nodes]
     assert ops.count("mla_attention_decode") == 3
@@ -230,8 +83,10 @@ def test_the_graph_has_no_indexer_and_no_selection():
 
 
 def test_latent_rows_attended_are_counted_from_the_cursors(driver):
-    _run(driver, _seqs(40), [(WINDOW, [16, 16, 8])] * 2)
-    driver.step(np.zeros((SLOTS, 1), np.int32), fed=[1, 0, 1])
+    idle = [0] * (SLOTS - 3)
+    blocks.run(driver, blocks.seqs(BLOCK, 40),
+               [(WINDOW, [16, 16, 8] + idle)] * 2)
+    driver.step(np.zeros((SLOTS, 1), np.int32), fed=[1, 0, 1] + idle)
     # slots at 32, 32, 16 fed 1, 0, 1: last queries see 33 and 17 keys,
     # and at S = 1 the pairs of all fed queries are those keys
     layers = 3
@@ -241,11 +96,11 @@ def test_latent_rows_attended_are_counted_from_the_cursors(driver):
         "attn.attended_rows": layers * (33 + 17),
         "mla_attended": layers * (33 + 17), "mla_pairs": layers * (33 + 17)}
     assert driver.read_counts["mla_pairs"] == (None, "mla_pairs")
+    assert len(driver._state["rows"]) == 3          # 3 latent pools, no index
     assert not [c for c in driver.read_counts if c.startswith("dsa.")]
-    assert driver.positional and driver.feeds
     # a window that feeds 16, 0 and 5 rows at 33, 32 and 17: the last
     # queries see 49 and 22 keys; query t of a slot at p sees p + t + 1
-    driver.step(np.zeros((SLOTS, WINDOW), np.int32), fed=[16, 0, 5])
+    driver.step(np.zeros((SLOTS, WINDOW), np.int32), fed=[16, 0, 5] + idle)
     pairs = sum(33 + t + 1 for t in range(16)) \
         + sum(17 + t + 1 for t in range(5))
     assert (driver.last_reads["mla_attended"],
@@ -463,7 +318,7 @@ def test_the_shares_add_up_to_the_uncut_layer_under_group_limited_routing():
     reference's own share."""
     rs = np.random.RandomState(4)
     D, F, E, T = 64, 32, 24, 40
-    cfg = dict(CFG, hidden_size=D)
+    cfg = blocks.reference_cfg(BLOCK, d_model=D)
     f = lambda *s: np.asarray(rs.randn(*s) * 0.3, np.float32)  # noqa: E731
     params = {"p_moe_router_weight": f(E, D),
               "p_moe_gate_weight": f(E, D, F), "p_moe_up_weight": f(E, D, F),
@@ -535,9 +390,10 @@ def test_the_store_joins_at_the_longest_common_head():
 
 def _tiny_server(name, prefix_mb):
     return mx.serve.serve_decoder(
-        _symbol(1), PARAMS, name=name, capacity=CAPACITY, ladder=[2],
-        symbol_gen=_symbol, prefill_chunk=8, start=False,
-        prefix_cache_mb=prefix_mb)
+        blocks.symbol(BLOCK, 1), blocks.params(BLOCK), name=name,
+        capacity=CAPACITY, ladder=[2],
+        symbol_gen=lambda s: blocks.symbol(BLOCK, s), prefill_chunk=8,
+        start=False, prefix_cache_mb=prefix_mb)
 
 
 def test_a_join_at_a_common_head_is_a_cold_prefill_and_compiles_once():
@@ -548,14 +404,13 @@ def test_a_join_at_a_common_head_is_a_cold_prefill_and_compiles_once():
     answers equal a store-less server's, and two joins of different
     lengths compile nothing after warm-up."""
     from mxnet_tpu import telemetry
-    old = _tier("xla")
-    try:
+    with blocks.tier("xla"):
         rng = np.random.default_rng(12)
-        doc = rng.integers(0, CFG["vocab_size"], 24)
-        tails = [rng.integers(0, CFG["vocab_size"], n) for n in (5, 9, 13)]
+        doc = rng.integers(0, VOCAB, 24)
+        tails = [rng.integers(0, VOCAB, n) for n in (5, 9, 13)]
         prompts = [np.concatenate([doc, t]).astype(np.int32) for t in tails]
         other = prompts[0].copy()
-        other[0] = (other[0] + 1) % CFG["vocab_size"]
+        other[0] = (other[0] + 1) % VOCAB
         answers, rows = {}, {}
         for name, budget in (("cold", 0), ("warm", 4)):
             # the store-less server first: ``compiles_since_warmup`` is
@@ -606,8 +461,6 @@ def test_a_join_at_a_common_head_is_a_cold_prefill_and_compiles_once():
         for r in spans:
             assert r["cursor"] == 24 and r["dur_us"] > 0
             assert r["bytes"] == 3 * 3 * 8 * mla.latent_width(64, 16) * 4
-    finally:
-        _restore(old)
 
 
 def test_the_scheduler_counts_the_slots_a_window_feeds_one_row():
@@ -616,10 +469,9 @@ def test_the_scheduler_counts_the_slots_a_window_feeds_one_row():
     form of the kernel, interpreted - and ``serve.decode.window.*``
     count them from the plan; its answer is the one it gives alone."""
     from mxnet_tpu import telemetry
-    old = _tier("pallas")
-    try:
+    with blocks.tier("pallas"):
         rng = np.random.default_rng(21)
-        short, long_ = (rng.integers(0, CFG["vocab_size"], n)
+        short, long_ = (rng.integers(0, VOCAB, n)
                         .astype(np.int32) for n in (3, 20))
         server = _tiny_server("axk1-tiny-ride", 0)
         riding = server.submit(short, max_new_tokens=10)
@@ -637,5 +489,3 @@ def test_the_scheduler_counts_the_slots_a_window_feeds_one_row():
         server.pump()
         assert list(riding.result(timeout=60)) \
             == list(alone.result(timeout=60))
-    finally:
-        _restore(old)

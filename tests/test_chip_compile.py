@@ -1,24 +1,20 @@
 """The chip rehearsals that cost no chip time (guide on-chip-measurement
 section 2), kept as tests:
 
-* every kernel site of ``chip_smoke.py``'s *kernels* phase compiled by the
-  TPU compiler for a described (not attached) ``v5e:2x2`` device at the
-  smoke models' real widths — what interpret mode cannot show (tile
-  alignment, unlowerable primitives, scoped-VMEM overruns);
-* ``python chip_smoke.py`` itself failing at the device check on a host
-  with no TPU;
-* its phase bodies at tiny sizes on ``mx.cpu()`` — arguments and control
-  flow;
-* what the bring-up removed: an accelerator context that silently became
-  the CPU or another chip, an autotune that swallowed compiler refusals,
-  a compile cache with two spellings.
+* every kernel site of ``chip_smoke.py``'s *kernels* phase, and every
+  decode op, compiled by the TPU compiler for a described (not attached)
+  ``v5e:2x2`` device at the smoke models' and the published widths -
+  what interpret mode cannot show (tile alignment, unlowerable
+  primitives, scoped-VMEM overruns);
+* the pins: the text every block's programs lower to and the graphs the
+  builder makes, at the tiny sizes of ``tests/decode_blocks.py``.
+
+Whole served programs compiled for the v5e are
+``tests/test_chip_compile_programs.py``'s, ``chip_smoke.py`` without a
+chip ``tests/test_chip_smoke_bodies.py``'s (files of their own so that
+three workers take them: ROADMAP D22).
 """
-import argparse
-import json
-import os
 import re
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -28,42 +24,11 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx
 from mxnet_tpu import kernel_tier
-from mxnet_tpu.ops import pallas_kernels
 from mxnet_tpu.ops.registry import get_op
 
 import chip_smoke
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
 # ------------------------------------------------ compile for a v5e chip
-@pytest.fixture(scope="module")
-def v5e_chip():
-    """One described v5e device. The persistent compile cache cannot
-    read such executables back without a chip, so it stays off
-    (conftest)."""
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    # nothing is attached, so several test workers may load libtpu at once
-    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:       # no TPU compiler in this installation
-        pytest.skip(f"cannot describe a v5e topology here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture
-def v5e(v5e_chip, monkeypatch):
-    """The described chip, with Pallas steered off interpret mode: the
-    program picks the mode from ``jax.default_backend()``, which is the
-    CPU here."""
-    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
-    return v5e_chip
-
-
 _SITES = chip_smoke.kernel_sites(chip_smoke.SMOKE_WIDTHS)
 #: sites whose backward is a hand-written kernel too
 _HAND_BACKWARD = {"softmax_ce", "layernorm", "bias_gelu"}
@@ -521,7 +486,7 @@ def test_the_s1_latent_programs_lower_to_the_parents_text(lowering, v5e):
 
 #: the first 16 hex digits of the sha256 of the text that the whole
 #: inference program of each block lowers to at the tiny sizes of
-#: ``tests/window_pack_cases.py`` (4 slots; S = 1 and the window program
+#: ``tests/decode_blocks.py`` (4 slots; S = 1 and the window program
 #: of 16 rows a slot; the XLA compositions, on the CPU). The fed blocks'
 #: S = 1 and whole-window digests are PR 43's parent's (7112c03),
 #: computed on a copy of it: those graphs pass through ``pack_rows`` /
@@ -530,12 +495,9 @@ def test_the_s1_latent_programs_lower_to_the_parents_text(lowering, v5e):
 #: and the two blocks without ``fed`` are PR 44's parent's (7b8989b),
 #: computed on it before ``models/transformer.py`` built every block
 #: from one record: the builder changed, no program did. Since ISSUE 47
-#: the slot-pooled GPT-2 and OLMoE graphs take ``fed``: the parent's
-#: digests stand under ``*_unfed``, for the same graphs built by hand
-#: without the input (``window_pack_cases.unfed_symbol``: the builder
-#: still makes the parent's text where it is not handed ``fed``), and
-#: the fed graphs' own - S = 1, whole window, packed - were recorded on
-#: ISSUE 47's tree; ``gpt2_rotary`` joined then. ISSUE 51 changed the
+#: the slot-pooled GPT-2 and OLMoE graphs take ``fed``: their digests
+#: - S = 1, whole window, packed - were recorded on ISSUE 47's tree
+#: (``gpt2_rotary`` joined then). ISSUE 51 changed the
 #: packed forms and nothing else: ``packed_window`` selects each slot's
 #: last fed row in front of the head and the program returns ``(slots,
 #: 1, V)``, so the seven ``"packed"`` digests were recorded anew on its
@@ -560,10 +522,6 @@ _PARENT_PROGRAM_SHA256 = {
     ("axk1", 16, "packed"): "8a92ce89c1fc83d9",
     ("afmoe", 16, "packed"): "d1f0d55c4b980ee2",
     ("evabyte", 16, "packed"): "b04035b7d3c443b1",
-    ("gpt2_unfed", 1, "whole"): "e20dacc2cf812172",
-    ("gpt2_unfed", 16, "whole"): "a13e4ed537bdc517",
-    ("olmoe_unfed", 1, "whole"): "99256b25c8affc72",
-    ("olmoe_unfed", 16, "whole"): "60daa79edf026f83",
     ("gpt2", 1, "whole"): "21ee45bab5a96eb9",
     ("gpt2", 16, "whole"): "450794b962ca88af",
     ("gpt2", 16, "packed"): "a0175b9779668163",
@@ -576,13 +534,6 @@ _PARENT_PROGRAM_SHA256 = {
 }
 
 
-def _pinned_symbol(block, S):
-    import window_pack_cases as cases
-    if block.endswith("_unfed"):
-        return cases.unfed_symbol(block[:-len("_unfed")], S)
-    return cases.symbol(block, S)
-
-
 @pytest.mark.parametrize("block,S,form", sorted(_PARENT_PROGRAM_SHA256))
 def test_decode_programs_lower_to_the_parents_text(block, S, form,
                                                    monkeypatch):
@@ -591,12 +542,12 @@ def test_decode_programs_lower_to_the_parents_text(block, S, form,
     ``check_reference`` run) and the packed form of that (what a
     serving window runs) lower to the text they had."""
     import hashlib
-    import window_pack_cases as cases
+    import decode_blocks as cases
     from mxnet_tpu.models import transformer as tfm
     monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
     kernel_tier.clear()
     try:
-        sym = _pinned_symbol(block, S)
+        sym = cases.symbol(block, S)
         if form == "packed":
             sym, budget = tfm.packed_window(sym, cases.SLOTS)
             assert budget == 24
@@ -620,7 +571,7 @@ def test_a_packed_window_program_holds_no_window_of_logits(block,
     of the same graph holds the window's logits as it did. At 5 slots
     of 16 rows (80 a whole window, a budget of 24) and a vocabulary of
     53, numbers that no width of the tiny blocks shares."""
-    import window_pack_cases as cases
+    import decode_blocks as cases
     from mxnet_tpu.models import transformer as tfm
     slots, S, V = 5, 16, 53
     kw = dict(cases.config(block.split("_multibyte")[0]), vocab_size=V)
@@ -676,7 +627,6 @@ def test_a_packed_window_program_holds_no_window_of_logits(block,
 #: PR 44's parent's (7b8989b), computed on it. The nodes that ``+`` and ``*``
 #: make are named from a counter, so each graph is built under a name
 #: manager of its own. The slot-pooled GPT-2 and OLMoE graphs as above:
-#: the parent's under ``*_unfed``, built by hand, the fed ones' own
 #: recorded on ISSUE 47's tree (``fp8_cache`` is a slot-pooled graph
 #: too); the training and one-cursor graphs are the parent's still.
 _PARENT_GRAPH_SHA256 = {
@@ -690,10 +640,6 @@ _PARENT_GRAPH_SHA256 = {
     ("afmoe", 16): "17df5a2e3b2f960c",
     ("evabyte", 1): "0e294d777b975dbf",
     ("evabyte", 16): "988ab43155750348",
-    ("gpt2_unfed", 1): "9d0b16baf0ccae00",
-    ("gpt2_unfed", 16): "c8ced922302c849b",
-    ("olmoe_unfed", 1): "8e930426538a9771",
-    ("olmoe_unfed", 16): "f334ca8be1f1cc19",
     ("gpt2", 1): "e13693f6a86a2957",
     ("gpt2", 16): "c8cf5a65db6de372",
     ("gpt2_rotary", 1): "942fe74ea9b1182b",
@@ -712,11 +658,11 @@ _PARENT_GRAPH_SHA256 = {
 
 
 def _pinned_graph(block, form):
-    import window_pack_cases as cases
+    import decode_blocks as cases
     from mxnet_tpu.models import transformer as tfm
     with mx.name.NameManager():
         if isinstance(form, int):
-            return _pinned_symbol(block, form)
+            return cases.symbol(block, form)
         if form == "scalar_cursor":         # ``KVCacheDecoder``'s graph
             return tfm.get_decode_symbol(
                 block=block, step_len=4, capacity=cases.CAPACITY,
@@ -752,7 +698,7 @@ def test_hyper_connected_programs_lower_at_tiny_sizes(S, form, monkeypatch):
     as rows of four copies (256 numbers) over the rows the program
     runs - 4 slots, 4 x 16 of a whole window, the budget's 24 - and
     never as ``(rows, 4, 64)``."""
-    import window_pack_cases as cases
+    import decode_blocks as cases
     from mxnet_tpu.models import transformer as tfm
     monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
     kernel_tier.clear()
@@ -766,7 +712,8 @@ def test_hyper_connected_programs_lower_at_tiny_sizes(S, form, monkeypatch):
     finally:
         kernel_tier.clear()
     ops = [n.op for n in sym._topo_nodes() if not n.is_variable]
-    assert ops.count("mhc_pre") == ops.count("mhc_post") == 6
+    assert ops.count("mhc_pre") == ops.count("mhc_post") \
+        == 2 * cases.config("xing4")["n_layer"]
     assert f"tensor<{rows}x256xf32>" in text        # the stream's rows
     assert f"tensor<{rows}x16xf32>" in text         # Hres, 16 a row
     assert f"tensor<{rows}x4x64xf32>" not in text
@@ -776,13 +723,13 @@ def test_hyper_connected_programs_lower_at_tiny_sizes(S, form, monkeypatch):
                                      (16, "packed")])
 def test_granite_hybrid_programs_lower_over_the_rows_they_run(
         S, form, monkeypatch):
-    """Granite 4.0-H's tiny graph (``window_pack_cases``: mamba,
+    """Granite 4.0-H's tiny graph (``decode_blocks``: mamba,
     attention, mamba) lowers as its S = 1, whole-window and packed
     programs: two recurrent mixers whose state keeps its shape whatever
     the rows, the mixer's projection over the rows the program runs -
     4 slots, 4 x 16 of a whole window, the budget's 24 - and the
     attention layer's K/V heads paired in one row."""
-    import window_pack_cases as cases
+    import decode_blocks as cases
     from mxnet_tpu.models import transformer as tfm
     monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
     kernel_tier.clear()
@@ -810,602 +757,3 @@ def test_granite_hybrid_programs_lower_over_the_rows_they_run(
         assert "stablehlo.while" in text
     families = tfm.slot_state(sym)
     assert sorted(families) == ["conv", "cursor", "recurrent", "rows"]
-
-
-#: two layers of the Cerebras and the OLMoE configuration at their
-#: published widths, as the doc and chat cells serve them (8 slots,
-#: windows of 64): the keywords, and the shape of a result that only the
-#: row-wise operations of a window compute - the first feed-forward
-#: product, the experts' gated rows (8 assignments a row)
-_SERVED_WIDTHS = {
-    "gpt2": (dict(vocab_size=50257, d_model=2048, n_layer=2, n_head=16,
-                  pos_embed="learned", max_seq_len=2048, capacity=2048),
-             lambda rows: f"{rows},8192"),
-    "olmoe": (dict(block="olmoe", vocab_size=50304, d_model=2048, n_layer=2,
-                   n_head=16, pos_embed="rotary", rope_base=1e4,
-                   capacity=4096, n_expert=64, top_k=8, expert_width=1024,
-                   tie_head=False, embed_scale=False),
-              lambda rows: f"{rows * 8},1024"),
-}
-
-
-@pytest.mark.parametrize("block", sorted(_SERVED_WIDTHS))
-def test_fused_blocks_pack_a_window_of_8x64_to_the_ridge_on_v5e(
-        block, v5e, monkeypatch):
-    """ISSUE 47: the slot-pooled GPT-2 and OLMoE graphs take ``fed``, so
-    their window of 8 x 64 has a packed form, over the 256 rows a
-    weight-bound matmul carries for free. It compiles for the chip with
-    the block's kernels, its row-wise operations run over 256 rows and
-    none over the whole window's 512, and the whole-window form of the
-    same graph runs them over 512."""
-    from mxnet_tpu.executor import _build_graph_runner
-    from mxnet_tpu.models import transformer as tfm
-    monkeypatch.setenv("MXNET_KERNEL_TIER", "pallas")
-    kernel_tier.clear()
-    kw, row_wise = _SERVED_WIDTHS[block]
-    B, S = 8, 64
-    whole = tfm.get_decode_symbol(step_len=S, per_slot=True, **kw)
-    packed, R = tfm.packed_window(whole, B)
-    assert R == tfm.ridge_rows() == 256
-    assert tfm.packed_window(whole, 4) is None      # 4 x 64: free as it is
-    texts = {}
-    try:
-        for rows, symbol in ((R, packed), (B * S, whole)):
-            runner, arg_names, aux_names, _ = _build_graph_runner(
-                symbol, compute_dtype="bfloat16")
-            given = {nm: (B, S) for nm in ("data", "pos_ids")
-                     if nm in arg_names}
-            arg_shapes, _, aux_shapes = symbol.infer_shape(fed=(B,), **given)
-
-            def sds(shape, dtype):
-                return jax.ShapeDtypeStruct(tuple(shape), dtype,
-                                            sharding=v5e)
-
-            args = {nm: sds(s, jnp.int32 if nm in ("data", "fed")
-                            else jnp.bfloat16)
-                    for nm, s in zip(arg_names, arg_shapes)}
-            aux = {nm: sds(s, jnp.int32 if len(s) < 4 else jnp.bfloat16)
-                   for nm, s in zip(aux_names, aux_shapes)}
-
-            def prog(arg_vals, aux_vals):
-                outs, new_aux = runner(arg_vals, aux_vals, False, None)
-                return outs, {**aux_vals, **new_aux}
-
-            texts[rows] = jax.jit(prog, donate_argnums=(1,)) \
-                .lower(args, aux).compile().as_text()
-    finally:
-        kernel_tier.clear()
-    for text in texts.values():
-        for kernel in ("decode_attn", "cache_write") + (
-                ("moe_gmm_gate_up", "moe_gmm_down") if block == "olmoe"
-                else ()):
-            assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), \
-                kernel
-    def computes(rows, text):
-        return re.search(rf"= bf16\[{row_wise(rows)}\]\S* "
-                         r"(fusion|convolution|custom-call)\(", text)
-
-    assert computes(R, texts[R]) and not computes(B * S, texts[R])
-    assert computes(B * S, texts[B * S])
-
-
-@pytest.mark.parametrize("rows", [8, 1152, 8192],
-                         ids=["decode", "packed", "whole"])
-@pytest.mark.parametrize("op", ["mhc_pre", "mhc_post"])
-def test_hyper_connection_kernels_compile_for_v5e(op, rows, v5e):
-    """``ops/mhc.py``'s two kernels at Xing4.0's published sizes (four
-    copies of 3,584, bfloat16) over the rows of the top rung's three
-    programs: the S = 1 step's 8 (the mapping down the sublanes), the
-    packed window's 1,152 and the whole window's 8,192 (whole tiles,
-    along the lanes). One Mosaic kernel each, and nothing beside it that
-    passes over the stream: no fusion reads or writes ``(rows,
-    14336)``."""
-    import re
-    opdef = get_op(op)
-    attrs = opdef.normalize_attrs({"n": 4})
-    n, C = 4, 3584
-
-    def sds(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    ins = [sds((1, rows, n * C)), sds((24, n * C)), sds((24,)), sds((3,))] \
-        if op == "mhc_pre" else \
-        [sds((1, rows, n * C)), sds((rows, C)), sds((rows, 4), jnp.float32),
-         sds((rows, 16), jnp.float32)]
-    assert opdef.variant_eligible("pallas", attrs, [i.shape for i in ins],
-                                  [i.dtype for i in ins])
-    fn = opdef.variant_fn("pallas")
-    text = jax.jit(lambda r: fn(attrs, list(r), [], False, None)[0]) \
-        .lower(ins).compile().as_text()
-    assert len(re.findall(rf"{op}[.\w]* = .*tpu_custom_call", text)) == 1
-    assert not re.findall(rf"bf16\[(1,)?{rows},{n * C}\]\S* fusion\(", text)
-
-
-@pytest.mark.parametrize("op", ["pack", "unpack"])
-def test_packing_compiles_for_v5e_to_block_copies_in_place(op, v5e):
-    """``ops/rows.py`` at GLM-5.2's widest operand (8 slots of 1,024
-    rows of 64 x 256 queries, a budget of 1,152): packing and unpacking
-    are one loop each of ``dynamic-slice`` / ``dynamic-update-slice``
-    over one buffer - no gather, no scatter, and the 268 MB block of all the
-    slots' rows is neither copied nor laid out anew."""
-    import re
-    from mxnet_tpu.ops import rows
-    B, S, R, n = 8, 1024, 1152, 16384
-
-    def sds(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    if op == "pack":
-        # a computed operand, as the attention's result is
-        fn = lambda x, fed, w: jnp.dot(             # noqa: E731
-            rows.pack(x * 2, fed, R)[0][0], w)
-        args = (sds((B, S, n)), sds((B,), jnp.int32), sds((n, 128)))
-    else:
-        fn = lambda x, fed, w: rows.unpack(         # noqa: E731
-            jnp.dot(x, w), fed, S, R, (n,))
-        args = (sds((R, 128)), sds((B,), jnp.int32), sds((128, n)))
-    compiled = jax.jit(fn).lower(*args).compile()
-    text = compiled.as_text()
-    assert " gather(" not in text and " scatter(" not in text
-    assert len(re.findall(r" while\(", text)) == 1
-    assert "dynamic-update-slice(" in text and "dynamic-slice(" in text
-    assert not re.findall(r"= bf16\[[\d,]+\]\S* copy\(", text)
-    # nothing beside the operand (pack: 268 MB) or the result (unpack)
-    block = B * S * n * 2
-    assert compiled.memory_analysis().temp_size_in_bytes \
-        < (block if op == "pack" else 0) + (8 << 20)
-
-
-def test_packed_window_program_compiles_for_v5e_and_copies_no_pool(
-        v5e, monkeypatch):
-    """One layer of GLM-5.2 at the published widths (an indexer, latent
-    attention under its selection, 16 held experts beside a shared one;
-    8 slots, windows of 1,024, a capacity of 32,768, bfloat16) in the
-    packed form of its window program: it compiles for the chip, its
-    dense products run over the budget's 1,152 rows, nothing that packs
-    or unpacks is a gather or a scatter, and with the aux arrays donated
-    both pools come back in the buffers they came in."""
-    import re
-    from mxnet_tpu.executor import _build_graph_runner
-    from mxnet_tpu.models import transformer as tfm
-    monkeypatch.setenv("MXNET_KERNEL_TIER", "pallas")
-    kernel_tier.clear()
-    B, S, C, D, V = 8, 1024, 32768, 6144, 2048
-    glm = dict(q_lora_rank=2048, kv_lora_rank=512, qk_nope_head_dim=192,
-               qk_rope_head_dim=64, v_head_dim=256, index_n_heads=32,
-               index_head_dim=128, index_topk=2048, indexer_types=["full"],
-               first_k_dense_replace=0, intermediate_size=12288,
-               moe_intermediate_size=2048, n_routed_experts=256,
-               num_experts_per_tok=8, n_shared_experts=1,
-               routed_scaling_factor=2.5, norm_topk_prob=True, held=(0, 16))
-    whole = tfm.get_decode_symbol(
-        vocab_size=V, d_model=D, n_layer=1, n_head=64, rope_base=8e6,
-        capacity=C, step_len=S, per_slot=True, block="glm_dsa",
-        tie_head=False, embed_scale=False, glm=glm)
-    symbol, R = tfm.packed_window(whole, B)
-    assert R == 1152
-    try:
-        runner, arg_names, aux_names, _ = _build_graph_runner(
-            symbol, compute_dtype="bfloat16")
-        arg_shapes, _, aux_shapes = symbol.infer_shape(data=(B, S),
-                                                       fed=(B,))
-
-        def sds(shape, dtype):
-            return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e)
-
-        args = {nm: sds(s, jnp.int32 if nm in ("data", "fed")
-                        else jnp.bfloat16)
-                for nm, s in zip(arg_names, arg_shapes)}
-        aux = {nm: sds(s, jnp.int32 if len(s) < 4 else jnp.bfloat16)
-               for nm, s in zip(aux_names, aux_shapes)}
-
-        def prog(arg_vals, aux_vals):
-            outs, new_aux = runner(arg_vals, aux_vals, False, None)
-            return outs, {**aux_vals, **new_aux}
-
-        compiled = jax.jit(prog, donate_argnums=(1,)).lower(args, aux) \
-            .compile()
-    finally:
-        kernel_tier.clear()
-    text = compiled.as_text()
-    for kernel in ("mla_attn_window", "mla_attn_ride", "dsa_index_scores",
-                   "moe_gmm_gate_up"):
-        assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
-    # the projections' products are R rows tall, none is slots x S
-    assert re.search(rf"= bf16\[{R},\d+\]\S* (fusion|convolution)\(", text)
-    assert not re.search(rf"= bf16\[{B * S},{D}\]", text)
-    # packing and unpacking: the nodes' names are the operations' scopes
-    moved = [line for line in text.splitlines()
-             if re.search(r'op_name="[^"]*(_pack|_unfold|_split|_rows|'
-                          r'logits_bsv)/', line)]
-    assert moved and not [line for line in moved
-                          if " gather(" in line or " scatter(" in line]
-    pools = [s for s in aux_shapes if len(s) == 4]
-    assert sorted(p[-1] for p in pools) == [128, 640]
-    for p in pools:
-        pool = rf"= bf16\[{','.join(map(str, p))}\]\S* "
-        assert not re.findall(pool + r"(copy|fusion)\(", text)
-    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
-        2 * int(np.prod(p)) for p in pools)
-
-
-@pytest.mark.parametrize("S", [1, 1024], ids=["decode", "window"])
-def test_group_limited_share_compiles_for_v5e_with_the_grouped_kernels(S, v5e):
-    """A.X-K1's ``MoEFFN`` at the published sizes (8 slots, rows of
-    7,168, 192 experts in 8 groups, 12 held of width 2,048 beside a
-    shared expert): rows of 7,168 are inside the grouped kernels' widths,
-    so the Pallas lowering is eligible and both ``moe_gmm_*`` kernels
-    compile for the chip."""
-    import re
-    B, D, F, E, held = 8, 7168, 2048, 192, 12
-    opdef = get_op("MoEFFN")
-    attrs = opdef.normalize_attrs(dict(
-        num_experts=E, num_hidden=F, top_k=8, norm_topk=True,
-        scoring="sigmoid", scaling=2.5, held_first=0, held_count=held,
-        shared_hidden=F, step_len=S, n_group=8, topk_group=4))
-
-    def sds(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    ins = [sds((B * S, D)), sds((B,), jnp.int32), sds((E, D)),
-           sds((held, D, F)), sds((held, D, F)), sds((held, F, D)),
-           sds((D, F)), sds((D, F)), sds((F, D))]
-    assert opdef.input_names(attrs) == [
-        "data", "fed", "router_weight", "gate_weight", "up_weight",
-        "down_weight", "shared_gate_weight", "shared_up_weight",
-        "shared_down_weight"]
-    aux = [sds((5,), jnp.int32)]
-    assert opdef.variant_eligible("pallas", attrs,
-                                  [a.shape for a in ins + aux],
-                                  [str(a.dtype) for a in ins + aux])
-    fn = opdef.variant_fn("pallas")
-    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None)) \
-        .lower(ins, aux).compile()
-    text = compiled.as_text()
-    for kernel in ("moe_gmm_gate_up", "moe_gmm_down"):
-        assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
-
-
-@pytest.mark.parametrize("step_len,rows,slots", [
-    (1, 32, 32), (256, 384, 1), (256, 8192, 32)],
-    ids=["decode", "packed_window", "whole_window"])
-def test_softmax_share_compiles_for_v5e_with_the_grouped_kernels(
-        step_len, rows, slots, v5e):
-    """Granite 4.0-H Small's ``MoEFFN`` at the published sizes (rows of
-    4,096, a softmax router over 72 experts, 10 a token, 36 held of
-    width 768 beside a shared feed-forward of 1,536; ISSUE 54): the
-    softmax branch of the share - ``moe_route`` under ``held_first`` /
-    ``held_count`` - is eligible for the Pallas lowering and both
-    ``moe_gmm_*`` kernels compile for the chip inside the loop over the
-    held rows' segments, over an S = 1 step's 32 rows, a packed
-    window's 384 (one count for all of them) and the whole window's
-    8,192 that the benchmark's ``check_reference`` runs."""
-    import re
-    D, F, Fs, E, held = 4096, 768, 1536, 72, 36
-    opdef = get_op("MoEFFN")
-    attrs = opdef.normalize_attrs(dict(
-        num_experts=E, num_hidden=F, top_k=10, norm_topk=True,
-        held_first=0, held_count=held, shared_hidden=Fs,
-        step_len=step_len))
-
-    def sds(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    ins = [sds((rows, D)), sds((slots,), jnp.int32), sds((E, D)),
-           sds((held, D, F)), sds((held, D, F)), sds((held, F, D)),
-           sds((D, Fs)), sds((D, Fs)), sds((Fs, D))]
-    assert opdef.input_names(attrs) == [
-        "data", "fed", "router_weight", "gate_weight", "up_weight",
-        "down_weight", "shared_gate_weight", "shared_up_weight",
-        "shared_down_weight"]
-    aux = [sds((5,), jnp.int32)]
-    assert opdef.variant_eligible("pallas", attrs,
-                                  [a.shape for a in ins + aux],
-                                  [str(a.dtype) for a in ins + aux])
-    fn = opdef.variant_fn("pallas")
-    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None)) \
-        .lower(ins, aux).compile()
-    text = compiled.as_text()
-    for kernel in ("moe_gmm_gate_up", "moe_gmm_down"):
-        assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
-
-
-@pytest.mark.parametrize("form", ["plain", "held"])
-def test_two_expert_layers_lower_the_grouped_kernels_once(form, v5e,
-                                                          monkeypatch):
-    """A graph of two ``MoEFFN`` layers of equal shapes, lowered for the
-    chip: ``grouped_expert_ffn`` is a jitted function, so the program
-    holds one body of ``moe_gmm_gate_up`` and one of ``moe_gmm_down``
-    and calls them from both layers (inside the held experts' loop over
-    the segments too) - what a bind pays to turn the kernels into text
-    does not grow with the depth."""
-    import re
-    from mxnet_tpu.executor import _build_graph_runner
-    monkeypatch.setenv("MXNET_KERNEL_TIER", "pallas")
-    kernel_tier.clear()
-    T, D, F, E = 256, 256, 128, 8
-    kw = dict(num_experts=E, num_hidden=F, top_k=2)
-    if form == "held":
-        kw.update(scoring="sigmoid", norm_topk=True, held_first=2,
-                  held_count=4)
-    x = mx.sym.var("data")
-    for layer in range(2):
-        x = mx.sym.MoEFFN(x, name=f"moe{layer}", **kw)
-    try:
-        runner, arg_names, aux_names, _ = _build_graph_runner(
-            x, compute_dtype="bfloat16")
-        arg_shapes, _, aux_shapes = x.infer_shape(data=(T, D))
-        args = {nm: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=v5e)
-                for nm, s in zip(arg_names, arg_shapes)}
-        aux = {nm: jax.ShapeDtypeStruct(s, jnp.int32, sharding=v5e)
-               for nm, s in zip(aux_names, aux_shapes)}
-        lowered = jax.jit(lambda a, st: runner(a, st, False, None)) \
-            .lower(args, aux)
-    finally:
-        kernel_tier.clear()
-    text = lowered.as_text()
-    assert text.count("@tpu_custom_call") == 2
-    for kernel in ("moe_gmm_gate_up", "moe_gmm_down"):
-        assert len(re.findall(kernel, text)) == 1, kernel
-    assert len(re.findall(r"call @_grouped_expert_ffn", text)) == 2
-    # and the chip's compiler takes both calls of the one body
-    compiled = lowered.compile().as_text()
-    for kernel in ("moe_gmm_gate_up", "moe_gmm_down"):
-        assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", compiled)
-
-
-def test_a_prefix_join_compiles_for_v5e_and_copies_no_pool(v5e):
-    """``BatchedKVCacheDecoder``'s row programs at A.X-K1's sizes (five
-    latent pools of 8 x 32,768 rows of 640 lanes, 1,024 rows a launch):
-    ``restore_rows`` takes the pools over and hands every one back in
-    its buffer - a dynamic-update-slice in place, no copy of 335 MB -
-    and ``capture_rows`` reads 1,024 rows of one slot, not a pool."""
-    import re
-    from mxnet_tpu.models.transformer import row_blocks, row_programs
-    B, C, W, L, block = 8, 32768, 640, 5, 1024
-
-    def sds(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-
-    assert list(row_blocks(16384 + 5, block, C)) == [
-        (i * block, 0, block) for i in range(16)] + [(16384, 0, 5)]
-    assert list(row_blocks(C, block, C))[-1] == (C - block, 0, block)
-    capture, restore = row_programs(B, block, [v5e] * L)
-    pools = tuple(sds((B, 1, C, W)) for _ in range(L))
-    rows = tuple(sds((1, block, W)) for _ in range(L))
-    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e)
-    compiled = restore.lower(pools, rows, scalar, scalar).compile()
-    text = compiled.as_text()
-    pool = rf"= bf16\[{B},1,{C},{W}\]\S* "
-    assert not re.findall(pool + r"copy\(", text)
-    assert compiled.memory_analysis().alias_size_in_bytes \
-        >= L * B * C * W * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
-    compiled = capture.lower(pools, scalar, scalar).compile()
-    assert not re.findall(pool + r"copy\(", compiled.as_text())
-    assert L * block * W * 2 \
-        <= compiled.memory_analysis().output_size_in_bytes \
-        < L * block * W * 2 + 4096
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
-
-
-# ------------------------------------------- chip_smoke.py without a chip
-def test_chip_smoke_fails_at_device_check_without_a_chip():
-    res = subprocess.run([sys.executable, os.path.join(ROOT,
-                                                       "chip_smoke.py")],
-                         capture_output=True, text=True, timeout=300,
-                         cwd=ROOT)
-    assert res.returncode != 0
-    assert "no TPU" in res.stderr
-    assert res.stdout.strip() == ""           # no result line of any kind
-
-
-_TINY_WIDTHS = dict(batch=8, classes=10, conv=(2, 4, 8, 8), fc=(50, 33),
-                    slots=2, window=4, vocab=64, d_model=32, n_head=4,
-                    capacity=16, lm_batch=1, lm_seq=16)
-
-
-def _phase_line(capsys, phase):
-    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
-             if l.startswith("{")]
-    return [l for l in lines if l["phase"] == phase][-1]
-
-
-def test_kernels_phase_body_tiny(capsys):
-    """Every registered Pallas variant has a site, and each passes the
-    numerics gate (interpret mode here)."""
-    chip_smoke.kernels_phase(chip_smoke.kernel_sites(_TINY_WIDTHS),
-                             require_mosaic=False)
-    line = _phase_line(capsys, "kernels")
-    assert line["ok"] and not line["compiled"]
-    assert len(line["kernels"]) == len(_SITES)
-
-
-def test_kernels_phase_demands_mosaic_on_the_chip():
-    with pytest.raises(SystemExit, match="interpret mode"):
-        chip_smoke.kernels_phase([], require_mosaic=True)
-
-
-def _tiny_fit_args(extra=()):
-    _, fit = chip_smoke._imagenet_example()
-    parser = fit.add_fit_args(argparse.ArgumentParser())
-    return parser.parse_args(["--batch-size", "8", "--num-epochs", "1",
-                              "--lr", "0.05", *extra])
-
-
-def _tiny_train_setup(extra=()):
-    from mxnet_tpu.models import mlp
-    rs = np.random.RandomState(0)
-    x = rs.rand(32, 16).astype("f")
-    y = rs.randint(0, 4, 32).astype("f")
-    iters = (mx.io.NDArrayIter(x, y, 8), mx.io.NDArrayIter(x[:8], y[:8], 8))
-    return _tiny_fit_args(extra), mlp.get_symbol(num_classes=4), iters
-
-
-def test_train_phase_body_tiny(capsys):
-    mod, watch = chip_smoke.train_phase(
-        *_tiny_train_setup(["--dtype", "bfloat16"]),
-        devices=[mx.cpu().jax_device()], seed=0)
-    line = _phase_line(capsys, "train")
-    assert line["ok"] and line["steps"] == 4 == len(watch.losses)
-    assert mod._compute_dtype == "bfloat16"          # --dtype is wired
-    # the placement check is real: buffers on cpu(0) are not on cpu(1)
-    checked, stray = chip_smoke.stray_buffers(mod, [mx.cpu(1).jax_device()])
-    assert checked == line["buffers_checked"] and len(stray) == checked
-
-
-def test_serve_phase_body_tiny(capsys):
-    answers = chip_smoke.serve_phase(
-        dict(vocab_size=64, d_model=32, n_layer=1, n_head=4), capacity=32,
-        ladder=[2], prompt_lens=(3, 12), n_requests=3, max_new=5,
-        context=mx.cpu(), compute_dtype=None, seed=0, prefill_chunk=4)
-    line = _phase_line(capsys, "serve")
-    assert line["ok"] and line["compiles_since_warmup"] == 0
-    assert line["agreed_tokens"] == [5, 5, 5] and line["windows"] == [4]
-    assert [len(a) for a in answers] == [5, 5, 5]
-
-
-def test_layer_pair_phase_body_tiny(capsys, monkeypatch):
-    """The smoke's layer pair (ISSUE 54: a mamba layer and the attention
-    layer with routed experts, half held) at tiny widths on the CPU,
-    float32: a packed window with riders, then the S = 1 step they are
-    held to."""
-    monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
-    kernel_tier.clear()
-    model = dict(vocab_size=64, d_model=32, n_layer=2, n_head=4,
-                 granite=dict(chip_smoke.GRANITE_SMALL_PAIR["granite"],
-                              num_key_value_heads=1, mamba_n_heads=8,
-                              mamba_d_head=8, mamba_d_state=16,
-                              mamba_chunk_size=8,
-                              shared_intermediate_size=24,
-                              num_local_experts=8, num_experts_per_tok=3,
-                              intermediate_size=16, held=(0, 4)))
-    try:
-        chip_smoke.layer_pair_phase(model, slots=4, window=16, capacity=64,
-                                    context=mx.cpu(), compute_dtype=None,
-                                    seed=0)
-    finally:
-        kernel_tier.clear()
-    line = _phase_line(capsys, "pair")
-    assert line["ok"] and line["packed_rows"] == 24
-    assert (line["prefill_rows"], line["riders"]) == (16, 3)
-    assert line["assignments"] == 2 * 3 * 19
-    assert 0 < line["held_assignments"] < line["assignments"]
-    assert line["rider_vs_step_max_abs_err"] <= line["tolerance"]
-    pair = chip_smoke.GRANITE_SMALL_PAIR
-    assert (pair["d_model"], pair["granite"]["mamba_n_heads"],
-            pair["granite"]["num_local_experts"],
-            pair["granite"]["held"]) == (4096, 128, 72, (0, 36))
-
-
-def test_multichip_phase_body_on_virtual_devices(capsys, monkeypatch):
-    """The --multichip body over four virtual CPU devices (``mx.gpu`` is
-    steered to them: this host has no accelerator to name)."""
-    monkeypatch.setattr(mx.context, "_accelerator_devices",
-                        mx.context._local_cpu_devices)
-
-    def build(gpus):
-        return _tiny_train_setup(["--gpus", gpus])
-
-    chip_smoke.multichip_phase(build, "0,1,2,3", seed=0, rtol=1e-4)
-    line = _phase_line(capsys, "multichip")
-    assert line["ok"] and line["chips"] == 4 and line["all_reduce_in_hlo"]
-    assert line["data_shard_shapes"] == ["(2, 16)"]
-
-
-# ------------------------------------------------ the fallbacks are gone
-def test_accelerator_context_raises_without_an_accelerator():
-    assert mx.num_gpus() == 0
-    for ctx in (mx.tpu(0), mx.gpu(0)):
-        with pytest.raises(mx.base.MXNetError, match="0 accelerator"):
-            ctx.jax_device()
-
-
-def test_accelerator_context_raises_beyond_the_last_chip(monkeypatch):
-    monkeypatch.setattr(mx.context, "_accelerator_devices",
-                        lambda: mx.context._local_cpu_devices()[:2])
-    assert mx.tpu(1).jax_device() == mx.context._local_cpu_devices()[1]
-    with pytest.raises(mx.base.MXNetError, match="2 accelerator"):
-        mx.tpu(9).jax_device()
-
-
-def test_autotune_raises_what_the_compiler_refuses(monkeypatch):
-    """A lowering error is a defect of a registered variant, not an
-    ``xla`` outcome: it raises with op, shapes and dtypes."""
-    def refuse(*_a, **_k):
-        raise NotImplementedError("Unimplemented primitive in Pallas TPU "
-                                  "lowering: erf")
-    monkeypatch.setattr(kernel_tier, "_backend", lambda: "tpu")
-    monkeypatch.setattr(kernel_tier, "_device_kind", lambda: "TPU test")
-    monkeypatch.setattr(kernel_tier, "numerics_gate", refuse)
-    kernel_tier.clear()
-    bg = get_op("FusedBiasGeLU")
-    with pytest.raises(mx.base.MXNetError) as exc:
-        kernel_tier.resolve(bg, {}, [(16, 64), (64,)],
-                            ["float32", "float32"], True)
-    msg = str(exc.value)
-    assert "FusedBiasGeLU" in msg and "[16, 64]" in msg
-    assert "float32" in msg and "erf" in msg
-    kernel_tier.clear()
-
-
-def test_autotune_measures_inside_an_enclosing_trace(monkeypatch):
-    """``resolve`` runs while the enclosing program is being traced; the
-    measurement must still see concrete arrays (jax 0.9 stages every op
-    issued under a trace)."""
-    monkeypatch.setattr(kernel_tier, "_backend", lambda: "tpu")
-    monkeypatch.setattr(kernel_tier, "_device_kind", lambda: "TPU test")
-    kernel_tier.clear()
-    sm = get_op("SoftmaxOutput")
-    attrs = sm.normalize_attrs({})
-
-    @jax.jit
-    def program(x, label):
-        return kernel_tier.dispatch(sm, attrs, [x, label], [], True,
-                                    None)[0][0]
-
-    program(jnp.ones((8, 10)), jnp.zeros((8,)))
-    dec = kernel_tier.decisions()[-1]
-    assert dec["source"] == "autotune" and "xla_ms" in dec, dec
-    kernel_tier.clear()
-
-
-def test_kernel_erf_matches_lax_erf():
-    x = jnp.asarray(np.linspace(-6, 6, 20001).astype("f"))
-    err = jnp.max(jnp.abs(pallas_kernels._erf32(x) - jax.lax.erf(x)))
-    assert float(err) < 1e-6
-
-
-@pytest.mark.parametrize("env_dir", [None, "/somewhere/else"])
-def test_compile_cache_has_one_place(monkeypatch, env_dir):
-    """JAX_COMPILATION_CACHE_DIR set: nothing is set in code. Unset: the
-    cache is <checkout>/.jax_cache."""
-    from mxnet_tpu import context
-    saved = jax.config.jax_compilation_cache_dir
-    if env_dir is None:
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    else:
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
-    try:
-        jax.config.update("jax_compilation_cache_dir", "untouched")
-        context._init_compilation_cache()
-        want = "untouched" if env_dir else os.path.join(ROOT, ".jax_cache")
-        assert jax.config.jax_compilation_cache_dir == want
-    finally:
-        jax.config.update("jax_compilation_cache_dir", saved)
-
-
-def test_mesh_binding_keeps_mosaic_kernels_out(monkeypatch):
-    """XLA cannot partition a Mosaic kernel: over more than one device a
-    TPU backend resolves the composition, and says so."""
-    monkeypatch.setenv("MXNET_KERNEL_TIER", "pallas")
-    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
-    kernel_tier.clear()
-    sm = get_op("SoftmaxOutput")
-    site = (sm, sm.normalize_attrs({}), [(8, 10), (8,)],
-            ["float32", "float32"], True)
-    assert kernel_tier.resolve(*site) == "pallas"
-    assert kernel_tier.resolve(*site, n_devices=4) == "xla"
-    dec = kernel_tier.decisions()[-1]
-    assert dec["source"] == "mesh" and "4 devices" in dec["reason"]
-    kernel_tier.clear()

@@ -24,7 +24,7 @@ from mxnet_tpu.models import transformer as tfm
 from mxnet_tpu.ops.registry import get_op
 from mxnet_tpu.serve import FakeClock, QueueFullError
 
-import window_pack_cases as cases
+import decode_blocks as cases
 
 V, D, L, H, T = 64, 32, 2, 4, 16      # tiny LM; T doubles as capacity
 
@@ -550,12 +550,14 @@ def test_cursor_program_never_compiles_after_warmup(trained):
     of 1..rung rows, retirements, rung switches — compiles nothing
     after warm-up outside ``DecodeEngine.migrate`` (whose eager per-row
     copies are not steady state), and ``cursor.updates`` / ``.rows``
-    count exactly the joins plus the non-empty rewinds. A graph without
-    ``fed``, built by hand: a fed one rewinds nobody after a window."""
+    count exactly the joins plus the non-empty rewinds: none after a
+    window (the graph is fed its real tokens and advances by them),
+    1..rung rows where somebody asks for them (speculation's rollback,
+    a prefix joined at a cursor)."""
     def gen(s):
-        return cases.unfed_symbol(
-            "gpt2_rotary", s, vocab_size=V, d_model=D, n_layer=L, n_head=H,
-            capacity=T, max_seq_len=T, rope_base=10000.0)
+        return cases.symbol(
+            "gpt2_rotary", s, capacity=T, vocab_size=V, d_model=D,
+            n_layer=L, n_head=H, rope_base=10000.0)
     sched = mx.serve.serve_decoder(
         gen(1), _args_nd(trained), name="cursor29", capacity=T,
         ladder=[1, 2, 4], clock=FakeClock(), start=False,
@@ -569,13 +571,14 @@ def test_cursor_program_never_compiles_after_warmup(trained):
                 for k in ("cursor.updates", "cursor.rows", "joins",
                           "prefill.chunks")}
 
-    moved, in_migrate = [], [0]
+    moved, targets, in_migrate = [], [], [0]
     for rung in eng.ladder:
         drv = eng.driver(rung)
 
         def rewind_many(rows, positions, inner=drv.rewind_many):
             if len(rows):
                 moved.append(len(rows))
+                targets.extend(int(p) for p in positions)
             inner(rows, positions)
         drv.rewind_many = rewind_many
     migrate = eng.migrate
@@ -600,6 +603,12 @@ def test_cursor_program_never_compiles_after_warmup(trained):
         sched.pump()
     for h in handles:
         h.result(timeout=5)
+    # nobody is rewound after a window: a row nobody owns goes back to
+    # 0 where it stands too near the capacity, and that is all
+    assert not any(targets)
+    for rung in eng.ladder:             # a rollback of 1..rung rows
+        for n in range(1, rung + 1):
+            eng.driver(rung).rewind_many(list(range(n)), [0] * n)
     got = {k: v - before[k] for k, v in counters().items()}
     assert sched.stats()["migrations"] >= 4
     assert got["joins"] == len(handles) and got["prefill.chunks"] >= 10
@@ -1116,54 +1125,25 @@ def test_scalar_decode_unchanged(trained):
 _LAUNCH_S = 4                                    # the window program
 
 
+#: a ``gpt2`` block with learned positions beside tokens and ``fed``,
+#: and EVA attention's (rotary: tokens and ``fed``): rows of the table
+_LAUNCH = {"learned": ("gpt2", dict(capacity=T, vocab_size=V, d_model=D,
+                                    n_layer=L, n_head=H, max_seq_len=T)),
+           "fed": ("evabyte", dict(capacity=64, n_layer=1, n_pred_heads=1))}
+
+
 def _launch_symbol(kind, step_len):
-    if kind == "learned":
-        # built by hand without ``fed``: every slot advances by S
-        return cases.unfed_symbol(
-            "gpt2", step_len, vocab_size=V, d_model=D, n_layer=L, n_head=H,
-            capacity=T, max_seq_len=T)
-    return tfm.get_decode_symbol(           # rotary, with a ``fed`` input
-        vocab_size=40, d_model=32, n_layer=1, n_head=2,
-        pos_embed="rotary", rope_base=1e5, capacity=64,
-        step_len=step_len, per_slot=True, block="evabyte", window=32,
-        chunk=4, n_pred_heads=1, ffn_width=48, tie_head=False,
-        embed_scale=False)
+    case, over = _LAUNCH[kind]
+    return cases.symbol(case, step_len, **over)
 
 
 def _launch_driver(kind, slots=2):
     """A two-slot pool with its S=4 window program, bound as
     ``DecodeEngine`` binds (tokens and ``fed`` int32, learned positions
-    float32), every parameter drawn from one seed."""
-    inputs = {"learned": ("data", "pos_ids"), "fed": ("data", "fed")}[kind]
-
-    def bound(step_len, shared=None):
-        descs = [mx.io.DataDesc("data", (slots, step_len), np.int32)]
-        if kind == "learned":
-            descs.append(mx.io.DataDesc("pos_ids", (slots, step_len),
-                                        np.float32))
-        else:
-            descs.append(mx.io.DataDesc("fed", (slots,), np.int32))
-        symbol = _launch_symbol(kind, step_len)
-        mod = mx.mod.Module(symbol, data_names=inputs, label_names=[])
-        mod.bind(descs, None, for_training=False, shared_module=shared)
-        if shared is None:
-            shapes, _, _ = symbol.infer_shape(
-                **{d.name: d.shape for d in descs})
-            rng = np.random.default_rng(3)
-            mod.init_params(initializer=None, aux_params={}, arg_params={
-                nm: mx.nd.array(
-                    (0.25 * rng.standard_normal(shp)).astype(np.float32))
-                for nm, shp in zip(symbol.list_arguments(), shapes)
-                if nm not in inputs}, allow_missing=True)
-        return mod
-
-    base = bound(1)
-    capacity = T if kind == "learned" else 64
-    drv = tfm.BatchedKVCacheDecoder(base, capacity, slots=slots,
-                                    pos_embed="learned"
-                                    if kind == "learned" else "rotary")
-    drv.add_window(_LAUNCH_S, bound(_LAUNCH_S, shared=base))
-    return drv
+    float32)."""
+    case, over = _LAUNCH[kind]
+    return cases.driver(case, packed=False, slots=slots, window=_LAUNCH_S,
+                        **over)
 
 
 def _launch_schedule(drv):
@@ -1178,15 +1158,12 @@ def _launch_schedule(drv):
 
     def step(S, fed=None):
         tokens = rs.randint(1, 40, (drv.slots, S))
-        outs.append(drv.step(tokens, fed=fed if drv.feeds else None)
-                    .asnumpy())
+        outs.append(drv.step(tokens, fed=fed).asnumpy())
 
     for _ in range(3):
         step(1)
-    step(_LAUNCH_S, fed=[_LAUNCH_S, 2])
-    if not drv.feeds:
-        drv.rewind(1, 5)                 # the window's two pads
-    drv.rewind(0, 6)                     # and one real token taken back
+    step(_LAUNCH_S, fed=[_LAUNCH_S, 2])  # the window's two pads: not fed
+    drv.rewind(0, 6)                     # one real token taken back
     for _ in range(2):
         step(1)
     return outs
@@ -1223,7 +1200,8 @@ def test_a_decode_steps_launch_puts_nothing_and_draws_no_key(kind):
     finally:
         tm.disable()
     assert (puts, draws) == (0, 0)
-    assert aliased == 2 * len(got)               # two inputs, six steps
+    n_inputs = 3 if kind == "learned" else 2     # tokens, positions, fed
+    assert aliased == n_inputs * len(got)        # every input, six steps
     after = mx.random.get_state()["key"]
     assert chain is after or np.array_equal(chain, after)
 
@@ -1237,7 +1215,7 @@ def test_a_decode_steps_launch_puts_nothing_and_draws_no_key(kind):
             a - b for a, b in zip(_launch_counts(), before))
     finally:
         tm.disable()
-    assert (aliased, puts, draws) == (0, 2 * len(want), 0)
+    assert (aliased, puts, draws) == (0, n_inputs * len(want), 0)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
     assert np.abs(got[-1]).max() > 0
@@ -1246,17 +1224,16 @@ def test_a_decode_steps_launch_puts_nothing_and_draws_no_key(kind):
 # ============ ISSUE 46: the next S=1 step is launched before the last
 # one's ids reach the host
 _S46 = 4                              # the fixtures' prefill window
-_T46 = {"gpt2": T, "fed": 64, "routed": T, "unfed": T}
-_V46 = {"gpt2": V, "fed": 40, "routed": V, "unfed": V}
+_T46 = {"gpt2": T, "fed": 64, "routed": T}
+_V46 = {"gpt2": V, "fed": 40, "routed": V}
 
 
 def _symbol46(kind, step_len=1):
-    """A ``gpt2`` block with learned positions, a graph that is fed
-    (EVA attention), the routed block of ``_pool_symbol``, or the
-    ``gpt2`` block built by hand without ``fed``."""
-    if kind in ("fed", "unfed"):
-        return _launch_symbol({"fed": "fed", "unfed": "learned"}[kind],
-                              step_len)
+    """A ``gpt2`` block with learned positions, EVA attention's graph
+    (``"fed"``: the first that was), or the routed block of
+    ``_pool_symbol``."""
+    if kind == "fed":
+        return _launch_symbol("fed", step_len)
     return _pool_symbol({"gpt2": "dense-learned",
                          "routed": "rotary-routed"}[kind], step_len)[0]
 
@@ -1292,7 +1269,7 @@ def _plain_greedy(kind, prompt, max_new, eos_id=None):
     ``np.argmax`` on the host."""
     drv = _sched46(kind, "plain").engine.driver(1)
     drv.join(0)
-    fed = [1] if drv.feeds else None
+    fed = [1]
     for t in prompt[:-1]:
         drv.step(np.asarray([[t]], np.int32), fed=fed)
     cur, out = int(prompt[-1]), []
@@ -1772,28 +1749,3 @@ def test_a_slot_retires_while_a_window_launched_ahead_is_on_the_chip(kind,
     at, n = (2, 3) if how == "eos" else (1, _S46)
     assert ahead[1][at] == (_S46, [n, 1, 0, 0], 1)
     assert sync[1][at] == (_S46, [n, 0, 0, 0], 0)
-
-
-def test_an_engine_that_is_not_fed_launches_no_window_ahead():
-    """ISSUE 53 (f): a graph built by hand without ``fed`` advances
-    every cursor by S and is rewound before the next dispatch reads
-    it: its windows wait for their predecessor's commit as ever, the
-    S=1 steps behind them run ahead as since ISSUE 46."""
-    prompts = [_prompt53("unfed", 90 + i, n) for i, n in enumerate((10, 7, 3))]
-
-    def script(sched):
-        assert not sched.engine.feeds
-        hs = [sched.submit(p, max_new_tokens=4) for p in prompts]
-        sched.pump()
-        return hs
-
-    ahead, sync = _orders53("unfed", script)
-    assert ahead[0] == sync[0]
-    _same_dispatches53(ahead, sync)
-    assert [t for t, _why in ahead[0]] == \
-        [_plain_greedy("unfed", p, 4) for p in prompts]
-    assert ahead[2]["window.dispatches"] >= 3
-    assert ahead[2]["runahead.windows"] == 0
-    assert not any(a for w, _fed, a in ahead[1] if w > 1)
-    assert ahead[2]["runahead.launched"] > 0
-

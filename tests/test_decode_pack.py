@@ -5,9 +5,10 @@ the packed program - whose head runs over each slot's last fed row and
 which returns that row alone, ISSUE 51 - against the whole-window
 program of the same graph at that row, for each block - all take
 ``fed`` -, at tiny sizes on the CPU in
-float32; the two blocks that took none before ISSUE 47 against the
-graphs they were, built by hand; and the scheduler's plan inside the
-budget against the plan without one."""
+float32; and what one script through the scheduler counts for each
+block (``tests/decode_counts_parent.py``). The scheduler's plan inside
+the budget is ``tests/test_decode_pack_sched.py``'s (a file of its own
+so that two workers take them: ROADMAP D22)."""
 import numpy as np
 import pytest
 
@@ -19,7 +20,7 @@ from mxnet_tpu.ops import rows
 from mxnet_tpu.serve.clock import FakeClock
 from mxnet_tpu.serve.decode import DecodeEngine, DecodeScheduler
 
-import window_pack_cases as cases
+import decode_blocks as cases
 from decode_counts_parent import COUNTS
 
 S, SLOTS = cases.WINDOW, cases.SLOTS
@@ -137,11 +138,9 @@ def test_the_budget_follows_from_the_shapes():
                    for n in sym._topo_nodes() if not n.is_variable)
     assert sym.infer_shape(data=(SLOTS, S), fed=(SLOTS,))[1] \
         == [(SLOTS, S, 48)]
-    # nobody rides at rung 1, an S = 1 graph has nothing to pack, and a
-    # graph without ``fed`` (built by hand) has no such nodes
+    # nobody rides at rung 1, and an S = 1 graph has nothing to pack
     assert tfm.packed_window(sym, 1) is None
     assert tfm.packed_window(cases.symbol("glm_dsa", 1), SLOTS) is None
-    assert tfm.packed_window(cases.unfed_symbol("gpt2", S), SLOTS) is None
     for case in cases.FUSED:
         assert tfm.packed_window(cases.symbol(case, S), SLOTS)[1] == R
 
@@ -294,9 +293,8 @@ def _fresh(drv, tokens):
     for slot in range(SLOTS):
         drv.join(slot)
     for step in range(3):
-        feed = {"fed": (np.arange(SLOTS) <= step + 1).astype(np.int64)} \
-            if drv.feeds else {}
-        drv.step(tokens[:, step], **feed)
+        drv.step(tokens[:, step],
+                 fed=(np.arange(SLOTS) <= step + 1).astype(np.int64))
 
 
 @pytest.mark.parametrize("mix", sorted(MIXES))
@@ -406,440 +404,6 @@ def test_a_step_without_fed_takes_the_whole_window_program(pair):
     assert packed.last_program_rows == SLOTS
 
 
-# ------------------------------------------------------------- the scheduler
-def _engine(block, name, ladder=(1, SLOTS), gen=None):
-    gen = gen or (lambda s: cases.symbol(block, s))
-    return DecodeEngine(name, gen(1), cases.params(block),
-                        capacity=cases.CAPACITY, ladder=list(ladder),
-                        symbol_gen=gen, window_lens=[S])
-
-
-def _serve(engine, prompts, max_new, budget=True):
-    """Every window dispatch's ``fed`` and the requests' tokens, under
-    greedy sampling on a fake clock; ``budget=False`` plans as an engine
-    without packed programs is planned."""
-    if not budget:
-        engine.window_budget = lambda rung, step_len: None
-    sched = DecodeScheduler(engine, clock=FakeClock(), prefill_chunk=S,
-                            prefix_store=None)
-    feds, first = [], {}
-    for rung in engine.ladder:
-        drv = engine.driver(rung)
-
-        def step(tokens, fed=None, now=None, _step=drv.step, _drv=drv):
-            out = _step(tokens, fed=fed, now=now)
-            if np.asarray(tokens).shape[1:] == (S,):
-                feds.append((None if fed is None else list(map(int, fed)),
-                             _drv.last_program_rows))
-            return out
-
-        drv.step = step
-    handles = [sched.submit(p, max_new_tokens=max_new) for p in prompts]
-    for i, h in enumerate(handles):
-        h.add_token_callback(
-            lambda _h, _t, index, i=i: first.setdefault(i, len(feds))
-            if index == 0 else None)
-    sched.pump()
-    if not budget:
-        del engine.window_budget
-    return feds, [h.result(timeout=0).tolist() for h in handles], \
-        [first[i] for i in range(len(handles))], sched
-
-
-@pytest.mark.parametrize("block", ["evabyte", "axk1", "gpt2", "olmoe"])
-def test_the_scheduler_plans_inside_the_budget_oldest_first(block):
-    rs = np.random.RandomState(7)
-    vocab = cases.config(block)["vocab_size"]
-    # three prompts of three chunks and a short one, admitted together
-    prompts = [rs.randint(0, vocab, n) for n in (40, 40, 40, 5)]
-    engine = _engine(block, f"pack-{block}")
-    assert engine.window_budget(SLOTS, S) == R
-    assert engine.window_budget(1, S) is None
-    feds, tokens, first, sched = _serve(engine, prompts, max_new=6)
-    windows = [(fed, ran) for fed, ran in feds if fed is not None]
-    assert windows and len(windows) == len(feds)
-    for fed, ran in windows:
-        assert sum(fed) <= R and ran == R       # never the whole window
-    # the oldest prefilling slot takes its whole chunk, the next what is
-    # left of the budget, the others wait their turn
-    assert windows[0][0] == [S, R - S, 0, 0]
-    # oldest first: equal prompts reach their first token in the order
-    # they were admitted, and nobody starves
-    assert first[0] < first[1] < first[2]
-    assert [len(t) for t in tokens] == [6] * 4
-    assert sched.stats()["compiles_since_warmup"] == 0
-    # the counters: real rows over the rows the programs ran
-    real = mx.telemetry.get_metric("serve.decode.window.real_rows",
-                                   model=engine.name).value
-    ran = mx.telemetry.get_metric("serve.decode.window.program_rows",
-                                  model=engine.name).value
-    heads = mx.telemetry.get_metric("serve.decode.window.head_rows",
-                                    model=engine.name).value
-    assert real == sum(sum(fed) for fed, _ in windows)
-    assert ran == R * len(windows)
-    # every window selected in front of its head: a row a slot
-    assert heads == SLOTS * len(windows)
-
-    # the same requests planned without a budget (every active slot
-    # min(S, remaining) a window): the same tokens, in fewer and wider
-    # windows
-    feds0, tokens0, _first, _sched = _serve(
-        _engine(block, f"pack-{block}-whole"), prompts, max_new=6,
-        budget=False)
-    assert tokens0 == tokens
-    assert feds0[0][0] == [S, S, S, 5] and feds0[0][1] == SLOTS * S
-    assert len(feds0) < len(windows)
-    # a window past the budget runs its head over every row
-    whole = f"pack-{block}-whole"
-    assert mx.telemetry.get_metric("serve.decode.window.head_rows",
-                                   model=whole).value \
-        == mx.telemetry.get_metric("serve.decode.window.program_rows",
-                                   model=whole).value > 0
-
-
-def test_an_engine_without_fed_is_planned_as_before():
-    """A graph built by hand without the input: every active slot is
-    fed its chunk, every cursor advances by S and the slots that fed
-    fewer are rewound after the window."""
-    gen = lambda s: cases.unfed_symbol("gpt2", s)       # noqa: E731
-    engine = _engine("gpt2", "pack-plain", gen=gen)
-    assert not engine.feeds
-    assert engine.window_budget(SLOTS, S) is None
-    assert not [k for k in engine._window_mods if len(k) == 3]
-    sched = DecodeScheduler(engine, clock=FakeClock(), prefill_chunk=S,
-                            prefix_store=None)
-    rs = np.random.RandomState(0)
-    handles = [sched.submit(rs.randint(0, 32, n), max_new_tokens=3)
-               for n in (40, 40, 20, 5)]
-    with sched._lock:
-        sched._admit_locked(0.0)
-        plan = sched._plan_window(S, sched._cursors()[0])
-    assert [(row, n) for row, _seq, n in plan] == [(0, S), (1, S), (2, S),
-                                                   (3, 5)]
-    rewound = _spy_on_rewinds(engine)
-    sched.pump()
-    assert [len(h.result(timeout=0)) for h in handles] == [3] * 4
-    assert rewound and all(pos for _rows, positions in rewound
-                           for pos in positions)
-    ran = mx.telemetry.get_metric("serve.decode.window.program_rows",
-                                  model="pack-plain").value
-    windows = mx.telemetry.get_metric("serve.decode.prefill.chunks",
-                                      model="pack-plain").value
-    assert ran % (SLOTS * S) == 0 and ran and windows
-
-
-def _spy_on_rewinds(engine):
-    """Every ``rewind_many`` of every rung's driver from here on:
-    ``[(rows, positions)]`` of the calls that moved a cursor."""
-    calls = []
-    for rung in engine.ladder:
-        drv = engine.driver(rung)
-
-        def rewind_many(rows, positions, _inner=drv.rewind_many):
-            if len(rows):
-                calls.append((list(rows), list(positions)))
-            _inner(rows, positions)
-
-        drv.rewind_many = rewind_many
-    return calls
-
-
-@pytest.mark.parametrize("block", sorted(cases.FUSED))
-def test_a_fed_graph_serves_the_tokens_of_the_graph_it_was(block):
-    """ISSUE 47: the slot-pooled GPT-2 and OLMoE graphs take ``fed``.
-    The same requests through the fed graph (windows planned inside the
-    budget, cursors advanced by ``fed``) and through the graph as it
-    was, built by hand (every slot S rows, the riders rewound): the
-    same tokens, request by request, and the fed engine rewinds nobody."""
-    rs = np.random.RandomState(47)
-    vocab = cases.config(block)["vocab_size"]
-    prompts = [rs.randint(0, vocab, n) for n in (40, 23, 5, 1, 17)]
-    streams = {}
-    for kind, gen in (("fed", lambda s: cases.symbol(block, s)),
-                      ("unfed", lambda s: cases.unfed_symbol(block, s))):
-        engine = _engine(block, f"was-{block}-{kind}", gen=gen)
-        assert engine.feeds == (kind == "fed")
-        sched = DecodeScheduler(engine, clock=FakeClock(), prefill_chunk=S,
-                                prefix_store=None)
-        rewound = _spy_on_rewinds(engine)
-        handles = [sched.submit(p, max_new_tokens=6) for p in prompts[:3]]
-        sched.pump(max_iterations=2)
-        handles += [sched.submit(p, max_new_tokens=6) for p in prompts[3:]]
-        sched.pump()
-        streams[kind] = [h.result(timeout=0).tolist() for h in handles]
-        assert bool(rewound) == (kind == "unfed")
-        assert sched.stats()["compiles_since_warmup"] == 0
-    assert streams["fed"] == streams["unfed"]
-    assert [len(t) for t in streams["fed"]] == [6] * 5
-
-
-@pytest.mark.parametrize("block", ["gpt2", "olmoe"])
-def test_a_draft_shadows_a_window_with_the_targets_fed(block):
-    """A draft engine is stepped with the target's ``fed``: a window of
-    a drafted scheduler is planned inside the budget and runs the packed
-    program of both engines, both cursors advance by the real tokens
-    alone and neither is rewound after it. The verify window of a
-    speculative iteration feeds every slot all K rows, which is the
-    whole-window program's: the scheduler compiled it before the
-    engine's warm-up, which compiles the packed form of that length in
-    its place, so nothing compiles in steady state. The tokens are the
-    undrafted scheduler's."""
-    K = 4
-    gen = lambda s: cases.symbol(block, s)              # noqa: E731
-
-    def engine(name):
-        return DecodeEngine(name, gen(1), cases.params(block),
-                            capacity=cases.CAPACITY, ladder=[SLOTS],
-                            symbol_gen=gen, window_lens=[S, K])
-
-    rs = np.random.RandomState(48)
-    vocab = cases.config(block)["vocab_size"]
-    prompts = [rs.randint(0, vocab, n) for n in (40, 23, 5, 17)]
-    plain = DecodeScheduler(engine(f"undrafted-{block}"), clock=FakeClock(),
-                            prefill_chunk=S, prefix_store=None)
-    want = [plain.submit(p, max_new_tokens=9) for p in prompts]
-    plain.pump()
-
-    target, draft = engine(f"drafted-{block}"), engine(f"draft-{block}")
-    assert target.window_budget(SLOTS, S) == R
-    assert target.window_budget(SLOTS, K) == 8      # a packed verify form
-    sched = DecodeScheduler(target, clock=FakeClock(), draft_engine=draft,
-                            prefill_chunk=S, spec_k=K, prefix_store=None)
-    rewound = _spy_on_rewinds(target) + _spy_on_rewinds(draft)
-    drv, ddrv = target.driver(SLOTS), draft.driver(SLOTS)
-    mx.telemetry.flightrec.configure(capacity=4096)
-    handles = [sched.submit(p, max_new_tokens=9) for p in prompts]
-    modes = []
-    while not all(h.done() for h in handles):
-        assert sched.pump(max_iterations=1) == 1
-        rec = [r for r in mx.telemetry.flightrec.get_records()
-               if r.get("kind") == "serve.decode.step"
-               and r.get("model") == target.name][-1]
-        modes.append((rec["mode"], rec["window"]))
-        if modes[-1] == ("window", S):
-            assert drv.last_program_rows == ddrv.last_program_rows == R
-            assert not rewound
-        elif rec["mode"] == "spec":
-            assert drv.last_program_rows == SLOTS * K
-        live = [seq.slot for seq in sched._active()]
-        np.testing.assert_array_equal(drv.pos[live], ddrv.pos[live])
-    assert modes.count(("window", S)) >= 3 and modes.count(("spec", K)) >= 1
-    assert target.compiles_since_warmup() == 0
-    assert draft.compiles_since_warmup() == 0
-    assert draft.backend_compiles_since_warmup() == 0
-    assert [h.result(timeout=0).tolist() for h in handles] == \
-        [h.result(timeout=0).tolist() for h in want]
-
-
-def test_a_draft_built_without_fed_is_refused_beside_a_fed_target():
-    """One plan names each slot's real tokens to both engines or to
-    neither: a pair of which one graph takes ``fed`` and the other was
-    built by hand without would need a rewind of its own after every
-    window."""
-    unfed = lambda s: cases.unfed_symbol("gpt2", s)     # noqa: E731
-    for kinds in ((None, unfed), (unfed, None)):
-        target, draft = (
-            _engine("gpt2", f"alike-{i}-{gen is None}", ladder=[SLOTS],
-                    gen=gen) for i, gen in enumerate(kinds))
-        with pytest.raises(mx.base.MXNetError, match="stepped alike"):
-            DecodeScheduler(target, clock=FakeClock(), draft_engine=draft,
-                            prefill_chunk=S, spec_k=S, prefix_store=None)
-
-
-#: eight slots of one window: two prefilling, five riding, one idle
-_PLAN8 = [12, 7, 1, 1, 1, 1, 1, 0]
-
-
-@pytest.mark.parametrize("block", sorted(cases.FUSED))
-def test_a_packed_window_equals_the_whole_window_and_s1_steps(block):
-    """The plan of a serving window at rung 8 - two prefilling slots,
-    five riders, one idle slot, 24 rows of 128 real - through the
-    packed program, through the whole-window program fed the same,
-    token by token through the S = 1 program, and through the graph as
-    it was before ISSUE 47 (no ``fed``: every cursor advances by S and
-    is rewound): the same logits on every real row - of the packed
-    program, which hands back a row a slot, on each slot's last -
-    within the block's tolerance, the same greedy tokens, the same
-    cursors, and the same logits from the S = 1 step after."""
-    slots, fed = len(_PLAN8), np.asarray(_PLAN8)
-    assert tfm.packed_rows(slots, S) == int(fed.sum()) == 24
-    rs = np.random.RandomState(8)
-    vocab = cases.config(block)["vocab_size"]
-    tokens = rs.randint(0, vocab, (slots, 2 + S + 1))
-    start = np.asarray([2, 1, 2, 0, 1, 2, 0, 1])
-
-    def walk(drv, window):
-        """Slots joined at staggered cursors, the window, one more
-        S = 1 step: the window's real rows, the cursors after it, the
-        step's logits."""
-        for slot in range(slots):
-            drv.join(slot)
-        for step in range(2):
-            drv.step(tokens[:, step], **(
-                {"fed": (start > step).astype(np.int64)} if drv.feeds
-                else {}))
-        if not drv.feeds:
-            drv.rewind_many(list(range(slots)), start)
-        out = window(drv)
-        after = drv.pos.copy()
-        live = fed > 0
-        nxt = drv.step(tokens[:, -1], **(
-            {"fed": live.astype(np.int64)} if drv.feeds else {})).asnumpy()
-        return out, after, nxt[live]
-
-    def by_window(drv):
-        out = drv.step(tokens[:, 2:2 + S], fed=fed).asnumpy()
-        if out.shape[1] == 1:       # the packed program's row a slot
-            return [out[slot, :min(n, 1)] for slot, n in enumerate(fed)]
-        return [out[slot, :n] for slot, n in enumerate(fed)]
-
-    def by_steps(drv):
-        rows = [[] for _ in range(slots)]
-        for t in range(int(fed.max())):
-            out = drv.step(tokens[:, 2 + t],
-                           fed=(fed > t).astype(np.int64)).asnumpy()
-            for slot in np.nonzero(fed > t)[0]:
-                rows[slot].append(out[slot, 0])
-        return [np.asarray(r).reshape(-1, vocab) for r in rows]
-
-    def as_it_was(drv):
-        out = drv.step(tokens[:, 2:2 + S]).asnumpy()
-        drv.rewind_many(list(range(slots)), start + fed)
-        return [out[slot, :n] for slot, n in enumerate(fed)]
-
-    packed = cases.driver(block, slots=slots)
-    whole = cases.driver(block, packed=False, slots=slots)
-    unfed = cases.unfed_driver(block, slots=slots)
-    got = {"packed": walk(packed, by_window), "whole": walk(whole, by_window),
-           "steps": walk(cases.driver(block, slots=slots), by_steps),
-           "as_it_was": walk(unfed, as_it_was)}
-    assert packed.last_program_rows == whole.last_program_rows == slots
-    assert packed.window_budget(S) == 24 and whole.window_budget(S) is None
-    want_rows, want_pos, want_next = got.pop("as_it_was")
-    np.testing.assert_array_equal(want_pos, start + fed)
-    # against the S = 1 program a window differs by the order of its
-    # sums, the packed program's head (over 8 rows) by the block's bit
-    tol = {"packed": TOL[block], "whole": 0.0, "steps": 2e-5}
-    for form, (rows, pos, nxt) in got.items():
-        np.testing.assert_array_equal(pos, want_pos, err_msg=form)
-        for slot, n in enumerate(fed):
-            # of the packed program each slot's last fed row alone
-            want = want_rows[slot][-1:] if form == "packed" \
-                else want_rows[slot]
-            assert rows[slot].shape == want.shape
-            np.testing.assert_allclose(rows[slot], want, rtol=0,
-                                       atol=tol[form], err_msg=form)
-            np.testing.assert_array_equal(
-                rows[slot].argmax(-1), want.argmax(-1), form)
-        np.testing.assert_allclose(nxt, want_next, rtol=0, atol=tol[form],
-                                   err_msg=form)
-
-
-@pytest.mark.parametrize("block", ["gpt2_rotary", "olmoe"])
-def test_two_chunks_and_the_riders_fit_one_window_at_rung_8(block):
-    """The Cerebras and OLMoE cells' shapes, 8 slots of 64 rows: the
-    budget is the 256 rows a weight-bound matmul carries for free, so
-    two prefilling slots get a whole chunk each beside six riders in
-    one packed window; nobody is rewound after it; the second and the
-    third such window are launched before their predecessor's ids are
-    on the host (ISSUE 53: the chunks from the host, the riders' tokens
-    from the chip), and so is the first S = 1 step behind the last
-    (ISSUE 46), with no rewind before it. The synchronous order runs
-    the same windows and serves the same tokens. The graph as it
-    was (no ``fed``) serves them too, launches no window ahead, rewinds
-    the riders before that step is launched and not again at the
-    commit."""
-    chunk, slots, capacity = 64, 8, 320
-    kw = dict(cases.config(block), capacity=capacity, per_slot=True)
-    gens = {"fed": lambda s: tfm.get_decode_symbol(step_len=s, **kw),
-            "unfed": lambda s: cases.unfed_symbol(block, s,
-                                                  capacity=capacity)}
-    rs = np.random.RandomState(64)
-    vocab = kw["vocab_size"]
-    riders = [rs.randint(0, vocab, n) for n in (3, 4, 5, 3, 4, 5)]
-    long = [rs.randint(0, vocab, 150) for _ in range(2)]
-    mx.telemetry.flightrec.configure(capacity=4096)
-    streams = {}
-    for kind, order in (("fed", "ahead"), ("fed", "sync"),
-                        ("unfed", "ahead")):
-        name = f"rung8-{block}-{kind}"
-        if order == "sync":
-            # the same scheduler, every dispatch planned after its
-            # predecessor's commit
-            sched._plan_ahead = lambda d, now: None
-        else:
-            gen = gens[kind]
-            engine = DecodeEngine(name, gen(1), cases.params(block),
-                                  capacity=capacity, ladder=[slots],
-                                  symbol_gen=gen, window_lens=[chunk])
-            assert engine.window_budget(slots, chunk) == \
-                (256 if kind == "fed" else None)
-            sched = DecodeScheduler(engine, clock=FakeClock(),
-                                    prefill_chunk=chunk, prefix_store=None)
-        drv = engine.driver(slots)
-        windows = []
-
-        def step(tokens, fed=None, now=None, _step=type(drv).step,
-                 _drv=drv):
-            out = _step(_drv, tokens, fed=fed, now=now)
-            if tokens.shape[1:] == (chunk,):
-                windows.append((None if fed is None else sorted(fed),
-                                _drv.last_program_rows))
-            return out
-
-        drv.step = step
-        n_rec = sched.iterations
-        handles = [sched.submit(p, max_new_tokens=30) for p in riders]
-        sched.pump(max_iterations=3)             # the riders decode
-        rewound = _spy_on_rewinds(engine)
-        handles += [sched.submit(p, max_new_tokens=4) for p in long]
-        sched.pump()
-        streams[kind, order] = [h.result(timeout=0).tolist()
-                                for h in handles]
-        ring = [r for r in mx.telemetry.flightrec.get_records()
-                if r["kind"] == "serve.decode.step" and r["model"] == name
-                and r["iter"] >= n_rec]
-        last = max(i for i, r in enumerate(ring) if r["window"] == chunk)
-        ahead = [r["ahead"] for r in ring if r["window"] == chunk]
-        assert sched.stats()["compiles_since_warmup"] == 0
-        if order == "sync":
-            assert not any(r["ahead"] for r in ring)
-        else:
-            # the step behind the last window was launched ahead of
-            # its ids
-            assert ring[last + 1]["window"] == 1 and \
-                ring[last + 1]["ahead"] == 1
-        if kind == "fed":
-            assert not rewound
-            assert windows[1:] == [([1] * 6 + [64, 64], 256)] * 2 \
-                + [([1] * 6 + [22, 22], 256)]
-            # the long prompts are admitted while an S = 1 step is on
-            # the chip, their first window launched behind it; the two
-            # behind that need no id's value either
-            assert ahead[1:] == [int(order == "ahead")] * 3
-        else:
-            # three windows, the riders back by 63 after each, the long
-            # prompts by 42 after the last: once a window
-            assert [len(rows) for rows, _pos in rewound] == [6, 6, 8]
-            assert windows[1:] == [(None, slots * chunk)] * 3
-            assert not any(ahead)
-    assert sched.stats()["runahead"]["windows"] == 0
-    assert streams["fed", "ahead"] == streams["fed", "sync"] \
-        == streams["unfed", "ahead"]
-    assert [len(t) for t in streams["fed", "ahead"]] == [30] * 6 + [4] * 2
-
-
-def test_a_fed_graph_bound_without_fed_is_refused():
-    """``fed`` left among the parameters would stay what it was set to
-    and every step would advance the slots by that."""
-    sym = cases.symbol("gpt2_rotary", 1)
-    mod = mx.mod.Module(sym, data_names=["data"], label_names=[])
-    mod.bind([mx.io.DataDesc("data", (SLOTS, 1), np.int32)], None,
-             for_training=False)
-    with pytest.raises(mx.base.MXNetError, match="bind it as data"):
-        tfm.BatchedKVCacheDecoder(mod, cases.CAPACITY, slots=SLOTS)
-
-
 # ------------------------------------------- what a dispatch reads, counted
 def _counts_script(block):
     """One fixed script through the scheduler for any block: three
@@ -853,10 +417,7 @@ def _counts_script(block):
     ring record says beside its times, and the bytes that one S = 1
     step of every rung takes over."""
     name = f"counts-{block}"
-    gen = lambda s: cases.symbol(block, s)          # noqa: E731
-    engine = DecodeEngine(name, gen(1), cases.params(block),
-                          capacity=cases.CAPACITY, ladder=[1, SLOTS],
-                          symbol_gen=gen, window_lens=[S])
+    engine = cases.engine(block, name, ladder=[1, SLOTS])
     sched = DecodeScheduler(engine, clock=FakeClock(), prefill_chunk=S,
                             prefix_store=None)
     mx.telemetry.flightrec.clear()

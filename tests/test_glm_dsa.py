@@ -1,227 +1,58 @@
 """GLM-5.2's block behind the serving path (ops/mla.py, the share of
 ops/moe.py, block="glm_dsa" of models/transformer.py, the selection
-reads of BatchedKVCacheDecoder and serve/decode.py) against the plain
-reference chipbench/reference/glm_dsa.py, at small widths on the CPU:
-``index_topk`` 16 and contexts of some 80 positions, so that the
-selection drops most keys; three layers (dense + full, sparse + full,
-sparse + shared) holding experts 4-7 of 16. Sixteen index heads: with
-four, one key in sixteen scores exactly 0 (every head's dot product
-negative under the relu), the 16th largest is often one of several
-zeros, and the two sides then differ by their rule for ties (the served
-path keeps all, the reference's sort the lowest positions)."""
-import os
-import sys
-
+reads of BatchedKVCacheDecoder and serve/decode.py). What every served
+block does is ``tests/decode_block_suite.py``'s, over the row
+``glm_dsa`` of ``tests/decode_blocks.py`` against the plain reference
+chipbench/reference/glm_dsa.py: ``index_topk`` 16 and contexts of some
+80 positions, so that the selection drops most keys; three layers
+(dense + full, sparse + full, sparse + shared) holding experts 4-7 of
+16. Sixteen index heads: with four, one key in sixteen scores exactly 0
+(every head's dot product negative under the relu), the 16th largest is
+often one of several zeros, and the two sides then differ by their rule
+for ties (the served path keeps all, the reference's sort the lowest
+positions). Below that the block's own: the selection that matters,
+both pools' cursors, what the selection reads, the two ops, a shared
+layer, bfloat16 serving against the dense control."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu import kernel_tier
 from mxnet_tpu.models import transformer as tfm
 from mxnet_tpu.ops import mla
 from mxnet_tpu.ops.registry import get_op
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import decode_blocks as blocks
+from decode_blocks import CAPACITY, SLOTS, WINDOW
+from decode_block_suite import *  # noqa: F401,F403
 
-from chipbench.reference import glm_dsa as ref  # noqa: E402
 import mla_window_cases  # noqa: E402
-# the quick cases of the benchmark's own tests of the architecture file
-# run here as they stand (its CPU rehearsals stay by hand)
-from chipbench.tests.test_glm_dsa import (  # noqa: E402,F401
-    test_both_controls_are_further_than_the_emulation,
-    test_costs_against_a_count_by_hand)
 
-CFG = {"vocab_size": 48, "hidden_size": 64, "num_attention_heads": 4,
-       "num_hidden_layers": 3, "q_lora_rank": 48, "kv_lora_rank": 64,
-       "qk_nope_head_dim": 24, "qk_rope_head_dim": 16, "v_head_dim": 32,
-       "index_n_heads": 16, "index_head_dim": 32, "index_topk": 16,
-       "indexer_types": ["full", "full", "shared"],
-       "first_k_dense_replace": 1, "intermediate_size": 96,
-       "moe_intermediate_size": 32, "n_routed_experts": 16,
-       "num_experts_per_tok": 4, "n_shared_experts": 1,
-       "routed_scaling_factor": 2.5, "norm_topk_prob": True,
-       "n_routed_experts_held": 4, "held_first": 4,
-       "rope_parameters": {"rope_theta": 8000000}, "rms_norm_eps": 1e-5}
-CAPACITY, WINDOW, SLOTS = 128, 16, 3            # WINDOW: the S > 1 program
-#: float32 served against the float32 reference through 3 layers, on
-#: logits of magnitude about 2 (measured here: 2e-6 to 6e-6)
-TOL = 5e-5
+BLOCK = "glm_dsa"
+TOL = blocks.TOL[BLOCK]
+_W = (WINDOW, [WINDOW] * SLOTS)
+_IDLE = [0] * (SLOTS - 3)
 
 
-def _glm(held=None):
-    glm = {k: CFG[k] for k in tfm.GLM_KEYS}
-    glm["held"] = held or (CFG["held_first"], CFG["n_routed_experts_held"])
-    return glm
-
-
-def _symbol(step_len, capacity=CAPACITY):
-    return tfm.get_decode_symbol(
-        vocab_size=CFG["vocab_size"], d_model=CFG["hidden_size"],
-        n_layer=CFG["num_hidden_layers"],
-        n_head=CFG["num_attention_heads"], pos_embed="rotary",
-        rope_base=8e6, capacity=capacity, step_len=step_len, per_slot=True,
-        block="glm_dsa", rms_eps=CFG["rms_norm_eps"], tie_head=False,
-        embed_scale=False, glm=_glm())
-
-
-def _params(seed=5):
-    symbol = _symbol(1)
-    shapes, _, _ = symbol.infer_shape(data=(SLOTS, 1), fed=(SLOTS,))
-    rng = np.random.default_rng(seed)
-    out = {}
-    for name, shape in zip(symbol.list_arguments(), shapes):
-        if name in ("data", "fed"):
-            continue
-        draw = rng.standard_normal(shape)
-        if name.endswith(("_gamma", "_kv_norm_weight")):
-            draw = 1.0 + 0.3 * draw
-        out[name] = (draw if "gamma" in name or "norm_weight" in name
-                     else 0.25 * draw).astype(np.float32)
-    return out
-
-
-PARAMS = _params()
-
-
-def _bound(step_len, shared=None, slots=SLOTS, params=None, dtype=None):
-    mod = mx.mod.Module(_symbol(step_len), data_names=("data", "fed"),
-                        label_names=[], compute_dtype=dtype)
-    mod.bind([mx.io.DataDesc("data", (slots, step_len), np.int32),
-              mx.io.DataDesc("fed", (slots,), np.int32)],
-             None, for_training=False, shared_module=shared)
-    if shared is None:
-        mod.init_params(initializer=None,
-                        arg_params=dict(params or PARAMS), aux_params={},
-                        allow_missing=True)
-    return mod
-
-
-def _tier(name):
-    old = os.environ.get("MXNET_KERNEL_TIER")
-    os.environ["MXNET_KERNEL_TIER"] = name
-    kernel_tier.clear()
-    return old
-
-
-def _restore(old):
-    if old is None:
-        os.environ.pop("MXNET_KERNEL_TIER", None)
-    else:
-        os.environ["MXNET_KERNEL_TIER"] = old
-    kernel_tier.clear()
-
-
-@pytest.fixture(scope="module", params=["xla", "pallas"])
-def driver(request):
-    """A three-slot pool with its S = 16 window program under one
-    kernel tier (the Pallas kernels in interpret mode)."""
-    old = _tier(request.param)
-    base = _bound(1)
-    drv = tfm.BatchedKVCacheDecoder(base, CAPACITY, slots=SLOTS)
-    drv.add_window(WINDOW, _bound(WINDOW, shared=base))
-    yield drv
-    _restore(old)
-
-
-def _reference(seqs, **kw):
-    fwd = jax.jit(lambda p, t: ref.forward(p, t, CFG, **kw))
-    return np.asarray(fwd(PARAMS, jnp.asarray(seqs)))
-
-
-def _reference_one(seq):
-    return np.asarray(ref.forward(PARAMS, jnp.asarray(seq)[None], CFG))[0]
-
-
-def _run(drv, seqs, schedule, start=None):
-    """Feed ``seqs`` (slots, T) through ``schedule``, a list of (S, fed
-    counts a slot): the logits of every fed position, (slots, T, V)."""
-    if start is None:
-        for slot in range(drv.slots):
-            if drv.active[slot]:
-                drv.leave(slot)
-            drv.join(slot)
-        start = [0] * drv.slots
-    got = np.zeros(seqs.shape + (CFG["vocab_size"],), np.float32)
-    at = np.asarray(start)
-    for S, fed in schedule:
-        tokens = np.full((drv.slots, S), 7, np.int32)
-        for slot, n in enumerate(fed):
-            tokens[slot, :n] = seqs[slot, at[slot]:at[slot] + n]
-        out = drv.step(tokens, fed=fed).asnumpy()
-        for slot, n in enumerate(fed):
-            got[slot, at[slot]:at[slot] + n] = out[slot, :n]
-        at = at + np.asarray(fed)
-        assert list(drv.pos) == list(at)
-    return got, at
-
-
-def _seqs(T, seed=1):
-    return np.random.default_rng(seed).integers(
-        0, CFG["vocab_size"], (SLOTS, T)).astype(np.int32)
-
-
-def test_prefill_in_windows_then_decode_equals_the_reference(driver):
+def test_the_selection_matters_at_these_positions(driver):
     """Four windows and sixteen S = 1 steps, 80 positions, of which a
-    query attends 16: the cache, both lowerings of the selection and of
-    the attention, the share of the experts."""
-    seqs = _seqs(80)
-    got, at = _run(driver, seqs, [(WINDOW, [WINDOW] * SLOTS)] * 4
-                   + [(1, [1] * SLOTS)] * 16)
+    query attends 16: the served logits are the reference's, and the
+    reference without the selection is not correct."""
+    seqs = blocks.seqs(BLOCK, 80)
+    got, at, _ = blocks.run(driver, seqs, [_W] * 4 + [(1, [1] * SLOTS)] * 16)
     assert list(at) == [80] * SLOTS
-    want = _reference(seqs)
+    want = blocks.reference(BLOCK, seqs)
     assert np.max(np.abs(want)) > 0.5
     np.testing.assert_allclose(got, want, atol=TOL, rtol=TOL)
-    # the selection matters at these positions: without it, not correct
-    dense = _reference(seqs, select=False)
+    dense = blocks.reference(BLOCK, seqs, select=False)
     assert np.max(np.abs(dense[:, 40:] - want[:, 40:])) > 100 * TOL
 
 
-def test_ragged_slots_and_fed_keep_both_pools_right(driver):
-    """Slots at their own lengths, windows that feed 16, 5 and 0 real
-    tokens, a slot that decodes while another prefills: every fed
-    position equals the reference, the cursors of both pools move by
-    ``fed`` alone."""
-    seqs = _seqs(60, seed=2)
-    schedule = [(WINDOW, [16, 5, 0]), (1, [1, 1, 1]), (WINDOW, [16, 16, 9]),
-                (WINDOW, [1, 16, 16]), (1, [1, 0, 1]), (WINDOW, [7, 3, 16])]
-    got, at = _run(driver, seqs, schedule)
-    want = _reference(seqs)
-    for slot in range(SLOTS):
-        np.testing.assert_allclose(got[slot, :at[slot]],
-                                   want[slot, :at[slot]], atol=TOL, rtol=TOL)
-    exe = driver._mod._exec_group.executor
-    for name in driver._state["cursor"]:
-        assert list(exe.aux_dict[name].asnumpy().ravel()) == list(at), name
-    assert sorted(driver._state) == ["cursor", "rows"]
-    assert len(driver._state["rows"]) == 5      # 3 latent + 2 index pools
-
-
-def test_leave_join_and_rewind_reuse_a_slot(driver):
-    """A slot that leaves and joins again attends nothing of its old
-    rows; a positional rewind (both pools are a row per position) puts
-    a slot back where the reference is."""
-    seqs = _seqs(48, seed=3)
-    _run(driver, _seqs(48, seed=4), [(WINDOW, [WINDOW] * SLOTS)] * 3)
-    got, at = _run(driver, seqs, [(WINDOW, [WINDOW] * SLOTS)] * 2)
-    driver.rewind_many([0, 2], [20, 32])
-    more, at = _run(driver, seqs, [(WINDOW, [16, 0, 16])],
-                    start=[20, 32, 32])
-    want = _reference(seqs)
-    np.testing.assert_allclose(got[:, :32], want[:, :32], atol=TOL, rtol=TOL)
-    np.testing.assert_allclose(more[0, 20:36], want[0, 20:36], atol=TOL,
-                               rtol=TOL)
-    np.testing.assert_allclose(more[2, 32:48], want[2, 32:48], atol=TOL,
-                               rtol=TOL)
-
-
 def test_selection_reads_are_counted_from_the_cursors(driver):
-    _run(driver, _seqs(40), [(WINDOW, [16, 16, 8])] * 2)
-    driver.step(np.zeros((SLOTS, 1), np.int32), fed=[1, 0, 1])
+    blocks.run(driver, blocks.seqs(BLOCK, 40),
+               [(WINDOW, [16, 16, 8] + _IDLE)] * 2)
+    driver.step(np.zeros((SLOTS, 1), np.int32), fed=[1, 0, 1] + _IDLE)
     # slots at 32, 32, 16 fed 1, 0, 1: last queries see 33 and 17 keys
     layers, indexed, topk = 3, 2, 16
     assert driver.last_reads == {
@@ -230,7 +61,7 @@ def test_selection_reads_are_counted_from_the_cursors(driver):
         "dsa.scored_rows": indexed * (33 + 17)}
     assert driver.read_counts["dsa.selected_rows"] == (
         "dsa.selected_rows", "dsa_selected")
-    assert driver.positional and driver.feeds
+    assert len(driver._state["rows"]) == 5      # 3 latent + 2 index pools
 
 
 # ----------------------------------------------------------- the two ops
@@ -319,7 +150,7 @@ def test_a_shared_layer_attends_the_set_its_full_layer_chose():
     """The graph hands layer 1's selection to layer 2: one
     ``dsa_index_select`` output feeds both ``mla_attention_decode``
     nodes, and layer 2 has no indexer parameters and no index pool."""
-    symbol = _symbol(4)
+    symbol = blocks.symbol(BLOCK, 4)
     consumers = {}
     for node in symbol._topo_nodes():
         if not node.is_variable and node.op == "mla_attention_decode":
@@ -350,25 +181,20 @@ def test_bfloat16_serving_is_inside_a_bound_the_dense_control_is_not():
     the logits stay within bfloat16's rounding of the float32 reference
     through the cache, and the reference WITHOUT the selection is an
     order of magnitude further away."""
-    old = _tier("pallas")
-    try:
-        import ml_dtypes
-        params = {k: v.astype(ml_dtypes.bfloat16) for k, v in PARAMS.items()}
-        base = _bound(1, params=params, dtype="bfloat16")
-        drv = tfm.BatchedKVCacheDecoder(base, CAPACITY, slots=SLOTS)
-        drv.add_window(WINDOW, _bound(WINDOW, shared=base, dtype="bfloat16"))
-        exe = base._exec_group.executor
+    import ml_dtypes
+    params = {k: v.astype(ml_dtypes.bfloat16)
+              for k, v in blocks.params(BLOCK).items()}
+    with blocks.tier("pallas"):
+        drv = blocks.driver(BLOCK, packed=False, slots=3, arg_params=params,
+                            dtype="bfloat16")
+        exe = drv._mod._exec_group.executor
         assert str(exe.aux_dict["lm_l0_attn_latent"].dtype) == "bfloat16"
         assert str(exe.aux_dict["lm_l1_idx_index_k"].dtype) == "bfloat16"
-        seqs = _seqs(72, seed=6)
-        got, _ = _run(drv, seqs, [(WINDOW, [WINDOW] * SLOTS)] * 4
-                      + [(1, [1] * SLOTS)] * 8)
-        fwd = jax.jit(lambda p, t, s: ref.forward(p, t, CFG, select=s),
-                      static_argnums=2)
-        want = np.asarray(fwd(params, jnp.asarray(seqs), True))
-        dense = np.asarray(fwd(params, jnp.asarray(seqs), False))
-    finally:
-        _restore(old)
+        seqs = blocks.seqs(BLOCK, 72, seed=6, slots=3)
+        got, _, _ = blocks.run(drv, seqs, [(WINDOW, [WINDOW] * 3)] * 4
+                               + [(1, [1] * 3)] * 8)
+        want = blocks.reference(BLOCK, seqs, params)
+        dense = blocks.reference(BLOCK, seqs, params, select=False)
     # 16 keys of some 50: a key swapped at the threshold by bfloat16's
     # rounding moves a sixteenth of a query's attention, so the worst
     # position says nothing here (at 2,048 keys it does: the chip's
@@ -380,39 +206,19 @@ def test_bfloat16_serving_is_inside_a_bound_the_dense_control_is_not():
 
 
 # -------------------------------------------------- the engine's contract
-def test_engine_migrates_both_pools_across_rungs_and_counts_reads():
-    """``serve_decoder`` over the block: ladder 1, 2, a window of 8;
-    requests of ragged lengths grow the rung (``migrate`` copies every
-    ``rows`` pool and cursor), their tokens equal greedy decoding of
-    the reference, and the selection's counters move."""
+def test_the_selections_counters_move_through_the_scheduler(engine):
+    """Requests of ragged lengths through the scheduler over the
+    suite's engine: the selection's counters move, a share of the
+    assignments lands here, and no ``attention_decode`` layer counts."""
     from mxnet_tpu import telemetry
-    server = mx.serve.serve_decoder(
-        _symbol(1), PARAMS, name="glm-tiny", capacity=CAPACITY,
-        ladder=[1, 2], symbol_gen=_symbol, prefill_chunk=8, start=True)
-    try:
-        rng = np.random.default_rng(9)
-        prompts = [rng.integers(0, CFG["vocab_size"], n).astype(np.int32)
-                   for n in (21, 9, 34)]
-        handles = [server.submit(p, max_new_tokens=6) for p in prompts]
-        answers = [h.result(timeout=300) for h in handles]
-        engine = server.engine if hasattr(server, "engine") \
-            else server._engine
-        assert engine.positional and engine.feeds
-    finally:
-        server.stop()
-    for prompt, answer in zip(prompts, answers):
-        # one forward over prompt + answer: position len(prompt) - 1 + i
-        # predicts the i-th answered token
-        seq = np.concatenate([prompt, np.asarray(answer, np.int32)])
-        logits = _reference_one(seq)
-        for i, tok in enumerate(answer):
-            row = logits[len(prompt) - 1 + i]
-            top = np.sort(row)[-2:]
-            if top[1] - top[0] > 1e-3:            # no rounding-level tie
-                assert int(np.argmax(row)) == tok
+    sched = mx.serve.DecodeScheduler(engine, clock=mx.serve.FakeClock(),
+                                     prefill_chunk=WINDOW, prefix_store=None)
+    rng = np.random.default_rng(9)
+    blocks.served(sched, [rng.integers(0, 48, n).tolist()
+                          for n in (21, 9, 34)], 6)
     counters = {m.name: m.value for m in telemetry.metrics.all_metrics()
                 if isinstance(m, telemetry.Counter)
-                and ("model", "glm-tiny") in m.labels}
+                and ("model", engine.name) in m.labels}
     assert counters["serve.decode.dsa.selected_rows"] \
         < counters["serve.decode.dsa.live_rows"]
     assert counters["serve.decode.dsa.scored_rows"] * 3 \

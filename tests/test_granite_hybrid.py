@@ -1,34 +1,29 @@
 """Granite 4.0-H's block behind the serving path (``block=
 "granite_hybrid"`` of models/transformer.py: Mamba-2 mixers whose state
 is constant in the context - ``ssm_mixer_decode``, ops/ssm.py - beside
-attention layers without positions, the four Granite multipliers)
-against the plain reference chipbench/reference/granite_hybrid.py, at
-small widths on the CPU: three layers (mamba, attention, mamba), 8 heads
-of 8 with a state of 16, a chunk of 8 under a window of 16 - two chunks
-in one dispatch -, 4 query heads on 2 K/V heads of 8 that lie paired in
-one row of 16."""
-import os
-import sys
-
+attention layers without positions, the four Granite multipliers). What
+every served block does is ``tests/decode_block_suite.py``'s, over the
+row ``granite_hybrid`` of ``tests/decode_blocks.py`` against the plain
+reference chipbench/reference/granite_hybrid.py: three layers (mamba,
+attention, mamba), 8 heads of 16 with a state of 16, a chunk of 8 under
+a window of 16 - two chunks in one dispatch -, 4 query heads on 2 K/V
+heads of 16 that lie paired in one row of 32. Below that the block's
+own: the op against its recurrence, a pad, the state's sizes and counts,
+the bfloat16-state control."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu import kernel_tier
-from mxnet_tpu.base import MXNetError
-from mxnet_tpu.models import transformer as tfm
 from mxnet_tpu.ops import ssm
 from mxnet_tpu.ops.registry import get_op
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import decode_blocks as blocks
+from decode_blocks import CAPACITY, SLOTS, WINDOW
+from decode_block_suite import *  # noqa: F401,F403
 
 from chipbench.archs import granite_hybrid as arch  # noqa: E402
-from chipbench.reference import granite_hybrid as ref  # noqa: E402
 # the quick cases of the benchmark's own tests of the architecture file
 # run here as they stand (its CPU rehearsal stays by hand)
 from chipbench.tests.test_granite_hybrid import (  # noqa: E402,F401
@@ -60,197 +55,22 @@ def test_the_traffic_is_the_issues(monkeypatch):
     _bench.test_the_traffic_is_the_issues()
 
 
-CFG = {"vocab_size": 96, "hidden_size": 32, "num_attention_heads": 4,
-       "num_key_value_heads": 2, "num_hidden_layers": 3,
-       "layer_types": ["mamba", "attention", "mamba"],
-       "mamba_n_heads": 8, "mamba_d_head": 8, "mamba_d_state": 16,
-       "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_expand": 2,
-       "mamba_chunk_size": 8, "mamba_conv_bias": True,
-       "mamba_proj_bias": False, "shared_intermediate_size": 48,
-       "num_local_experts": 0, "num_experts_per_tok": 0,
-       "intermediate_size": 48, "position_embedding_type": "nope",
-       "embedding_multiplier": 12.0, "residual_multiplier": 0.22,
-       "attention_multiplier": 0.125, "logits_scaling": 8.0,
-       "rms_norm_eps": 1e-5}
-CAPACITY, WINDOW, SLOTS = 128, 16, 3            # WINDOW: the S > 1 program
-#: float32 served against the float32 reference through 3 layers, on
-#: logits of magnitude about 1 (measured here: 1e-6 to 2e-5; the chunked
-#: form sums in another order than the recurrence)
-TOL = 2e-4
+BLOCK = "granite_hybrid"
+G = blocks.config(BLOCK)["granite"]
+TOL = blocks.TOL[BLOCK]
+_W = blocks.window(*[WINDOW] * SLOTS)
+_fed, _ones = blocks.window, blocks.steps
 
 
-def _symbol(step_len, cfg=CFG, capacity=CAPACITY):
-    return tfm.get_decode_symbol(
-        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-        n_layer=cfg["num_hidden_layers"],
-        n_head=cfg["num_attention_heads"], capacity=capacity,
-        step_len=step_len, per_slot=True, block="granite_hybrid",
-        rms_eps=cfg["rms_norm_eps"],
-        granite={k: cfg[k] for k in tfm.GRANITE_KEYS})
-
-
-def _params(cfg=CFG, seed=5, decay=None, skip=1.0):
-    """Matrices of deviation 0.25, gains about 1; the mixer's own
-    parameters as Mamba-2 draws them (``A_log = log U(1, 16)``, ``dt``
-    of 1e-3 to 1e-1 through the inverse softplus), or with ``decay =
-    (A, dt)`` the same for every head: (1, 1e-3) is a decay of 0.999 a
-    token, a memory of a thousand tokens. ``skip`` is ``D``."""
-    symbol = _symbol(1, cfg)
-    shapes, _, _ = symbol.infer_shape(data=(SLOTS, 1), fed=(SLOTS,))
-    rng = np.random.default_rng(seed)
-    out = {}
-    for name, shape in zip(symbol.list_arguments(), shapes):
-        if name in ("data", "fed"):
-            continue
-        draw = rng.standard_normal(shape)
-        if name.endswith("_gamma"):
-            draw = 1.0 + 0.3 * draw
-        elif name.endswith("_A_log"):
-            draw = np.log(np.full(shape, decay[0]) if decay
-                          else rng.uniform(1, 16, shape))
-        elif name.endswith("_dt_bias"):
-            dt = np.full(shape, decay[1]) if decay else np.exp(
-                rng.uniform(np.log(1e-3), np.log(1e-1), shape))
-            draw = dt + np.log(-np.expm1(-dt))
-        elif name.endswith("_mamba_D"):
-            draw = np.full(shape, skip)
-        else:
-            draw = 0.25 * draw
-        out[name] = draw.astype(np.float32)
-    return out
-
-
-PARAMS = _params()
-
-
-def _bound(symbol, step_len, shared=None, slots=SLOTS, params=PARAMS):
-    mod = mx.mod.Module(symbol, data_names=("data", "fed"), label_names=[])
-    mod.bind([mx.io.DataDesc("data", (slots, step_len), np.int32),
-              mx.io.DataDesc("fed", (slots,), np.int32)],
-             None, for_training=False, shared_module=shared)
-    if shared is None:
-        mod.init_params(initializer=None, arg_params=dict(params),
-                        aux_params={}, allow_missing=True)
-    return mod
-
-
-def _driver(cfg=CFG, params=PARAMS):
-    base = _bound(_symbol(1, cfg), 1, params=params)
-    drv = tfm.BatchedKVCacheDecoder(base, CAPACITY, slots=SLOTS)
-    packed, budget = tfm.packed_window(_symbol(WINDOW, cfg), SLOTS)
-    assert budget == 24
-    drv.add_window(WINDOW, _bound(_symbol(WINDOW, cfg), WINDOW, shared=base),
-                   packed=(_bound(packed, WINDOW, shared=base), budget))
-    return drv
-
-
-@pytest.fixture(scope="module", params=["xla", "pallas"])
-def driver(request):
-    """A three-slot pool with its S = 16 window program, whole and
-    packed (24 rows), under one kernel tier (``ssm_update`` and the
-    attention kernels in interpret mode)."""
-    old = os.environ.get("MXNET_KERNEL_TIER")
-    os.environ["MXNET_KERNEL_TIER"] = request.param
-    kernel_tier.clear()
-    yield _driver()
-    if old is None:
-        os.environ.pop("MXNET_KERNEL_TIER", None)
-    else:
-        os.environ["MXNET_KERNEL_TIER"] = old
-    kernel_tier.clear()
-
-
-def _reference(seqs, cfg=CFG, params=PARAMS, **kw):
-    fwd = jax.jit(lambda p, t: ref.forward(p, t, cfg, **kw))
-    return np.asarray(fwd(params, jnp.asarray(seqs)))
-
-
-def _run(drv, seqs, schedule, start=None, packed=None):
-    """Feed ``seqs`` (slots, T) through ``schedule``, a list of (S, fed
-    counts a slot): the logits of every fed position that a dispatch
-    hands back, (slots, T, V) - all of an S = 1 step's and a
-    whole-window program's, of a packed window's each slot's last fed
-    row alone (ISSUE 51: the others stay NaN; ``_err`` compares what
-    is there) - and the rows each dispatch's program ran over. Every
-    slot joins fresh first, or goes on from ``start``; a pad is a junk
-    token."""
-    if start is None:
-        for slot in range(drv.slots):
-            if drv.active[slot]:
-                drv.leave(slot)
-            drv.join(slot)
-        start = [0] * drv.slots
-    got = np.full(seqs.shape + (CFG["vocab_size"],), np.nan, np.float32)
-    at, rows = np.asarray(start), []
-    for S, fed in schedule:
-        tokens = np.full((drv.slots, S), 7, np.int32)
-        for slot, n in enumerate(fed):
-            tokens[slot, :n] = seqs[slot, at[slot]:at[slot] + n]
-        out = drv.step(tokens, fed=fed).asnumpy()
-        rows.append(drv.last_program_rows)
-        assert out.shape[1] == (S if rows[-1] == drv.slots * S else 1)
-        for slot, n in enumerate(fed):
-            if out.shape[1] == S:
-                got[slot, at[slot]:at[slot] + n] = out[slot, :n]
-            elif n:
-                got[slot, at[slot] + n - 1] = out[slot, 0]
-        at = at + np.asarray(fed)
-        assert list(drv.pos) == list(at)
-    return got, at, rows
-
-
-def _err(got, want):
-    """The largest difference over the positions that ``_run`` holds
-    logits of (at least one)."""
-    held = ~np.isnan(got).any(axis=-1)
-    assert held.any()
-    return np.abs(got[held] - want[held]).max()
-
-
-def _full(n):                       # n full windows for every slot
-    return [(WINDOW, [WINDOW] * SLOTS)] * n
-
-
-def _ones(n, fed=(1,) * SLOTS):
-    return [(1, list(fed))] * n
-
-
-SCHEDULES = {
-    # whole windows (48 rows: the whole-window program, two chunks a
-    # slot a dispatch), then S = 1 through the state
-    "whole_windows_then_decode": _full(3) + _ones(6),
-    # the packed program (at most 24 rows): a chunk and riders, a part
-    # of a chunk beside another, a ragged last chunk, a slot fed nothing
-    "packed_windows_with_riders": [
-        (WINDOW, [16, 1, 1]), (WINDOW, [16, 1, 1]), (WINDOW, [5, 1, 13]),
-        (WINDOW, [1, 11, 9]), (WINDOW, [1, 16, 0]), (WINDOW, [1, 3, 1])]
-    + _ones(4),
-    # two chunks in one dispatch with a ragged second (13 = 8 + 5)
-    "two_chunks_and_a_ragged_last": [
-        (WINDOW, [13, 16, 9]), (WINDOW, [16, 7, 12]), (WINDOW, [2, 1, 3])]
-    + _ones(3, fed=(1, 0, 1)) + [(WINDOW, [9, 2, 1])],
-    # decode first (the state starts by steps), then windows over it
-    "decode_then_windows": _ones(5) + [(WINDOW, [16, 1, 1]),
-                                       (WINDOW, [10, 12, 2])] + _ones(2),
-}
-
-
-@pytest.mark.parametrize("case", sorted(SCHEDULES))
-def test_prefill_and_decode_match_the_reference_full_forward(driver, case):
-    """Every fed position's logits against the plain reference's full
-    forward (the recurrence step by step), within the float32 bound -
-    which is inside the architecture's ``LOGIT_TOL``."""
-    rng = np.random.default_rng(3)
-    seqs = rng.integers(0, CFG["vocab_size"], (SLOTS, 80)).astype(np.int32)
-    got, at, rows = _run(driver, seqs, SCHEDULES[case])
-    want = _reference(seqs)
-    for slot in range(SLOTS):
-        err = _err(got[slot, :at[slot]], want[slot, :at[slot]])
-        assert err <= TOL <= arch.LOGIT_TOL, (case, slot, err)
-    if case == "packed_windows_with_riders":
-        assert rows[:6] == [24] * 6      # the packed program ran them
-    if case == "whole_windows_then_decode":
-        assert rows[:3] == [SLOTS * WINDOW] * 3
+def _decays(decay, skip=1.0):
+    """``draws`` of ``blocks.params`` with ``decay = (A, dt)`` the same
+    for every head: (1, 1e-3) is a decay of 0.999 a token, a memory of
+    a thousand tokens. ``skip`` is ``D``."""
+    dt = decay[1]
+    return {"_A_log": lambda draw, rng: np.log(np.full(draw.shape, decay[0])),
+            "_dt_bias": lambda draw, rng: np.full(
+                draw.shape, dt + np.log(-np.expm1(-dt))),
+            "_mamba_D": lambda draw, rng: np.full(draw.shape, skip)}
 
 
 def test_an_odd_number_of_kv_heads_is_served_unpaired():
@@ -258,19 +78,17 @@ def test_an_odd_number_of_kv_heads_is_served_unpaired():
     or a head of 128 cannot): the pools hold it as it is and the
     queries are not widened - windows, the packed program with riders,
     then S = 1, against the reference."""
-    cfg = dict(CFG, num_key_value_heads=1)
-    params = _params(cfg, seed=8)
-    drv = _driver(cfg, params)
-    assert drv.state_bytes["rows"] == 2 * SLOTS * CAPACITY * 8 * 4
-    rng = np.random.default_rng(14)
-    seqs = rng.integers(0, cfg["vocab_size"], (SLOTS, 80)).astype(np.int32)
-    got, at, rows = _run(drv, seqs, _full(1) + [(WINDOW, [16, 1, 1]),
-                                                (WINDOW, [3, 1, 12])]
-                         + _ones(4))
+    over = {"granite": {"num_key_value_heads": 1}}
+    params = blocks.params(BLOCK, seed=8, **over)
+    drv = blocks.driver(BLOCK, arg_params=params, **over)
+    assert drv.state_bytes["rows"] == 2 * SLOTS * CAPACITY * 16 * 4
+    seqs = blocks.seqs(BLOCK, 80, seed=14)
+    got, at, rows = blocks.run(drv, seqs, [_W, _fed(16), _fed(3, 1, 12)]
+                               + _ones(4))
     assert rows[:3] == [SLOTS * WINDOW, 24, 24]
-    want = _reference(seqs, cfg, params)
+    want = blocks.reference(BLOCK, seqs, params, over)
     for slot in range(SLOTS):
-        assert _err(got[slot, :at[slot]], want[slot, :at[slot]]) <= TOL
+        assert blocks.err(got[slot, :at[slot]], want[slot, :at[slot]]) <= TOL
 
 
 def _op_case(variant, S, fed, packed_rows=None, chunk=8, T=40, seed=0):
@@ -356,28 +174,12 @@ def test_the_chunked_form_is_the_recurrence(variant, layout):
     _op_case(variant, S, fed, packed_rows=rows)
 
 
-def test_a_slot_left_and_joined_again_reads_a_clean_state(driver):
-    """A slot that carried 60 tokens of another sequence serves a new
-    one as a fresh pool does - ``join`` moves the cursor alone and the
-    program reads tail and state as zeros at cursor 0 - both through a
-    window and through S = 1 steps first."""
-    rng = np.random.default_rng(4)
-    old = rng.integers(0, CFG["vocab_size"], (SLOTS, 80)).astype(np.int32)
-    _run(driver, old, _full(3) + _ones(6))
-    seqs = rng.integers(0, CFG["vocab_size"], (SLOTS, 80)).astype(np.int32)
-    got, at, _ = _run(driver, seqs, [(WINDOW, [16, 1, 1])] + _ones(3)
-                      + _full(1))                        # leaves, joins
-    want = _reference(seqs)
-    for slot in range(SLOTS):
-        assert _err(got[slot, :at[slot]], want[slot, :at[slot]]) <= TOL
-
 
 def test_a_pad_advances_nothing(driver):
     """A slot fed nothing, inside a window and in an S = 1 step, keeps
     tail, state and cursor to the bit, whatever tokens ride its rows."""
-    rng = np.random.default_rng(9)
-    seqs = rng.integers(0, CFG["vocab_size"], (SLOTS, 80)).astype(np.int32)
-    _run(driver, seqs, [(WINDOW, [11, 16, 5])])
+    seqs = blocks.seqs(BLOCK, 80, seed=9)
+    blocks.run(driver, seqs, [_fed(11, 16, 5)])
     # (the attention layer writes a window's pads behind the cursor,
     # where the next dispatch writes over them: not its pools)
     carried = lambda: [nc for family in ("conv", "recurrent", "cursor")  # noqa
@@ -385,73 +187,48 @@ def test_a_pad_advances_nothing(driver):
     before = {nm: np.asarray(cell.asjax())[1].copy()
               for nm, cell in carried()}
     assert len(before) == 7
-    _run(driver, seqs, [(WINDOW, [16, 0, 1]), (1, [1, 0, 1]),
-                        (WINDOW, [2, 0, 7])], start=[11, 16, 5])
+    blocks.run(driver, seqs, [_fed(16, 0), (1, [1, 0] + [1] * (SLOTS - 2)),
+                              _fed(2, 0, 7)], start=list(driver.pos))
     for nm, cell in carried():
         assert np.array_equal(np.asarray(cell.asjax())[1], before[nm]), nm
     # and the pads of a fed slot's window: 5 real rows, 11 pads, then on
-    got, at, _ = _run(driver, seqs, _ones(3), start=list(driver.pos))
-    want = _reference(seqs)
+    got, at, _ = blocks.run(driver, seqs, _ones(3), start=list(driver.pos))
+    want = blocks.reference(BLOCK, seqs)
     assert np.abs(got[1, 16:19] - want[1, 16:19]).max() <= TOL
 
 
-def test_the_ops_declare_their_state_families(driver):
-    assert sorted(driver._state) == ["conv", "cursor", "recurrent", "rows"]
-    assert not driver.positional and driver.feeds
-    assert driver._carried == ["conv", "recurrent"]
+def test_the_state_is_sized_and_counted_by_hand(driver):
+    H, P, N = G["mamba_n_heads"], G["mamba_d_head"], G["mamba_d_state"]
     # two kinds of stateful layer: two mamba layers alike, one attention
     assert sorted(n for n, _reads in driver._reads) == [1, 2]
-    assert driver.state_bytes["recurrent"] == 2 * SLOTS * 8 * 8 * 16 * 4
-    assert driver.state_bytes["conv"] == 2 * SLOTS * 3 * (64 + 32) * 4
-    # two K/V heads of 8 lie paired in one row of 16
-    assert driver.state_bytes["rows"] == 2 * SLOTS * CAPACITY * 16 * 4
-    driver.active[:] = False
-    driver.rewind_many(list(range(SLOTS)), [0] * SLOTS)
-    driver.step(np.zeros((SLOTS, WINDOW), np.int32), fed=[16, 1, 0])
+    assert driver.state_bytes["recurrent"] == 2 * SLOTS * H * P * N * 4
+    assert driver.state_bytes["conv"] == 2 * SLOTS * 3 * (H * P + 2 * N) * 4
+    # two K/V heads of 16 lie paired in one row of 32
+    assert driver.state_bytes["rows"] == 2 * SLOTS * CAPACITY * 32 * 4
+    blocks.reset(driver)
+    driver.step(np.zeros((SLOTS, WINDOW), np.int32),
+                fed=[16, 1] + [0] * (SLOTS - 2))
     assert driver.last_reads["ssm.rows"] == 2 * 17
     assert driver.last_reads["ssm.touched"] == 2 * 2
     assert driver.last_reads["attn.live_rows"] == 17
-
-
-def test_rewind_capture_and_restore_name_the_families(driver):
-    rng = np.random.default_rng(2)
-    seqs = rng.integers(0, CFG["vocab_size"], (SLOTS, 80)).astype(np.int32)
-    _run(driver, seqs, _full(1) + [(WINDOW, [8, 0, 16])])   # 24, 16, 32
-    for move in ((0, 23), (0, 8), (2, 33)):
-        with pytest.raises(MXNetError, match=r"conv.*recurrent.*goes to 0"):
-            driver.rewind(*move)
-    with pytest.raises(MXNetError, match="cannot move"):
-        driver.rewind_many([0, 1], [0, 3])
-    assert list(driver.pos) == [24, 16, 32]      # a refusal moves nothing
-    driver.rewind(1, 16)                         # where it is
-    driver.rewind(2, 0)
-    assert list(driver.pos) == [24, 16, 0]
-    for call in (lambda: driver.capture_rows(0, 8),
-                 lambda: driver.restore_rows(0, {})):
-        with pytest.raises(MXNetError, match=r"conv.*recurrent"):
-            call()
-    driver.pos[:] = [CAPACITY - 16, CAPACITY - 15, 5]
-    assert driver.overflowing(WINDOW) == [1]
-    driver.active[:] = False
-    driver.rewind_many(list(range(SLOTS)), [0] * SLOTS)
+    blocks.reset(driver)
 
 
 def test_the_state_is_alive():
     """With the attention layers cut out, the last logits move when a
     token 64 positions back changes: the state carries it."""
-    cfg = dict(CFG, layer_types=["mamba"] * 3)
-    params = _params(cfg, seed=6, decay=(1.0, 0.03))    # 0.97 a token
-    drv = _driver(cfg, params)
-    rng = np.random.default_rng(12)
-    seqs = rng.integers(0, cfg["vocab_size"], (SLOTS, 80)).astype(np.int32)
+    over = {"granite": {"layer_types": ["mamba"] * 3}}
+    params = blocks.params(BLOCK, seed=6, draws=_decays((1.0, 0.03)), **over)
+    drv = blocks.driver(BLOCK, arg_params=params, **over)   # 0.97 a token
+    seqs = blocks.seqs(BLOCK, 80, seed=12)
     other = seqs.copy()
-    other[:, 15] = (other[:, 15] + 1) % cfg["vocab_size"]
-    sched = _full(4) + _ones(16)
-    a, at, _ = _run(drv, seqs, sched)
-    b, _, _ = _run(drv, other, sched)
+    other[:, 15] = (other[:, 15] + 1) % seqs.max()
+    sched = [_W] * 4 + _ones(16)
+    a, at, _ = blocks.run(drv, seqs, sched)
+    b, _, _ = blocks.run(drv, other, sched)
     moved = np.abs(a[:, 79] - b[:, 79]).max(axis=-1)
     assert np.array_equal(a[:, :15], b[:, :15])
-    want = _reference(other, cfg, params)
+    want = blocks.reference(BLOCK, other, params, over)
     noise = np.abs(b - want).max()
     # measured: moved 2e-4 to 1.5e-3, float32 noise 1e-6 to 2e-5
     assert noise <= TOL / 4 and (moved > 1e-4).all(), (moved, noise)
@@ -466,178 +243,51 @@ def test_a_bfloat16_state_misses_the_tolerance():
     (``D = 0`` and multipliers of 1: the mixer's output is the state's
     read-out alone and weighs in the stream what the embedding does, as
     at the published widths; measured 0.18 on logits up to 0.8.)"""
-    cfg = dict(CFG, layer_types=["mamba"] * 3, embedding_multiplier=1.0,
-               residual_multiplier=1.0)
-    params = _params(cfg, seed=6, decay=(1.0, 1e-3), skip=0.0)
-    rng = np.random.default_rng(13)
-    seqs = rng.integers(0, cfg["vocab_size"], (2, 1000)).astype(np.int32)
-    want = _reference(seqs, cfg, params, tail=32)
-    low = _reference(seqs, cfg, params, state_dtype=jnp.bfloat16, tail=32)
+    # the widths the control was measured at: heads of 8 in a stream of
+    # 32 over a vocabulary of 96
+    over = {"d_model": 32, "vocab_size": 96,
+            "granite": {"layer_types": ["mamba"] * 3, "mamba_d_head": 8,
+                        "shared_intermediate_size": 48,
+                        "intermediate_size": 48,
+                        "embedding_multiplier": 1.0,
+                        "residual_multiplier": 1.0}}
+    params = blocks.params(BLOCK, seed=6, draws=_decays((1.0, 1e-3), 0.0),
+                           **over)
+    seqs = blocks.seqs(BLOCK, 1000, seed=13, slots=2, **over)
+    want = blocks.reference(BLOCK, seqs, params, over, tail=32)
+    low = blocks.reference(BLOCK, seqs, params, over,
+                           state_dtype=jnp.bfloat16, tail=32)
     bound = arch.LOGIT_TOL + arch.LOGIT_TOL * np.abs(want)
     assert (np.abs(low - want) / bound).max() > 1.0
     # and the bound is not met by accident of the scale: float32 again
-    again = _reference(seqs, cfg, params, state_dtype=jnp.float32, tail=32)
+    again = blocks.reference(BLOCK, seqs, params, over,
+                             state_dtype=jnp.float32, tail=32)
     assert (np.abs(again - want) / bound).max() <= 0.01
 
 
-#: what the block refuses, by key (``_granite_spec``); with routed
-#: experts on (ISSUE 54: ``num_local_experts`` > 0 builds ``MoEFFN``) a
-#: choice of no expert or of more than the router has, and a held range
-#: outside the router's width
-_ROUTED = {"num_local_experts": 4, "num_experts_per_tok": 2,
-           "intermediate_size": 16}
-_REFUSED = {
-    "two_groups": {"mamba_n_groups": 2},
-    "projection_bias": {"mamba_proj_bias": True},
-    "positions": {"position_embedding_type": "rope"},
-    "a_layer_short": {"layer_types": ["mamba", "attention"]},
-    "a_layer_of_another_kind": {"layer_types": ["mamba", "mlp", "mamba"]},
-    "no_expert_a_token": dict(_ROUTED, num_experts_per_tok=0),
-    "more_experts_a_token_than_the_router_has":
-        dict(_ROUTED, num_experts_per_tok=5),
-    "held_past_the_router": dict(_ROUTED, held=(2, 3)),
-    "held_before_the_router": dict(_ROUTED, held=(-1, 2)),
-    "nothing_held": dict(_ROUTED, held=(0, 0)),
-}
-
-
-@pytest.mark.parametrize("case", sorted(_REFUSED))
-def test_the_builder_refuses_what_the_block_is_not(case):
-    with pytest.raises(MXNetError, match="granite_hybrid"):
-        tfm.get_decode_symbol(
-            vocab_size=96, d_model=32, n_layer=3, n_head=4, capacity=CAPACITY,
-            per_slot=True, block="granite_hybrid",
-            granite=dict({k: CFG[k] for k in tfm.GRANITE_KEYS},
-                         **_REFUSED[case]))
-
-
-def test_the_builder_refuses_a_graph_it_does_not_have():
-    with pytest.raises(MXNetError, match="served, not trained"):
-        tfm.get_symbol(block="granite_hybrid")
-    with pytest.raises(MXNetError, match="needs granite="):
-        tfm.get_decode_symbol(block="granite_hybrid", per_slot=True,
-                              granite={"num_local_experts": 0})
-    # routed experts are built now, every one held or a share of them
+def test_routed_experts_are_built_every_one_held_or_a_share():
+    """ISSUE 54: ``num_local_experts`` > 0 builds ``MoEFFN``."""
+    routed = {"num_local_experts": 4, "num_experts_per_tok": 2,
+              "intermediate_size": 16}
     for held in (None, (1, 2)):
-        sym = tfm.get_decode_symbol(
-            vocab_size=96, d_model=32, n_layer=3, n_head=4, capacity=CAPACITY,
-            per_slot=True, block="granite_hybrid",
-            granite=dict({k: CFG[k] for k in tfm.GRANITE_KEYS}, **_ROUTED,
-                         **({"held": held} if held else {})))
+        sym = blocks.symbol(BLOCK, 1, granite=dict(
+            routed, **({"held": held} if held else {})))
         moe = [n for n in sym._topo_nodes() if n.op == "MoEFFN"]
         assert len(moe) == 3
         assert int(moe[0].attrs["held_count"]) == (2 if held else 4)
 
 
-# --------------------------------------------------- engine and scheduler
-def _gen(step_len):
-    return _symbol(step_len)
-
-
-@pytest.fixture(scope="module")
-def engine():
-    return mx.serve.DecodeEngine(
-        "tiny-granite", _gen(1), PARAMS, capacity=CAPACITY,
-        ladder=[2, 4], symbol_gen=_gen, window_lens=[WINDOW])
-
-
-def test_migrate_mid_sequence_continues_as_the_reference(engine):
-    """Two slots at positions 37 and 50 of the 2-slot pool move to the
-    4-slot pool, swapped, with their tails, states, K/V rows and
-    cursors, and decode on: the reference's logits."""
-    rng = np.random.default_rng(6)
-    seqs = rng.integers(0, CFG["vocab_size"], (2, 60)).astype(np.int32)
-    want = _reference(seqs)
-    small, big = engine.driver(2), engine.driver(4)
-    for drv in (big, small):
-        drv.active[:] = False
-    small.join(0), small.join(1)
-    lens, at = [37, 50], [0, 0]
-    while any(a < n for a, n in zip(at, lens)):
-        tokens = np.zeros((2, WINDOW), np.int32)
-        fed = np.zeros(2, np.int32)
-        for s in range(2):
-            n = min(WINDOW, lens[s] - at[s])
-            tokens[s, :n] = seqs[s, at[s]:at[s] + n]
-            fed[s], at[s] = n, at[s] + n
-        small.step(tokens, fed=fed)
-    engine.migrate(2, 4, [(0, 3), (1, 1)])
-    assert list(big.pos) == [0, 50, 0, 37] and not small.active.any()
-    for j in range(5):
-        tokens = np.zeros((4, 1), np.int32)
-        tokens[3, 0], tokens[1, 0] = seqs[0, 37 + j], seqs[1, 50 + j]
-        out = big.step(tokens, fed=[0, 1, 0, 1]).asnumpy()
-        assert np.abs(out[3, 0] - want[0, 37 + j]).max() <= TOL
-        assert np.abs(out[1, 0] - want[1, 50 + j]).max() <= TOL
-    big.active[:] = False
-    assert sorted(engine.state_bytes) == ["conv", "cursor", "recurrent",
-                                          "rows"]
-
-
-def _served(sched, prompts, max_new):
-    handles = [sched.submit(p, max_new_tokens=max_new) for p in prompts]
-    sched.pump()
-    return [[int(t) for t in h.result(timeout=5)] for h in handles]
-
-
-def test_mixed_prefill_and_decode_equals_one_request_at_a_time(engine):
-    """Four requests of ragged lengths admitted together through the
-    scheduler (packed windows with riders, a rung switch, run-ahead):
-    the greedy tokens of each request served alone; the counters and
-    the ring's fields say what the state was asked for."""
-    from mxnet_tpu import telemetry
-    from mxnet_tpu.telemetry import flightrec
-    rng = np.random.default_rng(8)
-    prompts = [rng.integers(0, CFG["vocab_size"], n).tolist()
-               for n in (45, 9, 30, 70)]
-    sched = mx.serve.DecodeScheduler(engine, clock=mx.serve.FakeClock(),
-                                     prefill_chunk=WINDOW)
-    alone = [_served(sched, [p], 12)[0] for p in prompts]
-    before = {k: sched._counter(k).value
-              for k in ("ssm.rows", "ssm.touched", "cursor.rows")}
-    mixed = _served(sched, prompts, 12)
-    assert mixed == alone and all(len(t) == 12 for t in mixed)
-    grew = {k: sched._counter(k).value - v for k, v in before.items()}
+def test_the_scheduler_counts_the_rows_the_state_was_asked_for(engine):
+    """Four requests of ragged lengths through the scheduler: the
+    counters and the ring's fields say what the state was asked for."""
+    prompts, grew, steps = blocks.counted(BLOCK, engine,
+                                          ("ssm.rows", "ssm.touched"))
     # positions 0..n+10 of each request are fed, in two mamba layers
     assert grew["ssm.rows"] == 2 * sum(len(p) + 11 for p in prompts)
     assert grew["ssm.rows"] > grew["ssm.touched"] > 0
-    assert grew["cursor.rows"] == 4          # the joins; nothing rewound
-    steps = [r for r in flightrec.get_records()
-             if r.get("kind") == "serve.decode.step"
-             and r.get("model") == "tiny-granite"]
     assert steps and all("ssm_rows" in r and "ssm_touched" in r
                          for r in steps)
     assert any(r["window"] > 1 and r["ssm_rows"] > r["ssm_touched"]
                for r in steps)
-    assert sched.stats()["compiles_since_warmup"] == 0
-    assert sched.stats()["runahead"]["launched"] > 0
-    assert telemetry.get_metric("serve.decode.ssm.rows",
-                                model="tiny-granite").value > 0
-    for family in ("conv", "recurrent"):
-        assert telemetry.get_metric("serve.decode.state.bytes",
-                                    model="tiny-granite",
-                                    family=family).value > 0
-
-
-def test_the_scheduler_refuses_drafts_and_prefix_stores(engine):
-    from mxnet_tpu.serve.prefix import PrefixStore
-    with pytest.raises(MXNetError, match=r"prefix_store.*conv.*recurrent"):
-        mx.serve.DecodeScheduler(engine, clock=mx.serve.FakeClock(),
-                                 prefix_store=PrefixStore(1 << 20))
-    with pytest.raises(MXNetError, match=r"spec_k.*conv.*recurrent"):
-        mx.serve.DecodeScheduler(engine, clock=mx.serve.FakeClock(),
-                                 draft_engine=engine, spec_k=4)
-
-
-def test_serve_decoder_serves_the_block_with_no_side_script():
-    sched = mx.serve.serve_decoder(
-        _gen(1), PARAMS, name="tiny-granite-front", capacity=CAPACITY,
-        ladder=[1, 2], symbol_gen=_gen, prefill_chunk=WINDOW, start=False,
-        clock=mx.serve.FakeClock())
-    assert sched.prefix_store is None and sched.engine.feeds
-    rng = np.random.default_rng(1)
-    prompt = rng.integers(0, CFG["vocab_size"], 41)
-    tokens = _served(sched, [prompt.tolist()], 6)[0]
-    seq = np.concatenate([prompt, tokens[:-1]])[None].astype(np.int32)
-    want = _reference(seq)[0]
-    assert tokens == np.argmax(want[40:], axis=-1).tolist()
+    assert mx.telemetry.get_metric("serve.decode.ssm.rows",
+                                   model=engine.name).value > 0

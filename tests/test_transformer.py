@@ -95,7 +95,8 @@ def test_ring_attention_parity_forward(causal):
     from mxnet_tpu.parallel.mesh import build_mesh
     q, k, v = _qkv(0, 2, 2, 8, 4)
     mesh = build_mesh(MeshConfig(seq=4), devices=jax.devices("cpu")[:4])
-    got = ring_attention_sharded(q, k, v, mesh, causal=causal)
+    got = jax.jit(lambda *a: ring_attention_sharded(
+        *a, mesh, causal=causal))(q, k, v)
     ref = full_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
@@ -104,7 +105,8 @@ def test_ring_attention_parity_forward(causal):
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_parity_grad(causal):
     """Ring gradients == full-attention gradients (the training path
-    differentiates through the ppermute ring)."""
+    differentiates through the ppermute ring), traced as one program
+    as the training path traces it."""
     from mxnet_tpu.parallel.collectives import shard_map
     from mxnet_tpu.parallel.ring_attention import ring_attention
     from jax.sharding import PartitionSpec as P
@@ -119,8 +121,8 @@ def test_ring_attention_parity_grad(causal):
     w = jnp.asarray(np.random.RandomState(2).randn(*q.shape)
                     .astype(np.float32))
 
-    g_ring = jax.grad(lambda *a: jnp.sum(ring(*a) * w),
-                      argnums=(0, 1, 2))(q, k, v)
+    g_ring = jax.jit(jax.grad(lambda *a: jnp.sum(ring(*a) * w),
+                              argnums=(0, 1, 2)))(q, k, v)
     g_full = jax.grad(
         lambda *a: jnp.sum(full_attention(*a, causal=causal) * w),
         argnums=(0, 1, 2))(q, k, v)
