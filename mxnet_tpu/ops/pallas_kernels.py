@@ -1232,7 +1232,7 @@ def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv,
 
 
 def decode_attention(q, k_cache, v_cache, pos, window=0, ring=False,
-                     scale=None):
+                     scale=None, riding=None):
     """Cursor-bounded flash-decode read over a fixed-capacity KV cache.
 
     ``q`` is (B, H, S, Dh) already-rotated queries, the caches are
@@ -1260,19 +1260,38 @@ def decode_attention(q, k_cache, v_cache, pos, window=0, ring=False,
     window's, whatever the context. ``ring``: the pools are rings, a
     position at its value modulo ``C`` (``C >= window + S``).
 
+    **The riders of a window program** (``riding``, a (B,) mask, with
+    every slot's first query as ``q``): a slot fed one row of a long
+    window is read here, by the kernel, the blocks and the rounding that
+    serve it in the S = 1 program (``window_attn_ride`` in the device
+    trace), and a slot that does not ride is dead to the launch - its
+    cursor goes in as -1, under which no block is live: no step of it
+    computes, none of its own blocks is fetched (its steps stand on the
+    next slot's first) and zeros come out.
+
     The call is a jitted function of its own (the kernel is
     ``decode_attn`` in the device trace), so that a step program lowers
     it once and calls it from every layer."""
     more = {} if scale is None else {"scale": float(scale)}
-    return _decode_attention(pos.astype(jnp.int32), q, k_cache, v_cache,
+    pos = pos.astype(jnp.int32)
+    if riding is not None:
+        pos = jnp.where(riding, pos, -1)
+        more.update(name="window_attn_ride", dead_slots=True)
+    return _decode_attention(pos, q, k_cache, v_cache,
                              interpret=_interpret(), window=int(window),
                              ring=bool(ring), **more)
 
 
 @partial(jax.jit, static_argnames=("interpret", "name", "window", "ring",
-                                    "scale"))
+                                    "scale", "dead_slots"))
 def _decode_attention(pos, q, k_cache, v_cache, interpret,
-                      name="decode_attn", window=0, ring=False, scale=None):
+                      name="decode_attn", window=0, ring=False, scale=None,
+                      dead_slots=False):
+    """``decode_attention`` as one jitted program; ``name`` is the
+    kernel's in the device trace. ``dead_slots``: a cursor may be -1, a
+    slot no step of which is live (the kernel's own rule: no key lies
+    at or before -1), and the index maps keep such a slot off its own
+    blocks."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, heads, S, Dh = q.shape
@@ -1298,18 +1317,32 @@ def _decode_attention(pos, q, k_cache, v_cache, interpret,
         last group of the last slot stays on its last live block."""
         last_live = (pos_ref[b] + (S - 1)) // block_k
         more = g + 1 < H // hb
+        if dead_slots:
+            # -1 under a dead slot, every step of which looks ahead: to
+            # the next slot, not to a further group of its own (the
+            # last slot has none to look to, and stays on its block 0)
+            more = more & (last_live >= 0)
         ahead = (j > last_live) & (more | (b + 1 < B))
         return (jnp.where(ahead & ~more, b + 1, b),
                 jnp.where(ahead, jnp.where(more, g + 1, 0), g),
-                jnp.where(ahead, 0, jnp.minimum(j, last_live)), 0)
+                jnp.where(ahead, 0, jnp.minimum(
+                    j, jnp.maximum(last_live, 0) if dead_slots
+                    else last_live)), 0)
 
     def _window_map(b, g, j, pos_ref):
         """Under a window: the live blocks from the first on, a dead
         step on the last live one (no copy)."""
+        if dead_slots:
+            # a dead slot stands on the first block the next slot reads
+            dead = (pos_ref[b] < 0) & (b + 1 < B)
+            b, g, j = (jnp.where(dead, x, y)
+                       for x, y in ((b + 1, b), (0, g), (0, j)))
         cursor = pos_ref[b]
         first, count = _live_blocks(
             jnp.maximum(cursor - window + 1, 0), cursor + (S - 1), block_k,
             n_kb, C if ring else 0)
+        if dead_slots:              # no live block: 0, its block 0
+            count = jnp.maximum(count, 1)
         block = first + jnp.minimum(j, count - 1)
         return (b, g, jax.lax.rem(block, n_kb) if ring else block, 0)
 
@@ -1442,9 +1475,12 @@ def window_attention(q, k_cache, v_cache, pos, fed, window=0, ring=False,
     fetched K/V block serves them all) visits the key blocks from the
     one that holds its first query's oldest attended key (position 0,
     or ``t - window + 1``) to the one that holds its last query's own
-    position, and no other is fetched or computed. ``ring``: the pools
-    are rings of ``C >= window + S`` rows. p.V is one bfloat16 product
-    where the rows are bfloat16 values (float32 at HIGHEST otherwise).
+    position, and no other is fetched or computed; a query block wholly
+    past ``fed`` (a slot fed nothing, the pads behind a ragged chunk)
+    computes nothing, fetches no key block but its first and comes out
+    zero. ``ring``: the pools are rings of ``C >= window + S`` rows.
+    p.V is one bfloat16 product where the rows are bfloat16 values
+    (float32 at HIGHEST otherwise).
     Returns (B, H, S, Dh) at ``q``'s dtype.
 
     A jitted function of its own: the kernel is ``window_attn`` in the
@@ -1472,11 +1508,13 @@ def _window_attention(pos, fed, q, k_cache, v_cache, interpret, window,
 
     def _kv_map(b, h, i, j, pos_ref, fed_ref):
         """The live blocks from the first on; a dead step stays on the
-        last live one (no copy), a query block of pads on its first."""
+        last live one (no copy), a query block of pads (wholly past
+        ``fed``: the kernel walks none of its blocks) on its first."""
         q_lo = pos_ref[b] + i * block_q
         lo = jnp.maximum(q_lo - window + 1, 0) if window else 0
         first, count = _live_blocks(lo, q_lo + (block_q - 1), block_k,
                                     n_kb, C if ring else 0)
+        count = jnp.where(i * block_q < fed_ref[b], count, 1)
         block = first + jnp.minimum(j, count - 1)
         return (b, h, jax.lax.rem(block, n_kb) if ring else block, 0)
 

@@ -913,7 +913,11 @@ def _attention_decode_pallas_variant(attrs, inputs, aux, is_train, rng):
     takes a group of heads and a long key block of one slot and whose
     scalar-prefetched cursor bounds the K/V blocks actually fetched
     from HBM to the live prefix ``[0, cursor_b + S)`` instead of the
-    full capacity."""
+    full capacity. A window of more than ``_DECODE_ROWS`` query rows a
+    K/V head is read by ``pallas_kernels.window_attention``
+    (``window_attn``), which tiles the queries too - but for its slots
+    fed one row, which take the S = 1 read inside the window program
+    (``window_attn_ride``)."""
     from functools import partial
     from .base import parse_bool
     from .ops.pallas_kernels import (cache_write, decode_attention,
@@ -945,15 +949,29 @@ def _attention_decode_pallas_variant(attrs, inputs, aux, is_train, rng):
     # the kernel is row-cursor uniform: the scalar layout is the
     # per-slot layout with every row at the same position
     pos_rows = pos if per_slot else jnp.broadcast_to(pos, (B,))
+    geometry = _read_geometry(geo)
     if geo.groups * S <= _DECODE_ROWS:
-        out = decode_attention(q, k_cache, v_cache, pos_rows,
-                               **_read_geometry(geo))
-    else:
+        out = decode_attention(q, k_cache, v_cache, pos_rows, **geometry)
+    elif fed is None:
         # a long window: the read that tiles the queries too
-        out = window_attention(
-            q, k_cache, v_cache, pos_rows,
-            jnp.full((B,), S, jnp.int32) if fed is None else fed,
-            **_read_geometry(geo))
+        out = window_attention(q, k_cache, v_cache, pos_rows,
+                               jnp.full((B,), S, jnp.int32), **geometry)
+    else:
+        # which read a slot of a long window takes is read from ``fed``:
+        # a slot fed one row (a decoding slot riding a window in which
+        # another prefills) is dead to ``window_attn``, where a whole
+        # query block would be scored for it, and is read as the one
+        # query it is, as in the S = 1 program (``window_attn_ride``,
+        # to which every other slot is dead); the window's zeros in its
+        # row 0 take the result
+        riding = fed == 1
+        out = window_attention(q, k_cache, v_cache, pos_rows,
+                               jnp.where(riding, 0, fed), **geometry)
+        ride = decode_attention(q[:, :, :1], k_cache, v_cache, pos_rows,
+                                riding=riding, **geometry)
+        row = jnp.where(riding[:, None, None, None], ride.astype(out.dtype),
+                        out[:, :, :1])
+        out = jax.lax.dynamic_update_slice(out, row, (0, 0, 0, 0))
     return [out.astype(q.dtype)], [k_cache, v_cache, new_cursor]
 
 

@@ -311,52 +311,138 @@ def test_ungrouped_unwindowed_graphs_lower_to_the_parents_text(capacity, S,
     assert digest[:16] == _PARENT_TEXT_SHA256[(capacity, S)]
 
 
-@pytest.mark.parametrize("S", [1, 1024], ids=["decode", "window"])
-@pytest.mark.parametrize("kind", ["full", "sliding"])
-def test_grouped_window_attention_compiles_for_v5e_and_copies_no_pool(kind, S,
-                                                                      v5e):
-    """Trinity-Mini's ``attention_decode`` at the published sizes (8
-    slots, 32 query heads on 4 K/V heads of 128, bfloat16, a capacity
-    of 32,768): a full layer's pools of a row per position and a
-    sliding layer's rings of 2,048 + 1,024 rows, in the S=1 program and
-    the window program of 1,024. The write is ``cache_write``, the read
-    ``decode_attn`` (8 rows a K/V head) or ``window_attn`` (8,192), and
-    with the aux arrays donated every pool comes back in the buffer it
-    came in, neither copied nor re-laid."""
-    import re
-    from mxnet_tpu.models.transformer import ring_rows
-    B, H, Hkv, d, C = 8, 32, 4, 128, 32768
-    ring = ring_rows(2048, 1024) if kind == "sliding" else 0
-    assert ring in (0, 3072)
+#: ``attention_decode`` as the served graphs give it (``fed`` an input)
+#: at the published sizes: (attributes, slots, query heads, K/V heads,
+#: rows of a pool, rows of a long window). Trinity-Mini's full layers
+#: (pools of a row per position) and sliding layers (rings of 2,048 +
+#: 1,024 rows, rotary), 32 query heads on 4 K/V heads of 128; Granite
+#: 4.0-H Small's (32 on 8) and Micro's (32 heads of 64 on 8, two to a
+#: row of 128: 4 rows) attention layers, unrotated, at the published
+#: multipliers; Cerebras-GPT's and OLMoE's, a K/V head a query head
+_FED_GRAPHS = {
+    "full": (dict(capacity=32768, kv_heads=4), 8, 32, 4, 32768, 1024),
+    "sliding": (dict(capacity=32768, kv_heads=4, rope=True, window=2048,
+                     ring=3072), 8, 32, 4, 3072, 1024),
+    "granite_small": (dict(capacity=8192, kv_heads=8, scale=0.0078125),
+                      32, 32, 8, 8192, 256),
+    "granite_micro": (dict(capacity=4096, kv_heads=4, scale=0.015625),
+                      32, 32, 4, 4096, 256),
+    "cerebras": (dict(capacity=2048), 8, 16, 16, 2048, 64),
+    "olmoe": (dict(capacity=4096, rope=True), 8, 16, 16, 4096, 64),
+}
+
+
+def _fed_graph(kind, S, v5e):
+    """``(attrs, inputs, aux)`` of ``_FED_GRAPHS[kind]`` at ``S`` rows a
+    slot, as shapes on the described chip."""
+    raw, B, H, Hkv, rows, _ = _FED_GRAPHS[kind]
+    d = 128
     opdef = get_op("attention_decode")
-    attrs = opdef.normalize_attrs(dict(
-        capacity=C, per_slot=True, rope=kind == "sliding", kv_heads=Hkv,
-        fed=True, **({"window": 2048, "ring": ring} if ring else {})))
+    attrs = opdef.normalize_attrs(dict(raw, per_slot=True, fed=True))
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
     ins = [sds((B, H, S, d)), sds((B, Hkv, S, d)), sds((B, Hkv, S, d)),
            sds((B,), jnp.int32)]
-    rows = ring or C
     aux = [sds((B, Hkv, rows, d))] * 2 + [sds((B, 1), jnp.int32)]
-    assert opdef.aux_names(attrs)[0] == ("k_ring" if ring else "k_cache")
     assert opdef.variant_eligible("pallas", attrs,
                                   [a.shape for a in ins + aux],
                                   [str(a.dtype) for a in ins + aux])
+    return attrs, ins, aux
+
+
+@pytest.mark.parametrize("S", [1, 0], ids=["decode", "window"])
+@pytest.mark.parametrize("kind", ["full", "sliding", "granite_small",
+                                  "granite_micro"])
+def test_grouped_window_attention_compiles_for_v5e_and_copies_no_pool(kind, S,
+                                                                      v5e):
+    """Trinity-Mini's and the two Granites' ``attention_decode`` at the
+    published sizes (``_FED_GRAPHS``), in the S = 1 program and the
+    window program of 1,024 or 256 rows a slot. The write is
+    ``cache_write``; the read ``decode_attn`` (4 or 8 rows a K/V head)
+    or, in a window, ``window_attn`` for the slots that prefill and
+    ``window_attn_ride`` for those fed one row (ISSUE 58), whose row 0
+    is laid into the window's result where it lies; two layers hold one
+    lowering of each and call it twice, and with the aux arrays donated
+    every pool comes back in the buffer it came in, neither copied nor
+    re-laid."""
+    from mxnet_tpu.models.transformer import ring_rows
+    assert ring_rows(2048, 1024) == _FED_GRAPHS["sliding"][0]["ring"]
+    _, B, H, Hkv, rows, window = _FED_GRAPHS[kind]
+    S = S or window
+    attrs, ins, aux = _fed_graph(kind, S, v5e)
+    opdef = get_op("attention_decode")
+    assert opdef.aux_names(attrs)[0] == (
+        "k_ring" if kind == "sliding" else "k_cache")
     fn = opdef.variant_fn("pallas")
-    lowered = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
-                      donate_argnums=(1,)).lower(ins, aux)
-    assert re.findall(r'kernel_name = "(\w+)"', lowered.as_text()) == [
-        "cache_write", "decode_attn" if S == 1 else "window_attn"]
+
+    def two_layers(r, a1, a2):
+        o1, n1 = fn(attrs, r, a1, False, None)
+        o2, n2 = fn(attrs, [o1[0]] + r[1:], a2, False, None)
+        return o2, n1, n2
+
+    lowered = jax.jit(two_layers, donate_argnums=(1, 2)).lower(ins, aux, aux)
+    kernels = ["cache_write", "decode_attn"] if S == 1 else [
+        "cache_write", "window_attn", "window_attn_ride"]
+    assert re.findall(r'kernel_name = "(\w+)"', lowered.as_text()) == kernels
     compiled = lowered.compile()
     text = compiled.as_text()
-    pool = rf"= bf16\[{B},{Hkv},{rows},{d}\]\S* "
+    for kernel in kernels:
+        assert len(re.findall(rf"%{kernel}[.\d]* = .* custom-call\(",
+                              text)) == 2, kernel
+    pool = rf"= bf16\[{B},{Hkv},{rows},128\]\S* "
     assert not re.findall(pool + r"copy\(", text)
     assert not re.findall(pool + r"fusion\(", text)
     assert " scatter(" not in text
     assert compiled.memory_analysis().alias_size_in_bytes >= \
-        2 * B * Hkv * rows * d * 2
+        4 * B * Hkv * rows * 128 * 2
+    if S > 1:
+        # the ride reads every slot's first query against the pools as
+        # the write handed them over, and gives a row a slot
+        rides = re.findall(
+            r"%window_attn_ride[.\w]* = (\S+) custom-call\(([^)]*)\)", text)
+        for out, operands in rides:
+            assert out.startswith(f"f32[{B},{Hkv},{H // Hkv},128]")
+            assert all(x.startswith("%get-tuple-element")
+                       for x in operands.split(", ")[-2:])
+
+
+#: the first 16 hex digits of the sha256 of the text that ONE layer of
+#: ``attention_decode``'s Pallas lowering lowers to where ISSUE 58 adds
+#: no second read, Mosaic bodies without their source locations
+#: (``_text_without_locations``): the S = 1 program of every served
+#: graph of ``_FED_GRAPHS`` and the 8 x 64 windows of the Cerebras and
+#: OLMoE graphs (64 query rows a K/V head: ``decode_attn``'s). PR 58's
+#: parent's (e049b28), computed on a copy of it
+_PARENT_FED_TEXT_SHA256 = {
+    ("cerebras", 1): "c6311d42d107a145",
+    ("cerebras", 64): "cf10b18aa45f4ec0",
+    ("olmoe", 1): "47963a8c5f731692",
+    ("olmoe", 64): "74c601dd27d91082",
+    ("full", 1): "3fcd589773f3ff23",
+    ("sliding", 1): "99409f889c43bfe4",
+    ("granite_small", 1): "c020b71179ae8e83",
+    ("granite_micro", 1): "e4c575084307e085",
+}
+
+
+@pytest.mark.parametrize("kind,S", sorted(_PARENT_FED_TEXT_SHA256))
+def test_programs_without_a_long_window_lower_to_the_parents_text(kind, S,
+                                                                  v5e):
+    """A rider is taken out of ``window_attn`` inside a long window's
+    program alone: the S = 1 programs, and the windows of at most 64
+    query rows a K/V head, lower to the text they had."""
+    import hashlib
+    attrs, ins, aux = _fed_graph(kind, S, v5e)
+    fn = get_op("attention_decode").variant_fn("pallas")
+    text = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
+                   donate_argnums=(1,)).lower(ins, aux).as_text()
+    assert re.findall(r'kernel_name = "(\w+)"', text) == [
+        "cache_write", "decode_attn"]
+    digest = hashlib.sha256(
+        _text_without_locations(text).encode()).hexdigest()
+    assert digest[:16] == _PARENT_FED_TEXT_SHA256[(kind, S)]
 
 
 def _mla_published(selected, B, C, S, sds):

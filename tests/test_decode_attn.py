@@ -13,6 +13,8 @@ rules: a scripted slower measurement can never pick the kernel, and
 with the kernel + fp8 cache armed the decode engine compiles nothing
 after warmup at any rung.
 """
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -223,6 +225,241 @@ def test_grouped_heads_and_rings_are_the_slot_pools_and_check_their_sizes():
     assert OP.input_names(attrs) == ["q", "k", "v", "fed"]
     assert [OP.slot_state[n] for n in OP.aux_names(attrs)] == [
         "ring", "ring", "cursor"]
+
+
+# ------------------------------------- a window's riders (ISSUE 58)
+#: (heads, K/V heads, S, window, ring, context capacity, cursors, fed):
+#: one long window (``window_attn``'s: more than 64 query rows a K/V
+#: head) of mixed slots, at key blocks of 16 - a slot fed all S rows, one
+#: fed a ragged chunk, three riders fed one row (at a key block's first
+#: position, at a block's last, and far on: past several turns of a
+#: ring), a slot fed nothing, and a rider with no room left for S rows,
+#: which writes nothing and stays
+_RIDING_CASES = {
+    "plain": (2, 2, 80, 0, 0, 192,
+              [16, 5, 32, 47, 111, 20, 190], [80, 33, 1, 1, 1, 0, 1]),
+    "grouped": (8, 2, 32, 0, 0, 96,
+                [16, 5, 32, 47, 63, 20, 94], [32, 7, 1, 1, 1, 0, 1]),
+    "window": (8, 2, 32, 16, 0, 96,
+               [16, 5, 32, 47, 63, 20, 94], [32, 2, 1, 1, 1, 0, 1]),
+    "ring": (8, 2, 32, 16, 48, 256,
+             [16, 5, 96, 47, 203, 20, 250], [32, 31, 1, 1, 1, 0, 1]),
+}
+_RIDERS = [2, 3, 4]
+
+
+def _riding_state(case, dtype="float32", S=None, overflow=True):
+    """``(attrs, inputs, aux, cursors, fed)`` of a case; ``S`` 1: the
+    S = 1 program's view of the same slots (every slot's first row, fed
+    one); without ``overflow`` the last slot, whose cursor is past the
+    room, is left out (an eager call raises for it)."""
+    heads, kv_heads, s_len, window, ring, capacity, cursors, fed = \
+        _RIDING_CASES[case]
+    slots = len(cursors)            # drawn for all: a slot's draws stay
+    n = slots - (0 if overflow else 1)
+    cursors, fed = cursors[:n], fed[:n]
+    attrs = OP.normalize_attrs({
+        "capacity": capacity, "per_slot": True, "rope": True, "fed": True,
+        **({"kv_heads": kv_heads} if kv_heads != heads else {}),
+        **({"window": window} if window else {}),
+        **({"ring": ring} if ring else {})})
+    rng = np.random.RandomState(3)
+    dt = np.dtype(dtype)
+    q = jnp.asarray(rng.randn(slots, heads, s_len,
+                              DH), dt)[:n]
+    k, v = (jnp.asarray(rng.randn(slots, kv_heads,
+                                  s_len, DH), dt)[:n] for _ in "kv")
+    pools = [jnp.asarray(rng.randn(slots, kv_heads,
+                                   ring or capacity, DH), dt)[:n]
+             for _ in "kv"]
+    if S == 1:
+        q, k, v = (x[:, :, :1] for x in (q, k, v))
+        fed = [1] * n
+    cur = jnp.asarray(np.reshape(cursors, (n, 1)), jnp.int32)
+    return attrs, [q, k, v, jnp.asarray(fed, jnp.int32)], pools + [cur], \
+        cursors, fed
+
+
+@pytest.fixture
+def key_blocks_of_16(monkeypatch):
+    """Several key blocks a slot at a test's sizes, and one K/V head a
+    grid step of the S = 1 read (two head groups a slot)."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "_READ_BLOCK_K", 16)
+    monkeypatch.setattr(pk, "_READ_VMEM_BUDGET", 20000)
+    pk._decode_attention.clear_cache()
+    pk._window_attention.clear_cache()
+    yield pk
+    pk._decode_attention.clear_cache()
+    pk._window_attention.clear_cache()
+
+
+def _pallas(attrs, inputs, aux):
+    return jax.jit(lambda i, a: OP.variants["pallas"]["fn"](
+        attrs, i, a, False, None))(inputs, aux)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-4),
+                                       ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case", sorted(_RIDING_CASES))
+def test_a_window_of_mixed_slots_matches_the_composition(
+        case, dtype, tol, key_blocks_of_16):
+    """A prefilling slot, a ragged chunk, riders at staggered cursors,
+    an idle slot and a rider without room through one window program:
+    each slot's fed rows within the tier tolerance of the composition's,
+    both pools bit-identical, every cursor advanced by what was fed."""
+    attrs, inputs, aux, cursors, fed = _riding_state(case, dtype)
+    S = inputs[0].shape[2]
+    assert OP.variants["pallas"]["eligible"](
+        attrs, [x.shape for x in inputs + aux],
+        [str(x.dtype) for x in inputs + aux])
+    assert inputs[0].shape[1] // inputs[1].shape[1] * S > 64
+    ref, ref_aux, pal, pal_aux = _both(attrs, inputs, aux, jit=True)
+    fed[-1] = 0                     # no room under the capacity
+    for slot, n in enumerate(fed):
+        np.testing.assert_allclose(
+            np.asarray(ref[slot, :, :n], np.float32),
+            np.asarray(pal[slot, :, :n], np.float32), atol=tol, rtol=tol)
+    # a rider's pads come out zero, as before
+    assert not np.asarray(pal[_RIDERS, :, 1:], np.float32).any()
+    for r, p in zip(ref_aux[:2], pal_aux[:2]):
+        assert r.dtype == p.dtype
+        assert np.array_equal(np.asarray(r, np.float32),
+                              np.asarray(p, np.float32))
+    assert np.asarray(pal_aux[2]).ravel().tolist() == \
+        np.asarray(ref_aux[2]).ravel().tolist() == \
+        [c + n for c, n in zip(cursors, fed)]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_RIDING_CASES))
+def test_a_riders_row_is_the_s1_programs_bit_for_bit(case, dtype,
+                                                     key_blocks_of_16):
+    """The same slot at the same cursor over the same pools: row 0 of a
+    rider in the window program and the S = 1 program's output are the
+    same bits (the same kernel over the same blocks; the window's pads
+    lie past the rider's position and are masked)."""
+    attrs, inputs, aux, _, _ = _riding_state(case, dtype)
+    window, _ = _pallas(attrs, inputs, aux)
+    attrs, inputs, aux, _, _ = _riding_state(case, dtype, S=1)
+    single, _ = _pallas(attrs, inputs, aux)
+    assert window[0].dtype == single[0].dtype == np.dtype(dtype)
+    got = np.asarray(window[0][_RIDERS, :, 0], np.float32)
+    assert np.abs(got).min() > 0
+    assert np.array_equal(got,
+                          np.asarray(single[0][_RIDERS, :, 0], np.float32))
+
+
+@pytest.mark.parametrize("case", sorted(_RIDING_CASES))
+def test_each_read_of_a_window_is_dead_to_the_other_reads_slots(
+        case, key_blocks_of_16, monkeypatch):
+    """``window_attn`` is launched with a rider's ``fed`` at 0 and gives
+    zeros for it; ``window_attn_ride`` is launched over every slot's
+    first query with the riders marked, gives zeros for every other
+    slot, and its K/V index map names none of such a slot's blocks but
+    what a dead step names: the first block of the slot after it (the
+    last slot, which has none after it, its own first)."""
+    pk = key_blocks_of_16
+    attrs, inputs, aux, cursors, fed = _riding_state(case, overflow=False)
+    slots, S = len(fed), inputs[0].shape[2]
+    riding = np.asarray(fed) == 1
+    assert np.flatnonzero(riding).tolist() == _RIDERS
+    seen, specs = {}, {}
+    for name in ("window_attention", "decode_attention"):
+        def spy(*args, _fn=getattr(pk, name), _name=name, **kw):
+            seen[_name] = (args, kw)
+            return _fn(*args, **kw)
+        monkeypatch.setattr(pk, name, spy)
+    call = pk.pallas_call
+
+    def pallas_call(kernel, out_shape, **kw):
+        specs[kw["name"]] = kw["grid_spec"]
+        return call(kernel, out_shape, **kw)
+    monkeypatch.setattr(pk, "pallas_call", pallas_call)
+    out, new_aux = OP.variants["pallas"]["fn"](attrs, inputs, aux, False,
+                                               None)
+    assert {"window_attn", "window_attn_ride"} <= set(specs)
+    (_q, k_cache, v_cache, pos, fed_in), geometry = seen["window_attention"]
+    assert np.asarray(fed_in).tolist() == np.where(riding, 0, fed).tolist()
+    (q1, _k, _v, pos1), kw = seen["decode_attention"]
+    assert q1.shape[2] == 1 and np.array_equal(q1, _q[:, :, :1])
+    assert np.asarray(kw.pop("riding")).tolist() == riding.tolist()
+    assert kw == geometry and np.array_equal(pos, pos1)
+    assert np.asarray(pos).tolist() == cursors
+    # each launch alone: zeros where the other read serves
+    tiled = np.asarray(pk.window_attention(_q, k_cache, v_cache, pos,
+                                           fed_in, **geometry))
+    assert not tiled[riding].any() and not tiled[np.asarray(fed) == 0].any()
+    assert np.abs(tiled[0]).min() > 0
+    ride = np.asarray(pk.decode_attention(q1, k_cache, v_cache, pos,
+                                          riding=jnp.asarray(riding), **kw))
+    assert not ride[~riding].any() and np.abs(ride[riding]).min() > 0
+    assert np.array_equal(ride[riding].astype(np.float32),
+                          np.asarray(out[0])[riding][:, :, :1])
+    # the window read fetches none of a dead query block's keys (a
+    # rider's, an idle slot's, a ragged chunk's pads): its steps stay
+    # on one block, where a live query block walks its live ones
+    grid_spec = specs["window_attn"]
+    _, n_heads, n_q, n_kb = grid_spec.grid
+    index_map = grid_spec.in_specs[1].index_map
+    block_q = S // n_q
+    for b in range(slots):
+        for i in range(n_q):
+            named = {tuple(int(x) for x in index_map(
+                b, h, i, j, pos, fed_in)[:3])
+                for h in range(n_heads) for j in range(n_kb)}
+            assert all(x[0] == b for x in named)
+            if i * block_q >= int(fed_in[b]):
+                assert len(named) == n_heads, (b, i, named)
+    assert len({tuple(int(x) for x in index_map(0, 0, 0, j, pos, fed_in)[:3])
+                for j in range(n_kb)}) > 1
+    # the ride's K/V index map, step by step
+    grid_spec = specs["window_attn_ride"]
+    n_slots, n_groups, n_kb = grid_spec.grid
+    block_k = grid_spec.in_specs[1].block_shape[2]
+    assert (n_slots, n_groups, block_k) == (slots, 2, 16)
+    index_map = grid_spec.in_specs[1].index_map
+    cursor = jnp.where(riding, pos, -1)
+    window = int(attrs.get("window") or 0)
+    for b in range(slots):
+        named = {tuple(int(x) for x in index_map(b, g, j, cursor)[:3])
+                 for g in range(n_groups) for j in range(n_kb)}
+        def lo(b):
+            return max(cursors[b] - window + 1, 0) if window else 0
+        if not riding[b]:
+            ahead = lo(b + 1) // block_k % n_kb \
+                if b + 1 < slots and riding[b + 1] else 0
+            assert named == ({(b + 1, 0, ahead)} if b + 1 < slots else
+                             {(b, g, 0) for g in range(n_groups)}), (b, named)
+            continue
+        lo = lo(b)
+        live = {(b, g, (t // block_k) % n_kb) for g in range(n_groups)
+                for t in range(lo, cursors[b] + 1)}
+        assert live <= named
+        # and nothing else of its own: a trailing dead step looks ahead
+        assert {x for x in named if x[0] == b} == live, (b, named)
+
+
+@pytest.mark.parametrize("case", sorted(_RIDING_CASES))
+def test_a_graph_without_fed_has_no_ride(case, key_blocks_of_16):
+    """The whole-window program (``check_reference``'s: every slot fed
+    ``S``) has no rider: ``cache_write`` and ``window_attn`` and no
+    second read; the same graph with ``fed`` has all three, and its
+    S = 1 program ``decode_attn`` alone."""
+    def kernels(attrs, inputs, aux):
+        text = str(jax.make_jaxpr(lambda i, a: OP.variants["pallas"]["fn"](
+            attrs, i, a, False, None))(inputs, aux))
+        return [name for name in re.findall(r"name=(\w+)", text)
+                if name in ("cache_write", "decode_attn", "window_attn",
+                            "window_attn_ride")]
+    attrs, inputs, aux, _, _ = _riding_state(case)
+    assert kernels(attrs, inputs, aux) == [
+        "cache_write", "window_attn", "window_attn_ride"]
+    whole = OP.normalize_attrs({k: v for k, v in attrs.items()
+                                if k != "fed"})
+    assert kernels(whole, inputs[:3], aux) == ["cache_write", "window_attn"]
+    attrs, inputs, aux, _, _ = _riding_state(case, S=1)
+    assert kernels(attrs, inputs, aux) == ["cache_write", "decode_attn"]
 
 
 def test_decode_kernel_parity_staggered_and_edge_cursors():
