@@ -3,6 +3,7 @@ the compile watch, the profiler switch and the result line."""
 from __future__ import annotations
 
 import json
+import math
 import os
 import shutil
 import sys
@@ -204,8 +205,12 @@ def per_layer_metrics(cell, obs):
 
 
 def result_line(correct, attempted, failed, metrics, device, peak_bytes,
-                tracer=None, **extra):
-    """The contract's last line."""
+                tracer=None, compared=None):
+    """The contract's last line. ``compared`` is every number that
+    decided ``correct`` beside its limit, ``{name: (value, limit)}``
+    (the value may not pass the limit; a name that ends in
+    ``_at_least`` may not fall under it): the line's last key, and the
+    last lines on standard error."""
     from . import trace
     device = dict(device, memory_peak_bytes=int(peak_bytes))
     out = {"correct": bool(correct), "attempted": int(attempted),
@@ -216,6 +221,13 @@ def result_line(correct, attempted, failed, metrics, device, peak_bytes,
         bd = trace.breakdown(tracer.events)
         if bd:
             out["breakdown"] = bd
-    out.update(extra)
+    # a NaN or an infinity is no JSON: such a reading goes out by name
+    out["compared"] = {
+        name: {"value": v if math.isfinite(v) else repr(v), "limit": lim}
+        for name, (v, lim) in (compared or {}).items()}
     sys.stdout.flush()
+    for name, c in out["compared"].items():
+        print(f"chipbench compared {name}: {c['value']} (limit "
+              f"{c['limit']})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(out), flush=True)

@@ -36,8 +36,9 @@ latency. ``skew`` measures the miss on the trace's own markers -
 one-operation programs (``jit_<fn>`` on the ``XLA Modules`` line, under
 0.1 ms) that found the chip idle, each of which started while its call
 (the host event ``PjitFunction(<fn>)``) was on the host - and every
-device time is taken less that skew. The two latencies that cross the
-planes are None where a trace has no marker.
+device time is taken less that skew. Where a trace has no marker the
+account's ``skew_ms`` is None, and the two latencies that cross the
+planes then mean nothing.
 
 An iteration *orders impossibly* when its program starts on the chip
 before its launch span does, when the ids are on the host before the
@@ -287,13 +288,3 @@ def account(obs):
         say("critical_path", **out)
     return out
 
-
-def segment_ms_p50(obs, segment):
-    """Median of one segment over the traced S=1 iterations, in ms; None
-    without the spans, where iterations order impossibly, and for a
-    segment that crosses the planes where nothing ties their clocks."""
-    found = account(obs)
-    if found is None or found["p50_ms"] is None or \
-            (segment in CROSSING and found["skew_ms"] is None):
-        return None
-    return found["p50_ms"][segment]

@@ -247,5 +247,13 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
         metrics = common.per_layer_metrics(cell, obs)
     else:
         metrics = common.end_to_end_metrics(cell, values)
+    compared = {
+        "loss_abs_diff": (report["abs_diff"], report["tolerance"]
+                          * max(1.0, abs(report["reference_loss"]))),
+        "parameter_unchanged": (int(not report["parameter_changed"]), 0),
+        "loss_not_finite": (int(not finite), 0),
+        "compiles_in_window": (len(compiles_in_window), 0),
+        "steps_in_window_at_least": (win.steps, 1)}
     common.result_line(correct, win.steps, 0 if finite else win.steps,
-                       metrics, device, peak, tracer=tracer)
+                       metrics, device, peak, tracer=tracer,
+                       compared=compared)

@@ -50,6 +50,10 @@ ARCH_INTERFACE = {
             "reference_loss", "LOSS_TOL", "costs"),
 }
 
+#: What an architecture MAY state beside it (README.md has the table);
+#: one that states neither is read as 1 and None.
+ARCH_OPTIONAL = {"serve": ("decode_step_len", "mask_token")}
+
 
 @dataclass
 class Cell:

@@ -62,17 +62,10 @@ def ring_p50(obs, decl):
 
 
 def ring_interval_p50(obs, decl):
-    """Time from one record to the next (the later record's iteration),
-    less that record's ``minus`` field when given."""
+    """Time from one record to the next (the later record's iteration)."""
     recs = _ring(obs, decl)
-    vals = []
-    for prev, rec in zip(recs, recs[1:]):
-        if not _match(rec, decl.get("where")):
-            continue
-        v = rec["ts_us"] - prev["ts_us"]
-        if decl.get("minus"):
-            v -= rec.get(decl["minus"], 0)
-        vals.append(v)
+    vals = [rec["ts_us"] - prev["ts_us"] for prev, rec in zip(recs, recs[1:])
+            if _match(rec, decl.get("where"))]
     if not vals:
         return None
     return decl.get("scale", 1.0) * median(vals)

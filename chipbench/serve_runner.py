@@ -15,6 +15,7 @@ Then the window opens. ``correct`` is decided after it.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import gc
 import math
@@ -23,7 +24,7 @@ import time
 
 import numpy as np
 
-from . import common, manifest, stats, traffic as traffic_mod
+from . import common, critical_path, manifest, stats, traffic as traffic_mod
 
 
 class _Record:
@@ -145,56 +146,226 @@ def served_params(engine):
             if n not in engine.data_names}
 
 
-def check_reference(engine, arrays, config, seed, arch):
-    """Prefill through the top rung's window program, then decode
-    through its S=1 program, on two seeded sequences; the logits of the
-    last 16 positions of each path against the architecture's plain
-    float32 reference's full forward, within its ``LOGIT_TOL``:
-    |served - reference| <= TOL + TOL * |reference| on every compared
-    logit. Returns ``(ok, report)``."""
+def decode_step(arch, config, drv, S, n_cmp):
+    """``(L, mask)``: the tokens a slot is fed in one decode dispatch
+    and the id that stands for a token not yet chosen, as the
+    architecture states them (``manifest.ARCH_OPTIONAL``; 1 and None
+    where it states neither). An engine that cannot be held to them is
+    refused by name."""
+    stated = {n: getattr(arch, n)(config)
+              for n in manifest.ARCH_OPTIONAL["serve"] if hasattr(arch, n)}
+    L = int(stated.get("decode_step_len", 1))
+    mask = stated.get("mask_token")
+    lens = sorted({1, *drv.window_lens})
+    if L not in lens:
+        raise SystemExit(
+            f"chipbench: decode_step_len {L}: the engine has programs of "
+            f"{lens} tokens a slot (drv.window_lens) and none of {L}")
+    if n_cmp % L or S % L:
+        # blocks are counted from position 0: every feed of the check
+        # begins on a block's edge only if L divides both
+        raise SystemExit(
+            f"chipbench: decode_step_len {L} does not divide the "
+            f"{n_cmp} positions compared and the window of {S}")
+    if mask is not None and not drv.positional:
+        raise SystemExit(
+            f"chipbench: mask_token {mask}: a masked feed is taken back "
+            "by rewind_many, and this engine is not positional (a "
+            "carried state rewinds to 0 alone)")
+    return L, None if mask is None else int(mask)
+
+
+def _feed(rung, width, rows):
+    """``(rung, width)`` ids: ``rows[i]`` at the head of slot ``i``'s
+    row, 0 elsewhere."""
+    tokens = np.zeros((rung, width), np.int32)
+    for slot, ids in enumerate(rows):
+        tokens[slot, :len(ids)] = ids
+    return tokens
+
+
+def _over(got, want, tol):
+    """``(max |got - want|, max of it over its bound)``; a NaN stays
+    one, and is under no limit."""
+    err = np.abs(got - want)
+    return float(np.max(err)), float(np.max(err / (tol + tol * np.abs(want))))
+
+
+@contextlib.contextmanager
+def _no_detail():
+    """A reference call traced under it reports nothing: the
+    architectures hand their emulation and controls over the call's
+    tail to ``jax.experimental.io_callback`` (the ``reference_detail``
+    line) and add its zero to the logits, which do not depend on them.
+    With the callback a zero of its own they are dead code, and a call
+    whose tail is other positions than the line's costs one forward and
+    not five to eight."""
     import jax
+    import jax.numpy as jnp
+
+    def silent(_callback, result, *_args, **_kw):
+        return jax.tree.map(lambda r: jnp.zeros(r.shape, r.dtype), result)
+
+    real = jax.experimental.io_callback
+    jax.experimental.io_callback = silent
+    try:
+        yield
+    finally:
+        jax.experimental.io_callback = real
+
+
+def check_reference(engine, arrays, config, seed, arch):
+    """The programs the window timed against the architecture's plain
+    float32 reference's full forward, on two seeded sequences, within
+    its ``LOGIT_TOL``: |served - reference| <= TOL + TOL * |reference|
+    on every compared logit. Three parts, in this order (nothing is
+    rewound between them: a carried state rewinds to 0 alone):
+
+    1. prefill through the top rung's whole-window program (``step``
+       without ``fed``), the last 16 rows compared;
+    2. decode 16 positions at the step length the architecture states
+       (``decode_step``; S=1 steps where it states none), every row
+       compared - where it states a mask token each step is fed twice:
+       a seeded subset of its columns masked, those rows compared with
+       the reference's forward over the same ids, the cursors put back,
+       then the clean ids;
+    3. two windows inside the budget with ``fed`` given, as a serving
+       window is launched (the packed program where the rung has one):
+       one sequence its next S tokens and the other its next L, then
+       the roles swapped; the first sequence's two rows compared - a
+       chunk's last row and a rider's row.
+
+    A reference row is read only from the last 32 positions of the call
+    that produced it (several architectures return no others); parts 1
+    and 2 are one call on the parent's own tokens, part 3 one more at a
+    second length over the first sequence alone (fewer tokens than the
+    first call's, so that it fits beside a live engine wherever that
+    one fits), traced under ``_no_detail``. Returns ``(ok, report)``;
+    the report's older keys are over parts 1 and 2 alone."""
+    import jax
+    t0 = time.perf_counter()
     rung = engine.ladder.max
     drv = engine.driver(rung)
     S = max(drv.window_lens) if drv.window_lens else 1
     n_cmp = min(16, S)
-    t_pre = S * max(1, min(4, (engine.capacity - n_cmp) // S))
+    L, mask = decode_step(arch, config, drv, S, n_cmp)
+    V = config["vocab_size"]
+
+    def whole_windows(room):
+        return max(1, min(4, room // S))
+
+    windows = whole_windows(engine.capacity - n_cmp - S - L)
+    t_pre = S * windows
+    P = t_pre + n_cmp
+    if P + S + L > engine.capacity:
+        raise SystemExit(
+            f"chipbench: a capacity of {engine.capacity} has no room for "
+            f"the check's {P} + {S} + {L} positions")
     rng = np.random.default_rng([int(seed) % (1 << 32), 11])
     n_seq = min(2, rung)
-    seqs = rng.integers(0, config["vocab_size"],
-                        (n_seq, t_pre + n_cmp)).astype(np.int32)
+    slots = list(range(n_seq))
+    # the parent's own draw first, so that parts 1 and 2 are its tokens
+    seqs = np.concatenate(
+        [rng.integers(0, V, (n_seq, P)), rng.integers(0, V, (n_seq, S + L))],
+        axis=1).astype(np.int32)
+    steps = n_cmp // L
+    if mask is not None:        # the columns each masked feed hides
+        hidden = rng.random((steps, L)) < 0.5
+        hidden[~hidden.any(axis=1), rng.integers(0, L)] = True
     drv.active[:] = False
     drv.rewind_many(list(range(rung)), [0] * rung)
-    for slot in range(n_seq):
+    for slot in slots:
         drv.join(slot)
-    got = np.zeros((n_seq, 2 * n_cmp, config["vocab_size"]), np.float32)
-    for w in range(t_pre // S):
-        tokens = np.zeros((rung, S), np.int32)
-        tokens[:n_seq] = seqs[:, w * S:(w + 1) * S]
-        out = drv.step(tokens)
-        if w == t_pre // S - 1:
-            got[:, :n_cmp] = out.asnumpy()[:n_seq, S - n_cmp:] \
-                .astype(np.float32)
-    for j in range(n_cmp):
-        tokens = np.zeros((rung, 1), np.int32)
-        tokens[:n_seq, 0] = seqs[:, t_pre + j]
-        got[:, n_cmp + j] = drv.step(tokens).asnumpy()[:n_seq, 0] \
-            .astype(np.float32)
-    for slot in range(n_seq):
+
+    def step(tokens, read=True, **fed):
+        """One dispatch; the sequences' rows on the host. The program
+        lets go of what it made at once (a whole window's logits are
+        gigabytes, and the reference needs the room)."""
+        out = drv.step(tokens, **fed)
+        rows = out.asnumpy()[:n_seq].astype(np.float32) if read else None
+        del out
+        drv.release_outputs()
+        return rows
+
+    got = np.zeros((n_seq, 2 * n_cmp, V), np.float32)
+    for w in range(windows):
+        rows = step(_feed(rung, S, seqs[:, w * S:(w + 1) * S]),
+                    read=w == windows - 1)
+    got[:, :n_cmp] = rows[:, S - n_cmp:]
+    got_masked = []
+    for j in range(steps):
+        at = t_pre + j * L
+        clean = seqs[:, at:at + L]
+        if mask is not None:    # a feed that must leave nothing behind
+            got_masked.append(step(
+                _feed(rung, L, np.where(hidden[j], mask, clean))))
+            drv.rewind_many(slots, [at] * n_seq)
+        got[:, n_cmp + j * L:n_cmp + (j + 1) * L] = \
+            step(_feed(rung, L, clean))
+    # a chunk and a rider in one dispatch, then the roles swapped
+    at = [P] * n_seq
+    got_fed, program_rows = [], []
+    for widths in ((S, L), (L, S)):
+        widths = widths[:n_seq]
+        fed = np.zeros(rung, np.int64)
+        fed[:n_seq] = widths
+        rows = step(_feed(rung, S, [seqs[s, at[s]:at[s] + n]
+                                    for s, n in enumerate(widths)]),
+                    fed=fed)        # (n_seq, 1, V) packed, else (n_seq, S, V)
+        program_rows.append(int(drv.last_program_rows))
+        for s, n in enumerate(widths):
+            at[s] += n
+        # the first sequence's last fed row: window 1's chunk, window
+        # 2's rider (the second sequence is the other half of each)
+        got_fed.append((at[0] - 1,
+                        rows[0, 0 if rows.shape[1] == 1 else widths[0] - 1]))
+    for slot in slots:
         drv.leave(slot)
     drv.rewind_many(list(range(rung)), [0] * rung)
+    t1 = time.perf_counter()
 
     fwd = jax.jit(functools.partial(arch.reference_logits, cfg=config))
-    want = np.asarray(fwd(arrays, seqs))[:, t_pre - n_cmp:t_pre + n_cmp]
-    err = np.abs(got - want)
     tol = arch.LOGIT_TOL
+    want = np.asarray(fwd(arrays, seqs[:, :P]))[:, t_pre - n_cmp:P]
+    err = np.abs(got - want)
     bound = tol + tol * np.abs(want)
     ok = bool(np.all(err <= bound))       # a NaN fails
-    return ok, {"sequences": n_seq, "tokens": int(t_pre + n_cmp),
-                "positions_compared": 2 * n_cmp,
-                "max_abs_err": float(np.max(err)),
-                "max_err_over_bound": float(np.max(err / bound)),
-                "max_abs_logit": float(np.max(np.abs(want))),
-                "tolerance": tol}
+    report = {"sequences": n_seq, "tokens": int(P),
+              "positions_compared": 2 * n_cmp,
+              "max_abs_err": float(np.max(err)),
+              "max_err_over_bound": float(np.max(err / bound)),
+              "max_abs_logit": float(np.max(np.abs(want))),
+              "tolerance": tol, "decode_step_len": L,
+              "masked_feeds": len(got_masked)}
+    parent = whole_windows(engine.capacity - n_cmp)
+    if windows != parent:   # room made for part 3: other tokens than before
+        report["tokens_before_fed_windows"] = S * parent + n_cmp
+    overs = []
+    for j, rows_j in enumerate(got_masked):   # the first call's shape
+        ids = seqs[:, :P].copy()
+        at = t_pre + j * L
+        ids[:, at:at + L] = np.where(hidden[j], mask, ids[:, at:at + L])
+        overs.append(_over(
+            rows_j, np.asarray(fwd(arrays, ids))[:, at:at + L], tol)[1])
+    if overs:
+        report["masked_max_err_over_bound"] = max(overs)
+        ok = ok and all(over <= 1.0 for over in overs)
+    t2 = time.perf_counter()
+    # the first length's program leaves the chip before the second's is
+    # loaded: beside a live engine there is room for one
+    fwd.clear_cache()
+    with _no_detail():
+        want = np.asarray(fwd(arrays, seqs[:1, :P + S + L]))[0]
+    fed_err, fed_over = _over(np.stack([r for _p, r in got_fed]),
+                              np.stack([want[p] for p, _r in got_fed]), tol)
+    report["fed_windows"] = {
+        "program_rows": program_rows,
+        "packed": [r < rung * S for r in program_rows],
+        "rows_compared": len(got_fed), "max_abs_err": fed_err,
+        "max_err_over_bound": fed_over}
+    report["seconds"] = {"steps": t1 - t0, "reference": t2 - t1,
+                         "fed_windows_reference": time.perf_counter() - t2}
+    return ok and fed_over <= 1.0, report
 
 
 def _counters(model):
@@ -362,6 +533,17 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
     common.say("reference", ok=ok_ref, **report)
     correct = (ok_ref and not failed and not wrong_len
                and not compiles_in_window and n_tokens > 0)
+    compared = {
+        "reference_err_over_bound": (report["max_err_over_bound"], 1.0),
+        "fed_windows_err_over_bound":
+            (report["fed_windows"]["max_err_over_bound"], 1.0),
+        "requests_failed": (len(failed), 0),
+        "wrong_length": (len(wrong_len), 0),
+        "compiles_in_window": (len(compiles_in_window), 0),
+        "tokens_in_window_at_least": (n_tokens, 1)}
+    if "masked_max_err_over_bound" in report:
+        compared["masked_err_over_bound"] = \
+            (report["masked_max_err_over_bound"], 1.0)
 
     if trace:
         live_rows = float(np.mean(pos_samples)) if pos_samples else 0.0
@@ -377,8 +559,9 @@ def run(cell, seed, seconds, trace, device, t_start, rehearse=False):
         common.say("traced", live_rows=live_rows, counters=counters,
                    ring_records=len(ring),
                    events=len(tracer.events or []))
+        critical_path.account(obs)      # its line; it decides nothing
         metrics = common.per_layer_metrics(cell, obs)
     else:
         metrics = common.end_to_end_metrics(cell, values)
     common.result_line(correct, len(in_window), len(failed), metrics,
-                       device, peak, tracer=tracer)
+                       device, peak, tracer=tracer, compared=compared)

@@ -1,6 +1,9 @@
-"""The critical path of a decode iteration (chipbench/critical_path.py
-and the eight ``layers/`` files that ISSUE 36 added) on a scripted trace
-of three iterations small enough to check by hand. Run by tier-1 through
+"""The critical path of a decode iteration (chipbench/critical_path.py:
+the account and its ``critical_path`` line, which decide nothing) and the
+five ``layers/`` files of ISSUE 36's eight that read the flight ring, on
+a scripted trace of three iterations small enough to check by hand. The
+three that read the account's segments went with PR 59: every iteration
+has ordered impossibly since dispatches run ahead (PR 46, PR 53). Run by tier-1 through
 ``tests/test_chipbench_yardstick.py``; by hand: ``python -m pytest
 chipbench/tests/test_critical_path.py``."""
 import json
@@ -21,9 +24,11 @@ from chipbench.tests.test_spans import scripted as phases_alone  # noqa: E402
 CELLS = ("cgpt1.3b-serve-chat-closed", "olmoe-1b-7b-serve-chat-closed",
          "evabyte-6.5b-serve-longdoc-closed", "glm5.2-serve-longctx-closed")
 NEW = {"engine.stage_ms_p50.chat", "engine.launch_ms_p50.chat",
-       "engine.select_ms_p50.chat", "engine.launch_latency_ms_p50.chat",
-       "engine.wake_latency_ms_p50.chat", "sched.turnaround_ms_p50.chat",
-       "sched.loop_turn_ms_p50.chat", "sched.lock_wait_ms_p50.chat"}
+       "engine.select_ms_p50.chat", "sched.loop_turn_ms_p50.chat",
+       "sched.lock_wait_ms_p50.chat"}
+GONE = {"engine.launch_latency_ms_p50.chat",
+        "engine.wake_latency_ms_p50.chat", "sched.turnaround_ms_p50.chat",
+        "sched.iter_host_ms_p50"}
 S1, WINDOW = "jit_fwd_infer_8x1(7)", "jit_fwd_infer_8x64(9)"
 
 
@@ -180,20 +185,26 @@ def _ring():
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_the_eight_layer_files_read_ring_and_trace(cell):
+    """Five files since PR 59 (the name is what tier-1 imports,
+    ``tests/test_chipbench_yardstick.py``): the ring's fields, whatever
+    the trace holds; the account's three segments are no metric's."""
     per_layer = {m.name: m for m in
                  manifest.resolve(manifest.load(), cell).per_layer}
-    assert NEW <= set(per_layer)
+    assert NEW <= set(per_layer) and not GONE & set(per_layer)
+    assert not [g for g in GONE for ext in (".py", ".json")
+                if os.path.exists(os.path.join(ROOT, "chipbench", "layers",
+                                               g + ext))]
     obs = {"events": scripted(), "ring": _ring()}
+    p50 = critical_path.account(obs)["p50_ms"]
+    assert (p50["launch_latency"], p50["wake_latency"], p50["turnaround"]) \
+        == (pytest.approx(0.150), pytest.approx(0.050), pytest.approx(0.250))
     got = {n: readers.read(per_layer[n], obs) for n in NEW}
     assert got == {
         "engine.stage_ms_p50.chat": pytest.approx(0.2),
         "engine.launch_ms_p50.chat": pytest.approx(2.0),
         "engine.select_ms_p50.chat": pytest.approx(0.02),
         "sched.loop_turn_ms_p50.chat": pytest.approx(0.04),
-        "sched.lock_wait_ms_p50.chat": pytest.approx(0.006),
-        "engine.launch_latency_ms_p50.chat": pytest.approx(0.150),
-        "engine.wake_latency_ms_p50.chat": pytest.approx(0.050),
-        "sched.turnaround_ms_p50.chat": pytest.approx(0.250)}
+        "sched.lock_wait_ms_p50.chat": pytest.approx(0.006)}
     for m in per_layer.values():
         if m.name in NEW:
             assert (m.unit, m.source, m.moves, m.layer) == (
@@ -242,18 +253,17 @@ def _read(names, obs):
 
 
 def test_without_a_marker_the_crossing_latencies_read_as_none():
-    """Nothing ties the planes' clocks: the two latencies that cross
-    from one to the other are None; the turn-around (host alone) and the
-    ring's fields are read as ever."""
+    """Nothing ties the planes' clocks: the account says so
+    (``skew_ms`` None, no marker; its two segments that cross from one
+    plane to the other then mean nothing) and the ring's fields are read
+    as ever."""
     obs = {"events": scripted(markers=False), "ring": _ring()}
     assert critical_path.skew(obs) is None
     found = critical_path.account(obs)
     assert found["skew_ms"] is None and found["skew_markers"] == 0
     assert found["p50_ms"]["device"] == pytest.approx(0.510)
-    assert _read(sorted(NEW), obs) == dict(
-        _read(sorted(NEW), {"events": scripted(), "ring": _ring()}),
-        **{"engine.launch_latency_ms_p50.chat": None,
-           "engine.wake_latency_ms_p50.chat": None})
+    assert _read(sorted(NEW), obs) == \
+        _read(sorted(NEW), {"events": scripted(), "ring": _ring()})
 
 
 @pytest.mark.parametrize("fault", [
@@ -262,8 +272,8 @@ def test_an_impossible_order_reads_as_none_with_its_count(fault, capsys):
     """Ids 80 us early are on the host before ``select_rows`` ended, in
     one iteration of four; a device plane 260 us early that no marker
     ties back starts every program before its launch span. Either way
-    the three trace metrics are None and the line says how many
-    iterations said so."""
+    the account has no medians and its line says how many iterations
+    said so."""
     obs = {"events": scripted(**fault), "ring": _ring()}
     found = critical_path.account(obs)
     assert found["impossible"] == (4 if "skew_us" in fault else 1)
@@ -273,10 +283,6 @@ def test_an_impossible_order_reads_as_none_with_its_count(fault, capsys):
     line = capsys.readouterr().out
     assert '"chipbench": "critical_path"' in line and \
         f'"impossible": {found["impossible"]}' in line
-    trace_metrics = ("engine.launch_latency_ms_p50.chat",
-                     "engine.wake_latency_ms_p50.chat",
-                     "sched.turnaround_ms_p50.chat")
-    assert _read(trace_metrics, obs) == dict.fromkeys(trace_metrics)
     # the ring's fields do not depend on the trace's clocks
     assert _read(["engine.stage_ms_p50.chat"], obs) == \
         {"engine.stage_ms_p50.chat": pytest.approx(0.2)}

@@ -133,7 +133,7 @@ def test_the_traffic_is_the_issues():
         < cell.config["capacity"]
     names = {m.name for m in cell.per_layer}
     assert set(NEW_METRICS) <= names
-    assert {"sched.iter_host_ms_p50", "engine.fetch_ms_p50.chat",
+    assert {"engine.step_ms_p50", "engine.fetch_ms_p50.chat",
             "sched.runahead_share_of_steps", "attn.read_share_of_step",
             "decode_program_roofline",
             "sched.riding_share_of_window_slots",
@@ -149,10 +149,10 @@ def test_the_traffic_is_the_issues():
         "serve_tokens_per_s", "serve_ttft_p90_ms", "setup_s"}
     for m in man["per_layer"]:
         if m["name"] in NEW_METRICS:
-            assert m["workloads"] == [REAL_CELL]
+            assert m["workloads"][0] == REAL_CELL   # later cells join
             assert (m["moves"], m["source"], m["layer"]) == (
                 "serve_tokens_per_s", "device_trace", "kernels")
-    assert len(man["workloads"]) == 11
+    assert len(man["workloads"]) >= 11          # eleven when PR 48 wrote it
     assert sum(w["chips"] == 4 for w in man["workloads"]) == 1
 
 
