@@ -38,6 +38,7 @@ from ..base import MXNetError
 
 __all__ = ["get_symbol", "get_decode_symbol", "SyntheticLMIter",
            "KVCacheDecoder", "BatchedKVCacheDecoder", "slot_state",
+           "decode_procedure",
            "default_cache_capacity", "default_cache_dtype"]
 
 
@@ -266,22 +267,27 @@ def _latent_attention(x, fed, selection, i, spec):
 
 
 def _grouped_attention(x, fed, carry, i, spec):
-    """Trinity's (``spec["cfg"]``: ``_afmoe_spec``): attention over
-    grouped K/V heads (``n_head`` query heads on ``num_key_value_heads``,
-    q and k normed per head), on a layer ``layer_types`` marks
-    ``sliding_attention`` with rotary positions and a window whose
-    pools are rings, on a ``full_attention`` layer with neither; its
-    output gated by a sigmoid of a projection of the layer's input. q,
-    k and v are unpacked before their per-head norms, where the S = 1
+    """Attention over grouped K/V heads (``n_head`` query heads on
+    ``num_key_value_heads``, q and k normed per head), what differs a
+    record (``spec["cfg"]``). Trinity's (``_afmoe_spec``): on a layer
+    ``layer_types`` marks ``sliding_attention`` rotary positions and a
+    window whose pools are rings, on a ``full_attention`` layer
+    neither; its output gated by a sigmoid of a projection of the
+    layer's input. SDAR's (``_sdar_spec``): no gate (``gate`` False),
+    rotary on every layer (``rope_full``), no window, and the mask's
+    upper edge the end of the query's block (``block_length``). q, k
+    and v are unpacked before their per-head norms, where the S = 1
     program's text has them."""
     pfx, cfg, n_head = f"{spec['name']}_l{i}", spec["cfg"], spec["n_head"]
     n_kv, dh = cfg["num_key_value_heads"], cfg["head_dim"]
     sliding = cfg["layer_types"][i] == "sliding_attention"
+    gated = cfg.get("gate", True)
 
     rows = sym.Reshape(x, shape=(-3, 0), name=f"{pfx}_attn_fold")  # (B*T, D)
     # q, k, v and the gate as the row blocks of one projection
-    wide = sym.FullyConnected(rows, num_hidden=2 * (n_head + n_kv) * dh,
-                              no_bias=True, name=f"{pfx}_qkvg")
+    wide = sym.FullyConnected(
+        rows, num_hidden=((2 if gated else 1) * n_head + 2 * n_kv) * dh,
+        no_bias=True, name=f"{pfx}_qkvg" if gated else f"{pfx}_qkv")
     at, heads = 0, {}
     for nm, n in (("q", n_head), ("k", n_kv), ("v", n_kv)):
         part = sym.slice_axis(wide, axis=1, begin=at, end=at + n * dh,
@@ -293,16 +299,20 @@ def _grouped_attention(x, fed, carry, i, spec):
             part = _norm(part, f"{pfx}_{nm}_norm", spec)
         heads[nm] = sym.transpose(part, axes=(0, 2, 1, 3),
                                   name=f"{pfx}_{nm}")        # (B, n, T, dh)
-    gate = sym.slice_axis(wide, axis=1, begin=at, end=at + n_head * dh,
-                          name=f"{pfx}_gate_rows")
     att = sym.attention_decode(
         heads["q"], heads["k"], heads["v"], fed, capacity=spec["capacity"],
-        rope=sliding, rope_base=spec["rope_base"], per_slot=True,
+        rope=sliding or cfg.get("rope_full", False),
+        rope_base=spec["rope_base"], per_slot=True,
         kv_heads=n_kv, fed=True, name=f"{pfx}_attn",
         **({"window": cfg["sliding_window"], "ring": cfg["ring"]}
-           if sliding else {}))
+           if sliding else {}),
+        **({"block": cfg["block_length"]} if cfg.get("block_length") else {}))
     att = sym.transpose(att, axes=(0, 2, 1, 3), name=f"{pfx}_attn_t")
     att = _packed_rows(att, fed, f"{pfx}_attn_merge", fold=(-3, -3))
+    if not gated:
+        return att, None
+    gate = sym.slice_axis(wide, axis=1, begin=at, end=at + n_head * dh,
+                          name=f"{pfx}_gate_rows")
     return att * sym.Activation(gate, act_type="sigmoid",
                                 name=f"{pfx}_gate"), None
 
@@ -886,6 +896,92 @@ def _afmoe_spec(spec):
         fed=True, pos_embed="rotary", residual="normed", tie_head=False)
 
 
+#: the keys of SDAR's published ``config.json`` (``model_type sdar_moe``)
+#: that ``block="sdar_moe"`` reads (``get_decode_symbol(sdar=...)``), and
+#: after them how the model decodes, which the file does not state and
+#: the published generation procedure does (``DECODE_KEYS``)
+SDAR_KEYS = ("num_key_value_heads", "head_dim", "num_experts",
+             "num_experts_per_tok", "moe_intermediate_size",
+             "norm_topk_prob", "block_length", "mask_token_id",
+             "denoising_steps", "remasking", "confidence_threshold")
+
+#: how a graph that decodes by blocks says so (``decode_procedure``):
+#: the block's positions, the id that stands for a position not yet
+#: decided, and a request's defaults - the feeds a block is denoised in,
+#: which positions a feed decides, the confidence past which a position
+#: is decided whatever the quota
+DECODE_KEYS = ("block_length", "mask_token_id", "denoising_steps",
+               "remasking", "confidence_threshold")
+
+#: ``remasking``: a feed decides its quota's most confident positions
+#: (``low_confidence_static``), or those and every other whose
+#: confidence passes the threshold (``low_confidence_dynamic``)
+REMASKING = ("low_confidence_static", "low_confidence_dynamic")
+
+
+def _sdar_spec(spec):
+    """SDAR's block (per-slot only) from ``sdar``, the published
+    config's keys and the published generation procedure's
+    (``SDAR_KEYS``), no bias anywhere: RMSNorm, ``_grouped_attention``
+    without gate, window or ring and with rotary positions on every
+    layer, softmax-routed experts on every layer (all held, the chosen
+    weights normed where ``norm_topk_prob``), no shared expert, an
+    unscaled embedding and an untied head - under one changed rule: a
+    query attends every key up to the END of its own block of
+    ``block_length`` positions (``attention_decode(block=)``). **The
+    graph says how it decodes** (``decode_procedure``): a step is a
+    block of ``block_length`` positions, some of them
+    ``mask_token_id``, fed until every position is decided and then
+    once more to keep its keys and values; row ``t`` of the logits
+    scores the token AT position ``t``."""
+    cfg = _given_keys(spec, "sdar", SDAR_KEYS)
+    n_head, L = spec["n_head"], int(cfg["block_length"])
+    if n_head % cfg["num_key_value_heads"] or cfg["head_dim"] % 2:
+        raise MXNetError(
+            f"block='sdar_moe': {n_head} query heads on "
+            f"{cfg['num_key_value_heads']} K/V heads of "
+            f"{cfg['head_dim']}: the K/V heads divide the query "
+            "heads, and a head's width is even (rotary pairs)")
+    if L < 1 or spec["T"] % L and spec["T"] != 1 or spec["capacity"] % L:
+        raise MXNetError(
+            f"block='sdar_moe': block_length {L} divides neither the "
+            f"{spec['T']} rows a slot of this graph nor the capacity "
+            f"{spec['capacity']}: every dispatch is whole blocks (the "
+            "S = 1 graph is bound beside them and serves no request)")
+    steps = int(cfg["denoising_steps"])
+    if not 1 <= steps <= L or cfg["remasking"] not in REMASKING \
+            or not 0 <= int(cfg["mask_token_id"]) < spec["vocab_size"]:
+        raise MXNetError(
+            f"block='sdar_moe': denoising_steps {steps} of 1..{L}, "
+            f"remasking {cfg['remasking']!r} of {REMASKING}, "
+            f"mask_token_id {cfg['mask_token_id']} inside the "
+            f"vocabulary of {spec['vocab_size']}")
+    cfg.update(layer_types=["full_attention"] * spec["n_layer"],
+               gate=False, rope_full=True, block_length=L)
+    return dict(
+        spec, cfg=cfg, norm=_rms(spec), attention=_grouped_attention,
+        bias=False, dense_layers=0, moe_fold="ffn_fold",
+        moe=dict(step_len=spec["T"], num_experts=cfg["num_experts"],
+                 num_hidden=cfg["moe_intermediate_size"],
+                 top_k=cfg["num_experts_per_tok"],
+                 norm_topk=bool(cfg["norm_topk_prob"])),
+        fed=True, pos_embed="rotary", tie_head=False, embed_scale=False,
+        procedure={k: cfg[k] for k in DECODE_KEYS})
+
+
+def decode_procedure(symbol):
+    """How a slot-pooled graph decodes where that is not "one token a
+    step": ``DECODE_KEYS`` of a graph that decodes by blocks, as its
+    builder wrote them on the output (``block="sdar_moe"``), None of
+    any other graph. ``DecodeEngine`` reads it, the way ``slot_state``
+    reads the state families."""
+    node = symbol._outputs[0][0]
+    if "__decode_block_length__" not in node._extra:
+        return None
+    kinds = dict(zip(DECODE_KEYS, (int, int, int, str, float)))
+    return {k: kinds[k](node._extra[f"__decode_{k}__"]) for k in DECODE_KEYS}
+
+
 #: the keys of Granite 4.0-H's published ``config.json`` (``model_type
 #: granitemoehybrid``) that ``block="granite_hybrid"`` reads
 #: (``get_decode_symbol(granite=...)``); ``layer_types`` one entry a
@@ -1076,7 +1172,7 @@ def _ling_spec(spec):
 _SPECS = {"gpt2": _gpt2_spec, "olmoe": _olmoe_spec, "evabyte": _eva_spec,
           "glm_dsa": _glm_spec, "axk1": _axk1_spec, "afmoe": _afmoe_spec,
           "xing4": _xing4_spec, "granite_hybrid": _granite_spec,
-          "ling_hybrid": _ling_spec}
+          "ling_hybrid": _ling_spec, "sdar_moe": _sdar_spec}
 
 
 def _spec(given, decode):
@@ -1104,7 +1200,8 @@ def _spec(given, decode):
         "rms_eps": float(given["rms_eps"]),
         # what most blocks do not have
         "fed": False, "residual": "plain", "heads": 1, "next_byte": False,
-        "moe": None, "dense": None, "multipliers": None})
+        "moe": None, "dense": None, "multipliers": None,
+        "procedure": None})
 
 
 def _embedded(spec):
@@ -1209,7 +1306,7 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                       chunk=16, n_pred_heads=1, ffn_width=None,
                       multibyte=False, glm=None, afmoe=None,
                       max_step_len=None, axk1=None, xing4=None,
-                      granite=None, ling=None):
+                      granite=None, ling=None, sdar=None):
     """Incremental KV-cache decoder: ``(B, step_len)`` new token ids in,
     logits ``(B, step_len, vocab)`` out, per-layer K/V caches of
     ``capacity`` positions riding executor aux state. Parameter names
@@ -1245,8 +1342,10 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     ``AXK1_KEYS``), ``"xing4"`` (``_xing4_spec``: ``xing4``,
     ``XING4_KEYS``), ``"afmoe"`` (``_afmoe_spec``: ``afmoe``,
     ``AFMOE_KEYS``; ``max_step_len``), ``"granite_hybrid"``
-    (``_granite_spec``: ``granite``, ``GRANITE_KEYS``) and
-    ``"ling_hybrid"`` (``_ling_spec``: ``ling``, ``LING_KEYS``).
+    (``_granite_spec``: ``granite``, ``GRANITE_KEYS``),
+    ``"ling_hybrid"`` (``_ling_spec``: ``ling``, ``LING_KEYS``) and
+    ``"sdar_moe"`` (``_sdar_spec``: ``sdar``, ``SDAR_KEYS``; its graph
+    says that it decodes by blocks, ``decode_procedure``).
 
     Every slot-pooled graph (``per_slot=True``, whatever the block)
     takes one more input, ``fed`` ``(slots,)`` int32 - how many of each
@@ -1270,7 +1369,11 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     if spec["next_byte"]:
         logits = sym.slice_axis(logits, axis=1, begin=0, end=vocab_size,
                                 name=f"{name}_next_byte")
-    return _slots(logits, fed, step_len, f"{name}_logits_bsv")
+    out = _slots(logits, fed, step_len, f"{name}_logits_bsv")
+    if spec["procedure"]:
+        out._set_attr(**{f"__decode_{k}__": str(v)
+                         for k, v in spec["procedure"].items()})
+    return out
 
 
 class SyntheticLMIter:
@@ -1684,6 +1787,7 @@ class BatchedKVCacheDecoder:
         self._row_progs = None                       # capture, restore
         self._select_programs = {}                   # step_len -> program
         self._merge_programs = {}                    # step_len -> program
+        self._denoise_programs = {}                  # block length -> program
         self._moe_program = None                     # built at first use
         exe = module._exec_group.executor
         # what every step program takes over and updates in place (the
@@ -2022,6 +2126,70 @@ class BatchedKVCacheDecoder:
             ids.copy_to_host_async()
         self.last_select = None if now is None else now() - t0
         return rows, ids, tokens
+
+    def denoise_select(self, out, ids, undecided, quota, threshold,
+                       now=None):
+        """What one feed of a block decides, on the device: from a
+        step's ``(slots, L, V)`` output as it lies there, the block's
+        ``ids`` ``(slots, L)`` as they were fed, which of its positions
+        are ``undecided`` ``(slots, L)`` bools, and each slot's
+        ``quota`` ``(slots,)`` ints and ``threshold`` ``(slots,)``
+        floats: at every undecided position ``x0 = argmax`` (the first
+        maximum) with confidence ``c = softmax(logits)[x0]`` in
+        float32; the positions with ``c > threshold`` are decided, and
+        the ``quota`` most confident whatever the threshold (the
+        earlier position first among equals). Returns ``(2, slots,
+        L)`` int32 on the device, its copy to the host started: the
+        ids with ``x0`` at the positions decided, and 1 where a
+        position is still undecided. A slot with nothing undecided (a
+        feed that commits; a row nobody owns) comes back as it went
+        in. One launch of ``denoise_select_<slots>x<L>`` (its name in
+        the trace): **ids and a mask cross to the host, 8 x L bytes a
+        slot, never logits**, as ``select_rows`` does for one row. The
+        annotation is ``decode.denoise_select``; ``now`` makes
+        ``last_select`` its seconds."""
+        t0 = None if now is None else now()
+        with _telemetry.span("decode.denoise_select"):
+            arr = out.asjax()
+            L = arr.shape[1]
+            ids = np.asarray(ids, np.int32)
+            undecided = np.asarray(undecided, bool)
+            if arr.ndim != 3 or ids.shape != (self.slots, L) \
+                    or undecided.shape != (self.slots, L):
+                raise MXNetError(
+                    f"denoise_select() wants ({self.slots}, L, V) rows "
+                    f"with ({self.slots}, L) ids and flags, got "
+                    f"{arr.shape}, {ids.shape}, {undecided.shape}")
+            program = self._denoise_programs.get(L)
+            if program is None:
+                import jax
+                import jax.numpy as jnp
+
+                def denoise_select(rows, ids, undecided, quota, threshold):
+                    rows = rows.astype(jnp.float32)
+                    x0 = jnp.argmax(rows, axis=-1).astype(jnp.int32)
+                    conf = jnp.where(
+                        undecided,
+                        jnp.max(jax.nn.softmax(rows, axis=-1), axis=-1),
+                        -jnp.inf)
+                    # a position's place among its block's confidences
+                    order = jnp.argsort(-conf, axis=-1, stable=True)
+                    rank = jnp.argsort(order, axis=-1, stable=True)
+                    decided = undecided & ((conf > threshold[:, None])
+                                           | (rank < quota[:, None]))
+                    return jnp.stack([
+                        jnp.where(decided, x0, ids),
+                        (undecided & ~decided).astype(jnp.int32)])
+
+                denoise_select.__name__ = f"denoise_select_{self.slots}x{L}"
+                program = self._denoise_programs[L] = jax.jit(denoise_select)
+            state = program(arr, ids, undecided,
+                            np.asarray(quota, np.int32).reshape(self.slots),
+                            np.asarray(threshold, np.float32)
+                            .reshape(self.slots))
+            state.copy_to_host_async()
+        self.last_select = None if now is None else now() - t0
+        return state
 
     def merge_tokens(self, tokens, ids, chip):
         """The token input of a step that takes some slots' first token

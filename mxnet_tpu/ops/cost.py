@@ -233,6 +233,9 @@ def _attention_bytes(attrs, in_shapes):
 
 def _attention_decode_flops(attrs, in_shapes):
     # S query tokens against the full C-capacity cache: qk^T + pv
+    # (``block=L``, a query attending to the end of its block and not to
+    # itself alone, moves the edge inside the S rows counted here: the
+    # same keys, the same bound)
     b, h, s, d = in_shapes[0]
     c = parse_int(attrs.get("capacity", 256))
     return 4.0 * b * h * s * c * d

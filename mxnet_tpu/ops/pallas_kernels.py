@@ -1099,7 +1099,7 @@ def _key_positions(block, block_k, last, shape, ring):
 
 
 def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv,
-                        period=0, window=0, ring=0):
+                        period=0, window=0, ring=0, block=0):
     """Grid (slot, head group, key block); the group's heads in a loop
     of ``_READ_UNROLL`` heads a turn. ``period``: a head of the pool is
     read by a group of query heads, whose rows lie one head after
@@ -1107,7 +1107,10 @@ def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv,
     ``cursor + r % period``); 0: a row a position. ``window``: a query
     at ``t`` attends ``j > t - window`` alone, and the steps walk the
     blocks from the first that holds such a key (``_live_blocks``);
-    ``ring``: the pool is a ring of that many rows. ``narrow_q``: q and the cache
+    ``ring``: the pool is a ring of that many rows. ``block``: a model
+    that decodes by blocks of that many positions - a query attends up
+    to the end of its own block (``j < (t // block + 1) * block``) and
+    never past the newest row written. ``narrow_q``: q and the cache
     rows are bfloat16 values, so q.K is one bfloat16 product with
     float32 accumulation, exact as the composition's; ``narrow_kv``:
     the rows are, so p.V is the float32 p split in three against the
@@ -1117,6 +1120,7 @@ def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv,
     f32, bf16 = jnp.float32, jnp.bfloat16
     exact = jax.lax.Precision.HIGHEST
     turn = _divisor_block(hb, _READ_UNROLL)
+    step = block                # (``block`` is a key block's index below)
 
     def widen(ref, h, to):
         x = ref[h]
@@ -1186,7 +1190,12 @@ def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv,
             else:
                 k_pos = k_start + jax.lax.broadcasted_iota(
                     jnp.int32, (s_len, block_k), 1)
-                attends = k_pos <= q_pos
+                if step:
+                    attends = k_pos < jnp.minimum(
+                        (q_pos // step + 1) * step,
+                        cursor + (period or s_len))
+                else:
+                    attends = k_pos <= q_pos
 
             def head(h):
                 if narrow_q:
@@ -1232,7 +1241,7 @@ def _decode_attn_kernel(hb, block_k, s_len, scale, narrow_q, narrow_kv,
 
 
 def decode_attention(q, k_cache, v_cache, pos, window=0, ring=False,
-                     scale=None, riding=None):
+                     scale=None, riding=None, block=0):
     """Cursor-bounded flash-decode read over a fixed-capacity KV cache.
 
     ``q`` is (B, H, S, Dh) already-rotated queries, the caches are
@@ -1259,6 +1268,9 @@ def decode_attention(q, k_cache, v_cache, pos, window=0, ring=False,
     start at the first that holds such a key, so the traffic is the
     window's, whatever the context. ``ring``: the pools are rings, a
     position at its value modulo ``C`` (``C >= window + S``).
+    **Blocks** (``block`` > 0, no window): the query at ``t`` attends
+    ``j < (t // block + 1) * block``, every position of its own block,
+    and none past the ``S`` rows the dispatch wrote.
 
     **The riders of a window program** (``riding``, a (B,) mask, with
     every slot's first query as ``q``): a slot fed one row of a long
@@ -1273,6 +1285,8 @@ def decode_attention(q, k_cache, v_cache, pos, window=0, ring=False,
     ``decode_attn`` in the device trace), so that a step program lowers
     it once and calls it from every layer."""
     more = {} if scale is None else {"scale": float(scale)}
+    if block:
+        more["block"] = int(block)
     pos = pos.astype(jnp.int32)
     if riding is not None:
         pos = jnp.where(riding, pos, -1)
@@ -1283,10 +1297,10 @@ def decode_attention(q, k_cache, v_cache, pos, window=0, ring=False,
 
 
 @partial(jax.jit, static_argnames=("interpret", "name", "window", "ring",
-                                    "scale", "dead_slots"))
+                                    "scale", "dead_slots", "block"))
 def _decode_attention(pos, q, k_cache, v_cache, interpret,
                       name="decode_attn", window=0, ring=False, scale=None,
-                      dead_slots=False):
+                      dead_slots=False, block=0):
     """``decode_attention`` as one jitted program; ``name`` is the
     kernel's in the device trace. ``dead_slots``: a cursor may be -1, a
     slot no step of which is live (the kernel's own rule: no key lies
@@ -1296,7 +1310,7 @@ def _decode_attention(pos, q, k_cache, v_cache, interpret,
 
     B, heads, S, Dh = q.shape
     H, C = k_cache.shape[1:3]
-    period = 0
+    period, step = 0, block     # (``block`` is a BlockSpec below)
     if heads != H:
         # the group's query heads as the rows of their K/V head
         period, q = S, q.reshape(B, H, heads // H * S, Dh)
@@ -1360,6 +1374,8 @@ def _decode_attention(pos, q, k_cache, v_cache, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary"))}
     geometry = {} if not (period or window) else {
         "period": period, "window": window, "ring": C if ring else 0}
+    if step:
+        geometry["block"] = step
     out = pallas_call(
         _decode_attn_kernel(hb, block_k, rows_n,
                             float(Dh) ** -0.5 if scale is None else scale,
@@ -1393,17 +1409,22 @@ def _window_attn_blocks(G, S, C):
                                           max(1, _READ_BLOCK_K // unit))
 
 
-def _window_attn_kernel(G, block_q, block_k, scale, narrow, window, ring):
+def _window_attn_kernel(G, block_q, block_k, scale, narrow, window, ring,
+                        block=0):
     """Grid (slot, K/V head, query block, key block): the online softmax
     of one query block - ``G`` query heads x ``block_q`` positions as
     the rows of one product - against one key block of their K/V head.
     The steps of a query block walk the key blocks that hold a key one
     of its queries attends (``_live_blocks``: bounded below by the
     window, above by causality), and a query block wholly past ``fed``
-    (pads) walks none and comes out zero."""
+    (pads) walks none and comes out zero. ``block``: a model that
+    decodes by blocks of that many positions - the upper bound is the
+    end of the query's own block (and the newest row written), which
+    moves the mask of the diagonal key blocks alone."""
     f32, bf16 = jnp.float32, jnp.bfloat16
     exact = jax.lax.Precision.HIGHEST
     rows = G * block_q
+    step = block                # (``block`` is a key block's index below)
 
     def kernel(pos_ref, fed_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s,
                acc_s):
@@ -1414,6 +1435,8 @@ def _window_attn_kernel(G, block_q, block_k, scale, narrow, window, ring):
         q_hi = q_lo + (block_q - 1)
         last = cursor + (n_q * block_q - 1)      # the newest row written
         lo = jnp.maximum(q_lo - window + 1, 0) if window else 0
+        if step:        # the last query's block may end past it
+            q_hi = jnp.minimum((q_hi // step + 1) * step - 1, last)
         first, count = _live_blocks(lo, q_hi, block_k, n_kb, ring)
         count = jnp.where(i * block_q < fed_ref[b], count, 0)
 
@@ -1431,7 +1454,12 @@ def _window_attn_kernel(G, block_q, block_k, scale, narrow, window, ring):
             k_pos = _key_positions(block, block_k, last, (1, block_k), ring)
             row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
             q_pos = q_lo + (jax.lax.rem(row, block_q) if G > 1 else row)
-            attends = (k_pos <= q_pos) & (k_pos >= 0)
+            if step:
+                attends = k_pos < jnp.minimum(
+                    (q_pos // step + 1) * step,
+                    cursor + jnp.maximum(fed_ref[b], 1))
+            else:
+                attends = (k_pos <= q_pos) & (k_pos >= 0)
             if window:
                 attends = attends & (k_pos > q_pos - window)
             q = q_ref[...].reshape(rows, q_ref.shape[-1])
@@ -1465,7 +1493,7 @@ def _window_attn_kernel(G, block_q, block_k, scale, narrow, window, ring):
 
 
 def window_attention(q, k_cache, v_cache, pos, fed, window=0, ring=False,
-                     scale=None):
+                     scale=None, block=0):
     """The read of a long window (chunked prefill): ``q`` (B, H, S, Dh)
     already-rotated queries of a slot's ``S`` positions from its cursor
     ``pos`` (B,) on, of which ``fed`` (B,) are real; the caches (B,
@@ -1480,20 +1508,25 @@ def window_attention(q, k_cache, v_cache, pos, fed, window=0, ring=False,
     computes nothing, fetches no key block but its first and comes out
     zero. ``ring``: the pools are rings of ``C >= window + S`` rows.
     p.V is one bfloat16 product where the rows are bfloat16 values
-    (float32 at HIGHEST otherwise).
+    (float32 at HIGHEST otherwise). ``block`` (no window): a query
+    attends up to the end of its own block of that many positions, and
+    never past ``pos + fed``.
     Returns (B, H, S, Dh) at ``q``'s dtype.
 
     A jitted function of its own: the kernel is ``window_attn`` in the
     device trace, lowered once a step program."""
     more = {} if scale is None else {"scale": float(scale)}
+    if block:
+        more["block"] = int(block)
     return _window_attention(pos.astype(jnp.int32), fed.astype(jnp.int32),
                              q, k_cache, v_cache, interpret=_interpret(),
                              window=int(window), ring=bool(ring), **more)
 
 
-@partial(jax.jit, static_argnames=("interpret", "window", "ring", "scale"))
+@partial(jax.jit, static_argnames=("interpret", "window", "ring", "scale",
+                                    "block"))
 def _window_attention(pos, fed, q, k_cache, v_cache, interpret, window,
-                      ring, scale=None):
+                      ring, scale=None, block=0):
     from jax.experimental.pallas import tpu as pltpu
 
     B, heads, S, Dh = q.shape
@@ -1502,6 +1535,7 @@ def _window_attention(pos, fed, q, k_cache, v_cache, interpret, window,
     block_q, block_k = _window_attn_blocks(G, S, C)
     n_kb = C // block_k
     qg = q.reshape(B, H, G, S, Dh)
+    step = block                # (``block`` is a BlockSpec below)
 
     def _q_map(b, h, i, j, pos_ref, fed_ref):
         return (b, h, 0, i, 0)
@@ -1512,8 +1546,12 @@ def _window_attention(pos, fed, q, k_cache, v_cache, interpret, window,
         ``fed``: the kernel walks none of its blocks) on its first."""
         q_lo = pos_ref[b] + i * block_q
         lo = jnp.maximum(q_lo - window + 1, 0) if window else 0
-        first, count = _live_blocks(lo, q_lo + (block_q - 1), block_k,
-                                    n_kb, C if ring else 0)
+        q_hi = q_lo + (block_q - 1)
+        if step:
+            q_hi = jnp.minimum((q_hi // step + 1) * step - 1,
+                               pos_ref[b] + (S - 1))
+        first, count = _live_blocks(lo, q_hi, block_k, n_kb,
+                                    C if ring else 0)
         count = jnp.where(i * block_q < fed_ref[b], count, 1)
         block = first + jnp.minimum(j, count - 1)
         return (b, h, jax.lax.rem(block, n_kb) if ring else block, 0)
@@ -1537,7 +1575,8 @@ def _window_attention(pos, fed, q, k_cache, v_cache, interpret, window,
     out = pallas_call(
         _window_attn_kernel(G, block_q, block_k,
                             float(Dh) ** -0.5 if scale is None else scale,
-                            narrow, window, C if ring else 0),
+                            narrow, window, C if ring else 0,
+                            **({"block": step} if step else {})),
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         grid_spec=grid_spec, name="window_attn", interpret=interpret,
         **kwargs)(pos, fed, qg, k_cache, v_cache)

@@ -669,10 +669,17 @@ def _register_attention():
 #   slot's S rows are real. The cursor advances by that (by 0 where S
 #   rows do not fit under the capacity), so nothing runs ahead and
 #   nothing is rewound after a window; the pads' rows are written
-#   behind the cursor, where the next dispatch writes over them.
+#   behind the cursor, where the next dispatch writes over them;
+# * ``block`` — a model that decodes by blocks of that many positions
+#   (counted from position 0): the query at t attends every key up to
+#   the END of its own block, ``j < (t // block + 1) * block``, and
+#   never past the rows the dispatch wrote (``j < cursor + fed``). Inside
+#   a block every position sees every other; across blocks the mask is
+#   the causal one. S is a multiple of ``block``; no window, no ring.
 # --------------------------------------------------------------------------
 _Geometry = namedtuple("_Geometry",
-                       "groups window ring fed capacity scale")
+                       "groups window ring fed capacity scale block",
+                       defaults=(0,))
 
 
 def _decode_geometry(attrs, q, k_cache):
@@ -694,9 +701,20 @@ def _decode_geometry(attrs, q, k_cache):
         raise MXNetError("attention_decode: kv_heads, window, fed and scale "
                          "are the slot-pooled lowering's (per_slot=True)")
     scale = attrs.get("scale")
+    block = int(attrs.get("block") or 0)
+    if block and (window or ring
+                  or q.shape[2] != 1 and q.shape[2] % block
+                  or not parse_bool(attrs.get("per_slot", False))):
+        raise MXNetError(
+            f"attention_decode: block={block} with window {window}, ring "
+            f"{ring}, {q.shape[2]} rows a dispatch: a model that decodes "
+            "by blocks has no window= and no ring=, its dispatches are "
+            "whole blocks (S a multiple of block, or the one row of the "
+            "S = 1 program) and its graph is the slot-pooled one "
+            "(per_slot=True)")
     return _Geometry(H // Hkv, window, ring, fed,
                      int(attrs.get("capacity", 256)),
-                     None if scale is None else float(scale))
+                     None if scale is None else float(scale), block)
 
 
 def _fed_cursor(geo, pos, S, fed):
@@ -882,6 +900,14 @@ def _attention_decode_per_slot(attrs, q, k, v, k_cache, v_cache, cursor,
         last = (pos + (S - 1))[:, None]
         key_pos = (last - (last - key_pos[None, :]) % geo.ring)[:, None, :]
         mask = (key_pos <= q_pos[:, :, None]) & (key_pos >= 0)
+    elif geo.block:
+        # up to the end of the query's own block, and never past what
+        # this dispatch wrote
+        written = pos + (S if fed is None else
+                         _fed_cursor(geo, pos, S, fed)[0])
+        edge = jnp.minimum((q_pos // geo.block + 1) * geo.block,
+                           jnp.maximum(written, pos + 1)[:, None])
+        mask = key_pos[None, None, :] < edge[:, :, None]
     else:
         mask = key_pos[None, None, :] <= q_pos[:, :, None]
     if geo.window:
@@ -952,10 +978,14 @@ def _attention_decode_pallas_variant(attrs, inputs, aux, is_train, rng):
     geometry = _read_geometry(geo)
     if geo.groups * S <= _DECODE_ROWS:
         out = decode_attention(q, k_cache, v_cache, pos_rows, **geometry)
-    elif fed is None:
-        # a long window: the read that tiles the queries too
-        out = window_attention(q, k_cache, v_cache, pos_rows,
-                               jnp.full((B,), S, jnp.int32), **geometry)
+    elif fed is None or geo.block:
+        # a long window: the read that tiles the queries too (a graph
+        # that decodes by blocks feeds whole blocks: nobody rides with
+        # one row)
+        out = window_attention(
+            q, k_cache, v_cache, pos_rows,
+            jnp.full((B,), S, jnp.int32) if fed is None else fed,
+            **geometry)
     else:
         # which read a slot of a long window takes is read from ``fed``:
         # a slot fed one row (a decoding slot riding a window in which
@@ -985,6 +1015,8 @@ def _read_geometry(geo):
     every key at or before the query is attended and the scores are
     scaled by the head's width."""
     more = {} if geo.scale is None else {"scale": geo.scale}
+    if geo.block:
+        more["block"] = geo.block
     return {"window": geo.window, "ring": bool(geo.ring), **more} \
         if geo.window else more
 
@@ -1168,7 +1200,8 @@ def _register_attention_decode():
                             "window": (int, None),
                             "ring": (int, None),
                             "fed": (None, None),
-                            "scale": (float, None)},
+                            "scale": (float, None),
+                            "block": (int, None)},
                  variants={"pallas": (_attention_decode_pallas_variant,
                                       _attention_decode_eligible,
                                       _ATTENTION_DECODE_PALLAS_KSPEC)})

@@ -2,8 +2,10 @@
 slot-pooled KV cache (the Orca-style serving path, ROADMAP 3b).
 
 ``InferenceServer`` batches one-shot requests; a KV-cache decoder is a
-*sequence* — hundreds of single-token dispatches carrying device state
-between them — and serving it one sequence at a time pins decode
+*sequence* — hundreds of dispatches carrying device state between
+them, a token each or, of a model that decodes by blocks, a block's
+positions fed until all are decided — and serving it one sequence at
+a time pins decode
 throughput at batch 1. This module serves SLOTS sequences through ONE
 pinned program per iteration:
 
@@ -73,6 +75,20 @@ compiled and pinned at warmup):
   target-only decode and bit-identical under greedy, with rejected
   tails rolled back by cursor rewind on both engines.
 
+**A model that decodes by blocks** (``models.transformer
+.decode_procedure(symbol)``: the graph says so, nothing is passed in):
+the engine adds the program of one block's ``L`` rows a slot on every
+rung beside the prefill chunk, and the scheduler plans a decoding slot
+as its block - ``L`` ids at a cursor that is a multiple of ``L``, the
+undecided positions the mask id -, launches ``denoise_select`` behind
+the step (ids and a mask come back, never logits), takes a feed that
+still had undecided positions back (``rewind_many``: its keys and
+values are not kept), streams every token no earlier position of which
+is undecided, and advances the cursor by a clean feed alone. A window
+carries prefill chunks in multiples of ``L`` and the decoding slots
+wait that iteration; nothing is launched ahead (docs/serving.md, "A
+model that decodes by blocks").
+
 Per-sequence traces survive being batched with strangers: every
 sequence keeps its own session trace (root span
 ``serve.decode.sequence``), and each iteration records ONE shared
@@ -85,7 +101,9 @@ Telemetry (always on, docs/serving.md has the catalog):
 ``migrations``/``requests``/``responses``/``errors``/``fetch.bytes``/
 ``sample.device``/``sample.host``/``state.donated_bytes``/
 ``runahead.launched``/``runahead.windows``/``runahead.dropped``/
-``window.dispatches`` counters,
+``window.dispatches`` counters, of a block engine also
+``diffusion.feeds``/``blocks``/``decided``/``rows_dropped``/
+``undelivered``,
 ``serve.decode.step.seconds`` + ``serve.decode.request.latency.seconds``
 histograms, and one flight-ring record per iteration.
 """
@@ -184,10 +202,11 @@ class _Sequence:
     __slots__ = ("id", "prompt", "max_new", "eos_id", "arrival",
                  "deadline", "trace", "root_sid", "handle", "fed",
                  "generated", "slot", "finish_reason", "sampling",
-                 "rng", "first_dispatch_at", "prefix_id", "prefix_cold")
+                 "rng", "first_dispatch_at", "prefix_id", "prefix_cold",
+                 "block_len", "block", "stopped")
 
     def __init__(self, prompt, max_new, eos_id, arrival, deadline,
-                 trace=None, sampling=None, prefix_id=None):
+                 trace=None, sampling=None, prefix_id=None, block_len=0):
         self.id = next(_seq_ids)
         self.prompt = prompt
         self.max_new = max_new
@@ -205,6 +224,13 @@ class _Sequence:
         self.first_dispatch_at = None     # first dispatch covering us
         self.prefix_id = prefix_id
         self.prefix_cold = False          # missed: capture after prefill
+        # of an engine that decodes by blocks (``block_len``: a block's
+        # positions; 0 of any other): the block in flight, and why the
+        # stream stopped inside it ("eos") until its commit retires the
+        # request
+        self.block_len = block_len
+        self.block = None
+        self.stopped = None
         self.handle = DecodeHandle(self)
 
     def stream_len(self):
@@ -223,6 +249,30 @@ class _Sequence:
     def window(self, at, n):
         """The ``n`` stream tokens from position ``at`` on."""
         return [self.stream_token(at + j) for j in range(n)]
+
+    def prefill_left(self):
+        """Of a sequence decoded by blocks: the prompt tokens still to
+        prefill - the prompt's whole blocks less the cursor; its last
+        ``P mod L`` tokens are the first block's."""
+        whole = len(self.prompt) // self.block_len * self.block_len
+        return max(0, whole - self.fed)
+
+
+class _Block:
+    """A block in flight: its ``L`` ids as the next feed takes them
+    (the mask id at an undecided position), which positions are
+    undecided - BY POSITION: a prompt may hold the mask id -, and the
+    feeds it has had."""
+
+    __slots__ = ("ids", "undecided", "feeds")
+
+    def __init__(self, seq, mask_id):
+        L, at = seq.block_len, seq.fed
+        held = max(0, min(L, len(seq.prompt) - at))   # the prompt's tail
+        self.ids = np.full(L, mask_id, np.int32)
+        self.ids[:held] = seq.prompt[at:at + held]
+        self.undecided = np.arange(L) >= held
+        self.feeds = 0
 
 
 class DecodeHandle:
@@ -401,6 +451,11 @@ class DecodeEngine:
             else current_context()
         self.pos_embed = "learned" \
             if "pos_ids" in symbol.list_arguments() else "rotary"
+        from ..models.transformer import decode_procedure
+        # how the graph decodes where it says so: by blocks
+        self.block = decode_procedure(symbol)
+        self.block_len = None if self.block is None \
+            else int(self.block["block_length"])
         # the state advances by the real tokens alone: their count a
         # slot goes in beside the tokens
         self.data_names = ("data",) + (
@@ -464,6 +519,23 @@ class DecodeEngine:
         self.window_lens = sorted(
             {min(int(w), self.capacity) for w in (window_lens or ())}
             - {0, 1})
+        if self.block is not None:
+            L = self.block_len
+            if symbol_gen is None:
+                raise MXNetError(
+                    f"DecodeEngine({name!r}): the graph decodes by blocks "
+                    f"of {L} positions, and the program of one block "
+                    "needs symbol_gen= (a step_len -> per-slot decode "
+                    "symbol factory)")
+            off = [w for w in self.window_lens + [self.capacity] if w % L]
+            if off:
+                raise MXNetError(
+                    f"DecodeEngine({name!r}): the graph decodes by blocks "
+                    f"of {L} positions, which divide neither window "
+                    f"lengths nor capacity {off}: every dispatch is whole "
+                    "blocks from a block's edge (choose a prefill chunk "
+                    f"and a capacity that are multiples of {L})")
+            self.window_lens = sorted({L, *self.window_lens} - {1})
         self._window_mods = {}               # (rung, S) -> Module
         if self.window_lens:
             if symbol_gen is None:
@@ -512,7 +584,16 @@ class DecodeEngine:
             for S in self.window_lens:
                 symbol = symbol_gen(S)
                 mod = self._window_mods[(rung, S)] = bind(symbol, rung, S)
-                form = packed_window(symbol, rung)
+                # every row of a block's own program is read
+                form = None if S == self.block_len \
+                    else packed_window(symbol, rung)
+                if form is not None and self.block_len \
+                        and form[1] % self.block_len:
+                    raise MXNetError(
+                        f"DecodeEngine({self.name!r}): the packed window "
+                        f"of {S} rows a slot on rung {rung} has a budget "
+                        f"of {form[1]} rows, which blocks of "
+                        f"{self.block_len} positions do not divide")
                 if form is not None:
                     form = (bind(form[0], rung, S), form[1])
                     self._window_mods[(rung, S, "packed")] = form[0]
@@ -613,6 +694,8 @@ class DecodeEngine:
         restore programs, whose shapes are the rung's too whatever
         length a join has."""
         mark = _progcache.compile_count()
+        if self.block is not None:
+            return self._warmup_blocks(clock, rows, mark)
         for rung in self.ladder:
             drv = self._drivers[rung]
             last = np.zeros(rung, np.int32)
@@ -658,12 +741,58 @@ class DecodeEngine:
                 drv.warm_rows()
             drv.active[:] = False
             drv.rewind_many(list(range(rung)), [0] * rung)
+        return self._warmed(mark)
+
+    def _warmed(self, mark):
         self._pin_programs()
         self._warm_mark = _progcache.compile_count()
         self._warm_backend_mark = _telemetry.core.backend_compiles()
         self._warm_launch_mark = self._launch_counts()
         self.warmup_compiles = self._warm_mark - mark
         return dict(self.exec_est)
+
+    def _warmup_blocks(self, clock, rows, mark):
+        """``warmup`` of an engine that decodes by blocks: every rung's
+        block program with ``denoise_select`` behind it and the cursors
+        put back, as a feed that keeps nothing runs, and every other
+        window program as a prefill window is launched - one slot its
+        whole chunk, nobody else a row (the packed form where the rung
+        has one). Nothing is launched ahead of a block engine, so the
+        forms fed from the chip are not compiled; the S = 1 program,
+        which serves no request, stays bound and compiles at its first
+        direct call."""
+        L = self.block_len
+        for rung in self.ladder:
+            drv = self._drivers[rung]
+            slots = list(range(rung))
+            for S in drv.window_lens:
+                wz = np.zeros((rung, S), np.int32)
+                fed = np.asarray([S] + [0] * (rung - 1)) \
+                    if S != L and drv.window_budget(S) is not None else None
+
+                def launch():
+                    drv.rewind_many(slots, [0] * rung)
+                    out = drv.step(wz, fed=fed)
+                    drv.release_outputs()
+                    if S == L:
+                        state = drv.denoise_select(
+                            out, wz, np.ones((rung, L), bool),
+                            np.ones(rung), np.full(rung, np.inf))
+                    else:
+                        state = drv.select_rows(
+                            out, np.zeros(rung, np.int32))[1]
+                    drv.moe_stats_begin()
+                    return np.asarray(state)
+
+                launch()                         # trace + compile
+                t0 = clock.now()
+                launch()                         # steady state
+                self.exec_est[(rung, S)] = max(0.0, clock.now() - t0)
+            if rows and drv.positional:
+                drv.warm_rows()
+            drv.active[:] = False
+            drv.rewind_many(slots, [0] * rung)
+        return self._warmed(mark)
 
     def note_exec(self, rung, seconds):
         prev = self.exec_est.get(rung)
@@ -719,7 +848,10 @@ class DecodeEngine:
 
     def program_keys(self):
         keys = []
-        for rung, mod in self._bm._buckets.items():
+        # (the S = 1 program of an engine that decodes by blocks serves
+        # no request and is not compiled at warm-up)
+        for rung, mod in () if self.block is not None \
+                else self._bm._buckets.items():
             key = mod._exec_group.executor.program_cache_key("fwd_infer")
             if key is not None:
                 keys.append(key)
@@ -789,6 +921,16 @@ _WINDOW_COUNTERS = ("window.dispatches", "window.fed_slots",
                     "window.program_rows", "window.head_rows")
 
 
+#: ``serve.decode.<name>`` counters of an engine that decodes by blocks:
+#: a slot's block fed once (whether or not the feed was kept), blocks
+#: committed, positions decided, rows fed whose keys and values were
+#: not kept (``L`` a feed that is taken back), and positions denoised
+#: past a request's length, which nobody is delivered
+_DIFFUSION_COUNTERS = ("diffusion.feeds", "diffusion.blocks",
+                       "diffusion.decided", "diffusion.rows_dropped",
+                       "diffusion.undelivered")
+
+
 #: what one dispatch's launches left on the device (``_launch``): the
 #: whole output (the speculative path alone), the selected rows, their
 #: ids, the ids as the next S=1 step's token input, the ``moe_stats``
@@ -809,11 +951,19 @@ class _Dispatch:
     device (``launched``), and its own clock: ``t0`` where its step
     starts (the plan's first reading, or for a dispatch launched
     ``ahead`` the moment its predecessor's ids were on the host),
-    ``plan_s`` and ``phases``."""
+    ``plan_s`` and ``phases``. A dispatch of one block a slot
+    (``block``: the block's length; 0 of any other) carries instead of
+    ``last`` / ``feed`` / ``chip`` what ``denoise_select`` takes -
+    ``undecided``, ``quota``, ``threshold`` - and ``back``, the rows
+    and cursors of the slots whose feed keeps nothing; its ``meta`` is
+    ``(row, seq, L)`` and ``kinds`` says of each row whether its feed
+    is ``"tentative"``, a ``"commit"`` or a ``"prefill"`` of prompt
+    tokens."""
 
     __slots__ = ("mode", "S", "meta", "tokens", "last", "fed", "feed",
                  "chip", "want_rows", "n_active", "shared_sid", "t0",
-                 "plan_s", "phases", "ahead", "launched")
+                 "plan_s", "phases", "ahead", "launched", "block",
+                 "undecided", "quota", "threshold", "back", "kinds")
 
     def __init__(self, mode, S, t0, ahead=False):
         self.mode, self.S, self.t0, self.ahead = mode, S, t0, ahead
@@ -822,8 +972,12 @@ class _Dispatch:
         self.want_rows = False
         self.n_active = 0
         self.shared_sid = self.launched = None
+        self.block = 0
+        self.undecided = self.quota = self.threshold = None
+        self.back, self.kinds = ([], []), {}
         self.plan_s = 0.0
-        self.phases = {"dispatch": 0.0, "fetch": 0.0, "bytes": 0,
+        self.phases = {"denoise": 0.0, "decided": 0,
+                       "dispatch": 0.0, "fetch": 0.0, "bytes": 0,
                        "stage": 0.0, "launch": 0.0, "select": 0.0,
                        "ids": 0.0, "program_rows": 0, "head_rows": 0,
                        "reads": collections.Counter()}
@@ -851,6 +1005,12 @@ class DecodeScheduler:
     verify, exact rejection, cursor rollback on both engines);
     ``prefix_store`` joins at cursor C on ``submit(prefix_id=...)``
     hits and snapshots cold prefixes when their prefill completes.
+
+    Over an engine that decodes by blocks (``engine.block``) a step
+    yields 0 to ``L`` tokens a slot (``_plan_block``,
+    ``_commit_block``): every cursor stays on a block's edge, a window
+    carries prefill chunks alone, nothing is launched ahead, a draft
+    engine and a request that is not greedy are refused by name.
     """
 
     def __init__(self, engine, clock=None, max_queue=None,
@@ -865,6 +1025,16 @@ class DecodeScheduler:
             is not None else _env_int("MXNET_SERVE_DECODE_MAX_NEW", 64)
         self.logger = logger or log
 
+        self._block = engine.block          # None: one token a step
+        if self.draft is not None and (self._block is not None
+                                       or self.draft.block is not None):
+            L = (self._block or self.draft.block)["block_length"]
+            raise MXNetError(
+                f"decode {engine.name!r}: speculative decoding "
+                "(draft_engine / spec_k) proposes one token a step and "
+                "verifies a window of them, and an engine that decodes "
+                f"by blocks of {L} positions decides a block's positions "
+                "in no such order")
         if self.draft is not None:
             if list(self.draft.ladder.sizes) != list(engine.ladder.sizes):
                 raise MXNetError(
@@ -993,7 +1163,8 @@ class DecodeScheduler:
                         "fetch.bytes", "sample.device", "sample.host",
                         "runahead.launched", "runahead.dropped",
                         "runahead.windows")
-                       + _WINDOW_COUNTERS}
+                       + _WINDOW_COUNTERS
+                       + (_DIFFUSION_COUNTERS if self._block else ())}
             # what the graph's ops count of a dispatch (the driver's
             # ``read_counts``: ``OpDef.state_reads``)
             handles.update({k: self._counter(k) for k, _field in
@@ -1035,6 +1206,7 @@ class DecodeScheduler:
                       else self._default_max_new)
         if max_new < 1:
             raise MXNetError("max_new_tokens must be >= 1")
+        self._check_sampling(sampling)
         now = self._clock.now()
         deadline = None if deadline_ms is None \
             else now + deadline_ms / 1000.0
@@ -1042,7 +1214,8 @@ class DecodeScheduler:
         if tr is None and _trace.sample():
             tr = _trace.new_trace(session=True)
         seq = _Sequence(prompt, max_new, eos_id, now, deadline, trace=tr,
-                        sampling=sampling, prefix_id=prefix_id)
+                        sampling=sampling, prefix_id=prefix_id,
+                        block_len=self.engine.block_len or 0)
         if tr is not None:
             seq.root_sid = _trace.next_span_id()
             if tr.root is None:
@@ -1066,6 +1239,48 @@ class DecodeScheduler:
         self._counter("requests").inc()
         self._gauge("queue.depth").set(depth)
         return seq.handle
+
+    def _check_sampling(self, sampling):
+        """Refuse by name what the engine cannot serve: of an engine
+        that decodes by blocks a request that is not greedy (a block's
+        positions are decided on the device, by confidence) or whose
+        denoising parameters the block has no room for; of any other a
+        request that sets denoising parameters."""
+        denoising = sampling is not None and sampling.denoises
+        if self._block is None:
+            if denoising:
+                raise MXNetError(
+                    f"decode {self.engine.name!r}: {sampling!r} sets "
+                    "denoising parameters, and this engine decodes one "
+                    "token a step (they are a request's of a model that "
+                    "decodes by blocks)")
+            return
+        L = self.engine.block_len
+        if sampling is not None and not sampling.greedy:
+            raise MXNetError(
+                f"decode {self.engine.name!r}: {sampling!r} is not greedy, "
+                f"and this engine decodes by blocks of {L} positions, "
+                "which it decides on the device by their confidence "
+                "(argmax; temperature 0)")
+        if denoising and sampling.denoising_steps is not None \
+                and not 1 <= sampling.denoising_steps <= L:
+            raise MXNetError(
+                f"decode {self.engine.name!r}: denoising_steps "
+                f"{sampling.denoising_steps} of a block of {L} positions "
+                f"(1 to {L}: a feed decides at least one)")
+
+    def _denoising(self, seq):
+        """``(steps, threshold)`` a sequence's blocks are denoised
+        with: the request's where it set them, else the graph's; a
+        static schedule never looks at the threshold."""
+        given, graph = seq.sampling, self._block
+        steps = given.denoising_steps or graph["denoising_steps"]
+        remasking = given.remasking or graph["remasking"]
+        threshold = graph["confidence_threshold"] \
+            if given.confidence_threshold is None \
+            else given.confidence_threshold
+        return int(steps), float(threshold) \
+            if remasking == "low_confidence_dynamic" else float("inf")
 
     # ----------------------------------------------------------- scheduling
     def _active(self):
@@ -1176,6 +1391,11 @@ class DecodeScheduler:
             else ("target",)
         c, entry = self.prefix_store.lookup(
             seq.prefix_id, seq.prompt, tags=tags, least=self.prefill_chunk)
+        if entry is not None and self._block is not None:
+            # a join lands on a block's edge, rounded down (the rows of
+            # a block are its whole block's; the rest is prefilled)
+            c = c // seq.block_len * seq.block_len
+            entry = entry if c else None
         if entry is None:
             seq.prefix_cold = True
             self._counter("prefix.misses").inc()
@@ -1486,7 +1706,8 @@ class DecodeScheduler:
         # token would overflow its cache slice fails ALONE — the
         # program was never dispatched for it, batchmates continue
         self._retire_expired(now)
-        for row in self.engine.driver(self._rung).overflowing():
+        for row in self.engine.driver(self._rung).overflowing(
+                self.engine.block_len or 1):
             seq = self._slots[row]
             if seq is None:          # retired row still advancing
                 continue
@@ -1505,6 +1726,8 @@ class DecodeScheduler:
         target = self.engine.ladder.bucket_for(len(active))
         if target is not None and target < self._rung:
             self._switch_rung(target)
+        if self._block is not None:
+            return self._plan_block(now)
         cursors = self._cursors()[0]
         d = _Dispatch(*self._plan_dispatch(cursors), t0=now)
         if d.mode != "spec":
@@ -1559,6 +1782,10 @@ class DecodeScheduler:
         way is admitted by the plan behind it. ``d`` need not be
         launched yet: the ids are ``d``'s to give once it is."""
         if d.mode != "window" or self.draft is not None:
+            return None
+        if self._block is not None:
+            # an engine that decodes by blocks: the next feed's ids are
+            # what this one decides (the ring's ``ahead`` stays 0)
             return None
         cursors, sampled, leaving = self._cursors(after=d)
         if any(at + 1 > self.engine.capacity
@@ -1640,6 +1867,9 @@ class DecodeScheduler:
             verdicts = self._dispatch_spec(
                 drv, ddrv, d.tokens, d.meta, d.S, d.phases)
             end = clock()
+        elif d.block:
+            d.launched, planned = self._launch_block(drv, d, planned)
+            ids, end = self._fetch_block(drv, d, planned)
         else:
             if fresh:
                 d.launched, planned = self._launch(
@@ -1680,6 +1910,8 @@ class DecodeScheduler:
                     emitted = self._commit_spec(
                         d.meta, verdicts, S, d.t0, end, d.shared_sid,
                         rew_rows, rew_pos)
+                elif d.block:
+                    emitted, chunks = self._commit_block(d, ids, end)
                 else:
                     emitted, chunks = self._commit_window(
                         d, ids, picked, end)
@@ -1760,7 +1992,12 @@ class DecodeScheduler:
                     else _us(arrived - turn_from),
                     lock_us=_us(locked - arrived), mode=mode, window=S,
                     ahead=int(d.ahead),
-                    compiles_since_warmup=compiles, **read_fields)
+                    compiles_since_warmup=compiles, **read_fields,
+                    **({"block": d.block,
+                        "tentative": len(d.back[0]),
+                        "decided": phases["decided"],
+                        "denoise_us": _us(phases["denoise"])}
+                       if d.block else {}))
         return max(1, emitted)
 
     def _commit_window(self, d, ids, picked, end):
@@ -1785,7 +2022,10 @@ class DecodeScheduler:
                 dropped += d.ahead
                 continue
             was_prefilling = seq.remaining() > 1
-            samples = seq.fed + n == seq.stream_len()
+            # (a window of a block engine carries prompt tokens alone,
+            # and a block's tokens are its own feeds' to decide)
+            samples = seq.fed + n == seq.stream_len() \
+                and self._block is None
             if seq.trace is not None:
                 _trace.record(
                     seq.trace, "serve.decode.step", t0, end,
@@ -1824,6 +2064,217 @@ class DecodeScheduler:
         if dropped:
             m["runahead.dropped"].inc(dropped)
         return emitted, chunks
+
+    # ------------------------------------------------- decoding by blocks
+    def _plan_block(self, now):
+        """One dispatch of an engine that decodes by blocks, from the
+        state as it stands (caller holds the lock). While a slot with
+        room for a chunk still prefills, a window: the prefilling slots
+        take ``min(chunk, what is left of their prompt's whole blocks,
+        what is left of the packed budget)`` rounded down to whole
+        blocks, oldest admission first, and **the decoding slots wait
+        that iteration** (fed 0; a window needs room for a chunk behind
+        every live cursor, as ``_plan_dispatch``'s). Otherwise a
+        dispatch of one block a slot: a decoding slot its block in
+        flight (a new one starts as the prompt's last ``P mod L``
+        tokens and the mask id elsewhere) - fed to be taken back while
+        a position is undecided, once more to be kept when none is -
+        and a slot that prefills without room for a chunk its next
+        ``L`` prompt tokens, kept."""
+        L, S, cap = self.engine.block_len, self.prefill_chunk, \
+            self.engine.capacity
+        live = [(row, seq) for row, seq in enumerate(self._slots)
+                if seq is not None]
+        # a window writes S rows behind every live cursor: all have room
+        # for them, or the slots that prefill go a block at a time
+        chunked = [(row, seq) for row, seq in live if seq.prefill_left()] \
+            if S > L and all(seq.fed + S <= cap for _row, seq in live) \
+            else []
+        d = _Dispatch("window", S if chunked else L, t0=now)
+        d.tokens = np.zeros((self._rung, d.S), np.int32)
+        d.fed = np.zeros(self._rung, np.int32)
+        d.n_active = len(live)
+        if any(seq.trace is not None for _row, seq in live):
+            d.shared_sid = _trace.next_span_id()
+        if chunked:
+            d.last = np.zeros(self._rung, np.int32)
+            d.feed = np.zeros(self._rung, bool)
+            room = self.engine.window_budget(self._rung, S)
+            room = S * len(chunked) if room is None else room
+            for row, seq in sorted(chunked, key=lambda c: c[1].id):
+                n = min(S, seq.prefill_left(), room) // L * L
+                if not n:
+                    continue
+                room -= n
+                d.tokens[row, :n] = seq.prompt[seq.fed:seq.fed + n]
+                d.last[row], d.fed[row] = n - 1, n
+                d.meta.append((row, seq, n))
+            d.meta.sort(key=lambda entry: entry[0])
+        else:
+            d.block = L
+            d.undecided = np.zeros((self._rung, L), bool)
+            d.quota = np.zeros(self._rung, np.int32)
+            d.threshold = np.full(self._rung, np.inf, np.float32)
+            for row, seq in live:
+                d.fed[row] = L
+                d.meta.append((row, seq, L))
+                if seq.prefill_left():
+                    d.tokens[row] = seq.prompt[seq.fed:seq.fed + L]
+                    d.kinds[row] = "prefill"
+                    continue
+                if seq.block is None:
+                    seq.block = _Block(seq, self._block["mask_token_id"])
+                blk = seq.block
+                d.tokens[row] = blk.ids
+                if not blk.undecided.any():
+                    d.kinds[row] = "commit"
+                    continue
+                d.kinds[row] = "tentative"
+                d.undecided[row] = blk.undecided
+                steps, d.threshold[row] = self._denoising(seq)
+                # L / steps a feed, the remainder to the first feeds
+                d.quota[row] = L // steps + (blk.feeds < L % steps) \
+                    if blk.feeds < steps else L
+                d.back[0].append(row)
+                d.back[1].append(seq.fed)
+        for _row, seq, _n in d.meta:
+            if seq.first_dispatch_at is None:
+                seq.first_dispatch_at = now
+        return d
+
+    def _launch_block(self, drv, d, t):
+        """The launches of a dispatch of one block a slot,
+        ``serve.decode.iter.dispatch``: the step, ``denoise_select``
+        behind it over all its rows (``decode.denoise_select``), the
+        cursors of the slots whose feed keeps nothing put back
+        (``rewind_many``: one small launch, queued behind the step, so
+        the host waits for neither) and the ``moe_stats`` stack.
+        Returns ``((state, routed), the reading that closed the
+        phase)``; ``phases["denoise"]`` runs from the decision
+        program's launch to its ids on the host (``_fetch_block``)."""
+        now, phases = self._clock.now, d.phases
+        with _telemetry.span("serve.decode.iter.dispatch"):
+            out = drv.step(d.tokens, fed=d.fed, now=now)
+            drv.release_outputs()       # ``out`` is this call's alone
+            phases["reads"].update(drv.last_reads)
+            phases["stage"] += drv.last_stage
+            phases["launch"] += drv.last_launch
+            phases["program_rows"] += drv.last_program_rows
+            phases["head_rows"] += drv.last_head_rows
+            phases["denoise"] = -now()
+            state = drv.denoise_select(out, d.tokens, d.undecided, d.quota,
+                                       d.threshold, now=now)
+            phases["select"] += drv.last_select
+            out = None
+            if d.back[0]:
+                drv.rewind_many(*d.back)
+            routed = drv.moe_stats_begin()
+        t_launched = now()
+        phases["dispatch"] += t_launched - t
+        return (state, routed), t_launched
+
+    def _fetch_block(self, drv, d, t):
+        """What a dispatch of one block a slot decided, on the host:
+        ``(2, rung, L)`` int32 - the ids and which positions are still
+        undecided -, 8 x L bytes a slot and never a row of logits.
+        ``serve.decode.iter.fetch`` as ``_fetch``."""
+        now, phases = self._clock.now, d.phases
+        state, routed = d.launched
+        with _telemetry.span("serve.decode.iter.fetch"):
+            t_ids = now()
+            with _telemetry.span("serve.decode.iter.fetch.ids"):
+                state = np.asarray(state)
+            t_got = now()
+            phases["ids"] += t_got - t_ids
+            phases["denoise"] += t_got
+            nbytes = state.nbytes
+            if routed is not None:
+                with _telemetry.span("serve.decode.iter.moe_stats"):
+                    phases["reads"].update(drv.moe_stats(routed))
+                nbytes += routed.nbytes
+        end = now()
+        phases["fetch"] += end - t
+        phases["bytes"] += nbytes
+        return state, end
+
+    def _commit_block(self, d, state, end):
+        """Apply a dispatch of one block a slot (caller holds the
+        lock). A tentative feed: the block takes the ids and the mask
+        the device decided (``serve.decode.iter.denoise``), and every
+        token with no undecided position before it is streamed, in
+        stream order, up to ``max_new`` exactly - a decided position is
+        final; an ``eos_id`` stops the stream there. A feed that was
+        kept moves the cursor by ``L``; where it was a block's, the
+        block is done, and the request with it once its stream stopped
+        or reached its length (positions of that last block past the
+        length were denoised and are nobody's). Returns ``(emitted,
+        prefill chunks)``."""
+        L = d.block
+        emitted = chunks = feeds = blocks = decided = undelivered = 0
+        ids, left = state
+        for row, seq, _n in d.meta:
+            if seq.slot is None:
+                continue
+            kind = d.kinds[row]
+            if seq.trace is not None:
+                _trace.record(
+                    seq.trace, "serve.decode.step", d.t0, end,
+                    span_id=d.shared_sid, parent=seq.root_sid,
+                    rung=self._rung, n_active=d.n_active, shared=True,
+                    pos=seq.fed, window=L, feed=kind)
+            if kind == "prefill":
+                chunks += 1
+                seq.fed += L
+                self._capture_prefix(seq, end)
+                continue
+            feeds += 1
+            blk = seq.block
+            if kind == "tentative":
+                with _telemetry.span("serve.decode.iter.denoise"):
+                    still = left[row].astype(bool)
+                    decided += int(blk.undecided.sum() - still.sum())
+                    blk.ids, blk.undecided = ids[row].copy(), still
+                    blk.feeds += 1
+                    emitted += self._deliver(seq, blk, end)
+                continue
+            blocks += 1
+            seq.fed += L
+            seq.block = None
+            self._capture_prefix(seq, end)
+            over = seq.fed - len(seq.prompt) - seq.max_new
+            if seq.stopped is not None:
+                self._finish(seq, reason=seq.stopped, now=end)
+            elif over >= 0:
+                undelivered += over
+                self._finish(seq, reason="length", now=end)
+        d.phases["decided"] = decided
+        m = self._iter_metrics()
+        m["sample.device"].inc(decided)
+        for key, n in (("feeds", feeds), ("blocks", blocks),
+                       ("decided", decided),
+                       ("rows_dropped", L * len(d.back[0])),
+                       ("undelivered", undelivered)):
+            if n:
+                m[f"diffusion.{key}"].inc(n)
+        return emitted, chunks
+
+    def _deliver(self, seq, blk, now):
+        """Stream the tokens of ``seq``'s block that no undecided
+        position precedes and nobody has been handed yet; returns
+        their count."""
+        emitted = 0
+        while seq.stopped is None and len(seq.generated) < seq.max_new:
+            j = len(seq.prompt) + len(seq.generated) - seq.fed
+            if j >= seq.block_len or blk.undecided[:j + 1].any():
+                break
+            tok = int(blk.ids[j])
+            if seq.eos_id is not None and tok == seq.eos_id:
+                seq.stopped = "eos"     # retires at the block's commit
+                break
+            seq.generated.append(tok)
+            seq.handle._emit(tok, now=now)
+            emitted += 1
+        return emitted
 
     def _commit_spec(self, meta, verdicts, K, t0, end, shared_sid,
                      rew_rows, rew_pos):
@@ -1881,16 +2332,22 @@ class DecodeScheduler:
         (caller holds the lock): the slot's first ``len(prompt)`` cache
         positions on the target (and draft, when armed) plus the token
         ids they encode."""
+        # of a model that decodes by blocks the prompt's whole blocks
+        # alone: the rows of its last P mod L tokens are their block's,
+        # and depend on what was generated beside them
+        length = len(seq.prompt) if self._block is None \
+            else len(seq.prompt) // seq.block_len * seq.block_len
         if not seq.prefix_cold or self.prefix_store is None or \
-                seq.slot is None or seq.fed < len(seq.prompt):
+                seq.slot is None or seq.fed < length:
             return
         payloads = {"target": self.engine.driver(self._rung)
-                    .capture_rows(seq.slot, len(seq.prompt))}
+                    .capture_rows(seq.slot, length)}
         if self.draft is not None:
             payloads["draft"] = self.draft.driver(self._rung) \
-                .capture_rows(seq.slot, len(seq.prompt))
+                .capture_rows(seq.slot, length)
         stored = self.prefix_store.put(
-            seq.prefix_id, np.asarray(seq.prompt, np.int64), payloads)
+            seq.prefix_id, np.asarray(seq.prompt[:length], np.int64),
+            payloads)
         seq.prefix_cold = False
         if stored:
             self._counter("prefix.captures").inc()
@@ -2043,6 +2500,10 @@ class DecodeScheduler:
             }
         if self.prefix_store is not None:
             out["prefix"] = self.prefix_store.stats()
+        if self._block is not None:
+            out["diffusion"] = dict(
+                self._block, **{k.split(".")[1]: c(k)
+                                for k in _DIFFUSION_COUNTERS})
         return out
 
 

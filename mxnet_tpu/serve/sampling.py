@@ -43,11 +43,26 @@ class SamplingParams:
     ``top_k``/``top_p`` filter the distribution before the draw.
     ``seed`` seeds the request's rng chain — resubmitting the same
     prompt with the same params replays the same token stream byte for
-    byte (the trace-plane replay contract)."""
+    byte (the trace-plane replay contract).
 
-    __slots__ = ("temperature", "top_k", "top_p", "seed")
+    **The denoising parameters of a request** to a model that decodes
+    by blocks (``serve.decode``; each None: the graph's own default,
+    ``models.transformer.decode_procedure``): ``denoising_steps``, the
+    feeds a block's positions are decided in (1 to the block's length;
+    a feed decides ``length / steps`` positions, the remainder in the
+    first feeds); ``remasking``, which positions a feed decides -
+    ``"low_confidence_static"`` the quota's most confident,
+    ``"low_confidence_dynamic"`` those and every other whose confidence
+    passes ``confidence_threshold``. Such a request is greedy: the
+    positions are decided on the device, by argmax. An engine that
+    decodes one token a step refuses a request that sets any."""
 
-    def __init__(self, temperature=0.0, top_k=0, top_p=1.0, seed=0):
+    __slots__ = ("temperature", "top_k", "top_p", "seed",
+                 "denoising_steps", "remasking", "confidence_threshold")
+
+    def __init__(self, temperature=0.0, top_k=0, top_p=1.0, seed=0,
+                 denoising_steps=None, remasking=None,
+                 confidence_threshold=None):
         temperature = float(temperature)
         top_k = int(top_k)
         top_p = float(top_p)
@@ -61,10 +76,33 @@ class SamplingParams:
         self.top_k = top_k
         self.top_p = top_p
         self.seed = int(seed)
+        if denoising_steps is not None and int(denoising_steps) < 1:
+            raise MXNetError(f"denoising_steps {denoising_steps} must be "
+                             ">= 1 (a feed decides at least one position)")
+        if remasking not in (None, "low_confidence_static",
+                             "low_confidence_dynamic"):
+            raise MXNetError(
+                f"remasking {remasking!r}: 'low_confidence_static' or "
+                "'low_confidence_dynamic'")
+        if confidence_threshold is not None \
+                and not 0.0 <= float(confidence_threshold) <= 1.0:
+            raise MXNetError(f"confidence_threshold {confidence_threshold} "
+                             "must be in [0, 1]")
+        self.denoising_steps = None if denoising_steps is None \
+            else int(denoising_steps)
+        self.remasking = remasking
+        self.confidence_threshold = None if confidence_threshold is None \
+            else float(confidence_threshold)
 
     @property
     def greedy(self):
         return self.temperature == 0.0
+
+    @property
+    def denoises(self):
+        """Does the request set a denoising parameter?"""
+        return not (self.denoising_steps is None and self.remasking is None
+                    and self.confidence_threshold is None)
 
     def make_rng(self):
         """The request's recorded rng chain: reseeding reproduces every
@@ -72,9 +110,13 @@ class SamplingParams:
         return np.random.Generator(np.random.PCG64(self.seed))
 
     def __repr__(self):
+        more = "" if not self.denoises else (
+            f", denoising_steps={self.denoising_steps}, "
+            f"remasking={self.remasking!r}, "
+            f"confidence_threshold={self.confidence_threshold}")
         return (f"SamplingParams(temperature={self.temperature}, "
                 f"top_k={self.top_k}, top_p={self.top_p}, "
-                f"seed={self.seed})")
+                f"seed={self.seed}{more})")
 
 
 def token_probs(logits, params):

@@ -36,12 +36,21 @@ __all__ = [
 ]
 
 BUDGET = tfm.packed_rows(SLOTS, WINDOW)
-SCHEDULES = blocks.schedules(WINDOW, SLOTS, BUDGET)
 T = 120                                 # positions a sequence of a test
 
 
+def _step(block):
+    """The positions a decode step of the block feeds: 1, or the length
+    of its blocks (``blocks.STEP``)."""
+    return blocks.STEP.get(block, 1)
+
+
+def _common(block):
+    return blocks.schedules(WINDOW, SLOTS, BUDGET, step=_step(block))
+
+
 def _schedules(block):
-    return {**SCHEDULES, **blocks.OWN_SCHEDULES.get(block, {})}
+    return {**_common(block), **blocks.OWN_SCHEDULES.get(block, {})}
 
 
 def pytest_generate_tests(metafunc):
@@ -112,7 +121,7 @@ def test_a_slot_left_and_joined_again_reads_a_clean_state(driver, block):
     one as a fresh pool does - ``join`` moves the cursor alone, and
     whatever the state holds at cursor 0 is read as nothing - both
     through a window and through S = 1 steps first."""
-    table = SCHEDULES
+    table = _common(block)
     blocks.run(driver, blocks.seqs(block, T, seed=4),
                table["whole_windows_then_decode"])
     seqs = blocks.seqs(block, T, seed=5)
@@ -143,16 +152,20 @@ def test_rewind_capture_and_restore_name_the_families(driver, block):
     whole = (WINDOW, [WINDOW] * SLOTS)
     blocks.run(driver, seqs, [whole] * 4)
     if blocks.positional(block):
+        step = _step(block)             # a block's edge where it has blocks
+        back = 23 if step == 1 else 24
         rows = driver.capture_rows(0, 40)
         driver.restore_rows(1, rows)
-        driver.rewind_many([0, 1], [23, 40])
-        assert list(driver.pos[:2]) == [23, 40]
+        driver.rewind_many([0, 1], [back, 40])
+        assert list(driver.pos[:2]) == [back, 40]
         seqs[1] = seqs[0]
-        got, at, _ = blocks.run(driver, seqs, [(1, [1, 1] + [0] * (SLOTS - 2))]
-                                * 3, start=[23, 40] + [64] * (SLOTS - 2))
+        got, at, _ = blocks.run(
+            driver, seqs, [(step, [step, step] + [0] * (SLOTS - 2))] * 3,
+            start=[back, 40] + [64] * (SLOTS - 2))
         want = blocks.reference(block, seqs)
-        for slot, t0 in ((0, 23), (1, 40)):
-            assert np.abs(got[slot, t0:t0 + 3] - want[slot, t0:t0 + 3]) \
+        for slot, t0 in ((0, back), (1, 40)):
+            n = 3 * step
+            assert np.abs(got[slot, t0:t0 + n] - want[slot, t0:t0 + n]) \
                 .max() <= blocks.TOL[block]
     else:
         named = ".*".join(blocks.FAMILIES[block])
@@ -260,7 +273,8 @@ def test_migrate_mid_sequence_continues_as_the_reference(engine, block):
     for drv in (big, small):
         drv.active[:] = False
     small.join(0), small.join(1)
-    lens, at = [37, 50], [0, 0]
+    step = _step(block)                 # whole blocks where it has blocks
+    lens, at = [37 // step * step, 50 // step * step], [0, 0]
     while any(a < n for a, n in zip(at, lens)):
         tokens = np.zeros((2, WINDOW), np.int32)
         fed = np.zeros(2, np.int32)
@@ -270,13 +284,16 @@ def test_migrate_mid_sequence_continues_as_the_reference(engine, block):
             fed[s], at[s] = n, at[s] + n
         small.step(tokens, fed=fed)
     engine.migrate(2, 4, [(0, 3), (1, 1)])
-    assert list(big.pos) == [0, 50, 0, 37] and not small.active.any()
-    for j in range(5):
-        tokens = np.zeros((4, 1), np.int32)
-        tokens[3, 0], tokens[1, 0] = seqs[0, 37 + j], seqs[1, 50 + j]
-        out = big.step(tokens, fed=[0, 1, 0, 1]).asnumpy()
-        assert np.abs(out[3, 0] - want[0, 37 + j]).max() <= blocks.TOL[block]
-        assert np.abs(out[1, 0] - want[1, 50 + j]).max() <= blocks.TOL[block]
+    assert list(big.pos) == [0, lens[1], 0, lens[0]] \
+        and not small.active.any()
+    for j in range(0, (5 if step == 1 else 3) * step, step):
+        tokens = np.zeros((4, step), np.int32)
+        tokens[3] = seqs[0, lens[0] + j:lens[0] + j + step]
+        tokens[1] = seqs[1, lens[1] + j:lens[1] + j + step]
+        out = big.step(tokens, fed=[0, step, 0, step]).asnumpy()
+        for row, (seq, t0) in ((3, (0, lens[0])), (1, (1, lens[1]))):
+            assert np.abs(out[row] - want[seq, t0 + j:t0 + j + step]) \
+                .max() <= blocks.TOL[block]
     big.active[:] = False
     assert sorted(engine.state_bytes) == blocks.FAMILIES[block]
 
@@ -296,13 +313,21 @@ def test_mixed_prefill_and_decode_equals_one_request_at_a_time(engine, block):
     before = sched._counter("cursor.rows").value
     mixed = blocks.served(sched, prompts, 12)
     assert mixed == alone and all(len(t) == 12 for t in mixed)
-    # the joins; nothing rewound
-    assert sched._counter("cursor.rows").value - before == 4
     assert sched.stats()["compiles_since_warmup"] == 0
-    assert sched.stats()["runahead"]["launched"] > 0
-    seq = np.asarray([prompts[1] + alone[1][:-1]], np.int32)
-    want = blocks.reference(block, seq)[0]
-    assert alone[1] == np.argmax(want[len(prompts[1]) - 1:], axis=-1).tolist()
+    if block in blocks.STEP:
+        # a feed that keeps nothing is taken back, nothing runs ahead,
+        # and the tokens are the reference's own procedure's
+        assert sched._counter("cursor.rows").value - before > 4
+        assert sched.stats()["runahead"]["launched"] == 0
+        assert alone[1] == blocks.plain_greedy(block, prompts[1], 12)
+    else:
+        # the joins; nothing rewound
+        assert sched._counter("cursor.rows").value - before == 4
+        assert sched.stats()["runahead"]["launched"] > 0
+        seq = np.asarray([prompts[1] + alone[1][:-1]], np.int32)
+        want = blocks.reference(block, seq)[0]
+        assert alone[1] == np.argmax(want[len(prompts[1]) - 1:],
+                                     axis=-1).tolist()
     for family in blocks.FAMILIES[block]:
         assert mx.telemetry.get_metric(
             "serve.decode.state.bytes", model=engine.name,
@@ -320,6 +345,10 @@ def test_the_scheduler_takes_drafts_and_prefix_stores_or_says_why_not(
         sched = mx.serve.DecodeScheduler(engine, clock=clock,
                                          prefix_store=PrefixStore(1 << 20))
         assert sched.prefix_store is not None
+        if block in blocks.STEP:        # a draft proposes a token a step
+            with pytest.raises(MXNetError, match=r"spec_k.*by blocks of"):
+                mx.serve.DecodeScheduler(engine, clock=clock,
+                                         draft_engine=engine, spec_k=4)
         return
     named = ".*".join(sorted(set(blocks.FAMILIES[block]) - {"cursor"}))
     with pytest.raises(MXNetError, match=rf"prefix_store.*{named}"):
@@ -337,6 +366,9 @@ def test_serve_decoder_serves_the_block_with_no_side_script(front, block):
     prompt = np.random.default_rng(1).integers(
         0, blocks.config(block)["vocab_size"], 41)
     tokens = blocks.served(front, [prompt.tolist()], 6)[0]
+    if block in blocks.STEP:
+        assert tokens == blocks.plain_greedy(block, prompt.tolist(), 6)
+        return
     seq = np.concatenate([prompt, tokens[:-1]])[None].astype(np.int32)
     want = blocks.reference(block, seq)[0]
     assert tokens == np.argmax(want[40:], axis=-1).tolist()

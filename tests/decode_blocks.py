@@ -91,6 +91,15 @@ _LING = {"layer_types": ["kda", "kda", "mla"], "head_dim": 16,
          "value_norm": False, "use_nGPT": False, "held": (4, 8),
          "kda_chunk": 8}
 
+#: SDAR's: the published keys, and how the model decodes (blocks of 4
+#: positions, the mask id the last row of the vocabulary, a position a
+#: feed unless a confidence passes the threshold)
+_SDAR = {"num_key_value_heads": 2, "head_dim": 16, "num_experts": 16,
+         "num_experts_per_tok": 4, "moe_intermediate_size": 32,
+         "norm_topk_prob": True, "block_length": 4, "mask_token_id": 47,
+         "denoising_steps": 4, "remasking": "low_confidence_dynamic",
+         "confidence_threshold": 0.9}
+
 #: block -> ``get_decode_symbol``'s arguments beside the step length
 BLOCKS = {
     "glm_dsa": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
@@ -110,6 +119,8 @@ BLOCKS = {
     "evabyte": dict(vocab_size=40, d_model=32, n_layer=2, n_head=2,
                     rope_base=1e5, window=32, chunk=4, n_pred_heads=2,
                     ffn_width=48),
+    "sdar_moe": dict(vocab_size=48, d_model=64, n_layer=3, n_head=8,
+                     rope_base=1e6, rms_eps=1e-6, sdar=_SDAR),
 }
 
 #: the blocks with a training form (``_fused_attention``), likewise:
@@ -145,7 +156,12 @@ FED = sorted(BLOCKS) + sorted(FUSED)
 #: the keyword that holds a block's published keys
 PUBLISHED = {"glm_dsa": "glm", "axk1": "axk1", "xing4": "xing4",
              "afmoe": "afmoe", "granite_hybrid": "granite",
-             "ling_hybrid": "ling"}
+             "ling_hybrid": "ling", "sdar_moe": "sdar"}
+
+#: the positions a decode step of a block feeds and decides between
+#: them, where that is not one: its dispatches are whole blocks from a
+#: block's edge, and its tokens the reference's ``generate``'s
+STEP = {"sdar_moe": _SDAR["block_length"]}
 
 
 def config(case, **over):
@@ -285,6 +301,10 @@ def driver(case, packed=True, slots=SLOTS, window=WINDOW, capacity=CAPACITY,
         window, bound(whole, window, shared=base, slots=slots, dtype=dtype),
         packed=form and (bound(form[0], window, shared=base, slots=slots,
                                dtype=dtype), form[1]))
+    if case in STEP and STEP[case] != window:   # a block's own program
+        drv.add_window(STEP[case], bound(
+            symbol(case, STEP[case], capacity, **over), STEP[case],
+            shared=base, slots=slots, dtype=dtype))
     return drv
 
 
@@ -378,6 +398,7 @@ REFERENCE = {
     "ling_hybrid": ("ling_hybrid", _ling_cfg),
     "evabyte": ("evabyte", _evabyte_cfg),
     "gpt2": ("gpt2", _gpt2_cfg), "olmoe": ("olmoe", _olmoe_cfg),
+    "sdar_moe": ("sdar_moe", _published),
 }
 
 
@@ -429,11 +450,20 @@ def reference(case, seqs, arg_params=None, over=None, **kw):
     return np.asarray(_FORWARDS[key](arg_params, jnp.asarray(seqs)))
 
 
-def plain_greedy(case, prompt, max_new, T=64):
+def plain_greedy(case, prompt, max_new, T=64, **request):
     """The tokens a greedy request of ``prompt`` is served, by the
     reference: one full forward a token over the sequence so far, padded
     to ``T`` (a causal model: what follows a position moves nothing at
-    it), so that one program serves every length."""
+    it), so that one program serves every length; of a block that
+    decodes by blocks (``STEP``) the reference's own ``generate`` under
+    ``request``'s denoising parameters."""
+    if case in STEP:
+        ref = importlib.import_module(
+            f"chipbench.reference.{REFERENCE[case][0]}")
+        if case not in _PARAMS:
+            _PARAMS[case] = params(case)
+        return ref.generate(_PARAMS[case], prompt, max_new,
+                            reference_cfg(case), **request)
     seq = np.zeros((1, T), np.int32)
     seq[0, :len(prompt)] = prompt
     out = []
@@ -463,6 +493,9 @@ TOL = {
     # chunked form sums in another order than the recurrence, the
     # grouped matmuls in another than one expert at a time)
     "granite_hybrid": 2e-4, "granite_moe_hybrid": 2e-4, "ling_hybrid": 2e-4,
+    # 3 layers, logits about 3 (tests/test_sdar_moe.py, PR 60: 2e-6 to
+    # 6e-6, Trinity's head geometry and OLMoE's router)
+    "sdar_moe": 5e-5,
     # 2 layers, logits up to 0.8 (tests/test_moe.py's free routing: 1.5e-7
     # to 6e-7; the dense blocks measured with ISSUE 57's suite: under 1e-6)
     "olmoe": 1e-5, "gpt2": 1e-5, "gpt2_rotary": 1e-5,
@@ -474,7 +507,7 @@ _ROWS = ["cursor", "rows"]
 #: rewinds anywhere, and takes drafts and prefix stores
 FAMILIES = {
     "gpt2": _ROWS, "gpt2_rotary": _ROWS, "olmoe": _ROWS, "glm_dsa": _ROWS,
-    "axk1": _ROWS, "xing4": _ROWS,
+    "axk1": _ROWS, "xing4": _ROWS, "sdar_moe": _ROWS,
     "afmoe": ["cursor", "ring", "rows"],
     "evabyte": ["cursor", "summary", "window"],
     "granite_hybrid": ["conv", "cursor", "recurrent", "rows"],
@@ -545,31 +578,40 @@ REFUSED["granite_hybrid"]["no_published_keys"] = ({"granite": None},
 
 
 # ------------------------------------------------- a schedule of dispatches
-def window(*fed, slots=SLOTS, S=WINDOW):
+def window(*fed, slots=SLOTS, S=WINDOW, rider=1):
     """One window dispatch, ``(S, fed counts a slot)``: the slots past
-    those named ride with one token."""
-    return (S, list(fed) + [1] * (slots - len(fed)))
+    those named ride with one token (``rider``; 0: they wait)."""
+    return (S, list(fed) + [rider] * (slots - len(fed)))
 
 
-def steps(n, *fed, slots=SLOTS):
-    """``n`` S = 1 dispatches: the slots past those named are fed."""
-    return [window(*fed, slots=slots, S=1)] * n
+def steps(n, *fed, slots=SLOTS, step=1):
+    """``n`` decode dispatches of ``step`` rows a slot (S = 1; a
+    block's length where a block decodes by blocks): the slots past
+    those named are fed, a named one ``step`` rows or none."""
+    return [(step, [step * f for f in fed]
+             + [step] * (slots - len(fed)))] * n
 
 
-def schedules(W, slots, budget):
+def schedules(W, slots, budget, step=1):
     """The dispatches every block is walked through, ``{name: [(S, fed
     counts a slot)]}``: whole windows of ``W`` rows, windows inside the
     packed program's ``budget`` with riders, ragged chunks, decode
-    before the windows."""
+    before the windows. Of a block that decodes ``step`` positions a
+    step every count is whole blocks (rounded down, and a block at
+    least), a decoding slot waits where it would ride a window, and a
+    decode dispatch is one block a slot."""
+    rider = 1 if step == 1 else 0
+
     def mix(*fed):
-        return window(*fed, slots=slots, S=W)
+        return window(*[max(step, n // step * step) if n else 0
+                        for n in fed], slots=slots, S=W, rider=rider)
 
     def ones(n, *fed):
-        return steps(n, *fed, slots=slots)
+        return steps(n, *fed, slots=slots, step=step)
 
     full = mix(*[W] * slots)
-    packed = [mix(W), mix(W), mix(5, 1, W - 3), mix(1, W - 5, W - 7),
-              mix(1, W, 0), mix(1, 3)]
+    packed = [mix(W), mix(W), mix(5, rider, W - 3),
+              mix(rider, W - 5, W - 7), mix(rider, W, 0), mix(rider, 3)]
     assert all(sum(fed) <= budget for _S, fed in packed)
     return {
         # whole windows (the whole-window program, two chunks a slot a

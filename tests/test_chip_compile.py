@@ -329,6 +329,10 @@ _FED_GRAPHS = {
                       32, 32, 4, 4096, 256),
     "cerebras": (dict(capacity=2048), 8, 16, 16, 2048, 64),
     "olmoe": (dict(capacity=4096, rope=True), 8, 16, 16, 4096, 64),
+    # SDAR's (ISSUE 60): Trinity's head geometry, rotary, and the mask's
+    # upper edge the end of the query's block of 4
+    "sdar": (dict(capacity=8192, kv_heads=4, rope=True, block=4),
+             8, 32, 4, 8192, 512),
 }
 
 
@@ -406,6 +410,42 @@ def test_grouped_window_attention_compiles_for_v5e_and_copies_no_pool(kind, S,
             assert out.startswith(f"f32[{B},{Hkv},{H // Hkv},128]")
             assert all(x.startswith("%get-tuple-element")
                        for x in operands.split(", ")[-2:])
+
+
+@pytest.mark.parametrize("S", [1, 4, 512], ids=["s1", "block", "window"])
+def test_block_attention_compiles_for_v5e_and_copies_no_pool(S, v5e):
+    """SDAR's ``attention_decode(block=4)`` at the published sizes (8
+    slots, 32 query heads on 4 K/V heads of 128, capacity 8,192) in the
+    S = 1 program, the program of one block (``decode_attn``: 8 heads x
+    4 rows of a K/V head) and the prefill window of 512 rows a slot
+    (``window_attn`` alone: nobody rides a window of a graph that
+    decodes by blocks with one row); two layers hold one lowering of
+    each and call it twice, and every pool comes back in the buffer it
+    came in."""
+    _, B, H, Hkv, rows, _ = _FED_GRAPHS["sdar"]
+    attrs, ins, aux = _fed_graph("sdar", S, v5e)
+    assert attrs["block"] == 4
+    fn = get_op("attention_decode").variant_fn("pallas")
+
+    def two_layers(r, a1, a2):
+        o1, n1 = fn(attrs, r, a1, False, None)
+        o2, n2 = fn(attrs, [o1[0]] + r[1:], a2, False, None)
+        return o2, n1, n2
+
+    lowered = jax.jit(two_layers, donate_argnums=(1, 2)).lower(ins, aux, aux)
+    kernels = ["cache_write", "decode_attn" if S < 512 else "window_attn"]
+    assert re.findall(r'kernel_name = "(\w+)"', lowered.as_text()) == kernels
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for kernel in kernels:
+        assert len(re.findall(rf"%{kernel}[.\d]* = .* custom-call\(",
+                              text)) == 2, kernel
+    pool = rf"= bf16\[{B},{Hkv},{rows},128\]\S* "
+    assert not re.findall(pool + r"copy\(", text)
+    assert not re.findall(pool + r"fusion\(", text)
+    assert " scatter(" not in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        4 * B * Hkv * rows * 128 * 2
 
 
 #: the first 16 hex digits of the sha256 of the text that ONE layer of
@@ -617,6 +657,13 @@ _PARENT_PROGRAM_SHA256 = {
     ("olmoe", 1, "whole"): "afab9133b8a82585",
     ("olmoe", 16, "whole"): "c801f808ba5a429b",
     ("olmoe", 16, "packed"): "44ac33c89bb0b8c2",
+    # ISSUE 60's own tree: the block that decodes by blocks - its S = 1
+    # program, the program of one block, the prefill window and its
+    # packed form join the rest
+    ("sdar_moe", 1, "whole"): "0a898f651820b8d4",
+    ("sdar_moe", 4, "whole"): "11ef4bec07b8939e",
+    ("sdar_moe", 16, "whole"): "3d1bbf14ec4620a1",
+    ("sdar_moe", 16, "packed"): "f975eb222572fb9c",
 }
 
 
@@ -732,6 +779,9 @@ _PARENT_GRAPH_SHA256 = {
     ("gpt2_rotary", 16): "ba38ab3f681ca623",
     ("olmoe", 1): "e2aeb2264db3a8f5",
     ("olmoe", 16): "2b430acdadc4d962",
+    ("sdar_moe", 1): "20d23c640e7c2682",                           # ISSUE 60's own tree
+    ("sdar_moe", 4): "35fb89b50c5d5e98",
+    ("sdar_moe", 16): "86c9b0fa92012e11",
     ("gpt2", "loss"): "f3eafb9ae1e1305d",
     ("gpt2", "logits"): "454a7d008d7a8bd4",
     ("olmoe", "loss"): "bce42d5f0c8d936c",
