@@ -1771,6 +1771,7 @@ class BatchedKVCacheDecoder:
             slots = module.data_shapes[0].shape[0]
         self.slots = int(slots)
         self.pos = np.zeros(self.slots, np.int64)    # device-cursor mirror
+        self._sent_back = None      # rewind_many(where=)'s rows, till kept
         self.active = np.zeros(self.slots, bool)
         self._windows = {}                           # step_len -> module
         # step_len -> how a step's host arrays reach that module's cells
@@ -1788,6 +1789,7 @@ class BatchedKVCacheDecoder:
         self._select_programs = {}                   # step_len -> program
         self._merge_programs = {}                    # step_len -> program
         self._denoise_programs = {}                  # block length -> program
+        self._block_programs = {}                    # block length -> program
         self._moe_program = None                     # built at first use
         exe = module._exec_group.executor
         # what every step program takes over and updates in place (the
@@ -1982,14 +1984,16 @@ class BatchedKVCacheDecoder:
         """Slot indices with no active sequence."""
         return [i for i in range(self.slots) if not self.active[i]]
 
-    def _set_cursors(self, rows, positions):
+    def _set_cursors(self, rows, positions, where=None):
         """Set the device cursor of every slot in ``rows`` to its entry
         of ``positions`` in every layer, and the host mirror with it:
         one launch of one program that takes all ``*cache_pos`` cells
         (donated), a (slots,) position vector and a (slots,) mask, and
         returns ``where(mask, position, cell)`` for each. Rows not named
         keep their value; every cell keeps its placement and dtype and
-        gets a buffer of its own. A row named twice is refused."""
+        gets a buffer of its own. A row named twice is refused.
+        ``where`` (``rewind_many``) takes the mask's place as it lies on
+        the device."""
         rows = np.asarray(rows, np.int64).reshape(-1)
         if not rows.size:
             return
@@ -2004,6 +2008,10 @@ class BatchedKVCacheDecoder:
         if mask.sum() != rows.size:
             raise MXNetError(f"slot named twice in one cursor update: "
                              f"{rows.tolist()}")
+        if where is not None and self._sent_back is not None:
+            raise MXNetError(
+                "rewind_many(where=) before kept() has said who of "
+                f"slots {self._sent_back[0].tolist()} stayed")
         if self._ring is not None:
             cur = self.pos[rows]
             back = cur - positions
@@ -2059,15 +2067,18 @@ class BatchedKVCacheDecoder:
             self._cursor_program = jax.jit(
                 cursor_update, donate_argnums=0,
                 out_shardings=tuple(a.sharding for a in arrays))
-        for cell, new in zip(cells,
-                             self._cursor_program(arrays, target, mask)):
+        for cell, new in zip(cells, self._cursor_program(
+                arrays, target, mask if where is None else where)):
             cell._set(new)
+        if where is not None:
+            self._sent_back = rows, self.pos[rows].copy()
         self.pos[rows] = positions
         if self.name is not None:
             _telemetry.counter("serve.decode.cursor.updates",
                                model=self.name).inc()
-            _telemetry.counter("serve.decode.cursor.rows",
-                               model=self.name).inc(int(rows.size))
+            if where is None:       # else ``kept`` counts who moved
+                _telemetry.counter("serve.decode.cursor.rows",
+                                   model=self.name).inc(int(rows.size))
 
     def select_rows(self, out, idx, feed=None, now=None):
         """From a step's ``(slots, S, V)`` output as it lies on the
@@ -2138,7 +2149,9 @@ class BatchedKVCacheDecoder:
         maximum) with confidence ``c = softmax(logits)[x0]`` in
         float32; the positions with ``c > threshold`` are decided, and
         the ``quota`` most confident whatever the threshold (the
-        earlier position first among equals). Returns ``(2, slots,
+        earlier position first among equals). ``ids`` and ``undecided``
+        may be device arrays, ``merge_block``'s first two results,
+        taken as they lie (the same program). Returns ``(2, slots,
         L)`` int32 on the device, its copy to the host started: the
         ids with ``x0`` at the positions decided, and 1 where a
         position is still undecided. A slot with nothing undecided (a
@@ -2148,12 +2161,15 @@ class BatchedKVCacheDecoder:
         slot, never logits**, as ``select_rows`` does for one row. The
         annotation is ``decode.denoise_select``; ``now`` makes
         ``last_select`` its seconds."""
+        import jax
         t0 = None if now is None else now()
         with _telemetry.span("decode.denoise_select"):
             arr = out.asjax()
             L = arr.shape[1]
-            ids = np.asarray(ids, np.int32)
-            undecided = np.asarray(undecided, bool)
+            if not isinstance(ids, jax.Array):
+                ids = np.asarray(ids, np.int32)
+            if not isinstance(undecided, jax.Array):
+                undecided = np.asarray(undecided, bool)
             if arr.ndim != 3 or ids.shape != (self.slots, L) \
                     or undecided.shape != (self.slots, L):
                 raise MXNetError(
@@ -2162,7 +2178,6 @@ class BatchedKVCacheDecoder:
                     f"{arr.shape}, {ids.shape}, {undecided.shape}")
             program = self._denoise_programs.get(L)
             if program is None:
-                import jax
                 import jax.numpy as jnp
 
                 def denoise_select(rows, ids, undecided, quota, threshold):
@@ -2226,6 +2241,51 @@ class BatchedKVCacheDecoder:
             program = self._merge_programs[S] = jax.jit(merge_tokens)
         return program(tokens, ids, chip)
 
+    def merge_block(self, tokens, undecided, state, chip):
+        """What a feed of one block a slot takes where some slots'
+        blocks are the chip's to give: the host's ``(slots, L)``
+        ``tokens`` and ``undecided`` with the rows of the slots that
+        ``chip`` (``(slots,)`` bools) names taken from ``state``,
+        ``denoise_select``'s ``(2, slots, L)`` of the feed before as it
+        lies on the device - its ids, and its mask of what is still
+        undecided. Returns ``(ids, undecided, back)``, all on the
+        device: the ids in the data cell's dtype and where a step's
+        batch lies (``step`` puts nothing), the mask as
+        ``denoise_select`` takes it, and ``back`` ``(slots,)`` bools,
+        the slots whose block has a position undecided - whose feed
+        keeps nothing, and whose cursor ``rewind_many(where=back)``
+        puts back behind the step. One launch of
+        ``merge_block_<slots>x<L>`` (its name in the trace), one
+        program a rung whatever ``chip`` holds."""
+        tokens = np.asarray(tokens)
+        undecided = np.asarray(undecided, bool)
+        chip = np.asarray(chip, bool).reshape(-1)
+        L = tokens.shape[-1]
+        if tokens.shape != (self.slots, L) or undecided.shape != tokens.shape \
+                or chip.shape != (self.slots,) \
+                or state.shape != (2, self.slots, L):
+            raise MXNetError(
+                f"merge_block() wants ({self.slots}, L) tokens and flags, "
+                f"(2, {self.slots}, L) ids and mask and ({self.slots},) "
+                f"flags, got {tokens.shape}, {undecided.shape}, "
+                f"{state.shape}, {chip.shape}")
+        program = self._block_programs.get(L)
+        if program is None:
+            import jax
+            import jax.numpy as jnp
+            token_dtype = self._mod._exec_group.executor \
+                .arg_dict[self._mod.data_names[0]].dtype
+
+            def merge_block(tokens, undecided, state, chip):
+                chip = chip[:, None]
+                ids = jnp.where(chip, state[0], tokens.astype(state.dtype))
+                left = jnp.where(chip, state[1] != 0, undecided)
+                return ids.astype(token_dtype), left, jnp.any(left, axis=-1)
+
+            merge_block.__name__ = f"merge_block_{self.slots}x{L}"
+            program = self._block_programs[L] = jax.jit(merge_block)
+        return program(tokens, undecided, state, chip)
+
     def join(self, slot):
         """Claim ``slot`` for a new sequence: set its device cursor to
         0 in every layer (one launch of the cursor program — never a
@@ -2257,11 +2317,41 @@ class BatchedKVCacheDecoder:
         the same bit-clean contract as ``join``."""
         self._set_cursors([slot], [pos])
 
-    def rewind_many(self, slots, positions):
+    def rewind_many(self, slots, positions, where=None):
         """Batched ``rewind``: still ONE launch of the cursor program,
         for any number of distinct slots (the chunk-dispatch epilogue
-        touches most of a rung); an empty list launches nothing."""
-        self._set_cursors(slots, positions)
+        touches most of a rung); an empty list launches nothing.
+
+        ``where`` - ``(slots,)`` bools as they lie on the device,
+        ``merge_block``'s third result - is which of ``slots`` go back:
+        it takes the mask's place in the same program, so it may name
+        nobody outside ``slots``. The host cannot know who stayed: the
+        mirror ``pos`` takes ``positions`` for every slot named until
+        whoever fetches what ``where`` was computed from says who did
+        (``kept``), before a step or a cursor update over them and
+        before the next call with ``where``."""
+        self._set_cursors(slots, positions, where=where)
+
+    def kept(self, slots):
+        """Who stayed of the slots that the last
+        ``rewind_many(where=)`` named (``where`` read false there, as
+        the caller has it on the host now): their mirror ``pos`` goes
+        back to what it was before that call, as the device's cells
+        stand; the others moved and are counted
+        (``serve.decode.cursor.rows``)."""
+        if self._sent_back is None:
+            raise MXNetError("kept() with no rewind_many(where=) before")
+        rows, before = self._sent_back
+        stayed = np.isin(rows, np.asarray(slots, np.int64).reshape(-1))
+        if stayed.sum() != np.size(slots):
+            raise MXNetError(
+                f"kept({list(slots)}) names a slot that the last "
+                f"rewind_many(where=) did not: {rows.tolist()}")
+        self._sent_back = None
+        self.pos[rows[stayed]] = before[stayed]
+        if self.name is not None:
+            _telemetry.counter("serve.decode.cursor.rows", model=self.name) \
+                .inc(int((~stayed).sum()))
 
     @property
     def row_block(self):

@@ -86,8 +86,11 @@ still had undecided positions back (``rewind_many``: its keys and
 values are not kept), streams every token no earlier position of which
 is undecided, and advances the cursor by a clean feed alone. A window
 carries prefill chunks in multiples of ``L`` and the decoding slots
-wait that iteration; nothing is launched ahead (docs/serving.md, "A
-model that decodes by blocks").
+wait that iteration. A block dispatch is launched behind the one on
+the chip like any other (``_plan_block_ahead``): the ids, the mask and
+whether the feed keeps its rows are taken from ``denoise_select``'s
+result as it lies there (docs/serving.md, "A model that decodes by
+blocks").
 
 Per-sequence traces survive being batched with strangers: every
 sequence keeps its own session trace (root span
@@ -100,8 +103,8 @@ Telemetry (always on, docs/serving.md has the catalog):
 ``serve.decode.iterations``/``tokens``/``joins``/``leaves``/
 ``migrations``/``requests``/``responses``/``errors``/``fetch.bytes``/
 ``sample.device``/``sample.host``/``state.donated_bytes``/
-``runahead.launched``/``runahead.windows``/``runahead.dropped``/
-``window.dispatches`` counters, of a block engine also
+``runahead.launched``/``runahead.windows``/``runahead.blocks``/
+``runahead.dropped``/``window.dispatches`` counters, of a block engine also
 ``diffusion.feeds``/``blocks``/``decided``/``rows_dropped``/
 ``undelivered``,
 ``serve.decode.step.seconds`` + ``serve.decode.request.latency.seconds``
@@ -262,12 +265,14 @@ class _Block:
     """A block in flight: its ``L`` ids as the next feed takes them
     (the mask id at an undecided position), which positions are
     undecided - BY POSITION: a prompt may hold the mask id -, and the
-    feeds it has had."""
+    feeds it has had. It starts at the cursor ``at``: the sequence's
+    own, or where the commit of a dispatch still on the chip will
+    leave it."""
 
     __slots__ = ("ids", "undecided", "feeds")
 
-    def __init__(self, seq, mask_id):
-        L, at = seq.block_len, seq.fed
+    def __init__(self, seq, mask_id, at=None):
+        L, at = seq.block_len, seq.fed if at is None else at
         held = max(0, min(L, len(seq.prompt) - at))   # the prompt's tail
         self.ids = np.full(L, mask_id, np.int32)
         self.ids[:held] = seq.prompt[at:at + held]
@@ -757,10 +762,12 @@ class DecodeEngine:
         put back, as a feed that keeps nothing runs, and every other
         window program as a prefill window is launched - one slot its
         whole chunk, nobody else a row (the packed form where the rung
-        has one). Nothing is launched ahead of a block engine, so the
-        forms fed from the chip are not compiled; the S = 1 program,
-        which serves no request, stays bound and compiles at its first
-        direct call."""
+        has one). A block dispatch that runs ahead takes ids, mask and
+        who goes back from the chip: one more of the block's launches
+        fed that way (``merge_block``, and the step, the decision and
+        the cursor program over what it leaves there). The S = 1
+        program, which serves no request, stays bound and compiles at
+        its first direct call."""
         L = self.block_len
         for rung in self.ladder:
             drv = self._drivers[rung]
@@ -770,24 +777,34 @@ class DecodeEngine:
                 fed = np.asarray([S] + [0] * (rung - 1)) \
                     if S != L and drv.window_budget(S) is not None else None
 
-                def launch():
+                def launch(chip=None):
                     drv.rewind_many(slots, [0] * rung)
-                    out = drv.step(wz, fed=fed)
+                    tokens, left, back = wz, np.ones((rung, S), bool), None
+                    if chip is not None:    # the block's, from the chip
+                        tokens, left, back = drv.merge_block(
+                            tokens, left, chip, np.arange(rung) > 0)
+                    out = drv.step(tokens, fed=fed)
                     drv.release_outputs()
-                    if S == L:
-                        state = drv.denoise_select(
-                            out, wz, np.ones((rung, L), bool),
-                            np.ones(rung), np.full(rung, np.inf))
-                    else:
+                    if S != L:
                         state = drv.select_rows(
                             out, np.zeros(rung, np.int32))[1]
+                    else:
+                        state = drv.denoise_select(
+                            out, tokens, left, np.ones(rung),
+                            np.full(rung, np.inf))
+                        if back is not None:
+                            drv.rewind_many(slots, [0] * rung, where=back)
+                            drv.kept(())    # whoever did goes to 0 below
                     drv.moe_stats_begin()
-                    return np.asarray(state)
+                    np.asarray(state)           # waits for the device
+                    return state
 
                 launch()                         # trace + compile
                 t0 = clock.now()
-                launch()                         # steady state
+                state = launch()                 # steady state
                 self.exec_est[(rung, S)] = max(0.0, clock.now() - t0)
+                if S == L:
+                    launch(chip=state)           # as one launched ahead
             if rows and drv.positional:
                 drv.warm_rows()
             drv.active[:] = False
@@ -953,17 +970,25 @@ class _Dispatch:
     ``ahead`` the moment its predecessor's ids were on the host),
     ``plan_s`` and ``phases``. A dispatch of one block a slot
     (``block``: the block's length; 0 of any other) carries instead of
-    ``last`` / ``feed`` / ``chip`` what ``denoise_select`` takes -
+    ``last`` / ``feed`` what ``denoise_select`` takes -
     ``undecided``, ``quota``, ``threshold`` - and ``back``, the rows
     and cursors of the slots whose feed keeps nothing; its ``meta`` is
     ``(row, seq, L)`` and ``kinds`` says of each row whether its feed
     is ``"tentative"``, a ``"commit"`` or a ``"prefill"`` of prompt
-    tokens."""
+    tokens. Of one launched ``ahead``, ``chip`` names the slots whose
+    feed before it was tentative: their ids, their mask and whether
+    this feed keeps its rows are what that feed's ``denoise_select``
+    leaves on the chip (kind ``"chip"``, among ``back`` in case),
+    until the commit of the feed before settles each as a commit or
+    tentative (``_settle_block``); ``blocks`` holds the blocks that
+    start at this dispatch, each its sequence's once that commit has
+    closed the block before."""
 
     __slots__ = ("mode", "S", "meta", "tokens", "last", "fed", "feed",
                  "chip", "want_rows", "n_active", "shared_sid", "t0",
                  "plan_s", "phases", "ahead", "launched", "block",
-                 "undecided", "quota", "threshold", "back", "kinds")
+                 "undecided", "quota", "threshold", "back", "kinds",
+                 "blocks")
 
     def __init__(self, mode, S, t0, ahead=False):
         self.mode, self.S, self.t0, self.ahead = mode, S, t0, ahead
@@ -974,7 +999,7 @@ class _Dispatch:
         self.shared_sid = self.launched = None
         self.block = 0
         self.undecided = self.quota = self.threshold = None
-        self.back, self.kinds = ([], []), {}
+        self.back, self.kinds, self.blocks = ([], []), {}, {}
         self.plan_s = 0.0
         self.phases = {"denoise": 0.0, "decided": 0,
                        "dispatch": 0.0, "fetch": 0.0, "bytes": 0,
@@ -1009,8 +1034,10 @@ class DecodeScheduler:
     Over an engine that decodes by blocks (``engine.block``) a step
     yields 0 to ``L`` tokens a slot (``_plan_block``,
     ``_commit_block``): every cursor stays on a block's edge, a window
-    carries prefill chunks alone, nothing is launched ahead, a draft
-    engine and a request that is not greedy are refused by name.
+    carries prefill chunks alone and waits for a commit on both sides,
+    a block dispatch runs ahead of the one before it
+    (``_plan_block_ahead``), a draft engine and a request that is not
+    greedy are refused by name.
     """
 
     def __init__(self, engine, clock=None, max_queue=None,
@@ -1164,7 +1191,10 @@ class DecodeScheduler:
                         "runahead.launched", "runahead.dropped",
                         "runahead.windows")
                        + _WINDOW_COUNTERS
-                       + (_DIFFUSION_COUNTERS if self._block else ())}
+                       # a block engine's: its own, and the dispatches
+                       # of one block a slot that were launched ahead
+                       + (_DIFFUSION_COUNTERS + ("runahead.blocks",)
+                          if self._block else ())}
             # what the graph's ops count of a dispatch (the driver's
             # ``read_counts``: ``OpDef.state_reads``)
             handles.update({k: self._counter(k) for k, _field in
@@ -1780,29 +1810,24 @@ class DecodeScheduler:
         one token computed for it, which the commit of this dispatch
         drops, and a request that arrives while this dispatch is on its
         way is admitted by the plan behind it. ``d`` need not be
-        launched yet: the ids are ``d``'s to give once it is."""
+        launched yet: the ids are ``d``'s to give once it is.
+
+        Over an engine that decodes by blocks the same, a block
+        dispatch behind a block dispatch (``_plan_block_ahead``)."""
         if d.mode != "window" or self.draft is not None:
             return None
         if self._block is not None:
-            # an engine that decodes by blocks: the next feed's ids are
-            # what this one decides (the ring's ``ahead`` stays 0)
-            return None
+            return self._plan_block_ahead(d, now)
         cursors, sampled, leaving = self._cursors(after=d)
         if any(at + 1 > self.engine.capacity
                for _row, _seq, at, _left in cursors) or \
                 not all(self._slots[row].sampling.greedy for row in sampled):
             return None
         if self._queue:
-            want = min(len(self._active()) + len(self._queue),
-                       self.engine.ladder.max)
-            if leaving or self.engine.ladder.bucket_for(want) > self._rung \
-                    or any(s.deadline is not None and now > s.deadline
-                           or s.prefix_id is not None
-                           and self.prefix_store is not None
-                           for s in self._queue):
+            free = None in self._slots
+            if not self._admit_behind(leaving, now):
                 return None
-            if None in self._slots:
-                self._admit_locked(now)
+            if free:            # somebody joined
                 cursors, sampled, leaving = self._cursors(after=d)
         if not cursors or \
                 self.engine.ladder.bucket_for(len(cursors)) != self._rung:
@@ -1820,6 +1845,26 @@ class DecodeScheduler:
             nxt.chip = np.zeros(self._rung, bool)
             nxt.chip[sorted(sampled)] = True
         return nxt
+
+    def _admit_behind(self, leaving, now):
+        """Admit whoever waits in the queue into the slots that are
+        free now, behind a dispatch on the chip (caller holds the lock;
+        the queue is not empty). False, and nobody admitted, where that
+        waits for the dispatch's commit: a slot frees there
+        (``leaving``) and would be theirs, a larger rung would, a
+        deadline in the queue has passed, or one names a prefix that a
+        store might join at a cursor."""
+        want = min(len(self._active()) + len(self._queue),
+                   self.engine.ladder.max)
+        if leaving or self.engine.ladder.bucket_for(want) > self._rung \
+                or any(s.deadline is not None and now > s.deadline
+                       or s.prefix_id is not None
+                       and self.prefix_store is not None
+                       for s in self._queue):
+            return False
+        if None in self._slots:
+            self._admit_locked(now)
+        return True
 
     def _run_iteration(self, iter_span):
         """Commit one dispatch: the one launched an iteration ago
@@ -1868,8 +1913,14 @@ class DecodeScheduler:
                 drv, ddrv, d.tokens, d.meta, d.S, d.phases)
             end = clock()
         elif d.block:
-            d.launched, planned = self._launch_block(drv, d, planned)
+            if fresh:
+                d.launched, planned = self._launch_block(drv, d, planned)
+            if nxt is not None:
+                nxt.launched, planned = self._launch_block(
+                    drv, nxt, planned, state=d.launched[0])
             ids, end = self._fetch_block(drv, d, planned)
+            if nxt is not None:
+                nxt.t0 = end    # a feed a slot from here to its own ids
         else:
             if fresh:
                 d.launched, planned = self._launch(
@@ -1912,6 +1963,8 @@ class DecodeScheduler:
                         rew_rows, rew_pos)
                 elif d.block:
                     emitted, chunks = self._commit_block(d, ids, end)
+                    if nxt is not None:
+                        self._settle_block(nxt, ids)
                 else:
                     emitted, chunks = self._commit_window(
                         d, ids, picked, end)
@@ -1954,6 +2007,8 @@ class DecodeScheduler:
                     m["window.dispatches"].inc()
                     if d.ahead:
                         m["runahead.windows"].inc()
+                        if d.block:
+                            m["runahead.blocks"].inc()
                     rows = [n for _row, _seq, n in d.meta]
                     m["window.fed_slots"].inc(sum(n >= 1 for n in rows))
                     m["window.riding_slots"].inc(sum(n == 1 for n in rows))
@@ -2066,7 +2121,43 @@ class DecodeScheduler:
         return emitted, chunks
 
     # ------------------------------------------------- decoding by blocks
-    def _plan_block(self, now):
+    def _block_cursors(self, after=None):
+        """``(row, seq, at, blk, chip)`` of every live slot of an engine
+        that decodes by blocks (caller holds the lock): its cursor, its
+        block in flight (None: its next feed prefills, or starts one)
+        and whether that block's ids are the chip's to give - as they
+        stand, or as the commit of the block dispatch ``after`` will
+        leave them. The host has committed the dispatch before
+        ``after``, so it knows what ``after`` is to each slot
+        (``after.kinds``): a feed that is kept moves the cursor by
+        ``L`` and closes the block, and the request with it once its
+        stream stopped or reached its length (``_commit_block``); a
+        tentative feed leaves the cursor, and the block's ids, its mask
+        and whether the next feed keeps its rows on the chip. A slot
+        joined behind ``after`` stands as it is. Beside them the
+        cursors of those who leave at ``after``'s commit."""
+        L = self.engine.block_len
+        fed_by = {} if after is None else {
+            row: seq for row, seq, _n in after.meta}
+        cursors, leaving = [], []
+        for row, seq in enumerate(self._slots):
+            if seq is None:
+                continue
+            kind = after.kinds[row] if fed_by.get(row) is seq else None
+            if kind in ("prefill", "commit"):
+                at = seq.fed + L
+                if kind == "commit" and (
+                        seq.stopped is not None
+                        or at >= len(seq.prompt) + seq.max_new):
+                    leaving.append(at)
+                    continue
+                cursors.append((row, seq, at, None, False))
+            else:
+                cursors.append((row, seq, seq.fed, seq.block,
+                                kind == "tentative"))
+        return cursors, leaving
+
+    def _plan_block(self, now, after=None, cursors=None):
         """One dispatch of an engine that decodes by blocks, from the
         state as it stands (caller holds the lock). While a slot with
         room for a chunk still prefills, a window: the prefilling slots
@@ -2080,21 +2171,47 @@ class DecodeScheduler:
         tokens and the mask id elsewhere) - fed to be taken back while
         a position is undecided, once more to be kept when none is -
         and a slot that prefills without room for a chunk its next
-        ``L`` prompt tokens, kept."""
+        ``L`` prompt tokens, kept.
+
+        With ``after``, a block dispatch still on the chip, the block
+        dispatch behind it from the state as ``after``'s commit will
+        leave it (``_block_cursors(after=)``, or ``cursors`` where the
+        caller has them; ``_plan_block_ahead`` says when), or None
+        where that is a window, nobody is left, a smaller rung is due
+        or a cache has no room for ``L`` more rows. A slot whose feed
+        at ``after`` is tentative is fed what that feed decides -
+        ``"chip"``: the host's row of tokens and mask stays 0 and
+        ``_launch_block`` merges the chip's in; the quota is its next
+        feed's, the cursor among ``back`` in case -, and a block that
+        starts here is held in ``blocks`` until ``after``'s commit has
+        closed the one before (``_settle_block``)."""
         L, S, cap = self.engine.block_len, self.prefill_chunk, \
             self.engine.capacity
-        live = [(row, seq) for row, seq in enumerate(self._slots)
-                if seq is not None]
+        live, leaving = cursors or self._block_cursors(after)
+        # until ``after``'s commit whoever leaves there is the driver's
+        # to guard: it refuses a step that their cursor has no room for
+        if after is not None and (
+                not live or self.engine.ladder.bucket_for(len(live))
+                != self._rung or any(
+                    at + L > cap for at in leaving
+                    + [at for _row, _seq, at, *_b in live])):
+            return None
+        left = {row: max(0, len(seq.prompt) // L * L - at)
+                for row, seq, at, _blk, _chip in live}
         # a window writes S rows behind every live cursor: all have room
         # for them, or the slots that prefill go a block at a time
-        chunked = [(row, seq) for row, seq in live if seq.prefill_left()] \
-            if S > L and all(seq.fed + S <= cap for _row, seq in live) \
+        chunked = [(row, seq) for row, seq, *_at in live if left[row]] \
+            if S > L and all(at + S <= cap for _row, _seq, at, *_b in live) \
             else []
-        d = _Dispatch("window", S if chunked else L, t0=now)
+        if chunked and after is not None:
+            return None
+        d = _Dispatch("window", S if chunked else L,
+                      t0=now if after is None else None,
+                      ahead=after is not None)
         d.tokens = np.zeros((self._rung, d.S), np.int32)
         d.fed = np.zeros(self._rung, np.int32)
         d.n_active = len(live)
-        if any(seq.trace is not None for _row, seq in live):
+        if any(seq.trace is not None for _row, seq, *_at in live):
             d.shared_sid = _trace.next_span_id()
         if chunked:
             d.last = np.zeros(self._rung, np.int32)
@@ -2102,7 +2219,7 @@ class DecodeScheduler:
             room = self.engine.window_budget(self._rung, S)
             room = S * len(chunked) if room is None else room
             for row, seq in sorted(chunked, key=lambda c: c[1].id):
-                n = min(S, seq.prefill_left(), room) // L * L
+                n = min(S, left[row], room) // L * L
                 if not n:
                     continue
                 room -= n
@@ -2115,46 +2232,130 @@ class DecodeScheduler:
             d.undecided = np.zeros((self._rung, L), bool)
             d.quota = np.zeros(self._rung, np.int32)
             d.threshold = np.full(self._rung, np.inf, np.float32)
-            for row, seq in live:
+            for row, seq, at, blk, chip in live:
                 d.fed[row] = L
                 d.meta.append((row, seq, L))
-                if seq.prefill_left():
-                    d.tokens[row] = seq.prompt[seq.fed:seq.fed + L]
+                if left[row]:
+                    d.tokens[row] = seq.prompt[at:at + L]
                     d.kinds[row] = "prefill"
                     continue
-                if seq.block is None:
-                    seq.block = _Block(seq, self._block["mask_token_id"])
-                blk = seq.block
-                d.tokens[row] = blk.ids
-                if not blk.undecided.any():
-                    d.kinds[row] = "commit"
-                    continue
-                d.kinds[row] = "tentative"
-                d.undecided[row] = blk.undecided
+                if chip:
+                    if d.chip is None:
+                        d.chip = np.zeros(self._rung, bool)
+                    d.chip[row] = True
+                    d.kinds[row] = "chip"
+                    feeds = blk.feeds + 1
+                else:
+                    if blk is None:
+                        blk = d.blocks[row] = _Block(
+                            seq, self._block["mask_token_id"], at)
+                    d.tokens[row] = blk.ids
+                    if not blk.undecided.any():
+                        d.kinds[row] = "commit"
+                        continue
+                    d.kinds[row] = "tentative"
+                    d.undecided[row] = blk.undecided
+                    feeds = blk.feeds
                 steps, d.threshold[row] = self._denoising(seq)
                 # L / steps a feed, the remainder to the first feeds
-                d.quota[row] = L // steps + (blk.feeds < L % steps) \
-                    if blk.feeds < steps else L
+                d.quota[row] = L // steps + (feeds < L % steps) \
+                    if feeds < steps else L
                 d.back[0].append(row)
-                d.back[1].append(seq.fed)
+                d.back[1].append(at)
+        if after is None:
+            self._settle_block(d)
         for _row, seq, _n in d.meta:
             if seq.first_dispatch_at is None:
                 seq.first_dispatch_at = now
         return d
 
-    def _launch_block(self, drv, d, t):
+    def _plan_block_ahead(self, d, now):
+        """``_plan_ahead`` of an engine that decodes by blocks: the
+        block dispatch behind the block dispatch ``d``, planned while
+        ``d`` is on the chip (caller holds the lock), or None. What a
+        slot's next feed needs of a tentative feed at ``d`` -
+        the ids with the decided positions filled in, the mask of what
+        is still undecided, and with that whether the next feed is the
+        one that is kept - ``denoise_select`` leaves on the chip;
+        everything else (``_block_cursors(after=d)``) the host has.
+
+        None under ``_plan_ahead``'s own conditions - somebody waits in
+        the queue and a deadline there has passed, a slot that ``d``'s
+        commit frees would be theirs, a larger rung would, or one names
+        a prefix to a store; a smaller rung is due; a cache has no room
+        for ``L`` more rows - and where ``d`` or the dispatch behind it
+        is a window of prefill chunks, which is planned after the
+        commit. A request's last block is known by its length, and an
+        ``eos_id`` inside a block by the feed before the one that keeps
+        it (``seq.stopped``), so a slot that leaves at ``d`` is planned
+        as gone; whom a caller submits from ``d``'s callbacks is
+        admitted by the plan behind, and a sequence whose deadline
+        passes while its feed is on the chip has that feed dropped
+        (``serve.decode.runahead.dropped``)."""
+        if not d.block:
+            return None
+        cursors = self._block_cursors(after=d)
+        if self._queue:
+            free = None in self._slots
+            if not self._admit_behind(cursors[1], now):
+                return None
+            if free:            # somebody joined
+                cursors = self._block_cursors(after=d)
+        return self._plan_block(now, after=d, cursors=cursors)
+
+    def _settle_block(self, d, state=None):
+        """What the commit of the dispatch before tells the block
+        dispatch ``d`` (caller holds the lock; of a dispatch planned
+        after that commit, at once): a block that starts at ``d`` is
+        its sequence's from here, and of a slot whose ids were the
+        chip's (``state``: the ``(2, rung, L)`` ids and mask of the
+        dispatch before, now on the host) ``d`` is the feed that is
+        kept where nothing was left undecided, and a tentative one
+        otherwise - as the device has decided already
+        (``rewind_many(where=)``), and the driver is told who stayed
+        (``kept``). ``back`` is from here the slots whose feed keeps
+        nothing."""
+        for row, seq, _n in d.meta:
+            if row in d.blocks and seq.slot is not None:
+                seq.block = d.blocks[row]
+        if d.chip is None:
+            return
+        rows, cursors, stayed = [], [], []
+        for row, at in zip(*d.back):
+            if d.kinds[row] == "chip":
+                d.kinds[row] = "tentative" if state[1][row].any() \
+                    else "commit"
+            if d.kinds[row] == "commit":
+                stayed.append(row)
+                continue
+            rows.append(row)
+            cursors.append(at)
+        self.engine.driver(self._rung).kept(stayed)
+        d.back = (rows, cursors)
+
+    def _launch_block(self, drv, d, t, state=None):
         """The launches of a dispatch of one block a slot,
         ``serve.decode.iter.dispatch``: the step, ``denoise_select``
         behind it over all its rows (``decode.denoise_select``), the
         cursors of the slots whose feed keeps nothing put back
         (``rewind_many``: one small launch, queued behind the step, so
         the host waits for neither) and the ``moe_stats`` stack.
+        Where some slots' blocks are the chip's (``d.chip``; ``state``
+        is what the dispatch before decided, as ``denoise_select`` left
+        it there) one launch more in front, ``merge_block``: the step
+        and the decision take its ids and mask as they lie, and the
+        cursors go back where its third result says a block has a
+        position undecided.
         Returns ``((state, routed), the reading that closed the
         phase)``; ``phases["denoise"]`` runs from the decision
         program's launch to its ids on the host (``_fetch_block``)."""
         now, phases = self._clock.now, d.phases
         with _telemetry.span("serve.decode.iter.dispatch"):
-            out = drv.step(d.tokens, fed=d.fed, now=now)
+            tokens, undecided, back = d.tokens, d.undecided, None
+            if d.chip is not None:
+                tokens, undecided, back = drv.merge_block(
+                    tokens, undecided, state, d.chip)
+            out = drv.step(tokens, fed=d.fed, now=now)
             drv.release_outputs()       # ``out`` is this call's alone
             phases["reads"].update(drv.last_reads)
             phases["stage"] += drv.last_stage
@@ -2162,12 +2363,12 @@ class DecodeScheduler:
             phases["program_rows"] += drv.last_program_rows
             phases["head_rows"] += drv.last_head_rows
             phases["denoise"] = -now()
-            state = drv.denoise_select(out, d.tokens, d.undecided, d.quota,
+            state = drv.denoise_select(out, tokens, undecided, d.quota,
                                        d.threshold, now=now)
             phases["select"] += drv.last_select
             out = None
             if d.back[0]:
-                drv.rewind_many(*d.back)
+                drv.rewind_many(*d.back, where=back)
             routed = drv.moe_stats_begin()
         t_launched = now()
         phases["dispatch"] += t_launched - t
@@ -2207,13 +2408,17 @@ class DecodeScheduler:
         kept moves the cursor by ``L``; where it was a block's, the
         block is done, and the request with it once its stream stopped
         or reached its length (positions of that last block past the
-        length were denoised and are nobody's). Returns ``(emitted,
-        prefill chunks)``."""
+        length were denoised and are nobody's). A slot that retired
+        while ``d``, launched ahead, was on the chip has its feed
+        dropped and counted (``serve.decode.runahead.dropped``).
+        Returns ``(emitted, prefill chunks)``."""
         L = d.block
         emitted = chunks = feeds = blocks = decided = undelivered = 0
+        dropped = 0
         ids, left = state
         for row, seq, _n in d.meta:
             if seq.slot is None:
+                dropped += d.ahead
                 continue
             kind = d.kinds[row]
             if seq.trace is not None:
@@ -2250,6 +2455,8 @@ class DecodeScheduler:
         d.phases["decided"] = decided
         m = self._iter_metrics()
         m["sample.device"].inc(decided)
+        if dropped:
+            m["runahead.dropped"].inc(dropped)
         for key, n in (("feeds", feeds), ("blocks", blocks),
                        ("decided", decided),
                        ("rows_dropped", L * len(d.back[0])),
@@ -2472,6 +2679,7 @@ class DecodeScheduler:
             "prefill_chunks": c("prefill.chunks"),
             "runahead": {"launched": c("runahead.launched"),
                          "windows": c("runahead.windows"),
+                         "blocks": c("runahead.blocks"),
                          "dropped": c("runahead.dropped")},
             "latency_ms": None if h is None or not h.count else {
                 "p50": round((h.quantile(0.50) or 0) * 1e3, 3),

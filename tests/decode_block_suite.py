@@ -315,10 +315,12 @@ def test_mixed_prefill_and_decode_equals_one_request_at_a_time(engine, block):
     assert mixed == alone and all(len(t) == 12 for t in mixed)
     assert sched.stats()["compiles_since_warmup"] == 0
     if block in blocks.STEP:
-        # a feed that keeps nothing is taken back, nothing runs ahead,
-        # and the tokens are the reference's own procedure's
+        # a feed that keeps nothing is taken back, a block dispatch
+        # runs ahead of the one on the chip, and the tokens are the
+        # reference's own procedure's
         assert sched._counter("cursor.rows").value - before > 4
-        assert sched.stats()["runahead"]["launched"] == 0
+        ahead = sched.stats()["runahead"]
+        assert 0 < ahead["blocks"] <= ahead["launched"]
         assert alone[1] == blocks.plain_greedy(block, prompts[1], 12)
     else:
         # the joins; nothing rewound

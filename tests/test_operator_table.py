@@ -440,11 +440,12 @@ def test_armed_step_waits_for_the_dispatch_before_never_its_own(
         monkeypatch):
     mod = _bound("lw_")
     group = mod._exec_group
-    calls = []
+    calls, alive = [], []
     prog = group._fused_prog
 
     def dispatch(*args):
         out = prog(*args)
+        alive.append(out[6][0])     # an id is one array's while it lives
         calls.append(("dispatch", id(out[6][0])))    # mets[0]
         return out
 

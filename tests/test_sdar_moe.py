@@ -5,7 +5,9 @@ rule - a query attends every key up to the end of its own block of 4
 positions - under which a decode step is a block: fed with some
 positions the mask id, its rows thrown away, some positions decided
 from the logits on the device, fed again until none is undecided and
-once more to keep its keys and values). What every served block does is
+once more to keep its keys and values; a block dispatch launched while
+the one before it is on the chip, its ids, its mask and who goes back
+taken from there). What every served block does is
 ``tests/decode_block_suite.py``'s, over the row ``sdar_moe`` of
 ``tests/decode_blocks.py`` against the plain reference
 chipbench/reference/sdar_moe.py (its schedules in whole blocks:
@@ -115,6 +117,40 @@ def test_a_masked_feed_leaves_nothing_behind(driver):
     blocks.reset(driver)
 
 
+def test_the_mirror_follows_a_rewind_whose_mask_is_the_devices(driver):
+    """``rewind_many(where=)`` sends back whom a mask on the device
+    names; the host's mirror goes back with every slot named until
+    ``kept`` says who stayed, and is then the device's cells. A second
+    ``where`` before that, a ``kept`` of a slot not named and a
+    ``kept`` with nothing before it are refused."""
+    seqs = blocks.seqs(BLOCK, WINDOW + L, seed=13)
+    blocks.reset(driver)
+    for slot in range(SLOTS):
+        driver.join(slot)
+    driver.step(seqs[:, :WINDOW])
+    driver.step(seqs[:, WINDOW:])
+    named = list(range(SLOTS - 1))              # the last slot: nobody's
+    back = np.arange(SLOTS) % 2 == 0
+    back[-1] = False
+    driver.rewind_many(named, [WINDOW] * len(named), where=jnp.asarray(back))
+    assert list(driver.pos) == [WINDOW] * len(named) + [WINDOW + L]
+    with pytest.raises(MXNetError, match="before kept"):
+        driver.rewind_many(named, [WINDOW] * len(named),
+                           where=jnp.asarray(back))
+    stayed = [slot for slot in named if not back[slot]]
+    with pytest.raises(MXNetError, match="did not"):
+        driver.kept(stayed + [SLOTS - 1])
+    driver.kept(stayed)
+    with pytest.raises(MXNetError, match="no rewind_many"):
+        driver.kept(stayed)
+    want = np.where(back, WINDOW, WINDOW + L)
+    assert list(driver.pos) == list(want)
+    for cell in driver._cursor_cells():
+        assert (np.asarray(cell.asjax()).reshape(SLOTS, -1)
+                == want[:, None]).all()
+    blocks.reset(driver)
+
+
 @pytest.mark.parametrize("S,fed", [(1, None), (L, None), (L, [L, 0, L]),
                                    (16, None), (16, [16, 8, 4]),
                                    (16, [0, 12, 16])])
@@ -204,21 +240,52 @@ def _grew(sched, keys=("feeds", "blocks", "decided", "rows_dropped",
     return {k: sched._counter(f"diffusion.{k}").value for k in keys}
 
 
+def _ahead(sched):
+    """What ran ahead, by the scheduler's own count."""
+    return dict(sched.stats()["runahead"])
+
+
+def _records(engine):
+    from mxnet_tpu.telemetry import flightrec
+    return [r for r in flightrec.get_records()
+            if r.get("kind") == "serve.decode.step"
+            and r.get("model") == engine.name]
+
+
+def _behind(sched):
+    """The synchronous order: every dispatch planned after the commit
+    of the one before it."""
+    sched._plan_ahead = lambda d, now: None
+    return sched
+
+
+_LOW = SamplingParams(confidence_threshold=0.08)
+
+
 @pytest.mark.parametrize("P,N", [(16, 7), (13, 10), (6, 5), (23, 9),
                                  (2, 3)])
 @pytest.mark.parametrize("request_", ["quota", "threshold"])
-def test_the_served_stream_is_the_references_generate(engine, P, N, request_):
+@pytest.mark.parametrize("order", ["ahead", "behind"])
+def test_the_served_stream_is_the_references_generate(engine, P, N, request_,
+                                                      order):
     """``P mod L`` in {0, 1, 2, 3}, ``N`` no multiple of ``L``: under
     the quota schedule (a position a feed) a request costs exactly the
     feeds of its blocks, a feed more than a block has undecided
     positions; under a threshold low enough that some feeds decide two
-    or more positions fewer. Either way the reference's tokens."""
-    sampling = _STATIC if request_ == "quota" \
-        else SamplingParams(confidence_threshold=0.08)
+    or more positions fewer - the feed behind such a one is the commit,
+    and only the chip's mask says so when it is launched. Either way
+    the reference's tokens and the same feeds, whether each block
+    dispatch is launched behind the one on the chip (``ahead``) or
+    after its commit."""
+    from mxnet_tpu.telemetry import flightrec
+    sampling = _STATIC if request_ == "quota" else _LOW
     more = {} if request_ == "quota" else {"confidence_threshold": 0.08}
     sched = _scheduler(engine)
+    if order == "behind":
+        _behind(sched)
     prompt = _prompt(P, seed=P)
-    before = _grew(sched)
+    before, ran = _grew(sched), _ahead(sched)
+    flightrec.clear()
     handle = sched.submit(prompt, max_new_tokens=N, sampling=sampling)
     sched.pump()
     trace = []
@@ -240,7 +307,16 @@ def test_the_served_stream_is_the_references_generate(engine, P, N, request_):
         assert any(decided.sum() > 1 for *_feed, decided in trace)
         assert grew["feeds"] < undecided + n_blocks
     assert sched.stats()["compiles_since_warmup"] == 0
-    assert sched.stats()["runahead"]["launched"] == 0
+    ran = {k: v - ran[k] for k, v in _ahead(sched).items()}
+    records = [r for r in _records(engine) if "block" in r]
+    assert ran["blocks"] == sum(r["ahead"] for r in records)
+    assert ran["dropped"] == 0
+    if order == "behind":
+        assert ran["launched"] == 0
+    else:
+        # one request alone: every block dispatch but the first behind
+        # a window (or the first of all) was launched ahead
+        assert ran["blocks"] == ran["launched"] == len(records) - 1
 
 
 def test_staggered_slots_a_window_among_block_steps_and_a_rung_switch(
@@ -264,19 +340,109 @@ def test_staggered_slots_a_window_among_block_steps_and_a_rung_switch(
     sched.pump()
     assert [h.result(timeout=5).tolist() for h in handles] == alone
     assert sched.migrations - migrations >= 2
-    records = [r for r in flightrec.get_records()
-               if r.get("kind") == "serve.decode.step"
-               and r.get("model") == engine.name]
+    records = _records(engine)
     kinds = ["block" if "block" in r else "window" for r in records]
     first = kinds.index("window", 3)    # a window among the block steps
     assert "block" in kinds[:first] and "block" in kinds[first:]
-    for r in records:
-        assert r["ahead"] == 0
+    for before, r in zip([None] + records, records):
+        # a block dispatch behind a block dispatch runs ahead; a window
+        # and the block dispatch behind it wait for a commit
+        if "block" not in r or before is None or "block" not in before:
+            assert r["ahead"] == 0
         if "block" in r:
             assert r["block"] == r["window"] == L
             assert 0 <= r["decided"] <= L * r["tentative"]
             assert r["denoise_us"] >= 0 and r["tentative"] <= r["rung"]
     assert sched.stats()["compiles_since_warmup"] == 0
+
+
+def _cursor_cells(drv):
+    """Every layer's cursor as it lies on the device, a column a
+    layer."""
+    return np.stack([np.asarray(c.asjax()).reshape(-1)
+                     for c in drv._cursor_cells()])
+
+
+@pytest.mark.parametrize("request_", ["quota", "threshold"])
+def test_run_ahead_serves_the_streams_of_the_synchronous_order(
+        engine, request_):
+    """Four requests together (``P mod L`` over 0..3, answers no
+    multiple of ``L``, the rung grown to 4 and shrunk with blocks in
+    flight), under the quota schedule and under a threshold that the
+    tiny model's confidences pass at some positions, so that a block
+    commits early - the case only the chip's mask can plan: token for
+    token the streams of the scheduler that plans every dispatch after
+    a commit, which are the reference's; after every commit the host's
+    cursor mirror is the device's cursor cells, a feed launched ahead
+    included; nothing compiles, in the program cache or behind it."""
+    sampling = _STATIC if request_ == "quota" else _LOW
+    more = {} if request_ == "quota" else {"confidence_threshold": 0.08}
+    prompts = [_prompt(n, seed=60 + n) for n in (16, 13, 6, 23)]
+    lens = (7, 10, 5, 9)
+    want = [blocks.plain_greedy(BLOCK, p, n, remasking=sampling.remasking,
+                                **more) for p, n in zip(prompts, lens)]
+    streams = {}
+    for order in ("behind", "ahead"):
+        sched = _scheduler(engine)
+        if order == "behind":
+            _behind(sched)
+        ran, grew = _ahead(sched), _grew(sched)
+        compiled = engine.backend_compiles_since_warmup()
+        migrations = sched.migrations
+        handles = [sched.submit(p, max_new_tokens=n, sampling=sampling)
+                   for p, n in zip(prompts, lens)]
+        while sched.pump(max_iterations=1):
+            drv = engine.driver(sched._rung)
+            cells = _cursor_cells(drv)
+            assert (cells == drv.pos[None, :]).all(), (order, cells, drv.pos)
+            for seq in sched._active():
+                # behind the cursor of its sequence by nothing but the
+                # rows of a feed on the chip that is kept
+                ahead = drv.pos[seq.slot] - seq.fed
+                assert ahead in ((0, L) if sched._ahead else (0,))
+        streams[order] = [h.result(timeout=5).tolist() for h in handles]
+        assert sched.migrations - migrations >= 2
+        assert sched.stats()["compiles_since_warmup"] == 0
+        if order == "ahead":
+            # (the first order through compiles ``migrate``'s eager
+            # copies; the forms fed from the chip were warmed)
+            assert engine.backend_compiles_since_warmup() == compiled
+        ran = {k: v - ran[k] for k, v in _ahead(sched).items()}
+        grew = {k: v - grew[k] for k, v in _grew(sched).items()}
+        streams[order + ".feeds"] = grew
+        assert (ran["launched"] > 0) == (order == "ahead")
+    assert streams["ahead"] == streams["behind"] == want
+    # and the same feeds: blocks, positions decided, rows dropped - a
+    # position a feed under the quota, fewer feeds where a confidence
+    # passed the threshold
+    fed = streams["ahead.feeds"]
+    assert fed == streams["behind.feeds"]
+    assert (fed["feeds"] == fed["decided"] + fed["blocks"]) \
+        == (request_ == "quota")
+
+
+def test_a_deadline_that_passes_drops_the_feed_on_the_chip(engine):
+    """A sequence whose time runs out while its next feed is on the
+    chip leaves with what it was delivered, that feed is dropped and
+    counted, and the request that takes its slot starts clean."""
+    sched = _scheduler(engine)
+    prompt, after = _prompt(10, seed=71), _prompt(7, seed=72)
+    dropped = _ahead(sched)["dropped"]
+    handle = sched.submit(prompt, max_new_tokens=12, deadline_ms=1000)
+    sched.pump(max_iterations=5)
+    assert sched._ahead is not None and sched._ahead.block
+    sched._clock.advance(2.0)
+    behind = sched.submit(after, max_new_tokens=6)
+    sched.pump()
+    assert handle.finish_reason == "deadline"
+    got = handle.result(timeout=5).tolist()
+    assert 0 < len(got) < 12
+    assert got == blocks.plain_greedy(BLOCK, prompt, 12)[:len(got)]
+    assert _ahead(sched)["dropped"] - dropped == 1
+    assert behind.result(timeout=5).tolist() \
+        == blocks.plain_greedy(BLOCK, after, 6)
+    drv = engine.driver(sched._rung)
+    assert (_cursor_cells(drv) == drv.pos[None, :]).all()
 
 
 def test_a_slot_that_overflows_fails_alone(engine):
@@ -294,13 +460,21 @@ def test_a_slot_that_overflows_fails_alone(engine):
 
 
 def test_an_eos_inside_a_block_ends_the_request_at_its_commit(engine):
+    """The stream stops at the id and the request retires at that
+    block's commit. The host has the block's ids a feed before the one
+    that keeps it, so it plans the slot as gone behind that feed:
+    nothing was launched for it that has to be dropped, and the
+    request that takes the slot starts clean."""
     sched = _scheduler(engine)
-    prompt = _prompt(9, seed=21)
+    prompt, after = _prompt(9, seed=21), _prompt(11, seed=22)
     stream = blocks.plain_greedy(BLOCK, prompt, 14)
     at = next(i for i, t in enumerate(stream)
               if i >= 2 and t not in stream[:i])
-    before = _grew(sched)
+    before, ran = _grew(sched), _ahead(sched)
     handle = sched.submit(prompt, max_new_tokens=14, eos_id=stream[at])
+    handle.add_done_callback(
+        lambda _h: behind.append(sched.submit(after, max_new_tokens=6)))
+    behind = []
     sched.pump()
     assert handle.result(timeout=5).tolist() == stream[:at]
     assert handle.finish_reason == "eos"
@@ -308,7 +482,13 @@ def test_an_eos_inside_a_block_ends_the_request_at_its_commit(engine):
                                               eos_id=stream[at])
     # the block that holds it was committed whole
     grew = {k: v - before[k] for k, v in _grew(sched).items()}
-    assert grew["blocks"] == (9 + at) // L + 1 - 9 // L
+    assert grew["blocks"] - (-(-(11 + 6) // L) - 11 // L) \
+        == (9 + at) // L + 1 - 9 // L
+    ran = {k: v - ran[k] for k, v in _ahead(sched).items()}
+    assert ran["blocks"] > 0 and ran["dropped"] == 0
+    assert behind[0].result(timeout=5).tolist() \
+        == blocks.plain_greedy(BLOCK, after, 6)
+    assert sched._slots == [None] * sched._rung
 
 
 def test_a_prompt_may_hold_the_mask_id(engine):
