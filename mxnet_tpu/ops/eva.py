@@ -57,6 +57,18 @@ scores never materialised, dead blocks neither fetched nor computed),
 and ``eva_write`` (the new rows into the ring). Rows land at ragged
 offsets through a one-hot matrix product, never an unaligned store.
 
+Which launches a slot takes is read from ``fed``, and from nothing
+else. In a program of S > 1 the three kernels run at S for the slots fed
+more than one row, and once more at the S = 1 program's geometry -
+``eva_summarise_ride``, ``eva_attn_ride``, ``eva_write_ride`` - for the
+slots fed exactly one (a decoding slot that rides another's prefill
+window is one query, not a window of which one row is real). A launch's
+grid runs the slots it is fed and spends no step on another (its first
+bound is data): a slot costs the triple it does not take nothing, a
+slot fed nothing costs neither anything, and its state comes back bit
+for bit. Each kernel-calling function is jitted by itself, so a program
+lowers a shape of it once, whatever its layers.
+
 The op asks the executor to donate its aux arrays to the step program
 (``donate_aux``): the pools are updated in place, a few blocks a
 dispatch, instead of being copied whole.
@@ -325,15 +337,78 @@ _BLOCK_BUDGET = 12 << 20
 _VMEM_LIMIT = 64 << 20
 
 
-def _kernel_call(kernel, name, **kwargs):
+def _kernel_call(kernel, name, interpret, **kwargs):
     """``pallas_call`` of a kernel over a (slot, head group, block)
     grid: the first two axes parallel, and on the chip the VMEM limit
     the blocks were budgeted against."""
-    if not _pk._interpret():
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT)
-    return _pk.pallas_call(kernel, name=name, **kwargs)
+    return _pk.pallas_call(kernel, name=name, interpret=interpret, **kwargs)
+
+
+#: the launches of one kernel-calling function, by the program and the
+#: slots they serve: ``decode`` is the S = 1 program's; in a program of
+#: S > 1 a slot fed more than one row takes the ``window`` launch and a
+#: slot fed exactly one the ``ride`` launch, the S = 1 geometry again
+#: (``_eva_pallas``). The last two run the slots they are fed and no
+#: other (``_FedSlots``). What a launch is called in the device trace:
+_NAMES = {"summarise": {"decode": "eva_summarise", "window": "eva_summarise",
+                        "ride": "eva_summarise_ride"},
+          "attend": {"decode": "eva_attn_decode", "window": "eva_attn_window",
+                     "ride": "eva_attn_ride"},
+          "write": {"decode": "eva_write", "window": "eva_write",
+                    "ride": "eva_write_ride"}}
+
+
+class _EverySlot:
+    """The first axis of the S = 1 program's grids: every slot in its
+    turn, one fed nothing too (it selects no row and writes none)."""
+    operands = ()
+
+    def __init__(self, fed):
+        self.bound = fed.shape[0]
+
+    @staticmethod
+    def at(index_map):
+        return index_map
+
+    @staticmethod
+    def slot(refs):
+        return pl.program_id(0)
+
+
+class _FedSlots:
+    """The first axis of a grid of a program of S > 1: the slots the
+    launch is fed something, and no step for another - the axis' bound
+    is data, as ``grouped_matmul``'s is. ``operands`` is what the launch
+    prefetches behind ``p`` and ``fed``: the slots in the order of the
+    axis, the fed ones first. A slot the grid does not reach keeps its
+    pools, which the writers' outputs alias, bit for bit, and its rows
+    of ``attend``'s output are whatever the buffer held (``_eva_pallas``
+    masks them). Where no slot is fed the bound is 1 and slot 0 runs as
+    a slot fed nothing runs in the S = 1 program."""
+
+    def __init__(self, fed):
+        live = fed > 0
+        self.operands = (jnp.argsort(jnp.logical_not(live), stable=True)
+                         .astype(jnp.int32),)
+        self.bound = jnp.maximum(jnp.sum(live.astype(jnp.int32)), 1)
+
+    @staticmethod
+    def at(index_map):
+        def mapped(i, g, j, p_ref, fed_ref, order_ref):
+            return index_map(order_ref[i], g, j, p_ref, fed_ref)
+        return mapped
+
+    @staticmethod
+    def slot(refs):
+        return refs[0][pl.program_id(0)]
+
+
+def _slots(form, fed):
+    return (_EverySlot if form == "decode" else _FedSlots)(fed)
 
 
 def _place(select, rows, old):
@@ -351,13 +426,17 @@ def _iota(shape, axis):
     return lax.broadcasted_iota(jnp.int32, shape, axis)
 
 
-def _summarise_kernel(hb, n_c, n_pad, chunk, bt, n_blocks, scale):
+def _summarise_kernel(hb, n_c, n_pad, chunk, bt, n_blocks, scale, slots,
+                      as_fed):
     """Grid (slot, head group, target block of the summary pool): pool
     the chunks of the head group and lay those that real rows complete
-    into the pool's block."""
-    def kernel(p_ref, fed_ref, nk_ref, nv_ref, sk_ref, sv_ref, phi_ref,
-               mu_ref, mk_ref, mv_ref, ok_ref, ov_ref):
-        b, j = pl.program_id(0), pl.program_id(2)
+    into the pool's block. ``as_fed``: the new rows come as the op was
+    fed them and are rotated to their offset in the cursor's chunk
+    here (what wraps around lies under the ring's rows)."""
+    def kernel(p_ref, fed_ref, *refs):
+        (nk_ref, nv_ref, sk_ref, sv_ref, phi_ref, mu_ref, mk_ref, mv_ref,
+         ok_ref, ov_ref) = refs[-10:]
+        b, j = slots.slot(refs), pl.program_id(2)
         p = p_ref[b]
         c0 = p // chunk
         off = p - c0 * chunk
@@ -381,6 +460,8 @@ def _summarise_kernel(hb, n_c, n_pad, chunk, bt, n_blocks, scale):
             pooled = []
             for new_ref, ring_ref in ((nk_ref, sk_ref), (nv_ref, sv_ref)):
                 rows = new_ref[h].astype(_F32)              # (n, d)
+                if as_fed:
+                    rows = pltpu.roll(rows, off, 0)
                 first = jnp.where(head, ring_ref[h].astype(_F32),
                                   rows[:chunk])
                 pooled.append(first if n_c == 1 else jnp.concatenate(
@@ -408,12 +489,23 @@ def _spanned(rows, block, n_blocks):
     return 1 if rows == 1 else min(n_blocks, (rows - 1) // block + 2)
 
 
-def summarise(new_k, new_v, sk, sv, phi, mu, mk, mv, p, fed, chunk):
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "form"))
+def summarise(k, v, sk, sv, phi, mu, mk, mv, p, fed, chunk, interpret,
+              form="decode"):
     """The kernel ``eva_summarise``: pools the chunks that this
-    dispatch's rows complete (``eva_pool``; the chunk that holds the
-    cursor takes its first rows from the ring) and lays them into the
-    summary pools in place. ``new_k``, ``new_v`` are ``_chunk_rows``.
-    Returns the pools."""
+    dispatch's rows ``k``, ``v`` complete (``eva_pool``; the chunk that
+    holds the cursor takes its first rows from the ring) and lays them
+    into the summary pools in place. Returns the pools. The kernel
+    takes the rows at their offset in the cursor's chunk: a window of
+    whole chunks it rotates there itself, any other comes laid out
+    (``_chunk_rows``: passes over ``slots x S`` rows outside the kernel,
+    1.1 ms a layer at the published sizes: PERF.md, PR 62). Like
+    ``attend`` and ``write_rows`` a jitted function of its own, so that
+    a program lowers each of its shapes once and calls it from every
+    layer; ``form``: ``_NAMES``."""
+    as_fed = form == "window" and k.shape[2] % chunk == 0
+    new_k, new_v = (k, v) if as_fed else (
+        _chunk_rows(x, p % chunk, chunk) for x in (k, v))
     B, H, n, d = new_k.shape
     W, n_sum = sk.shape[2], mk.shape[2]
     n_c = n // chunk
@@ -422,6 +514,7 @@ def summarise(new_k, new_v, sk, sv, phi, mu, mk, mv, p, fed, chunk):
     n_blocks = n_sum // bt
     per_head = 2 * d * mk.dtype.itemsize * (2 * n + 2 * chunk + 4 * bt)
     hb = _head_group(H, _BLOCK_BUDGET // per_head, multiple_of=8)
+    slots = _slots(form, fed)
 
     def fixed(b, g, j, p_ref, fed_ref):
         return b, g, 0, 0
@@ -436,30 +529,33 @@ def summarise(new_k, new_v, sk, sv, phi, mu, mk, mv, p, fed, chunk):
     def vec(b, g, j, p_ref, fed_ref):
         return g, 0
 
-    rows = pl.BlockSpec((None, hb, n, d), fixed)
-    old = pl.BlockSpec((None, hb, chunk, d), ring)
-    pool = pl.BlockSpec((None, hb, bt, d), target)
+    rows = pl.BlockSpec((None, hb, n, d), slots.at(fixed))
+    old = pl.BlockSpec((None, hb, chunk, d), slots.at(ring))
+    vecs = pl.BlockSpec((hb, d), slots.at(vec))
+    pool = pl.BlockSpec((None, hb, bt, d), slots.at(target))
+    n_prefetch = 2 + len(slots.operands)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H // hb, _spanned(n_c, bt, n_blocks)),
-        in_specs=[rows, rows, old, old, pl.BlockSpec((hb, d), vec),
-                  pl.BlockSpec((hb, d), vec), pool, pool],
+        num_scalar_prefetch=n_prefetch,
+        grid=(slots.bound, H // hb, _spanned(n_c, bt, n_blocks)),
+        in_specs=[rows, rows, old, old, vecs, vecs, pool, pool],
         out_specs=(pool, pool))
     return _kernel_call(
         _summarise_kernel(hb, n_c, n_pad, chunk, bt, n_blocks,
-                          float(d) ** -0.5), "eva_summarise",
+                          float(d) ** -0.5, slots, as_fed),
+        _NAMES["summarise"][form], interpret,
         out_shape=(jax.ShapeDtypeStruct(mk.shape, mk.dtype),
                    jax.ShapeDtypeStruct(mv.shape, mv.dtype)),
-        grid_spec=grid_spec, input_output_aliases={8: 0, 9: 1})(
-            p, fed, new_k, new_v, sk, sv, phi, mu, mk, mv)
+        grid_spec=grid_spec,
+        input_output_aliases={n_prefetch + 6: 0, n_prefetch + 7: 1})(
+            p, fed, *slots.operands, new_k, new_v, sk, sv, phi, mu, mk, mv)
 
 
-def _write_kernel(hb, S, bt, n_blocks, window):
+def _write_kernel(hb, S, bt, n_blocks, window, slots):
     """Grid (slot, head group, target block of the ring): lay the fed
     rows at ``(cursor + s) mod window``."""
-    def kernel(p_ref, fed_ref, nk_ref, nv_ref, sk_ref, sv_ref, ok_ref,
-               ov_ref):
-        b, j = pl.program_id(0), pl.program_id(2)
+    def kernel(p_ref, fed_ref, *refs):
+        nk_ref, nv_ref, sk_ref, sv_ref, ok_ref, ov_ref = refs[-6:]
+        b, j = slots.slot(refs), pl.program_id(2)
         r0 = p_ref[b] % window
         block = (r0 // bt + j) % n_blocks
         s = block * bt + _iota((bt, S), 0) - r0
@@ -471,7 +567,8 @@ def _write_kernel(hb, S, bt, n_blocks, window):
     return kernel
 
 
-def write_rows(k, v, sk, sv, p, fed):
+@functools.partial(jax.jit, static_argnames=("interpret", "form"))
+def write_rows(k, v, sk, sv, p, fed, interpret, form="decode"):
     """The kernel ``eva_write``: the first ``fed`` of each slot's new
     rows into the rings in place, at ``(cursor + s) mod W``. Returns
     the rings."""
@@ -481,6 +578,7 @@ def write_rows(k, v, sk, sv, p, fed):
     n_blocks = W // bt
     per_head = 2 * d * sk.dtype.itemsize * (2 * S + 4 * bt)
     hb = _head_group(H, _BLOCK_BUDGET // per_head)
+    slots = _slots(form, fed)
 
     def fixed(b, g, j, p_ref, fed_ref):
         return b, g, 0, 0
@@ -488,27 +586,32 @@ def write_rows(k, v, sk, sv, p, fed):
     def target(b, g, j, p_ref, fed_ref):
         return b, g, (p_ref[b] % W // bt + j) % n_blocks, 0
 
-    rows = pl.BlockSpec((None, hb, S, d), fixed)
-    ring = pl.BlockSpec((None, hb, bt, d), target)
+    rows = pl.BlockSpec((None, hb, S, d), slots.at(fixed))
+    ring = pl.BlockSpec((None, hb, bt, d), slots.at(target))
+    n_prefetch = 2 + len(slots.operands)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H // hb, _spanned(S, bt, n_blocks)),
+        num_scalar_prefetch=n_prefetch,
+        grid=(slots.bound, H // hb, _spanned(S, bt, n_blocks)),
         in_specs=[rows, rows, ring, ring], out_specs=(ring, ring))
     return _kernel_call(
-        _write_kernel(hb, S, bt, n_blocks, W), "eva_write",
+        _write_kernel(hb, S, bt, n_blocks, W, slots),
+        _NAMES["write"][form], interpret,
         out_shape=(jax.ShapeDtypeStruct(sk.shape, sk.dtype),
                    jax.ShapeDtypeStruct(sv.shape, sv.dtype)),
-        grid_spec=grid_spec, input_output_aliases={4: 0, 5: 1})(
-            p, fed, k, v, sk, sv)
+        grid_spec=grid_spec,
+        input_output_aliases={n_prefetch + 2: 0, n_prefetch + 3: 1})(
+            p, fed, *slots.operands, k, v, sk, sv)
 
 
-def _attn_kernel(hb, S, bs, bm, nb_s, nb_m, window, per_window, scale):
+def _attn_kernel(hb, S, bs, bm, nb_s, nb_m, window, per_window, scale,
+                 slots):
     """Grid (slot, head group, key block): the ring's blocks, then the
     summary pool's, then the S new rows and the division. Online
     softmax per head in float32 scratch."""
-    def kernel(p_ref, fed_ref, q_ref, kn_ref, vn_ref, sk_ref, sv_ref,
-               mk_ref, mv_ref, o_ref, m_s, l_s, acc_s):
-        b, j = pl.program_id(0), pl.program_id(2)
+    def kernel(p_ref, fed_ref, *refs):
+        (q_ref, kn_ref, vn_ref, sk_ref, sv_ref, mk_ref, mv_ref, o_ref, m_s,
+         l_s, acc_s) = refs[-11:]
+        b, j = slots.slot(refs), pl.program_id(2)
         p = p_ref[b]
         r0 = p % window
         w0 = p // window
@@ -571,7 +674,10 @@ def _attn_kernel(hb, S, bs, bm, nb_s, nb_m, window, per_window, scale):
     return kernel
 
 
-def attend(q, k, v, sk, sv, mk, mv, p, fed, window, chunk):
+@functools.partial(jax.jit,
+                   static_argnames=("window", "chunk", "interpret", "form"))
+def attend(q, k, v, sk, sv, mk, mv, p, fed, window, chunk, interpret,
+           form="decode"):
     """``eva_attend`` as one kernel, ``eva_attn_decode`` at S = 1 and
     ``eva_attn_window`` beyond: per slot and head group, the blocks of
     the ring below the cursor, the blocks of the summary pool that a
@@ -579,7 +685,8 @@ def attend(q, k, v, sk, sv, mk, mv, p, fed, window, chunk):
     either bound re-references the last live one, so it moves no data
     and computes nothing. The rows are padded to 16 so that the scores
     are matrix products at S = 1 too; a pad row is a later query that
-    nobody reads."""
+    nobody reads. In a program of S > 1 the rows of a slot the launch
+    is fed nothing come out undefined (``_FedSlots``)."""
     B, H, S, d = q.shape
     W, n_sum, per_window = sk.shape[2], mk.shape[2], window // chunk
     S_pad = k.shape[2]                  # the rows come padded to 16
@@ -589,6 +696,7 @@ def attend(q, k, v, sk, sv, mk, mv, p, fed, window, chunk):
     nb_s, nb_m = W // bs, n_sum // bm
     per_head = 2 * d * sk.dtype.itemsize * (3 * S_pad + 2 * bs + 2 * bm)
     hb = _head_group(H, _BLOCK_BUDGET // per_head)
+    slots = _slots(form, fed)
 
     def new_map(b, g, j, p_ref, fed_ref):
         return b, g, 0, 0
@@ -603,43 +711,72 @@ def attend(q, k, v, sk, sv, mk, mv, p, fed, window, chunk):
             ((last // window) * per_window + bm - 1) // bm, 1)
         return b, g, jnp.clip(j - nb_s, 0, live - 1), 0
 
-    new = pl.BlockSpec((None, hb, S_pad, d), new_map)
+    new = pl.BlockSpec((None, hb, S_pad, d), slots.at(new_map))
+    ring = pl.BlockSpec((None, hb, bs, d), slots.at(ring_map))
+    summary = pl.BlockSpec((None, hb, bm, d), slots.at(summary_map))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B, H // hb, nb_s + nb_m + 1),
-        in_specs=[new, new, new,
-                  pl.BlockSpec((None, hb, bs, d), ring_map),
-                  pl.BlockSpec((None, hb, bs, d), ring_map),
-                  pl.BlockSpec((None, hb, bm, d), summary_map),
-                  pl.BlockSpec((None, hb, bm, d), summary_map)],
+        num_scalar_prefetch=2 + len(slots.operands),
+        grid=(slots.bound, H // hb, nb_s + nb_m + 1),
+        in_specs=[new, new, new, ring, ring, summary, summary],
         out_specs=new,
         scratch_shapes=[pltpu.VMEM((hb, S_pad, 1), _F32),
                         pltpu.VMEM((hb, S_pad, 1), _F32),
                         pltpu.VMEM((hb, S_pad, d), _F32)])
     out = _kernel_call(
         _attn_kernel(hb, S_pad, bs, bm, nb_s, nb_m, window, per_window,
-                     float(d) ** -0.5),
-        "eva_attn_decode" if S == 1 else "eva_attn_window",
+                     float(d) ** -0.5, slots),
+        _NAMES["attend"][form], interpret,
         out_shape=jax.ShapeDtypeStruct((B, H, S_pad, d), _F32),
-        grid_spec=grid_spec)(p, fed, q, k, v, sk, sv, mk, mv)
+        grid_spec=grid_spec)(
+            p, fed, *slots.operands, q, k, v, sk, sv, mk, mv)
     return out[:, :, :S]
+
+
+def _serve(q, k, v, fed, sk, sv, mk, mv, phi, mu, p, W, C, form):
+    """The three launches of one form, in the op's order: the summaries
+    written, then the read, then the rows written. Returns the read and
+    the four pools."""
+    interpret = _pk._interpret()
+    mk, mv = summarise(k, v, sk, sv, phi, mu, mk, mv, p, fed, chunk=C,
+                       interpret=interpret, form=form)
+    grow = ((0, 0), (0, 0), (0, -q.shape[2] % 16), (0, 0))
+    k, v = jnp.pad(k, grow), jnp.pad(v, grow)
+    out = attend(q, k, v, sk, sv, mk, mv, p, fed, window=W, chunk=C,
+                 interpret=interpret, form=form)
+    sk, sv = write_rows(k, v, sk, sv, p, fed, interpret=interpret, form=form)
+    return out, sk, sv, mk, mv
 
 
 def _eva_pallas(attrs, inputs, aux, is_train, rng):
     """The kernels' lowering: every read and write of a pool is a
     kernel's aligned block, so the pools keep their layout and, their
-    arrays donated, are updated in place."""
+    arrays donated, are updated in place. Which launches a slot takes
+    in a program of S > 1 is read from ``fed``: more than one row, the
+    window's; exactly one (a decoding slot that rides a prefill
+    window), the S = 1 program's geometry on its first row, whose
+    result is row 0 of the slot's output; nothing, neither, and its
+    rows come out zero. Slots are independent, so the two triples
+    follow each other."""
     q, k, v, fed, p, W, C = _prologue(attrs, inputs, aux, is_train)
     phi, mu = inputs[4:]
-    sk, sv, mk, mv, _cursor = aux
+    pools = aux[:4]
     S = q.shape[2]
-    off = p % C
-    mk, mv = summarise(_chunk_rows(k, off, C), _chunk_rows(v, off, C),
-                       sk, sv, phi, mu, mk, mv, p, fed, C)
-    grow = ((0, 0), (0, 0), (0, -S % 16), (0, 0))
-    k, v = jnp.pad(k, grow), jnp.pad(v, grow)
-    out = attend(q, k, v, sk, sv, mk, mv, p, fed, W, C)
-    sk, sv = write_rows(k, v, sk, sv, p, fed)
-    return _epilogue(out, q, sk, sv, mk, mv, p, fed)
+    if S == 1:
+        out, *pools = _serve(q, k, v, fed, *pools, phi, mu, p, W, C,
+                             "decode")
+        return _epilogue(out, q, *pools, p, fed)
+    riding = fed == 1
+    out, *pools = _serve(q, k, v, jnp.where(riding, 0, fed), *pools, phi,
+                         mu, p, W, C, "window")
+    ride, *pools = _serve(q[:, :, :1], k[:, :, :1], v[:, :, :1],
+                          riding.astype(jnp.int32), *pools, phi, mu, p, W,
+                          C, "ride")
+    # one elementwise pass, which the cast behind it takes in: a slot's
+    # rows are the launch's that ran it, and what no launch wrote is 0
+    first = (jnp.arange(S) == 0)[None, None, :, None]
+    out = jnp.where((fed > 1)[:, None, None, None], out,
+                    jnp.where(riding[:, None, None, None] & first, ride, 0.0))
+    return _epilogue(out, q, *pools, p, fed)
 
 
 def _eva_eligible(attrs, in_shapes, in_dtypes):
@@ -677,11 +814,14 @@ EVA_SLOT_STATE = {"singles_k": "window", "singles_v": "window",
 #: what one execution reads and writes of the state
 #: (``OpDef.state_reads``): the exact rows and the summaries that each
 #: fed slot's last real query attends, the chunks summarised and the
-#: windows closed
+#: windows closed; and of the slots a dispatch with a slot fed more
+#: than one row feeds, those that take the window launches and those
+#: that ride (``_eva_pallas``)
 _EVA_COUNTS = read_counts(
     ("eva.layer_steps", None), ("eva.exact_rows", "eva_exact"),
     ("eva.summary_rows", "eva_summary"), ("eva.chunks_summarised", None),
-    ("eva.windows_closed", None))
+    ("eva.windows_closed", None), ("eva.window_slots", None),
+    ("eva.ride_slots", None), ("eva.fed_slots", None))
 
 
 def _eva_reads(attrs, capacity, sources):
@@ -691,11 +831,15 @@ def _eva_reads(attrs, capacity, sources):
         live = fed > 0
         start, end = pos[live], (pos + fed)[live]
         last = end - 1
+        window = int(np.sum(fed > 1))
+        ride = int(np.sum(fed == 1)) if window else 0
         return {"eva.layer_steps": 1,
                 "eva.exact_rows": int(np.sum(last % W + 1)),
                 "eva.summary_rows": int(np.sum(last // W * (W // C))),
                 "eva.chunks_summarised": int(np.sum(end // C - start // C)),
-                "eva.windows_closed": int(np.sum(end // W - start // W))}
+                "eva.windows_closed": int(np.sum(end // W - start // W)),
+                "eva.window_slots": window, "eva.ride_slots": ride,
+                "eva.fed_slots": window + ride}
 
     return reads
 

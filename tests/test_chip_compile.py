@@ -65,22 +65,17 @@ def test_kernel_compiles_for_v5e(site, v5e):
             f"{name}: no Mosaic kernel in the compiled {prog.__name__}"
 
 
-@pytest.mark.parametrize("S", [1, 512], ids=["decode", "window"])
-def test_eva_attention_compiles_for_v5e_and_copies_no_pool(S, v5e):
-    """EvaByte's attention at the published sizes (8 slots, 32 heads of
-    128, a window of 2,048 rows, 2,048 summaries, bfloat16): the op's
-    Pallas lowering compiles for the chip, every pool access is a
-    kernel's, and with the aux arrays donated no pool is copied or
-    re-laid - the four pools (512 MB) come back in the buffers they
-    came in."""
-    import re
+def _eva_published(S, v5e, layers=1):
+    """``eva_attention_decode`` at the published sizes (8 slots, 32
+    heads of 128, a window of 2,048 rows, 2,048 summaries, bfloat16) as
+    a program of ``layers`` layers with their aux arrays donated, for
+    the described chip: the jitted function and its arguments."""
     opdef = get_op("eva_attention_decode")
     attrs = opdef.normalize_attrs({"capacity": 32768, "window": 2048,
                                    "chunk": 16, "rope_base": 1e5})
     B, H, d = 8, 32, 128
-    bf16 = jnp.bfloat16
 
-    def sds(shape, dtype=bf16):
+    def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
     x = sds((B, H, S, d))
@@ -90,17 +85,102 @@ def test_eva_attention_compiles_for_v5e_and_copies_no_pool(S, v5e):
     assert opdef.variant_eligible("pallas", attrs,
                                   [a.shape for a in ins + aux],
                                   [str(a.dtype) for a in ins + aux])
-    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None),
-                       donate_argnums=(1,)).lower(ins, aux).compile()
+
+    def program(r, a):
+        state = []
+        for layer in range(layers):
+            (out,), new = fn(attrs, r, a[5 * layer:5 * layer + 5], False,
+                             None)
+            r = [out] + r[1:]
+            state += new
+        return out, state
+
+    return jax.jit(program, donate_argnums=(1,)), ins, aux * layers
+
+
+#: the launches of the op's Pallas lowering, by program: the three
+#: kernels, and in a window program the riding slots' three behind them
+_EVA_LAUNCHES = {
+    1: ("eva_summarise", "eva_attn_decode", "eva_write"),
+    512: ("eva_summarise", "eva_attn_window", "eva_write",
+          "eva_summarise_ride", "eva_attn_ride", "eva_write_ride"),
+}
+
+
+@pytest.mark.parametrize("S", [1, 512], ids=["decode", "window"])
+def test_eva_attention_compiles_for_v5e_and_copies_no_pool(S, v5e):
+    """EvaByte's attention at the published sizes (8 slots, 32 heads of
+    128, a window of 2,048 rows, 2,048 summaries, bfloat16): the op's
+    Pallas lowering compiles for the chip, every pool access is a
+    kernel's - a window program's six launches: the slots fed a chunk
+    and the slots that ride (ISSUE 62) -, and with the aux arrays
+    donated no pool is copied or re-laid - the four pools (512 MB) come
+    back in the buffers they came in."""
+    program, ins, aux = _eva_published(S, v5e)
+    compiled = program.lower(ins, aux).compile()
     text = compiled.as_text()
-    for kernel in ("eva_summarise", "eva_write",
-                   "eva_attn_decode" if S == 1 else "eva_attn_window"):
-        assert re.search(rf"%{kernel}[.\w]* = .*tpu_custom_call", text), \
+    for kernel in _EVA_LAUNCHES[S]:
+        assert re.search(rf"%{kernel}[.\d]* = .*tpu_custom_call", text), \
             kernel
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) \
+        == len(_EVA_LAUNCHES[S])
     assert not re.findall(r"= bf16\[8,32,2048,128\]\S* copy\(", text)
     assert " scatter(" not in text
     assert compiled.memory_analysis().alias_size_in_bytes >= 4 * (
-        B * H * 2048 * d * 2)
+        8 * 32 * 2048 * 128 * 2)
+
+
+def _mosaic_bodies(text):
+    """The Mosaic module of every kernel in a lowered program's text,
+    printed without locations (a kernel's serialised body carries the
+    lines of its source)."""
+    import base64
+    import json
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    bodies = []
+    for config in re.findall(r'backend_config = "(.*?)"[,}]', text, re.S):
+        body = json.loads(config.replace("\\22", '"'))["custom_call_config"]
+        ctx = ir.Context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True  # the attribute 'stable_mosaic'
+        with ctx:
+            bodies.append(ir.Module.parse(base64.b64decode(body["body"]))
+                          .operation.get_asm(enable_debug_info=False))
+    return bodies
+
+
+#: the first 16 hex digits of the sha256 of the three kernels of the
+#: S = 1 program at the published sizes, as Mosaic modules without
+#: locations: ISSUE 62's parent's (04bb371), computed on a copy of it.
+#: ISSUE 62 put the kernel-calling functions behind ``jax.jit`` and gave
+#: the launches of a window program a grid over the slots they are fed;
+#: the S = 1 program calls the same kernels through a call boundary
+_PARENT_EVA_S1_KERNELS = {"3956dc910fa03186", "fad848bb0f66eae8",
+                          "e648953d01fd1f67"}
+
+
+@pytest.mark.parametrize("S", [1, 512], ids=["decode", "window"])
+def test_eva_programs_hold_each_kernel_once_whatever_their_layers(S, v5e):
+    """A two-layer program lowers every launch once and calls it from
+    both layers (each kernel-calling function of ``ops/eva.py`` is a
+    jitted function of its own: a kernel lowered bare is lowered anew
+    in every layer, and ``setup_s`` pays it), and the S = 1 program's
+    three kernels are the parent's, body for body."""
+    import hashlib
+    program, ins, aux = _eva_published(S, v5e, layers=2)
+    text = program.lower(ins, aux).as_text()
+    bodies = _mosaic_bodies(text)
+    assert len(bodies) == len(set(bodies)) == len(_EVA_LAUNCHES[S])
+    for function in ("summarise", "attend", "write_rows"):
+        defined = re.findall(rf"func\.func private @{function}(?:_\d+)?\(",
+                             text)
+        called = re.findall(rf"call @{function}(?:_\d+)?\(", text)
+        assert len(defined) == len(_EVA_LAUNCHES[S]) // 3, function
+        assert len(called) == 2 * len(defined), function
+    if S == 1:
+        assert {hashlib.sha256(body.encode()).hexdigest()[:16]
+                for body in bodies} == _PARENT_EVA_S1_KERNELS
 
 
 @pytest.mark.parametrize("S,rows", [(1, 32), (256, 384)],

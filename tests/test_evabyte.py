@@ -53,20 +53,44 @@ def test_every_heads_logits_match_the_reference():
         assert np.abs(got[slot, :n] - want[slot, :n]).max() <= TOL, slot
 
 
+def _slot_state(drv, slot):
+    """Every state cell's share of one slot, by name."""
+    return {name: np.asarray(cell.asjax())[slot]
+            for name, cell in drv.slot_cells()}
+
+
 def test_a_rider_just_before_the_boundary_decodes_as_if_alone(driver):
     """A slot at position W - 1 that rides a window dispatch with one
     real token and 15 pads closes its window on the real token alone:
-    the logits of a slot that took the same steps at S = 1."""
+    the logits of a slot that took the same steps at S = 1, and the
+    state that slot wrote - every row of both rings, every summary
+    there is, the cursor. The slot that the windows fed nothing keeps
+    its state bit for bit."""
     seqs = blocks.seqs(BLOCK, 96, seed=9)
     seqs[2] = seqs[1]                   # slot 2 decodes slot 1's bytes alone
     idle = [0] * (SLOTS - 3)
     lead = [_W, (WINDOW, [16, 15, 15] + idle)]              # 1, 2 at 31
+    blocks.run(driver, seqs, lead)
+    waiting = _slot_state(driver, 2)
     rode, _, _ = blocks.run(driver, seqs,
                             lead + [(WINDOW, [16, 1, 0] + idle)] * 3)
+    rider, waited = _slot_state(driver, 1), _slot_state(driver, 2)
     alone, _, _ = blocks.run(driver, seqs,
                              lead + [(1, [0, 0, 1] + idle)] * 3)
     np.testing.assert_allclose(rode[1, 33], alone[2, 33], rtol=0, atol=2e-6)
     assert np.abs(rode[1, 33]).max() > 0.1
+    stepped = _slot_state(driver, 2)
+    assert len(rider) == 5 * KW["n_layer"]
+    for name, cell in rider.items():
+        assert np.array_equal(waited[name], waiting[name]), name
+        if name.endswith("cache_pos"):
+            assert cell.tolist() == stepped[name].tolist() == [34], name
+            continue
+        # a summary past the cursor is whatever an earlier request left
+        rows = 34 // C if "summary" in name else W
+        assert np.abs(cell[:, :rows]).max() > 0.1, name
+        np.testing.assert_allclose(cell[:, :rows], stepped[name][:, :rows],
+                                   rtol=0, atol=5e-6, err_msg=name)
 
 
 @pytest.mark.parametrize("variant", ["xla", "pallas"])
@@ -109,6 +133,92 @@ def test_the_op_matches_the_references_layer(variant, S):
         step += 1
         assert list(np.asarray(aux[4])[:, 0]) == list(at)
     assert np.abs(got - want).max() <= 5e-6
+
+
+#: one window dispatch of 32 rows a slot over a window of 256 and
+#: chunks of 16 (two ring blocks of 128 in ``eva_write``, four blocks of
+#: 16 summaries in ``eva_summarise``): each slot's cursor - inside the
+#: second window, on a window's last row (a rider there closes a chunk
+#: AND a window), on a chunk's last row, at 0, past a ring block's edge,
+#: on the third window's last row - and by case the rows it is fed
+_CURSORS = [300, 255, 47, 0, 390, 767]
+_FED = {
+    "whole_windows": [32, 32, 32, 32, 32, 32],
+    "one_riding": [32, 1, 32, 32, 32, 32],
+    "none_fed": [0, 0, 0, 0, 0, 0],
+    "ragged_chunks": [7, 32, 17, 5, 2, 30],
+    "all_riding": [1, 1, 1, 1, 1, 1],
+    "riders_beside_a_chunk_and_dead_slots": [0, 1, 1, 32, 0, 1],
+    "dead_slots_around_live_ones": [0, 0, 9, 1, 0, 0],
+}
+
+
+@pytest.fixture(scope="module")
+def before_the_window():
+    """The op's inputs and the state of six slots at ``_CURSORS``,
+    written by the XLA composition, with the reference's attention of
+    every position."""
+    B, H, d, S, T = len(_CURSORS), 2, 16, 32, 800
+    rng = np.random.default_rng(62)
+    q, k, v = (rng.standard_normal((B, H, T, d)).astype("f")
+               for _ in range(3))
+    phi, mu = (rng.standard_normal((H, d)).astype("f") for _ in range(2))
+    want = np.asarray(ref.eva_attention(
+        ref.rope(jnp.asarray(q), 1e4), ref.rope(jnp.asarray(k), 1e4),
+        jnp.asarray(v), phi, mu, 256, 16))
+    opdef = get_op("eva_attention_decode")
+    attrs = opdef.normalize_attrs(
+        {"capacity": 1024, "window": 256, "chunk": 16, "rope_base": 1e4})
+
+    def window(at, fed):
+        new = [np.full((B, H, S, d), junk, "f") for junk in (7., -9., 5.)]
+        for b, n in enumerate(fed):
+            for dst, src in zip(new, (q, k, v)):
+                dst[b, :, :n] = src[b, :, at[b]:at[b] + n]
+        return new + [jnp.asarray(fed, jnp.int32), phi, mu]
+
+    def program(fn):
+        return jax.jit(lambda ins, aux: fn(attrs, ins, aux, False, None))
+
+    programs = {variant: program(opdef.variant_fn(variant))
+                for variant in ("xla", "pallas")}
+    aux = [jnp.zeros((B, H, 256, d))] * 2 + [jnp.zeros((B, H, 64, d))] * 2 \
+        + [jnp.zeros((B, 1), jnp.int32)]
+    at = np.zeros(B, int)
+    while (at < _CURSORS).any():
+        fed = np.minimum(S, np.asarray(_CURSORS) - at)
+        _, aux = programs["xla"](window(at, fed), aux)
+        at += fed
+    return window, programs, [np.asarray(a) for a in aux], want
+
+
+@pytest.mark.parametrize("variant", ["xla", "pallas"])
+@pytest.mark.parametrize("case", sorted(_FED))
+def test_a_window_serves_each_slot_by_what_it_is_fed(
+        before_the_window, variant, case):
+    """Which form a slot takes is read from ``fed``: a window whose
+    slots are fed a whole window, one row (riding), nothing, a ragged
+    few. Both lowerings: every fed position against the reference's
+    layer, every pool and cursor the XLA composition's, and the four
+    pools of a slot fed nothing bit for bit what they were."""
+    window, programs, aux, want = before_the_window
+    fed = _FED[case]
+    ins = window(_CURSORS, fed)
+    (out,), new = programs[variant](ins, aux)
+    _, plain = programs["xla"](ins, aux)
+    for b, (at, n) in enumerate(zip(_CURSORS, fed)):
+        assert np.abs(np.asarray(out)[b, :, :n]
+                      - want[b, :, at:at + n]).max(initial=0) <= 5e-6, b
+        for old, cell in zip(aux[:4], new[:4]):
+            assert n or np.array_equal(old[b], np.asarray(cell)[b]), b
+    assert np.asarray(new[4])[:, 0].tolist() \
+        == [at + n for at, n in zip(_CURSORS, fed)]
+    for cell, same in zip(new[:2], plain[:2]):          # the rings: copies
+        assert np.array_equal(np.asarray(cell), np.asarray(same))
+    for cell, same in zip(new[2:4], plain[2:4]):        # the summaries
+        np.testing.assert_allclose(np.asarray(cell), np.asarray(same),
+                                   rtol=0, atol=2e-6)
+    assert np.isfinite(np.asarray(out)).all()
 
 
 def test_a_slot_without_room_is_fed_nothing():
@@ -179,7 +289,9 @@ def test_the_scheduler_counts_chunks_and_windows_by_arithmetic(engine):
     asked for."""
     prompts, grew, steps = blocks.counted(BLOCK, engine, (
         "eva.layer_steps", "eva.exact_rows", "eva.summary_rows",
-        "eva.chunks_summarised", "eva.windows_closed"))
+        "eva.chunks_summarised", "eva.windows_closed", "eva.window_slots",
+        "eva.ride_slots", "eva.fed_slots", "window.fed_slots",
+        "window.riding_slots"))
     layers = KW["n_layer"]
     # positions 0..n+10 of each request are fed (the last token is
     # sampled, not fed): chunks and windows by arithmetic
@@ -192,6 +304,23 @@ def test_the_scheduler_counts_chunks_and_windows_by_arithmetic(engine):
     assert any(r["eva_summary"] > 0 and r["window"] == 1 for r in steps)
     assert mx.telemetry.get_metric("serve.decode.eva.layer_steps",
                                    model=engine.name).value > 0
+    # which launches the windows' slots took, once a layer: the driver's
+    # own count of a window's fed slots and of its riders (every window
+    # of this script holds a chunk of more than one row)
+    assert grew["eva.fed_slots"] == layers * grew["window.fed_slots"] > 0
+    assert grew["eva.ride_slots"] == layers * grew["window.riding_slots"] > 0
+    assert grew["eva.window_slots"] \
+        == grew["eva.fed_slots"] - grew["eva.ride_slots"] > 0
+    # and the benchmark's metric over them, as BENCHMARK.json declares it
+    from chipbench import manifest, readers
+    cell = manifest.resolve(manifest.load(),
+                            "evabyte-6.5b-serve-longdoc-closed")
+    metric, = (m for m in cell.per_layer
+               if m.name == "eva.ride_share_of_window_slots")
+    obs = {"counters": {f"serve.decode.{k}": v for k, v in grew.items()}}
+    assert readers.read(metric, obs) == pytest.approx(
+        100.0 * grew["eva.ride_slots"] / grew["eva.fed_slots"])
+    assert readers.read(metric, {"counters": {}}) is None   # a parent's
 
 
 def test_the_older_blocks_step_programs_take_no_fed_and_donate_their_pools():
@@ -222,3 +351,36 @@ def test_the_older_blocks_step_programs_take_no_fed_and_donate_their_pools():
     tfm.BatchedKVCacheDecoder(eva_mod, CAPACITY, slots=1).step(
         np.zeros((1, 1), np.int32))
     assert held.is_deleted() and not ring.asjax().is_deleted()
+
+
+def test_eva_ride_check_rehearses(tmp_path):
+    """``tools/eva_ride_check.py``, the op's by-hand check on the chip
+    at the published sizes, at tiny sizes on the CPU: the window
+    program fed a chunk, riders and nothing against the XLA composition
+    and against the S = 1 program, and its times by what it is fed."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
+               MXNET_CRASH_DIR=str(tmp_path / "crash"))
+    env.pop("MXNET_KERNEL_TIER", None)
+    run = subprocess.run(
+        [sys.executable, os.path.join(root, "tools", "eva_ride_check.py"),
+         "--rehearse", "--times", "1"],
+        capture_output=True, text=True, env=env, cwd=root, timeout=600)
+    assert run.returncode == 0, run.stderr[-2000:] + run.stdout[-2000:]
+    line = json.loads(run.stdout.strip().splitlines()[-1])
+    assert line["riding_form"] and line["device"] == "cpu"
+    assert line["mixed"]["fed"] == [32, 1, 1, 0, 10, 1]
+    assert line["mixed"]["cursors"] == [330, 256, 129, 0, 402, 768]
+    for case in ("mixed", "chunk_and_riders", "all_riding", "all_dead"):
+        got = line[case]
+        assert got["riders_row_equals_s1_program"], case
+        assert got["riders_state_equals_s1_program"], case
+        assert got["dead_slots_pools_untouched"], case
+        assert got["rings_equal_xla"] and got["cursors_equal_xla"], case
+        assert got["out_max_abs_err_vs_xla"] <= 5e-6, case
+    assert set(line["window_program_ms_p50"]) >= {"all_dead", "chunk_alone"}
