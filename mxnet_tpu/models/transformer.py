@@ -102,11 +102,11 @@ def _packed_rows(x, fed, name, fold=(-3, 0)):
 # the stream, the ends of the graph. ``_layer`` and ``_logits`` walk
 # it; nothing below the spec constructors knows a model by its name.
 
-def _norm(x, name, spec):
+def _norm(x, name, spec, **more):
     """The block's normalisation: ``spec["norm"]``, an operation and its
-    attributes."""
+    attributes (and ``more``, a call's own)."""
     op, attrs = spec["norm"]
-    return getattr(sym, op)(x, name=name, **attrs)
+    return getattr(sym, op)(x, name=name, **attrs, **more)
 
 
 def _fused_attention(x, fed, carry, i, spec):
@@ -352,11 +352,13 @@ def _unpaired_heads(att, n_pairs, group, dh, name):
 
 
 def _nope_attention(x, fed, i, spec):
-    """Granite 4.0-H's attention layers (``spec["cfg"]``:
-    ``_granite_spec``): ``n_head`` query heads on ``num_key_value_heads``
-    K/V heads, no bias, no norm, **no positions of any kind**, the
-    scores times ``attention_multiplier`` in place of ``1 / sqrt(head
-    width)``; q, k and v the row blocks of one projection. Where two
+    """Granite 4.0-H's and Nemotron-H's attention layers
+    (``spec["cfg"]``: ``_granite_spec``, ``_nemotron_h_spec``):
+    ``n_head`` query heads on ``num_key_value_heads`` K/V heads of
+    ``head_dim`` (whatever ``d_model / n_head`` is), no bias, no norm,
+    **no positions of any kind**, the scores times
+    ``attention_multiplier`` in place of ``1 / sqrt(head width)`` where
+    the block has one; q, k and v the row blocks of one projection. Where two
     K/V heads fit a row of 128 lanes or less (the published 8 heads of
     64) the pools hold them side by side - half the heads of twice the
     width, the same bytes - and each query is widened with zeros
@@ -382,7 +384,9 @@ def _nope_attention(x, fed, i, spec):
     att = sym.attention_decode(
         heads["q"], heads["k"], heads["v"], fed, capacity=spec["capacity"],
         rope=False, per_slot=True, kv_heads=n_kv // 2 if paired else n_kv,
-        fed=True, scale=cfg["attention_multiplier"], name=f"{pfx}_attn")
+        fed=True, name=f"{pfx}_attn",
+        **({"scale": cfg["attention_multiplier"]}
+           if "attention_multiplier" in cfg else {}))
     att = sym.transpose(att, axes=(0, 2, 1, 3), name=f"{pfx}_attn_t")
     if paired:
         att = _unpaired_heads(att, n_kv // 2, n_head // n_kv, dh,
@@ -391,23 +395,28 @@ def _nope_attention(x, fed, i, spec):
 
 
 def _mamba_mixer(x, fed, i, spec):
-    """Granite 4.0-H's Mamba-2 layers: one projection of the normed
-    rows to ``[z | xBC | dt]``, the convolution and the selective state
-    update over the rows as they lie (``ssm_mixer_decode``,
-    ``ops/ssm.py``: it finds each slot's rows by ``fed`` in either view
-    and costs by the real ones), and RMSNorm with a gain over all
-    ``heads x head_dim`` gated numbers (one group); the layer's output
-    projection follows in ``_layer``."""
+    """Granite 4.0-H's and Nemotron-H's Mamba-2 layers: one projection
+    of the normed rows to ``[z | xBC | dt]``, the convolution and the
+    selective state update over the rows as they lie
+    (``ssm_mixer_decode``, ``ops/ssm.py``: it finds each slot's rows by
+    ``fed`` in either view and costs by the real ones; B and C in
+    ``mamba_n_groups`` groups), and RMSNorm of the gated numbers with a
+    gain over all ``heads x head_dim`` of them, the statistic over each
+    group's own; the layer's output projection follows in ``_layer``."""
     pfx, cfg = f"{spec['name']}_l{i}", spec["cfg"]
     H, P, N = cfg["mamba_n_heads"], cfg["mamba_d_head"], cfg["mamba_d_state"]
+    groups = cfg["mamba_n_groups"]
+    grouped = {"groups": groups} if groups > 1 else {}
     rows = sym.Reshape(x, shape=(-3, 0), name=f"{pfx}_mamba_fold")   # (B*T, D)
-    wide = sym.FullyConnected(rows, num_hidden=2 * H * P + 2 * N + H,
-                              no_bias=True, name=f"{pfx}_mamba_in")
+    wide = sym.FullyConnected(
+        rows, num_hidden=2 * H * P + 2 * groups * N + H, no_bias=True,
+        name=f"{pfx}_mamba_in")
     y = sym.ssm_mixer_decode(
         wide, fed, heads=H, head_dim=P, d_state=N,
         d_conv=cfg["mamba_d_conv"], chunk=cfg["mamba_chunk_size"],
-        step_len=spec["T"], capacity=spec["capacity"], name=f"{pfx}_mamba")
-    return _norm(y, f"{pfx}_mamba_norm", spec)
+        step_len=spec["T"], capacity=spec["capacity"], name=f"{pfx}_mamba",
+        **grouped)
+    return _norm(y, f"{pfx}_mamba_norm", spec, **grouped)
 
 
 def _hybrid_mixer(x, fed, carry, i, spec):
@@ -524,24 +533,30 @@ def _sub_layer(x, pfx, sub, spec):
 
 def _layer(x, fed, fed_rows, carry, i, spec):
     """Layer ``i`` of any block, pre-norm: ``x = x + proj(attention(
-    norm(x)))``, then ``x = x + ffn(norm(x))``, what a sub-layer reads
-    and how its output joins the stream as ``_sub_layer`` has them. In
+    norm(x)))``, then ``x = x + ffn(norm(x))`` - or the one of the two
+    that ``spec["sub_layers"][i]`` names, where a block's layers are a
+    mixer alone or a feed-forward alone (Nemotron-H) -, what a sub-layer
+    reads and how its output joins the stream as ``_sub_layer`` has
+    them. In
     a fed graph ``x`` and every row-wise operation are in the packed
     view of the window's rows (``ops/rows.py``: all ``slots x S`` of
     them, or the real ones under a budget, ``fed_rows`` their count as
     ``MoEFFN`` takes it). Returns ``(x, carry)``: what the attention
     hands its next layer."""
     pfx = f"{spec['name']}_l{i}"
-    u, join = _sub_layer(x, pfx, "proj", spec)
-    att, carry = spec["attention"](_norm(u, f"{pfx}_ln1", spec), fed,
-                                   carry, i, spec)
-    proj = sym.FullyConnected(att, num_hidden=spec["d_model"],
-                              name=f"{pfx}_proj",
-                              **({} if spec["bias"] else {"no_bias": True}))
-    x = join(proj)
-    u, join = _sub_layer(x, pfx, "ffn", spec)
-    h = _ffn(_norm(u, f"{pfx}_ln2", spec), fed_rows, i, spec)
-    return join(h), carry
+    subs = spec["sub_layers"][i] if spec["sub_layers"] else _SUB_LAYERS
+    if "proj" in subs:
+        u, join = _sub_layer(x, pfx, "proj", spec)
+        att, carry = spec["attention"](_norm(u, f"{pfx}_ln1", spec), fed,
+                                       carry, i, spec)
+        proj = sym.FullyConnected(
+            att, num_hidden=spec["d_model"], name=f"{pfx}_proj",
+            **({} if spec["bias"] else {"no_bias": True}))
+        x = join(proj)
+    if "ffn" in subs:
+        u, join = _sub_layer(x, pfx, "ffn", spec)
+        x = join(_ffn(_norm(u, f"{pfx}_ln2", spec), fed_rows, i, spec))
+    return x, carry
 
 
 def _head(x, tok_w, spec):
@@ -1020,8 +1035,9 @@ def _granite_spec(spec):
     gated SiLU of ``shared_intermediate_size`` that every token passes
     (``MoEFFN``: ``norm_topk`` over a softmax of all is the softmax
     over the chosen), of which this graph holds ``held`` (first, count;
-    default all). What this graph does not build is refused: more than
-    one group of B and C, a bias on the mixer's projections, positions,
+    default all). What this graph does not build is refused: groups of
+    B and C that do not divide the heads, a bias on the mixer's
+    projections, positions,
     a choice of no expert or of more than the router has, a share
     outside the router's width."""
     cfg = _given_keys(spec, "granite", GRANITE_KEYS)
@@ -1032,15 +1048,16 @@ def _granite_spec(spec):
             f"block='granite_hybrid': layer_types {kinds} must name "
             f"'mamba' or 'attention' for each of {n_layer} layers")
     d_in = cfg["mamba_n_heads"] * cfg["mamba_d_head"]
-    if cfg["mamba_n_groups"] != 1 or cfg["mamba_proj_bias"] \
-            or not cfg["mamba_conv_bias"] \
+    if cfg["mamba_n_groups"] < 1 \
+            or cfg["mamba_n_heads"] % cfg["mamba_n_groups"] \
+            or cfg["mamba_proj_bias"] or not cfg["mamba_conv_bias"] \
             or cfg["position_embedding_type"] != "nope" \
             or d_in != cfg["mamba_expand"] * spec["d_model"]:
         raise MXNetError(
-            "block='granite_hybrid' builds one group of B and C, a "
-            "convolution with a bias, projections without, no positions "
-            "and mamba_n_heads x mamba_d_head = mamba_expand x d_model "
-            f"(got {cfg})")
+            "block='granite_hybrid' builds groups of B and C of whole "
+            "heads, a convolution with a bias, projections without, no "
+            "positions and mamba_n_heads x mamba_d_head = mamba_expand x "
+            f"d_model (got {cfg})")
     if n_head % cfg["num_key_value_heads"] or spec["d_model"] % n_head:
         raise MXNetError(
             f"block='granite_hybrid': {n_head} query heads on "
@@ -1075,6 +1092,106 @@ def _granite_spec(spec):
         multipliers={"embedding": float(cfg["embedding_multiplier"]),
                      "residual": float(cfg["residual_multiplier"]),
                      "logits": float(cfg["logits_scaling"])})
+
+
+#: the keys of Nemotron-H's published ``config.json`` (``model_type
+#: nemotron_h``: NVIDIA-Nemotron-3-Nano) that ``block="nemotron_h"``
+#: reads (``get_decode_symbol(nemotron_h=...)``);
+#: ``hybrid_override_pattern`` one letter a layer that is run - ``M`` a
+#: Mamba-2 mixer, ``*`` attention, ``E`` routed experts, each the
+#: layer's only sub-layer -; optionally ``held``, the (first, count) of
+#: the routed experts this graph holds
+NEMOTRON_H_KEYS = ("hybrid_override_pattern", "num_key_value_heads",
+                   "head_dim", "mamba_num_heads", "mamba_head_dim",
+                   "ssm_state_size", "n_groups", "conv_kernel",
+                   "chunk_size", "use_conv_bias", "mamba_proj_bias",
+                   "mamba_hidden_act", "attention_bias", "mlp_bias",
+                   "mlp_hidden_act", "n_routed_experts",
+                   "num_experts_per_tok", "moe_intermediate_size",
+                   "moe_shared_expert_intermediate_size", "n_shared_experts",
+                   "n_group", "topk_group", "norm_topk_prob",
+                   "routed_scaling_factor")
+
+
+def _nemotron_h_spec(spec):
+    """Nemotron-H's block (per-slot only) from ``nemotron_h``, the
+    published config's keys (``NEMOTRON_H_KEYS``): **a layer is ONE
+    sub-layer**, ``x = x + Mixer_i(RMSNorm(x))``, by the layer's letter
+    of ``hybrid_override_pattern`` - no bias but the convolution's, an
+    unscaled embedding, an untied head. ``M`` is a Mamba-2 mixer with B
+    and C in ``n_groups`` groups and a gated norm whose statistic is a
+    group's own (``_mamba_mixer``; ``mamba_num_heads x mamba_head_dim``
+    need not be a multiple of ``d_model``); ``*`` is grouped attention
+    of ``head_dim`` without positions under ``1 / sqrt(head_dim)``
+    (``_nope_attention``); ``E`` is ``n_routed_experts`` ungated experts
+    ``down(relu(up x) ** 2)`` of ``moe_intermediate_size``,
+    ``num_experts_per_tok`` a token under the sigmoid router with a
+    correction bias, normalised over the chosen and times
+    ``routed_scaling_factor``, beside one shared expert of the same
+    form (``MoEFFN(act="relu2")``), of which this graph holds ``held``
+    (first, count; default all). What this graph does not build is
+    refused: a letter that is none of the three (``-``, a dense
+    feed-forward), a bias on a projection, another activation, a
+    router that limits its choice to groups of experts, more or fewer
+    shared experts than one, groups of B and C that do not divide the
+    heads, a share outside the router's width."""
+    cfg = _given_keys(spec, "nemotron_h", NEMOTRON_H_KEYS)
+    pattern, n_layer, n_head = str(cfg["hybrid_override_pattern"]), \
+        spec["n_layer"], spec["n_head"]
+    if len(pattern) != n_layer or set(pattern) - set("M*E"):
+        raise MXNetError(
+            f"block='nemotron_h': hybrid_override_pattern {pattern!r} must "
+            f"name 'M', '*' or 'E' for each of {n_layer} layers")
+    heads, groups = int(cfg["mamba_num_heads"]), int(cfg["n_groups"])
+    experts, top_k = int(cfg["n_routed_experts"]), \
+        int(cfg["num_experts_per_tok"])
+    first, count = map(int, cfg.get("held") or (0, experts))
+    if groups < 1 or heads % groups or cfg["mamba_proj_bias"] \
+            or cfg["attention_bias"] or cfg["mlp_bias"] \
+            or not cfg["use_conv_bias"] \
+            or cfg["mamba_hidden_act"] != "silu" \
+            or cfg["mlp_hidden_act"] != "relu2" \
+            or int(cfg["n_shared_experts"]) != 1 \
+            or (int(cfg["n_group"]), int(cfg["topk_group"])) != (1, 1) \
+            or not cfg["norm_topk_prob"]:
+        raise MXNetError(
+            "block='nemotron_h' builds groups of B and C of whole heads, "
+            "a convolution with a bias and SiLU, projections without a "
+            "bias, relu2 experts beside one shared expert, and a router "
+            "that chooses among all experts (n_group 1, topk_group 1) and "
+            f"normalises the chosen (got {cfg})")
+    if n_head % cfg["num_key_value_heads"]:
+        raise MXNetError(
+            f"block='nemotron_h': {n_head} query heads on "
+            f"{cfg['num_key_value_heads']} K/V heads")
+    if not 1 <= top_k <= experts or first < 0 or count < 1 \
+            or first + count > experts:
+        raise MXNetError(
+            f"block='nemotron_h': num_experts_per_tok {top_k} and held "
+            f"experts {first}..{first + count} of n_routed_experts "
+            f"{experts}")
+    # the mixers read one vocabulary of keys: Granite's
+    cfg.update(
+        held=(first, count), mamba_n_heads=heads,
+        mamba_d_head=int(cfg["mamba_head_dim"]),
+        mamba_d_state=int(cfg["ssm_state_size"]), mamba_n_groups=groups,
+        mamba_d_conv=int(cfg["conv_kernel"]),
+        mamba_chunk_size=int(cfg["chunk_size"]),
+        layer_types=[{"M": "mamba", "*": "attention", "E": "experts"}[c]
+                     for c in pattern])
+    return dict(
+        spec, cfg=cfg, norm=_rms(spec), attention=_hybrid_mixer, bias=False,
+        fed=True, pos_embed="rotary", tie_head=False, embed_scale=False,
+        sub_layers=[("ffn",) if c == "E" else ("proj",) for c in pattern],
+        dense_layers=0, moe_fold="ffn_fold",
+        moe=dict(step_len=spec["T"], num_experts=experts,
+                 num_hidden=int(cfg["moe_intermediate_size"]), top_k=top_k,
+                 norm_topk=True, scoring="sigmoid", router_bias=True,
+                 scaling=float(cfg["routed_scaling_factor"]),
+                 held_first=first, held_count=count,
+                 shared_hidden=int(
+                     cfg["moe_shared_expert_intermediate_size"]),
+                 act="relu2"))
 
 
 #: the keys of Ling-3.0's published ``config.json`` (``model_type
@@ -1172,7 +1289,8 @@ def _ling_spec(spec):
 _SPECS = {"gpt2": _gpt2_spec, "olmoe": _olmoe_spec, "evabyte": _eva_spec,
           "glm_dsa": _glm_spec, "axk1": _axk1_spec, "afmoe": _afmoe_spec,
           "xing4": _xing4_spec, "granite_hybrid": _granite_spec,
-          "ling_hybrid": _ling_spec, "sdar_moe": _sdar_spec}
+          "ling_hybrid": _ling_spec, "sdar_moe": _sdar_spec,
+          "nemotron_h": _nemotron_h_spec}
 
 
 def _spec(given, decode):
@@ -1181,7 +1299,8 @@ def _spec(given, decode):
     those, ``decode``, ``T`` (the rows a slot or sequence has in this
     graph), the defaults of what most blocks do not have (no ``fed``
     input, a plain residual add at the compute width, one head, no
-    dropout, no multipliers) and what the block's own constructor
+    dropout, no multipliers, both sub-layers in every layer) and what
+    the block's own constructor
     (``_SPECS``) makes of them and checks."""
     make = _SPECS.get(given["block"])
     if make is None:
@@ -1201,7 +1320,7 @@ def _spec(given, decode):
         # what most blocks do not have
         "fed": False, "residual": "plain", "heads": 1, "next_byte": False,
         "moe": None, "dense": None, "multipliers": None,
-        "procedure": None})
+        "procedure": None, "sub_layers": None})
 
 
 def _embedded(spec):
@@ -1306,7 +1425,7 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
                       chunk=16, n_pred_heads=1, ffn_width=None,
                       multibyte=False, glm=None, afmoe=None,
                       max_step_len=None, axk1=None, xing4=None,
-                      granite=None, ling=None, sdar=None):
+                      granite=None, ling=None, sdar=None, nemotron_h=None):
     """Incremental KV-cache decoder: ``(B, step_len)`` new token ids in,
     logits ``(B, step_len, vocab)`` out, per-layer K/V caches of
     ``capacity`` positions riding executor aux state. Parameter names
@@ -1343,9 +1462,11 @@ def get_decode_symbol(vocab_size=256, d_model=64, n_layer=2, n_head=4,
     ``XING4_KEYS``), ``"afmoe"`` (``_afmoe_spec``: ``afmoe``,
     ``AFMOE_KEYS``; ``max_step_len``), ``"granite_hybrid"``
     (``_granite_spec``: ``granite``, ``GRANITE_KEYS``),
-    ``"ling_hybrid"`` (``_ling_spec``: ``ling``, ``LING_KEYS``) and
+    ``"ling_hybrid"`` (``_ling_spec``: ``ling``, ``LING_KEYS``),
     ``"sdar_moe"`` (``_sdar_spec``: ``sdar``, ``SDAR_KEYS``; its graph
-    says that it decodes by blocks, ``decode_procedure``).
+    says that it decodes by blocks, ``decode_procedure``) and
+    ``"nemotron_h"`` (``_nemotron_h_spec``: ``nemotron_h``,
+    ``NEMOTRON_H_KEYS``; a layer is one sub-layer).
 
     Every slot-pooled graph (``per_slot=True``, whatever the block)
     takes one more input, ``fed`` ``(slots,)`` int32 - how many of each

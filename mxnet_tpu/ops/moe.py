@@ -20,6 +20,23 @@ weights a layer):
     up_weight     (E, D, F)  ``experts[e].up_proj.weight.T``
     down_weight   (E, F, D)  ``experts[e].down_proj.weight.T``
 
+**The expert's form** (``act``). ``"silu"``, the default, is the gated
+SiLU of three matrices above. ``"relu2"`` is the ungated expert of two
+(Nemotron-H): ``y = down[e](relu(x @ up[e]) ** 2)``, and the op has no
+``gate_weight`` input (a shared expert likewise: no
+``shared_gate_weight``). Both of its stacked matrices lie with the
+model's width last,
+
+    up_weight     (E, F, D)  ``experts[e].up_proj.weight`` (out, in)
+    down_weight   (E, F, D)  ``experts[e].down_proj.weight.T``
+
+because an expert's width need not be whole lanes (1,856 = 14.5 x 128)
+and the model's is: the chip lays a ``(D, 1,856)`` matrix out
+transposed, and a kernel that wants it row-major gets a copy of all the
+experts in front of every call. The first product contracts the last
+dimension of both operands (``moe_gmm_up``, one matrix, relu squared in
+its epilogue), the second is ``moe_gmm_down`` as above.
+
 ``forward`` (the XLA composition and fallback) sorts the (token,
 expert) assignments by expert and runs ``jax.lax.ragged_dot`` over
 exactly the routed rows: no expert is computed for a token not routed
@@ -104,15 +121,24 @@ def cpu_wide(*arrays):
 
 
 # ------------------------------------------------------------------ RMSNorm
-def rms_norm(x, gamma, eps, unit_offset=False, cast_to_gain=False):
+def rms_norm(x, gamma, eps, unit_offset=False, cast_to_gain=False,
+             groups=1):
     """x * rsqrt(mean(x^2) + eps) * gamma over the last axis; the
     statistics in float32 whatever the input dtype (LayerNorm's rule).
     ``unit_offset`` scales by ``1 + gamma`` (a gain stored as its
     distance from one); ``cast_to_gain`` hands the result over at the
     gain's dtype, not the input's: a float32 residual stream is
-    normalised into the compute width the matmuls behind it run at."""
+    normalised into the compute width the matmuls behind it run at.
+    ``groups`` > 1 takes the statistic over each of that many equal
+    parts of the last axis by itself, under the one gain (Mamba-2's
+    gated norm where B and C come in groups)."""
     x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    if groups > 1:
+        parts = x32.reshape(x32.shape[:-1] + (groups, -1))
+        var = jnp.repeat(jnp.mean(jnp.square(parts), axis=-1),
+                         parts.shape[-1], axis=-1)
+    else:
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     gain = gamma.astype(jnp.float32)
     out = x32 * lax.rsqrt(var + eps) * (1.0 + gain if unit_offset else gain)
     return out.astype(gamma.dtype if cast_to_gain else x.dtype)
@@ -128,14 +154,16 @@ def _rms_infer(attrs, in_shapes):
 @register("RMSNorm", inputs=("data", "gamma"),
           attr_spec={"eps": (parse_float, 1e-5),
                      "unit_offset": (parse_bool, False),
-                     "cast_to_gain": (parse_bool, False)},
+                     "cast_to_gain": (parse_bool, False),
+                     "groups": (parse_int, None)},
           infer_shape=_rms_infer)
 def _rms_norm_op(attrs, data, gamma):
     """Root-mean-square normalisation over the last axis with a gain
     and no bias (arXiv:1910.07467)."""
     return rms_norm(data, gamma, parse_float(attrs.get("eps", 1e-5)),
                     parse_bool(attrs.get("unit_offset", False)),
-                    parse_bool(attrs.get("cast_to_gain", False)))
+                    parse_bool(attrs.get("cast_to_gain", False)),
+                    parse_int(attrs.get("groups", 1)))
 
 
 # ------------------------------------------------------------------- MoEFFN
@@ -223,7 +251,7 @@ def _segment_rows(assignments):
     return max(min(assignments, 1024), assignments // 8)
 
 
-def _held_experts(x, weights, experts, real, first, count, gate, up, down,
+def _held_experts(x, weights, experts, real, first, count, mats,
                   experts_fn):
     """The held experts' part of every real token's weighted sum,
     float32 (T, D), and the held group sizes (count,); ``real`` (T,)
@@ -249,7 +277,7 @@ def _held_experts(x, weights, experts, real, first, count, gate, up, down,
         rows = lax.dynamic_slice(order, (lo,), (R,))
         token = rows // k
         part = jnp.clip(ends, lo, lo + R) - jnp.clip(starts, lo, lo + R)
-        y = experts_fn(x[token], part, gate, up, down)
+        y = experts_fn(x[token], part, *mats)
         live = (lo + jnp.arange(R, dtype=jnp.int32) < total)[:, None]
         y = jnp.where(live, y * flat_w[rows][:, None], 0.0)
         return out.at[token].add(y)
@@ -259,11 +287,21 @@ def _held_experts(x, weights, experts, real, first, count, gate, up, down,
     return out, sizes
 
 
-def _dense_expert(x, gate, up, down):
-    """One gated-SiLU expert over every row, float32 accumulation."""
+def _relu2(u):
+    return jnp.square(jnp.maximum(u, 0.0))
+
+
+def _dense_expert(x, *mats):
+    """One expert over every row, float32 accumulation: the gated SiLU
+    of ``(gate, up, down)``, or relu squared between ``(up, down)``
+    (K-major both: a shared expert's matrices)."""
     f32 = jnp.float32
-    rows, gate, up, down = cpu_wide(x, *(w.astype(x.dtype)
-                                         for w in (gate, up, down)))
+    if len(mats) == 2:
+        rows, up, down = cpu_wide(x, *(w.astype(x.dtype) for w in mats))
+        h, = cpu_wide(_relu2(jnp.dot(rows, up, preferred_element_type=f32))
+                      .astype(x.dtype))
+        return jnp.dot(h, down, preferred_element_type=f32)
+    rows, gate, up, down = cpu_wide(x, *(w.astype(x.dtype) for w in mats))
     g = jnp.dot(rows, gate, preferred_element_type=f32)
     u = jnp.dot(rows, up, preferred_element_type=f32)
     h, = cpu_wide((jax.nn.silu(g) * u).astype(x.dtype))
@@ -272,6 +310,26 @@ def _dense_expert(x, gate, up, down):
 
 _Share = namedtuple("_Share", "sigmoid bias scaling first count shared "
                     "n_group topk_group")
+
+
+def _n_mats(attrs):
+    """The matrices of one expert: three (gate, up, down) of the gated
+    SiLU, two (up, down) of ``act="relu2"``. **The layout is part of
+    the form** (module docstring, The expert's form): the gated form's
+    ``up`` lies ``(E, D, F)`` and needs F in whole lanes, the ungated
+    form's ``(E, F, D)`` - read with ``grouped_matmul(n_major=True)`` -
+    and needs F in whole sublanes alone. What forced the second layout
+    is a width that is not whole lanes (1,856), not the activation; the
+    op keys it on ``act`` because the one block with such a width is
+    the ungated one, so a gated expert of such a width is still refused
+    by the Pallas tier (``_moe_eligible``; the XLA composition runs it)
+    and an ungated one of whole lanes takes the transposed read without
+    needing it. Everything that reads the pairing asks here."""
+    act = str(attrs.get("act", "silu"))
+    if act not in ("silu", "relu2"):
+        raise ValueError(f"MoEFFN: act {act!r} is 'silu' (gated, three "
+                         "matrices) or 'relu2' (ungated, two)")
+    return 2 if act == "relu2" else 3
 
 
 def _step_len(attrs):
@@ -333,7 +391,7 @@ def moe_share_ffn(attrs, inputs, experts_fn):
     real = _real_rows(x, rest.pop(0)) if _step_len(attrs) else None
     router = rest.pop(0)
     router_bias = rest.pop(0) if share.bias else None
-    gate, up, down = rest[:3]
+    n = _n_mats(attrs)
     top_k = parse_int(attrs.get("top_k", 1))
     norm_topk = parse_bool(attrs.get("norm_topk", False))
     if share.sigmoid:
@@ -344,9 +402,9 @@ def moe_share_ffn(attrs, inputs, experts_fn):
         weights, experts = moe_route(x, router, top_k, norm_topk)
         weights = weights * share.scaling
     out, sizes = _held_experts(x, weights, experts, real, share.first,
-                               share.count, gate, up, down, experts_fn)
+                               share.count, rest[:n], experts_fn)
     if share.shared:
-        out = out + _dense_expert(x, *rest[3:6])
+        out = out + _dense_expert(x, *rest[n:2 * n])
     routed = experts.size if real is None \
         else top_k * jnp.sum(real.astype(jnp.int32))
     stats = jnp.stack([jnp.int32(1), jnp.asarray(routed, jnp.int32),
@@ -355,10 +413,19 @@ def moe_share_ffn(attrs, inputs, experts_fn):
     return [out.astype(x.dtype), experts], [stats]
 
 
-def _experts_ragged(xs, group_sizes, gate, up, down):
+def _experts_ragged(xs, group_sizes, *mats):
     """(M, D) sorted rows -> (M, D) float32: the three grouped matmuls
-    over exactly the routed rows, float32 accumulation."""
+    (two of the ungated form, whose ``up`` lies (E, F, D)) over exactly
+    the routed rows, float32 accumulation."""
     f32 = jnp.float32
+    if len(mats) == 2:
+        up, down = mats
+        u = lax.ragged_dot(xs, jnp.swapaxes(up, 1, 2).astype(xs.dtype),
+                           group_sizes, preferred_element_type=f32)
+        return lax.ragged_dot(_relu2(u).astype(xs.dtype),
+                              down.astype(xs.dtype), group_sizes,
+                              preferred_element_type=f32)
+    gate, up, down = mats
     g = lax.ragged_dot(xs, gate.astype(xs.dtype), group_sizes,
                        preferred_element_type=f32)
     u = lax.ragged_dot(xs, up.astype(xs.dtype), group_sizes,
@@ -370,14 +437,15 @@ def _experts_ragged(xs, group_sizes, gate, up, down):
 
 def moe_ffn(attrs, inputs, experts_fn):
     """The op's body with the grouped expert computation supplied:
-    ``experts_fn(xs, group_sizes, gate, up, down) -> (M, D) float32``.
-    Returns ``([out, experts], [stats])``."""
+    ``experts_fn(xs, group_sizes, *mats) -> (M, D) float32``, ``mats``
+    an expert's stacked matrices (``_n_mats``). Returns ``([out,
+    experts], [stats])``."""
     if _share_spec(attrs) is not None:
         return moe_share_ffn(attrs, inputs, experts_fn)
     x, *rest = inputs
     real = _real_rows(x, rest.pop(0)) if _step_len(attrs) else None
-    router, gate, up, down = rest
-    num_experts = gate.shape[0]
+    router, *mats = rest
+    num_experts = mats[0].shape[0]
     top_k = parse_int(attrs.get("top_k", 1))
     weights, experts = moe_route(x, router, top_k,
                                  parse_bool(attrs.get("norm_topk", False)))
@@ -390,7 +458,7 @@ def moe_ffn(attrs, inputs, experts_fn):
             jnp.where(real[:, None], experts, num_experts), num_experts + 1)
         group_sizes = group_sizes[:num_experts]
         weights = jnp.where(real[:, None], weights, 0.0)
-    y = experts_fn(x[token_of_row], group_sizes, gate, up, down)
+    y = experts_fn(x[token_of_row], group_sizes, *mats)
     if real is not None:
         # what the grouped matmuls leave in rows of no group is not read
         routed = jnp.arange(y.shape[0], dtype=jnp.int32) \
@@ -432,9 +500,14 @@ def _moe_infer(attrs, in_shapes):
     shapes.append((E, D))
     if share is not None and share.bias:
         shapes.append((E,))
-    shapes += [(held, D, F), (held, D, F), (held, F, D)]
+    if _n_mats(attrs) == 2:             # the model's width last in both
+        shapes += [(held, F, D), (held, F, D)]
+        shared_shapes = [(D, shared), (shared, D)]
+    else:
+        shapes += [(held, D, F), (held, D, F), (held, F, D)]
+        shared_shapes = [(D, shared), (D, shared), (shared, D)]
     if shared:
-        shapes += [(D, shared), (D, shared), (shared, D)]
+        shapes += shared_shapes
     return shapes, [data_s, (T, k)], stats
 
 
@@ -450,10 +523,10 @@ def _moe_inputs(attrs):
     names.append("router_weight")
     if share is not None and share.bias:
         names.append("router_bias")
-    names += ["gate_weight", "up_weight", "down_weight"]
+    mats = ["gate_weight", "up_weight", "down_weight"][-_n_mats(attrs):]
+    names += mats
     if share is not None and share.shared:
-        names += ["shared_gate_weight", "shared_up_weight",
-                  "shared_down_weight"]
+        names += ["shared_" + m for m in mats]
     return names
 
 
@@ -486,7 +559,9 @@ register("MoEFFN", inputs=_moe_inputs, aux=("moe_stats",), full=_moe_fwd,
                     "shared_hidden": (parse_int, 0),
                     "step_len": (parse_int, 0),
                     "n_group": (parse_int, 1),
-                    "topk_group": (parse_int, 1)},
+                    "topk_group": (parse_int, 1),
+                    "act": (None, None)},
          infer_shape=_moe_infer,
-         doc="Routed expert feed-forward: top_k of num_experts gated-SiLU "
-             "experts of width num_hidden per token (ops/moe.py).")
+         doc="Routed expert feed-forward: top_k of num_experts experts of "
+             "width num_hidden per token, gated SiLU or (act='relu2') "
+             "ungated relu squared (ops/moe.py).")

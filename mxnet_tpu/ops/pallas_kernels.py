@@ -1705,6 +1705,18 @@ def _cache_write(pos, news, pools, interpret, name="cache_write",
 #: a weight tile streamed from HBM is at most this many bytes: long
 #: contiguous rows at a decode step, where the kernel is bandwidth-bound
 _GMM_TILE_BYTES = 1 << 20
+#: an ``(N, bk)`` block of a weight that lies with its contracted
+#: dimension last (``grouped_matmul(n_major=True)``) is at most this
+#: many bytes: its rows are ``bk`` numbers long, not N. Set by
+#: arithmetic, not by a sweep on the chip: of K = 2,688 = 21 x 128 the
+#: lane-aligned divisors are 128, 384, 896 and 2,688, and under
+#: ``_GMM_TILE_BYTES`` an expert of N = 1,856 would be read in blocks
+#: whose rows are 128 numbers (256 B) long; 4 MB admits 896 (a block
+#: of 3.3 MB, three an expert). The one reading with it (a v5e, PR 63's
+#: traced runs): ``moe_gmm_up`` 4.62 ms an S = 1 step of 32 slots for
+#: the 3.6 GB of the experts it touched, 0.95 of the HBM peak; no other
+#: size was tried
+_GMM_NMAJOR_TILE_BYTES = 4 << 20
 #: rows of the sorted assignments a grid step multiplies, and the rows
 #: of an output tile
 _GMM_ROWS = 128
@@ -1780,10 +1792,12 @@ def _identity(y):
     return y
 
 
-def _gmm_kernel(n_rhs, window, epilogue):
+def _gmm_kernel(n_rhs, window, epilogue, n_major=False):
     """One work item's ``k`` step (``_gmm_work_items``): the window's
     rows times the group's ``(bk, N)`` block of each of ``n_rhs``
-    stacked weights, float32 accumulation over the k steps; at the last
+    stacked weights (``n_major``: its ``(N, bk)`` block, contracted
+    over the last dimension of both), float32 accumulation over the k
+    steps; at the last
     one ``epilogue`` of the accumulators goes to the item's rows of the
     output tile its first row lies in, granule by granule, and to no
     other row. Where the item's rows reach into the next tile, the next
@@ -1830,8 +1844,11 @@ def _gmm_kernel(n_rhs, window, epilogue):
         def _accumulate():
             x = x_ref[...]
             for w_ref, acc in zip(w_refs, accs):
-                part = jnp.dot(x, w_ref[...],
-                               preferred_element_type=jnp.float32)
+                part = jax.lax.dot_general(
+                    x, w_ref[...], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) if n_major \
+                    else jnp.dot(x, w_ref[...],
+                                 preferred_element_type=jnp.float32)
                 # the first step finds whatever the accumulator held
                 acc[...] = jnp.where(kk == 0, part, acc[...] + part)
 
@@ -1841,11 +1858,11 @@ def _gmm_kernel(n_rhs, window, epilogue):
     return kernel
 
 
-def _gmm_block_k(K, N, itemsize):
+def _gmm_block_k(K, N, itemsize, tile_bytes=_GMM_TILE_BYTES):
     """Rows of a (block_k, N) weight tile: the largest divisor of K
-    that is a multiple of 128 and keeps the tile within
-    ``_GMM_TILE_BYTES``; K itself where it has no such divisor."""
-    cap = max(128, _GMM_TILE_BYTES // (N * itemsize))
+    that is a multiple of 128 and keeps the tile within ``tile_bytes``;
+    K itself where it has no such divisor."""
+    cap = max(128, tile_bytes // (N * itemsize))
     for b in range(min(K, cap) // 128 * 128, 0, -128):
         if K % b == 0:
             return b
@@ -1853,12 +1870,15 @@ def _gmm_block_k(K, N, itemsize):
 
 
 def grouped_matmul(x, weights, items, window, epilogue, out_dtype, name,
-                   interpret):
+                   interpret, n_major=False):
     """``epilogue(x @ w[g] for w in weights)`` row group by row group.
 
     ``x`` (rows, K) holds the sorted rows, whole granules of them;
-    ``weights`` are stacked (E, K, N) matrices read K-major; ``items``
-    is ``_gmm_work_items``' pair. A work item is a chunk of one group's
+    ``weights`` are stacked (E, K, N) matrices read K-major - or, with
+    ``n_major``, (E, N, K) matrices read in ``(N, bk)`` blocks of up to
+    ``_GMM_NMAJOR_TILE_BYTES`` (a block's rows are ``bk`` numbers long,
+    so they are kept long) and contracted over their last dimension;
+    ``items`` is ``_gmm_work_items``' pair. A work item is a chunk of one group's
     own rows - ``window`` less a granule of them, counted from the
     group's first row, wherever that lies - so **each ``(bk, N)`` block
     of a group's weights crosses HBM once for every such chunk the
@@ -1876,9 +1896,10 @@ def grouped_matmul(x, weights, items, window, epilogue, out_dtype, name,
     from jax.experimental.pallas import tpu as pltpu
 
     rows, K = x.shape
-    N = weights[0].shape[2]
+    N = weights[0].shape[1 if n_major else 2]
     itemsize = weights[0].dtype.itemsize
-    bk = _gmm_block_k(K, N, itemsize)
+    bk = _gmm_block_k(K, N, itemsize, _GMM_NMAJOR_TILE_BYTES if n_major
+                      else _GMM_TILE_BYTES)
     n_k = K // bk
     tiles_m = -(-rows // window)
     table, n_items = items
@@ -1887,10 +1908,17 @@ def grouped_matmul(x, weights, items, window, epilogue, out_dtype, name,
         # the item behind the last stays on the block that one held
         return jnp.where(i < n[0], kk, n_k - 1)
 
+    # a K without a divisor of whole lanes (an expert of 1,856) is one
+    # block, which the compiler takes as the whole dimension alone
+    whole_k = bk % 128 != 0
+
     def x_map(i, kk, item, n):
-        return item[1, i] * _GMM_GRANULE, k_of(i, kk, n) * bk
+        return item[1, i] * _GMM_GRANULE, \
+            0 if whole_k else k_of(i, kk, n) * bk
 
     def w_map(i, kk, item, n):
+        if n_major:
+            return item[0, i], 0, k_of(i, kk, n)
         return item[0, i], k_of(i, kk, n), 0
 
     def o_map(i, kk, item, n):
@@ -1899,7 +1927,8 @@ def grouped_matmul(x, weights, items, window, epilogue, out_dtype, name,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(n_items[0] + 1, n_k),
         in_specs=[pl.BlockSpec((pl.Element(window), pl.Element(bk)), x_map)]
-        + [pl.BlockSpec((None, bk, N), w_map) for _ in weights],
+        + [pl.BlockSpec((None, N, bk) if n_major else (None, bk, N), w_map)
+           for _ in weights],
         out_specs=pl.BlockSpec((window, N), o_map),
         scratch_shapes=[pltpu.VMEM((window, N), jnp.float32)
                         for _ in weights])
@@ -1912,38 +1941,61 @@ def grouped_matmul(x, weights, items, window, epilogue, out_dtype, name,
         "compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=4 * resident)}
     return pallas_call(
-        _gmm_kernel(len(weights), window, epilogue),
+        _gmm_kernel(len(weights), window, epilogue, n_major),
         out_shape=jax.ShapeDtypeStruct((tiles_m * window, N), out_dtype),
         grid_spec=grid_spec, name=name, interpret=interpret,
         **kwargs)(table, n_items, x, *weights)
 
 
-def grouped_expert_ffn(xs, group_sizes, gate, up, down):
+def grouped_expert_ffn(xs, group_sizes, *mats):
     """The experts of ``MoEFFN`` over the sorted assignment rows:
     ``xs`` (M, D) in the compute dtype, expert ``e`` owning
-    ``group_sizes[e]`` consecutive rows -> (M, D) float32. Two kernels:
-    ``moe_gmm_gate_up`` (both matmuls over one read of the rows, SiLU
-    gate fused) and ``moe_gmm_down``. An expert's weights are read once
-    a call and kernel wherever its rows lie - once more for every 112
-    rows past the first 112 (``grouped_matmul``) - and an expert without
-    rows is not read. Rows past ``sum(group_sizes)`` come out undefined.
+    ``group_sizes[e]`` consecutive rows -> (M, D) float32. Two kernels.
+    Of the gated form's ``(gate, up, down)``: ``moe_gmm_gate_up`` (both
+    matmuls over one read of the rows, SiLU gate fused) and
+    ``moe_gmm_down``. Of the ungated form's ``(up, down)``, ``up``
+    (E, F, D) (ops/moe.py, **The expert's form**): ``moe_gmm_up`` (one
+    matmul, relu squared fused) and ``moe_gmm_down``. An expert's
+    weights are read once a call and kernel wherever its rows lie - once
+    more for every 112 rows past the first 112 (``grouped_matmul``) -
+    and an expert without rows is not read. Rows past
+    ``sum(group_sizes)`` come out undefined.
 
     The pair is a jitted function of its own, so that a step program
     lowers the two kernels once and calls them from every layer."""
-    return _grouped_expert_ffn(xs, group_sizes, gate, up, down,
-                               interpret=_interpret())
+    ffn = _grouped_ungated_ffn if len(mats) == 2 else _grouped_expert_ffn
+    return ffn(xs, group_sizes, *mats, interpret=_interpret())
+
+
+def _gmm_rows(xs, group_sizes):
+    """The sorted rows padded to whole granules, the work items over
+    them and the rows of a window."""
+    M = xs.shape[0]
+    rows, window = _gmm_geometry(M, group_sizes.shape[0])[:2]
+    if rows != M:
+        xs = jnp.pad(xs, ((0, rows - M), (0, 0)))
+    return xs, _gmm_work_items(group_sizes.astype(jnp.int32), M), window
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def _grouped_expert_ffn(xs, group_sizes, gate, up, down, interpret):
     M = xs.shape[0]
-    rows, window = _gmm_geometry(M, group_sizes.shape[0])[:2]
-    if rows != M:
-        xs = jnp.pad(xs, ((0, rows - M), (0, 0)))
-    items = _gmm_work_items(group_sizes.astype(jnp.int32), M)
+    xs, items, window = _gmm_rows(xs, group_sizes)
     h = grouped_matmul(
         xs, (gate.astype(xs.dtype), up.astype(xs.dtype)), items, window,
         _silu_gate, xs.dtype, "moe_gmm_gate_up", interpret)
+    y = grouped_matmul(h, (down.astype(xs.dtype),), items, window,
+                       _identity, jnp.float32, "moe_gmm_down", interpret)
+    return y[:M]
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _grouped_ungated_ffn(xs, group_sizes, up, down, interpret):
+    from .moe import _relu2
+    M = xs.shape[0]
+    xs, items, window = _gmm_rows(xs, group_sizes)
+    h = grouped_matmul(xs, (up.astype(xs.dtype),), items, window, _relu2,
+                       xs.dtype, "moe_gmm_up", interpret, n_major=True)
     y = grouped_matmul(h, (down.astype(xs.dtype),), items, window,
                        _identity, jnp.float32, "moe_gmm_down", interpret)
     return y[:M]
@@ -1957,17 +2009,21 @@ def _moe_variant(attrs, inputs, aux, is_train, rng):
 def _moe_eligible(attrs, in_shapes, in_dtypes):
     """Lane-aligned widths (any in interpret mode), float rows, and
     weight tiles of full output width within the declared tile set."""
-    # the gate weights follow the router's (fed before it and its bias
-    # behind it, if any)
-    gate = next((s for s in in_shapes[2:5] if len(s) == 3), None)
-    if len(in_shapes) < 5 or len(in_shapes[0]) != 2 or gate is None:
+    # the first of an expert's matrices follows the router's (fed
+    # before it and its bias behind it, if any)
+    first = next((s for s in in_shapes[2:5] if len(s) == 3), None)
+    if len(in_shapes) < 4 or len(in_shapes[0]) != 2 or first is None:
         return False
     if str(in_dtypes[0]) not in ("float32", "bfloat16", "float16"):
         return False
-    D, F = gate[1], gate[2]
+    # the ungated form's matrices lie (E, F, D) both: an expert's width
+    # is a whole block of either product, whole sublanes of it
+    ungated = str(attrs.get("act", "silu")) == "relu2"
+    D, F = first[1:][::-1] if ungated else first[1:]
     if max(D, F) > _GMM_MAX_WIDTH:
         return False
-    return (D % 128 == 0 and F % 128 == 0) or _interpret()
+    return (D % 128 == 0 and F % (16 if ungated else 128) == 0) \
+        or _interpret()
 
 
 #: worst case at the eligibility bounds (widths <= ``_GMM_MAX_WIDTH``,

@@ -4,17 +4,21 @@ last ``d_conv`` inputs and the selective state update (state-space
 duality, arXiv:2405.21060), for slot-pooled serving.
 
 Per token ``t`` of one sequence, ``H`` heads of ``P`` channels, a state
-of ``N`` numbers a channel, one group (B and C shared by the heads), the
-row ``[z_t | xBC_t | dt_t]`` being the mixer's input projection:
+of ``N`` numbers a channel, B and C in ``groups`` groups of ``N`` (head
+``h`` reads group ``h // (H / groups)``: one group, Granite 4.0-H's, is
+B and C shared by all heads; Nemotron-H has eight), the row ``[z_t |
+xBC_t | dt_t]`` being the mixer's input projection:
 
     c_t   = silu(sum_k w_conv[:, k] * xBC_{t-(K-1)+k} + b_conv)
-    [x_t | B_t | C_t] = c_t
+    [x_t | B_t | C_t] = c_t                    B_t, C_t: (groups, N)
     dlt_t = softplus(dt_t + dt_bias)           a_t = exp(dlt_t * -exp(A_log))
-    H_t[h] = a_t[h] H_{t-1}[h] + dlt_t[h] x_t[h] (outer) B_t
-    y_t[h] = H_t[h] C_t + D[h] x_t[h]          out_t = y_t * silu(z_t)
+    H_t[h] = a_t[h] H_{t-1}[h] + dlt_t[h] x_t[h] (outer) B_t[group of h]
+    y_t[h] = H_t[h] C_t[group of h] + D[h] x_t[h]
+    out_t = y_t * silu(z_t)
 
 all of it in float32 whatever the rows' dtype. The gated norm and the
-output projection stay in the graph.
+output projection stay in the graph. ``groups`` 1 is the op as it was
+before it had the attribute, to the lowered text.
 
 **State**, slot-pooled, two families no cursor indexes (``slot_state``):
 
@@ -24,7 +28,8 @@ output projection stay in the graph.
                with N down the sublanes and ``W = P x`` as many heads as
                fill 128 lanes across (channel ``c = h P + p`` at ``[c //
                W, :, c % W]``), so that the read-out ``H C`` is a sum
-               down sublanes and lands lane-dense
+               down sublanes and lands lane-dense; the heads of a lane
+               group lie inside one group of B and C (``lane_width``)
     cache_pos  (slots, 1)              int32     family "cursor"
 
 Nothing of it is read by position: **a slot whose cursor is 0 at the
@@ -67,6 +72,8 @@ their own names.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -86,11 +93,12 @@ _F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
 
 
-def lane_width(heads, head_dim):
+def lane_width(heads, head_dim, groups=1):
     """W of the state's layout: ``head_dim`` times the most heads (a
-    divisor of ``heads``) that lie side by side in 128 lanes."""
+    divisor of the heads of one group of B and C) that lie side by side
+    in 128 lanes."""
     pack = max(1, 128 // head_dim)
-    while heads % pack:
+    while (heads // groups) % pack:
         pack -= 1
     return pack * head_dim
 
@@ -103,6 +111,16 @@ def _geometry(attrs):
         raise MXNetError(f"ssm_mixer_decode: sizes {geo} (heads, head_dim, "
                          "d_state, d_conv >= 2, chunk, step_len, capacity)")
     return geo
+
+
+def _groups(attrs):
+    """The groups of B and C, whole numbers of heads each."""
+    groups, heads = parse_int(attrs.get("groups", 1)), parse_int(
+        attrs["heads"])
+    if groups < 1 or heads % groups:
+        raise MXNetError(f"ssm_mixer_decode: {groups} groups of B and C "
+                         f"over {heads} heads")
+    return groups
 
 
 def _dot(a, b):
@@ -187,7 +205,8 @@ def _prologue(attrs, inputs, aux, is_train):
     data, fed, conv_w, conv_b, dt_bias, a_log, _d = inputs
     tail, _state, cursor = aux
     H, P, N, K, _chunk, S, capacity = _geometry(attrs)
-    d_in, C = H * P, H * P + 2 * N
+    groups = _groups(attrs)
+    d_in, C = H * P, H * P + 2 * groups * N
     NR = data.shape[0]
     lay = fed_rows_layout(fed, cursor, NR, S, capacity)
     pos, fed, off, idx, valid = (lay[k] for k in (
@@ -203,13 +222,14 @@ def _prologue(attrs, inputs, aux, is_train):
     A = -jnp.exp(a_log.astype(_F32))                         # (H,)
     # one array of everything a row brings to the recurrence
     feat = jnp.concatenate([act, dlt], axis=1)     # [x | B | C | dlt]
-    return dict(P=P, N=N, S=S, d_in=d_in, NR=NR, pos=pos, fed=fed, off=off,
-                idx=idx, feat=feat, A=A, new_tail=new_tail)
+    return dict(P=P, BC=groups * N, S=S, d_in=d_in, NR=NR, pos=pos, fed=fed,
+                off=off, idx=idx, feat=feat, A=A, new_tail=new_tail)
 
 
-def _split(feat, d_in, N):
-    return (feat[:, :d_in], feat[:, d_in:d_in + N],
-            feat[:, d_in + N:d_in + 2 * N], feat[:, d_in + 2 * N:])
+def _split(feat, d_in, BC):
+    """``[x | B | C | dlt]``, B and C ``BC = groups x N`` wide."""
+    return (feat[:, :d_in], feat[:, d_in:d_in + BC],
+            feat[:, d_in + BC:d_in + 2 * BC], feat[:, d_in + 2 * BC:])
 
 
 def _step_operands(p):
@@ -221,33 +241,45 @@ def _step_operands(p):
     one = p["fed"] == 1
     lay = (p["idx"][None, :] == p["off"][:, None]) & one[:, None]
     lay = lay.astype(_F32)                                   # (slots, NR)
-    x, B, C, dlt = _split(_dot(lay, p["feat"]), p["d_in"], p["N"])
+    x, B, C, dlt = _split(_dot(lay, p["feat"]), p["d_in"], p["BC"])
     a = jnp.repeat(jnp.exp(dlt * p["A"][None, :]), p["P"], axis=1)
     u = jnp.repeat(dlt, p["P"], axis=1) * x
     return a, u, B, C, lay
 
 
-def _update_xla(state, pos, a, u, B, C):
+def _update_xla(state, pos, a, u, B, C, groups=1):
     """One step of the recurrence for every slot, a slot at cursor 0
     from zeros: ``(state', y)``."""
     slots, G, N, W = state.shape
     h0 = jnp.where((pos == 0)[:, None, None, None], 0.0, state)
+    if groups > 1:                      # each lane group's own B and C
+        B, C = (jnp.repeat(v.reshape(slots, groups, N), G // groups, axis=1)
+                for v in (B, C))
+        spread = lambda v: v[:, :, :, None]                  # noqa: E731
+    else:
+        spread = lambda v: v[:, None, :, None]               # noqa: E731
     hn = a.reshape(slots, G, 1, W) * h0 \
-        + B[:, None, :, None] * u.reshape(slots, G, 1, W)
-    y = jnp.sum(hn * C[:, None, :, None], axis=2)            # (slots, G, W)
+        + spread(B) * u.reshape(slots, G, 1, W)
+    y = jnp.sum(hn * spread(C), axis=2)                      # (slots, G, W)
     return hn, y.reshape(slots, G * W)
 
 
 def ssm_recurrence(x, dlt, A, B, C, h0):
     """The recurrence of the module docstring, step by step, for one
     sequence: ``x (T, H, P)``, ``dlt (T, H)``, ``A (H,)``, ``B``, ``C``
-    ``(T, N)``, ``h0 (H, P, N)`` -> ``(y (T, H, P) without the D term,
-    the last state)``. What the chunked form is tested against."""
+    ``(T, N)`` - or ``(T, groups, N)``, head ``h`` reading group ``h //
+    (H / groups)`` -, ``h0 (H, P, N)`` -> ``(y (T, H, P) without the D
+    term, the last state)``. What the chunked form is tested against."""
+    H = x.shape[1]
+    if B.ndim == 2:
+        B, C = B[:, None], C[:, None]
+    B, C = (jnp.repeat(v, H // v.shape[1], axis=1) for v in (B, C))
+
     def step(h, row):
-        x_t, d_t, b_t, c_t = row
+        x_t, d_t, b_t, c_t = row                             # b, c: (H, N)
         h = jnp.exp(d_t * A)[:, None, None] * h \
-            + (d_t[:, None] * x_t)[:, :, None] * b_t[None, None, :]
-        return h, jnp.sum(h * c_t[None, None, :], axis=-1)
+            + (d_t[:, None] * x_t)[:, :, None] * b_t[:, None, :]
+        return h, jnp.sum(h * c_t[:, None, :], axis=-1)
     h, y = lax.scan(step, h0.astype(_F32), (x, dlt, B, C))
     return y, h
 
@@ -259,26 +291,41 @@ def _log_decays(tri, dlt, A):
     return _dot(tri.astype(_F32), dlt * A[None, :])
 
 
-def _chunk_step(x, dlt, A, B, C, hb):
+def _chunk_step(x, dlt, A, B, C, hb, groups=1):
     """One chunk of one slot in the chunked form: ``x (Q, H P)``, ``dlt
-    (Q, H)`` (0 on a pad), ``B``, ``C`` ``(Q, N)``, the incoming state
-    ``hb (G, N, W)`` -> ``(y (Q, H P), the state after the chunk)``."""
+    (Q, H)`` (0 on a pad), ``B``, ``C`` ``(Q, groups N)``, the incoming
+    state ``hb (G, N, W)`` -> ``(y (Q, H P), the state after the
+    chunk)``. ``C B^T`` is made once a group of B and C."""
     Q, H = dlt.shape
     P = x.shape[1] // H
-    G, _N, W = hb.shape
+    G, N, W = hb.shape
     tri = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
     cs = _log_decays(tri, dlt, A)                            # (Q, H)
     u = (dlt[:, :, None] * x.reshape(Q, H, P))               # (Q, H, P)
     diff = cs.T[:, :, None] - cs.T[:, None, :]               # (H, i, j)
     decay = jnp.exp(jnp.where(tri[None], diff, -jnp.inf))
-    scores = jnp.einsum("in,jn->ij", C, B, precision=_HI)
-    y = jnp.einsum("hij,jhp->ihp", scores[None] * decay, u, precision=_HI)
+    if groups > 1:
+        B, C = B.reshape(Q, groups, N), C.reshape(Q, groups, N)
+        lanes = (groups, G // groups)   # a group's lane groups
+        scores = jnp.repeat(jnp.einsum("ibn,jbn->bij", C, B, precision=_HI),
+                            H // groups, axis=0)             # (H, i, j)
+        read = lambda: jnp.einsum(                           # noqa: E731
+            "qbn,blnw->qblw", C, hb.reshape(lanes + (N, W)), precision=_HI)
+        wrote = lambda uw: jnp.einsum(                       # noqa: E731
+            "qbn,qblw->blnw", B, uw.reshape((Q,) + lanes + (W,)),
+            precision=_HI).reshape(G, N, W)
+    else:
+        scores = jnp.einsum("in,jn->ij", C, B, precision=_HI)[None]
+        read = lambda: jnp.einsum("qn,gnw->qgw", C, hb,      # noqa: E731
+                                  precision=_HI)
+        wrote = lambda uw: jnp.einsum("qn,qgw->gnw", B, uw,  # noqa: E731
+                                      precision=_HI)
+    y = jnp.einsum("hij,jhp->ihp", scores * decay, u, precision=_HI)
     y = y.reshape(Q, H * P) + jnp.repeat(jnp.exp(cs), P, axis=1) \
-        * jnp.einsum("qn,gnw->qgw", C, hb, precision=_HI).reshape(Q, G * W)
+        * read().reshape(Q, G * W)
     last = cs[-1]
     uw = (u * jnp.exp(last[None, :] - cs)[:, :, None]).reshape(Q, G, W)
-    hn = jnp.repeat(jnp.exp(last), P).reshape(G, 1, W) * hb \
-        + jnp.einsum("qn,qgw->gnw", B, uw, precision=_HI)
+    hn = jnp.repeat(jnp.exp(last), P).reshape(G, 1, W) * hb + wrote(uw)
     return y, hn
 
 
@@ -286,7 +333,7 @@ def _scan(p, state, y, chunk, chunk_step):
     """The chunked form for every slot fed more than one row: one trip
     of ``chunk_step`` a chunk, slot after slot, ``state`` and the rows'
     results ``y (NR, H P)`` updated in place."""
-    Q, NR, d_in, N = chunk, p["NR"], p["d_in"], p["N"]
+    Q, NR, d_in, BC = chunk, p["NR"], p["d_in"], p["BC"]
     fed, off = p["fed"], p["off"]
     trips = jnp.where(fed > 1, (fed + Q - 1) // Q, 0)
     ends = jnp.cumsum(trips)
@@ -302,7 +349,7 @@ def _scan(p, state, y, chunk, chunk_step):
         real = i * Q + at < _at(fed, b)
         x, B, C, dlt = _split(
             lax.dynamic_slice(feat, (start, 0), (Q, feat.shape[1])),
-            d_in, N)
+            d_in, BC)
         y_new, hn = chunk_step(x, jnp.where(real[:, None], dlt, 0.0),
                                p["A"], B, C, _at(state, b))
         old = lax.dynamic_slice(y, (start, 0), (Q, d_in))
@@ -316,6 +363,10 @@ def _scan(p, state, y, chunk, chunk_step):
 
 
 def _forward(attrs, inputs, aux, is_train, update, chunk_step):
+    groups = _groups(attrs)
+    if groups > 1:
+        update, chunk_step = (partial(f, groups=groups)
+                              for f in (update, chunk_step))
     with jax.named_scope("ssm_conv"):
         p = _prologue(attrs, inputs, aux, is_train)
         a, u, B, C, lay = _step_operands(p)
@@ -355,14 +406,28 @@ _UPDATE_BLOCK = 1 << 20
 _VMEM_LIMIT = 32 << 20
 
 
-def _update_kernel(gb):
+def _update_kernel(gb, per=0):
     """Grid (slot, block of ``gb`` lane groups): ``H <- a H + B u`` and
     ``y = sum_n H C`` for every channel of the block, N down the
-    sublanes; a slot at cursor 0 reads zeros."""
+    sublanes; a slot at cursor 0 reads zeros. ``per`` 0: one group of B
+    and C, spread along the lanes; else the block's groups of B and C
+    arrive a row each, ``per`` lane groups read one, and the row is
+    stood up as a column by a sum along the diagonal."""
     def kernel(pos_ref, s_ref, a_ref, u_ref, b_ref, c_ref, so_ref, y_ref):
         fresh = pos_ref[pl.program_id(0)] == 0
-        bb, cb = b_ref[...], c_ref[...]                      # (N, W)
+        if per:
+            N = s_ref.shape[1]
+            diag = lax.broadcasted_iota(jnp.int32, (N, N), 0) \
+                == lax.broadcasted_iota(jnp.int32, (N, N), 1)
+
+            def column(ref, j):                              # (N, 1)
+                return jnp.sum(jnp.where(diag, ref[j], 0.0), axis=1,
+                               keepdims=True)
+        else:
+            bb, cb = b_ref[...], c_ref[...]                  # (N, W)
         for g in range(gb):
+            if per and g % per == 0:
+                bb, cb = column(b_ref, g // per), column(c_ref, g // per)
             h0 = jnp.where(fresh, 0.0, s_ref[g])
             hn = a_ref[g:g + 1, :] * h0 + bb * u_ref[g:g + 1, :]
             so_ref[g] = hn
@@ -370,13 +435,17 @@ def _update_kernel(gb):
     return kernel
 
 
-def _update_pallas(state, pos, a, u, B, C):
+def _update_pallas(state, pos, a, u, B, C, groups=1):
     """``_update_xla`` as the kernel ``ssm_update``: a slot's state
-    through VMEM once, in place (``B`` and ``C`` arrive spread along the
-    lanes, 3 % of the state's bytes)."""
+    through VMEM once, in place (one group of ``B`` and ``C`` arrives
+    spread along the lanes, 3 % of the state's bytes; several arrive as
+    they are, a row a group)."""
     slots, G, N, W = state.shape
-    gb = _pk._divisor_block(G, max(1, _UPDATE_BLOCK // (N * W * 4)))
-    spread = lambda v: jnp.broadcast_to(v[:, :, None], (slots, N, W))  # noqa
+    per = G // groups                   # lane groups that read one group
+    cap = max(1, _UPDATE_BLOCK // (N * W * 4))
+    # a block is whole groups of B and C, or lies inside one
+    gb = max(d for d in range(1, min(G, cap) + 1)
+             if G % d == 0 and (d % per == 0 or per % d == 0))
 
     def block(b, g, pos_ref):
         return b, g, 0, 0
@@ -384,40 +453,49 @@ def _update_pallas(state, pos, a, u, B, C):
     def group(b, g, pos_ref):
         return b, g, 0
 
-    def slot(b, g, pos_ref):
-        return b, 0, 0
-
     st = pl.BlockSpec((None, gb, N, W), block)
     vec = pl.BlockSpec((None, gb, W), group)
-    wide = pl.BlockSpec((None, N, W), slot)
+    if groups > 1:
+        nb = max(1, gb // per)          # the block's groups of B and C
+        wide = pl.BlockSpec((None, nb, 1, N),
+                            lambda b, g, pos_ref: (b, g * gb // per // nb,
+                                                   0, 0))
+        B, C = (v.reshape(slots, groups, 1, N) for v in (B, C))
+        kernel = _update_kernel(gb, per)
+    else:
+        wide = pl.BlockSpec((None, N, W), lambda b, g, pos_ref: (b, 0, 0))
+        B, C = (jnp.broadcast_to(v[:, :, None], (slots, N, W))
+                for v in (B, C))
+        kernel = _update_kernel(gb)
     kwargs = {} if _pk._interpret() else {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT)}
     state, y = _pk.pallas_call(
-        _update_kernel(gb), name="ssm_update",
+        kernel, name="ssm_update",
         out_shape=(jax.ShapeDtypeStruct(state.shape, _F32),
                    jax.ShapeDtypeStruct((slots, G, W), _F32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(slots, G // gb),
             in_specs=[st, vec, vec, wide, wide], out_specs=(st, vec)),
         input_output_aliases={1: 0}, **kwargs)(
-            pos, state, a.reshape(slots, G, W), u.reshape(slots, G, W),
-            spread(B), spread(C))
+            pos, state, a.reshape(slots, G, W), u.reshape(slots, G, W), B, C)
     return state, y.reshape(slots, G * W)
 
 
-def _chunk_kernel(P):
+def _chunk_kernel(P, per=0):
     """Grid (lane group): ``_chunk_step`` for the ``W / P`` heads whose
     channels lie in the group's W lanes. ``C B^T`` is made once, at the
-    first group; a head's running log decay arrives as a row ``(1, Q)``
-    and is stood up as a column by a sum along the diagonal."""
+    first group (``per`` > 0: at the first of every ``per`` lane groups,
+    which read one group of B and C); a head's running log decay arrives
+    as a row ``(1, Q)`` and is stood up as a column by a sum along the
+    diagonal."""
     def kernel(x_ref, cs_ref, dl_ref, bt_ref, c_ref, h_ref, y_ref, hn_ref,
                scores_ref):
         g = pl.program_id(0)
         Q, W = x_ref.shape
 
-        @pl.when(g == 0)
+        @pl.when((g % per if per else g) == 0)
         def _():
             scores_ref[...] = _dot(c_ref[...], bt_ref[...])
 
@@ -452,23 +530,33 @@ def _chunk_kernel(P):
     return kernel
 
 
-def _chunk_pallas(x, dlt, A, B, C, hb):
+def _chunk_pallas(x, dlt, A, B, C, hb, groups=1):
     """``_chunk_step`` as the kernel ``ssm_scan``: a lane group of the
     chunk's rows and of the state through VMEM a grid step."""
     Q, H = dlt.shape
     G, N, W = hb.shape
+    per = G // groups if groups > 1 else 0
     tri = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
     cs = _log_decays(tri, dlt, A)                            # (Q, H)
-    whole = lambda a: pl.BlockSpec(a.shape, lambda g: (0, 0))  # noqa: E731
+    if per:                             # a lane group's own B^T and C
+        Bt = B.reshape(Q, groups, N).transpose(1, 2, 0)
+        C = C.reshape(Q, groups, N).transpose(1, 0, 2)
+        whole = lambda a: pl.BlockSpec(                      # noqa: E731
+            a.shape if a.ndim == 2 else (None,) + a.shape[1:],
+            (lambda g: (0, 0)) if a.ndim == 2
+            else (lambda g: (g // per, 0, 0)))
+    else:
+        Bt = B.T
+        whole = lambda a: pl.BlockSpec(a.shape, lambda g: (0, 0))  # noqa
     rows = pl.BlockSpec((Q, W), lambda g: (0, g))
     cell = pl.BlockSpec((None, N, W), lambda g: (g, 0, 0))
-    operands = (x, cs.T, dlt.T, B.T, C, hb)
+    operands = (x, cs.T, dlt.T, Bt, C, hb)
     kwargs = {} if _pk._interpret() else {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT)}
     return _pk.pallas_call(
-        _chunk_kernel(x.shape[1] // H), name="ssm_scan",
+        _chunk_kernel(x.shape[1] // H, per), name="ssm_scan",
         out_shape=(jax.ShapeDtypeStruct(x.shape, _F32),
                    jax.ShapeDtypeStruct(hb.shape, _F32)),
         grid=(G,),
@@ -494,17 +582,18 @@ def _ssm_eligible(attrs, in_shapes, in_dtypes):
 def _ssm_infer(attrs, in_shapes):
     data_s, fed_s = in_shapes[:2]
     H, P, N, K, _chunk, S, _capacity = _geometry(attrs)
-    d_in, C = H * P, H * P + 2 * N
+    groups = _groups(attrs)
+    d_in, C = H * P, H * P + 2 * groups * N
     if data_s is None:
         return in_shapes, [None], [None] * 3
-    if len(data_s) != 2 or data_s[1] != 2 * d_in + 2 * N + H:
+    if len(data_s) != 2 or data_s[1] != d_in + C + H:
         raise ValueError(f"ssm_mixer_decode: rows {data_s} are not (rows, "
-                         f"[z | xBC | dt] = {2 * d_in + 2 * N + H})")
+                         f"[z | xBC | dt] = {d_in + C + H})")
     params = [(C, K), (C,), (H,), (H,), (H,)]
     out = [(data_s[0], d_in)]
     if fed_s is None:                   # fed alone says how many slots
         return [data_s, None] + params, out, [None] * 3
-    slots, W = fed_s[0], lane_width(H, P)
+    slots, W = fed_s[0], lane_width(H, P, groups)
     return ([data_s, fed_s] + params, out,
             [(slots, K - 1, C), (slots, d_in // W, N, W), (slots, 1)])
 
@@ -543,9 +632,9 @@ register("ssm_mixer_decode",
          aux_dtypes={"conv_tail": "float32", "ssm_state": "float32",
                      "cache_pos": "int32"},
          infer_shape=_ssm_infer,
-         attr_spec={k: (parse_int, None) for k in (
+         attr_spec={**{k: (parse_int, None) for k in (
              "heads", "head_dim", "d_state", "d_conv", "chunk", "step_len",
-             "capacity")},
+             "capacity")}, "groups": (parse_int, None)},
          slot_state=SSM_SLOT_STATE, state_reads=(_SSM_COUNTS, _ssm_reads),
          donate_aux=True,
          variants={"pallas": (_lowering(_update_pallas, _chunk_pallas),

@@ -1,6 +1,7 @@
 """The slot-pooled decoders at tiny sizes, one table: GLM-5.2's block,
 A.X-K1's, Xing4.0's, Trinity's, Granite 4.0-H's (dense and routed),
-Ling-3.0's and EvaByte's, which are served alone (``BLOCKS``), and the
+Ling-3.0's, SDAR's, Nemotron-H's and EvaByte's, which are served alone
+(``BLOCKS``), and the
 two that are trained too (``FUSED``: GPT-2's with learned and with
 rotary positions, OLMoE's). A block's row holds what differs -
 ``get_decode_symbol``'s keywords, how its parameters are drawn
@@ -100,6 +101,22 @@ _SDAR = {"num_key_value_heads": 2, "head_dim": 16, "num_experts": 16,
          "denoising_steps": 4, "remasking": "low_confidence_dynamic",
          "confidence_threshold": 0.9}
 
+#: Nemotron-H's: a layer is one sub-layer (five of them: M E M * E),
+#: B and C in two groups of four heads, a K/V head of 24 (not 64 / 4)
+#: read by four query heads, ungated experts of which half are held
+_NEMOTRON_H = {"hybrid_override_pattern": "MEM*E", "num_key_value_heads": 1,
+               "head_dim": 24, "mamba_num_heads": 8, "mamba_head_dim": 8,
+               "ssm_state_size": 16, "n_groups": 2, "conv_kernel": 4,
+               "chunk_size": 8, "use_conv_bias": True,
+               "mamba_proj_bias": False, "mamba_hidden_act": "silu",
+               "attention_bias": False, "mlp_bias": False,
+               "mlp_hidden_act": "relu2", "n_routed_experts": 8,
+               "num_experts_per_tok": 3, "moe_intermediate_size": 24,
+               "moe_shared_expert_intermediate_size": 40,
+               "n_shared_experts": 1, "n_group": 1, "topk_group": 1,
+               "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+               "held": (2, 4)}
+
 #: block -> ``get_decode_symbol``'s arguments beside the step length
 BLOCKS = {
     "glm_dsa": dict(vocab_size=48, d_model=64, n_layer=3, n_head=4,
@@ -121,6 +138,8 @@ BLOCKS = {
                     ffn_width=48),
     "sdar_moe": dict(vocab_size=48, d_model=64, n_layer=3, n_head=8,
                      rope_base=1e6, rms_eps=1e-6, sdar=_SDAR),
+    "nemotron_h": dict(vocab_size=48, d_model=64, n_layer=5, n_head=4,
+                       nemotron_h=_NEMOTRON_H),
 }
 
 #: the blocks with a training form (``_fused_attention``), likewise:
@@ -156,7 +175,8 @@ FED = sorted(BLOCKS) + sorted(FUSED)
 #: the keyword that holds a block's published keys
 PUBLISHED = {"glm_dsa": "glm", "axk1": "axk1", "xing4": "xing4",
              "afmoe": "afmoe", "granite_hybrid": "granite",
-             "ling_hybrid": "ling", "sdar_moe": "sdar"}
+             "ling_hybrid": "ling", "sdar_moe": "sdar",
+             "nemotron_h": "nemotron_h"}
 
 #: the positions a decode step of a block feeds and decides between
 #: them, where that is not one: its dispatches are whole blocks from a
@@ -223,6 +243,7 @@ DRAWS = {
     # 1e-1 through the inverse softplus, ``D`` 1
     "granite_hybrid": _MAMBA,
     "granite_moe_hybrid": _MAMBA,
+    "nemotron_h": _MAMBA,
     # KDA's decays span the bound: ``A_log`` about 0, ``dt_bias`` of
     # U(-6, 3)
     "ling_hybrid": {"_kda_norm_weight": _GAIN,
@@ -308,10 +329,11 @@ def driver(case, packed=True, slots=SLOTS, window=WINDOW, capacity=CAPACITY,
     return drv
 
 
-def lowered_text(sym, slots, step_len):
+def lowered_text(sym, slots, step_len, debug_info=False):
     """The text that the inference program of ``sym`` bound at ``(slots,
     step_len)`` lowers to (the function ``Executor`` jits, on the CPU
-    under whatever kernel tier is set)."""
+    under whatever kernel tier is set); with ``debug_info`` the
+    operations' locations too, which carry the named scopes."""
     exe = bound(sym, step_len, arg_params={}, slots=slots) \
         ._exec_group.executor
 
@@ -319,7 +341,8 @@ def lowered_text(sym, slots, step_len):
         return exe._runner(arg_vals, aux_vals, False, rng)
 
     return jax.jit(prog).lower(exe._arg_vals(), exe._aux_vals(),
-                               jax.random.PRNGKey(0)).as_text()
+                               jax.random.PRNGKey(0)) \
+        .as_text(**({"debug_info": True} if debug_info else {}))
 
 
 # ----------------------------------------------------- the plain references
@@ -357,6 +380,13 @@ def _ling_cfg(kw):
 
 def _granite_cfg(kw):
     return _published(kw, layers_run=list(range(kw["n_layer"])))
+
+
+def _nemotron_cfg(kw):
+    cfg = _published(kw, layers_run=list(range(kw["n_layer"])),
+                     layer_norm_epsilon=kw.get("rms_eps", 1e-5))
+    cfg["n_routed_experts_held"] = cfg.pop("num_experts_held")
+    return cfg
 
 
 def _evabyte_cfg(kw):
@@ -399,6 +429,7 @@ REFERENCE = {
     "evabyte": ("evabyte", _evabyte_cfg),
     "gpt2": ("gpt2", _gpt2_cfg), "olmoe": ("olmoe", _olmoe_cfg),
     "sdar_moe": ("sdar_moe", _published),
+    "nemotron_h": ("nemotron_h", _nemotron_cfg),
 }
 
 
@@ -493,6 +524,9 @@ TOL = {
     # chunked form sums in another order than the recurrence, the
     # grouped matmuls in another than one expert at a time)
     "granite_hybrid": 2e-4, "granite_moe_hybrid": 2e-4, "ling_hybrid": 2e-4,
+    # 5 sub-layers, logits about 2 (tests/test_nemotron_h.py, PR 63:
+    # 1e-6 to 1e-5; the same chunked form and grouped matmuls)
+    "nemotron_h": 2e-4,
     # 3 layers, logits about 3 (tests/test_sdar_moe.py, PR 60: 2e-6 to
     # 6e-6, Trinity's head geometry and OLMoE's router)
     "sdar_moe": 5e-5,
@@ -513,6 +547,7 @@ FAMILIES = {
     "granite_hybrid": ["conv", "cursor", "recurrent", "rows"],
     "granite_moe_hybrid": ["conv", "cursor", "recurrent", "rows"],
     "ling_hybrid": ["conv", "cursor", "recurrent", "rows"],
+    "nemotron_h": ["conv", "cursor", "recurrent", "rows"],
 }
 
 
@@ -540,7 +575,7 @@ _LING_REFUSED = {
 REFUSED = {
     "granite_hybrid": {
         case: ({"granite": over}, "granite_hybrid") for case, over in {
-            "two_groups": {"mamba_n_groups": 2},
+            "groups_that_split_a_head": {"mamba_n_groups": 3},
             "projection_bias": {"mamba_proj_bias": True},
             "positions": {"position_embedding_type": "rope"},
             "a_layer_short": {"layer_types": ["mamba", "attention"]},
@@ -575,6 +610,27 @@ REFUSED = {
 }
 REFUSED["granite_hybrid"]["no_published_keys"] = ({"granite": None},
                                                   "needs granite=")
+#: ``_nemotron_h_spec``: a letter it has no sub-layer for, a pattern of
+#: another length, what the config could say and the block does not build
+REFUSED["nemotron_h"] = {
+    **{case: ({"nemotron_h": over}, "nemotron_h") for case, over in {
+        "a_dense_feed_forward_layer": {"hybrid_override_pattern": "MEM-E"},
+        "a_layer_short": {"hybrid_override_pattern": "MEM*"},
+        "groups_that_split_a_head": {"n_groups": 3},
+        "projection_bias": {"mamba_proj_bias": True},
+        "attention_bias": {"attention_bias": True},
+        "expert_bias": {"mlp_bias": True},
+        "a_gated_activation": {"mlp_hidden_act": "silu"},
+        "two_shared_experts": {"n_shared_experts": 2},
+        "a_group_limited_router": {"n_group": 2, "topk_group": 1},
+        "gates_not_normalised": {"norm_topk_prob": False},
+        "more_experts_a_token_than_the_router_has":
+            {"num_experts_per_tok": 9},
+        "held_past_the_router": {"held": (6, 4)},
+        "kv_heads_that_split_the_query_heads": {"num_key_value_heads": 3},
+    }.items()},
+    "no_published_keys": ({"nemotron_h": None}, "needs nemotron_h="),
+}
 
 
 # ------------------------------------------------- a schedule of dispatches

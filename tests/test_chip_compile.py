@@ -185,28 +185,34 @@ def test_eva_programs_hold_each_kernel_once_whatever_their_layers(S, v5e):
 
 @pytest.mark.parametrize("S,rows", [(1, 32), (256, 384)],
                          ids=["decode", "packed_window"])
-@pytest.mark.parametrize("H", [64, 128], ids=["micro", "small"])
-def test_ssm_mixer_compiles_for_v5e_and_copies_no_state(H, S, rows, v5e):
+@pytest.mark.parametrize("H,groups,chunk", [(64, 1, 256), (128, 1, 256),
+                                            (64, 8, 128)],
+                         ids=["micro", "small", "nemotron"])
+def test_ssm_mixer_compiles_for_v5e_and_copies_no_state(H, groups, chunk, S,
+                                                        rows, v5e):
     """Granite 4.0-H's mixer at the published sizes (32 slots, 64 heads
     of 64 - Micro - or 128 - Small, ISSUE 54: 64 lane groups, rows of
     8,448 channels -, a state of 128, a convolution over 4, chunks of
-    256; the S = 1 program's 32 rows and the packed window's 384): the
-    Pallas lowering compiles for the chip - the step's kernel, and in a
-    window the chunk's inside XLA's loop over the trips -, and with the
-    aux arrays donated the 67 MB (134 MB) state comes back in the
-    buffer it came in, never copied."""
+    256; the S = 1 program's 32 rows and the packed window's 384) and
+    Nemotron-H's (ISSUE 63: 64 heads of 64 with B and C in 8 groups,
+    rows of 6,144 channels, chunks of 128 - four lane groups read one
+    group, its B and C stood up as columns in the kernel): the Pallas
+    lowering compiles for the chip - the step's kernel, and in a window
+    the chunk's inside XLA's loop over the trips -, and with the aux
+    arrays donated the 67 MB (134 MB) state comes back in the buffer it
+    came in, never copied."""
     import re
     opdef = get_op("ssm_mixer_decode")
     P, N, K = 64, 128, 4
-    d_in, C = H * P, H * P + 2 * N
+    d_in, C = H * P, H * P + 2 * groups * N
     attrs = opdef.normalize_attrs(dict(
-        heads=H, head_dim=P, d_state=N, d_conv=K, chunk=256, step_len=S,
-        capacity=4096))
+        heads=H, head_dim=P, d_state=N, d_conv=K, chunk=chunk, step_len=S,
+        capacity=4096, **({"groups": groups} if groups > 1 else {})))
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
 
-    ins = [sds((rows, 2 * d_in + 2 * N + H)), sds((32,), "int32"),
+    ins = [sds((rows, d_in + C + H)), sds((32,), "int32"),
            sds((C, K)), sds((C,)), sds((H,)), sds((H,)), sds((H,))]
     aux = [sds((32, K - 1, C), "float32"),
            sds((32, d_in // 128, N, 128), "float32"), sds((32, 1), "int32")]
@@ -413,6 +419,9 @@ _FED_GRAPHS = {
     # upper edge the end of the query's block of 4
     "sdar": (dict(capacity=8192, kv_heads=4, rope=True, block=4),
              8, 32, 4, 8192, 512),
+    # Nemotron-H's (ISSUE 63): 16 query heads a K/V head, unrotated,
+    # the default scale
+    "nemotron": (dict(capacity=8192, kv_heads=2), 32, 32, 2, 8192, 256),
 }
 
 
@@ -438,13 +447,14 @@ def _fed_graph(kind, S, v5e):
 
 @pytest.mark.parametrize("S", [1, 0], ids=["decode", "window"])
 @pytest.mark.parametrize("kind", ["full", "sliding", "granite_small",
-                                  "granite_micro"])
+                                  "granite_micro", "nemotron"])
 def test_grouped_window_attention_compiles_for_v5e_and_copies_no_pool(kind, S,
                                                                       v5e):
-    """Trinity-Mini's and the two Granites' ``attention_decode`` at the
-    published sizes (``_FED_GRAPHS``), in the S = 1 program and the
-    window program of 1,024 or 256 rows a slot. The write is
-    ``cache_write``; the read ``decode_attn`` (4 or 8 rows a K/V head)
+    """Trinity-Mini's, the two Granites' and Nemotron-H's
+    ``attention_decode`` at the published sizes (``_FED_GRAPHS``), in
+    the S = 1 program and the window program of 1,024 or 256 rows a
+    slot. The write is ``cache_write``; the read ``decode_attn`` (4, 8
+    or - Nemotron-H, inside ``_DECODE_ROWS`` 64 - 16 rows a K/V head)
     or, in a window, ``window_attn`` for the slots that prefill and
     ``window_attn_ride`` for those fed one row (ISSUE 58), whose row 0
     is laid into the window's result where it lies; two layers hold one

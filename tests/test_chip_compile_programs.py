@@ -316,6 +316,58 @@ def test_softmax_share_compiles_for_v5e_with_the_grouped_kernels(
         assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
 
 
+@pytest.mark.parametrize("step_len,rows,slots", [
+    (1, 32, 32), (256, 384, 1), (256, 8192, 32)],
+    ids=["decode", "packed_window", "whole_window"])
+def test_ungated_share_compiles_for_v5e_and_copies_no_expert(
+        step_len, rows, slots, v5e):
+    """Nemotron-H's ``MoEFFN(act="relu2")`` at the published sizes (rows
+    of 2,688, a sigmoid router with a bias over 128 experts, 6 a token
+    times 2.5, 64 held of width 1,856 - 14.5 x 128 lanes - beside a
+    shared expert of 3,712; ISSUE 63): the ungated form is eligible for
+    the Pallas lowering, ``moe_gmm_up`` and ``moe_gmm_down`` compile for
+    the chip inside the loop over the held rows' segments - no
+    ``moe_gmm_gate_up`` -, and neither stack of 64 matrices, each with
+    the model's width last, is copied or laid out anew in front of a
+    kernel (a ``(2,688, 1,856)`` matrix would be: the chip holds it
+    transposed)."""
+    import re
+    D, F, Fs, E, held = 2688, 1856, 3712, 128, 64
+    opdef = get_op("MoEFFN")
+    attrs = opdef.normalize_attrs(dict(
+        num_experts=E, num_hidden=F, top_k=6, norm_topk=True,
+        scoring="sigmoid", router_bias=True, scaling=2.5, held_first=0,
+        held_count=held, shared_hidden=Fs, step_len=step_len, act="relu2"))
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ins = [sds((rows, D)), sds((slots,), jnp.int32), sds((E, D)), sds((E,)),
+           sds((held, F, D)), sds((held, F, D)), sds((D, Fs)), sds((Fs, D))]
+    assert opdef.input_names(attrs) == [
+        "data", "fed", "router_weight", "router_bias", "up_weight",
+        "down_weight", "shared_up_weight", "shared_down_weight"]
+    aux = [sds((5,), jnp.int32)]
+    assert opdef.variant_eligible("pallas", attrs,
+                                  [a.shape for a in ins + aux],
+                                  [str(a.dtype) for a in ins + aux])
+    # an expert's width off the sublanes is not offered to the kernels
+    off = [a.shape for a in ins + aux]
+    off[4] = off[5] = (held, 1850, D)
+    assert not opdef.variant_eligible("pallas", attrs, off,
+                                      [str(a.dtype) for a in ins + aux])
+    fn = opdef.variant_fn("pallas")
+    compiled = jax.jit(lambda r, a: fn(attrs, r, a, False, None)) \
+        .lower(ins, aux).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_gmm_up", "moe_gmm_down"):
+        assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
+    assert "moe_gmm_gate_up" not in text
+    stack = rf"= bf16\[{held},{F},{D}\]\S* "
+    assert not re.findall(stack + r"(copy|fusion|transpose)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 600 << 20
+
+
 @pytest.mark.parametrize("form", ["plain", "held"])
 def test_two_expert_layers_lower_the_grouped_kernels_once(form, v5e,
                                                           monkeypatch):

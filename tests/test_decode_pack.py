@@ -68,7 +68,9 @@ TOL = {"evabyte": 0.0, "glm_dsa": 2e-5, "axk1": 2e-5, "afmoe": 2e-5,
        "ling_hybrid": 2e-5,
        # Trinity's head geometry and OLMoE's router; the packed and the
        # whole program mask a ragged window alike, wherever it starts
-       "sdar_moe": 2e-5}
+       "sdar_moe": 2e-5,
+       # Granite's convolution and a shared expert, as above
+       "nemotron_h": 2e-5}
 
 #: ``packed_rows`` before ISSUE 47: a chunk and a token a slot, rounded
 #: up to the tile (128 rows; 8 under that)

@@ -32,7 +32,7 @@ and riders share one dispatch. Prints one JSON line.
     python3 tools/window_pack_check.py
         --config a.x-k1|glm-5.2|xing4.0-29b-a4b|cerebras-gpt-1.3b|olmoe-1b-7b
                  |granite-4.0-h-micro|ling-3.0-flash|granite-4.0-h-small
-                 |trinity-mini
+                 |trinity-mini|nemotron-3-nano-30b-a3b
         [--seed N] [--part N] [--context N] [--rehearse]
 
 ``--context N`` prefills N positions (whole windows) in place of all the
@@ -67,6 +67,7 @@ _TINY = {"a.x-k1": ("axk1", "tiny-axk1.json"),
          "granite-4.0-h-small": ("granite_moe_hybrid",
                                  "tiny-granite-small.json"),
          "trinity-mini": ("afmoe", "tiny-afmoe.json"),
+         "nemotron-3-nano-30b-a3b": ("nemotron_h", "tiny-nemotron.json"),
          "cerebras-gpt-1.3b": None}
 
 #: the Cerebras configuration cut to a rehearsal's size (learned
