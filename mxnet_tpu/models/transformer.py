@@ -35,6 +35,7 @@ import numpy as np
 from .. import symbol as sym
 from .. import telemetry as _telemetry
 from ..base import MXNetError
+from ..ops.rows import one_chunk
 
 __all__ = ["get_symbol", "get_decode_symbol", "SyntheticLMIter",
            "KVCacheDecoder", "BatchedKVCacheDecoder", "slot_state",
@@ -1750,6 +1751,19 @@ def packed_window(symbol, slots):
     return packed, rows
 
 
+def copy_sites(symbol, step_len):
+    """``(sites, static)`` of a window graph of ``step_len`` rows a
+    slot: its ``pack_rows`` / ``unpack_rows`` nodes that carry a row
+    budget - a copy of rows each, where a node without one is nothing or
+    a reshape - and those of them that lower without a loop
+    (``ops/rows.py``'s ``one_chunk``: all where a slot's rows are one
+    chunk, none otherwise). A count of the graph."""
+    sites = sum(node.op in ("pack_rows", "unpack_rows")
+                and int(node.attrs.get("rows", 0)) > 0
+                for node in symbol._topo_nodes())
+    return sites, sites if one_chunk(int(step_len)) else 0
+
+
 def row_programs(slots, block, shardings):
     """``(capture, restore)``: two jitted programs over the ``"rows"``
     pools of a ``slots``-slot driver, of the pools' shapes and ``block``
@@ -1897,13 +1911,18 @@ class BatchedKVCacheDecoder:
         self._windows = {}                           # step_len -> module
         # step_len -> how a step's host arrays reach that module's cells
         self._stagers = {1: module._exec_group.input_stager()}
-        # step_len -> (module, its stager, R): the window program over
-        # the real rows alone, where there is one (``add_window``)
+        # step_len -> (module, its stager, R, its copy sites): the
+        # window program over the real rows alone, where there is one
+        # (``add_window``)
         self._packed = {}
         # rows the latest step's program ran its row-wise operations
         # over: slots x S, or R where it was the packed one; and those
         # its head ran over: the same, but a packed window's ``slots``
         self.last_program_rows = self.last_head_rows = None
+        # the copies of rows in the latest step's program and those of
+        # them that hold no loop (``copy_sites``): a packed window's
+        # alone, (0, 0) of any other program
+        self.last_copy_sites = None
         self._stepped = None          # the module the latest step ran
         self._cursor_program = None                  # built at first use
         self._row_progs = None                       # capture, restore
@@ -2011,7 +2030,8 @@ class BatchedKVCacheDecoder:
         if packed is not None:
             form, rows = packed
             self._packed[int(step_len)] = (
-                form, form._exec_group.input_stager(), int(rows))
+                form, form._exec_group.input_stager(), int(rows),
+                copy_sites(form.symbol, step_len))
 
     def window_budget(self, step_len):
         """R, the rows that the slots of one ``step_len`` window may be
@@ -2604,7 +2624,9 @@ class BatchedKVCacheDecoder:
         program (``add_window(packed=)``) and ``fed`` is given and sums to no
         more than its budget, that is the program launched: its
         row-wise operations run over the budget's rows, not ``slots x
-        S`` (``last_program_rows`` says which ran), its head over each
+        S`` (``last_program_rows`` says which ran; ``last_copy_sites``
+        how many copies of rows it holds and how many of them without a
+        loop), its head over each
         slot's last fed row (``last_head_rows``: ``slots``), and what
         it returns is that row alone, ``(slots, 1, V)``: row
         ``fed[b] - 1`` of slot ``b``'s window, the row a serving window
@@ -2633,6 +2655,7 @@ class BatchedKVCacheDecoder:
                                  f"got {tokens.shape}")
             stage = self._stagers.get(S)
             self.last_program_rows = self.last_head_rows = self.slots * S
+            self.last_copy_sites = (0, 0)
             if S == 1:
                 mod = self._mod
             else:
@@ -2661,7 +2684,8 @@ class BatchedKVCacheDecoder:
             # fed
             fed = np.where(self.pos + S <= self.capacity, fed, 0)
             if packed is not None and fed.sum() <= packed[2]:
-                mod, stage, self.last_program_rows = packed
+                mod, stage, self.last_program_rows, self.last_copy_sites \
+                    = packed
                 self.last_head_rows = self.slots
             self.last_reads = self._dispatch_reads(fed)
             if self.name is not None:    # the pools' bytes a dispatch

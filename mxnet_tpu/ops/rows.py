@@ -21,13 +21,31 @@ from one view to the other, and carry the row budget ``rows``:
   as ``(slots, S, ...)``, pads zero. Whoever launches the program keeps
   ``fed.sum() <= R``; rows past the budget would be dropped.
 
-Both are copies of contiguous blocks and no gather: a slot's real rows
-are a prefix of its S, so packing copies each slot's real rows in place,
-``_chunk`` rows a copy and as many copies as the slot has real rows for
-(one loop whose trips follow ``fed``: a riding slot costs one copy, an
-unfed one none), in slot order - each later block lands on the pad tail
-of the one before - and unpacking copies them back the same way under a
-row mask. Neither touches a pad row beyond a copy's tail.
+A slot's real rows are a prefix of its S, and the shape says how they
+are moved (``one_chunk``, the one predicate, known when the program is
+traced):
+
+* a slot of several chunks (``step_len`` 256, 512, 1,024): copies of
+  contiguous blocks and no gather. Packing copies each slot's real rows
+  in place, ``_chunk`` rows a copy and as many copies as the slot has
+  real rows for (one loop whose trips follow ``fed``: a riding slot
+  costs one copy of 128 rows and not its slot's 1,024, an unfed one
+  none), in slot order - each later block lands on the pad tail of the
+  one before - and unpacking copies them back the same way under a row
+  mask. Neither touches a pad row beyond a copy's tail;
+* a slot of one chunk (``step_len`` 64, and every size the CPU tests
+  run): no loop, because there the trips are a copy a fed slot whatever
+  ``fed`` holds and a trip costs several times its copy (5.5 us for 64
+  rows, 50 loops a Cerebras window program). Unpacking is the loop's
+  body once a slot, laid out in the program's text: a slice of the block
+  at the slot's offset under the loop's row mask, the ``slots`` slices
+  stacked, nothing carried and no output updated in place. Packing is
+  one gather of whole rows by an index computed from ``fed`` - a packed
+  row past the real ones reads nothing and is zero - which the compiler
+  fuses into its reader; ``slots`` in-place updates in the text cost the
+  loop's trips over again, a 64-row block laid at an odd row offset of a
+  tiled buffer each (``PERF.md`` section 6, PR 65: the forms timed).
+  The real rows are the loop's, bit for bit.
 
 ``pack_rows`` also returns ``fed`` in the packed view: itself at ``rows
 = 0``, the one pseudo-slot's count of real rows ``(1,)`` under a budget
@@ -53,13 +71,19 @@ from jax import lax
 from ..base import parse_int, parse_tuple
 from .registry import register
 
-__all__ = ["pack", "unpack", "last"]
+__all__ = ["pack", "unpack", "last", "one_chunk"]
 
 
 def _chunk(step_len):
     """Rows a copy: 128 where a slot's rows are whole chunks of that,
     else all of a slot's."""
     return 128 if step_len % 128 == 0 else step_len
+
+
+def one_chunk(step_len):
+    """Whether a slot's ``step_len`` rows are one copy's: ``pack`` and
+    ``unpack`` then hold no loop (module docstring)."""
+    return _chunk(step_len) == step_len
 
 
 def _offsets(fed, step_len, rows):
@@ -92,6 +116,12 @@ def _at(values, b):
 def pack(x, fed, rows):
     """``x (slots, S, ...)`` -> ``(1, rows, ...)`` and the packed view's
     ``fed (1,)`` (module docstring)."""
+    form = _pack_gathered if one_chunk(x.shape[1]) else _pack_looped
+    return form(x, fed, rows)
+
+
+def _pack_looped(x, fed, rows):
+    """``pack`` as copies of chunks, as many as hold a real row."""
     step_len = x.shape[1]
     chunk = _chunk(step_len)
     fed, starts, total = _offsets(fed, step_len, rows)
@@ -109,10 +139,34 @@ def pack(x, fed, rows):
     return _copy_real(out, fed, chunk, copy)[:, :rows], total
 
 
+def _pack_gathered(x, fed, rows):
+    """``pack`` as one gather of whole rows, the rows past the real ones
+    zero."""
+    slots, step_len = x.shape[:2]
+    fed, starts, total = _offsets(fed, step_len, rows)
+    at = jnp.arange(rows, dtype=jnp.int32)
+    # a packed row lies as far behind its place in ``x`` as the slots
+    # that end at or before it have pads (an unfed slot ends where it
+    # starts)
+    pads = jnp.where((starts + fed)[:, None] <= at, (step_len - fed)[:, None],
+                     0).sum(0)
+    at = jnp.where(at < total, at + pads, slots * step_len)   # else no row
+    out = x.reshape((-1,) + x.shape[2:]).at[at].get(mode="fill",
+                                                    fill_value=0)
+    return out[None], total
+
+
 def unpack(x, fed, step_len, rows, tail):
     """``x (rows, ...)`` -> ``(slots, step_len) + tail`` (module
     docstring)."""
-    slots = fed.shape[0]
+    form = _unpack_sliced if one_chunk(step_len) else _unpack_looped
+    return form(x, fed, step_len, rows, tail)
+
+
+def _chunk_reader(x, fed, step_len, rows, tail):
+    """``real, fed``: ``real(b, i)`` is chunk ``i`` of slot ``b`` out of
+    the packed ``x``, its rows past ``fed[b]`` zero; ``fed`` inside the
+    budget."""
     chunk = _chunk(step_len)
     fed, starts, _ = _offsets(fed, step_len, rows)
     zero = (0,) * len(tail)
@@ -123,16 +177,32 @@ def unpack(x, fed, step_len, rows, tail):
     at = jnp.arange(chunk, dtype=jnp.int32) \
         .reshape((chunk,) + (1,) * len(tail))
 
-    def copy(out, b, i):
+    def real(b, i):
         block = lax.dynamic_slice(
             x, (_at(starts, b) + i * chunk,) + zero, (chunk,) + tail)
-        block = jnp.where(i * chunk + at < _at(fed, b), block,
-                          jnp.zeros((), x.dtype))
-        return lax.dynamic_update_slice(out, block[None],
-                                        (b, i * chunk) + zero)
+        return jnp.where(i * chunk + at < _at(fed, b), block,
+                         jnp.zeros((), x.dtype))
 
-    out = jnp.zeros((slots, step_len) + tail, x.dtype)
+    return real, fed
+
+
+def _unpack_looped(x, fed, step_len, rows, tail):
+    """``unpack`` as copies of chunks, as many as hold a real row."""
+    chunk = _chunk(step_len)
+    real, fed = _chunk_reader(x, fed, step_len, rows, tail)
+
+    def copy(out, b, i):
+        return lax.dynamic_update_slice(out, real(b, i)[None],
+                                        (b, i * chunk) + (0,) * len(tail))
+
+    out = jnp.zeros((fed.shape[0], step_len) + tail, x.dtype)
     return _copy_real(out, fed, chunk, copy)
+
+
+def _unpack_sliced(x, fed, step_len, rows, tail):
+    """``unpack`` of one chunk a slot: the loop's body once a slot."""
+    real, fed = _chunk_reader(x, fed, step_len, rows, tail)
+    return jnp.stack([real(b, 0) for b in range(fed.shape[0])])
 
 
 def last(x, fed, step_len=None, rows=0):

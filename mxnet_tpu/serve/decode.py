@@ -932,10 +932,16 @@ class DecodeEngine:
 #: its head ran over - the final norm and the product with the
 #: vocabulary: the same, but of a packed program each slot's last fed
 #: row alone (``slots``) - so head / program says how often the form
-#: that selects before the head engaged
+#: that selects before the head engaged. And the copies of rows in the
+#: launched program (its ``pack_rows`` / ``unpack_rows`` nodes under a
+#: budget; none in a whole-window program) beside those of them that
+#: hold no loop - all where a slot's rows are one chunk, ``step_len``
+#: 64, and none at 256 or more (``ops/rows.py``): static / copy says
+#: which lowering the served windows took, from the graph alone
 _WINDOW_COUNTERS = ("window.dispatches", "window.fed_slots",
                     "window.riding_slots", "window.real_rows",
-                    "window.program_rows", "window.head_rows")
+                    "window.program_rows", "window.head_rows",
+                    "window.copy_sites", "window.static_copy_sites")
 
 
 #: ``serve.decode.<name>`` counters of an engine that decodes by blocks:
@@ -1005,6 +1011,7 @@ class _Dispatch:
                        "dispatch": 0.0, "fetch": 0.0, "bytes": 0,
                        "stage": 0.0, "launch": 0.0, "select": 0.0,
                        "ids": 0.0, "program_rows": 0, "head_rows": 0,
+                       "copy_sites": 0, "static_copy_sites": 0,
                        "reads": collections.Counter()}
 
 
@@ -1594,7 +1601,9 @@ class DecodeScheduler:
         the graph's ops count it (``drv.last_reads``), adds up in
         ``phases["reads"]``, a ``Counter``, the rows its program and
         its head ran over in ``phases["program_rows"]`` and
-        ``["head_rows"]``. Returns what ``_fetch``
+        ``["head_rows"]``, the copies of rows the program holds and
+        those without a loop in ``["copy_sites"]`` and
+        ``["static_copy_sites"]``. Returns what ``_fetch``
         takes - a ``_Launched``, all of it on the device, ``out`` the
         whole output where ``last`` is None (the speculative path: the
         verifier reads every row of target and draft) and None
@@ -1613,6 +1622,8 @@ class DecodeScheduler:
             phases["launch"] += drv.last_launch
             phases["program_rows"] += drv.last_program_rows
             phases["head_rows"] += drv.last_head_rows
+            phases["copy_sites"] += drv.last_copy_sites[0]
+            phases["static_copy_sites"] += drv.last_copy_sites[1]
             if last is not None:
                 picked, ids, nxt = drv.select_rows(out, last, feed=feed,
                                                    now=now)
@@ -2015,6 +2026,9 @@ class DecodeScheduler:
                     m["window.real_rows"].inc(sum(rows))
                     m["window.program_rows"].inc(phases["program_rows"])
                     m["window.head_rows"].inc(phases["head_rows"])
+                    m["window.copy_sites"].inc(phases["copy_sites"])
+                    m["window.static_copy_sites"].inc(
+                        phases["static_copy_sites"])
                 # what the dispatches read of the state, under the
                 # names its ops gave: a counter, a ring field, or both
                 read_fields = {}
@@ -2362,6 +2376,8 @@ class DecodeScheduler:
             phases["launch"] += drv.last_launch
             phases["program_rows"] += drv.last_program_rows
             phases["head_rows"] += drv.last_head_rows
+            phases["copy_sites"] += drv.last_copy_sites[0]
+            phases["static_copy_sites"] += drv.last_copy_sites[1]
             phases["denoise"] = -now()
             state = drv.denoise_select(out, tokens, undecided, d.quota,
                                        d.threshold, now=now)

@@ -718,6 +718,12 @@ def test_the_s1_latent_programs_lower_to_the_parents_text(lowering, v5e):
 #: last fed row in front of the head and the program returns ``(slots,
 #: 1, V)``, so the seven ``"packed"`` digests were recorded anew on its
 #: tree; every S = 1 and whole-window digest stands as it was.
+#: ISSUE 65 changed the packed forms again and nothing else: at the 16
+#: rows a slot of these programs a slot's rows are one chunk, where
+#: ``pack_rows`` / ``unpack_rows`` under a budget lower to a gather of
+#: rows and to a slice a slot and no longer to a loop (``ops/rows.py``),
+#: so the nine ``"packed"`` digests were recorded anew on its tree;
+#: every S = 1 and whole-window digest stands as it was.
 #: ``granite_hybrid`` (``num_local_experts`` 0, Micro's dense block)
 #: joined with ISSUE 54, which taught ``_granite_spec`` the routed
 #: layer: its three digests are PR 54's parent's (60de662), computed on
@@ -725,7 +731,7 @@ def test_the_s1_latent_programs_lower_to_the_parents_text(lowering, v5e):
 _PARENT_PROGRAM_SHA256 = {
     ("granite_hybrid", 1, "whole"): "e339ed008af6b332",
     ("granite_hybrid", 16, "whole"): "c603e7f61d202afb",
-    ("granite_hybrid", 16, "packed"): "539fd786d5a6ebaa",
+    ("granite_hybrid", 16, "packed"): "2e96e86a9b0e1824",
     ("glm_dsa", 1, "whole"): "9461136fdd6eaa09",
     ("glm_dsa", 16, "whole"): "96061a4da742fd99",
     ("axk1", 1, "whole"): "2927901ccdca78df",
@@ -734,26 +740,26 @@ _PARENT_PROGRAM_SHA256 = {
     ("afmoe", 16, "whole"): "005db05c189522c6",
     ("evabyte", 1, "whole"): "3507d30cfe287fb6",
     ("evabyte", 16, "whole"): "88305a7d0fb20b40",
-    ("glm_dsa", 16, "packed"): "edfd90ea26b02ac1",
-    ("axk1", 16, "packed"): "8a92ce89c1fc83d9",
-    ("afmoe", 16, "packed"): "d1f0d55c4b980ee2",
-    ("evabyte", 16, "packed"): "b04035b7d3c443b1",
+    ("glm_dsa", 16, "packed"): "fa885957a7552a6f",
+    ("axk1", 16, "packed"): "6ac093ffd8a8e037",
+    ("afmoe", 16, "packed"): "a85cd904c7c6245c",
+    ("evabyte", 16, "packed"): "69f18e2f99666c0b",
     ("gpt2", 1, "whole"): "21ee45bab5a96eb9",
     ("gpt2", 16, "whole"): "450794b962ca88af",
-    ("gpt2", 16, "packed"): "a0175b9779668163",
+    ("gpt2", 16, "packed"): "725c081618075ec3",
     ("gpt2_rotary", 1, "whole"): "27a812ab4885915c",
     ("gpt2_rotary", 16, "whole"): "10f9b19f6dbac22d",
-    ("gpt2_rotary", 16, "packed"): "3e3cc32ee1bfdedd",
+    ("gpt2_rotary", 16, "packed"): "6a8a616ec76139f9",
     ("olmoe", 1, "whole"): "afab9133b8a82585",
     ("olmoe", 16, "whole"): "c801f808ba5a429b",
-    ("olmoe", 16, "packed"): "44ac33c89bb0b8c2",
+    ("olmoe", 16, "packed"): "bf69baefc4282d35",
     # ISSUE 60's own tree: the block that decodes by blocks - its S = 1
     # program, the program of one block, the prefill window and its
     # packed form join the rest
     ("sdar_moe", 1, "whole"): "0a898f651820b8d4",
     ("sdar_moe", 4, "whole"): "11ef4bec07b8939e",
     ("sdar_moe", 16, "whole"): "3d1bbf14ec4620a1",
-    ("sdar_moe", 16, "packed"): "f975eb222572fb9c",
+    ("sdar_moe", 16, "packed"): "c5b2be53345c6ff1",
 }
 
 
@@ -779,6 +785,31 @@ def test_decode_programs_lower_to_the_parents_text(block, S, form,
         kernel_tier.clear()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == _PARENT_PROGRAM_SHA256[(block, S, form)]
+
+
+@pytest.mark.parametrize("block,parents", [("gpt2", 6), ("olmoe", 9)])
+def test_a_packed_window_of_8x64_lowers_without_a_loop(block, parents,
+                                                       monkeypatch):
+    """ISSUE 65, from the lowered text: the rung-8 packed form of the
+    Cerebras and the OLMoE block's window of 64 rows a slot (256 rows;
+    two layers at the tests' widths) holds no ``while`` at all. The
+    parent's held one a copy site - the tokens, the learned positions,
+    a layer's split into heads (OLMoE: q, k and v apart) and its merge:
+    ``parents`` here, 50 in the doc cell's 24 layers - and nothing
+    else of these graphs loops under the XLA compositions."""
+    import decode_blocks as cases
+    from mxnet_tpu.models import transformer as tfm
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
+    kernel_tier.clear()
+    try:
+        packed, budget = tfm.packed_window(
+            cases.symbol(block, 64, capacity=256), 8)
+        text = cases.lowered_text(packed, 8, 64)
+    finally:
+        kernel_tier.clear()
+    assert budget == 256
+    assert tfm.copy_sites(packed, 64) == (parents, parents)
+    assert "stablehlo.while" not in text
 
 
 @pytest.mark.parametrize("block", [

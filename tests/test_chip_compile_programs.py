@@ -35,6 +35,100 @@ _SERVED_WIDTHS = {
 }
 
 
+def _compiled_window(symbol, B, S, v5e):
+    """The text that the window program of ``symbol`` at ``(B, S)``
+    compiles to for the described chip, bfloat16, its state donated."""
+    from mxnet_tpu.executor import _build_graph_runner
+    runner, arg_names, aux_names, _ = _build_graph_runner(
+        symbol, compute_dtype="bfloat16")
+    given = {nm: (B, S) for nm in ("data", "pos_ids") if nm in arg_names}
+    arg_shapes, _, aux_shapes = symbol.infer_shape(fed=(B,), **given)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e)
+
+    args = {nm: sds(s, jnp.int32 if nm in ("data", "fed") else jnp.bfloat16)
+            for nm, s in zip(arg_names, arg_shapes)}
+    aux = {nm: sds(s, jnp.int32 if len(s) < 4 else jnp.bfloat16)
+           for nm, s in zip(aux_names, aux_shapes)}
+
+    def prog(arg_vals, aux_vals):
+        outs, new_aux = runner(arg_vals, aux_vals, False, None)
+        return outs, {**aux_vals, **new_aux}
+
+    return jax.jit(prog, donate_argnums=(1,)).lower(args, aux).compile() \
+        .as_text()
+
+
+#: the scopes of a fed graph's ``pack_rows`` / ``unpack_rows`` nodes
+#: (``models/transformer.py``: the tokens, the learned positions, a
+#: layer's split into heads and its merge), as an operation's
+#: ``op_name`` or location carries them in front of what they lower to
+_ROW_COPY_LOOP = r"(_rows|_split|_pack|_unfold)/while\b"
+
+
+@pytest.mark.parametrize("block", sorted(_SERVED_WIDTHS))
+def test_a_window_of_one_chunk_a_slot_compiles_for_v5e_without_a_copy_loop(
+        block, v5e, monkeypatch):
+    """ISSUE 65: the packed window program of the Cerebras and the OLMoE
+    block at 8 x 64 / 256 rows, compiled for the chip: a slot's 64 rows
+    are one chunk, so no ``while`` of it is a copy of rows (what is left
+    are the compiler's own around the kernels' grids and the embedding
+    reads) where the parent held one a site - the tokens, the
+    positions, a layer's split into heads and its merge: 6 and 9 in
+    these two layers, 50 in the doc cell's 24 - and the sites are
+    counted as static, every one."""
+    from mxnet_tpu.models import transformer as tfm
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "pallas")
+    kernel_tier.clear()
+    kw, _row_wise = _SERVED_WIDTHS[block]
+    B, S = 8, 64
+    packed, R = tfm.packed_window(
+        tfm.get_decode_symbol(step_len=S, per_slot=True, **kw), B)
+    assert R == 256
+    sites = {"gpt2": 2 * 2 + 2, "olmoe": 4 * 2 + 1}[block]
+    assert tfm.copy_sites(packed, S) == (sites, sites)
+    try:
+        text = _compiled_window(packed, B, S, v5e)
+    finally:
+        kernel_tier.clear()
+    for kernel in ("decode_attn", "cache_write"):
+        assert re.search(rf"{kernel}[.\w]* = .*tpu_custom_call", text), kernel
+    assert not [line for line in text.splitlines()
+                if " while(" in line and re.search(_ROW_COPY_LOOP, line)]
+    # the copies are there, under the sites' names: a gather of rows
+    # where the graph packs, a slice a slot where it unpacks
+    assert re.search(r' gather\(.*op_name="[^"]*(_rows|_pack)/', text)
+    assert re.search(r' dynamic-slice\(.*op_name="[^"]*_split/', text)
+
+
+def test_a_window_of_several_chunks_a_slot_keeps_its_copy_loops(monkeypatch):
+    """The other branch of the same predicate: Granite's block at its
+    tiny widths with 256 rows a slot (two chunks of 128; 8 slots pack
+    to 384) lowers every copy site of its packed window to the loop
+    whose trips follow ``fed``, one ``while`` a site beside the mixers'
+    own, and counts none of them static."""
+    import decode_blocks as cases
+    from mxnet_tpu.models import transformer as tfm
+    monkeypatch.setenv("MXNET_KERNEL_TIER", "xla")
+    kernel_tier.clear()
+    B, S = 8, 256
+    try:
+        packed, R = tfm.packed_window(
+            cases.symbol("granite_hybrid", S, capacity=2 * S), B)
+        text = cases.lowered_text(packed, B, S, debug_info=True)
+    finally:
+        kernel_tier.clear()
+    assert R == 384
+    sites, static = tfm.copy_sites(packed, S)
+    assert (sites, static) == (5, 0)    # the tokens, q, k, v, the merge
+    loops = re.findall(r'loc\("([^"]*/while)"', text)
+    assert len(loops) == text.count("stablehlo.while") == sites + 2
+    assert len([nm for nm in loops if re.search(_ROW_COPY_LOOP, nm)]) \
+        == sites
+    assert not re.search(r'loc\("[^"]*(_rows|_split|_pack)/gather', text)
+
+
 @pytest.mark.parametrize("block", sorted(_SERVED_WIDTHS))
 def test_fused_blocks_pack_a_window_of_8x64_to_the_ridge_on_v5e(
         block, v5e, monkeypatch):
@@ -44,7 +138,6 @@ def test_fused_blocks_pack_a_window_of_8x64_to_the_ridge_on_v5e(
     the block's kernels, its row-wise operations run over 256 rows and
     none over the whole window's 512, and the whole-window form of the
     same graph runs them over 512."""
-    from mxnet_tpu.executor import _build_graph_runner
     from mxnet_tpu.models import transformer as tfm
     monkeypatch.setenv("MXNET_KERNEL_TIER", "pallas")
     kernel_tier.clear()
@@ -57,28 +150,7 @@ def test_fused_blocks_pack_a_window_of_8x64_to_the_ridge_on_v5e(
     texts = {}
     try:
         for rows, symbol in ((R, packed), (B * S, whole)):
-            runner, arg_names, aux_names, _ = _build_graph_runner(
-                symbol, compute_dtype="bfloat16")
-            given = {nm: (B, S) for nm in ("data", "pos_ids")
-                     if nm in arg_names}
-            arg_shapes, _, aux_shapes = symbol.infer_shape(fed=(B,), **given)
-
-            def sds(shape, dtype):
-                return jax.ShapeDtypeStruct(tuple(shape), dtype,
-                                            sharding=v5e)
-
-            args = {nm: sds(s, jnp.int32 if nm in ("data", "fed")
-                            else jnp.bfloat16)
-                    for nm, s in zip(arg_names, arg_shapes)}
-            aux = {nm: sds(s, jnp.int32 if len(s) < 4 else jnp.bfloat16)
-                   for nm, s in zip(aux_names, aux_shapes)}
-
-            def prog(arg_vals, aux_vals):
-                outs, new_aux = runner(arg_vals, aux_vals, False, None)
-                return outs, {**aux_vals, **new_aux}
-
-            texts[rows] = jax.jit(prog, donate_argnums=(1,)) \
-                .lower(args, aux).compile().as_text()
+            texts[rows] = _compiled_window(symbol, B, S, v5e)
     finally:
         kernel_tier.clear()
     for text in texts.values():

@@ -193,6 +193,137 @@ def test_rows_are_copied_a_chunk_at_a_time_where_a_slot_holds_several(fed):
     np.testing.assert_array_equal(back, np.where(real[:, :, None], x, 0.0))
 
 
+#: ISSUE 65: a slot of one chunk is packed and unpacked without a loop.
+#: (slots, rows a slot, budget) and what each slot is fed: the Cerebras
+#: / OLMoE window's eight slots of 64 rows against its budget of 256,
+#: and the tests' own four of 16 against 24
+_ONE_CHUNK = {
+    "all_riding": (8, 64, 256, [1] * 8),
+    "a_chunk_and_riders": (8, 64, 256, [64, 1, 1, 1, 1, 1, 1, 1]),
+    "every_slot_full_and_so_over": (8, 64, 256, [64] * 8),
+    "four_chunks_fill_the_budget": (8, 64, 256, [64, 64, 64, 64, 0, 0, 0, 0]),
+    "fed_nothing_first": (8, 64, 256, [0, 64, 1, 1, 7, 1, 1, 1]),
+    "fed_nothing_in_the_middle": (8, 64, 256, [64, 1, 0, 0, 33, 1, 0, 1]),
+    "fed_nothing_last": (8, 64, 256, [5, 64, 1, 1, 1, 1, 1, 0]),
+    "a_ragged_last_chunk": (8, 64, 256, [37, 1, 1, 1, 1, 1, 1, 1]),
+    "nothing_fed": (8, 64, 256, [0] * 8),
+    "one_row_over": (8, 64, 256, [64, 64, 64, 63, 1, 1, 0, 0]),
+    "half_over_a_small_budget": (4, 64, 128, [64, 50, 40, 3]),
+}
+_ONE_CHUNK.update({f"tiny_{name}": (SLOTS, S, R, fed)
+                   for name, fed in MIXES.items()})
+
+#: what a site carries: the token ids and the learned positions (no
+#: trailing dimension), a layer's merged heads and its split into them
+_ROWS = {"ids": (jnp.int32, ()), "rows": (jnp.bfloat16, (48,)),
+         "heads": (jnp.bfloat16, (3, 16))}
+
+
+@pytest.mark.parametrize("kind", sorted(_ROWS))
+@pytest.mark.parametrize("mix", sorted(_ONE_CHUNK))
+def test_a_slot_of_one_chunk_is_packed_as_the_loop_packs_it(mix, kind):
+    """``pack`` / ``unpack`` where ``one_chunk(step_len)`` against the
+    loop's own form at the same shape (``_pack_looped``,
+    ``_unpack_looped``: ``_copy_real``, what the parent ran): the packed
+    real rows ``[0, total)`` equal to the bit and every row past them
+    finite, the whole ``(slots, step_len, ...)`` equal after ``unpack``
+    (pads zero), and ``unpack(pack(x))`` the real rows again - inside
+    the budget, at it and over it."""
+    slots, step_len, budget, fed = _ONE_CHUNK[mix]
+    dtype, tail = _ROWS[kind]
+    assert rows.one_chunk(step_len) and len(fed) == slots
+    rs = np.random.RandomState(sum(fed))
+    real = (np.arange(step_len)[None, :] < np.asarray(fed)[:, None]) \
+        .reshape((slots, step_len) + (1,) * len(tail))
+    if dtype == jnp.int32:
+        x = jnp.asarray(rs.randint(0, 1 << 20, (slots, step_len)), dtype)
+    else:
+        # a pad row may hold anything, a NaN too: nothing of it is packed
+        x = jnp.where(real, jnp.asarray(
+            rs.randn(slots, step_len, *tail), dtype), jnp.nan)
+    fed = jnp.asarray(fed, jnp.int32)
+    total = min(int(fed.sum()), budget)
+    packed, count = rows.pack(x, fed, budget)
+    want, want_count = rows._pack_looped(x, fed, budget)
+    assert packed.shape == (1, budget) + tail and packed.dtype == dtype
+    assert int(count[0]) == int(want_count[0]) == total
+    np.testing.assert_array_equal(packed[:, :total], want[:, :total])
+    assert np.isfinite(np.asarray(packed, np.float32)).all()
+    # as a layer hands them on: one row a row, the heads merged
+    flat = packed[0].reshape((budget, -1) if tail else (budget,))
+    back = rows.unpack(flat, fed, step_len, budget, tail)
+    assert back.shape == x.shape and back.dtype == dtype
+    np.testing.assert_array_equal(
+        back, rows._unpack_looped(flat, fed, step_len, budget, tail))
+    if int(fed.sum()) <= budget:
+        np.testing.assert_array_equal(
+            back, jnp.where(real, x, jnp.zeros((), dtype)))
+
+
+def _lowered(op, slots, step_len, budget):
+    import jax
+    fed = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    if op == "pack":
+        x = jax.ShapeDtypeStruct((slots, step_len, 48), jnp.bfloat16)
+        return jax.jit(lambda x, fed: rows.pack(x, fed, budget)) \
+            .lower(x, fed).as_text()
+    y = jax.ShapeDtypeStruct((budget, 48), jnp.bfloat16)
+    return jax.jit(lambda y, fed: rows.unpack(
+        y, fed, step_len, budget, (48,))).lower(y, fed).as_text()
+
+
+#: the first 16 hex digits of the sha256 of what ``jax.jit`` lowers
+#: ``_lowered``'s two functions to where a slot holds several chunks,
+#: recorded on PR 65's parent (7c770d7) before ``ops/rows.py`` changed:
+#: the loop's text stands as it was, to the letter
+_PARENT_LOOP_SHA256 = {
+    ("pack", 3, 256, 384): "ce7f23eede12985e",
+    ("unpack", 3, 256, 384): "666aec16d32909e4",
+    ("pack", 8, 256, 384): "518a3bfa561ae884",
+    ("unpack", 8, 256, 384): "77abc68c3fd1d0f7",
+    ("pack", 8, 1024, 1152): "9f52f86d279b513e",
+    ("unpack", 8, 1024, 1152): "2a6c94c52b485ef7",
+}
+
+
+@pytest.mark.parametrize("op", ["pack", "unpack"])
+def test_the_shape_decides_the_lowering(op):
+    """From the lowered text: at ``(8, 64, .)`` against 256 rows (the
+    Cerebras and OLMoE window) neither op holds a ``while``; at ``(3,
+    256, .)`` against 384 each holds one, and there and at ``(8, 256,
+    .)`` and ``(8, 1024, .)`` the text is the parent's, by digest."""
+    import hashlib
+    assert rows.one_chunk(64) and rows.one_chunk(16) \
+        and not any(map(rows.one_chunk, (256, 512, 1024)))
+    text = _lowered(op, 8, 64, 256)
+    assert "while" not in text
+    # one gather of rows packs; a slice a slot unpacks
+    assert text.count('"stablehlo.gather"') == 1 if op == "pack" \
+        else text.count("stablehlo.dynamic_slice") >= 8
+    for case, digest in _PARENT_LOOP_SHA256.items():
+        if case[0] == op:
+            text = _lowered(*case)
+            assert text.count("stablehlo.while") == 1
+            assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+@pytest.mark.parametrize("step_len,static", [
+    (16, True), (64, True), (256, False), (512, False), (1024, False)])
+def test_copy_sites_counts_the_budgeted_nodes_and_asks_the_shape(step_len,
+                                                                 static):
+    """What ``serve.decode.window.copy_sites`` / ``.static_copy_sites``
+    count a launch: nothing of a whole-window graph, of its packed form
+    two copies a layer (the split into heads, the merge), the tokens
+    and the learned positions - all without a loop where a slot's rows
+    are one chunk, none where they are several."""
+    whole = cases.symbol("gpt2", step_len, capacity=2 * step_len)
+    assert tfm.copy_sites(whole, step_len) == (0, 0)
+    packed, _budget = tfm.packed_window(whole, 8)
+    sites = 2 * cases.config("gpt2")["n_layer"] + 2
+    assert tfm.copy_sites(packed, step_len) \
+        == (sites, sites if static else 0)
+
+
 @pytest.mark.parametrize("fed", sorted(MIXES.values()),
                          ids=sorted(MIXES, key=MIXES.get))
 def test_last_rows_reads_each_slots_last_fed_row_in_both_views(fed):
@@ -474,6 +605,16 @@ def test_the_counters_and_the_ring_fields_are_the_parents(block):
     windows = [r for r in ring if r["window"] > 1]
     assert heads == sum(SLOTS if r["rung"] == SLOTS else S
                         for r in windows) > 0
+    # ISSUE 65: the copies of rows in the launched programs - the packed
+    # form's ``pack_rows`` / ``unpack_rows`` nodes under the budget, a
+    # whole-window launch (rung 1) holds none - and every one of them
+    # without a loop at the tests' one chunk a slot
+    sites, static = tfm.copy_sites(
+        tfm.packed_window(cases.symbol(block, S), SLOTS)[0], S)
+    assert sites == static > 2
+    assert counters.pop("window.copy_sites") \
+        == counters.pop("window.static_copy_sites") \
+        == sites * sum(r["rung"] == SLOTS for r in windows) > 0
     launched = counters.pop("runahead.launched")
     assert counters.pop("runahead.dropped") == 0
     assert counters.pop("window.dispatches") == len(windows)

@@ -89,6 +89,13 @@ def test_the_scheduler_plans_inside_the_budget_oldest_first(block):
     assert ran == R * len(windows)
     # every window selected in front of its head: a row a slot
     assert heads == SLOTS * len(windows)
+    # ISSUE 65: every window launched the packed form, whose copies of
+    # rows are counted a launch, and at one chunk a slot every one of
+    # them holds no loop; the S = 1 steps behind them added nothing
+    sites = tfm.copy_sites(
+        tfm.packed_window(cases.symbol(block, S), SLOTS)[0], S)[0]
+    assert sites > 2 and len(feds) < sched.iterations
+    assert _copies(engine.name) == (sites * len(windows),) * 2
 
     # the same requests planned without a budget (every active slot
     # min(S, remaining) a window): the same tokens, in fewer and wider
@@ -105,6 +112,36 @@ def test_the_scheduler_plans_inside_the_budget_oldest_first(block):
                                    model=whole).value \
         == mx.telemetry.get_metric("serve.decode.window.program_rows",
                                    model=whole).value > 0
+    # and copies no rows: the whole-window program has no such site
+    assert _copies(whole) == (0, 0)
+
+
+def _copies(model):
+    return tuple(mx.telemetry.get_metric(f"serve.decode.window.{key}",
+                                         model=model).value
+                 for key in ("copy_sites", "static_copy_sites"))
+
+
+def test_a_window_of_several_chunks_a_slot_counts_no_static_copy():
+    """256 rows a slot are two chunks: the packed form (8 slots, 384
+    rows) keeps the loops, so its launches add their sites to
+    ``window.copy_sites`` alone."""
+    step_len, slots = 256, 8
+    engine = cases.engine("gpt2", "pack-gpt2-256", ladder=(slots,),
+                          windows=(step_len,), capacity=2 * step_len)
+    assert engine.window_budget(slots, step_len) == 384
+    sched = DecodeScheduler(engine, clock=FakeClock(),
+                            prefill_chunk=step_len, prefix_store=None)
+    rs = np.random.RandomState(9)
+    vocab = cases.config("gpt2")["vocab_size"]
+    cases.served(sched, [rs.randint(0, vocab, n) for n in (300, 20)], 3)
+    drv = engine.driver(slots)
+    sites = 2 * cases.config("gpt2")["n_layer"] + 2
+    assert drv._packed[step_len][3] == (sites, 0)
+    copied, static = _copies(engine.name)
+    assert copied > 0 and copied % sites == 0 and static == 0
+    assert copied == sites * mx.telemetry.get_metric(
+        "serve.decode.window.dispatches", model=engine.name).value
 
 
 def _spy_on_rewinds(engine):
